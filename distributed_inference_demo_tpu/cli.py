@@ -34,16 +34,17 @@ def _load_tokenizer(path: Optional[str]):
     return Tokenizer.from_file(path)
 
 
-def _load_full_params(args, cfg):
+def _load_full_params(args, cfg, mesh=None):
     """Resolve the full parameter tree for a CLI invocation: checkpoint if
     ``--checkpoint`` was given, else seed-init (int8-quantized during init
-    for ``-int8`` configs).  Shared by the single-node and ``--chain``
-    serve paths so a checkpoint can never be silently ignored on one of
-    them."""
+    for ``-int8`` configs); under a tp ``mesh`` the tree arrives sharded
+    in the engines' layout (seeded weights are born on their shards).
+    Shared by the single-node and ``--chain`` serve paths so a checkpoint
+    can never be silently ignored on one of them."""
     from .models.loader import load_or_init
 
     return load_or_init(args.model, cfg, getattr(args, "checkpoint", None),
-                        seed=args.weights_seed)
+                        seed=args.weights_seed, mesh=mesh)
 
 
 def _sampling_from_args(args):
@@ -67,12 +68,8 @@ def _load_params_for_mesh(args, cfg):
     """(params, mesh): checkpoint-or-seed params, sharded onto the --tp
     mesh when one is requested — the one load+shard sequence shared by
     every engine builder."""
-    params = _load_full_params(args, cfg)
     mesh = _tp_mesh_from_args(args)
-    if mesh is not None:
-        from .runtime.engine import shard_engine_params
-        params = shard_engine_params(params, cfg, mesh)
-    return params, mesh
+    return _load_full_params(args, cfg, mesh), mesh
 
 
 def _load_draft_for_mesh(args, mesh):
@@ -86,10 +83,7 @@ def _load_draft_for_mesh(args, mesh):
         argparse.Namespace(**{**vars(args),
                               "model": args.draft_model,
                               "checkpoint": args.draft_checkpoint}),
-        draft_cfg)
-    if mesh is not None:
-        from .runtime.engine import shard_engine_params
-        draft_params = shard_engine_params(draft_params, draft_cfg, mesh)
+        draft_cfg, mesh)
     return draft_cfg, draft_params
 
 
@@ -962,7 +956,14 @@ def cmd_plan(args) -> int:
 
     with open(args.devices) as f:
         dev_json = json.load(f)
-    devs = [DeviceProfile(**d) for d in dev_json]
+    try:
+        devs = [DeviceProfile(**d) for d in dev_json]
+    except TypeError as e:
+        # a missing flops_per_sec lands here: the planner takes a
+        # measured or stated rate, never a default
+        print(f"bad device profile in {args.devices}: {e}",
+              file=sys.stderr)
+        return 1
     if args.round_robin:
         plan = round_robin_plan(cfg, args.model, devs)
     else:
@@ -1220,7 +1221,7 @@ def _add_engine_args(ap):
                          "and top-p)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--attn-backend", default="auto",
-                    choices=["auto", "flash", "flash-interpret", "jnp"])
+                    choices=["auto", "flash", "jnp"])
     ap.add_argument("--eos-id", type=int, default=None,
                     help="end-of-sequence token id: finished rows pad "
                          "with it and generation stops early once every "
@@ -1355,6 +1356,26 @@ def _add_draft_args(p) -> None:
                    help="pin K_row = --num-draft in the mixed dispatch "
                         "instead of adapting per-row draft length to "
                         "measured acceptance (serve --batch-slots only)")
+
+
+def configure_compile_cache() -> Optional[str]:
+    """Place JAX's persistent compilation cache — the ONE site that
+    does, passed by every entry point before a backend exists.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; nothing is
+    set in code and the directory is returned for the record.  Unset:
+    ``<checkout>/.jax_cache``, derived from this package's own location
+    — never from a temp name, a pid or a time, because the directory is
+    part of how a later process finds what an earlier one compiled."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    import jax
+    cache_dir = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    return cache_dir
 
 
 def main(argv=None) -> int:
@@ -1586,6 +1607,10 @@ def main(argv=None) -> int:
     args.rest = rest
     if args.cmd == "plan" and not (args.devices or args.load):
         ap.error("plan needs --devices or --load")
+    if args.cmd not in ("gateway", "chat", "plan"):
+        # the commands that compile; the gateway, the chat client and
+        # the planner never touch a backend and stay off jax.config
+        configure_compile_cache()
     if args.jax_coordinator:
         from .parallel.mesh import init_multihost
         init_multihost(args.jax_coordinator, args.jax_num_processes,

@@ -1,7 +1,8 @@
 """ctypes bindings for the native wire codec (native/codec.cc).
 
 Byte-compatible with the pure-Python codec in ``wire.py``; `available()`
-gates use so every caller can fall back to Python transparently.  The
+is False only on a machine without a C++ compiler, where callers use
+the Python codec.  The
 reference's equivalent layer is the JNI bridge over ``utils.cpp``
 (``native-lib.cpp:662-694``); here the binding is ctypes because pybind11
 isn't in the image.
@@ -25,12 +26,16 @@ def _load() -> Optional[ctypes.CDLL]:
     global _lib, _load_failed
     if _lib is not None or _load_failed:
         return _lib
+    from .native.build import NativeUnavailable, build
     try:
-        from .native.build import build
-        lib = ctypes.CDLL(str(build()))
-    except Exception:
+        path = build()
+    except NativeUnavailable:
+        # no compiler on this machine: the pure-Python codec serves.  A
+        # build that FAILS, or a library that does not load, raises —
+        # neither is a reason to run the other codec unseen
         _load_failed = True
         return None
+    lib = ctypes.CDLL(str(path))
     u8p = ctypes.POINTER(ctypes.c_uint8)
     u64p = ctypes.POINTER(ctypes.c_uint64)
     lib.dwt_serialized_size.restype = ctypes.c_uint64

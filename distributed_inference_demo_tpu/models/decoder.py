@@ -19,7 +19,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from .._jax_compat import axis_size
 
 from ..ops.attention import alibi_slopes, attention, update_kv_cache
 from ..ops.quant import dense
@@ -291,7 +290,7 @@ def _moe_mlp_ep(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     b, s, H = x.shape
     T = b * s
     E, k = cfg.num_experts, cfg.experts_per_token
-    n = axis_size(ep_axis)
+    n = jax.lax.axis_size(ep_axis)
     e_loc = lp["w_gate"].shape[0]       # E-sliced inside shard_map
     assert e_loc * n == E, (e_loc, n, E)
     xt = x.reshape(T, H)

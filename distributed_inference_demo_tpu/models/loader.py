@@ -11,6 +11,7 @@ Zero-egress environment: loading requires a *local* checkpoint directory.
 Tests use random init instead.
 """
 
+import functools
 import json
 import os
 import re
@@ -294,7 +295,8 @@ def stage_params_from_bytes(data: bytes) -> StageParams:
 
 def load_or_init(model_name: str, cfg: ModelConfig,
                  checkpoint_dir: Optional[str] = None,
-                 seed: int = 0, quantize: bool = True) -> StageParams:
+                 seed: int = 0, quantize: bool = True,
+                 mesh=None) -> StageParams:
     """Load from a local checkpoint if given/found, else random-init.
 
     The random path keeps every test and benchmark runnable with zero
@@ -302,6 +304,14 @@ def load_or_init(model_name: str, cfg: ModelConfig,
     weight-value independent.  ``quantize=False`` returns the float tree
     even for ``-int8`` configs — used by the server app, whose artifact
     channel ships float weights and lets each stage quantize locally.
+
+    ``mesh``: a tp mesh — the tree comes back in the engines' tp layout
+    (``parallel.sharding``, replicated embed).  Seeded weights are BORN
+    sharded: one jit with ``out_shardings`` writes every device's slice
+    in place, because a model sized for N chips (qwen2.5-7b bf16, 15.2
+    GB, on four 16 GB chips) does not fit on device 0 on its way there.
+    The values are those of the unsharded init (the threefry stream
+    does not depend on the partitioning; pinned by a test).
     """
     import jax
     if checkpoint_dir and os.path.isdir(checkpoint_dir):
@@ -312,21 +322,31 @@ def load_or_init(model_name: str, cfg: ModelConfig,
             from ..checkpoint import load_params
             params, _ = load_params(checkpoint_dir, cfg,
                                     model_name=model_name)
-            return params
-        params = params_from_state_dict(load_safetensors_dir(checkpoint_dir),
-                                        cfg)
-    else:
-        # random path: quantize during init (layer-chunked) so peak HBM
-        # stays near the quantized footprint — an 8B -int8/-int4 config
-        # must be initializable on exactly the chips its bf16 tree would
-        # not fit.
-        return init_full_params(
-            jax.random.PRNGKey(seed), cfg,
-            quantize=quantize and cfg.quantization in ("int8", "int4"))
-    if not quantize:
+        else:
+            params = params_from_state_dict(
+                load_safetensors_dir(checkpoint_dir), cfg)
+            if quantize:
+                from ..ops.quant import maybe_quantize
+                params = maybe_quantize(params, cfg)
+        if mesh is not None:
+            from ..parallel.sharding import shard_params
+            params = shard_params(params, cfg, mesh,
+                                  vocab_parallel_embed=False)
         return params
-    from ..ops.quant import maybe_quantize
-    return maybe_quantize(params, cfg)
+    # random path: quantize during init (layer-chunked) so peak HBM
+    # stays near the quantized footprint — an 8B -int8/-int4 config
+    # must be initializable on exactly the chips its bf16 tree would
+    # not fit.
+    init = functools.partial(
+        init_full_params, cfg=cfg,
+        quantize=quantize and cfg.quantization in ("int8", "int4"))
+    rng = jax.random.PRNGKey(seed)
+    if mesh is None:
+        return init(rng)
+    from ..parallel.sharding import stage_param_shardings
+    shardings = stage_param_shardings(jax.eval_shape(init, rng), cfg, mesh,
+                                      vocab_parallel_embed=False)
+    return jax.jit(init, out_shardings=shardings)(rng)
 
 
 # ---------------------------------------------------------------------------

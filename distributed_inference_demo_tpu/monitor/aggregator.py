@@ -60,10 +60,14 @@ class MonitorAggregator:
             bw = (rep.get("bandwidth") or {}).get(nxt, DEFAULT_BANDWIDTH)
             lat = (rep.get("latency") or {}).get(nxt, DEFAULT_LATENCY)
             mem = rep.get("memory") or {}
+            if not rep.get("flops"):
+                raise ValueError(
+                    f"device {dev_id!r} reported no flops measurement; "
+                    "the planner does not invent a compute rate")
             profiles.append(DeviceProfile(
                 device_id=dev_id,
                 address=addresses.get(dev_id, ""),
-                flops_per_sec=rep.get("flops") or 1e12,
+                flops_per_sec=rep["flops"],
                 memory_bytes=int(mem.get("available")
                                  or mem.get("total") or (16 << 30)),
                 platform=rep.get("platform", "cpu"),

@@ -60,7 +60,8 @@ def _kernel(scalar_ref, q_ref, k_ref, v_ref, slopes_ref, o_ref,
     scalar_ref (SMEM, int32[2]): [q_start, kv_len].
     q_ref:      [1, 1, rows_blk, hd]   (rows = chunk * groups)
     k_ref/v_ref:[1, 1, block_k, hd]    (one streamed block of the kv plane)
-    slopes_ref: [1, 1, groups] f32     (ALiBi slopes of this head group)
+    slopes_ref: [1, rows_blk, 1] f32   (per-row ALiBi slope, a sublane
+                                        column — see flash_attention)
     o_ref:      [1, 1, rows_blk, hd]
     scratch: o_acc [rows_blk, hd] f32; m_acc/l_acc [rows_blk, 128] f32
     (lane-broadcast storage).
@@ -97,8 +98,7 @@ def _kernel(scalar_ref, q_ref, k_ref, v_ref, slopes_ref, o_ref,
                   + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1))
         valid = (kv_pos <= q_pos) & (kv_pos < kv_len)     # [rows, bk]
         if use_alibi:
-            slope = slopes_ref[0, 0, :]                   # [groups]
-            slope_row = jnp.tile(slope, rows_blk // groups)[:, None]
+            slope_row = slopes_ref[0, :, :]               # [rows_blk, 1]
             s = s - slope_row * (q_pos - kv_pos).astype(jnp.float32)
         s = jnp.where(valid, s, _NEG)
 
@@ -143,12 +143,12 @@ def _pick_block(total: int, target: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "block_rows",
-                                             "use_alibi", "interpret"))
+                                             "groups", "use_alibi",
+                                             "interpret"))
 def _flash_call(q_g, k_cache, v_cache, scalars, slopes, *, block_k,
-                block_rows, use_alibi, interpret):
+                block_rows, groups, use_alibi, interpret):
     b, nkv, rows, hd = q_g.shape
     max_seq = k_cache.shape[2]
-    groups = slopes.shape[2]
     grid = (b, nkv, rows // block_rows, max_seq // block_k)
 
     def kv_map(bb, h, r, ki, s):
@@ -168,7 +168,7 @@ def _flash_call(q_g, k_cache, v_cache, scalars, slopes, *, block_k,
                              lambda bb, h, r, ki, s: (bb, h, r, 0)),
                 pl.BlockSpec((1, 1, block_k, hd), kv_map),
                 pl.BlockSpec((1, 1, block_k, hd), kv_map),
-                pl.BlockSpec((1, 1, groups),
+                pl.BlockSpec((1, block_rows, 1),
                              lambda bb, h, r, ki, s: (h, 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, 1, block_rows, hd),
@@ -216,11 +216,6 @@ def flash_attention(
     q_g = q.reshape(b, chunk, nkv, g, hd).transpose(0, 2, 1, 3, 4)
     q_g = q_g.reshape(b, nkv, chunk * g, hd)
 
-    if slopes is None:
-        slopes_g = jnp.zeros((nkv, 1, g), jnp.float32)  # zero slope: no bias
-    else:
-        slopes_g = slopes.astype(jnp.float32).reshape(nkv, 1, g)
-
     bk = _pick_block(max_seq, block_k)
     # Row blocks must hold whole query groups (so q_pos stays block-affine)
     # and satisfy the TPU sublane constraint: divisible by 8, or the whole
@@ -231,9 +226,19 @@ def flash_attention(
     br = d * g if (d * g) % 8 == 0 and chunk % d == 0 else chunk * g
     scalars = jnp.stack([jnp.asarray(q_start, jnp.int32),
                          jnp.asarray(kv_len, jnp.int32)])
+    # per-row slope as a [br, 1] sublane COLUMN built out here: row r of
+    # a block is group member r % g (blocks hold whole groups), so one
+    # column serves every row block of a kv head.  Tiling the g-vector
+    # inside the kernel is a lane->sublane relayout Mosaic refuses
+    # ("Input offsets outside of the first tile", MHA at chunk 256).
+    if slopes is None:
+        slopes_g = jnp.zeros((nkv, br, 1), jnp.float32)  # zero: no bias
+    else:
+        slopes_g = jnp.tile(slopes.astype(jnp.float32).reshape(nkv, g),
+                            (1, br // g))[:, :, None]
 
     out = _flash_call(q_g, k_cache, v_cache, scalars, slopes_g,
-                      block_k=bk, block_rows=br,
+                      block_k=bk, block_rows=br, groups=g,
                       use_alibi=slopes is not None, interpret=interpret)
     out = out.reshape(b, nkv, chunk, g, hd).transpose(0, 2, 1, 3, 4)
     return out.reshape(b, chunk, nh, hd)

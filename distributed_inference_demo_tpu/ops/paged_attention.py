@@ -537,60 +537,120 @@ def paged_prefill_attention(
 # ---------------------------------------------------------------------------
 # the attn_impl seam (models/decoder.py hook)
 
+PATH_DECODE_KERNEL = "pallas_decode"
+PATH_PREFILL_KERNEL = "pallas_prefill"
+PATH_GATHER = "gather"
+
+
+class AttnPathRecord:
+    """Which attention path each compiled program took, and why.
+
+    The routing below is a Python decision made while a program is
+    TRACED (chunk length, page dtype and page size are static), so a
+    program that quietly compiled onto the XLA gather looks exactly
+    like one on the kernels from outside.  Every engine owns one record,
+    passes it down the seam, and serves :meth:`snapshot` under
+    ``/stats["attention_paths"]``: ``{program: {"chunk=N": path}}`` with
+    ``path`` one of ``pallas_decode`` / ``pallas_prefill`` /
+    ``gather: <reason>``."""
+
+    def __init__(self):
+        self._paths: dict = {}
+
+    def note(self, program: str, chunk: int, path: str, why: str) -> None:
+        entry = path if not why else f"{path}: {why}"
+        self._paths.setdefault(program, {})[f"chunk={chunk}"] = entry
+
+    def snapshot(self) -> dict:
+        # tracing runs on the scheduler thread, /stats on an HTTP
+        # thread: list() and dict() each copy in one step under the GIL
+        return {prog: dict(chunks)
+                for prog, chunks in list(self._paths.items())}
+
+
+def route_paged_attention(backend: str, platform: str, k_pages,
+                          chunk: int, groups: int):
+    """``(path, why)`` for one traced attention call — the ONE routing
+    rule, a pure function of what the trace can see.
+
+    ``backend`` "xla" always gathers; "auto" takes a kernel on TPU when
+    the kernel covers the shape and gathers otherwise (``why`` says
+    which gate refused); "pallas" is an explicit request and RAISES
+    where "auto" would have gathered for a shape reason — honor or
+    reject, never a silent downgrade.  Gates: int4 pages never take the
+    kernel (nibble unpack in the lane dimension); int8 pages need
+    ``block_tokens % 32 == 0`` on real hardware (the int8 tile is 32
+    sublanes; forced-"pallas" runs interpret and may use smaller
+    pages); every page needs ``block_tokens % 8 == 0``; the prefill
+    kernel holds ``chunk x group`` query rows in VMEM and stops at
+    ``PREFILL_KERNEL_MAX_ROWS``."""
+    if backend == "xla":
+        return PATH_GATHER, "backend=xla"
+    if backend == "auto" and platform != "tpu":
+        return PATH_GATHER, f"backend=auto on platform={platform}"
+    bt = k_pages.shape[2]
+    why = ""
+    if isinstance(k_pages, QuantizedKVPages) and k_pages.bits != 8:
+        why = f"int{k_pages.bits} pages have no kernel"
+    elif bt % 8:
+        why = f"block_tokens={bt} is not a multiple of 8"
+    elif (isinstance(k_pages, QuantizedKVPages) and bt % 32
+          and backend != "pallas"):
+        why = (f"int8 pages need block_tokens % 32 == 0 on the chip, "
+               f"got {bt}")
+    elif chunk > 1 and -(-(chunk * groups) // 8) * 8 > PREFILL_KERNEL_MAX_ROWS:
+        why = (f"chunk {chunk} x group {groups} = {chunk * groups} query "
+               f"rows > PREFILL_KERNEL_MAX_ROWS={PREFILL_KERNEL_MAX_ROWS}")
+    if why:
+        if backend == "pallas":
+            raise ValueError(f"paged attention backend 'pallas' cannot "
+                             f"take this shape: {why}")
+        return PATH_GATHER, why
+    return (PATH_DECODE_KERNEL if chunk == 1 else PATH_PREFILL_KERNEL), ""
+
 
 def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
-                         interpret: bool = False):
+                         interpret: bool = False,
+                         record: Optional[AttnPathRecord] = None):
     """``(impl, bind)``: an attention hook for paged-layout caches plus
     the binder that hands it the block tables.
 
     The decoder's ``attn_impl`` signature has no table slot, so the
     caller's jitted program binds the traced table array immediately
-    before invoking the forward — ``bind(tables)`` at the top of the
-    traced body, then ``fwd(...)``; the impl reads the binding during
-    tracing (the layer scan closes over it as a loop constant).
+    before invoking the forward — ``bind(tables, program)`` at the top
+    of the traced body, then ``fwd(...)``; the impl reads the binding
+    during tracing (the layer scan closes over it as a loop constant).
+    ``program`` names the compiled program being traced; the path each
+    of its attention calls takes lands in ``record`` under that name.
 
     ``backend``: "auto" (Pallas on TPU, XLA gather elsewhere), "xla", or
-    "pallas".  The Pallas decode kernel covers 1-token chunks and the
-    prefill kernel covers multi-token chunks up to
-    ``PREFILL_KERNEL_MAX_ROWS`` query rows, both with 8-aligned pages;
-    anything else takes the gather path.
+    "pallas" — the rule is :func:`route_paged_attention`.
     """
     if backend not in ("auto", "xla", "pallas"):
         raise ValueError(f"unknown paged attention backend {backend!r}; "
                          "expected 'auto', 'xla', or 'pallas'")
     bound = {}
 
-    def bind(tables):
+    def bind(tables, program: str):
         bound["tables"] = tables
+        bound["program"] = program
 
     def impl(q, k, v, k_pages, v_pages, positions, cache_start, slopes):
         tables = bound["tables"]
         k_pages, v_pages = write_paged_kv(k_pages, v_pages, k, v,
                                           tables, positions)
-        use_pallas = (backend == "pallas"
-                      or (backend == "auto"
-                          and jax.default_backend() == "tpu"))
-        # the pool's own type selects the numerics — no kv_dtype
-        # threading through the seam: int4 never takes the kernel, int8
-        # needs 32-aligned pages on real hardware (the int8 min tile's
-        # sublane granule; forced-"pallas" test runs interpret and may
-        # use smaller pages)
-        bt = k_pages.shape[2]
-        if isinstance(k_pages, QuantizedKVPages):
-            kernel_ok = (k_pages.bits == 8
-                         and (bt % 32 == 0 or backend == "pallas")
-                         and bt % 8 == 0)
-        else:
-            kernel_ok = bt % 8 == 0
         chunk = q.shape[1]
-        groups = q.shape[2] // k.shape[2]
-        if (use_pallas and chunk == 1 and kernel_ok):
+        path, why = route_paged_attention(
+            backend, jax.default_backend(), k_pages, chunk,
+            q.shape[2] // k.shape[2])
+        if record is not None:
+            record.note(bound["program"], chunk, path, why)
+        if path == PATH_DECODE_KERNEL:
             kv_lens = positions[:, -1] + 1
             out = paged_flash_attention(q, k_pages, v_pages, tables,
                                         kv_lens, slopes,
                                         interpret=interpret)
-        elif (use_pallas and chunk > 1 and kernel_ok
-              and -(-(chunk * groups) // 8) * 8 <= PREFILL_KERNEL_MAX_ROWS):
+        elif path == PATH_PREFILL_KERNEL:
             out = paged_prefill_attention(q, k_pages, v_pages, tables,
                                           positions, slopes,
                                           interpret=interpret)

@@ -308,6 +308,29 @@ def alloc_kv_pages(shape, kv_dtype: Optional[str], base_dtype):
         zero=jnp.zeros((*lead, 1), jnp.float32), bits=4)
 
 
+def alloc_kv_pool(shape, kv_dtype: Optional[str], base_dtype,
+                  pool_sharding=None):
+    """``(k_pool, v_pool)``, both zeroed :func:`alloc_kv_pages` tensors.
+
+    ``pool_sharding`` (the paged seam's ``KVCache`` of NamedShardings,
+    or None off-mesh): the pools are BORN on their kv-head shards — a
+    jit with ``out_shardings`` writes each device's slice in place.
+    Allocating whole and ``device_put``-ing afterwards would first
+    materialize the full pool on device 0, which a pool sized for N
+    chips does not fit.  The one sharding per pool broadcasts over the
+    quantized layouts' data/scale/zero leaves (all keep the
+    ``[L, N, H(tp), bt, ·]`` axis order), so scales shard WITH their
+    pages."""
+    def alloc():
+        return (alloc_kv_pages(shape, kv_dtype, base_dtype),
+                alloc_kv_pages(shape, kv_dtype, base_dtype))
+
+    if pool_sharding is None:
+        return alloc()
+    return jax.jit(alloc, out_shardings=(pool_sharding.keys,
+                                         pool_sharding.values))()
+
+
 def kv_token_head_bytes(head_dim: int, kv_dtype: Optional[str],
                         base_dtype) -> int:
     """Bytes one (token, kv-head) of ONE tensor (K or V) occupies in the

@@ -29,7 +29,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from .._jax_compat import axis_size
 
 _NEG = -1e30
 
@@ -100,7 +99,7 @@ def ring_self_attention(
     the ring; after ``sp_size`` steps every device has attended its queries
     to every causally-visible key.  Returns [b, lq, nh, hd] in q.dtype.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, lq, nh, hd = q.shape
     nkv = k.shape[2]

@@ -15,7 +15,6 @@ the only cross-chip traffic is the two all_to_alls per MoE layer.
 
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
-from .compat import shard_map
 
 from ..models.base import KVCache, ModelConfig, StageParams, StageSpec
 from ..models.decoder import stage_forward
@@ -87,7 +86,7 @@ def make_ep_stage_fn(cfg: ModelConfig, spec: StageSpec, mesh: Mesh,
             raise ValueError(
                 f"batch={inputs.shape[0]} not divisible by ep={ep} "
                 "(tokens are data-parallel over the ep axis)")
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(p_specs, data, _CACHE_SPEC, data),
             out_specs=(data, _CACHE_SPEC),

@@ -26,7 +26,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from .compat import axis_size, shard_map
 
 from ..models.base import KVCache, ModelConfig, StageParams, StageSpec
 from .sharding import stage_param_spec_tree
@@ -102,7 +101,7 @@ def pipeline_apply(
     same program (SPMD); first/last-stage roles are data selections, not
     control flow.
     """
-    S = axis_size(pp_axis)
+    S = jax.lax.axis_size(pp_axis)
     my = jax.lax.axis_index(pp_axis)
     is_first = my == 0
     is_last = my == S - 1
@@ -344,7 +343,7 @@ def make_pipeline_generate_fn(cfg: ModelConfig, mesh: Mesh, *,
         return out
 
     def fn(params, ids_mb, rng):
-        sharded = shard_map(
+        sharded = jax.shard_map(
             body, mesh=mesh,
             in_specs=(_pp_in_specs(params, cfg, use_tp), P(), P()),
             out_specs=P(),
@@ -404,7 +403,7 @@ def make_pipeline_train_step(cfg: ModelConfig, mesh: Mesh, optimizer,
             return loss, grads
 
         data_spec = P(None, "dp")  # [M, batch, seq]: batch over dp
-        sharded = shard_map(
+        sharded = jax.shard_map(
             sm_loss_and_grads, mesh=mesh,
             in_specs=(in_specs_params, data_spec, data_spec),
             out_specs=(P(), in_specs_params),

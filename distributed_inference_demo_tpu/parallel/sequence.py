@@ -23,7 +23,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from .compat import axis_size, shard_map
 
 from ..models.base import KVCache, ModelConfig, StageSpec
 from ..models.decoder import stage_forward
@@ -78,7 +77,7 @@ def _wrap_sp_body(body, mesh: Mesh, sp: int, max_seq: int,
                   num_new_tokens: int):
     """shard_map + jit + host-side shape validation, shared by both
     sequence-parallel strategies (prompt sharded over sp's seq axis)."""
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(None, "sp"), P()),
         out_specs=P(),
@@ -164,7 +163,7 @@ def _make_ring_cores(cfg: ModelConfig, spec: StageSpec, s_loc: int,
     cache_dtype = kv_dtype if kv_dtype is not None else cfg.dtype
 
     def prefill_core(params, ids, rng):
-        n = axis_size("sp")
+        n = jax.lax.axis_size("sp")
         idx = jax.lax.axis_index("sp")
         b, chunk = ids.shape
 
@@ -204,7 +203,7 @@ def _make_ring_cores(cfg: ModelConfig, spec: StageSpec, s_loc: int,
     def step_core(params, carry, step_rng):
         # ---- decode: sharded cache + lse-combined partial attention -----
         kc_all, vc_all, kv_pos, plen, length, tok = carry
-        n = axis_size("sp")
+        n = jax.lax.axis_size("sp")
         idx = jax.lax.axis_index("sp")
         b = tok.shape[0]
         chunk = plen // n
@@ -310,11 +309,11 @@ def _wrap_stream_fns(prefill_core, step_core, mesh: Mesh, state_specs,
                                    jax.random.split(rng, block))
         return (*carry, jnp.swapaxes(toks, 0, 1))       # [b, block]
 
-    prefill_fn = jax.jit(shard_map(
+    prefill_fn = jax.jit(jax.shard_map(
         prefill_body, mesh=mesh,
         in_specs=(P(), P(None, "sp"), P()),
         out_specs=(*state_specs, P()), check_vma=False))
-    decode_fn = jax.jit(shard_map(
+    decode_fn = jax.jit(jax.shard_map(
         decode_body, mesh=mesh,
         in_specs=(P(), *state_specs, P()),
         out_specs=(*state_specs, P()), check_vma=False),

@@ -13,7 +13,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.base import KVCache, ModelConfig, StageParams, StageSpec
 from ..models.decoder import stage_forward
-from .compat import shard_map
 from .sharding import stage_param_spec_tree
 
 
@@ -73,28 +72,13 @@ def make_tp_forward(cfg: ModelConfig, spec: StageSpec, mesh: Mesh,
             return stage_forward(p, cfg, spec, i, c, po, tp_axis="tp",
                                  attn_impl=attn_impl,
                                  last_logits_only=last_logits_only)
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(p_specs, P(), _CACHE_SPEC, P()),
             out_specs=(P(), _CACHE_SPEC),
             check_vma=False)(p, inputs, cache, positions)
 
     return fwd
-
-
-def resolve_tp_attn_backend(tp: int, attn_backend: str) -> str:
-    """The one rule for attention backends under a tp mesh: force jnp
-    (the Pallas kernel is not exercised per-shard), rejecting an explicit
-    non-jnp request rather than silently downgrading it.  Shared by every
-    engine that takes ``mesh=``."""
-    if tp > 1:
-        if attn_backend not in ("auto", "jnp"):
-            raise ValueError(
-                f"attn_backend={attn_backend!r} is incompatible with a tp "
-                "mesh (the Pallas kernel is not exercised per-shard); use "
-                "'auto' or 'jnp'")
-        return "jnp"
-    return attn_backend
 
 
 def make_forward_seam(cfg: ModelConfig, spec: StageSpec, mesh,
@@ -120,25 +104,31 @@ def make_forward_seam(cfg: ModelConfig, spec: StageSpec, mesh,
 
 def make_paged_forward_seam(cfg: ModelConfig, spec: StageSpec, mesh,
                             params_template: StageParams,
-                            block_tokens: int, backend: str = "auto"):
+                            block_tokens: int, backend: str = "auto",
+                            interpret: bool = False, record=None):
     """``(fwd, bind, pool_sharding)`` for a PAGED-cache engine: the
     forward runs ``ops.paged_attention``'s block-table hook over a page
     pool ``[L, N, H, bt, D]`` standing in for the dense cache buffers.
 
-    ``bind(tables)`` hands the current dispatch's block tables to the
-    hook — call it at the top of the caller's jitted body, before the
-    first ``fwd``.  Off-mesh, the hook reads the binding by closure (a
-    loop constant of the trace).  Under a tp mesh the tables are
-    threaded through ``shard_map`` as an explicit replicated argument
-    instead — shard_map bodies must not close over traced values — and
-    the pool shards by kv head exactly like the dense cache
-    (``_CACHE_SPEC``: axis 2 either way), so each chip pages only its
-    own head planes.  The one paged-dispatch rule shared by the
-    batching scheduler and the ring stage runtimes."""
+    ``bind(tables, program)`` hands the current dispatch's block tables
+    to the hook and names the compiled program being traced — call it
+    at the top of the caller's jitted body, before the first ``fwd``.
+    Off-mesh, the hook reads the binding by closure (a loop constant of
+    the trace).  Under a tp mesh the tables are threaded through
+    ``shard_map`` as an explicit replicated argument instead —
+    shard_map bodies must not close over traced values — and the pool
+    shards by kv head exactly like the dense cache (``_CACHE_SPEC``:
+    axis 2 either way), so each chip pages only its own head planes and
+    the attention kernels run per shard on ``nkv / tp`` heads.
+    ``backend`` / ``interpret`` / ``record`` go to
+    ``make_paged_attn_impl`` unchanged, on-mesh and off: the path every
+    program took is in ``record``.  The one paged-dispatch rule shared
+    by the batching scheduler and the ring stage runtimes."""
     from ..ops.paged_attention import make_paged_attn_impl
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
     if tp <= 1:
-        impl, bind = make_paged_attn_impl(block_tokens, backend)
+        impl, bind = make_paged_attn_impl(block_tokens, backend,
+                                          interpret, record)
 
         def fwd(p, inputs, cache, positions, last_logits_only):
             return stage_forward(p, cfg, spec, inputs, cache, positions,
@@ -150,20 +140,22 @@ def make_paged_forward_seam(cfg: ModelConfig, spec: StageSpec, mesh,
     p_specs = _tp_param_specs(params_template, cfg)
     bound = {}
 
-    def bind(tables):
+    def bind(tables, program: str):
         bound["tables"] = tables
+        bound["program"] = program
 
     def fwd(p, inputs, cache, positions, last_logits_only):
+        program = bound["program"]
+
         def body(p_, i_, c_, po_, tab_):
-            # the Pallas kernel is not exercised per-shard (the dense
-            # tp rule, resolve_tp_attn_backend) — force the XLA gather
-            impl, bind_local = make_paged_attn_impl(block_tokens, "xla")
-            bind_local(tab_)
+            impl, bind_local = make_paged_attn_impl(block_tokens, backend,
+                                                    interpret, record)
+            bind_local(tab_, program)
             return stage_forward(p_, cfg, spec, i_, c_, po_,
                                  tp_axis="tp", attn_impl=impl,
                                  last_logits_only=last_logits_only)
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(p_specs, P(), _CACHE_SPEC, P(), P()),
             out_specs=(P(), _CACHE_SPEC),

@@ -28,7 +28,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
-from .compat import axis_size
 
 from ..models.base import KVCache, ModelConfig, StageSpec
 from ..models.decoder import stage_forward
@@ -98,7 +97,7 @@ def _make_ulysses_cores(cfg: ModelConfig, max_seq: int, sp: int,
                                             nh_loc, axis=0)
 
     def prefill_core(params, ids, rng):
-        n = axis_size("sp")
+        n = jax.lax.axis_size("sp")
         idx = jax.lax.axis_index("sp")
         b, chunk = ids.shape            # local contiguous prompt chunk
         S = n * chunk

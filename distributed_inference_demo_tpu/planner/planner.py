@@ -41,7 +41,10 @@ class DeviceProfile:
 
     device_id: str
     address: str
-    flops_per_sec: float = 1e12
+    # measured (monitor flops probe) or stated by the operator — never
+    # defaulted: a made-up rate would plan layers onto a device nobody
+    # timed
+    flops_per_sec: float
     memory_bytes: int = 16 << 30
     platform: str = "cpu"              # cpu | tpu
     chips: int = 1                     # TPU chips for intra-stage tp

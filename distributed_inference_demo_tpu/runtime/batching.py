@@ -470,20 +470,16 @@ class ContinuousBatchingEngine:
         N = self.kv_cache.num_blocks
         self._page_sentinel = N
         page_dtype = self.kv_cache_dtype or cfg.dtype
+        # which attention path each compiled program took, written at
+        # trace time and served under /stats["attention_paths"]
+        from ..ops.paged_attention import AttnPathRecord
+        self.attn_paths = AttnPathRecord()
         fwd_p, bind_tables, pool_sharding = make_paged_forward_seam(
-            cfg, self.spec, mesh, params, bt)
-        from ..ops.quant import alloc_kv_pages
-        self._pk = alloc_kv_pages(
+            cfg, self.spec, mesh, params, bt, record=self.attn_paths)
+        from ..ops.quant import alloc_kv_pool
+        self._pk, self._pv = alloc_kv_pool(
             (cfg.num_layers, N, cfg.num_kv_heads, bt, cfg.head_dim),
-            self.kv_dtype, page_dtype)
-        self._pv = jax.tree.map(jnp.zeros_like, self._pk)
-        if pool_sharding is not None:
-            # a single NamedSharding broadcasts over the pool's leaves:
-            # the quantized layouts' data/scale/zero all keep the
-            # [L, N, H(tp), bt, ·] axis order, so the kv-head spec
-            # shards scales WITH their pages
-            self._pk = jax.device_put(self._pk, pool_sharding.keys)
-            self._pv = jax.device_put(self._pv, pool_sharding.values)
+            self.kv_dtype, page_dtype, pool_sharding)
         self._tables = np.full((B, self._table_width), N, np.int32)
         # write_row_to_pages survives for the DRAFT side only: the draft
         # prefill still runs a dense temp row (the draft is small by
@@ -535,7 +531,7 @@ class ContinuousBatchingEngine:
         @partial(jax.jit, donate_argnums=(1, 2))
         def paged_step(params, pk, pv, tables, lengths, last_tok,
                        active, rng):
-            bind_tables(tables)
+            bind_tables(tables, "paged_step")
             cache = KVCache(pk, pv, jnp.zeros((), jnp.int32))
             cache, lengths, tok, lp = paged_one_step(
                 params, cache, lengths, last_tok, active, rng)
@@ -547,9 +543,8 @@ class ContinuousBatchingEngine:
             """The device-resident fused-block loop shared by the dense
             and paged multi-step jits (docs/DESIGN.md §13): up to
             ``num_steps`` lockstep steps in one dispatch (one host sync
-            per BLOCK, not per token — on a device with ~15 ms dispatch
-            latency this is the difference between ~100 tok/s and the
-            HBM roofline), with EARLY EXIT the moment every active row
+            per BLOCK, not per token), with EARLY EXIT the moment every
+            active row
             is done — eos'd on device, or out of its remaining token
             ``budget`` — so a block whose rows all finish at step
             j < num_steps stops after j steps instead of decoding into
@@ -606,7 +601,7 @@ class ContinuousBatchingEngine:
             others run keep writing — through their own still-reserved
             pages, or through sentinel entries that drop the write (the
             paged stale-slot route)."""
-            bind_tables(tables)
+            bind_tables(tables, "paged_multi_step")
             cache = KVCache(pk, pv, jnp.zeros((), jnp.int32))
             cache, lengths, tok, toks, lps, steps = _fused_loop(
                 paged_one_step, params, cache, lengths, last_tok,
@@ -645,7 +640,7 @@ class ContinuousBatchingEngine:
             pages (or sentinel-drop past the reservation), and decode
             overwrites each such position before any query can attend
             it (stale-slot invariant above)."""
-            bind_tables(table)
+            bind_tables(table, "paged_prefill")
             b, s = ids.shape
             pos = start + jnp.broadcast_to(jnp.arange(s), (b, s))
             cache = KVCache(pk, pv, jnp.zeros((), jnp.int32))
@@ -731,7 +726,8 @@ class ContinuousBatchingEngine:
                 B_ = last_tok.shape[0]
                 cache = KVCache(pk, pv, jnp.zeros((), jnp.int32))
                 logits, cache = slab_body(params, cache, seg_ids,
-                                          seg_tables, seg_starts)
+                                          seg_tables, seg_starts,
+                                          "mixed_step")
                 if with_finals:
                     final_toks, final_lps = slab_finals(
                         logits, seg_lens, seg_keys)
@@ -751,7 +747,7 @@ class ContinuousBatchingEngine:
                     final_toks = jnp.zeros((n_seg,), jnp.int32)
                     final_lps = jnp.zeros((n_seg,), jnp.float32)
                     done0 = None
-                bind_tables(dec_tables)
+                bind_tables(dec_tables, "mixed_step")
                 cache, lengths, tok, toks, lps, steps = _fused_loop(
                     paged_one_step, params, cache, lengths, last_tok,
                     active, dec_rng, eos, budget, num_steps,
@@ -812,7 +808,7 @@ class ContinuousBatchingEngine:
                 K/V lands in each row's own reserved pages (the slack
                 columns folded into S cover the fused overshoot)."""
                 b = last_tok.shape[0]
-                bind_tables(tables)
+                bind_tables(tables, "pld_step")
                 cache = KVCache(pk, pv, jnp.zeros((), jnp.int32))
 
                 def one_round(carry, sub):
@@ -884,7 +880,8 @@ class ContinuousBatchingEngine:
                     b = last_tok.shape[0]
                     cache = KVCache(pk, pv, jnp.zeros((), jnp.int32))
                     logits, cache = slab_body(params, cache, seg_ids,
-                                              seg_tables, seg_starts)
+                                              seg_tables, seg_starts,
+                                              "mixed_pld_step")
                     if with_finals:
                         final_toks, final_lps = slab_finals(
                             logits, seg_lens, seg_keys)
@@ -895,7 +892,7 @@ class ContinuousBatchingEngine:
                     else:
                         final_toks = jnp.zeros((n_seg,), jnp.int32)
                         final_lps = jnp.zeros((n_seg,), jnp.float32)
-                    bind_tables(dec_tables)
+                    bind_tables(dec_tables, "mixed_pld_step")
 
                     def one_round(carry, sub):
                         cache, history, lengths, last_tok = carry
@@ -964,7 +961,8 @@ class ContinuousBatchingEngine:
                                dcache_sharding.values))
             fwd_dp, bind_dtables, dpool_sharding = \
                 make_paged_forward_seam(draft_cfg, dspec, mesh,
-                                        draft_params, bt)
+                                        draft_params, bt,
+                                        record=self.attn_paths)
             # the draft page pool: pure per-request SCRATCH — no radix
             # tree ever adopts draft pages (only the target's logits
             # gate emission, so reuse is a target-side property); the
@@ -976,15 +974,10 @@ class ContinuousBatchingEngine:
                 kv_dtype=self.kv_dtype)
             ND = self._dmgr.num_blocks
             self._dpage_sentinel = ND
-            self._dpk = alloc_kv_pages(
+            self._dpk, self._dpv = alloc_kv_pool(
                 (draft_cfg.num_layers, ND, draft_cfg.num_kv_heads, bt,
-                 draft_cfg.head_dim), self.kv_dtype, page_dtype)
-            self._dpv = jax.tree.map(jnp.zeros_like, self._dpk)
-            if dpool_sharding is not None:
-                self._dpk = jax.device_put(self._dpk,
-                                           dpool_sharding.keys)
-                self._dpv = jax.device_put(self._dpv,
-                                           dpool_sharding.values)
+                 draft_cfg.head_dim), self.kv_dtype, page_dtype,
+                dpool_sharding)
             self._dtables = np.full((B, self._table_width), ND, np.int32)
 
             @partial(jax.jit, donate_argnums=(2, 3, 4, 5),
@@ -1000,8 +993,8 @@ class ContinuousBatchingEngine:
                 to drain; inactive rows advance by 0 and keep
                 last_tok."""
                 b = last_tok.shape[0]
-                bind_tables(tables)
-                bind_dtables(dtables)
+                bind_tables(tables, "spec_step")
+                bind_dtables(dtables, "spec_step/draft")
                 cache = KVCache(pk, pv, jnp.zeros((), jnp.int32))
                 dcache = KVCache(dpk, dpv, jnp.zeros((), jnp.int32))
 
@@ -1098,7 +1091,8 @@ class ContinuousBatchingEngine:
                     b = last_tok.shape[0]
                     cache = KVCache(pk, pv, jnp.zeros((), jnp.int32))
                     logits, cache = slab_body(params, cache, seg_ids,
-                                              seg_tables, seg_starts)
+                                              seg_tables, seg_starts,
+                                              "mixed_spec_step")
                     if with_finals:
                         final_toks, final_lps = slab_finals(
                             logits, seg_lens, seg_keys)
@@ -1109,8 +1103,8 @@ class ContinuousBatchingEngine:
                     else:
                         final_toks = jnp.zeros((n_seg,), jnp.int32)
                         final_lps = jnp.zeros((n_seg,), jnp.float32)
-                    bind_tables(dec_tables)
-                    bind_dtables(dec_dtables)
+                    bind_tables(dec_tables, "mixed_spec_step")
+                    bind_dtables(dec_dtables, "mixed_spec_step/draft")
                     dcache = KVCache(dpk, dpv, jnp.zeros((), jnp.int32))
 
                     def one_round(carry, sub):
@@ -1301,6 +1295,7 @@ class ContinuousBatchingEngine:
         self._kv_bytes_per_token = max(
             1, self.kv_cache.block_bytes // self.kv_cache.block_tokens)
         self._running = True
+        self._scheduler_error: Optional[str] = None
         # serializes submit() against close(): no request can be enqueued
         # after close() returns, so none can slip past the shutdown drain
         self._submit_lock = threading.Lock()
@@ -2024,6 +2019,9 @@ class ContinuousBatchingEngine:
         # steps/dispatches ≈ decode_block when fusion is engaging
         out["device_loop"] = dict(self.loop_stats,
                                   decode_block=self.decode_block)
+        # kernel routing made visible: per compiled program, which
+        # attention path each of its chunk shapes was traced onto
+        out["attention_paths"] = self.attn_paths.snapshot()
         # completed is the MONOTONIC count; the reservoirs are bounded
         # (the last 512 samples feed the percentiles).  deque.__copy__ is
         # atomic under the GIL — plain iteration would race the
@@ -2101,6 +2099,17 @@ class ContinuousBatchingEngine:
         # observation its own stats() build would trigger.
         self.anomaly.observe(out)
         return out
+
+    def health(self) -> dict:
+        """``/health`` fragment: "ok" while the scheduler thread serves;
+        once it has died (a failed dispatch drained every request with
+        the error) the status says so and carries that error."""
+        if self._scheduler_error is not None:
+            return {"status": "scheduler_dead",
+                    "error": self._scheduler_error}
+        if not (self._running and self._thread.is_alive()):
+            return {"status": "closed"}
+        return {"status": "ok"}
 
     def debug_state(self) -> dict:
         """Backend fragment of ``GET /debugz``: anomaly-detector state
@@ -3360,6 +3369,7 @@ class ContinuousBatchingEngine:
             # drain runs, so none can slip past onto the dead thread.
             # The flight ring holds the admissions/steps leading up to
             # the failure; capture them before the drain mutates state.
+            self._scheduler_error = f"{type(e).__name__}: {e}"
             self._flight.record("scheduler_crash",
                                 error=type(e).__name__, detail=str(e))
             postmortem.trigger(

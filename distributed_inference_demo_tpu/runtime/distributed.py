@@ -130,17 +130,11 @@ class StageRuntime:
                 from .engine import shard_engine_params
                 params = shard_engine_params(params, cfg, mesh)
             self.params = params
-            from ..ops.quant import alloc_kv_pages
+            from ..ops.quant import alloc_kv_pool
             page_dtype = self.kv_cache_dtype or cfg.dtype
-            self._pk = alloc_kv_pages(
+            self._pk, self._pv = alloc_kv_pool(
                 (spec.num_layers, n_blocks, cfg.num_kv_heads, bt,
-                 cfg.head_dim), self.kv_dtype, page_dtype)
-            self._pv = jax.tree.map(jnp.zeros_like, self._pk)
-            if pool_sharding is not None:
-                # single sharding broadcasts over the (possibly
-                # quantized) leaf subtree — sidecars shard with pages
-                self._pk = jax.device_put(self._pk, pool_sharding.keys)
-                self._pv = jax.device_put(self._pv, pool_sharding.values)
+                 cfg.head_dim), self.kv_dtype, page_dtype, pool_sharding)
             self._sentinel = n_blocks
             self._pool_free = list(range(n_blocks - 1, -1, -1))
             self._tables: Dict[int, np.ndarray] = {}
@@ -149,7 +143,7 @@ class StageRuntime:
 
             @jax.jit
             def forward_p(params, inputs, pk, pv, table, length):
-                bind(table)
+                bind(table, "stage_forward")
                 cache = KVCache(pk, pv, length)
                 b, s = inputs.shape[0], inputs.shape[1]
                 pos = length + jnp.broadcast_to(jnp.arange(s), (b, s))
@@ -163,7 +157,7 @@ class StageRuntime:
                 """Paged tail hot path: layer range + LM head + in-jit
                 sampling in ONE dispatch over the page pool — same rng,
                 same sample_logits as the split pair (§13)."""
-                bind(table)
+                bind(table, "stage_forward_sample")
                 cache = KVCache(pk, pv, length)
                 b, s = inputs.shape[0], inputs.shape[1]
                 pos = length + jnp.broadcast_to(jnp.arange(s), (b, s))

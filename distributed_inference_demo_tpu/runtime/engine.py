@@ -15,7 +15,7 @@ collapses all of it into two compiled programs:
 
 ``generate_stream`` runs the same loop in K-token chunks
 (``stream_block``): one host dispatch per K tokens instead of per token
-(the BENCH_SELF_r05 15.31 ms dispatch floor amortizes K-fold), flushing
+(the host dispatch floor amortizes K-fold), flushing
 early when the device reports all rows done; K=1 keeps the per-token
 jitted step the loop is bit-identical to (the reference streams partial
 strings to the UI via DataRepository, ``Communication.java:629-638``).
@@ -175,19 +175,21 @@ def make_paged_chunk_programs(fwd_p, bind_tables):
         """One non-final prompt chunk at global offset ``start``,
         written through ``tables`` [b, W]: extend the pool, drop
         logits."""
-        bind_tables(tables)
+        bind_tables(tables, "paged_chunk_mid")
         b, s = ids.shape
         pos = start + jnp.broadcast_to(jnp.arange(s), (b, s))
         cache = KVCache(pk, pv, jnp.zeros((), jnp.int32))
         _, cache = fwd_p(params, ids, cache, pos, True)
         return cache.keys, cache.values
 
-    def slab_body(params, cache, ids, tables, starts):
+    def slab_body(params, cache, ids, tables, starts, program):
         """Traced slab forward: row r of ``ids`` [n, s] runs at
         positions ``starts[r] + arange(s)`` through ``tables[r]``;
         returns all-position logits (callers slice their own final
-        positions) and the extended cache."""
-        bind_tables(tables)
+        positions) and the extended cache.  ``program`` names the
+        jitted program composing the slab (the attention-path
+        record's key)."""
+        bind_tables(tables, program)
         b, s = ids.shape
         pos = starts[:, None] + jnp.arange(s)[None, :]
         logits, cache = fwd_p(params, ids, cache, pos, False)
@@ -311,6 +313,29 @@ def resolve_cache_dtype_backend(kv_cache_dtype, attn_backend: str):
     return dt, attn_backend
 
 
+def resolve_attn_impl(kv_cache_dtype, attn_backend: str):
+    """``(kv_cache_dtype, attn_backend, attn_impl)`` for the dense-cache
+    engines (plain / speculative / prompt-lookup) — the ONE place that
+    turns "auto" into a kernel choice: the Pallas flash kernel when
+    JAX's default backend is a TPU, the jnp path elsewhere.  JAX falls
+    back to the CPU without a word when it finds no TPU, so the
+    resolved name is what callers print and report (``SERVE_ENGINE
+    ... attn=``); a run that must be on the chip sets
+    ``JAX_PLATFORMS=tpu`` so that a missing chip is an error.  Under a
+    tp mesh the impl runs inside the shard on its local kv heads."""
+    dt, attn_backend = resolve_cache_dtype_backend(kv_cache_dtype,
+                                                   attn_backend)
+    if attn_backend == "auto":
+        attn_backend = ("flash" if jax.default_backend() == "tpu"
+                        else "jnp")
+    if attn_backend == "flash":
+        return dt, attn_backend, make_flash_attn_impl()
+    if attn_backend == "jnp":
+        return dt, attn_backend, None
+    raise ValueError(f"unknown attn_backend {attn_backend!r}; expected "
+                     "'auto', 'flash', or 'jnp'")
+
+
 class InferenceEngine:
     """KV-cached generation over a full model — single chip, or
     tensor-parallel over a tp mesh (``mesh=`` + :func:`shard_engine_params`)."""
@@ -330,7 +355,7 @@ class InferenceEngine:
                  stop_token_ids=None,
                  stream_block: Optional[int] = None):
         """``attn_backend``: "auto" (Pallas flash kernel on TPU, jnp
-        elsewhere), "flash", "flash-interpret" (testing), or "jnp".
+        elsewhere), "flash", or "jnp".
 
         ``kv_layout``: layout of the prefix-reuse pool behind the
         ``runtime/kvcache`` backend seam (docs/DESIGN.md §14).  "paged"
@@ -405,8 +430,8 @@ class InferenceEngine:
         ``generate_stream`` host dispatch (K).  The device loop checks
         eos/stop and all-rows-done ON DEVICE, so an early finish exits
         after j <= K steps instead of burning the block; the host sees
-        tokens in K-sized chunks (dispatches/token ≈ 1/K — the
-        BENCH_SELF_r05 15.31 ms host dispatch floor amortizes K-fold).
+        tokens in K-sized chunks (dispatches/token ≈ 1/K — the host
+        dispatch floor amortizes K-fold).
         1 (default; ``DWT_STREAM_BLOCK`` env between) keeps the
         per-token path, which the fused loop is bit-identical to
         (greedy) by construction."""
@@ -428,31 +453,13 @@ class InferenceEngine:
         # decode_fused bench leg and the 1/K invariant test read these
         self.loop_stats = {"host_dispatches": 0, "device_loop_steps": 0}
         self.mesh = mesh
-        tp = mesh.shape.get("tp", 1) if mesh is not None else 1
-        from ..parallel.tensor import resolve_tp_attn_backend
         # kv_cache_dtype composes with a tp mesh: the insert cast
         # (update_kv_cache) and the read upcast (ops.attention) both run
         # INSIDE the shard on its local kv-head planes, and the cache
-        # sharding specs are dtype-agnostic — tp just forces the jnp
-        # attention path, which is what reduced-precision caches use
-        # anyway (parity pinned by tests/test_engine.py)
-        attn_backend = resolve_tp_attn_backend(tp, attn_backend)
-        self.kv_cache_dtype, attn_backend = resolve_cache_dtype_backend(
-            kv_cache_dtype, attn_backend)
-        if attn_backend == "auto":
-            attn_backend = ("flash" if jax.default_backend() == "tpu"
-                            else "jnp")
-        self.attn_backend = attn_backend
-        if attn_backend == "flash":
-            attn_impl = make_flash_attn_impl()
-        elif attn_backend == "flash-interpret":
-            attn_impl = make_flash_attn_impl(interpret=True)
-        elif attn_backend == "jnp":
-            attn_impl = None
-        else:
-            raise ValueError(
-                f"unknown attn_backend {attn_backend!r}; expected "
-                "'auto', 'flash', 'flash-interpret', or 'jnp'")
+        # sharding specs are dtype-agnostic (parity pinned by
+        # tests/test_engine.py)
+        self.kv_cache_dtype, self.attn_backend, attn_impl = \
+            resolve_attn_impl(kv_cache_dtype, attn_backend)
 
         self._attn_impl = attn_impl   # shared with MultimodalEngine
 

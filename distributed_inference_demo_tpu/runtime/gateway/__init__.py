@@ -12,8 +12,12 @@ traffic across N independent engine replicas, each a full
 - :class:`GatewayHTTPServer` — the HTTP process and streaming proxy
   with retry-before-first-token (``server.py``).
 
-The gateway holds no engine and no jax: it imports only the telemetry
-layer and ``runtime/overload.py``, so it runs anywhere a socket does.
+The gateway holds no engine and never initialises a JAX backend: its own
+modules import only the telemetry layer and ``runtime/overload.py``.
+Importing this package does pull ``jax`` in (through ``runtime/__init__``),
+but an import holds no device — ``chip_smoke.py`` starts the gateway
+FIRST, in the replica's own ``JAX_PLATFORMS=tpu`` environment, and the
+replica still gets the chip.
 """
 
 from .registry import Replica, ReplicaRegistry, http_stats_prober

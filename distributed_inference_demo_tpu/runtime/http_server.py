@@ -505,11 +505,35 @@ class InferenceHTTPServer:
                                                   "export"})
                 elif self.path == "/health":
                     import jax
-                    self._json(200, {
-                        "status": "ok",
+                    devs = jax.devices()
+                    # status follows the backend: a batching engine
+                    # whose scheduler thread died answers 503 with the
+                    # error that killed it, not "ok"
+                    health = (outer.backend.health()
+                              if hasattr(outer.backend, "health")
+                              else {"status": "ok"})
+                    # every device JAX reports, in JAX's order (the
+                    # order local_tp_mesh takes them in), with the
+                    # allocator's view where the backend has one (the
+                    # CPU's memory_stats() is None): weights and pool
+                    # resident on every chip of a tp mesh
+                    devices = []
+                    for d in devs:
+                        ms = d.memory_stats() or {}
+                        devices.append({
+                            "id": d.id, "device": str(d),
+                            **{k: ms[k] for k in (
+                                "bytes_in_use", "peak_bytes_in_use",
+                                "bytes_limit") if k in ms}})
+                    self._json(200 if health["status"] == "ok" else 503, {
+                        **health,
                         "model": outer.model_name,
                         "backend": type(outer.backend).__name__,
-                        "device": str(jax.devices()[0]),
+                        "device": str(devs[0]),
+                        "platform": devs[0].platform,
+                        "device_kind": devs[0].device_kind,
+                        "device_count": len(devs),
+                        "devices": devices,
                         "max_seq": getattr(outer.backend, "max_seq", None),
                     })
                 elif self.path == "/stats":

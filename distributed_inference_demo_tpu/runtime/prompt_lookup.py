@@ -44,7 +44,6 @@ import numpy as np
 from ..models.base import (KVCache, ModelConfig, StageParams,
                            StageSpec, pad_cache_capacity)
 from ..models.decoder import stage_forward
-from ..ops.flash_attention import make_flash_attn_impl
 from ..ops.sampling import SamplingParams, sample_logits
 from .engine import GenerationResult, check_capacity
 from .speculative import (SpecStats, drain_round_blocks, emit_stream_block,
@@ -131,17 +130,9 @@ class PromptLookupEngine:
         self.prefill_chunk = validate_prefill_chunk(prefill_chunk,
                                                     self.max_seq)
 
-        from ..parallel.tensor import resolve_tp_attn_backend
-        from .engine import resolve_cache_dtype_backend
-        tp = mesh.shape.get("tp", 1) if mesh is not None else 1
-        attn_backend = resolve_tp_attn_backend(tp, attn_backend)
-        self.kv_cache_dtype, attn_backend = resolve_cache_dtype_backend(
+        from .engine import resolve_attn_impl
+        self.kv_cache_dtype, _, attn_impl = resolve_attn_impl(
             kv_cache_dtype, attn_backend)
-        if attn_backend == "auto":
-            attn_backend = ("flash" if jax.default_backend() == "tpu"
-                            else "jnp")
-        attn_impl = (make_flash_attn_impl() if attn_backend == "flash"
-                     else None)
 
         cfg_, spec_, samp_, K = cfg, self.spec, sampling, num_draft
         # history/cache slack per round, sublane-aligned for flash

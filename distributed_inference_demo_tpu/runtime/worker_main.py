@@ -331,4 +331,8 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    # run as a module (the socket-pipeline launchers do): pass the one
+    # compile-cache rule cli.main() would have passed
+    from ..cli import configure_compile_cache
+    configure_compile_cache()
     sys.exit(main())

@@ -785,8 +785,9 @@ PROFILE_ACHIEVED_BPS = gauge(
     "bound: weights and activations ride on top)", ("signature",))
 PROFILE_ROOFLINE_FRAC = gauge(
     "dwt_profile_roofline_ratio",
-    "Achieved-bandwidth attribution over the ROOFLINE_LEDGER.json "
-    "ceiling (DWT_ROOFLINE_GBS overrides), per signature",
+    "Achieved-bandwidth attribution over the published HBM peak of "
+    "this device_kind (telemetry/profiling.DEVICE_PEAKS; not emitted "
+    "for a kind the table lacks), per signature",
     ("signature",))
 
 COMPILE_EVENTS = counter(

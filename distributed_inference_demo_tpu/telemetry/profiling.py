@@ -18,7 +18,8 @@ worker and HTTP surface in the process reports into the same ledger.
    on the outputs (a sync the fused paths already pay via their
    ``int(steps)`` readback) and records wall time plus an achieved-
    bytes/s attribution computed from the one-owner KV byte math in
-   ``ops/quant.py``, reconciled against ``ROOFLINE_LEDGER.json``.
+   ``ops/quant.py``, reconciled against the published peak of the
+   device it ran on (:data:`DEVICE_PEAKS`, keyed by ``device_kind``).
 
 2. :class:`CompileTracker` — wraps jitted callables at their creation
    site and counts cache-entry growth per program variant (compiles,
@@ -55,12 +56,13 @@ from __future__ import annotations
 
 import bisect
 import json
+import logging
 import threading
 import time
 from collections import deque
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
-from ._env import env_float, env_int
+from ._env import env_int
 
 # -- knobs ------------------------------------------------------------------
 
@@ -120,30 +122,55 @@ def parse_signature(sig: str) -> dict:
 
 # -- roofline reconciliation ------------------------------------------------
 
+class DevicePeaks(NamedTuple):
+    """Published peak rates of ONE chip of a ``device_kind``."""
+    hbm_gbs: float        # HBM bandwidth, GB/s
+    bf16_tflops: float    # dense bf16 matmul, TFLOP/s
+    hbm_gb: float         # HBM capacity, GB
+    source: str
+
+
+# The ONE peaks table of the repo, keyed by ``jax.devices()[0]
+# .device_kind``.  Only kinds whose numbers were read from the vendor's
+# own page belong here; a device that is not in the table has NO peak —
+# ratios against an assumed or self-measured ceiling are how a 0.64
+# share of the roofline once got reported against its own numerator.
+DEVICE_PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(
+        hbm_gbs=819.0, bf16_tflops=197.0, hbm_gb=16.0,
+        source='Google Cloud documentation, "TPU v5e"'),
+}
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """Peaks of ``device_kind``; an unknown kind is an error, never a
+    default (``KeyError`` naming the kinds the table does hold)."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device_kind {device_kind!r}; "
+            f"known: {sorted(DEVICE_PEAKS)}") from None
+
+
 _ROOFLINE_CACHE: List[Optional[float]] = []
 
 
 def roofline_ceiling_gbs() -> Optional[float]:
     """The HBM GB/s ceiling achieved-bandwidth attributions reconcile
-    against: ``DWT_ROOFLINE_GBS`` env override, else the max entry in
-    the repo's ``ROOFLINE_LEDGER.json``, else None (no frac emitted).
-    Cached after first read (the ledger is a committed artifact)."""
-    env = env_float("DWT_ROOFLINE_GBS", 0.0)
-    if env > 0:
-        return env
+    against: the published peak of the device this process runs on, or
+    None — no ratio emitted, said once in the log — when its
+    ``device_kind`` has none.  Cached after the first read."""
     if _ROOFLINE_CACHE:
         return _ROOFLINE_CACHE[0]
-    ceiling: Optional[float] = None
+    import jax
+    kind = jax.devices()[0].device_kind
     try:
-        import pathlib
-        path = (pathlib.Path(__file__).resolve().parents[2]
-                / "ROOFLINE_LEDGER.json")
-        ledger = json.loads(path.read_text())
-        vals = [float(v["hbm_gbs"]) for v in ledger.values()
-                if isinstance(v, dict) and "hbm_gbs" in v]
-        ceiling = max(vals) if vals else None
-    except Exception:
+        ceiling: Optional[float] = device_peaks(kind).hbm_gbs
+    except KeyError as e:
         ceiling = None
+        logging.getLogger(__name__).warning(
+            "dwt_profile_roofline_ratio not emitted: %s", e.args[0])
     _ROOFLINE_CACHE.append(ceiling)
     return ceiling
 

@@ -461,8 +461,9 @@ class NativeTokenizer:
 class Tokenizer:
     """Unified tokenizer with backend selection + bos/eos convenience.
 
-    ``backend``: "native" (C++, default, falls back to python if the build
-    fails), "python", or "hf" (HuggingFace tokenizers passthrough).
+    ``backend``: "native" (C++, default; python instead on a machine
+    with no compiler or for a spec the C++ side rejects), "python", or
+    "hf" (HuggingFace tokenizers passthrough).
     """
 
     def __init__(self, impl, spec: TokenizerSpec, backend: str):
@@ -519,9 +520,12 @@ class Tokenizer:
             return Tokenizer(_HFAdapter(HFTok.from_str(raw)), spec, "hf")
         spec = TokenizerSpec.from_json(data)
         if backend == "native":
+            from .comm.native.build import NativeUnavailable
             try:
                 return Tokenizer(NativeTokenizer(spec), spec, "native")
-            except Exception:
+            except (NativeUnavailable, ValueError):
+                # no compiler here, or a spec the C++ side rejects; a
+                # failed build or load is an error, not a fallback
                 backend = "python"
         if backend == "python":
             return Tokenizer(PyBPETokenizer(spec), spec, "python")

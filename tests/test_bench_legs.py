@@ -1,9 +1,8 @@
 """Smoke the bench legs' code paths at tiny scale on CPU.
 
-A leg bug on the real TPU burns one of the measurement session's three
-retry attempts (plus a subprocess budget of up to 40 minutes), so every
-leg that can run its full structure on tiny models must prove it here
-first.  Numbers are not asserted — only structure and non-error shape.
+A leg bug found on the real TPU costs chip time, so every leg that can
+run its full structure on tiny models must prove it here first.  Numbers
+are not asserted — only structure and non-error shape.
 """
 
 import sys
@@ -29,7 +28,7 @@ def test_leg_moe_structure_tiny():
 
 
 def test_bench_engine_latency_percentiles_tiny():
-    """The headline legs' TTFT/TPOT block (BENCH_SELF trajectory): real
+    """The headline legs' TTFT/TPOT block: real
     percentiles, ordered, from the streamed per-request measurement."""
     out = bench._bench_engine("llama-test", 2, 8, 4, latency=True)
     lat = out["latency"]
@@ -521,7 +520,7 @@ def test_run_leg_stamps_dispatch_profile_extras(monkeypatch):
     """The §20 bench satellite's CPU dryrun: a headline-order leg run
     through run_leg stamps the ``dispatch_profile`` extras block —
     per-signature p50/p95 from the sampled dispatch profiler plus the
-    compile ledger — so BENCH_SELF r06+ artifacts carry the cost
+    compile ledger — so bench artifacts carry the cost
     observatory without a TPU session proving the plumbing first.
     Sampling is forced to every dispatch so the tiny micro shape still
     banks samples deterministically."""
@@ -571,3 +570,34 @@ def test_run_leg_micro_variants_stamp_and_shrink():
     # the micro decode_fused variant runs the reduced point grid
     assert {(pt["batch"], pt["stream_block"])
             for pt in out["points"]} == {(1, 1), (1, 4)}
+
+
+def test_headline_summary_null_when_not_comparable():
+    # a different batch than the stored CPU baseline must report null,
+    # never a mislabeled multiplier
+    s = bench.headline_summary(
+        {"decode_tokens_per_sec": 100.0, "dtype": "bf16"},
+        {"model": "tinyllama-1.1b", "batch": 999, "prompt_len": 64,
+         "new_tokens": 128, "flagship": "f"}, "dev")
+    assert s["value"] == 100.0 and s["vs_baseline"] is None
+
+
+def test_multichip_render_matches_driver_bytes():
+    """The driver rewrites MULTICHIP artifacts from parsed JSON in its
+    own format; tools/record_multichip.render_artifact must reproduce a
+    driver-written file BYTE-IDENTICALLY (no git_head field, no trailing
+    newline) or every re-run shows the artifact dirty."""
+    import importlib.util
+    import json
+    spec = importlib.util.spec_from_file_location(
+        "record_multichip", REPO / "tools" / "record_multichip.py")
+    rm = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(rm)
+    raw = (REPO / "MULTICHIP_r05.json").read_text()
+    parsed = json.loads(raw)
+    rendered = rm.render_artifact(parsed["n_devices"], parsed["rc"],
+                                  parsed["tail"],
+                                  skipped=parsed["skipped"])
+    assert rendered == raw
+    assert not rendered.endswith("\n")
+    assert "git_head" not in rendered

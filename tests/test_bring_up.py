@@ -1,0 +1,432 @@
+"""Bring-up invariants (PR 21): the chip cannot be hidden.
+
+Everything here runs on the CPU and is cheap.  What it pins:
+
+- ``chip_smoke.py`` fails, naming the missing TPU, where JAX has none —
+  and its request script passes against ``serve --model qwen2-test`` on
+  the CPU, so the command is debugged before chip time is spent on it;
+- ``bench.py`` refuses to run without a TPU and prints no number;
+- the compile-cache rule: ``JAX_COMPILATION_CACHE_DIR`` set → the code
+  sets nothing; unset → ``<checkout>/.jax_cache``, whatever the cwd;
+- the attention-path record: ``gather`` (with the reason) on the CPU,
+  the kernel names under a forced ``"pallas"`` backend, also per shard
+  under a tp mesh; an explicit ``"pallas"`` on a shape no kernel takes
+  raises instead of gathering unseen;
+- ``/health`` carries platform / device_kind / device count and its
+  status follows the scheduler thread;
+- seeded weights and the page pool are born sharded under a tp mesh
+  with the values of the unsharded init;
+- the native library is keyed on a hash of its sources, and a failed
+  build is an error;
+- every kernel specialisation gets through Mosaic for a TPU v5e, checked
+  here by compiling ahead of time against a device-less v5e topology
+  (skipped where libtpu offers none).
+"""
+
+import json
+import os
+import subprocess
+import sys
+import time
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_inference_demo_tpu.models import get_model_config
+from distributed_inference_demo_tpu.models.base import KVCache, StageSpec
+from distributed_inference_demo_tpu.models.decoder import init_full_params
+from distributed_inference_demo_tpu.ops import paged_attention as pa
+from distributed_inference_demo_tpu.ops.quant import (
+    alloc_kv_pages, alloc_kv_pool)
+from distributed_inference_demo_tpu.ops.sampling import SamplingParams
+from distributed_inference_demo_tpu.parallel.mesh import local_tp_mesh
+from distributed_inference_demo_tpu.parallel.tensor import (
+    make_paged_forward_seam)
+from distributed_inference_demo_tpu.runtime.batching import (
+    ContinuousBatchingEngine)
+
+REPO = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(REPO))
+
+import chip_smoke  # noqa: E402
+
+CPU_ENV = dict(os.environ, JAX_PLATFORMS="cpu")
+
+
+# ------------------------------------------------------ smoke and bench
+
+def test_chip_smoke_fails_without_a_tpu():
+    """In a sandbox like this one the smoke must fail: its children ask
+    for the ``tpu`` platform, so JAX errors out instead of falling back."""
+    proc = subprocess.run([sys.executable, str(REPO / "chip_smoke.py")],
+                          env=CPU_ENV, capture_output=True, text=True,
+                          timeout=300)
+    assert proc.returncode == 1
+    assert "JAX found no TPU" in proc.stdout
+    assert '"ok"' not in proc.stdout         # no result line
+
+
+def test_chip_smoke_outside_a_checkout(tmp_path):
+    (tmp_path / "chip_smoke.py").write_text(
+        (REPO / "chip_smoke.py").read_text())
+    proc = subprocess.run([sys.executable, "chip_smoke.py"], cwd=tmp_path,
+                          env=CPU_ENV, capture_output=True, text=True,
+                          timeout=60)
+    assert proc.returncode == 2 and proc.stdout == ""
+    assert "not a checkout" in proc.stderr
+
+
+def test_smoke_request_script_passes_on_cpu(tmp_path):
+    """The same gateway → serve --batch-slots command and the same
+    requests, against the tiny qwen2 config on the CPU: every check of
+    the script holds, and the attention paths say gather and why."""
+    try:
+        ph = chip_smoke.serving_phase("cpu", "qwen2-test", 256, "cpu",
+                                      tmp_path, ready_timeout=300)
+    finally:
+        chip_smoke.stop_all_children()
+    assert ph["health"]["platform"] == "cpu"
+    assert ph["health"]["device_count"] >= 1
+    assert ph["stats"]["attention_paths"]["mixed_step"] == \
+        chip_smoke.expected_paths("cpu")
+    assert ph["tokens"]["long"] == ph["tokens"]["long_again"]
+    assert ph["tokens"]["prefix_b"] == ph["tokens"]["prefix_b_again"]
+    assert ph["stats"]["kvcache"]["hits"] >= 1
+
+
+def test_smoke_stats_check_catches_a_hidden_gather():
+    """A program that should have taken a kernel and gathered fails the
+    smoke: the TPU expectation is both kernel names."""
+    stats = {"kvcache": {"hits": 1, "partial_hit_tokens": 16},
+             "device_loop": {"host_dispatches": 2, "device_loop_steps": 8},
+             "mixed": {"dispatches": 3, "prefill_tokens": 64},
+             "chunked_prefill": {"chunks": 2},
+             "attention_paths": {"mixed_step": {
+                 "chunk=1": "pallas_decode",
+                 "chunk=64": "gather: chunk 64 x group 9 = 576 query rows "
+                             "> PREFILL_KERNEL_MAX_ROWS=512"}}}
+    with pytest.raises(chip_smoke.SmokeFailure, match="attention paths"):
+        chip_smoke.check_stats(stats, "tpu")
+    stats["attention_paths"]["mixed_step"]["chunk=64"] = "pallas_prefill"
+    chip_smoke.check_stats(stats, "tpu")
+
+
+def test_bench_refuses_to_run_without_a_tpu():
+    proc = subprocess.run([sys.executable, str(REPO / "bench.py")],
+                          env=CPU_ENV, capture_output=True, text=True,
+                          timeout=300)
+    assert proc.returncode == 1
+    assert proc.stdout.strip() == ""         # no number, old or new
+    assert "JAX found no TPU" in proc.stderr
+
+
+# ------------------------------------------------------- compile cache
+
+def test_compile_cache_rule(monkeypatch, tmp_path):
+    from distributed_inference_demo_tpu import cli
+
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: calls.append((name, value)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
+    assert cli.configure_compile_cache() == str(tmp_path / "c")
+    assert calls == []                       # env set: code sets nothing
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    want = str(REPO / ".jax_cache")
+    assert cli.configure_compile_cache() == want
+    assert calls == [("jax_compilation_cache_dir", want)]
+
+    # a second process, another cwd: the same directory
+    env = {k: v for k, v in CPU_ENV.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = str(REPO)
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; from distributed_inference_demo_tpu.cli import "
+         "configure_compile_cache as c; print(c()); "
+         "print(jax.config.jax_compilation_cache_dir)"],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=120)
+    assert out.stdout.split() == [want, want], out.stderr
+
+
+# ---------------------------------------------------- attention routing
+
+def _pages(kind, bt=16):
+    return alloc_kv_pages((4, 2, bt, 8), kind, jnp.float32)
+
+
+@pytest.mark.parametrize("backend,platform,kind,bt,chunk,groups,want", [
+    ("auto", "cpu", "bf16", 16, 1, 7, "gather: backend=auto on platform=cpu"),
+    ("xla", "tpu", "bf16", 16, 1, 7, "gather: backend=xla"),
+    ("auto", "tpu", "bf16", 16, 1, 7, "pallas_decode"),
+    ("auto", "tpu", "bf16", 16, 64, 7, "pallas_prefill"),
+    ("auto", "tpu", "int8", 32, 64, 7, "pallas_prefill"),
+    ("auto", "tpu", "int8", 16, 1, 7, "gather: int8 pages need"),
+    ("auto", "tpu", "int4", 32, 1, 7, "gather: int4 pages have no kernel"),
+    ("auto", "tpu", "bf16", 12, 1, 7, "gather: block_tokens=12"),
+    # the README's documented --prefill-chunk 256: 1792 rows at group 7
+    ("auto", "tpu", "bf16", 16, 256, 7, "gather: chunk 256 x group 7 = 1792"),
+    ("pallas", "cpu", "int8", 16, 1, 7, "pallas_decode"),   # interpret runs
+])
+def test_route_paged_attention(backend, platform, kind, bt, chunk, groups,
+                               want):
+    path, why = pa.route_paged_attention(backend, platform, _pages(kind, bt),
+                                         chunk, groups)
+    assert (path if not why else f"{path}: {why}").startswith(want)
+
+
+def test_forced_pallas_raises_where_no_kernel_fits():
+    for kind, bt, chunk in (("int4", 32, 1), ("bf16", 12, 1),
+                            ("bf16", 16, 256)):
+        with pytest.raises(ValueError, match="cannot take this shape"):
+            pa.route_paged_attention("pallas", "cpu", _pages(kind, bt),
+                                     chunk, 7)
+
+
+def _seam_programs(cfg, params, mesh, backend, record, bt=8, W=4, N=16):
+    """A decode step and a prefill slab over one paged seam — the two
+    attention shapes a mixed dispatch holds."""
+    spec = StageSpec(0, 1, 0, cfg.num_layers)
+    fwd, bind, pool_sharding = make_paged_forward_seam(
+        cfg, spec, mesh, params, bt, backend=backend, interpret=True,
+        record=record)
+    pk, pv = alloc_kv_pool((cfg.num_layers, N, cfg.num_kv_heads, bt,
+                            cfg.head_dim), "bf16", cfg.dtype, pool_sharding)
+    tables = jnp.arange(2 * W, dtype=jnp.int32).reshape(2, W)
+
+    @jax.jit
+    def slab(params, pk, pv, ids):
+        bind(tables, "slab")
+        pos = jnp.broadcast_to(jnp.arange(ids.shape[1]), ids.shape)
+        logits, cache = fwd(params, ids, KVCache(pk, pv, jnp.int32(0)),
+                            pos, False)
+        return logits, cache.keys, cache.values
+
+    @jax.jit
+    def step(params, pk, pv, tok, lengths):
+        bind(tables, "step")
+        logits, _ = fwd(params, tok[:, None], KVCache(pk, pv, jnp.int32(0)),
+                        lengths[:, None], True)
+        return logits
+
+    ids = jnp.asarray(np.random.RandomState(0).randint(
+        0, cfg.vocab_size, (2, 16)), jnp.int32)
+    logits, pk, pv = slab(params, pk, pv, ids)
+    return logits, step(params, pk, pv, ids[:, -1],
+                        jnp.asarray([16, 16], jnp.int32))
+
+
+@pytest.mark.parametrize("tp", [1, 2])
+def test_attention_path_record_names_kernels_under_forced_pallas(tp):
+    """Forced "pallas" (interpreted here) runs both kernels, the record
+    names them per program, and the logits match the gather path — also
+    INSIDE a tp shard_map, where each shard's kernel sees nkv / tp kv
+    heads (the seam used to hard-code the gather there)."""
+    cfg = get_model_config("llama-test")
+    params = init_full_params(jax.random.PRNGKey(0), cfg)
+    mesh = local_tp_mesh(tp)
+    if mesh is not None:
+        from distributed_inference_demo_tpu.runtime.engine import (
+            shard_engine_params)
+        params = shard_engine_params(params, cfg, mesh)
+    got, want = {}, {}
+    for backend, out in (("pallas", got), ("xla", want)):
+        out["record"] = pa.AttnPathRecord()
+        out["slab"], out["step"] = _seam_programs(cfg, params, mesh,
+                                                  backend, out["record"])
+    assert got["record"].snapshot() == {
+        "slab": {"chunk=16": "pallas_prefill"},
+        "step": {"chunk=1": "pallas_decode"}}
+    assert want["record"].snapshot() == {
+        "slab": {"chunk=16": "gather: backend=xla"},
+        "step": {"chunk=1": "gather: backend=xla"}}
+    # f32 model: the kernel's online softmax vs the gather's one-shot
+    # softmax differ by reduction order only
+    np.testing.assert_allclose(got["slab"], want["slab"], atol=2e-5)
+    np.testing.assert_allclose(got["step"], want["step"], atol=2e-5)
+
+
+# ------------------------------------------------ engine, /stats, /health
+
+def _get(port, path):
+    import http.client
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=60)
+    try:
+        conn.request("GET", path)
+        resp = conn.getresponse()
+        return resp.status, json.loads(resp.read())
+    finally:
+        conn.close()
+
+
+def test_engine_reports_gather_on_cpu_and_health_follows_scheduler():
+    from distributed_inference_demo_tpu.runtime.http_server import (
+        InferenceHTTPServer)
+
+    cfg = get_model_config("llama-test")
+    params = init_full_params(jax.random.PRNGKey(0), cfg)
+    eng = ContinuousBatchingEngine(
+        cfg, params, max_seq=64, max_batch=2,
+        sampling=SamplingParams(greedy=True), decode_block=2,
+        prefill_chunk=8, mixed_token_budget=16)
+    server = InferenceHTTPServer(eng, model_name="llama-test")
+    server.start()
+    try:
+        eng.submit(list(range(1, 20)), 4).wait(timeout=120)
+        why = "gather: backend=auto on platform=cpu"
+        assert eng.stats()["attention_paths"] == {
+            "mixed_step": {"chunk=8": why, "chunk=1": why}}
+
+        status, health = _get(server.port, "/health")
+        dev = jax.devices()[0]
+        assert status == 200 and health["status"] == "ok"
+        assert (health["platform"], health["device_kind"],
+                health["device_count"]) == (dev.platform, dev.device_kind,
+                                            len(jax.devices()))
+        assert [d["id"] for d in health["devices"]] == \
+            [d.id for d in jax.devices()]
+
+        # a pure-decode dispatch failure kills the scheduler thread: it
+        # drains every request with the error — and /health says so
+        def boom(*a, **k):
+            raise RuntimeError("device lost")
+        row = eng.submit([5, 4, 3], 40)
+        deadline = time.monotonic() + 60
+        while len(row.tokens) < 1:
+            assert time.monotonic() < deadline
+            time.sleep(0.005)
+        eng._mixed_step = boom
+        with pytest.raises(RuntimeError, match="device lost"):
+            row.wait(timeout=60)
+        status, health = _get(server.port, "/health")
+        assert status == 503 and health["status"] == "scheduler_dead"
+        assert "device lost" in health["error"]
+    finally:
+        server.shutdown()
+        eng.close()
+
+
+# --------------------------------------------------- born-sharded state
+
+def test_seeded_weights_and_pool_are_born_sharded():
+    from distributed_inference_demo_tpu.models.loader import load_or_init
+
+    # int8: the layer-by-layer quantizing init is the one with structure
+    # to lose under an outer jit; its q and scale leaves both shard
+    mesh = local_tp_mesh(2)
+    name = "qwen2-test-int8"
+    cfg = get_model_config(name)
+    whole = load_or_init(name, cfg, seed=3)
+    sharded = load_or_init(name, cfg, seed=3, mesh=mesh)
+    for a, b in zip(jax.tree.leaves(whole), jax.tree.leaves(sharded)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    wq = sharded.layers["wq"].q
+    assert wq.sharding.spec == jax.sharding.PartitionSpec(None, None, "tp")
+    assert len(wq.addressable_shards) == 2
+    assert wq.addressable_shards[0].data.shape[-1] == wq.shape[-1] // 2
+
+    from distributed_inference_demo_tpu.parallel.tensor import (
+        tp_cache_sharding)
+    pk, pv = alloc_kv_pool((2, 8, 2, 8, 16), "int8", jnp.bfloat16,
+                           tp_cache_sharding(mesh))
+    for leaf in jax.tree.leaves((pk, pv)):     # data AND scale sidecars
+        assert leaf.sharding.spec[2] == "tp"
+        assert leaf.addressable_shards[0].data.shape[2] == 1
+        assert not np.asarray(leaf).any()
+
+
+# -------------------------------------------------------- native build
+
+def test_native_library_is_keyed_on_its_sources(tmp_path, monkeypatch):
+    from distributed_inference_demo_tpu.comm.native import build as nb
+
+    # a one-function stand-in for the real sources keeps g++ quick
+    monkeypatch.setattr(nb, "SOURCES", ["one.cc"])
+    (tmp_path / "one.cc").write_text('extern "C" int one() { return 1; }\n')
+    monkeypatch.setattr(nb, "_DIR", tmp_path)
+    first = nb.build()
+    assert first.exists() and first == nb.lib_path()
+    assert nb.build() == first               # nothing to do
+
+    # other sources, other name: a stale binary cannot be picked up, and
+    # the old one is swept once the new one exists
+    with open(tmp_path / nb.SOURCES[0], "a") as f:
+        f.write("\n// edited\n")
+    second = nb.lib_path()
+    assert second != first and not second.exists()
+    assert nb.build() == second and not first.exists()
+
+    # a compiler that is there and fails is an error, not a fallback
+    (tmp_path / nb.SOURCES[0]).write_text("this is not C++\n")
+    with pytest.raises(nb.NativeBuildError, match="failed"):
+        nb.build()
+    assert not list(tmp_path.glob("*.tmp"))
+
+    # no compiler at all: the one case the Python codec may serve
+    monkeypatch.setattr(nb.shutil, "which", lambda _: None)
+    with pytest.raises(nb.NativeUnavailable):
+        nb.build()
+
+
+# ------------------------------------------------- Mosaic, ahead of time
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One device of a device-less TPU v5e topology: libtpu compiles for
+    it (Mosaic included) without a chip being there."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                            platform="tpu")
+    except Exception as e:                   # no libtpu, or no such target
+        pytest.skip(f"no ahead-of-time TPU compiler here: {e}")
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+def _compile_for(sharding, fn, *shapes):
+    args = [jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        x) for x in shapes]
+    jax.jit(fn).lower(*args).compile()       # raises on a Mosaic refusal
+
+
+@pytest.mark.parametrize("kind,bt", [("bf16", 16), ("int8", 32)])
+@pytest.mark.parametrize("alibi", [False, True])
+def test_paged_kernels_get_through_mosaic(v5e, kind, bt, alibi):
+    """qwen2.5-7b heads (28 q / 4 kv x 128) without ALiBi, bloom560m heads
+    (16 / 16 x 64) with: decode and prefill kernel, bf16 and int8 pages."""
+    nh, nkv, hd = (16, 16, 64) if alibi else (28, 4, 128)
+    S = jax.ShapeDtypeStruct
+    pages = jax.eval_shape(
+        lambda: alloc_kv_pages((64, nkv, bt, hd), kind, jnp.bfloat16))
+    slopes = (S((nh,), jnp.float32),) if alibi else ()
+    _compile_for(
+        v5e, lambda q, pk, pv, t, n, *s: pa.paged_flash_attention(
+            q, pk, pv, t, n, *s),
+        S((8, 1, nh, hd), jnp.bfloat16), pages, pages,
+        S((8, 8), jnp.int32), S((8,), jnp.int32), *slopes)
+    chunk = 64 if nh // nkv * 64 <= 512 else 32
+    _compile_for(
+        v5e, lambda q, pk, pv, t, p, *s: pa.paged_prefill_attention(
+            q, pk, pv, t, p, *s),
+        S((2, chunk, nh, hd), jnp.bfloat16), pages, pages,
+        S((2, 8), jnp.int32), S((2, chunk), jnp.int32), *slopes)
+
+
+def test_flash_kernel_with_alibi_gets_through_mosaic(v5e):
+    """MHA + ALiBi at a 256-token chunk: Mosaic refused the in-kernel
+    ``jnp.tile`` of the slope vector ("Input offsets outside of the
+    first tile"); the per-row slope column is now built outside."""
+    from distributed_inference_demo_tpu.ops.flash_attention import (
+        flash_attention)
+    S = jax.ShapeDtypeStruct
+    _compile_for(
+        v5e, lambda q, k, v, a, b, s: flash_attention(q, k, v, a, b, s),
+        S((2, 256, 16, 64), jnp.bfloat16),
+        S((2, 16, 1024, 64), jnp.bfloat16),
+        S((2, 16, 1024, 64), jnp.bfloat16),
+        S((), jnp.int32), S((), jnp.int32), S((16,), jnp.float32))

@@ -116,13 +116,37 @@ def test_tp_mesh_engine_matches_single(engine):
     assert lp.logprobs.shape == (2, 4) and (lp.logprobs <= 0).all()
 
 
-def test_tp_mesh_validation(engine):
+@pytest.fixture
+def flash_interpret(monkeypatch):
+    """attn_backend="flash" builds the Pallas kernel in INTERPRET mode:
+    the engines offer no way to serve from the interpreter, so the tests
+    that need the kernel on a CPU swap the factory the engine calls."""
+    from distributed_inference_demo_tpu.ops.flash_attention import (
+        make_flash_attn_impl)
+    from distributed_inference_demo_tpu.runtime import engine as engine_mod
+    monkeypatch.setattr(engine_mod, "make_flash_attn_impl",
+                        lambda: make_flash_attn_impl(interpret=True))
+
+
+def test_tp_mesh_runs_flash_kernel_per_shard(engine, flash_interpret):
+    """Under a tp mesh the flash kernel runs INSIDE each shard on its
+    nkv / tp local kv heads (it used to be refused there, unexercised):
+    greedy output equals the single-chip jnp engine's.  The 16-token
+    prompt is a prefill-sized chunk, so the kernel is on the path."""
     from distributed_inference_demo_tpu.parallel import MeshConfig, make_mesh
+    from distributed_inference_demo_tpu.runtime.engine import (
+        shard_engine_params)
 
     mesh = make_mesh(MeshConfig(tp=2), jax.devices()[:2])
-    with pytest.raises(ValueError, match="incompatible"):
-        InferenceEngine(engine.cfg, engine.params, max_seq=64, mesh=mesh,
-                        attn_backend="flash")
+    params = shard_engine_params(engine.params, engine.cfg, mesh)
+    tp_flash = InferenceEngine(engine.cfg, params, max_seq=64, mesh=mesh,
+                               sampling=SamplingParams(greedy=True),
+                               attn_backend="flash")
+    assert tp_flash.attn_backend == "flash"
+    prompt = np.random.RandomState(1).randint(0, engine.cfg.vocab_size,
+                                              (2, 16))
+    np.testing.assert_array_equal(engine.generate(prompt, 6).tokens,
+                                  tp_flash.generate(prompt, 6).tokens)
 
 
 def test_fp8_kv_cache_under_tp_mesh(engine):
@@ -235,32 +259,32 @@ def test_eos_early_stop():
     assert len(toks) == 1
 
 
-def test_attn_backend_flash_interpret_parity():
-    """Engine-level wiring of the Pallas attention backend: the
-    'flash-interpret' engine must generate identical tokens to 'jnp'."""
+def test_attn_backend_flash_parity(flash_interpret):
+    """Engine-level wiring of the Pallas attention backend: the 'flash'
+    engine (interpreted here) must generate identical tokens to 'jnp'."""
     cfg = get_model_config("llama-test")
     params = init_full_params(jax.random.PRNGKey(0), cfg)
     prompt = np.random.RandomState(0).randint(0, cfg.vocab_size, (2, 16))
     toks = {}
-    for backend in ("jnp", "flash-interpret"):
+    for backend in ("jnp", "flash"):
         eng = InferenceEngine(cfg, params, max_seq=32,
                               sampling=SamplingParams(greedy=True),
                               attn_backend=backend)
         toks[backend] = eng.generate(prompt, 8, seed=0).tokens
-    np.testing.assert_array_equal(toks["jnp"], toks["flash-interpret"])
+    np.testing.assert_array_equal(toks["jnp"], toks["flash"])
 
 
-def test_flash_accepts_misaligned_max_seq():
+def test_flash_accepts_misaligned_max_seq(flash_interpret):
     """A max_seq that is NOT a multiple of 8 must still work on the flash
     backend: the engine pads the cache BUFFER to the sublane granule
     (models/base.pad_cache_capacity) while check_capacity keeps enforcing
-    the caller's bound.  Regression: the r04 bench speculative leg died
+    the caller's bound.  Regression: a speculative bench leg once died
     with 'flash attention requires max_seq divisible by 8, got 197'."""
     cfg = get_model_config("llama-test")
     params = init_full_params(jax.random.PRNGKey(0), cfg)
     prompt = np.random.RandomState(0).randint(0, cfg.vocab_size, (2, 11))
     toks = {}
-    for backend in ("jnp", "flash-interpret"):
+    for backend in ("jnp", "flash"):
         eng = InferenceEngine(cfg, params, max_seq=27,
                               sampling=SamplingParams(greedy=True),
                               attn_backend=backend)
@@ -268,7 +292,7 @@ def test_flash_accepts_misaligned_max_seq():
         toks[backend] = eng.generate(prompt, 8, seed=0).tokens
         with pytest.raises(ValueError, match="exceeds KV-cache capacity"):
             eng.generate(prompt, 17, seed=0)      # 11+17 > 27 still rejected
-    np.testing.assert_array_equal(toks["jnp"], toks["flash-interpret"])
+    np.testing.assert_array_equal(toks["jnp"], toks["flash"])
 
 
 def test_chunked_prefill_misaligned_max_seq(engine):

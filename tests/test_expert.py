@@ -18,7 +18,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
-from distributed_inference_demo_tpu.parallel.compat import shard_map
 
 from distributed_inference_demo_tpu.models import (
     KVCache, StageSpec, get_model_config)
@@ -45,7 +44,7 @@ def _layer_moe_params(rng, cfg):
 def _run_ep_mlp(cfg, lp, x, mesh):
     specs = {"router": P(), "w_gate": P("ep", None, None),
              "w_up": P("ep", None, None), "w_down": P("ep", None, None)}
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda lp_, x_: _moe_mlp_ep(cfg, lp_, x_, "ep"),
         mesh=mesh, in_specs=(specs, P("ep")), out_specs=P("ep"),
         check_vma=False)

@@ -120,8 +120,18 @@ def test_mixed_cold_parity_stats_and_zero_h2d(params, oracle):
 def test_mixed_sampled_stream_bit_identical_to_serialized(params):
     """The rng contract: one split per packed final in pack order, one
     decode split per decoding dispatch — the serialized path's exact
-    spend, so SAMPLED streams (tokens and logprobs) match bit-for-bit
-    across sequential requests."""
+    spend, so SAMPLED token streams match bit-for-bit across sequential
+    requests.
+
+    What is guaranteed for the reported LOG-PROBABILITIES is agreement
+    to float32 rounding, not bit-identity: the two schedules run the
+    same arithmetic through differently shaped XLA programs (a
+    [n_seg, C] slab here, a bucket-wide prefill there), and XLA makes
+    no promise that two programs reduce in the same order.  On jax 0.9
+    they differ by 1-3 ulp; a wrong rng split, mask or page would move
+    them by O(1) (and move the tokens, which stay pinned exactly).  The
+    bound is ~100 ulp of an |lp| ~ 4 float32 — slack for accumulated
+    rounding over the layer stack, nothing more."""
     samp = SamplingParams(greedy=False, temperature=0.9, top_k=40)
 
     def run(**kw):
@@ -135,7 +145,10 @@ def test_mixed_sampled_stream_bit_identical_to_serialized(params):
                 outs.append((list(r.wait(timeout=300)), list(r.lps)))
             return outs
 
-    assert run() == run(mixed_token_budget=24)
+    for (toks, lps), (m_toks, m_lps) in zip(run(),
+                                            run(mixed_token_budget=24)):
+        assert toks == m_toks
+        np.testing.assert_allclose(lps, m_lps, rtol=1e-5, atol=0)
 
 
 @pytest.mark.quick

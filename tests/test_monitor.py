@@ -63,11 +63,16 @@ def test_aggregator_ready_and_profiles():
 
 
 def test_aggregator_defaults_for_missing_measurements():
+    """Link and memory measurements fall back to documented defaults; a
+    compute rate nobody measured is refused, not invented."""
     agg = MonitorAggregator(["d0"])
-    agg.add_report("d0", {})
+    agg.add_report("d0", {"flops": 3e11})
     p = agg.device_profiles({"d0": "a:1"})[0]
-    assert p.flops_per_sec > 0 and p.memory_bytes > 0
+    assert p.flops_per_sec == 3e11 and p.memory_bytes > 0
     assert p.egress_bandwidth > 0
+    agg.add_report("d0", {})
+    with pytest.raises(ValueError, match="no flops measurement"):
+        agg.device_profiles({"d0": "a:1"})
 
 
 # ------------------------------------------------- end-to-end monitor round

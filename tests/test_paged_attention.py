@@ -232,7 +232,7 @@ def test_impl_binds_tables_and_matches_manual_sequence():
 
     @jax.jit
     def step(q, k, v, pk, pv, tables, pos):
-        bind(tables)
+        bind(tables, "step")
         return impl(q, k, v, pk, pv, pos, jnp.int32(0), None)
 
     out, pk2, pv2 = step(q, k, v, pk, pv, tables, pos)

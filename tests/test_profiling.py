@@ -118,8 +118,13 @@ def test_sample_n_zero_disables_even_counting():
 def test_profiler_snapshot_percentiles_and_attribution(monkeypatch):
     """Sampled durations roll up to deterministic p50/p95/mean, and an
     hbm_bytes attribution yields achieved GB/s reconciled against the
-    DWT_ROOFLINE_GBS ceiling override."""
-    monkeypatch.setenv("DWT_ROOFLINE_GBS", "100.0")
+    published peak of the device kind (a 100 GB/s kind injected into
+    the table here: the CPU the tests run on has no entry)."""
+    import jax
+    monkeypatch.setitem(
+        profiling.DEVICE_PEAKS, jax.devices()[0].device_kind,
+        profiling.DevicePeaks(100.0, 1.0, 1.0, "test"))
+    monkeypatch.setattr(profiling, "_ROOFLINE_CACHE", [])
     clock = FakeClock()
     prof = DispatchProfiler(sample_n=1, clock=clock)
     sig = dispatch_signature("decode_loop", batch=8, chunk=4)
@@ -506,3 +511,30 @@ def test_observatory_state_shape(monkeypatch):
     finally:
         monkeypatch.delenv("DWT_PROFILE_SAMPLE_N", raising=False)
         profiling.reset_observatory()
+
+
+def test_peaks_table_refuses_unknown_device_kind(monkeypatch, caplog):
+    """One peaks table keyed by device_kind: the v5e row carries the
+    published numbers and its source; a kind the table lacks is an
+    error for callers that need a peak, and the profiler's roofline
+    ratio is simply absent for it (said once), never computed against
+    a default."""
+    v5e = profiling.device_peaks("TPU v5 lite")
+    assert (v5e.hbm_gbs, v5e.bf16_tflops, v5e.hbm_gb) == (819.0, 197.0, 16.0)
+    assert "TPU v5e" in v5e.source
+    with pytest.raises(KeyError, match="no published peaks.*'cpu'"):
+        profiling.device_peaks("cpu")
+
+    monkeypatch.setattr(profiling, "_ROOFLINE_CACHE", [])
+    clock = FakeClock()
+    prof = DispatchProfiler(sample_n=1, clock=clock)
+    sig = dispatch_signature("decode_loop", batch=8, chunk=4)
+    with caplog.at_level("WARNING", logger=profiling.__name__):
+        for _ in range(3):
+            t0 = prof.begin(sig)
+            clock.advance(1e-3)
+            prof.end(sig, t0, hbm_bytes=10 ** 6)
+        snap = prof.snapshot()[sig]
+    assert "achieved_gbs" in snap and "roofline_frac" not in snap
+    said = [r for r in caplog.records if "roofline_ratio" in r.getMessage()]
+    assert len(said) == 1

@@ -12,7 +12,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
-from distributed_inference_demo_tpu.parallel.compat import shard_map
 
 from distributed_inference_demo_tpu.models import (
     KVCache, StageSpec, get_model_config)
@@ -56,7 +55,7 @@ def test_ring_self_attention_matches_dense(sp_mesh, alibi):
 
     expected = _dense_causal(q, k, v, slopes)
 
-    ring = shard_map(
+    ring = jax.shard_map(
         lambda q, k, v: ring_self_attention(q, k, v, "sp", slopes=slopes),
         mesh=sp_mesh, in_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp")),
         out_specs=P(None, "sp"), check_vma=False)
@@ -92,7 +91,7 @@ def test_sp_decode_attention_matches_dense(sp_mesh):
             v_shard[:, :, slot] = np.asarray(v_dense[:, pos])
             kv_pos[slot] = pos
 
-    dec = shard_map(
+    dec = jax.shard_map(
         lambda q, k, v, kp: sp_decode_attention(q, k, v, kp, q_pos, "sp"),
         mesh=sp_mesh,
         in_specs=(P(), P(None, None, "sp"), P(None, None, "sp"), P("sp")),

@@ -1,7 +1,8 @@
 """Probe: how much of a decode step does SAMPLING eat at large batch?
 
-The sweep in BENCH_SELF_r03 shows achieved GB/s falling as batch grows
-(0.61 roofline at b8 -> 0.24 at b64).  Weights traffic is batch-invariant,
+An early batch sweep (a record since deleted with the harness that took
+it) showed achieved GB/s falling as batch grows.  Weights traffic is
+batch-invariant,
 so the extra per-step time is activation work — and top-k over [b, 32000]
 logits (lax.top_k sorts) is a prime suspect.  This times the SAME decode
 loop under greedy / top-k=7 / top-p sampling to isolate that cost.
