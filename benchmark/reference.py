@@ -1,0 +1,190 @@
+"""A plain reference forward pass, independent of the code under test.
+
+Float32 ``jax.numpy`` under ``jax.default_matmul_precision("highest")``:
+the published equations of the two block families the benchmark's
+configurations use, over the whole sequence at once, with no KV cache, no
+kernel, no batching and no line of ``models/decoder.py`` or ``ops/``.
+It reads the program's parameter tree only as data (the names of its
+leaves); an int8 leaf is its stored integers times its scales.
+
+* ``qwen2`` (Qwen2.5 technical report; HF ``modeling_qwen2``): pre-RMSNorm,
+  q/k/v projections with bias, rotary embedding in the rotate-half form,
+  grouped-query causal attention, SwiGLU feed-forward, untied head.
+* ``bloom`` (BLOOM paper, section 3; HF ``modeling_bloom``): LayerNorm
+  after the embedding, pre-LayerNorm blocks with biases everywhere, ALiBi
+  (score + slope_h * key_position), causal multi-head attention, a 4H
+  feed-forward with the tanh form of GELU, head tied to the embedding.
+
+One layer runs at a time (one jitted function, the layer picked by index),
+and the head runs in blocks of vocabulary rows with a running
+log-sum-exp, so the float32 copies stay a few hundred MB.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+F32 = jnp.float32
+HEAD_BLOCK = 16384          # vocabulary rows a head block covers
+
+
+def _f32(leaf):
+    """A parameter leaf as float32: an int8 leaf (``.q`` integers and
+    ``.scale`` per output channel) is dequantized exactly."""
+    if hasattr(leaf, "q"):
+        if leaf.q.dtype != jnp.int8:
+            raise ValueError(f"reference handles int8 leaves, not "
+                             f"{leaf.q.dtype}")
+        return leaf.q.astype(F32) * leaf.scale.astype(F32)
+    return leaf.astype(F32)
+
+
+def _rms_norm(x, w, eps):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
+
+
+def _layer_norm(x, w, b, eps):
+    mu = jnp.mean(x, -1, keepdims=True)
+    var = jnp.mean((x - mu) ** 2, -1, keepdims=True)
+    return (x - mu) * jax.lax.rsqrt(var + eps) * w + b
+
+
+def _rope(x, theta):
+    """Rotate-half rotary embedding.  x: [T, heads, d], positions 0..T-1."""
+    t, _, d = x.shape
+    inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=F32) / d)
+    ang = jnp.arange(t, dtype=F32)[:, None] * inv[None, :]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    x1, x2 = x[..., : d // 2], x[..., d // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+def alibi_slopes(n_heads: int) -> list:
+    """ALiBi slopes (Press et al. 2022, as BLOOM uses them)."""
+    def pow2(n):
+        start = 2.0 ** (-8.0 / n)
+        return [start ** (i + 1) for i in range(n)]
+    if math.log2(n_heads).is_integer():
+        return pow2(n_heads)
+    closest = 2 ** math.floor(math.log2(n_heads))
+    return pow2(closest) + pow2(2 * closest)[0::2][: n_heads - closest]
+
+
+def _attention(q, k, v, slopes):
+    """Causal softmax attention.  q: [T, nh, d]; k, v: [T, nkv, d]."""
+    t, nh, d = q.shape
+    g = nh // k.shape[1]
+    k, v = jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1)
+    s = jnp.einsum("qhd,khd->hqk", q, k) / math.sqrt(d)
+    if slopes is not None:
+        s = s + slopes[:, None, None] * jnp.arange(t, dtype=F32)[None, None, :]
+    causal = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
+    s = jnp.where(causal[None], s, -jnp.inf)
+    return jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, -1), v)
+
+
+def _gelu_tanh(x):
+    return 0.5 * x * (1.0 + jnp.tanh(
+        math.sqrt(2.0 / math.pi) * (x + 0.044715 * x ** 3)))
+
+
+def _make_layer_fn(cfg: dict):
+    family = cfg["family"]
+    nh, nkv = cfg["num_heads"], cfg["num_kv_heads"]
+    hd = cfg.get("head_dim_override") or cfg["hidden_size"] // nh
+    eps = cfg.get("norm_eps", 1e-5)
+    theta = cfg.get("rope_theta", 10000.0)
+    slopes = (jnp.asarray(alibi_slopes(nh), F32) if family == "bloom"
+              else None)
+
+    @jax.jit
+    def layer(x, layers, i):
+        p = {k: _f32(jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+            v)) for k, v in layers.items()}
+        t = x.shape[0]
+        if family == "bloom":
+            h = _layer_norm(x, p["attn_norm_w"], p["attn_norm_b"], eps)
+        else:
+            h = _rms_norm(x, p["attn_norm_w"], eps)
+        q = h @ p["wq"] + p["bq"]
+        k = h @ p["wk"] + p["bk"]
+        v = h @ p["wv"] + p["bv"]
+        q, k, v = (q.reshape(t, nh, hd), k.reshape(t, nkv, hd),
+                   v.reshape(t, nkv, hd))
+        if family != "bloom":
+            q, k = _rope(q, theta), _rope(k, theta)
+        a = _attention(q, k, v, slopes).reshape(t, nh * hd) @ p["wo"]
+        if family == "bloom":
+            a = a + p["bo"]
+        x = x + a
+        if family == "bloom":
+            h = _layer_norm(x, p["mlp_norm_w"], p["mlp_norm_b"], eps)
+            m = _gelu_tanh(h @ p["w_up"] + p["b_up"]) @ p["w_down"] \
+                + p["b_down"]
+        else:
+            h = _rms_norm(x, p["mlp_norm_w"], eps)
+            m = (jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])) @ p["w_down"]
+        return x + m
+
+    return layer
+
+
+def emitted_logprobs(params, cfg: dict, ids: list, n_prompt: int) -> dict:
+    """Teacher-forced over ``ids`` (prompt then the tokens the server
+    emitted): for each emitted token the reference's log-probability of
+    it, and the reference's own best token and its log-probability.
+
+    ``cfg`` is the configuration file's ``model_config`` group."""
+    if cfg["family"] not in ("qwen2", "bloom"):
+        raise ValueError(f"no reference equations for {cfg['family']!r}")
+    eps = cfg.get("norm_eps", 1e-5)
+    ids_a = jnp.asarray(ids, jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        x = params.embed["tokens"][ids_a].astype(F32)
+        if cfg["family"] == "bloom":
+            x = _layer_norm(x, _f32(params.embed["norm_w"]),
+                            _f32(params.embed["norm_b"]), eps)
+        layer = _make_layer_fn(cfg)
+        for i in range(cfg["num_layers"]):
+            x = layer(x, params.layers, jnp.int32(i))
+        x = x[n_prompt - 1: len(ids) - 1]           # rows that predict
+        if cfg["family"] == "bloom":
+            x = _layer_norm(x, _f32(params.final_norm["w"]),
+                            _f32(params.final_norm["b"]), eps)
+        else:
+            x = _rms_norm(x, _f32(params.final_norm["w"]), eps)
+        target = ids_a[n_prompt:]
+        vocab = cfg["vocab_size"]
+        run_max = jnp.full((x.shape[0],), -jnp.inf, F32)
+        run_sum = jnp.zeros((x.shape[0],), F32)
+        best = jnp.full((x.shape[0],), -jnp.inf, F32)
+        best_id = jnp.zeros((x.shape[0],), jnp.int32)
+        picked = jnp.zeros((x.shape[0],), F32)
+        for lo in range(0, vocab, HEAD_BLOCK):
+            hi = min(vocab, lo + HEAD_BLOCK)
+            if cfg.get("tie_embeddings"):
+                w = params.embed["tokens"][lo:hi].astype(F32).T
+            else:
+                w = _f32(params.lm_head["w"])[:, lo:hi]
+            logits = x @ w                              # [G, hi - lo]
+            m = jnp.maximum(run_max, logits.max(-1))
+            run_sum = (run_sum * jnp.exp(run_max - m)
+                       + jnp.exp(logits - m[:, None]).sum(-1))
+            run_max = m
+            blk_best = logits.max(-1)
+            blk_id = logits.argmax(-1).astype(jnp.int32) + lo
+            best_id = jnp.where(blk_best > best, blk_id, best_id)
+            best = jnp.maximum(best, blk_best)
+            inside = (target >= lo) & (target < hi)
+            col = jnp.clip(target - lo, 0, hi - lo - 1)
+            picked = jnp.where(
+                inside, jnp.take_along_axis(logits, col[:, None], 1)[:, 0],
+                picked)
+        lse = run_max + jnp.log(run_sum)
+    return {"logprobs": [float(v) for v in (picked - lse)],
+            "best_ids": [int(v) for v in best_id],
+            "best_logprobs": [float(v) for v in (best - lse)]}

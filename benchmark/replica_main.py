@@ -1,0 +1,133 @@
+#!/usr/bin/env python3
+"""The replica child of a benchmark run: the program's own ``serve``.
+
+The main thread calls the program's entry point
+``cli.main(["serve", "--model", ..., "--batch-slots", ...])`` with the
+flags of the configuration file.  What this wrapper adds lies outside the
+program's files:
+
+1. the configuration's ``model_config`` goes into the model registry under
+   the file's ``model`` name before ``serve`` resolves it (a name the
+   registry already holds must hold the same sizes);
+2. a control thread reads lines from stdin: ``TRACE_START <dir>`` /
+   ``TRACE_STOP`` switch ``jax.profiler`` and stamp ``time.time_ns()`` and
+   ``time.monotonic()`` at both ends, so the client's timeline and the
+   device trace share a clock; ``REFERENCE <json>`` runs
+   ``reference.emitted_logprobs`` on the served parameters;
+3. the one loader call ``models.loader.load_or_init`` is wrapped to keep a
+   read-only handle on those parameters.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import json
+import sys
+import threading
+import time
+import traceback
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parent
+sys.path.insert(0, str(BENCH.parent))
+sys.path.insert(0, str(BENCH))
+
+_HELD = {}                      # "params": the served parameter tree
+
+
+def _register(model: str, fields: dict) -> None:
+    from distributed_inference_demo_tpu.models.base import ModelConfig
+    from distributed_inference_demo_tpu.models.registry import MODEL_REGISTRY
+    want = ModelConfig(**fields)
+    have = MODEL_REGISTRY.get(model)
+    if have is None:
+        MODEL_REGISTRY[model] = want
+    elif have != want:
+        diff = {k: (getattr(have, k), v)
+                for k, v in dataclasses.asdict(want).items()
+                if getattr(have, k) != v}
+        raise SystemExit(f"replica_main: the registry's {model!r} differs "
+                         f"from the configuration file: {diff}")
+
+
+def _hold_params() -> None:
+    from distributed_inference_demo_tpu.models import loader
+    inner = loader.load_or_init
+
+    def load_or_init(*args, **kwargs):
+        params = inner(*args, **kwargs)
+        _HELD.setdefault("params", params)
+        return params
+
+    loader.load_or_init = load_or_init
+
+
+def _say(marker: str, payload: dict) -> None:
+    print(f"{marker} {json.dumps(payload)}", flush=True)
+
+
+def _stamp() -> dict:
+    return {"time_ns": time.time_ns(), "monotonic": time.monotonic()}
+
+
+def _control(model_fields: dict) -> None:
+    """Serve control lines until stdin closes.  A failure answers with an
+    ``error`` field: the parent decides what it means for the run."""
+    import jax
+    for line in sys.stdin:
+        cmd, _, rest = line.strip().partition(" ")
+        try:
+            if cmd == "TRACE_START":
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level = 0
+                opts.host_tracer_level = 1
+                before = _stamp()
+                jax.profiler.start_trace(rest, profiler_options=opts)
+                _say("TRACE_STARTED", {"start": before, "running": _stamp()})
+            elif cmd == "TRACE_STOP":
+                before = _stamp()
+                jax.profiler.stop_trace()
+                _say("TRACE_STOPPED", {"stop": before, "written": _stamp()})
+            elif cmd == "REFERENCE":
+                import reference
+                req = json.loads(rest)
+                t0 = time.monotonic()
+                out = reference.emitted_logprobs(
+                    _HELD["params"], model_fields, req["ids"],
+                    req["n_prompt"])
+                out["seconds"] = time.monotonic() - t0
+                _say("REFERENCE_RESULT", out)
+        except Exception as e:          # the thread must answer, not die
+            traceback.print_exc()
+            _say({"TRACE_START": "TRACE_STARTED", "TRACE_STOP":
+                  "TRACE_STOPPED"}.get(cmd, "REFERENCE_RESULT"),
+                 {"error": f"{type(e).__name__}: {e}"})
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--config", required=True)
+    ap.add_argument("--port", type=int, required=True)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--rehearse", action="store_true",
+                    help="serve the file's toy rehearsal model instead")
+    ap.add_argument("--flag", action="append", default=[],
+                    help="extra serve flag (one word each), after the file's")
+    args = ap.parse_args(argv)
+    conf = json.loads(Path(args.config).read_text())
+    if args.rehearse:
+        conf = conf["rehearsal"]
+    _register(conf["model"], conf["model_config"])
+    _hold_params()
+    threading.Thread(target=_control, args=(conf["model_config"],),
+                     daemon=True).start()
+    from distributed_inference_demo_tpu import cli
+    return cli.main(["serve", "--model", conf["serve_model"],
+                     *conf["serve_flags"], *args.flag,
+                     "--weights-seed", str(args.seed),
+                     "--http-port", str(args.port)])
+
+
+if __name__ == "__main__":
+    sys.exit(main())
