@@ -637,26 +637,28 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
 
     def impl(q, k, v, k_pages, v_pages, positions, cache_start, slopes):
         tables = bound["tables"]
-        k_pages, v_pages = write_paged_kv(k_pages, v_pages, k, v,
-                                          tables, positions)
         chunk = q.shape[1]
         path, why = route_paged_attention(
             backend, jax.default_backend(), k_pages, chunk,
             q.shape[2] // k.shape[2])
         if record is not None:
             record.note(bound["program"], chunk, path, why)
-        if path == PATH_DECODE_KERNEL:
-            kv_lens = positions[:, -1] + 1
-            out = paged_flash_attention(q, k_pages, v_pages, tables,
-                                        kv_lens, slopes,
-                                        interpret=interpret)
-        elif path == PATH_PREFILL_KERNEL:
-            out = paged_prefill_attention(q, k_pages, v_pages, tables,
-                                          positions, slopes,
-                                          interpret=interpret)
-        else:
-            out = paged_gather_attention(q, k_pages, v_pages, tables,
-                                         positions, slopes)
+        # metadata only: a profiler capture keeps the scope with each op
+        with jax.named_scope("paged_attention"):
+            k_pages, v_pages = write_paged_kv(k_pages, v_pages, k, v,
+                                              tables, positions)
+            if path == PATH_DECODE_KERNEL:
+                kv_lens = positions[:, -1] + 1
+                out = paged_flash_attention(q, k_pages, v_pages, tables,
+                                            kv_lens, slopes,
+                                            interpret=interpret)
+            elif path == PATH_PREFILL_KERNEL:
+                out = paged_prefill_attention(q, k_pages, v_pages, tables,
+                                              positions, slopes,
+                                              interpret=interpret)
+            else:
+                out = paged_gather_attention(q, k_pages, v_pages, tables,
+                                             positions, slopes)
         return out, k_pages, v_pages
 
     return impl, bind

@@ -79,7 +79,8 @@ from ..telemetry import profiling as _profiling
 from ..telemetry.anomaly import AnomalyMonitor
 from ..telemetry.flightrecorder import get_flight_recorder
 from ..telemetry.slo import get_slo_ledger, sanitize_tenant
-from ..telemetry.tracing import TraceRecorder, to_chrome_trace
+from ..telemetry.tracing import (DispatchTrace, TraceRecorder,
+                                 to_chrome_trace)
 from .engine import (GenerationResult, check_capacity,
                      make_paged_chunk_programs, validate_prefill_chunk)
 from .speculative import verify_emit_per_row
@@ -148,6 +149,10 @@ class Request:
     trace_id: int = 0
     t_submit_wall: float = 0.0     # epoch seconds at admission
     t_sched: float = 0.0           # perf_counter at scheduler pickup
+    # mixed path: the dispatches (DispatchTrace.seq) that carried the
+    # request's first and its final prefill segment
+    first_seq: int = 0
+    final_seq: int = 0
     migration_pause: float = 0.0   # accumulated seconds frozen
     migrated: bool = False         # was live-migrated out at least once
     # adopted (migrated-IN) requests never close a timeline here: the
@@ -522,9 +527,10 @@ class ContinuousBatchingEngine:
             pos = lengths[:, None]
             logits, cache = fwd_p(params, last_tok[:, None], cache, pos,
                                   True)
-            tok = sample_logits(logits[:, 0], rng, samp_)
-            tok = jnp.where(active, tok, last_tok)
-            lp = _emitted_logprob(logits[:, 0], tok)
+            with jax.named_scope("sampling"):
+                tok = sample_logits(logits[:, 0], rng, samp_)
+                tok = jnp.where(active, tok, last_tok)
+                lp = _emitted_logprob(logits[:, 0], tok)
             lengths = lengths + active.astype(jnp.int32)
             return cache, lengths, tok, lp
 
@@ -725,12 +731,18 @@ class ContinuousBatchingEngine:
                 the loop already done."""
                 B_ = last_tok.shape[0]
                 cache = KVCache(pk, pv, jnp.zeros((), jnp.int32))
-                logits, cache = slab_body(params, cache, seg_ids,
-                                          seg_tables, seg_starts,
-                                          "mixed_step")
+                # the scopes are metadata on the ops: a capture keeps
+                # each op's path (`jit(mixed_step)/decode_loop/...`) in
+                # the op's event metadata.  The whole-pool relayout
+                # copies are the compiler's own and carry none
+                with jax.named_scope("slab_body"):
+                    logits, cache = slab_body(params, cache, seg_ids,
+                                              seg_tables, seg_starts,
+                                              "mixed_step")
                 if with_finals:
-                    final_toks, final_lps = slab_finals(
-                        logits, seg_lens, seg_keys)
+                    with jax.named_scope("slab_finals"):
+                        final_toks, final_lps = slab_finals(
+                            logits, seg_lens, seg_keys)
                     lengths = lengths.at[seg_slot].set(
                         seg_plen, mode="drop")
                     last_tok = last_tok.at[seg_slot].set(
@@ -748,10 +760,11 @@ class ContinuousBatchingEngine:
                     final_lps = jnp.zeros((n_seg,), jnp.float32)
                     done0 = None
                 bind_tables(dec_tables, "mixed_step")
-                cache, lengths, tok, toks, lps, steps = _fused_loop(
-                    paged_one_step, params, cache, lengths, last_tok,
-                    active, dec_rng, eos, budget, num_steps,
-                    done0=done0)
+                with jax.named_scope("decode_loop"):
+                    cache, lengths, tok, toks, lps, steps = _fused_loop(
+                        paged_one_step, params, cache, lengths, last_tok,
+                        active, dec_rng, eos, budget, num_steps,
+                        done0=done0)
                 return (cache.keys, cache.values, lengths, tok,
                         final_toks, final_lps, toks, lps, steps)
 
@@ -1199,8 +1212,12 @@ class ContinuousBatchingEngine:
         # the /stats percentile source (reference analog: the per-stage
         # timer story, runtime/stats.py)
         self._lat = {"ttft": deque(maxlen=512), "e2e": deque(maxlen=512),
-                     "per_token": deque(maxlen=512)}
+                     "per_token": deque(maxlen=512),
+                     "queue_wait": deque(maxlen=512)}
         self._completed = 0
+        # one record per mixed dispatch + the host phases around it
+        # (docs/DESIGN.md §20); scheduler thread writes, /stats reads
+        self.dispatch_trace = DispatchTrace()
 
         # (mixed mode never dispatches the serialized step programs —
         # its two mixed_step variants compile on first use instead)
@@ -2051,6 +2068,7 @@ class ContinuousBatchingEngine:
                     round(cs["mixed_packed_tokens"]
                           / cs["mixed_budget_tokens"], 4)
                     if cs["mixed_budget_tokens"] else None)}
+            out["dispatch_trace"] = self.dispatch_trace.snapshot()
         if self.disagg_stats["premigrated_requests"]:
             out["disagg"] = dict(self.disagg_stats)
         if self.resume_stats["requests"]:
@@ -2132,6 +2150,7 @@ class ContinuousBatchingEngine:
             self.kv_cache.reset_stats()
         self.spec_stats = {"rounds": 0, "drafted": 0, "accepted": 0}
         self._reset_chunk_stats()
+        self.dispatch_trace.reset()
         self._completed = 0
         for res in self._lat.values():
             res.clear()
@@ -2763,7 +2782,10 @@ class ContinuousBatchingEngine:
                     "engine.prefill", req.trace_id,
                     ts=base + max(0.0, t_sched - req.t_submit),
                     dur=max(0.0, t_first - t_sched),
-                    rid=req.rid, tenant=req.tenant)
+                    rid=req.rid, tenant=req.tenant,
+                    # the dispatches that served it (dispatch_trace.seq)
+                    first_seq=req.first_seq or None,
+                    final_seq=req.final_seq or None)
                 if t_done > t_first and len(req.tokens) > 1:
                     self.tracer.record(
                         "engine.decode", req.trace_id,
@@ -2977,6 +2999,8 @@ class ContinuousBatchingEngine:
         packed prefill segments.  The serialized loop's per-iteration
         bookkeeping (cancel sweep, export service) rides along at the
         same points."""
+        trace = self.dispatch_trace
+        trace.enter("intake")
         free = [i for i, s in enumerate(self._slots) if s is None]
         # block for work only when truly idle: nothing decoding, no
         # admission mid-stream, nothing waiting to be served
@@ -2985,7 +3009,11 @@ class ContinuousBatchingEngine:
                    else 0.0)
         while True:
             try:
-                req = self._queue.get(timeout=timeout)
+                if timeout is None:
+                    with trace.idle():     # nobody's host time
+                        req = self._queue.get()
+                else:
+                    req = self._queue.get(timeout=timeout)
             except queue.Empty:
                 break
             timeout = 0.0
@@ -3044,11 +3072,19 @@ class ContinuousBatchingEngine:
         self._service_exports()
         if not any(self._slots) and not self._adms:
             return
-        self._dispatch_mixed(
+        record = self._dispatch_mixed(
             [i for i, s in enumerate(self._slots) if s is None])
+        # committed here, not inside: `drain` then runs until the call
+        # has returned, so it holds the frame's teardown too (freeing
+        # the dispatch's device arrays drops the GIL, and the HTTP
+        # threads woken by the tokens just delivered take their turn)
+        if record is not None:
+            trace.commit(**record)
 
-    def _dispatch_mixed(self, free: list) -> None:
-        """Build and run ONE mixed token-budget dispatch, then drain it.
+    def _dispatch_mixed(self, free: list) -> Optional[dict]:
+        """Build and run ONE mixed token-budget dispatch, then drain it;
+        returns the dispatch record's fields (``DispatchTrace.commit``)
+        if the dispatch reached the device.
 
         Packing policy (docs/DESIGN.md §19): every active decode row
         contributes its ``decode_block`` fused-loop tokens off the top
@@ -3062,6 +3098,9 @@ class ContinuousBatchingEngine:
         in pack order, then ONE decode split iff any row decodes —
         exactly the serialized path's spend, which keeps cold-start
         sampled streams bit-identical."""
+        trace = self.dispatch_trace
+        trace.enter("pack")
+        seq = trace.seq + 1  # this dispatch's number, if it gets there
         B = self.max_batch
         C = self.prefill_chunk
         W = self._table_width
@@ -3173,6 +3212,24 @@ class ContinuousBatchingEngine:
             num_rounds, k_disp = 0, 0
             dec_sub = jax.random.PRNGKey(0)   # prefill-only: loop is
                                               # a 0-step no-op
+        # what the decode kernel has to read: tokens of KV held by the
+        # rows that decode here (host state only, never the device's)
+        kv_tokens = sum(len(s.prompt) + len(s.tokens)
+                        for s in self._slots if s is not None)
+        # a request's queue wait ends at the launch of the first
+        # dispatch that carries one of its segments: pending, waiting
+        # for pages, for budget, for the running execution to end
+        now = time.perf_counter()
+        for (_, a, is_final, _) in packed:
+            req = a["req"]
+            if req.first_seq == 0:
+                req.first_seq = seq
+                req.t_sched = now
+                self._lat["queue_wait"].append(now - req.t_submit)
+                trace.queue_wait(now - req.t_submit)
+            if is_final:
+                req.final_seq = seq
+                kv_tokens += len(req.prompt)
         prog = ("mixed_step" if not spec_mixed else
                 "mixed_spec_step" if self._mixed_spec_step is not None
                 else "mixed_pld_step")
@@ -3180,19 +3237,22 @@ class ContinuousBatchingEngine:
             prog, batch=int(active_mask.sum()),
             chunk=self.decode_block, kv_dtype=self.kv_cache.kv_dtype)
         _t0 = self._prof.begin(_sig)
+        t_launch = trace.enter("launch")
         try:
             if not spec_mixed:
-                (self._pk, self._pv, self._lengths, tok, final_toks,
-                 final_lps, toks, lps, steps) = self._mixed_step(
-                    self.params, self._pk, self._pv,
-                    jnp.asarray(seg_ids), jnp.asarray(seg_tables),
-                    jnp.asarray(seg_starts), jnp.asarray(seg_lens),
-                    jnp.asarray(seg_slot), jnp.asarray(seg_plen),
-                    jnp.asarray(seg_keys), jnp.asarray(self._tables),
-                    self._lengths, self._last_tok,
-                    jnp.asarray(active_mask), dec_sub,
-                    self._eos_scalar(), jnp.asarray(budget_vec),
-                    self.decode_block, with_finals)
+                with jax.profiler.StepTraceAnnotation("mixed_step",
+                                                      step_num=seq):
+                    (self._pk, self._pv, self._lengths, tok, final_toks,
+                     final_lps, toks, lps, steps) = self._mixed_step(
+                        self.params, self._pk, self._pv,
+                        jnp.asarray(seg_ids), jnp.asarray(seg_tables),
+                        jnp.asarray(seg_starts), jnp.asarray(seg_lens),
+                        jnp.asarray(seg_slot), jnp.asarray(seg_plen),
+                        jnp.asarray(seg_keys), jnp.asarray(self._tables),
+                        self._lengths, self._last_tok,
+                        jnp.asarray(active_mask), dec_sub,
+                        self._eos_scalar(), jnp.asarray(budget_vec),
+                        self.decode_block, with_finals)
                 self._last_tok = tok
             elif self._mixed_spec_step is not None:
                 (self._pk, self._pv, self._dpk, self._dpv,
@@ -3238,7 +3298,19 @@ class ContinuousBatchingEngine:
                           if a["req"] not in failed]
             for req in failed:
                 self._fail_request(req, e)
-            return
+            return None
+        trace.enter("wait")          # the first blocking read
+        if spec_mixed:
+            em_np, ns_np = np.asarray(em), np.asarray(ns)
+            steps = num_rounds
+        else:
+            steps = int(steps)       # the on-device active count
+        record = dict(
+            t_launch=t_launch, t_done=trace.enter("drain"),
+            with_finals=with_finals, segments=len(packed),
+            finals=sum(1 for (_, _, f, _) in packed if f),
+            prefill_tokens=prefill_tokens, active_rows=n_active,
+            steps=steps, kv_tokens=kv_tokens)
         cs = self.chunk_stats
         cs["mixed_dispatches"] += 1
         cs["mixed_prefill_tokens"] += prefill_tokens
@@ -3299,7 +3371,6 @@ class ContinuousBatchingEngine:
                     slot, req, int(final_toks_np[r0]),
                     None if spec_mixed else float(final_lps_np[r0]))
         if spec_mixed:
-            em_np, ns_np = np.asarray(em), np.asarray(ns)
             if _t0 is not None:
                 self._prof.end(_sig, _t0, out=self._last_tok,
                                hbm_bytes=(
@@ -3318,8 +3389,7 @@ class ContinuousBatchingEngine:
                                            num_rounds)
             if num_rounds > 0 and self._adms:
                 cs["interleaved_steps"] += 1
-            return
-        steps = int(steps)           # the on-device active count
+            return record
         if _t0 is not None:
             # sampled only (int(steps) above already synced): packed
             # prefill writes + every active row's per-step history read
@@ -3336,6 +3406,7 @@ class ContinuousBatchingEngine:
                 np.asarray(lps))
         if steps > 0 and self._adms:
             cs["interleaved_steps"] += 1
+        return record
 
     def _update_spec_krow(self, live0, k_vec, ns_np, num_rounds: int
                           ) -> None:
@@ -3390,9 +3461,11 @@ class ContinuousBatchingEngine:
             # below is untouched: it is the bit-identity reference and
             # the bench baseline.
             while self._running:
+                self.dispatch_trace.enter("bookkeeping")
                 self.anomaly.observe(self.stats)
                 self._sample_hbm()
                 self._mixed_iteration()
+            self.dispatch_trace.leave()
             self._drain_all(
                 RuntimeError("engine closed while request in flight"))
             return

@@ -21,10 +21,19 @@ wall-clock start ONCE at span open and measures the duration on
 ``perf_counter``, so a span's start cannot drift when NTP steps the wall
 clock mid-span (reconstructing start as ``time.time() - dur`` at close
 would move it by exactly the step).
+
+Beside the request spans sits the scheduler's own record,
+:class:`DispatchTrace`: one row per mixed dispatch on ``time.monotonic()``
+with the host phases around it, read by ``/stats`` and mirrored as
+``sched.*`` annotations into any ``jax.profiler`` capture.  A request's
+``engine.prefill`` span carries the ``seq`` of the dispatches that served
+it, which ties the two together.
 """
 
 from __future__ import annotations
 
+import contextlib
+import copy
 import itertools
 import os
 import random
@@ -143,6 +152,144 @@ class TraceRecorder:
     def __len__(self) -> int:
         with self._lock:
             return len(self._spans)
+
+
+# ---------------------------------------------------------------------------
+# the scheduler's dispatch record (docs/DESIGN.md §20)
+
+DISPATCH_PHASES = ("bookkeeping", "intake", "pack", "launch", "wait",
+                   "drain")
+DISPATCH_FIELDS = (("seq", "t_launch", "t_done") + DISPATCH_PHASES
+                   + ("with_finals", "segments", "finals",
+                      "prefill_tokens", "active_rows", "steps",
+                      "kv_tokens"))
+_DISPATCH_RING = 128       # x ~120 bytes a row: /stats stays under 16 KB
+
+
+class DispatchTrace:
+    """One record per mixed dispatch that reached the device, and the
+    host phases of the scheduler iteration around it.
+
+    Always on; written by the scheduler thread only; read by ``/stats``
+    through :meth:`snapshot`.  Instants are ``time.monotonic()`` (the
+    clock a profiler capture can be placed on: stamp it beside
+    ``jax.profiler.start_trace``), durations differences of it.
+
+    The scheduler walks a cursor through the phases of an iteration:
+    :meth:`enter` ends the phase in progress and starts the next at the
+    same instant (one clock read a boundary, so the phases tile the
+    iteration without holes), and each phase is also a
+    ``jax.profiler.TraceAnnotation("sched.<phase>", seq=...)`` — inert
+    unless a capture runs, then a row on the ``/host:CPU`` plane above
+    the device lines it explains.  :meth:`commit` ends ``drain`` and
+    turns what the phases accumulated since the last record into one
+    row of :data:`DISPATCH_FIELDS`; an iteration that dispatched nothing
+    carries its seconds into the next record.  The blocking wait of an
+    idle engine is no phase (:meth:`idle`)."""
+
+    def __init__(self):
+        from jax.profiler import TraceAnnotation
+        self._annotate = TraceAnnotation
+        self._names = {p: f"sched.{p}" for p in DISPATCH_PHASES}
+        self._phase: Optional[str] = None
+        self._t0 = 0.0
+        self._ann = None
+        self.recent: "deque[tuple]" = deque(maxlen=_DISPATCH_RING)
+        self.reset()
+
+    def reset(self) -> None:
+        self.recent.clear()
+        self.seq = 0
+        self.phase_s = dict.fromkeys(DISPATCH_PHASES, 0.0)
+        self._carry = dict.fromkeys(DISPATCH_PHASES, 0.0)
+        self.idle_wait_s = 0.0
+        self.decode_only = 0
+        self.prefill = 0
+        self.kv_token_steps = 0
+        self.queue_wait_ms_sum = 0.0
+        self.queue_wait_count = 0
+
+    def enter(self, phase: str) -> float:
+        """Start ``phase`` now, ending the one in progress; returns the
+        instant."""
+        now = time.monotonic()
+        self._close(now)
+        self._phase, self._t0 = phase, now
+        self._ann = self._annotate(self._names[phase], seq=self.seq + 1)
+        self._ann.__enter__()
+        return now
+
+    def leave(self) -> float:
+        """End the phase in progress; returns the instant."""
+        now = time.monotonic()
+        self._close(now)
+        return now
+
+    def _close(self, now: float) -> None:
+        if self._phase is None:
+            return
+        dt = now - self._t0
+        self._carry[self._phase] += dt
+        self.phase_s[self._phase] += dt
+        self._ann.__exit__(None, None, None)
+        self._phase = self._ann = None
+
+    @contextlib.contextmanager
+    def idle(self):
+        """Around a wait with nothing to do: books ``idle_wait_s`` and
+        keeps the wait out of the phase it interrupts."""
+        phase = self._phase
+        t0 = self.leave()
+        try:
+            yield
+        finally:
+            self.idle_wait_s += time.monotonic() - t0
+            if phase is not None:
+                self.enter(phase)
+
+    def queue_wait(self, seconds: float) -> None:
+        """A request's submit -> launch of its first dispatch."""
+        self.queue_wait_ms_sum += seconds * 1e3
+        self.queue_wait_count += 1
+
+    def commit(self, *, t_launch: float, t_done: float, with_finals: bool,
+               segments: int, finals: int, prefill_tokens: int,
+               active_rows: int, steps: int, kv_tokens: int) -> int:
+        """The dispatch in progress reached the device and is drained:
+        one record.  Returns its ``seq``."""
+        self.leave()
+        self.seq += 1
+        carry = self._carry
+        self.recent.append((
+            self.seq, round(t_launch, 5), round(t_done, 5),
+            *(round(carry[p], 5) for p in DISPATCH_PHASES),
+            int(with_finals), segments, finals, prefill_tokens,
+            active_rows, steps, kv_tokens))
+        for p in DISPATCH_PHASES:
+            carry[p] = 0.0
+        if segments:
+            self.prefill += 1
+        else:
+            self.decode_only += 1
+        self.kv_token_steps += kv_tokens * steps
+        return self.seq
+
+    def snapshot(self) -> dict:
+        """The ``/stats`` section.  ``recent`` is the ring as rows of
+        numbers in the order of ``fields``.  ``copy.copy`` of a deque is
+        atomic under the GIL; iterating it would race the scheduler's
+        appends."""
+        return {"seq": self.seq,
+                "phase_s": {p: round(v, 6)
+                            for p, v in self.phase_s.items()},
+                "idle_wait_s": round(self.idle_wait_s, 6),
+                "decode_only": self.decode_only,
+                "prefill": self.prefill,
+                "kv_token_steps": self.kv_token_steps,
+                "queue_wait_ms_sum": round(self.queue_wait_ms_sum, 3),
+                "queue_wait_count": self.queue_wait_count,
+                "fields": list(DISPATCH_FIELDS),
+                "recent": [list(r) for r in copy.copy(self.recent)]}
 
 
 def to_chrome_trace(spans: Iterable[dict]) -> dict:

@@ -1,0 +1,292 @@
+"""The scheduler's dispatch record (docs/DESIGN.md §20).
+
+One record per mixed dispatch that reached the device, kept by
+``telemetry.tracing.DispatchTrace`` and shown under ``/stats`` as
+``dispatch_trace``.  Pinned here, at toy size on the CPU (counts and
+order only; a time from this file is a time of XLA's CPU backend):
+
+- the records add up to the counters that were there before
+  (``mixed.dispatches``, ``mixed.prefill_tokens``, ``device_loop_steps``);
+- ``kv_token_steps`` is the hand count of what two scripted requests
+  made the decode kernel read;
+- the host phases tile the iteration: they never exceed the wall time
+  between a record and its neighbour, and an idle engine's blocking wait
+  is in none of them;
+- the ring is bounded and ``/stats`` stays small JSON;
+- a request's queue wait ends at the launch of its first dispatch, so
+  the SLO ledger books a prefill time in the mixed path;
+- ``GET /trace`` ties a request to its dispatches by id;
+- a ``jax.profiler`` capture holds the ``sched.*`` rows and changes no
+  generated id.
+"""
+
+import json
+import sys
+import threading
+import time
+import types
+from pathlib import Path
+
+import pytest
+
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+
+import jax  # noqa: E402
+
+from distributed_inference_demo_tpu.models import get_model_config
+from distributed_inference_demo_tpu.models.loader import load_or_init
+from distributed_inference_demo_tpu.ops.sampling import SamplingParams
+from distributed_inference_demo_tpu.runtime.batching import (
+    ContinuousBatchingEngine)
+from distributed_inference_demo_tpu.telemetry.slo import get_slo_ledger
+from distributed_inference_demo_tpu.telemetry import tracing
+from distributed_inference_demo_tpu.telemetry.tracing import (
+    DISPATCH_FIELDS, DISPATCH_PHASES, DispatchTrace)
+
+# bf16 weights and pages, and the int8-weight family the chip cells serve
+MODELS = ("llama-test", "qwen2-test-int8")
+LONG, SHORT = list(range(2, 24)), [3, 14, 15]      # 22 and 3 tokens
+ROUNDING = 2e-5 + 6e-5      # two instants and six phases at 1e-5
+
+
+def engine(model="llama-test", **kw):
+    cfg = get_model_config(model)
+    kw.setdefault("max_seq", 96)
+    kw.setdefault("max_batch", 4)
+    kw.setdefault("sampling", SamplingParams(greedy=True))
+    kw.setdefault("prompt_buckets", (16, 48))
+    kw.setdefault("kv_block_tokens", 8)
+    kw.setdefault("prefill_chunk", 8)
+    kw.setdefault("decode_block", 4)
+    kw.setdefault("mixed_token_budget", 24)
+    return ContinuousBatchingEngine(cfg, load_or_init(model, cfg, seed=0),
+                                    **kw)
+
+
+def settled_stats(eng) -> dict:
+    """``stats()`` once the scheduler has committed its last dispatch: a
+    request's ``wait`` returns while that dispatch is still draining."""
+    deadline = time.monotonic() + 10
+    while True:
+        st = eng.stats()
+        if (st["dispatch_trace"]["seq"] == st["mixed"]["dispatches"]
+                or time.monotonic() > deadline):
+            return st
+        time.sleep(0.01)
+
+
+def rows(stats) -> list:
+    """``dispatch_trace.recent`` as dicts keyed by ``fields``."""
+    dt = stats["dispatch_trace"]
+    return [dict(zip(dt["fields"], r)) for r in dt["recent"]]
+
+
+@pytest.fixture(scope="module")
+def scripted():
+    """One engine, two requests one after the other (so the packing does
+    not depend on thread timing), a pause with nothing to do between
+    them; ``(stats, requests, exported trace)``."""
+    with engine() as eng:
+        first = eng.submit(LONG, 10, trace_id=11)
+        first.wait(timeout=300)
+        time.sleep(0.3)
+        second = eng.submit(SHORT, 6, trace_id=12)
+        second.wait(timeout=300)
+        yield settled_stats(eng), (first, second), eng.export_trace()
+
+
+@pytest.mark.quick
+@pytest.mark.parametrize("model", MODELS)
+def test_records_add_up_to_the_counters_that_were_there(model):
+    prompts = [SHORT, LONG, [9, 2, 6, 5, 3, 5], list(range(40, 75))]
+    with engine(model) as eng:
+        for r in [eng.submit(p, n) for p, n in zip(prompts, (10, 12, 8, 9))]:
+            r.wait(timeout=300)
+        st = settled_stats(eng)
+    dt, recs = st["dispatch_trace"], rows(st)
+    assert tuple(dt["fields"]) == DISPATCH_FIELDS
+    assert dt["seq"] == st["mixed"]["dispatches"] == len(recs)
+    assert [r["seq"] for r in recs] == list(range(1, dt["seq"] + 1))
+    assert (sum(r["prefill_tokens"] for r in recs)
+            == st["mixed"]["prefill_tokens"] == sum(map(len, prompts)))
+    assert (sum(r["steps"] for r in recs)
+            == st["device_loop"]["device_loop_steps"])
+    assert dt["decode_only"] + dt["prefill"] == dt["seq"]
+    assert dt["prefill"] == sum(1 for r in recs if r["segments"])
+    assert sum(r["finals"] for r in recs) == len(prompts)
+    assert all(r["with_finals"] == (r["finals"] > 0) for r in recs)
+    assert dt["kv_token_steps"] == sum(r["kv_tokens"] * r["steps"]
+                                       for r in recs)
+    assert dt["queue_wait_count"] == len(prompts)
+
+
+def test_kv_token_steps_is_the_hand_count(scripted):
+    """22-token prompt, 10 new: one dispatch packs 8 + 8 + 6 (the final
+    installs the row: 22 tokens of KV) and decodes 4 steps; the next
+    finds 22 + 5 tokens and decodes 4; the last finds 22 + 9 and has one
+    token of budget left.  3-token prompt, 6 new: 3 tokens x 4 steps,
+    then 3 + 5 tokens x 1 step."""
+    stats, _, _ = scripted
+    recs = rows(stats)
+    assert [(r["segments"], r["finals"], r["prefill_tokens"],
+             r["active_rows"], r["steps"], r["kv_tokens"])
+            for r in recs] == [(3, 1, 22, 0, 4, 22), (0, 0, 0, 1, 4, 27),
+                               (0, 0, 0, 1, 1, 31), (1, 1, 3, 0, 4, 3),
+                               (0, 0, 0, 1, 1, 8)]
+    assert (stats["dispatch_trace"]["kv_token_steps"]
+            == 22 * 4 + 27 * 4 + 31 * 1 + 3 * 4 + 8 * 1)
+    assert stats["dispatch_trace"]["decode_only"] == 3
+    assert stats["dispatch_trace"]["prefill"] == 2
+
+
+def test_phases_fit_between_a_record_and_its_neighbour(scripted):
+    recs = rows(scripted[0])
+    for r in recs:
+        assert all(r[p] >= 0 for p in DISPATCH_PHASES)
+        assert r["t_launch"] <= r["t_done"]
+        # launch and wait are exactly what lies between the two instants
+        assert (abs(r["launch"] + r["wait"] - (r["t_done"] - r["t_launch"]))
+                <= ROUNDING)
+    for a, b in zip(recs, recs[1:]):
+        between = (a["drain"] + b["bookkeeping"] + b["intake"] + b["pack"])
+        assert between <= b["t_launch"] - a["t_done"] + ROUNDING
+
+
+def test_an_idle_engines_wait_is_no_phase(scripted):
+    stats, (first, second), _ = scripted
+    dt = stats["dispatch_trace"]
+    assert dt["idle_wait_s"] >= 0.25            # the scripted pause
+    fourth = rows(stats)[3]                      # the first dispatch after
+    assert fourth["bookkeeping"] + fourth["intake"] < 0.25
+    assert second.t_sched - second.t_submit < 0.25
+
+
+def test_ring_is_bounded_and_stats_stay_small_json(monkeypatch):
+    # values as wide as a long-lived chip replica's: two weeks of uptime,
+    # every phase with all its digits, a full four-chip batch
+    ticks = iter(range(10 ** 6))
+    monkeypatch.setattr(tracing, "time", types.SimpleNamespace(
+        monotonic=lambda: 1234567.123456 + 0.00123457 * next(ticks)))
+    tr = DispatchTrace()
+    tr.seq = 99_000
+    for _ in range(300):
+        for phase in DISPATCH_PHASES[:-1]:
+            tr.enter(phase)
+        tr.commit(t_launch=tr.enter("drain") - 0.3, t_done=tr._t0,
+                  with_finals=True, segments=3, finals=3,
+                  prefill_tokens=768, active_rows=64, steps=4,
+                  kv_tokens=262144)
+    monkeypatch.undo()
+    snap = tr.snapshot()
+    assert snap["seq"] == 99_300 and len(snap["recent"]) == 128
+    assert [r[0] for r in snap["recent"]] == list(range(99_173, 99_301))
+    assert all(r[3:9] == [0.00123] * 6 for r in snap["recent"])
+    assert 14 * 1024 < len(json.dumps(snap["recent"])) < 16 * 1024
+    assert snap["kv_token_steps"] == 300 * 262144 * 4
+    with engine() as eng:
+        eng.submit(SHORT, 3).wait(timeout=300)
+        st = settled_stats(eng)
+        assert json.loads(json.dumps(st))["dispatch_trace"]["seq"] == st[
+            "mixed"]["dispatches"]
+        eng.reset_stats()
+        st = eng.stats()
+        assert st["dispatch_trace"]["seq"] == st["mixed"]["dispatches"] == 0
+        assert st["dispatch_trace"]["recent"] == []
+
+
+def test_queue_wait_ends_at_the_first_dispatch_with_one_slot():
+    """One slot, two requests: the second's only segment is a final,
+    which parks until the slot frees, so it waits out the first's whole
+    decode; and from its launch to its first token is prefill, which
+    the SLO ledger booked as 0 s in the mixed path before."""
+    with engine(max_batch=1) as eng:
+        first = eng.submit(LONG, 12)
+        second = eng.submit(SHORT, 4)
+        first.wait(timeout=300)
+        second.wait(timeout=300)
+        st = settled_stats(eng)
+    for r in (first, second):
+        assert r.t_submit < r.t_sched < r.t_first
+        assert 1 <= r.first_seq <= r.final_seq
+    assert second.first_seq > first.final_seq
+    assert (second.t_sched - second.t_submit
+            >= first.t_done - max(first.t_first, second.t_submit))
+    lat = st["latency"]
+    assert 0 < lat["queue_wait_p50_ms"] <= lat["queue_wait_p95_ms"]
+    assert lat["queue_wait_p95_ms"] <= lat["ttft_p95_ms"]
+    dt = st["dispatch_trace"]
+    assert dt["queue_wait_count"] == 2
+    assert dt["queue_wait_ms_sum"] == pytest.approx(
+        sum(r.t_sched - r.t_submit for r in (first, second)) * 1e3,
+        abs=0.01)
+    closed = {t["rid"]: t for t in get_slo_ledger().recent(256)}
+    for r in (first, second):
+        assert closed[r.rid]["prefill_s"] > 0
+        assert closed[r.rid]["queue_wait_s"] == pytest.approx(
+            r.t_sched - r.t_submit)
+
+
+def test_prefill_spans_carry_the_ids_of_their_dispatches(scripted):
+    stats, (first, second), trace = scripted
+    spans = {e["args"]["rid"]: e["args"] for e in trace["traceEvents"]
+             if e.get("name") == "engine.prefill"}
+    assert spans[first.rid]["first_seq"] == spans[first.rid]["final_seq"] == 1
+    assert spans[second.rid]["first_seq"] == 4
+    with engine() as eng:            # 40 tokens: five segments, budget 3
+        req = eng.submit(list(range(40, 80)), 2, trace_id=13)
+        req.wait(timeout=300)
+        args = [e["args"] for e in eng.export_trace()["traceEvents"]
+                if e.get("name") == "engine.prefill"][0]
+    assert args["first_seq"] == req.first_seq == 1
+    assert args["final_seq"] == req.final_seq == 2
+
+
+def _capture(logdir, out):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    with engine() as eng:
+        out["plain"] = eng.submit(LONG, 10).wait(timeout=120).tolist()
+        jax.profiler.start_trace(str(logdir), profiler_options=opts)
+        try:
+            out["traced"] = eng.submit(LONG, 10).wait(timeout=120).tolist()
+        finally:
+            jax.profiler.stop_trace()
+        out["seq"] = settled_stats(eng)["dispatch_trace"]["seq"]
+
+
+@pytest.fixture(scope="module")
+def capture(tmp_path_factory):
+    """A ``jax.profiler`` capture around one request, under a time limit
+    of its own; ``(ids before, ids while tracing, host events)``."""
+    logdir, out = tmp_path_factory.mktemp("capture"), {}
+    worker = threading.Thread(target=_capture, args=(logdir, out),
+                              daemon=True)
+    worker.start()
+    worker.join(timeout=240)
+    assert not worker.is_alive(), "the capture did not end in 240 s"
+    from jax.profiler import ProfileData
+    pb = sorted(logdir.rglob("*.xplane.pb"))[-1]
+    events = [(plane.name, ev.name, dict(ev.stats))
+              for plane in ProfileData.from_file(str(pb)).planes
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith("sched.") or ev.name == "mixed_step"]
+    return out, events
+
+
+def test_ids_are_identical_with_and_without_a_capture(capture):
+    out, _ = capture
+    assert out["plain"] == out["traced"] and len(out["traced"]) == 10
+
+
+def test_a_capture_holds_the_sched_rows_on_a_host_plane(capture):
+    out, events = capture
+    assert {p for p, _, _ in events} == {"/host:CPU"}
+    names = {n for _, n, _ in events}
+    assert {f"sched.{p}" for p in DISPATCH_PHASES} <= names
+    assert "mixed_step" in names
+    # the request's three dispatches, each under its number
+    packs = sorted(s["seq"] for _, n, s in events if n == "sched.pack")
+    assert packs == list(range(out["seq"] - 2, out["seq"] + 1))
+    steps = sorted(s["step_num"] for _, n, s in events if n == "mixed_step")
+    assert steps == packs
