@@ -27,11 +27,14 @@ Two interchangeable compute paths (same numerics as ``ops.attention``):
   everywhere (``JAX_PLATFORMS=cpu`` tier-1 and interpret-mode tests
   exercise the same code path the TPU fallback uses).
 - :func:`paged_flash_attention` — Pallas TPU decode kernel: grid
-  ``(batch, nkv, W)``, the block table rides scalar prefetch so each
-  grid step DMAs exactly one [block_tokens, hd] page HBM->VMEM (pages
-  beyond a row's live count are index-clamped: Mosaic skips the repeat
-  DMA, ``pl.when`` skips the compute), online-softmax accumulators in
-  VMEM scratch — decode reads O(kv_len) HBM, never O(max_seq).
+  ``(batch,)``, the pools stay in HBM and the block table and lengths
+  ride scalar prefetch.  One grid step walks one row: a loop over the
+  row's ``ceil(kv_len / block_tokens)`` LIVE pages copies page
+  ``tables[b, j]`` (``[nkv, block_tokens, hd]``, all kv heads in one
+  DMA) into a VMEM ring ahead of the fold, and folds it into
+  online-softmax accumulators carried by the loop.  A call's time is
+  ``batch`` grid steps plus the live pages — the table's width ``W`` is
+  not in it, and a freed slot costs its grid step alone.
 """
 
 import functools
@@ -133,80 +136,105 @@ def paged_gather_attention(
 # Pallas TPU decode kernel
 
 
+# bytes of K (and as many of V) a row keeps in flight while it folds:
+# the page ring below is as deep as this buys, between 2 and 8 pages
+_RING_BYTES = 1 << 20
+
+
 def _paged_kernel(tab_ref, len_ref, q_ref, *refs, block_tokens: int,
-                  groups: int, use_alibi: bool, quantized: bool):
-    """Grid (b, nkv, W), page index innermost: each step folds one
-    streamed [block_tokens, hd] page into the online-softmax accumulators
-    (VMEM scratch persists across the sequential grid).  Rows are the
-    q-head group members of one kv head (decode chunk = 1), all at the
-    same query position ``kv_len - 1``.
+                  ring: int, use_alibi: bool, quantized: bool):
+    """Grid (b,): one step walks ONE row's live pages, all kv heads at
+    once.  The pools stay in HBM; page ``tables[b, j]`` (``[nkv, bt,
+    hd]``, contiguous in the pool) is copied into slot ``j % ring`` of a
+    VMEM ring while earlier pages fold into the online-softmax
+    accumulators, which are loop carries.  The loop runs
+    ``ceil(kv_len / bt)`` times (at most ``W``), so a row with no live
+    page costs the grid step alone.  Rows of a head are the q-head group
+    members of that kv head (decode chunk = 1), all at the same query
+    position ``kv_len - 1``.
 
     tab_ref (SMEM int32 [b, W]): the block tables; len_ref (SMEM int32
     [b]): per-row valid lengths AFTER the current token's insert.  With
-    ``quantized`` the page refs are int8 and each is followed by its
-    [bt, 1] f32 scale block (same page index map): the dequant happens
-    in-register right after the narrow DMA — HBM traffic stays 1 byte +
-    4/bt per element."""
+    ``quantized`` the pools are int8 and each is followed by its f32
+    scale sidecar as ``[num_pages, nkv, bt]``, copied page for page
+    beside it: the dequant happens in-register right after the narrow
+    DMA — HBM traffic stays 1 byte + 4/hd per element."""
     if quantized:
-        (k_ref, ks_ref, v_ref, vs_ref, slopes_ref,
-         o_ref, o_acc, m_acc, l_acc) = refs
+        (k_hbm, ks_hbm, v_hbm, vs_hbm, slopes_ref, o_ref,
+         k_buf, ks_buf, v_buf, vs_buf, sems) = refs
+        streams = ((k_hbm, k_buf), (ks_hbm, ks_buf),
+                   (v_hbm, v_buf), (vs_hbm, vs_buf))
     else:
-        k_ref, v_ref, slopes_ref, o_ref, o_acc, m_acc, l_acc = refs
-        ks_ref = vs_ref = None
+        k_hbm, v_hbm, slopes_ref, o_ref, k_buf, v_buf, sems = refs
+        streams = ((k_hbm, k_buf), (v_hbm, v_buf))
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    num_j = pl.num_programs(2)
-    rows, hd = q_ref.shape[2], q_ref.shape[3]
+    num_pages, W = k_hbm.shape[0], tab_ref.shape[1]
+    _, nkv, rows, hd = q_ref.shape
     kv_len = len_ref[b]
     bt = block_tokens
+    # a length past the table (a row that finished inside a fused block
+    # keeps stepping) walks the table's W entries and no further
+    n_live = jnp.minimum((kv_len + bt - 1) // bt, W)
 
-    @pl.when(j == 0)
-    def _init():
-        o_acc[:] = jnp.zeros_like(o_acc)
-        m_acc[:] = jnp.full_like(m_acc, _NEG)
-        l_acc[:] = jnp.zeros_like(l_acc)
+    def page_copies(j):
+        # sentinel entries clamp in-range: the garbage is masked below
+        page = jnp.minimum(tab_ref[b, j], num_pages - 1)
+        slot = j % ring
+        return [pltpu.make_async_copy(hbm.at[page], buf.at[slot],
+                                      sems.at[slot, i])
+                for i, (hbm, buf) in enumerate(streams)]
 
-    n_live = (kv_len + bt - 1) // bt
+    for j in range(ring - 1):
+        @pl.when(j < n_live)
+        def _prime():
+            for c in page_copies(j):
+                c.start()
 
-    @pl.when(j < n_live)
-    def _step():
-        q = q_ref[0, 0, :, :].astype(jnp.float32)
-        q = q * (1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32)))
-        k_blk = k_ref[0, 0, :, :].astype(jnp.float32)
-        v_blk = v_ref[0, 0, :, :].astype(jnp.float32)
+    q = q_ref[0].astype(jnp.float32)                    # [nkv, rows, hd]
+    q = q * (1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32)))
+
+    def fold(j, carry):
+        o, m, l = carry
+
+        @pl.when(j + ring - 1 < n_live)
+        def _prefetch():
+            for c in page_copies(j + ring - 1):
+                c.start()
+
+        for c in page_copies(j):
+            c.wait()
+        slot = j % ring
+        k_blk = k_buf[slot].astype(jnp.float32)         # [nkv, bt, hd]
+        v_blk = v_buf[slot].astype(jnp.float32)
         if quantized:
-            k_blk = k_blk * ks_ref[0, 0, :, :]      # [bt, hd] * [bt, 1]
-            v_blk = v_blk * vs_ref[0, 0, :, :]
-        s = jnp.dot(q, k_blk.T,
-                    preferred_element_type=jnp.float32)     # [rows, bt]
+            k_blk = k_blk * ks_buf[slot][:, :, None]    # * [nkv, bt, 1]
+            v_blk = v_blk * vs_buf[slot][:, :, None]
+        s = jnp.einsum("hrd,htd->hrt", q, k_blk,
+                       preferred_element_type=jnp.float32)
         kv_pos = (j * bt
-                  + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1))
+                  + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bt), 2))
         # every q row is the same decode position kv_len - 1, so the
         # causal bound and the validity bound coincide
-        valid = kv_pos < kv_len                             # [1, bt]
-        valid = jnp.broadcast_to(valid, (rows, bt))
+        valid = jnp.broadcast_to(kv_pos < kv_len, s.shape)
         if use_alibi:
-            slope = slopes_ref[0, 0, :][:, None]            # [rows, 1]
             dist = ((kv_len - 1) - kv_pos).astype(jnp.float32)
-            s = s - slope * dist
+            s = s - slopes_ref[:] * dist                # [nkv, rows, 1]
         s = jnp.where(valid, s, _NEG)
 
-        m = jnp.max(m_acc[:], axis=-1, keepdims=True)       # [rows, 1]
-        l = jnp.max(l_acc[:], axis=-1, keepdims=True)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        o_acc[:] = o_acc[:] * alpha + jnp.dot(
-            p, v_blk, preferred_element_type=jnp.float32)
-        m_acc[:] = jnp.broadcast_to(m_new, m_acc.shape)
-        l_acc[:] = jnp.broadcast_to(l_new, l_acc.shape)
+        o_new = o * alpha + jnp.einsum(
+            "hrt,htd->hrd", p, v_blk, preferred_element_type=jnp.float32)
+        return o_new, m_new, l_new
 
-    @pl.when(j == num_j - 1)
-    def _finalize():
-        l = jnp.max(l_acc[:], axis=-1, keepdims=True)
-        o_ref[0, 0, :, :] = (o_acc[:]
-                             / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    o, _, l = jax.lax.fori_loop(
+        0, n_live, fold,
+        (jnp.zeros((nkv, rows, hd), jnp.float32),
+         jnp.full((nkv, rows, 1), _NEG, jnp.float32),
+         jnp.zeros((nkv, rows, 1), jnp.float32)))
+    o_ref[0] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -216,52 +244,50 @@ def _paged_call(q_g, k_pages, v_pages, tables, kv_lens, slopes, *,
                 block_tokens, use_alibi, interpret):
     b, nkv, rows, hd = q_g.shape
     quantized = isinstance(k_pages, QuantizedKVPages)
-    num_pages = k_pages.shape[0]
-    W = tables.shape[1]
     bt = block_tokens
+    k_data = k_pages.data if quantized else k_pages
+    page_bytes = nkv * bt * hd * k_data.dtype.itemsize
+    ring = max(2, min(8, _RING_BYTES // page_bytes))
 
-    def page_map(bb, h, j, tab, lens):
-        # clamp to the live frontier: beyond it the index repeats (no
-        # DMA, pl.when skips compute); sentinel entries clamp in-range
-        live = (lens[bb] + bt - 1) // bt
-        jj = jnp.minimum(j, jnp.maximum(live - 1, 0))
-        page = jnp.minimum(tab[bb, jj], num_pages - 1)
-        return (page, h, 0, 0)
-
-    q_spec = pl.BlockSpec((1, 1, rows, hd),
-                          lambda bb, h, j, tab, lens: (bb, h, 0, 0))
-    slopes_spec = pl.BlockSpec((1, 1, rows),
-                               lambda bb, h, j, tab, lens: (h, 0, 0))
-    page_spec = pl.BlockSpec((1, 1, bt, hd), page_map)
+    row_spec = pl.BlockSpec((1, nkv, rows, hd),
+                            lambda bb, tab, lens: (bb, 0, 0, 0))
+    slopes_spec = pl.BlockSpec((nkv, rows, 1),
+                               lambda bb, tab, lens: (0, 0, 0))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    page_buf = pltpu.VMEM((ring, nkv, bt, hd), k_data.dtype)
     if quantized:
-        # the scale sidecar rides the SAME page index map — a [bt, 1]
-        # f32 block DMA'd alongside its narrow page
-        scale_spec = pl.BlockSpec((1, 1, bt, 1), page_map)
-        in_specs = [q_spec, page_spec, scale_spec, page_spec,
-                    scale_spec, slopes_spec]
-        operands = (tables, kv_lens, q_g, k_pages.data, k_pages.scale,
-                    v_pages.data, v_pages.scale, slopes)
+        # Mosaic slices an HBM ref only where its minor dimension fills
+        # the lanes, which the pool's [.., bt, 1] sidecar does not: the
+        # kernel takes it as [num_pages, nkv, bt]
+        scale_buf = pltpu.VMEM((ring, nkv, bt), k_pages.scale.dtype)
+        in_specs = [row_spec] + [pool_spec] * 4 + [slopes_spec]
+        operands = (tables, kv_lens, q_g,
+                    k_pages.data, k_pages.scale[..., 0],
+                    v_pages.data, v_pages.scale[..., 0], slopes)
+        buffers = [page_buf, scale_buf, page_buf, scale_buf]
     else:
-        in_specs = [q_spec, page_spec, page_spec, slopes_spec]
+        in_specs = [row_spec, pool_spec, pool_spec, slopes_spec]
         operands = (tables, kv_lens, q_g, k_pages, v_pages, slopes)
+        buffers = [page_buf, page_buf]
 
     return pl.pallas_call(
-        functools.partial(_paged_kernel, block_tokens=bt, groups=rows,
+        functools.partial(_paged_kernel, block_tokens=bt, ring=ring,
                           use_alibi=use_alibi, quantized=quantized),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, nkv, W),
+            grid=(b,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, rows, hd),
-                                   lambda bb, h, j, tab, lens:
-                                   (bb, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((rows, hd), jnp.float32),
-                pltpu.VMEM((rows, 128), jnp.float32),
-                pltpu.VMEM((rows, 128), jnp.float32),
-            ],
+            out_specs=row_spec,
+            scratch_shapes=buffers + [
+                pltpu.SemaphoreType.DMA((ring, len(buffers)))],
         ),
         out_shape=jax.ShapeDtypeStruct((b, nkv, rows, hd), q_g.dtype),
+        # the two rings, and a page of K and of V widened to f32 twice
+        # over (the fold's operands and its products), beside the
+        # compiler's default 16 MiB where that is more
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=max(
+            16 << 20,
+            2 * ring * page_bytes + 16 * nkv * bt * hd + (4 << 20))),
         interpret=interpret,
     )(*operands)
 
@@ -281,7 +307,10 @@ def paged_flash_attention(
 
     Requires ``block_tokens % 8 == 0`` (the page's token axis is the
     sublane dimension of the streamed tiles) and a 1-token chunk; the
-    caller falls back to the gather path otherwise."""
+    caller falls back to the gather path otherwise.  Heads of a
+    multiple of 128 take the page loop (:func:`_paged_kernel`; int8
+    pages at a multiple of 128 tokens too); every other shape runs the
+    prefill kernel as a 1-token chunk."""
     b, chunk, nh, hd = q.shape
     if chunk != 1:
         raise ValueError(f"paged_flash_attention is decode-only (chunk=1), "
@@ -297,6 +326,15 @@ def paged_flash_attention(
     if bt % 8:
         raise ValueError(f"block_tokens must be a multiple of 8 for the "
                          f"Pallas kernel, got {bt}")
+    if hd % 128 or (isinstance(k_pages, QuantizedKVPages) and bt % 128):
+        # Mosaic copies a slice of an HBM ref only where the ref's minor
+        # dimension fills the 128 lanes.  A narrower head, or a scale
+        # sidecar of a narrower page, goes through the prefill kernel's
+        # BlockSpec pipeline as a 1-token chunk: the same fold, over a
+        # grid that still has the table's width in it
+        return paged_prefill_attention(
+            q, k_pages, v_pages, tables, (kv_lens - 1)[:, None], slopes,
+            interpret=interpret)
     g = nh // nkv
     rows = max(8, -(-g // 8) * 8)    # pad group rows to the sublane granule
 
@@ -306,14 +344,19 @@ def paged_flash_attention(
     if rows > g:
         q_g = jnp.pad(q_g, ((0, 0), (0, 0), (0, rows - g), (0, 0)))
     if slopes is None:
-        slopes_g = jnp.zeros((nkv, 1, rows), jnp.float32)
+        slopes_g = jnp.zeros((nkv, rows, 1), jnp.float32)
     else:
-        slopes_g = slopes.astype(jnp.float32).reshape(nkv, 1, g)
-        slopes_g = jnp.pad(slopes_g, ((0, 0), (0, 0), (0, rows - g)))
+        slopes_g = slopes.astype(jnp.float32).reshape(nkv, g, 1)
+        slopes_g = jnp.pad(slopes_g, ((0, 0), (0, rows - g), (0, 0)))
+    # a freed slot keeps its last length on the device and only its
+    # table row is sentineled: it has no page, so it has no length (a
+    # live row's first entry is always a real page, and the caller
+    # discards a dead row's output)
+    tables = tables.astype(jnp.int32)
+    kv_lens = jnp.where(tables[:, 0] >= num_pages, 0,
+                        kv_lens.astype(jnp.int32))
 
-    out = _paged_call(q_g, k_pages, v_pages,
-                      tables.astype(jnp.int32),
-                      kv_lens.astype(jnp.int32), slopes_g,
+    out = _paged_call(q_g, k_pages, v_pages, tables, kv_lens, slopes_g,
                       block_tokens=bt, use_alibi=slopes is not None,
                       interpret=interpret)
     return out[:, :, :g, :].reshape(b, 1, nh, hd)
@@ -326,7 +369,9 @@ def paged_flash_attention(
 def _paged_prefill_kernel(tab_ref, start_ref, q_ref, *refs,
                           block_tokens: int, chunk: int, groups: int,
                           use_alibi: bool, quantized: bool):
-    """Grid (b, nkv, W), page index innermost — the prefill twin of
+    """Grid (b, nkv, W), page index innermost: each step folds one
+    streamed [block_tokens, hd] page into online-softmax accumulators
+    (VMEM scratch persists across the sequential grid), the fold of
     :func:`_paged_kernel`.  Rows are (chunk position, q-head group
     member) pairs: row ``r`` is query position ``start + r // g`` of
     q head ``h*g + r % g``, so the whole C-token segment of one kv

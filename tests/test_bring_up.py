@@ -394,27 +394,44 @@ def _compile_for(sharding, fn, *shapes):
     jax.jit(fn).lower(*args).compile()       # raises on a Mosaic refusal
 
 
-@pytest.mark.parametrize("kind,bt", [("bf16", 16), ("int8", 32)])
-@pytest.mark.parametrize("alibi", [False, True])
-def test_paged_kernels_get_through_mosaic(v5e, kind, bt, alibi):
-    """qwen2.5-7b heads (28 q / 4 kv x 128) without ALiBi, bloom560m heads
-    (16 / 16 x 64) with: decode and prefill kernel, bf16 and int8 pages."""
-    nh, nkv, hd = (16, 16, 64) if alibi else (28, 4, 128)
+QWEN, BLOOM560M, BLOOM7B1 = (28, 4, 128), (16, 16, 64), (32, 32, 128)
+MOSAIC_CASES = {
+    # (heads, page kind, page tokens, slots, table width, pool pages)
+    "qwen-bf16-p16": (QWEN, "bf16", 16, 8, 8, 64),
+    "qwen-int8-p32": (QWEN, "int8", 32, 8, 8, 64),
+    "bloom560m-bf16-p16": (BLOOM560M, "bf16", 16, 8, 8, 64),
+    "bloom560m-int8-p32": (BLOOM560M, "int8", 32, 8, 8, 64),
+    # the benchmark's cells as they are served (PERF.md §4), so that a
+    # refusal is found here and not on the chip
+    "cell-qwen2.5-7b": (QWEN, "bf16", 128, 32, 32, 416),
+    "cell-bloom7b1": (BLOOM7B1, "bf16", 128, 8, 16, 46),
+    "cell-tp4-shard": ((7, 1, 128), "bf16", 128, 64, 32, 2048),
+    "qwen-int8-p128": (QWEN, "int8", 128, 32, 32, 416),
+}
+
+
+@pytest.mark.parametrize("case", MOSAIC_CASES)
+def test_paged_kernels_get_through_mosaic(v5e, case):
+    """qwen2.5-7b heads (28 q / 4 kv x 128, one kv head a chip under
+    tp4) without ALiBi, bloom heads (16 / 16 x 64, 32 / 32 x 128) with:
+    decode and prefill kernel, bf16 and int8 pages."""
+    (nh, nkv, hd), kind, bt, b, W, N = MOSAIC_CASES[case]
+    alibi = nh == nkv
     S = jax.ShapeDtypeStruct
     pages = jax.eval_shape(
-        lambda: alloc_kv_pages((64, nkv, bt, hd), kind, jnp.bfloat16))
+        lambda: alloc_kv_pages((N, nkv, bt, hd), kind, jnp.bfloat16))
     slopes = (S((nh,), jnp.float32),) if alibi else ()
     _compile_for(
         v5e, lambda q, pk, pv, t, n, *s: pa.paged_flash_attention(
             q, pk, pv, t, n, *s),
-        S((8, 1, nh, hd), jnp.bfloat16), pages, pages,
-        S((8, 8), jnp.int32), S((8,), jnp.int32), *slopes)
+        S((b, 1, nh, hd), jnp.bfloat16), pages, pages,
+        S((b, W), jnp.int32), S((b,), jnp.int32), *slopes)
     chunk = 64 if nh // nkv * 64 <= 512 else 32
     _compile_for(
         v5e, lambda q, pk, pv, t, p, *s: pa.paged_prefill_attention(
             q, pk, pv, t, p, *s),
         S((2, chunk, nh, hd), jnp.bfloat16), pages, pages,
-        S((2, 8), jnp.int32), S((2, chunk), jnp.int32), *slopes)
+        S((2, W), jnp.int32), S((2, chunk), jnp.int32), *slopes)
 
 
 def test_flash_kernel_with_alibi_gets_through_mosaic(v5e):

@@ -60,6 +60,21 @@ def _linearize(pk, pv, tables, N, bt, W):
     return jnp.asarray(k_lin), jnp.asarray(v_lin)
 
 
+def _apply_mode(mode, pk, pv, nh):
+    """``(pk, pv, slopes)`` of a kernel sweep's mode: plain f32 pages,
+    ALiBi slopes, or int8 pages with their scale sidecar."""
+    slopes = None
+    if mode == "int8":
+        from distributed_inference_demo_tpu.ops.quant import (
+            quantize_kv_pages)
+        pk, pv = quantize_kv_pages(pk, 8), quantize_kv_pages(pv, 8)
+    elif mode == "alibi":
+        from distributed_inference_demo_tpu.ops.attention import (
+            alibi_slopes)
+        slopes = alibi_slopes(nh)
+    return pk, pv, slopes
+
+
 # lengths chosen to hit: mid-block, exact block boundary, single-token
 # tail block, single-token sequence, full table
 SWEEP = [
@@ -93,26 +108,79 @@ def test_gather_matches_dense_reference(case, alibi):
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
 
 
-@pytest.mark.parametrize("case", SWEEP)
-def test_pallas_interpret_matches_gather(case):
+# what the decode kernel's page loop depends on (heads of 128 take the
+# loop; narrower heads and int8 pages under 128 tokens take the prefill
+# kernel's pipeline as a 1-token chunk): a freed slot (all-sentinel
+# table, stale length), one partial page, a length exactly on a page
+# boundary, a row that fills its whole table; the 7 -> 8 row padding of
+# qwen's GQA; 128-token pages, where int8 pages take the loop too
+DECODE_SWEEP = SWEEP + [
+    dict(nh=4, nkv=2, hd=128, bt=8, W=4, lens=[0, 3, 16, 32]),
+    dict(nh=7, nkv=1, hd=128, bt=16, W=5, lens=[80, 0, 1, 33]),
+    dict(nh=8, nkv=2, hd=128, bt=128, W=3, lens=[0, 100, 128, 384]),
+]
+STALE_LEN = 21       # what a freed slot's `lengths` entry may still say
+
+
+@pytest.mark.parametrize("case", DECODE_SWEEP)
+@pytest.mark.parametrize("mode", ["f32", "alibi", "int8"])
+def test_pallas_interpret_matches_gather(case, mode):
     """The TPU kernel (interpret mode) against the XLA fallback — same
-    pages, same tables, f32 tolerance (online softmax vs one-shot)."""
+    pages, same tables, f32 tolerance (online softmax vs one-shot).  A
+    row with no page (length 0 here, a stale length at the kernel) is a
+    freed slot: its output is discarded by the caller and only has to
+    be finite."""
     if case["bt"] % 8:
         pytest.skip("pallas path needs 8-aligned pages")
-    rng = np.random.default_rng(hash(str(case)) % 2**32)
+    rng = np.random.default_rng(hash(str(case) + mode) % 2**32)
     lens = case["lens"]
     b, bt, W = len(lens), case["bt"], case["W"]
     pk, pv, tables, N = _random_paged(rng, b, case["nkv"], case["hd"],
                                       bt, W, lens)
+    pk, pv, slopes = _apply_mode(mode, pk, pv, case["nh"])
     q = jnp.asarray(rng.standard_normal((b, 1, case["nh"], case["hd"])),
                     jnp.float32)
-    qpos = jnp.asarray([l - 1 for l in lens], jnp.int32)[:, None]
-    ref = paged_gather_attention(q, pk, pv, tables, qpos, None)
-    got = paged_flash_attention(q, pk, pv, tables,
-                                jnp.asarray(lens, jnp.int32), None,
+    live = np.asarray(lens) > 0
+    qpos = jnp.asarray([max(l - 1, 0) for l in lens], jnp.int32)[:, None]
+    ref = paged_gather_attention(q, pk, pv, tables, qpos, slopes)
+    kv_lens = jnp.asarray([l or STALE_LEN for l in lens], jnp.int32)
+    got = paged_flash_attention(q, pk, pv, tables, kv_lens, slopes,
                                 interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(ref)[live],
                                rtol=2e-5, atol=2e-5)
+    assert np.isfinite(np.asarray(got)).all()
+
+
+def test_decode_kernel_grid_has_no_table_width():
+    """A call's cost must not scale with the table's width: the page
+    loop runs inside the kernel body for a row's live pages, so the
+    ``pallas_call`` has one grid step a row whatever ``W`` is."""
+    from distributed_inference_demo_tpu.ops.paged_attention import (
+        _paged_call)
+
+    def grid(W):
+        b, nkv, rows, hd, bt, N = 3, 2, 8, 128, 16, 5
+        S = jax.ShapeDtypeStruct
+        jaxpr = jax.make_jaxpr(
+            lambda *a: _paged_call(*a, block_tokens=bt, use_alibi=False,
+                                   interpret=False))(
+            S((b, nkv, rows, hd), jnp.float32),
+            S((N, nkv, bt, hd), jnp.float32),
+            S((N, nkv, bt, hd), jnp.float32), S((b, W), jnp.int32),
+            S((b,), jnp.int32), S((nkv, rows, 1), jnp.float32))
+        calls = []
+
+        def walk(jp):
+            for eqn in jp.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    calls.append(eqn)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+        walk(jaxpr.jaxpr)
+        assert len(calls) == 1
+        return tuple(calls[0].params["grid_mapping"].grid)
+
+    assert grid(8) == grid(64) == (3,)
 
 
 # per-row starts hit: chunk from zero, chunk mid-page, chunk crossing a
@@ -141,20 +209,12 @@ def test_pallas_prefill_interpret_matches_gather(case, mode):
     lens = [s + chunk for s in starts]     # in-chunk keys already paged
     pk, pv, tables, N = _random_paged(rng, b, case["nkv"], case["hd"],
                                       bt, W, lens)
-    if mode == "int8":
-        from distributed_inference_demo_tpu.ops.quant import (
-            quantize_kv_pages)
-        pk, pv = quantize_kv_pages(pk, 8), quantize_kv_pages(pv, 8)
+    pk, pv, slopes = _apply_mode(mode, pk, pv, case["nh"])
     q = jnp.asarray(
         rng.standard_normal((b, chunk, case["nh"], case["hd"])),
         jnp.float32)
     qpos = (jnp.asarray(starts, jnp.int32)[:, None]
             + jnp.arange(chunk, dtype=jnp.int32)[None, :])
-    slopes = None
-    if mode == "alibi":
-        from distributed_inference_demo_tpu.ops.attention import (
-            alibi_slopes)
-        slopes = alibi_slopes(case["nh"])
     ref = paged_gather_attention(q, pk, pv, tables, qpos, slopes)
     got = paged_prefill_attention(q, pk, pv, tables, qpos, slopes,
                                   interpret=True)

@@ -274,6 +274,16 @@ def main(argv=None) -> int:
         ok &= paged_cases(model, "bf16", 16, W, b, chunk, args.interpret)
         ok &= paged_cases(model, "int8", 32, W // 2, b, chunk,
                           args.interpret)
+    # the benchmark's page size (128 tokens) at its cells' slots and table
+    # widths, ragged lengths from 1 token to the whole table: the decode
+    # kernel's page loop runs from 1 to W times a row (int8 pages take
+    # the loop only at this page size)
+    for model, kv_dtype, cw, cb in (("qwen2.5-7b", "bf16", 32, 32),
+                                    ("bloom7b1", "bf16", 16, 8),
+                                    ("qwen2.5-7b", "int8", 32, 32)):
+        ok &= paged_cases(model, kv_dtype, 128,
+                          *((W, b) if args.interpret else (cw, cb)),
+                          64, args.interpret, prefill=False)
     if not args.interpret:
         # the block table at max_seq 32768 with 16-token pages: 8 x 2048
         # int32 = 64 KB of scalar memory
