@@ -5,8 +5,12 @@ the program where the batch is built) x the decode steps the device ran x
 the bytes a token holds on one chip (``bytes.kv_bytes_per_token`` from
 the configuration file: the yardstick's number, not the program's).
 Time: the own time of ``_paged_call.*`` in the trace (a chip's mean),
-scaled by matched / all executions.  Not a roofline share of the call:
-the kernel also walks dead grid steps and reads block tables."""
+scaled by matched / all executions.  Since PR 25 the kernel walks a
+row's live pages only, so this is close to a roofline share of the call,
+and not one: the bytes count tokens where the kernel reads whole pages
+(and block tables), and the value can pass 100 where the plane the kernel
+reads is not in HBM (161.9 in the saturated long-context cell, whose MHA
+pages are 1 MiB; ledger, PR 25; PERF.md section 6)."""
 import importlib
 
 from dispatch_join import join

@@ -1,19 +1,17 @@
 """A plain reference forward pass, independent of the code under test.
 
-Float32 ``jax.numpy`` under ``jax.default_matmul_precision("highest")``:
-the published equations of the two block families the benchmark's
-configurations use, over the whole sequence at once, with no KV cache, no
-kernel, no batching and no line of ``models/decoder.py`` or ``ops/``.
-It reads the program's parameter tree only as data (the names of its
-leaves); an int8 leaf is its stored integers times its scales.
+Float32 ``jax.numpy`` under ``jax.default_matmul_precision("highest")``,
+over the whole sequence at once, with no KV cache, no kernel, no batching
+and no line of ``models/decoder.py`` or ``ops/``.  It reads the program's
+parameter tree only as data (the names of its leaves); an int8 leaf is its
+stored integers times its scales.
 
-* ``qwen2`` (Qwen2.5 technical report; HF ``modeling_qwen2``): pre-RMSNorm,
-  q/k/v projections with bias, rotary embedding in the rotate-half form,
-  grouped-query causal attention, SwiGLU feed-forward, untied head.
-* ``bloom`` (BLOOM paper, section 3; HF ``modeling_bloom``): LayerNorm
-  after the embedding, pre-LayerNorm blocks with biases everywhere, ALiBi
-  (score + slope_h * key_position), causal multi-head attention, a 4H
-  feed-forward with the tanh form of GELU, head tied to the embedding.
+What is specific to one kind of block (the embedding step, one layer, the
+final norm, as its paper and its HF ``modeling_*`` file give them) is the
+family's own module, ``families/<family>.py``, found by the name in the
+configuration's ``model_config.family``.  Here is what every family
+shares: the helpers their equations are written in, the layer loop and
+the head.
 
 One layer runs at a time (one jitted function, the layer picked by index),
 and the head runs in blocks of vocabulary rows with a running
@@ -26,6 +24,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+import families
 
 F32 = jnp.float32
 HEAD_BLOCK = 16384          # vocabulary rows a head block covers
@@ -63,7 +63,7 @@ def _rope(x, theta):
 
 
 def alibi_slopes(n_heads: int) -> list:
-    """ALiBi slopes (Press et al. 2022, as BLOOM uses them)."""
+    """ALiBi slopes (Press et al. 2022), one a head."""
     def pow2(n):
         start = 2.0 ** (-8.0 / n)
         return [start ** (i + 1) for i in range(n)]
@@ -91,44 +91,16 @@ def _gelu_tanh(x):
         math.sqrt(2.0 / math.pi) * (x + 0.044715 * x ** 3)))
 
 
-def _make_layer_fn(cfg: dict):
-    family = cfg["family"]
-    nh, nkv = cfg["num_heads"], cfg["num_kv_heads"]
-    hd = cfg.get("head_dim_override") or cfg["hidden_size"] // nh
-    eps = cfg.get("norm_eps", 1e-5)
-    theta = cfg.get("rope_theta", 10000.0)
-    slopes = (jnp.asarray(alibi_slopes(nh), F32) if family == "bloom"
-              else None)
+def _make_layer_fn(layer_eq):
+    """A family's ``layer_eq(p, x)`` as one jitted function of the stacked
+    leaves and a layer's index."""
 
     @jax.jit
     def layer(x, layers, i):
         p = {k: _f32(jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
             v)) for k, v in layers.items()}
-        t = x.shape[0]
-        if family == "bloom":
-            h = _layer_norm(x, p["attn_norm_w"], p["attn_norm_b"], eps)
-        else:
-            h = _rms_norm(x, p["attn_norm_w"], eps)
-        q = h @ p["wq"] + p["bq"]
-        k = h @ p["wk"] + p["bk"]
-        v = h @ p["wv"] + p["bv"]
-        q, k, v = (q.reshape(t, nh, hd), k.reshape(t, nkv, hd),
-                   v.reshape(t, nkv, hd))
-        if family != "bloom":
-            q, k = _rope(q, theta), _rope(k, theta)
-        a = _attention(q, k, v, slopes).reshape(t, nh * hd) @ p["wo"]
-        if family == "bloom":
-            a = a + p["bo"]
-        x = x + a
-        if family == "bloom":
-            h = _layer_norm(x, p["mlp_norm_w"], p["mlp_norm_b"], eps)
-            m = _gelu_tanh(h @ p["w_up"] + p["b_up"]) @ p["w_down"] \
-                + p["b_down"]
-        else:
-            h = _rms_norm(x, p["mlp_norm_w"], eps)
-            m = (jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])) @ p["w_down"]
-        return x + m
+        return layer_eq(p, x)
 
     return layer
 
@@ -139,24 +111,15 @@ def emitted_logprobs(params, cfg: dict, ids: list, n_prompt: int) -> dict:
     it, and the reference's own best token and its log-probability.
 
     ``cfg`` is the configuration file's ``model_config`` group."""
-    if cfg["family"] not in ("qwen2", "bloom"):
-        raise ValueError(f"no reference equations for {cfg['family']!r}")
-    eps = cfg.get("norm_eps", 1e-5)
+    embed, layer_eq, final_norm = families.load(cfg["family"]).equations(cfg)
     ids_a = jnp.asarray(ids, jnp.int32)
     with jax.default_matmul_precision("highest"):
-        x = params.embed["tokens"][ids_a].astype(F32)
-        if cfg["family"] == "bloom":
-            x = _layer_norm(x, _f32(params.embed["norm_w"]),
-                            _f32(params.embed["norm_b"]), eps)
-        layer = _make_layer_fn(cfg)
+        x = embed(params, ids_a)
+        layer = _make_layer_fn(layer_eq)
         for i in range(cfg["num_layers"]):
             x = layer(x, params.layers, jnp.int32(i))
         x = x[n_prompt - 1: len(ids) - 1]           # rows that predict
-        if cfg["family"] == "bloom":
-            x = _layer_norm(x, _f32(params.final_norm["w"]),
-                            _f32(params.final_norm["b"]), eps)
-        else:
-            x = _rms_norm(x, _f32(params.final_norm["w"]), eps)
+        x = final_norm(params, x)
         target = ids_a[n_prompt:]
         vocab = cfg["vocab_size"]
         run_max = jnp.full((x.shape[0],), -jnp.inf, F32)

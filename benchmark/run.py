@@ -6,8 +6,9 @@
 The cell, its configuration, its traffic mix and its metrics are all looked
 up by name: ``BENCHMARK.json`` (cells, metric lists), ``configs/<name>.json``,
 ``traffic/<mix>.json``, ``cells/<cell>.json`` (the cell's rate or client
-count), ``generators/<generator>.py``, ``end_to_end/<metric>.py`` and
-``layer_metrics/<metric>.py``.  This file holds none of those names.
+count), ``generators/<generator>.py``, ``end_to_end/<metric>.py``,
+``layer_metrics/<metric>.py`` and, for the configuration's kind of block,
+``families/<family>.py``.  This file holds none of those names.
 
 This parent is load generator and meter and never imports JAX.  It starts
 the replica (``replica_main.py`` around the program's ``serve``) and the
@@ -42,6 +43,7 @@ BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 sys.path.insert(0, str(BENCH))
 
+import families                                    # noqa: E402
 from client import Request, ask, run_plan          # noqa: E402
 from stack import BenchFailure, Stack              # noqa: E402
 
@@ -91,7 +93,9 @@ def flag_value(flags: list, name: str) -> int:
 
 def load_cell(workload: str) -> tuple:
     """``(manifest, cell, configuration's manifest entry, configuration
-    file, mix)`` of the cell named ``workload``."""
+    file, mix)`` of the cell named ``workload``.  A configuration whose
+    family (its own or its rehearsal model's) has no module under
+    ``families/`` is refused here, before any child starts."""
     manifest = load_json(ROOT / "BENCHMARK.json")
     cells = {w["name"]: w for w in manifest["workloads"]}
     if workload not in cells:
@@ -99,7 +103,13 @@ def load_cell(workload: str) -> tuple:
                            f"cells: {sorted(cells)}")
     cell = cells[workload]
     entry = next(c for c in manifest["configs"] if c["name"] == cell["config"])
-    return (manifest, cell, entry, load_json(ROOT / entry["file"]),
+    conf = load_json(ROOT / entry["file"])
+    for served in (conf, conf["rehearsal"]):
+        try:
+            families.require(served["model_config"]["family"])
+        except families.UnknownFamily as e:
+            raise BenchFailure(f"{entry['file']}: {e}") from None
+    return (manifest, cell, entry, conf,
             load_json(BENCH / "traffic" / f"{cell['traffic']}.json"))
 
 
