@@ -37,7 +37,7 @@ import jax.numpy as jnp
                       "attn_layernorm", "attn_qkv_bias", "num_experts",
                       "experts_per_token", "moe_capacity_factor",
                       "quantization", "head_dim_override", "embed_scale",
-                      "mlp_act"])
+                      "mlp_act", "qk_norm", "norm_topk_prob"])
 @dataclass(frozen=True)
 class ModelConfig:
     """Static, hashable architecture description shared by all model families.
@@ -77,6 +77,14 @@ class ModelConfig:
     # MoE (mixtral): 0 experts means dense MLP
     num_experts: int = 0
     experts_per_token: int = 2
+    # the router's order: softmax over ALL experts, the k largest, then
+    # renormalise the k to sum to 1 iff ``norm_topk_prob`` (mixtral;
+    # the same arithmetic as its "top-k then softmax").  olmoe keeps the
+    # probabilities as they are: a token's k weights sum to less than 1
+    norm_topk_prob: bool = True
+    # olmoe: RMSNorm over the WHOLE q and k projections (all heads'
+    # channels in one mean square), before the head split and rope
+    qk_norm: bool = False
     # expert-parallel dispatch capacity: slots per expert =
     # ceil(tokens * k / num_experts * factor); over-capacity tokens drop
     moe_capacity_factor: float = 2.0

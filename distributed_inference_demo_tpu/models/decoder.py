@@ -7,6 +7,8 @@ inferred at SURVEY.md §2.2), a single pure ``stage_forward`` covers:
 - **bloom family** (bloom560m..7b1, reference ``data/Data.kt:19-33``):
   LayerNorm+bias, ALiBi, fused dense MLP with GELU.
 - **mixtral family** (Mixtral-8x7B): llama blocks with top-k routed MoE MLP.
+- **olmoe family** (OLMoE-1B-7B): the same routed MLP with the router's
+  probabilities kept as they are, and RMSNorm over the q and k projections.
 
 The per-stage forward is a single ``lax.scan`` over stacked layer weights —
 XLA compiles one loop body reused across layers, keeping compile time flat in
@@ -21,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.attention import alibi_slopes, attention, update_kv_cache
+from ..ops.grouped_matmul import LayerOf, grouped_matmul
 from ..ops.quant import dense
 from ..ops.norms import layer_norm, rms_norm
 from ..ops.rope import apply_rope
@@ -52,7 +55,7 @@ def _init_quantized_layer(rng, scale, shape, dtype, mode="int8"):
     if mode == "int4":
         qa = quantize_array4(w)
         return qa.q, qa.scale
-    qa = quantize_array(w, stacked=False)
+    qa = quantize_array(w)
     return qa.q, qa.scale
 
 
@@ -120,7 +123,10 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
         p["bq"] = jnp.zeros((L, nh * hd), dt)
         p["bk"] = jnp.zeros((L, nkv * hd), dt)
         p["bv"] = jnp.zeros((L, nkv * hd), dt)
-    if cfg.num_experts > 0:  # mixtral MoE
+    if cfg.qk_norm:  # olmoe: RMSNorm over the whole q / k projection
+        p["q_norm_w"] = jnp.ones((L, nh * hd), dt)
+        p["k_norm_w"] = jnp.ones((L, nkv * hd), dt)
+    if cfg.num_experts > 0:  # mixtral / olmoe MoE
         E = cfg.num_experts
         p["router"] = _dense_init(keys[4], (L, H, E), dt)
         p["w_gate"] = big(keys[5], (L, E, H, I), dt)
@@ -220,39 +226,91 @@ def _mlp(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     return out
 
 
+_EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+
+
+def _route(cfg: ModelConfig, lp: dict, h: jnp.ndarray):
+    """The router: ``(weights [T, k] float32, experts [T, k] int32)`` for
+    rows ``h`` [T, H].
+
+    Matmul, softmax and top-k all in float32 on float32-cast rows (the
+    router leaf is never quantized): where the k-th and (k+1)-th
+    probabilities nearly tie, a bf16 router picks another expert than the
+    model's, an error that does not shrink with the width of the rest.
+    The k largest of ``softmax(h Wr)`` over ALL experts; renormalised to
+    sum to 1 iff ``cfg.norm_topk_prob`` (mixtral: the same arithmetic as
+    its "top-k of the logits, then softmax over the k"), else kept as they
+    are (olmoe: they sum to less than 1)."""
+    with jax.named_scope("moe_route"):
+        logits = jnp.einsum("th,he->te", h.astype(jnp.float32),
+                            lp["router"].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, experts = jax.lax.top_k(probs, cfg.experts_per_token)
+        if cfg.norm_topk_prob:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, experts.astype(jnp.int32)
+
+
+def _moe_routed(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
+                tp_axis: Optional[str] = None):
+    """The routed expert layer, dropless: ``(y [b, s, H], rows [E] int32)``
+    with ``rows[e]`` the token-expert rows routed to expert ``e``.
+
+    ``[b, s, H]`` is flattened to ``T`` tokens and each token's ``k``
+    (token, expert) rows are sorted by expert: ``T k`` rows in ragged
+    groups, one grouped matmul a projection
+    (``ops.grouped_matmul``: a Pallas call on the chip that reads a
+    touched expert's int8 matrix once and never widens a stack in HBM,
+    ``ragged_dot`` elsewhere), silu(gate) x up, the down projection, each
+    row times its router weight, and a token's ``k`` rows summed in
+    float32.  Shapes are static and the group sizes are data, so an
+    expert may take every row or none: nothing is dropped, and every row
+    is routed, a padded slab row or an idle slot like any other
+    (``runtime.batching`` counts those apart).
+
+    Under ``tp_axis`` the expert stacks arrive E-sliced (expert
+    parallelism over ``tp``): this rank's groups are its local experts,
+    the other ranks' rows sort behind them into no group, and the
+    partial sums meet in the ``psum``."""
+    b, s, H = x.shape
+    T, k, E = b * s, cfg.experts_per_token, cfg.num_experts
+    xt = x.reshape(T, H)
+    weights, experts = _route(cfg, lp, xt)
+    with jax.named_scope("moe_experts"):
+        flat = experts.reshape(T * k)
+        rows = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+        sizes = rows
+        if tp_axis is not None:
+            e_local = lp["w_gate"].shape[0]  # quantized, LayerOf: .shape
+            e0 = jax.lax.axis_index(tp_axis) * e_local
+            mine = (flat >= e0) & (flat < e0 + e_local)
+            flat = jnp.where(mine, flat - e0, e_local)
+            sizes = jax.lax.dynamic_slice_in_dim(rows, e0, e_local)
+        order = jnp.argsort(flat, stable=True)
+        token = order // k
+        xs = xt[token]                                    # [T k, H]
+        gate = grouped_matmul(xs, lp["w_gate"], sizes)
+        up = grouped_matmul(xs, lp["w_up"], sizes)
+        hh = (jax.nn.silu(gate.astype(jnp.float32))
+              * up.astype(jnp.float32)).astype(x.dtype)
+        out = grouped_matmul(hh, lp["w_down"], sizes).astype(jnp.float32)
+        if tp_axis is not None:
+            # rows of other ranks' experts belong to no group here
+            out = jnp.where((jnp.arange(T * k) < jnp.sum(sizes))[:, None],
+                            out, 0.0)
+        # back to token order: row t*k + j is token t's j-th expert
+        out = out[jnp.argsort(order)].reshape(T, k, H)
+        y = jnp.einsum("tkh,tk->th", out, weights)
+        if tp_axis is not None:
+            y = jax.lax.psum(y, tp_axis)
+    return y.reshape(b, s, H).astype(x.dtype), rows
+
+
 def _moe_mlp(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
              tp_axis: Optional[str] = None) -> jnp.ndarray:
-    """Top-k routed MoE (mixtral).
-
-    Round-1 strategy: compute all experts batched on the MXU and combine with
-    the (sparse) routing weights.  For small decode batches this trades FLOPs
-    for zero gather/scatter overhead and static shapes; a capacity-based
-    dispatch kernel is the later optimization.  Expert parallelism shards the
-    leading E axis of w_gate/w_up/w_down over the "ep"/"tp" mesh axis.
-    """
-    E, k = cfg.num_experts, cfg.experts_per_token
-    logits = jnp.einsum("bsh,he->bse", x, lp["router"]).astype(jnp.float32)
-    topv, topi = jax.lax.top_k(logits, k)                      # [b,s,k]
-    weights = jax.nn.softmax(topv, axis=-1)                    # [b,s,k]
-    # dense routing matrix [b,s,E] with top-k softmax weights, zeros elsewhere
-    route = jnp.zeros_like(logits).at[
-        jnp.arange(x.shape[0])[:, None, None],
-        jnp.arange(x.shape[1])[None, :, None],
-        topi].set(weights)
-    if tp_axis is not None:
-        # expert parallelism: this rank holds E_local experts; select its
-        # slice of the routing matrix and psum partial outputs across ranks.
-        e_local = lp["w_gate"].shape[0]  # QuantizedArray exposes .shape
-        e0 = jax.lax.axis_index(tp_axis) * e_local
-        route = jax.lax.dynamic_slice_in_dim(route, e0, e_local, axis=-1)
-    gate = dense(x, lp["w_gate"], "bsh,ehi->besi")
-    up = dense(x, lp["w_up"], "bsh,ehi->besi")
-    h = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(x.dtype)
-    out = dense(h, lp["w_down"], "besi,eih->besh")        # [b,E,s,h]
-    out = jnp.einsum("besh,bse->bsh", out, route.astype(x.dtype))
-    if tp_axis is not None:
-        out = jax.lax.psum(out, tp_axis)
-    return out
+    """Top-k routed MoE (mixtral, olmoe): :func:`_moe_routed`'s output."""
+    return _moe_routed(cfg, lp, x, tp_axis)[0]
 
 
 def _default_attn(q, k, v, k_cache, v_cache, positions, cache_start, slopes):
@@ -295,9 +353,7 @@ def _moe_mlp_ep(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     assert e_loc * n == E, (e_loc, n, E)
     xt = x.reshape(T, H)
 
-    logits = dense(xt, lp["router"], "th,he->te").astype(jnp.float32)
-    topv, topi = jax.lax.top_k(logits, k)                  # [T, k]
-    weights = jax.nn.softmax(topv, axis=-1)                # [T, k]
+    weights, topi = _route(cfg, lp, xt)                    # [T, k]
 
     C = int(math.ceil(T * k / E * cfg.moe_capacity_factor))
     onehot = jax.nn.one_hot(topi, E, dtype=jnp.int32)      # [T, k, E]
@@ -330,15 +386,34 @@ def _moe_mlp_ep(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     return y.reshape(b, s, H).astype(x.dtype)
 
 
+def _whole_row_rms_norm(x: jnp.ndarray, w: jnp.ndarray, eps: float,
+                        tp_axis: Optional[str]) -> jnp.ndarray:
+    """RMSNorm over a whole q or k projection (olmoe's ``q_norm`` /
+    ``k_norm``: one mean square over all heads' channels, before the head
+    split and rope).  Under manual TP a rank holds a column slice of the
+    projection (and of ``w``), so the squares are summed over ``tp_axis``
+    and divided by the full width: the same mean square on every rank."""
+    if tp_axis is None:
+        return rms_norm(x, w, eps)
+    xf = x.astype(jnp.float32)
+    width = x.shape[-1] * jax.lax.axis_size(tp_axis)
+    ms = jax.lax.psum(jnp.sum(xf * xf, axis=-1, keepdims=True),
+                      tp_axis) / width
+    return (xf * jax.lax.rsqrt(ms + eps)
+            * w.astype(jnp.float32)).astype(x.dtype)
+
+
 def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
            k_cache: jnp.ndarray, v_cache: jnp.ndarray,
            positions: jnp.ndarray, cache_start: jnp.ndarray,
            slopes: Optional[jnp.ndarray],
            tp_axis: Optional[str] = None,
            attn_impl=None,
-           ep_axis: Optional[str] = None
-           ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """One decoder block. x: [b, s, H]. Returns (x', k_cache', v_cache').
+           ep_axis: Optional[str] = None,
+           moe_stats: bool = False):
+    """One decoder block. x: [b, s, H]. Returns (x', k_cache', v_cache'),
+    and with ``moe_stats`` a fourth value, the rows routed to each expert
+    in this layer call ([E] int32; ``_moe_routed``).
 
     Head counts derive from the weight shards, not the config, so the same
     code runs full-model (GSPMD) and per-TP-rank (manual shard_map) — under
@@ -361,6 +436,9 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     if cfg.attn_layernorm or cfg.attn_qkv_bias:
         # bq/bk/bv are column-sharded with their weights under TP
         q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+    if cfg.qk_norm:
+        q = _whole_row_rms_norm(q, lp["q_norm_w"], cfg.norm_eps, tp_axis)
+        k = _whole_row_rms_norm(k, lp["k_norm_w"], cfg.norm_eps, tp_axis)
     q = q.reshape(b, s, nh, hd)
     k = k.reshape(b, s, nkv, hd)
     v = v.reshape(b, s, nkv, hd)
@@ -384,6 +462,9 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
         h = layer_norm(x, lp["mlp_norm_w"], lp["mlp_norm_b"], cfg.norm_eps)
     else:
         h = rms_norm(x, lp["mlp_norm_w"], cfg.norm_eps)
+    if moe_stats:
+        y, rows = _moe_routed(cfg, lp, h, tp_axis)
+        return x + y, k_cache, v_cache, rows
     x = x + _mlp(cfg, lp, h, tp_axis, ep_axis)
     return x, k_cache, v_cache
 
@@ -400,8 +481,13 @@ def stage_forward(
     ep_axis: Optional[str] = None,  # expert-parallel MoE axis (shard_map)
     last_logits_only: bool = False,  # head over the final position only
     cache_in_carry: bool = True,  # in-place cache (inference) vs ys (train)
-) -> Tuple[jnp.ndarray, KVCache]:
+    moe_stats: bool = False,    # also return the experts' row counts
+):
     """Run this stage's layer range. Returns (hidden or logits, updated cache).
+
+    ``moe_stats`` (a model with experts, inference layout): a third value,
+    ``[layers, E]`` int32, the token-expert rows each layer call routed to
+    each expert (the scheduler's routing counters read it).
 
     ``last_logits_only`` narrows the LM-head matmul to the chunk's final
     position (shape [b, 1, V]) — prefill only samples from the last token,
@@ -444,30 +530,44 @@ def stage_forward(
         # The cache planes are pytrees, not bare arrays, when the pool
         # is quantized (ops.quant.QuantizedKVPages: narrow data + scale
         # leaves share the leading layer axis) — index/update per leaf.
+        # the expert stacks stay whole beside the scan: a layer of them
+        # sliced out for the grouped matmul's custom call would be a
+        # copy in HBM, so the layer goes in as (stack, index)
+        # (ops.grouped_matmul.LayerOf).  The capacity-slot EP path
+        # takes its slices as before
+        whole = (_EXPERT_STACKS if cfg.num_experts > 0 and ep_axis is None
+                 else ())
+        scanned_layers = {k: v for k, v in params.layers.items()
+                          if k not in whole}
+
         def body(carry, scanned):
             x, K, V = carry
             lp, li = scanned
+            lp = dict(lp, **{k: LayerOf(params.layers[k], li)
+                             for k in whole})
             kc = jax.tree.map(
                 lambda a: jax.lax.dynamic_index_in_dim(
                     a, li, 0, keepdims=False), K)
             vc = jax.tree.map(
                 lambda a: jax.lax.dynamic_index_in_dim(
                     a, li, 0, keepdims=False), V)
-            x, kc, vc = _layer(cfg, lp, x, kc, vc, positions, cache_start,
-                               slopes, tp_axis, attn_impl, ep_axis)
+            x, kc, vc, *rows = _layer(cfg, lp, x, kc, vc, positions,
+                                      cache_start, slopes, tp_axis,
+                                      attn_impl, ep_axis, moe_stats)
             K = jax.tree.map(
                 lambda a, c: jax.lax.dynamic_update_index_in_dim(
                     a, c, li, 0), K, kc)
             V = jax.tree.map(
                 lambda a, c: jax.lax.dynamic_update_index_in_dim(
                     a, c, li, 0), V, vc)
-            return (x, K, V), None
+            return (x, K, V), (rows[0] if rows else None)
 
         n_layers = jax.tree.leaves(cache.keys)[0].shape[0]
-        (x, new_k, new_v), _ = jax.lax.scan(
+        (x, new_k, new_v), expert_rows = jax.lax.scan(
             body, (x, cache.keys, cache.values),
-            (params.layers, jnp.arange(n_layers)))
+            (scanned_layers, jnp.arange(n_layers)))
     else:
+        assert not moe_stats, "moe_stats is for the inference layout"
         # Training layout: per-layer cache planes as xs/ys.  Under
         # differentiation a big carry would be saved per scan iteration by
         # the VJP; ys keeps residuals at one cache's worth.
@@ -498,4 +598,6 @@ def stage_forward(
             # head was replicated (e.g. tied embeddings) and logits are
             # already full-width.
             x = jax.lax.all_gather(x, tp_axis, axis=-1, tiled=True)
+    if moe_stats:
+        return x, new_cache, expert_rows
     return x, new_cache

@@ -156,15 +156,31 @@ def bloom_params_from_state_dict(raw: Dict[str, np.ndarray],
                        lm_head={})  # bloom ties the head to the embedding
 
 
-def mixtral_params_from_state_dict(raw: Dict[str, np.ndarray],
-                                   cfg: ModelConfig) -> StageParams:
-    """Map a MixtralForCausalLM state dict onto the stacked layout.
+# where a routed family keeps its router and its experts' three linears:
+# (block prefix, gate / up / down names).  mixtral: w1 -> w_gate,
+# w3 -> w_up, w2 -> w_down
+_MOE_NAMES = {
+    "mixtral": ("block_sparse_moe.", "w1", "w3", "w2"),
+    "olmoe": ("mlp.", "gate_proj", "up_proj", "down_proj"),
+}
 
-    Per-expert linears (``block_sparse_moe.experts.{e}.w1/w2/w3``) stack into
-    [L, E, in, out] blocks: w1 -> w_gate, w3 -> w_up, w2 -> w_down.
+
+def moe_params_from_state_dict(raw: Dict[str, np.ndarray],
+                               cfg: ModelConfig) -> StageParams:
+    """Map a MixtralForCausalLM or OlmoeForCausalLM state dict onto the
+    stacked layout.
+
+    Per-expert linears (``<block>experts.{e}.<name>.weight``) stack into
+    [L, E, in, out] blocks, the router is ``<block>gate.weight``; olmoe
+    adds ``self_attn.q_norm`` / ``k_norm`` (``cfg.qk_norm``).
     """
     dt = cfg.dtype
     E = cfg.num_experts
+    block, *names = _MOE_NAMES[cfg.family]
+    attn_map = dict(_ATTN_NORM_MAP)
+    if cfg.qk_norm:
+        attn_map.update({"self_attn.q_norm.weight": ("q_norm_w", False),
+                         "self_attn.k_norm.weight": ("k_norm_w", False)})
     layers: Dict[str, list] = {}
 
     def push(key, val):
@@ -172,19 +188,14 @@ def mixtral_params_from_state_dict(raw: Dict[str, np.ndarray],
 
     for i in range(cfg.num_layers):
         p = f"layers.{i}."
-        for hf_name, (ours, transpose) in _ATTN_NORM_MAP.items():
+        for hf_name, (ours, transpose) in attn_map.items():
             w = _get(raw, p + hf_name)
             push(ours, w.T if transpose else w)
-        push("router", _get(raw, p + "block_sparse_moe.gate.weight").T)
-        push("w_gate", np.stack([
-            _get(raw, p + f"block_sparse_moe.experts.{e}.w1.weight").T
-            for e in range(E)]))
-        push("w_up", np.stack([
-            _get(raw, p + f"block_sparse_moe.experts.{e}.w3.weight").T
-            for e in range(E)]))
-        push("w_down", np.stack([
-            _get(raw, p + f"block_sparse_moe.experts.{e}.w2.weight").T
-            for e in range(E)]))
+        push("router", _get(raw, p + block + "gate.weight").T)
+        for ours, name in zip(("w_gate", "w_up", "w_down"), names):
+            push(ours, np.stack([
+                _get(raw, p + f"{block}experts.{e}.{name}.weight").T
+                for e in range(E)]))
     stacked = {k: jnp.asarray(np.stack(v), dt) for k, v in layers.items()}
 
     embed = {"tokens": jnp.asarray(_get(raw, "embed_tokens.weight"), dt)}
@@ -220,7 +231,8 @@ _SD_MAPPERS = {
     "qwen2": llama_params_from_state_dict,   # same names + qkv biases
     "gemma": gemma_params_from_state_dict,
     "bloom": bloom_params_from_state_dict,
-    "mixtral": mixtral_params_from_state_dict,
+    "mixtral": moe_params_from_state_dict,
+    "olmoe": moe_params_from_state_dict,
 }
 
 

@@ -83,6 +83,15 @@ MODEL_REGISTRY = {
         family="llama", vocab_size=32000, hidden_size=1024, num_layers=8,
         num_heads=16, num_kv_heads=4, intermediate_size=7168,
         max_seq_len=2048, rope_theta=1000000.0),
+    # --- olmoe (allenai/OLMoE-1B-7B-0125-Instruct config.json): MHA,
+    # RMSNorm over the whole q and k projections, 64 experts of width
+    # 1024 with 8 a token, softmax over all 64 and no renormalising ---
+    "olmoe-1b-7b": ModelConfig(
+        family="olmoe", vocab_size=50304, hidden_size=2048, num_layers=16,
+        num_heads=16, num_kv_heads=16, intermediate_size=1024,
+        max_seq_len=4096, rope_theta=10000.0, norm_eps=1e-5,
+        num_experts=64, experts_per_token=8, qk_norm=True,
+        norm_topk_prob=False),
     # --- tiny configs for tests and virtual-mesh dry runs ---
     "llama-test": ModelConfig(
         family="llama", vocab_size=256, hidden_size=64, num_layers=4,
@@ -106,6 +115,11 @@ MODEL_REGISTRY = {
         family="mixtral", vocab_size=256, hidden_size=64, num_layers=2,
         num_heads=4, num_kv_heads=2, intermediate_size=128, max_seq_len=128,
         num_experts=4, experts_per_token=2, dtype_name="float32"),
+    "olmoe-test": ModelConfig(
+        family="olmoe", vocab_size=256, hidden_size=64, num_layers=2,
+        num_heads=4, num_kv_heads=4, intermediate_size=32, max_seq_len=128,
+        num_experts=8, experts_per_token=2, qk_norm=True,
+        norm_topk_prob=False, dtype_name="float32"),
 }
 
 

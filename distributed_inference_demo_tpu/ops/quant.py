@@ -45,17 +45,17 @@ class QuantizedArray:
         return (self.q.astype(jnp.float32) * self.scale).astype(dtype)
 
 
-def quantize_array(w: jax.Array, stacked: bool = False) -> QuantizedArray:
+def quantize_array(w: jax.Array) -> QuantizedArray:
     """Symmetric per-output-channel (last axis) int8 quantization.
 
-    With ``stacked=True`` the leading axis is the pipeline layer stack and
-    gets its own scales, so both leaves keep the layer axis (required for
-    lax.scan over layers and for stage slicing).
+    The absmax is taken over the INPUT axis (-2) alone, so every leading
+    axis keeps scales of its own: the layer stack (required for lax.scan
+    over layers and for stage slicing) and, in an expert stack
+    ``[L, E, in, out]``, each expert (scale ``[L, E, 1, out]``: the
+    grouped matmul applies the scale of a row's expert to its output).
     """
     wf = w.astype(jnp.float32)
-    reduce_from = 1 if stacked else 0
-    axes = tuple(range(reduce_from, w.ndim - 1))
-    absmax = jnp.max(jnp.abs(wf), axis=axes, keepdims=True)
+    absmax = jnp.max(jnp.abs(wf), axis=-2, keepdims=True)
     scale = jnp.maximum(absmax, 1e-8) / 127.0
     q = jnp.clip(jnp.round(wf / scale), -127, 127).astype(jnp.int8)
     return QuantizedArray(q=q, scale=scale)
@@ -148,8 +148,7 @@ _QUANTIZABLE = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 
 
 def quantize_layer_params(layers: dict, mode: str = "int8") -> dict:
-    quant = (quantize_array4 if mode == "int4"
-             else partial(quantize_array, stacked=True))
+    quant = quantize_array4 if mode == "int4" else quantize_array
     return {k: (quant(v)
                 if k in _QUANTIZABLE and not isinstance(v, AnyQuantized)
                 else v)

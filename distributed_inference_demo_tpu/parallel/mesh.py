@@ -15,7 +15,9 @@ header, ``Config.java:111-134``) with hand-rolled port arithmetic
   absent in the reference — SURVEY.md §5.7)
 
 Expert parallelism for MoE rides the ``tp`` axis (experts are sharded over
-the same chips that would otherwise shard heads).
+the same chips that would otherwise shard heads): every rank routes all
+tokens, runs the grouped matmul over its local experts' groups, and the
+partial sums meet in a ``psum``.
 
 Collectives ride ICI when the mesh maps to a physical slice; across hosts
 XLA routes them over DCN.  Axis order is chosen so the innermost (fastest)

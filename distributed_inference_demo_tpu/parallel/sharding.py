@@ -10,7 +10,9 @@ Megatron-style TP layout:
 - wo        [L, heads*hd, H]   -> shard first non-L axis over tp (row parallel)
 - w_gate/up [L, H, I]          -> column parallel
 - w_down    [L, I, H]          -> row parallel
-- MoE experts [L, E, H, I]     -> shard E over tp (expert parallelism)
+- MoE experts [L, E, H, I]     -> shard E over tp (expert parallelism: a
+                                  rank's grouped matmul runs its local
+                                  experts' groups, outputs psum)
 - embed [V, H] / lm_head [H, V]-> shard V over tp (vocab parallel); logits
                                   all-gather only at the sampling boundary
 - KV cache [Ls, B, S, nkv, hd] -> batch over dp, kv heads over tp, seq over sp
@@ -35,6 +37,10 @@ _LAYER_SPECS = {
     "bq": P(None, "tp"),
     "bk": P(None, "tp"),
     "bv": P(None, "tp"),
+    # olmoe's q/k RMSNorm weights span the projection's columns and are
+    # sliced with them (decoder._whole_row_rms_norm psums the squares)
+    "q_norm_w": P(None, "tp"),
+    "k_norm_w": P(None, "tp"),
     "wo": P(None, "tp", None),
     "bo": P(),
     "w_gate": P(None, None, "tp"),
@@ -93,19 +99,16 @@ def quant4_specs(v: QuantizedArray4, spec: P):
 
 
 def quant_scale_spec(q_spec: P) -> P:
-    """Scale spec matching ``quantize_array(stacked=True)`` layout.
+    """Scale spec matching ``quantize_array``'s layout.
 
-    The scale's shape is ``[L, 1, ..., out]`` — only the leading layer axis
-    and the final output axis are real, so only those can inherit the q
-    array's sharding (the collapsed middle axes are size 1 and must stay
-    unsharded; e.g. MoE experts shard q's E axis but the scale broadcasts
-    over it).
+    The scale has the q array's shape with the input axis (-2) collapsed
+    to 1, so it inherits every axis of the q array's sharding but that
+    one (a row-parallel ``wo`` shards its input axis and its scales stay
+    whole; an expert stack shards E and its scales shard with it).
     """
-    if len(q_spec) == 0:
-        return P()
-    if len(q_spec) == 1:
-        return P(q_spec[0])
-    return P(q_spec[0], *([None] * (len(q_spec) - 2)), q_spec[-1])
+    if len(q_spec) < 2:
+        return q_spec
+    return P(*q_spec[:-2], None, q_spec[-1])
 
 
 def stage_param_spec_tree(params: StageParams, cfg: ModelConfig, *,

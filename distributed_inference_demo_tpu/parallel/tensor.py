@@ -123,17 +123,23 @@ def make_paged_forward_seam(cfg: ModelConfig, spec: StageSpec, mesh,
     ``backend`` / ``interpret`` / ``record`` go to
     ``make_paged_attn_impl`` unchanged, on-mesh and off: the path every
     program took is in ``record``.  The one paged-dispatch rule shared
-    by the batching scheduler and the ring stage runtimes."""
+    by the batching scheduler and the ring stage runtimes.
+
+    ``fwd(..., moe_stats=True)`` (a model with experts) returns
+    ``stage_forward``'s third value too, the ``[layers, E]`` rows routed
+    to each expert, replicated under a mesh."""
     from ..ops.paged_attention import make_paged_attn_impl
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
     if tp <= 1:
         impl, bind = make_paged_attn_impl(block_tokens, backend,
                                           interpret, record)
 
-        def fwd(p, inputs, cache, positions, last_logits_only):
+        def fwd(p, inputs, cache, positions, last_logits_only,
+                moe_stats=False):
             return stage_forward(p, cfg, spec, inputs, cache, positions,
                                  attn_impl=impl,
-                                 last_logits_only=last_logits_only)
+                                 last_logits_only=last_logits_only,
+                                 moe_stats=moe_stats)
 
         return fwd, bind, None
     validate_tp(cfg, mesh)
@@ -144,7 +150,8 @@ def make_paged_forward_seam(cfg: ModelConfig, spec: StageSpec, mesh,
         bound["tables"] = tables
         bound["program"] = program
 
-    def fwd(p, inputs, cache, positions, last_logits_only):
+    def fwd(p, inputs, cache, positions, last_logits_only,
+            moe_stats=False):
         program = bound["program"]
 
         def body(p_, i_, c_, po_, tab_):
@@ -153,12 +160,13 @@ def make_paged_forward_seam(cfg: ModelConfig, spec: StageSpec, mesh,
             bind_local(tab_, program)
             return stage_forward(p_, cfg, spec, i_, c_, po_,
                                  tp_axis="tp", attn_impl=impl,
-                                 last_logits_only=last_logits_only)
+                                 last_logits_only=last_logits_only,
+                                 moe_stats=moe_stats)
 
         return jax.shard_map(
             body, mesh=mesh,
             in_specs=(p_specs, P(), _CACHE_SPEC, P(), P()),
-            out_specs=(P(), _CACHE_SPEC),
+            out_specs=(P(), _CACHE_SPEC) + ((P(),) if moe_stats else ()),
             check_vma=False)(p, inputs, cache, positions,
                              bound["tables"])
 

@@ -78,8 +78,11 @@ def model_cost_profile(cfg: ModelConfig, ctx: int = 1024) -> ModelCostProfile:
     qo = h * cfg.num_heads * hd
     attn_params = qo + 2 * h * kvh * hd + qo
     # mlp weights: 2 matrices for bloom's dense GELU MLP, 3 for every
-    # gated family (llama/qwen2/gemma SwiGLU-or-GeGLU, mixtral experts)
-    # — mirrors decoder._mlp's branch exactly
+    # gated family (llama/qwen2/gemma SwiGLU-or-GeGLU, mixtral / olmoe
+    # experts) — mirrors decoder._mlp's branch; an MoE layer adds its
+    # router (H x E, never quantized) and pays k experts a token, which is
+    # what the routed layer (decoder._moe_routed) computes.  What one
+    # decode step READS is more: every expert some row touched
     gated = cfg.family != "bloom"
     mlp_params_dense = (3 if gated else 2) * h * inter
     if cfg.num_experts > 0:
@@ -91,6 +94,8 @@ def model_cost_profile(cfg: ModelConfig, ctx: int = 1024) -> ModelCostProfile:
         mlp_params = mlp_params_dense
         mlp_flops = 2 * mlp_params_dense
     norm_params = 2 * h * (2 if cfg.attn_layernorm else 1)
+    if cfg.qk_norm:  # olmoe: RMSNorm weights over the q and k projections
+        norm_params += (cfg.num_heads + kvh) * hd
 
     # decode-step attention FLOPs: projections + scores/values over ctx
     attn_flops = 2 * attn_params + 2 * 2 * cfg.num_heads * hd * ctx

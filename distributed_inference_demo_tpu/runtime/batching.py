@@ -79,7 +79,8 @@ from ..telemetry import profiling as _profiling
 from ..telemetry.anomaly import AnomalyMonitor
 from ..telemetry.flightrecorder import get_flight_recorder
 from ..telemetry.slo import get_slo_ledger, sanitize_tenant
-from ..telemetry.tracing import (DispatchTrace, TraceRecorder,
+from ..telemetry.tracing import (MOE_DISPATCH_FIELDS, DispatchTrace,
+                                 MoeCounters, TraceRecorder,
                                  to_chrome_trace)
 from .engine import (GenerationResult, check_capacity,
                      make_paged_chunk_programs, validate_prefill_chunk)
@@ -517,6 +518,15 @@ class ContinuousBatchingEngine:
                 jax.nn.log_softmax(logits.astype(jnp.float32), -1),
                 tok[:, None].astype(jnp.int32), axis=-1)[:, 0]
 
+        def _sample_step(logits, lengths, last_tok, active, rng):
+            """``(lengths', tok, lp)`` of one lockstep step from its
+            logits [B, 1, V]: inactive rows keep their token."""
+            with jax.named_scope("sampling"):
+                tok = sample_logits(logits[:, 0], rng, samp_)
+                tok = jnp.where(active, tok, last_tok)
+                lp = _emitted_logprob(logits[:, 0], tok)
+            return lengths + active.astype(jnp.int32), tok, lp
+
         def paged_one_step(params, cache, lengths, last_tok, active,
                            rng):
             """One paged lockstep decode step over all slots — the
@@ -527,12 +537,8 @@ class ContinuousBatchingEngine:
             pos = lengths[:, None]
             logits, cache = fwd_p(params, last_tok[:, None], cache, pos,
                                   True)
-            with jax.named_scope("sampling"):
-                tok = sample_logits(logits[:, 0], rng, samp_)
-                tok = jnp.where(active, tok, last_tok)
-                lp = _emitted_logprob(logits[:, 0], tok)
-            lengths = lengths + active.astype(jnp.int32)
-            return cache, lengths, tok, lp
+            return (cache, *_sample_step(logits, lengths, last_tok,
+                                         active, rng))
 
         @partial(jax.jit, donate_argnums=(1, 2))
         def paged_step(params, pk, pv, tables, lengths, last_tok,
@@ -708,6 +714,37 @@ class ContinuousBatchingEngine:
                     jnp.stack(f_lps))
 
         if self.mixed_token_budget > 0 and not spec_mode:
+            # a model with experts returns one more small array, its
+            # routing counters ([E + 3] int32: rows to each expert
+            # summed over the execution's layer calls, then experts
+            # touched, the fullest expert's rows in one layer call,
+            # and the layer calls); a dense model's program is as it was
+            moe_kw = ({"moe_stats": True} if cfg_.num_experts > 0 else {})
+            E_ = cfg_.num_experts
+
+            def moe_acc0():
+                return jnp.zeros((E_ + 3,), jnp.int32)
+
+            def moe_fold(acc, rows):
+                """Fold one pass's ``[layers, E]`` row counts in."""
+                return jnp.concatenate([
+                    acc[:E_] + rows.sum(0),
+                    jnp.stack([acc[E_] + (rows > 0).sum(),
+                               jnp.maximum(acc[E_ + 1], rows.max()),
+                               acc[E_ + 2] + rows.shape[0]])
+                ]).astype(jnp.int32)
+
+            def paged_one_step_moe(params, carry, lengths, last_tok,
+                                   active, rng):
+                """``paged_one_step`` with the counters in the carry."""
+                cache, acc = carry
+                pos = lengths[:, None]
+                logits, cache, rows = fwd_p(
+                    params, last_tok[:, None], cache, pos, True,
+                    moe_stats=True)
+                return ((cache, moe_fold(acc, rows)),
+                        *_sample_step(logits, lengths, last_tok, active,
+                                      rng))
 
             @partial(jax.jit, donate_argnums=(1, 2),
                      static_argnums=(17, 18))
@@ -736,9 +773,11 @@ class ContinuousBatchingEngine:
                 # the op's event metadata.  The whole-pool relayout
                 # copies are the compiler's own and carry none
                 with jax.named_scope("slab_body"):
-                    logits, cache = slab_body(params, cache, seg_ids,
-                                              seg_tables, seg_starts,
-                                              "mixed_step")
+                    logits, cache, *moe = slab_body(
+                        params, cache, seg_ids, seg_tables, seg_starts,
+                        "mixed_step", **moe_kw)
+                if moe:
+                    moe_acc = moe_fold(moe_acc0(), moe[0])
                 if with_finals:
                     with jax.named_scope("slab_finals"):
                         final_toks, final_lps = slab_finals(
@@ -761,6 +800,17 @@ class ContinuousBatchingEngine:
                     done0 = None
                 bind_tables(dec_tables, "mixed_step")
                 with jax.named_scope("decode_loop"):
+                    if moe:
+                        # the counters ride the loop's carry beside the
+                        # cache, which _fused_loop never looks into
+                        ((cache, moe_acc), lengths, tok, toks, lps,
+                         steps) = _fused_loop(
+                            paged_one_step_moe, params, (cache, moe_acc),
+                            lengths, last_tok, active, dec_rng, eos,
+                            budget, num_steps, done0=done0)
+                        return (cache.keys, cache.values, lengths, tok,
+                                final_toks, final_lps, toks, lps, steps,
+                                moe_acc)
                     cache, lengths, tok, toks, lps, steps = _fused_loop(
                         paged_one_step, params, cache, lengths, last_tok,
                         active, dec_rng, eos, budget, num_steps,
@@ -1217,7 +1267,12 @@ class ContinuousBatchingEngine:
         self._completed = 0
         # one record per mixed dispatch + the host phases around it
         # (docs/DESIGN.md §20); scheduler thread writes, /stats reads
-        self.dispatch_trace = DispatchTrace()
+        # a model with experts also counts its routing (tracing.
+        # MoeCounters; mixed dispatches only: the path that is served)
+        moe = cfg.num_experts > 0 and self._mixed_step is not None
+        self.moe_counters = MoeCounters(cfg.num_experts) if moe else None
+        self.dispatch_trace = DispatchTrace(
+            MOE_DISPATCH_FIELDS if moe else ())
 
         # (mixed mode never dispatches the serialized step programs —
         # its two mixed_step variants compile on first use instead)
@@ -2069,6 +2124,8 @@ class ContinuousBatchingEngine:
                           / cs["mixed_budget_tokens"], 4)
                     if cs["mixed_budget_tokens"] else None)}
             out["dispatch_trace"] = self.dispatch_trace.snapshot()
+            if self.moe_counters is not None:
+                out["moe"] = self.moe_counters.snapshot()
         if self.disagg_stats["premigrated_requests"]:
             out["disagg"] = dict(self.disagg_stats)
         if self.resume_stats["requests"]:
@@ -2151,6 +2208,8 @@ class ContinuousBatchingEngine:
         self.spec_stats = {"rounds": 0, "drafted": 0, "accepted": 0}
         self._reset_chunk_stats()
         self.dispatch_trace.reset()
+        if self.moe_counters is not None:
+            self.moe_counters.reset()
         self._completed = 0
         for res in self._lat.values():
             res.clear()
@@ -3243,7 +3302,8 @@ class ContinuousBatchingEngine:
                 with jax.profiler.StepTraceAnnotation("mixed_step",
                                                       step_num=seq):
                     (self._pk, self._pv, self._lengths, tok, final_toks,
-                     final_lps, toks, lps, steps) = self._mixed_step(
+                     final_lps, toks, lps, steps, *moe_acc
+                     ) = self._mixed_step(
                         self.params, self._pk, self._pv,
                         jnp.asarray(seg_ids), jnp.asarray(seg_tables),
                         jnp.asarray(seg_starts), jnp.asarray(seg_lens),
@@ -3311,6 +3371,17 @@ class ContinuousBatchingEngine:
             finals=sum(1 for (_, _, f, _) in packed if f),
             prefill_tokens=prefill_tokens, active_rows=n_active,
             steps=steps, kv_tokens=kv_tokens)
+        if self.moe_counters is not None:
+            # real tokens: the live segments' prompt tokens, and the
+            # steps of the slots that decoded (rows that finish inside
+            # the block still step to its end); each is k rows a layer
+            acc = np.asarray(moe_acc[0])
+            E = self.cfg.num_experts
+            n_final = sum(1 for (_, _, f, _) in packed if f)
+            record.update(self.moe_counters.add(
+                acc[:E], int(acc[E]), int(acc[E + 1]), int(acc[E + 2]),
+                (prefill_tokens + (n_active + n_final) * steps)
+                * self.cfg.experts_per_token * self.cfg.num_layers))
         cs = self.chunk_stats
         cs["mixed_dispatches"] += 1
         cs["mixed_prefill_tokens"] += prefill_tokens

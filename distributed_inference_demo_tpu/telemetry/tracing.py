@@ -163,7 +163,56 @@ DISPATCH_FIELDS = (("seq", "t_launch", "t_done") + DISPATCH_PHASES
                    + ("with_finals", "segments", "finals",
                       "prefill_tokens", "active_rows", "steps",
                       "kv_tokens"))
+# what a model with experts adds to a record (runtime.batching): the
+# token-expert rows the execution routed over all its passes and layers,
+# those of real tokens (a live segment's prompt tokens, an active slot's
+# steps), the experts with >= 1 row summed over the execution's layer
+# calls, and the fullest expert's rows in any one layer call
+MOE_DISPATCH_FIELDS = ("moe_rows", "moe_valid_rows", "moe_touched",
+                       "moe_load_max")
 _DISPATCH_RING = 128       # x ~120 bytes a row: /stats stays under 16 KB
+
+
+class MoeCounters:
+    """Running sums of the routing counters of a model with experts: the
+    ``/stats.moe`` section.  Scheduler thread writes (one :meth:`add` a
+    mixed dispatch), ``/stats`` reads."""
+
+    def __init__(self, num_experts: int):
+        self.num_experts = num_experts
+        self.reset()
+
+    def reset(self) -> None:
+        self.dispatches = 0
+        self.rows = 0
+        self.valid_rows = 0
+        self.touched = 0
+        self.load_max = 0
+        self.layer_calls = 0
+        self.expert_rows = [0] * self.num_experts
+
+    def add(self, expert_rows, touched: int, load_max: int,
+            layer_calls: int, valid_rows: int) -> dict:
+        """Fold one execution in; returns its ``MOE_DISPATCH_FIELDS``."""
+        rows = int(sum(expert_rows))
+        self.expert_rows = [a + int(n) for a, n in zip(self.expert_rows,
+                                                       expert_rows)]
+        self.dispatches += 1
+        self.rows += rows
+        self.valid_rows += valid_rows
+        self.touched += touched
+        self.load_max = max(self.load_max, load_max)
+        self.layer_calls += layer_calls
+        return dict(moe_rows=rows, moe_valid_rows=valid_rows,
+                    moe_touched=touched, moe_load_max=load_max)
+
+    def snapshot(self) -> dict:
+        return {"experts": self.num_experts,
+                "dispatches": self.dispatches, "rows": self.rows,
+                "valid_rows": self.valid_rows, "touched": self.touched,
+                "load_max": self.load_max,
+                "layer_calls": self.layer_calls,
+                "expert_rows": list(self.expert_rows)}
 
 
 class DispatchTrace:
@@ -187,9 +236,13 @@ class DispatchTrace:
     carries its seconds into the next record.  The blocking wait of an
     idle engine is no phase (:meth:`idle`)."""
 
-    def __init__(self):
+    def __init__(self, extra_fields: tuple = ()):
+        """``extra_fields``: columns after :data:`DISPATCH_FIELDS`
+        (``MOE_DISPATCH_FIELDS`` for a model with experts), passed to
+        :meth:`commit` by name."""
         from jax.profiler import TraceAnnotation
         self._annotate = TraceAnnotation
+        self.extra_fields = tuple(extra_fields)
         self._names = {p: f"sched.{p}" for p in DISPATCH_PHASES}
         self._phase: Optional[str] = None
         self._t0 = 0.0
@@ -254,7 +307,8 @@ class DispatchTrace:
 
     def commit(self, *, t_launch: float, t_done: float, with_finals: bool,
                segments: int, finals: int, prefill_tokens: int,
-               active_rows: int, steps: int, kv_tokens: int) -> int:
+               active_rows: int, steps: int, kv_tokens: int,
+               **extra: int) -> int:
         """The dispatch in progress reached the device and is drained:
         one record.  Returns its ``seq``."""
         self.leave()
@@ -264,7 +318,8 @@ class DispatchTrace:
             self.seq, round(t_launch, 5), round(t_done, 5),
             *(round(carry[p], 5) for p in DISPATCH_PHASES),
             int(with_finals), segments, finals, prefill_tokens,
-            active_rows, steps, kv_tokens))
+            active_rows, steps, kv_tokens,
+            *(extra[f] for f in self.extra_fields)))
         for p in DISPATCH_PHASES:
             carry[p] = 0.0
         if segments:
@@ -288,7 +343,7 @@ class DispatchTrace:
                 "kv_token_steps": self.kv_token_steps,
                 "queue_wait_ms_sum": round(self.queue_wait_ms_sum, 3),
                 "queue_wait_count": self.queue_wait_count,
-                "fields": list(DISPATCH_FIELDS),
+                "fields": list(DISPATCH_FIELDS + self.extra_fields),
                 "recent": [list(r) for r in copy.copy(self.recent)]}
 
 

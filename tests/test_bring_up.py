@@ -447,3 +447,30 @@ def test_flash_kernel_with_alibi_gets_through_mosaic(v5e):
         S((2, 16, 1024, 64), jnp.bfloat16),
         S((2, 16, 1024, 64), jnp.bfloat16),
         S((), jnp.int32), S((), jnp.int32), S((16,), jnp.float32))
+
+
+@pytest.mark.parametrize("rows", [256, 4096])
+@pytest.mark.parametrize("quant", ["int8", "bf16"])
+def test_grouped_matmul_gets_through_mosaic(v5e, rows, quant):
+    """The experts' grouped matmul at ``olmoe-1b-7b-int8.chat``'s two
+    shapes (256 token-expert rows: a decode step at 32 slots; 4,096: the
+    512-token slab), both projections, the layer picked out of the whole
+    16-layer stack by index.  The compiler's temporaries stay under a
+    MiB: no copy of a 128 MiB expert stack (sliced out, or widened from
+    int8) is written to HBM."""
+    from distributed_inference_demo_tpu.ops.grouped_matmul import (
+        LayerOf, grouped_matmul)
+    from distributed_inference_demo_tpu.ops.quant import QuantizedArray
+    S = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=v5e)  # noqa: E731
+    L, E = 16, 64
+    for k, n in ((2048, 1024), (1024, 2048)):
+        stack = (QuantizedArray(q=S((L, E, k, n), jnp.int8),
+                                scale=S((L, E, 1, n), jnp.float32))
+                 if quant == "int8" else S((L, E, k, n), jnp.bfloat16))
+        compiled = jax.jit(
+            lambda x, w, g, i: grouped_matmul(x, LayerOf(w, i), g,
+                                              backend="pallas")
+        ).lower(S((rows, k), jnp.bfloat16), stack, S((E,), jnp.int32),
+                S((), jnp.int32)).compile()
+        assert "moe_gmm" in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20

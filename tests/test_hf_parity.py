@@ -86,6 +86,26 @@ def _hf_model(name):
             rms_norm_eps=cfg.norm_eps, rope_theta=cfg.rope_theta,
             tie_word_embeddings=cfg.tie_embeddings)
         model = transformers.MixtralForCausalLM(hf_cfg)
+    elif cfg.family == "olmoe":
+        hf_cfg = transformers.OlmoeConfig(
+            vocab_size=cfg.vocab_size, hidden_size=cfg.hidden_size,
+            num_hidden_layers=cfg.num_layers,
+            num_attention_heads=cfg.num_heads,
+            num_key_value_heads=cfg.num_kv_heads,
+            intermediate_size=cfg.intermediate_size,
+            num_experts=cfg.num_experts,
+            num_experts_per_tok=cfg.experts_per_token,
+            norm_topk_prob=cfg.norm_topk_prob,
+            max_position_embeddings=cfg.max_seq_len,
+            rms_norm_eps=cfg.norm_eps, rope_theta=cfg.rope_theta,
+            tie_word_embeddings=cfg.tie_embeddings)
+        model = transformers.OlmoeForCausalLM(hf_cfg)
+        # HF initialises every norm weight to 1; the q/k norms must not
+        # pass by being the identity scale
+        with torch.no_grad():
+            for name, p in model.named_parameters():
+                if name.endswith(("q_norm.weight", "k_norm.weight")):
+                    p.copy_(1.0 + 0.3 * torch.randn_like(p))
     else:
         raise AssertionError(cfg.family)
     model = model.float().eval()
@@ -106,7 +126,7 @@ def _hf_logits(model, ids):
 PROMPT = np.array([[5, 17, 42, 7, 99, 3, 12, 56, 200, 131]], dtype=np.int32)
 
 FAMILIES = ["llama-test", "qwen2-test", "gemma-test", "bloom-test",
-            "mixtral-test"]
+            "mixtral-test", "olmoe-test"]
 
 
 @pytest.mark.parametrize("name", FAMILIES)

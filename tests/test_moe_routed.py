@@ -1,0 +1,393 @@
+"""The routed expert layer (``decoder._route`` / ``_moe_routed`` over
+``ops.grouped_matmul``) against its definition, olmoe through the mixed
+engine, and the routing counters.
+
+The definition of a routed layer is "every expert for every token, weights
+zero off the chosen k": written here by hand, with dequantized matrices,
+no sort and no grouping.  The routed layer must equal it whatever the
+routing does: spread evenly, pile every token onto the same experts, or
+leave experts empty.  Nothing is dropped.
+"""
+
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from distributed_inference_demo_tpu.models import (
+    KVCache, StageSpec, get_model_config)
+from distributed_inference_demo_tpu.models.decoder import (
+    _moe_mlp, _moe_mlp_ep, _moe_routed, _route, init_full_params,
+    stage_forward)
+from distributed_inference_demo_tpu.ops.grouped_matmul import (
+    LayerOf, grouped_matmul, route_grouped_matmul, tiling)
+from distributed_inference_demo_tpu.ops.quant import (
+    QuantizedArray, QuantizedArray4, quantize_array, quantize_array4)
+from distributed_inference_demo_tpu.ops.sampling import SamplingParams
+from distributed_inference_demo_tpu.parallel import MeshConfig, make_mesh
+from distributed_inference_demo_tpu.parallel.tensor import make_tp_stage_fn
+from distributed_inference_demo_tpu.runtime.batching import (
+    ContinuousBatchingEngine)
+from distributed_inference_demo_tpu.telemetry.tracing import (
+    DISPATCH_FIELDS, MOE_DISPATCH_FIELDS, DispatchTrace, MoeCounters)
+
+OLMOE = get_model_config("olmoe-test")          # 8 experts, 2 a token
+MIXTRAL = get_model_config("mixtral-test")      # 4 experts, 2 a token
+
+
+def _layer(rng, cfg, routing="uniform", dtype=jnp.float32):
+    """One layer's router and expert matrices, float32.  ``routing``
+    "one": every token's logits order the experts the same way, so all
+    tokens land on the same k experts and the rest stay empty (with
+    positive rows, below)."""
+    E, H, I = cfg.num_experts, cfg.hidden_size, cfg.intermediate_size
+    ks = jax.random.split(rng, 4)
+    router = jax.random.normal(ks[0], (H, E), dtype) * H ** -0.5
+    if routing == "one":
+        router = jnp.ones((H, 1), dtype) * jnp.linspace(-2.0, 2.0, E) / H
+    return {"router": router,
+            "w_gate": jax.random.normal(ks[1], (E, H, I), dtype) * H ** -0.5,
+            "w_up": jax.random.normal(ks[2], (E, H, I), dtype) * H ** -0.5,
+            "w_down": jax.random.normal(ks[3], (E, I, H), dtype) * I ** -0.5}
+
+
+def _f32(w):
+    return (w.dequantize(jnp.float32)
+            if isinstance(w, (QuantizedArray, QuantizedArray4)) else w)
+
+
+def _definition(cfg, lp, x):
+    """Every expert for every token, weights zero off the top k."""
+    T, E, k = x.shape[0], cfg.num_experts, cfg.experts_per_token
+    probs = np.asarray(jax.nn.softmax(
+        np.asarray(x, np.float64) @ np.asarray(lp["router"], np.float64)))
+    order = np.argsort(-probs, axis=-1)[:, :k]
+    w = np.zeros((T, E))
+    for t in range(T):
+        w[t, order[t]] = probs[t, order[t]]
+    if cfg.norm_topk_prob:
+        w /= w.sum(-1, keepdims=True)
+    g, u, d = (np.asarray(_f32(lp[n]), np.float64)
+               for n in ("w_gate", "w_up", "w_down"))
+    xs = np.asarray(x, np.float64)
+    y = np.zeros_like(xs)
+    for e in range(E):
+        a = xs @ g[e]
+        y += w[:, e:e + 1] * ((a / (1 + np.exp(-a)) * (xs @ u[e])) @ d[e])
+    return y, (w > 0).sum(0)
+
+
+# ------------------------------------------------------------- the router
+
+@pytest.mark.parametrize("cfg", [OLMOE, MIXTRAL], ids=["olmoe", "mixtral"])
+def test_route_by_hand(cfg):
+    """olmoe keeps the k largest of softmax over ALL experts as they are
+    (they sum to less than 1); mixtral's renormalised k equal its own
+    "top-k of the logits, then softmax over the k" to 1e-6."""
+    lp = _layer(jax.random.PRNGKey(0), cfg)
+    h = jax.random.normal(jax.random.PRNGKey(1), (12, cfg.hidden_size))
+    w, e = _route(cfg, lp, h)
+    assert w.dtype == jnp.float32 and e.dtype == jnp.int32
+    assert w.shape == e.shape == (12, cfg.experts_per_token)
+    logits = np.asarray(h, np.float64) @ np.asarray(lp["router"], np.float64)
+    probs = np.exp(logits) / np.exp(logits).sum(-1, keepdims=True)
+    top = np.argsort(-logits, -1)[:, :cfg.experts_per_token]
+    assert (np.asarray(e) == top).all()
+    if cfg.norm_topk_prob:
+        tl = np.take_along_axis(logits, top, -1)
+        want = np.exp(tl) / np.exp(tl).sum(-1, keepdims=True)
+        np.testing.assert_allclose(np.asarray(w).sum(-1), 1.0, atol=1e-6)
+    else:
+        want = np.take_along_axis(probs, top, -1)
+        assert (np.asarray(w).sum(-1) < 1.0 - 1e-3).all()
+    np.testing.assert_allclose(np.asarray(w), want, atol=1e-6)
+
+
+def test_route_stays_float32_on_bf16_rows():
+    """bf16 activations and a bf16 router leaf: the matmul, the softmax
+    and the top-k still run in float32 on the float32-cast rows."""
+    lp = _layer(jax.random.PRNGKey(0), OLMOE, dtype=jnp.bfloat16)
+    h = jax.random.normal(jax.random.PRNGKey(1), (9, OLMOE.hidden_size),
+                          jnp.bfloat16)
+    w, e = _route(OLMOE, lp, h)
+    logits = (np.asarray(h, np.float32).astype(np.float64)
+              @ np.asarray(lp["router"], np.float32).astype(np.float64))
+    probs = np.exp(logits) / np.exp(logits).sum(-1, keepdims=True)
+    np.testing.assert_allclose(
+        np.asarray(w), np.take_along_axis(probs, np.asarray(e), -1),
+        atol=1e-6)
+
+
+# ------------------------------------------------------- the routed layer
+
+@pytest.mark.parametrize("quant", ["float32", "int8", "int4"])
+@pytest.mark.parametrize("routing", ["uniform", "one", "empty"])
+@pytest.mark.parametrize("cfg", [OLMOE, MIXTRAL], ids=["olmoe", "mixtral"])
+def test_routed_layer_equals_its_definition(cfg, routing, quant):
+    """Uniform routing; every token on the same k experts (a group as
+    long as all the rows, E - k empty groups); three tokens (most experts
+    empty).  float32 to 2e-5; the quantized stacks against the definition
+    on their own dequantized matrices (the scale multiplies the output
+    instead of the matrix: float32 rounding), 1e-4."""
+    lp = _layer(jax.random.PRNGKey(2), cfg,
+                "one" if routing == "one" else "uniform")
+    if quant != "float32":
+        q = quantize_array if quant == "int8" else quantize_array4
+        lp = dict(lp, **{n: q(lp[n]) for n in ("w_gate", "w_up", "w_down")})
+    T = 3 if routing == "empty" else 24
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(3),
+                                  (T, cfg.hidden_size)))
+    want, want_rows = _definition(cfg, lp, x)
+    got, rows = _moe_routed(cfg, lp, x.reshape(2 if T == 24 else 1, -1,
+                                              cfg.hidden_size))
+    assert (np.asarray(rows) == want_rows).all()
+    assert int(rows.sum()) == T * cfg.experts_per_token      # none dropped
+    if routing == "one":
+        assert sorted(np.asarray(rows))[-cfg.experts_per_token:] \
+            == [T] * cfg.experts_per_token
+        assert int((np.asarray(rows) == 0).sum()) \
+            == cfg.num_experts - cfg.experts_per_token
+    if routing == "empty":
+        assert int((np.asarray(rows) == 0).sum()) >= 1
+    np.testing.assert_allclose(
+        np.asarray(got).reshape(T, -1), want,
+        atol=2e-5 if quant == "float32" else 1e-4)
+    assert (np.asarray(_moe_mlp(cfg, lp, x[None])) == np.asarray(
+        got).reshape(1, T, -1)).all()
+
+
+def test_int8_scales_are_an_expert_s_own():
+    w = jax.random.normal(jax.random.PRNGKey(0), (3, 4, 16, 8)) \
+        * jnp.arange(1, 5)[None, :, None, None]
+    q = quantize_array(w)
+    assert q.scale.shape == (3, 4, 1, 8)
+    np.testing.assert_allclose(np.asarray(q.scale),
+                               np.abs(np.asarray(w)).max(-2, keepdims=True)
+                               / 127.0, rtol=1e-6)
+
+
+@pytest.mark.parametrize("quant", ["float32", "int8"])
+def test_kernel_form_equals_the_xla_form(quant):
+    """The Pallas form (interpreted) against ``ragged_dot`` on the same
+    sorted rows: ragged groups, empty groups, rows that are no multiple
+    of the tile, a layer picked out of a stack by index."""
+    rs = np.random.RandomState(0)
+    L, E, K, N = 3, 8, 256, 128
+    w = jnp.asarray(rs.randn(L, E, K, N), jnp.float32) * K ** -0.5
+    rhs = quantize_array(w) if quant == "int8" else w
+    for m, sizes in ((64, [8] * 8), (64, [64] + [0] * 7),
+                     (64, [0, 3, 0, 40, 0, 0, 21, 0]), (40, [5] * 8),
+                     (64, [0, 3, 0, 30, 0, 0, 21, 0])):
+        x = jnp.asarray(rs.randn(m, K), jnp.float32)
+        gs = jnp.asarray(sizes, jnp.int32)
+        n = int(sum(sizes))
+        for layer in (0, 2):
+            one = jax.tree.map(lambda a: a[layer], rhs)
+            want = grouped_matmul(x, one, gs, backend="xla")
+            got = grouped_matmul(x, LayerOf(rhs, jnp.int32(layer)), gs,
+                                 backend="pallas", interpret=True)
+            np.testing.assert_allclose(np.asarray(got)[:n],
+                                       np.asarray(want)[:n], atol=1e-4)
+            got1 = grouped_matmul(x, one, gs, backend="pallas",
+                                  interpret=True)
+            np.testing.assert_allclose(np.asarray(got1)[:n],
+                                       np.asarray(want)[:n], atol=1e-4)
+
+
+def test_grouped_matmul_routing_and_tiles():
+    assert route_grouped_matmul("tpu", 2048, 1024) == "pallas_gmm"
+    assert route_grouped_matmul("cpu", 2048, 1024) == "ragged_dot"
+    assert route_grouped_matmul("tpu", 64, 32) == "ragged_dot"
+    # the cell's two shapes: a whole int8 expert matrix is one tile
+    assert tiling(256, 2048, 1024, 1) == (32, 2048, 1024)
+    assert tiling(4096, 1024, 2048, 1) == (64, 1024, 1024)
+    assert tiling(8, 14336, 4096, 2)[1:] == (1024, 1024)
+
+
+# ----------------------------------------------------------- whole models
+
+def _full_spec(cfg):
+    return StageSpec(0, 1, 0, cfg.num_layers)
+
+
+@pytest.mark.parametrize("name", ["olmoe-test", "mixtral-test"])
+def test_routed_moe_under_manual_tp(name, devices):
+    """tp=2: experts sharded over tp (a rank's groups are its local
+    experts, the partial sums meet in the psum), and olmoe's q/k RMSNorm
+    takes its mean square over the whole projection (psum of squares),
+    with norm weights that are not the identity."""
+    cfg = get_model_config(name)
+    params = init_full_params(jax.random.PRNGKey(0), cfg)
+    if cfg.qk_norm:
+        layers = dict(params.layers)
+        for i, key in enumerate(("q_norm_w", "k_norm_w")):
+            layers[key] = 1.0 + 0.3 * jax.random.normal(
+                jax.random.PRNGKey(5 + i), layers[key].shape)
+        params.layers = layers
+    spec = _full_spec(cfg)
+    ids = jnp.arange(10, dtype=jnp.int32).reshape(1, 10) % cfg.vocab_size
+    pos = jnp.arange(10)[None, :]
+    ref, _ = stage_forward(params, cfg, spec, ids,
+                           KVCache.create(cfg, cfg.num_layers, 1, 32), pos)
+    mesh = make_mesh(MeshConfig(tp=2), devices)
+    with mesh:
+        fn = make_tp_stage_fn(cfg, spec, mesh, params)
+        out, _ = fn(params, ids, KVCache.create(cfg, cfg.num_layers, 1, 32),
+                    pos)
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
+                               rtol=2e-4, atol=2e-4)
+
+
+def test_ep_path_routes_like_the_routed_layer(devices):
+    """``--ep`` with a ``norm_topk_prob: false`` model: the capacity-slot
+    path calls the same ``_route``; with capacity to spare it equals the
+    routed layer."""
+    from jax.sharding import PartitionSpec as P
+    cfg = OLMOE.replace(moe_capacity_factor=8.0)
+    lp = _layer(jax.random.PRNGKey(0), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 10, cfg.hidden_size))
+    mesh = make_mesh(MeshConfig(ep=2), devices)
+    specs = {"router": P(), "w_gate": P("ep", None, None),
+             "w_up": P("ep", None, None), "w_down": P("ep", None, None)}
+    with mesh:
+        ep = jax.shard_map(
+            lambda lp_, x_: _moe_mlp_ep(cfg, lp_, x_, "ep"), mesh=mesh,
+            in_specs=(specs, P("ep")), out_specs=P("ep"),
+            check_vma=False)(lp, x)
+    np.testing.assert_allclose(np.asarray(ep), np.asarray(
+        _moe_mlp(cfg, lp, x)), rtol=2e-4, atol=2e-4)
+
+
+def _engine(cfg, params, **kw):
+    return ContinuousBatchingEngine(
+        cfg, params, max_seq=128, max_batch=4,
+        sampling=SamplingParams(temperature=0.0), prefill_chunk=16,
+        decode_block=4, mixed_token_budget=48, kv_cache_blocks=64,
+        kv_block_tokens=8, **kw)
+
+
+@pytest.fixture(scope="module")
+def olmoe_run():
+    """olmoe-test (int8 experts) through the mixed path: three prompts
+    (one longer than two chunks), ten tokens each, with logprobs."""
+    cfg = get_model_config("olmoe-test-int8")
+    params = init_full_params(jax.random.PRNGKey(0), cfg, quantize=True)
+    rs = np.random.RandomState(1)
+    prompts = [rs.randint(1, cfg.vocab_size, size=n).astype(np.int32)
+               for n in (37, 9, 20)]
+    with _engine(cfg, params) as eng:
+        reqs = [eng.submit(p, 10) for p in prompts]
+        outs = [np.asarray(r.wait(timeout=300)) for r in reqs]
+        lps = [list(r.lps) for r in reqs]
+        # the last dispatch delivers its tokens before its record is
+        # committed: read /stats once the two counts agree
+        for _ in range(200):
+            stats = eng.stats()
+            if stats["dispatch_trace"]["seq"] == stats["moe"]["dispatches"]:
+                break
+            time.sleep(0.02)
+    return cfg, params, prompts, outs, lps, stats
+
+
+def test_olmoe_mixed_path_equals_stage_forward(olmoe_run):
+    """Prefill in chunks, then decode through pages: the tokens and their
+    log-probabilities equal ``stage_forward`` over the whole sequence."""
+    cfg, params, prompts, outs, lps, _ = olmoe_run
+    for p, o, lp in zip(prompts, outs, lps):
+        ids = np.concatenate([p, o])[None]
+        logits, _ = stage_forward(
+            params, cfg, _full_spec(cfg), jnp.asarray(ids),
+            KVCache.create(cfg, cfg.num_layers, 1, 128),
+            jnp.arange(ids.shape[1])[None])
+        ref = jax.nn.log_softmax(logits[0].astype(jnp.float32), -1)
+        rows = ref[len(p) - 1: ids.shape[1] - 1]
+        assert [int(t) for t in o] == [int(t) for t in rows.argmax(-1)]
+        np.testing.assert_allclose(
+            lp, [float(rows[i, t]) for i, t in enumerate(o)], atol=1e-4)
+
+
+def test_moe_counters_sum_to_tokens_times_k_times_layers(olmoe_run):
+    """Every pass routes every row it carries: per execution ``moe_rows``
+    = (slab rows + slots x steps) x k x layers; over the run the experts'
+    rows sum to it, the valid rows to the real tokens', and the dispatch
+    records carry the same numbers."""
+    cfg, _, prompts, outs, _, st = olmoe_run
+    k, L, E = cfg.experts_per_token, cfg.num_layers, cfg.num_experts
+    moe, dt = st["moe"], st["dispatch_trace"]
+    assert dt["fields"] == list(DISPATCH_FIELDS + MOE_DISPATCH_FIELDS)
+    recs = [dict(zip(dt["fields"], r)) for r in dt["recent"]]
+    assert len(recs) == moe["dispatches"] == dt["seq"]
+    slab, slots = 48, 4
+    for r in recs:
+        assert r["moe_rows"] == (slab + slots * r["steps"]) * k * L
+        assert r["moe_valid_rows"] == (
+            r["prefill_tokens"] + (r["active_rows"] + r["finals"])
+            * r["steps"]) * k * L
+        assert 0 < r["moe_touched"] <= (1 + r["steps"]) * L * E
+        assert r["moe_load_max"] <= slab * k
+    assert moe["rows"] == sum(r["moe_rows"] for r in recs) \
+        == sum(moe["expert_rows"])
+    assert moe["layer_calls"] == sum((1 + r["steps"]) * L for r in recs)
+    assert moe["touched"] == sum(r["moe_touched"] for r in recs)
+    assert moe["load_max"] == max(r["moe_load_max"] for r in recs)
+    assert len(moe["expert_rows"]) == moe["experts"] == E
+    # prompt tokens are routed once each; a token decoded is routed in
+    # the step that reads it (the last of a request never is) and rows
+    # that finish inside a block still step to its end
+    prompt_rows = sum(len(p) for p in prompts) * k * L
+    assert prompt_rows == sum(r["prefill_tokens"] for r in recs) * k * L
+    assert moe["valid_rows"] >= prompt_rows + sum(
+        len(o) - 1 for o in outs) * k * L
+
+
+def test_dense_engine_has_no_moe_section_and_the_record_is_unchanged():
+    cfg = get_model_config("llama-test")
+    params = init_full_params(jax.random.PRNGKey(0), cfg)
+    with _engine(cfg, params) as eng:
+        eng.submit(np.arange(1, 20, dtype=np.int32), 4).wait(timeout=300)
+        st = eng.stats()
+    assert "moe" not in st
+    assert st["dispatch_trace"]["fields"] == list(DISPATCH_FIELDS)
+
+
+def test_dispatch_trace_extra_fields_and_counters():
+    tr = DispatchTrace(MOE_DISPATCH_FIELDS)
+    c = MoeCounters(4)
+    tr.enter("pack")
+    extra = c.add([3, 0, 5, 0], touched=2, load_max=5, layer_calls=1,
+                  valid_rows=6)
+    assert extra == dict(moe_rows=8, moe_valid_rows=6, moe_touched=2,
+                         moe_load_max=5)
+    tr.commit(t_launch=1.0, t_done=2.0, with_finals=False, segments=0,
+              finals=0, prefill_tokens=0, active_rows=1, steps=1,
+              kv_tokens=3, **extra)
+    snap = tr.snapshot()
+    assert snap["fields"][-4:] == list(MOE_DISPATCH_FIELDS)
+    assert snap["recent"][0][-4:] == [8, 6, 2, 5]
+    c.add([1, 1, 0, 0], touched=2, load_max=1, layer_calls=1, valid_rows=2)
+    assert c.snapshot() == {
+        "experts": 4, "dispatches": 2, "rows": 10, "valid_rows": 8,
+        "touched": 4, "load_max": 5, "layer_calls": 2,
+        "expert_rows": [4, 1, 5, 0]}
+    c.reset()
+    assert c.snapshot()["rows"] == 0
+
+
+def test_model_parity_tool_at_toy_size():
+    """``tools/model_parity.py`` walks its whole path on the CPU (paged
+    prefill in chunks, decode through pages, the benchmark's reference,
+    router margins): float32 toy weights pass, int8 KV pages are refused."""
+    import importlib.util
+    from pathlib import Path
+    spec = importlib.util.spec_from_file_location(
+        "model_parity", Path(__file__).resolve().parent.parent / "tools"
+        / "model_parity.py")
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    toy = ["--model", "olmoe-test-int8", "--prompt", "48", "--chunk", "16",
+           "--steps", "8", "--page", "8", "--batch", "2"]
+    assert tool.main(toy) == 0
+    assert tool.main(toy + ["--kv-dtype", "int8"]) == 1

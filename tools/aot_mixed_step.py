@@ -1,0 +1,126 @@
+#!/usr/bin/env python3
+"""Compile a model's ``mixed_step`` for a TPU v5e that is not there, and
+say what the compiler reports: device memory (arguments, temporaries),
+the attention path each chunk shape took, and the Pallas custom calls.
+
+    python tools/aot_mixed_step.py --model olmoe-1b-7b-int8 \\
+        --batch-slots 32 --prefill-chunk 256 --decode-block 4 \\
+        --mixed-token-budget 640 --max-seq 4096 --kv-block-tokens 128 \\
+        --kv-cache-blocks 192 208 224
+
+libtpu compiles for a described topology (``v5e:2x2``, one of its
+devices) without a chip; parameters and the page pool are shapes only, so
+nothing model-sized is allocated.  It proves compilation and sizes a pool
+before any chip call (PERF.md section 4); times need the chip.  One
+compile a ``--kv-cache-blocks`` value; a refusal (out of memory) is
+printed, not raised.
+"""
+
+from __future__ import annotations
+
+import argparse
+import re
+import sys
+from pathlib import Path
+from unittest import mock
+
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+
+GIB = float(1 << 30)
+
+
+def compile_mixed_step(model: str, blocks: int, args, with_finals=True):
+    """``(compiled, engine)`` of ``mixed_step`` at ``blocks`` pool pages."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+
+    from distributed_inference_demo_tpu.models import get_model_config
+    from distributed_inference_demo_tpu.models.decoder import (
+        init_full_params)
+    from distributed_inference_demo_tpu.ops import quant
+    from distributed_inference_demo_tpu.ops.sampling import SamplingParams
+    from distributed_inference_demo_tpu.runtime.batching import (
+        ContinuousBatchingEngine)
+
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                        platform="tpu")
+    sharding = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    cfg = get_model_config(model)
+    params = jax.eval_shape(
+        lambda: init_full_params(jax.random.PRNGKey(0), cfg, quantize=True))
+    pool = quant.alloc_kv_pool
+
+    def abstract_pool(*a, **k):
+        return jax.eval_shape(lambda: pool(*a, **k))
+
+    with mock.patch.object(quant, "alloc_kv_pool", abstract_pool):
+        eng = ContinuousBatchingEngine(
+            cfg, params, max_seq=args.max_seq, max_batch=args.batch_slots,
+            sampling=SamplingParams(temperature=0.0),
+            prefill_chunk=args.prefill_chunk,
+            decode_block=args.decode_block,
+            mixed_token_budget=args.mixed_token_budget,
+            kv_cache_blocks=blocks, kv_block_tokens=args.kv_block_tokens)
+    try:
+        B, C, W = args.batch_slots, args.prefill_chunk, eng._table_width
+        n_seg = eng._mixed_seg_cap
+
+        def S(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+        def on_chip(tree):
+            return jax.tree.map(lambda x: S(x.shape, x.dtype), tree)
+
+        i32 = jnp.int32
+        call = (on_chip(params), on_chip(eng._pk), on_chip(eng._pv),
+                S((n_seg, C), i32), S((n_seg, W), i32), S((n_seg,), i32),
+                S((n_seg,), i32), S((n_seg,), i32), S((n_seg,), i32),
+                S((n_seg, 2), jnp.uint32), S((B, W), i32), S((B,), i32),
+                S((B,), i32), S((B,), jnp.bool_), S((2,), jnp.uint32),
+                S((), i32), S((B,), i32), args.decode_block, with_finals)
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+            compiled = eng._mixed_step.inner.lower(*call).compile()
+        return compiled, eng
+    finally:
+        eng.close()
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--model", required=True)
+    ap.add_argument("--batch-slots", type=int, default=32)
+    ap.add_argument("--prefill-chunk", type=int, default=256)
+    ap.add_argument("--decode-block", type=int, default=4)
+    ap.add_argument("--mixed-token-budget", type=int, default=640)
+    ap.add_argument("--max-seq", type=int, default=4096)
+    ap.add_argument("--kv-block-tokens", type=int, default=128)
+    ap.add_argument("--kv-cache-blocks", type=int, nargs="+", required=True)
+    args = ap.parse_args(argv)
+    for blocks in args.kv_cache_blocks:
+        try:
+            compiled, eng = compile_mixed_step(args.model, blocks, args)
+        except Exception as e:              # the compiler's refusal
+            msg = " ".join(str(e).split())
+            print(f"blocks={blocks}: REFUSED {type(e).__name__}: "
+                  f"{msg[:600]}", flush=True)
+            continue
+        ma = compiled.memory_analysis()
+        calls = sorted(set(re.findall(
+            r"%([\w.-]+) = [^\n]*custom-call\([^\n]*tpu_custom_call",
+            compiled.as_text())))
+        total = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+                 + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+        print(f"blocks={blocks}: arguments "
+              f"{ma.argument_size_in_bytes / GIB:.2f} GiB, temporaries "
+              f"{ma.temp_size_in_bytes / GIB:.2f} GiB, outputs "
+              f"{ma.output_size_in_bytes / GIB:.2f} GiB, aliased "
+              f"{ma.alias_size_in_bytes / GIB:.2f} GiB: "
+              f"{total / GIB:.2f} GiB; paths "
+              f"{eng.attn_paths.snapshot()}; pallas calls {calls}",
+              flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

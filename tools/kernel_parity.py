@@ -1,6 +1,7 @@
 #!/usr/bin/env python3
-"""Compile every Pallas attention-kernel specialisation on this device and
-compare it with the XLA path it replaces, at the shapes the server runs.
+"""Compile every Pallas kernel specialisation on this device (the attention
+kernels and the experts' grouped matmul) and compare it with the XLA path
+it replaces, at the shapes the server runs.
 
     python tools/kernel_parity.py                 # the chip (chip_smoke's
                                                   # third phase)
@@ -19,6 +20,13 @@ which also quantizes), the reference is ``paged_gather_attention`` /
 ("highest")`` on the SAME device — on a TPU a float32 einsum is otherwise
 a one-pass bf16 product, and a reference must not share the error it is
 there to bound.
+
+The grouped matmul (``ops.grouped_matmul``) runs at the expert layer's two
+shapes in ``olmoe-1b-7b`` (256 token-expert rows: a decode step at 32
+slots; 4,096: the 512-token slab), both projections' shapes, int8 and
+bf16 stacks, with uniform, Zipf and one-expert-takes-all groups (the last
+two leave experts empty), against ``ragged_dot`` at the highest precision
+on the same device, and then timed alone (``gmm_times``).
 
 One ``KERNEL {json}`` line per case, ``KERNEL_PARITY_DONE`` at the end,
 exit code 1 if any case was refused or disagreed.  A refusal carries
@@ -48,7 +56,10 @@ from distributed_inference_demo_tpu.ops.flash_attention import (  # noqa: E402
 from distributed_inference_demo_tpu.ops.paged_attention import (  # noqa: E402
     paged_flash_attention, paged_gather_attention, paged_prefill_attention,
     write_paged_kv)
-from distributed_inference_demo_tpu.ops.quant import alloc_kv_pages  # noqa: E402
+from distributed_inference_demo_tpu.ops.grouped_matmul import (  # noqa: E402
+    grouped_matmul)
+from distributed_inference_demo_tpu.ops.quant import (  # noqa: E402
+    alloc_kv_pages, quantize_array)
 
 # the head geometries served: GQA at two head widths, MHA + ALiBi at two
 MODELS = ("qwen2.5-7b", "tinyllama-1.1b", "bloom560m", "bloom7b1")
@@ -103,6 +114,106 @@ def run_case(name: str, kernel_fn, ref_fn, args) -> bool:
         row["error"] = f"{type(e).__name__}: {str(e)[:600]}"
     emit(row)
     return row["ok"]
+
+
+def group_sizes(kind: str, rows: int, experts: int, seed: int):
+    """Rows a group, summing to ``rows``: "uniform"; "zipf" (expert e
+    drawn with weight 1 / (e + 1)^1.2: a few experts take most rows and
+    many stay empty at 256 rows); "one" (a single expert takes all)."""
+    rng = np.random.default_rng(seed)
+    if kind == "uniform":
+        sizes = np.full(experts, rows // experts)
+        sizes[: rows - sizes.sum()] += 1
+    elif kind == "one":
+        sizes = np.zeros(experts, np.int64)
+        sizes[int(rng.integers(experts))] = rows
+    else:
+        w = 1.0 / (np.arange(experts) + 1.0) ** 1.2
+        sizes = np.bincount(rng.choice(experts, size=rows, p=w / w.sum()),
+                            minlength=experts)
+    return sizes.astype(np.int32)
+
+
+def gmm_cases(interpret: bool) -> bool:
+    """The grouped matmul at the cell's shapes (module docstring)."""
+    cfg = get_model_config("olmoe-1b-7b")
+    E, H, I = cfg.num_experts, cfg.hidden_size, cfg.intermediate_size
+    if interpret:
+        E, H, I = 8, 256, 128
+    ok = True
+    rng = np.random.default_rng(0)
+    stacks = {}
+    for k, n in ((H, I), (I, H)):
+        w = jnp.asarray(rng.standard_normal((E, k, n)) * k ** -0.5,
+                        jnp.bfloat16)
+        for quant in ("int8", "bf16"):
+            rhs = stacks[quant, k] = (quantize_array(w) if quant == "int8"
+                                      else w)
+            for rows in ((64,) if interpret else (256, 4096)):
+                x = jnp.asarray(rng.standard_normal((rows, k)),
+                                jnp.bfloat16)
+                for kind in ("uniform", "zipf", "one"):
+                    gs = jnp.asarray(group_sizes(kind, rows, E, rows + k))
+                    backend = "pallas" if interpret else "auto"
+                    ok &= run_case(
+                        f"gmm/{quant}/rows={rows}/k={k}/n={n}/{kind}",
+                        lambda x_, r_, g_: grouped_matmul(
+                            x_, r_, g_, backend=backend,
+                            interpret=interpret),
+                        lambda x_, r_, g_: grouped_matmul(
+                            x_.astype(jnp.float32),
+                            jax.tree.map(lambda a: a if a.dtype == jnp.int8
+                                         else a.astype(jnp.float32), r_),
+                            g_, backend="xla"),
+                        (x, rhs, gs))
+    if not interpret:
+        gmm_times(stacks, E, H, I)
+    return ok
+
+
+def gmm_times(stacks, E, H, I, pairs: int = 8) -> None:
+    """The call's time alone.  One host dispatch costs several calls'
+    worth, so a jitted loop chains ``pairs`` x (an H -> I projection, then
+    the I -> H one) and the row reports the loop's time over its 2 x
+    ``pairs`` calls (group metadata and all), and that against the chip's
+    peaks: ``hbm_pct`` over the touched experts' matrices and scales plus
+    the rows in and out, ``mxu_pct`` over 2 x rows x k x n."""
+    from distributed_inference_demo_tpu.telemetry.profiling import (
+        device_peaks)
+    peaks = device_peaks(jax.devices()[0].device_kind)
+    rng = np.random.default_rng(1)
+    for quant in ("int8", "bf16"):
+        up, down = stacks[quant, H], stacks[quant, I]
+
+        @jax.jit
+        def chain(x, gs):
+            return jax.lax.fori_loop(
+                0, pairs, lambda _, y: grouped_matmul(
+                    grouped_matmul(y, up, gs), down, gs), x)
+
+        for rows in (256, 4096):
+            x = jnp.asarray(rng.standard_normal((rows, H)), jnp.bfloat16)
+            for kind in ("uniform", "zipf", "one"):
+                sizes = group_sizes(kind, rows, E, rows + H)
+                gs = jnp.asarray(sizes)
+                jax.block_until_ready(chain(x, gs))
+                ts = []
+                for _ in range(10):
+                    t0 = time.perf_counter()
+                    jax.block_until_ready(chain(x, gs))
+                    ts.append(time.perf_counter() - t0)
+                t = float(np.median(ts)) / (2 * pairs)
+                touched = int((sizes > 0).sum())
+                wb = 1 if quant == "int8" else 2
+                nbytes = (touched * (H * I * wb + (2 * (H + I) if wb == 1
+                                                   else 0))
+                          + rows * (H + I) * 2)
+                emit({"name": f"gmm/{quant}/rows={rows}/{kind}/time",
+                      "ms_a_call": round(t * 1e3, 4), "touched": touched,
+                      "hbm_pct": round(100 * nbytes / t
+                                       / (peaks.hbm_gbs * 1e9), 1),
+                      "mxu_pct": round(100 * 2 * rows * H * I / t
+                                       / (peaks.bf16_tflops * 1e12), 1)})
 
 
 def paged_pool(rng, b, nkv, hd, bt, W, kv_dtype):
@@ -250,6 +361,8 @@ def main(argv=None) -> int:
     ap.add_argument("--interpret", action="store_true",
                     help="Pallas interpreter at small tables: debugs this "
                          "tool on a CPU, proves nothing about Mosaic")
+    ap.add_argument("--gmm-only", action="store_true",
+                    help="the grouped-matmul cases and timings alone")
     ap.add_argument("--engines", action="store_true",
                     help="also run bloom560m (ALiBi) and qwen2.5-0.5b "
                          "(GQA) engines with bf16 and int8 pages")
@@ -267,6 +380,10 @@ def main(argv=None) -> int:
         return 1
     W, b = (4, 2) if args.interpret else (64, 8)   # 64 pages = max_seq 1024
     ok = True
+    if args.gmm_only:
+        ok = gmm_cases(args.interpret)
+        print("KERNEL_PARITY_DONE", flush=True)
+        return 0 if ok else 1
     for model in MODELS:
         nh, nkv, _, _ = heads(model)
         group = nh // nkv
@@ -300,6 +417,7 @@ def main(argv=None) -> int:
         ok &= flash_cases(model, 64 if args.interpret else 1024,
                           (16,) if args.interpret else (64, 256),
                           args.interpret)
+    ok &= gmm_cases(args.interpret)
     if args.engines:
         for model, kv_dtype, bt in (("bloom560m", "bf16", 16),
                                     ("bloom560m", "int8", 32),

@@ -1,0 +1,283 @@
+#!/usr/bin/env python3
+"""A whole model through the paged serving path against the plain float32
+reference, at published width on this device: LOGITS, not tokens.
+
+    python tools/model_parity.py --model olmoe-1b-7b-int8         # the chip
+    python tools/model_parity.py --model olmoe-1b-7b-int8 --kv-dtype int8
+    python tools/model_parity.py --model olmoe-1b-7b-int8 --bf16-router
+    JAX_PLATFORMS=cpu python tools/model_parity.py --model olmoe-test-int8 \\
+        --prompt 48 --chunk 16 --steps 8 --page 8     # debug the tool itself
+
+Seeded weights (quantized as the model's name says), ``--batch`` seeded
+prompts of ``--prompt`` tokens prefilled in ``--chunk``-token chunks
+straight into a page pool (the program's paged forward seam: the prefill
+kernel on a TPU), then ``--steps`` greedy decode steps through the pages
+(the decode kernel).  The reference is the benchmark's
+(``benchmark/reference.py`` + ``benchmark/families/<family>.py``: float32
+under ``jax.default_matmul_precision("highest")``, the whole sequence at
+once, no cache, no kernel, the experts as their definition), run on the
+same parameters over prompt + the tokens the served path chose.  Compared:
+the log-softmax over the WHOLE vocabulary at the prompt's last position
+and at every decode step.
+
+With random weights the largest logit changes on rounding, so sampled
+tokens say little; the log-probabilities say how far the arithmetic is.
+A position's number is the largest |served - reference| over the
+vocabulary.  The run passes if the MEAN of that over the positions is <=
+``TOL_MEAN`` (the statistic that is steady from seed to seed) and the
+largest over the positions is <= ``TOL_MAX`` (a gross fault at one
+position).  ``mean_abs`` (over positions and vocabulary) and the error at
+the served path's own token are printed beside them.
+
+Readings behind the limits (``READINGS``: olmoe-1b-7b-int8, 4 x 512 + 32,
+seeds 0, 1, 2, TPU v5e; my chip runs, PR 28).  The served configuration
+reads 0.0554-0.0580; int8 KV pages 0.0737-0.0739, which ``TOL_MEAN``
+refuses with room on both sides.  A router whose matmul runs on the bf16
+rows at the default precision reads 0.0578-0.0608: 2-5 % above the sound
+reading OF THE SAME SEED every time, and inside the sound readings' own
+spread from seed to seed, so no fixed limit refuses it and this tool does
+not claim to.  Why: on seeded weights the router's probabilities are near
+1/64 each, a third of the (position, layer) pairs have a reference margin
+p8 - p9 under 1e-3 (``router_margin_under_1e-3``: 685-728 of 2,112), the
+served path's bf16 activations flip those whatever the router's own
+precision, and a flip between two experts of near-equal weight moves a
+log-probability by less than the bf16 activations already do.
+
+One ``MODEL_PARITY {json}`` line, exit code 1 if a limit is passed.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import json
+import sys
+import time
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(ROOT))
+sys.path.insert(0, str(ROOT / "benchmark"))
+
+READINGS = {   # max_over_vocab_mean, max_over_vocab_max, mean_abs a seed
+    "served (bf16 pages, float32 router)": [
+        (0.0566, 0.089, None), (0.0554, 0.097, 0.01009),
+        (0.0580, 0.108, 0.01057)],
+    "--kv-dtype int8": [
+        (0.0739, 0.129, None), (0.0739, 0.122, 0.01332),
+        (0.0737, 0.126, 0.01334)],
+    "--bf16-router": [
+        (0.0578, 0.099, None), (0.0579, 0.101, 0.01055),
+        (0.0608, 0.112, 0.01098)],
+}
+TOL_MEAN = 0.066    # between 0.0580 (sound) and 0.0737 (int8 pages)
+TOL_MAX = 0.25      # 2.3 x the largest sound maximum: a gross fault
+
+
+def seeded_ids(seed: int, n: int, vocab: int):
+    import numpy as np
+    return np.random.default_rng(seed).integers(1, vocab, size=n,
+                                                dtype=np.int32)
+
+
+def bf16_router():
+    """A router as a careless port would write it: the matmul on the
+    activations as they come (bf16) at the default precision, the softmax
+    over its bf16 result.  Swapped in for ``decoder._route`` by
+    ``--bf16-router`` (what it reads is under ``READINGS``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_inference_demo_tpu.models import decoder
+
+    def route(cfg, lp, h):
+        logits = jnp.einsum("th,he->te", h, lp["router"].astype(h.dtype))
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        w, e = jax.lax.top_k(probs, cfg.experts_per_token)
+        if cfg.norm_topk_prob:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        return w, e.astype(jnp.int32)
+
+    decoder._route = route
+
+
+def served_logprobs(cfg, params, prompts, args):
+    """``(tokens [b, steps], logprobs [b, steps + 1, V], paths)``: the
+    served path's log-softmax at the prompt's last position and after
+    each decode step, and the greedy tokens it chose."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_inference_demo_tpu.models import KVCache, StageSpec
+    from distributed_inference_demo_tpu.ops.paged_attention import (
+        AttnPathRecord)
+    from distributed_inference_demo_tpu.ops.quant import alloc_kv_pool
+    from distributed_inference_demo_tpu.parallel.tensor import (
+        make_paged_forward_seam)
+
+    b, plen = prompts.shape
+    bt, C = args.page, args.chunk
+    W = -(-(plen + args.steps + 1) // bt)
+    record = AttnPathRecord()
+    fwd, bind, _ = make_paged_forward_seam(
+        cfg, StageSpec(0, 1, 0, cfg.num_layers), None, params, bt,
+        record=record)
+    pk, pv = alloc_kv_pool(
+        (cfg.num_layers, b * W, cfg.num_kv_heads, bt, cfg.head_dim),
+        args.kv_dtype, cfg.dtype)
+    tables = jnp.arange(b * W, dtype=jnp.int32).reshape(b, W)
+
+    @jax.jit
+    def chunk(params, pk, pv, ids, start):
+        bind(tables, "prefill")
+        pos = start + jnp.broadcast_to(jnp.arange(ids.shape[1]), ids.shape)
+        logits, cache = fwd(params, ids, KVCache(pk, pv, jnp.int32(0)),
+                            pos, True)
+        return (jax.nn.log_softmax(logits[:, -1].astype(jnp.float32), -1),
+                cache.keys, cache.values)
+
+    @jax.jit
+    def step(params, pk, pv, tok, length):
+        bind(tables, "decode")
+        logits, cache = fwd(params, tok[:, None],
+                            KVCache(pk, pv, jnp.int32(0)), length[:, None],
+                            True)
+        return (jax.nn.log_softmax(logits[:, 0].astype(jnp.float32), -1),
+                cache.keys, cache.values)
+
+    for start in range(0, plen, C):
+        lp, pk, pv = chunk(params, pk, pv,
+                           jnp.asarray(prompts[:, start:start + C]),
+                           jnp.int32(start))
+    lps, toks = [np.asarray(lp)], []
+    length = jnp.full((b,), plen, jnp.int32)
+    for _ in range(args.steps):
+        tok = jnp.argmax(lp, -1).astype(jnp.int32)
+        toks.append(np.asarray(tok))
+        lp, pk, pv = step(params, pk, pv, tok, length)
+        lps.append(np.asarray(lp))
+        length = length + 1
+    return np.stack(toks, 1), np.stack(lps, 1), record.snapshot()
+
+
+def reference_logprobs(cfg, params, ids, n_prompt: int):
+    """``(logprobs, margins)``: the reference's log-softmax
+    ``[len(ids) - n_prompt + 1, V]`` at the positions that predict token
+    ``n_prompt`` onward and the one after the last (the whole sequence at
+    once, float32, highest precision); and, for a model with experts, the
+    router's margin ``p_k - p_(k+1)`` at those positions in every layer
+    (``[layers, positions]``, else None): where it is tiny, rounding
+    picks another expert and the error does not shrink with precision.
+    The rows that enter a layer's router are the family's own layer with
+    the experts' down projections zeroed (x + attention), normed."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import families
+    import reference
+
+    mc = dataclasses.asdict(cfg)
+    embed, layer_eq, final_norm = families.load(cfg.family).equations(mc)
+    E, k = cfg.num_experts, cfg.experts_per_token
+
+    @jax.jit
+    def margin(x, layers, i):
+        p = {key: reference._f32(jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+            v)) for key, v in layers.items()}
+        mid = layer_eq(dict(p, w_down=jnp.zeros_like(p["w_down"])), x)
+        h = reference._rms_norm(mid[n_prompt - 1:], p["mlp_norm_w"],
+                                cfg.norm_eps)
+        s = jnp.sort(jax.nn.softmax(h @ p["router"], -1), -1)
+        return s[:, E - k] - s[:, E - k - 1]
+
+    margins = []
+    with jax.default_matmul_precision("highest"):
+        x = embed(params, jnp.asarray(ids, jnp.int32))
+        layer = reference._make_layer_fn(layer_eq)
+        for i in range(cfg.num_layers):
+            if E:
+                margins.append(np.asarray(
+                    margin(x, params.layers, jnp.int32(i))))
+            x = layer(x, params.layers, jnp.int32(i))
+        x = final_norm(params, x[n_prompt - 1:])
+        head = (params.embed["tokens"].astype(jnp.float32).T
+                if cfg.tie_embeddings
+                else reference._f32(params.lm_head["w"]))
+        return (np.asarray(jax.nn.log_softmax(x @ head, -1)),
+                np.stack(margins) if margins else None)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--model", required=True)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--batch", type=int, default=4)
+    ap.add_argument("--prompt", type=int, default=512)
+    ap.add_argument("--chunk", type=int, default=256)
+    ap.add_argument("--steps", type=int, default=32)
+    ap.add_argument("--page", type=int, default=128)
+    ap.add_argument("--kv-dtype", default="bf16",
+                    choices=("bf16", "int8"))
+    ap.add_argument("--bf16-router", action="store_true",
+                    help="swap in a bf16 router (see READINGS)")
+    args = ap.parse_args(argv)
+    from distributed_inference_demo_tpu.cli import configure_compile_cache
+    configure_compile_cache()
+    import jax
+    import numpy as np
+
+    from distributed_inference_demo_tpu.models import get_model_config
+    from distributed_inference_demo_tpu.models.decoder import (
+        init_full_params)
+
+    if args.bf16_router:
+        bf16_router()
+    dev = jax.devices()[0]
+    cfg = get_model_config(args.model)
+    t0 = time.monotonic()
+    params = init_full_params(jax.random.PRNGKey(args.seed), cfg,
+                              quantize=True)
+    prompts = np.stack([seeded_ids(args.seed * 1000 + 17 + i, args.prompt,
+                                   cfg.vocab_size)
+                        for i in range(args.batch)])
+    toks, served, paths = served_logprobs(cfg, params, prompts, args)
+    t_served = time.monotonic() - t0
+    worst, own, margins, means = [], [], [], []
+    for r in range(args.batch):
+        ids = np.concatenate([prompts[r], toks[r]])
+        ref, margin = reference_logprobs(cfg, params, ids, args.prompt)
+        if margin is not None:
+            margins.extend(float(m) for m in margin.ravel())
+        err = np.abs(served[r] - ref)                 # [steps + 1, V]
+        worst.extend(float(e) for e in err.max(-1))
+        means.append(float(err.mean()))
+        # the served path's own choice at each position (the last
+        # position's is never fed back: take its argmax)
+        chosen = list(toks[r]) + [int(served[r, -1].argmax())]
+        own.extend(float(err[i, t]) for i, t in enumerate(chosen))
+    row = {"model": args.model, "platform": dev.platform,
+           "device_kind": dev.device_kind, "kv_dtype": args.kv_dtype,
+           "bf16_router": args.bf16_router, "batch": args.batch,
+           "prompt": args.prompt, "steps": args.steps,
+           "positions": len(worst), "paths": paths,
+           "max_over_vocab_max": max(worst),
+           "max_over_vocab_mean": sum(worst) / len(worst),
+           "mean_abs": sum(means) / len(means),
+           "own_token_max": max(own), "own_token_mean": sum(own) / len(own),
+           "tol_mean": TOL_MEAN, "tol_max": TOL_MAX,
+           "router_pairs": len(margins),
+           "router_margin_under_1e-3": sum(m < 1e-3 for m in margins),
+           "router_margin_under_1e-4": sum(m < 1e-4 for m in margins),
+           "served_s": round(t_served, 1),
+           "total_s": round(time.monotonic() - t0, 1)}
+    row["ok"] = bool(row["max_over_vocab_mean"] <= TOL_MEAN
+                     and row["max_over_vocab_max"] <= TOL_MAX)
+    print("MODEL_PARITY " + json.dumps(row), flush=True)
+    return 0 if row["ok"] else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())
