@@ -12,8 +12,10 @@ program's files:
 2. a control thread reads lines from stdin: ``TRACE_START <dir>`` /
    ``TRACE_STOP`` switch ``jax.profiler`` and stamp ``time.time_ns()`` and
    ``time.monotonic()`` at both ends, so the client's timeline and the
-   device trace share a clock; ``REFERENCE <json>`` runs
-   ``reference.emitted_logprobs`` on the served parameters;
+   device trace share a clock (``TRACE_STOP`` writes the ``.xplane.pb``
+   and not the viewer's ``trace.json.gz``: ``_stop_trace``);
+   ``REFERENCE <json>`` runs ``reference.emitted_logprobs`` on the served
+   parameters;
 3. the one loader call ``models.loader.load_or_init`` is wrapped to keep a
    read-only handle on those parameters.
 """
@@ -23,6 +25,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import socket
 import sys
 import threading
 import time
@@ -71,10 +74,42 @@ def _stamp() -> dict:
     return {"time_ns": time.time_ns(), "monotonic": time.monotonic()}
 
 
+def _stop_trace(log_dir: str) -> dict:
+    """End the profiler's session and write its ``.xplane.pb`` under
+    ``log_dir``, and nothing else; returns the stamp between the two.
+
+    ``jax.profiler.stop_trace()`` also converts the whole trace to a
+    ``trace.json.gz`` for a viewer.  Nothing here reads that file, and
+    writing it took 35 of the call's 44 s on one chip (PR 30; PERF.md
+    section 6), with the replica serving the window's remaining
+    requests beside it.  The session's own ``stop()`` hands back the same
+    ``XSpace``, serialised.  It is reached through a private name; a JAX
+    that does not show it gets the public call and both files."""
+    import jax
+    try:
+        from jax._src import profiler as impl
+        state = impl._profile_state
+        lock, session, reset = state.lock, state.profile_session, state.reset
+        stop = session.stop
+    except AttributeError:
+        jax.profiler.stop_trace()
+        return _stamp()
+    with lock:
+        xspace = stop()
+        reset()
+    collected = _stamp()
+    out = (Path(log_dir) / "plugins" / "profile"
+           / time.strftime("%Y_%m_%d_%H_%M_%S"))
+    out.mkdir(parents=True, exist_ok=True)
+    (out / f"{socket.gethostname()}.xplane.pb").write_bytes(xspace)
+    return collected
+
+
 def _control(model_fields: dict) -> None:
     """Serve control lines until stdin closes.  A failure answers with an
     ``error`` field: the parent decides what it means for the run."""
     import jax
+    trace_dir = ""
     for line in sys.stdin:
         cmd, _, rest = line.strip().partition(" ")
         try:
@@ -82,13 +117,15 @@ def _control(model_fields: dict) -> None:
                 opts = jax.profiler.ProfileOptions()
                 opts.python_tracer_level = 0
                 opts.host_tracer_level = 1
+                trace_dir = rest
                 before = _stamp()
                 jax.profiler.start_trace(rest, profiler_options=opts)
                 _say("TRACE_STARTED", {"start": before, "running": _stamp()})
             elif cmd == "TRACE_STOP":
                 before = _stamp()
-                jax.profiler.stop_trace()
-                _say("TRACE_STOPPED", {"stop": before, "written": _stamp()})
+                collected = _stop_trace(trace_dir)
+                _say("TRACE_STOPPED", {"stop": before, "collected": collected,
+                                       "written": _stamp()})
             elif cmd == "REFERENCE":
                 import reference
                 req = json.loads(rest)

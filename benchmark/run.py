@@ -21,7 +21,8 @@ a device's.
 Phases: children up -> warm-up (both ``mixed_step`` variants) -> reference
 check and canaries -> ramp (the cell's traffic, unmeasured) -> window of
 ``--seconds`` -> drain -> canaries again -> ``/stats``, ``/health`` -> stop
-children -> (traced run) reduce the trace -> the result line.
+children -> (traced run) reduce the trace -> the result line.  The wall
+seconds of each are printed in the ``[time]`` line (``Stages``).
 """
 
 from __future__ import annotations
@@ -54,6 +55,38 @@ REHEARSAL_EXIT = 3
 
 def say(msg: str) -> None:
     print(msg, flush=True)
+
+
+class Stages:
+    """Where a run's wall seconds went: ``lap(name)`` books the seconds
+    since the last lap (or up to the instant ``at``, if that has come)
+    under ``name``, in the order of the run, from the process's start."""
+
+    def __init__(self, start: float):
+        self.start = self.last = start
+        self.seconds = {}
+
+    def lap(self, name: str, at: float | None = None) -> None:
+        now = time.monotonic()
+        at = now if at is None else min(at, now)
+        self.seconds[name] = self.seconds.get(name, 0.0) + at - self.last
+        self.last = at
+
+    def report(self, marks: dict, reducer: dict) -> dict:
+        """The stages, the profiler's own two calls (they overlap the
+        window), what the reducer says of itself, and the total."""
+        out = dict(self.seconds)
+        for key, mark, a, b in (
+                ("trace_start_s", "trace_started", "start", "running"),
+                ("trace_collect_s", "trace_stopped", "stop", "collected"),
+                ("trace_stop_s", "trace_stopped", "stop", "written")):
+            stamp = marks.get(mark) or {}
+            if a in stamp and b in stamp:
+                out[key] = stamp[b]["monotonic"] - stamp[a]["monotonic"]
+        if reducer:
+            out["reducer"] = reducer
+        out["total_s"] = self.last - self.start
+        return out
 
 
 def load_json(path: Path) -> dict:
@@ -244,10 +277,12 @@ def run(args) -> int:
     drain_s = float(mix.get("drain_s", 10))
     params = dict(mix, **load, horizon_s=ramp_s + args.seconds)
     generator = importlib.import_module(f"generators.{mix['generator']}")
+    stages = Stages(T_PROCESS_START)
 
     with Stack(ROOT / conf_entry["file"], args.seed,
                "cpu" if rehearse else "tpu", cell["chips"], rehearse,
                out_dir, args.flag) as stack:
+        stages.lap("children_up_s")
         health = stack.health()
         if not rehearse and (health.get("platform") != "tpu"
                              or health.get("device_count") != cell["chips"]):
@@ -270,6 +305,7 @@ def run(args) -> int:
         bad = [r.problem(vocab) for r in recs if r.problem(vocab)]
         if bad:
             raise BenchFailure(f"warm-up failed: {bad}")
+        stages.lap("warm_up_s")
         say(f"[setup] warm-up done at "
             f"{time.monotonic() - T_PROCESS_START:.1f} s; compile ledger "
             f"{json.dumps(stack.stats().get('compile', {}))}")
@@ -283,18 +319,31 @@ def run(args) -> int:
             f"errs {[round(e, 4) for e in ref['errs']]}")
 
         plan = generator.make(params, args.seed, vocab, scale)
+        stages.lap("reference_and_canaries_s")
         t0 = time.monotonic()
         marks = Marks(stack, t0 + ramp_s, args.seconds, trace_dir)
         marks.start()
         _, records = run_plan(stack.gw_port, plan, ramp_s + args.seconds,
                               ramp_s + args.seconds + drain_s, t0=t0)
+        stages.lap("ramp_s", t0 + ramp_s)
+        stages.lap("window_s", t0 + ramp_s + args.seconds)
+        stages.lap("drain_s")
         marks.join(timeout=600)
         if marks.error is not None or marks.is_alive():
             raise BenchFailure(f"reading /stats or tracing failed: "
                                f"{marks.error!r}")
         stack.check_alive()
+        stages.lap("marks_joined_s")
         second = canaries(stack.gw_port, vocab, args.seed, scale)
         stats_end, health_end = stack.stats(), stack.health()
+        stages.lap("canaries_again_s")
+
+    stages.lap("children_stopped_s")
+    reduced, reducer = {}, {}
+    if trace_dir is not None:
+        reduced = reduce_trace(trace_dir, out_dir / "trace_reduced.json")
+        reducer = reduced.pop("reducer", {})    # its own cost: no metric's
+        stages.lap("reduce_trace_s")
 
     # children are stopped; everything below is arithmetic
     t_open, t_close = t0 + ramp_s, t0 + ramp_s + args.seconds
@@ -304,9 +353,6 @@ def run(args) -> int:
     failed = [(r, p) for r, p in problems
               if p and t_open <= r.due < t_close]
     ok = [r for r in sample if not r.problem(vocab)]
-    reduced = {}
-    if trace_dir is not None:
-        reduced = reduce_trace(trace_dir, out_dir / "trace_reduced.json")
     ctx = {
         "cell": cell, "config": conf, "mix": mix, "load": load,
         "served": served, "rehearse": rehearse, "seconds": args.seconds,
@@ -351,8 +397,11 @@ def run(args) -> int:
     say(f"[paths] {json.dumps(paths)}")
     say(f"[compile] {json.dumps(stats_end.get('compile', {}))} "
         f"in window {json.dumps(compiled_in_window)}")
+    stages.lap("report_s")
+    spent = stages.report(marks.out, reducer)
+    say(f"[time] {json.dumps(spent)}")
     (out_dir / f"records_seed{args.seed}_trace{args.trace}.json").write_text(
-        json.dumps({"t0": t0, "window": [t_open, t_close],
+        json.dumps({"t0": t0, "window": [t_open, t_close], "time": spent,
                     "checks": checks, "reference": ref, "load": load,
                     "stats_open": ctx["stats_open"],
                     "stats_close": ctx["stats_close"],
