@@ -23,8 +23,9 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.attention import alibi_slopes, attention, update_kv_cache
-from ..ops.grouped_matmul import LayerOf, grouped_matmul
+from ..ops.grouped_matmul import grouped_matmul
 from ..ops.quant import dense
+from ..ops.stacked import LayerOf
 from ..ops.norms import layer_norm, rms_norm
 from ..ops.rope import apply_rope
 from .base import KVCache, ModelConfig, StageParams, StageSpec
@@ -413,7 +414,9 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
            moe_stats: bool = False):
     """One decoder block. x: [b, s, H]. Returns (x', k_cache', v_cache'),
     and with ``moe_stats`` a fourth value, the rows routed to each expert
-    in this layer call ([E] int32; ``_moe_routed``).
+    in this layer call ([E] int32; ``_moe_routed``).  The caches are this
+    layer's planes, or ``LayerOf`` the whole stacks where ``attn_impl``
+    addresses a page pool in place; either goes to the hook untouched.
 
     Head counts derive from the weight shards, not the config, so the same
     code runs full-model (GSPMD) and per-TP-rank (manual shard_map) — under
@@ -530,36 +533,35 @@ def stage_forward(
         # The cache planes are pytrees, not bare arrays, when the pool
         # is quantized (ops.quant.QuantizedKVPages: narrow data + scale
         # leaves share the leading layer axis) — index/update per leaf.
-        # the expert stacks stay whole beside the scan: a layer of them
-        # sliced out for the grouped matmul's custom call would be a
-        # copy in HBM, so the layer goes in as (stack, index)
-        # (ops.grouped_matmul.LayerOf).  The capacity-slot EP path
-        # takes its slices as before
+        # One seam for what a layer must not get as a slice
+        # (ops.stacked.LayerOf: the whole stack and the layer's index).
+        # A slice that XLA fuses into its consumer is free; one made for
+        # a custom call or a scatter is a copy in HBM, every layer call.
+        # So the expert stacks stay whole beside the scan for the
+        # grouped matmul's kernel (the capacity-slot EP path takes its
+        # slices as before), and a PAGE POOL stays whole in the carry:
+        # the hook made for it (``stacked_cache``) addresses
+        # (layer, page) and hands the stacks back.  A dense cache is
+        # sliced and updated here, as it always was.
         whole = (_EXPERT_STACKS if cfg.num_experts > 0 and ep_axis is None
                  else ())
         scanned_layers = {k: v for k, v in params.layers.items()
                           if k not in whole}
+        stacked_cache = getattr(attn_impl, "stacked_cache", False)
 
         def body(carry, scanned):
             x, K, V = carry
             lp, li = scanned
             lp = dict(lp, **{k: LayerOf(params.layers[k], li)
                              for k in whole})
-            kc = jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(
-                    a, li, 0, keepdims=False), K)
-            vc = jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(
-                    a, li, 0, keepdims=False), V)
+            k_of, v_of = LayerOf(K, li), LayerOf(V, li)
+            kc, vc = ((k_of, v_of) if stacked_cache
+                      else (k_of.sliced(), v_of.sliced()))
             x, kc, vc, *rows = _layer(cfg, lp, x, kc, vc, positions,
                                       cache_start, slopes, tp_axis,
                                       attn_impl, ep_axis, moe_stats)
-            K = jax.tree.map(
-                lambda a, c: jax.lax.dynamic_update_index_in_dim(
-                    a, c, li, 0), K, kc)
-            V = jax.tree.map(
-                lambda a, c: jax.lax.dynamic_update_index_in_dim(
-                    a, c, li, 0), V, vc)
+            K, V = ((kc.stack, vc.stack) if stacked_cache
+                    else (k_of.updated(kc), v_of.updated(vc)))
             return (x, K, V), (rows[0] if rows else None)
 
         n_layers = jax.tree.leaves(cache.keys)[0].shape[0]

@@ -24,7 +24,7 @@ Two forms of the same arithmetic, picked like the paged attention paths
   ever written to HBM; row counts that are no multiple of the tile (padded
   here); a contraction tile that spans ``k`` where it fits, so an
   expert's matrix is read once however many row tiles its group covers;
-  and a right-hand side that is one layer OF a stack (:class:`LayerOf`):
+  and a right-hand side that is one layer OF a stack (``stacked.LayerOf``):
   the kernel takes the whole ``[L, E, k, n]`` stack and the layer's index
   as a scalar, because a layer sliced out of the stack for a custom call
   is a copy of it in HBM (128 MiB a projection a layer call at
@@ -42,7 +42,6 @@ HBM, 4 x the packed bytes, a layer call) and takes the bf16 form.
 from __future__ import annotations
 
 import functools
-from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -50,28 +49,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .quant import QuantizedArray, QuantizedArray4
-
-
-class LayerOf(NamedTuple):
-    """One layer of a stack of right-hand sides, not sliced out of it:
-    ``stack`` has a leading layer axis (``[L, E, k, n]``; a quantized
-    stack's leaves all do) and ``layer`` is a traced int32 scalar.  The
-    decoder's layer scan hands the expert stacks over like this."""
-
-    stack: Any
-    layer: jax.Array
-
-    @property
-    def shape(self):
-        return self.stack.shape[1:]
-
-    def sliced(self):
-        """The layer as a tree of its own (a copy, where it is large)."""
-        return jax.tree.map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, self.layer, 0,
-                                                   keepdims=False),
-            self.stack)
-
+from .stacked import LayerOf
 
 PATH_KERNEL = "pallas_gmm"
 PATH_XLA = "ragged_dot"

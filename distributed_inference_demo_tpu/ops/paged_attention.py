@@ -5,7 +5,13 @@ The PagedAttention memory model (vLLM, SOSP'23): instead of one dense
 shared pool of fixed-size pages ``[num_pages, nkv, block_tokens, hd]``
 (one pool per layer — the engines stack a leading layer axis) and each
 sequence addresses its pages through a block table ``[batch, W]`` of
-page ids.  Two consequences the dense layout cannot give:
+page ids.  Every function here takes a pool operand either as one
+layer's plane or as ``LayerOf(stack, layer)`` (``ops.stacked``): the
+stacked ``[L, N, H, bt, D]`` pool and the layer's index.  Both address
+``(layer, page)`` in a stack (a plane is a stack of one), so the decoder's
+layer scan hands over its carried pool whole, and where the hook
+addresses it in place (:func:`route_pool`) no plane of it and no second
+copy of the pool is made.  Two consequences the dense layout cannot give:
 
 - HBM is reserved per page actually allocated, not ``batch x max_seq``
   worst-case rows;
@@ -47,17 +53,51 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .attention import attention, prepare_kv_chunk
 from .quant import QuantizedKVPages, quantize_kv_like
+from .stacked import LayerOf
 
 _NEG = -1e30
 
+# how a layer call reaches the pool (route_pool picks one): in place in
+# the stack, by one of write_paged_kv's two forms, or through the layer's
+# plane, sliced out and put back
+WRITE_SCATTER = "scatter write"
+WRITE_KERNEL = "kernel write"
+POOL_PLANE = "plane"
+
+
+def _pool(pages):
+    """The pages themselves, a plane or the stack a ``LayerOf`` holds."""
+    return pages.stack if isinstance(pages, LayerOf) else pages
+
+
+def _stacked(k_pages, v_pages):
+    """``(k_stack, v_stack, layer)`` of a pair of pool operands: a
+    :class:`LayerOf` as it is, a plane ``[N, H, bt, D]`` as the stack of
+    one layer it is (a reshape)."""
+    if isinstance(k_pages, LayerOf):
+        return (k_pages.stack, v_pages.stack,
+                jnp.asarray(k_pages.layer, jnp.int32))
+    one = lambda pages: jax.tree.map(lambda a: a[None], pages)
+    return one(k_pages), one(v_pages), jnp.zeros((), jnp.int32)
+
+
+def _like(pages, stack):
+    """``stack`` handed back in the form ``pages`` came in."""
+    if isinstance(pages, LayerOf):
+        return LayerOf(stack, pages.layer)
+    return jax.tree.map(lambda a: a[0], stack)
+
 
 def write_paged_kv(
-    k_pages: jnp.ndarray,   # [num_pages, nkv, block_tokens, hd]
-    v_pages: jnp.ndarray,
+    k_pages,                # [num_pages, nkv, block_tokens, hd] or LayerOf
+    v_pages,
     k_new: jnp.ndarray,     # [batch, chunk, nkv, hd] (projection layout)
     v_new: jnp.ndarray,
     tables: jnp.ndarray,    # [batch, W] int32 page ids (>= num_pages = none)
-    positions: jnp.ndarray  # [batch, chunk] absolute token positions
+    positions: jnp.ndarray,  # [batch, chunk] absolute token positions
+    *,
+    form: str = WRITE_SCATTER,  # or WRITE_KERNEL: route_pool says which
+    interpret: bool = False,
 ):
     """Scatter the chunk's K/V into its pages: token at position ``p`` of
     row ``b`` lands in page ``tables[b, p // bt]`` at offset ``p % bt``.
@@ -72,37 +112,241 @@ def write_paged_kv(
     real page — the write would corrupt a live position ``p % bt`` deep
     into it.  Write contract (stale-slot invariant, shared with the
     dense path): :func:`ops.attention.prepare_kv_chunk`.
+
+    The pools come back in the form they came in.  A ``LayerOf`` is
+    written at ``(layer, page, :, off)`` of the stacked pool, in place in
+    the scan's carry, and every other layer's pages are untouched.
+    ``form`` picks between two forms of the same write: one scatter a
+    leaf (``WRITE_SCATTER``, everywhere), and the Pallas write below
+    (``WRITE_KERNEL``: :func:`_page_write_call`; plain pages on the chip,
+    ``positions`` CONTIGUOUS per row as in
+    :func:`paged_prefill_attention`, and no two rows writing into the
+    same tile group: :func:`route_pool` holds the chunk to that), which
+    exists because of what the scatter on the stack costs there.
     """
-    bt = k_pages.shape[2]
-    if isinstance(k_pages, QuantizedKVPages):
+    K, V, li = _stacked(k_pages, v_pages)
+    bt = K.shape[3]
+    if form == WRITE_KERNEL:
+        k_new, v_new = prepare_kv_chunk(k_new, v_new, K.dtype, V.dtype)
+        K, V = _kernel_write(K, V, li, k_new, v_new,
+                             tables.astype(jnp.int32),
+                             positions[:, 0].astype(jnp.int32), interpret)
+        return _like(k_pages, K), _like(v_pages, V)
+    if isinstance(K, QuantizedKVPages):
         # quantize ONCE at write time, per token over head_dim: the
         # scale/zero sidecar leaves take the exact same scatter index
         # (their trailing axis is a broadcast singleton).
         k_new, v_new = prepare_kv_chunk(k_new, v_new, jnp.float32,
                                         jnp.float32)
     else:
-        k_new, v_new = prepare_kv_chunk(k_new, v_new, k_pages.dtype,
-                                        v_pages.dtype)
-    qk = quantize_kv_like(k_pages, k_new)
-    qv = quantize_kv_like(v_pages, v_new)
-    num_pages, W = k_pages.shape[0], tables.shape[1]
+        k_new, v_new = prepare_kv_chunk(k_new, v_new, K.dtype, V.dtype)
+    qk = quantize_kv_like(K, k_new)
+    qv = quantize_kv_like(V, v_new)
+    num_pages, W = K.shape[1], tables.shape[1]
     pidx = positions // bt                                       # [b, s]
     page = jnp.take_along_axis(tables, jnp.minimum(pidx, W - 1), axis=1)
     page = jnp.where(pidx < W, page, num_pages)  # past-table -> drop
     off = positions % bt                                         # [b, s]
-    # advanced indices at dims (0, 2) around the head slice: the indexed
-    # result layout [b, s, nkv, hd] is exactly the projection layout the
-    # chunk arrives in — no transpose.
-    scatter = lambda p, c: p.at[page, :, off].set(c, mode="drop")
-    k_pages = jax.tree.map(scatter, k_pages, qk)
-    v_pages = jax.tree.map(scatter, v_pages, qv)
-    return k_pages, v_pages
+    # advanced indices at dims (0, 1, 3) around the head slice: the
+    # indexed result layout [b, s, nkv, hd] is exactly the projection
+    # layout the chunk arrives in — no transpose.
+    scatter = lambda p, c: p.at[li, page, :, off].set(c, mode="drop")
+    return (_like(k_pages, jax.tree.map(scatter, K, qk)),
+            _like(v_pages, jax.tree.map(scatter, V, qv)))
+
+
+# ---------------------------------------------------------------------------
+# Pallas TPU page write
+#
+# On the chip the scatter above asks the compiler for the pool in ANOTHER
+# layout than the attention kernels read (tokens outside heads, so that a
+# token's [nkv, hd] window is contiguous), and the compiler then copies
+# the WHOLE pool from one layout to the other between the write and the
+# kernel, every layer call (compiled ahead of time for a v5e, PR 31:
+# `copy(...)` of `[L, N, H, bt, D]` inside the layer loop).  A custom call
+# takes its operands in the default layout, so the write is one too: the
+# pool is aliased in and out and only the tiles that hold the chunk's
+# tokens move.
+
+
+def _write_group(dtype) -> int:
+    """Tokens in one sublane tile of a page (8 of 32 bits, 16 of 16): what
+    the write kernel reads, merges and writes back, since a DMA moves
+    whole tiles."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def _page_write_kernel(page_ref, row_ref, lo_ref, hi_ref, layer_ref,
+                       k_new_ref, v_new_ref, k_in, v_in, k_hbm, v_hbm,
+                       k_buf, v_buf, sems, *, units: int, group: int):
+    """Grid (batches of ``units``,).  Unit ``n`` is one tile group of one
+    row's chunk: rows ``[row, row + group)`` of page ``page_ref[n]``, all
+    kv heads (``[nkv, group, hd]``, strided over the heads).  A batch
+    reads its units' tiles out of the pool, takes rows ``[lo, hi)`` of
+    each from the chunk (``*_new_ref``: the chunk's tokens laid out like
+    the tiles), and writes the tiles back.  A unit with ``hi <= lo`` (a
+    sentinel page, a position past the table, padding) moves nothing."""
+    del k_in, v_in                      # the pools, aliased to the outputs
+    first = pl.program_id(0) * units
+    layer = layer_ref[0]
+    streams = ((k_hbm, k_buf, k_new_ref), (v_hbm, v_buf, v_new_ref))
+
+    def copies(u, to_pool):
+        row = pl.multiple_of(row_ref[first + u], group)
+        out = []
+        for i, (hbm, buf, _) in enumerate(streams):
+            tile = hbm.at[layer, page_ref[first + u], :,
+                          pl.ds(row, group), :]
+            src, dst = (buf.at[u], tile) if to_pool else (tile, buf.at[u])
+            out.append(pltpu.make_async_copy(src, dst, sems.at[i, u]))
+        return out
+
+    def each_live(fn):
+        def body(u, carry):
+            @pl.when(hi_ref[first + u] > lo_ref[first + u])
+            def _live():
+                fn(u)
+            return carry
+        jax.lax.fori_loop(0, units, body, 0)
+
+    def merge(u):
+        r = jax.lax.broadcasted_iota(jnp.int32, k_buf.shape[1:], 1)
+        mine = (r >= lo_ref[first + u]) & (r < hi_ref[first + u])
+        for _, buf, new in streams:
+            # selected in 32 bits (exact): a mask over packed rows is
+            # not something Mosaic has to lower
+            buf[u] = jnp.where(mine, new[u].astype(jnp.float32),
+                               buf[u].astype(jnp.float32)).astype(buf.dtype)
+
+    each_live(lambda u: [c.start() for c in copies(u, False)])
+    each_live(lambda u: [c.wait() for c in copies(u, False)])
+    each_live(merge)
+    each_live(lambda u: [c.start() for c in copies(u, True)])
+    each_live(lambda u: [c.wait() for c in copies(u, True)])
+
+
+@functools.partial(jax.jit, static_argnames=("units", "interpret"))
+def _page_write_call(page, row, lo, hi, layer, k_new, v_new, k_pages,
+                     v_pages, *, units, interpret):
+    """The Pallas call: ``k_pages`` / ``v_pages`` ``[L, N, nkv, bt, hd]``
+    aliased to the outputs, ``k_new`` / ``v_new`` ``[U, nkv, group, hd]``
+    with ``U`` a multiple of ``units``."""
+    U, nkv, group, hd = k_new.shape
+    new_spec = pl.BlockSpec((units, nkv, group, hd),
+                            lambda i, *_: (i, 0, 0, 0))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    buf = pltpu.VMEM((units, nkv, group, hd), k_pages.dtype)
+    return pl.pallas_call(
+        functools.partial(_page_write_kernel, units=units, group=group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(U // units,),
+            in_specs=[new_spec, new_spec, pool_spec, pool_spec],
+            out_specs=[pool_spec, pool_spec],
+            scratch_shapes=[buf, buf,
+                            pltpu.SemaphoreType.DMA((2, units))],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
+                   jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
+        # operands count the five scalar arrays too
+        input_output_aliases={7: 0, 8: 1},
+        interpret=interpret,
+        name="kv_page_write",
+    )(page, row, lo, hi, layer, k_new, v_new, k_pages, v_pages)
+
+
+# units of one batch: their tiles are in flight together
+_WRITE_UNITS = 32
+
+
+def _kernel_write(K, V, li, k_new, v_new, tables, starts, interpret):
+    """The chunk cut into the kernel's units.  Row ``b``'s tokens sit at
+    positions ``starts[b] + arange(chunk)``; they touch at most ``n_g``
+    tile groups, each inside one page (``bt % group == 0``).  A group is
+    one row's alone (:func:`route_pool`): two units naming the same
+    group would both read it before either wrote it back."""
+    _, num_pages, nkv, bt, hd = K.shape
+    b, chunk = k_new.shape[:2]
+    W = tables.shape[1]
+    G = _write_group(K.dtype)
+    n_g = (chunk + 2 * G - 2) // G
+    first = ((starts // G) * G)[:, None] + G * jnp.arange(n_g)  # [b, n_g]
+    pidx = first // bt
+    page = jnp.take_along_axis(tables, jnp.minimum(pidx, W - 1), axis=1)
+    live = (pidx < W) & (page < num_pages)      # else the write drops
+    lo = jnp.clip(starts[:, None] - first, 0, G)
+    hi = jnp.where(live, jnp.clip(starts[:, None] + chunk - first, 0, G), lo)
+    # token (group g, row r) of a row is chunk column first + r - start
+    col = (first - starts[:, None])[:, :, None] + jnp.arange(G)
+    col = jnp.clip(col, 0, chunk - 1).reshape(b, n_g * G)
+
+    def tiles(x):                       # [b, chunk, nkv, hd] -> units
+        x = jnp.take_along_axis(x, col[:, :, None, None], axis=1)
+        x = x.reshape(b, n_g, G, nkv, hd).transpose(0, 1, 3, 2, 4)
+        return x.reshape(b * n_g, nkv, G, hd)
+
+    U = b * n_g
+    batches = -(-U // _WRITE_UNITS)
+    units = -(-U // batches)
+    pad = batches * units - U
+    flat = lambda a: jnp.pad(a.reshape(U).astype(jnp.int32), (0, pad))
+    padded = lambda x: jnp.pad(x, ((0, pad), (0, 0), (0, 0), (0, 0)))
+    return _page_write_call(
+        flat(jnp.minimum(page, num_pages - 1)), flat(first % bt), flat(lo),
+        flat(hi), li.reshape(1), padded(tiles(k_new)), padded(tiles(v_new)),
+        K, V, units=units, interpret=interpret)
+
+
+def route_pool(backend: str, platform: str, k_pages, chunk: int) -> str:
+    """How a traced layer call reaches the pool: in place in the stack by
+    one of :func:`write_paged_kv`'s forms, or through the layer's plane
+    (``POOL_PLANE``).  A pure function of what the trace can see, like
+    :func:`route_paged_attention`.
+
+    Off the chip the stack is addressed in place: the Pallas write where
+    "pallas" asks for the kernels and the write covers the shape, else the
+    scatter (the kernels interpreted, or the gather, read ``(layer,
+    page)``).
+
+    On the chip the stack is addressed in place where the Pallas write
+    covers the shape, and nowhere else: there the scatter asks for the
+    stack in another layout than the kernels read, and a pool of narrow
+    heads or of narrow pages lies in HBM in another layout than a custom
+    call takes (the compiler picks the one that pads least), and either
+    costs copies of the WHOLE pool, a layer call (compiled ahead of time,
+    PR 31: ``tests/test_bring_up.py``).  Such a call slices its layer's
+    plane out, runs on the plane, and puts it back, which is the program
+    every pool had before PR 31.
+
+    The Pallas write covers plain pages whose head fills the lanes and
+    whose page holds whole tile groups, **at a chunk of one token or of
+    whole tile groups**.  It reads, merges and writes back whole tile
+    groups, a batch of them in flight at once, so no two rows of a call
+    may write into the same group.  Rows of different requests never do
+    (they write their own pages).  Rows of ONE request exist: the mixed
+    slab packs an admission's sequential chunks as rows of one call, each
+    starting where the last ended, and the first on a page boundary (a
+    prefix hit is whole pages).  At a chunk of whole groups every row
+    then starts on a group boundary; at any other chunk two rows share a
+    group and the second write-back would restore the first's tokens to
+    what they were.  A quantized pool's sidecar has a minor dimension of
+    1, which a DMA cannot slice."""
+    pool = _pool(k_pages)
+    kernel = False
+    if (backend != "xla" and not isinstance(pool, QuantizedKVPages)
+            and pool.shape[-1] % 128 == 0):
+        group = _write_group(pool.dtype)
+        kernel = pool.shape[-2] % group == 0 and (chunk == 1
+                                                  or chunk % group == 0)
+    if platform == "tpu":
+        return WRITE_KERNEL if kernel else POOL_PLANE
+    return WRITE_KERNEL if kernel and backend == "pallas" else WRITE_SCATTER
 
 
 def paged_gather_attention(
     q: jnp.ndarray,          # [batch, chunk, nh, hd]
-    k_pages: jnp.ndarray,    # [num_pages, nkv, block_tokens, hd]
-    v_pages: jnp.ndarray,
+    k_pages,                 # [num_pages, nkv, block_tokens, hd] or LayerOf
+    v_pages,
     tables: jnp.ndarray,     # [batch, W] int32
     q_positions: jnp.ndarray,  # [batch, chunk]
     slopes: Optional[jnp.ndarray] = None,
@@ -115,17 +359,19 @@ def paged_gather_attention(
     TPU path is the Pallas kernel.  Quantized pools gather the NARROW
     leaves through the table first, then dequantize the gathered view to
     f32 — the same per-element ``convert * scale (+ zero)`` the kernel
-    runs in-register, so the two paths stay bit-exact."""
-    num_pages, nkv, bt, hd = k_pages.shape
+    runs in-register, so the two paths stay bit-exact.  The gather reads
+    ``(layer, page)`` of the stacked pool: only the table's pages move."""
+    K, V, li = _stacked(k_pages, v_pages)
+    _, num_pages, nkv, bt, hd = K.shape
     safe = jnp.clip(tables, 0, num_pages - 1)
-    gather = lambda p: jnp.take(p, safe, axis=0)  # [b, W, nkv, bt, ·]
+    gather = lambda p: p[li, safe]                # [b, W, nkv, bt, ·]
     b, W = safe.shape
-    if isinstance(k_pages, QuantizedKVPages):
-        k_lin = jax.tree.map(gather, k_pages).dequantize(jnp.float32)
-        v_lin = jax.tree.map(gather, v_pages).dequantize(jnp.float32)
+    if isinstance(K, QuantizedKVPages):
+        k_lin = jax.tree.map(gather, K).dequantize(jnp.float32)
+        v_lin = jax.tree.map(gather, V).dequantize(jnp.float32)
     else:
-        k_lin = gather(k_pages)
-        v_lin = gather(v_pages)
+        k_lin = gather(K)
+        v_lin = gather(V)
     k_lin = k_lin.transpose(0, 2, 1, 3, 4).reshape(b, nkv, W * bt, hd)
     v_lin = v_lin.transpose(0, 2, 1, 3, 4).reshape(b, nkv, W * bt, hd)
     return attention(q, k_lin, v_lin, q_positions,
@@ -141,11 +387,13 @@ def paged_gather_attention(
 _RING_BYTES = 1 << 20
 
 
-def _paged_kernel(tab_ref, len_ref, q_ref, *refs, block_tokens: int,
-                  ring: int, use_alibi: bool, quantized: bool):
+def _paged_kernel(tab_ref, len_ref, layer_ref, q_ref, *refs,
+                  block_tokens: int, ring: int, use_alibi: bool,
+                  quantized: bool):
     """Grid (b,): one step walks ONE row's live pages, all kv heads at
-    once.  The pools stay in HBM; page ``tables[b, j]`` (``[nkv, bt,
-    hd]``, contiguous in the pool) is copied into slot ``j % ring`` of a
+    once.  The stacked pools stay in HBM; page ``tables[b, j]`` of layer
+    ``layer_ref[0]`` (``[nkv, bt, hd]``, contiguous in the pool) is copied
+    into slot ``j % ring`` of a
     VMEM ring while earlier pages fold into the online-softmax
     accumulators, which are loop carries.  The loop runs
     ``ceil(kv_len / bt)`` times (at most ``W``), so a row with no live
@@ -154,21 +402,20 @@ def _paged_kernel(tab_ref, len_ref, q_ref, *refs, block_tokens: int,
     position ``kv_len - 1``.
 
     tab_ref (SMEM int32 [b, W]): the block tables; len_ref (SMEM int32
-    [b]): per-row valid lengths AFTER the current token's insert.  With
+    [b]): per-row valid lengths AFTER the current token's insert;
+    layer_ref (SMEM int32 [1]): the layer of the stack.  With
     ``quantized`` the pools are int8 and each is followed by its f32
-    scale sidecar as ``[num_pages, nkv, bt]``, copied page for page
-    beside it: the dequant happens in-register right after the narrow
-    DMA — HBM traffic stays 1 byte + 4/hd per element."""
+    scale sidecar ``[L, N, nkv, bt]``, copied page for page beside it:
+    the dequant happens in-register right after the narrow DMA — HBM
+    traffic stays 1 byte + 4/hd per element."""
     if quantized:
         (k_hbm, ks_hbm, v_hbm, vs_hbm, slopes_ref, o_ref,
          k_buf, ks_buf, v_buf, vs_buf, sems) = refs
-        streams = ((k_hbm, k_buf), (ks_hbm, ks_buf),
-                   (v_hbm, v_buf), (vs_hbm, vs_buf))
     else:
         k_hbm, v_hbm, slopes_ref, o_ref, k_buf, v_buf, sems = refs
-        streams = ((k_hbm, k_buf), (v_hbm, v_buf))
     b = pl.program_id(0)
-    num_pages, W = k_hbm.shape[0], tab_ref.shape[1]
+    layer = layer_ref[0]
+    num_pages, W = k_hbm.shape[1], tab_ref.shape[1]
     _, nkv, rows, hd = q_ref.shape
     kv_len = len_ref[b]
     bt = block_tokens
@@ -180,9 +427,13 @@ def _paged_kernel(tab_ref, len_ref, q_ref, *refs, block_tokens: int,
         # sentinel entries clamp in-range: the garbage is masked below
         page = jnp.minimum(tab_ref[b, j], num_pages - 1)
         slot = j % ring
-        return [pltpu.make_async_copy(hbm.at[page], buf.at[slot],
-                                      sems.at[slot, i])
-                for i, (hbm, buf) in enumerate(streams)]
+        streams = [(k_hbm.at[layer, page], k_buf),
+                   (v_hbm.at[layer, page], v_buf)]
+        if quantized:
+            streams += [(ks_hbm.at[layer, page], ks_buf),
+                        (vs_hbm.at[layer, page], vs_buf)]
+        return [pltpu.make_async_copy(src, buf.at[slot], sems.at[slot, i])
+                for i, (src, buf) in enumerate(streams)]
 
     for j in range(ring - 1):
         @pl.when(j < n_live)
@@ -240,8 +491,12 @@ def _paged_kernel(tab_ref, len_ref, q_ref, *refs, block_tokens: int,
 @functools.partial(jax.jit,
                    static_argnames=("block_tokens", "use_alibi",
                                     "interpret"))
-def _paged_call(q_g, k_pages, v_pages, tables, kv_lens, slopes, *,
+def _paged_call(q_g, k_pages, v_pages, layer, tables, kv_lens, slopes, *,
                 block_tokens, use_alibi, interpret):
+    """The Pallas call.  ``k_pages`` / ``v_pages`` are the STACKED pools
+    ``[L, N, nkv, bt, hd]`` and ``layer`` [1] int32 picks the layer: the
+    pools are ``pl.ANY`` operands, so nothing of them moves but the pages
+    the kernel copies."""
     b, nkv, rows, hd = q_g.shape
     quantized = isinstance(k_pages, QuantizedKVPages)
     bt = block_tokens
@@ -250,31 +505,32 @@ def _paged_call(q_g, k_pages, v_pages, tables, kv_lens, slopes, *,
     ring = max(2, min(8, _RING_BYTES // page_bytes))
 
     row_spec = pl.BlockSpec((1, nkv, rows, hd),
-                            lambda bb, tab, lens: (bb, 0, 0, 0))
+                            lambda bb, tab, lens, lay: (bb, 0, 0, 0))
     slopes_spec = pl.BlockSpec((nkv, rows, 1),
-                               lambda bb, tab, lens: (0, 0, 0))
+                               lambda bb, tab, lens, lay: (0, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     page_buf = pltpu.VMEM((ring, nkv, bt, hd), k_data.dtype)
     if quantized:
         # Mosaic slices an HBM ref only where its minor dimension fills
         # the lanes, which the pool's [.., bt, 1] sidecar does not: the
-        # kernel takes it as [num_pages, nkv, bt]
+        # kernel takes it as [L, N, nkv, bt].  (On the chip a narrow pool
+        # comes as one layer's plane, route_pool: that is a plane's
+        # sidecar, as it always was, and never the stack's.)
         scale_buf = pltpu.VMEM((ring, nkv, bt), k_pages.scale.dtype)
         in_specs = [row_spec] + [pool_spec] * 4 + [slopes_spec]
-        operands = (tables, kv_lens, q_g,
-                    k_pages.data, k_pages.scale[..., 0],
+        operands = (q_g, k_pages.data, k_pages.scale[..., 0],
                     v_pages.data, v_pages.scale[..., 0], slopes)
         buffers = [page_buf, scale_buf, page_buf, scale_buf]
     else:
         in_specs = [row_spec, pool_spec, pool_spec, slopes_spec]
-        operands = (tables, kv_lens, q_g, k_pages, v_pages, slopes)
+        operands = (q_g, k_pages, v_pages, slopes)
         buffers = [page_buf, page_buf]
 
     return pl.pallas_call(
         functools.partial(_paged_kernel, block_tokens=bt, ring=ring,
                           use_alibi=use_alibi, quantized=quantized),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b,),
             in_specs=in_specs,
             out_specs=row_spec,
@@ -289,13 +545,13 @@ def _paged_call(q_g, k_pages, v_pages, tables, kv_lens, slopes, *,
             16 << 20,
             2 * ring * page_bytes + 16 * nkv * bt * hd + (4 << 20))),
         interpret=interpret,
-    )(*operands)
+    )(tables, kv_lens, layer, *operands)
 
 
 def paged_flash_attention(
     q: jnp.ndarray,          # [batch, 1, nh, hd] — decode chunk only
-    k_pages: jnp.ndarray,    # [num_pages, nkv, block_tokens, hd]
-    v_pages: jnp.ndarray,
+    k_pages,                 # [num_pages, nkv, block_tokens, hd] or LayerOf
+    v_pages,
     tables: jnp.ndarray,     # [batch, W] int32
     kv_lens: jnp.ndarray,    # [batch] int32 valid length incl. this token
     slopes: Optional[jnp.ndarray] = None,
@@ -315,18 +571,19 @@ def paged_flash_attention(
     if chunk != 1:
         raise ValueError(f"paged_flash_attention is decode-only (chunk=1), "
                          f"got chunk={chunk}")
-    if isinstance(k_pages, QuantizedKVPages) and k_pages.bits != 8:
+    K, V, li = _stacked(k_pages, v_pages)
+    if isinstance(K, QuantizedKVPages) and K.bits != 8:
         # int4's nibble lane-interleave is Mosaic-hostile (an unpack in
         # the lane dimension per element); int4 is the CAPACITY config
         # and always takes the gather path — a deliberate gate, see
         # docs/DESIGN.md §17.
         raise ValueError("the Pallas kernel streams bf16 or int8 pages; "
                          "int4 KV takes the XLA gather path")
-    num_pages, nkv, bt, _ = k_pages.shape
+    _, num_pages, nkv, bt, _ = K.shape
     if bt % 8:
         raise ValueError(f"block_tokens must be a multiple of 8 for the "
                          f"Pallas kernel, got {bt}")
-    if hd % 128 or (isinstance(k_pages, QuantizedKVPages) and bt % 128):
+    if hd % 128 or (isinstance(K, QuantizedKVPages) and bt % 128):
         # Mosaic copies a slice of an HBM ref only where the ref's minor
         # dimension fills the 128 lanes.  A narrower head, or a scale
         # sidecar of a narrower page, goes through the prefill kernel's
@@ -356,7 +613,7 @@ def paged_flash_attention(
     kv_lens = jnp.where(tables[:, 0] >= num_pages, 0,
                         kv_lens.astype(jnp.int32))
 
-    out = _paged_call(q_g, k_pages, v_pages, tables, kv_lens, slopes_g,
+    out = _paged_call(q_g, K, V, li.reshape(1), tables, kv_lens, slopes_g,
                       block_tokens=bt, use_alibi=slopes is not None,
                       interpret=interpret)
     return out[:, :, :g, :].reshape(b, 1, nh, hd)
@@ -366,7 +623,7 @@ def paged_flash_attention(
 # Pallas TPU prefill kernel (docs/DESIGN.md §19)
 
 
-def _paged_prefill_kernel(tab_ref, start_ref, q_ref, *refs,
+def _paged_prefill_kernel(tab_ref, start_ref, layer_ref, q_ref, *refs,
                           block_tokens: int, chunk: int, groups: int,
                           use_alibi: bool, quantized: bool):
     """Grid (b, nkv, W), page index innermost: each step folds one
@@ -383,7 +640,10 @@ def _paged_prefill_kernel(tab_ref, start_ref, q_ref, *refs,
     query see exactly its prefix plus its own earlier in-chunk keys.
 
     tab_ref (SMEM int32 [b, W]): block tables; start_ref (SMEM int32
-    [b]): per-row segment start offsets (position of chunk column 0)."""
+    [b]): per-row segment start offsets (position of chunk column 0);
+    layer_ref (SMEM int32 [1]): the layer of the stacked pool, read by
+    the page index map alone."""
+    del layer_ref
     if quantized:
         (k_ref, ks_ref, v_ref, vs_ref, slopes_ref,
          o_ref, o_acc, m_acc, l_acc) = refs
@@ -453,50 +713,52 @@ def _paged_prefill_kernel(tab_ref, start_ref, q_ref, *refs,
 @functools.partial(jax.jit,
                    static_argnames=("block_tokens", "chunk", "groups",
                                     "use_alibi", "interpret"))
-def _paged_prefill_call(q_g, k_pages, v_pages, tables, starts, slopes, *,
-                        block_tokens, chunk, groups, use_alibi,
+def _paged_prefill_call(q_g, k_pages, v_pages, layer, tables, starts,
+                        slopes, *, block_tokens, chunk, groups, use_alibi,
                         interpret):
+    """The Pallas call.  ``k_pages`` / ``v_pages`` are the STACKED pools
+    ``[L, N, nkv, bt, hd]`` and ``layer`` [1] int32 picks the layer: the
+    page index map returns ``(layer, page, head, 0, 0)``, so the pipeline
+    copies the table's pages out of the stack and nothing else."""
     b, nkv, rows, hd = q_g.shape
     quantized = isinstance(k_pages, QuantizedKVPages)
-    num_pages = k_pages.shape[0]
+    num_pages = k_pages.shape[1]
     W = tables.shape[1]
     bt = block_tokens
 
-    def page_map(bb, h, j, tab, starts_):
+    def page_map(bb, h, j, tab, starts_, lay):
         # clamp to the segment's live frontier (start + chunk tokens):
         # beyond it the index repeats (no DMA, pl.when skips compute);
         # sentinel entries clamp in-range
         live = (starts_[bb] + chunk + bt - 1) // bt
         jj = jnp.minimum(j, jnp.maximum(live - 1, 0))
         page = jnp.minimum(tab[bb, jj], num_pages - 1)
-        return (page, h, 0, 0)
+        return (lay[0], page, h, 0, 0)
 
     q_spec = pl.BlockSpec((1, 1, rows, hd),
-                          lambda bb, h, j, tab, starts_: (bb, h, 0, 0))
+                          lambda bb, h, j, tab, starts_, lay: (bb, h, 0, 0))
     slopes_spec = pl.BlockSpec((1, 1, rows),
-                               lambda bb, h, j, tab, starts_: (h, 0, 0))
-    page_spec = pl.BlockSpec((1, 1, bt, hd), page_map)
+                               lambda bb, h, j, tab, starts_, lay: (h, 0, 0))
+    page_spec = pl.BlockSpec((None, 1, 1, bt, hd), page_map)
     if quantized:
-        scale_spec = pl.BlockSpec((1, 1, bt, 1), page_map)
+        scale_spec = pl.BlockSpec((None, 1, 1, bt, 1), page_map)
         in_specs = [q_spec, page_spec, scale_spec, page_spec,
                     scale_spec, slopes_spec]
-        operands = (tables, starts, q_g, k_pages.data, k_pages.scale,
+        operands = (q_g, k_pages.data, k_pages.scale,
                     v_pages.data, v_pages.scale, slopes)
     else:
         in_specs = [q_spec, page_spec, page_spec, slopes_spec]
-        operands = (tables, starts, q_g, k_pages, v_pages, slopes)
+        operands = (q_g, k_pages, v_pages, slopes)
 
     return pl.pallas_call(
         functools.partial(_paged_prefill_kernel, block_tokens=bt,
                           chunk=chunk, groups=groups,
                           use_alibi=use_alibi, quantized=quantized),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b, nkv, W),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, rows, hd),
-                                   lambda bb, h, j, tab, starts_:
-                                   (bb, h, 0, 0)),
+            out_specs=q_spec,
             scratch_shapes=[
                 pltpu.VMEM((rows, hd), jnp.float32),
                 pltpu.VMEM((rows, 128), jnp.float32),
@@ -505,7 +767,7 @@ def _paged_prefill_call(q_g, k_pages, v_pages, tables, starts, slopes, *,
         ),
         out_shape=jax.ShapeDtypeStruct((b, nkv, rows, hd), q_g.dtype),
         interpret=interpret,
-    )(*operands)
+    )(tables, starts, layer, *operands)
 
 
 # one kernel invocation's query rows = chunk * group; past this the
@@ -516,8 +778,8 @@ PREFILL_KERNEL_MAX_ROWS = 512
 
 def paged_prefill_attention(
     q: jnp.ndarray,          # [batch, chunk, nh, hd], chunk >= 1
-    k_pages: jnp.ndarray,    # [num_pages, nkv, block_tokens, hd]
-    v_pages: jnp.ndarray,
+    k_pages,                 # [num_pages, nkv, block_tokens, hd] or LayerOf
+    v_pages,
     tables: jnp.ndarray,     # [batch, W] int32
     q_positions: jnp.ndarray,  # [batch, chunk]; CONTIGUOUS per row
     slopes: Optional[jnp.ndarray] = None,
@@ -537,10 +799,11 @@ def paged_prefill_attention(
     bf16 or int8 pages, ``block_tokens % 8 == 0``; int4 takes the
     gather path."""
     b, chunk, nh, hd = q.shape
-    if isinstance(k_pages, QuantizedKVPages) and k_pages.bits != 8:
+    K, V, li = _stacked(k_pages, v_pages)
+    if isinstance(K, QuantizedKVPages) and K.bits != 8:
         raise ValueError("the Pallas kernel streams bf16 or int8 pages; "
                          "int4 KV takes the XLA gather path")
-    num_pages, nkv, bt, _ = k_pages.shape
+    _, num_pages, nkv, bt, _ = K.shape
     if bt % 8:
         raise ValueError(f"block_tokens must be a multiple of 8 for the "
                          f"Pallas kernel, got {bt}")
@@ -571,7 +834,7 @@ def paged_prefill_attention(
                            ((0, 0), (0, 0), (0, rows - rows_real)))
 
     out = _paged_prefill_call(
-        q_g, k_pages, v_pages, tables.astype(jnp.int32),
+        q_g, K, V, li.reshape(1), tables.astype(jnp.int32),
         q_positions[:, 0].astype(jnp.int32), slopes_g,
         block_tokens=bt, chunk=chunk, groups=g,
         use_alibi=slopes is not None, interpret=interpret)
@@ -597,20 +860,36 @@ class AttnPathRecord:
     passes it down the seam, and serves :meth:`snapshot` under
     ``/stats["attention_paths"]``: ``{program: {"chunk=N": path}}`` with
     ``path`` one of ``pallas_decode`` / ``pallas_prefill`` /
-    ``gather: <reason>``."""
+    ``gather: <reason>``.
+
+    How the call reached the pool (:func:`route_pool`) is recorded
+    beside the path and served under ``/stats["pool_addressing"]`` in the
+    same shape: ``kernel write`` and ``scatter write`` address ``(layer,
+    page)`` in the carried pool and copy nothing of it; ``plane`` slices
+    the layer's plane out and puts it back, so a program that fell back
+    to planes shows there."""
 
     def __init__(self):
         self._paths: dict = {}
+        self._addressing: dict = {}
 
-    def note(self, program: str, chunk: int, path: str, why: str) -> None:
+    def note(self, program: str, chunk: int, path: str, why: str,
+             pool: str) -> None:
         entry = path if not why else f"{path}: {why}"
         self._paths.setdefault(program, {})[f"chunk={chunk}"] = entry
+        self._addressing.setdefault(program, {})[f"chunk={chunk}"] = pool
 
-    def snapshot(self) -> dict:
+    @staticmethod
+    def _copy(table: dict) -> dict:
         # tracing runs on the scheduler thread, /stats on an HTTP
         # thread: list() and dict() each copy in one step under the GIL
-        return {prog: dict(chunks)
-                for prog, chunks in list(self._paths.items())}
+        return {prog: dict(chunks) for prog, chunks in list(table.items())}
+
+    def snapshot(self) -> dict:
+        return self._copy(self._paths)
+
+    def addressing(self) -> dict:
+        return self._copy(self._addressing)
 
 
 def route_paged_attention(backend: str, platform: str, k_pages,
@@ -633,7 +912,8 @@ def route_paged_attention(backend: str, platform: str, k_pages,
         return PATH_GATHER, "backend=xla"
     if backend == "auto" and platform != "tpu":
         return PATH_GATHER, f"backend=auto on platform={platform}"
-    bt = k_pages.shape[2]
+    k_pages = _pool(k_pages)
+    bt = k_pages.shape[-2]
     why = ""
     if isinstance(k_pages, QuantizedKVPages) and k_pages.bits != 8:
         why = f"int{k_pages.bits} pages have no kernel"
@@ -670,6 +950,13 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
 
     ``backend``: "auto" (Pallas on TPU, XLA gather elsewhere), "xla", or
     "pallas" — the rule is :func:`route_paged_attention`.
+
+    The hook is made for a page pool, and a pool is addressed in place:
+    ``impl.stacked_cache`` tells the decoder's layer scan to hand it the
+    carried stacks and the layer's index (``LayerOf``) instead of a
+    layer's plane, and it hands the stacks back the same way.  It takes
+    nothing else, and where :func:`route_pool` says ``plane`` it is the
+    hook that slices the layer out and puts it back.
     """
     if backend not in ("auto", "xla", "pallas"):
         raise ValueError(f"unknown paged attention backend {backend!r}; "
@@ -681,17 +968,24 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
         bound["program"] = program
 
     def impl(q, k, v, k_pages, v_pages, positions, cache_start, slopes):
+        assert isinstance(k_pages, LayerOf), "the pool comes stacked"
         tables = bound["tables"]
         chunk = q.shape[1]
         path, why = route_paged_attention(
             backend, jax.default_backend(), k_pages, chunk,
             q.shape[2] // k.shape[2])
+        pool = route_pool(backend, jax.default_backend(), k_pages, chunk)
         if record is not None:
-            record.note(bound["program"], chunk, path, why)
+            record.note(bound["program"], chunk, path, why, pool)
+        whole_k, whole_v = k_pages, v_pages
         # metadata only: a profiler capture keeps the scope with each op
         with jax.named_scope("paged_attention"):
-            k_pages, v_pages = write_paged_kv(k_pages, v_pages, k, v,
-                                              tables, positions)
+            if pool == POOL_PLANE:
+                k_pages, v_pages = whole_k.sliced(), whole_v.sliced()
+            k_pages, v_pages = write_paged_kv(
+                k_pages, v_pages, k, v, tables, positions,
+                form=WRITE_SCATTER if pool == POOL_PLANE else pool,
+                interpret=interpret)
             if path == PATH_DECODE_KERNEL:
                 kv_lens = positions[:, -1] + 1
                 out = paged_flash_attention(q, k_pages, v_pages, tables,
@@ -704,6 +998,10 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
             else:
                 out = paged_gather_attention(q, k_pages, v_pages, tables,
                                              positions, slopes)
+            if pool == POOL_PLANE:
+                k_pages = LayerOf(whole_k.updated(k_pages), whole_k.layer)
+                v_pages = LayerOf(whole_v.updated(v_pages), whole_v.layer)
         return out, k_pages, v_pages
 
+    impl.stacked_cache = True
     return impl, bind

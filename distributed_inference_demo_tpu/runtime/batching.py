@@ -2094,6 +2094,9 @@ class ContinuousBatchingEngine:
         # kernel routing made visible: per compiled program, which
         # attention path each of its chunk shapes was traced onto
         out["attention_paths"] = self.attn_paths.snapshot()
+        # and how the pool reached it: "stacked" (addressed in place in
+        # the scan's carry) or "plane" (a layer's plane was copied out)
+        out["pool_addressing"] = self.attn_paths.addressing()
         # completed is the MONOTONIC count; the reservoirs are bounded
         # (the last 512 samples feed the percentiles).  deque.__copy__ is
         # atomic under the GIL — plain iteration would race the

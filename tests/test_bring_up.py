@@ -23,12 +23,14 @@ Everything here runs on the CPU and is cheap.  What it pins:
   (skipped where libtpu offers none).
 """
 
+import math
 import json
 import os
 import subprocess
 import sys
 import time
 from pathlib import Path
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -407,6 +409,7 @@ MOSAIC_CASES = {
     "cell-bloom7b1": (BLOOM7B1, "bf16", 128, 8, 16, 46),
     "cell-tp4-shard": ((7, 1, 128), "bf16", 128, 64, 32, 2048),
     "qwen-int8-p128": (QWEN, "int8", 128, 32, 32, 416),
+    "bloom560m-bf16-p128": (BLOOM560M, "bf16", 128, 8, 16, 512),
 }
 
 
@@ -432,6 +435,71 @@ def test_paged_kernels_get_through_mosaic(v5e, case):
             q, pk, pv, t, p, *s),
         S((2, chunk, nh, hd), jnp.bfloat16), pages, pages,
         S((2, W), jnp.int32), S((2, chunk), jnp.int32), *slopes)
+
+
+@pytest.mark.parametrize("case,chunk,how", [
+    ("cell-qwen2.5-7b", 1, "kernel write"),
+    ("cell-qwen2.5-7b", 64, "kernel write"),
+    ("cell-bloom7b1", 1, "kernel write"),
+    ("cell-bloom7b1", 64, "kernel write"),
+    ("cell-tp4-shard", 1, "kernel write"),
+    ("cell-tp4-shard", 64, "kernel write"),
+    # what the Pallas write does not take: a chunk that is not whole
+    # tile groups, int8 pages (their sidecars), heads of 64
+    ("cell-qwen2.5-7b", 24, "plane"),
+    ("qwen-int8-p128", 1, "plane"),
+    ("qwen-int8-p128", 64, "plane"),
+    ("bloom560m-bf16-p128", 1, "plane"),
+    ("bloom560m-bf16-p128", 64, "plane")])
+def test_pool_is_addressed_in_place_on_the_chip(v5e, case, chunk, how):
+    """A layer call of the paged hook (KV write, then the kernel) over
+    the STACKED pool, the layer picked by index, compiled for the chip.
+    Where the write is the Pallas one (the benchmark's cells), the
+    optimized program makes nothing as large as one layer's plane: no
+    plane and no pool is copied.  Everywhere else the hook goes through
+    the layer's plane, and then nothing of the POOL's shape is made but
+    the plane put back in place: the stacked scatter, and a custom call
+    on a pool of narrow heads (which lies in HBM in a layout that pads
+    less than the one a custom call takes), each cost copies of the whole
+    pool, a layer call."""
+    from distributed_inference_demo_tpu.ops.stacked import LayerOf
+    sys.path.insert(0, str(REPO / "tools"))
+    from aot_mixed_step import large_ops
+    (nh, nkv, hd), kind, bt, b, W, N = MOSAIC_CASES[case]
+    L = 8
+    S = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=v5e)  # noqa: E731
+    record = pa.AttnPathRecord()
+    impl, bind = pa.make_paged_attn_impl(bt, record=record)
+    slopes = (S((nh,), jnp.float32),) if nh == nkv else ()
+
+    def layer_call(q, k, v, pk, pv, tables, pos, li, *slopes):
+        bind(tables, "layer")
+        out, pk, pv = impl(q, k, v, LayerOf(pk, li), LayerOf(pv, li), pos,
+                           jnp.int32(0), *(slopes or (None,)))
+        return out, pk.stack, pv.stack
+
+    pool = jax.tree.map(
+        lambda a: S(a.shape, a.dtype), jax.eval_shape(
+            lambda: alloc_kv_pages((L, N, nkv, bt, hd), kind, jnp.bfloat16)))
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        hlo = jax.jit(layer_call, donate_argnums=(3, 4)).lower(
+            S((b, chunk, nh, hd), jnp.bfloat16),
+            S((b, chunk, nkv, hd), jnp.bfloat16),
+            S((b, chunk, nkv, hd), jnp.bfloat16), pool, pool,
+            S((b, W), jnp.int32), S((b, chunk), jnp.int32),
+            S((), jnp.int32), *slopes).compile().as_text()
+    assert record.addressing() == {"layer": {f"chunk={chunk}": how}}
+    assert ("kv_page_write" in hlo) == (how == "kernel write")
+    leaves = jax.tree.leaves(pool)
+    plane = min(a.dtype.itemsize * math.prod(a.shape[1:]) for a in leaves)
+    large = large_ops(hlo, plane)
+    if how == "kernel write":
+        assert not large, large
+    else:
+        whole = {str(list(a.shape)) for a in leaves}
+        made = [op for op in large if op[2].lstrip("bfsu0123456789") in whole]
+        assert made and all("dynamic-update-slice" in op[0] for op in made), (
+            made)
 
 
 def test_flash_kernel_with_alibi_gets_through_mosaic(v5e):

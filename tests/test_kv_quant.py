@@ -201,6 +201,60 @@ def test_write_quantizes_at_the_page_boundary(kv_dtype):
                                   np.asarray(pv2.scale))
 
 
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("kv_dtype", ["int8", "int4"])
+def test_stacked_write_into_narrow_pages_is_the_planes_write(kv_dtype,
+                                                             layer):
+    """The pool addressed in place: a write at ``(layer, page, :, off)``
+    of a stacked narrow pool quantizes once and lands, data and sidecars
+    on the same index, exactly what the per-plane write lands in that
+    layer's plane; every other layer's leaves are bit-identical; a
+    sentinel row and a position past the table drop."""
+    from distributed_inference_demo_tpu.ops.stacked import LayerOf
+    rng = np.random.default_rng(5)
+    L, N, nkv, hd, bt, W, b, chunk = 3, 7, 2, 16, 8, 3, 3, 3
+    full = lambda: jnp.asarray(  # noqa: E731
+        rng.standard_normal((L, N, nkv, bt, hd)), jnp.float32)
+    K = quantize_kv_pages(full(), _bits(kv_dtype))
+    V = quantize_kv_pages(full(), _bits(kv_dtype))
+    tables = jnp.asarray([[0, 1, 2], [3, 4, 5], [N + 7] * 3], jnp.int32)
+    k_new = jnp.asarray(rng.standard_normal((b, chunk, nkv, hd)),
+                        jnp.float32)
+    v_new = jnp.asarray(rng.standard_normal((b, chunk, nkv, hd)),
+                        jnp.float32)
+    # row 0 crosses a page boundary, row 1 runs off its table, row 2 is
+    # a freed slot
+    pos = (jnp.asarray([6, W * bt - 1, 2], jnp.int32)[:, None]
+           + jnp.arange(chunk, dtype=jnp.int32))
+    li = jnp.int32(layer)
+    k2, v2 = write_paged_kv(LayerOf(K, li), LayerOf(V, li), k_new, v_new,
+                            tables, pos)
+    assert isinstance(k2, LayerOf) and isinstance(v2, LayerOf)
+    plane = lambda t, l: jax.tree.map(lambda a: a[l], t)  # noqa: E731
+    want_k, want_v = write_paged_kv(plane(K, layer), plane(V, layer),
+                                    k_new, v_new, tables, pos)
+    for got, want, before in ((k2.stack, want_k, K), (v2.stack, want_v, V)):
+        assert isinstance(got, QuantizedKVPages) and got.bits == before.bits
+        for l in range(L):
+            ref = want if l == layer else plane(before, l)
+            for g, w in zip(jax.tree.leaves(plane(got, l)),
+                            jax.tree.leaves(ref), strict=True):
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    # the bytes are a direct quantize of the chunk: row 0's second token
+    # is position 7, page 0 offset 7; its third is page 1 offset 0
+    qk = quantize_kv_pages(k_new, _bits(kv_dtype))
+    np.testing.assert_array_equal(np.asarray(k2.stack.data)[layer, 0, :, 7],
+                                  np.asarray(qk.data)[0, 1])
+    np.testing.assert_array_equal(np.asarray(k2.stack.scale)[layer, 1, :, 0],
+                                  np.asarray(qk.scale)[0, 2])
+    # page 6 is in nobody's table, pages 3-4 are row 1's and its one
+    # in-table token went to page 5
+    for untouched in (3, 4, 6):
+        np.testing.assert_array_equal(
+            np.asarray(k2.stack.data)[layer, untouched],
+            np.asarray(K.data)[layer, untouched])
+
+
 @pytest.mark.quick
 def test_byte_owners_and_resolver(monkeypatch):
     """kv_token_head_bytes is the ONE owner of page-width math: narrow

@@ -10,6 +10,7 @@ to 8x, and ALiBi.  The Pallas kernel runs in interpret mode on CPU
 against the same oracle the XLA fallback uses.
 """
 
+import functools
 import sys
 from pathlib import Path
 
@@ -23,7 +24,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 from distributed_inference_demo_tpu.ops.attention import attention
 from distributed_inference_demo_tpu.ops.paged_attention import (
     make_paged_attn_impl, paged_flash_attention, paged_gather_attention,
-    paged_prefill_attention, write_paged_kv)
+    paged_prefill_attention, route_pool, write_paged_kv)
 
 
 def _random_paged(rng, b, nkv, hd, bt, W, lens, extra_pages=3,
@@ -165,9 +166,10 @@ def test_decode_kernel_grid_has_no_table_width():
             lambda *a: _paged_call(*a, block_tokens=bt, use_alibi=False,
                                    interpret=False))(
             S((b, nkv, rows, hd), jnp.float32),
-            S((N, nkv, bt, hd), jnp.float32),
-            S((N, nkv, bt, hd), jnp.float32), S((b, W), jnp.int32),
-            S((b,), jnp.int32), S((nkv, rows, 1), jnp.float32))
+            S((2, N, nkv, bt, hd), jnp.float32),
+            S((2, N, nkv, bt, hd), jnp.float32), S((1,), jnp.int32),
+            S((b, W), jnp.int32), S((b,), jnp.int32),
+            S((nkv, rows, 1), jnp.float32))
         calls = []
 
         def walk(jp):
@@ -293,10 +295,319 @@ def test_impl_binds_tables_and_matches_manual_sequence():
     @jax.jit
     def step(q, k, v, pk, pv, tables, pos):
         bind(tables, "step")
-        return impl(q, k, v, pk, pv, pos, jnp.int32(0), None)
+        li = jnp.int32(0)
+        return impl(q, k, v, LayerOf(pk[None], li), LayerOf(pv[None], li),
+                    pos, jnp.int32(0), None)
 
     out, pk2, pv2 = step(q, k, v, pk, pv, tables, pos)
     epk, epv = write_paged_kv(pk, pv, k, v, tables, pos)
     eout = paged_gather_attention(q, epk, epv, tables, pos, None)
-    np.testing.assert_array_equal(np.asarray(pk2), np.asarray(epk))
+    np.testing.assert_array_equal(np.asarray(pk2.stack[0]), np.asarray(epk))
     np.testing.assert_array_equal(np.asarray(out), np.asarray(eout))
+
+
+# ---------------------------------------------------------------------------
+# the pool addressed in place: (stack, layer) against the layer's plane
+
+
+from distributed_inference_demo_tpu.ops.stacked import LayerOf  # noqa: E402
+
+LAYERS = 3
+# GQA with heads of 64 (the decode path through the prefill kernel) and
+# MHA with heads of 128 (the decode kernel's page loop; int8 pages take
+# it at 128-token pages only)
+STACK_SHAPES = {
+    "gqa-hd64": dict(nh=4, nkv=2, hd=64, bt=8, W=3, lens=[5, 8, 17]),
+    "mha-hd128": dict(nh=2, nkv=2, hd=128, bt=128, W=2, lens=[130, 7]),
+}
+
+
+def _quantized(pages, mode):
+    if mode == "bf16":
+        return pages.astype(jnp.bfloat16)
+    from distributed_inference_demo_tpu.ops.quant import quantize_kv_pages
+    return quantize_kv_pages(pages, {"int8": 8, "int4": 4}[mode])
+
+
+def _stacked_case(shape, mode, alibi, seed):
+    """``(stacks, tables, N, q, lens, slopes)``: ``LAYERS`` unlike
+    layers of pages behind one set of tables."""
+    c = STACK_SHAPES[shape]
+    rng = np.random.default_rng(seed)
+    b = len(c["lens"])
+    layers = [_random_paged(rng, b, c["nkv"], c["hd"], c["bt"], c["W"],
+                            c["lens"], append_room=1)
+              for _ in range(LAYERS)]
+    tables, N = layers[0][2], layers[0][3]
+    K = _quantized(jnp.stack([l[0] for l in layers]), mode)
+    V = _quantized(jnp.stack([l[1] for l in layers]), mode)
+    q = jnp.asarray(rng.standard_normal((b, 1, c["nh"], c["hd"])),
+                    jnp.float32)
+    slopes = None
+    if alibi:
+        from distributed_inference_demo_tpu.ops.attention import (
+            alibi_slopes)
+        slopes = alibi_slopes(c["nh"])
+    return (K, V), tables, N, q, c, slopes
+
+
+def _plane(stack, layer):
+    return jax.tree.map(lambda a: a[layer], stack)
+
+
+def _assert_trees_equal(got, want):
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                    strict=True):
+        np.testing.assert_array_equal(np.asarray(g.astype(jnp.float32)),
+                                      np.asarray(w.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("layer", range(LAYERS))
+@pytest.mark.parametrize("form,shape,chunk", [
+    ("scatter write", "gqa-hd64", 5), ("scatter write", "mha-hd128", 5),
+    # the Pallas write moves whole sublane tiles of lane-filling heads,
+    # a token or whole tiles a row: route_pool keeps every other shape on
+    # the scatter, on the chip over the layer's plane
+    ("kernel write", "mha-hd128", 1), ("kernel write", "mha-hd128", 16),
+    ("kernel write", "mha-hd128", 5)])
+def test_stacked_write_is_the_planes_write_and_touches_no_other_layer(
+        form, shape, chunk, layer):
+    """``write_paged_kv`` at ``(layer, page, :, off)`` of the stacked
+    pool: that layer's pages are what the per-plane write gives, every
+    other layer's are bit-identical, and a sentinel row and positions
+    past the table drop.  Both forms of the write (the Pallas one
+    interpreted; its rows here are of different tables, so any chunk is
+    sound)."""
+    (K, V), tables, N, _, c, _ = _stacked_case(shape, "bf16", False, 11)
+    rng = np.random.default_rng(12)
+    b, bt, W = len(c["lens"]), c["bt"], c["W"]
+    k_new = jnp.asarray(rng.standard_normal((b, chunk, c["nkv"], c["hd"])),
+                        jnp.float32)
+    v_new = -k_new
+    # row 0 appends in its pages, row 1 runs off the end of the table,
+    # the last row is a freed slot (all sentinel)
+    tables = tables.at[-1].set(N + 7)
+    starts = jnp.asarray([c["lens"][0] - 3, W * bt - 2]
+                         + [3] * (b - 2), jnp.int32)[:b]
+    pos = starts[:, None] + jnp.arange(chunk, dtype=jnp.int32)
+    kernel = shape == "mha-hd128" and chunk in (1, 16)
+    assert route_pool("pallas", "cpu", K, chunk) == (
+        "kernel write" if kernel else "scatter write")
+    assert route_pool("auto", "tpu", LayerOf(K, 0), chunk) == (
+        "kernel write" if kernel else "plane")
+    assert route_pool("auto", "cpu", K, chunk) == "scatter write"
+    assert route_pool("xla", "cpu", K, chunk) == "scatter write"
+    assert route_pool("xla", "tpu", K, chunk) == "plane"
+    li = jnp.int32(layer)
+    k2, v2 = jax.jit(functools.partial(
+        write_paged_kv, form=form, interpret=True))(
+            LayerOf(K, li), LayerOf(V, li), k_new, v_new, tables, pos)
+    assert isinstance(k2, LayerOf) and isinstance(v2, LayerOf)
+    want_k, want_v = write_paged_kv(_plane(K, layer), _plane(V, layer),
+                                    k_new, v_new, tables, pos)
+    for got, want, before in ((k2.stack, want_k, K), (v2.stack, want_v, V)):
+        for l in range(LAYERS):
+            _assert_trees_equal(_plane(got, l),
+                                want if l == layer else _plane(before, l))
+    # something was written, and the freed slot's row and the tail past
+    # the table changed nothing: only rows 0 and 1 can differ
+    changed = np.asarray(k2.stack[layer] != K[layer]).any(axis=(1, 2, 3))
+    tt = np.asarray(tables)
+    assert changed.any() and set(np.flatnonzero(changed)) <= set(
+        tt[:2].ravel().tolist())
+
+
+@pytest.mark.parametrize("layer", range(LAYERS))
+@pytest.mark.parametrize("shape", list(STACK_SHAPES))
+@pytest.mark.parametrize("alibi", [False, True])
+@pytest.mark.parametrize("mode", ["bf16", "int8", "int4"])
+def test_stacked_gather_is_the_planes_gather(mode, alibi, shape, layer):
+    (K, V), tables, _, q, c, slopes = _stacked_case(shape, mode, alibi, 21)
+    qpos = jnp.asarray([l - 1 for l in c["lens"]], jnp.int32)[:, None]
+    li = jnp.int32(layer)
+    got = paged_gather_attention(q, LayerOf(K, li), LayerOf(V, li), tables,
+                                 qpos, slopes)
+    want = paged_gather_attention(q, _plane(K, layer), _plane(V, layer),
+                                  tables, qpos, slopes)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("layer", range(LAYERS))
+@pytest.mark.parametrize("shape", list(STACK_SHAPES))
+@pytest.mark.parametrize("alibi", [False, True])
+@pytest.mark.parametrize("mode", ["bf16", "int8"])
+def test_stacked_decode_kernel_is_the_planes_kernel(mode, alibi, shape,
+                                                    layer):
+    """``paged_flash_attention`` (interpreted) reading ``(layer, page)``
+    of the stacked pool, the int8 sidecar gathered for the table's
+    pages: bit for bit the kernel over that layer's plane."""
+    (K, V), tables, _, q, c, slopes = _stacked_case(shape, mode, alibi, 31)
+    kv_lens = jnp.asarray(c["lens"], jnp.int32)
+    li = jnp.int32(layer)
+    got = paged_flash_attention(q, LayerOf(K, li), LayerOf(V, li), tables,
+                                kv_lens, slopes, interpret=True)
+    want = paged_flash_attention(q, _plane(K, layer), _plane(V, layer),
+                                 tables, kv_lens, slopes, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    ref = paged_gather_attention(q, LayerOf(K, li), LayerOf(V, li), tables,
+                                 (kv_lens - 1)[:, None], slopes)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("layer", range(LAYERS))
+@pytest.mark.parametrize("shape", list(STACK_SHAPES))
+@pytest.mark.parametrize("alibi", [False, True])
+@pytest.mark.parametrize("mode", ["bf16", "int8"])
+def test_stacked_prefill_kernel_is_the_planes_kernel(mode, alibi, shape,
+                                                     layer):
+    (K, V), tables, _, _, c, slopes = _stacked_case(shape, mode, alibi, 41)
+    rng = np.random.default_rng(42)
+    b, chunk = len(c["lens"]), 4
+    q = jnp.asarray(rng.standard_normal((b, chunk, c["nh"], c["hd"])),
+                    jnp.float32)
+    qpos = (jnp.asarray(c["lens"], jnp.int32)[:, None] - chunk
+            + jnp.arange(chunk, dtype=jnp.int32))
+    li = jnp.int32(layer)
+    got = paged_prefill_attention(q, LayerOf(K, li), LayerOf(V, li), tables,
+                                  qpos, slopes, interpret=True)
+    want = paged_prefill_attention(q, _plane(K, layer), _plane(V, layer),
+                                   tables, qpos, slopes, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_hook_made_for_a_pool_takes_the_stack_and_says_so():
+    """The paged hook asks the layer scan for the stacks
+    (``stacked_cache``), hands them back as ``LayerOf``, and the record
+    says how the pool reached each call."""
+    from distributed_inference_demo_tpu.ops.paged_attention import (
+        AttnPathRecord)
+    (K, V), tables, _, q, c, _ = _stacked_case("gqa-hd64", "bf16", False, 51)
+    record = AttnPathRecord()
+    impl, bind = make_paged_attn_impl(c["bt"], backend="xla", record=record)
+    assert impl.stacked_cache
+    k = jnp.ones((len(c["lens"]), 1, c["nkv"], c["hd"]), jnp.float32)
+    pos = jnp.asarray(c["lens"], jnp.int32)[:, None]
+    bind(tables, "stack")
+    _, k2, _ = impl(q, k, k, LayerOf(K, jnp.int32(1)), LayerOf(V, jnp.int32(1)),
+                    pos, jnp.int32(0), None)
+    assert isinstance(k2, LayerOf)
+    k3, _ = write_paged_kv(_plane(K, 1), _plane(V, 1), k, k, tables, pos)
+    _assert_trees_equal(k2.stack[1], k3)
+    # a plane is not a pool: the hook takes the stack alone
+    with pytest.raises(AssertionError, match="stacked"):
+        impl(q, k, k, _plane(K, 1), _plane(V, 1), pos, jnp.int32(0), None)
+    assert record.addressing() == {"stack": {"chunk=1": "scatter write"}}
+    assert set(record.snapshot()) == {"stack"}
+
+
+@pytest.mark.parametrize("layer", range(LAYERS))
+@pytest.mark.parametrize("chunk", [1, 5])
+@pytest.mark.parametrize("shape,mode", [
+    ("gqa-hd64", "bf16"), ("mha-hd128", "int8"), ("gqa-hd64", "int4")])
+def test_hook_through_the_layers_plane_is_the_hook_in_place(shape, mode,
+                                                            chunk, layer):
+    """What the Pallas write does not cover reaches the pool, on the
+    chip, through the layer's plane (``route_pool``: narrow heads, int8
+    and int4 pages): the hook slices the plane out, writes and attends on
+    it, and puts it back.  Same output and same pool, leaf for leaf and
+    bit for bit, as the hook addressing the stack in place, and every
+    other layer untouched.  (The platform is what the route reads; the
+    XLA paths run anywhere.)"""
+    from unittest import mock
+    from distributed_inference_demo_tpu.ops.paged_attention import (
+        AttnPathRecord)
+    (K, V), tables, N, _, c, slopes = _stacked_case(shape, mode, True, 71)
+    rng = np.random.default_rng(72)
+    b = len(c["lens"])
+    q = jnp.asarray(rng.standard_normal((b, chunk, c["nh"], c["hd"])),
+                    jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, chunk, c["nkv"], c["hd"])),
+                    jnp.float32)
+    tables = tables.at[-1].set(N + 7)            # a freed slot
+    pos = (jnp.asarray(c["lens"], jnp.int32)[:, None] - chunk + 1
+           + jnp.arange(chunk, dtype=jnp.int32))
+    li = jnp.int32(layer)
+
+    def run(platform):
+        record = AttnPathRecord()
+        impl, bind = make_paged_attn_impl(c["bt"], backend="xla",
+                                          record=record)
+        bind(tables, "layer")
+        with mock.patch.object(jax, "default_backend", lambda: platform):
+            out, k2, v2 = impl(q, k, -k, LayerOf(K, li), LayerOf(V, li),
+                               pos, jnp.int32(0), slopes)
+        return out, k2, v2, record.addressing()["layer"][f"chunk={chunk}"]
+
+    out, k2, v2, how = run("tpu")
+    want, wk, wv, in_place = run("cpu")
+    assert (how, in_place) == ("plane", "scatter write")
+    assert isinstance(k2, LayerOf) and isinstance(v2, LayerOf)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    _assert_trees_equal(k2.stack, wk.stack)
+    _assert_trees_equal(v2.stack, wv.stack)
+    for l in range(LAYERS):
+        if l != layer:
+            _assert_trees_equal(_plane(k2.stack, l), _plane(K, l))
+    assert any(np.asarray(g != w).any() for g, w in zip(
+        jax.tree.leaves(_plane(k2.stack, layer)),
+        jax.tree.leaves(_plane(K, layer))))
+
+
+@pytest.mark.parametrize("chunk,write", [
+    (16, "kernel write"), (32, "kernel write"),
+    (24, "scatter write"), (8, "scatter write"), (40, "scatter write")])
+def test_rows_of_one_table_in_one_call_write_what_the_scatter_writes(
+        chunk, write):
+    """The mixed slab packs sequential chunks of ONE admission as rows of
+    one call: the same table, each row starting where the last ended, the
+    first on a page boundary.  The Pallas write reads a batch of tile
+    groups before it writes any back, so two rows must not share a group:
+    at a chunk of whole groups (16 tokens of bf16) none does and the
+    kernel writes bit for bit what the scatter writes; at any other chunk
+    the last group of one row is the first of the next, and the hook
+    keeps the scatter (``route_pool``; on the chip, over the layer's
+    plane).  Through the hook, as a program runs it, the kernel
+    interpreted."""
+    from distributed_inference_demo_tpu.ops.paged_attention import (
+        AttnPathRecord)
+    c = STACK_SHAPES["mha-hd128"]
+    rng = np.random.default_rng(61)
+    rows, N, bt = 3, 5, c["bt"]
+    K = jnp.asarray(rng.standard_normal((LAYERS, N, c["nkv"], bt, c["hd"])),
+                    jnp.bfloat16)
+    V = -K
+    table = jnp.asarray([3, 1, 4], jnp.int32)
+    tables = jnp.broadcast_to(table, (rows, 3))
+    # the admission starts on its second page (a prefix hit of one page)
+    pos = (bt + chunk * jnp.arange(rows, dtype=jnp.int32)[:, None]
+           + jnp.arange(chunk, dtype=jnp.int32))
+    k = jnp.asarray(rng.standard_normal((rows, chunk, c["nkv"], c["hd"])),
+                    jnp.float32)
+    q = jnp.zeros((rows, chunk, c["nh"], c["hd"]), jnp.float32)
+    record = AttnPathRecord()
+    impl, bind = make_paged_attn_impl(bt, backend="pallas", interpret=True,
+                                      record=record)
+    li = jnp.int32(1)
+
+    @jax.jit
+    def slab(K, V):
+        bind(tables, "slab")
+        _, k2, v2 = impl(q, k, -k, LayerOf(K, li), LayerOf(V, li), pos,
+                         jnp.int32(0), None)
+        return k2.stack, v2.stack
+
+    got_k, got_v = slab(K, V)
+    assert record.addressing() == {"slab": {f"chunk={chunk}": write}}
+    want_k, want_v = write_paged_kv(LayerOf(K, li), LayerOf(V, li), k, -k,
+                                    tables, pos)
+    _assert_trees_equal(got_k, want_k.stack)
+    _assert_trees_equal(got_v, want_v.stack)
+    # every token of every row is in its place: none was restored to what
+    # the page held before a neighbour's write-back
+    flat = np.asarray(pos).ravel()
+    np.testing.assert_array_equal(
+        np.asarray(got_k[1].astype(jnp.float32))[
+            np.asarray(table)[flat // bt], :, flat % bt],
+        np.asarray(k.astype(jnp.bfloat16).astype(jnp.float32)).reshape(
+            rows * chunk, c["nkv"], c["hd"]))

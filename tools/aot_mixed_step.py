@@ -1,7 +1,9 @@
 #!/usr/bin/env python3
 """Compile a model's ``mixed_step`` for a TPU v5e that is not there, and
 say what the compiler reports: device memory (arguments, temporaries),
-the attention path each chunk shape took, and the Pallas custom calls.
+the attention path each chunk shape took, how the pool reached it, the
+Pallas custom calls, and every op of the optimized HLO whose result is at
+least as large as one layer's plane of the page pool.
 
     python tools/aot_mixed_step.py --model olmoe-1b-7b-int8 \\
         --batch-slots 32 --prefill-chunk 256 --decode-block 4 \\
@@ -14,11 +16,28 @@ nothing model-sized is allocated.  It proves compilation and sizes a pool
 before any chip call (PERF.md section 4); times need the chip.  One
 compile a ``--kv-cache-blocks`` value; a refusal (out of memory) is
 printed, not raised.
+
+Reading the large ops ("no pool copy" without a chip).  The pool is
+addressed in place (``ops.stacked.LayerOf``): on the chip the KV write is
+the Pallas call ``kv_page_write`` (named under "pallas calls"; its result
+is a tuple aliased to the pool, and tuples, like the layer scan's
+``while``, are not listed), so a sound program lists NOTHING of the pool's
+or of a plane's shape, "pool" says ``kernel write`` for every chunk shape,
+and the temporaries stay far under the pool's size.  What must not be
+there: an op of a PLANE's shape ``[N, H, bt, D]`` (a layer sliced out of
+the pool: ``dynamic-slice`` / ``dynamic-update-slice`` fusions, once a
+layer call; "pool" then says ``plane``, which is what int8 / int4 pages,
+heads under 128 and a ``--prefill-chunk`` that is not a multiple of 16
+take: ``ops.paged_attention.route_pool``), and a ``copy`` of the pool's
+shape (the compiler giving a consumer another layout; it shows as
+temporaries of about the pool's size).  Ops as large that are not the
+pool's (a weight matrix widened, logits) are listed too, by shape.
 """
 
 from __future__ import annotations
 
 import argparse
+import math
 import re
 import sys
 from pathlib import Path
@@ -86,6 +105,39 @@ def compile_mixed_step(model: str, blocks: int, args, with_finals=True):
         eng.close()
 
 
+_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "s4": 1, "u4": 1, "bf16": 2,
+             "f16": 2, "s16": 2, "u16": 2, "f32": 4, "s32": 4, "u32": 4,
+             "f64": 8, "s64": 8, "u64": 8}
+# ops that name a buffer and make none
+_NO_BUFFER = ("parameter", "get-tuple-element", "bitcast", "tuple",
+              "while", "conditional", "call")
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.-]+) = (\w+)\[([\d,]*)\][^ ]* ([\w-]+)\(")
+
+
+def large_ops(hlo_text: str, min_bytes: int) -> list:
+    """``[(name, opcode, shape, bytes)]`` of the instructions of an
+    optimized HLO module whose array result is at least ``min_bytes``,
+    outside fused computations (what a fusion computes inside makes no
+    buffer), largest first."""
+    fused = set(re.findall(r"calls=%?([\w.-]+)", hlo_text))
+    out, skip = [], False
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.-]+) \(.*\{\s*$", line)
+        if head:
+            skip = head.group(1) in fused
+            continue
+        m = None if skip else _INSTRUCTION.match(line)
+        if not m or m.group(4) in _NO_BUFFER or m.group(2) not in _ITEMSIZE:
+            continue
+        name, dtype, dims, opcode = m.groups()
+        shape = [int(d) for d in dims.split(",") if d]
+        size = _ITEMSIZE[dtype] * math.prod(shape)
+        if size >= min_bytes:
+            out.append((name, opcode, f"{dtype}{shape}", size))
+    return sorted(out, key=lambda r: -r[3])
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--model", required=True)
@@ -97,6 +149,7 @@ def main(argv=None) -> int:
     ap.add_argument("--kv-block-tokens", type=int, default=128)
     ap.add_argument("--kv-cache-blocks", type=int, nargs="+", required=True)
     args = ap.parse_args(argv)
+    import jax
     for blocks in args.kv_cache_blocks:
         try:
             compiled, eng = compile_mixed_step(args.model, blocks, args)
@@ -106,9 +159,9 @@ def main(argv=None) -> int:
                   f"{msg[:600]}", flush=True)
             continue
         ma = compiled.memory_analysis()
+        hlo = compiled.as_text()
         calls = sorted(set(re.findall(
-            r"%([\w.-]+) = [^\n]*custom-call\([^\n]*tpu_custom_call",
-            compiled.as_text())))
+            r"%([\w.-]+) = [^\n]*custom-call\([^\n]*tpu_custom_call", hlo)))
         total = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
                  + ma.output_size_in_bytes - ma.alias_size_in_bytes)
         print(f"blocks={blocks}: arguments "
@@ -117,8 +170,18 @@ def main(argv=None) -> int:
               f"{ma.output_size_in_bytes / GIB:.2f} GiB, aliased "
               f"{ma.alias_size_in_bytes / GIB:.2f} GiB: "
               f"{total / GIB:.2f} GiB; paths "
-              f"{eng.attn_paths.snapshot()}; pallas calls {calls}",
+              f"{eng.attn_paths.snapshot()}; pool "
+              f"{eng.attn_paths.addressing()}; pallas calls {calls}",
               flush=True)
+        leaf = jax.tree.leaves(eng._pk)[0]
+        plane = leaf.dtype.itemsize * math.prod(leaf.shape[1:])
+        print(f"  pool leaf {leaf.dtype}{list(leaf.shape)} "
+              f"{plane * leaf.shape[0] / GIB:.2f} GiB, one plane "
+              f"{plane / (1 << 20):.1f} MiB; ops with a result of at "
+              f"least a plane:", flush=True)
+        for name, opcode, shape, size in large_ops(hlo, plane):
+            print(f"    {name}  {opcode}  {shape}  "
+                  f"{size / (1 << 20):.1f} MiB", flush=True)
     return 0
 
 
