@@ -1147,7 +1147,9 @@ def cmd_classify(args) -> int:
 
 
 def cmd_bench(args) -> int:
-    """Engine decode benchmark (same shape as the repo-root bench.py).
+    """Engine decode timing of one model on the local device (a user
+    role of the reference, PARITY.md row 6; the repo's yardstick is
+    ``benchmark/run.py``, not this).
 
     With ``--prompt-lookup`` or ``--draft-model``, ALSO times the
     speculative engine on the same workload and reports the speedup with

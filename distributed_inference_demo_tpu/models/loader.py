@@ -312,8 +312,7 @@ def load_or_init(model_name: str, cfg: ModelConfig,
     """Load from a local checkpoint if given/found, else random-init.
 
     The random path keeps every test and benchmark runnable with zero
-    network egress; the bench harness measures throughput, which is
-    weight-value independent.  ``quantize=False`` returns the float tree
+    network egress; throughput is weight-value independent.  ``quantize=False`` returns the float tree
     even for ``-int8`` configs — used by the server app, whose artifact
     channel ships float weights and lets each stage quantize locally.
 

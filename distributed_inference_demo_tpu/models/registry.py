@@ -69,20 +69,6 @@ MODEL_REGISTRY = {
         num_heads=32, num_kv_heads=8, intermediate_size=14336,
         max_seq_len=8192, rope_theta=1000000.0, num_experts=8,
         experts_per_token=2),
-    # --- chip-fitting MoE bench pair (BASELINE.json config 4 at a scale
-    # a single 16 GB chip holds: ~0.8 B params bf16).  The -dense twin
-    # has the SAME active FLOPs per token (top-2 of 8 experts = 2x the
-    # expert intermediate, dense I = 2 x 3584) so moe-vs-dense decode
-    # tok/s isolates the routing/dispatch cost. ---
-    "mixtral-tpu-1b": ModelConfig(
-        family="mixtral", vocab_size=32000, hidden_size=1024, num_layers=8,
-        num_heads=16, num_kv_heads=4, intermediate_size=3584,
-        max_seq_len=2048, rope_theta=1000000.0, num_experts=8,
-        experts_per_token=2),
-    "mixtral-tpu-1b-dense": ModelConfig(
-        family="llama", vocab_size=32000, hidden_size=1024, num_layers=8,
-        num_heads=16, num_kv_heads=4, intermediate_size=7168,
-        max_seq_len=2048, rope_theta=1000000.0),
     # --- olmoe (allenai/OLMoE-1B-7B-0125-Instruct config.json): MHA,
     # RMSNorm over the whole q and k projections, 64 experts of width
     # 1024 with 8 a token, softmax over all 64 and no renormalising ---

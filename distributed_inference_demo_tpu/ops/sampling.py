@@ -64,10 +64,10 @@ def kth_largest(logits: jnp.ndarray, k: int) -> jnp.ndarray:
 
     Decode's top-k filter needs only this one VALUE per row, but
     ``lax.top_k`` pays a full-vocab sort per step — per-row VPU work that
-    grows with batch and shows up as the large-batch roofline erosion in
-    the bench sweep (tools/decode_profile_probe.py measures both paths
-    on-chip).  k-1 (argmax, mask-one-element) rounds plus a final max are
-    O(k*V) elementwise/reduce work with no sort; each round masks only
+    grows with batch (not measured on the chip: ROADMAP Speed 3 reads
+    it from a traced run's ``breakdown.device_ops``).  k-1 (argmax,
+    mask-one-element) rounds plus a final max are O(k*V)
+    elementwise/reduce work with no sort; each round masks only
     the FIRST occurrence of the current max (argmax's tie rule), so
     duplicate logit values count toward k exactly as in top_k.  For
     large k the unrolled rounds lose to the sort — callers gate on k."""
@@ -191,7 +191,7 @@ def sample_logits(logits: jnp.ndarray, rng: jax.Array,
     categorical over the [batch, k] candidate VALUES and gathers the
     chosen index, instead of masking the vocab and drawing over
     [batch, vocab] — saves the full-vocab gumbel+softmax passes that
-    grow with batch (see tools/sampling_cost_probe.py).  The sampling
+    grow with batch.  The sampling
     DISTRIBUTION is identical to ``softmax(filtered_logits(...))`` (the
     contract speculative decoding's accept/resample rule depends on);
     only the RNG consumption pattern differs, so a fixed seed yields a

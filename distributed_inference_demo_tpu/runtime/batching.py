@@ -3532,8 +3532,7 @@ class ContinuousBatchingEngine:
             # MIXED mode: one token-budget dispatch per iteration —
             # decode fusion survives admission (no fuse suppression,
             # no one-admission-at-a-time rule).  The serialized loop
-            # below is untouched: it is the bit-identity reference and
-            # the bench baseline.
+            # below is untouched: it is the bit-identity reference.
             while self._running:
                 self.dispatch_trace.enter("bookkeeping")
                 self.anomaly.observe(self.stats)

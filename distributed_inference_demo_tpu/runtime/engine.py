@@ -60,7 +60,8 @@ def count_device_loop(engine_name: str, steps: int,
                       dispatches: int = 1) -> None:
     """Feed the device-loop telemetry pair: one host DISPATCH issued,
     ``steps`` decode steps executed inside it.  dispatches/token ≈ 1/K
-    is the headline invariant the decode_fused bench leg measures."""
+    is the invariant ``tests/test_device_loop.py`` holds; the benchmark
+    reads the pair from ``/stats`` as ``sched_steps_per_dispatch``."""
     from ..telemetry.catalog import (ENGINE_DEVICE_LOOP_STEPS,
                                      ENGINE_HOST_DISPATCHES)
     ENGINE_HOST_DISPATCHES.inc(dispatches, engine=engine_name)
@@ -453,8 +454,8 @@ class InferenceEngine:
         self._stop_ids = pad_stop_ids(stop_token_ids)
         self._has_stop_ids = bool(stop_token_ids)
         # host-dispatch / device-step counters for THIS engine instance
-        # (the dwt_engine_* series aggregate across instances); the
-        # decode_fused bench leg and the 1/K invariant test read these
+        # (the dwt_engine_* series aggregate across instances); ``stats()``
+        # and the 1/K invariant test (tests/test_device_loop.py) read these
         self.loop_stats = {"host_dispatches": 0, "device_loop_steps": 0}
         self.mesh = mesh
         # kv_cache_dtype composes with a tp mesh: the insert cast
@@ -665,8 +666,8 @@ class InferenceEngine:
 
     def _decode(self, params, last_logits, cache, rng, eos, num_steps,
                 with_logprobs=False):
-        """Back-compat fused-decode surface (multimodal engine, bench
-        long_context leg): the device loop with ``limit == num_steps``
+        """Back-compat fused-decode surface (multimodal engine): the
+        device loop with ``limit == num_steps``
         — same output contract as the old fixed-trip scan, now with
         all-rows-done early exit.  Returns ``(toks, lps, cache)``."""
         b = last_logits.shape[0]
@@ -699,9 +700,9 @@ class InferenceEngine:
         """Batch generation, fused decode scan (the throughput path).
 
         Runs exactly once; ``seconds`` includes compile on the first call
-        for a given shape signature (jit-cached afterwards).  Benchmarks
-        wanting steady-state timing call this twice and keep the second
-        result (see bench.py).  ``logprobs=True`` also returns each
+        for a given shape signature (jit-cached afterwards).  A caller
+        wanting steady-state timing calls this twice and keeps the second
+        result (``cli.py``'s ``bench`` does).  ``logprobs=True`` also returns each
         emitted token's raw log-softmax probability.
         """
         import time

@@ -1,8 +1,8 @@
 """Speculative decoding: a draft model proposes, the target verifies.
 
 Decode is HBM-bandwidth-bound — every step streams all target weights for
-one token's worth of MXU work (see bench.py roofline legs).  Speculative
-decoding converts that stream into several tokens: a small DRAFT model
+one token's worth of MXU work (PERF.md §5, ``step_weight_stream_pct``).
+Speculative decoding converts that stream into several tokens: a small DRAFT model
 autoregressively proposes ``num_draft`` tokens (cheap — its weights are a
 fraction of the target's), then the TARGET verifies all of them in ONE
 prefill-shaped forward ([batch, K+1] positions — the MXU-friendly shape),

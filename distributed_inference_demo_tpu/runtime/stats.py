@@ -5,7 +5,7 @@ The reference accumulates per-token communication and inference time in
 (``Communication.java:104-107,859-896``) and prints the sums at the end of a
 run (``:650-661``).  This module is the structured equivalent: every pipeline
 role owns a ``StageStats``, the ring loop feeds it, and a ``snapshot()``
-dict flows to the ``/stats`` HTTP endpoint, the bench harness, and the
+dict flows to the ``/stats`` HTTP endpoint and the
 cross-process stats collection (header polls workers with a ``statsreq``
 control message — the GET_STATUS idea applied to the data plane).
 

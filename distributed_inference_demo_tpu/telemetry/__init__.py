@@ -8,8 +8,8 @@ The integrated pieces (docs/DESIGN.md §7-§8):
 - ``metrics``: a hand-rolled Prometheus registry (no new dependency) +
   ``catalog``, the standard ``dwt_*`` series bridging StageStats,
   batching/speculative counters, and monitor probes to ``GET /metrics``;
-- ``runlog``: structured JSONL run logs shared by bench, the engines,
-  and the control-plane lifecycle;
+- ``runlog``: structured JSONL run logs shared by the engines and the
+  control-plane lifecycle;
 - ``flightrecorder``: a bounded always-on ring of recent runtime events
   (the aircraft black box);
 - ``anomaly``: online detectors over the existing stats surfaces

@@ -295,8 +295,7 @@ class DispatchProfiler:
 
     def snapshot(self) -> dict:
         """Deterministic per-signature summary (sorted keys, rounded
-        floats) — what ``/debugz``, bench extras and the probe tools
-        all export."""
+        floats) — what ``/debugz`` exports."""
         ceil = roofline_ceiling_gbs()
         out: Dict[str, dict] = {}
         with self._lock:

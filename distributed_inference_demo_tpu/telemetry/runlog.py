@@ -1,13 +1,12 @@
 """Structured JSONL run logs: one event per line, one file per run.
 
 Replaces scattered prints as the machine-readable record of a run: the
-bench harness, the engines, and the control-plane lifecycle all emit
-through one surface.  Every line is a self-contained JSON object::
+engines and the control-plane lifecycle emit through one surface.  Every line is a self-contained JSON object::
 
     {"ts": <epoch seconds>, "run_id": "...", "event": "<kind>", ...fields}
 
 Enabling: pass a path explicitly (``RunLog(path)`` + ``set_run_log``), use
-``serve --run-log`` / ``bench --run-log``, or set ``DWT_RUN_LOG=<path>``
+``serve --run-log``, or set ``DWT_RUN_LOG=<path>``
 in the environment — any process in the deployment then appends to its
 own file (the path gets a ``.<pid>`` suffix when it would be shared, so
 workers never interleave partial lines with the header).  When nothing is

@@ -5,7 +5,8 @@ Everything here runs on the CPU and is cheap.  What it pins:
 - ``chip_smoke.py`` fails, naming the missing TPU, where JAX has none —
   and its request script passes against ``serve --model qwen2-test`` on
   the CPU, so the command is debugged before chip time is spent on it;
-- ``bench.py`` refuses to run without a TPU and prints no number;
+- ``benchmark/run.py`` refuses to run without a TPU and prints no result
+  line (only ``--rehearse-cpu`` walks it on the CPU, and that prints none);
 - the compile-cache rule: ``JAX_COMPILATION_CACHE_DIR`` set → the code
   sets nothing; unset → ``<checkout>/.jax_cache``, whatever the cwd;
 - the attention-path record: ``gather`` (with the reason) on the CPU,
@@ -58,7 +59,7 @@ import chip_smoke  # noqa: E402
 CPU_ENV = dict(os.environ, JAX_PLATFORMS="cpu")
 
 
-# ------------------------------------------------------ smoke and bench
+# -------------------------------------------------- smoke and benchmark
 
 def test_chip_smoke_fails_without_a_tpu():
     """In a sandbox like this one the smoke must fail: its children ask
@@ -116,12 +117,16 @@ def test_smoke_stats_check_catches_a_hidden_gather():
     chip_smoke.check_stats(stats, "tpu")
 
 
-def test_bench_refuses_to_run_without_a_tpu():
-    proc = subprocess.run([sys.executable, str(REPO / "bench.py")],
-                          env=CPU_ENV, capture_output=True, text=True,
-                          timeout=300)
-    assert proc.returncode == 1
-    assert proc.stdout.strip() == ""         # no number, old or new
+def test_benchmark_refuses_to_run_without_a_tpu():
+    """The promise of ``benchmark/run.py``'s docstring: the children start
+    under ``JAX_PLATFORMS=tpu``, so where there is no TPU the run ends
+    non-zero and stdout, where the result line would go, stays empty."""
+    proc = subprocess.run(
+        [sys.executable, str(REPO / "benchmark" / "run.py"), "--workload",
+         "qwen2.5-7b-int8.chat", "--seconds", "2"],
+        env=CPU_ENV, capture_output=True, text=True, timeout=120)
+    assert proc.returncode not in (0, 3)     # 3 is the rehearsal's
+    assert proc.stdout.strip() == ""         # no result line
     assert "JAX found no TPU" in proc.stderr
 
 

@@ -803,12 +803,15 @@ def test_multirow_generate_shed_cancels_admitted_rows():
 def test_http_generate_returns_503_with_retry_after():
     from distributed_inference_demo_tpu.runtime.http_server import (
         InferenceHTTPServer)
-    with _tiny_batching_engine(max_queue_depth=1) as eng:
+    with _tiny_batching_engine(max_seq=1100, max_queue_depth=1) as eng:
         srv = InferenceHTTPServer(eng, port=0)
         srv.start()
         try:
             prompt = list(range(8))
-            r1 = eng.submit(np.arange(8, dtype=np.int32), 56)
+            # r1 holds the only slot until it is cancelled below (1,000
+            # tokens, as the 504 test's blocker), so r2 fills the queue
+            # and stays there whenever the HTTP request lands
+            r1 = eng.submit(np.arange(8, dtype=np.int32), 1000)
             _wait_for(lambda: eng.stats()["active_slots"] == 1,
                       what="r1 to take the slot")
             r2 = eng.submit(np.arange(8, dtype=np.int32), 4)

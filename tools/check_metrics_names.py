@@ -139,7 +139,8 @@ UNIT_SUFFIX_EXEMPT = {"dwt_kvcache_blocks_in_use",
                       # ISSUE-15 pins this exact name: a dimensionless
                       # packed/budgeted fraction (a _ratio in spirit;
                       # "utilization" is the roofline-adjacent term the
-                      # §19 runbook and bench leg both use)
+                      # §19 runbook uses; the benchmark's
+                      # sched_budget_util_pct is the same fraction)
                       "dwt_batching_token_budget_utilization",
                       # ISSUE-19 pins this exact name: the per-bucket
                       # adaptive-K occupancy gauge — "len" is the
