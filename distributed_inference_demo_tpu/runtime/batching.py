@@ -687,8 +687,14 @@ class ContinuousBatchingEngine:
         # installing their slot in-program) with the fused decode loop
         # over all active rows.  Segment count is FIXED at
         # budget // C (unused rows ride all-sentinel tables and slot=B,
-        # so every install drops), giving exactly two compiled variants
-        # (with_finals x num_steps is static per decode_block).
+        # so every install drops).  A dispatch that packed no segment
+        # passes ``seg=None`` and its program has no slab at all, giving
+        # exactly two compiled variants (slab or none x num_steps, which
+        # is static per decode_block).  Whether a packed segment is a
+        # final is NOT a third: the slab always samples its rows,
+        # because a warm-up of prompts that end within n_seg segments
+        # launches no chunk-only dispatch, and a variant for those
+        # would first compile under traffic.
         self._mixed_step = None
         self._mixed_pld_step = None
         self._mixed_spec_step = None
@@ -746,39 +752,45 @@ class ContinuousBatchingEngine:
                         *_sample_step(logits, lengths, last_tok, active,
                                       rng))
 
-            @partial(jax.jit, donate_argnums=(1, 2),
-                     static_argnums=(17, 18))
-            def mixed_step(params, pk, pv, seg_ids, seg_tables,
-                           seg_starts, seg_lens, seg_slot, seg_plen,
-                           seg_keys, dec_tables, lengths, last_tok,
-                           active, dec_rng, eos, budget, num_steps,
-                           with_finals):
-                """One mixed dispatch.  Prefill slab first: row r of
+            @partial(jax.jit, donate_argnums=(1, 2), static_argnums=(11,))
+            def mixed_step(params, pk, pv, seg, dec_tables, lengths,
+                           last_tok, active, dec_rng, eos, budget,
+                           num_steps):
+                """One mixed dispatch.  ``seg`` is None where nothing was
+                packed (the program is then the fused decode loop alone:
+                no slab, no KV write of one, no prefill attention), else
+                ``(seg_ids, seg_tables, seg_starts, seg_lens, seg_slot,
+                seg_plen, seg_keys)``.  Prefill slab first: row r of
                 ``seg_ids`` [n_seg, C] runs at positions
                 ``seg_starts[r] + arange(C)`` through ``seg_tables[r]``
-                (sentinel rows compute into dropped writes).  If
-                ``with_finals``, each row samples token #1 at
-                ``seg_lens[r] - 1`` from its OWN batch-1 rng key
-                (``seg_keys[r]`` — the serialized prefill's exact
-                spend) and installs itself at ``seg_slot[r]``
-                (slot = B = not-a-final, the install drops).  Then the
-                fused decode loop runs over ``dec_tables`` with the
-                updated row state — freshly installed rows decode in
-                the SAME dispatch, rows whose token #1 was eos enter
-                the loop already done."""
+                (sentinel rows compute into dropped writes).  Each row
+                samples token #1 at ``seg_lens[r] - 1`` from its OWN
+                batch-1 rng key (``seg_keys[r]`` — the serialized
+                prefill's exact spend) and installs itself at
+                ``seg_slot[r]`` (slot = B = not-a-final, the install
+                drops).  Then the fused decode loop runs over
+                ``dec_tables`` with the updated row state — freshly
+                installed rows decode in the SAME dispatch, rows whose
+                token #1 was eos enter the loop already done."""
                 B_ = last_tok.shape[0]
                 cache = KVCache(pk, pv, jnp.zeros((), jnp.int32))
-                # the scopes are metadata on the ops: a capture keeps
-                # each op's path (`jit(mixed_step)/decode_loop/...`) in
-                # the op's event metadata.  The whole-pool relayout
-                # copies are the compiler's own and carry none
-                with jax.named_scope("slab_body"):
-                    logits, cache, *moe = slab_body(
-                        params, cache, seg_ids, seg_tables, seg_starts,
-                        "mixed_step", **moe_kw)
-                if moe:
-                    moe_acc = moe_fold(moe_acc0(), moe[0])
-                if with_finals:
+                moe_acc = moe_acc0() if moe_kw else None
+                if seg is None:
+                    final_toks = jnp.zeros((n_seg,), jnp.int32)
+                    final_lps = jnp.zeros((n_seg,), jnp.float32)
+                    done0 = None
+                else:
+                    (seg_ids, seg_tables, seg_starts, seg_lens, seg_slot,
+                     seg_plen, seg_keys) = seg
+                    # the scopes are metadata on the ops: a capture keeps
+                    # each op's path (`jit(mixed_step)/decode_loop/...`)
+                    # in the op's event metadata
+                    with jax.named_scope("slab_body"):
+                        logits, cache, *moe = slab_body(
+                            params, cache, seg_ids, seg_tables,
+                            seg_starts, "mixed_step", **moe_kw)
+                    if moe:
+                        moe_acc = moe_fold(moe_acc, moe[0])
                     with jax.named_scope("slab_finals"):
                         final_toks, final_lps = slab_finals(
                             logits, seg_lens, seg_keys)
@@ -794,13 +806,9 @@ class ContinuousBatchingEngine:
                     # rows always have budget >= 1 — completed rows
                     # free their slot at drain time)
                     done0 = done0 | (budget <= 0)
-                else:
-                    final_toks = jnp.zeros((n_seg,), jnp.int32)
-                    final_lps = jnp.zeros((n_seg,), jnp.float32)
-                    done0 = None
                 bind_tables(dec_tables, "mixed_step")
                 with jax.named_scope("decode_loop"):
-                    if moe:
+                    if moe_kw:
                         # the counters ride the loop's carry beside the
                         # cache, which _fused_loop never looks into
                         ((cache, moe_acc), lengths, tok, toks, lps,
@@ -819,7 +827,7 @@ class ContinuousBatchingEngine:
                         final_toks, final_lps, toks, lps, steps)
 
             # the §19 invariant the recompile_storm detector enforces:
-            # with_finals x one static num_steps = exactly two variants
+            # (slab or none) x one static num_steps = exactly two variants
             self._mixed_step = _ct.wrap("mixed_step", mixed_step,
                                         variant_budget=2)
 
@@ -3308,14 +3316,17 @@ class ContinuousBatchingEngine:
                      final_lps, toks, lps, steps, *moe_acc
                      ) = self._mixed_step(
                         self.params, self._pk, self._pv,
-                        jnp.asarray(seg_ids), jnp.asarray(seg_tables),
-                        jnp.asarray(seg_starts), jnp.asarray(seg_lens),
-                        jnp.asarray(seg_slot), jnp.asarray(seg_plen),
-                        jnp.asarray(seg_keys), jnp.asarray(self._tables),
+                        # nothing packed: the variant without a slab,
+                        # and no segment array is transferred
+                        tuple(jnp.asarray(x) for x in (
+                            seg_ids, seg_tables, seg_starts, seg_lens,
+                            seg_slot, seg_plen, seg_keys))
+                        if packed else None,
+                        jnp.asarray(self._tables),
                         self._lengths, self._last_tok,
                         jnp.asarray(active_mask), dec_sub,
                         self._eos_scalar(), jnp.asarray(budget_vec),
-                        self.decode_block, with_finals)
+                        self.decode_block)
                 self._last_tok = tok
             elif self._mixed_spec_step is not None:
                 (self._pk, self._pv, self._dpk, self._dpv,

@@ -24,9 +24,11 @@ Everything here runs on the CPU and is cheap.  What it pins:
   (skipped where libtpu offers none).
 """
 
+import argparse
 import math
 import json
 import os
+import re
 import subprocess
 import sys
 import time
@@ -317,6 +319,50 @@ def test_engine_reports_gather_on_cpu_and_health_follows_scheduler():
         eng.close()
 
 
+def test_attention_paths_whichever_variant_is_traced_first():
+    """PR 33: ``mixed_step`` has a variant with no slab.  Compiled FIRST
+    (a decode-only dispatch over an idle engine, by hand: served traffic
+    always starts with a prefill), then a request through the scheduler:
+    ``attention_paths`` names one program with both chunk shapes, as the
+    benchmark's configurations state it, and ``compile`` counts the two
+    variants its budget allows."""
+    from distributed_inference_demo_tpu.telemetry import profiling
+    cfg = get_model_config("llama-test")
+    params = init_full_params(jax.random.PRNGKey(0), cfg)
+    B = 2
+    profiling.reset_observatory()   # the compile ledger is the process's
+    eng = ContinuousBatchingEngine(
+        cfg, params, max_seq=64, max_batch=B,
+        sampling=SamplingParams(greedy=True), decode_block=2,
+        prefill_chunk=8, mixed_token_budget=16)
+    try:
+        why = "gather: backend=auto on platform=cpu"
+        out = eng._mixed_step(
+            eng.params, eng._pk, eng._pv, None, jnp.asarray(eng._tables),
+            eng._lengths, eng._last_tok, jnp.zeros((B,), bool),
+            jax.random.PRNGKey(0), eng._eos_scalar(),
+            jnp.zeros((B,), jnp.int32), eng.decode_block)
+        eng._pk, eng._pv = out[0], out[1]
+        assert int(out[8]) == 0              # no row active: no step ran
+        assert eng.stats()["attention_paths"] == {
+            "mixed_step": {"chunk=1": why}}
+        want = eng.submit(list(range(1, 20)), 6).wait(timeout=120)
+        stats = eng.stats()
+        assert stats["attention_paths"] == {
+            "mixed_step": {"chunk=1": why, "chunk=8": why}}
+        assert stats["compile"]["mixed_step"]["compiles"] == \
+            stats["compile"]["mixed_step"]["variant_budget"] == 2
+        assert stats["dispatch_trace"]["decode_only"] > 0
+    finally:
+        eng.close()
+        profiling.reset_observatory()
+    from distributed_inference_demo_tpu.runtime import InferenceEngine
+    oracle = InferenceEngine(cfg, params, max_seq=64,
+                             sampling=SamplingParams(greedy=True))
+    np.testing.assert_array_equal(
+        want, oracle.generate(np.arange(1, 20)[None, :], 6).tokens[0])
+
+
 # --------------------------------------------------- born-sharded state
 
 def test_seeded_weights_and_pool_are_born_sharded():
@@ -505,6 +551,50 @@ def test_pool_is_addressed_in_place_on_the_chip(v5e, case, chunk, how):
         made = [op for op in large if op[2].lstrip("bfsu0123456789") in whole]
         assert made and all("dynamic-update-slice" in op[0] for op in made), (
             made)
+
+
+@pytest.mark.parametrize("slab", [True, False],
+                         ids=["slab", "nothing-packed"])
+@pytest.mark.parametrize("model,blocks", [("qwen2.5-7b-int8", 416),
+                                          ("olmoe-1b-7b-int8", 224)])
+def test_each_variant_of_mixed_step_leaves_the_pool_in_place(
+        v5e, model, blocks, slab):
+    """PR 33: both variants of ``mixed_step`` (a dispatch that packed a
+    segment: slab + decode loop; one that packed none: the decode loop
+    alone), compiled whole for the chip through ``tools/aot_mixed_step``
+    at a dense and the expert cell's flags (PERF.md section 4).  Neither
+    makes anything of the pool's or of a plane's shape, and the variant
+    without a slab holds no prefill attention and one KV write."""
+    sys.path.insert(0, str(REPO / "tools"))
+    from aot_mixed_step import compile_mixed_step, large_ops
+    flags = argparse.Namespace(
+        batch_slots=32, prefill_chunk=256, decode_block=4,
+        mixed_token_budget=640, max_seq=4096, kv_block_tokens=128)
+    compiled, eng = compile_mixed_step(model, blocks, flags, slab)
+    hlo = compiled.as_text()
+    leaf = jax.tree.leaves(eng._pk)[0]
+    plane = leaf.dtype.itemsize * math.prod(leaf.shape[1:])
+    pool_shapes = {str(list(leaf.shape)), str(list(leaf.shape[1:]))}
+    large = large_ops(hlo, plane)
+    assert not [op for op in large
+                if op[2].lstrip("bfsu0123456789") in pool_shapes], large
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries < plane * leaf.shape[0]      # far under one pool
+    chunks = {"chunk=256", "chunk=1"} if slab else {"chunk=1"}
+    assert eng.attn_paths.addressing() == {
+        "mixed_step": dict.fromkeys(chunks, "kernel write")}
+    assert set(eng.attn_paths.snapshot()["mixed_step"]) == chunks
+    assert eng.attn_paths.snapshot()["mixed_step"]["chunk=1"] == \
+        "pallas_decode"
+    calls = set(re.findall(
+        r"%([\w.-]+) = [^\n]*custom-call\([^\n]*tpu_custom_call", hlo))
+    writes = {c for c in calls if c.startswith("kv_page_write")}
+    assert len(writes) == (2 if slab else 1), calls
+    if not slab:
+        assert not any("prefill" in c for c in calls), calls
+        # the experts' three projections, once: the decode step's
+        assert len([c for c in calls if c.startswith("moe_gmm")]) == (
+            3 if "olmoe" in model else 0), calls
 
 
 def test_flash_kernel_with_alibi_gets_through_mosaic(v5e):

@@ -86,6 +86,15 @@ def assert_no_leak(eng):
     assert mgr.debug_state()["leased_nodes"] == 0
 
 
+def assert_both_kinds_ran(eng):
+    """The parity above held over both variants of ``mixed_step``: the
+    run launched dispatches that packed a segment (slab + decode loop)
+    and dispatches that packed none (the decode loop alone)."""
+    dt = eng.stats()["dispatch_trace"]
+    assert dt["decode_only"] > 0 and dt["prefill"] > 0, dt
+    assert dt["decode_only"] + dt["prefill"] == dt["seq"]
+
+
 @pytest.mark.quick
 def test_mixed_cold_parity_stats_and_zero_h2d(params, oracle):
     """Concurrent cold requests through the mixed loop: greedy tokens
@@ -114,6 +123,68 @@ def test_mixed_cold_parity_stats_and_zero_h2d(params, oracle):
         assert st["pending_prefill_tokens"] == 0
         assert eng.kv_cache.snapshot()["h2d_bytes"] == 0
         assert_no_leak(eng)
+        assert_both_kinds_ran(eng)
+
+
+def abstract_mixed_call(eng, slab: bool):
+    """Abstract arguments of ``mixed_step``: of a dispatch that packed a
+    prefill segment (``slab``) or of one that packed none."""
+    S, i32, u32 = jax.ShapeDtypeStruct, np.int32, np.uint32
+    B, W = eng.max_batch, eng._table_width
+    n_seg, C = eng._mixed_seg_cap, eng.prefill_chunk
+    seg = (S((n_seg, C), i32), S((n_seg, W), i32), S((n_seg,), i32),
+           S((n_seg,), i32), S((n_seg,), i32), S((n_seg,), i32),
+           S((n_seg, 2), u32))
+    return (eng.params, eng._pk, eng._pv, seg if slab else None,
+            S((B, W), i32), S((B,), i32), S((B,), i32), S((B,), np.bool_),
+            S((2,), u32), S((), i32), S((B,), i32), eng.decode_block)
+
+
+@pytest.mark.quick
+@pytest.mark.parametrize("model", ["llama-test", "olmoe-test"])
+def test_a_dispatch_that_packed_nothing_runs_no_slab(model):
+    """PR 33: the program of a decode-only dispatch, as lowered, holds
+    nothing under the scopes ``slab_body`` / ``slab_finals``, traces no
+    prefill attention (only ``chunk=1`` reaches the paged hook), has as
+    many matmuls as ``paged_multi_step`` (the fused decode loop alone),
+    and returns what the variant with a slab returns, shape for shape.
+    Traced FIRST here: the slab variant then adds its chunk shape under
+    the same program name and nothing else."""
+    cfg = get_model_config(model)
+    with ContinuousBatchingEngine(
+            cfg, init_full_params(jax.random.PRNGKey(0), cfg), max_seq=96,
+            max_batch=4, sampling=GREEDY, kv_block_tokens=8,
+            prefill_chunk=8, decode_block=4, mixed_token_budget=24) as eng:
+        step = eng._mixed_step.inner
+        call = abstract_mixed_call(eng, slab=False)
+        alone = step.lower(*call)
+        paths = eng.attn_paths.snapshot()
+        assert list(paths) == ["mixed_step"]
+        assert list(paths["mixed_step"]) == ["chunk=1"]
+        # scopes as the ops' name stack has them, `jit(mixed_step)/<scope>`
+        # (a bare function name may also be a frame of some cached
+        # sub-program's first trace, which says nothing)
+        text = alone.as_text(debug_info=True)
+        assert "/decode_loop" in text
+        assert "/slab_body" not in text and "/slab_finals" not in text
+        multi = eng._paged_multi_step.inner.lower(
+            *call[:3], *call[4:]).as_text()
+        matmuls = alone.as_text().count("dot_general")
+        assert matmuls == multi.count("dot_general") > 0
+
+        packed = step.lower(*abstract_mixed_call(eng, slab=True))
+        slab_text = packed.as_text(debug_info=True)
+        assert "/slab_body" in slab_text and "/slab_finals" in slab_text
+        assert packed.as_text().count("dot_general") > matmuls
+        shapes = [jax.tree.map(lambda x: (x.shape, x.dtype), p.out_info)
+                  for p in (alone, packed)]
+        assert shapes[0] == shapes[1]
+        # with experts: one more output, the [E + 3] routing counters
+        assert len(shapes[0]) == (10 if cfg.num_experts else 9)
+        assert eng.attn_paths.snapshot() == {
+            "mixed_step": {"chunk=1": paths["mixed_step"]["chunk=1"],
+                           "chunk=8": paths["mixed_step"]["chunk=1"]},
+            "paged_multi_step": {"chunk=1": paths["mixed_step"]["chunk=1"]}}
 
 
 @pytest.mark.quick
@@ -143,6 +214,8 @@ def test_mixed_sampled_stream_bit_identical_to_serialized(params):
             for p, n in ((list(range(3, 30)), 8), ([9, 8, 7, 6], 6)):
                 r = eng.submit(p, n)
                 outs.append((list(r.wait(timeout=300)), list(r.lps)))
+            if kw:
+                assert_both_kinds_ran(eng)
             return outs
 
     for (toks, lps), (m_toks, m_lps) in zip(run(),
@@ -229,6 +302,8 @@ def test_mixed_matches_serialized_property_sweep(params, kv_dtype,
             reqs = [eng.submit(p, n) for p, n in prompts]
             outs = [list(r.wait(timeout=300)) for r in reqs]
             assert_no_leak(eng)
+            if mixed:
+                assert_both_kinds_ran(eng)
             return outs
 
     base = run(None, mixed=False)

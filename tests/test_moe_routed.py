@@ -322,15 +322,19 @@ def test_moe_counters_sum_to_tokens_times_k_times_layers(olmoe_run):
     assert len(recs) == moe["dispatches"] == dt["seq"]
     slab, slots = 48, 4
     for r in recs:
-        assert r["moe_rows"] == (slab + slots * r["steps"]) * k * L
+        # the slab runs where a segment was packed, and only there
+        passes = (r["segments"] > 0) + r["steps"]
+        assert r["moe_rows"] == (slab * (r["segments"] > 0)
+                                 + slots * r["steps"]) * k * L
         assert r["moe_valid_rows"] == (
             r["prefill_tokens"] + (r["active_rows"] + r["finals"])
             * r["steps"]) * k * L
-        assert 0 < r["moe_touched"] <= (1 + r["steps"]) * L * E
+        assert 0 < r["moe_touched"] <= passes * L * E
         assert r["moe_load_max"] <= slab * k
     assert moe["rows"] == sum(r["moe_rows"] for r in recs) \
         == sum(moe["expert_rows"])
-    assert moe["layer_calls"] == sum((1 + r["steps"]) * L for r in recs)
+    assert moe["layer_calls"] == sum(
+        ((r["segments"] > 0) + r["steps"]) * L for r in recs)
     assert moe["touched"] == sum(r["moe_touched"] for r in recs)
     assert moe["load_max"] == max(r["moe_load_max"] for r in recs)
     assert len(moe["expert_rows"]) == moe["experts"] == E
@@ -341,6 +345,38 @@ def test_moe_counters_sum_to_tokens_times_k_times_layers(olmoe_run):
     assert prompt_rows == sum(r["prefill_tokens"] for r in recs) * k * L
     assert moe["valid_rows"] >= prompt_rows + sum(
         len(o) - 1 for o in outs) * k * L
+
+
+def test_a_decode_only_record_counts_the_decode_steps_alone():
+    """PR 33: one request, so the dispatches are scripted: the first
+    packs the prompt's final (slab + 4 steps), the rest pack nothing and
+    run no slab.  Their counters hold the decode loop's layer calls and
+    rows and nothing of a slab's: ``steps x layers`` calls of ``slots x
+    k`` rows, no expert fuller than the slots."""
+    cfg = get_model_config("olmoe-test-int8")
+    params = init_full_params(jax.random.PRNGKey(0), cfg, quantize=True)
+    k, L, E = cfg.experts_per_token, cfg.num_layers, cfg.num_experts
+    slab, slots = 48, 4
+    with _engine(cfg, params) as eng:
+        before = eng.stats()["moe"]["layer_calls"]
+        eng.submit(np.arange(1, 10, dtype=np.int32), 10).wait(timeout=300)
+        for _ in range(200):
+            st = eng.stats()
+            if st["dispatch_trace"]["seq"] == st["moe"]["dispatches"] == 3:
+                break
+            time.sleep(0.02)
+    dt = st["dispatch_trace"]
+    first, *alone = [dict(zip(dt["fields"], r)) for r in dt["recent"]]
+    assert (first["segments"], first["steps"]) == (1, 4)
+    assert first["moe_rows"] == (slab + slots * 4) * k * L
+    assert [(r["segments"], r["steps"]) for r in alone] == [(0, 4), (0, 1)]
+    for r in alone:
+        assert r["moe_rows"] == slots * k * r["steps"] * L
+        assert r["moe_valid_rows"] == 1 * r["steps"] * k * L
+        assert 0 < r["moe_touched"] <= r["steps"] * L * min(E, slots * k)
+        assert r["moe_load_max"] <= slots
+    assert before == 0 and st["moe"]["layer_calls"] == (1 + 4 + 4 + 1) * L
+    assert dt["decode_only"] == 2 and dt["prefill"] == 1
 
 
 def test_dense_engine_has_no_moe_section_and_the_record_is_unchanged():

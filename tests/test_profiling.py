@@ -297,6 +297,49 @@ def test_recompile_storm_end_to_end_with_real_jit(tmp_path):
         postmortem.set_postmortem_writer(None)
 
 
+def test_every_dispatch_kind_compiles_the_budget_and_no_storm():
+    """PR 33: ``mixed_step`` is keyed by whether a segment was packed.
+    A run with every kind of dispatch (chunks alone, a final, decode
+    alone) compiles exactly the ``variant_budget`` the engine declares,
+    two, so the storm detector has nothing to say at slack 0."""
+    import jax
+
+    from distributed_inference_demo_tpu.models import get_model_config
+    from distributed_inference_demo_tpu.models.decoder import (
+        init_full_params)
+    from distributed_inference_demo_tpu.ops.sampling import SamplingParams
+    from distributed_inference_demo_tpu.runtime.batching import (
+        ContinuousBatchingEngine)
+
+    profiling.reset_observatory()
+    try:
+        cfg = get_model_config("llama-test")
+        params = init_full_params(jax.random.PRNGKey(0), cfg)
+        with ContinuousBatchingEngine(
+                cfg, params, max_seq=96, max_batch=2,
+                sampling=SamplingParams(greedy=True), kv_block_tokens=8,
+                prefill_chunk=8, decode_block=4,
+                mixed_token_budget=16) as eng:
+            # 37 tokens over two segments a dispatch: 16 + 16 + (5, final)
+            eng.submit(list(range(1, 38)), 10).wait(timeout=300)
+            eng.submit([3, 14, 15], 6).wait(timeout=300)
+            stats = eng.stats()
+            recent = eng.anomaly.state()["recent"]
+        dt = stats["dispatch_trace"]
+        recs = [dict(zip(dt["fields"], r)) for r in dt["recent"]]
+        kinds = {(r["segments"] > 0, r["finals"] > 0) for r in recs}
+        assert kinds == {(True, False), (True, True), (False, False)}
+        comp = stats["compile"]["mixed_step"]
+        assert comp["compiles"] == comp["cache_entries"] \
+            == comp["variant_budget"] == 2
+        clock = FakeClock()
+        det = AnomalyDetector(_storm_thresholds(), clock=clock)
+        assert det.observe({"compile": stats["compile"]}) == []
+        assert not [a for a in recent if a["kind"] == "recompile_storm"]
+    finally:
+        profiling.reset_observatory()
+
+
 # -- HBM watermark ledger ---------------------------------------------------
 
 def test_hbm_watermark_monotone_until_reset():

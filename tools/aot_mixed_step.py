@@ -13,9 +13,11 @@ least as large as one layer's plane of the page pool.
 libtpu compiles for a described topology (``v5e:2x2``, one of its
 devices) without a chip; parameters and the page pool are shapes only, so
 nothing model-sized is allocated.  It proves compilation and sizes a pool
-before any chip call (PERF.md section 4); times need the chip.  One
-compile a ``--kv-cache-blocks`` value; a refusal (out of memory) is
-printed, not raised.
+before any chip call (PERF.md section 4); times need the chip.  Two
+compiles a ``--kv-cache-blocks`` value, one a variant of the program (a
+dispatch that packed a prefill segment runs the slab and the decode
+loop, one that packed none the decode loop alone); a refusal (out of
+memory) is printed, not raised.
 
 Reading the large ops ("no pool copy" without a chip).  The pool is
 addressed in place (``ops.stacked.LayerOf``): on the chip the KV write is
@@ -37,6 +39,7 @@ pool's (a weight matrix widened, logits) are listed too, by shape.
 from __future__ import annotations
 
 import argparse
+import itertools
 import math
 import re
 import sys
@@ -48,8 +51,10 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 GIB = float(1 << 30)
 
 
-def compile_mixed_step(model: str, blocks: int, args, with_finals=True):
-    """``(compiled, engine)`` of ``mixed_step`` at ``blocks`` pool pages."""
+def compile_mixed_step(model: str, blocks: int, args, slab=True):
+    """``(compiled, engine)`` of ``mixed_step`` at ``blocks`` pool pages:
+    the variant of a dispatch that packed a prefill segment (``slab``),
+    or of one that packed none (the decode loop alone)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
@@ -92,12 +97,13 @@ def compile_mixed_step(model: str, blocks: int, args, with_finals=True):
             return jax.tree.map(lambda x: S(x.shape, x.dtype), tree)
 
         i32 = jnp.int32
+        seg = (S((n_seg, C), i32), S((n_seg, W), i32), S((n_seg,), i32),
+               S((n_seg,), i32), S((n_seg,), i32), S((n_seg,), i32),
+               S((n_seg, 2), jnp.uint32))
         call = (on_chip(params), on_chip(eng._pk), on_chip(eng._pv),
-                S((n_seg, C), i32), S((n_seg, W), i32), S((n_seg,), i32),
-                S((n_seg,), i32), S((n_seg,), i32), S((n_seg,), i32),
-                S((n_seg, 2), jnp.uint32), S((B, W), i32), S((B,), i32),
+                seg if slab else None, S((B, W), i32), S((B,), i32),
                 S((B,), i32), S((B,), jnp.bool_), S((2,), jnp.uint32),
-                S((), i32), S((B,), i32), args.decode_block, with_finals)
+                S((), i32), S((B,), i32), args.decode_block)
         with mock.patch.object(jax, "default_backend", lambda: "tpu"):
             compiled = eng._mixed_step.inner.lower(*call).compile()
         return compiled, eng
@@ -150,13 +156,17 @@ def main(argv=None) -> int:
     ap.add_argument("--kv-cache-blocks", type=int, nargs="+", required=True)
     args = ap.parse_args(argv)
     import jax
-    for blocks in args.kv_cache_blocks:
+    for blocks, slab in itertools.product(args.kv_cache_blocks,
+                                          (True, False)):
+        which = (f"blocks={blocks} "
+                 f"{'slab + decode loop' if slab else 'decode loop alone'}")
         try:
-            compiled, eng = compile_mixed_step(args.model, blocks, args)
+            compiled, eng = compile_mixed_step(args.model, blocks, args,
+                                               slab)
         except Exception as e:              # the compiler's refusal
             msg = " ".join(str(e).split())
-            print(f"blocks={blocks}: REFUSED {type(e).__name__}: "
-                  f"{msg[:600]}", flush=True)
+            print(f"{which}: REFUSED {type(e).__name__}: {msg[:600]}",
+                  flush=True)
             continue
         ma = compiled.memory_analysis()
         hlo = compiled.as_text()
@@ -164,7 +174,7 @@ def main(argv=None) -> int:
             r"%([\w.-]+) = [^\n]*custom-call\([^\n]*tpu_custom_call", hlo)))
         total = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
                  + ma.output_size_in_bytes - ma.alias_size_in_bytes)
-        print(f"blocks={blocks}: arguments "
+        print(f"{which}: arguments "
               f"{ma.argument_size_in_bytes / GIB:.2f} GiB, temporaries "
               f"{ma.temp_size_in_bytes / GIB:.2f} GiB, outputs "
               f"{ma.output_size_in_bytes / GIB:.2f} GiB, aliased "
