@@ -314,11 +314,16 @@ def cmd_serve(args) -> int:
         import jax
 
         from .comm.transport import ZmqTransport
-        from .models.base import split_layer_ranges
+        from .models.base import require_single_pass, split_layer_ranges
         from .models.registry import get_model_config
         from .runtime.elastic import ElasticHeader, ElasticStageRuntime
 
         cfg = get_model_config(args.model)
+        try:
+            require_single_pass(cfg, "--chain (a pipeline of stages)")
+        except ValueError as e:
+            print(e, file=sys.stderr)
+            return 1
         if getattr(args, "prefill_chunk", 0):
             print("--prefill-chunk is not supported with --chain",
                   file=sys.stderr)

@@ -37,7 +37,8 @@ import jax.numpy as jnp
                       "attn_layernorm", "attn_qkv_bias", "num_experts",
                       "experts_per_token", "moe_capacity_factor",
                       "quantization", "head_dim_override", "embed_scale",
-                      "mlp_act", "qk_norm", "norm_topk_prob"])
+                      "mlp_act", "qk_norm", "norm_topk_prob", "ut_steps",
+                      "sandwich_norm"])
 @dataclass(frozen=True)
 class ModelConfig:
     """Static, hashable architecture description shared by all model families.
@@ -90,10 +91,25 @@ class ModelConfig:
     moe_capacity_factor: float = 2.0
     # weight-only quantization: "none" | "int8" | "int4" (ops/quant.py)
     quantization: str = "none"
+    # ouro (looped decoder): the whole layer stack runs ``ut_steps`` times
+    # a token with the same weights, the final norm closing every pass;
+    # a pass attends to its OWN keys and values, so the cache holds
+    # ``kv_planes`` planes, pass ``t``'s layer ``l`` at ``t * L + l``
+    ut_steps: int = 1
+    # ouro: RMSNorm on each sublayer's OUTPUT too, before the residual
+    # add (four norms a block)
+    sandwich_norm: bool = False
 
     @property
     def dtype(self) -> jnp.dtype:
         return jnp.dtype(self.dtype_name)
+
+    @property
+    def kv_planes(self) -> int:
+        """K/V planes a token holds: one a layer a pass.  The one source
+        of every KV structure's plane count (dense cache, page pool,
+        host tier, exported blocks)."""
+        return self.num_layers * self.ut_steps
 
     @property
     def head_dim(self) -> int:
@@ -133,9 +149,10 @@ class StageSpec:
          data_fields=["keys", "values", "length"], meta_fields=[])
 @dataclass
 class KVCache:
-    """Per-stage KV cache: stacked over the stage's layers.
+    """Per-stage KV cache: stacked over the stage's planes (its layers,
+    once for each pass of a looped model: ``ModelConfig.kv_planes``).
 
-    keys/values: ``[num_layers, batch, num_kv_heads, max_seq, head_dim]``
+    keys/values: ``[planes, batch, num_kv_heads, max_seq, head_dim]``
     — **head-major**, so each kv head's cache is a contiguous ``[seq, hd]``
     plane: the layout the Pallas flash kernel streams HBM→VMEM per head,
     and the one XLA tiles best (the trailing ``[seq, hd]`` dims map onto
@@ -160,7 +177,11 @@ class KVCache:
         # to pad (see pad_cache_capacity below)
         max_seq = pad_cache_capacity(max_seq or cfg.max_seq_len)
         dtype = dtype or cfg.dtype
-        shape = (num_layers, batch, cfg.num_kv_heads, max_seq, cfg.head_dim)
+        # ``num_layers`` is the stage's layer count; a looped model
+        # (one stage only) holds each of them ``ut_steps`` times:
+        # cfg.kv_planes for the whole model
+        shape = (num_layers * cfg.ut_steps, batch, cfg.num_kv_heads,
+                 max_seq, cfg.head_dim)
         return KVCache(
             keys=jnp.zeros(shape, dtype),
             values=jnp.zeros(shape, dtype),
@@ -209,6 +230,21 @@ class StageParams:
             (self.layers, self.embed, self.final_norm, self.lm_head)))
 
 
+def require_single_pass(cfg: ModelConfig, what: str) -> None:
+    """Refuse a looped model (``ut_steps > 1``) where ``what`` visits a
+    layer once: a stage of a pipeline owns a layer range and would have
+    to be visited once a pass, a draft or a sequence-parallel forward
+    sizes its cache by layers.  Called where such a thing is built, so a
+    looped model is refused in a sentence and never run wrongly."""
+    if cfg.ut_steps > 1:
+        raise ValueError(
+            f"{what} does not support a looped model (family "
+            f"{cfg.family!r}, ut_steps={cfg.ut_steps}): every pass would "
+            f"have to visit it again, and it is built to be visited once. "
+            f"Serve it on one stage (serve --batch-slots, with or without "
+            f"--tp)")
+
+
 def slice_stage(full: StageParams, cfg: ModelConfig, spec: StageSpec) -> StageParams:
     """Cut a full-model StageParams into the slice owned by ``spec``.
 
@@ -216,6 +252,8 @@ def slice_stage(full: StageParams, cfg: ModelConfig, spec: StageSpec) -> StagePa
     export + zip + ship (``server.py:910-957``): shard manifests instead of
     ONNX zips, realized as array slices.
     """
+    if spec.num_stages > 1:
+        require_single_pass(cfg, "a pipeline of stages")
     layers = jax.tree.map(lambda x: x[spec.layer_start:spec.layer_end], full.layers)
     # Tied embeddings: the last stage needs the token table for the LM head.
     needs_embed = spec.is_first or (spec.is_last and cfg.tie_embeddings)

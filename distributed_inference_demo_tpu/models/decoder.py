@@ -9,6 +9,10 @@ inferred at SURVEY.md §2.2), a single pure ``stage_forward`` covers:
 - **mixtral family** (Mixtral-8x7B): llama blocks with top-k routed MoE MLP.
 - **olmoe family** (OLMoE-1B-7B): the same routed MLP with the router's
   probabilities kept as they are, and RMSNorm over the q and k projections.
+- **ouro family** (Ouro-2.6B): llama blocks that also norm each sublayer's
+  output (``sandwich_norm``), the whole stack run ``ut_steps`` times a
+  token with the final norm after every pass and K/V planes of each pass's
+  own.
 
 The per-stage forward is a single ``lax.scan`` over stacked layer weights —
 XLA compiles one loop body reused across layers, keeping compile time flat in
@@ -28,7 +32,8 @@ from ..ops.quant import dense
 from ..ops.stacked import LayerOf
 from ..ops.norms import layer_norm, rms_norm
 from ..ops.rope import apply_rope
-from .base import KVCache, ModelConfig, StageParams, StageSpec
+from .base import (KVCache, ModelConfig, StageParams, StageSpec,
+                   require_single_pass)
 
 
 # ---------------------------------------------------------------------------
@@ -127,6 +132,19 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
     if cfg.qk_norm:  # olmoe: RMSNorm over the whole q / k projection
         p["q_norm_w"] = jnp.ones((L, nh * hd), dt)
         p["k_norm_w"] = jnp.ones((L, nkv * hd), dt)
+    if cfg.sandwich_norm:
+        # ouro: RMSNorm on each sublayer's output.  Seeded at (2 L)^-1/2
+        # and not at one: a pass's 2 L normed outputs then sum to about
+        # the size of the stream that entered it.  At one they bury it
+        # (the stream grows tenfold a pass at L = 48) and the seeded
+        # loop amplifies any difference in its input about 2.5 times a
+        # pass (bf16 rounding read against float32, on the chip, after
+        # 1 / 2 / 3 / 4 passes: 0.086 / 0.089 / 0.218 / 0.579, PR 34),
+        # which no model trained to refine one state over its passes
+        # does.  A loaded checkpoint brings its own.
+        gain = (2 * cfg.num_layers) ** -0.5
+        p["attn_post_norm_w"] = jnp.full((L, H), gain, dt)
+        p["mlp_post_norm_w"] = jnp.full((L, H), gain, dt)
     if cfg.num_experts > 0:  # mixtral / olmoe MoE
         E = cfg.num_experts
         p["router"] = _dense_init(keys[4], (L, H, E), dt)
@@ -432,6 +450,8 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
         h = layer_norm(x, lp["attn_norm_w"], lp["attn_norm_b"], cfg.norm_eps)
     else:
         h = rms_norm(x, lp["attn_norm_w"], cfg.norm_eps)
+    if x.dtype != cfg.dtype:  # a looped model's float32 stream
+        h = h.astype(cfg.dtype)
 
     q = dense(h, lp["wq"], "bsh,hd->bsd")
     k = dense(h, lp["wk"], "bsh,hd->bsd")
@@ -459,17 +479,25 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
         attn = jax.lax.psum(attn, tp_axis)
     if cfg.attn_layernorm:
         attn = attn + lp["bo"]
+    if cfg.sandwich_norm:
+        attn = rms_norm(attn, lp["attn_post_norm_w"], cfg.norm_eps)
     x = x + attn
 
     if cfg.attn_layernorm:
         h = layer_norm(x, lp["mlp_norm_w"], lp["mlp_norm_b"], cfg.norm_eps)
     else:
         h = rms_norm(x, lp["mlp_norm_w"], cfg.norm_eps)
+    if x.dtype != cfg.dtype:
+        h = h.astype(cfg.dtype)
     if moe_stats:
         y, rows = _moe_routed(cfg, lp, h, tp_axis)
+    else:
+        y, rows = _mlp(cfg, lp, h, tp_axis, ep_axis), None
+    if cfg.sandwich_norm:
+        y = rms_norm(y, lp["mlp_post_norm_w"], cfg.norm_eps)
+    if moe_stats:
         return x + y, k_cache, v_cache, rows
-    x = x + _mlp(cfg, lp, h, tp_axis, ep_axis)
-    return x, k_cache, v_cache
+    return x + y, k_cache, v_cache
 
 
 def stage_forward(
@@ -522,6 +550,50 @@ def stage_forward(
             slopes, jax.lax.axis_index(tp_axis) * nh_local, nh_local, axis=0)
     cache_start = cache.length
 
+    def final_norm(x):
+        if cfg.attn_layernorm:
+            return layer_norm(x, params.final_norm["w"],
+                              params.final_norm["b"], cfg.norm_eps)
+        return rms_norm(x, params.final_norm["w"], cfg.norm_eps)
+
+    # a looped model (ouro): the layer scan below is the body of an outer
+    # scan over ``ut_steps`` passes, so the program holds ONE layer body
+    # however many passes run.  Pass ``t`` reads and writes its own planes
+    # of the cache, ``t * L + l``, through the same seam a layer index
+    # goes through; the final norm closes every pass.  Static Python
+    # branches: with one pass the traced program is what it always was.
+    T = cfg.ut_steps
+    planes = jax.tree.leaves(cache.keys)[0].shape[0]
+    n_layers = planes if T == 1 else params.layers["attn_norm_w"].shape[0]
+    if T > 1:
+        if not (spec.is_first and spec.is_last):
+            require_single_pass(cfg, "a pipeline stage")
+        if not cache_in_carry:
+            require_single_pass(cfg, "the training layout of the cache")
+        # T x L planes: a cache that is read again (every serving path;
+        # KVCache.create and the page pools are sized so).  L planes: a
+        # scratch for ONE call over a whole sequence from position 0
+        # (scoring): a pass overwrites what the pass before left and
+        # reads back only what it wrote, which is exact there and
+        # nowhere else, so no engine builds one.
+        if planes not in (n_layers, T * n_layers):
+            raise ValueError(
+                f"a looped model's cache holds {T} x {n_layers} planes "
+                f"(ModelConfig.kv_planes), or {n_layers} as a scratch for "
+                f"one call over a whole sequence; got {planes}")
+    own_planes = planes == T * n_layers
+    if T > 1:
+        # The stream rides the layer and pass scans in float32.  Every
+        # matmul still takes the model's dtype (``_layer`` casts each
+        # norm's output back) and the sublayers' outputs are added as
+        # they come; what goes is the rounding of a stream that 2L
+        # sublayers grow, 2L times a pass, which the next pass reads as
+        # its input.  On the chip at ouro-2.6b's width, log-probabilities
+        # over the whole vocabulary against the float32 reference
+        # (tools/model_parity.py, PR 34): 0.122 with a bf16 stream,
+        # 0.054-0.057 with this one, against 0.086 for ONE bf16 pass.
+        x = x.astype(jnp.float32)
+
     if cache_in_carry:
         # Inference layout: the full stacked cache rides the scan CARRY and
         # each iteration dynamic-slices its layer plane in/out — XLA keeps
@@ -549,25 +621,42 @@ def stage_forward(
                           if k not in whole}
         stacked_cache = getattr(attn_impl, "stacked_cache", False)
 
-        def body(carry, scanned):
-            x, K, V = carry
-            lp, li = scanned
-            lp = dict(lp, **{k: LayerOf(params.layers[k], li)
-                             for k in whole})
-            k_of, v_of = LayerOf(K, li), LayerOf(V, li)
-            kc, vc = ((k_of, v_of) if stacked_cache
-                      else (k_of.sliced(), v_of.sliced()))
-            x, kc, vc, *rows = _layer(cfg, lp, x, kc, vc, positions,
-                                      cache_start, slopes, tp_axis,
-                                      attn_impl, ep_axis, moe_stats)
-            K, V = ((kc.stack, vc.stack) if stacked_cache
-                    else (k_of.updated(kc), v_of.updated(vc)))
-            return (x, K, V), (rows[0] if rows else None)
+        def run_layers(x, kv, plane0):
+            def body(carry, scanned):
+                x, K, V = carry
+                lp, li = scanned
+                lp = dict(lp, **{k: LayerOf(params.layers[k], li)
+                                 for k in whole})
+                plane = li if plane0 is None else plane0 + li
+                k_of, v_of = LayerOf(K, plane), LayerOf(V, plane)
+                kc, vc = ((k_of, v_of) if stacked_cache
+                          else (k_of.sliced(), v_of.sliced()))
+                x, kc, vc, *rows = _layer(cfg, lp, x, kc, vc, positions,
+                                          cache_start, slopes, tp_axis,
+                                          attn_impl, ep_axis, moe_stats)
+                K, V = ((kc.stack, vc.stack) if stacked_cache
+                        else (k_of.updated(kc), v_of.updated(vc)))
+                return (x, K, V), (rows[0] if rows else None)
 
-        n_layers = jax.tree.leaves(cache.keys)[0].shape[0]
-        (x, new_k, new_v), expert_rows = jax.lax.scan(
-            body, (x, cache.keys, cache.values),
-            (scanned_layers, jnp.arange(n_layers)))
+            (x, K, V), rows = jax.lax.scan(
+                body, (x, *kv), (scanned_layers, jnp.arange(n_layers)))
+            return x, (K, V), rows
+
+        kv = (cache.keys, cache.values)
+        if T == 1:  # a plane's index is its layer's
+            x, (new_k, new_v), expert_rows = run_layers(x, kv, None)
+        else:
+            def one_pass(carry, t):
+                with jax.named_scope("ut_pass"):
+                    x, kv, rows = run_layers(
+                        *carry, t * n_layers if own_planes else 0)
+                    return (final_norm(x), kv), rows
+
+            (x, (new_k, new_v)), expert_rows = jax.lax.scan(
+                one_pass, (x, kv), jnp.arange(T))
+            if moe_stats:  # [T, L, E] -> one row a layer call
+                expert_rows = expert_rows.reshape(
+                    (T * n_layers,) + expert_rows.shape[2:])
     else:
         assert not moe_stats, "moe_stats is for the inference layout"
         # Training layout: per-layer cache planes as xs/ys.  Under
@@ -586,11 +675,9 @@ def stage_forward(
     if spec.is_last:
         if last_logits_only:
             x = x[:, -1:, :]
-        if cfg.attn_layernorm:
-            x = layer_norm(x, params.final_norm["w"], params.final_norm["b"],
-                           cfg.norm_eps)
-        else:
-            x = rms_norm(x, params.final_norm["w"], cfg.norm_eps)
+        if T == 1:  # a looped model's last pass closed with it already
+            x = final_norm(x)
+        x = x.astype(cfg.dtype)
         head = (params.embed["tokens"].T if cfg.tie_embeddings
                 else params.lm_head["w"])
         x = jnp.einsum("bsh,hv->bsv", x, head)

@@ -239,6 +239,14 @@ _SD_MAPPERS = {
 def params_from_state_dict(raw: Dict[str, np.ndarray],
                            cfg: ModelConfig) -> StageParams:
     """Family dispatch for HF-layout state dicts (numpy leaves)."""
+    if cfg.family == "ouro":
+        raise NotImplementedError(
+            "no state-dict mapper for family 'ouro': the checkpoint's "
+            "tensor names (the two output norms a block, the exit gate) "
+            "could not be checked against a published checkpoint when the "
+            "family was added, and a guessed name map loads wrong weights "
+            "without a word; serve it on seeded weights, or add the map "
+            "to models/loader.py from the checkpoint's own index")
     if cfg.family not in _SD_MAPPERS:
         raise NotImplementedError(f"no state-dict mapper for {cfg.family!r}")
     return _SD_MAPPERS[cfg.family](raw, cfg)

@@ -78,6 +78,18 @@ MODEL_REGISTRY = {
         max_seq_len=4096, rope_theta=10000.0, norm_eps=1e-5,
         num_experts=64, experts_per_token=8, qk_norm=True,
         norm_topk_prob=False),
+    # --- ouro (ByteDance/Ouro-2.6B config.json; arXiv:2510.25741): a
+    # LOOPED decoder.  The 48 llama-shaped layers (MHA, rope, SwiGLU, no
+    # bias) run total_ut_steps = 4 times a token with the same weights,
+    # each block norms its two sublayers' outputs as well as their inputs,
+    # the final norm closes every pass, and a pass keeps its own K/V:
+    # 192 planes.  early_exit_threshold is 1.0: all four passes always
+    # run (the exit gate decides nothing and is not evaluated) ---
+    "ouro-2.6b": ModelConfig(
+        family="ouro", vocab_size=49152, hidden_size=2048, num_layers=48,
+        num_heads=16, num_kv_heads=16, intermediate_size=5632,
+        max_seq_len=65536, rope_theta=1000000.0, norm_eps=1e-6,
+        ut_steps=4, sandwich_norm=True),
     # --- tiny configs for tests and virtual-mesh dry runs ---
     "llama-test": ModelConfig(
         family="llama", vocab_size=256, hidden_size=64, num_layers=4,
@@ -106,6 +118,12 @@ MODEL_REGISTRY = {
         num_heads=4, num_kv_heads=4, intermediate_size=32, max_seq_len=128,
         num_experts=8, experts_per_token=2, qk_norm=True,
         norm_topk_prob=False, dtype_name="float32"),
+    # 4 layers x 3 passes: a test can tell the passes from the layers
+    "ouro-test": ModelConfig(
+        family="ouro", vocab_size=256, hidden_size=64, num_layers=4,
+        num_heads=4, num_kv_heads=4, intermediate_size=128, max_seq_len=128,
+        norm_eps=1e-6, ut_steps=3, sandwich_norm=True,
+        dtype_name="float32"),
 }
 
 

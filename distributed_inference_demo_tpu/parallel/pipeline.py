@@ -27,7 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..models.base import KVCache, ModelConfig, StageParams, StageSpec
+from ..models.base import (KVCache, ModelConfig, StageParams, StageSpec,
+                           require_single_pass)
 from .sharding import stage_param_spec_tree
 
 
@@ -196,6 +197,7 @@ def make_pipeline_generate_fn(cfg: ModelConfig, mesh: Mesh, *,
     Composes with TP when the mesh has a tp axis > 1 (Megatron shard_map
     inside each stage).
     """
+    require_single_pass(cfg, "the circular pipeline")
     from ..models.decoder import stage_forward
     from ..ops.sampling import SamplingParams, sample_logits
 
@@ -361,6 +363,7 @@ def make_pipeline_train_step(cfg: ModelConfig, mesh: Mesh, optimizer,
     (params, opt_state, loss)`` where ids/targets are
     ``[batch, seq]`` int32 on host; batch must divide by dp*num_microbatches.
     """
+    require_single_pass(cfg, "the circular pipeline")
     use_tp = mesh.shape.get("tp", 1) > 1
     use_dp = mesh.shape.get("dp", 1) > 1
     axis_names = set(mesh.axis_names)

@@ -24,7 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..models.base import KVCache, ModelConfig, StageSpec
+from ..models.base import (KVCache, ModelConfig, StageSpec,
+                           require_single_pass)
 from ..models.decoder import stage_forward
 from ..ops.attention import update_kv_cache
 from ..ops.norms import layer_norm, rms_norm
@@ -160,6 +161,7 @@ def _make_ring_cores(cfg: ModelConfig, spec: StageSpec, s_loc: int,
     carry is ``(keys, values, kv_pos, plen, length, tok)``: ``plen``
     rides along explicitly so a decode dispatch needs no prompt shape
     (the fused path closes over it; the stream path cannot)."""
+    require_single_pass(cfg, "ring sequence parallelism")
     cache_dtype = kv_dtype if kv_dtype is not None else cfg.dtype
 
     def prefill_core(params, ids, rng):

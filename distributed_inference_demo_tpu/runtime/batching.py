@@ -72,15 +72,17 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.base import (KVCache, ModelConfig, StageParams,
-                           StageSpec, pad_cache_capacity)
+                           StageSpec, pad_cache_capacity,
+                           require_single_pass)
 from ..ops.sampling import SamplingParams, filtered_logits, sample_logits
 from ..telemetry import postmortem
 from ..telemetry import profiling as _profiling
 from ..telemetry.anomaly import AnomalyMonitor
 from ..telemetry.flightrecorder import get_flight_recorder
 from ..telemetry.slo import get_slo_ledger, sanitize_tenant
-from ..telemetry.tracing import (MOE_DISPATCH_FIELDS, DispatchTrace,
-                                 MoeCounters, TraceRecorder,
+from ..telemetry.tracing import (LOOP_DISPATCH_FIELDS,
+                                 MOE_DISPATCH_FIELDS, DispatchTrace,
+                                 LoopCounters, MoeCounters, TraceRecorder,
                                  to_chrome_trace)
 from .engine import (GenerationResult, check_capacity,
                      make_paged_chunk_programs, validate_prefill_chunk)
@@ -381,6 +383,7 @@ class ContinuousBatchingEngine:
         if (draft_cfg is None) != (draft_params is None):
             raise ValueError("draft_cfg and draft_params go together")
         if draft_cfg is not None:
+            require_single_pass(draft_cfg, "the draft side of speculation")
             if draft_cfg.vocab_size != cfg.vocab_size:
                 raise ValueError(
                     f"draft vocab ({draft_cfg.vocab_size}) != target vocab "
@@ -484,7 +487,7 @@ class ContinuousBatchingEngine:
             cfg, self.spec, mesh, params, bt, record=self.attn_paths)
         from ..ops.quant import alloc_kv_pool
         self._pk, self._pv = alloc_kv_pool(
-            (cfg.num_layers, N, cfg.num_kv_heads, bt, cfg.head_dim),
+            (cfg.kv_planes, N, cfg.num_kv_heads, bt, cfg.head_dim),
             self.kv_dtype, page_dtype, pool_sharding)
         self._tables = np.full((B, self._table_width), N, np.int32)
         # write_row_to_pages survives for the DRAFT side only: the draft
@@ -1046,7 +1049,7 @@ class ContinuousBatchingEngine:
             ND = self._dmgr.num_blocks
             self._dpage_sentinel = ND
             self._dpk, self._dpv = alloc_kv_pool(
-                (draft_cfg.num_layers, ND, draft_cfg.num_kv_heads, bt,
+                (draft_cfg.kv_planes, ND, draft_cfg.num_kv_heads, bt,
                  draft_cfg.head_dim), self.kv_dtype, page_dtype,
                 dpool_sharding)
             self._dtables = np.full((B, self._table_width), ND, np.int32)
@@ -1279,8 +1282,15 @@ class ContinuousBatchingEngine:
         # MoeCounters; mixed dispatches only: the path that is served)
         moe = cfg.num_experts > 0 and self._mixed_step is not None
         self.moe_counters = MoeCounters(cfg.num_experts) if moe else None
+        # a looped model counts its passes (tracing.LoopCounters); a
+        # one-pass model's record and /stats are as they were
+        loop = cfg.ut_steps > 1 and self._mixed_step is not None
+        self.loop_counters = LoopCounters(
+            cfg.ut_steps, cfg.kv_planes,
+            self.kv_cache.block_bytes // bt) if loop else None
         self.dispatch_trace = DispatchTrace(
-            MOE_DISPATCH_FIELDS if moe else ())
+            (MOE_DISPATCH_FIELDS if moe else ())
+            + (LOOP_DISPATCH_FIELDS if loop else ()))
 
         # (mixed mode never dispatches the serialized step programs —
         # its two mixed_step variants compile on first use instead)
@@ -1507,7 +1517,7 @@ class ContinuousBatchingEngine:
             k_blocks = np.asarray(k_blocks)
             v_blocks = np.asarray(v_blocks)
         bt = self.kv_cache.block_tokens
-        want = (self.cfg.num_layers, self.cfg.num_kv_heads, bt,
+        want = (self.cfg.kv_planes, self.cfg.num_kv_heads, bt,
                 self.cfg.head_dim)
         if (k_blocks.shape != v_blocks.shape or k_blocks.ndim != 5
                 or k_blocks.shape[1:] != want):
@@ -2137,6 +2147,8 @@ class ContinuousBatchingEngine:
             out["dispatch_trace"] = self.dispatch_trace.snapshot()
             if self.moe_counters is not None:
                 out["moe"] = self.moe_counters.snapshot()
+            if self.loop_counters is not None:
+                out["loop"] = self.loop_counters.snapshot()
         if self.disagg_stats["premigrated_requests"]:
             out["disagg"] = dict(self.disagg_stats)
         if self.resume_stats["requests"]:
@@ -2221,6 +2233,8 @@ class ContinuousBatchingEngine:
         self.dispatch_trace.reset()
         if self.moe_counters is not None:
             self.moe_counters.reset()
+        if self.loop_counters is not None:
+            self.loop_counters.reset()
         self._completed = 0
         for res in self._lat.values():
             res.clear()
@@ -3395,7 +3409,10 @@ class ContinuousBatchingEngine:
             record.update(self.moe_counters.add(
                 acc[:E], int(acc[E]), int(acc[E + 1]), int(acc[E + 2]),
                 (prefill_tokens + (n_active + n_final) * steps)
-                * self.cfg.experts_per_token * self.cfg.num_layers))
+                * self.cfg.experts_per_token * self.cfg.num_layers
+                * self.cfg.ut_steps))
+        if self.loop_counters is not None:
+            record.update(self.loop_counters.add(bool(packed), steps))
         cs = self.chunk_stats
         cs["mixed_dispatches"] += 1
         cs["mixed_prefill_tokens"] += prefill_tokens

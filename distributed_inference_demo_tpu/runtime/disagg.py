@@ -411,7 +411,7 @@ class PrefillWorker:
         self.kv_cache = PagedKVCacheManager.for_model(
             cfg, n_blocks, bt, kv_dtype=self.kv_dtype)
         N = self.kv_cache.num_blocks
-        self._pk = alloc_kv_pages((cfg.num_layers, N, cfg.num_kv_heads,
+        self._pk = alloc_kv_pages((cfg.kv_planes, N, cfg.num_kv_heads,
                                    bt, cfg.head_dim), self.kv_dtype,
                                   cfg.dtype)
         self._pv = jax.tree.map(jnp.zeros_like, self._pk)

@@ -48,7 +48,8 @@ import numpy as np
 from ..comm import wire
 from ..comm.transport import (BaseTransport, TransportTimeout,
                               record_corrupt_frame)
-from ..models.base import KVCache, ModelConfig, StageParams, StageSpec
+from ..models.base import (KVCache, ModelConfig, StageParams, StageSpec,
+                           require_single_pass)
 from ..ops.sampling import SamplingParams, sample_logits
 from ..telemetry import postmortem
 from ..telemetry import profiling as _profiling
@@ -89,6 +90,8 @@ class StageRuntime:
         ``DWT_STAGE_KV_ROWS`` = 16 rows' worth); exhaustion raises
         loudly rather than silently evicting live KV.  Paged is the
         only layout ("dense" was removed — docs/DESIGN.md §14)."""
+        if spec.num_stages > 1:
+            require_single_pass(cfg, "a pipeline of stages")
         self.cfg = cfg
         self.spec = spec
         self.max_seq = max_seq
@@ -133,8 +136,9 @@ class StageRuntime:
             from ..ops.quant import alloc_kv_pool
             page_dtype = self.kv_cache_dtype or cfg.dtype
             self._pk, self._pv = alloc_kv_pool(
-                (spec.num_layers, n_blocks, cfg.num_kv_heads, bt,
-                 cfg.head_dim), self.kv_dtype, page_dtype, pool_sharding)
+                (spec.num_layers * cfg.ut_steps, n_blocks,
+                 cfg.num_kv_heads, bt, cfg.head_dim), self.kv_dtype,
+                page_dtype, pool_sharding)
             self._sentinel = n_blocks
             self._pool_free = list(range(n_blocks - 1, -1, -1))
             self._tables: Dict[int, np.ndarray] = {}
@@ -218,8 +222,8 @@ class StageRuntime:
         # pool feeds the HBM watermark ledger per chunk served.
         self._prof = _profiling.get_profiler()
         self._kv_token_bytes = _profiling.kv_dispatch_bytes(
-            1, spec.num_layers, cfg.num_kv_heads, cfg.head_dim,
-            self.kv_dtype if self.kv_layout == "paged" else None,
+            1, spec.num_layers * cfg.ut_steps, cfg.num_kv_heads,
+            cfg.head_dim, self.kv_dtype if self.kv_layout == "paged" else None,
             (self.kv_cache_dtype or cfg.dtype))
 
     def _cache_for(self, rid: int, batch: int) -> KVCache:

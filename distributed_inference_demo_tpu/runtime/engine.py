@@ -600,7 +600,7 @@ class InferenceEngine:
         self._prof = _profiling.get_profiler()
         # dense-cache attribution: KV bytes one (row, token) touches
         self._kv_token_bytes = _profiling.kv_dispatch_bytes(
-            1, cfg.num_layers, cfg.num_kv_heads, cfg.head_dim,
+            1, cfg.kv_planes, cfg.num_kv_heads, cfg.head_dim,
             None, self.kv_cache_dtype)
 
     # ------------------------------------------------------------------

@@ -72,7 +72,7 @@ class PagedKVBackend:
         self.block_tokens = self.mgr.block_tokens
         page_dtype = dtype if dtype is not None else cfg.dtype
         self._pk = alloc_kv_pages(
-            (cfg.num_layers, self.mgr.num_blocks, cfg.num_kv_heads,
+            (cfg.kv_planes, self.mgr.num_blocks, cfg.num_kv_heads,
              self.mgr.block_tokens, cfg.head_dim), self.kv_dtype,
             page_dtype)
         self._pv = jax.tree.map(jnp.zeros_like, self._pk)
@@ -222,7 +222,7 @@ def make_kv_backend(cfg, kv_cache_blocks: Optional[int],
     # data + scale sidecar), not the full-width itemsize — one shared
     # owner with PagedKVCacheManager so admission and accounting agree
     dtype_ = dtype if dtype is not None else cfg.dtype
-    block_bytes = (2 * int(cfg.num_layers) * int(cfg.num_kv_heads)
+    block_bytes = (2 * int(cfg.kv_planes) * int(cfg.num_kv_heads)
                    * int(block_tokens)
                    * kv_token_head_bytes(int(cfg.head_dim), kv_dtype,
                                          dtype_))

@@ -153,12 +153,12 @@ class KVCacheManager:
         for the engines that means "cache off", and an env knob
         documented as a ceiling must never crash serve startup."""
         dtype = dtype if dtype is not None else cfg.dtype
-        block_bytes = (2 * int(cfg.num_layers) * int(cfg.num_kv_heads)
+        block_bytes = (2 * int(cfg.kv_planes) * int(cfg.num_kv_heads)
                        * int(block_tokens) * int(cfg.head_dim)
                        * np.dtype(dtype).itemsize)
         if apply_byte_budget(int(num_blocks), block_bytes) < 1:
             return None
-        return cls(cfg.num_layers, cfg.num_kv_heads, cfg.head_dim,
+        return cls(cfg.kv_planes, cfg.num_kv_heads, cfg.head_dim,
                    num_blocks, block_tokens, dtype)
 
     # ------------------------------------------------------------------

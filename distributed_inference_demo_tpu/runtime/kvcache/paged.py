@@ -110,7 +110,7 @@ class PagedKVCacheManager:
                   dtype=None,
                   kv_dtype: Optional[str] = None) -> "PagedKVCacheManager":
         dtype = dtype if dtype is not None else cfg.dtype
-        return cls(cfg.num_layers, cfg.num_kv_heads, cfg.head_dim,
+        return cls(cfg.kv_planes, cfg.num_kv_heads, cfg.head_dim,
                    num_blocks, block_tokens, dtype, kv_dtype=kv_dtype)
 
     # ------------------------------------------------------------------

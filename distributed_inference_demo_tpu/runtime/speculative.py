@@ -48,7 +48,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.base import KVCache, ModelConfig, StageParams, StageSpec
+from ..models.base import (KVCache, ModelConfig, StageParams, StageSpec,
+                           require_single_pass)
 from ..models.decoder import stage_forward
 from ..ops.sampling import SamplingParams, filtered_logits, sample_logits
 from .engine import GenerationResult, check_capacity
@@ -337,6 +338,7 @@ class SpeculativeEngine:
         from .engine import validate_prefill_chunk
         self.prefill_chunk = validate_prefill_chunk(prefill_chunk,
                                                     self.max_seq)
+        require_single_pass(draft_cfg, "the draft side of speculation")
         self.spec = StageSpec(0, 1, 0, cfg.num_layers)
         self.draft_spec = StageSpec(0, 1, 0, draft_cfg.num_layers)
         self.mesh = mesh

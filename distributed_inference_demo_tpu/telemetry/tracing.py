@@ -170,7 +170,45 @@ DISPATCH_FIELDS = (("seq", "t_launch", "t_done") + DISPATCH_PHASES
 # calls, and the fullest expert's rows in any one layer call
 MOE_DISPATCH_FIELDS = ("moe_rows", "moe_valid_rows", "moe_touched",
                        "moe_load_max")
+# what a looped model (``ut_steps > 1``) adds: the passes of the layer
+# stack the execution ran, ((1 if it packed a segment else 0) + steps)
+# x ut_steps
+LOOP_DISPATCH_FIELDS = ("ut_passes",)
 _DISPATCH_RING = 128       # x ~120 bytes a row: /stats stays under 16 KB
+
+
+class LoopCounters:
+    """The ``/stats.loop`` section of a looped model: what a token costs
+    (``ut_steps`` passes, ``kv_planes`` planes, ``kv_bytes_per_token``)
+    and running sums of the passes run, the slab's apart from the decode
+    steps'.  Scheduler thread writes (one :meth:`add` a mixed dispatch),
+    ``/stats`` reads."""
+
+    def __init__(self, ut_steps: int, kv_planes: int,
+                 kv_bytes_per_token: int):
+        self.ut_steps = ut_steps
+        self.kv_planes = kv_planes
+        self.kv_bytes_per_token = kv_bytes_per_token
+        self.reset()
+
+    def reset(self) -> None:
+        self.dispatches = 0
+        self.slab_passes = 0
+        self.decode_passes = 0
+
+    def add(self, slab: bool, steps: int) -> dict:
+        """Fold one execution in; returns its ``LOOP_DISPATCH_FIELDS``."""
+        self.dispatches += 1
+        self.slab_passes += self.ut_steps * slab
+        self.decode_passes += self.ut_steps * steps
+        return dict(ut_passes=self.ut_steps * (slab + steps))
+
+    def snapshot(self) -> dict:
+        return {"ut_steps": self.ut_steps, "kv_planes": self.kv_planes,
+                "kv_bytes_per_token": self.kv_bytes_per_token,
+                "dispatches": self.dispatches,
+                "slab_passes": self.slab_passes,
+                "decode_passes": self.decode_passes}
 
 
 class MoeCounters:
@@ -238,7 +276,8 @@ class DispatchTrace:
 
     def __init__(self, extra_fields: tuple = ()):
         """``extra_fields``: columns after :data:`DISPATCH_FIELDS`
-        (``MOE_DISPATCH_FIELDS`` for a model with experts), passed to
+        (``MOE_DISPATCH_FIELDS`` for a model with experts,
+        ``LOOP_DISPATCH_FIELDS`` for a looped one), passed to
         :meth:`commit` by name."""
         from jax.profiler import TraceAnnotation
         self._annotate = TraceAnnotation

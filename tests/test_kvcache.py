@@ -293,7 +293,7 @@ def test_env_knobs_and_byte_budget(monkeypatch):
     # a ceiling below ONE block disables the cache (for_model -> None)
     # instead of crashing engine construction — the knob is a ceiling
     import types
-    cfg = types.SimpleNamespace(num_layers=2, num_kv_heads=2, head_dim=4,
+    cfg = types.SimpleNamespace(kv_planes=2, num_kv_heads=2, head_dim=4,
                                 dtype=np.float32)
     monkeypatch.setenv("DWT_KVCACHE_BYTES", "1")
     assert KVCacheManager.for_model(cfg, 8, 4) is None

@@ -55,5 +55,5 @@ def test_the_benchmarks_own_suite_passes():
         [sys.executable, "-m", "pytest", str(BENCH / "tests"), "-q",
          "-p", "no:cacheprovider"],
         cwd=BENCH.parent, env=env, capture_output=True, text=True,
-        timeout=120)
+        timeout=300)
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-1000:]

@@ -71,8 +71,11 @@ def compile_mixed_step(model: str, blocks: int, args, slab=True):
                                         platform="tpu")
     sharding = jax.sharding.SingleDeviceSharding(topo.devices[0])
     cfg = get_model_config(model)
+    # a name without a quant suffix is served at its own dtype
+    # (quantize=True alone would make int8 leaves of a bf16 model)
     params = jax.eval_shape(
-        lambda: init_full_params(jax.random.PRNGKey(0), cfg, quantize=True))
+        lambda: init_full_params(jax.random.PRNGKey(0), cfg,
+                                 quantize=cfg.quantization != "none"))
     pool = quant.alloc_kv_pool
 
     def abstract_pool(*a, **k):

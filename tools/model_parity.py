@@ -124,7 +124,7 @@ def served_logprobs(cfg, params, prompts, args):
         cfg, StageSpec(0, 1, 0, cfg.num_layers), None, params, bt,
         record=record)
     pk, pv = alloc_kv_pool(
-        (cfg.num_layers, b * W, cfg.num_kv_heads, bt, cfg.head_dim),
+        (cfg.kv_planes, b * W, cfg.num_kv_heads, bt, cfg.head_dim),
         args.kv_dtype, cfg.dtype)
     tables = jnp.arange(b * W, dtype=jnp.int32).reshape(b, W)
 
@@ -239,7 +239,7 @@ def main(argv=None) -> int:
     cfg = get_model_config(args.model)
     t0 = time.monotonic()
     params = init_full_params(jax.random.PRNGKey(args.seed), cfg,
-                              quantize=True)
+                              quantize=cfg.quantization != "none")
     prompts = np.stack([seeded_ids(args.seed * 1000 + 17 + i, args.prompt,
                                    cfg.vocab_size)
                         for i in range(args.batch)])
