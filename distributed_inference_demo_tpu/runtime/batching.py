@@ -61,6 +61,7 @@ import itertools
 import queue
 import threading
 import time
+import types
 import uuid
 from collections import deque
 from dataclasses import dataclass, field
@@ -1346,6 +1347,10 @@ class ContinuousBatchingEngine:
         self._rid_salt = uuid.uuid4().hex[:8]
         self._rid_counter = itertools.count()
         self._export_q: "deque" = deque()
+        # why the mixed loop's last dispatch was not followed by one
+        # prepared under it (tracing.AHEAD_MISS_REASONS); None while
+        # nothing executes
+        self._ahead_miss: Optional[str] = None
         self.migration_stats = {"exported_requests": 0,
                                 "imported_requests": 0,
                                 "detached_requests": 0}
@@ -3082,7 +3087,15 @@ class ContinuousBatchingEngine:
         dispatch carrying every active row's fused decode block plus
         packed prefill segments.  The serialized loop's per-iteration
         bookkeeping (cancel sweep, export service) rides along at the
-        same points."""
+        same points.
+
+        While that dispatch executes the thread prepares the next one
+        from the state this one will leave (``_plan_ahead``); when it
+        returns and the prepared dispatch is what this order would have
+        packed (``_ahead_refusal``), the thread launches it first and
+        drains afterwards, under the new execution, and stays in this
+        call.  Otherwise it drains and returns, and the next iteration
+        is this one again from the top."""
         trace = self.dispatch_trace
         trace.enter("intake")
         free = [i for i, s in enumerate(self._slots) if s is None]
@@ -3094,6 +3107,8 @@ class ContinuousBatchingEngine:
         while True:
             try:
                 if timeout is None:
+                    # nothing executes: the next dispatch is a first
+                    self._ahead_miss = None
                     with trace.idle():     # nobody's host time
                         req = self._queue.get()
                 else:
@@ -3155,20 +3170,55 @@ class ContinuousBatchingEngine:
         self._sweep_cancelled()
         self._service_exports()
         if not any(self._slots) and not self._adms:
+            self._ahead_miss = None
             return
-        record = self._dispatch_mixed(
-            [i for i, s in enumerate(self._slots) if s is None])
-        # committed here, not inside: `drain` then runs until the call
-        # has returned, so it holds the frame's teardown too (freeing
-        # the dispatch's device arrays drops the GIL, and the HTTP
-        # threads woken by the tokens just delivered take their turn)
-        if record is not None:
+        trace.enter("pack")
+        plan = self._pack_mixed(
+            [(s, len(s.tokens)) if s is not None else None
+             for s in self._slots], self._adms,
+            [i for i, s in enumerate(self._slots) if s is None],
+            self._rng, self._tables)
+        plan.how = self._ahead_miss or "first"
+        flight = self._launch_mixed(plan)
+        del plan       # the flight holds it, and lets it go when drained
+        while flight is not None:
+            nxt, why = self._plan_ahead(flight)
+            self._await_mixed(flight)
+            if nxt is not None:
+                # the validation is the next dispatch's whole `pack`
+                flight.t_done = trace.enter("pack")
+                why = self._ahead_refusal(flight)
+                if why is None:
+                    ahead = self._launch_mixed(nxt)
+                    with trace.ahead():
+                        record = self._drain_mixed(flight)
+                    trace.commit(phases=flight.phases, **record)
+                    flight = ahead
+                    continue
+                trace.enter("drain")
+            else:
+                flight.t_done = trace.enter("drain")
+            record = self._drain_mixed(flight)
+            self._ahead_miss = why
+            # committed here, after the flight is let go: `drain` then
+            # holds the teardown too (freeing the dispatch's device
+            # arrays drops the GIL, and the HTTP threads woken by the
+            # tokens just delivered take their turn)
+            flight = nxt = None
             trace.commit(**record)
 
-    def _dispatch_mixed(self, free: list) -> Optional[dict]:
-        """Build and run ONE mixed token-budget dispatch, then drain it;
-        returns the dispatch record's fields (``DispatchTrace.commit``)
-        if the dispatch reached the device.
+    def _pack_mixed(self, rows: list, adms: list, free: list, rng,
+                    tables: np.ndarray) -> types.SimpleNamespace:
+        """Pack ONE mixed token-budget dispatch from a view of the
+        scheduler's state and return it as a plan; **commits nothing**
+        (``_launch_mixed`` does, if and when the plan is launched).
+
+        The view: ``rows[i]`` is ``(request, tokens it holds)`` of slot
+        i or None, ``adms`` the admissions in flight, ``free`` the slots
+        a final may take, ``rng`` the sampler key, ``tables`` the
+        ``[B, W]`` decode tables.  ``_mixed_iteration`` passes the state
+        as it is; ``_plan_ahead`` the state the dispatch in flight will
+        leave.
 
         Packing policy (docs/DESIGN.md §19): every active decode row
         contributes its ``decode_block`` fused-loop tokens off the top
@@ -3182,15 +3232,12 @@ class ContinuousBatchingEngine:
         in pack order, then ONE decode split iff any row decodes —
         exactly the serialized path's spend, which keeps cold-start
         sampled streams bit-identical."""
-        trace = self.dispatch_trace
-        trace.enter("pack")
-        seq = trace.seq + 1  # this dispatch's number, if it gets there
         B = self.max_batch
         C = self.prefill_chunk
         W = self._table_width
         n_seg = self._mixed_seg_cap
-        n_active = sum(1 for s in self._slots if s is not None)
-        live0 = [i for i, s in enumerate(self._slots) if s is not None]
+        live0 = [i for i, s in enumerate(rows) if s is not None]
+        n_active = len(live0)
         spec_mixed = (self._mixed_pld_step is not None
                       or self._mixed_spec_step is not None)
         if spec_mixed:
@@ -3208,7 +3255,7 @@ class ContinuousBatchingEngine:
             k_vec = None
             room = max(0, self.mixed_token_budget
                        - n_active * self.decode_block)
-        want = min(n_seg, max(1, room // C)) if self._adms else 0
+        want = min(n_seg, max(1, room // C)) if adms else 0
         seg_ids = np.zeros((n_seg, C), np.int32)
         seg_tables = np.full((n_seg, W), self._page_sentinel, np.int32)
         seg_starts = np.zeros((n_seg,), np.int32)
@@ -3217,32 +3264,39 @@ class ContinuousBatchingEngine:
         seg_plen = np.zeros((n_seg,), np.int32)
         seg_keys = np.zeros((n_seg, 2), np.uint32)
         packed = []          # (row, admission, is_final, slot)
+        advance = []         # (admission, its start, its suffix) after
+        rewound = []         # requests whose §23 rewind this spends
+        free = list(free)
+        chunks = 0
         prefill_tokens = 0
         r = 0
-        for a in self._adms:
+        for a in adms:
             if r >= want:
                 break
             req = a["req"]
-            while r < want and len(a["suffix"]) > C:
-                seg_ids[r, :] = np.asarray(a["suffix"][:C], np.int32)
+            start, suffix = a["start"], a["suffix"]
+            while r < want and len(suffix) > C:
+                seg_ids[r, :] = np.asarray(suffix[:C], np.int32)
                 seg_tables[r] = req._pkv["table"]
-                seg_starts[r] = a["start"]
+                seg_starts[r] = start
                 packed.append((r, a, False, -1))
                 prefill_tokens += C
-                a["start"] += C
-                a["suffix"] = a["suffix"][C:]
-                self.chunk_stats["chunks"] += 1
+                start += C
+                suffix = suffix[C:]
+                chunks += 1
                 r += 1
-            if r >= want or len(a["suffix"]) > C:
+            if start != a["start"]:
+                advance.append((a, start, suffix))
+            if r >= want or len(suffix) > C:
                 break
             if not free:
                 continue     # final parked until a slot frees; later
                              # admissions may still pack their chunks
             slot = free.pop(0)
-            n = len(a["suffix"])
-            seg_ids[r, :n] = np.asarray(a["suffix"], np.int32)
+            n = len(suffix)
+            seg_ids[r, :n] = np.asarray(suffix, np.int32)
             seg_tables[r] = req._pkv["table"]
-            seg_starts[r] = a["start"]
+            seg_starts[r] = start
             seg_lens[r] = n
             seg_slot[r] = slot
             seg_plen[r] = len(req.prompt)
@@ -3251,28 +3305,29 @@ class ContinuousBatchingEngine:
             if getattr(req, "_rng_rewind", False):
                 # §23 sampled resume rewind — same hook as the
                 # serialized _finish_admission, mixed-dispatch shape
-                self._rng = jax.random.PRNGKey(self._seed)
-                req._rng_rewind = False
-            self._rng, sub = jax.random.split(self._rng)
+                rng = jax.random.PRNGKey(self._seed)
+                rewound.append(req)
+            rng, sub = jax.random.split(rng)
             seg_keys[r] = np.asarray(sub)
-            # decode inside this dispatch pages through the installed
-            # row — its table must be live BEFORE the dispatch; the
-            # radix adoption (below) waits until the pages hold data
-            self._tables[slot] = req._pkv["table"]
             packed.append((r, a, True, slot))
             prefill_tokens += n
             r += 1
-        with_finals = any(f for (_, _, f, _) in packed)
-        active_mask = np.array([s is not None for s in self._slots])
+        finals = [(a["req"], slot) for (_, a, f, slot) in packed if f]
+        active_mask = np.array([s is not None for s in rows])
         # budget: remaining tokens per pre-existing row; a freshly
         # installed final's row has max_new - 1 left (token #1 came
         # from its prefill logits)
         budget_vec = np.array(
-            [(s.max_new - len(s.tokens)) if s is not None else 0
-             for s in self._slots], np.int32)
-        for (_, a, is_final, slot) in packed:
-            if is_final:
-                budget_vec[slot] = a["req"].max_new - 1
+            [(s[0].max_new - s[1]) if s is not None else 0
+             for s in rows], np.int32)
+        # decode inside this dispatch pages through the installed
+        # row — its table must be live BEFORE the dispatch; the
+        # radix adoption (drain) waits until the pages hold data
+        if finals:
+            tables = tables.copy()
+        for req, slot in finals:
+            budget_vec[slot] = req.max_new - 1
+            tables[slot] = req._pkv["table"]
         if spec_mixed:
             # §22 rng rule: the decode split is spent iff spec rounds
             # run, i.e. iff a row was ALREADY active — a freshly
@@ -3283,23 +3338,72 @@ class ContinuousBatchingEngine:
             k_disp = (max(int(k_vec[i]) for i in live0) if live0
                       else int(self._spec_buckets[-1]))
             if num_rounds > 0:
-                self._rng, dec_sub = jax.random.split(self._rng)
+                rng, dec_sub = jax.random.split(rng)
             else:
                 dec_sub = jax.random.PRNGKey(0)
-        elif n_active > 0 or with_finals:
+        elif n_active > 0 or finals:
             # ONE decode split per dispatch that decodes — the
             # serialized loop's spend (it skips the split when no slot
             # is active)
             num_rounds, k_disp = 0, 0
-            self._rng, dec_sub = jax.random.split(self._rng)
+            rng, dec_sub = jax.random.split(rng)
         else:
             num_rounds, k_disp = 0, 0
             dec_sub = jax.random.PRNGKey(0)   # prefill-only: loop is
                                               # a 0-step no-op
         # what the decode kernel has to read: tokens of KV held by the
         # rows that decode here (host state only, never the device's)
-        kv_tokens = sum(len(s.prompt) + len(s.tokens)
-                        for s in self._slots if s is not None)
+        kv_tokens = (sum(len(s[0].prompt) + s[1]
+                         for s in rows if s is not None)
+                     + sum(len(req.prompt) for req, _ in finals))
+        return types.SimpleNamespace(
+            rows=rows, packed=packed, advance=advance, rewound=rewound,
+            finals=finals, chunks=chunks, rng=rng, dec_sub=dec_sub,
+            seg=(seg_ids, seg_tables, seg_starts, seg_lens, seg_slot,
+                 seg_plen, seg_keys),
+            tables=tables, active_mask=active_mask,
+            budget_vec=budget_vec,
+            prefill_tokens=prefill_tokens, n_active=n_active,
+            live0=live0, kv_tokens=kv_tokens, spec_mixed=spec_mixed,
+            k_vec=k_vec, k_disp=k_disp, num_rounds=num_rounds,
+            dev=None, how=None, ahead_s=0.0)
+
+    def _put_mixed(self, plan) -> tuple:
+        """The plan's arrays on the device, as ``mixed_step`` takes them
+        (once: a plan prepared ahead has them there when it is
+        launched).  Nothing packed: no segment array is transferred."""
+        if plan.dev is None:
+            put = jnp.asarray
+            if self.mesh is not None:
+                # where the call's other arguments live: every chip of
+                # the mesh holds a copy, so the call spreads nothing
+                rep = jax.sharding.NamedSharding(
+                    self.mesh, jax.sharding.PartitionSpec())
+                put = partial(jax.device_put, device=rep)
+            plan.dev = (
+                tuple(put(x) for x in plan.seg) if plan.packed else None,
+                put(plan.tables), put(plan.active_mask),
+                put(self._eos_scalar()), put(plan.budget_vec),
+                put(plan.dec_sub))
+        return plan.dev
+
+    def _launch_mixed(self, plan) -> Optional[types.SimpleNamespace]:
+        """Commit ``plan``'s effects on the scheduler's state (rng
+        spend, admissions' progress, installed table rows, counters,
+        the requests' dispatch stamps) and enqueue its program; returns
+        the dispatch in flight, or None if it failed its requests and
+        never reached the device."""
+        trace = self.dispatch_trace
+        seq = trace.launched + 1     # this dispatch's number
+        packed = plan.packed
+        self._rng = plan.rng
+        for req in plan.rewound:
+            req._rng_rewind = False
+        for a, start, suffix in plan.advance:
+            a["start"], a["suffix"] = start, suffix
+        for req, slot in plan.finals:
+            self._tables[slot] = req._pkv["table"]
+        self.chunk_stats["chunks"] += plan.chunks
         # a request's queue wait ends at the launch of the first
         # dispatch that carries one of its segments: pending, waiting
         # for pages, for budget, for the running execution to end
@@ -3313,62 +3417,56 @@ class ContinuousBatchingEngine:
                 trace.queue_wait(now - req.t_submit)
             if is_final:
                 req.final_seq = seq
-                kv_tokens += len(req.prompt)
+        spec_mixed = plan.spec_mixed
         prog = ("mixed_step" if not spec_mixed else
                 "mixed_spec_step" if self._mixed_spec_step is not None
                 else "mixed_pld_step")
-        _sig = _profiling.dispatch_signature(
-            prog, batch=int(active_mask.sum()),
+        sig = _profiling.dispatch_signature(
+            prog, batch=int(plan.active_mask.sum()),
             chunk=self.decode_block, kv_dtype=self.kv_cache.kv_dtype)
-        _t0 = self._prof.begin(_sig)
-        t_launch = trace.enter("launch")
+        flight = types.SimpleNamespace(
+            plan=plan, sig=sig, t0=self._prof.begin(sig), t_done=0.0,
+            t_launch=trace.enter("launch"),
+            phases=trace.launched_phases, steps=0, out=None, tok=None)
         try:
             if not spec_mixed:
+                (seg, tables, active, eos, budget,
+                 dec_sub) = self._put_mixed(plan)
                 with jax.profiler.StepTraceAnnotation("mixed_step",
                                                       step_num=seq):
-                    (self._pk, self._pv, self._lengths, tok, final_toks,
-                     final_lps, toks, lps, steps, *moe_acc
-                     ) = self._mixed_step(
-                        self.params, self._pk, self._pv,
-                        # nothing packed: the variant without a slab,
-                        # and no segment array is transferred
-                        tuple(jnp.asarray(x) for x in (
-                            seg_ids, seg_tables, seg_starts, seg_lens,
-                            seg_slot, seg_plen, seg_keys))
-                        if packed else None,
-                        jnp.asarray(self._tables),
-                        self._lengths, self._last_tok,
-                        jnp.asarray(active_mask), dec_sub,
-                        self._eos_scalar(), jnp.asarray(budget_vec),
-                        self.decode_block)
+                    (self._pk, self._pv, self._lengths, tok,
+                     *flight.out) = self._mixed_step(
+                        self.params, self._pk, self._pv, seg, tables,
+                        self._lengths, self._last_tok, active,
+                        dec_sub, eos, budget, self.decode_block)
                 self._last_tok = tok
-            elif self._mixed_spec_step is not None:
-                (self._pk, self._pv, self._dpk, self._dpv,
-                 self._lengths, self._last_tok, final_toks, final_lps,
-                 em, ns) = self._mixed_spec_step(
-                    self.params, self.draft_params, self._pk, self._pv,
-                    self._dpk, self._dpv, jnp.asarray(seg_ids),
-                    jnp.asarray(seg_tables), jnp.asarray(seg_starts),
-                    jnp.asarray(seg_lens), jnp.asarray(seg_slot),
-                    jnp.asarray(seg_plen), jnp.asarray(seg_keys),
-                    jnp.asarray(self._tables),
-                    jnp.asarray(self._dtables), self._lengths,
-                    self._last_tok, jnp.asarray(active_mask), dec_sub,
-                    jnp.asarray(k_vec), k_disp, num_rounds,
-                    with_finals)
+                # (final_toks, final_lps, toks, lps, steps[, moe_acc]):
+                # small, and the drain reads them all
+                for o in flight.out:
+                    o.copy_to_host_async()
             else:
-                (self._pk, self._pv, self._history, self._lengths,
-                 self._last_tok, final_toks, final_lps, em,
-                 ns) = self._mixed_pld_step(
-                    self.params, self._pk, self._pv, self._history,
-                    jnp.asarray(seg_ids), jnp.asarray(seg_tables),
-                    jnp.asarray(seg_starts), jnp.asarray(seg_lens),
-                    jnp.asarray(seg_slot), jnp.asarray(seg_plen),
-                    jnp.asarray(seg_keys), jnp.asarray(self._tables),
-                    self._lengths, self._last_tok,
-                    jnp.asarray(active_mask), dec_sub,
-                    jnp.asarray(k_vec), k_disp, num_rounds,
-                    with_finals)
+                seg_dev = tuple(jnp.asarray(x) for x in plan.seg)
+                if self._mixed_spec_step is not None:
+                    (self._pk, self._pv, self._dpk, self._dpv,
+                     self._lengths, self._last_tok,
+                     *flight.out) = self._mixed_spec_step(
+                        self.params, self.draft_params, self._pk,
+                        self._pv, self._dpk, self._dpv, *seg_dev,
+                        jnp.asarray(plan.tables),
+                        jnp.asarray(self._dtables), self._lengths,
+                        self._last_tok, jnp.asarray(plan.active_mask),
+                        plan.dec_sub, jnp.asarray(plan.k_vec),
+                        plan.k_disp, plan.num_rounds, bool(plan.finals))
+                else:
+                    (self._pk, self._pv, self._history, self._lengths,
+                     self._last_tok,
+                     *flight.out) = self._mixed_pld_step(
+                        self.params, self._pk, self._pv, self._history,
+                        *seg_dev, jnp.asarray(plan.tables),
+                        self._lengths, self._last_tok,
+                        jnp.asarray(plan.active_mask), plan.dec_sub,
+                        jnp.asarray(plan.k_vec), plan.k_disp,
+                        plan.num_rounds, bool(plan.finals))
         except BaseException as e:
             # a per-request failure fails the packed requests, never
             # the engine — same contract as the serialized admission
@@ -3376,6 +3474,7 @@ class ContinuousBatchingEngine:
             # engine failure: re-raise into the crash drain.
             if not packed:
                 raise
+            trace.abandon()
             failed = []
             for (_, a, is_final, slot) in packed:
                 if a["req"] not in failed:
@@ -3387,28 +3486,165 @@ class ContinuousBatchingEngine:
             for req in failed:
                 self._fail_request(req, e)
             return None
-        trace.enter("wait")          # the first blocking read
-        if spec_mixed:
-            em_np, ns_np = np.asarray(em), np.asarray(ns)
-            steps = num_rounds
+        flight.tok = self._last_tok
+        trace.enter("wait")          # until the first blocking read
+        return flight
+
+    def _await_mixed(self, flight) -> None:
+        """Block until the dispatch in flight has returned: the first
+        read of one of its outputs."""
+        if flight.plan.spec_mixed:
+            em, ns = flight.out[2:]
+            flight.em_np, flight.ns_np = np.asarray(em), np.asarray(ns)
+            flight.steps = flight.plan.num_rounds
         else:
-            steps = int(steps)       # the on-device active count
+            flight.steps = int(flight.out[4])   # the on-device count
+
+    def _plan_ahead(self, flight) -> tuple:
+        """While ``flight`` executes: the next dispatch, packed from the
+        state ``flight`` will leave if no row of it ends by ``eos``, its
+        arrays already on the device; ``(plan, None)``, or ``(None,
+        why)`` where the engine can see now that the next dispatch will
+        not be that one.  Commits nothing.
+
+        The projection: a row holds ``min(steps, remaining)`` more
+        tokens after ``flight``, where ``steps`` is the fused loop's
+        count (it runs while any row has budget left, ``decode_block``
+        at most); a final installed by ``flight`` holds its token #1 and
+        then the same; a row whose budget ends in ``flight`` is gone,
+        its table row sentinel (``_record_token`` + ``_sentinel_slot``).
+        What stays on the old order, by what the engine is or holds: the
+        speculative programs (their pack reads what the drain learns),
+        a dispatch the profiler samples (its end must time one
+        execution), an admission still in flight after ``flight`` (its
+        next chunk or parked final), a resume replay (its drain may fail
+        the row), and any wait in ``_pending`` that ``flight``'s drain
+        could end: a final it installs (``store_shared`` moves the
+        tree's epoch) or a row it ends (pages and a slot come free)."""
+        plan = flight.plan
+        if plan.spec_mixed or flight.t0 is not None:
+            return None, "other"
+        with self.dispatch_trace.ahead() as spent:
+            rows = list(plan.rows)
+            for req, slot in plan.finals:
+                rows[slot] = (req, 1)
+            live = [s for s in rows if s is not None]
+            news = self._ahead_news(req for req, _ in live)
+            if news is not None:
+                return None, news
+            done = {id(req) for req, _ in plan.finals}
+            if (any(id(a["req"]) not in done for a in self._adms)
+                    or any(getattr(req, "_suppress", None)
+                           for req, _ in live)):
+                return None, "other"
+            steps = min(self.decode_block,
+                        max((req.max_new - k for req, k in live),
+                            default=0))
+            ended = []
+            for i, s in enumerate(rows):
+                if s is None:
+                    continue
+                req, k = s
+                k += min(steps, req.max_new - k)
+                rows[i] = (req, k) if k < req.max_new else None
+                if rows[i] is None:
+                    ended.append(i)
+            if not any(rows):
+                return None, "finish"
+            if self._pending:
+                if ended or plan.finals:
+                    return None, "finish"
+                # what is left waits for pages behind `_reserve_pages`'
+                # retry gate, which opens when the pool changes: the
+                # intake would turn it away again and touch nothing
+                pool = (self.kv_cache.epoch, self.kv_cache.free_blocks)
+                if any(getattr(req, "_resume", None) is not None
+                       or getattr(req, "_pkv_blocked", None) != pool
+                       for req in self._pending):
+                    return None, "other"
+            self.anomaly.observe(self.stats)
+            self._sample_hbm()
+            tables = self._tables.copy()
+            tables[ended] = self._page_sentinel
+            nxt = self._pack_mixed(rows, [], [], self._rng, tables)
+            self._put_mixed(nxt)
+        nxt.how, nxt.ahead_s = "hit", spent[0]
+        flight.steps_ahead = steps
+        return nxt, None
+
+    def _ahead_news(self, live) -> Optional[str]:
+        """What has reached the scheduler that the next intake would act
+        on, as a reason of ``tracing.AHEAD_MISS_REASONS``, or None: an
+        export asked, a request or wake in the queue, the engine
+        closing, a cancel among the ``live`` rows' requests or those in
+        ``_pending``."""
+        if self._export_q:
+            return "export"
+        if not self._queue.empty():
+            return "arrival"
+        if not self._running:
+            return "other"
+        if (any(req.cancelled for req in live)
+                or any(req.cancelled for req in self._pending)):
+            return "cancel"
+        return None
+
+    def _ahead_refusal(self, flight) -> Optional[str]:
+        """``flight`` has returned: None if the dispatch prepared under
+        it is what this order would pack now, else why not (one of
+        ``tracing.AHEAD_MISS_REASONS``).  It is iff ``flight`` did what
+        the projection assumed (the projected step count, no ``eos``
+        among the tokens a row keeps) and nothing reached the scheduler
+        meanwhile: no arrival or wake in the queue, no export asked, no
+        request cancelled, the engine not closing."""
+        plan = flight.plan
+        news = self._ahead_news(
+            [s[0] for s in plan.rows if s is not None]
+            + [req for req, _ in plan.finals])
+        if news is not None:
+            return news
+        if flight.steps != flight.steps_ahead:
+            return "finish"
+        if self.eos_id is not None:
+            final_toks, _, toks = flight.out[:3]
+            # a row keeps its budget's worth of the block (an empty
+            # slot's budget is 0, a final's what token #1 leaves)
+            kept = np.minimum(plan.budget_vec, flight.steps)
+            block = np.asarray(toks)
+            if (((block == self.eos_id)
+                 & (np.arange(block.shape[1])[None, :] < kept[:, None])
+                 ).any()
+                    or (plan.finals and any(
+                        int(t) == self.eos_id
+                        for t in np.asarray(final_toks)[
+                            [r for (r, _, f, _) in plan.packed if f]]))):
+                return "finish"
+        return None
+
+    def _drain_mixed(self, flight) -> dict:
+        """Install what a returned dispatch produced: finals (host
+        state, radix adoption, token #1), every row's tokens to its
+        stream, the counters; returns the dispatch record's fields
+        (``DispatchTrace.commit``)."""
+        plan, steps = flight.plan, flight.steps
+        packed, spec_mixed = plan.packed, plan.spec_mixed
+        prefill_tokens, n_active = plan.prefill_tokens, plan.n_active
+        final_toks, final_lps = flight.out[:2]
         record = dict(
-            t_launch=t_launch, t_done=trace.enter("drain"),
-            with_finals=with_finals, segments=len(packed),
-            finals=sum(1 for (_, _, f, _) in packed if f),
-            prefill_tokens=prefill_tokens, active_rows=n_active,
-            steps=steps, kv_tokens=kv_tokens)
+            t_launch=flight.t_launch, t_done=flight.t_done,
+            with_finals=bool(plan.finals), segments=len(packed),
+            finals=len(plan.finals), prefill_tokens=prefill_tokens,
+            active_rows=n_active, steps=steps,
+            kv_tokens=plan.kv_tokens, ahead=plan.ahead_s, how=plan.how)
         if self.moe_counters is not None:
             # real tokens: the live segments' prompt tokens, and the
             # steps of the slots that decoded (rows that finish inside
             # the block still step to its end); each is k rows a layer
-            acc = np.asarray(moe_acc[0])
+            acc = np.asarray(flight.out[5])
             E = self.cfg.num_experts
-            n_final = sum(1 for (_, _, f, _) in packed if f)
             record.update(self.moe_counters.add(
                 acc[:E], int(acc[E]), int(acc[E + 1]), int(acc[E + 2]),
-                (prefill_tokens + (n_active + n_final) * steps)
+                (prefill_tokens + (n_active + len(plan.finals)) * steps)
                 * self.cfg.experts_per_token * self.cfg.num_layers
                 * self.cfg.ut_steps))
         if self.loop_counters is not None:
@@ -3420,7 +3656,7 @@ class ContinuousBatchingEngine:
         # finals first: install host state + radix adoption, record
         # token #1.  The adoption waits until after the dispatch — the
         # tree must never serve pages whose K/V is still in flight.
-        if with_finals:
+        if plan.finals:
             final_toks_np = np.asarray(final_toks)
             final_lps_np = np.asarray(final_lps)
             for (r0, a, is_final, slot) in packed:
@@ -3473,12 +3709,16 @@ class ContinuousBatchingEngine:
                     slot, req, int(final_toks_np[r0]),
                     None if spec_mixed else float(final_lps_np[r0]))
         if spec_mixed:
-            if _t0 is not None:
-                self._prof.end(_sig, _t0, out=self._last_tok,
+            num_rounds, k_vec, live0 = (plan.num_rounds, plan.k_vec,
+                                        plan.live0)
+            em_np, ns_np = flight.em_np, flight.ns_np
+            if flight.t0 is not None:
+                self._prof.end(flight.sig, flight.t0, out=flight.tok,
                                hbm_bytes=(
                     prefill_tokens * self._kv_bytes_per_token
                     + self._decode_kv_bytes(
-                        active_mask, num_rounds * (k_disp + 1))))
+                        plan.active_mask,
+                        num_rounds * (plan.k_disp + 1))))
             emitted = int(ns_np[:, live0].sum()) if live0 else 0
             cs["mixed_packed_tokens"] += prefill_tokens + emitted
             if num_rounds > 0:
@@ -3492,12 +3732,14 @@ class ContinuousBatchingEngine:
             if num_rounds > 0 and self._adms:
                 cs["interleaved_steps"] += 1
             return record
-        if _t0 is not None:
-            # sampled only (int(steps) above already synced): packed
+        toks, lps = flight.out[2:4]
+        if flight.t0 is not None:
+            # sampled only (the step count is read already): packed
             # prefill writes + every active row's per-step history read
-            self._prof.end(_sig, _t0, out=tok, hbm_bytes=(
+            self._prof.end(flight.sig, flight.t0, out=flight.tok,
+                           hbm_bytes=(
                 prefill_tokens * self._kv_bytes_per_token
-                + self._decode_kv_bytes(active_mask, steps)))
+                + self._decode_kv_bytes(plan.active_mask, steps)))
         cs["mixed_packed_tokens"] += (prefill_tokens
                                       + n_active * steps)
         if steps > 0:

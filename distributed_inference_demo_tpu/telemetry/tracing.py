@@ -159,10 +159,19 @@ class TraceRecorder:
 
 DISPATCH_PHASES = ("bookkeeping", "intake", "pack", "launch", "wait",
                    "drain")
+# the three phases that belong to the dispatch that was launched last;
+# the other three to the one that is launched next
+_LAUNCHED_PHASES = ("launch", "wait", "drain")
+# a record's last column: the seconds of host work for this dispatch
+# that ran under the previous execution instead of in the gap before
+# this one (0 unless it was launched as prepared: DispatchTrace.ahead)
 DISPATCH_FIELDS = (("seq", "t_launch", "t_done") + DISPATCH_PHASES
                    + ("with_finals", "segments", "finals",
                       "prefill_tokens", "active_rows", "steps",
-                      "kv_tokens"))
+                      "kv_tokens", "ahead"))
+# why a dispatch that followed another at once was packed in the gap and
+# not under its predecessor (runtime.batching, docs/DESIGN.md §19)
+AHEAD_MISS_REASONS = ("arrival", "finish", "cancel", "export", "other")
 # what a model with experts adds to a record (runtime.batching): the
 # token-expert rows the execution routed over all its passes and layers,
 # those of real tokens (a live segment's prompt tokens, an active slot's
@@ -268,11 +277,16 @@ class DispatchTrace:
     iteration without holes), and each phase is also a
     ``jax.profiler.TraceAnnotation("sched.<phase>", seq=...)`` — inert
     unless a capture runs, then a row on the ``/host:CPU`` plane above
-    the device lines it explains.  :meth:`commit` ends ``drain`` and
-    turns what the phases accumulated since the last record into one
-    row of :data:`DISPATCH_FIELDS`; an iteration that dispatched nothing
-    carries its seconds into the next record.  The blocking wait of an
-    idle engine is no phase (:meth:`idle`)."""
+    the device lines it explains.  ``bookkeeping``, ``intake`` and
+    ``pack`` accrue to the dispatch that is launched next, ``launch``,
+    ``wait`` and ``drain`` to the one launched last: entering ``launch``
+    is the cut, and the scheduler may launch dispatch n+1 before it has
+    committed n (it drains n under n+1's execution), so each launched
+    dispatch keeps its own seconds until its :meth:`commit` turns them
+    into one row of :data:`DISPATCH_FIELDS`.  An iteration that
+    dispatched nothing carries its seconds into the next record.  The
+    blocking wait of an idle engine is no phase (:meth:`idle`), and host
+    work done under an execution is no seventh tile (:meth:`ahead`)."""
 
     def __init__(self, extra_fields: tuple = ()):
         """``extra_fields``: columns after :data:`DISPATCH_FIELDS`
@@ -282,34 +296,70 @@ class DispatchTrace:
         from jax.profiler import TraceAnnotation
         self._annotate = TraceAnnotation
         self.extra_fields = tuple(extra_fields)
-        self._names = {p: f"sched.{p}" for p in DISPATCH_PHASES}
+        self._names = {p: f"sched.{p}"
+                       for p in DISPATCH_PHASES + ("ahead",)}
         self._phase: Optional[str] = None
         self._t0 = 0.0
         self._ann = None
         self.recent: "deque[tuple]" = deque(maxlen=_DISPATCH_RING)
+        self.seq = self.launched = 0
         self.reset()
 
     def reset(self) -> None:
         self.recent.clear()
+        # a dispatch in flight commits after the reset, as number 1
+        self.launched -= self.seq
         self.seq = 0
-        self.phase_s = dict.fromkeys(DISPATCH_PHASES, 0.0)
-        self._carry = dict.fromkeys(DISPATCH_PHASES, 0.0)
+        self.phase_s = dict.fromkeys(DISPATCH_PHASES + ("ahead",), 0.0)
+        self._next = dict.fromkeys(DISPATCH_PHASES, 0.0)
+        self._last = dict.fromkeys(DISPATCH_PHASES, 0.0)
+        self._into = self._next
         self.idle_wait_s = 0.0
         self.decode_only = 0
         self.prefill = 0
         self.kv_token_steps = 0
         self.queue_wait_ms_sum = 0.0
         self.queue_wait_count = 0
+        self.ahead_hits = 0
+        self.ahead_misses = dict.fromkeys(AHEAD_MISS_REASONS, 0)
+        self.ahead_first = 0
+
+    def _cut(self) -> None:
+        self.launched += 1
+        self._last = self._next
+        self._next = dict.fromkeys(DISPATCH_PHASES, 0.0)
 
     def enter(self, phase: str) -> float:
         """Start ``phase`` now, ending the one in progress; returns the
-        instant."""
+        instant.  ``launch`` opens the next dispatch's own seconds, which
+        :attr:`launched_phases` hands to its :meth:`commit`."""
         now = time.monotonic()
         self._close(now)
+        if phase == "launch":
+            self._cut()
+        after = phase in _LAUNCHED_PHASES
+        self._into = self._last if after else self._next
         self._phase, self._t0 = phase, now
-        self._ann = self._annotate(self._names[phase], seq=self.seq + 1)
+        self._ann = self._annotate(
+            self._names[phase],
+            seq=self.launched if after else self.launched + 1)
         self._ann.__enter__()
         return now
+
+    @property
+    def launched_phases(self) -> dict:
+        """The seconds of the dispatch launched last, for ``commit``."""
+        return self._last
+
+    def abandon(self) -> None:
+        """The dispatch launched last never reached the device: its
+        seconds go on to the next record."""
+        self.launched -= 1
+        for p, v in self._last.items():
+            self._next[p] += v
+        self._last = dict.fromkeys(DISPATCH_PHASES, 0.0)
+        if self._phase in _LAUNCHED_PHASES:
+            self._into = self._next
 
     def leave(self) -> float:
         """End the phase in progress; returns the instant."""
@@ -321,7 +371,7 @@ class DispatchTrace:
         if self._phase is None:
             return
         dt = now - self._t0
-        self._carry[self._phase] += dt
+        self._into[self._phase] += dt
         self.phase_s[self._phase] += dt
         self._ann.__exit__(None, None, None)
         self._phase = self._ann = None
@@ -339,6 +389,24 @@ class DispatchTrace:
             if phase is not None:
                 self.enter(phase)
 
+    @contextlib.contextmanager
+    def ahead(self):
+        """Around host work done while the device executes (the next
+        dispatch prepared, the last one drained): the cursor stays in
+        ``wait``, which still runs from the call's return to ``t_done``,
+        and the seconds are booked to ``phase_s["ahead"]``, so the six
+        phases keep tiling the iteration and ``phase_s`` without ``wait``
+        is still all the host did.  Yields a one-element list that holds
+        the seconds once the block has ended."""
+        spent = [0.0]
+        t0 = time.monotonic()
+        with self._annotate(self._names["ahead"], seq=self.launched + 1):
+            try:
+                yield spent
+            finally:
+                spent[0] = time.monotonic() - t0
+                self.phase_s["ahead"] += spent[0]
+
     def queue_wait(self, seconds: float) -> None:
         """A request's submit -> launch of its first dispatch."""
         self.queue_wait_ms_sum += seconds * 1e3
@@ -347,25 +415,39 @@ class DispatchTrace:
     def commit(self, *, t_launch: float, t_done: float, with_finals: bool,
                segments: int, finals: int, prefill_tokens: int,
                active_rows: int, steps: int, kv_tokens: int,
-               **extra: int) -> int:
-        """The dispatch in progress reached the device and is drained:
-        one record.  Returns its ``seq``."""
-        self.leave()
+               ahead: float = 0.0, how: Optional[str] = None,
+               phases: Optional[dict] = None, **extra: int) -> int:
+        """A dispatch that reached the device is drained: one record.
+        ``phases``: its own seconds (``launched_phases`` as they were
+        when the NEXT dispatch had not been launched yet; by default the
+        last launched one's).  ``how``: ``"hit"`` (launched as prepared
+        under its predecessor's execution, ``ahead`` seconds of it), one
+        of :data:`AHEAD_MISS_REASONS` (it followed its predecessor at
+        once and was packed in the gap), or ``"first"`` (nothing was
+        executing before it).  Returns its ``seq``."""
+        if phases is None:
+            self.leave()
+            if self.launched == self.seq:    # no phase of a launch seen
+                self._cut()
+            phases = self._last
         self.seq += 1
-        carry = self._carry
         self.recent.append((
             self.seq, round(t_launch, 5), round(t_done, 5),
-            *(round(carry[p], 5) for p in DISPATCH_PHASES),
+            *(round(phases[p], 5) for p in DISPATCH_PHASES),
             int(with_finals), segments, finals, prefill_tokens,
-            active_rows, steps, kv_tokens,
+            active_rows, steps, kv_tokens, round(ahead, 5),
             *(extra[f] for f in self.extra_fields)))
-        for p in DISPATCH_PHASES:
-            carry[p] = 0.0
         if segments:
             self.prefill += 1
         else:
             self.decode_only += 1
         self.kv_token_steps += kv_tokens * steps
+        if how == "hit":
+            self.ahead_hits += 1
+        elif how == "first":
+            self.ahead_first += 1
+        elif how is not None:
+            self.ahead_misses[how] += 1
         return self.seq
 
     def snapshot(self) -> dict:
@@ -382,6 +464,9 @@ class DispatchTrace:
                 "kv_token_steps": self.kv_token_steps,
                 "queue_wait_ms_sum": round(self.queue_wait_ms_sum, 3),
                 "queue_wait_count": self.queue_wait_count,
+                "ahead_hits": self.ahead_hits,
+                "ahead_misses": dict(self.ahead_misses),
+                "ahead_first": self.ahead_first,
                 "fields": list(DISPATCH_FIELDS + self.extra_fields),
                 "recent": [list(r) for r in copy.copy(self.recent)]}
 

@@ -17,7 +17,12 @@ order only; a time from this file is a time of XLA's CPU backend):
   the SLO ledger books a prefill time in the mixed path;
 - ``GET /trace`` ties a request to its dispatches by id;
 - a ``jax.profiler`` capture holds the ``sched.*`` rows and changes no
-  generated id.
+  generated id;
+- every dispatch is counted once, as launched as prepared under its
+  predecessor's execution (a hit), as packed in the gap for a named
+  reason (a miss), or as the first after an idle wait; a hit's ``ahead``
+  seconds lie under the previous execution, and a dispatch launched
+  before its predecessor is committed keeps its own seconds.
 """
 
 import json
@@ -41,7 +46,7 @@ from distributed_inference_demo_tpu.runtime.batching import (
 from distributed_inference_demo_tpu.telemetry.slo import get_slo_ledger
 from distributed_inference_demo_tpu.telemetry import tracing
 from distributed_inference_demo_tpu.telemetry.tracing import (
-    DISPATCH_FIELDS, DISPATCH_PHASES, DispatchTrace)
+    AHEAD_MISS_REASONS, DISPATCH_FIELDS, DISPATCH_PHASES, DispatchTrace)
 
 # bf16 weights and pages, and the int8-weight family the chip cells serve
 MODELS = ("llama-test", "qwen2-test-int8")
@@ -241,6 +246,160 @@ def test_prefill_spans_carry_the_ids_of_their_dispatches(scripted):
     assert args["final_seq"] == req.final_seq == 2
 
 
+def test_every_dispatch_is_a_hit_a_miss_or_a_first(scripted):
+    """The scripted run: a first dispatch (it carries the prompt), two
+    prepared under their predecessors, a pause, a first again, one
+    prepared.  A record's ``ahead`` is positive exactly on a hit."""
+    dt = scripted[0]["dispatch_trace"]
+    recs = rows(scripted[0])
+    assert tuple(dt["ahead_misses"]) == AHEAD_MISS_REASONS
+    assert dt["ahead_first"] == 2 and dt["ahead_hits"] == 3
+    assert sum(dt["ahead_misses"].values()) == 0
+    assert [r["ahead"] > 0 for r in recs] == [False, True, True, False,
+                                              True]
+
+
+def test_ahead_seconds_lie_under_the_previous_execution(scripted):
+    """What was prepared for record n+1 was prepared while n executed:
+    inside n's ``wait``, which still runs from the call's return to
+    ``t_done``; and the seventh key of ``phase_s`` holds at least the
+    seconds the records name (it also holds the drains that ran under
+    an execution, and plans that were refused)."""
+    dt = scripted[0]["dispatch_trace"]
+    recs = rows(scripted[0])
+    for a, b in zip(recs, recs[1:]):
+        assert 0 <= b["ahead"] <= a["wait"] + ROUNDING
+        if b["ahead"] > 0:
+            assert a["t_launch"] < a["t_done"] <= b["t_launch"]
+            assert a["drain"] == 0     # it ran under b's execution
+            assert b["bookkeeping"] == b["intake"] == 0
+    assert set(dt["phase_s"]) == set(DISPATCH_PHASES) | {"ahead"}
+    assert dt["phase_s"]["ahead"] >= sum(r["ahead"] for r in recs) - ROUNDING
+    assert dt["phase_s"]["ahead"] <= dt["phase_s"]["wait"]
+
+
+def _during_call(eng, n, act):
+    """Run ``act`` on the scheduler's thread right after its ``n``-th
+    call of ``mixed_step`` was enqueued: during that execution."""
+    inner, calls = eng._mixed_step, [0]
+
+    def hooked(*a):
+        out = inner(*a)
+        calls[0] += 1
+        if calls[0] == n:
+            act()
+        return out
+
+    eng._mixed_step = hooked
+
+
+@pytest.mark.parametrize("reason", ["arrival", "cancel", "export",
+                                    "finish"])
+def test_a_miss_names_what_reached_the_scheduler(reason):
+    """Two rows decode; during the third execution something reaches the
+    scheduler (a request, a cancel, an export), or a row ends by ``eos``
+    unannounced: the fourth dispatch is packed in the gap, in the old
+    order, and counted under that reason."""
+    probe = {}
+    if reason == "finish":
+        with engine() as eng:
+            alone = eng.submit(SHORT, 12).wait(timeout=300).tolist()
+        probe = {"eos": alone[6], "ends": alone.index(alone[6]) + 1}
+    with engine(eos_id=probe.get("eos")) as eng:
+        box = {}
+
+        def act():
+            if reason == "arrival":
+                box["late"] = eng.submit([4, 4, 2], 3)
+            elif reason == "cancel":
+                box["short"].cancel()
+            elif reason == "export":
+                t = threading.Thread(
+                    target=lambda: box.update(
+                        ckpt=eng.export_request(box["long"])),
+                    daemon=True)
+                t.start()
+                deadline = time.monotonic() + 10
+                while not eng._export_q and time.monotonic() < deadline:
+                    time.sleep(0.001)
+
+        _during_call(eng, 3, act)
+        box["long"] = eng.submit(LONG, 30)
+        box["short"] = eng.submit(SHORT, 12)
+        for r in list(box.values()):
+            r.wait(timeout=300)
+        if "late" in box:
+            box["late"].wait(timeout=300)
+        st = settled_stats(eng)
+    dt = st["dispatch_trace"]
+    assert dt["ahead_misses"][reason] >= 1, dt["ahead_misses"]
+    assert (dt["ahead_hits"] + sum(dt["ahead_misses"].values())
+            + dt["ahead_first"] == dt["seq"])
+    assert dt["ahead_hits"] >= 2
+    if reason == "finish":
+        assert box["short"].tokens[-1] == probe["eos"]
+        assert len(box["short"].tokens) == probe["ends"] < 12
+    if reason == "export":
+        assert box["ckpt"]["tokens"]
+
+
+def test_a_dispatch_launched_before_its_predecessor_commits(monkeypatch):
+    """The cursor's cut: ``launch`` opens the next dispatch's seconds,
+    and a dispatch committed after its successor was launched (it was
+    drained under the successor's execution) still gets its own; work
+    under an execution is booked to ``ahead`` and is no tile."""
+    ticks = iter(range(10 ** 6))
+    monkeypatch.setattr(tracing, "time", types.SimpleNamespace(
+        monotonic=lambda: 100.0 + 0.001 * next(ticks)))
+    tr = DispatchTrace()
+    fields = dict(with_finals=False, segments=0, finals=0,
+                  prefill_tokens=0, active_rows=1, steps=4, kv_tokens=8)
+    for phase in ("bookkeeping", "intake", "pack"):
+        tr.enter(phase)
+    t1 = tr.enter("launch")
+    first = tr.launched_phases
+    tr.enter("wait")
+    with tr.ahead() as spent:          # the next one, prepared
+        pass
+    d1 = tr.enter("pack")              # its validation
+    t2 = tr.enter("launch")            # launched as prepared
+    tr.enter("wait")
+    with tr.ahead():                   # the first one, drained
+        pass
+    assert tr.launched == 2 and tr.seq == 0
+    tr.commit(t_launch=t1, t_done=d1, phases=first, how="first", **fields)
+    d2 = tr.enter("drain")
+    tr.commit(t_launch=t2, t_done=d2, ahead=spent[0], how="hit", **fields)
+    monkeypatch.undo()
+    snap = tr.snapshot()
+    a, b = (dict(zip(snap["fields"], r)) for r in snap["recent"])
+    assert (a["seq"], b["seq"]) == (1, 2)
+    assert [a[p] for p in DISPATCH_PHASES] == [.001, .001, .001, .001,
+                                               .003, 0]
+    assert [b[p] for p in DISPATCH_PHASES] == [0, 0, .001, .001, .003,
+                                               .001]
+    assert a["ahead"] == 0 and b["ahead"] == .001
+    assert a["launch"] + a["wait"] == pytest.approx(d1 - t1)
+    assert b["t_launch"] - a["t_done"] == pytest.approx(b["pack"])
+    assert snap["phase_s"]["ahead"] == pytest.approx(.002)
+    assert snap["phase_s"]["wait"] == pytest.approx(.006)
+    assert (snap["ahead_hits"], snap["ahead_first"]) == (1, 1)
+    # a launch that failed its requests hands its seconds on
+    tr.enter("pack")
+    tr.enter("launch")
+    tr.abandon()
+    assert tr.launched == tr.seq == 2
+    tr.enter("pack")
+    tr.enter("launch")
+    tr.enter("wait")
+    # counters reset with a dispatch in flight: it commits as number 1
+    tr.reset()
+    tr.commit(t_launch=1.0, t_done=tr.enter("drain"), how="other",
+              **fields)
+    assert tr.seq == tr.launched == 1
+    assert tr.snapshot()["ahead_misses"]["other"] == 1
+
+
 def _capture(logdir, out):
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
@@ -250,6 +409,13 @@ def _capture(logdir, out):
         jax.profiler.start_trace(str(logdir), profiler_options=opts)
         try:
             out["traced"] = eng.submit(LONG, 10).wait(timeout=120).tolist()
+            # one more dispatch before the capture ends: a request's
+            # `wait` returns while its last dispatch is still draining,
+            # and since the scheduler prepares a dispatch under its
+            # predecessor a request's only `sched.drain` and the
+            # `sched.bookkeeping` after it are that last dispatch's; the
+            # scheduler has closed both rows before it picks this up
+            eng.submit(SHORT, 1).wait(timeout=120)
         finally:
             jax.profiler.stop_trace()
         out["seq"] = settled_stats(eng)["dispatch_trace"]["seq"]
@@ -285,8 +451,10 @@ def test_a_capture_holds_the_sched_rows_on_a_host_plane(capture):
     names = {n for _, n, _ in events}
     assert {f"sched.{p}" for p in DISPATCH_PHASES} <= names
     assert "mixed_step" in names
-    # the request's three dispatches, each under its number
+    assert "sched.ahead" in names
+    # the request's three dispatches and the one after, each under its
+    # number
     packs = sorted(s["seq"] for _, n, s in events if n == "sched.pack")
-    assert packs == list(range(out["seq"] - 2, out["seq"] + 1))
+    assert packs == list(range(out["seq"] - 3, out["seq"] + 1))
     steps = sorted(s["step_num"] for _, n, s in events if n == "mixed_step")
     assert steps == packs

@@ -315,6 +315,260 @@ def test_mixed_matches_serialized_property_sweep(params, kv_dtype,
 
 
 # ---------------------------------------------------------------------------
+# §19: the next dispatch prepared under the execution in flight
+# ---------------------------------------------------------------------------
+
+KEEPER = list(range(2, 24))            # 22 tokens: two whole pages + 6
+# what reaches the scheduler, keyed by the call of ``mixed_step`` it
+# follows: the hook below acts on the scheduler's own thread right after
+# that call was enqueued, so each event lands DURING that execution
+# whatever the machine's timing, in either order of an iteration
+SCRIPT = {
+    # shares the keeper's first two pages (adopted by the tree when the
+    # keeper's final is drained: the arrival must find them)
+    1: [("submit", "share", KEEPER[:16] + [77, 78, 79], 30)],
+    # a final with nothing to decode, and a row whose budget ends one
+    # step into a later block (token #1 + 4 + 1)
+    2: [("submit", "one", [9, 2, 6], 1), ("submit", "mid", [5, 4, 3, 2], 6)],
+    # the batch is full (keeper, share, mid): the final parks
+    3: [("submit", "parked", list(range(40, 62)), 7)],
+    7: [("cancel", "share")],
+    9: [("submit", "late", [8, 8, 1], 5)],
+    14: [("submit", "last", [7, 1, 7, 1, 7], 3)],
+}
+RECORD_FIELDS = ("segments", "finals", "prefill_tokens", "active_rows",
+                 "steps", "kv_tokens")
+
+
+def settle(eng):
+    """Wait until the scheduler has committed its last dispatch: a
+    request's ``wait`` returns while that dispatch is still draining."""
+    deadline = time.monotonic() + 10
+    while (eng.dispatch_trace.seq != eng.chunk_stats["mixed_dispatches"]
+           and time.monotonic() < deadline):
+        time.sleep(0.005)
+
+
+def scripted_run(params, sampling, eos_id, refuse):
+    """The scripted traffic through one engine of three slots; with
+    ``refuse`` every prepared dispatch is turned away by a patched
+    validator, so every iteration runs in the old order.  Returns what
+    the two runs must agree on, and what each alone must show."""
+    eng = mixed_engine(params, max_batch=3, sampling=sampling, seed=11,
+                       eos_id=eos_id)
+    reqs, fired, touched, calls = {}, [], [], [0]
+    inner, plan_ahead = eng._mixed_step, eng._plan_ahead
+
+    def snap():
+        dt = eng.dispatch_trace
+        return (np.asarray(eng._rng).tobytes(),
+                [(id(a["req"]), a["start"], len(a["suffix"]))
+                 for a in eng._adms],
+                eng._tables.tobytes(), dict(eng.chunk_stats),
+                dt.queue_wait_count, dt.queue_wait_ms_sum,
+                [r.first_seq for r in reqs.values()])
+
+    def hooked(*a):
+        out = inner(*a)
+        calls[0] += 1
+        for kind, name, *rest in SCRIPT.get(calls[0], ()):
+            if kind == "submit":
+                reqs[name] = eng.submit(*rest)
+            else:
+                reqs[name].cancel()
+            fired.append(name)
+        return out
+
+    def watched(flight):
+        before = snap()
+        out = plan_ahead(flight)
+        if snap() != before:
+            touched.append(calls[0])
+        return out
+
+    eng._mixed_step, eng._plan_ahead = hooked, watched
+    if refuse:
+        eng._ahead_refusal = lambda flight: "other"
+    with eng:
+        reqs["keeper"] = eng.submit(KEEPER, 60)
+        deadline = time.monotonic() + 300
+        while (len(fired) < sum(map(len, SCRIPT.values()))
+               or not all(r.done.is_set() for r in reqs.values())):
+            if (all(r.done.is_set() for r in list(reqs.values()))
+                    and eng.stats()["active_slots"] == 0):
+                break                  # the keeper ended under the script
+            assert time.monotonic() < deadline, (fired, calls)
+            time.sleep(0.005)
+        settle(eng)
+        st = eng.stats()
+        dt = st["dispatch_trace"]
+        recs = [dict(zip(dt["fields"], r)) for r in dt["recent"]]
+        assert_no_leak(eng)
+        return {
+            "same": {
+                "streams": {n: (list(r.tokens), list(r.lps), r.cancelled,
+                                repr(r.error), r.first_seq, r.final_seq)
+                            for n, r in reqs.items()},
+                "records": [tuple(r[f] for f in RECORD_FIELDS)
+                            for r in recs],
+                "rng": np.asarray(eng._rng).tolist(),
+                "chunk_stats": dict(eng.chunk_stats),
+                "queue_waits": dt["queue_wait_count"],
+                "fired": list(fired),
+                "kv": (eng.kv_cache.used_blocks,
+                       eng.kv_cache.tree.block_count),
+            },
+            "script_done": len(fired) == sum(map(len, SCRIPT.values())),
+            "touched": touched, "trace": dt, "recs": recs,
+            "compile": st["compile"]["mixed_step"],
+        }
+
+
+_PROBES = {}
+
+
+def scripted_pair(params, sampled, with_eos):
+    """The as-it-is run and the every-plan-refused run of one case.
+    With ``eos``: a token of the case's own streams, the first that ends
+    a row unannounced and lets the keeper outlive the script."""
+    sampling = (SamplingParams(greedy=False, temperature=0.9, top_k=40)
+                if sampled else GREEDY)
+    if not with_eos:
+        if sampled not in _PROBES:
+            _PROBES[sampled] = scripted_run(params, sampling, None, False)
+        return (_PROBES[sampled],
+                scripted_run(params, sampling, None, True))
+    probe = scripted_pair(params, sampled, False)[0]["same"]["streams"]
+    spared = probe["keeper"][0][:48] + probe["share"][0]
+    seen = []
+    for name in ("mid", "parked", "late"):
+        for tok in probe[name][0][1:-1]:
+            if tok not in spared and tok not in seen:
+                seen.append(tok)
+    for eos in seen[:4]:
+        run = scripted_run(params, sampling, eos, False)
+        ended = [n for n, (toks, *_) in run["same"]["streams"].items()
+                 if toks and toks[-1] == eos]
+        if (run["script_done"] and ended
+                and run["trace"]["ahead_misses"]["cancel"]):
+            return run, scripted_run(params, sampling, eos, True)
+    pytest.fail(f"no token of {seen[:4]} ends a row unannounced and "
+                f"spares the keeper and the row the script cancels")
+
+
+@pytest.mark.quick
+@pytest.mark.parametrize("with_eos", [False, True], ids=["no_eos", "eos"])
+@pytest.mark.parametrize("sampled", [False, True],
+                         ids=["greedy", "sampled"])
+def test_prepared_dispatches_change_nothing_but_the_order(params, sampled,
+                                                          with_eos):
+    """The reordering's whole contract: the same scripted traffic, once
+    as it is and once with every prepared dispatch refused (every
+    iteration then runs drain, intake, pack, launch), gives identical
+    token streams, log-probabilities, dispatch records field by field,
+    rng spend, counters and page accounting.  The script has an arrival
+    and a cancel during an execution, a ``max_new = 1`` final, a row
+    whose budget ends mid-block, a full batch with a parked final,
+    prefix-sharing prompts, and (``eos``) rows that end unannounced."""
+    ahead, old = scripted_pair(params, sampled, with_eos)
+    assert ahead["script_done"] and old["script_done"]
+    for key in ahead["same"]:
+        assert ahead["same"][key] == old["same"][key], key
+    streams = ahead["same"]["streams"]
+    assert len(streams["one"][0]) == 1
+    assert streams["share"][2] and len(streams["share"][0]) < 30
+    if not with_eos:
+        assert len(streams["mid"][0]) == 6
+    # the script did what it says: a full batch while a final waited,
+    # and a prefix found in the tree
+    recs = ahead["recs"]
+    assert any(r["active_rows"] == 3 and r["segments"] > r["finals"]
+               for r in recs) or streams["parked"][5] > streams["parked"][4]
+    assert (sum(r["prefill_tokens"] for r in recs)
+            < sum(len(x) for x in (KEEPER, KEEPER[:16] + [77, 78, 79],
+                                   [9, 2, 6], [5, 4, 3, 2],
+                                   list(range(40, 62)), [8, 8, 1],
+                                   [7, 1, 7, 1, 7])))
+    # a plan commits nothing, launched or not
+    assert ahead["touched"] == [] and old["touched"] == []
+    # every dispatch is counted once, and only the first run has hits
+    for run in (ahead, old):
+        dt = run["trace"]
+        assert (dt["ahead_hits"] + sum(dt["ahead_misses"].values())
+                + dt["ahead_first"] == dt["seq"] == len(run["recs"]))
+        assert run["compile"]["cache_entries"] == 2
+        assert [r["ahead"] > 0 for r in run["recs"]].count(True) == dt[
+            "ahead_hits"]
+    assert old["trace"]["ahead_hits"] == 0
+    dt = ahead["trace"]
+    assert dt["ahead_hits"] >= 5
+    assert dt["ahead_misses"]["arrival"] >= 3
+    assert dt["ahead_misses"]["cancel"] >= 1
+    assert dt["ahead_misses"]["finish"] >= (1 if with_eos else 0)
+    # a hit follows its predecessor with no segment and no new row
+    for a, b in zip(ahead["recs"], ahead["recs"][1:]):
+        if b["ahead"] > 0:
+            assert b["segments"] == 0 and b["active_rows"] > 0
+            assert 0 < b["ahead"] <= a["wait"] + 2e-5
+
+
+def test_under_a_mesh_a_plan_lies_where_the_call_wants_it():
+    """Over a tp mesh the call's small arguments are put on every chip of
+    it (committed, replicated), on both orders alike, so a launch spreads
+    nothing and a prepared dispatch adds no compiled entry to
+    ``mixed_step``: the same count with every plan refused, the same
+    tokens."""
+    from distributed_inference_demo_tpu.models.loader import load_or_init
+    from distributed_inference_demo_tpu.parallel.mesh import local_tp_mesh
+    cfg = get_model_config("qwen2-test")
+    seen = {}
+    for refuse in (True, False):
+        mesh = local_tp_mesh(2)
+        eng = ContinuousBatchingEngine(
+            cfg, load_or_init("qwen2-test", cfg, seed=0, mesh=mesh),
+            max_seq=96, max_batch=4, mesh=mesh, sampling=GREEDY,
+            kv_block_tokens=8, prefill_chunk=8, decode_block=4,
+            mixed_token_budget=24)
+        put, placed = eng._put_mixed, []
+
+        def watched(plan, put=put, placed=placed):
+            dev = put(plan)
+            placed.extend(x.sharding for x in jax.tree.leaves(dev))
+            return dev
+
+        eng._put_mixed = watched
+        if refuse:
+            eng._ahead_refusal = lambda flight: "other"
+        with eng:
+            toks = [eng.submit(p, n).wait(timeout=300).tolist()
+                    for p, n in ((KEEPER, 14), ([3, 14, 15], 9))]
+            settle(eng)
+            dt = eng.stats()["dispatch_trace"]
+        assert all(sh.is_fully_replicated and len(sh.device_set) == 2
+                   for sh in placed) and placed
+        seen[refuse] = (toks, eng._mixed_step.inner._cache_size(),
+                        dt["seq"])
+        assert (dt["ahead_hits"] > 0) != refuse
+    assert seen[True] == seen[False]
+
+
+@pytest.mark.quick
+def test_spec_mixed_engine_prepares_nothing(params):
+    """The speculative mixed programs pack from what their drain learns
+    (adaptive K, the proposer's host-side seeding): they keep the old
+    order because of what the engine is, and count no hit."""
+    with mixed_engine(params, **spec_kw("pld")) as eng:
+        for p, n in (([3, 14, 15], 14), (list(range(2, 24)), 9)):
+            eng.submit(p, n).wait(timeout=300)
+        settle(eng)
+        dt = eng.stats()["dispatch_trace"]
+    assert dt["seq"] > 4 and dt["ahead_hits"] == 0
+    assert dt["phase_s"]["ahead"] == 0.0
+    assert dt["ahead_misses"]["other"] + dt["ahead_first"] == dt["seq"]
+    assert all(r[dt["fields"].index("ahead")] == 0 for r in dt["recent"])
+
+
+# ---------------------------------------------------------------------------
 # §22: speculation inside the mixed dispatch (docs/DESIGN.md §22)
 # ---------------------------------------------------------------------------
 
