@@ -214,16 +214,18 @@ def embed_tokens(params: StageParams, cfg: ModelConfig,
 
 def _mlp(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
          tp_axis: Optional[str] = None,
-         ep_axis: Optional[str] = None) -> jnp.ndarray:
+         ep_axis: Optional[str] = None,
+         valid: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """MLP block.  Under manual TP (``tp_axis`` set inside shard_map),
     w_gate/w_up arrive column-sliced and w_down row-sliced: the partial
     products are summed with an explicit psum (Megatron layout); biases are
     added once, after the reduction.  ``ep_axis`` selects the expert-
-    parallel all_to_all dispatch path for MoE layers."""
+    parallel all_to_all dispatch path for MoE layers; ``valid`` reaches
+    the routed experts only (:func:`_moe_routed`)."""
     if cfg.num_experts > 0:
         if ep_axis is not None:
             return _moe_mlp_ep(cfg, lp, x, ep_axis)
-        return _moe_mlp(cfg, lp, x, tp_axis)
+        return _moe_mlp(cfg, lp, x, tp_axis, valid)
     if cfg.family == "bloom":
         # under manual TP, b_up arrives column-sliced (P(None, "tp")) to
         # match w_up's local columns, so a plain add is correct either way.
@@ -272,7 +274,8 @@ def _route(cfg: ModelConfig, lp: dict, h: jnp.ndarray):
 
 
 def _moe_routed(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
-                tp_axis: Optional[str] = None):
+                tp_axis: Optional[str] = None,
+                valid: Optional[jnp.ndarray] = None):
     """The routed expert layer, dropless: ``(y [b, s, H], rows [E] int32)``
     with ``rows[e]`` the token-expert rows routed to expert ``e``.
 
@@ -284,21 +287,31 @@ def _moe_routed(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     ``ragged_dot`` elsewhere), silu(gate) x up, the down projection, each
     row times its router weight, and a token's ``k`` rows summed in
     float32.  Shapes are static and the group sizes are data, so an
-    expert may take every row or none: nothing is dropped, and every row
-    is routed, a padded slab row or an idle slot like any other
-    (``runtime.batching`` counts those apart).
+    expert may take every row or none, and no row of a token is dropped.
+
+    ``valid`` ``[b, s]`` bool (the mixed dispatch: a slot that decodes,
+    a slab position that holds a prompt token) says which rows hold a
+    token.  The router still runs on every row; a row that holds none
+    takes the expert id ``E``, which sorts behind every group and counts
+    in none (``rows``, the group sizes), so the grouped matmuls read no
+    expert's matrix for it, and its output is zero.  A valid row's
+    arithmetic is what it is without the mask.  ``None``: every row holds
+    a token, and the program is the one traced without the argument.
 
     Under ``tp_axis`` the expert stacks arrive E-sliced (expert
     parallelism over ``tp``): this rank's groups are its local experts,
-    the other ranks' rows sort behind them into no group, and the
-    partial sums meet in the ``psum``."""
+    the other ranks' rows sort behind them into no group (with the rows
+    that hold no token, the same device), and the partial sums meet in
+    the ``psum``."""
     b, s, H = x.shape
     T, k, E = b * s, cfg.experts_per_token, cfg.num_experts
     xt = x.reshape(T, H)
     weights, experts = _route(cfg, lp, xt)
     with jax.named_scope("moe_experts"):
         flat = experts.reshape(T * k)
-        rows = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+        if valid is not None:
+            flat = jnp.where(jnp.repeat(valid.reshape(T), k), flat, E)
+        rows = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
         sizes = rows
         if tp_axis is not None:
             e_local = lp["w_gate"].shape[0]  # quantized, LayerOf: .shape
@@ -314,8 +327,9 @@ def _moe_routed(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
         hh = (jax.nn.silu(gate.astype(jnp.float32))
               * up.astype(jnp.float32)).astype(x.dtype)
         out = grouped_matmul(hh, lp["w_down"], sizes).astype(jnp.float32)
-        if tp_axis is not None:
-            # rows of other ranks' experts belong to no group here
+        if tp_axis is not None or valid is not None:
+            # rows of other ranks' experts, and rows that hold no token,
+            # belong to no group here
             out = jnp.where((jnp.arange(T * k) < jnp.sum(sizes))[:, None],
                             out, 0.0)
         # back to token order: row t*k + j is token t's j-th expert
@@ -327,9 +341,10 @@ def _moe_routed(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
 
 
 def _moe_mlp(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
-             tp_axis: Optional[str] = None) -> jnp.ndarray:
+             tp_axis: Optional[str] = None,
+             valid: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Top-k routed MoE (mixtral, olmoe): :func:`_moe_routed`'s output."""
-    return _moe_routed(cfg, lp, x, tp_axis)[0]
+    return _moe_routed(cfg, lp, x, tp_axis, valid)[0]
 
 
 def _default_attn(q, k, v, k_cache, v_cache, positions, cache_start, slopes):
@@ -429,10 +444,12 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
            tp_axis: Optional[str] = None,
            attn_impl=None,
            ep_axis: Optional[str] = None,
-           moe_stats: bool = False):
+           moe_stats: bool = False,
+           valid: Optional[jnp.ndarray] = None):
     """One decoder block. x: [b, s, H]. Returns (x', k_cache', v_cache'),
     and with ``moe_stats`` a fourth value, the rows routed to each expert
-    in this layer call ([E] int32; ``_moe_routed``).  The caches are this
+    in this layer call ([E] int32; ``_moe_routed``, which is also all
+    that reads ``valid``, the rows that hold a token).  The caches are this
     layer's planes, or ``LayerOf`` the whole stacks where ``attn_impl``
     addresses a page pool in place; either goes to the hook untouched.
 
@@ -490,9 +507,9 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     if x.dtype != cfg.dtype:
         h = h.astype(cfg.dtype)
     if moe_stats:
-        y, rows = _moe_routed(cfg, lp, h, tp_axis)
+        y, rows = _moe_routed(cfg, lp, h, tp_axis, valid)
     else:
-        y, rows = _mlp(cfg, lp, h, tp_axis, ep_axis), None
+        y, rows = _mlp(cfg, lp, h, tp_axis, ep_axis, valid), None
     if cfg.sandwich_norm:
         y = rms_norm(y, lp["mlp_post_norm_w"], cfg.norm_eps)
     if moe_stats:
@@ -513,12 +530,17 @@ def stage_forward(
     last_logits_only: bool = False,  # head over the final position only
     cache_in_carry: bool = True,  # in-place cache (inference) vs ys (train)
     moe_stats: bool = False,    # also return the experts' row counts
+    valid: Optional[jnp.ndarray] = None,  # [b, s] rows that hold a token
 ):
     """Run this stage's layer range. Returns (hidden or logits, updated cache).
 
     ``moe_stats`` (a model with experts, inference layout): a third value,
     ``[layers, E]`` int32, the token-expert rows each layer call routed to
     each expert (the scheduler's routing counters read it).
+
+    ``valid``: the rows of ``inputs`` that hold a token; the others enter
+    no expert's group (``_moe_routed``).  Nothing else reads it, and
+    ``None`` traces the program traced without it.
 
     ``last_logits_only`` narrows the LM-head matmul to the chunk's final
     position (shape [b, 1, V]) — prefill only samples from the last token,
@@ -633,7 +655,8 @@ def stage_forward(
                           else (k_of.sliced(), v_of.sliced()))
                 x, kc, vc, *rows = _layer(cfg, lp, x, kc, vc, positions,
                                           cache_start, slopes, tp_axis,
-                                          attn_impl, ep_axis, moe_stats)
+                                          attn_impl, ep_axis, moe_stats,
+                                          valid)
                 K, V = ((kc.stack, vc.stack) if stacked_cache
                         else (k_of.updated(kc), v_of.updated(vc)))
                 return (x, K, V), (rows[0] if rows else None)
@@ -665,7 +688,8 @@ def stage_forward(
         def body(x, scanned):
             lp, kc, vc = scanned
             x, kc, vc = _layer(cfg, lp, x, kc, vc, positions, cache_start,
-                               slopes, tp_axis, attn_impl, ep_axis)
+                               slopes, tp_axis, attn_impl, ep_axis,
+                               valid=valid)
             return x, (kc, vc)
 
         x, (new_k, new_v) = jax.lax.scan(

@@ -37,10 +37,13 @@ _CACHE_SPEC = KVCache(keys=P(None, None, "tp", None, None),
 
 def tp_cache_sharding(mesh: Mesh) -> KVCache:
     """NamedShardings for a KVCache on the tp mesh (kv-head-sharded) —
-    for committing fresh cache buffers to their shards up front."""
+    for committing fresh cache buffers to their shards up front.  The
+    spec is written as a program hands it back, without the trailing
+    ``None``s: a jit's call cache keys on the sharding as written, and a
+    fresh buffer then shares the entry of one that a program returned."""
     from jax.sharding import NamedSharding
-    return KVCache(keys=NamedSharding(mesh, _CACHE_SPEC.keys),
-                   values=NamedSharding(mesh, _CACHE_SPEC.values),
+    heads = NamedSharding(mesh, P(None, None, "tp"))
+    return KVCache(keys=heads, values=heads,
                    length=NamedSharding(mesh, _CACHE_SPEC.length))
 
 
@@ -127,7 +130,9 @@ def make_paged_forward_seam(cfg: ModelConfig, spec: StageSpec, mesh,
 
     ``fwd(..., moe_stats=True)`` (a model with experts) returns
     ``stage_forward``'s third value too, the ``[layers, E]`` rows routed
-    to each expert, replicated under a mesh."""
+    to each expert, replicated under a mesh; ``valid`` is
+    ``stage_forward``'s (the rows that hold a token), replicated like
+    the inputs."""
     from ..ops.paged_attention import make_paged_attn_impl
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
     if tp <= 1:
@@ -135,11 +140,11 @@ def make_paged_forward_seam(cfg: ModelConfig, spec: StageSpec, mesh,
                                           interpret, record)
 
         def fwd(p, inputs, cache, positions, last_logits_only,
-                moe_stats=False):
+                moe_stats=False, valid=None):
             return stage_forward(p, cfg, spec, inputs, cache, positions,
                                  attn_impl=impl,
                                  last_logits_only=last_logits_only,
-                                 moe_stats=moe_stats)
+                                 moe_stats=moe_stats, valid=valid)
 
         return fwd, bind, None
     validate_tp(cfg, mesh)
@@ -151,24 +156,25 @@ def make_paged_forward_seam(cfg: ModelConfig, spec: StageSpec, mesh,
         bound["program"] = program
 
     def fwd(p, inputs, cache, positions, last_logits_only,
-            moe_stats=False):
+            moe_stats=False, valid=None):
         program = bound["program"]
 
-        def body(p_, i_, c_, po_, tab_):
+        def body(p_, i_, c_, po_, tab_, valid_):
             impl, bind_local = make_paged_attn_impl(block_tokens, backend,
                                                     interpret, record)
             bind_local(tab_, program)
             return stage_forward(p_, cfg, spec, i_, c_, po_,
                                  tp_axis="tp", attn_impl=impl,
                                  last_logits_only=last_logits_only,
-                                 moe_stats=moe_stats)
+                                 moe_stats=moe_stats, valid=valid_)
 
+        # ``valid=None`` is an empty pytree: its spec matches nothing
         return jax.shard_map(
             body, mesh=mesh,
-            in_specs=(p_specs, P(), _CACHE_SPEC, P(), P()),
+            in_specs=(p_specs, P(), _CACHE_SPEC, P(), P(), P()),
             out_specs=(P(), _CACHE_SPEC) + ((P(),) if moe_stats else ()),
             check_vma=False)(p, inputs, cache, positions,
-                             bound["tables"])
+                             bound["tables"], valid)
 
     return fwd, bind, tp_cache_sharding(mesh)
 

@@ -57,6 +57,7 @@ bit-exact vs InferenceEngine (pinned by tests).
 
 from __future__ import annotations
 
+import copy
 import itertools
 import queue
 import threading
@@ -686,19 +687,19 @@ class ContinuousBatchingEngine:
 
         # ------------------------------------------------------------------
         # the MIXED token-budget dispatch (docs/DESIGN.md §19): one jit
-        # packing a [n_seg, C] prefill slab (chunk segments from one or
+        # packing a [r, C] prefill slab (chunk segments from one or
         # more admitting prompts, final segments sampling token #1 and
         # installing their slot in-program) with the fused decode loop
-        # over all active rows.  Segment count is FIXED at
-        # budget // C (unused rows ride all-sentinel tables and slot=B,
-        # so every install drops).  A dispatch that packed no segment
-        # passes ``seg=None`` and its program has no slab at all, giving
-        # exactly two compiled variants (slab or none x num_steps, which
-        # is static per decode_block).  Whether a packed segment is a
-        # final is NOT a third: the slab always samples its rows,
-        # because a warm-up of prompts that end within n_seg segments
-        # launches no chunk-only dispatch, and a variant for those
-        # would first compile under traffic.
+        # over all active rows.  The slab is as many segments as the
+        # dispatch packed: r = 1 .. budget // C, the shape of the
+        # segment arrays, and a dispatch that packed none passes
+        # ``seg=None`` and its program has no slab at all.  That gives
+        # n_seg + 1 compiled variants (x num_steps, which is static per
+        # decode_block), and every one is launched once before the
+        # engine takes a request (``_warm_mixed_variants``), so none
+        # compiles under traffic.  Whether a packed segment is a final
+        # is NOT one more: the slab always samples its rows, because
+        # what a warm-up launches is then a matter of shapes alone.
         self._mixed_step = None
         self._mixed_pld_step = None
         self._mixed_spec_step = None
@@ -713,7 +714,7 @@ class ContinuousBatchingEngine:
             shared by the plain and speculative mixed programs (each row
             its own key: the serialized final prefill's exact spend)."""
             f_toks, f_lps = [], []
-            for r in range(self._mixed_seg_cap):
+            for r in range(logits.shape[0]):
                 last = jax.lax.dynamic_index_in_dim(
                     logits[r], seg_lens[r] - 1, axis=0,
                     keepdims=True)                         # [1, V]
@@ -729,7 +730,7 @@ class ContinuousBatchingEngine:
             # summed over the execution's layer calls, then experts
             # touched, the fullest expert's rows in one layer call,
             # and the layer calls); a dense model's program is as it was
-            moe_kw = ({"moe_stats": True} if cfg_.num_experts > 0 else {})
+            moe_ = cfg_.num_experts > 0
             E_ = cfg_.num_experts
 
             def moe_acc0():
@@ -746,12 +747,15 @@ class ContinuousBatchingEngine:
 
             def paged_one_step_moe(params, carry, lengths, last_tok,
                                    active, rng):
-                """``paged_one_step`` with the counters in the carry."""
+                """``paged_one_step`` with the counters in the carry;
+                a slot that does not decode enters no expert's group
+                (``active`` is frozen for the block: a row that ends
+                inside it steps on to the block's end)."""
                 cache, acc = carry
                 pos = lengths[:, None]
                 logits, cache, rows = fwd_p(
                     params, last_tok[:, None], cache, pos, True,
-                    moe_stats=True)
+                    moe_stats=True, valid=active[:, None])
                 return ((cache, moe_fold(acc, rows)),
                         *_sample_step(logits, lengths, last_tok, active,
                                       rng))
@@ -764,8 +768,11 @@ class ContinuousBatchingEngine:
                 packed (the program is then the fused decode loop alone:
                 no slab, no KV write of one, no prefill attention), else
                 ``(seg_ids, seg_tables, seg_starts, seg_lens, seg_slot,
-                seg_plen, seg_keys)``.  Prefill slab first: row r of
-                ``seg_ids`` [n_seg, C] runs at positions
+                seg_plen, seg_keys)`` over the ``r`` segments that were
+                (the shapes are the program's key), and for a model with
+                experts an eighth array, the tokens each segment holds.
+                Prefill slab first: row r of
+                ``seg_ids`` [r, C] runs at positions
                 ``seg_starts[r] + arange(C)`` through ``seg_tables[r]``
                 (sentinel rows compute into dropped writes).  Each row
                 samples token #1 at ``seg_lens[r] - 1`` from its OWN
@@ -778,21 +785,23 @@ class ContinuousBatchingEngine:
                 token #1 was eos enter the loop already done."""
                 B_ = last_tok.shape[0]
                 cache = KVCache(pk, pv, jnp.zeros((), jnp.int32))
-                moe_acc = moe_acc0() if moe_kw else None
+                moe_acc = moe_acc0() if moe_ else None
                 if seg is None:
                     final_toks = jnp.zeros((n_seg,), jnp.int32)
                     final_lps = jnp.zeros((n_seg,), jnp.float32)
                     done0 = None
                 else:
                     (seg_ids, seg_tables, seg_starts, seg_lens, seg_slot,
-                     seg_plen, seg_keys) = seg
+                     seg_plen, seg_keys) = seg[:7]
+                    slab_kw = ({"moe_stats": True, "ntok": seg[7]}
+                               if moe_ else {})
                     # the scopes are metadata on the ops: a capture keeps
                     # each op's path (`jit(mixed_step)/decode_loop/...`)
                     # in the op's event metadata
                     with jax.named_scope("slab_body"):
                         logits, cache, *moe = slab_body(
                             params, cache, seg_ids, seg_tables,
-                            seg_starts, "mixed_step", **moe_kw)
+                            seg_starts, "mixed_step", **slab_kw)
                     if moe:
                         moe_acc = moe_fold(moe_acc, moe[0])
                     with jax.named_scope("slab_finals"):
@@ -812,7 +821,7 @@ class ContinuousBatchingEngine:
                     done0 = done0 | (budget <= 0)
                 bind_tables(dec_tables, "mixed_step")
                 with jax.named_scope("decode_loop"):
-                    if moe_kw:
+                    if moe_:
                         # the counters ride the loop's carry beside the
                         # cache, which _fused_loop never looks into
                         ((cache, moe_acc), lengths, tok, toks, lps,
@@ -831,9 +840,10 @@ class ContinuousBatchingEngine:
                         final_toks, final_lps, toks, lps, steps)
 
             # the §19 invariant the recompile_storm detector enforces:
-            # (slab or none) x one static num_steps = exactly two variants
+            # (no slab, or one of 1 .. n_seg segments) x one static
+            # num_steps = n_seg + 1 variants, all launched before ready
             self._mixed_step = _ct.wrap("mixed_step", mixed_step,
-                                        variant_budget=2)
+                                        variant_budget=n_seg + 1)
 
         def verify_slots(params, cache, drafts, q_logits, lengths,
                          last_tok, active, rng, k_cap=None):
@@ -1247,6 +1257,15 @@ class ContinuousBatchingEngine:
 
         self._lengths = jnp.zeros((B,), jnp.int32)
         self._last_tok = jnp.zeros((B,), jnp.int32)
+        # on every chip of the mesh: where a call's small arguments live
+        self._replicated = None if mesh is None else (
+            jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec()))
+        if mesh is not None:
+            # born where every program hands them back: a program's
+            # first call is then keyed like its later ones (one compiled
+            # entry a variant)
+            self._lengths, self._last_tok = jax.device_put(
+                (self._lengths, self._last_tok), self._replicated)
         self._rng = jax.random.PRNGKey(seed)
         # the resume replay (§23) rewinds the engine stream to this key
         # so a survivor re-derives a sampled prefix bit-exactly
@@ -1283,6 +1302,9 @@ class ContinuousBatchingEngine:
         # MoeCounters; mixed dispatches only: the path that is served)
         moe = cfg.num_experts > 0 and self._mixed_step is not None
         self.moe_counters = MoeCounters(cfg.num_experts) if moe else None
+        # ... and its slab is told the tokens each segment holds, an
+        # eighth segment array (`_blank_segments`)
+        self._seg_arrays = 8 if moe else 7
         # a looped model counts its passes (tracing.LoopCounters); a
         # one-pass model's record and /stats are as they were
         loop = cfg.ut_steps > 1 and self._mixed_step is not None
@@ -1293,8 +1315,8 @@ class ContinuousBatchingEngine:
             (MOE_DISPATCH_FIELDS if moe else ())
             + (LOOP_DISPATCH_FIELDS if loop else ()))
 
-        # (mixed mode never dispatches the serialized step programs —
-        # its two mixed_step variants compile on first use instead)
+        # (mixed mode never dispatches the serialized step programs: it
+        # launches every variant of mixed_step instead, below)
         if self.decode_block > 1 and self.mixed_token_budget == 0:
             # compile BOTH round-count variants now: the non-fused
             # variant's first use otherwise lands as a multi-second
@@ -1335,6 +1357,8 @@ class ContinuousBatchingEngine:
                         self.params, self._pk, self._pv, tbl,
                         self._lengths, self._last_tok, idle,
                         warm_rng)
+        if self._mixed_step is not None:
+            self._warm_mixed_variants()
 
         self._slots: List[Optional[Request]] = [None] * B
         self._queue: "queue.Queue" = queue.Queue()
@@ -3207,6 +3231,26 @@ class ContinuousBatchingEngine:
             flight = nxt = None
             trace.commit(**record)
 
+    def _blank_segments(self) -> tuple:
+        """The slab's arrays over ``n_seg`` segments that hold nothing:
+        ``(ids, tables, starts, lens, slot, plen, keys, ntok)``, every
+        table sentinel and every slot ``B`` (all writes and installs
+        drop).  ``ntok``, the tokens a segment holds, is the eighth;
+        ``mixed_step`` takes the first ``_seg_arrays``."""
+        n_seg, C, i32 = self._mixed_seg_cap, self.prefill_chunk, np.int32
+        return (np.zeros((n_seg, C), i32),
+                np.full((n_seg, self._table_width), self._page_sentinel,
+                        i32),
+                np.zeros((n_seg,), i32), np.ones((n_seg,), i32),
+                np.full((n_seg,), self.max_batch, i32),
+                np.zeros((n_seg,), i32), np.zeros((n_seg, 2), np.uint32),
+                np.zeros((n_seg,), i32))
+
+    def _slab_of(self, seg: tuple, r: int) -> tuple:
+        """The first ``r`` segments of the arrays ``mixed_step`` takes:
+        the slab of a dispatch that packed ``r``."""
+        return tuple(x[:r] for x in seg[:self._seg_arrays])
+
     def _pack_mixed(self, rows: list, adms: list, free: list, rng,
                     tables: np.ndarray) -> types.SimpleNamespace:
         """Pack ONE mixed token-budget dispatch from a view of the
@@ -3234,7 +3278,6 @@ class ContinuousBatchingEngine:
         sampled streams bit-identical."""
         B = self.max_batch
         C = self.prefill_chunk
-        W = self._table_width
         n_seg = self._mixed_seg_cap
         live0 = [i for i, s in enumerate(rows) if s is not None]
         n_active = len(live0)
@@ -3256,13 +3299,9 @@ class ContinuousBatchingEngine:
             room = max(0, self.mixed_token_budget
                        - n_active * self.decode_block)
         want = min(n_seg, max(1, room // C)) if adms else 0
-        seg_ids = np.zeros((n_seg, C), np.int32)
-        seg_tables = np.full((n_seg, W), self._page_sentinel, np.int32)
-        seg_starts = np.zeros((n_seg,), np.int32)
-        seg_lens = np.ones((n_seg,), np.int32)
-        seg_slot = np.full((n_seg,), B, np.int32)
-        seg_plen = np.zeros((n_seg,), np.int32)
-        seg_keys = np.zeros((n_seg, 2), np.uint32)
+        seg = self._blank_segments()
+        (seg_ids, seg_tables, seg_starts, seg_lens, seg_slot, seg_plen,
+         seg_keys, seg_ntok) = seg
         packed = []          # (row, admission, is_final, slot)
         advance = []         # (admission, its start, its suffix) after
         rewound = []         # requests whose §23 rewind this spends
@@ -3279,6 +3318,7 @@ class ContinuousBatchingEngine:
                 seg_ids[r, :] = np.asarray(suffix[:C], np.int32)
                 seg_tables[r] = req._pkv["table"]
                 seg_starts[r] = start
+                seg_ntok[r] = C
                 packed.append((r, a, False, -1))
                 prefill_tokens += C
                 start += C
@@ -3298,6 +3338,7 @@ class ContinuousBatchingEngine:
             seg_tables[r] = req._pkv["table"]
             seg_starts[r] = start
             seg_lens[r] = n
+            seg_ntok[r] = n
             seg_slot[r] = slot
             seg_plen[r] = len(req.prompt)
             # the final's batch-1 sampling key: the serialized
@@ -3313,6 +3354,12 @@ class ContinuousBatchingEngine:
             prefill_tokens += n
             r += 1
         finals = [(a["req"], slot) for (_, a, f, slot) in packed if f]
+        # the slab is the r segments that were packed: the arrays' shape
+        # picks ``mixed_step``'s variant (the speculative programs keep
+        # the full slab); a model with experts is also told how many
+        # tokens each segment holds
+        slab_segs = n_seg if spec_mixed else r
+        seg = self._slab_of(seg, slab_segs)
         active_mask = np.array([s is not None for s in rows])
         # budget: remaining tokens per pre-existing row; a freshly
         # installed final's row has max_new - 1 left (token #1 came
@@ -3359,8 +3406,7 @@ class ContinuousBatchingEngine:
         return types.SimpleNamespace(
             rows=rows, packed=packed, advance=advance, rewound=rewound,
             finals=finals, chunks=chunks, rng=rng, dec_sub=dec_sub,
-            seg=(seg_ids, seg_tables, seg_starts, seg_lens, seg_slot,
-                 seg_plen, seg_keys),
+            seg=seg, slab_rows=slab_segs * C,
             tables=tables, active_mask=active_mask,
             budget_vec=budget_vec,
             prefill_tokens=prefill_tokens, n_active=n_active,
@@ -3371,21 +3417,57 @@ class ContinuousBatchingEngine:
     def _put_mixed(self, plan) -> tuple:
         """The plan's arrays on the device, as ``mixed_step`` takes them
         (once: a plan prepared ahead has them there when it is
-        launched).  Nothing packed: no segment array is transferred."""
+        launched).  A slab of no segment: no segment array is
+        transferred, and ``seg`` is None."""
         if plan.dev is None:
             put = jnp.asarray
-            if self.mesh is not None:
+            if self._replicated is not None:
                 # where the call's other arguments live: every chip of
                 # the mesh holds a copy, so the call spreads nothing
-                rep = jax.sharding.NamedSharding(
-                    self.mesh, jax.sharding.PartitionSpec())
-                put = partial(jax.device_put, device=rep)
+                put = partial(jax.device_put, device=self._replicated)
             plan.dev = (
-                tuple(put(x) for x in plan.seg) if plan.packed else None,
+                tuple(put(x) for x in plan.seg) if plan.slab_rows else None,
                 put(plan.tables), put(plan.active_mask),
                 put(self._eos_scalar()), put(plan.budget_vec),
                 put(plan.dec_sub))
         return plan.dev
+
+    def _call_mixed_step(self, plan) -> list:
+        """Enqueue ``mixed_step`` on ``plan``'s arrays: the pool and the
+        rows' state become what it returns; the rest of its outputs
+        (``final_toks, final_lps, toks, lps, steps[, moe_acc]``) are
+        returned."""
+        seg, tables, active, eos, budget, dec_sub = self._put_mixed(plan)
+        (self._pk, self._pv, self._lengths, self._last_tok,
+         *out) = self._mixed_step(
+            self.params, self._pk, self._pv, seg, tables, self._lengths,
+            self._last_tok, active, dec_sub, eos, budget,
+            self.decode_block)
+        return out
+
+    def _warm_mixed_variants(self) -> None:
+        """Launch every variant of ``mixed_step`` once, before the
+        engine takes a request: the decode loop alone, then a slab of
+        1 .. ``n_seg`` segments.  Which of them a deployment's (or a
+        benchmark's) first prompts would launch is a matter of their
+        lengths and timing, and a variant that met its first execution
+        under traffic would compile there.  Real executions on purpose
+        (an AOT compile does not seed the jit's call cache), through
+        the serving path's own transfer and call.  Nothing is
+        admitted: no row is active, every table is sentinel and every
+        segment's slot is ``B``, so every write and install drops, as
+        an unused row's always did, and the rows' state is returned as
+        it was given."""
+        idle = self._pack_mixed([None] * self.max_batch, [], [], self._rng,
+                                np.full_like(self._tables,
+                                             self._page_sentinel))
+        blank = self._blank_segments()
+        for r in range(self._mixed_seg_cap + 1):
+            plan = copy.copy(idle)
+            plan.seg = self._slab_of(blank, r)
+            plan.slab_rows = r * self.prefill_chunk
+            self._call_mixed_step(plan)
+        jax.block_until_ready(self._last_tok)
 
     def _launch_mixed(self, plan) -> Optional[types.SimpleNamespace]:
         """Commit ``plan``'s effects on the scheduler's state (rng
@@ -3430,16 +3512,9 @@ class ContinuousBatchingEngine:
             phases=trace.launched_phases, steps=0, out=None, tok=None)
         try:
             if not spec_mixed:
-                (seg, tables, active, eos, budget,
-                 dec_sub) = self._put_mixed(plan)
                 with jax.profiler.StepTraceAnnotation("mixed_step",
                                                       step_num=seq):
-                    (self._pk, self._pv, self._lengths, tok,
-                     *flight.out) = self._mixed_step(
-                        self.params, self._pk, self._pv, seg, tables,
-                        self._lengths, self._last_tok, active,
-                        dec_sub, eos, budget, self.decode_block)
-                self._last_tok = tok
+                    flight.out = self._call_mixed_step(plan)
                 # (final_toks, final_lps, toks, lps, steps[, moe_acc]):
                 # small, and the drain reads them all
                 for o in flight.out:
@@ -3635,11 +3710,14 @@ class ContinuousBatchingEngine:
             with_finals=bool(plan.finals), segments=len(packed),
             finals=len(plan.finals), prefill_tokens=prefill_tokens,
             active_rows=n_active, steps=steps,
-            kv_tokens=plan.kv_tokens, ahead=plan.ahead_s, how=plan.how)
+            kv_tokens=plan.kv_tokens, ahead=plan.ahead_s, how=plan.how,
+            slab_rows=plan.slab_rows)
         if self.moe_counters is not None:
             # real tokens: the live segments' prompt tokens, and the
             # steps of the slots that decoded (rows that finish inside
-            # the block still step to its end); each is k rows a layer
+            # the block still step to its end); each is k rows a layer.
+            # They are the rows the device routed: a row that holds no
+            # token enters no expert's group
             acc = np.asarray(flight.out[5])
             E = self.cfg.num_experts
             record.update(self.moe_counters.add(

@@ -184,19 +184,21 @@ def make_paged_chunk_programs(fwd_p, bind_tables):
         return cache.keys, cache.values
 
     def slab_body(params, cache, ids, tables, starts, program,
-                  moe_stats=False):
+                  moe_stats=False, ntok=None):
         """Traced slab forward: row r of ``ids`` [n, s] runs at
         positions ``starts[r] + arange(s)`` through ``tables[r]``;
         returns all-position logits (callers slice their own final
         positions) and the extended cache, and with ``moe_stats`` the
-        seam's expert row counts.  ``program`` names the jitted
-        program composing the slab (the attention-path record's
-        key)."""
+        seam's expert row counts, over the first ``ntok[r]`` positions
+        of each row (the tokens it holds; the rest enter no expert's
+        group).  ``program`` names the jitted program composing the
+        slab (the attention-path record's key)."""
         bind_tables(tables, program)
         b, s = ids.shape
         pos = starts[:, None] + jnp.arange(s)[None, :]
         if moe_stats:
-            return fwd_p(params, ids, cache, pos, False, moe_stats=True)
+            return fwd_p(params, ids, cache, pos, False, moe_stats=True,
+                         valid=jnp.arange(s)[None, :] < ntok[:, None])
         logits, cache = fwd_p(params, ids, cache, pos, False)
         return logits, cache
 

@@ -17,8 +17,8 @@ them *continuously* and names the moment something leaves its envelope:
   stopped predicting the target; every round is wasted work);
 - **recompile_storm** — a tracked jitted program compiled past its
   documented variant budget (``stats()["compile"]`` fragment from
-  ``telemetry/profiling.py``; e.g. ``_mixed_step``'s two-variant
-  invariant) — a silent recompile latency cliff becomes a named event;
+  ``telemetry/profiling.py``; e.g. ``_mixed_step``'s ``n_seg + 1``
+  variants) — a silent recompile latency cliff becomes a named event;
 - **pipeline_stall** — work is in flight but the step counter has not
   advanced for longer than the watchdog window (the explicit
   TransportTimeout path in ``runtime/distributed.py`` covers the ring;
@@ -268,7 +268,8 @@ class AnomalyDetector:
         # recompile storm: a tracked program's compile count exceeds
         # its documented variant budget (telemetry/profiling.py feeds
         # the stats()["compile"] fragment; e.g. _mixed_step may compile
-        # exactly two variants, docs/DESIGN.md §19).  Keyed per program
+        # n_seg + 1 variants, all launched before the engine is ready,
+        # docs/DESIGN.md §19).  Keyed per program
         # so one storming program can't mask another's streak; only
         # budgeted programs are eligible (budget None = unbounded by
         # design, e.g. per-chunk-length prefill variants).

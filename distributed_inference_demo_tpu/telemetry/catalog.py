@@ -806,7 +806,7 @@ COMPILE_CACHE_ENTRIES = gauge(
 COMPILE_VARIANT_BUDGET = gauge(
     "dwt_compile_variant_budget_entries",
     "Documented compiled-variant budget per tracked program (e.g. "
-    "mixed_step's two-variant invariant, docs/DESIGN.md §19); only "
+    "mixed_step's n_seg + 1 variants, docs/DESIGN.md §19); only "
     "budgeted programs feed the recompile_storm detector", ("program",))
 
 HBM_OWNER_BYTES = gauge(

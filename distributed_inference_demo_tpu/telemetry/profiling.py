@@ -26,7 +26,7 @@ worker and HTTP surface in the process reports into the same ledger.
    compile-seconds, live cache entries, documented variant budget).
    The ``stats()["compile"]`` fragment feeds ``anomaly.py``'s
    ``recompile_storm`` detector: a program compiling past its budget
-   (e.g. ``_mixed_step``'s two-variant invariant, §19) becomes a named
+   (e.g. ``_mixed_step``'s ``n_seg + 1`` variants, §19) becomes a named
    anomaly + postmortem bundle instead of a silent latency cliff.
 
 3. :class:`HbmWatermarks` — high-water-mark ledger per pool owner
@@ -373,8 +373,8 @@ class _TrackedJit:
 
 class CompileTracker:
     """Per-program compile ledger.  ``variant_budget`` documents how
-    many compiled variants a program is ALLOWED (``mixed_step``: two —
-    the §19 invariant); the anomaly layer turns budget overruns into
+    many compiled variants a program is ALLOWED (``mixed_step``: the
+    budget's segments + 1, the §19 invariant); the anomaly layer turns budget overruns into
     ``recompile_storm``.  Wrapping the same program name again (a
     second engine in-process) accumulates into the same entry."""
 

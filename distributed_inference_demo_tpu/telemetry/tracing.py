@@ -173,10 +173,12 @@ DISPATCH_FIELDS = (("seq", "t_launch", "t_done") + DISPATCH_PHASES
 # not under its predecessor (runtime.batching, docs/DESIGN.md §19)
 AHEAD_MISS_REASONS = ("arrival", "finish", "cancel", "export", "other")
 # what a model with experts adds to a record (runtime.batching): the
-# token-expert rows the execution routed over all its passes and layers,
-# those of real tokens (a live segment's prompt tokens, an active slot's
-# steps), the experts with >= 1 row summed over the execution's layer
-# calls, and the fullest expert's rows in any one layer call
+# token-expert rows the execution routed over all its passes and layers
+# (the device's count), those of real tokens (the host's: a live
+# segment's prompt tokens, an active slot's steps; the two are equal, a
+# row that holds no token enters no expert's group), the experts with
+# >= 1 row summed over the execution's layer calls, and the fullest
+# expert's rows in any one layer call
 MOE_DISPATCH_FIELDS = ("moe_rows", "moe_valid_rows", "moe_touched",
                        "moe_load_max")
 # what a looped model (``ut_steps > 1``) adds: the passes of the layer
@@ -318,6 +320,8 @@ class DispatchTrace:
         self.decode_only = 0
         self.prefill = 0
         self.kv_token_steps = 0
+        self.prefill_tokens = 0
+        self.slab_rows = 0
         self.queue_wait_ms_sum = 0.0
         self.queue_wait_count = 0
         self.ahead_hits = 0
@@ -416,8 +420,12 @@ class DispatchTrace:
                segments: int, finals: int, prefill_tokens: int,
                active_rows: int, steps: int, kv_tokens: int,
                ahead: float = 0.0, how: Optional[str] = None,
-               phases: Optional[dict] = None, **extra: int) -> int:
+               phases: Optional[dict] = None, slab_rows: int = 0,
+               **extra: int) -> int:
         """A dispatch that reached the device is drained: one record.
+        ``slab_rows``: the rows of the prefill slab its program computed
+        (segments of the launched variant x the chunk), of which
+        ``prefill_tokens`` held a token; both are summed, no column.
         ``phases``: its own seconds (``launched_phases`` as they were
         when the NEXT dispatch had not been launched yet; by default the
         last launched one's).  ``how``: ``"hit"`` (launched as prepared
@@ -442,6 +450,8 @@ class DispatchTrace:
         else:
             self.decode_only += 1
         self.kv_token_steps += kv_tokens * steps
+        self.prefill_tokens += prefill_tokens
+        self.slab_rows += slab_rows
         if how == "hit":
             self.ahead_hits += 1
         elif how == "first":
@@ -462,6 +472,8 @@ class DispatchTrace:
                 "decode_only": self.decode_only,
                 "prefill": self.prefill,
                 "kv_token_steps": self.kv_token_steps,
+                "prefill_tokens": self.prefill_tokens,
+                "slab_rows": self.slab_rows,
                 "queue_wait_ms_sum": round(self.queue_wait_ms_sum, 3),
                 "queue_wait_count": self.queue_wait_count,
                 "ahead_hits": self.ahead_hits,

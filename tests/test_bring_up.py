@@ -320,12 +320,14 @@ def test_engine_reports_gather_on_cpu_and_health_follows_scheduler():
 
 
 def test_attention_paths_whichever_variant_is_traced_first():
-    """PR 33: ``mixed_step`` has a variant with no slab.  Compiled FIRST
-    (a decode-only dispatch over an idle engine, by hand: served traffic
-    always starts with a prefill), then a request through the scheduler:
-    ``attention_paths`` names one program with both chunk shapes, as the
-    benchmark's configurations state it, and ``compile`` counts the two
-    variants its budget allows."""
+    """``mixed_step`` has a variant with no slab (PR 33) and one a number
+    of packed segments (PR 39), and the engine launches them all before
+    it is ready, the one with no slab FIRST (served traffic always starts
+    with a prefill): ``attention_paths`` names one program with both
+    chunk shapes, as the benchmark's configurations state it, before any
+    request, and ``compile`` counts the variants its budget allows (two
+    segments: three); a decode-only dispatch by hand over the idle engine
+    and a request through the scheduler add nothing to either."""
     from distributed_inference_demo_tpu.telemetry import profiling
     cfg = get_model_config("llama-test")
     params = init_full_params(jax.random.PRNGKey(0), cfg)
@@ -337,6 +339,11 @@ def test_attention_paths_whichever_variant_is_traced_first():
         prefill_chunk=8, mixed_token_budget=16)
     try:
         why = "gather: backend=auto on platform=cpu"
+        both = {"mixed_step": {"chunk=1": why, "chunk=8": why}}
+        ready = eng.stats()
+        assert ready["attention_paths"] == both
+        assert ready["compile"]["mixed_step"]["compiles"] == \
+            ready["compile"]["mixed_step"]["variant_budget"] == 3
         out = eng._mixed_step(
             eng.params, eng._pk, eng._pv, None, jnp.asarray(eng._tables),
             eng._lengths, eng._last_tok, jnp.zeros((B,), bool),
@@ -344,14 +351,11 @@ def test_attention_paths_whichever_variant_is_traced_first():
             jnp.zeros((B,), jnp.int32), eng.decode_block)
         eng._pk, eng._pv = out[0], out[1]
         assert int(out[8]) == 0              # no row active: no step ran
-        assert eng.stats()["attention_paths"] == {
-            "mixed_step": {"chunk=1": why}}
         want = eng.submit(list(range(1, 20)), 6).wait(timeout=120)
         stats = eng.stats()
-        assert stats["attention_paths"] == {
-            "mixed_step": {"chunk=1": why, "chunk=8": why}}
-        assert stats["compile"]["mixed_step"]["compiles"] == \
-            stats["compile"]["mixed_step"]["variant_budget"] == 2
+        assert stats["attention_paths"] == both
+        assert stats["compile"]["mixed_step"] == ready["compile"][
+            "mixed_step"]
         assert stats["dispatch_trace"]["decode_only"] > 0
     finally:
         eng.close()
@@ -553,16 +557,17 @@ def test_pool_is_addressed_in_place_on_the_chip(v5e, case, chunk, how):
             made)
 
 
-@pytest.mark.parametrize("slab", [True, False],
-                         ids=["slab", "nothing-packed"])
+@pytest.mark.parametrize("slab", [2, 1, 0],
+                         ids=["slab", "one-segment", "nothing-packed"])
 @pytest.mark.parametrize("model,blocks", [("qwen2.5-7b-int8", 416),
                                           ("olmoe-1b-7b-int8", 224)])
 def test_each_variant_of_mixed_step_leaves_the_pool_in_place(
         v5e, model, blocks, slab):
-    """PR 33: both variants of ``mixed_step`` (a dispatch that packed a
-    segment: slab + decode loop; one that packed none: the decode loop
-    alone), compiled whole for the chip through ``tools/aot_mixed_step``
-    at a dense and the expert cell's flags (PERF.md section 4).  Neither
+    """Every variant of ``mixed_step`` at a budget of two segments (a
+    dispatch that packed two or one: a slab of as many + the decode loop;
+    one that packed none: the decode loop alone), compiled whole for the
+    chip through ``tools/aot_mixed_step``
+    at a dense and the expert cell's flags (PERF.md section 4).  None
     makes anything of the pool's or of a plane's shape, and the variant
     without a slab holds no prefill attention and one KV write."""
     sys.path.insert(0, str(REPO / "tools"))

@@ -123,6 +123,11 @@ def test_records_add_up_to_the_counters_that_were_there(model):
     assert dt["kv_token_steps"] == sum(r["kv_tokens"] * r["steps"]
                                        for r in recs)
     assert dt["queue_wait_count"] == len(prompts)
+    # the slab a dispatch ran is the segments it packed (chunk 8), and
+    # the prompt tokens are what of it held a token
+    assert dt["slab_rows"] == 8 * sum(r["segments"] for r in recs)
+    assert dt["prefill_tokens"] == st["mixed"]["prefill_tokens"]
+    assert 0 < dt["prefill_tokens"] < dt["slab_rows"]
 
 
 def test_kv_token_steps_is_the_hand_count(scripted):
@@ -142,6 +147,9 @@ def test_kv_token_steps_is_the_hand_count(scripted):
             == 22 * 4 + 27 * 4 + 31 * 1 + 3 * 4 + 8 * 1)
     assert stats["dispatch_trace"]["decode_only"] == 3
     assert stats["dispatch_trace"]["prefill"] == 2
+    # a slab of three segments for 22 tokens, of one for 3
+    assert stats["dispatch_trace"]["slab_rows"] == (3 + 1) * 8
+    assert stats["dispatch_trace"]["prefill_tokens"] == 22 + 3
 
 
 def test_phases_fit_between_a_record_and_its_neighbour(scripted):

@@ -27,6 +27,7 @@ import dataclasses
 import sys
 import time
 from pathlib import Path
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -34,6 +35,7 @@ import pytest
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
 
 from distributed_inference_demo_tpu.models import get_model_config
 from distributed_inference_demo_tpu.models.decoder import init_full_params
@@ -126,15 +128,15 @@ def test_mixed_cold_parity_stats_and_zero_h2d(params, oracle):
         assert_both_kinds_ran(eng)
 
 
-def abstract_mixed_call(eng, slab: bool):
-    """Abstract arguments of ``mixed_step``: of a dispatch that packed a
-    prefill segment (``slab``) or of one that packed none."""
+def abstract_mixed_call(eng, slab: bool, segments=None):
+    """Abstract arguments of ``mixed_step``: of a dispatch that packed
+    prefill segments (``slab``: the budget's ``n_seg`` of them, or
+    ``segments``) or of one that packed none."""
     S, i32, u32 = jax.ShapeDtypeStruct, np.int32, np.uint32
     B, W = eng.max_batch, eng._table_width
-    n_seg, C = eng._mixed_seg_cap, eng.prefill_chunk
-    seg = (S((n_seg, C), i32), S((n_seg, W), i32), S((n_seg,), i32),
-           S((n_seg,), i32), S((n_seg,), i32), S((n_seg,), i32),
-           S((n_seg, 2), u32))
+    r = eng._mixed_seg_cap if segments is None else segments
+    seg = tuple(S(x.shape, x.dtype)
+                for x in eng._slab_of(eng._blank_segments(), r))
     return (eng.params, eng._pk, eng._pv, seg if slab else None,
             S((B, W), i32), S((B,), i32), S((B,), i32), S((B,), np.bool_),
             S((2,), u32), S((), i32), S((B,), i32), eng.decode_block)
@@ -151,7 +153,10 @@ def test_a_dispatch_that_packed_nothing_runs_no_slab(model):
     Traced FIRST here: the slab variant then adds its chunk shape under
     the same program name and nothing else."""
     cfg = get_model_config(model)
-    with ContinuousBatchingEngine(
+    # an engine that launched nothing before it was ready (as it stands
+    # it has traced every variant by then): what ONE trace reaches
+    with mock.patch.object(ContinuousBatchingEngine, "_warm_mixed_variants",
+                           lambda self: None), ContinuousBatchingEngine(
             cfg, init_full_params(jax.random.PRNGKey(0), cfg), max_seq=96,
             max_batch=4, sampling=GREEDY, kv_block_tokens=8,
             prefill_chunk=8, decode_block=4, mixed_token_budget=24) as eng:
@@ -185,6 +190,149 @@ def test_a_dispatch_that_packed_nothing_runs_no_slab(model):
             "mixed_step": {"chunk=1": paths["mixed_step"]["chunk=1"],
                            "chunk=8": paths["mixed_step"]["chunk=1"]},
             "paged_multi_step": {"chunk=1": paths["mixed_step"]["chunk=1"]}}
+
+
+def _hand_packed(eng, r: int):
+    """The slab of a dispatch that packed ``r`` segments, by hand, over
+    pages nobody else holds: the first ``r - 1`` chunks of a prompt of
+    sixteen tokens (pages 8, 9) and the six-token final of another
+    (page 4), which installs at slot 1 with five tokens left."""
+    C, sent = eng.prefill_chunk, eng._page_sentinel
+    seg = [x.copy() for x in eng._slab_of(eng._blank_segments(), r)]
+    ids, tables, starts, lens, slot, plen, keys, *ntok = seg
+    long_p = np.arange(30, 46)
+    for i in range(r - 1):
+        ids[i] = long_p[i * C:(i + 1) * C]
+        tables[i, :2] = (8, 9)
+        starts[i] = i * C
+        if ntok:
+            ntok[0][i] = C
+    ids[r - 1, :6] = (9, 8, 7, 6, 5, 4)
+    tables[r - 1, 0] = 4
+    lens[r - 1], slot[r - 1], plen[r - 1] = 6, 1, 6
+    keys[r - 1] = (3, 4)
+    if ntok:
+        ntok[0][r - 1] = 6
+    assert (tables == sent).sum() == tables.size - 2 * (r - 1) - 1
+    return tuple(seg)
+
+
+@pytest.mark.parametrize("r", [1, 2, 3])
+@pytest.mark.parametrize("model", ["llama-test", "olmoe-test"])
+def test_a_slab_of_the_packed_segments_is_the_full_slab_s_dispatch(model, r):
+    """The slab is as many segments as were packed: the same dispatch
+    (``r`` segments, a row decoding beside them) through the
+    ``r``-segment program and through the budget's full ``n_seg`` slab,
+    its other rows blank as an unused row always was, gives the same
+    tokens (the final's token #1 and every decoded one), installs the
+    same slot, and leaves the same pool and, with experts, the same
+    routing counters: a blank row enters no expert's group.  Log-
+    probabilities and pages agree to float32 rounding: two shapes of one
+    matmul are two XLA programs, and on the CPU they differ by 1-2 ulp
+    in a few places (as the serialized and the mixed schedule do,
+    above); through one shape they are bit-identical."""
+    cfg = get_model_config(model)
+    with ContinuousBatchingEngine(
+            cfg, init_full_params(jax.random.PRNGKey(0), cfg), max_seq=96,
+            max_batch=4, sampling=GREEDY, kv_block_tokens=8,
+            prefill_chunk=8, decode_block=4, mixed_token_budget=24) as eng:
+        n_seg, B = eng._mixed_seg_cap, eng.max_batch
+        assert n_seg == 3
+        step, sent = eng._mixed_step.inner, eng._page_sentinel
+        copy = lambda t: jax.tree.map(jnp.copy, t)       # noqa: E731
+        key, eos = jax.random.PRNGKey(1), jnp.int32(-1)
+
+        def call(pool, seg, tables, lengths, last, active, budget):
+            out = step(eng.params, *copy(pool), seg, jnp.asarray(tables),
+                       lengths, last, jnp.asarray(active), key, eos,
+                       jnp.asarray(budget, jnp.int32), eng.decode_block)
+            return out[:2], out[2:]
+
+        # a row that decodes: five tokens installed at slot 0 (page 0)
+        first = [x.copy() for x in eng._slab_of(eng._blank_segments(), 1)]
+        first[0][0, :5] = (5, 4, 3, 2, 1)
+        first[1][0, 0] = 0
+        first[3][0], first[4][0], first[5][0] = 5, 0, 5
+        if len(first) == 8:
+            first[7][0] = 5
+        tables = np.full((B, eng._table_width), sent, np.int32)
+        tables[0, :2] = (0, 1)
+        pool, (lengths, last, *_) = call(
+            (eng._pk, eng._pv), tuple(first), tables,
+            jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
+            [False] * B, [20, 0, 0, 0])
+        assert int(lengths[0]) == 9
+
+        tables[1, :2] = (4, 5)       # the final's row, live before it runs
+        seg = _hand_packed(eng, r)
+        blank = eng._slab_of(eng._blank_segments(), n_seg - r)
+        full = tuple(np.concatenate([a, b]) for a, b in zip(seg, blank))
+        assert full[0].shape[0] == n_seg
+        args = (tables, lengths, last, [True, False, False, False],
+                [16, 5, 0, 0])
+        (pk, pv), cut = call(pool, seg, *args)
+        (pk_f, pv_f), whole = call(pool, full, *args)
+    names = ("lengths", "last_tok", "final_toks", "final_lps", "toks",
+             "lps", "steps", "moe_acc")
+    cut, whole = dict(zip(names, cut)), dict(zip(names, whole))
+    assert ("moe_acc" in cut) == (cfg.num_experts > 0)
+    assert int(cut["steps"]) == 4 and list(cut["lengths"][:2]) == [13, 10]
+    for name in cut:
+        a, b = np.asarray(cut[name]), np.asarray(whole[name])
+        if name in ("final_toks", "final_lps"):      # a row a segment
+            assert a.shape == (r,) and b.shape == (n_seg,)
+            a, b = a[r - 1], b[r - 1]
+        if a.dtype.kind == "f":
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=0,
+                                       err_msg=name)
+        else:
+            assert (a == b).all(), name
+    for a, b in zip(jax.tree.leaves((pk, pv)), jax.tree.leaves((pk_f, pv_f))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
+    if cfg.num_experts:
+        # the counters are the rows that hold a token, padding or none
+        k, L, E = cfg.experts_per_token, cfg.num_layers, cfg.num_experts
+        tokens = (r - 1) * 8 + 6 + 2 * 4     # slab, then two rows' steps
+        assert int(np.asarray(cut["moe_acc"])[:E].sum()) == tokens * k * L
+
+
+@pytest.mark.parametrize("budget", [16, 24])
+def test_every_variant_is_launched_before_the_first_request(params, budget):
+    """``n_seg + 1`` compiled entries of ``mixed_step`` when the engine
+    is ready (the decode loop alone, a slab of 1 .. ``n_seg`` segments),
+    every one launched and none met by traffic first: a first dispatch
+    of one, two (and on a three-segment budget, three) segments, and the
+    decode-only dispatches between them, add no entry, and the idle
+    launches left no dispatch record, no counter and no page behind."""
+    n_seg = budget // 8
+    with mixed_engine(params, mixed_token_budget=budget) as eng:
+        ready = eng.stats()
+        entry = ready["compile"]["mixed_step"]
+        assert entry["variant_budget"] == n_seg + 1
+        assert eng._mixed_step.inner._cache_size() == n_seg + 1 \
+            == entry["cache_entries"]
+        assert ready["dispatch_trace"]["seq"] == 0
+        assert ready["mixed"]["dispatches"] == 0
+        assert eng.kv_cache.used_blocks == 0
+        assert (eng._tables == eng._page_sentinel).all()
+        assert not np.asarray(eng._lengths).any()
+        for r in range(1, n_seg + 1):
+            # prompts that share no prefix: r - 1 chunks and a final
+            eng.submit(list(range(50 * r, 50 * r + 8 * r - 2)),
+                       6).wait(timeout=300)
+        settle(eng)
+        st = eng.stats()
+        dt = st["dispatch_trace"]
+        segs = [row[dt["fields"].index("segments")] for row in dt["recent"]]
+        assert set(segs) == set(range(n_seg + 1)), segs
+        assert eng._mixed_step.inner._cache_size() == n_seg + 1
+        assert st["compile"]["mixed_step"]["compiles"] == entry["compiles"]
+        # the rows the launched programs computed, against those that
+        # held a token
+        assert dt["slab_rows"] == 8 * sum(segs)
+        assert dt["prefill_tokens"] == sum(8 * r - 2
+                                           for r in range(1, n_seg + 1))
 
 
 @pytest.mark.quick
@@ -496,7 +644,9 @@ def test_prepared_dispatches_change_nothing_but_the_order(params, sampled,
         dt = run["trace"]
         assert (dt["ahead_hits"] + sum(dt["ahead_misses"].values())
                 + dt["ahead_first"] == dt["seq"] == len(run["recs"]))
-        assert run["compile"]["cache_entries"] == 2
+        # budget 24 / chunk 8: no slab or one of 1, 2, 3 segments, every
+        # one launched before the first request, none added by traffic
+        assert run["compile"]["cache_entries"] == 4
         assert [r["ahead"] > 0 for r in run["recs"]].count(True) == dt[
             "ahead_hits"]
     assert old["trace"]["ahead_hits"] == 0
@@ -550,6 +700,9 @@ def test_under_a_mesh_a_plan_lies_where_the_call_wants_it():
                         dt["seq"])
         assert (dt["ahead_hits"] > 0) != refuse
     assert seen[True] == seen[False]
+    # ... and that count is the variants', on a mesh as off it: the
+    # rows' state and the pool are born sharded as a program returns them
+    assert seen[True][1] == eng._mixed_seg_cap + 1 == 4
 
 
 @pytest.mark.quick
@@ -564,6 +717,8 @@ def test_spec_mixed_engine_prepares_nothing(params):
         dt = eng.stats()["dispatch_trace"]
     assert dt["seq"] > 4 and dt["ahead_hits"] == 0
     assert dt["phase_s"]["ahead"] == 0.0
+    # ... and their full slab: three segments of eight a dispatch
+    assert dt["slab_rows"] == 3 * 8 * dt["seq"]
     assert dt["ahead_misses"]["other"] + dt["ahead_first"] == dt["seq"]
     assert all(r[dt["fields"].index("ahead")] == 0 for r in dt["recent"])
 

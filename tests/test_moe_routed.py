@@ -241,6 +241,59 @@ def test_routed_moe_under_manual_tp(name, devices):
                                rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("quant", ["float32", "int8"])
+@pytest.mark.parametrize("tp", [1, 2])
+@pytest.mark.parametrize("cfg", [OLMOE, MIXTRAL], ids=["olmoe", "mixtral"])
+def test_a_row_that_holds_no_token_enters_no_group(cfg, tp, quant, devices):
+    """``_moe_routed(valid=...)``: on the rows that hold a token exactly
+    what the unmasked layer returns for them, bit for bit; zeros on the
+    others; ``rows`` counts the valid rows' ``k`` and nothing else (the
+    group sizes the grouped matmul runs on).  Also with the experts
+    sharded over ``tp`` (another rank's rows and the rows that hold no
+    token sort behind the local groups together), and with no row valid
+    at all (an idle engine's step: no group, all zeros)."""
+    lp = _layer(jax.random.PRNGKey(2), cfg)
+    if quant == "int8":
+        lp = dict(lp, **{n: quantize_array(lp[n])
+                         for n in ("w_gate", "w_up", "w_down")})
+    k, E, H = cfg.experts_per_token, cfg.num_experts, cfg.hidden_size
+    x = jax.random.normal(jax.random.PRNGKey(3), (3, 8, H))
+    valid = jnp.asarray(np.random.RandomState(4).rand(3, 8) < 0.4)
+    valid = valid.at[1].set(jnp.arange(8) < 5)     # a short final's rows
+    assert 0 < int(valid.sum()) < 24
+
+    if tp == 1:
+        def run(v):
+            return _moe_routed(cfg, lp, x, None, v)
+    else:
+        from jax.sharding import PartitionSpec as P
+        mesh = make_mesh(MeshConfig(tp=2), devices)
+        stack = {n: P("tp") for n in ("w_gate", "w_up", "w_down")}
+        specs = jax.tree.map(lambda _: P(), lp)
+        specs.update({n: jax.tree.map(lambda _: P("tp"), lp[n])
+                      for n in stack})
+
+        def run(v):
+            return jax.shard_map(
+                lambda lp_, x_, v_: _moe_routed(cfg, lp_, x_, "tp", v_),
+                mesh=mesh, in_specs=(specs, P(), P()),
+                out_specs=(P(), P()), check_vma=False)(lp, x, v)
+
+    whole, whole_rows = run(None)
+    got, rows = run(valid)
+    keep = np.asarray(valid)
+    assert (np.asarray(got)[keep] == np.asarray(whole)[keep]).all()
+    assert (np.asarray(got)[~keep] == 0).all()
+    assert np.abs(np.asarray(whole)[~keep]).min() > 0
+    assert int(rows.sum()) == int(valid.sum()) * k
+    assert int(whole_rows.sum()) == 24 * k
+    # the counts are those of routing the valid rows alone
+    _, alone = _moe_routed(cfg, lp, x[valid][None])
+    assert (np.asarray(rows) == np.asarray(alone)).all()
+    none, no_rows = run(jnp.zeros((3, 8), bool))
+    assert (np.asarray(none) == 0).all() and int(no_rows.sum()) == 0
+
+
 def test_ep_path_routes_like_the_routed_layer(devices):
     """``--ep`` with a ``norm_topk_prob: false`` model: the capacity-slot
     path calls the same ``_route``; with capacity to spare it equals the
@@ -310,29 +363,34 @@ def test_olmoe_mixed_path_equals_stage_forward(olmoe_run):
 
 
 def test_moe_counters_sum_to_tokens_times_k_times_layers(olmoe_run):
-    """Every pass routes every row it carries: per execution ``moe_rows``
-    = (slab rows + slots x steps) x k x layers; over the run the experts'
-    rows sum to it, the valid rows to the real tokens', and the dispatch
-    records carry the same numbers."""
+    """A pass routes the rows that hold a token and no other: on every
+    record the device's ``moe_rows`` equals the host's
+    ``moe_valid_rows`` = (prompt tokens packed + decoding slots x steps)
+    x k x layers, whatever the slab's padding (a short final, the rows
+    past it) and however many slots idle; over the run the experts' rows
+    sum to it, and the dispatch records carry the same numbers."""
     cfg, _, prompts, outs, _, st = olmoe_run
     k, L, E = cfg.experts_per_token, cfg.num_layers, cfg.num_experts
     moe, dt = st["moe"], st["dispatch_trace"]
     assert dt["fields"] == list(DISPATCH_FIELDS + MOE_DISPATCH_FIELDS)
     recs = [dict(zip(dt["fields"], r)) for r in dt["recent"]]
     assert len(recs) == moe["dispatches"] == dt["seq"]
-    slab, slots = 48, 4
+    chunk, slots = 16, 4
     for r in recs:
         # the slab runs where a segment was packed, and only there
         passes = (r["segments"] > 0) + r["steps"]
-        assert r["moe_rows"] == (slab * (r["segments"] > 0)
-                                 + slots * r["steps"]) * k * L
-        assert r["moe_valid_rows"] == (
+        assert r["moe_rows"] == r["moe_valid_rows"] == (
             r["prefill_tokens"] + (r["active_rows"] + r["finals"])
             * r["steps"]) * k * L
+        # ... of the rows its program computed
+        assert r["moe_rows"] <= (chunk * r["segments"]
+                                 + slots * r["steps"]) * k * L
         assert 0 < r["moe_touched"] <= passes * L * E
-        assert r["moe_load_max"] <= slab * k
-    assert moe["rows"] == sum(r["moe_rows"] for r in recs) \
-        == sum(moe["expert_rows"])
+        assert r["moe_load_max"] <= max(r["prefill_tokens"], slots)
+    # the run did pad: finals of 5 (37 = 2 x 16 + 5), 9 and 4 tokens
+    assert any(r["prefill_tokens"] % chunk for r in recs)
+    assert moe["rows"] == moe["valid_rows"] \
+        == sum(r["moe_rows"] for r in recs) == sum(moe["expert_rows"])
     assert moe["layer_calls"] == sum(
         ((r["segments"] > 0) + r["steps"]) * L for r in recs)
     assert moe["touched"] == sum(r["moe_touched"] for r in recs)
@@ -351,12 +409,12 @@ def test_a_decode_only_record_counts_the_decode_steps_alone():
     """PR 33: one request, so the dispatches are scripted: the first
     packs the prompt's final (slab + 4 steps), the rest pack nothing and
     run no slab.  Their counters hold the decode loop's layer calls and
-    rows and nothing of a slab's: ``steps x layers`` calls of ``slots x
-    k`` rows, no expert fuller than the slots."""
+    rows and nothing of a slab's: ``steps x layers`` calls of the ONE
+    decoding slot's ``k`` rows (the three idle slots enter no expert's
+    group), no expert with more than that one row."""
     cfg = get_model_config("olmoe-test-int8")
     params = init_full_params(jax.random.PRNGKey(0), cfg, quantize=True)
-    k, L, E = cfg.experts_per_token, cfg.num_layers, cfg.num_experts
-    slab, slots = 48, 4
+    k, L = cfg.experts_per_token, cfg.num_layers
     with _engine(cfg, params) as eng:
         before = eng.stats()["moe"]["layer_calls"]
         eng.submit(np.arange(1, 10, dtype=np.int32), 10).wait(timeout=300)
@@ -368,13 +426,15 @@ def test_a_decode_only_record_counts_the_decode_steps_alone():
     dt = st["dispatch_trace"]
     first, *alone = [dict(zip(dt["fields"], r)) for r in dt["recent"]]
     assert (first["segments"], first["steps"]) == (1, 4)
-    assert first["moe_rows"] == (slab + slots * 4) * k * L
+    # the nine prompt tokens of a sixteen-wide segment, then the
+    # installed row's four steps
+    assert first["moe_rows"] == first["moe_valid_rows"] == (9 + 4) * k * L
     assert [(r["segments"], r["steps"]) for r in alone] == [(0, 4), (0, 1)]
     for r in alone:
-        assert r["moe_rows"] == slots * k * r["steps"] * L
-        assert r["moe_valid_rows"] == 1 * r["steps"] * k * L
-        assert 0 < r["moe_touched"] <= r["steps"] * L * min(E, slots * k)
-        assert r["moe_load_max"] <= slots
+        assert r["moe_rows"] == r["moe_valid_rows"] == r["steps"] * k * L
+        # the parent's four routed slots touched up to ``slots x k`` a call
+        assert 0 < r["moe_touched"] <= r["steps"] * L * k
+        assert r["moe_load_max"] == 1
     assert before == 0 and st["moe"]["layer_calls"] == (1 + 4 + 4 + 1) * L
     assert dt["decode_only"] == 2 and dt["prefill"] == 1
 

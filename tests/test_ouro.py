@@ -460,11 +460,14 @@ def _parent_engine(model):
 @pytest.mark.parametrize("slab", [False, True], ids=["decode", "slab"])
 @pytest.mark.parametrize("model", ["qwen2-test", "bloom-test", "olmoe-test"])
 def test_one_pass_mixed_step_lowers_to_the_parent_s_program(model, slab):
-    """``ut_steps == 1``: the pre-optimisation program of both variants
-    of ``mixed_step`` is the parent's (PR 33, dd8ee66) character for
-    character, by the hash kept in ``tests/data``: the five cells of the
-    benchmark run what they ran.  The text is this JAX's; under another
-    version the kept hashes say nothing."""
+    """``ut_steps == 1``: the pre-optimisation program of ``mixed_step``
+    with no segment and with the budget's ``n_seg`` is the kept one
+    character for character, by the hash in ``tests/data``: a dense
+    model's is PR 33's (dd8ee66), so the dense cells run what they ran
+    where they pack a full slab or none; a model with experts' is PR
+    39's, which told it the rows that hold a token (the fixture's
+    ``since_pr39``).  The text is this JAX's; under another version the
+    kept hashes say nothing."""
     if jax.__version__ != PARENT["jax"]:
         pytest.skip(f"hashes were made under jax {PARENT['jax']}")
     with _parent_engine(model) as eng:

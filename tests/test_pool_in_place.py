@@ -59,15 +59,14 @@ def _programs(eng, params):
     its variants (PR 33: a dispatch that packed nothing has no slab)."""
     S = jax.ShapeDtypeStruct
     i32, u32 = jnp.int32, jnp.uint32
-    W, n_seg = eng._table_width, eng._mixed_seg_cap
+    W = eng._table_width
     pool = (params, eng._pk, eng._pv)
     row = (S((B, W), i32), S((B,), i32), S((B,), i32), S((B,), jnp.bool_),
            S((2,), u32), S((), i32), S((B,), i32))
     return {
         "mixed_step": (eng._mixed_step.inner, (
-            *pool, (S((n_seg, CHUNK), i32), S((n_seg, W), i32),
-                    S((n_seg,), i32), S((n_seg,), i32), S((n_seg,), i32),
-                    S((n_seg,), i32), S((n_seg, 2), u32)), *row, BLOCK)),
+            *pool, tuple(S(x.shape, x.dtype) for x in eng._slab_of(
+                eng._blank_segments(), eng._mixed_seg_cap)), *row, BLOCK)),
         "mixed_step, nothing packed": (eng._mixed_step.inner, (
             *pool, None, *row, BLOCK)),
         "paged_multi_step": (eng._paged_multi_step.inner,

@@ -298,10 +298,12 @@ def test_recompile_storm_end_to_end_with_real_jit(tmp_path):
 
 
 def test_every_dispatch_kind_compiles_the_budget_and_no_storm():
-    """PR 33: ``mixed_step`` is keyed by whether a segment was packed.
-    A run with every kind of dispatch (chunks alone, a final, decode
-    alone) compiles exactly the ``variant_budget`` the engine declares,
-    two, so the storm detector has nothing to say at slack 0."""
+    """``mixed_step`` is keyed by the segments a dispatch packed (none:
+    PR 33; one .. the budget's: PR 39).  A run with every kind of
+    dispatch (chunks alone, a final, decode alone; two segments, one,
+    none) compiles exactly the ``variant_budget`` the engine declares,
+    the budget's two segments + 1, all of it before the first request,
+    so the storm detector has nothing to say at slack 0."""
     import jax
 
     from distributed_inference_demo_tpu.models import get_model_config
@@ -330,8 +332,9 @@ def test_every_dispatch_kind_compiles_the_budget_and_no_storm():
         kinds = {(r["segments"] > 0, r["finals"] > 0) for r in recs}
         assert kinds == {(True, False), (True, True), (False, False)}
         comp = stats["compile"]["mixed_step"]
+        assert {r["segments"] for r in recs} == {0, 1, 2}
         assert comp["compiles"] == comp["cache_entries"] \
-            == comp["variant_budget"] == 2
+            == comp["variant_budget"] == 3
         clock = FakeClock()
         det = AnomalyDetector(_storm_thresholds(), clock=clock)
         assert det.observe({"compile": stats["compile"]}) == []

@@ -13,11 +13,12 @@ least as large as one layer's plane of the page pool.
 libtpu compiles for a described topology (``v5e:2x2``, one of its
 devices) without a chip; parameters and the page pool are shapes only, so
 nothing model-sized is allocated.  It proves compilation and sizes a pool
-before any chip call (PERF.md section 4); times need the chip.  Two
-compiles a ``--kv-cache-blocks`` value, one a variant of the program (a
-dispatch that packed a prefill segment runs the slab and the decode
-loop, one that packed none the decode loop alone); a refusal (out of
-memory) is printed, not raised.
+before any chip call (PERF.md section 4); times need the chip.  One
+compile a variant of the program a ``--kv-cache-blocks`` value: a
+dispatch runs a slab of as many segments as it packed, 1 to budget //
+chunk of them, and the decode loop, and one that packed none the decode
+loop alone (largest first); a refusal (out of memory) is printed, not
+raised.
 
 Reading the large ops ("no pool copy" without a chip).  The pool is
 addressed in place (``ops.stacked.LayerOf``): on the chip the KV write is
@@ -51,10 +52,10 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 GIB = float(1 << 30)
 
 
-def compile_mixed_step(model: str, blocks: int, args, slab=True):
+def compile_mixed_step(model: str, blocks: int, args, segments=None):
     """``(compiled, engine)`` of ``mixed_step`` at ``blocks`` pool pages:
-    the variant of a dispatch that packed a prefill segment (``slab``),
-    or of one that packed none (the decode loop alone)."""
+    the variant of a dispatch that packed ``segments`` prefill segments
+    (default: all the budget holds; 0: the decode loop alone)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
@@ -81,7 +82,10 @@ def compile_mixed_step(model: str, blocks: int, args, slab=True):
     def abstract_pool(*a, **k):
         return jax.eval_shape(lambda: pool(*a, **k))
 
-    with mock.patch.object(quant, "alloc_kv_pool", abstract_pool):
+    # shapes only: the engine launches nothing before it is ready
+    with mock.patch.object(quant, "alloc_kv_pool", abstract_pool), \
+            mock.patch.object(ContinuousBatchingEngine,
+                              "_warm_mixed_variants", lambda self: None):
         eng = ContinuousBatchingEngine(
             cfg, params, max_seq=args.max_seq, max_batch=args.batch_slots,
             sampling=SamplingParams(temperature=0.0),
@@ -90,8 +94,8 @@ def compile_mixed_step(model: str, blocks: int, args, slab=True):
             mixed_token_budget=args.mixed_token_budget,
             kv_cache_blocks=blocks, kv_block_tokens=args.kv_block_tokens)
     try:
-        B, C, W = args.batch_slots, args.prefill_chunk, eng._table_width
-        n_seg = eng._mixed_seg_cap
+        B, W = args.batch_slots, eng._table_width
+        r = eng._mixed_seg_cap if segments is None else segments
 
         def S(shape, dtype):
             return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
@@ -100,11 +104,9 @@ def compile_mixed_step(model: str, blocks: int, args, slab=True):
             return jax.tree.map(lambda x: S(x.shape, x.dtype), tree)
 
         i32 = jnp.int32
-        seg = (S((n_seg, C), i32), S((n_seg, W), i32), S((n_seg,), i32),
-               S((n_seg,), i32), S((n_seg,), i32), S((n_seg,), i32),
-               S((n_seg, 2), jnp.uint32))
+        seg = on_chip(eng._slab_of(eng._blank_segments(), r))
         call = (on_chip(params), on_chip(eng._pk), on_chip(eng._pv),
-                seg if slab else None, S((B, W), i32), S((B,), i32),
+                seg if r else None, S((B, W), i32), S((B,), i32),
                 S((B,), i32), S((B,), jnp.bool_), S((2,), jnp.uint32),
                 S((), i32), S((B,), i32), args.decode_block)
         with mock.patch.object(jax, "default_backend", lambda: "tpu"):
@@ -159,13 +161,14 @@ def main(argv=None) -> int:
     ap.add_argument("--kv-cache-blocks", type=int, nargs="+", required=True)
     args = ap.parse_args(argv)
     import jax
-    for blocks, slab in itertools.product(args.kv_cache_blocks,
-                                          (True, False)):
-        which = (f"blocks={blocks} "
-                 f"{'slab + decode loop' if slab else 'decode loop alone'}")
+    n_seg = max(1, args.mixed_token_budget // args.prefill_chunk)
+    for blocks, r in itertools.product(args.kv_cache_blocks,
+                                       range(n_seg, -1, -1)):
+        which = (f"blocks={blocks} " + (
+            f"slab of {r} segment{'s' * (r > 1)} + decode loop" if r
+            else "decode loop alone"))
         try:
-            compiled, eng = compile_mixed_step(args.model, blocks, args,
-                                               slab)
+            compiled, eng = compile_mixed_step(args.model, blocks, args, r)
         except Exception as e:              # the compiler's refusal
             msg = " ".join(str(e).split())
             print(f"{which}: REFUSED {type(e).__name__}: {msg[:600]}",
