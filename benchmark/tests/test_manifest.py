@@ -1,7 +1,12 @@
 """BENCHMARK.json against the files it names, and run.py against names."""
+import importlib
 import json
 import re
 from pathlib import Path
+
+import pytest
+
+import run as bench_run
 
 BENCH = Path(__file__).resolve().parent.parent
 M = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
@@ -74,3 +79,36 @@ def test_a_layer_metric_is_listed_only_where_the_metric_it_moves_is():
         have = [n for n, w in e2e.items() if w is None or cell in w]
         assert "setup_s" in have and len(have) >= 2
         assert any(cell in m.get("workloads", cells) for m in M["per_layer"])
+
+
+class _NeedsTheRun(Exception):
+    """A reader asked for something only a run has."""
+
+
+class _CellAlone(dict):
+    """A reader's context before any run: the cell, its mix and its load."""
+
+    def __missing__(self, key):
+        raise _NeedsTheRun(key)
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in M["workloads"]])
+def test_a_cell_lists_no_metric_whose_reader_refuses_its_kind_of_loop(cell):
+    """A reader that returns ``None`` on the mix alone (``tpot_p90_ms`` and
+    the client's TTFT statistics in a closed loop) has nothing to read in
+    that cell whatever the run: the cell may not list it."""
+    w = next(w for w in M["workloads"] if w["name"] == cell)
+    ctx = _CellAlone(
+        cell=w, mix=json.loads((BENCH / "traffic" / f"{w['traffic']}.json").read_text()),
+        load=json.loads((BENCH / "cells" / f"{cell}.json").read_text()))
+    listed = [(package, m["name"])
+              for package, group in (("end_to_end", "end_to_end"),
+                                     ("layer_metrics", "per_layer"))
+              for m in bench_run.metric_entries(M, group, cell)]
+    assert ("end_to_end", "tpot_p50_ms") in listed
+    for package, name in listed:
+        read = importlib.import_module(f"{package}.{name}").read
+        try:
+            assert read(ctx) is not None, f"{cell} lists {name}"
+        except _NeedsTheRun:
+            pass
