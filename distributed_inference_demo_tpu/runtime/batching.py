@@ -1418,6 +1418,7 @@ class ContinuousBatchingEngine:
         # serializes submit() against close(): no request can be enqueued
         # after close() returns, so none can slip past the shutdown drain
         self._submit_lock = threading.Lock()
+        self.dispatch_trace.watch_gc()     # until close()
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
 
@@ -2284,6 +2285,7 @@ class ContinuousBatchingEngine:
         self._running = False
         self._queue.put(None)              # wake the scheduler
         self._thread.join(timeout=30)
+        self.dispatch_trace.close()
         # the tier dies with its pool: demoted entries reference a page
         # layout the successor engine may not share, and the host ring /
         # mmap'd segment must not outlive the engine that budgeted them
@@ -3214,7 +3216,7 @@ class ContinuousBatchingEngine:
                 why = self._ahead_refusal(flight)
                 if why is None:
                     ahead = self._launch_mixed(nxt)
-                    with trace.ahead():
+                    with trace.ahead("ahead_drain"):
                         record = self._drain_mixed(flight)
                     trace.commit(phases=flight.phases, **record)
                     flight = ahead
@@ -3567,8 +3569,11 @@ class ContinuousBatchingEngine:
 
     def _await_mixed(self, flight) -> None:
         """Block until the dispatch in flight has returned: the first
-        read of one of its outputs."""
-        if flight.plan.spec_mixed:
+        read of one of its outputs, which the record says was late if
+        the output was there before the host came for it."""
+        spec = flight.plan.spec_mixed
+        self.dispatch_trace.awaiting(flight.out[2 if spec else 4].is_ready())
+        if spec:
             em, ns = flight.out[2:]
             flight.em_np, flight.ns_np = np.asarray(em), np.asarray(ns)
             flight.steps = flight.plan.num_rounds

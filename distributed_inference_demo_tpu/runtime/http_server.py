@@ -32,6 +32,7 @@ The backend is anything with the engine surface (``generate`` /
 from __future__ import annotations
 
 import json
+import os
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -534,6 +535,10 @@ class InferenceHTTPServer:
                         "device_kind": devs[0].device_kind,
                         "device_count": len(devs),
                         "devices": devices,
+                        # the cores this process may run on: whatever
+                        # else serves beside it (a gateway, a client,
+                        # a poller) shares them
+                        "host_cpus": len(os.sched_getaffinity(0)),
                         "max_seq": getattr(outer.backend, "max_seq", None),
                     })
                 elif self.path == "/stats":

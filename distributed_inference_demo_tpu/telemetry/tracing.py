@@ -34,13 +34,17 @@ from __future__ import annotations
 
 import contextlib
 import copy
+import gc
 import itertools
 import os
 import random
+import resource
 import threading
 import time
 from collections import deque
 from typing import Dict, Iterable, List, Optional
+
+from .flightrecorder import get_flight_recorder
 
 _MAX_SPANS = 8192          # bounded: long runs keep O(1) memory
 
@@ -162,13 +166,26 @@ DISPATCH_PHASES = ("bookkeeping", "intake", "pack", "launch", "wait",
 # the three phases that belong to the dispatch that was launched last;
 # the other three to the one that is launched next
 _LAUNCHED_PHASES = ("launch", "wait", "drain")
-# a record's last column: the seconds of host work for this dispatch
-# that ran under the previous execution instead of in the gap before
-# this one (0 unless it was launched as prepared: DispatchTrace.ahead)
+# a record's last three columns.  ``ahead``: the seconds of host work
+# for this dispatch that ran under the previous execution instead of in
+# the gap before this one (0 unless it was launched as prepared:
+# DispatchTrace.ahead).  ``late``: 1 if the device had already finished
+# it when the host came to read (DispatchTrace.awaiting): this dispatch
+# the host held the device up.  ``await``: the seconds the host was then
+# blocked in that read, the end of ``wait``
 DISPATCH_FIELDS = (("seq", "t_launch", "t_done") + DISPATCH_PHASES
                    + ("with_finals", "segments", "finals",
                       "prefill_tokens", "active_rows", "steps",
-                      "kv_tokens", "ahead"))
+                      "kv_tokens", "ahead", "late", "await"))
+# what a launched dispatch keeps until its commit: its phases' seconds
+# and the two columns its blocking read fills
+_OWN = DISPATCH_PHASES + ("await", "late")
+# the spans of ``/stats.dispatch_trace.spans`` (wall and thread CPU
+# seconds each): the phases but ``wait``, which is no work of the
+# host's, the two kinds of work done under an execution
+# (``phase_s["ahead"]`` is their sum), and the blocking read
+DISPATCH_SPANS = (tuple(p for p in DISPATCH_PHASES if p != "wait")
+                  + ("ahead_plan", "ahead_drain", "await"))
 # why a dispatch that followed another at once was packed in the gap and
 # not under its predecessor (runtime.batching, docs/DESIGN.md §19)
 AHEAD_MISS_REASONS = ("arrival", "finish", "cancel", "export", "other")
@@ -185,7 +202,24 @@ MOE_DISPATCH_FIELDS = ("moe_rows", "moe_valid_rows", "moe_touched",
 # stack the execution ran, ((1 if it packed a segment else 0) + steps)
 # x ut_steps
 LOOP_DISPATCH_FIELDS = ("ut_passes",)
-_DISPATCH_RING = 128       # x ~120 bytes a row: /stats stays under 16 KB
+_DISPATCH_RING = 128       # x ~135 bytes a row: /stats stays under 18 KB
+# a span that is work (every one but ``await``) and lasts this long is a
+# stall: ten times the longest ordinary span (four chips' ``ahead``,
+# 4.65 ms) and longer than the shortest execution (four chips' 30 ms),
+# so a span that long has certainly held the device up
+STALL_S = 0.05
+STALL_FIELDS = ("seq", "span", "t0", "wall", "cpu", "proc_cpu", "gc",
+                "nivcsw", "cause")
+# where a stall's seconds went, by rule and in this order: a garbage
+# collection (>= half of the wall seconds; any thread's holds the GIL),
+# the span's own Python or C work (thread CPU >= half), another thread
+# of the process (process CPU less the thread's >= half: the GIL's
+# holder), else blocked in a call or taken off the core (``nivcsw``, the
+# thread's involuntary context switches, says which)
+STALL_CAUSES = ("gc", "own_cpu", "other_threads", "off_cpu")
+_STALL_RING = 32
+_IDLE_RING = 64            # engine-empty waits of >= _IDLE_MIN_S
+_IDLE_MIN_S = 0.001
 
 
 class LoopCounters:
@@ -288,7 +322,18 @@ class DispatchTrace:
     into one row of :data:`DISPATCH_FIELDS`.  An iteration that
     dispatched nothing carries its seconds into the next record.  The
     blocking wait of an idle engine is no phase (:meth:`idle`), and host
-    work done under an execution is no seventh tile (:meth:`ahead`)."""
+    work done under an execution is no seventh tile (:meth:`ahead`).
+
+    ``wait`` is not "the device busy all the while": it runs from the
+    call's return to the first blocking read and holds the host's work
+    under the execution, which may outlast it.  :meth:`awaiting` says
+    for every dispatch whether it did (``late``).  Beside the record,
+    every boundary also reads the thread's and the process's CPU time,
+    the thread's involuntary context switches and the seconds garbage
+    collections have taken (:meth:`watch_gc`), so that :attr:`spans`
+    holds wall and CPU seconds of each kind of host work and a span of
+    :data:`STALL_S` or more leaves a row in :attr:`stalls` that says
+    where its seconds went (:data:`STALL_CAUSES`)."""
 
     def __init__(self, extra_fields: tuple = ()):
         """``extra_fields``: columns after :data:`DISPATCH_FIELDS`
@@ -298,23 +343,31 @@ class DispatchTrace:
         from jax.profiler import TraceAnnotation
         self._annotate = TraceAnnotation
         self.extra_fields = tuple(extra_fields)
-        self._names = {p: f"sched.{p}"
-                       for p in DISPATCH_PHASES + ("ahead",)}
+        self._names = {p: f"sched.{p}" for p in DISPATCH_SPANS + ("wait",)}
         self._phase: Optional[str] = None
-        self._t0 = 0.0
+        self._at: tuple = ()        # the boundary the phase started at
+        self._seq = 0               # the dispatch it belongs to
         self._ann = None
+        self._await = None          # (boundary, annotation) of the read
+        self._gc_t0: Optional[float] = None
         self.recent: "deque[tuple]" = deque(maxlen=_DISPATCH_RING)
+        self.idles: "deque[tuple]" = deque(maxlen=_IDLE_RING)
+        self.stalls: "deque[dict]" = deque(maxlen=_STALL_RING)
         self.seq = self.launched = 0
         self.reset()
 
     def reset(self) -> None:
         self.recent.clear()
+        self.idles.clear()
+        self.stalls.clear()
         # a dispatch in flight commits after the reset, as number 1
         self.launched -= self.seq
         self.seq = 0
         self.phase_s = dict.fromkeys(DISPATCH_PHASES + ("ahead",), 0.0)
-        self._next = dict.fromkeys(DISPATCH_PHASES, 0.0)
-        self._last = dict.fromkeys(DISPATCH_PHASES, 0.0)
+        # name -> (n, wall seconds, thread CPU seconds, longest)
+        self.spans = dict.fromkeys(DISPATCH_SPANS, (0, 0.0, 0.0, 0.0))
+        self._next = dict.fromkeys(_OWN, 0.0)
+        self._last = dict.fromkeys(_OWN, 0.0)
         self._into = self._next
         self.idle_wait_s = 0.0
         self.decode_only = 0
@@ -327,28 +380,85 @@ class DispatchTrace:
         self.ahead_hits = 0
         self.ahead_misses = dict.fromkeys(AHEAD_MISS_REASONS, 0)
         self.ahead_first = 0
+        self.late_reads = 0
+        self.stall_s = 0.0
+        self.stall_count = 0
+        self.gc_pause_s = 0.0
+        self.gc_max_pause_s = 0.0
+        self.gc_collections = [0, 0, 0]
+
+    def _stamp(self) -> tuple:
+        """One boundary: ``(monotonic, the thread's CPU seconds, the
+        process's, the thread's involuntary context switches, the
+        seconds collections have taken so far)``.  Two system calls:
+        the thread's two numbers come from one ``getrusage`` (a call
+        costs 6 us where the kernel is a sandbox's, 0.3 elsewhere)."""
+        ru = resource.getrusage(resource.RUSAGE_THREAD)
+        return (time.monotonic(), ru.ru_utime + ru.ru_stime,
+                time.process_time(), ru.ru_nivcsw, self.gc_pause_s)
+
+    def _span(self, name: str, a: tuple, b: tuple, seq: int) -> float:
+        """Book the span ``name`` of dispatch ``seq`` between two
+        boundaries; returns its wall seconds."""
+        wall, cpu = b[0] - a[0], b[1] - a[1]
+        n, wall_s, cpu_s, longest = self.spans[name]
+        self.spans[name] = (n + 1, wall_s + wall, cpu_s + cpu,
+                            max(longest, wall))
+        if wall >= STALL_S and name != "await":
+            proc, gc_s, half = b[2] - a[2], b[4] - a[4], wall / 2
+            cause = ("gc" if gc_s >= half else "own_cpu" if cpu >= half
+                     else "other_threads" if proc - cpu >= half
+                     else "off_cpu")
+            row = dict(zip(STALL_FIELDS, (
+                seq, name, round(a[0], 5), round(wall, 5), round(cpu, 5),
+                round(proc, 5), round(gc_s, 5), b[3] - a[3], cause)))
+            self.stalls.append(row)
+            self.stall_s += wall
+            self.stall_count += 1
+            # the black box keeps it for a postmortem bundle
+            get_flight_recorder().record("sched_stall", **row)
+        return wall
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._gc_t0 = time.monotonic()
+        elif self._gc_t0 is not None:
+            pause = time.monotonic() - self._gc_t0
+            self.gc_pause_s += pause
+            self.gc_max_pause_s = max(self.gc_max_pause_s, pause)
+            self.gc_collections[info["generation"]] += 1
+
+    def watch_gc(self) -> None:
+        """Time every garbage collection of the process from now on
+        (one ``gc.callbacks`` hook, until :meth:`close`).  A collection
+        on any thread holds the GIL, so the process-wide sum is what the
+        scheduler thread lost."""
+        gc.callbacks.append(self._on_gc)
+
+    def close(self) -> None:
+        with contextlib.suppress(ValueError):
+            gc.callbacks.remove(self._on_gc)
 
     def _cut(self) -> None:
         self.launched += 1
         self._last = self._next
-        self._next = dict.fromkeys(DISPATCH_PHASES, 0.0)
+        self._next = dict.fromkeys(_OWN, 0.0)
 
     def enter(self, phase: str) -> float:
         """Start ``phase`` now, ending the one in progress; returns the
         instant.  ``launch`` opens the next dispatch's own seconds, which
         :attr:`launched_phases` hands to its :meth:`commit`."""
-        now = time.monotonic()
+        now = self._stamp()
         self._close(now)
         if phase == "launch":
             self._cut()
         after = phase in _LAUNCHED_PHASES
         self._into = self._last if after else self._next
-        self._phase, self._t0 = phase, now
-        self._ann = self._annotate(
-            self._names[phase],
-            seq=self.launched if after else self.launched + 1)
+        self._phase, self._at = phase, now
+        self._seq = self.launched if after else self.launched + 1
+        self._ann = self._annotate(self._names[phase], seq=self._seq)
         self._ann.__enter__()
-        return now
+        return now[0]
 
     @property
     def launched_phases(self) -> dict:
@@ -361,54 +471,82 @@ class DispatchTrace:
         self.launched -= 1
         for p, v in self._last.items():
             self._next[p] += v
-        self._last = dict.fromkeys(DISPATCH_PHASES, 0.0)
+        self._last = dict.fromkeys(_OWN, 0.0)
         if self._phase in _LAUNCHED_PHASES:
             self._into = self._next
 
     def leave(self) -> float:
         """End the phase in progress; returns the instant."""
-        now = time.monotonic()
+        now = self._stamp()
         self._close(now)
-        return now
+        return now[0]
 
-    def _close(self, now: float) -> None:
+    def _close(self, now: tuple) -> None:
         if self._phase is None:
             return
-        dt = now - self._t0
+        if self._await is not None:      # the read ends with `wait`
+            at, ann = self._await
+            self._into["await"] += self._span("await", at, now, self._seq)
+            ann.__exit__(None, None, None)
+            self._await = None
+        dt = now[0] - self._at[0]
         self._into[self._phase] += dt
         self.phase_s[self._phase] += dt
+        if self._phase != "wait":
+            self._span(self._phase, self._at, now, self._seq)
         self._ann.__exit__(None, None, None)
         self._phase = self._ann = None
 
+    def awaiting(self, ready: bool) -> None:
+        """The host is about to block on an output of the dispatch in
+        flight (the cursor is in its ``wait``).  ``ready``: the output is
+        there already, so the device finished before the host came to
+        read and stood idle since (the record's ``late``).  The read,
+        span ``await``, ends where ``wait`` does: at the next
+        :meth:`enter`, the record's ``t_done``."""
+        self._into["late"] = late = int(ready)
+        self.late_reads += late
+        ann = self._annotate(self._names["await"], seq=self._seq)
+        ann.__enter__()
+        self._await = (self._stamp(), ann)
+
     @contextlib.contextmanager
     def idle(self):
-        """Around a wait with nothing to do: books ``idle_wait_s`` and
-        keeps the wait out of the phase it interrupts."""
+        """Around a wait with nothing to do: books ``idle_wait_s``,
+        keeps the wait out of the phase it interrupts and, from a
+        millisecond on, its two instants in :attr:`idles`: the device is
+        then idle because no request has reached the engine."""
         phase = self._phase
         t0 = self.leave()
         try:
             yield
         finally:
-            self.idle_wait_s += time.monotonic() - t0
+            t1 = time.monotonic()
+            self.idle_wait_s += t1 - t0
+            if t1 - t0 >= _IDLE_MIN_S:
+                self.idles.append((round(t0, 5), round(t1, 5)))
             if phase is not None:
                 self.enter(phase)
 
     @contextlib.contextmanager
-    def ahead(self):
-        """Around host work done while the device executes (the next
-        dispatch prepared, the last one drained): the cursor stays in
-        ``wait``, which still runs from the call's return to ``t_done``,
-        and the seconds are booked to ``phase_s["ahead"]``, so the six
-        phases keep tiling the iteration and ``phase_s`` without ``wait``
-        is still all the host did.  Yields a one-element list that holds
-        the seconds once the block has ended."""
+    def ahead(self, span: str = "ahead_plan"):
+        """Around host work done while the device executes: the next
+        dispatch prepared (span ``ahead_plan``) or the last one drained
+        (``ahead_drain``).  The cursor stays in ``wait``, which still
+        runs from the call's return to ``t_done``, and the seconds are
+        booked to ``phase_s["ahead"]``, so the six phases keep tiling
+        the iteration and ``phase_s`` without ``wait`` is still all the
+        host did.  Yields a one-element list that holds the seconds once
+        the block has ended."""
         spent = [0.0]
-        t0 = time.monotonic()
-        with self._annotate(self._names["ahead"], seq=self.launched + 1):
+        # the dispatch being prepared, or the one before the one launched
+        seq = self.launched + (1 if span == "ahead_plan" else -1)
+        t0 = self._stamp()
+        with self._annotate(self._names[span], seq=seq):
             try:
                 yield spent
             finally:
-                spent[0] = time.monotonic() - t0
+                spent[0] = self._span(span, t0, self._stamp(), seq)
                 self.phase_s["ahead"] += spent[0]
 
     def queue_wait(self, seconds: float) -> None:
@@ -444,6 +582,7 @@ class DispatchTrace:
             *(round(phases[p], 5) for p in DISPATCH_PHASES),
             int(with_finals), segments, finals, prefill_tokens,
             active_rows, steps, kv_tokens, round(ahead, 5),
+            int(phases["late"]), round(phases["await"], 5),
             *(extra[f] for f in self.extra_fields)))
         if segments:
             self.prefill += 1
@@ -462,9 +601,10 @@ class DispatchTrace:
 
     def snapshot(self) -> dict:
         """The ``/stats`` section.  ``recent`` is the ring as rows of
-        numbers in the order of ``fields``.  ``copy.copy`` of a deque is
-        atomic under the GIL; iterating it would race the scheduler's
-        appends."""
+        numbers in the order of ``fields``; ``idles`` rows of ``[t0,
+        t1]``; ``stalls`` rows by name (:data:`STALL_FIELDS`).
+        ``copy.copy`` of a deque is atomic under the GIL; iterating it
+        would race the scheduler's appends."""
         return {"seq": self.seq,
                 "phase_s": {p: round(v, 6)
                             for p, v in self.phase_s.items()},
@@ -479,6 +619,19 @@ class DispatchTrace:
                 "ahead_hits": self.ahead_hits,
                 "ahead_misses": dict(self.ahead_misses),
                 "ahead_first": self.ahead_first,
+                "late_reads": self.late_reads,
+                "spans": {name: {"n": n, "wall_s": round(wall, 6),
+                                 "cpu_s": round(cpu, 6),
+                                 "max_s": round(longest, 6)}
+                          for name, (n, wall, cpu, longest)
+                          in self.spans.items()},
+                "gc": {"pause_s": round(self.gc_pause_s, 6),
+                       "max_pause_s": round(self.gc_max_pause_s, 6),
+                       "collections": list(self.gc_collections)},
+                "stall_s": round(self.stall_s, 6),
+                "stall_count": self.stall_count,
+                "stalls": list(copy.copy(self.stalls)),
+                "idles": [list(r) for r in copy.copy(self.idles)],
                 "fields": list(DISPATCH_FIELDS + self.extra_fields),
                 "recent": [list(r) for r in copy.copy(self.recent)]}
 

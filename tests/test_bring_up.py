@@ -298,6 +298,8 @@ def test_engine_reports_gather_on_cpu_and_health_follows_scheduler():
                                             len(jax.devices()))
         assert [d["id"] for d in health["devices"]] == \
             [d.id for d in jax.devices()]
+        # the cores the replica shares with whatever serves beside it
+        assert health["host_cpus"] == len(os.sched_getaffinity(0)) >= 1
 
         # a pure-decode dispatch failure kills the scheduler thread: it
         # drains every request with the error — and /health says so
@@ -620,7 +622,7 @@ def test_flash_kernel_with_alibi_gets_through_mosaic(v5e):
 @pytest.mark.parametrize("rows", [256, 4096])
 @pytest.mark.parametrize("quant", ["int8", "bf16"])
 def test_grouped_matmul_gets_through_mosaic(v5e, rows, quant):
-    """The experts' grouped matmul at ``olmoe-1b-7b-int8.chat``'s two
+    """The experts' grouped matmul at the olmoe configuration's two
     shapes (256 token-expert rows: a decode step at 32 slots; 4,096: the
     512-token slab), both projections, the layer picked out of the whole
     16-layer stack by index.  The compiler's temporaries stay under a

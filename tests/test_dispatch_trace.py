@@ -22,9 +22,16 @@ order only; a time from this file is a time of XLA's CPU backend):
   predecessor's execution (a hit), as packed in the gap for a named
   reason (a miss), or as the first after an idle wait; a hit's ``ahead``
   seconds lie under the previous execution, and a dispatch launched
-  before its predecessor is committed keeps its own seconds.
+  before its predecessor is committed keeps its own seconds;
+- what the host was doing while the device sat idle: a record says
+  whether its read came late (``late``, ``await``), an idle engine's
+  wait is an interval on the clock (``idles``), every kind of host work
+  has wall and CPU seconds (``spans``), garbage collections are timed
+  (``gc``), and a span of ``STALL_S`` leaves a row that names where its
+  seconds went (``stalls``).
 """
 
+import gc
 import json
 import sys
 import threading
@@ -45,8 +52,12 @@ from distributed_inference_demo_tpu.runtime.batching import (
     ContinuousBatchingEngine)
 from distributed_inference_demo_tpu.telemetry.slo import get_slo_ledger
 from distributed_inference_demo_tpu.telemetry import tracing
+from distributed_inference_demo_tpu.telemetry.flightrecorder import (
+    get_flight_recorder)
 from distributed_inference_demo_tpu.telemetry.tracing import (
-    AHEAD_MISS_REASONS, DISPATCH_FIELDS, DISPATCH_PHASES, DispatchTrace)
+    AHEAD_MISS_REASONS, DISPATCH_FIELDS, DISPATCH_PHASES, DISPATCH_SPANS,
+    LOOP_DISPATCH_FIELDS, MOE_DISPATCH_FIELDS, STALL_CAUSES, STALL_FIELDS,
+    STALL_S, DispatchTrace)
 
 # bf16 weights and pages, and the int8-weight family the chip cells serve
 MODELS = ("llama-test", "qwen2-test-int8")
@@ -66,6 +77,15 @@ def engine(model="llama-test", **kw):
     kw.setdefault("mixed_token_budget", 24)
     return ContinuousBatchingEngine(cfg, load_or_init(model, cfg, seed=0),
                                     **kw)
+
+
+def ticking(start: float, tick: float) -> types.SimpleNamespace:
+    """A ``time`` whose monotonic clock advances one ``tick`` a read and
+    whose process clock stands still."""
+    ticks = iter(range(10 ** 6))
+    return types.SimpleNamespace(
+        monotonic=lambda: start + tick * next(ticks),
+        process_time=lambda: 0.0)
 
 
 def settled_stats(eng) -> dict:
@@ -177,15 +197,14 @@ def test_an_idle_engines_wait_is_no_phase(scripted):
 def test_ring_is_bounded_and_stats_stay_small_json(monkeypatch):
     # values as wide as a long-lived chip replica's: two weeks of uptime,
     # every phase with all its digits, a full four-chip batch
-    ticks = iter(range(10 ** 6))
-    monkeypatch.setattr(tracing, "time", types.SimpleNamespace(
-        monotonic=lambda: 1234567.123456 + 0.00123457 * next(ticks)))
+    monkeypatch.setattr(tracing, "time",
+                        ticking(1234567.123456, 0.00123457))
     tr = DispatchTrace()
     tr.seq = 99_000
     for _ in range(300):
         for phase in DISPATCH_PHASES[:-1]:
             tr.enter(phase)
-        tr.commit(t_launch=tr.enter("drain") - 0.3, t_done=tr._t0,
+        tr.commit(t_launch=tr.enter("drain") - 0.3, t_done=tr._at[0],
                   with_finals=True, segments=3, finals=3,
                   prefill_tokens=768, active_rows=64, steps=4,
                   kv_tokens=262144)
@@ -194,7 +213,7 @@ def test_ring_is_bounded_and_stats_stay_small_json(monkeypatch):
     assert snap["seq"] == 99_300 and len(snap["recent"]) == 128
     assert [r[0] for r in snap["recent"]] == list(range(99_173, 99_301))
     assert all(r[3:9] == [0.00123] * 6 for r in snap["recent"])
-    assert 14 * 1024 < len(json.dumps(snap["recent"])) < 16 * 1024
+    assert 16 * 1024 < len(json.dumps(snap["recent"])) < 18 * 1024
     assert snap["kv_token_steps"] == 300 * 262144 * 4
     with engine() as eng:
         eng.submit(SHORT, 3).wait(timeout=300)
@@ -356,9 +375,7 @@ def test_a_dispatch_launched_before_its_predecessor_commits(monkeypatch):
     and a dispatch committed after its successor was launched (it was
     drained under the successor's execution) still gets its own; work
     under an execution is booked to ``ahead`` and is no tile."""
-    ticks = iter(range(10 ** 6))
-    monkeypatch.setattr(tracing, "time", types.SimpleNamespace(
-        monotonic=lambda: 100.0 + 0.001 * next(ticks)))
+    monkeypatch.setattr(tracing, "time", ticking(100.0, 0.001))
     tr = DispatchTrace()
     fields = dict(with_finals=False, segments=0, finals=0,
                   prefill_tokens=0, active_rows=1, steps=4, kv_tokens=8)
@@ -406,6 +423,190 @@ def test_a_dispatch_launched_before_its_predecessor_commits(monkeypatch):
               **fields)
     assert tr.seq == tr.launched == 1
     assert tr.snapshot()["ahead_misses"]["other"] == 1
+
+
+def test_the_blocking_read_is_the_end_of_wait(scripted):
+    """``await`` runs from the end of the plan to ``t_done``, inside
+    ``wait``; ``late`` is 0 or 1 and ``late_reads`` their sum; the two
+    parts of the work under an execution add up to the seventh key of
+    ``phase_s``, which keeps its seven keys."""
+    dt = scripted[0]["dispatch_trace"]
+    recs = rows(scripted[0])
+    assert DISPATCH_FIELDS[-3:] == ("ahead", "late", "await")
+    for r in recs:
+        assert 0 < r["await"] <= r["wait"] + ROUNDING
+        assert r["late"] in (0, 1)
+    assert dt["late_reads"] == sum(r["late"] for r in recs)
+    assert list(dt["phase_s"]) == list(DISPATCH_PHASES) + ["ahead"]
+    spans = dt["spans"]
+    assert tuple(spans) == DISPATCH_SPANS and "wait" not in spans
+    assert (spans["ahead_plan"]["wall_s"] + spans["ahead_drain"]["wall_s"]
+            == pytest.approx(dt["phase_s"]["ahead"], abs=2e-6))
+    for name, sp in spans.items():
+        assert set(sp) == {"n", "wall_s", "cpu_s", "max_s"}
+        assert 0 <= sp["max_s"] <= sp["wall_s"]
+        if name != "await":     # a phase's seconds are its spans'
+            assert sp["wall_s"] == pytest.approx(
+                dt["phase_s"].get(name, sp["wall_s"]), abs=2e-6)
+    # every dispatch was read once and planned under once; the three
+    # hits were drained under their successors
+    assert spans["await"]["n"] == spans["ahead_plan"]["n"] == dt["seq"]
+    assert spans["ahead_drain"]["n"] == dt["ahead_hits"]
+    assert spans["await"]["wall_s"] == pytest.approx(
+        sum(r["await"] for r in recs), abs=len(recs) * 1e-5)
+    assert dt["stall_count"] == len(dt["stalls"])
+
+
+def _plan_with(eng, n, act):
+    """Run ``act`` inside the pack of dispatch ``n``: for every dispatch
+    of one request but the first, inside the plan made under its
+    predecessor's execution (``_plan_ahead``)."""
+    inner, calls = eng._pack_mixed, [0]
+
+    def hooked(*a):
+        calls[0] += 1
+        if calls[0] == n:
+            act()
+        return inner(*a)
+
+    eng._pack_mixed = hooked
+
+
+def _spin(seconds):
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+@pytest.mark.parametrize("how, cause", [
+    (lambda: time.sleep(0.2), "off_cpu"), (lambda: _spin(0.2), "own_cpu")],
+    ids=["sleep", "busy_loop"])
+def test_a_span_that_stands_still_leaves_one_stall_row(how, cause):
+    """0.2 s inside the plan of the third dispatch: one row in the ring,
+    the span and where its seconds went, the same row in the flight
+    recorder; and the dispatch that executed meanwhile was read late."""
+    get_flight_recorder().clear()
+    with engine() as eng:
+        _plan_with(eng, 3, how)
+        eng.submit(LONG, 14).wait(timeout=300)
+        st = settled_stats(eng)
+    dt = st["dispatch_trace"]
+    stalls = [r for r in dt["stalls"] if r["span"] == "ahead_plan"]
+    assert len(stalls) == 1, dt["stalls"]
+    row = stalls[0]
+    assert tuple(row) == STALL_FIELDS and row["cause"] == cause
+    assert row["seq"] == 3 and 0.2 <= row["wall"] < 2.0
+    assert (row["cpu"] < 0.05) if cause == "off_cpu" else (row["cpu"] >= 0.1)
+    assert row["proc_cpu"] >= row["cpu"] - 1e-4 and row["nivcsw"] >= 0
+    assert dt["stall_count"] == len(dt["stalls"])
+    assert dt["stall_s"] == pytest.approx(
+        sum(r["wall"] for r in dt["stalls"]), abs=1e-4)
+    assert dt["spans"]["ahead_plan"]["max_s"] == pytest.approx(row["wall"],
+                                                               abs=1e-4)
+    # the plan was the third dispatch's, made under the second's
+    # execution: a toy execution is long over after 0.2 s
+    recs = rows(st)
+    assert recs[1]["late"] == 1 and recs[1]["await"] < 0.1
+    assert dt["late_reads"] == sum(r["late"] for r in recs) >= 1
+    events = [e for e in get_flight_recorder().snapshot()
+              if e["kind"] == "sched_stall" and e["span"] == "ahead_plan"]
+    assert [{k: e[k] for k in STALL_FIELDS} for e in events] == [row]
+
+
+@pytest.mark.parametrize("cause", STALL_CAUSES)
+def test_a_stall_names_where_its_seconds_went(monkeypatch, cause):
+    """The rule, in its order, on a clock held by hand: 0.1 s in
+    ``pack``, of which the collector, the thread, another thread or
+    nobody had 60 ms."""
+    clock = dict(monotonic=10.0, thread_time=1.0, process_time=5.0)
+    monkeypatch.setattr(tracing, "time", types.SimpleNamespace(
+        monotonic=lambda: clock["monotonic"],
+        process_time=lambda: clock["process_time"]))
+    # the thread's CPU seconds come with its context switches
+    monkeypatch.setattr(tracing, "resource", types.SimpleNamespace(
+        RUSAGE_THREAD=None, getrusage=lambda who: types.SimpleNamespace(
+            ru_utime=clock["thread_time"], ru_stime=0.0, ru_nivcsw=0)))
+    tr = DispatchTrace()
+    tr.enter("pack")
+    clock["monotonic"] += 0.1
+    if cause == "gc":
+        tr.gc_pause_s += 0.06
+        clock["thread_time"] += 0.06      # the collector ran on it
+        clock["process_time"] += 0.06
+    elif cause == "own_cpu":
+        clock["thread_time"] += 0.06
+        clock["process_time"] += 0.07
+    elif cause == "other_threads":
+        clock["thread_time"] += 0.01
+        clock["process_time"] += 0.07
+    tr.enter("launch")
+    clock["monotonic"] += STALL_S - 0.001  # a long span, and no stall
+    tr.enter("wait")
+    clock["monotonic"] += 1.0              # nor is the device's time
+    tr.awaiting(False)
+    clock["monotonic"] += 1.0              # nor the read
+    tr.leave()
+    snap = tr.snapshot()
+    assert [(r["span"], r["seq"], r["t0"], r["wall"], r["cause"])
+            for r in snap["stalls"]] == [("pack", 1, 10.0, 0.1, cause)]
+    assert (snap["stall_count"], snap["stall_s"]) == (1, 0.1)
+    assert snap["spans"]["await"] == {"n": 1, "wall_s": 1.0, "cpu_s": 0.0,
+                                      "max_s": 1.0}
+
+
+def test_garbage_collections_are_timed_until_the_engine_closes():
+    """One hook in ``gc.callbacks`` from the engine's start to its
+    close; a full collection over a large graph of cycles shows in the
+    oldest generation's count and in the pause."""
+    before = list(gc.callbacks)
+    with engine() as eng:
+        hooks = [h for h in gc.callbacks if h not in before]
+        assert hooks == [eng.dispatch_trace._on_gc]
+        a = eng.stats()["dispatch_trace"]["gc"]
+        junk = []
+        for _ in range(100_000):
+            x, y = [], []
+            x.append(y), y.append(x)
+            junk.append(x)
+        del junk, x, y
+        gc.collect()
+        b = eng.stats()["dispatch_trace"]["gc"]
+    assert list(gc.callbacks) == before
+    assert set(b) == {"pause_s", "max_pause_s", "collections"}
+    assert b["collections"][2] > a["collections"][2]
+    assert b["pause_s"] > a["pause_s"] and b["max_pause_s"] > 0
+    assert b["max_pause_s"] <= b["pause_s"]
+
+
+def test_an_idle_engines_wait_is_an_interval_on_the_clock():
+    """The engine blocks in ``queue.get`` from its start to the first
+    submit: one row of ``idles``, which brackets the submit."""
+    with engine() as eng:
+        time.sleep(0.05)
+        t_submit = time.monotonic()
+        eng.submit(SHORT, 3).wait(timeout=300)
+        dt = settled_stats(eng)["dispatch_trace"]
+    assert len(dt["idles"]) == 1
+    t0, t1 = dt["idles"][0]
+    assert t0 <= t_submit - 0.04 and t_submit <= t1 + 1e-5
+    assert dt["idle_wait_s"] == pytest.approx(t1 - t0, abs=2e-5)
+    # the first dispatch was launched after the wait ended
+    assert rows({"dispatch_trace": dt})[0]["t_launch"] >= t1 - 1e-5
+
+
+@pytest.mark.parametrize("model, extra", [
+    ("llama-test", ()), ("ouro-test", LOOP_DISPATCH_FIELDS),
+    ("olmoe-test-int8", MOE_DISPATCH_FIELDS)])
+def test_a_models_own_columns_follow_the_base_columns(model, extra):
+    with engine(model) as eng:
+        eng.submit(LONG, 6).wait(timeout=300)
+        st = settled_stats(eng)
+    dt = st["dispatch_trace"]
+    assert tuple(dt["fields"]) == DISPATCH_FIELDS + extra
+    assert all(len(r) == len(dt["fields"]) for r in dt["recent"])
+    for r in rows(st):
+        assert r["late"] in (0, 1) and 0 < r["await"] <= r["wait"] + ROUNDING
+        assert all(isinstance(r[f], int) and r[f] > 0 for f in extra)
 
 
 def _capture(logdir, out):
@@ -459,7 +660,9 @@ def test_a_capture_holds_the_sched_rows_on_a_host_plane(capture):
     names = {n for _, n, _ in events}
     assert {f"sched.{p}" for p in DISPATCH_PHASES} <= names
     assert "mixed_step" in names
-    assert "sched.ahead" in names
+    # the work under an execution in its two parts, and the blocking read
+    assert {"sched.ahead_plan", "sched.ahead_drain", "sched.await"} <= names
+    assert "sched.ahead" not in names
     # the request's three dispatches and the one after, each under its
     # number
     packs = sorted(s["seq"] for _, n, s in events if n == "sched.pack")

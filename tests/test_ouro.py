@@ -483,8 +483,10 @@ def test_one_pass_record_and_stats_are_the_parent_s(model):
         eng.submit(np.arange(1, 20, dtype=np.int32), 4).wait(timeout=300)
         st = eng.stats()
     assert "loop" not in st
-    # the parent's columns, and the one every record got since (PR 35:
-    # `ahead`, after `kv_tokens` and before a model's own columns)
+    # the parent's columns, and those every record got since (PR 35:
+    # `ahead`, PR 41: `late` and `await`), after `kv_tokens` and before
+    # a model's own columns
     fields = list(PARENT["fields"][model])
-    fields.insert(fields.index("kv_tokens") + 1, "ahead")
+    at = fields.index("kv_tokens") + 1
+    fields[at:at] = ["ahead", "late", "await"]
     assert st["dispatch_trace"]["fields"] == fields
