@@ -479,6 +479,15 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     if cfg.qk_norm:
         q = _whole_row_rms_norm(q, lp["q_norm_w"], cfg.norm_eps, tp_axis)
         k = _whole_row_rms_norm(k, lp["k_norm_w"], cfg.norm_eps, tp_axis)
+    # The head reshape may not reach the dot: XLA's simplifier would merge
+    # the two into a convolution windowed over heads (``window={size=<heads>}``
+    # under ``bsh,hd->bsd/dot_general``) that reads the weight as [heads, hd,
+    # H], the stored matrix transposed.  Every execution then copies the whole
+    # wq / wk / wv stacks, and each layer call writes its matrix (dequantized)
+    # out before reading it again.  Behind the barrier each projection is one
+    # fusion (slice of the stack, dequant, matmul, bias) like the MLP's, for a
+    # round trip of q, k and v through memory (docs/DESIGN.md section 1).
+    q, k, v = jax.lax.optimization_barrier((q, k, v))
     q = q.reshape(b, s, nh, hd)
     k = k.reshape(b, s, nkv, hd)
     v = v.reshape(b, s, nkv, hd)

@@ -604,6 +604,43 @@ def test_each_variant_of_mixed_step_leaves_the_pool_in_place(
             3 if "olmoe" in model else 0), calls
 
 
+@pytest.mark.parametrize("slab", [2, 0], ids=["slab", "nothing-packed"])
+@pytest.mark.parametrize("model,blocks,slots,budget,max_seq", [
+    ("qwen2.5-7b-int8", 416, 32, 640, 4096),
+    ("bloom7b1-int8", 46, 8, 544, 2048),
+    ("ouro-2.6b", 44, 8, 544, 2048)],      # served at its own dtype, bf16
+    ids=["qwen2.5-7b-int8", "bloom7b1-int8", "ouro-2.6b-bf16"])
+def test_qkv_projections_read_their_weights_where_they_lie(
+        v5e, model, blocks, slots, budget, max_seq, slab):
+    """``mixed_step`` at three cells' flags (PERF.md section 4), compiled
+    whole for the chip.  The q, k and v projections are plain matmuls over
+    the stored ``[H, D]`` matrices: no instruction makes anything of the
+    shape and dtype of a leaf of ``params.layers`` (a ``copy`` of a
+    whole wq / wk / wv stack into another layout, once an execution), and
+    the head reshape has not been folded into the dot (a convolution
+    windowed over heads, which reads ``[heads, hd, H]`` and so has each
+    layer's matrix written out first).  A window of 1 is the slab's
+    segment axis, a plain matmul.  The decode-only program holds no
+    temporary of a stack's size."""
+    sys.path.insert(0, str(REPO / "tools"))
+    from aot_mixed_step import compile_mixed_step, copied_weight_leaves
+    flags = argparse.Namespace(
+        batch_slots=slots, prefill_chunk=256, decode_block=4,
+        mixed_token_budget=budget, max_seq=max_seq, kv_block_tokens=128)
+    compiled, eng = compile_mixed_step(model, blocks, flags, slab)
+    hlo = compiled.as_text()
+    assert copied_weight_leaves(hlo, eng.params.layers) == []
+    dots = [line for line in hlo.splitlines() if " convolution(" in line
+            and 'bsh,hd->bsd/dot_general"' in line]
+    assert len(dots) >= 3           # the scan still finds the projections
+    windows = [int(n) for line in dots
+               for size in re.findall(r"window=\{size=([\dx]+)", line)
+               for n in size.split("x")]
+    assert all(n == 1 for n in windows), windows
+    if not slab:
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 def test_flash_kernel_with_alibi_gets_through_mosaic(v5e):
     """MHA + ALiBi at a 256-token chunk: Mosaic refused the in-kernel
     ``jnp.tile`` of the slope vector ("Input offsets outside of the

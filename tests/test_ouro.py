@@ -466,8 +466,11 @@ def test_one_pass_mixed_step_lowers_to_the_parent_s_program(model, slab):
     model's is PR 33's (dd8ee66), so the dense cells run what they ran
     where they pack a full slab or none; a model with experts' is PR
     39's, which told it the rows that hold a token (the fixture's
-    ``since_pr39``).  The text is this JAX's; under another version the
-    kept hashes say nothing."""
+    ``since_pr39``).  Since PR 42 every layer body holds one
+    ``optimization_barrier`` before the head reshapes, so all six were
+    made again from that tree, each text the parent's line for line but
+    for the barrier (the fixture's ``since_pr42``).  The text is this
+    JAX's; under another version the kept hashes say nothing."""
     if jax.__version__ != PARENT["jax"]:
         pytest.skip(f"hashes were made under jax {PARENT['jax']}")
     with _parent_engine(model) as eng:

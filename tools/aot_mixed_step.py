@@ -35,6 +35,17 @@ take: ``ops.paged_attention.route_pool``), and a ``copy`` of the pool's
 shape (the compiler giving a consumer another layout; it shows as
 temporaries of about the pool's size).  Ops as large that are not the
 pool's (a weight matrix widened, logits) are listed too, by shape.
+
+"weight leaves copied" names every instruction whose result has the shape
+and dtype of a leaf of ``params.layers``, the stack's ``L`` included.
+A copied stack means a consumer wants the weights in another layout than
+they are stored in: the whole stack is rewritten every execution (its size
+in temporaries, its bytes twice through HBM) and, as a rule, each layer
+call then writes its own matrix out before the matmul reads it.  That is
+what a head reshape folded into the q / k / v dot did until PR 42 (a
+convolution with ``window={size=<heads>}``).  ``on chip`` marks a result in
+the compiler's fast memory (``S(1)`` in its layout): a prefetch of a small
+stack, no HBM temporary and no other layout.  A sound program lists none.
 """
 
 from __future__ import annotations
@@ -123,30 +134,51 @@ _ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "s4": 1, "u4": 1, "bf16": 2,
 _NO_BUFFER = ("parameter", "get-tuple-element", "bitcast", "tuple",
               "while", "conditional", "call")
 _INSTRUCTION = re.compile(
-    r"^\s*(?:ROOT )?%?([\w.-]+) = (\w+)\[([\d,]*)\][^ ]* ([\w-]+)\(")
+    r"^\s*(?:ROOT )?%?([\w.-]+) = (\w+)\[([\d,]*)\]([^ ]*) ([\w-]+)\(")
 
 
-def large_ops(hlo_text: str, min_bytes: int) -> list:
-    """``[(name, opcode, shape, bytes)]`` of the instructions of an
-    optimized HLO module whose array result is at least ``min_bytes``,
-    outside fused computations (what a fusion computes inside makes no
-    buffer), largest first."""
+def _buffers(hlo_text: str):
+    """``(name, opcode, shape, bytes, layout)`` of every instruction of an
+    optimized HLO module that makes an array, outside fused computations
+    (what a fusion computes inside makes no buffer)."""
     fused = set(re.findall(r"calls=%?([\w.-]+)", hlo_text))
-    out, skip = [], False
+    skip = False
     for line in hlo_text.splitlines():
         head = re.match(r"^(?:ENTRY )?%?([\w.-]+) \(.*\{\s*$", line)
         if head:
             skip = head.group(1) in fused
             continue
         m = None if skip else _INSTRUCTION.match(line)
-        if not m or m.group(4) in _NO_BUFFER or m.group(2) not in _ITEMSIZE:
+        if not m or m.group(5) in _NO_BUFFER or m.group(2) not in _ITEMSIZE:
             continue
-        name, dtype, dims, opcode = m.groups()
+        name, dtype, dims, layout, opcode = m.groups()
         shape = [int(d) for d in dims.split(",") if d]
-        size = _ITEMSIZE[dtype] * math.prod(shape)
-        if size >= min_bytes:
-            out.append((name, opcode, f"{dtype}{shape}", size))
-    return sorted(out, key=lambda r: -r[3])
+        yield (name, opcode, f"{dtype}{shape}",
+               _ITEMSIZE[dtype] * math.prod(shape), layout)
+
+
+def large_ops(hlo_text: str, min_bytes: int) -> list:
+    """``[(name, opcode, shape, bytes)]`` of the instructions of an
+    optimized HLO module whose array result is at least ``min_bytes``,
+    outside fused computations, largest first."""
+    return sorted((b[:4] for b in _buffers(hlo_text) if b[3] >= min_bytes),
+                  key=lambda r: -r[3])
+
+
+_HLO_DTYPE = {"int8": "s8", "uint8": "u8", "bfloat16": "bf16",
+              "float16": "f16", "float32": "f32", "int32": "s32"}
+
+
+def copied_weight_leaves(hlo_text: str, layers) -> list:
+    """``[(name, opcode, shape, bytes, on_chip)]`` of the instructions
+    whose result has the shape and dtype of a leaf of ``layers`` (the
+    stacked ``params.layers``), largest first; ``on_chip`` where the
+    result lies in the compiler's fast memory and not in HBM."""
+    import jax
+    stacks = {f"{_HLO_DTYPE.get(a.dtype.name, a.dtype.name)}{list(a.shape)}"
+              for a in jax.tree.leaves(layers)}
+    return sorted((b[:4] + ("S(1)" in b[4],) for b in _buffers(hlo_text)
+                   if b[2] in stacks), key=lambda r: -r[3])
 
 
 def main(argv=None) -> int:
@@ -198,6 +230,11 @@ def main(argv=None) -> int:
         for name, opcode, shape, size in large_ops(hlo, plane):
             print(f"    {name}  {opcode}  {shape}  "
                   f"{size / (1 << 20):.1f} MiB", flush=True)
+        copied = [f"{name} {shape} {size / (1 << 20):.1f} MiB"
+                  + " (on chip)" * on_chip for name, _, shape, size, on_chip
+                  in copied_weight_leaves(hlo, eng.params.layers)]
+        print(f"  weight leaves copied: {'; '.join(copied) or 'none'}",
+              flush=True)
     return 0
 
 
