@@ -314,13 +314,15 @@ def cmd_serve(args) -> int:
         import jax
 
         from .comm.transport import ZmqTransport
-        from .models.base import require_single_pass, split_layer_ranges
+        from .models.base import (require_kv_pair, require_single_pass,
+                                  split_layer_ranges)
         from .models.registry import get_model_config
         from .runtime.elastic import ElasticHeader, ElasticStageRuntime
 
         cfg = get_model_config(args.model)
         try:
             require_single_pass(cfg, "--chain (a pipeline of stages)")
+            require_kv_pair(cfg, "--chain (a pipeline of stages)")
         except ValueError as e:
             print(e, file=sys.stderr)
             return 1

@@ -38,7 +38,11 @@ import jax.numpy as jnp
                       "experts_per_token", "moe_capacity_factor",
                       "quantization", "head_dim_override", "embed_scale",
                       "mlp_act", "qk_norm", "norm_topk_prob", "ut_steps",
-                      "sandwich_norm"])
+                      "sandwich_norm", "kv_lora_rank", "qk_nope_head_dim",
+                      "qk_rope_head_dim", "v_head_dim", "lead_dense_layers",
+                      "lead_intermediate_size", "num_shared_experts",
+                      "router_scoring", "router_bias",
+                      "routed_scaling_factor"])
 @dataclass(frozen=True)
 class ModelConfig:
     """Static, hashable architecture description shared by all model families.
@@ -99,17 +103,72 @@ class ModelConfig:
     # ouro: RMSNorm on each sublayer's OUTPUT too, before the residual
     # add (four norms a block)
     sandwich_norm: bool = False
+    # deepseek_v3 (multi-head latent attention): a token's cache is the
+    # normed latent ``c`` (``kv_lora_rank``) and one roped key part
+    # ``k_pe`` (``qk_rope_head_dim``) that every head shares; a head's
+    # query is [no-rope ``qk_nope_head_dim`` | rope], its value
+    # ``v_head_dim`` wide, rope pairs interleaved (2i, 2i+1), softmax
+    # scale (nope + rope) ** -0.5.  0 = full keys and values
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # deepseek_v3: ``lead_dense_layers`` blocks with a dense SwiGLU of
+    # width ``lead_intermediate_size`` run once BEFORE the repeated
+    # stack; ``num_layers`` counts the repeated stack alone
+    lead_dense_layers: int = 0
+    lead_intermediate_size: int = 0
+    # shared experts: one dense SwiGLU of width ``num_shared_experts x
+    # intermediate_size`` on every row, added to the routed sum
+    num_shared_experts: int = 0
+    # the router's scores: "softmax" over all experts, or "sigmoid" of
+    # each logit (deepseek_v3 ``noaux_tc``: with ``router_bias`` the k
+    # experts are CHOSEN by score + a stored per-expert bias and WEIGHED
+    # by the score alone); the k weights, renormalised iff
+    # ``norm_topk_prob``, times ``routed_scaling_factor``
+    router_scoring: str = "softmax"
+    router_bias: bool = False
+    routed_scaling_factor: float = 1.0
 
     @property
     def dtype(self) -> jnp.dtype:
         return jnp.dtype(self.dtype_name)
 
     @property
+    def total_layers(self) -> int:
+        """Every block a token passes: the leading dense ones and the
+        repeated stack."""
+        return self.lead_dense_layers + self.num_layers
+
+    @property
     def kv_planes(self) -> int:
-        """K/V planes a token holds: one a layer a pass.  The one source
-        of every KV structure's plane count (dense cache, page pool,
-        host tier, exported blocks)."""
-        return self.num_layers * self.ut_steps
+        """K/V planes a token holds: one a layer a pass (the leading
+        dense blocks first).  The one source of every KV structure's
+        plane count (dense cache, page pool, host tier, exported
+        blocks)."""
+        return self.total_layers * self.ut_steps
+
+    @property
+    def latent_kv(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def kv_streams(self) -> int:
+        """Tensors a token holds in each plane: keys and values, or the
+        one latent row."""
+        return 1 if self.latent_kv else 2
+
+    @property
+    def kv_page_shape(self) -> tuple:
+        """``(heads, width)`` of one token's entry in a plane of the page
+        pool: every kv head's ``head_dim``, or the one latent row
+        ``[c | k_pe]`` padded to whole lanes
+        (``ops.latent_attention.latent_page_width``)."""
+        if self.latent_kv:
+            from ..ops.latent_attention import latent_page_width
+            return 1, latent_page_width(self.kv_lora_rank,
+                                        self.qk_rope_head_dim)
+        return self.num_kv_heads, self.head_dim
 
     @property
     def head_dim(self) -> int:
@@ -179,12 +238,17 @@ class KVCache:
         dtype = dtype or cfg.dtype
         # ``num_layers`` is the stage's layer count; a looped model
         # (one stage only) holds each of them ``ut_steps`` times:
-        # cfg.kv_planes for the whole model
-        shape = (num_layers * cfg.ut_steps, batch, cfg.num_kv_heads,
-                 max_seq, cfg.head_dim)
+        # cfg.kv_planes for the whole model (a model with leading dense
+        # blocks runs on one stage too, and they hold the first planes).
+        # A latent-attention model's cache is ``keys`` alone, one row a
+        # token; ``values`` holds no element
+        heads, width = cfg.kv_page_shape
+        shape = ((num_layers + cfg.lead_dense_layers) * cfg.ut_steps,
+                 batch, heads, max_seq)
         return KVCache(
-            keys=jnp.zeros(shape, dtype),
-            values=jnp.zeros(shape, dtype),
+            keys=jnp.zeros(shape + (width,), dtype),
+            values=jnp.zeros(
+                shape + (0 if cfg.latent_kv else width,), dtype),
             length=jnp.zeros((), jnp.int32),
         )
 
@@ -209,7 +273,7 @@ def pad_cache_capacity(n: int) -> int:
 
 
 @partial(jax.tree_util.register_dataclass,
-         data_fields=["layers", "embed", "final_norm", "lm_head"],
+         data_fields=["layers", "embed", "final_norm", "lm_head", "lead"],
          meta_fields=[])
 @dataclass
 class StageParams:
@@ -224,10 +288,28 @@ class StageParams:
     embed: Optional[dict] = None
     final_norm: Optional[dict] = None
     lm_head: Optional[dict] = None
+    # the leading dense blocks' leaves (``ModelConfig.lead_dense_layers``),
+    # stacked like ``layers``; an empty tree for every other model
+    lead: Optional[dict] = None
 
     def nbytes(self) -> int:
         return sum(x.nbytes for x in jax.tree.leaves(
-            (self.layers, self.embed, self.final_norm, self.lm_head)))
+            (self.layers, self.embed, self.final_norm, self.lm_head,
+             self.lead)))
+
+
+def require_kv_pair(cfg: ModelConfig, what: str) -> None:
+    """Refuse a latent-attention model (``kv_lora_rank > 0``) where
+    ``what`` is built for a pair of key and value tensors of one head
+    size: called where such a thing is built, so the model is refused in
+    a sentence and never run wrongly."""
+    if cfg.latent_kv:
+        raise ValueError(
+            f"{what} does not support a latent-attention model (family "
+            f"{cfg.family!r}, kv_lora_rank={cfg.kv_lora_rank}): a token's "
+            f"cache is one latent row a layer, and it is built for keys "
+            f"and values of one head size. Serve it on one chip with bf16 "
+            f"pages (serve --batch-slots)")
 
 
 def require_single_pass(cfg: ModelConfig, what: str) -> None:
@@ -254,6 +336,7 @@ def slice_stage(full: StageParams, cfg: ModelConfig, spec: StageSpec) -> StagePa
     """
     if spec.num_stages > 1:
         require_single_pass(cfg, "a pipeline of stages")
+        require_kv_pair(cfg, "a pipeline of stages")
     layers = jax.tree.map(lambda x: x[spec.layer_start:spec.layer_end], full.layers)
     # Tied embeddings: the last stage needs the token table for the LM head.
     needs_embed = spec.is_first or (spec.is_last and cfg.tie_embeddings)
@@ -262,6 +345,7 @@ def slice_stage(full: StageParams, cfg: ModelConfig, spec: StageSpec) -> StagePa
         embed=full.embed if needs_embed else None,
         final_norm=full.final_norm if spec.is_last else None,
         lm_head=full.lm_head if spec.is_last else None,
+        lead=full.lead if spec.is_first else None,
     )
 
 

@@ -13,6 +13,11 @@ inferred at SURVEY.md §2.2), a single pure ``stage_forward`` covers:
   output (``sandwich_norm``), the whole stack run ``ut_steps`` times a
   token with the final norm after every pass and K/V planes of each pass's
   own.
+- **deepseek_v3 family** (kanana-2-30b-a3b): multi-head latent attention
+  (a token's cache is one latent row a layer, read in absorbed form:
+  ``ops.latent_attention``), leading dense blocks before the repeated
+  expert blocks, a sigmoid router with a selection bias and a scale, and
+  shared experts beside the routed ones.
 
 The per-stage forward is a single ``lax.scan`` over stacked layer weights —
 XLA compiles one loop body reused across layers, keeping compile time flat in
@@ -31,7 +36,8 @@ from ..ops.grouped_matmul import grouped_matmul
 from ..ops.quant import dense
 from ..ops.stacked import LayerOf
 from ..ops.norms import layer_norm, rms_norm
-from ..ops.rope import apply_rope
+from ..ops.latent_attention import latent_dense_attn
+from ..ops.rope import apply_rope, apply_rope_interleaved
 from .base import (KVCache, ModelConfig, StageParams, StageSpec,
                    require_single_pass)
 
@@ -113,14 +119,33 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
     big = (partial(_init_quantized, mode=mode) if mode else _dense_init)
 
     keys = jax.random.split(rng, 16)
-    p = {
-        "attn_norm_w": jnp.ones((L, H), dt),
-        "wq": big(keys[0], (L, H, nh * hd), dt),
-        "wk": big(keys[1], (L, H, nkv * hd), dt),
-        "wv": big(keys[2], (L, H, nkv * hd), dt),
-        "wo": big(keys[3], (L, nh * hd, H), dt),
-        "mlp_norm_w": jnp.ones((L, H), dt),
-    }
+    if cfg.latent_kv:
+        # deepseek_v3: q in one matrix (q_lora_rank null), the latent and
+        # the shared rope key from ``wkv_a``, and ``kv_b`` kept as its two
+        # halves a head, laid out for the absorbed form: ``w_uk[i]`` =
+        # W_UK_i^T (q_nope_i -> latent), ``w_uv[i]`` = W_UV_i (latent ->
+        # v_i).  Seeded at fan-in ** -0.5 like every other matrix
+        dn, dr, dv, r = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                         cfg.v_head_dim, cfg.kv_lora_rank)
+        p = {
+            "attn_norm_w": jnp.ones((L, H), dt),
+            "wq": big(keys[0], (L, H, nh * (dn + dr)), dt),
+            "wkv_a": _dense_init(keys[1], (L, H, r + dr), dt),
+            "kv_norm_w": jnp.ones((L, r), dt),
+            "w_uk": _dense_init(keys[2], (L, nh, dn, r), dt, scale=r ** -0.5),
+            "w_uv": _dense_init(keys[8], (L, nh, r, dv), dt),
+            "wo": big(keys[3], (L, nh * dv, H), dt),
+            "mlp_norm_w": jnp.ones((L, H), dt),
+        }
+    else:
+        p = {
+            "attn_norm_w": jnp.ones((L, H), dt),
+            "wq": big(keys[0], (L, H, nh * hd), dt),
+            "wk": big(keys[1], (L, H, nkv * hd), dt),
+            "wv": big(keys[2], (L, H, nkv * hd), dt),
+            "wo": big(keys[3], (L, nh * hd, H), dt),
+            "mlp_norm_w": jnp.ones((L, H), dt),
+        }
     if cfg.attn_layernorm:  # bloom: LayerNorm has bias; linears have bias
         p["attn_norm_b"] = jnp.zeros((L, H), dt)
         p["mlp_norm_b"] = jnp.zeros((L, H), dt)
@@ -150,7 +175,35 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
         p["router"] = _dense_init(keys[4], (L, H, E), dt)
         p["w_gate"] = big(keys[5], (L, E, H, I), dt)
         p["w_up"] = big(keys[6], (L, E, H, I), dt)
-        p["w_down"] = big(keys[7], (L, E, I, H), dt)
+        # Routed down projections under a sigmoid router (deepseek_v3)
+        # are seeded at 1/32 of the fan-in scale.  There a token's k
+        # weights are renormalised to sum to ``routed_scaling_factor``:
+        # 0.41 an expert at kanana's 6 and 2.448, where olmoe's softmax
+        # over 64 gives about 1/64.  Seeded experts are unrelated random
+        # functions, so where the k-th and (k+1)-th scores nearly tie (a
+        # few per cent of (token, layer) pairs: the spacing of 128
+        # Gaussian order statistics against bf16 matmul noise) the served
+        # path and the float32 reference swap a sixth of the routed sum
+        # for another.  At the fan-in scale that read 0.03-0.66 on 16
+        # canary tokens and a mean of 1.07 over the vocabulary against
+        # olmoe's 0.056 (on the chip, lead + 7 layers; my chip run, PR
+        # 44), and no precision of the ROUTER mends it: the noise is in
+        # the rows it reads.  A trained checkpoint's routing is decisive
+        # and brings its own weights; at 1/32 one seeded expert's share
+        # of the stream (0.013) is what it is in olmoe's cell.
+        p["w_down"] = big(keys[7], (L, E, I, H), dt,
+                          scale=(I ** -0.5 / 32
+                                 if cfg.router_scoring == "sigmoid" else None))
+        if cfg.router_bias:
+            # non-zero, so that choosing by score + bias and weighing by
+            # the score differ; float32 like the scores it is added to
+            p["router_bias"] = 0.1 * jax.random.normal(
+                keys[9], (L, E), jnp.float32)
+        if cfg.num_shared_experts > 0:
+            Is = cfg.num_shared_experts * I
+            p["ws_gate"] = big(keys[10], (L, H, Is), dt)
+            p["ws_up"] = big(keys[11], (L, H, Is), dt)
+            p["ws_down"] = big(keys[12], (L, Is, H), dt)
     elif cfg.family == "bloom":  # dense 4H GELU MLP with bias
         p["w_up"] = big(keys[5], (L, H, I), dt)
         p["b_up"] = jnp.zeros((L, I), dt)
@@ -163,6 +216,15 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
     return p
 
 
+def lead_block_config(cfg: ModelConfig) -> ModelConfig:
+    """The configuration of a LEADING dense block: the model's attention,
+    and a dense SwiGLU of width ``lead_intermediate_size`` where the
+    repeated stack has its experts."""
+    return cfg.replace(num_experts=0, num_shared_experts=0,
+                       router_bias=False,
+                       intermediate_size=cfg.lead_intermediate_size)
+
+
 def init_full_params(rng: jax.Array, cfg: ModelConfig,
                      quantize=False) -> StageParams:
     """Random-init full model as a single StageParams (stage 0 of 1).
@@ -173,6 +235,11 @@ def init_full_params(rng: jax.Array, cfg: ModelConfig,
     if quantize is True and cfg.quantization in ("int8", "int4"):
         quantize = cfg.quantization
     k_emb, k_layers, k_head = jax.random.split(rng, 3)
+    lead = None
+    if cfg.lead_dense_layers > 0:
+        lead = init_layer_params(
+            jax.random.fold_in(k_layers, 1), lead_block_config(cfg),
+            cfg.lead_dense_layers, quantize=quantize)
     dt = cfg.dtype
     embed = {"tokens": _dense_init(k_emb, (cfg.vocab_size, cfg.hidden_size), dt,
                                    scale=0.02)}
@@ -189,7 +256,7 @@ def init_full_params(rng: jax.Array, cfg: ModelConfig,
     return StageParams(
         layers=init_layer_params(k_layers, cfg, cfg.num_layers,
                                  quantize=quantize),
-        embed=embed, final_norm=final_norm, lm_head=lm_head)
+        embed=embed, final_norm=final_norm, lm_head=lm_head, lead=lead)
 
 
 # ---------------------------------------------------------------------------
@@ -250,6 +317,15 @@ def _mlp(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
 _EXPERT_STACKS = ("w_gate", "w_up", "w_down")
 
 
+def _router_logits(h: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """The router's matmul in float32 at ``HIGHEST`` on float32-cast rows
+    (:func:`_route` says why); a function of its own so that a parity
+    tool can swap in a careless one (``tools/model_parity.py``)."""
+    return jnp.einsum("th,he->te", h.astype(jnp.float32),
+                      w.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
 def _route(cfg: ModelConfig, lp: dict, h: jnp.ndarray):
     """The router: ``(weights [T, k] float32, experts [T, k] int32)`` for
     rows ``h`` [T, H].
@@ -261,11 +337,29 @@ def _route(cfg: ModelConfig, lp: dict, h: jnp.ndarray):
     The k largest of ``softmax(h Wr)`` over ALL experts; renormalised to
     sum to 1 iff ``cfg.norm_topk_prob`` (mixtral: the same arithmetic as
     its "top-k of the logits, then softmax over the k"), else kept as they
-    are (olmoe: they sum to less than 1)."""
+    are (olmoe: they sum to less than 1).  ``router_scoring`` "sigmoid"
+    (deepseek_v3) scores each expert by the sigmoid of its logit, in the
+    same precision: there a flipped expert carries about a sixth of the
+    routed sum, not olmoe's 1/64."""
     with jax.named_scope("moe_route"):
-        logits = jnp.einsum("th,he->te", h.astype(jnp.float32),
-                            lp["router"].astype(jnp.float32),
-                            precision=jax.lax.Precision.HIGHEST)
+        logits = _router_logits(h, lp["router"])
+        if cfg.router_scoring == "sigmoid":
+            # deepseek_v3 ``noaux_tc``: each expert's score is the
+            # sigmoid of its logit; the k are CHOSEN by score + stored
+            # bias and WEIGHED by the score alone, renormalised with the
+            # source's 1e-20 and scaled.  (Its group step, the best
+            # ``topk_group`` of ``n_group`` groups, selects everything
+            # at n_group = 1 and is not written.)
+            scores = jax.nn.sigmoid(logits)
+            choice = (scores + lp["router_bias"].astype(jnp.float32)
+                      if cfg.router_bias else scores)
+            _, experts = jax.lax.top_k(choice, cfg.experts_per_token)
+            weights = jnp.take_along_axis(scores, experts, axis=-1)
+            if cfg.norm_topk_prob:
+                weights = weights / (jnp.sum(weights, axis=-1,
+                                             keepdims=True) + 1e-20)
+            weights = weights * cfg.routed_scaling_factor
+            return weights, experts.astype(jnp.int32)
         probs = jax.nn.softmax(logits, axis=-1)
         weights, experts = jax.lax.top_k(probs, cfg.experts_per_token)
         if cfg.norm_topk_prob:
@@ -337,6 +431,16 @@ def _moe_routed(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
         y = jnp.einsum("tkh,tk->th", out, weights)
         if tp_axis is not None:
             y = jax.lax.psum(y, tp_axis)
+    if cfg.num_shared_experts > 0:
+        # the shared experts: ONE dense SwiGLU of their summed width on
+        # every row, added to the routed sum in float32 before the cast
+        with jax.named_scope("moe_shared"):
+            gate = dense(xt, lp["ws_gate"], "th,hi->ti")
+            up = dense(xt, lp["ws_up"], "th,hi->ti")
+            hs = (jax.nn.silu(gate.astype(jnp.float32))
+                  * up.astype(jnp.float32)).astype(x.dtype)
+            y = y + dense(hs, lp["ws_down"], "ti,ih->th").astype(
+                jnp.float32)
     return y.reshape(b, s, H).astype(x.dtype), rows
 
 
@@ -437,39 +541,20 @@ def _whole_row_rms_norm(x: jnp.ndarray, w: jnp.ndarray, eps: float,
             * w.astype(jnp.float32)).astype(x.dtype)
 
 
-def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
-           k_cache: jnp.ndarray, v_cache: jnp.ndarray,
-           positions: jnp.ndarray, cache_start: jnp.ndarray,
-           slopes: Optional[jnp.ndarray],
-           tp_axis: Optional[str] = None,
-           attn_impl=None,
-           ep_axis: Optional[str] = None,
-           moe_stats: bool = False,
-           valid: Optional[jnp.ndarray] = None):
-    """One decoder block. x: [b, s, H]. Returns (x', k_cache', v_cache'),
-    and with ``moe_stats`` a fourth value, the rows routed to each expert
-    in this layer call ([E] int32; ``_moe_routed``, which is also all
-    that reads ``valid``, the rows that hold a token).  The caches are this
-    layer's planes, or ``LayerOf`` the whole stacks where ``attn_impl``
-    addresses a page pool in place; either goes to the hook untouched.
+def _kv_attention(cfg: ModelConfig, lp: dict, h: jnp.ndarray, k_cache,
+                  v_cache, positions, cache_start, slopes, tp_axis,
+                  attn_impl):
+    """Attention over full keys and values, from normed rows ``h``
+    [b, s, H] to the heads' outputs side by side: ``(attn [b, s, nh x
+    hd], k_cache', v_cache')``.
 
     Head counts derive from the weight shards, not the config, so the same
     code runs full-model (GSPMD) and per-TP-rank (manual shard_map) — under
-    TP this rank sees nh/tp query heads and nkv/tp kv heads.
-    """
-    b, s, H = x.shape
+    TP this rank sees nh/tp query heads and nkv/tp kv heads."""
+    b, s, _ = h.shape
     hd = cfg.head_dim
-    wq_shape = lp["wq"].shape  # QuantizedArray exposes .shape too
-    nh = wq_shape[-1] // hd
+    nh = lp["wq"].shape[-1] // hd  # QuantizedArray exposes .shape too
     nkv = lp["wk"].shape[-1] // hd
-
-    if cfg.attn_layernorm:
-        h = layer_norm(x, lp["attn_norm_w"], lp["attn_norm_b"], cfg.norm_eps)
-    else:
-        h = rms_norm(x, lp["attn_norm_w"], cfg.norm_eps)
-    if x.dtype != cfg.dtype:  # a looped model's float32 stream
-        h = h.astype(cfg.dtype)
-
     q = dense(h, lp["wq"], "bsh,hd->bsd")
     k = dense(h, lp["wk"], "bsh,hd->bsd")
     v = dense(h, lp["wv"], "bsh,hd->bsd")
@@ -499,7 +584,95 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     attn_fn = attn_impl if attn_impl is not None else _default_attn
     attn, k_cache, v_cache = attn_fn(
         q, k, v, k_cache, v_cache, positions, cache_start, slopes)
-    attn = attn.reshape(b, s, nh * hd)
+    return attn.reshape(b, s, nh * hd), k_cache, v_cache
+
+
+def _latent_attention(cfg: ModelConfig, lp: dict, h: jnp.ndarray, cache,
+                      positions: jnp.ndarray, cache_start: jnp.ndarray,
+                      attn_impl=None):
+    """Multi-head latent attention over normed rows ``h`` [b, s, H], in
+    the ABSORBED form on every path: ``(attn [b, s, nh * v_head_dim],
+    cache')``.
+
+    What is cached is one row a token, ``[c | k_pe | 0]``
+    (``ops.latent_attention``): ``c`` the normed latent, ``k_pe`` the
+    roped key part every head shares.  Head ``i``'s score against a
+    cached row is ``(q_nope_i W_UK_i^T | q_pe_i) . (c | k_pe)`` and its
+    output ``(softmax . c) W_UV_i``: ``kv_b``'s two halves are folded
+    into the query (``mla_absorb``) and the output (``mla_unabsorb``),
+    plain matmuls here, and the hook between them is multi-query
+    attention over the shared row (``attn_impl.latent``: a page pool;
+    else the dense cache).  The same arithmetic as decompressing keys
+    and values a head, re-associated; the softmax scale ``(nope + rope)
+    ** -0.5`` multiplies the hook's float32 scores."""
+    b, s, _ = h.shape
+    dn, dr, dv, r = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                     cfg.v_head_dim, cfg.kv_lora_rank)
+    nh = lp["wq"].shape[-1] // (dn + dr)
+    q = dense(h, lp["wq"], "bsh,hd->bsd")
+    ckv = dense(h, lp["wkv_a"], "bsh,hd->bsd")
+    # as in ``_layer``: the head reshape may not reach the dot
+    q, ckv = jax.lax.optimization_barrier((q, ckv))
+    q = q.reshape(b, s, nh, dn + dr)
+    c = rms_norm(ckv[..., :r], lp["kv_norm_w"], cfg.norm_eps)
+    k_pe = apply_rope_interleaved(ckv[:, :, None, r:], positions,
+                                  cfg.rope_theta)[:, :, 0]
+    q_pe = apply_rope_interleaved(q[..., dn:], positions, cfg.rope_theta)
+    pad = cache.shape[-1] - (r + dr)      # a plane's or a LayerOf's lanes
+    scale = (dn + dr) ** -0.5
+    with jax.named_scope("mla_absorb"):
+        q_c = jnp.einsum("bshd,hdr->bshr", q[..., :dn], lp["w_uk"])
+        q_abs = jnp.concatenate(
+            [q_c, q_pe, jnp.zeros((b, s, nh, pad), q_c.dtype)], axis=-1)
+    row = jnp.concatenate(
+        [c, k_pe.astype(c.dtype), jnp.zeros((b, s, pad), c.dtype)], axis=-1)
+    if getattr(attn_impl, "latent", False):
+        out, cache = attn_impl(q_abs, row, cache, positions)
+    elif attn_impl is not None:
+        raise ValueError(
+            "an attention hook made for keys and values cannot serve a "
+            "latent-attention model: its cache is one latent row a token "
+            "(ops.latent_attention.make_latent_attn_impl)")
+    else:
+        with jax.named_scope("mla_attend"):
+            out, cache = latent_dense_attn(q_abs, row, cache, positions,
+                                           cache_start, r, scale)
+    with jax.named_scope("mla_unabsorb"):
+        attn = jnp.einsum("bshr,hrv->bshv", out, lp["w_uv"])
+        attn = attn.reshape(b, s, nh * dv)
+    return attn, cache
+
+
+def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
+           k_cache: jnp.ndarray, v_cache: jnp.ndarray,
+           positions: jnp.ndarray, cache_start: jnp.ndarray,
+           slopes: Optional[jnp.ndarray],
+           tp_axis: Optional[str] = None,
+           attn_impl=None,
+           ep_axis: Optional[str] = None,
+           moe_stats: bool = False,
+           valid: Optional[jnp.ndarray] = None):
+    """One decoder block. x: [b, s, H]. Returns (x', k_cache', v_cache'),
+    and with ``moe_stats`` a fourth value, the rows routed to each expert
+    in this layer call ([E] int32; ``_moe_routed``, which is also all
+    that reads ``valid``, the rows that hold a token).  The caches are this
+    layer's planes, or ``LayerOf`` the whole stacks where ``attn_impl``
+    addresses a page pool in place; either goes to the hook untouched.
+    """
+    if cfg.attn_layernorm:
+        h = layer_norm(x, lp["attn_norm_w"], lp["attn_norm_b"], cfg.norm_eps)
+    else:
+        h = rms_norm(x, lp["attn_norm_w"], cfg.norm_eps)
+    if x.dtype != cfg.dtype:  # a looped model's float32 stream
+        h = h.astype(cfg.dtype)
+
+    if cfg.latent_kv:  # the cache is ``k_cache`` alone; ``v_cache`` is empty
+        attn, k_cache = _latent_attention(cfg, lp, h, k_cache, positions,
+                                          cache_start, attn_impl)
+    else:
+        attn, k_cache, v_cache = _kv_attention(
+            cfg, lp, h, k_cache, v_cache, positions, cache_start, slopes,
+            tp_axis, attn_impl)
     attn = dense(attn, lp["wo"], "bsd,dh->bsh")
     if tp_axis is not None:
         attn = jax.lax.psum(attn, tp_axis)
@@ -593,9 +766,31 @@ def stage_forward(
     # of the cache, ``t * L + l``, through the same seam a layer index
     # goes through; the final norm closes every pass.  Static Python
     # branches: with one pass the traced program is what it always was.
+    # A scorer that knows nothing of latent attention hands over keys and
+    # values a head.  Like a looped model's scratch of L planes (below):
+    # for ONE call over a whole sequence from position 0 the cache is
+    # read back only by the call that wrote it, so a latent model runs
+    # that call over a cache of its own and hands the caller's back
+    # untouched; exact there and nowhere else, so no engine builds one.
+    foreign = None
+    if cfg.latent_kv and attn_impl is None and (
+            cache.values.size or cache.keys.shape[2:] != (
+                1, cache.keys.shape[3], cfg.kv_page_shape[1])):
+        foreign = cache
+        cache = KVCache.create(cfg, cfg.num_layers, *inputs.shape[:2],
+                               dtype=cache.keys.dtype)
     T = cfg.ut_steps
     planes = jax.tree.leaves(cache.keys)[0].shape[0]
-    n_layers = planes if T == 1 else params.layers["attn_norm_w"].shape[0]
+    # leading dense blocks (deepseek_v3) run once before the scan and hold
+    # the cache's first planes; 0 for every other model
+    lead = cfg.lead_dense_layers
+    n_layers = (planes - lead if T == 1
+                else params.layers["attn_norm_w"].shape[0])
+    if lead and (T > 1 or not cache_in_carry or not spec.is_first):
+        raise ValueError(
+            f"a model with leading dense blocks (family {cfg.family!r}, "
+            f"lead_dense_layers={lead}) runs on one stage, in one pass, in "
+            f"the inference layout of the cache")
     if T > 1:
         if not (spec.is_first and spec.is_last):
             require_single_pass(cfg, "a pipeline stage")
@@ -652,31 +847,39 @@ def stage_forward(
                           if k not in whole}
         stacked_cache = getattr(attn_impl, "stacked_cache", False)
 
+        def block(block_cfg, lp, x, K, V, plane, stats):
+            """One block over plane ``plane`` of the carried caches."""
+            k_of, v_of = LayerOf(K, plane), LayerOf(V, plane)
+            kc, vc = ((k_of, v_of) if stacked_cache
+                      else (k_of.sliced(), v_of.sliced()))
+            x, kc, vc, *rows = _layer(block_cfg, lp, x, kc, vc, positions,
+                                      cache_start, slopes, tp_axis,
+                                      attn_impl, ep_axis, stats, valid)
+            K, V = ((kc.stack, vc.stack) if stacked_cache
+                    else (k_of.updated(kc), v_of.updated(vc)))
+            return (x, K, V), (rows[0] if rows else None)
+
         def run_layers(x, kv, plane0):
             def body(carry, scanned):
-                x, K, V = carry
                 lp, li = scanned
                 lp = dict(lp, **{k: LayerOf(params.layers[k], li)
                                  for k in whole})
                 plane = li if plane0 is None else plane0 + li
-                k_of, v_of = LayerOf(K, plane), LayerOf(V, plane)
-                kc, vc = ((k_of, v_of) if stacked_cache
-                          else (k_of.sliced(), v_of.sliced()))
-                x, kc, vc, *rows = _layer(cfg, lp, x, kc, vc, positions,
-                                          cache_start, slopes, tp_axis,
-                                          attn_impl, ep_axis, moe_stats,
-                                          valid)
-                K, V = ((kc.stack, vc.stack) if stacked_cache
-                        else (k_of.updated(kc), v_of.updated(vc)))
-                return (x, K, V), (rows[0] if rows else None)
+                return block(cfg, lp, *carry, plane, moe_stats)
 
             (x, K, V), rows = jax.lax.scan(
                 body, (x, *kv), (scanned_layers, jnp.arange(n_layers)))
             return x, (K, V), rows
 
         kv = (cache.keys, cache.values)
-        if T == 1:  # a plane's index is its layer's
-            x, (new_k, new_v), expert_rows = run_layers(x, kv, None)
+        for i in range(lead):  # plane i, then the stack from plane ``lead``
+            with jax.named_scope("lead_block"):
+                (x, *kv), _ = block(
+                    lead_block_config(cfg),
+                    jax.tree.map(lambda a: a[i], params.lead), x, *kv,
+                    jnp.int32(i), False)
+        if T == 1:  # a plane's index is its layer's, after the lead
+            x, (new_k, new_v), expert_rows = run_layers(x, kv, lead or None)
         else:
             def one_pass(carry, t):
                 with jax.named_scope("ut_pass"):
@@ -703,6 +906,8 @@ def stage_forward(
 
         x, (new_k, new_v) = jax.lax.scan(
             body, x, (params.layers, cache.keys, cache.values))
+    if foreign is not None:
+        new_k, new_v = foreign.keys, foreign.values
     new_cache = KVCache(new_k, new_v, cache_start + inputs.shape[1])
 
     if spec.is_last:

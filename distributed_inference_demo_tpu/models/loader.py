@@ -206,6 +206,70 @@ def moe_params_from_state_dict(raw: Dict[str, np.ndarray],
                        lm_head=lm_head)
 
 
+def deepseek_v3_params_from_state_dict(raw: Dict[str, np.ndarray],
+                                       cfg: ModelConfig) -> StageParams:
+    """Map a DeepseekV3ForCausalLM state dict (``q_lora_rank`` null:
+    kanana-2-30b-a3b) onto the stacked layout: the first
+    ``cfg.lead_dense_layers`` checkpoint layers are the leading dense
+    blocks (``StageParams.lead``), the rest the repeated expert stack.
+
+    ``kv_b_proj`` ``[nh (dn + dv), r]`` is kept as its two halves a head,
+    laid out for the absorbed form: ``w_uk[i] = W_UK_i^T`` ``[dn, r]``
+    (its rows as stored) and ``w_uv[i] = W_UV_i`` ``[r, dv]``.  The
+    checkpoint's rope columns (``q_proj``'s last ``dr`` of a head,
+    ``kv_a_proj_with_mqa``'s last ``dr``) are stored for INTERLEAVED
+    pairs and the program ropes interleaved pairs, so no column moves
+    (HF de-interleaves them and ropes in rotate-half form: the same
+    scores; ``tests/test_kanana.py``)."""
+    dt = cfg.dtype
+    E, nh = cfg.num_experts, cfg.num_heads
+    dn, dv, r = cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    lin = lambda name: _get(raw, name).T          # [out, in] -> [in, out]
+
+    def block(i: int) -> dict:
+        p = f"layers.{i}."
+        kv_b = _get(raw, p + "self_attn.kv_b_proj.weight").reshape(
+            nh, dn + dv, r)
+        out = {
+            "attn_norm_w": _get(raw, p + "input_layernorm.weight"),
+            "wq": lin(p + "self_attn.q_proj.weight"),
+            "wkv_a": lin(p + "self_attn.kv_a_proj_with_mqa.weight"),
+            "kv_norm_w": _get(raw, p + "self_attn.kv_a_layernorm.weight"),
+            "w_uk": kv_b[:, :dn, :],
+            "w_uv": kv_b[:, dn:, :].transpose(0, 2, 1),
+            "wo": lin(p + "self_attn.o_proj.weight"),
+            "mlp_norm_w": _get(raw, p + "post_attention_layernorm.weight"),
+        }
+        if i < cfg.lead_dense_layers:
+            for ours, name in (("w_gate", "gate_proj"), ("w_up", "up_proj"),
+                               ("w_down", "down_proj")):
+                out[ours] = lin(p + f"mlp.{name}.weight")
+            return out
+        out["router"] = lin(p + "mlp.gate.weight")
+        out["router_bias"] = _get(
+            raw, p + "mlp.gate.e_score_correction_bias").astype(np.float32)
+        for ours, name in (("gate", "gate_proj"), ("up", "up_proj"),
+                           ("down", "down_proj")):
+            out["w_" + ours] = np.stack([
+                lin(p + f"mlp.experts.{e}.{name}.weight") for e in range(E)])
+            out["ws_" + ours] = lin(p + f"mlp.shared_experts.{name}.weight")
+        return out
+
+    def stack(blocks: list) -> dict:
+        return {k: jnp.asarray(np.stack([b[k] for b in blocks]),
+                               jnp.float32 if k == "router_bias" else dt)
+                for k in blocks[0]}
+
+    n_lead = cfg.lead_dense_layers
+    blocks = [block(i) for i in range(cfg.total_layers)]
+    return StageParams(
+        layers=stack(blocks[n_lead:]),
+        embed={"tokens": jnp.asarray(_get(raw, "embed_tokens.weight"), dt)},
+        final_norm={"w": jnp.asarray(_get(raw, "norm.weight"), dt)},
+        lm_head={"w": jnp.asarray(_get(raw, "lm_head.weight", ("",)).T, dt)},
+        lead=stack(blocks[:n_lead]) if n_lead else None)
+
+
 def gemma_params_from_state_dict(raw: Dict[str, np.ndarray],
                                  cfg: ModelConfig) -> StageParams:
     """Gemma: llama names end to end, but every RMSNorm applies
@@ -233,6 +297,7 @@ _SD_MAPPERS = {
     "bloom": bloom_params_from_state_dict,
     "mixtral": moe_params_from_state_dict,
     "olmoe": moe_params_from_state_dict,
+    "deepseek_v3": deepseek_v3_params_from_state_dict,
 }
 
 
@@ -268,6 +333,10 @@ def stage_params_to_bytes(params: StageParams) -> bytes:
 
     from ..ops.quant import QuantizedArray
 
+    if params.lead:
+        raise TypeError(
+            "leading dense blocks (StageParams.lead) run on one stage and "
+            "are not shipped to pipeline stages")
     flat = {}
     for section in ("layers", "embed", "final_norm", "lm_head"):
         d = getattr(params, section)

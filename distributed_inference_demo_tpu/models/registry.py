@@ -90,6 +90,23 @@ MODEL_REGISTRY = {
         num_heads=16, num_kv_heads=16, intermediate_size=5632,
         max_seq_len=65536, rope_theta=1000000.0, norm_eps=1e-6,
         ut_steps=4, sandwich_norm=True),
+    # --- deepseek_v3 family: kanana-2-30b-a3b-instruct-2601
+    # (kakaocorp, config.json).  Multi-head latent attention (kv_lora_rank
+    # 512, q in one matrix, heads of 128 no-rope + 64 rope, values 128,
+    # interleaved rope), ONE leading dense block of width 6144 and then 47
+    # blocks of 128 experts of width 768, 6 a token, chosen by sigmoid +
+    # bias (``noaux_tc``, one group), weighed by the sigmoid, renormalised
+    # and scaled by 2.448, beside two shared experts.  ``num_layers``
+    # counts the repeated stack: 1 + 47 = the published 48 ---
+    "kanana-2-30b-a3b": ModelConfig(
+        family="deepseek_v3", vocab_size=128256, hidden_size=2048,
+        num_layers=47, num_heads=32, num_kv_heads=32, intermediate_size=768,
+        max_seq_len=32768, rope_theta=1000000.0, norm_eps=1e-6,
+        num_experts=128, experts_per_token=6, norm_topk_prob=True,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, lead_dense_layers=1, lead_intermediate_size=6144,
+        num_shared_experts=2, router_scoring="sigmoid", router_bias=True,
+        routed_scaling_factor=2.448),
     # --- tiny configs for tests and virtual-mesh dry runs ---
     "llama-test": ModelConfig(
         family="llama", vocab_size=256, hidden_size=64, num_layers=4,
@@ -124,6 +141,16 @@ MODEL_REGISTRY = {
         num_heads=4, num_kv_heads=4, intermediate_size=128, max_seq_len=128,
         norm_eps=1e-6, ut_steps=3, sandwich_norm=True,
         dtype_name="float32"),
+    # one leading dense block + 3 expert blocks, latent rank 32
+    "kanana-test": ModelConfig(
+        family="deepseek_v3", vocab_size=256, hidden_size=64, num_layers=3,
+        num_heads=4, num_kv_heads=4, intermediate_size=32, max_seq_len=128,
+        norm_eps=1e-6, num_experts=16, experts_per_token=3,
+        norm_topk_prob=True, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, lead_dense_layers=1,
+        lead_intermediate_size=96, num_shared_experts=1,
+        router_scoring="sigmoid", router_bias=True,
+        routed_scaling_factor=2.448, dtype_name="float32"),
 }
 
 

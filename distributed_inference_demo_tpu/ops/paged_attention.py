@@ -128,7 +128,7 @@ def write_paged_kv(
     bt = K.shape[3]
     if form == WRITE_KERNEL:
         k_new, v_new = prepare_kv_chunk(k_new, v_new, K.dtype, V.dtype)
-        K, V = _kernel_write(K, V, li, k_new, v_new,
+        K, V = _kernel_write((K, V), li, (k_new, v_new),
                              tables.astype(jnp.int32),
                              positions[:, 0].astype(jnp.int32), interpret)
         return _like(k_pages, K), _like(v_pages, V)
@@ -176,20 +176,25 @@ def _write_group(dtype) -> int:
     return 32 // jnp.dtype(dtype).itemsize
 
 
-def _page_write_kernel(page_ref, row_ref, lo_ref, hi_ref, layer_ref,
-                       k_new_ref, v_new_ref, k_in, v_in, k_hbm, v_hbm,
-                       k_buf, v_buf, sems, *, units: int, group: int):
+def _page_write_kernel(page_ref, row_ref, lo_ref, hi_ref, layer_ref, *refs,
+                       units: int, group: int):
     """Grid (batches of ``units``,).  Unit ``n`` is one tile group of one
     row's chunk: rows ``[row, row + group)`` of page ``page_ref[n]``, all
     kv heads (``[nkv, group, hd]``, strided over the heads).  A batch
     reads its units' tiles out of the pool, takes rows ``[lo, hi)`` of
     each from the chunk (``*_new_ref``: the chunk's tokens laid out like
     the tiles), and writes the tiles back.  A unit with ``hi <= lo`` (a
-    sentinel page, a position past the table, padding) moves nothing."""
-    del k_in, v_in                      # the pools, aliased to the outputs
+    sentinel page, a position past the table, padding) moves nothing.
+
+    ``refs``, for ``n`` pools (K and V; a latent pool is one): the ``n``
+    chunks, the ``n`` pools (aliased to the outputs and not read as
+    inputs), the ``n`` outputs, ``n`` tile buffers and the semaphores."""
+    n = (len(refs) - 1) // 4
+    new_refs, hbms, bufs = refs[:n], refs[2 * n:3 * n], refs[3 * n:4 * n]
+    sems = refs[-1]
     first = pl.program_id(0) * units
     layer = layer_ref[0]
-    streams = ((k_hbm, k_buf, k_new_ref), (v_hbm, v_buf, v_new_ref))
+    streams = tuple(zip(hbms, bufs, new_refs))
 
     def copies(u, to_pool):
         row = pl.multiple_of(row_ref[first + u], group)
@@ -210,7 +215,7 @@ def _page_write_kernel(page_ref, row_ref, lo_ref, hi_ref, layer_ref,
         jax.lax.fori_loop(0, units, body, 0)
 
     def merge(u):
-        r = jax.lax.broadcasted_iota(jnp.int32, k_buf.shape[1:], 1)
+        r = jax.lax.broadcasted_iota(jnp.int32, bufs[0].shape[1:], 1)
         mine = (r >= lo_ref[first + u]) & (r < hi_ref[first + u])
         for _, buf, new in streams:
             # selected in 32 bits (exact): a mask over packed rows is
@@ -226,49 +231,51 @@ def _page_write_kernel(page_ref, row_ref, lo_ref, hi_ref, layer_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("units", "interpret"))
-def _page_write_call(page, row, lo, hi, layer, k_new, v_new, k_pages,
-                     v_pages, *, units, interpret):
-    """The Pallas call: ``k_pages`` / ``v_pages`` ``[L, N, nkv, bt, hd]``
-    aliased to the outputs, ``k_new`` / ``v_new`` ``[U, nkv, group, hd]``
-    with ``U`` a multiple of ``units``."""
-    U, nkv, group, hd = k_new.shape
+def _page_write_call(page, row, lo, hi, layer, news, pools, *, units,
+                     interpret):
+    """The Pallas call: ``pools`` (a tuple: K and V, or one latent pool)
+    ``[L, N, nkv, bt, hd]`` aliased to the outputs, ``news`` their chunks
+    ``[U, nkv, group, hd]`` with ``U`` a multiple of ``units``."""
+    n = len(pools)
+    U, nkv, group, hd = news[0].shape
     new_spec = pl.BlockSpec((units, nkv, group, hd),
                             lambda i, *_: (i, 0, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
-    buf = pltpu.VMEM((units, nkv, group, hd), k_pages.dtype)
+    buf = pltpu.VMEM((units, nkv, group, hd), pools[0].dtype)
     return pl.pallas_call(
         functools.partial(_page_write_kernel, units=units, group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(U // units,),
-            in_specs=[new_spec, new_spec, pool_spec, pool_spec],
-            out_specs=[pool_spec, pool_spec],
-            scratch_shapes=[buf, buf,
-                            pltpu.SemaphoreType.DMA((2, units))],
+            in_specs=[new_spec] * n + [pool_spec] * n,
+            out_specs=[pool_spec] * n,
+            scratch_shapes=[buf] * n + [
+                pltpu.SemaphoreType.DMA((n, units))],
         ),
-        out_shape=[jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
-                   jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
         # operands count the five scalar arrays too
-        input_output_aliases={7: 0, 8: 1},
+        input_output_aliases={5 + n + i: i for i in range(n)},
         interpret=interpret,
         name="kv_page_write",
-    )(page, row, lo, hi, layer, k_new, v_new, k_pages, v_pages)
+    )(page, row, lo, hi, layer, *news, *pools)
 
 
 # units of one batch: their tiles are in flight together
 _WRITE_UNITS = 32
 
 
-def _kernel_write(K, V, li, k_new, v_new, tables, starts, interpret):
-    """The chunk cut into the kernel's units.  Row ``b``'s tokens sit at
+def _kernel_write(pools, li, news, tables, starts, interpret):
+    """The chunk cut into the kernel's units, for each of ``pools`` (K and
+    V, or one latent pool) and its chunk in ``news``; the written pools
+    come back in a list.  Row ``b``'s tokens sit at
     positions ``starts[b] + arange(chunk)``; they touch at most ``n_g``
     tile groups, each inside one page (``bt % group == 0``).  A group is
     one row's alone (:func:`route_pool`): two units naming the same
     group would both read it before either wrote it back."""
-    _, num_pages, nkv, bt, hd = K.shape
-    b, chunk = k_new.shape[:2]
+    _, num_pages, nkv, bt, hd = pools[0].shape
+    b, chunk = news[0].shape[:2]
     W = tables.shape[1]
-    G = _write_group(K.dtype)
+    G = _write_group(pools[0].dtype)
     n_g = (chunk + 2 * G - 2) // G
     first = ((starts // G) * G)[:, None] + G * jnp.arange(n_g)  # [b, n_g]
     pidx = first // bt
@@ -293,8 +300,8 @@ def _kernel_write(K, V, li, k_new, v_new, tables, starts, interpret):
     padded = lambda x: jnp.pad(x, ((0, pad), (0, 0), (0, 0), (0, 0)))
     return _page_write_call(
         flat(jnp.minimum(page, num_pages - 1)), flat(first % bt), flat(lo),
-        flat(hi), li.reshape(1), padded(tiles(k_new)), padded(tiles(v_new)),
-        K, V, units=units, interpret=interpret)
+        flat(hi), li.reshape(1), tuple(padded(tiles(x)) for x in news),
+        tuple(pools), units=units, interpret=interpret)
 
 
 def route_pool(backend: str, platform: str, k_pages, chunk: int) -> str:

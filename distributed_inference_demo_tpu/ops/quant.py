@@ -161,11 +161,12 @@ def maybe_quantize(params, cfg):
     used by loader / checkpoint / tests."""
     if cfg.quantization not in ("int8", "int4"):
         return params
-    from ..models.base import StageParams
-    return StageParams(layers=quantize_layer_params(params.layers,
-                                                    cfg.quantization),
-                       embed=params.embed, final_norm=params.final_norm,
-                       lm_head=params.lm_head)
+    import dataclasses
+    return dataclasses.replace(
+        params,
+        layers=quantize_layer_params(params.layers, cfg.quantization),
+        lead=(None if params.lead is None else
+              quantize_layer_params(params.lead, cfg.quantization)))
 
 
 # ---------------------------------------------------------------------------
@@ -308,8 +309,12 @@ def alloc_kv_pages(shape, kv_dtype: Optional[str], base_dtype):
 
 
 def alloc_kv_pool(shape, kv_dtype: Optional[str], base_dtype,
-                  pool_sharding=None):
+                  pool_sharding=None, streams: int = 2):
     """``(k_pool, v_pool)``, both zeroed :func:`alloc_kv_pages` tensors.
+    ``streams=1`` (a latent-attention model: one row a token,
+    ``ModelConfig.kv_streams``): the first is the pool and the second
+    holds no element (``shape`` with a last dimension of 0), so the seam
+    of two operands stays and nothing is stored twice.
 
     ``pool_sharding`` (the paged seam's ``KVCache`` of NamedShardings,
     or None off-mesh): the pools are BORN on their kv-head shards — a
@@ -320,9 +325,11 @@ def alloc_kv_pool(shape, kv_dtype: Optional[str], base_dtype,
     quantized layouts' data/scale/zero leaves (all keep the
     ``[L, N, H(tp), bt, ·]`` axis order), so scales shard WITH their
     pages."""
+    second = tuple(shape) if streams == 2 else tuple(shape[:-1]) + (0,)
+
     def alloc():
         return (alloc_kv_pages(shape, kv_dtype, base_dtype),
-                alloc_kv_pages(shape, kv_dtype, base_dtype))
+                alloc_kv_pages(second, kv_dtype, base_dtype))
 
     if pool_sharding is None:
         return alloc()

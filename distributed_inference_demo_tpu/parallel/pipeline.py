@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.base import (KVCache, ModelConfig, StageParams, StageSpec,
-                           require_single_pass)
+                           require_kv_pair, require_single_pass)
 from .sharding import stage_param_spec_tree
 
 
@@ -198,6 +198,7 @@ def make_pipeline_generate_fn(cfg: ModelConfig, mesh: Mesh, *,
     inside each stage).
     """
     require_single_pass(cfg, "the circular pipeline")
+    require_kv_pair(cfg, "the circular pipeline")
     from ..models.decoder import stage_forward
     from ..ops.sampling import SamplingParams, sample_logits
 
@@ -364,6 +365,7 @@ def make_pipeline_train_step(cfg: ModelConfig, mesh: Mesh, optimizer,
     ``[batch, seq]`` int32 on host; batch must divide by dp*num_microbatches.
     """
     require_single_pass(cfg, "the circular pipeline")
+    require_kv_pair(cfg, "the circular pipeline")
     use_tp = mesh.shape.get("tp", 1) > 1
     use_dp = mesh.shape.get("dp", 1) > 1
     axis_names = set(mesh.axis_names)

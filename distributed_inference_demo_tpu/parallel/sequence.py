@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.base import (KVCache, ModelConfig, StageSpec,
-                           require_single_pass)
+                           require_kv_pair, require_single_pass)
 from ..models.decoder import stage_forward
 from ..ops.attention import update_kv_cache
 from ..ops.norms import layer_norm, rms_norm
@@ -162,6 +162,7 @@ def _make_ring_cores(cfg: ModelConfig, spec: StageSpec, s_loc: int,
     rides along explicitly so a decode dispatch needs no prompt shape
     (the fused path closes over it; the stream path cannot)."""
     require_single_pass(cfg, "ring sequence parallelism")
+    require_kv_pair(cfg, "ring sequence parallelism")
     cache_dtype = kv_dtype if kv_dtype is not None else cfg.dtype
 
     def prefill_core(params, ids, rng):

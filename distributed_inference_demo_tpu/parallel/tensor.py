@@ -11,7 +11,8 @@ each chip only touches its heads' cache lines.
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..models.base import KVCache, ModelConfig, StageParams, StageSpec
+from ..models.base import (KVCache, ModelConfig, StageParams, StageSpec,
+                           require_kv_pair)
 from ..models.decoder import stage_forward
 from .sharding import stage_param_spec_tree
 
@@ -50,6 +51,8 @@ def tp_cache_sharding(mesh: Mesh) -> KVCache:
 def validate_tp(cfg: ModelConfig, mesh: Mesh) -> int:
     """Check the config can shard over the mesh's tp axis; returns tp."""
     tp = mesh.shape.get("tp", 1)
+    if tp > 1:
+        require_kv_pair(cfg, "tensor parallelism (--tp)")
     if tp > 1 and cfg.num_kv_heads % tp:
         raise ValueError(
             f"num_kv_heads={cfg.num_kv_heads} not divisible by tp={tp}")
@@ -136,8 +139,15 @@ def make_paged_forward_seam(cfg: ModelConfig, spec: StageSpec, mesh,
     from ..ops.paged_attention import make_paged_attn_impl
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
     if tp <= 1:
-        impl, bind = make_paged_attn_impl(block_tokens, backend,
-                                          interpret, record)
+        if cfg.latent_kv:   # one latent row a token: its own hook
+            from ..ops.latent_attention import make_latent_attn_impl
+            impl, bind = make_latent_attn_impl(
+                cfg.kv_lora_rank,
+                (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5,
+                backend, interpret, record)
+        else:
+            impl, bind = make_paged_attn_impl(block_tokens, backend,
+                                              interpret, record)
 
         def fwd(p, inputs, cache, positions, last_logits_only,
                 moe_stats=False, valid=None):

@@ -75,14 +75,15 @@ import numpy as np
 
 from ..models.base import (KVCache, ModelConfig, StageParams,
                            StageSpec, pad_cache_capacity,
-                           require_single_pass)
+                           require_kv_pair, require_single_pass)
 from ..ops.sampling import SamplingParams, filtered_logits, sample_logits
 from ..telemetry import postmortem
 from ..telemetry import profiling as _profiling
 from ..telemetry.anomaly import AnomalyMonitor
 from ..telemetry.flightrecorder import get_flight_recorder
 from ..telemetry.slo import get_slo_ledger, sanitize_tenant
-from ..telemetry.tracing import (LOOP_DISPATCH_FIELDS,
+from ..telemetry.tracing import (LATENT_DISPATCH_FIELDS,
+                                 LOOP_DISPATCH_FIELDS,
                                  MOE_DISPATCH_FIELDS, DispatchTrace,
                                  LoopCounters, MoeCounters, TraceRecorder,
                                  to_chrome_trace)
@@ -386,6 +387,7 @@ class ContinuousBatchingEngine:
             raise ValueError("draft_cfg and draft_params go together")
         if draft_cfg is not None:
             require_single_pass(draft_cfg, "the draft side of speculation")
+            require_kv_pair(draft_cfg, "the draft side of speculation")
             if draft_cfg.vocab_size != cfg.vocab_size:
                 raise ValueError(
                     f"draft vocab ({draft_cfg.vocab_size}) != target vocab "
@@ -401,6 +403,8 @@ class ContinuousBatchingEngine:
         # different grid): one knob or the other.
         from ..ops.quant import resolve_kv_dtype
         self.kv_dtype = resolve_kv_dtype(kv_dtype)
+        if self.kv_dtype != "bf16":
+            require_kv_pair(cfg, f"a page pool of {self.kv_dtype} pages")
         if self.kv_dtype != "bf16" and self.kv_cache_dtype is not None:
             raise ValueError(
                 f"kv_dtype={self.kv_dtype!r} quantizes the page pool and "
@@ -488,9 +492,12 @@ class ContinuousBatchingEngine:
         fwd_p, bind_tables, pool_sharding = make_paged_forward_seam(
             cfg, self.spec, mesh, params, bt, record=self.attn_paths)
         from ..ops.quant import alloc_kv_pool
+        # a latent-attention model's pool is ``_pk`` alone, one row a
+        # token a plane; ``_pv`` then holds no element
+        heads, width = cfg.kv_page_shape
         self._pk, self._pv = alloc_kv_pool(
-            (cfg.kv_planes, N, cfg.num_kv_heads, bt, cfg.head_dim),
-            self.kv_dtype, page_dtype, pool_sharding)
+            (cfg.kv_planes, N, heads, bt, width), self.kv_dtype,
+            page_dtype, pool_sharding, streams=cfg.kv_streams)
         self._tables = np.full((B, self._table_width), N, np.int32)
         # write_row_to_pages survives for the DRAFT side only: the draft
         # prefill still runs a dense temp row (the draft is small by
@@ -508,6 +515,7 @@ class ContinuousBatchingEngine:
             kv_host_tier_bytes, kv_disk_tier_path, kv_disk_tier_bytes)
         self._kv_tier = None
         if tier_host > 0:
+            require_kv_pair(cfg, "the host tier of the KV cache")
             self._kv_tier = TieredKVStore(
                 tier_host, bt, disk_path=tier_path,
                 disk_bytes=tier_disk)
@@ -1311,9 +1319,13 @@ class ContinuousBatchingEngine:
         self.loop_counters = LoopCounters(
             cfg.ut_steps, cfg.kv_planes,
             self.kv_cache.block_bytes // bt) if loop else None
+        # a latent-attention model's record says what its prefill kernel
+        # attended over
+        latent = cfg.latent_kv and self._mixed_step is not None
         self.dispatch_trace = DispatchTrace(
             (MOE_DISPATCH_FIELDS if moe else ())
-            + (LOOP_DISPATCH_FIELDS if loop else ()))
+            + (LOOP_DISPATCH_FIELDS if loop else ())
+            + (LATENT_DISPATCH_FIELDS if latent else ()))
 
         # (mixed mode never dispatches the serialized step programs: it
         # launches every variant of mixed_step instead, below)
@@ -1534,6 +1546,7 @@ class ContinuousBatchingEngine:
         the adopt scatter."""
         if k_blocks is None:
             return self.submit(prompt_ids, max_new_tokens)
+        require_kv_pair(self.cfg, "a premigrated prefill (disaggregation)")
         prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
         from ..ops.quant import QuantizedKVPages
         if isinstance(k_blocks, QuantizedKVPages):
@@ -1709,6 +1722,7 @@ class ContinuousBatchingEngine:
         acceptance EWMA) but NOT the draft scratch pages or n-gram
         history — the importer rebuilds proposer state from
         prompt+tokens, which is cheap and exact (docs/DESIGN.md §22)."""
+        require_kv_pair(self.cfg, "export_request (migration)")
         req = rid if isinstance(rid, Request) else self._by_rid.get(rid)
         if req is None:
             raise KeyError(f"unknown request id {rid!r}")
@@ -1850,6 +1864,7 @@ class ContinuousBatchingEngine:
         checkpointed length with NO prefill dispatch and zero dense-row
         h2d.  Restoring the rng key makes a single-request resume
         sample-exact; greedy streams are bit-identical regardless."""
+        require_kv_pair(self.cfg, "import_request (migration)")
         rid = request_id if request_id is not None else ckpt.get("rid")
         if not ckpt.get("tokens") or int(ckpt.get("length") or 0) <= 0:
             # cold checkpoint: nothing decoded yet — plain admission
@@ -3310,6 +3325,9 @@ class ContinuousBatchingEngine:
         free = list(free)
         chunks = 0
         prefill_tokens = 0
+        # (query, cached token) pairs the slab's tokens attend over: a
+        # token at position p, its p predecessors and itself
+        prefill_kv_tokens = 0
         r = 0
         for a in adms:
             if r >= want:
@@ -3323,6 +3341,7 @@ class ContinuousBatchingEngine:
                 seg_ntok[r] = C
                 packed.append((r, a, False, -1))
                 prefill_tokens += C
+                prefill_kv_tokens += C * start + C * (C + 1) // 2
                 start += C
                 suffix = suffix[C:]
                 chunks += 1
@@ -3354,6 +3373,7 @@ class ContinuousBatchingEngine:
             seg_keys[r] = np.asarray(sub)
             packed.append((r, a, True, slot))
             prefill_tokens += n
+            prefill_kv_tokens += n * start + n * (n + 1) // 2
             r += 1
         finals = [(a["req"], slot) for (_, a, f, slot) in packed if f]
         # the slab is the r segments that were packed: the arrays' shape
@@ -3411,7 +3431,8 @@ class ContinuousBatchingEngine:
             seg=seg, slab_rows=slab_segs * C,
             tables=tables, active_mask=active_mask,
             budget_vec=budget_vec,
-            prefill_tokens=prefill_tokens, n_active=n_active,
+            prefill_tokens=prefill_tokens,
+            prefill_kv_tokens=prefill_kv_tokens, n_active=n_active,
             live0=live0, kv_tokens=kv_tokens, spec_mixed=spec_mixed,
             k_vec=k_vec, k_disp=k_disp, num_rounds=num_rounds,
             dev=None, how=None, ahead_s=0.0)
@@ -3732,6 +3753,8 @@ class ContinuousBatchingEngine:
                 * self.cfg.ut_steps))
         if self.loop_counters is not None:
             record.update(self.loop_counters.add(bool(packed), steps))
+        if "prefill_kv_tokens" in self.dispatch_trace.extra_fields:
+            record["prefill_kv_tokens"] = plan.prefill_kv_tokens
         cs = self.chunk_stats
         cs["mixed_dispatches"] += 1
         cs["mixed_prefill_tokens"] += prefill_tokens

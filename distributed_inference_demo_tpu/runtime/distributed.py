@@ -49,7 +49,7 @@ from ..comm import wire
 from ..comm.transport import (BaseTransport, TransportTimeout,
                               record_corrupt_frame)
 from ..models.base import (KVCache, ModelConfig, StageParams, StageSpec,
-                           require_single_pass)
+                           require_kv_pair, require_single_pass)
 from ..ops.sampling import SamplingParams, sample_logits
 from ..telemetry import postmortem
 from ..telemetry import profiling as _profiling
@@ -92,6 +92,7 @@ class StageRuntime:
         only layout ("dense" was removed — docs/DESIGN.md §14)."""
         if spec.num_stages > 1:
             require_single_pass(cfg, "a pipeline of stages")
+            require_kv_pair(cfg, "a pipeline of stages")
         self.cfg = cfg
         self.spec = spec
         self.max_seq = max_seq

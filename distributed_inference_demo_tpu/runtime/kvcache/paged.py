@@ -69,7 +69,7 @@ class PagedKVCacheManager:
 
     def __init__(self, num_layers: int, num_kv_heads: int, head_dim: int,
                  num_blocks: int, block_tokens: int, dtype,
-                 kv_dtype: Optional[str] = None):
+                 kv_dtype: Optional[str] = None, streams: int = 2):
         from ...ops.quant import (kv_scale_token_head_bytes,
                                   kv_token_head_bytes, resolve_kv_dtype)
         bt = int(block_tokens)
@@ -77,7 +77,9 @@ class PagedKVCacheManager:
         # block_bytes accounts the ACTUAL page width incl. the quantized
         # layouts' scale sidecar — one owner (ops/quant.py) shared with
         # make_kv_backend's byte-budget admission
-        token_heads = 2 * int(num_layers) * int(num_kv_heads) * bt
+        # ``streams``: tensors a token holds in a plane (keys and values;
+        # a latent-attention model's one row: ModelConfig.kv_streams)
+        token_heads = int(streams) * int(num_layers) * int(num_kv_heads) * bt
         self.block_bytes = token_heads * kv_token_head_bytes(
             int(head_dim), self.kv_dtype, dtype)
         self.scale_block_bytes = token_heads * kv_scale_token_head_bytes(
@@ -110,8 +112,9 @@ class PagedKVCacheManager:
                   dtype=None,
                   kv_dtype: Optional[str] = None) -> "PagedKVCacheManager":
         dtype = dtype if dtype is not None else cfg.dtype
-        return cls(cfg.kv_planes, cfg.num_kv_heads, cfg.head_dim,
-                   num_blocks, block_tokens, dtype, kv_dtype=kv_dtype)
+        return cls(cfg.kv_planes, *cfg.kv_page_shape,
+                   num_blocks, block_tokens, dtype, kv_dtype=kv_dtype,
+                   streams=cfg.kv_streams)
 
     # ------------------------------------------------------------------
     # lookup (same tree walk as the dense manager)
@@ -331,6 +334,8 @@ class PagedKVCacheManager:
                        layout="paged",
                        h2d_bytes=self.stats["promote_h2d_bytes"],
                        block_tokens=self.block_tokens,
+                       bytes_per_token=(self.block_bytes
+                                        // self.block_tokens),
                        blocks_total=self.num_blocks,
                        blocks_used=used,
                        resident_bytes=0,

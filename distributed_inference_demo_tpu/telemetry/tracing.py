@@ -202,6 +202,11 @@ MOE_DISPATCH_FIELDS = ("moe_rows", "moe_valid_rows", "moe_touched",
 # stack the execution ran, ((1 if it packed a segment else 0) + steps)
 # x ut_steps
 LOOP_DISPATCH_FIELDS = ("ut_passes",)
+# what a latent-attention model adds: the (query, cached token) pairs
+# the slab's prompt tokens attend over, each token its predecessors and
+# itself (what the prefill kernel's arithmetic is proportional to, as
+# ``kv_tokens x steps`` is for the decode kernel's)
+LATENT_DISPATCH_FIELDS = ("prefill_kv_tokens",)
 _DISPATCH_RING = 128       # x ~135 bytes a row: /stats stays under 18 KB
 # a span that is work (every one but ``await``) and lasts this long is a
 # stall: ten times the longest ordinary span (four chips' ``ahead``,

@@ -681,3 +681,43 @@ def test_grouped_matmul_gets_through_mosaic(v5e, rows, quant):
                 S((), jnp.int32)).compile()
         assert "moe_gmm" in compiled.as_text()
         assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("b,chunk", [(16, 1), (2, 256)],
+                         ids=["decode", "slab"])
+def test_latent_kernels_get_through_mosaic(v5e, b, chunk):
+    """The latent (MLA) page write and the page-walking kernel at the
+    kanana cell's shapes (8 planes of 2,048 pages of 128 tokens x 640
+    lanes, 32 heads, rank 512): 16 decoding slots and a two-segment slab.
+    The calls carry the names the trace readers match by prefix, the pool
+    is aliased through the write (temporaries far under a plane), and a
+    576-wide row, the width as published, is refused by Mosaic's DMA
+    slicing, which is why the page is lane-padded."""
+    from distributed_inference_demo_tpu.ops import latent_attention as la
+    from distributed_inference_demo_tpu.ops.stacked import LayerOf
+    S = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=v5e)  # noqa: E731
+    L, N, bt, nh, rank = 8, 2048, 128, 32, 512
+
+    def step(q, pool, li, tables, pos, row):
+        pages = la.write_latent_pages(LayerOf(pool, li), row, tables, pos,
+                                      form=la.WRITE_KERNEL)
+        out = la.latent_paged_attention(q, pages, tables, pos, rank,
+                                        192 ** -0.5)
+        return out, pages.stack
+
+    def lower(width):
+        return jax.jit(step, donate_argnums=(1,)).lower(
+            S((b, chunk, nh, width), jnp.bfloat16),
+            S((L, N, 1, bt, width), jnp.bfloat16), S((), jnp.int32),
+            S((b, 96), jnp.int32), S((b, chunk), jnp.int32),
+            S((b, chunk, width), jnp.bfloat16))
+
+    compiled = lower(640).compile()
+    text = compiled.as_text()
+    assert "kv_page_write" in text
+    assert ("_paged_call_latent" if chunk == 1
+            else "_paged_prefill_call_latent") in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
+    if chunk == 1:
+        with pytest.raises(Exception, match="aligned to tiling"):
+            lower(576).compile()

@@ -10,6 +10,9 @@ least as large as one layer's plane of the page pool.
         --mixed-token-budget 640 --max-seq 4096 --kv-block-tokens 128 \\
         --kv-cache-blocks 192 208 224
 
+``--model`` is a registry name or a benchmark configuration's
+(``benchmark/configs/<name>.json``: ``tools/bench_config.py``).
+
 libtpu compiles for a described topology (``v5e:2x2``, one of its
 devices) without a chip; parameters and the page pool are shapes only, so
 nothing model-sized is allocated.  It proves compilation and sizes a pool
@@ -24,7 +27,9 @@ Reading the large ops ("no pool copy" without a chip).  The pool is
 addressed in place (``ops.stacked.LayerOf``): on the chip the KV write is
 the Pallas call ``kv_page_write`` (named under "pallas calls"; its result
 is a tuple aliased to the pool, and tuples, like the layer scan's
-``while``, are not listed), so a sound program lists NOTHING of the pool's
+``while``, are not listed; a latent-attention model's ONE pool comes back
+as the array itself and IS listed, once a ``kv_page_write``, aliased all
+the same: read the temporaries), so a sound program lists NOTHING of the pool's
 or of a plane's shape, "pool" says ``kernel write`` for every chunk shape,
 and the temporaries stay far under the pool's size.  What must not be
 there: an op of a PLANE's shape ``[N, H, bt, D]`` (a layer sliced out of
@@ -59,6 +64,7 @@ from pathlib import Path
 from unittest import mock
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 GIB = float(1 << 30)
 
@@ -71,7 +77,7 @@ def compile_mixed_step(model: str, blocks: int, args, segments=None):
     import jax.numpy as jnp
     from jax.experimental import topologies
 
-    from distributed_inference_demo_tpu.models import get_model_config
+    from bench_config import model_config_for
     from distributed_inference_demo_tpu.models.decoder import (
         init_full_params)
     from distributed_inference_demo_tpu.ops import quant
@@ -82,7 +88,7 @@ def compile_mixed_step(model: str, blocks: int, args, segments=None):
     topo = topologies.get_topology_desc(topology_name="v5e:2x2",
                                         platform="tpu")
     sharding = jax.sharding.SingleDeviceSharding(topo.devices[0])
-    cfg = get_model_config(model)
+    cfg = model_config_for(model)
     # a name without a quant suffix is served at its own dtype
     # (quantize=True alone would make int8 leaves of a bf16 model)
     params = jax.eval_shape(

@@ -43,6 +43,18 @@ served path's bf16 activations flip those whatever the router's own
 precision, and a flip between two experts of near-equal weight moves a
 log-probability by less than the bf16 activations already do.
 
+A latent-attention model (family ``deepseek_v3``: ``--model
+kanana-2-30b-a3b-bf16``, a benchmark configuration's name) is read twice,
+short (``--batch 2 --prompt 512 --steps 16``) and long (``--batch 1
+--prompt 8192 --steps 16``: chunked prefill, then decode over 65 pages a
+row; the reference's attention runs in query blocks of 512), and held
+against its own lower precision, ``--bf16-softmax-state`` (the kernels'
+online-softmax state between pages in bf16): ``READINGS_LATENT``.  There
+``--bf16-router`` reads what the served path reads to the last digit: the
+rows and the router's matrix are bf16 as stored, so the float32 matmul
+and the bf16 one differ only in a rounding of the result that the
+compiler elides before the widening.
+
 One ``MODEL_PARITY {json}`` line, exit code 1 if a limit is passed.
 """
 
@@ -58,6 +70,7 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
 sys.path.insert(0, str(ROOT / "benchmark"))
+sys.path.insert(0, str(ROOT / "tools"))
 
 READINGS = {   # max_over_vocab_mean, max_over_vocab_max, mean_abs a seed
     "served (bf16 pages, float32 router)": [
@@ -73,6 +86,29 @@ READINGS = {   # max_over_vocab_mean, max_over_vocab_max, mean_abs a seed
 TOL_MEAN = 0.066    # between 0.0580 (sound) and 0.0737 (int8 pages)
 TOL_MAX = 0.25      # 2.3 x the largest sound maximum: a gross fault
 
+READINGS_LATENT = {  # kanana-2-30b-a3b-bf16, TPU v5e; my chip runs, PR 44
+    "served, 2 x 512 + 16, seeds 0, 1": [
+        (0.0684, 0.090, 0.01209), (0.0650, 0.083, 0.01116)],
+    "served, 1 x 8192 + 16, seeds 0, 1, 2": [
+        (0.0727, 0.091, 0.01270), (0.0694, 0.080, 0.01234),
+        (0.0763, 0.105, 0.01351)],
+    "--bf16-softmax-state, 1 x 8192 + 16, seeds 0, 1, 2": [
+        (0.1473, 0.176, 0.02590), (0.1403, 0.166, 0.02495),
+        (0.1509, 0.185, 0.02617)],
+    # an earlier tree (routed down projections seeded at twice the scale),
+    # seed 0: four pages cannot show the state's precision, and the bf16
+    # router reads what the served path reads, digit for digit
+    "served / --bf16-softmax-state / --bf16-router, 2 x 512 + 16": [
+        (0.0815, 0.173, 0.01424), (0.0889, 0.195, 0.01543),
+        (0.0815, 0.173, 0.01424)],
+}
+# limits a family's own readings set: (mean, max).  deepseek_v3: the mean
+# between 0.0763 (sound, long) and 0.1403 (bf16 state, long), 1.3-1.4 x
+# room on both sides; the maximum is every family's, 2.4 x the largest
+# sound one here: a gross fault (the lower precision is refused by the
+# mean, not by each limit: its maxima read 0.166-0.185)
+FAMILY_TOL = {"deepseek_v3": (0.10, TOL_MAX)}
+
 
 def seeded_ids(seed: int, n: int, vocab: int):
     import numpy as np
@@ -82,23 +118,31 @@ def seeded_ids(seed: int, n: int, vocab: int):
 
 def bf16_router():
     """A router as a careless port would write it: the matmul on the
-    activations as they come (bf16) at the default precision, the softmax
-    over its bf16 result.  Swapped in for ``decoder._route`` by
-    ``--bf16-router`` (what it reads is under ``READINGS``)."""
-    import jax
+    activations as they come (bf16) at the default precision, the scores
+    (softmax or sigmoid) over its bf16 result.  Swapped in for
+    ``decoder._router_logits`` by ``--bf16-router`` (what it reads is
+    under ``READINGS``)."""
     import jax.numpy as jnp
 
     from distributed_inference_demo_tpu.models import decoder
 
-    def route(cfg, lp, h):
-        logits = jnp.einsum("th,he->te", h, lp["router"].astype(h.dtype))
-        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-        w, e = jax.lax.top_k(probs, cfg.experts_per_token)
-        if cfg.norm_topk_prob:
-            w = w / jnp.sum(w, axis=-1, keepdims=True)
-        return w, e.astype(jnp.int32)
+    def logits(h, w):
+        return jnp.einsum("th,he->te", h, w.astype(h.dtype)).astype(
+            jnp.float32)
 
-    decoder._route = route
+    decoder._router_logits = logits
+
+
+def bf16_softmax_state():
+    """The latent kernels' online-softmax state (running maximum, sum and
+    output between pages) kept in bf16, where the program keeps float32:
+    ``--bf16-softmax-state``, the lower precision a latent-attention
+    model's long-context reading is held against."""
+    import jax.numpy as jnp
+
+    from distributed_inference_demo_tpu.ops import latent_attention
+
+    latent_attention._STATE_DTYPE = jnp.bfloat16
 
 
 def served_logprobs(cfg, params, prompts, args):
@@ -123,9 +167,9 @@ def served_logprobs(cfg, params, prompts, args):
     fwd, bind, _ = make_paged_forward_seam(
         cfg, StageSpec(0, 1, 0, cfg.num_layers), None, params, bt,
         record=record)
-    pk, pv = alloc_kv_pool(
-        (cfg.kv_planes, b * W, cfg.num_kv_heads, bt, cfg.head_dim),
-        args.kv_dtype, cfg.dtype)
+    heads, width = cfg.kv_page_shape
+    pk, pv = alloc_kv_pool((cfg.kv_planes, b * W, heads, bt, width),
+                           args.kv_dtype, cfg.dtype, streams=cfg.kv_streams)
     tables = jnp.arange(b * W, dtype=jnp.int32).reshape(b, W)
 
     @jax.jit
@@ -187,10 +231,16 @@ def reference_logprobs(cfg, params, ids, n_prompt: int):
         p = {key: reference._f32(jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
             v)) for key, v in layers.items()}
-        mid = layer_eq(dict(p, w_down=jnp.zeros_like(p["w_down"])), x)
+        zeroed = {key: jnp.zeros_like(p[key])
+                  for key in ("w_down", "ws_down") if key in p}
+        mid = layer_eq(dict(p, **zeroed), x)
         h = reference._rms_norm(mid[n_prompt - 1:], p["mlp_norm_w"],
                                 cfg.norm_eps)
-        s = jnp.sort(jax.nn.softmax(h @ p["router"], -1), -1)
+        if cfg.router_scoring == "sigmoid":   # chosen by score + bias
+            s = jax.nn.sigmoid(h @ p["router"]) + p.get("router_bias", 0.0)
+        else:
+            s = jax.nn.softmax(h @ p["router"], -1)
+        s = jnp.sort(s, -1)
         return s[:, E - k] - s[:, E - k - 1]
 
     margins = []
@@ -223,20 +273,24 @@ def main(argv=None) -> int:
                     choices=("bf16", "int8"))
     ap.add_argument("--bf16-router", action="store_true",
                     help="swap in a bf16 router (see READINGS)")
+    ap.add_argument("--bf16-softmax-state", action="store_true",
+                    help="latent kernels: online-softmax state in bf16")
     args = ap.parse_args(argv)
     from distributed_inference_demo_tpu.cli import configure_compile_cache
     configure_compile_cache()
     import jax
     import numpy as np
 
-    from distributed_inference_demo_tpu.models import get_model_config
+    from bench_config import model_config_for
     from distributed_inference_demo_tpu.models.decoder import (
         init_full_params)
 
     if args.bf16_router:
         bf16_router()
+    if args.bf16_softmax_state:
+        bf16_softmax_state()
     dev = jax.devices()[0]
-    cfg = get_model_config(args.model)
+    cfg = model_config_for(args.model)
     t0 = time.monotonic()
     params = init_full_params(jax.random.PRNGKey(args.seed), cfg,
                               quantize=cfg.quantization != "none")
@@ -258,23 +312,26 @@ def main(argv=None) -> int:
         # position's is never fed back: take its argmax)
         chosen = list(toks[r]) + [int(served[r, -1].argmax())]
         own.extend(float(err[i, t]) for i, t in enumerate(chosen))
+    tol_mean, tol_max = FAMILY_TOL.get(cfg.family, (TOL_MEAN, TOL_MAX))
     row = {"model": args.model, "platform": dev.platform,
            "device_kind": dev.device_kind, "kv_dtype": args.kv_dtype,
-           "bf16_router": args.bf16_router, "batch": args.batch,
+           "bf16_router": args.bf16_router,
+           "bf16_softmax_state": args.bf16_softmax_state,
+           "batch": args.batch,
            "prompt": args.prompt, "steps": args.steps,
            "positions": len(worst), "paths": paths,
            "max_over_vocab_max": max(worst),
            "max_over_vocab_mean": sum(worst) / len(worst),
            "mean_abs": sum(means) / len(means),
            "own_token_max": max(own), "own_token_mean": sum(own) / len(own),
-           "tol_mean": TOL_MEAN, "tol_max": TOL_MAX,
+           "tol_mean": tol_mean, "tol_max": tol_max,
            "router_pairs": len(margins),
            "router_margin_under_1e-3": sum(m < 1e-3 for m in margins),
            "router_margin_under_1e-4": sum(m < 1e-4 for m in margins),
            "served_s": round(t_served, 1),
            "total_s": round(time.monotonic() - t0, 1)}
-    row["ok"] = bool(row["max_over_vocab_mean"] <= TOL_MEAN
-                     and row["max_over_vocab_max"] <= TOL_MAX)
+    row["ok"] = bool(row["max_over_vocab_mean"] <= tol_mean
+                     and row["max_over_vocab_max"] <= tol_max)
     print("MODEL_PARITY " + json.dumps(row), flush=True)
     return 0 if row["ok"] else 1
 
