@@ -1313,6 +1313,7 @@ class ContinuousBatchingEngine:
         # ... and its slab is told the tokens each segment holds, an
         # eighth segment array (`_blank_segments`)
         self._seg_arrays = 8 if moe else 7
+        self._set_row = jax.jit(lambda rows, r, row: rows.at[r].set(row))
         # a looped model counts its passes (tracing.LoopCounters); a
         # one-pass model's record and /stats are as they were
         loop = cfg.ut_steps > 1 and self._mixed_step is not None
@@ -3231,11 +3232,15 @@ class ContinuousBatchingEngine:
                 why = self._ahead_refusal(flight)
                 if why is None:
                     ahead = self._launch_mixed(nxt)
-                    with trace.ahead("ahead_drain"):
-                        record = self._drain_mixed(flight)
-                    trace.commit(phases=flight.phases, **record)
-                    flight = ahead
-                    continue
+                    if ahead is not None:
+                        with trace.ahead("ahead_drain"):
+                            record = self._drain_mixed(flight)
+                        trace.commit(phases=flight.phases, **record)
+                        flight = ahead
+                        continue
+                    # its slab failed its requests and it never reached
+                    # the device: `flight` is drained as after a miss
+                    why = "other"
                 trace.enter("drain")
             else:
                 flight.t_done = trace.enter("drain")
@@ -3322,6 +3327,7 @@ class ContinuousBatchingEngine:
         packed = []          # (row, admission, is_final, slot)
         advance = []         # (admission, its start, its suffix) after
         rewound = []         # requests whose §23 rewind this spends
+        keys = []            # (row, a final's sampling key on the device)
         free = list(free)
         chunks = 0
         prefill_tokens = 0
@@ -3370,7 +3376,7 @@ class ContinuousBatchingEngine:
                 rng = jax.random.PRNGKey(self._seed)
                 rewound.append(req)
             rng, sub = jax.random.split(rng)
-            seg_keys[r] = np.asarray(sub)
+            keys.append((r, sub))     # its row of `seg_keys`: _put_slab
             packed.append((r, a, True, slot))
             prefill_tokens += n
             prefill_kv_tokens += n * start + n * (n + 1) // 2
@@ -3428,7 +3434,8 @@ class ContinuousBatchingEngine:
         return types.SimpleNamespace(
             rows=rows, packed=packed, advance=advance, rewound=rewound,
             finals=finals, chunks=chunks, rng=rng, dec_sub=dec_sub,
-            seg=seg, slab_rows=slab_segs * C,
+            adms_left=len(adms) - len(finals),
+            seg=seg, keys=keys, slab_rows=slab_segs * C,
             tables=tables, active_mask=active_mask,
             budget_vec=budget_vec,
             prefill_tokens=prefill_tokens,
@@ -3436,6 +3443,19 @@ class ContinuousBatchingEngine:
             live0=live0, kv_tokens=kv_tokens, spec_mixed=spec_mixed,
             k_vec=k_vec, k_disp=k_disp, num_rounds=num_rounds,
             dev=None, how=None, ahead_s=0.0)
+
+    def _put_slab(self, plan, put) -> tuple:
+        """The plan's segment arrays on the device, each final's
+        sampling key written into its row of ``seg_keys`` there.  The
+        key is split on the device (``_pack_mixed``), and the device
+        runs its programs in order: a plan made under an execution that
+        read the key back to the host would wait for that execution's
+        end, where one more small program in the queue waits for
+        nobody."""
+        seg = [put(x) for x in plan.seg]
+        for r, sub in plan.keys:
+            seg[6] = self._set_row(seg[6], np.int32(r), sub)
+        return tuple(seg)
 
     def _put_mixed(self, plan) -> tuple:
         """The plan's arrays on the device, as ``mixed_step`` takes them
@@ -3449,7 +3469,7 @@ class ContinuousBatchingEngine:
                 # the mesh holds a copy, so the call spreads nothing
                 put = partial(jax.device_put, device=self._replicated)
             plan.dev = (
-                tuple(put(x) for x in plan.seg) if plan.slab_rows else None,
+                self._put_slab(plan, put) if plan.slab_rows else None,
                 put(plan.tables), put(plan.active_mask),
                 put(self._eos_scalar()), put(plan.budget_vec),
                 put(plan.dec_sub))
@@ -3489,6 +3509,9 @@ class ContinuousBatchingEngine:
             plan = copy.copy(idle)
             plan.seg = self._slab_of(blank, r)
             plan.slab_rows = r * self.prefill_chunk
+            # ... and the program that writes a final's key into a slab
+            # of this many rows (row 0's slot is `B`: nothing reads it)
+            plan.keys = [(0, idle.dec_sub)] if r else []
             self._call_mixed_step(plan)
         jax.block_until_ready(self._last_tok)
 
@@ -3543,7 +3566,7 @@ class ContinuousBatchingEngine:
                 for o in flight.out:
                     o.copy_to_host_async()
             else:
-                seg_dev = tuple(jnp.asarray(x) for x in plan.seg)
+                seg_dev = self._put_slab(plan, jnp.asarray)
                 if self._mixed_spec_step is not None:
                     (self._pk, self._pv, self._dpk, self._dpv,
                      self._lengths, self._last_tok,
@@ -3604,24 +3627,39 @@ class ContinuousBatchingEngine:
     def _plan_ahead(self, flight) -> tuple:
         """While ``flight`` executes: the next dispatch, packed from the
         state ``flight`` will leave if no row of it ends by ``eos``, its
-        arrays already on the device; ``(plan, None)``, or ``(None,
-        why)`` where the engine can see now that the next dispatch will
-        not be that one.  Commits nothing.
+        arrays (its slab's too) already on the device; ``(plan, None)``,
+        or ``(None, why)`` where the engine can see now that the next
+        dispatch will not be that one.  Commits nothing.
 
-        The projection: a row holds ``min(steps, remaining)`` more
-        tokens after ``flight``, where ``steps`` is the fused loop's
-        count (it runs while any row has budget left, ``decode_block``
-        at most); a final installed by ``flight`` holds its token #1 and
-        then the same; a row whose budget ends in ``flight`` is gone,
-        its table row sentinel (``_record_token`` + ``_sentinel_slot``).
+        The projection.  Rows: a row holds ``min(steps, remaining)``
+        more tokens after ``flight``, where ``steps`` is the fused
+        loop's count (it runs while any row has budget left,
+        ``decode_block`` at most); a final installed by ``flight`` holds
+        its token #1 and then the same; a row whose budget ends in
+        ``flight`` is gone, its table row sentinel (``_record_token`` +
+        ``_sentinel_slot``).  Admissions: ``flight``'s launch has moved
+        each to where its chunks in ``flight`` end, and its drain takes
+        out those whose final it packed, so they are ``_adms`` less
+        those, in order, and ``_pack_mixed`` gives them their next
+        chunks and finals as the gap's pack would.  Free slots: the
+        slots no row holds after ``flight``, those of rows that end in
+        it included, as the gap's pack would see them; but such a slot
+        and its pages come free only in ``flight``'s drain, which on a
+        hit runs after this plan's launch has written the slot's table
+        row, so a plan whose final took one is turned away (``finish``)
+        and the gap packs it after the drain.
+
         What stays on the old order, by what the engine is or holds: the
         speculative programs (their pack reads what the drain learns),
         a dispatch the profiler samples (its end must time one
-        execution), an admission still in flight after ``flight`` (its
-        next chunk or parked final), a resume replay (its drain may fail
-        the row), and any wait in ``_pending`` that ``flight``'s drain
-        could end: a final it installs (``store_shared`` moves the
-        tree's epoch) or a row it ends (pages and a slot come free)."""
+        execution), a resume replay among the rows or the admissions
+        (its drain may fail the row), and a request in ``_pending`` that
+        the intake skipped on a hit would act on: a row that ends in
+        ``flight`` frees it a slot and pages; where the cap on
+        admissions (fewer than the free slots, one at least) lets the
+        intake try it, a final that ``flight`` installs
+        (``store_shared`` moves the tree's epoch) or a page gate that
+        is open already."""
         plan = flight.plan
         if plan.spec_mixed or flight.t0 is not None:
             return None, "other"
@@ -3630,13 +3668,13 @@ class ContinuousBatchingEngine:
             for req, slot in plan.finals:
                 rows[slot] = (req, 1)
             live = [s for s in rows if s is not None]
-            news = self._ahead_news(req for req, _ in live)
+            done = {id(req) for req, _ in plan.finals}
+            adms = [a for a in self._adms if id(a["req"]) not in done]
+            held = [req for req, _ in live] + [a["req"] for a in adms]
+            news = self._ahead_news(held)
             if news is not None:
                 return None, news
-            done = {id(req) for req, _ in plan.finals}
-            if (any(id(a["req"]) not in done for a in self._adms)
-                    or any(getattr(req, "_suppress", None)
-                           for req, _ in live)):
+            if any(getattr(req, "_suppress", None) for req in held):
                 return None, "other"
             steps = min(self.decode_block,
                         max((req.max_new - k for req, k in live),
@@ -3650,42 +3688,51 @@ class ContinuousBatchingEngine:
                 rows[i] = (req, k) if k < req.max_new else None
                 if rows[i] is None:
                     ended.append(i)
-            if not any(rows):
+            if not any(rows) and not adms:
                 return None, "finish"
+            free = [i for i, s in enumerate(rows) if s is None]
             if self._pending:
-                if ended or plan.finals:
+                if ended:
                     return None, "finish"
-                # what is left waits for pages behind `_reserve_pages`'
-                # retry gate, which opens when the pool changes: the
-                # intake would turn it away again and touch nothing
-                pool = (self.kv_cache.epoch, self.kv_cache.free_blocks)
                 if any(getattr(req, "_resume", None) is not None
-                       or getattr(req, "_pkv_blocked", None) != pool
                        for req in self._pending):
                     return None, "other"
+                if len(adms) < max(1, len(free)):
+                    if plan.finals:
+                        return None, "finish"
+                    # what is left waits for pages behind
+                    # `_reserve_pages`' retry gate, which opens when the
+                    # pool changes: the intake would turn it away again
+                    # and touch nothing
+                    pool = (self.kv_cache.epoch, self.kv_cache.free_blocks)
+                    if any(getattr(req, "_pkv_blocked", None) != pool
+                           for req in self._pending):
+                        return None, "other"
             self.anomaly.observe(self.stats)
             self._sample_hbm()
             tables = self._tables.copy()
             tables[ended] = self._page_sentinel
-            nxt = self._pack_mixed(rows, [], [], self._rng, tables)
+            nxt = self._pack_mixed(rows, adms, free, self._rng, tables)
+            if any(slot in ended for _, slot in nxt.finals):
+                return None, "finish"
             self._put_mixed(nxt)
         nxt.how, nxt.ahead_s = "hit", spent[0]
         flight.steps_ahead = steps
         return nxt, None
 
-    def _ahead_news(self, live) -> Optional[str]:
+    def _ahead_news(self, held) -> Optional[str]:
         """What has reached the scheduler that the next intake would act
         on, as a reason of ``tracing.AHEAD_MISS_REASONS``, or None: an
         export asked, a request or wake in the queue, the engine
-        closing, a cancel among the ``live`` rows' requests or those in
-        ``_pending``."""
+        closing, a cancel among the requests ``held`` (the rows' and the
+        admissions') or those in ``_pending``."""
         if self._export_q:
             return "export"
         if not self._queue.empty():
             return "arrival"
         if not self._running:
             return "other"
-        if (any(req.cancelled for req in live)
+        if (any(req.cancelled for req in held)
                 or any(req.cancelled for req in self._pending)):
             return "cancel"
         return None
@@ -3697,11 +3744,12 @@ class ContinuousBatchingEngine:
         the projection assumed (the projected step count, no ``eos``
         among the tokens a row keeps) and nothing reached the scheduler
         meanwhile: no arrival or wake in the queue, no export asked, no
-        request cancelled, the engine not closing."""
+        request cancelled (a row's, an admission's, a waiting one), the
+        engine not closing."""
         plan = flight.plan
         news = self._ahead_news(
             [s[0] for s in plan.rows if s is not None]
-            + [req for req, _ in plan.finals])
+            + [a["req"] for a in self._adms])
         if news is not None:
             return news
         if flight.steps != flight.steps_ahead:
@@ -3835,7 +3883,7 @@ class ContinuousBatchingEngine:
                 if self.spec_adaptive:
                     self._update_spec_krow(live0, k_vec, ns_np,
                                            num_rounds)
-            if num_rounds > 0 and self._adms:
+            if num_rounds > 0 and plan.adms_left:
                 cs["interleaved_steps"] += 1
             return record
         toks, lps = flight.out[2:4]
@@ -3854,7 +3902,7 @@ class ContinuousBatchingEngine:
             self._record_row_blocks(
                 np.asarray(toks), np.full(len(self._slots), steps),
                 np.asarray(lps))
-        if steps > 0 and self._adms:
+        if steps > 0 and plan.adms_left:
             cs["interleaved_steps"] += 1
         return record
 

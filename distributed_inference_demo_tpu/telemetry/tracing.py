@@ -373,6 +373,7 @@ class DispatchTrace:
         self.spans = dict.fromkeys(DISPATCH_SPANS, (0, 0.0, 0.0, 0.0))
         self._next = dict.fromkeys(_OWN, 0.0)
         self._last = dict.fromkeys(_OWN, 0.0)
+        self._prior = self._last
         self._into = self._next
         self.idle_wait_s = 0.0
         self.decode_only = 0
@@ -382,7 +383,7 @@ class DispatchTrace:
         self.slab_rows = 0
         self.queue_wait_ms_sum = 0.0
         self.queue_wait_count = 0
-        self.ahead_hits = 0
+        self.ahead_hits = self.ahead_hits_slab = 0
         self.ahead_misses = dict.fromkeys(AHEAD_MISS_REASONS, 0)
         self.ahead_first = 0
         self.late_reads = 0
@@ -446,6 +447,7 @@ class DispatchTrace:
 
     def _cut(self) -> None:
         self.launched += 1
+        self._prior = self._last
         self._last = self._next
         self._next = dict.fromkeys(_OWN, 0.0)
 
@@ -472,11 +474,14 @@ class DispatchTrace:
 
     def abandon(self) -> None:
         """The dispatch launched last never reached the device: its
-        seconds go on to the next record."""
+        seconds go on to the next record, and the one launched before it
+        is the last one launched again (still in flight if this one was
+        prepared under it: ``drain`` and its commit then find its
+        seconds)."""
         self.launched -= 1
         for p, v in self._last.items():
             self._next[p] += v
-        self._last = dict.fromkeys(_OWN, 0.0)
+        self._last = self._prior
         if self._phase in _LAUNCHED_PHASES:
             self._into = self._next
 
@@ -572,10 +577,12 @@ class DispatchTrace:
         ``phases``: its own seconds (``launched_phases`` as they were
         when the NEXT dispatch had not been launched yet; by default the
         last launched one's).  ``how``: ``"hit"`` (launched as prepared
-        under its predecessor's execution, ``ahead`` seconds of it), one
-        of :data:`AHEAD_MISS_REASONS` (it followed its predecessor at
-        once and was packed in the gap), or ``"first"`` (nothing was
-        executing before it).  Returns its ``seq``."""
+        under its predecessor's execution, ``ahead`` seconds of it;
+        counted in ``ahead_hits``, and in ``ahead_hits_slab`` too if it
+        carried a segment), one of :data:`AHEAD_MISS_REASONS` (it
+        followed its predecessor at once and was packed in the gap), or
+        ``"first"`` (nothing was executing before it).  Returns its
+        ``seq``."""
         if phases is None:
             self.leave()
             if self.launched == self.seq:    # no phase of a launch seen
@@ -598,6 +605,7 @@ class DispatchTrace:
         self.slab_rows += slab_rows
         if how == "hit":
             self.ahead_hits += 1
+            self.ahead_hits_slab += bool(segments)
         elif how == "first":
             self.ahead_first += 1
         elif how is not None:
@@ -622,6 +630,7 @@ class DispatchTrace:
                 "queue_wait_ms_sum": round(self.queue_wait_ms_sum, 3),
                 "queue_wait_count": self.queue_wait_count,
                 "ahead_hits": self.ahead_hits,
+                "ahead_hits_slab": self.ahead_hits_slab,
                 "ahead_misses": dict(self.ahead_misses),
                 "ahead_first": self.ahead_first,
                 "late_reads": self.late_reads,
