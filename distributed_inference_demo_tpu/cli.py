@@ -314,7 +314,8 @@ def cmd_serve(args) -> int:
         import jax
 
         from .comm.transport import ZmqTransport
-        from .models.base import (require_kv_pair, require_single_pass,
+        from .models.base import (require_kv_pair, require_one_kind,
+                                  require_single_pass,
                                   split_layer_ranges)
         from .models.registry import get_model_config
         from .runtime.elastic import ElasticHeader, ElasticStageRuntime
@@ -323,6 +324,7 @@ def cmd_serve(args) -> int:
         try:
             require_single_pass(cfg, "--chain (a pipeline of stages)")
             require_kv_pair(cfg, "--chain (a pipeline of stages)")
+            require_one_kind(cfg, "--chain (a pipeline of stages)")
         except ValueError as e:
             print(e, file=sys.stderr)
             return 1

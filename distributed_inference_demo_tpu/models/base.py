@@ -28,6 +28,50 @@ import jax
 import jax.numpy as jnp
 
 
+@dataclass(frozen=True)
+class BlockKind:
+    """What one KIND of block of a period has of its own: its attention
+    (``attn`` "full", or "window" over the last ``window`` tokens: query
+    ``i`` sees key ``j`` iff ``0 <= i - j < window``), its query heads,
+    its rope (``rope_theta``; ``rotary_share`` of a head's channels turn,
+    the first ones, rotate-half within them; ``yarn`` = ``(factor,
+    original positions, beta_fast, beta_slow, attention_factor)`` or
+    empty: ``ops.rope.yarn_frequencies``) and its output gate ("none", or
+    "per-head": each head's output times the sigmoid of a linear map of
+    the block's normed input, one scalar a head, before ``wo``).  Built
+    from a JSON object (``ModelConfig.period``); hashable."""
+
+    attn: str = "full"
+    window: int = 0
+    num_heads: int = 0
+    rope_theta: float = 10000.0
+    rotary_share: float = 1.0
+    yarn: tuple = ()
+    gate: str = "none"
+
+    def __post_init__(self):
+        if self.attn not in ("full", "window"):
+            raise ValueError(f"a block kind's attn is 'full' or 'window', "
+                             f"got {self.attn!r}")
+        if (self.attn == "window") != (self.window > 0):
+            raise ValueError("a window kind states its window, a full "
+                             "kind none")
+        if self.gate not in ("none", "per-head"):
+            raise ValueError(f"unknown gate {self.gate!r}")
+        yarn = self.yarn
+        if isinstance(yarn, dict):
+            yarn = (yarn["factor"], yarn["original_max_position_embeddings"],
+                    yarn["beta_fast"], yarn["beta_slow"],
+                    yarn["attention_factor"])
+        object.__setattr__(self, "yarn", tuple(float(v) for v in yarn))
+        object.__setattr__(self, "rope_theta", float(self.rope_theta))
+        object.__setattr__(self, "rotary_share", float(self.rotary_share))
+
+    @staticmethod
+    def of(spec) -> "BlockKind":
+        return spec if isinstance(spec, BlockKind) else BlockKind(**spec)
+
+
 @partial(jax.tree_util.register_dataclass,
          data_fields=[],
          meta_fields=["family", "vocab_size", "hidden_size", "num_layers",
@@ -42,7 +86,8 @@ import jax.numpy as jnp
                       "qk_rope_head_dim", "v_head_dim", "lead_dense_layers",
                       "lead_intermediate_size", "num_shared_experts",
                       "router_scoring", "router_bias",
-                      "routed_scaling_factor"])
+                      "routed_scaling_factor", "period", "lead_kind",
+                      "experts_held"])
 @dataclass(frozen=True)
 class ModelConfig:
     """Static, hashable architecture description shared by all model families.
@@ -129,6 +174,31 @@ class ModelConfig:
     router_scoring: str = "softmax"
     router_bias: bool = False
     routed_scaling_factor: float = 1.0
+    # a PERIOD of unlike blocks (docs/DESIGN.md section 25): the repeated
+    # stack is ``num_layers`` repeats of these kinds in order, so
+    # ``num_layers`` counts repeats and a model of one kind of block (the
+    # empty period: its attention is the flat fields above) counts what it
+    # always did.  Each entry a :class:`BlockKind` or the JSON object of
+    # one; ``lead_kind`` is the kind of the leading dense blocks.
+    # ``StageParams.layers`` then holds one stack a kind, ``<leaf>.<kind
+    # name>`` of shape ``[repeats, blocks of that kind in a period, ...]``
+    period: tuple = ()
+    lead_kind: Optional[BlockKind] = None
+    # this chip's share of the routed experts, ``(held, first)``: the
+    # router scores all ``num_experts``, the expert stacks hold experts
+    # ``[first, first + held)`` and a row routed elsewhere enters no
+    # group; what the absent experts would add is left out (no psum, no
+    # stand-in).  Empty: every expert is here
+    experts_held: tuple = ()
+
+    def __post_init__(self):
+        object.__setattr__(self, "period",
+                           tuple(BlockKind.of(k) for k in self.period))
+        if self.lead_kind is not None:
+            object.__setattr__(self, "lead_kind",
+                               BlockKind.of(self.lead_kind))
+        object.__setattr__(self, "experts_held",
+                           tuple(int(v) for v in self.experts_held))
 
     @property
     def dtype(self) -> jnp.dtype:
@@ -137,8 +207,77 @@ class ModelConfig:
     @property
     def total_layers(self) -> int:
         """Every block a token passes: the leading dense ones and the
-        repeated stack."""
-        return self.lead_dense_layers + self.num_layers
+        repeated stack (``num_layers`` repeats of the period)."""
+        return (self.lead_dense_layers
+                + self.num_layers * max(1, len(self.period)))
+
+    @property
+    def experts_here(self) -> int:
+        """Routed experts whose matrices this chip holds."""
+        return self.experts_held[0] if self.experts_held else self.num_experts
+
+    @property
+    def kinds(self) -> tuple:
+        """The period's distinct kinds as ``(name, kind, positions)``, in
+        order of first appearance: ``positions`` the kind's places in the
+        period, ``name`` its ``attn`` (with the first place appended where
+        two kinds share one).  The parameter stacks are named by it."""
+        seen = []
+        for p, k in enumerate(self.period):
+            for entry in seen:
+                if entry[1] == k:
+                    entry[2].append(p)
+                    break
+            else:
+                seen.append([k.attn, k, [p]])
+        names = [e[0] for e in seen]
+        return tuple((n if names.count(n) == 1 else f"{n}{pos[0]}", k,
+                      tuple(pos)) for n, k, pos in seen)
+
+    @property
+    def cache_kinds(self) -> tuple:
+        """The cache spec by block kind, ``(window, planes)`` a POOL: how
+        many tokens back a block of the pool reads (0 = all) and the
+        planes a token holds there.  Blocks that read alike share a pool,
+        the full kind's (the one that fills) first; a model of one kind of
+        block has one entry, ``(0, kv_planes)``."""
+        if not self.period:
+            return ((0, self.kv_planes),)
+        count = {}
+        if self.lead_kind is not None and self.lead_dense_layers:
+            count[self.lead_kind.window] = self.lead_dense_layers
+        for k in self.period:
+            count[k.window] = count.get(k.window, 0) + self.num_layers
+        return tuple(sorted(count.items()))
+
+    def plane_of(self, block: int) -> tuple:
+        """``(pool, plane)`` of block ``block`` (the leading blocks first,
+        then the repeats of the period in order): the index of its pool in
+        ``cache_kinds`` and its plane there.  Within a pool the leading
+        blocks' planes come first, then repeat by repeat."""
+        windows = [w for w, _ in self.cache_kinds]
+        lead = self.lead_dense_layers
+        if block < lead:
+            return windows.index(self.lead_kind.window), block
+        r, p = divmod(block - lead, len(self.period))
+        w = self.period[p].window
+        mine = [q for q, k in enumerate(self.period) if k.window == w]
+        base = lead if (self.lead_kind is not None
+                        and self.lead_kind.window == w) else 0
+        return windows.index(w), base + r * len(mine) + mine.index(p)
+
+    def of_kind(self, kind: BlockKind) -> "ModelConfig":
+        """The configuration of ONE block of ``kind``: its heads and rope
+        in the flat fields, the kind itself as a period of one."""
+        return self.replace(num_heads=kind.num_heads,
+                            rope_theta=kind.rope_theta, period=(kind,),
+                            lead_kind=None, lead_dense_layers=0,
+                            num_layers=1)
+
+    @property
+    def block_kind(self) -> Optional[BlockKind]:
+        """The one kind of a single-kind configuration (``of_kind``)."""
+        return self.period[0] if len(self.period) == 1 else None
 
     @property
     def kv_planes(self) -> int:
@@ -147,6 +286,12 @@ class ModelConfig:
         plane count (dense cache, page pool, host tier, exported
         blocks)."""
         return self.total_layers * self.ut_steps
+
+    @property
+    def mixed_kinds(self) -> bool:
+        """A period model: its blocks are of more than one kind (each with
+        its own heads, rope, mask and cache)."""
+        return bool(self.period)
 
     @property
     def latent_kv(self) -> bool:
@@ -243,6 +388,14 @@ class KVCache:
         # A latent-attention model's cache is ``keys`` alone, one row a
         # token; ``values`` holds no element
         heads, width = cfg.kv_page_shape
+        if cfg.period:
+            # one stack a pool (``cfg.cache_kinds``); dense, so a window
+            # kind keeps every token and its mask bounds the view
+            zeros = lambda: tuple(
+                jnp.zeros((planes, batch, heads, max_seq, width), dtype)
+                for _, planes in cfg.cache_kinds)
+            return KVCache(keys=zeros(), values=zeros(),
+                           length=jnp.zeros((), jnp.int32))
         shape = ((num_layers + cfg.lead_dense_layers) * cfg.ut_steps,
                  batch, heads, max_seq)
         return KVCache(
@@ -254,7 +407,7 @@ class KVCache:
 
     @property
     def max_seq(self) -> int:
-        return self.keys.shape[3]
+        return jax.tree.leaves(self.keys)[0].shape[3]
 
 
 def pad_cache_capacity(n: int) -> int:
@@ -312,6 +465,21 @@ def require_kv_pair(cfg: ModelConfig, what: str) -> None:
             f"pages (serve --batch-slots)")
 
 
+def require_one_kind(cfg: ModelConfig, what: str) -> None:
+    """Refuse a model of more than one kind of block (``period``) where
+    ``what`` is built for one page table, one pool and one head count for
+    the whole stack: called where such a thing is built, so the model is
+    refused in a sentence and never run wrongly."""
+    if cfg.mixed_kinds:
+        raise ValueError(
+            f"{what} does not support a model of more than one kind of "
+            f"block (family {cfg.family!r}, a period of "
+            f"{len(cfg.period)}): its blocks differ in heads, rope, mask "
+            f"and cache, and it is built for one of each. Serve it on one "
+            f"chip with bf16 pages through the mixed dispatch (serve "
+            f"--batch-slots --prefill-chunk --mixed-token-budget)")
+
+
 def require_single_pass(cfg: ModelConfig, what: str) -> None:
     """Refuse a looped model (``ut_steps > 1``) where ``what`` visits a
     layer once: a stage of a pipeline owns a layer range and would have
@@ -335,6 +503,7 @@ def slice_stage(full: StageParams, cfg: ModelConfig, spec: StageSpec) -> StagePa
     ONNX zips, realized as array slices.
     """
     if spec.num_stages > 1:
+        require_one_kind(cfg, "a pipeline of stages")
         require_single_pass(cfg, "a pipeline of stages")
         require_kv_pair(cfg, "a pipeline of stages")
     layers = jax.tree.map(lambda x: x[spec.layer_start:spec.layer_end], full.layers)

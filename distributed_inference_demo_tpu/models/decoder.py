@@ -37,9 +37,10 @@ from ..ops.quant import dense
 from ..ops.stacked import LayerOf
 from ..ops.norms import layer_norm, rms_norm
 from ..ops.latent_attention import latent_dense_attn
-from ..ops.rope import apply_rope, apply_rope_interleaved
+from ..ops.rope import (apply_rope, apply_rope_interleaved,
+                        apply_rope_kind)
 from .base import (KVCache, ModelConfig, StageParams, StageSpec,
-                   require_single_pass)
+                   require_one_kind, require_single_pass)
 
 
 # ---------------------------------------------------------------------------
@@ -146,6 +147,10 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
             "wo": big(keys[3], (L, nh * hd, H), dt),
             "mlp_norm_w": jnp.ones((L, H), dt),
         }
+    kind = cfg.block_kind
+    if kind is not None and kind.gate == "per-head":
+        # one scalar a head from the block's normed input (``_kv_attention``)
+        p["wg"] = _dense_init(keys[13], (L, H, nh), dt)
     if cfg.attn_layernorm:  # bloom: LayerNorm has bias; linears have bias
         p["attn_norm_b"] = jnp.zeros((L, H), dt)
         p["mlp_norm_b"] = jnp.zeros((L, H), dt)
@@ -171,8 +176,11 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
         p["attn_post_norm_w"] = jnp.full((L, H), gain, dt)
         p["mlp_post_norm_w"] = jnp.full((L, H), gain, dt)
     if cfg.num_experts > 0:  # mixtral / olmoe MoE
-        E = cfg.num_experts
-        p["router"] = _dense_init(keys[4], (L, H, E), dt)
+        # the router scores every expert; the stacks hold this chip's
+        # share of them (``experts_held``: all, for every model but one
+        # cut to a deployment's share)
+        E = cfg.experts_here
+        p["router"] = _dense_init(keys[4], (L, H, cfg.num_experts), dt)
         p["w_gate"] = big(keys[5], (L, E, H, I), dt)
         p["w_up"] = big(keys[6], (L, E, H, I), dt)
         # Routed down projections under a sigmoid router (deepseek_v3)
@@ -190,10 +198,15 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
         # 44), and no precision of the ROUTER mends it: the noise is in
         # the rows it reads.  A trained checkpoint's routing is decisive
         # and brings its own weights; at 1/32 one seeded expert's share
-        # of the stream (0.013) is what it is in olmoe's cell.
+        # of the stream (0.013) is what it is in olmoe's cell.  The same
+        # holds under a softmax router whose k weights are renormalised
+        # and scaled up (a period model's top-10 at 2.5: a quarter of the
+        # routed sum an expert).
         p["w_down"] = big(keys[7], (L, E, I, H), dt,
                           scale=(I ** -0.5 / 32
-                                 if cfg.router_scoring == "sigmoid" else None))
+                                 if cfg.router_scoring == "sigmoid"
+                                 or cfg.routed_scaling_factor > 1.0
+                                 else None))
         if cfg.router_bias:
             # non-zero, so that choosing by score + bias and weighing by
             # the score differ; float32 like the scores it is added to
@@ -220,8 +233,10 @@ def lead_block_config(cfg: ModelConfig) -> ModelConfig:
     """The configuration of a LEADING dense block: the model's attention,
     and a dense SwiGLU of width ``lead_intermediate_size`` where the
     repeated stack has its experts."""
+    if cfg.lead_kind is not None:       # a period model's leading kind
+        cfg = cfg.of_kind(cfg.lead_kind)
     return cfg.replace(num_experts=0, num_shared_experts=0,
-                       router_bias=False,
+                       router_bias=False, experts_held=(),
                        intermediate_size=cfg.lead_intermediate_size)
 
 
@@ -253,10 +268,34 @@ def init_full_params(rng: jax.Array, cfg: ModelConfig,
         lm_head = {}  # reuse embed["tokens"]
     else:
         lm_head = {"w": _dense_init(k_head, (cfg.hidden_size, cfg.vocab_size), dt)}
-    return StageParams(
-        layers=init_layer_params(k_layers, cfg, cfg.num_layers,
-                                 quantize=quantize),
-        embed=embed, final_norm=final_norm, lm_head=lm_head, lead=lead)
+    if cfg.period:
+        layers = init_period_params(k_layers, cfg, quantize=quantize)
+    else:
+        layers = init_layer_params(k_layers, cfg, cfg.num_layers,
+                                   quantize=quantize)
+    return StageParams(layers=layers, embed=embed, final_norm=final_norm,
+                       lm_head=lm_head, lead=lead)
+
+
+def init_period_params(rng: jax.Array, cfg: ModelConfig,
+                       quantize=False) -> dict:
+    """A period model's repeated stack: ONE stack a kind of block, every
+    leaf of it named ``<leaf>.<kind name>`` (``ModelConfig.kinds``) and
+    shaped ``[repeats, blocks of the kind in a period, ...]``.  The
+    leading axis of every leaf is the repeat of the period, so a slice of
+    the tree along it is whole periods (what a scan, a stage and the
+    benchmark's reference index)."""
+    R = cfg.num_layers
+    out = {}
+    for i, (name, kind, places) in enumerate(cfg.kinds):
+        n = len(places)
+        stack = init_layer_params(jax.random.fold_in(rng, 2 + i),
+                                  cfg.of_kind(kind), R * n,
+                                  quantize=quantize)
+        for leaf, a in stack.items():
+            out[f"{leaf}.{name}"] = jax.tree.map(
+                lambda x: x.reshape((R, n) + x.shape[1:]), a)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -364,6 +403,8 @@ def _route(cfg: ModelConfig, lp: dict, h: jnp.ndarray):
         weights, experts = jax.lax.top_k(probs, cfg.experts_per_token)
         if cfg.norm_topk_prob:
             weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        if cfg.routed_scaling_factor != 1.0:
+            weights = weights * cfg.routed_scaling_factor
     return weights, experts.astype(jnp.int32)
 
 
@@ -407,6 +448,22 @@ def _moe_routed(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
             flat = jnp.where(jnp.repeat(valid.reshape(T), k), flat, E)
         rows = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
         sizes = rows
+        share = bool(cfg.experts_held)
+        if share:
+            # this chip's share of a block's experts (``experts_held``):
+            # the branch below without its ``psum``.  Rows routed to the
+            # experts of the other chips enter no group, and what those
+            # experts would add is left out; ``rows`` counts the held
+            # experts' rows alone
+            if tp_axis is not None:
+                raise ValueError(
+                    "a chip's share of the experts (experts_held) and "
+                    "tensor parallelism both cut the expert stacks: "
+                    "serve the share on one chip")
+            e_local, e0 = cfg.experts_held
+            mine = (flat >= e0) & (flat < e0 + e_local)
+            flat = jnp.where(mine, flat - e0, e_local)
+            rows = sizes = rows[e0:e0 + e_local]
         if tp_axis is not None:
             e_local = lp["w_gate"].shape[0]  # quantized, LayerOf: .shape
             e0 = jax.lax.axis_index(tp_axis) * e_local
@@ -421,7 +478,7 @@ def _moe_routed(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
         hh = (jax.nn.silu(gate.astype(jnp.float32))
               * up.astype(jnp.float32)).astype(x.dtype)
         out = grouped_matmul(hh, lp["w_down"], sizes).astype(jnp.float32)
-        if tp_axis is not None or valid is not None:
+        if tp_axis is not None or valid is not None or share:
             # rows of other ranks' experts, and rows that hold no token,
             # belong to no group here
             out = jnp.where((jnp.arange(T * k) < jnp.sum(sizes))[:, None],
@@ -577,13 +634,26 @@ def _kv_attention(cfg: ModelConfig, lp: dict, h: jnp.ndarray, k_cache,
     k = k.reshape(b, s, nkv, hd)
     v = v.reshape(b, s, nkv, hd)
 
-    if cfg.use_rope:
+    kind = cfg.block_kind       # a period model's block: its own rope
+    if kind is not None:
+        q = apply_rope_kind(q, positions, kind.rope_theta,
+                            kind.rotary_share, kind.yarn)
+        k = apply_rope_kind(k, positions, kind.rope_theta,
+                            kind.rotary_share, kind.yarn)
+    elif cfg.use_rope:
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
 
     attn_fn = attn_impl if attn_impl is not None else _default_attn
     attn, k_cache, v_cache = attn_fn(
         q, k, v, k_cache, v_cache, positions, cache_start, slopes)
+    if kind is not None and kind.gate == "per-head":
+        # headwise output gate: a head's output times the sigmoid of one
+        # scalar, a linear map of the block's normed input, in float32
+        gate = jax.nn.sigmoid(
+            dense(h, lp["wg"], "bsh,hn->bsn").astype(jnp.float32))
+        attn = (attn.astype(jnp.float32) * gate[..., None]).astype(
+            attn.dtype)
     return attn.reshape(b, s, nh * hd), k_cache, v_cache
 
 
@@ -699,6 +769,115 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     return x + y, k_cache, v_cache
 
 
+def _window_attn(window: int):
+    """:func:`_default_attn` over a dense cache under a window: the cache
+    keeps every token (nothing is freed) and the mask bounds the view."""
+
+    def attn(q, k, v, k_cache, v_cache, positions, cache_start, slopes):
+        k_cache, v_cache = update_kv_cache(k_cache, v_cache, k, v,
+                                           cache_start)
+        out = attention(q, k_cache, v_cache, positions,
+                        cache_start + q.shape[1], slopes, window)
+        return out, k_cache, v_cache
+
+    return attn
+
+
+def _period_blocks(params: StageParams, cfg: ModelConfig, x, cache: KVCache,
+                   positions, cache_start, attn_impl, moe_stats, valid):
+    """The blocks of a PERIOD model (docs/DESIGN.md section 25): the
+    leading blocks, then a scan over the repeats whose body is one period,
+    its unlike blocks in order.  Returns ``(x, keys, values, rows)``.
+
+    ``cache.keys`` / ``cache.values`` hold one stack a POOL
+    (``cfg.cache_kinds``: blocks that read alike share one) and ride the
+    carry whole.  A block takes ``LayerOf(its pool, its plane)``, and its
+    kind's hook: ``attn_impl.for_pool`` over a page pool (the table of
+    that pool, the kind's window), a windowed :func:`_default_attn` over
+    a dense cache.  The parameter stacks are one a kind
+    (:func:`init_period_params`): the scan slices the small leaves by
+    repeat and the block indexes its place, the expert stacks stay whole
+    beside the scan (``LayerOf``, as in the one-kind scan)."""
+    lead, P, R = cfg.lead_dense_layers, len(cfg.period), cfg.num_layers
+    pools = len(cfg.cache_kinds)
+    paged = getattr(attn_impl, "stacked_cache", False)
+    if attn_impl is not None and not hasattr(attn_impl, "for_pool"):
+        raise ValueError(
+            "a model of more than one kind of block needs an attention "
+            "hook that knows its pools (ops.paged_attention."
+            "make_paged_attn_impl); this one serves one kind")
+
+    def hook(name, kind, pool):
+        if attn_impl is not None:
+            return attn_impl.for_pool(pool, pools, kind.window, name)
+        return _window_attn(kind.window) if kind.window else None
+
+    def block(block_cfg, lp, x, Ks, Vs, pool, plane, impl, stats):
+        k_of, v_of = LayerOf(Ks[pool], plane), LayerOf(Vs[pool], plane)
+        kc, vc = (k_of, v_of) if paged else (k_of.sliced(), v_of.sliced())
+        x, kc, vc, *rows = _layer(block_cfg, lp, x, kc, vc, positions,
+                                  cache_start, None, None, impl, None,
+                                  stats, valid)
+        K, V = ((kc.stack, vc.stack) if paged
+                else (k_of.updated(kc), v_of.updated(vc)))
+        swap = lambda t, a: t[:pool] + (a,) + t[pool + 1:]
+        return x, swap(Ks, K), swap(Vs, V), (rows[0] if rows else None)
+
+    Ks, Vs = tuple(cache.keys), tuple(cache.values)
+    # the leading blocks' paths are recorded under their kind's name where
+    # the period has that kind too
+    lead_name = next((n for n, k, _ in cfg.kinds if k == cfg.lead_kind),
+                     "lead")
+    for i in range(lead):
+        pool, plane = cfg.plane_of(i)
+        with jax.named_scope("lead_block"):
+            x, Ks, Vs, _ = block(
+                lead_block_config(cfg),
+                jax.tree.map(lambda a: a[i], params.lead), x, Ks, Vs, pool,
+                jnp.int32(plane), hook(lead_name, cfg.lead_kind, pool),
+                False)
+
+    # place p of the period: its kind's name and configuration, its index
+    # among the kind's places, its pool and (plane at repeat 0, planes a
+    # repeat adds in that pool)
+    places = []
+    for name, kind, at in cfg.kinds:
+        for j, p in enumerate(at):
+            pool, plane0 = cfg.plane_of(lead + p)
+            stride = cfg.plane_of(lead + P + p)[1] - plane0 if R > 1 else 0
+            places.append((p, name, kind, cfg.of_kind(kind), j, len(at),
+                           pool, plane0, stride))
+    places.sort()
+    whole = _EXPERT_STACKS if cfg.num_experts > 0 else ()
+    is_whole = lambda leaf: leaf.split(".")[0] in whole
+    scanned = {k: v for k, v in params.layers.items() if not is_whole(k)}
+    # [R, n, E, ...] -> [R n, E, ...]: the leading axes merge in place
+    stacks = {k: jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), v)
+              for k, v in params.layers.items() if is_whole(k)}
+
+    def body(carry, xs):
+        x, Ks, Vs = carry
+        leaves, r = xs
+        out_rows = []
+        for (_, name, kind, kcfg, j, n, pool, plane0, stride) in places:
+            tail = "." + name
+            lp = {k[:-len(tail)]: jax.tree.map(lambda a: a[j], v)
+                  for k, v in leaves.items() if k.endswith(tail)}
+            lp.update({k[:-len(tail)]: LayerOf(v, r * n + j)
+                       for k, v in stacks.items() if k.endswith(tail)})
+            x, Ks, Vs, rows = block(kcfg, lp, x, Ks, Vs, pool,
+                                    plane0 + r * stride,
+                                    hook(name, kind, pool), moe_stats)
+            out_rows.append(rows)
+        return (x, Ks, Vs), (jnp.stack(out_rows) if moe_stats else None)
+
+    (x, Ks, Vs), rows = jax.lax.scan(body, (x, Ks, Vs),
+                                     (scanned, jnp.arange(R)))
+    if moe_stats:       # [R, P, held] -> one row a block
+        rows = rows.reshape((R * P,) + rows.shape[2:])
+    return x, Ks, Vs, rows
+
+
 def stage_forward(
     params: StageParams,
     cfg: ModelConfig,
@@ -779,7 +958,18 @@ def stage_forward(
         foreign = cache
         cache = KVCache.create(cfg, cfg.num_layers, *inputs.shape[:2],
                                dtype=cache.keys.dtype)
+    if cfg.period and attn_impl is None and not isinstance(cache.keys,
+                                                           tuple):
+        # the same courtesy for a period model, whose cache is a stack a
+        # pool: one array of planes is a scorer's, and is handed back
+        foreign = cache
+        cache = KVCache.create(cfg, cfg.num_layers, *inputs.shape[:2],
+                               dtype=cache.keys.dtype)
     T = cfg.ut_steps
+    if cfg.period and (T > 1 or cfg.latent_kv or not spec.is_first
+                       or not spec.is_last):
+        require_one_kind(cfg, "a looped or latent model, or a stage of a "
+                              "pipeline,")
     planes = jax.tree.leaves(cache.keys)[0].shape[0]
     # leading dense blocks (deepseek_v3) run once before the scan and hold
     # the cache's first planes; 0 for every other model
@@ -820,7 +1010,14 @@ def stage_forward(
         # 0.054-0.057 with this one, against 0.086 for ONE bf16 pass.
         x = x.astype(jnp.float32)
 
-    if cache_in_carry:
+    if cfg.period:
+        if tp_axis is not None or ep_axis is not None or not cache_in_carry:
+            require_one_kind(cfg, "a mesh axis or the training layout of "
+                                  "the cache")
+        x, new_k, new_v, expert_rows = _period_blocks(
+            params, cfg, x, cache, positions, cache_start, attn_impl,
+            moe_stats, valid)
+    elif cache_in_carry:
         # Inference layout: the full stacked cache rides the scan CARRY and
         # each iteration dynamic-slices its layer plane in/out — XLA keeps
         # the carry buffer in place, so a decode step writes one token

@@ -312,6 +312,15 @@ def params_from_state_dict(raw: Dict[str, np.ndarray],
             "family was added, and a guessed name map loads wrong weights "
             "without a word; serve it on seeded weights, or add the map "
             "to models/loader.py from the checkpoint's own index")
+    if cfg.family == "laguna":
+        raise NotImplementedError(
+            "no state-dict mapper for family 'laguna': the source gives a "
+            "configuration and no modeling file, so the tensor names (the "
+            "per-head gate, the stacks of the two kinds of block) could "
+            "not be checked against a checkpoint, and a guessed name map "
+            "loads wrong weights without a word; serve it on seeded "
+            "weights, or add the map to models/loader.py from the "
+            "checkpoint's own index")
     if cfg.family not in _SD_MAPPERS:
         raise NotImplementedError(f"no state-dict mapper for {cfg.family!r}")
     return _SD_MAPPERS[cfg.family](raw, cfg)

@@ -9,7 +9,7 @@ Also provides tiny "-test" configs for fast unit tests and virtual-mesh
 dry runs.
 """
 
-from .base import ModelConfig
+from .base import BlockKind, ModelConfig
 
 
 def _bloom(hidden, layers, heads, vocab=250880) -> ModelConfig:
@@ -19,6 +19,13 @@ def _bloom(hidden, layers, heads, vocab=250880) -> ModelConfig:
         max_seq_len=2048, use_alibi=True, use_rope=False, attn_layernorm=True,
         tie_embeddings=True, norm_eps=1e-5)
 
+
+LAGUNA_TEST_WINDOW = BlockKind(attn="window", window=8, num_heads=6,
+                               rope_theta=10000.0, gate="per-head")
+LAGUNA_TEST_FULL = BlockKind(attn="full", num_heads=4, rope_theta=500000.0,
+                             rotary_share=0.5,
+                             yarn=(8.0, 32.0, 4.0, 1.0, 1.2079441541679836),
+                             gate="per-head")
 
 MODEL_REGISTRY = {
     # --- bloom family (reference parity: data/Data.kt:19-33) ---
@@ -151,6 +158,20 @@ MODEL_REGISTRY = {
         lead_intermediate_size=96, num_shared_experts=1,
         router_scoring="sigmoid", router_bias=True,
         routed_scaling_factor=2.448, dtype_name="float32"),
+    # a period of unlike blocks: one leading dense block (full attention),
+    # then 2 repeats of (window, window, window, full); 6 / 4 query heads
+    # over 2 kv heads, window 8, YaRN on half a head of the full kind, a
+    # per-head output gate, and 4 of 16 routed experts held
+    "laguna-test": ModelConfig(
+        family="laguna", vocab_size=256, hidden_size=64, num_layers=2,
+        num_heads=4, num_kv_heads=2, head_dim_override=16,
+        intermediate_size=32, max_seq_len=256, norm_eps=1e-6,
+        num_experts=16, experts_per_token=3, norm_topk_prob=True,
+        lead_dense_layers=1, lead_intermediate_size=96,
+        num_shared_experts=1, routed_scaling_factor=2.5,
+        experts_held=(4, 0), dtype_name="float32",
+        period=tuple([LAGUNA_TEST_WINDOW] * 3 + [LAGUNA_TEST_FULL]),
+        lead_kind=LAGUNA_TEST_FULL),
 }
 
 

@@ -44,8 +44,10 @@ def attention(
     q_positions: jnp.ndarray,   # [batch, chunk] absolute positions of q tokens
     cache_len: jnp.ndarray,     # scalar int32: valid length of the cache
     slopes: Optional[jnp.ndarray] = None,  # [num_heads] ALiBi, or None
+    window: int = 0,            # > 0: a query sees its last `window` keys
 ) -> jnp.ndarray:
-    """Causal attention of the current chunk against the full cache.
+    """Causal attention of the current chunk against the full cache; with
+    ``window`` a query at position ``p`` sees keys ``p - window < j <= p``.
 
     Cache layout is head-major (see ``models.base.KVCache``).
     Returns [batch, chunk, num_heads, head_dim].
@@ -70,6 +72,8 @@ def attention(
     # causal + validity: a q token at position p attends to kv positions <= p
     # that are inside the filled cache region.
     valid = (kv_pos <= qpos) & (kv_pos < cache_len)              # [b, q, s]
+    if window:
+        valid = valid & (qpos - kv_pos < window)
     mask = valid[:, None, None, :, :]                            # [b,1,1,q,s]
 
     if slopes is not None:

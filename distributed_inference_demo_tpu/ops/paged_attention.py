@@ -57,6 +57,20 @@ from .stacked import LayerOf
 
 _NEG = -1e30
 
+# the dtype the kernels' online-softmax state (running maximum, sum and
+# output) is ROUNDED to between pages: float32, the state's own, rounds
+# nothing and traces nothing.  ``tools/model_parity.py
+# --bf16-softmax-state`` sets bfloat16: the next precision down, which a
+# long-context reading is held against
+_STATE_DTYPE = jnp.float32
+
+
+def _state(x):
+    if _STATE_DTYPE == jnp.float32:
+        return x
+    return x.astype(_STATE_DTYPE).astype(jnp.float32)
+
+
 # how a layer call reaches the pool (route_pool picks one): in place in
 # the stack, by one of write_paged_kv's two forms, or through the layer's
 # plane, sliced out and put back
@@ -357,6 +371,7 @@ def paged_gather_attention(
     tables: jnp.ndarray,     # [batch, W] int32
     q_positions: jnp.ndarray,  # [batch, chunk]
     slopes: Optional[jnp.ndarray] = None,
+    window: int = 0,
 ) -> jnp.ndarray:
     """Pure-XLA fallback: gather each row's pages into a linear
     ``[batch, nkv, W*bt, hd]`` view and run the reference ``attention``.
@@ -381,8 +396,9 @@ def paged_gather_attention(
         v_lin = gather(V)
     k_lin = k_lin.transpose(0, 2, 1, 3, 4).reshape(b, nkv, W * bt, hd)
     v_lin = v_lin.transpose(0, 2, 1, 3, 4).reshape(b, nkv, W * bt, hd)
+    # (pages behind a window are masked, whatever their entries hold)
     return attention(q, k_lin, v_lin, q_positions,
-                     jnp.asarray(W * bt, jnp.int32), slopes)
+                     jnp.asarray(W * bt, jnp.int32), slopes, window)
 
 
 # ---------------------------------------------------------------------------
@@ -396,7 +412,7 @@ _RING_BYTES = 1 << 20
 
 def _paged_kernel(tab_ref, len_ref, layer_ref, q_ref, *refs,
                   block_tokens: int, ring: int, use_alibi: bool,
-                  quantized: bool):
+                  quantized: bool, window: int = 0):
     """Grid (b,): one step walks ONE row's live pages, all kv heads at
     once.  The stacked pools stay in HBM; page ``tables[b, j]`` of layer
     ``layer_ref[0]`` (``[nkv, bt, hd]``, contiguous in the pool) is copied
@@ -414,7 +430,12 @@ def _paged_kernel(tab_ref, len_ref, layer_ref, q_ref, *refs,
     ``quantized`` the pools are int8 and each is followed by its f32
     scale sidecar ``[L, N, nkv, bt]``, copied page for page beside it:
     the dequant happens in-register right after the narrow DMA — HBM
-    traffic stays 1 byte + 4/hd per element."""
+    traffic stays 1 byte + 4/hd per element.
+
+    ``window`` > 0 (a window kind of block): the row's one query sees
+    keys ``kv_len - window <= j < kv_len``, and the loop visits only the
+    pages that meet that range; the table's entries behind it may be
+    sentinel (their pages went back to the pool)."""
     if quantized:
         (k_hbm, ks_hbm, v_hbm, vs_hbm, slopes_ref, o_ref,
          k_buf, ks_buf, v_buf, vs_buf, sems) = refs
@@ -429,6 +450,10 @@ def _paged_kernel(tab_ref, len_ref, layer_ref, q_ref, *refs,
     # a length past the table (a row that finished inside a fused block
     # keeps stepping) walks the table's W entries and no further
     n_live = jnp.minimum((kv_len + bt - 1) // bt, W)
+    # the first page the loop visits: a Python 0 without a window, so the
+    # trace is the one it was
+    first = (jnp.minimum(jnp.maximum(kv_len - window, 0) // bt, n_live)
+             if window else 0)
 
     def page_copies(j):
         # sentinel entries clamp in-range: the garbage is masked below
@@ -443,9 +468,9 @@ def _paged_kernel(tab_ref, len_ref, layer_ref, q_ref, *refs,
                 for i, (src, buf) in enumerate(streams)]
 
     for j in range(ring - 1):
-        @pl.when(j < n_live)
+        @pl.when(first + j < n_live)
         def _prime():
-            for c in page_copies(j):
+            for c in page_copies(first + j):
                 c.start()
 
     q = q_ref[0].astype(jnp.float32)                    # [nkv, rows, hd]
@@ -473,7 +498,9 @@ def _paged_kernel(tab_ref, len_ref, layer_ref, q_ref, *refs,
                   + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bt), 2))
         # every q row is the same decode position kv_len - 1, so the
         # causal bound and the validity bound coincide
-        valid = jnp.broadcast_to(kv_pos < kv_len, s.shape)
+        valid = jnp.broadcast_to(
+            (kv_pos < kv_len) & (kv_pos >= kv_len - window) if window
+            else kv_pos < kv_len, s.shape)
         if use_alibi:
             dist = ((kv_len - 1) - kv_pos).astype(jnp.float32)
             s = s - slopes_ref[:] * dist                # [nkv, rows, 1]
@@ -485,21 +512,18 @@ def _paged_kernel(tab_ref, len_ref, layer_ref, q_ref, *refs,
         l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
         o_new = o * alpha + jnp.einsum(
             "hrt,htd->hrd", p, v_blk, preferred_element_type=jnp.float32)
-        return o_new, m_new, l_new
+        return _state(o_new), _state(m_new), _state(l_new)
 
     o, _, l = jax.lax.fori_loop(
-        0, n_live, fold,
+        first, n_live, fold,
         (jnp.zeros((nkv, rows, hd), jnp.float32),
          jnp.full((nkv, rows, 1), _NEG, jnp.float32),
          jnp.zeros((nkv, rows, 1), jnp.float32)))
     o_ref[0] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block_tokens", "use_alibi",
-                                    "interpret"))
-def _paged_call(q_g, k_pages, v_pages, layer, tables, kv_lens, slopes, *,
-                block_tokens, use_alibi, interpret):
+def _paged_call_body(q_g, k_pages, v_pages, layer, tables, kv_lens, slopes,
+                     *, block_tokens, use_alibi, interpret, window=0):
     """The Pallas call.  ``k_pages`` / ``v_pages`` are the STACKED pools
     ``[L, N, nkv, bt, hd]`` and ``layer`` [1] int32 picks the layer: the
     pools are ``pl.ANY`` operands, so nothing of them moves but the pages
@@ -535,7 +559,8 @@ def _paged_call(q_g, k_pages, v_pages, layer, tables, kv_lens, slopes, *,
 
     return pl.pallas_call(
         functools.partial(_paged_kernel, block_tokens=bt, ring=ring,
-                          use_alibi=use_alibi, quantized=quantized),
+                          use_alibi=use_alibi, quantized=quantized,
+                          **({"window": window} if window else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b,),
@@ -555,6 +580,31 @@ def _paged_call(q_g, k_pages, v_pages, layer, tables, kv_lens, slopes, *,
     )(tables, kv_lens, layer, *operands)
 
 
+# the two jitted calls, named as the trace readers know them: a window
+# kind's calls carry their own name (``_paged_call_window.<n>``), and
+# without a window the program is the one it was
+@functools.partial(jax.jit,
+                   static_argnames=("block_tokens", "use_alibi",
+                                    "interpret"))
+def _paged_call(q_g, k_pages, v_pages, layer, tables, kv_lens, slopes, *,
+                block_tokens, use_alibi, interpret):
+    return _paged_call_body(q_g, k_pages, v_pages, layer, tables, kv_lens,
+                            slopes, block_tokens=block_tokens,
+                            use_alibi=use_alibi, interpret=interpret)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("block_tokens", "use_alibi",
+                                    "interpret", "window"))
+def _paged_call_window(q_g, k_pages, v_pages, layer, tables, kv_lens,
+                       slopes, *, block_tokens, use_alibi, interpret,
+                       window):
+    return _paged_call_body(q_g, k_pages, v_pages, layer, tables, kv_lens,
+                            slopes, block_tokens=block_tokens,
+                            use_alibi=use_alibi, interpret=interpret,
+                            window=window)
+
+
 def paged_flash_attention(
     q: jnp.ndarray,          # [batch, 1, nh, hd] — decode chunk only
     k_pages,                 # [num_pages, nkv, block_tokens, hd] or LayerOf
@@ -564,6 +614,7 @@ def paged_flash_attention(
     slopes: Optional[jnp.ndarray] = None,
     *,
     interpret: bool = False,
+    window: int = 0,
 ) -> jnp.ndarray:
     """Pallas paged decode attention; numerics match
     :func:`paged_gather_attention` (f32 online softmax, same masking).
@@ -598,7 +649,7 @@ def paged_flash_attention(
         # grid that still has the table's width in it
         return paged_prefill_attention(
             q, k_pages, v_pages, tables, (kv_lens - 1)[:, None], slopes,
-            interpret=interpret)
+            interpret=interpret, window=window)
     g = nh // nkv
     rows = max(8, -(-g // 8) * 8)    # pad group rows to the sublane granule
 
@@ -617,12 +668,22 @@ def paged_flash_attention(
     # live row's first entry is always a real page, and the caller
     # discards a dead row's output)
     tables = tables.astype(jnp.int32)
-    kv_lens = jnp.where(tables[:, 0] >= num_pages, 0,
-                        kv_lens.astype(jnp.int32))
+    if window:
+        # a window kind's first entries are sentinel once their pages
+        # went back: a live row is told by the page of its last token
+        last = jnp.take_along_axis(
+            tables, jnp.clip((kv_lens.astype(jnp.int32) - 1) // bt, 0,
+                             tables.shape[1] - 1)[:, None], axis=1)[:, 0]
+        kv_lens = jnp.where(last >= num_pages, 0, kv_lens.astype(jnp.int32))
+        call = functools.partial(_paged_call_window, window=window)
+    else:
+        kv_lens = jnp.where(tables[:, 0] >= num_pages, 0,
+                            kv_lens.astype(jnp.int32))
+        call = _paged_call
 
-    out = _paged_call(q_g, K, V, li.reshape(1), tables, kv_lens, slopes_g,
-                      block_tokens=bt, use_alibi=slopes is not None,
-                      interpret=interpret)
+    out = call(q_g, K, V, li.reshape(1), tables, kv_lens, slopes_g,
+               block_tokens=bt, use_alibi=slopes is not None,
+               interpret=interpret)
     return out[:, :, :g, :].reshape(b, 1, nh, hd)
 
 
@@ -632,7 +693,8 @@ def paged_flash_attention(
 
 def _paged_prefill_kernel(tab_ref, start_ref, layer_ref, q_ref, *refs,
                           block_tokens: int, chunk: int, groups: int,
-                          use_alibi: bool, quantized: bool):
+                          use_alibi: bool, quantized: bool,
+                          window: int = 0, page0_ref=None):
     """Grid (b, nkv, W), page index innermost: each step folds one
     streamed [block_tokens, hd] page into online-softmax accumulators
     (VMEM scratch persists across the sequential grid), the fold of
@@ -649,7 +711,13 @@ def _paged_prefill_kernel(tab_ref, start_ref, layer_ref, q_ref, *refs,
     tab_ref (SMEM int32 [b, W]): block tables; start_ref (SMEM int32
     [b]): per-row segment start offsets (position of chunk column 0);
     layer_ref (SMEM int32 [1]): the layer of the stacked pool, read by
-    the page index map alone."""
+    the page index map alone.
+
+    ``window`` > 0 (a window kind of block): ``tab_ref`` is the row's
+    table from page ``page0_ref[b]`` on (the page that holds the first
+    key the chunk's first query sees), the grid's last axis is as wide
+    as a chunk and a window need, and a row's query sees keys
+    ``q_pos - window < j <= q_pos``."""
     del layer_ref
     if quantized:
         (k_ref, ks_ref, v_ref, vs_ref, slopes_ref,
@@ -673,6 +741,10 @@ def _paged_prefill_kernel(tab_ref, start_ref, layer_ref, q_ref, *refs,
 
     kv_len = start + chunk
     n_live = (kv_len + bt - 1) // bt
+    jp = j      # the page's place in the row's whole table
+    if window:
+        n_live = n_live - page0_ref[b]
+        jp = j + page0_ref[b]
 
     @pl.when(j < n_live)
     def _step():
@@ -685,7 +757,7 @@ def _paged_prefill_kernel(tab_ref, start_ref, layer_ref, q_ref, *refs,
             v_blk = v_blk * vs_ref[0, 0, :, :]
         s = jnp.dot(q, k_blk.T,
                     preferred_element_type=jnp.float32)     # [rows, bt]
-        kv_pos = (j * bt
+        kv_pos = (jp * bt
                   + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1))
         # per-row query position: padding rows (r >= chunk*g) see a
         # position past the segment — their garbage output is sliced
@@ -693,6 +765,8 @@ def _paged_prefill_kernel(tab_ref, start_ref, layer_ref, q_ref, *refs,
         q_pos = (start
                  + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // g)
         valid = kv_pos <= q_pos                             # [rows, bt]
+        if window:
+            valid = valid & (q_pos - kv_pos < window)
         if use_alibi:
             slope = slopes_ref[0, 0, :][:, None]            # [rows, 1]
             dist = (q_pos - kv_pos).astype(jnp.float32)
@@ -705,10 +779,10 @@ def _paged_prefill_kernel(tab_ref, start_ref, layer_ref, q_ref, *refs,
         p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        o_acc[:] = o_acc[:] * alpha + jnp.dot(
-            p, v_blk, preferred_element_type=jnp.float32)
-        m_acc[:] = jnp.broadcast_to(m_new, m_acc.shape)
-        l_acc[:] = jnp.broadcast_to(l_new, l_acc.shape)
+        o_acc[:] = _state(o_acc[:] * alpha + jnp.dot(
+            p, v_blk, preferred_element_type=jnp.float32))
+        m_acc[:] = jnp.broadcast_to(_state(m_new), m_acc.shape)
+        l_acc[:] = jnp.broadcast_to(_state(l_new), l_acc.shape)
 
     @pl.when(j == num_j - 1)
     def _finalize():
@@ -717,12 +791,16 @@ def _paged_prefill_kernel(tab_ref, start_ref, layer_ref, q_ref, *refs,
                              / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block_tokens", "chunk", "groups",
-                                    "use_alibi", "interpret"))
-def _paged_prefill_call(q_g, k_pages, v_pages, layer, tables, starts,
-                        slopes, *, block_tokens, chunk, groups, use_alibi,
-                        interpret):
+def _paged_prefill_kernel_window(tab_ref, start_ref, layer_ref, page0_ref,
+                                 q_ref, *refs, **kw):
+    """:func:`_paged_prefill_kernel` behind a fourth prefetched scalar."""
+    _paged_prefill_kernel(tab_ref, start_ref, layer_ref, q_ref, *refs,
+                          page0_ref=page0_ref, **kw)
+
+
+def _paged_prefill_call_body(q_g, k_pages, v_pages, layer, tables, starts,
+                             slopes, *, block_tokens, chunk, groups,
+                             use_alibi, interpret, window=0, page0=None):
     """The Pallas call.  ``k_pages`` / ``v_pages`` are the STACKED pools
     ``[L, N, nkv, bt, hd]`` and ``layer`` [1] int32 picks the layer: the
     page index map returns ``(layer, page, head, 0, 0)``, so the pipeline
@@ -733,19 +811,21 @@ def _paged_prefill_call(q_g, k_pages, v_pages, layer, tables, starts,
     W = tables.shape[1]
     bt = block_tokens
 
-    def page_map(bb, h, j, tab, starts_, lay):
+    def page_map(bb, h, j, tab, starts_, lay, *first):
         # clamp to the segment's live frontier (start + chunk tokens):
         # beyond it the index repeats (no DMA, pl.when skips compute);
         # sentinel entries clamp in-range
         live = (starts_[bb] + chunk + bt - 1) // bt
+        if first:       # a window's table starts at the row's page0
+            live = live - first[0][bb]
         jj = jnp.minimum(j, jnp.maximum(live - 1, 0))
         page = jnp.minimum(tab[bb, jj], num_pages - 1)
         return (lay[0], page, h, 0, 0)
 
     q_spec = pl.BlockSpec((1, 1, rows, hd),
-                          lambda bb, h, j, tab, starts_, lay: (bb, h, 0, 0))
+                          lambda bb, h, j, *_: (bb, h, 0, 0))
     slopes_spec = pl.BlockSpec((1, 1, rows),
-                               lambda bb, h, j, tab, starts_, lay: (h, 0, 0))
+                               lambda bb, h, j, *_: (h, 0, 0))
     page_spec = pl.BlockSpec((None, 1, 1, bt, hd), page_map)
     if quantized:
         scale_spec = pl.BlockSpec((None, 1, 1, bt, 1), page_map)
@@ -757,12 +837,20 @@ def _paged_prefill_call(q_g, k_pages, v_pages, layer, tables, starts,
         in_specs = [q_spec, page_spec, page_spec, slopes_spec]
         operands = (q_g, k_pages, v_pages, slopes)
 
+    prefetch = (tables, starts, layer)
+    kernel = functools.partial(_paged_prefill_kernel, block_tokens=bt,
+                               chunk=chunk, groups=groups,
+                               use_alibi=use_alibi, quantized=quantized)
+    if window:
+        prefetch += (page0,)
+        kernel = functools.partial(_paged_prefill_kernel_window,
+                                   block_tokens=bt, chunk=chunk,
+                                   groups=groups, use_alibi=use_alibi,
+                                   quantized=quantized, window=window)
     return pl.pallas_call(
-        functools.partial(_paged_prefill_kernel, block_tokens=bt,
-                          chunk=chunk, groups=groups,
-                          use_alibi=use_alibi, quantized=quantized),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(prefetch),
             grid=(b, nkv, W),
             in_specs=in_specs,
             out_specs=q_spec,
@@ -774,7 +862,45 @@ def _paged_prefill_call(q_g, k_pages, v_pages, layer, tables, starts,
         ),
         out_shape=jax.ShapeDtypeStruct((b, nkv, rows, hd), q_g.dtype),
         interpret=interpret,
-    )(tables, starts, layer, *operands)
+    )(*prefetch, *operands)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("block_tokens", "chunk", "groups",
+                                    "use_alibi", "interpret"))
+def _paged_prefill_call(q_g, k_pages, v_pages, layer, tables, starts,
+                        slopes, *, block_tokens, chunk, groups, use_alibi,
+                        interpret):
+    return _paged_prefill_call_body(
+        q_g, k_pages, v_pages, layer, tables, starts, slopes,
+        block_tokens=block_tokens, chunk=chunk, groups=groups,
+        use_alibi=use_alibi, interpret=interpret)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("block_tokens", "chunk", "groups",
+                                    "use_alibi", "interpret", "window"))
+def _paged_prefill_call_window(q_g, k_pages, v_pages, layer, tables, starts,
+                               slopes, page0, *, block_tokens, chunk,
+                               groups, use_alibi, interpret, window):
+    """``tables`` [b, n] is each row's table from page ``page0[b]`` on
+    (:func:`window_tables`)."""
+    return _paged_prefill_call_body(
+        q_g, k_pages, v_pages, layer, tables, starts, slopes,
+        block_tokens=block_tokens, chunk=chunk, groups=groups,
+        use_alibi=use_alibi, interpret=interpret, window=window,
+        page0=page0)
+
+
+def window_tables(tables, starts, chunk: int, window: int, bt: int):
+    """``(tables [b, n], page0 [b])``: each row's table cut to the pages a
+    chunk at ``starts[b]`` can see under ``window`` (from the page of key
+    ``starts[b] - window + 1`` to the page of the chunk's last token: at
+    most ``n`` of them, a static count)."""
+    n = min(tables.shape[1], -(-(chunk + window - 1) // bt) + 1)
+    page0 = jnp.maximum(starts - window + 1, 0) // bt
+    cols = jnp.minimum(page0[:, None] + jnp.arange(n), tables.shape[1] - 1)
+    return jnp.take_along_axis(tables, cols, axis=1), page0.astype(jnp.int32)
 
 
 # one kernel invocation's query rows = chunk * group; past this the
@@ -792,6 +918,7 @@ def paged_prefill_attention(
     slopes: Optional[jnp.ndarray] = None,
     *,
     interpret: bool = False,
+    window: int = 0,
 ) -> jnp.ndarray:
     """Pallas paged PREFILL attention: each row's chunk of queries
     attends causally over its own prior pages plus the in-chunk keys
@@ -840,11 +967,21 @@ def paged_prefill_attention(
         slopes_g = jnp.pad(slopes_g,
                            ((0, 0), (0, 0), (0, rows - rows_real)))
 
-    out = _paged_prefill_call(
-        q_g, K, V, li.reshape(1), tables.astype(jnp.int32),
-        q_positions[:, 0].astype(jnp.int32), slopes_g,
-        block_tokens=bt, chunk=chunk, groups=g,
-        use_alibi=slopes is not None, interpret=interpret)
+    if window:
+        starts = q_positions[:, 0].astype(jnp.int32)
+        tab_w, page0 = window_tables(tables.astype(jnp.int32), starts,
+                                     chunk, window, bt)
+        out = _paged_prefill_call_window(
+            q_g, K, V, li.reshape(1), tab_w, starts, slopes_g, page0,
+            block_tokens=bt, chunk=chunk, groups=g,
+            use_alibi=slopes is not None, interpret=interpret,
+            window=window)
+    else:
+        out = _paged_prefill_call(
+            q_g, K, V, li.reshape(1), tables.astype(jnp.int32),
+            q_positions[:, 0].astype(jnp.int32), slopes_g,
+            block_tokens=bt, chunk=chunk, groups=g,
+            use_alibi=slopes is not None, interpret=interpret)
     out = out[:, :, :rows_real, :].reshape(b, nkv, chunk, g, hd)
     return out.transpose(0, 2, 1, 3, 4).reshape(b, chunk, nh, hd)
 
@@ -941,6 +1078,15 @@ def route_paged_attention(backend: str, platform: str, k_pages,
     return (PATH_DECODE_KERNEL if chunk == 1 else PATH_PREFILL_KERNEL), ""
 
 
+def sub_chunk(chunk: int, groups: int) -> int:
+    """The largest divisor of ``chunk`` whose ``x groups`` query rows the
+    prefill kernel holds (``PREFILL_KERNEL_MAX_ROWS``), a multiple of 8
+    where there is one; ``chunk`` itself where it fits."""
+    fits = [c for c in range(chunk, 0, -1) if chunk % c == 0
+            and -(-(c * groups) // 8) * 8 <= PREFILL_KERNEL_MAX_ROWS]
+    return next((c for c in fits if c % 8 == 0), fits[0])
+
+
 def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
                          interpret: bool = False,
                          record: Optional[AttnPathRecord] = None):
@@ -974,17 +1120,28 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
         bound["tables"] = tables
         bound["program"] = program
 
-    def impl(q, k, v, k_pages, v_pages, positions, cache_start, slopes):
+    def attend(q, k, v, k_pages, v_pages, positions, slopes, tables,
+               program, window=0, split=False):
+        """Write the chunk, then attend: one traced attention call over
+        ``tables``.  ``window`` > 0 (a window kind of block) bounds what a
+        query sees.  ``split`` (the kinds of a period model, whose context
+        no gathered view could hold): a chunk of more query rows than the
+        prefill kernel holds is cut into sub-chunks, a row of the call
+        each (the keys are written before any of them attends, so a
+        sub-chunk is a chunk that starts later)."""
         assert isinstance(k_pages, LayerOf), "the pool comes stacked"
-        tables = bound["tables"]
         chunk = q.shape[1]
+        groups = q.shape[2] // k.shape[2]
+        sub = chunk
+        if split and chunk > 1 and backend != "xla":
+            sub = sub_chunk(chunk, groups)
         path, why = route_paged_attention(
-            backend, jax.default_backend(), k_pages, chunk,
-            q.shape[2] // k.shape[2])
+            backend, jax.default_backend(), k_pages, sub, groups)
         pool = route_pool(backend, jax.default_backend(), k_pages, chunk)
         if record is not None:
-            record.note(bound["program"], chunk, path, why, pool)
+            record.note(program, chunk, path, why, pool)
         whole_k, whole_v = k_pages, v_pages
+        kw = {"window": window} if window else {}
         # metadata only: a profiler capture keeps the scope with each op
         with jax.named_scope("paged_attention"):
             if pool == POOL_PLANE:
@@ -997,18 +1154,51 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
                 kv_lens = positions[:, -1] + 1
                 out = paged_flash_attention(q, k_pages, v_pages, tables,
                                             kv_lens, slopes,
-                                            interpret=interpret)
+                                            interpret=interpret, **kw)
             elif path == PATH_PREFILL_KERNEL:
-                out = paged_prefill_attention(q, k_pages, v_pages, tables,
-                                              positions, slopes,
-                                              interpret=interpret)
+                if sub != chunk:
+                    b, n = q.shape[0], chunk // sub
+                    cut = lambda a: a.reshape((b * n, sub) + a.shape[2:])
+                    out = paged_prefill_attention(
+                        cut(q), k_pages, v_pages,
+                        jnp.repeat(tables, n, axis=0), cut(positions),
+                        slopes, interpret=interpret, **kw)
+                    out = out.reshape(q.shape)
+                else:
+                    out = paged_prefill_attention(
+                        q, k_pages, v_pages, tables, positions, slopes,
+                        interpret=interpret, **kw)
             else:
                 out = paged_gather_attention(q, k_pages, v_pages, tables,
-                                             positions, slopes)
+                                             positions, slopes, **kw)
             if pool == POOL_PLANE:
                 k_pages = LayerOf(whole_k.updated(k_pages), whole_k.layer)
                 v_pages = LayerOf(whole_v.updated(v_pages), whole_v.layer)
         return out, k_pages, v_pages
 
+    def impl(q, k, v, k_pages, v_pages, positions, cache_start, slopes):
+        return attend(q, k, v, k_pages, v_pages, positions, slopes,
+                      bound["tables"], bound["program"])
+
+    def for_pool(pool: int, pools: int, window: int, name: str):
+        """The hook of one KIND of block of a model with a cache spec a
+        kind: the bound tables hold one table a pool side by side
+        (``[rows, pools x W]``) and this kind reads pool ``pool``'s, under
+        ``window``; its paths are recorded as ``<program>/<name>``."""
+
+        def kind_impl(q, k, v, k_pages, v_pages, positions, cache_start,
+                      slopes):
+            tables = bound["tables"]
+            width = tables.shape[1] // pools
+            with jax.named_scope(f"attn_{name}"):
+                return attend(q, k, v, k_pages, v_pages, positions, slopes,
+                              tables[:, pool * width:(pool + 1) * width],
+                              f"{bound['program']}/{name}", window or 0,
+                              split=True)
+
+        kind_impl.stacked_cache = True
+        return kind_impl
+
+    impl.for_pool = for_pool
     impl.stacked_cache = True
     return impl, bind

@@ -28,7 +28,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.base import (KVCache, ModelConfig, StageParams, StageSpec,
-                           require_kv_pair, require_single_pass)
+                           require_kv_pair, require_one_kind,
+                           require_single_pass)
 from .sharding import stage_param_spec_tree
 
 
@@ -199,6 +200,7 @@ def make_pipeline_generate_fn(cfg: ModelConfig, mesh: Mesh, *,
     """
     require_single_pass(cfg, "the circular pipeline")
     require_kv_pair(cfg, "the circular pipeline")
+    require_one_kind(cfg, "the circular pipeline")
     from ..models.decoder import stage_forward
     from ..ops.sampling import SamplingParams, sample_logits
 
@@ -366,6 +368,7 @@ def make_pipeline_train_step(cfg: ModelConfig, mesh: Mesh, optimizer,
     """
     require_single_pass(cfg, "the circular pipeline")
     require_kv_pair(cfg, "the circular pipeline")
+    require_one_kind(cfg, "the circular pipeline")
     use_tp = mesh.shape.get("tp", 1) > 1
     use_dp = mesh.shape.get("dp", 1) > 1
     axis_names = set(mesh.axis_names)

@@ -25,7 +25,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.base import (KVCache, ModelConfig, StageSpec,
-                           require_kv_pair, require_single_pass)
+                           require_kv_pair, require_one_kind,
+                           require_single_pass)
 from ..models.decoder import stage_forward
 from ..ops.attention import update_kv_cache
 from ..ops.norms import layer_norm, rms_norm
@@ -163,6 +164,7 @@ def _make_ring_cores(cfg: ModelConfig, spec: StageSpec, s_loc: int,
     (the fused path closes over it; the stream path cannot)."""
     require_single_pass(cfg, "ring sequence parallelism")
     require_kv_pair(cfg, "ring sequence parallelism")
+    require_one_kind(cfg, "ring sequence parallelism")
     cache_dtype = kv_dtype if kv_dtype is not None else cfg.dtype
 
     def prefill_core(params, ids, rng):

@@ -12,7 +12,7 @@ import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.base import (KVCache, ModelConfig, StageParams, StageSpec,
-                           require_kv_pair)
+                           require_kv_pair, require_one_kind)
 from ..models.decoder import stage_forward
 from .sharding import stage_param_spec_tree
 
@@ -53,6 +53,7 @@ def validate_tp(cfg: ModelConfig, mesh: Mesh) -> int:
     tp = mesh.shape.get("tp", 1)
     if tp > 1:
         require_kv_pair(cfg, "tensor parallelism (--tp)")
+        require_one_kind(cfg, "tensor parallelism (--tp)")
     if tp > 1 and cfg.num_kv_heads % tp:
         raise ValueError(
             f"num_kv_heads={cfg.num_kv_heads} not divisible by tp={tp}")
@@ -157,6 +158,7 @@ def make_paged_forward_seam(cfg: ModelConfig, spec: StageSpec, mesh,
                                  moe_stats=moe_stats, valid=valid)
 
         return fwd, bind, None
+    require_one_kind(cfg, "tensor parallelism (--tp)")
     validate_tp(cfg, mesh)
     p_specs = _tp_param_specs(params_template, cfg)
     bound = {}

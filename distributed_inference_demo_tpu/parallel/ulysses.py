@@ -30,7 +30,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from ..models.base import (KVCache, ModelConfig, StageSpec,
-                           require_kv_pair, require_single_pass)
+                           require_kv_pair, require_one_kind,
+                           require_single_pass)
 from ..models.decoder import stage_forward
 from ..ops.attention import attention, update_kv_cache
 from ..ops.sampling import SamplingParams, sample_logits
@@ -86,6 +87,7 @@ def _make_ulysses_cores(cfg: ModelConfig, max_seq: int, sp: int,
     ``(keys, values, length, tok)`` with the cache head-sharded."""
     require_single_pass(cfg, "Ulysses sequence parallelism")
     require_kv_pair(cfg, "Ulysses sequence parallelism")
+    require_one_kind(cfg, "Ulysses sequence parallelism")
     cache_dtype = kv_dtype if kv_dtype is not None else cfg.dtype
     spec = StageSpec(0, 1, 0, cfg.num_layers)
     body_spec = StageSpec(0, 2, 0, cfg.num_layers)  # no head at prefill

@@ -75,7 +75,8 @@ import numpy as np
 
 from ..models.base import (KVCache, ModelConfig, StageParams,
                            StageSpec, pad_cache_capacity,
-                           require_kv_pair, require_single_pass)
+                           require_kv_pair, require_one_kind,
+                           require_single_pass)
 from ..ops.sampling import SamplingParams, filtered_logits, sample_logits
 from ..telemetry import postmortem
 from ..telemetry import profiling as _profiling
@@ -84,7 +85,8 @@ from ..telemetry.flightrecorder import get_flight_recorder
 from ..telemetry.slo import get_slo_ledger, sanitize_tenant
 from ..telemetry.tracing import (LATENT_DISPATCH_FIELDS,
                                  LOOP_DISPATCH_FIELDS,
-                                 MOE_DISPATCH_FIELDS, DispatchTrace,
+                                 MOE_DISPATCH_FIELDS,
+                                 WINDOW_DISPATCH_FIELDS, DispatchTrace,
                                  LoopCounters, MoeCounters, TraceRecorder,
                                  to_chrome_trace)
 from .engine import (GenerationResult, check_capacity,
@@ -385,7 +387,20 @@ class ContinuousBatchingEngine:
             raise ValueError("num_draft must be >= 1")
         if (draft_cfg is None) != (draft_params is None):
             raise ValueError("draft_cfg and draft_params go together")
+        if cfg.mixed_kinds:
+            # a cache spec a kind of block (docs/DESIGN.md section 25):
+            # one pool and one table a kind, window pages freed while the
+            # request runs.  What is built for one pool refuses it
+            if self.mixed_token_budget == 0:
+                require_one_kind(cfg, "the serialized interleave (no "
+                                      "--mixed-token-budget)")
+            if prompt_lookup or draft_cfg is not None:
+                require_one_kind(cfg, "speculation (a draft model or "
+                                      "prompt lookup)")
+            if mesh is not None and mesh.shape.get("tp", 1) > 1:
+                require_one_kind(cfg, "tensor parallelism (--tp)")
         if draft_cfg is not None:
+            require_one_kind(draft_cfg, "the draft side of speculation")
             require_single_pass(draft_cfg, "the draft side of speculation")
             require_kv_pair(draft_cfg, "the draft side of speculation")
             if draft_cfg.vocab_size != cfg.vocab_size:
@@ -405,6 +420,7 @@ class ContinuousBatchingEngine:
         self.kv_dtype = resolve_kv_dtype(kv_dtype)
         if self.kv_dtype != "bf16":
             require_kv_pair(cfg, f"a page pool of {self.kv_dtype} pages")
+            require_one_kind(cfg, f"a page pool of {self.kv_dtype} pages")
         if self.kv_dtype != "bf16" and self.kv_cache_dtype is not None:
             raise ValueError(
                 f"kv_dtype={self.kv_dtype!r} quantizes the page pool and "
@@ -479,11 +495,43 @@ class ContinuousBatchingEngine:
         self._table_width = S // bt
         n_blocks = (n_blocks_arg if n_blocks_arg >= 1
                     else B * self._table_width)
+        # the cache spec by kind of block: one POOL for the blocks that
+        # read alike (``cfg.cache_kinds``, the full kind's first).
+        # ``kv_cache`` is the full kind's manager, the pool that fills:
+        # what ``--kv-cache-blocks`` sizes and /stats.kvcache's
+        # ``blocks_used`` / ``blocks_total`` / ``bytes_per_token`` mean
+        self._pool_specs = cfg.cache_kinds
         self.kv_cache = PagedKVCacheManager.for_model(
             cfg, n_blocks, bt, dtype=self.kv_cache_dtype,
-            kv_dtype=self.kv_dtype)
+            kv_dtype=self.kv_dtype, planes=self._pool_specs[0][1])
         N = self.kv_cache.num_blocks
         self._page_sentinel = N
+        # a WINDOW pool (a kind that reads its last W tokens): a request
+        # holds the pages its window and its dispatches in flight meet
+        # and gives back what fell behind while it runs.  The engine
+        # sizes it: ``_window_quota`` pages a request that can hold any
+        # (the slots and one admission more), so an allocation inside
+        # the quota never fails
+        self._wmgr = None
+        self._window = 0
+        if len(self._pool_specs) > 1:
+            if len(self._pool_specs) > 2:
+                raise ValueError(
+                    f"one window size a model: got the pools "
+                    f"{self._pool_specs}")
+            self._window, w_planes = self._pool_specs[1]
+            span = max(decode_block, self.mixed_token_budget)
+            self._window_quota = -(-(self._window + 2 * span) // bt) + 1
+            self._window_reserved = 0
+            self._wmgr = PagedKVCacheManager(
+                w_planes, *cfg.kv_page_shape,
+                (B + 1) * self._window_quota, bt,
+                self.kv_cache_dtype or cfg.dtype, kv_dtype=self.kv_dtype)
+            self._page_sentinel = max(N, self._wmgr.num_blocks)
+            self.window_stats = {"pages_held_peak": 0, "pages_returned": 0,
+                                 "pages_unwindowed_peak": 0}
+        # a row of a table: one table a pool, side by side
+        self._table_cols = len(self._pool_specs) * self._table_width
         page_dtype = self.kv_cache_dtype or cfg.dtype
         # which attention path each compiled program took, written at
         # trace time and served under /stats["attention_paths"]
@@ -495,10 +543,19 @@ class ContinuousBatchingEngine:
         # a latent-attention model's pool is ``_pk`` alone, one row a
         # token a plane; ``_pv`` then holds no element
         heads, width = cfg.kv_page_shape
-        self._pk, self._pv = alloc_kv_pool(
-            (cfg.kv_planes, N, heads, bt, width), self.kv_dtype,
-            page_dtype, pool_sharding, streams=cfg.kv_streams)
-        self._tables = np.full((B, self._table_width), N, np.int32)
+        if self._wmgr is None:
+            self._pk, self._pv = alloc_kv_pool(
+                (cfg.kv_planes, N, heads, bt, width), self.kv_dtype,
+                page_dtype, pool_sharding, streams=cfg.kv_streams)
+        else:       # a tuple of pools, the full kind's first
+            pools = [alloc_kv_pool((planes, n, heads, bt, width),
+                                   self.kv_dtype, page_dtype)
+                     for (_, planes), n in zip(
+                         self._pool_specs, (N, self._wmgr.num_blocks))]
+            self._pk, self._pv = (tuple(p[0] for p in pools),
+                                  tuple(p[1] for p in pools))
+        self._tables = np.full((B, self._table_cols), self._page_sentinel,
+                               np.int32)
         # write_row_to_pages survives for the DRAFT side only: the draft
         # prefill still runs a dense temp row (the draft is small by
         # construction) and scatters it into the scratch pool; the
@@ -516,6 +573,7 @@ class ContinuousBatchingEngine:
         self._kv_tier = None
         if tier_host > 0:
             require_kv_pair(cfg, "the host tier of the KV cache")
+            require_one_kind(cfg, "the host tier of the KV cache")
             self._kv_tier = TieredKVStore(
                 tier_host, bt, disk_path=tier_path,
                 disk_bytes=tier_disk)
@@ -739,7 +797,7 @@ class ContinuousBatchingEngine:
             # touched, the fullest expert's rows in one layer call,
             # and the layer calls); a dense model's program is as it was
             moe_ = cfg_.num_experts > 0
-            E_ = cfg_.num_experts
+            E_ = cfg_.experts_here      # the experts this chip holds
 
             def moe_acc0():
                 return jnp.zeros((E_ + 3,), jnp.int32)
@@ -1309,7 +1367,8 @@ class ContinuousBatchingEngine:
         # a model with experts also counts its routing (tracing.
         # MoeCounters; mixed dispatches only: the path that is served)
         moe = cfg.num_experts > 0 and self._mixed_step is not None
-        self.moe_counters = MoeCounters(cfg.num_experts) if moe else None
+        self.moe_counters = (MoeCounters(cfg.experts_here, cfg.num_experts)
+                             if moe else None)
         # ... and its slab is told the tokens each segment holds, an
         # eighth segment array (`_blank_segments`)
         self._seg_arrays = 8 if moe else 7
@@ -1326,7 +1385,8 @@ class ContinuousBatchingEngine:
         self.dispatch_trace = DispatchTrace(
             (MOE_DISPATCH_FIELDS if moe else ())
             + (LOOP_DISPATCH_FIELDS if loop else ())
-            + (LATENT_DISPATCH_FIELDS if latent else ()))
+            + (LATENT_DISPATCH_FIELDS if latent else ())
+            + (WINDOW_DISPATCH_FIELDS if self._wmgr is not None else ()))
 
         # (mixed mode never dispatches the serialized step programs: it
         # launches every variant of mixed_step instead, below)
@@ -1548,6 +1608,7 @@ class ContinuousBatchingEngine:
         if k_blocks is None:
             return self.submit(prompt_ids, max_new_tokens)
         require_kv_pair(self.cfg, "a premigrated prefill (disaggregation)")
+        require_one_kind(self.cfg, "a premigrated prefill (disaggregation)")
         prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
         from ..ops.quant import QuantizedKVPages
         if isinstance(k_blocks, QuantizedKVPages):
@@ -1724,6 +1785,7 @@ class ContinuousBatchingEngine:
         history — the importer rebuilds proposer state from
         prompt+tokens, which is cheap and exact (docs/DESIGN.md §22)."""
         require_kv_pair(self.cfg, "export_request (migration)")
+        require_one_kind(self.cfg, "export_request (migration)")
         req = rid if isinstance(rid, Request) else self._by_rid.get(rid)
         if req is None:
             raise KeyError(f"unknown request id {rid!r}")
@@ -1866,6 +1928,7 @@ class ContinuousBatchingEngine:
         h2d.  Restoring the rng key makes a single-request resume
         sample-exact; greedy streams are bit-identical regardless."""
         require_kv_pair(self.cfg, "import_request (migration)")
+        require_one_kind(self.cfg, "import_request (migration)")
         rid = request_id if request_id is not None else ckpt.get("rid")
         if not ckpt.get("tokens") or int(ckpt.get("length") or 0) <= 0:
             # cold checkpoint: nothing decoded yet — plain admission
@@ -2151,6 +2214,26 @@ class ContinuousBatchingEngine:
                                    if s is not None)}
         if self.kv_cache is not None:
             out["kvcache"] = self.kv_cache.snapshot()
+            if self._wmgr is not None:
+                # by kind of block.  The keys above keep one meaning, the
+                # full kind's pool (the one that fills); the window kind's
+                # pool is its own entry, with what its window saved
+                full = {k: out["kvcache"][k] for k in (
+                    "blocks_used", "blocks_total", "bytes_per_token")}
+                w = self._wmgr.snapshot()
+                ws = self.window_stats
+                out["kvcache"]["kinds"] = {
+                    "full": dict(full, window=None),
+                    "window": {
+                        "window": self._window,
+                        "blocks_used": w["blocks_used"],
+                        "blocks_total": w["blocks_total"],
+                        "bytes_per_token": w["bytes_per_token"],
+                        "pages_held_peak": ws["pages_held_peak"],
+                        "pages_returned": ws["pages_returned"],
+                        "pages_unwindowed_peak":
+                            ws["pages_unwindowed_peak"],
+                        "quota_pages": self._window_quota}}
         # dispatch-floor picture (§13): dispatches vs device steps —
         # steps/dispatches ≈ decode_block when fusion is engaging
         out["device_loop"] = dict(self.loop_stats,
@@ -2378,9 +2461,17 @@ class ContinuousBatchingEngine:
             self._pk, self._pv, _ = promote_prefix(
                 mgr, self._kv_tier, self._pk, self._pv, req.prompt,
                 profiler=self._prof)
-        lease = mgr.match(req.prompt)
+        # prefix sharing is OFF for a model with a window kind: a shared
+        # prefix's window pages are gone by the time it could be hit
+        lease = mgr.match(req.prompt) if self._wmgr is None else None
         m = lease.tokens if lease is not None else 0
         n_pref = m // bt
+        if (self._wmgr is not None and self._window_reserved
+                + self._window_quota > self._wmgr.num_blocks):
+            # the page gate by kind: no quota of window pages is left
+            # (a completion returns one, and frees full-kind pages too)
+            req._pkv_blocked = (mgr.epoch, mgr.free_blocks)
+            raise _BlocksExhausted()
         private = mgr.alloc(n_total - n_pref)
         if private is None:
             if lease is not None:
@@ -2400,11 +2491,13 @@ class ContinuousBatchingEngine:
                 req._pkv_blocked = (mgr.epoch, mgr.free_blocks)
                 raise _BlocksExhausted()
         req._pkv_blocked = None
-        table = np.full((self._table_width,), self._page_sentinel,
+        table = np.full((self._table_cols,), self._page_sentinel,
                         np.int32)
         if lease is not None:
             table[:n_pref] = lease.block_ids
         table[n_pref:n_total] = private
+        if self._wmgr is not None:
+            self._window_reserved += self._window_quota
         dtable = None
         if dprivate is not None:
             dtable = np.full((self._table_width,), self._dpage_sentinel,
@@ -2413,7 +2506,10 @@ class ContinuousBatchingEngine:
         req._pkv = {"lease": lease, "store_lease": None,
                     "private": private, "adopted": (), "n_pref": n_pref,
                     "table": table, "dprivate": dprivate,
-                    "dtable": dtable, "released": False}
+                    "dtable": dtable, "released": False,
+                    # the window kind's pages by block of the table, and
+                    # the first block it may still read
+                    "wpages": {}, "wfirst": 0}
         # workload sketch: prefix-hit share = matched / prompt tokens,
         # recorded once per SUCCESSFUL reservation (a _BlocksExhausted
         # retry re-runs match and must not double-count)
@@ -2439,6 +2535,47 @@ class ContinuousBatchingEngine:
                             if b not in adopted])
         if st["dprivate"] is not None:
             self._dmgr.free(st["dprivate"])
+        if self._wmgr is not None:
+            self._wmgr.free(list(st["wpages"].values()))
+            st["wpages"].clear()
+            self._window_reserved -= self._window_quota
+
+    def _window_hold(self, req: Request, lo: int, hi: int) -> None:
+        """The window kind's pages for the tokens ``[lo, hi)`` the next
+        dispatch writes for ``req``: a page a block of the table not held
+        yet, into the request's table row (its window columns).  Inside
+        the request's quota, so the pool has them.  Called when a
+        dispatch is PACKED: a plan that is never launched leaves the
+        request pages it will need next."""
+        st, bt, W = req._pkv, self.kv_cache.block_tokens, self._table_width
+        need = [j for j in range(lo // bt, min(W, -(-hi // bt)))
+                if j not in st["wpages"] and j >= st["wfirst"]]
+        if not need:
+            return
+        pages = self._wmgr.alloc(len(need))
+        assert pages is not None, "window pages are inside a quota"
+        for j, page in zip(need, pages):
+            st["wpages"][j] = page
+            st["table"][W + j] = page
+        ws = self.window_stats
+        ws["pages_held_peak"] = max(ws["pages_held_peak"],
+                                    self._wmgr.used_blocks)
+
+    def _window_release(self, req: Request, lo: int) -> None:
+        """Give back the window kind's pages that no query at ``lo`` or
+        later can see (blocks wholly before token ``lo - window + 1``).
+        Called when the dispatch whose first write for ``req`` is ``lo``
+        is LAUNCHED: every earlier dispatch has been enqueued, the device
+        runs them in order, and a page given back here is written again
+        by a later dispatch at the earliest."""
+        st, bt, W = req._pkv, self.kv_cache.block_tokens, self._table_width
+        first = max(0, lo - self._window + 1) // bt
+        gone = [j for j in st["wpages"] if j < first]
+        if gone:
+            self._wmgr.free([st["wpages"].pop(j) for j in gone])
+            st["table"][[W + j for j in gone]] = self._page_sentinel
+            self.window_stats["pages_returned"] += len(gone)
+        st["wfirst"] = max(st["wfirst"], first)
 
     def _needs_stream(self, req: Request) -> bool:
         """Does this prompt need the one-at-a-time chunk stream, or can
@@ -3261,7 +3398,7 @@ class ContinuousBatchingEngine:
         ``mixed_step`` takes the first ``_seg_arrays``."""
         n_seg, C, i32 = self._mixed_seg_cap, self.prefill_chunk, np.int32
         return (np.zeros((n_seg, C), i32),
-                np.full((n_seg, self._table_width), self._page_sentinel,
+                np.full((n_seg, self._table_cols), self._page_sentinel,
                         i32),
                 np.zeros((n_seg,), i32), np.ones((n_seg,), i32),
                 np.full((n_seg,), self.max_batch, i32),
@@ -3340,6 +3477,10 @@ class ContinuousBatchingEngine:
                 break
             req = a["req"]
             start, suffix = a["start"], a["suffix"]
+            if self._wmgr is not None and r < want:
+                # every segment of this admission the dispatch can carry
+                self._window_hold(req, start, min(
+                    len(req.prompt), start + (want - r) * C))
             while r < want and len(suffix) > C:
                 seg_ids[r, :] = np.asarray(suffix[:C], np.int32)
                 seg_tables[r] = req._pkv["table"]
@@ -3398,11 +3539,29 @@ class ContinuousBatchingEngine:
         # decode inside this dispatch pages through the installed
         # row — its table must be live BEFORE the dispatch; the
         # radix adoption (drain) waits until the pages hold data
-        if finals:
+        if finals or self._wmgr is not None:
             tables = tables.copy()
         for req, slot in finals:
             budget_vec[slot] = req.max_new - 1
             tables[slot] = req._pkv["table"]
+        # the window kind: a row's next steps write tokens [held - 1 ...),
+        # and its table row is the request's, which the holds keep current
+        kv_window_tokens = prefill_window_pairs = 0
+        if self._wmgr is not None:
+            Wn, steps = self._window, self.decode_block
+            decoding = [(i, *s) for i, s in enumerate(rows) if s is not None]
+            decoding += [(slot, req, 1) for req, slot in finals]
+            for slot, req, k in decoding:
+                at = len(req.prompt) + k - 1
+                self._window_hold(req, at, at + steps)
+                tables[slot] = req._pkv["table"]
+                kv_window_tokens += min(at + 1, Wn)
+            for (r0, a, _, _) in packed:
+                n, s0 = int(seg_ntok[r0]), int(seg_starts[r0])
+                # token at position p sees min(p + 1, W) keys
+                full = max(0, min(n, Wn - 1 - s0))      # p + 1 < W
+                prefill_window_pairs += (
+                    full * s0 + full * (full + 1) // 2 + (n - full) * Wn)
         if spec_mixed:
             # §22 rng rule: the decode split is spent iff spec rounds
             # run, i.e. iff a row was ALREADY active — a freshly
@@ -3440,6 +3599,8 @@ class ContinuousBatchingEngine:
             budget_vec=budget_vec,
             prefill_tokens=prefill_tokens,
             prefill_kv_tokens=prefill_kv_tokens, n_active=n_active,
+            kv_window_tokens=kv_window_tokens,
+            prefill_window_pairs=prefill_window_pairs,
             live0=live0, kv_tokens=kv_tokens, spec_mixed=spec_mixed,
             k_vec=k_vec, k_disp=k_disp, num_rounds=num_rounds,
             dev=None, how=None, ahead_s=0.0)
@@ -3531,6 +3692,31 @@ class ContinuousBatchingEngine:
             a["start"], a["suffix"] = start, suffix
         for req, slot in plan.finals:
             self._tables[slot] = req._pkv["table"]
+        if self._wmgr is not None:
+            # what fell behind the window goes back to its pool, by the
+            # first token this dispatch writes for each request
+            first = {}
+            for (r0, a, _, _) in packed:
+                first.setdefault(id(a["req"]),
+                                 (a["req"], int(plan.seg[2][r0])))
+            for s in plan.rows:
+                if s is not None:
+                    first[id(s[0])] = (s[0], len(s[0].prompt) + s[1] - 1)
+            for req, lo in first.values():
+                self._window_release(req, lo)
+            # what the same requests would hold here with no window: a
+            # page a block of every token they hold (rows, and admissions
+            # as far as their chunks have come)
+            bt = self.kv_cache.block_tokens
+            ws = self.window_stats
+            ws["pages_unwindowed_peak"] = max(
+                ws["pages_unwindowed_peak"],
+                sum(-(-(len(s[0].prompt) + s[1]) // bt)
+                    for s in plan.rows if s is not None)
+                + sum(-(-a["start"] // bt) for a in self._adms))
+            for i, s in enumerate(plan.rows):
+                if s is not None:
+                    self._tables[i] = s[0]._pkv["table"]
         self.chunk_stats["chunks"] += plan.chunks
         # a request's queue wait ends at the launch of the first
         # dispatch that carries one of its segments: pending, waiting
@@ -3793,16 +3979,20 @@ class ContinuousBatchingEngine:
             # They are the rows the device routed: a row that holds no
             # token enters no expert's group
             acc = np.asarray(flight.out[5])
-            E = self.cfg.num_experts
+            E = self.cfg.experts_here
             record.update(self.moe_counters.add(
                 acc[:E], int(acc[E]), int(acc[E + 1]), int(acc[E + 2]),
                 (prefill_tokens + (n_active + len(plan.finals)) * steps)
-                * self.cfg.experts_per_token * self.cfg.num_layers
+                * self.cfg.experts_per_token
+                * (self.cfg.total_layers - self.cfg.lead_dense_layers)
                 * self.cfg.ut_steps))
         if self.loop_counters is not None:
             record.update(self.loop_counters.add(bool(packed), steps))
         if "prefill_kv_tokens" in self.dispatch_trace.extra_fields:
             record["prefill_kv_tokens"] = plan.prefill_kv_tokens
+        if self._wmgr is not None:
+            record["kv_window_tokens"] = plan.kv_window_tokens
+            record["prefill_window_pairs"] = plan.prefill_window_pairs
         cs = self.chunk_stats
         cs["mixed_dispatches"] += 1
         cs["mixed_prefill_tokens"] += prefill_tokens
@@ -3821,7 +4011,7 @@ class ContinuousBatchingEngine:
                 st = req._pkv
                 plen = len(req.prompt)
                 bt = self.kv_cache.block_tokens
-                if plen // bt >= 1:
+                if plen // bt >= 1 and self._wmgr is None:
                     adopted, store_lease = self.kv_cache.store_shared(
                         req.prompt, st["table"][:plen // bt])
                     st["adopted"] = adopted

@@ -109,10 +109,12 @@ class PagedKVCacheManager:
 
     @classmethod
     def for_model(cls, cfg, num_blocks: int, block_tokens: int,
-                  dtype=None,
-                  kv_dtype: Optional[str] = None) -> "PagedKVCacheManager":
+                  dtype=None, kv_dtype: Optional[str] = None,
+                  planes: Optional[int] = None) -> "PagedKVCacheManager":
+        """``planes``: the planes of THIS pool where the model has one a
+        kind of block (``ModelConfig.cache_kinds``); every plane, else."""
         dtype = dtype if dtype is not None else cfg.dtype
-        return cls(cfg.kv_planes, *cfg.kv_page_shape,
+        return cls(planes or cfg.kv_planes, *cfg.kv_page_shape,
                    num_blocks, block_tokens, dtype, kv_dtype=kv_dtype,
                    streams=cfg.kv_streams)
 

@@ -207,6 +207,11 @@ LOOP_DISPATCH_FIELDS = ("ut_passes",)
 # itself (what the prefill kernel's arithmetic is proportional to, as
 # ``kv_tokens x steps`` is for the decode kernel's)
 LATENT_DISPATCH_FIELDS = ("prefill_kv_tokens",)
+# a model with a window kind of block: what its window kernels had to
+# read.  ``kv_window_tokens``: the sum over the rows that decode of
+# min(tokens held, window); ``prefill_window_pairs``: the (query, key)
+# pairs inside the window that the slab's prompt tokens attend over
+WINDOW_DISPATCH_FIELDS = ("kv_window_tokens", "prefill_window_pairs")
 _DISPATCH_RING = 128       # x ~135 bytes a row: /stats stays under 18 KB
 # a span that is work (every one but ``await``) and lasts this long is a
 # stall: ten times the longest ordinary span (four chips' ``ahead``,
@@ -266,8 +271,12 @@ class MoeCounters:
     ``/stats.moe`` section.  Scheduler thread writes (one :meth:`add` a
     mixed dispatch), ``/stats`` reads."""
 
-    def __init__(self, num_experts: int):
+    def __init__(self, num_experts: int, routed: Optional[int] = None):
+        """``num_experts``: the experts whose matrices are HERE;
+        ``routed``: the experts the router scores (more, where this chip
+        holds a share: ``ModelConfig.experts_held``)."""
         self.num_experts = num_experts
+        self.routed = routed if routed is not None else num_experts
         self.reset()
 
     def reset(self) -> None:
@@ -295,7 +304,10 @@ class MoeCounters:
                     moe_touched=touched, moe_load_max=load_max)
 
     def snapshot(self) -> dict:
-        return {"experts": self.num_experts,
+        share = ({"experts_routed": self.routed,
+                  "rows_absent": self.valid_rows - self.rows}
+                 if self.routed != self.num_experts else {})
+        return {"experts": self.num_experts, **share,
                 "dispatches": self.dispatches, "rows": self.rows,
                 "valid_rows": self.valid_rows, "touched": self.touched,
                 "load_max": self.load_max,

@@ -1,0 +1,279 @@
+"""A period model (family ``laguna``, PR 46) in the ENGINE: served through
+the mixed dispatch over a pool a kind of block, window pages given back
+while a request runs, prefix sharing off; every older model's program the
+parent's; and what is built for one kind of block refusing in a sentence.
+``tests/test_laguna.py`` holds the model, the kernels and the share."""
+import dataclasses
+import hashlib
+import json
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_inference_demo_tpu.models.base import (BlockKind, KVCache,
+                                                        ModelConfig,
+                                                        StageSpec,
+                                                        slice_stage,
+                                                        split_layer_ranges)
+from distributed_inference_demo_tpu.models.decoder import (_moe_routed,
+                                                           init_full_params,
+                                                           stage_forward)
+from distributed_inference_demo_tpu.models.registry import (MODEL_REGISTRY,
+                                                            get_model_config)
+from distributed_inference_demo_tpu.ops import rope
+from distributed_inference_demo_tpu.ops.paged_attention import (
+    paged_flash_attention, paged_gather_attention, paged_prefill_attention,
+    sub_chunk, window_tables)
+from distributed_inference_demo_tpu.ops.sampling import SamplingParams
+from distributed_inference_demo_tpu.ops.stacked import LayerOf
+from distributed_inference_demo_tpu.runtime.batching import (
+    ContinuousBatchingEngine)
+from test_mixed_batching import abstract_mixed_call
+
+ROOT = Path(__file__).resolve().parent.parent
+for extra in ("benchmark", "tools"):
+    if str(ROOT / extra) not in sys.path:
+        sys.path.insert(0, str(ROOT / extra))
+
+import families  # noqa: E402  (benchmark/)
+import model_parity  # noqa: E402  (tools/)
+
+CFG = get_model_config("laguna-test")
+MC = dataclasses.asdict(CFG)
+SPEC = StageSpec(0, 1, 0, CFG.num_layers)
+GREEDY = SamplingParams(temperature=0.0)
+MIXED = dict(prefill_chunk=8, decode_block=4, mixed_token_budget=24)
+PARENT = json.loads((ROOT / "tests" / "data" / "mixed_step_hlo_pr45.json")
+                    .read_text())
+FAM = families.load("laguna")
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_full_params(jax.random.PRNGKey(0), CFG)
+
+
+def _engine(params, cfg=CFG, **kw):
+    kw.setdefault("max_seq", 200)
+    kw.setdefault("max_batch", 3)
+    kw.setdefault("kv_block_tokens", 4)
+    return ContinuousBatchingEngine(cfg, params, sampling=GREEDY, **kw)
+
+
+# ------------------------------------------------------------ the engine
+
+def _dense_greedy(params, prompt, new):
+    cache = KVCache.create(CFG, CFG.num_layers, 1, len(prompt) + new + 8)
+    logits, cache = stage_forward(params, CFG, SPEC, jnp.asarray([prompt]),
+                                  cache, jnp.arange(len(prompt))[None])
+    out = [int(logits[0, -1].argmax())]
+    for t in range(new - 1):
+        logits, cache = stage_forward(
+            params, CFG, SPEC, jnp.asarray([[out[-1]]]), cache,
+            jnp.asarray([[len(prompt) + t]]))
+        out.append(int(logits[0, -1].argmax()))
+    return out
+
+
+def test_engine_serves_the_dense_path_s_tokens_and_returns_its_pages(params):
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 256, size=n) for n in (37, 70, 9, 50)]
+    with _engine(params, **MIXED) as eng:
+        reqs = [eng.submit(p, 24) for p in prompts]
+        outs = [list(r.wait(300)) for r in reqs]
+        st = eng.stats()
+        assert eng._wmgr.used_blocks == 0 and eng._window_reserved == 0
+        assert eng.kv_cache.used_blocks == 0
+    for p, out in zip(prompts, outs):
+        assert out == _dense_greedy(params, list(p), 24)
+    kinds = st["kvcache"]["kinds"]
+    assert kinds["window"]["pages_returned"] > 0
+    assert kinds["full"]["blocks_total"] == st["kvcache"]["blocks_total"]
+    assert st["kvcache"]["bytes_per_token"] == 3 * 2 * 2 * 16 * 4
+    assert kinds["window"]["bytes_per_token"] == 6 * 2 * 2 * 16 * 4
+    assert set(st["attention_paths"]) == {"mixed_step/full",
+                                          "mixed_step/window"}
+    moe = st["moe"]
+    assert (moe["experts"], moe["experts_routed"]) == (4, 16)
+    assert len(moe["expert_rows"]) == 4
+    assert moe["rows"] + moe["rows_absent"] == moe["valid_rows"]
+    assert 0.1 < moe["rows"] / moe["valid_rows"] < 0.45
+    fields = st["dispatch_trace"]["fields"]
+    assert {"kv_window_tokens", "prefill_window_pairs"} <= set(fields)
+
+
+def test_a_request_of_twenty_windows_holds_its_window_and_a_dispatch(params):
+    """160 tokens under a window of 8, pages of 4: the window kind never
+    holds more than its quota (the window's pages, two dispatches' tokens
+    and one), where no window would hold 40; and the pool's free count is
+    back where it started."""
+    prompt = np.random.default_rng(1).integers(0, 256, size=100)
+    with _engine(params, **MIXED) as eng:
+        start = eng._wmgr.free_blocks
+        quota = eng._window_quota
+        assert quota == -(-(8 + 2 * 24) // 4) + 1
+        out = eng.submit(prompt, 60).wait(300)
+        assert len(out) == 60
+        ws = dict(eng.window_stats)
+        assert eng._wmgr.free_blocks == start
+    assert 3 <= ws["pages_held_peak"] <= quota
+    assert ws["pages_unwindowed_peak"] >= 39
+    assert ws["pages_returned"] >= 35
+
+
+def test_prefix_sharing_is_off_under_a_window(params):
+    """Two requests with one prompt: no hit, no store, the same tokens."""
+    prompt = np.random.default_rng(2).integers(0, 256, size=48)
+    with _engine(params, **MIXED) as eng:
+        a = list(eng.submit(prompt, 8).wait(300))
+        b = list(eng.submit(prompt, 8).wait(300))
+        kv = eng.stats()["kvcache"]
+    assert a == b
+    assert kv["hits"] == 0 and kv["stores"] == 0 and kv["tree_blocks"] == 0
+
+
+# ------------------------------------------- every other model is what it was
+
+def _parent_engine(model):
+    cfg = get_model_config(model)
+    return ContinuousBatchingEngine(
+        cfg, init_full_params(jax.random.PRNGKey(0), cfg), max_seq=96,
+        max_batch=4, sampling=GREEDY, kv_block_tokens=8, prefill_chunk=8,
+        decode_block=4, mixed_token_budget=24)
+
+
+@pytest.mark.parametrize("slab", [False, True], ids=["decode", "slab"])
+@pytest.mark.parametrize("model", ["qwen2-test", "bloom-test", "olmoe-test",
+                                   "ouro-test", "kanana-test"])
+def test_every_existing_model_lowers_to_the_parent_s_program(model, slab):
+    """``mixed_step`` of the five older toy families, as lowered, is the
+    parent's (9760ca4, PR 45) character for character: the period, the
+    window bound, the gate and the share are Python their traces never
+    take."""
+    if jax.__version__ != PARENT["jax"]:
+        pytest.skip(f"hashes were made under jax {PARENT['jax']}")
+    with _parent_engine(model) as eng:
+        text = eng._mixed_step.inner.lower(
+            *abstract_mixed_call(eng, slab)).as_text()
+    key = f"{model}.{'slab' if slab else 'decode'}"
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENT["sha256"][key]
+
+
+# --------------------------------------------- what refuses, in a sentence
+
+def _draft(params):
+    llama = get_model_config("llama-test")
+    ContinuousBatchingEngine(
+        llama, init_full_params(jax.random.PRNGKey(0), llama), max_seq=64,
+        max_batch=2, draft_cfg=CFG, draft_params=params, num_draft=2)
+
+
+def _tp(params):
+    from distributed_inference_demo_tpu.parallel.mesh import (MeshConfig,
+                                                              make_mesh)
+    from distributed_inference_demo_tpu.parallel.tensor import validate_tp
+    validate_tp(CFG, make_mesh(MeshConfig(tp=2)))
+
+
+def _export(params):
+    with _engine(params, **MIXED) as eng:
+        eng.export_request("nobody")
+
+
+def _import(params):
+    with _engine(params, **MIXED) as eng:
+        eng.import_request({"tokens": [1], "length": 3})
+
+
+def _premigrated(params):
+    with _engine(params, **MIXED) as eng:
+        z = np.zeros((1, 4, 2, 4, 16), np.float32)
+        eng.submit_premigrated(np.arange(1, 12, dtype=np.int32), 2, z, z)
+
+
+def _ring(params):
+    from distributed_inference_demo_tpu.parallel.sequence import (
+        _make_ring_cores)
+    _make_ring_cores(CFG, SPEC, 16, GREEDY, None)
+
+
+def _ulysses(params):
+    from distributed_inference_demo_tpu.parallel.ulysses import (
+        _make_ulysses_cores)
+    _make_ulysses_cores(CFG, 32, 2, GREEDY, None)
+
+
+def _one_kind_hook(params):
+    hook = lambda *a: None
+    stage_forward(params, CFG, SPEC, jnp.asarray([[1, 2]]),
+                  KVCache.create(CFG, CFG.num_layers, 1, 8),
+                  jnp.arange(2)[None], attn_impl=hook)
+
+
+def _load(params):
+    from distributed_inference_demo_tpu.models.loader import (
+        params_from_state_dict)
+    params_from_state_dict({}, CFG)
+
+
+KINDS = "does not support a model of more than one kind of block"
+REFUSALS = {
+    "int8 pages": (ValueError, "a page pool of int8 pages " + KINDS,
+                   lambda p: _engine(p, kv_dtype="int8", **MIXED)),
+    "int4 pages": (ValueError, "a page pool of int4 pages " + KINDS,
+                   lambda p: _engine(p, kv_dtype="int4", **MIXED)),
+    "host tier": (ValueError, "the host tier of the KV cache " + KINDS,
+                  lambda p: _engine(p, kv_host_tier_bytes=1 << 20, **MIXED)),
+    "export_request": (ValueError, r"export_request \(migration\) " + KINDS,
+                       _export),
+    "import_request": (ValueError, r"import_request \(migration\) " + KINDS,
+                       _import),
+    "premigrated prefill": (ValueError, "a premigrated prefill .* " + KINDS,
+                            _premigrated),
+    "draft": (ValueError, "the draft side of speculation " + KINDS, _draft),
+    "prompt lookup": (ValueError, "speculation .* " + KINDS,
+                      lambda p: _engine(p, prompt_lookup=True, **MIXED)),
+    "serialized interleave": (ValueError, "the serialized interleave .* "
+                              + KINDS, lambda p: _engine(p, prefill_chunk=8)),
+    "manual TP": (ValueError, r"tensor parallelism \(--tp\) " + KINDS, _tp),
+    "pipeline stages": (ValueError, "a pipeline of stages " + KINDS,
+                        lambda p: slice_stage(p, CFG,
+                                              split_layer_ranges(2, 2)[0])),
+    "ring sequence parallelism": (ValueError,
+                                  "ring sequence parallelism " + KINDS,
+                                  _ring),
+    "ulysses": (ValueError, "Ulysses sequence parallelism " + KINDS,
+                _ulysses),
+    "a hook for one kind": (ValueError, "needs an attention hook that knows "
+                            "its pools", _one_kind_hook),
+    "a checkpoint": (NotImplementedError, "no state-dict mapper for family "
+                     "'laguna'", _load),
+}
+
+
+@pytest.mark.parametrize("what", sorted(REFUSALS))
+def test_what_is_built_for_one_kind_refuses_in_a_sentence(what, params):
+    error, sentence, build = REFUSALS[what]
+    with pytest.raises(error, match=sentence):
+        build(params)
+
+
+def test_serve_chain_refuses_a_period_model_in_a_sentence(capsys):
+    from distributed_inference_demo_tpu import cli
+    assert cli.main(["serve", "--model", "laguna-test", "--chain",
+                     "w1@127.0.0.1:1", "--device-id", "h"]) == 1
+    assert KINDS in capsys.readouterr().err
+
+
+def test_a_share_of_the_experts_and_tensor_parallelism_do_not_compose():
+    cfg = CFG.of_kind(CFG.period[0])
+    from distributed_inference_demo_tpu.models.decoder import (
+        init_layer_params)
+    lp = jax.tree.map(lambda a: a[0], init_layer_params(
+        jax.random.PRNGKey(5), cfg, 1))
+    with pytest.raises(ValueError, match="both cut the expert stacks"):
+        _moe_routed(cfg, lp, jnp.zeros((1, 2, 64)), tp_axis="tp")

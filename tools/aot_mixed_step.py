@@ -111,7 +111,7 @@ def compile_mixed_step(model: str, blocks: int, args, segments=None):
             mixed_token_budget=args.mixed_token_budget,
             kv_cache_blocks=blocks, kv_block_tokens=args.kv_block_tokens)
     try:
-        B, W = args.batch_slots, eng._table_width
+        B, W = args.batch_slots, eng._table_cols  # a table a pool
         r = eng._mixed_seg_cap if segments is None else segments
 
         def S(shape, dtype):
@@ -227,12 +227,17 @@ def main(argv=None) -> int:
               f"{eng.attn_paths.snapshot()}; pool "
               f"{eng.attn_paths.addressing()}; pallas calls {calls}",
               flush=True)
-        leaf = jax.tree.leaves(eng._pk)[0]
-        plane = leaf.dtype.itemsize * math.prod(leaf.shape[1:])
-        print(f"  pool leaf {leaf.dtype}{list(leaf.shape)} "
-              f"{plane * leaf.shape[0] / GIB:.2f} GiB, one plane "
-              f"{plane / (1 << 20):.1f} MiB; ops with a result of at "
-              f"least a plane:", flush=True)
+        # one pool a kind of block (a model of one kind: one pool); the
+        # smallest plane bounds the list below
+        leaves = jax.tree.leaves(eng._pk)
+        plane = min(leaf.dtype.itemsize * math.prod(leaf.shape[1:])
+                    for leaf in leaves)
+        for leaf in leaves:
+            size = leaf.dtype.itemsize * math.prod(leaf.shape)
+            print(f"  pool leaf {leaf.dtype}{list(leaf.shape)} "
+                  f"{size / GIB:.2f} GiB, one plane "
+                  f"{size / leaf.shape[0] / (1 << 20):.1f} MiB", flush=True)
+        print("  ops with a result of at least a plane:", flush=True)
         for name, opcode, shape, size in large_ops(hlo, plane):
             print(f"    {name}  {opcode}  {shape}  "
                   f"{size / (1 << 20):.1f} MiB", flush=True)

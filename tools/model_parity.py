@@ -55,6 +55,15 @@ rows and the router's matrix are bf16 as stored, so the float32 matmul
 and the bf16 one differ only in a rounding of the result that the
 compiler elides before the widening.
 
+A period model (family ``laguna``: ``--model laguna-s-2.1-bf16-ep4``) is
+read the same two ways, through one pool and one table a kind of block;
+the window kind's pages are a ring (``period_tables``), so the long
+reading runs sixteen windows deep over pages that came back from behind
+the window.  It is held against two controls that must be refused:
+``--bf16-softmax-state`` (the paged kernels' state rounded to bf16
+between pages) and ``--window-ignored`` (its window blocks served as full
+ones): ``READINGS_PERIOD``.
+
 One ``MODEL_PARITY {json}`` line, exit code 1 if a limit is passed.
 """
 
@@ -102,12 +111,24 @@ READINGS_LATENT = {  # kanana-2-30b-a3b-bf16, TPU v5e; my chip runs, PR 44
         (0.0815, 0.173, 0.01424), (0.0889, 0.195, 0.01543),
         (0.0815, 0.173, 0.01424)],
 }
+# (max_over_vocab_mean, max_over_vocab_max, mean_abs); my chip runs, PR 46,
+# TPU v5 lite, laguna-s-2.1-bf16-ep4 at published widths (1 leading block
+# + one period, 64 of 256 experts held), seeds 1 and 2
+READINGS_PERIOD = {
+    "served, 2 x 512 + 16": [(0.1023, 0.134, 0.0177), (0.1026, 0.138, 0.0178)],
+    "served, 1 x 8192 + 16": [(0.0906, 0.106, 0.0162), (0.0936, 0.109, 0.0167)],
+    "--bf16-softmax-state, 1 x 8192 + 16, seed 1": [(0.1628, 0.231, 0.0288)],
+    "--window-ignored, 1 x 8192 + 16, seed 1": [(0.7447, 0.858, 0.1314)],
+}
 # limits a family's own readings set: (mean, max).  deepseek_v3: the mean
 # between 0.0763 (sound, long) and 0.1403 (bf16 state, long), 1.3-1.4 x
 # room on both sides; the maximum is every family's, 2.4 x the largest
 # sound one here: a gross fault (the lower precision is refused by the
 # mean, not by each limit: its maxima read 0.166-0.185)
-FAMILY_TOL = {"deepseek_v3": (0.10, TOL_MAX)}
+# laguna: the mean between 0.1026 (the largest sound reading, short) and
+# 0.1628 (bf16 state, long), 1.25 x room on both sides; a window ignored
+# reads 0.74 (refused by both limits)
+FAMILY_TOL = {"deepseek_v3": (0.10, TOL_MAX), "laguna": (0.13, TOL_MAX)}
 
 
 def seeded_ids(seed: int, n: int, vocab: int):
@@ -140,9 +161,41 @@ def bf16_softmax_state():
     model's long-context reading is held against."""
     import jax.numpy as jnp
 
-    from distributed_inference_demo_tpu.ops import latent_attention
+    from distributed_inference_demo_tpu.ops import (latent_attention,
+                                                    paged_attention)
 
     latent_attention._STATE_DTYPE = jnp.bfloat16
+    paged_attention._STATE_DTYPE = jnp.bfloat16   # rounded between pages
+
+
+def window_ignored(cfg):
+    """``cfg`` with every window kind's window wider than any context:
+    its blocks are then served as full ones (``--window-ignored``, the
+    control a wrong window must not pass; the reference keeps the
+    window)."""
+    wide = lambda k: dataclasses.replace(k, window=1 << 30) if k.window else k
+    return cfg.replace(period=tuple(wide(k) for k in cfg.period))
+
+
+def period_tables(cfg, b: int, W: int, bt: int, lo: int, hi: int,
+                  span: int):
+    """A period model's tables for a call that writes tokens ``[lo, hi)``
+    of every row, ``[b, pools x W]`` (one table a pool side by side, the
+    full kind's first), and the pages of each pool.  The window kind's
+    pages are a RING of as many as its window and one call's tokens meet:
+    a page that fell behind the window is the page a later block is
+    written to, as in the engine, where it went back to the pool and came
+    out again; table entries behind the window are sentinel."""
+    import numpy as np
+    window = cfg.cache_kinds[1][0]
+    ring = -(-(min(window, W * bt) + span) // bt) + 1
+    sentinel = b * W
+    full = np.arange(b * W, dtype=np.int32).reshape(b, W)
+    win = np.full((b, W), sentinel, np.int32)
+    first = max(0, lo - window + 1) // bt
+    for j in range(first, min(W, -(-hi // bt))):
+        win[:, j] = np.arange(b) * ring + j % ring
+    return np.concatenate([full, win], 1), (b * W, b * ring)
 
 
 def served_logprobs(cfg, params, prompts, args):
@@ -168,12 +221,24 @@ def served_logprobs(cfg, params, prompts, args):
         cfg, StageSpec(0, 1, 0, cfg.num_layers), None, params, bt,
         record=record)
     heads, width = cfg.kv_page_shape
-    pk, pv = alloc_kv_pool((cfg.kv_planes, b * W, heads, bt, width),
-                           args.kv_dtype, cfg.dtype, streams=cfg.kv_streams)
-    tables = jnp.arange(b * W, dtype=jnp.int32).reshape(b, W)
+    if cfg.mixed_kinds:     # one pool and one table a kind of block
+        _, pages = period_tables(cfg, b, W, bt, 0, C, C)
+        pools = [alloc_kv_pool((planes, n, heads, bt, width), args.kv_dtype,
+                               cfg.dtype)
+                 for (_, planes), n in zip(cfg.cache_kinds, pages)]
+        pk, pv = tuple(p[0] for p in pools), tuple(p[1] for p in pools)
+
+        def tables_for(lo, hi):
+            return jnp.asarray(period_tables(cfg, b, W, bt, lo, hi, C)[0])
+    else:
+        pk, pv = alloc_kv_pool((cfg.kv_planes, b * W, heads, bt, width),
+                               args.kv_dtype, cfg.dtype,
+                               streams=cfg.kv_streams)
+        whole = jnp.arange(b * W, dtype=jnp.int32).reshape(b, W)
+        tables_for = lambda lo, hi: whole
 
     @jax.jit
-    def chunk(params, pk, pv, ids, start):
+    def chunk(params, pk, pv, ids, start, tables):
         bind(tables, "prefill")
         pos = start + jnp.broadcast_to(jnp.arange(ids.shape[1]), ids.shape)
         logits, cache = fwd(params, ids, KVCache(pk, pv, jnp.int32(0)),
@@ -182,7 +247,7 @@ def served_logprobs(cfg, params, prompts, args):
                 cache.keys, cache.values)
 
     @jax.jit
-    def step(params, pk, pv, tok, length):
+    def step(params, pk, pv, tok, length, tables):
         bind(tables, "decode")
         logits, cache = fwd(params, tok[:, None],
                             KVCache(pk, pv, jnp.int32(0)), length[:, None],
@@ -193,13 +258,14 @@ def served_logprobs(cfg, params, prompts, args):
     for start in range(0, plen, C):
         lp, pk, pv = chunk(params, pk, pv,
                            jnp.asarray(prompts[:, start:start + C]),
-                           jnp.int32(start))
+                           jnp.int32(start), tables_for(start, start + C))
     lps, toks = [np.asarray(lp)], []
     length = jnp.full((b,), plen, jnp.int32)
-    for _ in range(args.steps):
+    for t in range(args.steps):
         tok = jnp.argmax(lp, -1).astype(jnp.int32)
         toks.append(np.asarray(tok))
-        lp, pk, pv = step(params, pk, pv, tok, length)
+        lp, pk, pv = step(params, pk, pv, tok, length,
+                          tables_for(plen + t, plen + t + 1))
         lps.append(np.asarray(lp))
         length = length + 1
     return np.stack(toks, 1), np.stack(lps, 1), record.snapshot()
@@ -224,7 +290,8 @@ def reference_logprobs(cfg, params, ids, n_prompt: int):
 
     mc = dataclasses.asdict(cfg)
     embed, layer_eq, final_norm = families.load(cfg.family).equations(mc)
-    E, k = cfg.num_experts, cfg.experts_per_token
+    # (a period model's leaves are named by kind: no margins are read)
+    E, k = 0 if cfg.mixed_kinds else cfg.num_experts, cfg.experts_per_token
 
     @jax.jit
     def margin(x, layers, i):
@@ -274,7 +341,10 @@ def main(argv=None) -> int:
     ap.add_argument("--bf16-router", action="store_true",
                     help="swap in a bf16 router (see READINGS)")
     ap.add_argument("--bf16-softmax-state", action="store_true",
-                    help="latent kernels: online-softmax state in bf16")
+                    help="attention kernels: online-softmax state in bf16")
+    ap.add_argument("--window-ignored", action="store_true",
+                    help="serve a period model's window blocks as full "
+                         "ones (a control: must be refused)")
     args = ap.parse_args(argv)
     from distributed_inference_demo_tpu.cli import configure_compile_cache
     configure_compile_cache()
@@ -297,7 +367,9 @@ def main(argv=None) -> int:
     prompts = np.stack([seeded_ids(args.seed * 1000 + 17 + i, args.prompt,
                                    cfg.vocab_size)
                         for i in range(args.batch)])
-    toks, served, paths = served_logprobs(cfg, params, prompts, args)
+    toks, served, paths = served_logprobs(
+        window_ignored(cfg) if args.window_ignored else cfg, params,
+        prompts, args)
     t_served = time.monotonic() - t0
     worst, own, margins, means = [], [], [], []
     for r in range(args.batch):
@@ -317,6 +389,7 @@ def main(argv=None) -> int:
            "device_kind": dev.device_kind, "kv_dtype": args.kv_dtype,
            "bf16_router": args.bf16_router,
            "bf16_softmax_state": args.bf16_softmax_state,
+           "window_ignored": args.window_ignored,
            "batch": args.batch,
            "prompt": args.prompt, "steps": args.steps,
            "positions": len(worst), "paths": paths,
