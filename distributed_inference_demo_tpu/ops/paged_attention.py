@@ -26,7 +26,7 @@ to nowhere); reads CLAMP (the gathered garbage is causally masked, and
 pool pages always hold finite values, so masked garbage contributes
 exact zeros).
 
-Two interchangeable compute paths (same numerics as ``ops.attention``):
+Interchangeable compute paths (same numerics as ``ops.attention``):
 
 - :func:`paged_gather_attention` — pure XLA ``jnp.take`` gather of the
   table's pages into a linear view + the reference ``attention``.  Runs
@@ -41,6 +41,27 @@ Two interchangeable compute paths (same numerics as ``ops.attention``):
   online-softmax accumulators carried by the loop.  A call's time is
   ``batch`` grid steps plus the live pages — the table's width ``W`` is
   not in it, and a freed slot costs its grid step alone.
+- :func:`paged_prefill_attention` — the Pallas TPU prefill kernel, the
+  same walk for a chunk of queries: grid ``(rows of the call, blocks of
+  kv heads)``, one step one QUERY TILE (a chunk, or a sub-chunk of one:
+  :func:`sub_chunk`) over as many kv heads as its float32 state lets
+  VMEM hold (:func:`_heads_a_step`).  The step loops over the tile's
+  live pages, from the page of the first key its first query sees (0
+  without a window) to its own causal frontier ``ceil((start + chunk) /
+  bt)``, copying those heads of page ``tables[b, j]`` (``[heads, bt,
+  hd]``, one DMA) through a VMEM ring and folding them, head by head,
+  into float32 online-softmax state in scratch (:func:`_fold_page`).
+  The table's width is not in a call's time, and a page the chunk cannot
+  see costs nothing.
+
+The gate both loops share (:func:`_page_loop_covers`): Mosaic (jax 0.9)
+slices an HBM ref by hand only where its minor dimension fills the 128
+lanes, so heads that are no multiple of 128 and int8 pages under 128
+tokens keep the prefill GRID kernel (:func:`_paged_prefill_kernel`: grid
+``(b, nkv, W)``, one page of the table a step through the BlockSpec
+pipeline, the decode path of those shapes as a 1-token chunk).  It folds
+with the same :func:`_fold_page`, so the two agree bit for bit; no served
+configuration runs it.
 """
 
 import functools
@@ -410,6 +431,24 @@ def paged_gather_attention(
 _RING_BYTES = 1 << 20
 
 
+def _ring_depth(page_bytes: int) -> int:
+    return max(2, min(8, _RING_BYTES // page_bytes))
+
+
+def _page_loop_covers(k_pages) -> bool:
+    """Whether a kernel may copy this pool's pages by hand (the page loop
+    of :func:`_paged_kernel` and :func:`_paged_prefill_loop_kernel`).
+    Mosaic (jax 0.9) slices an HBM ref only where the ref's minor
+    dimension fills the 128 lanes: a head that is a multiple of 128 and,
+    for int8 pages, a scale sidecar ``[.., bt]`` of 128-token pages.
+    Every other shape keeps the BlockSpec pipeline, whose grid has the
+    table's width in it."""
+    k_pages = _pool(k_pages)
+    hd, bt = k_pages.shape[-1], k_pages.shape[-2]
+    return not (hd % 128
+                or (isinstance(k_pages, QuantizedKVPages) and bt % 128))
+
+
 def _paged_kernel(tab_ref, len_ref, layer_ref, q_ref, *refs,
                   block_tokens: int, ring: int, use_alibi: bool,
                   quantized: bool, window: int = 0):
@@ -533,7 +572,7 @@ def _paged_call_body(q_g, k_pages, v_pages, layer, tables, kv_lens, slopes,
     bt = block_tokens
     k_data = k_pages.data if quantized else k_pages
     page_bytes = nkv * bt * hd * k_data.dtype.itemsize
-    ring = max(2, min(8, _RING_BYTES // page_bytes))
+    ring = _ring_depth(page_bytes)
 
     row_spec = pl.BlockSpec((1, nkv, rows, hd),
                             lambda bb, tab, lens, lay: (bb, 0, 0, 0))
@@ -641,12 +680,11 @@ def paged_flash_attention(
     if bt % 8:
         raise ValueError(f"block_tokens must be a multiple of 8 for the "
                          f"Pallas kernel, got {bt}")
-    if hd % 128 or (isinstance(K, QuantizedKVPages) and bt % 128):
-        # Mosaic copies a slice of an HBM ref only where the ref's minor
-        # dimension fills the 128 lanes.  A narrower head, or a scale
-        # sidecar of a narrower page, goes through the prefill kernel's
-        # BlockSpec pipeline as a 1-token chunk: the same fold, over a
-        # grid that still has the table's width in it
+    if not _page_loop_covers(K):
+        # a narrow head, or a scale sidecar of a narrow page, goes
+        # through the prefill kernel's BlockSpec pipeline as a 1-token
+        # chunk: the same fold, over a grid that still has the table's
+        # width in it
         return paged_prefill_attention(
             q, k_pages, v_pages, tables, (kv_lens - 1)[:, None], slopes,
             interpret=interpret, window=window)
@@ -688,25 +726,264 @@ def paged_flash_attention(
 
 
 # ---------------------------------------------------------------------------
-# Pallas TPU prefill kernel (docs/DESIGN.md §19)
+# Pallas TPU prefill kernels (docs/DESIGN.md §19): one fold, two ways to
+# bring it its pages.  Where the kernel may copy pages by hand
+# (:func:`_page_loop_covers`: every served shape) a grid step is one
+# query tile and walks the tile's live pages; every other shape keeps a
+# grid step a page of the table.
+
+
+def _fold_page(q, k_blk, v_blk, state, kv_pos, q_pos, slope, window):
+    """One page folded into one kv head's online-softmax ``state``
+    ``(o [rows, hd], m [rows, 1], l [rows, 1])``, all float32: ``q``
+    [rows, hd] scaled, ``k_blk`` / ``v_blk`` [bt, hd] dequantized,
+    ``kv_pos`` [1, bt] and ``q_pos`` [rows, 1] the keys' and the rows'
+    positions.  The causal bound is per ROW (``kv_pos <= q_pos``), not
+    the single shared decode position: in-chunk keys were already written
+    to the pages by ``write_paged_kv`` (write-before-attend inside the
+    layer), so causality alone makes a query see exactly its prefix plus
+    its own earlier in-chunk keys.  Under a ``window`` a row sees keys
+    ``q_pos - window < j <= q_pos``.  Both prefill kernels fold with
+    this, so they agree bit for bit on the pages both visit."""
+    o, m, l = state
+    s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)  # [rows, bt]
+    valid = kv_pos <= q_pos
+    if window:
+        valid = valid & (q_pos - kv_pos < window)
+    if slope is not None:
+        s = s - slope * (q_pos - kv_pos).astype(jnp.float32)
+    s = jnp.where(valid, s, _NEG)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    alpha = jnp.exp(m - m_new)
+    l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    o_new = o * alpha + jnp.dot(p, v_blk,
+                                preferred_element_type=jnp.float32)
+    return _state(o_new), _state(m_new), _state(l_new)
+
+
+def _row_positions(start, rows: int, groups: int):
+    """Query position of each row of a tile [rows, 1]: row ``r`` is chunk
+    position ``r // g`` of q head ``h*g + r % g``.  Padding rows
+    (``r >= chunk*g``) see a position past the segment; their garbage
+    output is sliced away by the caller."""
+    return start + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, 1), 0) // groups
+
+
+def _walk_bounds(start, chunk: int, bt: int, width: int, window: int):
+    """``(first, end)``: the pages ``[first, end)`` of its table that a
+    query tile of ``chunk`` tokens at position ``start`` walks: up to its
+    causal frontier ``ceil((start + chunk) / bt)`` and never past the
+    table; under a ``window`` from the page of key ``start - window +
+    1``, the first its first query sees (else a Python 0).  On traced
+    scalars inside the kernel, on arrays of starts in a test."""
+    end = jnp.minimum((start + chunk + bt - 1) // bt, width)
+    if not window:
+        return 0, end
+    return jnp.minimum(jnp.maximum(start - window + 1, 0) // bt, end), end
+
+
+def _paged_prefill_loop_kernel(tab_ref, start_ref, layer_ref, q_ref, *refs,
+                               block_tokens: int, chunk: int, groups: int,
+                               ring: int, use_alibi: bool, quantized: bool,
+                               window: int = 0):
+    """Grid (b, kv head blocks): one step is ONE query tile (a row of the
+    call: a chunk or a sub-chunk of one segment) over ``heads`` kv heads,
+    and walks the tile's live pages: from the page of the first key its
+    first query sees (0 without a ``window``) to its own causal frontier
+    ``ceil((start + chunk) / bt)``, never past the table.  The stacked
+    pools stay in HBM; ``heads`` heads of page ``tables[b, j]`` of layer
+    ``layer_ref[0]`` (``[heads, bt, hd]``, contiguous in the pool) are
+    copied into slot ``j % ring`` of a VMEM ring while earlier pages
+    fold, head by head (:func:`_fold_page`), into float32 state in VMEM
+    scratch.  Entries of the table outside the walk are never read: a
+    window kind's sentinel entries behind the window, the tail past the
+    frontier.  A call's time is its tiles' grid steps plus the pages
+    they walk; the table's width is not in it.
+
+    tab_ref (SMEM int32 [b, W]): block tables; start_ref (SMEM int32
+    [b]): the position of each tile's column 0; layer_ref (SMEM int32
+    [1]): the layer of the stack.  With ``quantized`` the pools are int8
+    and each is followed by its f32 scale sidecar ``[L, N, nkv, bt]``,
+    copied page for page beside it."""
+    if quantized:
+        (k_hbm, ks_hbm, v_hbm, vs_hbm, slopes_ref, o_ref,
+         k_buf, ks_buf, v_buf, vs_buf, sems, o_acc, m_acc, l_acc) = refs
+    else:
+        (k_hbm, v_hbm, slopes_ref, o_ref,
+         k_buf, v_buf, sems, o_acc, m_acc, l_acc) = refs
+    b = pl.program_id(0)
+    layer = layer_ref[0]
+    num_pages, W = k_hbm.shape[1], tab_ref.shape[1]
+    _, heads, rows, hd = q_ref.shape
+    mine = pl.ds(pl.program_id(1) * heads, heads)
+    start = start_ref[b]
+    bt = block_tokens
+    first, n_live = _walk_bounds(start, chunk, bt, W, window)
+
+    def page_copies(j):
+        # sentinel entries clamp in-range: the garbage is masked
+        page = jnp.minimum(tab_ref[b, j], num_pages - 1)
+        slot = j % ring
+        streams = [(k_hbm, k_buf), (v_hbm, v_buf)]
+        if quantized:
+            streams += [(ks_hbm, ks_buf), (vs_hbm, vs_buf)]
+        return [pltpu.make_async_copy(hbm.at[layer, page, mine],
+                                      buf.at[slot], sems.at[slot, i])
+                for i, (hbm, buf) in enumerate(streams)]
+
+    for j in range(ring - 1):
+        @pl.when(first + j < n_live)
+        def _prime():
+            for c in page_copies(first + j):
+                c.start()
+
+    o_acc[...] = jnp.zeros_like(o_acc)
+    m_acc[...] = jnp.full_like(m_acc, _NEG)
+    l_acc[...] = jnp.zeros_like(l_acc)
+    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+    q_pos = _row_positions(start, rows, groups)
+
+    def fold(j, carry):
+        @pl.when(j + ring - 1 < n_live)
+        def _prefetch():
+            for c in page_copies(j + ring - 1):
+                c.start()
+
+        for c in page_copies(j):
+            c.wait()
+        slot = j % ring
+        kv_pos = j * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+        if quantized:
+            ks = ks_buf[slot][:, :, None]               # [heads, bt, 1]
+            vs = vs_buf[slot][:, :, None]
+        for h in range(heads):
+            q = q_ref[0, h].astype(jnp.float32) * scale
+            k_blk = k_buf[slot, h].astype(jnp.float32)  # [bt, hd]
+            v_blk = v_buf[slot, h].astype(jnp.float32)
+            if quantized:
+                k_blk = k_blk * ks[h]
+                v_blk = v_blk * vs[h]
+            slope = slopes_ref[h, 0, :][:, None] if use_alibi else None
+            o, m, l = _fold_page(
+                q, k_blk, v_blk, (o_acc[h], m_acc[h][:, :1],
+                                  l_acc[h][:, :1]),
+                kv_pos, q_pos, slope, window)
+            o_acc[h] = o
+            m_acc[h] = jnp.broadcast_to(m, m_acc.shape[1:])
+            l_acc[h] = jnp.broadcast_to(l, l_acc.shape[1:])
+        return carry
+
+    jax.lax.fori_loop(first, n_live, fold, 0)
+    for h in range(heads):
+        o_ref[0, h] = (o_acc[h] / jnp.maximum(l_acc[h][:, :1], 1e-30)
+                       ).astype(o_ref.dtype)
+
+
+# bytes of float32 online-softmax state one grid step of the loop holds:
+# a step takes as many of a tile's kv heads as this buys, so that one
+# copy moves them all (laguna's 8 x 384 rows whole, 16 of bloom's
+# 32 x 256)
+_PREFILL_STATE_BYTES = 6 << 20
+
+
+def _state_bytes(rows: int, hd: int) -> int:
+    """One kv head's state: the output ``[rows, hd]`` and the running
+    maximum and sum, ``[rows, 128]`` each, in float32."""
+    return rows * (hd + 2 * 128) * 4
+
+
+def _heads_a_step(nkv: int, rows: int, hd: int) -> int:
+    """The kv heads one grid step of the prefill loop folds: the largest
+    divisor of ``nkv`` whose state fits ``_PREFILL_STATE_BYTES``."""
+    fit = max(1, _PREFILL_STATE_BYTES // _state_bytes(rows, hd))
+    return max(h for h in range(1, nkv + 1) if nkv % h == 0 and h <= fit)
+
+
+def _paged_prefill_loop_call(q_g, k_pages, v_pages, layer, tables, starts,
+                             slopes, *, block_tokens, chunk, groups,
+                             use_alibi, interpret, window=0):
+    """The page loop's Pallas call: the pools are ``pl.ANY`` operands, so
+    nothing of them moves but the pages the kernel copies."""
+    b, nkv, rows, hd = q_g.shape
+    quantized = isinstance(k_pages, QuantizedKVPages)
+    bt = block_tokens
+    k_data = k_pages.data if quantized else k_pages
+    heads = _heads_a_step(nkv, rows, hd)
+    page_bytes = heads * bt * hd * k_data.dtype.itemsize
+    ring = _ring_depth(page_bytes)
+
+    tile_spec = pl.BlockSpec((1, heads, rows, hd),
+                             lambda bb, hb, *_: (bb, hb, 0, 0))
+    slopes_spec = pl.BlockSpec((heads, 1, rows),
+                               lambda bb, hb, *_: (hb, 0, 0))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    page_buf = pltpu.VMEM((ring, heads, bt, hd), k_data.dtype)
+    if quantized:
+        # the sidecar as [L, N, nkv, bt]: :func:`_paged_call_body`
+        scale_buf = pltpu.VMEM((ring, heads, bt), k_pages.scale.dtype)
+        in_specs = [tile_spec] + [pool_spec] * 4 + [slopes_spec]
+        operands = (q_g, k_pages.data, k_pages.scale[..., 0],
+                    v_pages.data, v_pages.scale[..., 0], slopes)
+        buffers = [page_buf, scale_buf, page_buf, scale_buf]
+    else:
+        in_specs = [tile_spec, pool_spec, pool_spec, slopes_spec]
+        operands = (q_g, k_pages, v_pages, slopes)
+        buffers = [page_buf, page_buf]
+    tile_bytes = heads * rows * hd * q_g.dtype.itemsize
+    return pl.pallas_call(
+        functools.partial(_paged_prefill_loop_kernel, block_tokens=bt,
+                          chunk=chunk, groups=groups, ring=ring,
+                          use_alibi=use_alibi, quantized=quantized,
+                          **({"window": window} if window else {})),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, nkv // heads),
+            in_specs=in_specs,
+            out_specs=tile_spec,
+            scratch_shapes=buffers + [
+                pltpu.SemaphoreType.DMA((ring, len(buffers))),
+                pltpu.VMEM((heads, rows, hd), jnp.float32),
+                pltpu.VMEM((heads, rows, 128), jnp.float32),
+                pltpu.VMEM((heads, rows, 128), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, nkv, rows, hd), q_g.dtype),
+        # the state, the tile in and out (each buffered twice), the two
+        # rings, and one head's fold in float32 (the tile, a page of K
+        # and of V, scores and weights) with room to spare
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=max(
+            16 << 20,
+            heads * _state_bytes(rows, hd) + 4 * tile_bytes
+            + 2 * ring * page_bytes
+            + 16 * rows * max(hd, bt) + 16 * bt * hd + (8 << 20))),
+        interpret=interpret,
+    )(tables, starts, layer, *operands)
+
+
+def window_tables(tables, starts, chunk: int, window: int, bt: int):
+    """``(tables [b, n], page0 [b])``: each row's table cut to the pages a
+    chunk at ``starts[b]`` can see under ``window`` (from the page of key
+    ``starts[b] - window + 1`` to the page of the chunk's last token: at
+    most ``n`` of them, a static count)."""
+    n = min(tables.shape[1], -(-(chunk + window - 1) // bt) + 1)
+    page0 = jnp.maximum(starts - window + 1, 0) // bt
+    cols = jnp.minimum(page0[:, None] + jnp.arange(n), tables.shape[1] - 1)
+    return jnp.take_along_axis(tables, cols, axis=1), page0.astype(jnp.int32)
 
 
 def _paged_prefill_kernel(tab_ref, start_ref, layer_ref, q_ref, *refs,
                           block_tokens: int, chunk: int, groups: int,
                           use_alibi: bool, quantized: bool,
                           window: int = 0, page0_ref=None):
-    """Grid (b, nkv, W), page index innermost: each step folds one
-    streamed [block_tokens, hd] page into online-softmax accumulators
-    (VMEM scratch persists across the sequential grid), the fold of
-    :func:`_paged_kernel`.  Rows are (chunk position, q-head group
-    member) pairs: row ``r`` is query position ``start + r // g`` of
-    q head ``h*g + r % g``, so the whole C-token segment of one kv
-    head folds each streamed page into the online-softmax accumulators
-    in ONE grid pass.  The causal bound is per ROW (``kv_pos <=
-    start + r // g``), not the single shared decode position — in-chunk
-    keys were already written to the pages by ``write_paged_kv``
-    (write-before-attend inside the layer), so causality alone makes a
-    query see exactly its prefix plus its own earlier in-chunk keys.
+    """The grid kernel, for the shapes the page loop does not cover.
+    Grid (b, nkv, W), page index innermost: each step folds one
+    streamed [block_tokens, hd] page (:func:`_fold_page`) into
+    online-softmax accumulators (VMEM scratch persists across the
+    sequential grid).  Rows are (chunk position, q-head group member)
+    pairs, so the whole C-token segment of one kv head folds each
+    streamed page in ONE grid pass.  Steps past the live frontier move
+    no page and skip the fold, but they are grid steps all the same.
 
     tab_ref (SMEM int32 [b, W]): block tables; start_ref (SMEM int32
     [b]): per-row segment start offsets (position of chunk column 0);
@@ -731,7 +1008,6 @@ def _paged_prefill_kernel(tab_ref, start_ref, layer_ref, q_ref, *refs,
     rows, hd = q_ref.shape[2], q_ref.shape[3]
     start = start_ref[b]
     bt = block_tokens
-    g = groups
 
     @pl.when(j == 0)
     def _init():
@@ -755,34 +1031,17 @@ def _paged_prefill_kernel(tab_ref, start_ref, layer_ref, q_ref, *refs,
         if quantized:
             k_blk = k_blk * ks_ref[0, 0, :, :]      # [bt, hd] * [bt, 1]
             v_blk = v_blk * vs_ref[0, 0, :, :]
-        s = jnp.dot(q, k_blk.T,
-                    preferred_element_type=jnp.float32)     # [rows, bt]
         kv_pos = (jp * bt
                   + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1))
-        # per-row query position: padding rows (r >= chunk*g) see a
-        # position past the segment — their garbage output is sliced
-        # away by the caller
-        q_pos = (start
-                 + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // g)
-        valid = kv_pos <= q_pos                             # [rows, bt]
-        if window:
-            valid = valid & (q_pos - kv_pos < window)
-        if use_alibi:
-            slope = slopes_ref[0, 0, :][:, None]            # [rows, 1]
-            dist = (q_pos - kv_pos).astype(jnp.float32)
-            s = s - slope * dist
-        s = jnp.where(valid, s, _NEG)
-
-        m = jnp.max(m_acc[:], axis=-1, keepdims=True)       # [rows, 1]
-        l = jnp.max(l_acc[:], axis=-1, keepdims=True)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        o_acc[:] = _state(o_acc[:] * alpha + jnp.dot(
-            p, v_blk, preferred_element_type=jnp.float32))
-        m_acc[:] = jnp.broadcast_to(_state(m_new), m_acc.shape)
-        l_acc[:] = jnp.broadcast_to(_state(l_new), l_acc.shape)
+        slope = slopes_ref[0, 0, :][:, None] if use_alibi else None
+        o, m, l = _fold_page(
+            q, k_blk, v_blk,
+            (o_acc[:], jnp.max(m_acc[:], axis=-1, keepdims=True),
+             jnp.max(l_acc[:], axis=-1, keepdims=True)),
+            kv_pos, _row_positions(start, rows, groups), slope, window)
+        o_acc[:] = o
+        m_acc[:] = jnp.broadcast_to(m, m_acc.shape)
+        l_acc[:] = jnp.broadcast_to(l, l_acc.shape)
 
     @pl.when(j == num_j - 1)
     def _finalize():
@@ -798,18 +1057,23 @@ def _paged_prefill_kernel_window(tab_ref, start_ref, layer_ref, page0_ref,
                           page0_ref=page0_ref, **kw)
 
 
-def _paged_prefill_call_body(q_g, k_pages, v_pages, layer, tables, starts,
+def _paged_prefill_grid_call(q_g, k_pages, v_pages, layer, tables, starts,
                              slopes, *, block_tokens, chunk, groups,
-                             use_alibi, interpret, window=0, page0=None):
-    """The Pallas call.  ``k_pages`` / ``v_pages`` are the STACKED pools
-    ``[L, N, nkv, bt, hd]`` and ``layer`` [1] int32 picks the layer: the
-    page index map returns ``(layer, page, head, 0, 0)``, so the pipeline
-    copies the table's pages out of the stack and nothing else."""
+                             use_alibi, interpret, window=0):
+    """The grid kernel's Pallas call: the page index map returns
+    ``(layer, page, head, 0, 0)``, so the pipeline copies the table's
+    pages out of the stack and nothing else.  Under a ``window`` the
+    table is first cut to the pages a chunk can see
+    (:func:`window_tables`), so that the grid is as wide as those."""
     b, nkv, rows, hd = q_g.shape
     quantized = isinstance(k_pages, QuantizedKVPages)
     num_pages = k_pages.shape[1]
-    W = tables.shape[1]
     bt = block_tokens
+    prefetch = (tables, starts, layer)
+    if window:
+        tables, page0 = window_tables(tables, starts, chunk, window, bt)
+        prefetch = (tables, starts, layer, page0)
+    W = tables.shape[1]
 
     def page_map(bb, h, j, tab, starts_, lay, *first):
         # clamp to the segment's live frontier (start + chunk tokens):
@@ -837,16 +1101,10 @@ def _paged_prefill_call_body(q_g, k_pages, v_pages, layer, tables, starts,
         in_specs = [q_spec, page_spec, page_spec, slopes_spec]
         operands = (q_g, k_pages, v_pages, slopes)
 
-    prefetch = (tables, starts, layer)
-    kernel = functools.partial(_paged_prefill_kernel, block_tokens=bt,
-                               chunk=chunk, groups=groups,
-                               use_alibi=use_alibi, quantized=quantized)
-    if window:
-        prefetch += (page0,)
-        kernel = functools.partial(_paged_prefill_kernel_window,
-                                   block_tokens=bt, chunk=chunk,
-                                   groups=groups, use_alibi=use_alibi,
-                                   quantized=quantized, window=window)
+    kernel = functools.partial(
+        _paged_prefill_kernel_window if window else _paged_prefill_kernel,
+        block_tokens=bt, chunk=chunk, groups=groups, use_alibi=use_alibi,
+        quantized=quantized, **({"window": window} if window else {}))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -865,6 +1123,20 @@ def _paged_prefill_call_body(q_g, k_pages, v_pages, layer, tables, starts,
     )(*prefetch, *operands)
 
 
+def _paged_prefill_call_body(q_g, k_pages, v_pages, layer, tables, starts,
+                             slopes, **kw):
+    """One of the two Pallas calls, by the pool's shape.  ``k_pages`` /
+    ``v_pages`` are the STACKED pools ``[L, N, nkv, bt, hd]``, ``layer``
+    [1] int32 picks the layer, ``tables`` [b, W] is each row's whole
+    table."""
+    call = (_paged_prefill_loop_call if _page_loop_covers(k_pages)
+            else _paged_prefill_grid_call)
+    return call(q_g, k_pages, v_pages, layer, tables, starts, slopes, **kw)
+
+
+# the two jitted calls, named as the trace readers know them
+# (``_paged_prefill_call.<n>``, a window kind's
+# ``_paged_prefill_call_window.<n>``)
 @functools.partial(jax.jit,
                    static_argnames=("block_tokens", "chunk", "groups",
                                     "use_alibi", "interpret"))
@@ -881,32 +1153,42 @@ def _paged_prefill_call(q_g, k_pages, v_pages, layer, tables, starts,
                    static_argnames=("block_tokens", "chunk", "groups",
                                     "use_alibi", "interpret", "window"))
 def _paged_prefill_call_window(q_g, k_pages, v_pages, layer, tables, starts,
-                               slopes, page0, *, block_tokens, chunk,
-                               groups, use_alibi, interpret, window):
-    """``tables`` [b, n] is each row's table from page ``page0[b]`` on
-    (:func:`window_tables`)."""
+                               slopes, *, block_tokens, chunk, groups,
+                               use_alibi, interpret, window):
     return _paged_prefill_call_body(
         q_g, k_pages, v_pages, layer, tables, starts, slopes,
         block_tokens=block_tokens, chunk=chunk, groups=groups,
-        use_alibi=use_alibi, interpret=interpret, window=window,
-        page0=page0)
-
-
-def window_tables(tables, starts, chunk: int, window: int, bt: int):
-    """``(tables [b, n], page0 [b])``: each row's table cut to the pages a
-    chunk at ``starts[b]`` can see under ``window`` (from the page of key
-    ``starts[b] - window + 1`` to the page of the chunk's last token: at
-    most ``n`` of them, a static count)."""
-    n = min(tables.shape[1], -(-(chunk + window - 1) // bt) + 1)
-    page0 = jnp.maximum(starts - window + 1, 0) // bt
-    cols = jnp.minimum(page0[:, None] + jnp.arange(n), tables.shape[1] - 1)
-    return jnp.take_along_axis(tables, cols, axis=1), page0.astype(jnp.int32)
+        use_alibi=use_alibi, interpret=interpret, window=window)
 
 
 # one kernel invocation's query rows = chunk * group; past this the
 # f32 VMEM accumulators (rows x hd + 2 x rows x 128) crowd the page
 # stream — larger chunks take the gather path
 PREFILL_KERNEL_MAX_ROWS = 512
+
+
+def _query_tiles(q, nkv: int, slopes):
+    """``(q_g [b, nkv, rows, hd], slopes_g [nkv, 1, rows])``: a call's
+    queries ``[b, chunk, nh, hd]`` as one tile of ``chunk x g`` rows a kv
+    head (row ``c*g + r`` of kv head ``h`` is chunk position ``c`` of q
+    head ``h*g + r``), zero-padded to whole sublane tiles, and each
+    row's ALiBi slope (zeros without)."""
+    b, chunk, nh, hd = q.shape
+    g = nh // nkv
+    rows_real = chunk * g
+    rows = max(8, -(-rows_real // 8) * 8)
+    q_g = q.reshape(b, chunk, nkv, g, hd).transpose(0, 2, 1, 3, 4)
+    q_g = q_g.reshape(b, nkv, rows_real, hd)
+    if rows > rows_real:
+        q_g = jnp.pad(q_g, ((0, 0), (0, 0), (0, rows - rows_real),
+                            (0, 0)))
+    if slopes is None:
+        return q_g, jnp.zeros((nkv, 1, rows), jnp.float32)
+    # per-row slope = slopes[h*g + r % g]: the g-vector repeats once per
+    # chunk position
+    slopes_g = jnp.tile(slopes.astype(jnp.float32).reshape(nkv, 1, g),
+                        (1, 1, chunk))
+    return q_g, jnp.pad(slopes_g, ((0, 0), (0, 0), (0, rows - rows_real)))
 
 
 def paged_prefill_attention(
@@ -942,47 +1224,18 @@ def paged_prefill_attention(
         raise ValueError(f"block_tokens must be a multiple of 8 for the "
                          f"Pallas kernel, got {bt}")
     g = nh // nkv
-    rows_real = chunk * g
-    rows = max(8, -(-rows_real // 8) * 8)
-    if rows > PREFILL_KERNEL_MAX_ROWS:
+    q_g, slopes_g = _query_tiles(q, nkv, slopes)
+    if q_g.shape[2] > PREFILL_KERNEL_MAX_ROWS:
         raise ValueError(
-            f"prefill kernel rows {rows} (chunk {chunk} x group {g}) "
-            f"exceed {PREFILL_KERNEL_MAX_ROWS}; use the gather path")
-
-    # [b, chunk, nh, hd] -> [b, nkv, chunk*g, hd]: row c*g + r of kv
-    # head h is chunk position c of q head h*g + r
-    q_g = q.reshape(b, chunk, nkv, g, hd).transpose(0, 2, 1, 3, 4)
-    q_g = q_g.reshape(b, nkv, rows_real, hd)
-    if rows > rows_real:
-        q_g = jnp.pad(q_g, ((0, 0), (0, 0), (0, rows - rows_real),
-                            (0, 0)))
-    if slopes is None:
-        slopes_g = jnp.zeros((nkv, 1, rows), jnp.float32)
-    else:
-        # per-row slope = slopes[h*g + r % g]: the g-vector repeats
-        # once per chunk position
-        slopes_g = jnp.tile(
-            slopes.astype(jnp.float32).reshape(nkv, 1, g),
-            (1, 1, chunk))
-        slopes_g = jnp.pad(slopes_g,
-                           ((0, 0), (0, 0), (0, rows - rows_real)))
-
-    if window:
-        starts = q_positions[:, 0].astype(jnp.int32)
-        tab_w, page0 = window_tables(tables.astype(jnp.int32), starts,
-                                     chunk, window, bt)
-        out = _paged_prefill_call_window(
-            q_g, K, V, li.reshape(1), tab_w, starts, slopes_g, page0,
-            block_tokens=bt, chunk=chunk, groups=g,
-            use_alibi=slopes is not None, interpret=interpret,
-            window=window)
-    else:
-        out = _paged_prefill_call(
-            q_g, K, V, li.reshape(1), tables.astype(jnp.int32),
-            q_positions[:, 0].astype(jnp.int32), slopes_g,
-            block_tokens=bt, chunk=chunk, groups=g,
-            use_alibi=slopes is not None, interpret=interpret)
-    out = out[:, :, :rows_real, :].reshape(b, nkv, chunk, g, hd)
+            f"prefill kernel rows {q_g.shape[2]} (chunk {chunk} x group "
+            f"{g}) exceed {PREFILL_KERNEL_MAX_ROWS}; use the gather path")
+    call = (functools.partial(_paged_prefill_call_window, window=window)
+            if window else _paged_prefill_call)
+    out = call(q_g, K, V, li.reshape(1), tables.astype(jnp.int32),
+               q_positions[:, 0].astype(jnp.int32), slopes_g,
+               block_tokens=bt, chunk=chunk, groups=g,
+               use_alibi=slopes is not None, interpret=interpret)
+    out = out[:, :, :chunk * g, :].reshape(b, nkv, chunk, g, hd)
     return out.transpose(0, 2, 1, 3, 4).reshape(b, chunk, nh, hd)
 
 
@@ -1085,6 +1338,22 @@ def sub_chunk(chunk: int, groups: int) -> int:
     fits = [c for c in range(chunk, 0, -1) if chunk % c == 0
             and -(-(c * groups) // 8) * 8 <= PREFILL_KERNEL_MAX_ROWS]
     return next((c for c in fits if c % 8 == 0), fits[0])
+
+
+def prefill_pages_walked(start: int, chunk: int, tile: int, bt: int,
+                         width: int, window: int = 0) -> int:
+    """The pages the prefill page loop walks for ONE segment of ``chunk``
+    tokens at position ``start``, cut into query tiles of ``tile`` tokens
+    (:func:`sub_chunk`; ``chunk`` itself where it is not cut): the loop
+    bounds of :func:`_paged_prefill_loop_kernel` (and of the latent
+    kernel, which has no window) in Python integers, for the scheduler's
+    dispatch record.  Host arithmetic: nothing here reads the device."""
+    pages = 0
+    for s in range(start, start + chunk, tile):
+        n_live = min(-(-(s + tile) // bt), width)
+        first = min(max(s - window + 1, 0) // bt, n_live) if window else 0
+        pages += n_live - first
+    return pages
 
 
 def make_paged_attn_impl(block_tokens: int, backend: str = "auto",

@@ -77,6 +77,8 @@ from ..models.base import (KVCache, ModelConfig, StageParams,
                            StageSpec, pad_cache_capacity,
                            require_kv_pair, require_one_kind,
                            require_single_pass)
+from ..ops.latent_attention import latent_tile_tokens
+from ..ops.paged_attention import prefill_pages_walked, sub_chunk
 from ..ops.sampling import SamplingParams, filtered_logits, sample_logits
 from ..telemetry import postmortem
 from ..telemetry import profiling as _profiling
@@ -1382,6 +1384,11 @@ class ContinuousBatchingEngine:
         # a latent-attention model's record says what its prefill kernel
         # attended over
         latent = cfg.latent_kv and self._mixed_step is not None
+        # the query tiles a pool's prefill kernel cuts a segment into
+        # (``prefill_pages_walked`` of the dispatch record; the mixed
+        # path's, which alone has a record)
+        self._prefill_tiles = (self._tiles_a_pool() if self.prefill_chunk
+                               else ())
         self.dispatch_trace = DispatchTrace(
             (MOE_DISPATCH_FIELDS if moe else ())
             + (LOOP_DISPATCH_FIELDS if loop else ())
@@ -2540,6 +2547,25 @@ class ContinuousBatchingEngine:
             st["wpages"].clear()
             self._window_reserved -= self._window_quota
 
+    def _tiles_a_pool(self) -> tuple:
+        """``(tile tokens, window)`` a pool, the full (or only) kind's
+        first: the query tiles its prefill kernel cuts a segment of
+        ``prefill_chunk`` tokens into.  A period model's kinds cut a
+        chunk into sub-chunks (``sub_chunk``: the first kind of each
+        window stands for its pool), the latent kernel into tiles of its
+        own, and every other model's chunk is one tile."""
+        cfg, C = self.cfg, self.prefill_chunk
+        if cfg.latent_kv:
+            return ((latent_tile_tokens(C, cfg.num_heads), 0),)
+        if not cfg.period:
+            return ((C, 0),)
+        kinds = ([cfg.lead_kind] if cfg.lead_kind is not None else []
+                 ) + list(cfg.period)
+        return tuple(
+            (sub_chunk(C, next(k for k in kinds if k.window == window)
+                       .num_heads // cfg.num_kv_heads), window)
+            for window, _ in self._pool_specs)
+
     def _window_hold(self, req: Request, lo: int, hi: int) -> None:
         """The window kind's pages for the tokens ``[lo, hi)`` the next
         dispatch writes for ``req``: a page a block of the table not held
@@ -3562,6 +3588,18 @@ class ContinuousBatchingEngine:
                 full = max(0, min(n, Wn - 1 - s0))      # p + 1 < W
                 prefill_window_pairs += (
                     full * s0 + full * (full + 1) // 2 + (n - full) * Wn)
+        # what the prefill kernel's page loop walks for the packed
+        # segments, a pool of one kind of block each (host arithmetic on
+        # the starts; the kernel computes every row of a segment), and
+        # the steps a grid of the table's width would have had for the
+        # full kind's tiles
+        walked = [sum(prefill_pages_walked(int(seg_starts[r0]), C, tile,
+                                           self.kv_cache.block_tokens,
+                                           self._table_width, window)
+                      for (r0, _, _, _) in packed)
+                  for tile, window in self._prefill_tiles]
+        pages_grid = (len(packed) * (C // self._prefill_tiles[0][0])
+                      * self._table_width)
         if spec_mixed:
             # §22 rng rule: the decode split is spent iff spec rounds
             # run, i.e. iff a row was ALREADY active — a freshly
@@ -3601,6 +3639,7 @@ class ContinuousBatchingEngine:
             prefill_kv_tokens=prefill_kv_tokens, n_active=n_active,
             kv_window_tokens=kv_window_tokens,
             prefill_window_pairs=prefill_window_pairs,
+            prefill_pages_walked=walked, prefill_pages_grid=pages_grid,
             live0=live0, kv_tokens=kv_tokens, spec_mixed=spec_mixed,
             k_vec=k_vec, k_disp=k_disp, num_rounds=num_rounds,
             dev=None, how=None, ahead_s=0.0)
@@ -3971,7 +4010,9 @@ class ContinuousBatchingEngine:
             finals=len(plan.finals), prefill_tokens=prefill_tokens,
             active_rows=n_active, steps=steps,
             kv_tokens=plan.kv_tokens, ahead=plan.ahead_s, how=plan.how,
-            slab_rows=plan.slab_rows)
+            slab_rows=plan.slab_rows,
+            prefill_pages_walked=plan.prefill_pages_walked[0],
+            prefill_pages_grid=plan.prefill_pages_grid)
         if self.moe_counters is not None:
             # real tokens: the live segments' prompt tokens, and the
             # steps of the slots that decoded (rows that finish inside
@@ -3993,6 +4034,8 @@ class ContinuousBatchingEngine:
         if self._wmgr is not None:
             record["kv_window_tokens"] = plan.kv_window_tokens
             record["prefill_window_pairs"] = plan.prefill_window_pairs
+            record["prefill_window_pages_walked"] = (
+                plan.prefill_pages_walked[1])
         cs = self.chunk_stats
         cs["mixed_dispatches"] += 1
         cs["mixed_prefill_tokens"] += prefill_tokens

@@ -173,9 +173,15 @@ _LAUNCHED_PHASES = ("launch", "wait", "drain")
 # it when the host came to read (DispatchTrace.awaiting): this dispatch
 # the host held the device up.  ``await``: the seconds the host was then
 # blocked in that read, the end of ``wait``
+# ``prefill_pages_walked`` (after ``prefill_tokens``): the pages the
+# prefill kernel's page loop walks for the slab, summed over the query
+# tiles of the packed segments, one layer call of the full (or only)
+# kind of block (``ops.paged_attention.prefill_pages_walked``: host
+# arithmetic on the segments' starts); 0 on a decode-only dispatch
 DISPATCH_FIELDS = (("seq", "t_launch", "t_done") + DISPATCH_PHASES
                    + ("with_finals", "segments", "finals",
-                      "prefill_tokens", "active_rows", "steps",
+                      "prefill_tokens", "prefill_pages_walked",
+                      "active_rows", "steps",
                       "kv_tokens", "ahead", "late", "await"))
 # what a launched dispatch keeps until its commit: its phases' seconds
 # and the two columns its blocking read fills
@@ -210,8 +216,11 @@ LATENT_DISPATCH_FIELDS = ("prefill_kv_tokens",)
 # a model with a window kind of block: what its window kernels had to
 # read.  ``kv_window_tokens``: the sum over the rows that decode of
 # min(tokens held, window); ``prefill_window_pairs``: the (query, key)
-# pairs inside the window that the slab's prompt tokens attend over
-WINDOW_DISPATCH_FIELDS = ("kv_window_tokens", "prefill_window_pairs")
+# pairs inside the window that the slab's prompt tokens attend over;
+# ``prefill_window_pages_walked``: ``prefill_pages_walked`` of the
+# window kind, the pages its tiles' windows meet
+WINDOW_DISPATCH_FIELDS = ("kv_window_tokens", "prefill_window_pairs",
+                          "prefill_window_pages_walked")
 _DISPATCH_RING = 128       # x ~135 bytes a row: /stats stays under 18 KB
 # a span that is work (every one but ``await``) and lasts this long is a
 # stall: ten times the longest ordinary span (four chips' ``ahead``,
@@ -393,6 +402,8 @@ class DispatchTrace:
         self.kv_token_steps = 0
         self.prefill_tokens = 0
         self.slab_rows = 0
+        self.prefill_pages_walked = 0
+        self.prefill_pages_grid = 0
         self.queue_wait_ms_sum = 0.0
         self.queue_wait_count = 0
         self.ahead_hits = self.ahead_hits_slab = 0
@@ -581,11 +592,16 @@ class DispatchTrace:
                active_rows: int, steps: int, kv_tokens: int,
                ahead: float = 0.0, how: Optional[str] = None,
                phases: Optional[dict] = None, slab_rows: int = 0,
+               prefill_pages_walked: int = 0, prefill_pages_grid: int = 0,
                **extra: int) -> int:
         """A dispatch that reached the device is drained: one record.
         ``slab_rows``: the rows of the prefill slab its program computed
         (segments of the launched variant x the chunk), of which
         ``prefill_tokens`` held a token; both are summed, no column.
+        ``prefill_pages_walked`` (a column, and summed) beside
+        ``prefill_pages_grid`` (summed): the pages the prefill kernel's
+        loop walks for the slab, and the steps a grid of one page of the
+        table a step would have had, its tiles x the table's width.
         ``phases``: its own seconds (``launched_phases`` as they were
         when the NEXT dispatch had not been launched yet; by default the
         last launched one's).  ``how``: ``"hit"`` (launched as prepared
@@ -605,7 +621,8 @@ class DispatchTrace:
             self.seq, round(t_launch, 5), round(t_done, 5),
             *(round(phases[p], 5) for p in DISPATCH_PHASES),
             int(with_finals), segments, finals, prefill_tokens,
-            active_rows, steps, kv_tokens, round(ahead, 5),
+            prefill_pages_walked, active_rows, steps, kv_tokens,
+            round(ahead, 5),
             int(phases["late"]), round(phases["await"], 5),
             *(extra[f] for f in self.extra_fields)))
         if segments:
@@ -615,6 +632,8 @@ class DispatchTrace:
         self.kv_token_steps += kv_tokens * steps
         self.prefill_tokens += prefill_tokens
         self.slab_rows += slab_rows
+        self.prefill_pages_walked += prefill_pages_walked
+        self.prefill_pages_grid += prefill_pages_grid
         if how == "hit":
             self.ahead_hits += 1
             self.ahead_hits_slab += bool(segments)
@@ -639,6 +658,8 @@ class DispatchTrace:
                 "kv_token_steps": self.kv_token_steps,
                 "prefill_tokens": self.prefill_tokens,
                 "slab_rows": self.slab_rows,
+                "prefill_pages_walked": self.prefill_pages_walked,
+                "prefill_pages_grid": self.prefill_pages_grid,
                 "queue_wait_ms_sum": round(self.queue_wait_ms_sum, 3),
                 "queue_wait_count": self.queue_wait_count,
                 "ahead_hits": self.ahead_hits,

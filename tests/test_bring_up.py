@@ -494,6 +494,49 @@ def test_paged_kernels_get_through_mosaic(v5e, case):
         S((2, W), jnp.int32), S((2, chunk), jnp.int32), *slopes)
 
 
+# the cells' prefill calls as the slab makes them (PERF.md §4): (query
+# heads, kv heads, ALiBi, rows of the call, tokens a tile, table width,
+# pool pages, window); a tile is a chunk of 256 tokens, or the sub-chunk
+# a period model's kind cuts it into (6 and 9 query heads a kv head)
+PREFILL_LOOP_CASES = {
+    "laguna-full": (48, 8, False, 8, 64, 200, 3328, 0),
+    "laguna-window": (72, 8, False, 16, 32, 200, 255, 512),
+    "bloom7b1": (32, 32, True, 2, 256, 16, 46, 0),
+    "olmoe-1b-7b": (16, 16, False, 2, 256, 32, 224, 0),
+    "ouro-2.6b": (16, 16, False, 2, 256, 16, 44, 0),
+    "tp4-shard": (7, 1, False, 2, 64, 32, 2048, 0),
+    # no cell serves int8 pages; at 128 tokens they take the loop too
+    "int8-p128": (16, 16, False, 2, 256, 32, 224, 0),
+}
+
+
+@pytest.mark.parametrize("case", PREFILL_LOOP_CASES)
+def test_prefill_page_loop_gets_through_mosaic(v5e, case):
+    """The prefill kernel's page loop at the shapes the cells serve
+    (laguna's full kind 8 x 384 rows and window kind 8 x 288 at a table
+    of 200, bloom 32 x 256 with ALiBi, olmoe and ouro 16 x 256, one kv
+    head of a four-chip shard), compiled for the chip under both jitted
+    names; the compiled call is the loop (its pools stay in HBM, no
+    block a page), not the grid kernel."""
+    nh, nkv, alibi, b, tile, W, N, window = PREFILL_LOOP_CASES[case]
+    S = jax.ShapeDtypeStruct
+    pages = jax.eval_shape(lambda: alloc_kv_pages(
+        (N, nkv, 128, 128), "int8" if case.startswith("int8") else "bf16",
+        jnp.bfloat16))
+    assert pa._page_loop_covers(pages)
+    slopes = (S((nh,), jnp.float32),) if alibi else ()
+    kw = {"window": window} if window else {}
+    args = [jax.tree.map(
+        lambda s: S(s.shape, s.dtype, sharding=v5e), x) for x in (
+            S((b, tile, nh, 128), jnp.bfloat16), pages, pages,
+            S((b, W), jnp.int32), S((b, tile), jnp.int32), *slopes)]
+    text = jax.jit(
+        lambda q, pk, pv, t, p, *s: pa.paged_prefill_attention(
+            q, pk, pv, t, p, *s, **kw)).lower(*args).compile().as_text()
+    assert ("_paged_prefill_call_window." if window
+            else "_paged_prefill_call.") in text
+
+
 @pytest.mark.parametrize("case,chunk,how", [
     ("cell-qwen2.5-7b", 1, "kernel write"),
     ("cell-qwen2.5-7b", 64, "kernel write"),

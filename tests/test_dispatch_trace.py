@@ -172,6 +172,19 @@ def test_kv_token_steps_is_the_hand_count(scripted):
     assert stats["dispatch_trace"]["prefill_tokens"] == 22 + 3
 
 
+def test_prefill_pages_walked_is_the_hand_count(scripted):
+    """Chunk 8 over pages of 8 tokens, a table of 96 / 8 = 12 pages: the
+    22-token prompt's segments start at 0, 8 and 16 and their tiles end
+    on pages 1, 2 and 3; the 3-token prompt's one segment walks one page.
+    A dispatch without a slab walks none.  Beside it the steps a grid of
+    one page of the table a step would have had: 4 tiles x 12."""
+    stats, _, _ = scripted
+    assert [r["prefill_pages_walked"] for r in rows(stats)] == [
+        1 + 2 + 3, 0, 0, 1, 0]
+    assert stats["dispatch_trace"]["prefill_pages_walked"] == 7
+    assert stats["dispatch_trace"]["prefill_pages_grid"] == 4 * 12
+
+
 def test_phases_fit_between_a_record_and_its_neighbour(scripted):
     recs = rows(scripted[0])
     for r in recs:

@@ -7,6 +7,7 @@ import dataclasses
 import hashlib
 import json
 import sys
+import time
 from pathlib import Path
 
 import jax
@@ -104,6 +105,57 @@ def test_engine_serves_the_dense_path_s_tokens_and_returns_its_pages(params):
     assert 0.1 < moe["rows"] / moe["valid_rows"] < 0.45
     fields = st["dispatch_trace"]["fields"]
     assert {"kv_window_tokens", "prefill_window_pairs"} <= set(fields)
+
+
+def test_the_record_s_pages_walked_are_the_kernels_loop_bounds(params):
+    """``prefill_pages_walked`` (the full kind's) and
+    ``prefill_window_pages_walked`` of every packed slab are the sums of
+    the loop bounds the prefill kernel computes from the same starts
+    (``_walk_bounds``: chunk 8, pages of 4, a table of 50, window 8), a
+    dispatch without a slab walks none, and /stats holds the totals
+    beside the grid's tiles x width.  Host arithmetic: the plan is read
+    as packed, before anything of it reaches the device."""
+    from distributed_inference_demo_tpu.ops.paged_attention import (
+        _walk_bounds)
+    plans = []
+    with _engine(params, **MIXED) as eng:
+        pack = eng._pack_mixed
+
+        def spy(*a, **kw):
+            plans.append(pack(*a, **kw))
+            return plans[-1]
+
+        eng._pack_mixed = spy
+        assert eng._prefill_tiles == ((8, 0), (8, 8))
+        rng = np.random.default_rng(5)
+        for r in [eng.submit(rng.integers(0, 256, size=n), 6)
+                  for n in (61, 30)]:
+            r.wait(300)
+        deadline = time.monotonic() + 10
+        while (eng.stats()["dispatch_trace"]["seq"]
+               != eng.stats()["mixed"]["dispatches"]
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
+        dt = eng.stats()["dispatch_trace"]
+    assert any(p.packed for p in plans) and not all(p.packed for p in plans)
+    for plan in plans:
+        starts = np.asarray([int(plan.seg[2][r]) for r, *_ in plan.packed],
+                            np.int32)
+        want = []
+        for window in (0, 8):
+            first, end = _walk_bounds(starts, 8, 4, 50, window)
+            want.append(int(np.sum(np.asarray(end) - np.asarray(first))))
+        assert plan.prefill_pages_walked == want
+        # a tile of 8 under a window of 8 meets at most 5 pages of 4
+        assert want[1] <= 5 * len(starts)
+    recs = [dict(zip(dt["fields"], r)) for r in dt["recent"]]
+    assert dt["prefill_pages_walked"] == sum(
+        r["prefill_pages_walked"] for r in recs) > 0
+    assert dt["prefill_pages_grid"] == 50 * sum(r["segments"] for r in recs)
+    for r in recs:
+        assert (r["prefill_pages_walked"] > 0) == (r["segments"] > 0)
+        assert (r["prefill_window_pages_walked"] > 0) == (r["segments"] > 0)
+        assert r["prefill_window_pages_walked"] <= r["prefill_pages_walked"]
 
 
 def test_a_request_of_twenty_windows_holds_its_window_and_a_dispatch(params):

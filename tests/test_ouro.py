@@ -492,4 +492,7 @@ def test_one_pass_record_and_stats_are_the_parent_s(model):
     fields = list(PARENT["fields"][model])
     at = fields.index("kv_tokens") + 1
     fields[at:at] = ["ahead", "late", "await"]
+    # ... and PR 47's, what the prefill kernel's page loop walks
+    at = fields.index("prefill_tokens") + 1
+    fields[at:at] = ["prefill_pages_walked"]
     assert st["dispatch_trace"]["fields"] == fields

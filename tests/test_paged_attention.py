@@ -224,6 +224,168 @@ def test_pallas_prefill_interpret_matches_gather(case, mode):
                                rtol=2e-5, atol=2e-5)
 
 
+def _walk_tables(rng, b, W, N, rows):
+    """Tables of ``b`` rows whose pages ``[lo, hi)`` are live (distinct
+    pages of the pool) and every other entry the sentinel."""
+    pages = iter(rng.permutation(N))
+    tables = np.full((b, W), N + 7, np.int32)
+    for i, (lo, hi) in enumerate(rows):
+        for j in range(lo, hi):
+            tables[i, j] = next(pages)
+    return tables
+
+
+# the page loop (heads of 128; int8 at 128-token pages): what its walk
+# depends on.  ``rows`` says which pages of each row's table are live,
+# ``same`` that every row reads ONE table (an admission's chunk cut into
+# sub-chunks, each a row of the call that starts where the last ended)
+PREFILL_LOOP_SWEEP = {
+    # chunk from zero, mid-page, across a page boundary, deep context;
+    # behind each frontier the table's tail is sentinel
+    "ragged": dict(nh=4, nkv=2, bt=128, W=6, chunk=16,
+                   starts=[0, 70, 120, 600]),
+    # six query heads a kv head (48 rows of 8 x 6), small pages
+    "group-6": dict(nh=6, nkv=1, bt=16, W=9, chunk=8, starts=[0, 13, 120]),
+    "sub-chunks": dict(nh=4, nkv=2, bt=128, W=5, chunk=8, same=True,
+                       starts=[240, 248, 256, 264, 500]),
+    # a window kind: the pages behind the window went back to the pool
+    # (sentinel entries the walk must not read), the first chunk sees
+    # less than a window, the last starts deep in its table
+    "window": dict(nh=4, nkv=2, bt=128, W=6, chunk=24, window=130,
+                   starts=[0, 100, 250, 700]),
+    "window-sub-chunks": dict(nh=6, nkv=2, bt=16, W=12, chunk=8, window=40,
+                              same=True, starts=[96, 104, 112, 120]),
+}
+
+
+@pytest.mark.parametrize("name,mode", [
+    (name, mode) for name, case in PREFILL_LOOP_SWEEP.items()
+    for mode in ("f32", "alibi", "int8")
+    # int8 pages take the loop at 128-token pages only
+    if mode != "int8" or case["bt"] % 128 == 0])
+def test_prefill_page_loop_matches_gather_and_the_grid_kernel(name, mode):
+    """Heads of 128 take the page loop in interpret mode as on the chip:
+    against the XLA gather at f32 tolerance, and EQUAL to the grid kernel
+    on the same tiles (kept for narrow heads; called here directly): the
+    two fold the same live pages in the same order with the same
+    arithmetic, and a page outside the walk contributed nothing."""
+    from distributed_inference_demo_tpu.ops import paged_attention as pa
+    case = PREFILL_LOOP_SWEEP[name]
+    rng = np.random.default_rng(hash(name + mode) % 2**32)
+    starts, chunk, bt, W = (case[k] for k in ("starts", "chunk", "bt", "W"))
+    window = case.get("window", 0)
+    b, nkv, hd, N = len(starts), case["nkv"], 128, 40
+    hi = [-(-(s + chunk) // bt) for s in starts]
+    lo = [max(0, s - window + 1) // bt if window else 0 for s in starts]
+    if case.get("same"):
+        tables = np.repeat(_walk_tables(rng, 1, W, N,
+                                        [(min(lo), max(hi))]), b, axis=0)
+    else:
+        tables = _walk_tables(rng, b, W, N, zip(lo, hi))
+    tables = jnp.asarray(tables)
+    pk = jnp.asarray(rng.standard_normal((N, nkv, bt, hd)), jnp.float32)
+    pv = jnp.asarray(rng.standard_normal((N, nkv, bt, hd)), jnp.float32)
+    pk, pv, slopes = _apply_mode(mode, pk, pv, case["nh"])
+    assert pa._page_loop_covers(pk)
+    q = jnp.asarray(rng.standard_normal((b, chunk, case["nh"], hd)),
+                    jnp.float32)
+    start = jnp.asarray(starts, jnp.int32)
+    qpos = start[:, None] + jnp.arange(chunk, dtype=jnp.int32)[None, :]
+    kw = {"window": window} if window else {}
+    ref = paged_gather_attention(q, pk, pv, tables, qpos, slopes, **kw)
+    got = paged_prefill_attention(q, pk, pv, tables, qpos, slopes,
+                                  interpret=True, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    q_g, slopes_g = pa._query_tiles(q, nkv, slopes)
+    K, V, li = pa._stacked(pk, pv)
+    tiles = dict(block_tokens=bt, chunk=chunk, groups=case["nh"] // nkv,
+                 use_alibi=slopes is not None, interpret=True, **kw)
+    loop, grid = (call(q_g, K, V, li.reshape(1), tables, start, slopes_g,
+                       **tiles)
+                  for call in (pa._paged_prefill_loop_call,
+                               pa._paged_prefill_grid_call))
+    np.testing.assert_array_equal(np.asarray(loop), np.asarray(grid))
+
+
+@pytest.mark.parametrize("window", [0, 512])
+def test_prefill_kernel_grid_has_no_table_width(window):
+    """Beside the decode kernel's: the prefill ``pallas_call`` of both
+    jitted names has one grid step a query tile and block of kv heads,
+    whatever the table's width (the page loop runs inside the step)."""
+    from distributed_inference_demo_tpu.ops import paged_attention as pa
+    call = (functools.partial(pa._paged_prefill_call_window, window=window)
+            if window else pa._paged_prefill_call)
+
+    def grid(W):
+        b, nkv, rows, hd, bt, N = 8, 8, 384, 128, 128, 16
+        S = jax.ShapeDtypeStruct
+        jaxpr = jax.make_jaxpr(
+            lambda *a: call(*a, block_tokens=bt, chunk=64, groups=6,
+                            use_alibi=False, interpret=False))(
+            S((b, nkv, rows, hd), jnp.bfloat16),
+            S((2, N, nkv, bt, hd), jnp.bfloat16),
+            S((2, N, nkv, bt, hd), jnp.bfloat16), S((1,), jnp.int32),
+            S((b, W), jnp.int32), S((b,), jnp.int32),
+            S((nkv, 1, rows), jnp.float32))
+        calls = []
+
+        def walk(jp):
+            for eqn in jp.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    calls.append(eqn)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+        walk(jaxpr.jaxpr)
+        assert len(calls) == 1
+        return tuple(calls[0].params["grid_mapping"].grid)
+
+    assert grid(8) == grid(200) == (8, 1)
+
+
+def test_narrow_heads_keep_the_grid_kernel():
+    """The gate is the decode kernel's, a property of the pool's shape:
+    Mosaic slices an HBM ref by hand only where its minor dimension fills
+    the lanes, so heads under 128 and int8 pages under 128 tokens keep
+    the BlockSpec pipeline (the narrow cases of ``PREFILL_SWEEP`` run
+    it)."""
+    from distributed_inference_demo_tpu.ops import paged_attention as pa
+    from distributed_inference_demo_tpu.ops.quant import quantize_kv_pages
+    pages = lambda bt, hd: jnp.zeros((2, 2, bt, hd), jnp.float32)
+    assert pa._page_loop_covers(pages(8, 128))
+    assert pa._page_loop_covers(pages(16, 256))
+    assert not pa._page_loop_covers(pages(128, 64))
+    assert pa._page_loop_covers(quantize_kv_pages(pages(128, 128), 8))
+    assert not pa._page_loop_covers(quantize_kv_pages(pages(32, 128), 8))
+    # as many kv heads a step as the state's bytes buy: all of laguna's
+    # eight, half of bloom's thirty-two, a shard's one
+    assert pa._heads_a_step(8, 384, 128) == 8
+    assert pa._heads_a_step(32, 256, 128) == 16
+    assert pa._heads_a_step(1, 448, 128) == 1
+
+
+def test_pages_walked_on_the_host_are_the_kernel_s_loop_bounds():
+    """``prefill_pages_walked`` (Python integers, for the scheduler's
+    record) against ``_walk_bounds`` (what the kernel computes from the
+    same starts), a segment cut into tiles: full and window kind, from
+    zero, across pages, up against the table's end."""
+    from distributed_inference_demo_tpu.ops import paged_attention as pa
+    for chunk, tile, bt, W, window in [(256, 64, 128, 200, 0),
+                                       (256, 32, 128, 200, 512),
+                                       (256, 256, 128, 16, 0),
+                                       (8, 8, 4, 50, 8), (24, 8, 16, 6, 0)]:
+        for start in (0, 1, bt - 1, 5 * bt + 3, W * bt - chunk, W * bt):
+            tiles = start + tile * np.arange(chunk // tile)
+            first, end = pa._walk_bounds(tiles, tile, bt, W, window)
+            assert pa.prefill_pages_walked(
+                start, chunk, tile, bt, W, window) == int(
+                    np.sum(np.asarray(end) - np.asarray(first)))
+    # laguna's full kind at a context of 6,528 tokens: 4 tiles that end on
+    # pages 52, 52, 53, 53 of 200
+    assert pa.prefill_pages_walked(6528, 256, 64, 128, 200) == 210
+    assert pa.prefill_pages_walked(6528, 256, 32, 128, 200, 512) == 8 * 5
+
+
 def test_prefill_kernel_rejects_int4_and_unaligned_pages():
     """int4 packed pages and non-8-aligned page sizes stay on the
     gather fallback — the kernel refuses them loudly instead of

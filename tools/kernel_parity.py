@@ -21,6 +21,13 @@ which also quantizes), the reference is ``paged_gather_attention`` /
 a one-pass bf16 product, and a reference must not share the error it is
 there to bound.
 
+The prefill kernel's page loop runs ragged calls at 128-token pages and the
+cells' shapes (``PREFILL_LOOP_SHAPES``: laguna's full and window kind over
+a table of 200 at contexts of 0 pages, 1, 53 and 200, bloom with ALiBi,
+olmoe): against the gather like every case, and against the GRID kernel on
+the same tiles, which must be EQUAL; both are then timed alone
+(``--prefill-only`` runs these cases and nothing else).
+
 The grouped matmul (``ops.grouped_matmul``) runs at the expert layer's two
 shapes in ``olmoe-1b-7b`` (256 token-expert rows: a decode step at 32
 slots; 4,096: the 512-token slab), both projections' shapes, int8 and
@@ -36,6 +43,7 @@ Mosaic's message.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import sys
 import time
@@ -53,6 +61,8 @@ from distributed_inference_demo_tpu.ops.attention import (  # noqa: E402
     alibi_slopes, attention)
 from distributed_inference_demo_tpu.ops.flash_attention import (  # noqa: E402
     flash_attention)
+from distributed_inference_demo_tpu.ops import (  # noqa: E402
+    paged_attention as pa)
 from distributed_inference_demo_tpu.ops.paged_attention import (  # noqa: E402
     paged_flash_attention, paged_gather_attention, paged_prefill_attention,
     write_paged_kv)
@@ -216,6 +226,97 @@ def gmm_times(stacks, E, H, I, pairs: int = 8) -> None:
                                        / (peaks.bf16_tflops * 1e12), 1)})
 
 
+# the prefill page loop at the shapes the cells serve it (PERF.md §4;
+# 128-token bf16 pages): (query heads, kv heads, ALiBi, the tile's
+# tokens, the table's width, the window, [the two segments' starts])
+PREFILL_LOOP_SHAPES = {
+    # laguna's full kind: a 256-token chunk as 4 sub-chunks of 64, two
+    # segments a call; contexts of 0 and of 1 page, of 53 and of 200
+    "laguna-full": (48, 8, False, 64, 200, 0,
+                    [(0, 128), (6528, 25344)]),
+    "laguna-window": (72, 8, False, 32, 200, 512,
+                      [(0, 384), (6528, 25344)]),
+    "bloom7b1": (32, 32, True, 256, 16, 0, [(0, 1536)]),
+    "olmoe-1b-7b": (16, 16, False, 256, 32, 0, [(256, 3840)]),
+}
+
+
+def prefill_loop_cases(interpret: bool) -> bool:
+    """Ragged prefill calls through the page loop: against the gather at
+    the highest precision (``run_case``), and the loop's output against
+    the grid kernel's on the same operands, which must be EQUAL (the two
+    fold the same live pages in the same order with the same float32
+    arithmetic); then both timed alone (``prefill_times``)."""
+    ok = True
+    hd, bt = 128, 128
+    for name, (nh, nkv, alibi, tile, W, window, pairs) in (
+            PREFILL_LOOP_SHAPES.items()):
+        if interpret:
+            W, pairs = 6, [(0, 128 * 6 - 256)]
+        rng = np.random.default_rng(len(name))
+        N = 2 * W
+        pk, pv = (jnp.asarray(rng.standard_normal((N, nkv, bt, hd)),
+                              jnp.bfloat16) for _ in range(2))
+        seg_tables = rng.permutation(N).reshape(2, W)
+        n = 256 // tile                        # tiles a segment
+        tables = jnp.asarray(np.repeat(seg_tables, n, axis=0), jnp.int32)
+        slopes = alibi_slopes(nh) if alibi else None
+        kw = {"window": window} if window else {}
+        for s0, s1 in pairs:
+            starts = jnp.asarray(
+                [s + tile * t for s in (s0, s1) for t in range(n)],
+                jnp.int32)
+            pos = starts[:, None] + jnp.arange(tile, dtype=jnp.int32)[None]
+            q = jnp.asarray(rng.standard_normal((2 * n, tile, nh, hd)),
+                            jnp.bfloat16)
+            tag = f"{name} starts={s0},{s1} W={W}"
+            ok &= run_case(
+                f"paged_prefill loop {tag}",
+                lambda q, pk, pv, t, p: paged_prefill_attention(
+                    q, pk, pv, t, p, slopes, interpret=interpret, **kw),
+                lambda q, pk, pv, t, p: paged_gather_attention(
+                    q, pk, pv, t, p, slopes, **kw),
+                (q, pk, pv, tables, pos))
+            q_g, slopes_g = pa._query_tiles(q, nkv, slopes)
+            K, V, li = pa._stacked(pk, pv)
+            args = (q_g, K, V, li.reshape(1), tables, starts, slopes_g)
+            static = dict(block_tokens=bt, chunk=tile, groups=nh // nkv,
+                          use_alibi=alibi, interpret=interpret, **kw)
+            calls = {how: jax.jit(functools.partial(fn, **static))
+                     for how, fn in (("loop", pa._paged_prefill_loop_call),
+                                     ("grid", pa._paged_prefill_grid_call))}
+            outs = {how: np.asarray(jax.block_until_ready(fn(*args)),
+                                    np.float32)
+                    for how, fn in calls.items()}
+            row = {"name": f"paged_prefill loop == grid {tag}", "tol": 0,
+                   "max_abs_err": float(np.max(np.abs(
+                       outs["loop"] - outs["grid"])))}
+            row["ok"] = bool(np.array_equal(outs["loop"], outs["grid"]))
+            if not interpret:
+                row.update(prefill_times(calls, args))
+            emit(row)
+            ok &= row["ok"]
+    return ok
+
+
+def prefill_times(calls, args, chain: int = 8) -> dict:
+    """``{"loop_ms": .., "grid_ms": ..}``: one call's time, each kernel
+    chained ``chain`` times inside one program (a call's output is the
+    next one's queries) so that the host's dispatch is not in it."""
+    out = {}
+    for how, fn in calls.items():
+        many = jax.jit(lambda q, *rest: jax.lax.fori_loop(
+            0, chain, lambda _, y: fn(y, *rest), q))
+        jax.block_until_ready(many(*args))
+        ts = []
+        for _ in range(10):
+            t0 = time.perf_counter()
+            jax.block_until_ready(many(*args))
+            ts.append(time.perf_counter() - t0)
+        out[f"{how}_ms"] = round(float(np.median(ts)) / chain * 1e3, 4)
+    return out
+
+
 def paged_pool(rng, b, nkv, hd, bt, W, kv_dtype):
     """A pool whose every page of every row's table holds seeded K/V,
     written through the served write path."""
@@ -363,6 +464,8 @@ def main(argv=None) -> int:
                          "tool on a CPU, proves nothing about Mosaic")
     ap.add_argument("--gmm-only", action="store_true",
                     help="the grouped-matmul cases and timings alone")
+    ap.add_argument("--prefill-only", action="store_true",
+                    help="the prefill page loop's cases and timings alone")
     ap.add_argument("--engines", action="store_true",
                     help="also run bloom560m (ALiBi) and qwen2.5-0.5b "
                          "(GQA) engines with bf16 and int8 pages")
@@ -380,8 +483,9 @@ def main(argv=None) -> int:
         return 1
     W, b = (4, 2) if args.interpret else (64, 8)   # 64 pages = max_seq 1024
     ok = True
-    if args.gmm_only:
-        ok = gmm_cases(args.interpret)
+    if args.gmm_only or args.prefill_only:
+        ok = (gmm_cases if args.gmm_only
+              else prefill_loop_cases)(args.interpret)
         print("KERNEL_PARITY_DONE", flush=True)
         return 0 if ok else 1
     for model in MODELS:
@@ -413,6 +517,7 @@ def main(argv=None) -> int:
         ok &= paged_cases("qwen2.5-7b", "int8", 16, W, b, 64,
                           args.interpret)
         ok &= int8_pool_layout_case()
+    ok &= prefill_loop_cases(args.interpret)
     for model in ("qwen2.5-7b", "bloom560m"):
         ok &= flash_cases(model, 64 if args.interpret else 1024,
                           (16,) if args.interpret else (64, 256),
