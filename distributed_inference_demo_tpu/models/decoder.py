@@ -888,7 +888,7 @@ def stage_forward(
     tp_axis: Optional[str] = None,  # set inside shard_map for manual TP
     attn_impl=None,             # attention hook (see _default_attn)
     ep_axis: Optional[str] = None,  # expert-parallel MoE axis (shard_map)
-    last_logits_only: bool = False,  # head over the final position only
+    logits_at=None,             # [b] int32: the head's one position a row
     cache_in_carry: bool = True,  # in-place cache (inference) vs ys (train)
     moe_stats: bool = False,    # also return the experts' row counts
     valid: Optional[jnp.ndarray] = None,  # [b, s] rows that hold a token
@@ -903,10 +903,18 @@ def stage_forward(
     no expert's group (``_moe_routed``).  Nothing else reads it, and
     ``None`` traces the program traced without it.
 
-    ``last_logits_only`` narrows the LM-head matmul to the chunk's final
-    position (shape [b, 1, V]) — prefill only samples from the last token,
-    and a full [b, s, V] logits tensor at long prompts is GBs of HBM for
-    nothing.  Training and scoring paths keep the default (all positions).
+    ``logits_at`` says which positions want logits.  ``None``: all of
+    them, ``[b, s, V]`` (training and scoring).  An int32 index along
+    ``s``, one a row (``[b]``, or a scalar for every row): the last stage
+    gathers ``x[b, logits_at[b]]`` after its last layer and runs the
+    final norm and the head over those rows alone, ``[b, 1, V]`` (an
+    index is read as ``lax.dynamic_slice`` reads one: a negative one
+    counts from the end, and it is clamped into ``[0, s)``).  Whoever
+    samples reads one position a row: decode and a whole-prompt prefill
+    pass ``s - 1``, a padded chunk its last real token's column, the
+    mixed dispatch's slab each segment's ``seg_lens - 1``; a full
+    ``[b, s, V]`` product is the head's whole weight times every
+    position, and GBs of HBM at long prompts, for rows nobody reads.
 
     The stage seam replaces the reference's ``run_inference`` module boundary
     (``cpp/inference.cpp:145-218``): first stage embeds ids, last stage
@@ -1108,8 +1116,12 @@ def stage_forward(
     new_cache = KVCache(new_k, new_v, cache_start + inputs.shape[1])
 
     if spec.is_last:
-        if last_logits_only:
-            x = x[:, -1:, :]
+        if logits_at is not None:
+            at = jnp.broadcast_to(jnp.asarray(logits_at), x.shape[:1])
+            assert jnp.issubdtype(at.dtype, jnp.integer), (
+                f"logits_at is an index along s, not {at.dtype}")
+            x = jax.vmap(lambda row, i: jax.lax.dynamic_slice_in_dim(
+                row, i, 1, axis=0))(x, at)                 # [b, 1, H]
         if T == 1:  # a looped model's last pass closed with it already
             x = final_norm(x)
         x = x.astype(cfg.dtype)

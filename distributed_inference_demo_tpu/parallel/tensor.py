@@ -62,11 +62,17 @@ def validate_tp(cfg: ModelConfig, mesh: Mesh) -> int:
 
 def make_tp_forward(cfg: ModelConfig, spec: StageSpec, mesh: Mesh,
                     params_template: StageParams, attn_impl=None):
-    """``fwd(params, inputs, cache, positions, last_logits_only)`` running
+    """``fwd(params, inputs, cache, positions, logits_at)`` running
     ``stage_forward`` inside a tp shard_map — the seam every engine builds
     its jits on (runtime/engine.py, speculative.py, prompt_lookup.py,
     batching.py).  Activations/positions/logits are replicated; weights
     and the KV cache stay sharded per this module's specs.
+
+    ``logits_at`` is ``stage_forward``'s: ``None`` for logits at every
+    position, else the one position a row whose logits the caller reads
+    (replicated, like the inputs).  Each rank gathers those rows before
+    its slice of the head, so the vocab-parallel ``all_gather`` moves
+    ``[b, 1, V / tp]`` a rank, never ``[b, s, V / tp]``.
 
     ``attn_impl`` runs INSIDE the shard (per-rank head counts, local
     kv-head cache plane) — e.g. batching's per-slot scatter impl; None
@@ -74,16 +80,16 @@ def make_tp_forward(cfg: ModelConfig, spec: StageSpec, mesh: Mesh,
     validate_tp(cfg, mesh)
     p_specs = _tp_param_specs(params_template, cfg)
 
-    def fwd(p, inputs, cache, positions, last_logits_only):
-        def body(p, i, c, po):
+    def fwd(p, inputs, cache, positions, logits_at):
+        def body(p, i, c, po, at):
             return stage_forward(p, cfg, spec, i, c, po, tp_axis="tp",
-                                 attn_impl=attn_impl,
-                                 last_logits_only=last_logits_only)
+                                 attn_impl=attn_impl, logits_at=at)
+        # ``logits_at=None`` is an empty pytree: its spec matches nothing
         return jax.shard_map(
             body, mesh=mesh,
-            in_specs=(p_specs, P(), _CACHE_SPEC, P()),
+            in_specs=(p_specs, P(), _CACHE_SPEC, P(), P()),
             out_specs=(P(), _CACHE_SPEC),
-            check_vma=False)(p, inputs, cache, positions)
+            check_vma=False)(p, inputs, cache, positions, logits_at)
 
     return fwd
 
@@ -101,10 +107,9 @@ def make_forward_seam(cfg: ModelConfig, spec: StageSpec, mesh,
                                 attn_impl=attn_impl),
                 tp_cache_sharding(mesh))
 
-    def fwd(p, inputs, cache, positions, last_logits_only):
+    def fwd(p, inputs, cache, positions, logits_at):
         return stage_forward(p, cfg, spec, inputs, cache, positions,
-                             attn_impl=attn_impl,
-                             last_logits_only=last_logits_only)
+                             attn_impl=attn_impl, logits_at=logits_at)
 
     return fwd, None
 
@@ -135,8 +140,9 @@ def make_paged_forward_seam(cfg: ModelConfig, spec: StageSpec, mesh,
     ``fwd(..., moe_stats=True)`` (a model with experts) returns
     ``stage_forward``'s third value too, the ``[layers, E]`` rows routed
     to each expert, replicated under a mesh; ``valid`` is
-    ``stage_forward``'s (the rows that hold a token), replicated like
-    the inputs."""
+    ``stage_forward``'s (the rows that hold a token) and ``logits_at``
+    too (the one position a row that wants logits, or ``None`` for all:
+    see :func:`make_tp_forward`), both replicated like the inputs."""
     from ..ops.paged_attention import make_paged_attn_impl
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
     if tp <= 1:
@@ -150,11 +156,10 @@ def make_paged_forward_seam(cfg: ModelConfig, spec: StageSpec, mesh,
             impl, bind = make_paged_attn_impl(block_tokens, backend,
                                               interpret, record)
 
-        def fwd(p, inputs, cache, positions, last_logits_only,
+        def fwd(p, inputs, cache, positions, logits_at,
                 moe_stats=False, valid=None):
             return stage_forward(p, cfg, spec, inputs, cache, positions,
-                                 attn_impl=impl,
-                                 last_logits_only=last_logits_only,
+                                 attn_impl=impl, logits_at=logits_at,
                                  moe_stats=moe_stats, valid=valid)
 
         return fwd, bind, None
@@ -167,26 +172,27 @@ def make_paged_forward_seam(cfg: ModelConfig, spec: StageSpec, mesh,
         bound["tables"] = tables
         bound["program"] = program
 
-    def fwd(p, inputs, cache, positions, last_logits_only,
+    def fwd(p, inputs, cache, positions, logits_at,
             moe_stats=False, valid=None):
         program = bound["program"]
 
-        def body(p_, i_, c_, po_, tab_, valid_):
+        def body(p_, i_, c_, po_, tab_, at_, valid_):
             impl, bind_local = make_paged_attn_impl(block_tokens, backend,
                                                     interpret, record)
             bind_local(tab_, program)
             return stage_forward(p_, cfg, spec, i_, c_, po_,
                                  tp_axis="tp", attn_impl=impl,
-                                 last_logits_only=last_logits_only,
+                                 logits_at=at_,
                                  moe_stats=moe_stats, valid=valid_)
 
-        # ``valid=None`` is an empty pytree: its spec matches nothing
+        # ``None`` (``logits_at``, ``valid``) is an empty pytree: its
+        # spec matches nothing
         return jax.shard_map(
             body, mesh=mesh,
-            in_specs=(p_specs, P(), _CACHE_SPEC, P(), P(), P()),
+            in_specs=(p_specs, P(), _CACHE_SPEC, P(), P(), P(), P()),
             out_specs=(P(), _CACHE_SPEC) + ((P(),) if moe_stats else ()),
             check_vma=False)(p, inputs, cache, positions,
-                             bound["tables"], valid)
+                             bound["tables"], logits_at, valid)
 
     return fwd, bind, tp_cache_sharding(mesh)
 
@@ -204,6 +210,6 @@ def make_tp_stage_fn(cfg: ModelConfig, spec: StageSpec, mesh: Mesh,
     fwd = make_tp_forward(cfg, spec, mesh, params_template)
 
     def fn(params, inputs, cache, positions):
-        return fwd(params, inputs, cache, positions, False)
+        return fwd(params, inputs, cache, positions, None)
 
     return jax.jit(fn, donate_argnums=(2,))

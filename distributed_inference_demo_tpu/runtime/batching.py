@@ -608,8 +608,7 @@ class ContinuousBatchingEngine:
             (same rng spends, same masking) so paged-vs-plain-engine
             greedy parity is structural."""
             pos = lengths[:, None]
-            logits, cache = fwd_p(params, last_tok[:, None], cache, pos,
-                                  True)
+            logits, cache = fwd_p(params, last_tok[:, None], cache, pos, 0)
             return (cache, *_sample_step(logits, lengths, last_tok,
                                          active, rng))
 
@@ -729,9 +728,8 @@ class ContinuousBatchingEngine:
             b, s = ids.shape
             pos = start + jnp.broadcast_to(jnp.arange(s), (b, s))
             cache = KVCache(pk, pv, jnp.zeros((), jnp.int32))
-            logits, cache = fwd_p(params, ids, cache, pos, False)
-            last = jax.lax.dynamic_index_in_dim(
-                logits, real_len - 1, axis=1, keepdims=False)  # [1, V]
+            logits, cache = fwd_p(params, ids, cache, pos, real_len - 1)
+            last = logits[:, 0]                                # [1, V]
             tok = sample_logits(last, rng, samp_)
             lp = _emitted_logprob(last, tok)
             return cache.keys, cache.values, tok[0], lp[0]
@@ -777,15 +775,15 @@ class ContinuousBatchingEngine:
             n_seg = max(1, self.mixed_token_budget // C_mixed)
             self._mixed_seg_cap = n_seg
 
-        def slab_finals(logits, seg_lens, seg_keys):
+        def slab_finals(logits, seg_keys):
             """Per-row batch-1 sampling of the packed finals' token #1 —
             shared by the plain and speculative mixed programs (each row
-            its own key: the serialized final prefill's exact spend)."""
+            its own key: the serialized final prefill's exact spend).
+            ``logits`` [r, 1, V] is ``slab_body``'s: each segment's
+            last token's, the one position the head ran on."""
             f_toks, f_lps = [], []
             for r in range(logits.shape[0]):
-                last = jax.lax.dynamic_index_in_dim(
-                    logits[r], seg_lens[r] - 1, axis=0,
-                    keepdims=True)                         # [1, V]
+                last = logits[r]                           # [1, V]
                 tok_r = sample_logits(last, seg_keys[r], samp_)
                 f_toks.append(tok_r[0])
                 f_lps.append(_emitted_logprob(last, tok_r)[0])
@@ -822,7 +820,7 @@ class ContinuousBatchingEngine:
                 cache, acc = carry
                 pos = lengths[:, None]
                 logits, cache, rows = fwd_p(
-                    params, last_tok[:, None], cache, pos, True,
+                    params, last_tok[:, None], cache, pos, 0,
                     moe_stats=True, valid=active[:, None])
                 return ((cache, moe_fold(acc, rows)),
                         *_sample_step(logits, lengths, last_tok, active,
@@ -843,7 +841,8 @@ class ContinuousBatchingEngine:
                 ``seg_ids`` [r, C] runs at positions
                 ``seg_starts[r] + arange(C)`` through ``seg_tables[r]``
                 (sentinel rows compute into dropped writes).  Each row
-                samples token #1 at ``seg_lens[r] - 1`` from its OWN
+                samples token #1 at ``seg_lens[r] - 1``, the one
+                position of the row the head runs on, from its OWN
                 batch-1 rng key (``seg_keys[r]`` — the serialized
                 prefill's exact spend) and installs itself at
                 ``seg_slot[r]`` (slot = B = not-a-final, the install
@@ -869,12 +868,13 @@ class ContinuousBatchingEngine:
                     with jax.named_scope("slab_body"):
                         logits, cache, *moe = slab_body(
                             params, cache, seg_ids, seg_tables,
-                            seg_starts, "mixed_step", **slab_kw)
+                            seg_starts, seg_lens - 1, "mixed_step",
+                            **slab_kw)
                     if moe:
                         moe_acc = moe_fold(moe_acc, moe[0])
                     with jax.named_scope("slab_finals"):
                         final_toks, final_lps = slab_finals(
-                            logits, seg_lens, seg_keys)
+                            logits, seg_keys)
                     lengths = lengths.at[seg_slot].set(
                         seg_plen, mode="drop")
                     last_tok = last_tok.at[seg_slot].set(
@@ -929,7 +929,7 @@ class ContinuousBatchingEngine:
             verify_in = jnp.concatenate([last_tok[:, None], drafts],
                                         axis=1)
             pos = lengths[:, None] + jnp.arange(K + 1)[None, :]
-            t_logits, cache = fwd_p(params, verify_in, cache, pos, False)
+            t_logits, cache = fwd_p(params, verify_in, cache, pos, None)
             rng, sub_u, sub_x = jax.random.split(rng, 3)
             emitted, n, new_last = verify_emit_per_row(
                 t_logits, drafts, q_logits, samp_, sub_u, sub_x,
@@ -1034,10 +1034,11 @@ class ContinuousBatchingEngine:
                     cache = KVCache(pk, pv, jnp.zeros((), jnp.int32))
                     logits, cache = slab_body(params, cache, seg_ids,
                                               seg_tables, seg_starts,
+                                              seg_lens - 1,
                                               "mixed_pld_step")
                     if with_finals:
                         final_toks, final_lps = slab_finals(
-                            logits, seg_lens, seg_keys)
+                            logits, seg_keys)
                         lengths = lengths.at[seg_slot].set(
                             seg_plen, mode="drop")
                         last_tok = last_tok.at[seg_slot].set(
@@ -1162,7 +1163,7 @@ class ContinuousBatchingEngine:
                         tok, dc, r = c
                         pos = (lengths + j)[:, None]
                         logits, dc = fwd_dp(dparams, tok[:, None], dc,
-                                            pos, True)
+                                            pos, 0)
                         logits = logits[:, 0]
                         r, s = jax.random.split(r)
                         if samp_.greedy:
@@ -1206,7 +1207,7 @@ class ContinuousBatchingEngine:
                 b, s = ids.shape
                 pos = jnp.broadcast_to(jnp.arange(s), (b, s))
                 dcache = KVCache(row_k, row_v, jnp.zeros((), jnp.int32))
-                _, dcache = fwd_d(dparams, ids, dcache, pos, True)
+                _, dcache = fwd_d(dparams, ids, dcache, pos, s - 1)
                 return dcache.keys, dcache.values
 
             @partial(jax.jit, out_shardings=drow_shardings)
@@ -1245,10 +1246,11 @@ class ContinuousBatchingEngine:
                     cache = KVCache(pk, pv, jnp.zeros((), jnp.int32))
                     logits, cache = slab_body(params, cache, seg_ids,
                                               seg_tables, seg_starts,
+                                              seg_lens - 1,
                                               "mixed_spec_step")
                     if with_finals:
                         final_toks, final_lps = slab_finals(
-                            logits, seg_lens, seg_keys)
+                            logits, seg_keys)
                         lengths = lengths.at[seg_slot].set(
                             seg_plen, mode="drop")
                         last_tok = last_tok.at[seg_slot].set(
@@ -1267,7 +1269,7 @@ class ContinuousBatchingEngine:
                             tok, dc, r = c
                             pos = (lengths + j)[:, None]
                             dlogits, dc = fwd_dp(dparams, tok[:, None],
-                                                 dc, pos, True)
+                                                 dc, pos, 0)
                             dlogits = dlogits[:, 0]
                             r, s = jax.random.split(r)
                             if samp_.greedy:
@@ -4004,6 +4006,13 @@ class ContinuousBatchingEngine:
         packed, spec_mixed = plan.packed, plan.spec_mixed
         prefill_tokens, n_active = plan.prefill_tokens, plan.n_active
         final_toks, final_lps = flight.out[:2]
+        # the rows the head ran over: the slab's one a segment (a
+        # speculative program samples its slab only where it packed a
+        # final), and every slot's at each step (a verify round's chunk
+        # is the dispatch's draft width + 1 positions of each)
+        head_rows = (
+            (len(plan.seg[0]) if plan.finals or not spec_mixed else 0)
+            + steps * self.max_batch * (plan.k_disp + 1))
         record = dict(
             t_launch=flight.t_launch, t_done=flight.t_done,
             with_finals=bool(plan.finals), segments=len(packed),
@@ -4012,7 +4021,8 @@ class ContinuousBatchingEngine:
             kv_tokens=plan.kv_tokens, ahead=plan.ahead_s, how=plan.how,
             slab_rows=plan.slab_rows,
             prefill_pages_walked=plan.prefill_pages_walked[0],
-            prefill_pages_grid=plan.prefill_pages_grid)
+            prefill_pages_grid=plan.prefill_pages_grid,
+            head_rows=head_rows)
         if self.moe_counters is not None:
             # real tokens: the live segments' prompt tokens, and the
             # steps of the slots that decoded (rows that finish inside

@@ -116,6 +116,9 @@ class StageRuntime:
 
         from ..parallel.tensor import (make_forward_seam,
                                        make_paged_forward_seam)
+        # the last stage samples from the chunk's final position and its
+        # head runs on that position alone (``logits_at = s - 1``); a
+        # stage that is not last hands on every position's hidden state
         take_last = spec.is_last
         if self.kv_layout == "paged":
             import math
@@ -154,7 +157,7 @@ class StageRuntime:
                 cache = KVCache(pk, pv, length)
                 b, s = inputs.shape[0], inputs.shape[1]
                 pos = length + jnp.broadcast_to(jnp.arange(s), (b, s))
-                out, cache = fwd(params, inputs, cache, pos, False)
+                out, cache = fwd(params, inputs, cache, pos, s - 1)
                 return ((out[:, -1] if take_last else out),
                         cache.keys, cache.values)
 
@@ -168,7 +171,7 @@ class StageRuntime:
                 cache = KVCache(pk, pv, length)
                 b, s = inputs.shape[0], inputs.shape[1]
                 pos = length + jnp.broadcast_to(jnp.arange(s), (b, s))
-                out, cache = fwd(params, inputs, cache, pos, False)
+                out, cache = fwd(params, inputs, cache, pos, s - 1)
                 return (sample_logits(out[:, -1], rng, sampling),
                         cache.keys, cache.values)
 
@@ -187,7 +190,7 @@ class StageRuntime:
                 b, s = inputs.shape[0], inputs.shape[1]
                 pos = cache.length + jnp.broadcast_to(jnp.arange(s),
                                                       (b, s))
-                out, cache = fwd(params, inputs, cache, pos, False)
+                out, cache = fwd(params, inputs, cache, pos, s - 1)
                 return (out[:, -1] if take_last else out), cache
 
             @jax.jit
@@ -201,7 +204,7 @@ class StageRuntime:
                 b, s = inputs.shape[0], inputs.shape[1]
                 pos = cache.length + jnp.broadcast_to(jnp.arange(s),
                                                       (b, s))
-                out, cache = fwd(params, inputs, cache, pos, False)
+                out, cache = fwd(params, inputs, cache, pos, s - 1)
                 return sample_logits(out[:, -1], rng, sampling), cache
 
             self._forward = forward

@@ -137,19 +137,18 @@ def make_chunk_programs(fwd):
         """One non-final prompt chunk: extend the cache, drop logits."""
         b, s = ids.shape
         pos = start + jnp.broadcast_to(jnp.arange(s), (b, s))
-        _, cache = fwd(params, ids, cache, pos, True)
+        _, cache = fwd(params, ids, cache, pos, s - 1)
         return cache
 
     @partial(jax.jit, donate_argnums=(2,))
     def chunk_last(params, ids, cache, start, gather_idx):
         """Final (possibly pad-tailed) chunk: logits at the prompt's
-        true last position."""
+        true last position, column ``gather_idx`` of the chunk (the
+        head runs on that position alone)."""
         b, s = ids.shape
         pos = start + jnp.broadcast_to(jnp.arange(s), (b, s))
-        logits, cache = fwd(params, ids, cache, pos, False)
-        last = jax.lax.dynamic_index_in_dim(logits, gather_idx, axis=1,
-                                            keepdims=False)
-        return last, cache
+        logits, cache = fwd(params, ids, cache, pos, gather_idx)
+        return logits[:, 0], cache
 
     return chunk_mid, chunk_last
 
@@ -180,14 +179,16 @@ def make_paged_chunk_programs(fwd_p, bind_tables):
         b, s = ids.shape
         pos = start + jnp.broadcast_to(jnp.arange(s), (b, s))
         cache = KVCache(pk, pv, jnp.zeros((), jnp.int32))
-        _, cache = fwd_p(params, ids, cache, pos, True)
+        _, cache = fwd_p(params, ids, cache, pos, s - 1)
         return cache.keys, cache.values
 
-    def slab_body(params, cache, ids, tables, starts, program,
+    def slab_body(params, cache, ids, tables, starts, last, program,
                   moe_stats=False, ntok=None):
         """Traced slab forward: row r of ``ids`` [n, s] runs at
         positions ``starts[r] + arange(s)`` through ``tables[r]``;
-        returns all-position logits (callers slice their own final
+        returns logits at ONE position a row, column ``last[r]`` (the
+        last token the segment holds, the only one anybody samples:
+        ``[n, 1, V]``, the head never sees the other ``s - 1``
         positions) and the extended cache, and with ``moe_stats`` the
         seam's expert row counts, over the first ``ntok[r]`` positions
         of each row (the tokens it holds; the rest enter no expert's
@@ -197,9 +198,9 @@ def make_paged_chunk_programs(fwd_p, bind_tables):
         b, s = ids.shape
         pos = starts[:, None] + jnp.arange(s)[None, :]
         if moe_stats:
-            return fwd_p(params, ids, cache, pos, False, moe_stats=True,
+            return fwd_p(params, ids, cache, pos, last, moe_stats=True,
                          valid=jnp.arange(s)[None, :] < ntok[:, None])
-        logits, cache = fwd_p(params, ids, cache, pos, False)
+        logits, cache = fwd_p(params, ids, cache, pos, last)
         return logits, cache
 
     return chunk_mid, slab_body
@@ -492,11 +493,11 @@ class InferenceEngine:
         def prefill(params, ids, cache):
             b, s = ids.shape
             pos = jnp.broadcast_to(jnp.arange(s), (b, s))
-            # last_logits_only: the LM head runs on the final position only
-            # ([b, 1, V]) — a full [b, s, V] logits tensor at long prompts
-            # would burn GBs of HBM and head-matmul FLOPs for nothing.
-            logits, cache = fwd(params, ids, cache, pos, True)
-            return logits[:, -1], cache
+            # the LM head runs on the final position only ([b, 1, V]) — a
+            # full [b, s, V] logits tensor at long prompts would burn GBs
+            # of HBM and head-matmul FLOPs for nothing.
+            logits, cache = fwd(params, ids, cache, pos, s - 1)
+            return logits[:, 0], cache
 
         self._prefill_chunk_mid, self._prefill_chunk_last = \
             make_chunk_programs(fwd)
@@ -560,7 +561,7 @@ class InferenceEngine:
                 lps = jax.lax.dynamic_update_slice(
                     lps, lp[:, None], (jnp.int32(0), j))
                 pos = jnp.broadcast_to(cache.length, (b, 1))
-                out, cache = fwd(params, tok[:, None], cache, pos, False)
+                out, cache = fwd(params, tok[:, None], cache, pos, None)
                 return (j + 1, out[:, 0], cache, rng, done, toks, lps)
 
             (steps, logits, cache, rng, done, toks, lps) = \
@@ -587,7 +588,7 @@ class InferenceEngine:
             # streaming path is dispatch-bound, so it's in the noise)
             lp = _emitted_lp(last_logits, tok)
             pos = jnp.broadcast_to(cache.length, (b, 1))
-            out, cache = fwd(params, tok[:, None], cache, pos, False)
+            out, cache = fwd(params, tok[:, None], cache, pos, None)
             return tok, lp, out[:, 0], cache, rng, done
 
         # observatory seams (docs/DESIGN.md §20): compile accounting on

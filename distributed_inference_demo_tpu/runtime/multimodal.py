@@ -85,7 +85,7 @@ class MultimodalEngine:
             pos = jnp.broadcast_to(jnp.arange(s), (b, s))
             logits, cache = stage_forward(dec_params, cfg, spec, embeds,
                                           cache, pos, attn_impl=attn_impl,
-                                          last_logits_only=True)
+                                          logits_at=s - 1)
             return logits[:, -1], cache
 
         self._prefill_embeds = prefill_embeds

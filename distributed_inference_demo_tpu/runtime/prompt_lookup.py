@@ -146,7 +146,7 @@ class PromptLookupEngine:
         def prefill(params, ids, cache):
             b, s = ids.shape
             pos = jnp.broadcast_to(jnp.arange(s), (b, s))
-            logits, cache = fwd(params, ids, cache, pos, True)
+            logits, cache = fwd(params, ids, cache, pos, s - 1)
             return logits[:, -1], cache
 
         def one_round(params, last_tok, cache, history, hist_len, rng):
@@ -158,7 +158,7 @@ class PromptLookupEngine:
             verify_in = jnp.concatenate([last_tok[:, None], drafts], axis=1)
             pos = n + jnp.broadcast_to(jnp.arange(K + 1), (b, K + 1))
             t_logits, cache = fwd(params, verify_in, cache, pos,
-                                  False)                      # [b, K+1, V]
+                                  None)                       # [b, K+1, V]
 
             # shared rejection rule; q_logits=None = one-hot proposer
             rng, sub_u, sub_x = jax.random.split(rng, 3)

@@ -367,8 +367,8 @@ class SpeculativeEngine:
         def prefill_both(tparams, dparams, ids, tcache, dcache):
             b, s = ids.shape
             pos = jnp.broadcast_to(jnp.arange(s), (b, s))
-            t_logits, tcache = fwd_t(tparams, ids, tcache, pos, True)
-            _, dcache = fwd_d(dparams, ids, dcache, pos, True)
+            t_logits, tcache = fwd_t(tparams, ids, tcache, pos, s - 1)
+            _, dcache = fwd_d(dparams, ids, dcache, pos, s - 1)
             return t_logits[:, -1], tcache, dcache
 
         # chunked-prefill programs (engine.run_chunked_prefill drives
@@ -405,7 +405,7 @@ class SpeculativeEngine:
             def dstep(carry, _):
                 tok, dc, rng = carry
                 pos = jnp.broadcast_to(dc.length, (b, 1))
-                logits, dc = fwd_d(dparams, tok[:, None], dc, pos, True)
+                logits, dc = fwd_d(dparams, tok[:, None], dc, pos, 0)
                 logits = logits[:, 0]
                 rng, sub = jax.random.split(rng)
                 if samp_.greedy:
@@ -426,7 +426,7 @@ class SpeculativeEngine:
             verify_in = jnp.concatenate([last_tok[:, None], drafts], axis=1)
             pos = n + jnp.broadcast_to(jnp.arange(K + 1), (b, K + 1))
             t_logits, tcache = fwd_t(tparams, verify_in, tcache, pos,
-                                     False)        # [b, K+1, V]
+                                     None)         # [b, K+1, V]
 
             # --- accept / resample / lockstep advance (shared rule) -------
             rng, sub_u, sub_x = jax.random.split(rng, 3)

@@ -178,10 +178,14 @@ _LAUNCHED_PHASES = ("launch", "wait", "drain")
 # tiles of the packed segments, one layer call of the full (or only)
 # kind of block (``ops.paged_attention.prefill_pages_walked``: host
 # arithmetic on the segments' starts); 0 on a decode-only dispatch
+# ``head_rows`` (after it): the rows of hidden state the LM head ran
+# over in the execution, one a segment of the slab (the position it
+# samples, PR 48: not the chunk's every position) and one a slot at
+# each decode step (host arithmetic on the launched program's shapes)
 DISPATCH_FIELDS = (("seq", "t_launch", "t_done") + DISPATCH_PHASES
                    + ("with_finals", "segments", "finals",
                       "prefill_tokens", "prefill_pages_walked",
-                      "active_rows", "steps",
+                      "head_rows", "active_rows", "steps",
                       "kv_tokens", "ahead", "late", "await"))
 # what a launched dispatch keeps until its commit: its phases' seconds
 # and the two columns its blocking read fills
@@ -404,6 +408,7 @@ class DispatchTrace:
         self.slab_rows = 0
         self.prefill_pages_walked = 0
         self.prefill_pages_grid = 0
+        self.head_rows = 0
         self.queue_wait_ms_sum = 0.0
         self.queue_wait_count = 0
         self.ahead_hits = self.ahead_hits_slab = 0
@@ -593,7 +598,7 @@ class DispatchTrace:
                ahead: float = 0.0, how: Optional[str] = None,
                phases: Optional[dict] = None, slab_rows: int = 0,
                prefill_pages_walked: int = 0, prefill_pages_grid: int = 0,
-               **extra: int) -> int:
+               head_rows: int = 0, **extra: int) -> int:
         """A dispatch that reached the device is drained: one record.
         ``slab_rows``: the rows of the prefill slab its program computed
         (segments of the launched variant x the chunk), of which
@@ -602,6 +607,8 @@ class DispatchTrace:
         ``prefill_pages_grid`` (summed): the pages the prefill kernel's
         loop walks for the slab, and the steps a grid of one page of the
         table a step would have had, its tiles x the table's width.
+        ``head_rows`` (a column, and summed): the rows the LM head ran
+        over, one a segment of the slab and one a slot a decode step.
         ``phases``: its own seconds (``launched_phases`` as they were
         when the NEXT dispatch had not been launched yet; by default the
         last launched one's).  ``how``: ``"hit"`` (launched as prepared
@@ -621,7 +628,7 @@ class DispatchTrace:
             self.seq, round(t_launch, 5), round(t_done, 5),
             *(round(phases[p], 5) for p in DISPATCH_PHASES),
             int(with_finals), segments, finals, prefill_tokens,
-            prefill_pages_walked, active_rows, steps, kv_tokens,
+            prefill_pages_walked, head_rows, active_rows, steps, kv_tokens,
             round(ahead, 5),
             int(phases["late"]), round(phases["await"], 5),
             *(extra[f] for f in self.extra_fields)))
@@ -634,6 +641,7 @@ class DispatchTrace:
         self.slab_rows += slab_rows
         self.prefill_pages_walked += prefill_pages_walked
         self.prefill_pages_grid += prefill_pages_grid
+        self.head_rows += head_rows
         if how == "hit":
             self.ahead_hits += 1
             self.ahead_hits_slab += bool(segments)
@@ -660,6 +668,7 @@ class DispatchTrace:
                 "slab_rows": self.slab_rows,
                 "prefill_pages_walked": self.prefill_pages_walked,
                 "prefill_pages_grid": self.prefill_pages_grid,
+                "head_rows": self.head_rows,
                 "queue_wait_ms_sum": round(self.queue_wait_ms_sum, 3),
                 "queue_wait_count": self.queue_wait_count,
                 "ahead_hits": self.ahead_hits,

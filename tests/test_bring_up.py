@@ -212,14 +212,14 @@ def _seam_programs(cfg, params, mesh, backend, record, bt=8, W=4, N=16):
         bind(tables, "slab")
         pos = jnp.broadcast_to(jnp.arange(ids.shape[1]), ids.shape)
         logits, cache = fwd(params, ids, KVCache(pk, pv, jnp.int32(0)),
-                            pos, False)
+                            pos, None)
         return logits, cache.keys, cache.values
 
     @jax.jit
     def step(params, pk, pv, tok, lengths):
         bind(tables, "step")
         logits, _ = fwd(params, tok[:, None], KVCache(pk, pv, jnp.int32(0)),
-                        lengths[:, None], True)
+                        lengths[:, None], 0)
         return logits
 
     ids = jnp.asarray(np.random.RandomState(0).randint(

@@ -185,6 +185,19 @@ def test_prefill_pages_walked_is_the_hand_count(scripted):
     assert stats["dispatch_trace"]["prefill_pages_grid"] == 4 * 12
 
 
+def test_head_rows_is_the_hand_count(scripted):
+    """PR 48: the head runs on one position a segment of the slab, not
+    on the chunk's eight, and on every slot (four here) at each decode
+    step: 3 segments + 4 steps x 4 slots, then 4 x 4, 1 x 4, 1 + 4 x 4,
+    1 x 4.  With the head over every position the two slabs would have
+    read 24 and 8 where they read 3 and 1."""
+    stats, _, _ = scripted
+    recs = rows(stats)
+    assert [r["head_rows"] for r in recs] == [
+        r["segments"] + r["steps"] * 4 for r in recs] == [19, 16, 4, 17, 4]
+    assert stats["dispatch_trace"]["head_rows"] == 60
+
+
 def test_phases_fit_between_a_record_and_its_neighbour(scripted):
     recs = rows(scripted[0])
     for r in recs:

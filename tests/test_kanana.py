@@ -365,7 +365,7 @@ def test_idle_rows_enter_no_group_and_write_no_page(params):
     bind(tables, "test")
     _, cache, rows = fwd(
         params, jnp.asarray([[5], [6], [7], [8]]),
-        KVCache(pk, pv, jnp.int32(0)), jnp.full((4, 1), n), True,
+        KVCache(pk, pv, jnp.int32(0)), jnp.full((4, 1), n), 0,
         moe_stats=True, valid=valid[:, None])
     assert rows.shape == (L, CFG.num_experts)
     assert [int(r.sum()) for r in rows] == [2 * CFG.experts_per_token] * L

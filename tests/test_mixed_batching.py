@@ -299,6 +299,122 @@ def test_a_slab_of_the_packed_segments_is_the_full_slab_s_dispatch(model, r):
         assert int(np.asarray(cut["moe_acc"])[:E].sum()) == tokens * k * L
 
 
+@pytest.mark.parametrize("sampled", [False, True],
+                         ids=["greedy", "sampled"])
+@pytest.mark.parametrize("r", [1, 3])
+@pytest.mark.parametrize("model", ["llama-test", "olmoe-test"])
+def test_a_final_s_token_is_the_serialized_admission_s(model, r, sampled):
+    """PR 48: the slab's head runs on ONE position a segment.  A slab of
+    one segment and of the budget's ``n_seg``, the last a final that
+    holds six tokens of its chunk's eight (``seg_lens < C``), the others
+    chunks of another prompt that is not done (``seg_slot = B``): the
+    final's token #1 and its log-probability are those the serialized
+    admission gives (``paged_prefill`` over a bucket of sixteen, the same
+    table, the same batch-1 key), greedy and sampled; a chunk installs
+    nothing; and its pages hold what ``paged_chunk_mid`` writes there."""
+    cfg = get_model_config(model)
+    samp = (SamplingParams(greedy=False, temperature=0.9, top_k=40)
+            if sampled else GREEDY)
+    with ContinuousBatchingEngine(
+            cfg, init_full_params(jax.random.PRNGKey(0), cfg), max_seq=96,
+            max_batch=4, sampling=samp, prompt_buckets=(16, 48),
+            kv_block_tokens=8, prefill_chunk=8, decode_block=4,
+            mixed_token_budget=24) as eng:
+        B, sent = eng.max_batch, eng._page_sentinel
+        copy = lambda t: jax.tree.map(jnp.copy, t)       # noqa: E731
+        seg = _hand_packed(eng, r)
+        tables = np.full((B, eng._table_width), sent, np.int32)
+        tables[1, :2] = (4, 5)       # the final's row, live before it runs
+        zeros = jnp.zeros((B,), jnp.int32)
+        out = eng._mixed_step.inner(
+            eng.params, *copy((eng._pk, eng._pv)), seg,
+            jnp.asarray(tables), zeros, zeros, jnp.zeros((B,), bool),
+            jax.random.PRNGKey(1), jnp.int32(-1),
+            jnp.asarray([0, 5, 0, 0], jnp.int32), eng.decode_block)
+        pool, lengths, last = out[:2], out[2], out[3]
+        final_toks, final_lps = np.asarray(out[4]), np.asarray(out[5])
+        assert final_toks.shape == final_lps.shape == (r,)
+        # the serialized admission of the same six tokens
+        ids = np.zeros((1, 16), np.int32)
+        ids[0, :6] = seg[0][r - 1, :6]
+        pk, pv, tok, lp = eng._paged_prefill.inner(
+            eng.params, *copy((eng._pk, eng._pv)), jnp.asarray(ids),
+            jnp.asarray(tables[1][None]), jnp.int32(0), jnp.int32(6),
+            jnp.asarray(seg[6][r - 1]))
+        if r > 1:       # ... and of the other prompt's first chunks
+            long_t = np.full((1, eng._table_width), sent, np.int32)
+            long_t[0, :2] = (8, 9)
+            for i in range(r - 1):
+                pk, pv = eng._paged_chunk_mid.inner(
+                    eng.params, pk, pv, jnp.asarray(seg[0][i][None]),
+                    jnp.asarray(long_t), jnp.int32(8 * i))
+    assert final_toks[r - 1] == int(tok)
+    np.testing.assert_allclose(final_lps[r - 1], float(lp), rtol=1e-5)
+    # slot 1 took the final (six tokens, then four steps); a chunk's
+    # sample went nowhere
+    assert list(np.asarray(lengths)) == [0, 10, 0, 0]
+    assert np.asarray(last)[[0, 2, 3]].tolist() == [0, 0, 0]
+    # the prompts' pages: the six tokens' rows of page 4, the chunks'
+    for got, want in zip(jax.tree.leaves(pool), jax.tree.leaves((pk, pv))):
+        got, want = np.asarray(got), np.asarray(want)
+        np.testing.assert_allclose(got[:, 4, :, :6], want[:, 4, :, :6],
+                                   rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(got[:, 8:8 + r - 1], want[:, 8:8 + r - 1],
+                                   rtol=1e-5, atol=1e-6)
+        assert r == 1 or np.abs(want[:, 8:8 + r - 1]).sum() > 0
+
+
+def _shapes_in(jaxpr, prim=None):
+    """Every eqn's output shapes in a jaxpr and its sub-jaxprs (of the
+    primitive named, its operands' instead)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if prim is None:
+            found += [v.aval.shape for v in eqn.outvars]
+        elif eqn.primitive.name == prim:
+            found += [v.aval.shape for v in eqn.invars]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _shapes_in(sub, prim)
+    return found
+
+
+@pytest.mark.parametrize("tp", [1, 4])
+def test_no_array_of_the_slab_s_every_position_by_the_vocabulary(tp):
+    """PR 48, the guard: as traced, ``mixed_step`` with a full slab holds
+    no array of ``r x C`` rows by ``V`` (or ``V / tp``) columns: the
+    widest thing with the vocabulary's columns is ``[rows, 1, V]``, for
+    the slab's ``r`` segments and for the decode loop's ``B`` slots.
+    Over a mesh of four (CPU) devices the vocab-parallel head's
+    ``all_gather`` moves ``[r, 1, V / 4]`` and ``[B, 1, V / 4]``."""
+    from distributed_inference_demo_tpu.parallel.mesh import local_tp_mesh
+    from distributed_inference_demo_tpu.runtime.engine import (
+        shard_engine_params)
+    # four kv heads shard over four chips; an untied head, so that no
+    # weight is [H, V] transposed; V and V / 4 are no other width
+    cfg = dataclasses.replace(CFG, num_kv_heads=4, vocab_size=320)
+    mesh = local_tp_mesh(tp)
+    params = init_full_params(jax.random.PRNGKey(0), cfg)
+    with mock.patch.object(ContinuousBatchingEngine, "_warm_mixed_variants",
+                           lambda self: None), ContinuousBatchingEngine(
+            cfg, params, max_seq=96, max_batch=4, mesh=mesh,
+            sampling=GREEDY, kv_block_tokens=8, prefill_chunk=8,
+            decode_block=4, mixed_token_budget=24) as eng:
+        if mesh is not None:
+            eng.params = shard_engine_params(params, cfg, mesh)
+        r, C, B, V = eng._mixed_seg_cap, 8, eng.max_batch, cfg.vocab_size
+        call = abstract_mixed_call(eng, slab=True)
+        jaxpr = jax.make_jaxpr(eng._mixed_step.inner, static_argnums=(11,))(
+            *call).jaxpr
+    wide = {s for s in _shapes_in(jaxpr) if s and s[-1] in (V, V // tp)}
+    assert (r, 1, V) in wide and (B, 1, V) in wide
+    assert max(int(np.prod(s[:-1])) for s in wide) == max(r, B) < r * C
+    gathered = [s for s in _shapes_in(jaxpr, "all_gather")]
+    if tp == 1:
+        assert not gathered
+    else:
+        assert set(gathered) == {(r, 1, V // 4), (B, 1, V // 4)}
+
+
 @pytest.mark.parametrize("budget", [16, 24])
 def test_every_variant_is_launched_before_the_first_request(params, budget):
     """``n_seg + 1`` compiled entries of ``mixed_step`` when the engine

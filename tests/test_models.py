@@ -38,6 +38,55 @@ def test_forward_shapes(name):
     assert np.isfinite(np.asarray(logits, np.float32)).all()
 
 
+@pytest.mark.quick
+@pytest.mark.parametrize("at", [(4, 0), 3, (9, -1)],
+                         ids=["a-row-each", "one-for-all", "clamped"])
+@pytest.mark.parametrize("name", ["llama-test", "bloom-test", "ouro-test",
+                                  "olmoe-test"])
+def test_logits_at_one_position_are_that_row_of_all_positions(name, at):
+    """``logits_at`` (PR 48): the head over ONE position a row gives the
+    row that the head over every position gives there, ``[b, 1, V]`` of
+    ``[b, s, V]``, for a dense model, a tied head behind a layer norm
+    (bloom), a looped model (ouro: ``T > 1``, the last pass closed with
+    the final norm already, so the gather comes after the loop) and a
+    model with experts under ``valid`` (the gather is after the last
+    layer: the rows routed and the cache are the same).  An index is one
+    a row or one for all, and read as ``dynamic_index_in_dim`` read it:
+    a negative one counts from the end, then it is clamped into the
+    chunk."""
+    cfg = get_model_config(name)
+    params = init_full_params(jax.random.PRNGKey(0), cfg)
+    # a norm left out, or applied to the wrong rows, moves the logits
+    params.final_norm["w"] = 1.0 + 0.3 * jax.random.normal(
+        jax.random.PRNGKey(1), params.final_norm["w"].shape)
+    b, s = 2, 6
+    ids = (jnp.arange(b * s, dtype=jnp.int32).reshape(b, s) * 7 + 3) % 256
+    pos = jnp.broadcast_to(jnp.arange(s), (b, s))
+    kw = {}
+    if cfg.num_experts:
+        kw = dict(moe_stats=True,
+                  valid=jnp.arange(s)[None, :] < jnp.asarray([[s], [5]]))
+
+    def run(logits_at):
+        cache = KVCache.create(cfg, cfg.num_layers, batch=b, max_seq=16)
+        return stage_forward(params, cfg, _full_spec(cfg), ids, cache, pos,
+                             logits_at=logits_at, **kw)
+
+    whole, one = run(None), run(jnp.asarray(at, jnp.int32))
+    assert whole[0].shape == (b, s, cfg.vocab_size)
+    assert one[0].shape == (b, 1, cfg.vocab_size)
+    rows = np.broadcast_to(np.asarray(at), (b,))
+    rows = np.clip(np.where(rows < 0, rows + s, rows), 0, s - 1)
+    np.testing.assert_allclose(
+        np.asarray(one[0][:, 0]), np.asarray(whole[0])[np.arange(b), rows],
+        rtol=1e-5, atol=1e-6)
+    # ... and nothing before the head knows which rows it was asked for
+    for x, y in zip(jax.tree.leaves(whole[1:]), jax.tree.leaves(one[1:])):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    with pytest.raises(AssertionError, match="index along s"):
+        run(True)       # the flag this argument replaced
+
+
 @pytest.mark.parametrize("name", [
     "llama-test", "bloom-test",
     # MoE twin — slow lane: the cache layout is llama's; the routed

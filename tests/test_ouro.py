@@ -221,7 +221,8 @@ def test_poisoning_a_plane_changes_only_what_its_pass_reads(params, t, l):
 
     def run(pk, pv, x, pos):
         bind(tables, "test")
-        logits, c = fwd(params, x, KVCache(pk, pv, jnp.int32(0)), pos, True)
+        logits, c = fwd(params, x, KVCache(pk, pv, jnp.int32(0)), pos,
+                        x.shape[1] - 1)
         return logits, c.keys, c.values
 
     _, pk, pv = run(pk, pv, ids, jnp.arange(n)[None])
@@ -495,4 +496,6 @@ def test_one_pass_record_and_stats_are_the_parent_s(model):
     # ... and PR 47's, what the prefill kernel's page loop walks
     at = fields.index("prefill_tokens") + 1
     fields[at:at] = ["prefill_pages_walked"]
+    # ... and PR 48's, the rows the head ran over
+    fields[at + 1:at + 1] = ["head_rows"]
     assert st["dispatch_trace"]["fields"] == fields

@@ -242,7 +242,7 @@ def served_logprobs(cfg, params, prompts, args):
         bind(tables, "prefill")
         pos = start + jnp.broadcast_to(jnp.arange(ids.shape[1]), ids.shape)
         logits, cache = fwd(params, ids, KVCache(pk, pv, jnp.int32(0)),
-                            pos, True)
+                            pos, ids.shape[1] - 1)
         return (jax.nn.log_softmax(logits[:, -1].astype(jnp.float32), -1),
                 cache.keys, cache.values)
 
@@ -251,7 +251,7 @@ def served_logprobs(cfg, params, prompts, args):
         bind(tables, "decode")
         logits, cache = fwd(params, tok[:, None],
                             KVCache(pk, pv, jnp.int32(0)), length[:, None],
-                            True)
+                            0)
         return (jax.nn.log_softmax(logits[:, 0].astype(jnp.float32), -1),
                 cache.keys, cache.values)
 
