@@ -170,7 +170,6 @@ def _build_spec_engine(args):
         mesh=mesh, eos_id=getattr(args, "eos_id", None),
         kv_cache_dtype=getattr(args, "kv_cache_dtype", None) or None,
         prefill_chunk=getattr(args, "prefill_chunk", 0) or None,
-        kv_layout=getattr(args, "kv_layout", None),
         kv_dtype=getattr(args, "kv_dtype", None),
         **_kvcache_from_args(args))
 
@@ -197,7 +196,6 @@ def _build_prompt_lookup_engine(args):
         eos_id=getattr(args, "eos_id", None),
         kv_cache_dtype=getattr(args, "kv_cache_dtype", None) or None,
         prefill_chunk=getattr(args, "prefill_chunk", 0) or None,
-        kv_layout=getattr(args, "kv_layout", None),
         kv_dtype=getattr(args, "kv_dtype", None),
         **_kvcache_from_args(args))
 
@@ -218,7 +216,6 @@ def _build_engine(args):
         prefill_chunk=getattr(args, "prefill_chunk", 0) or None,
         stream_block=getattr(args, "stream_block", None),
         mesh=mesh, eos_id=getattr(args, "eos_id", None),
-        kv_layout=getattr(args, "kv_layout", None),
         kv_dtype=getattr(args, "kv_dtype", None),
         **_kvcache_from_args(args))
 
@@ -359,8 +356,7 @@ def cmd_serve(args) -> int:
         # own business — the wire carries activations, not cache state)
         rt = ElasticStageRuntime(
             cfg, specs[0], full, args.max_seq, sampling,
-            kv_cache_dtype=getattr(args, "kv_cache_dtype", "") or None,
-            kv_layout=getattr(args, "kv_layout", None))
+            kv_cache_dtype=getattr(args, "kv_cache_dtype", "") or None)
         header = ElasticHeader(rt, transport, chain,
                                eos_id=getattr(args, "eos_id", None),
                                step_timeout=args.step_timeout)
@@ -419,8 +415,7 @@ def cmd_serve(args) -> int:
             strategy=args.sp_strategy, sampling=_sampling_from_args(args),
             kv_cache_dtype=getattr(args, "kv_cache_dtype", None) or None,
             eos_id=getattr(args, "eos_id", None),
-            max_queue_depth=getattr(args, "sp_queue_depth", None),
-            kv_layout=getattr(args, "kv_layout", None))
+            max_queue_depth=getattr(args, "sp_queue_depth", None))
         print(f"SERVE_SP {args.model} sp={args.sp} "
               f"strategy={args.sp_strategy} max_seq={args.max_seq}",
               flush=True)
@@ -489,7 +484,6 @@ def cmd_serve(args) -> int:
             sampling=_sampling_from_args(args),
             eos_id=getattr(args, "eos_id", None),
             attn_backend=args.attn_backend,
-            kv_layout=getattr(args, "kv_layout", None),
             kv_dtype=getattr(args, "kv_dtype", None)))
         print(f"SERVE_VISION {args.model} tower={args.vision_preset} "
               f"image={vcfg.image_size} patches={vcfg.num_patches}",
@@ -525,14 +519,11 @@ def cmd_serve(args) -> int:
             prefill_chunk=getattr(args, "prefill_chunk", 0) or None,
             mixed_token_budget=getattr(args, "mixed_token_budget", 0)
             or None,
-            kv_layout=getattr(args, "kv_layout", None),
             kv_dtype=getattr(args, "kv_dtype", None),
             max_queue_depth=getattr(args, "admission_queue_depth", 0),
             **_kvcache_from_args(args), **_kv_tier_from_args(args))
         kvc = backend.kv_cache
-        kv_desc = "off" if kvc is None else (
-            f"{getattr(kvc, 'num_blocks', None) or kvc.pool.num_blocks}"
-            f"x{kvc.block_tokens}tok {backend.kv_layout}")
+        kv_desc = f"{kvc.num_blocks}x{kvc.block_tokens}tok"
         print(f"SERVE_BATCHING {args.model} slots={args.batch_slots} "
               f"kv_cache={kv_desc} "
               f"tp={getattr(args, 'tp', 1)}"
@@ -766,12 +757,6 @@ def cmd_worker(args) -> int:
     ap.add_argument("--kv-cache-dtype", default="",
                     help="reduced-precision KV cache storage for this "
                          "stage, e.g. float8_e4m3fn")
-    ap.add_argument("--kv-layout", default=None,
-                    choices=["paged"],
-                    help="this stage's request-cache layout (paged is "
-                         "the only layout: per-stage page pool, blocks "
-                         "reserved per chunk actually run; 'dense' was "
-                         "removed — docs/DESIGN.md §14)")
     ap.add_argument("--fault-plan", default="",
                     help="CHAOS TESTING ONLY: JSON fault-plan spec "
                          "(path or inline); requires --chaos")
@@ -795,8 +780,7 @@ def cmd_worker(args) -> int:
     from .parallel.mesh import local_tp_mesh
     rt = ElasticStageRuntime(cfg, spec, full, a.max_seq, sampling,
                              mesh=local_tp_mesh(a.tp),
-                             kv_cache_dtype=a.kv_cache_dtype or None,
-                             kv_layout=a.kv_layout)
+                             kv_cache_dtype=a.kv_cache_dtype or None)
     transport = maybe_wrap(
         ZmqTransport(a.device_id, bind_host=a.bind_host, port=a.port),
         fault_plan)
@@ -1295,18 +1279,6 @@ def _add_engine_args(ap):
                          "fixed HBM budget, small pinned accuracy "
                          "cost.  Default DWT_KV_DTYPE, else bf16; "
                          "mutually exclusive with --kv-cache-dtype")
-    ap.add_argument("--kv-layout", default=None,
-                    choices=["paged"],
-                    help="KV cache memory layout (docs/DESIGN.md §14). "
-                         "paged is the ONLY layout: device-resident "
-                         "block pool + block tables (vLLM-style "
-                         "PagedAttention) — HBM reserved per block "
-                         "actually allocated instead of B x max_seq "
-                         "rows, radix prefix hits shared by reference "
-                         "with zero H2D.  'dense' (the host-pool "
-                         "escape hatch) was removed after its "
-                         "one-release deprecation; resolving it fails "
-                         "loudly naming this removal")
     ap.add_argument("--tp", type=int, default=1,
                     help="tensor parallelism over the first N local "
                          "devices (Megatron-sliced weights, kv-head-"
@@ -1616,6 +1588,10 @@ def main(argv=None) -> int:
 
     args, rest = ap.parse_known_args(argv)
     args.rest = rest
+    if rest and args.cmd != "worker":
+        # `worker` hands what is left to its role's own parser; anywhere
+        # else a flag nobody knows is a mistake, not something to drop
+        ap.error("unrecognized arguments: " + " ".join(rest))
     if args.cmd == "plan" and not (args.devices or args.load):
         ap.error("plan needs --devices or --load")
     if args.cmd not in ("gateway", "chat", "plan"):

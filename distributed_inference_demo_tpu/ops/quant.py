@@ -186,9 +186,8 @@ KV_DTYPES = ("bf16", "int8", "int4")
 
 def resolve_kv_dtype(kv_dtype: Optional[str] = None) -> str:
     """``kv_dtype`` arg over ``DWT_KV_DTYPE`` env over "bf16" — the one
-    owner of KV-width resolution (mirrors ``resolve_kv_layout``), called
-    at every pool-creation site so the env knob reaches engines that
-    never grew an explicit kwarg."""
+    owner of KV-width resolution, called at every pool-creation site so
+    the env knob reaches engines that never grew an explicit kwarg."""
     dt = kv_dtype or os.environ.get("DWT_KV_DTYPE", "") or "bf16"
     if dt not in KV_DTYPES:
         raise ValueError(

@@ -2,8 +2,7 @@ from .engine import InferenceEngine, GenerationResult
 from .elastic import ElasticHeader, ElasticStageRuntime, ElasticWorker
 from .speculative import SpeculativeEngine, SpecStats
 from .batching import ContinuousBatchingEngine
-from .kvcache import KVCacheManager
 
 __all__ = ["InferenceEngine", "GenerationResult", "ElasticHeader",
            "ElasticStageRuntime", "ElasticWorker", "SpeculativeEngine",
-           "SpecStats", "ContinuousBatchingEngine", "KVCacheManager"]
+           "SpecStats", "ContinuousBatchingEngine"]

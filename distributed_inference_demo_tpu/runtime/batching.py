@@ -208,7 +208,6 @@ class ContinuousBatchingEngine:
                  prompt_lookup: bool = False,
                  decode_block: int = 1,
                  prefill_chunk: Optional[int] = None,
-                 kv_layout: Optional[str] = None,
                  max_queue_depth: Optional[int] = None,
                  mixed_token_budget: Optional[int] = None,
                  spec_adaptive: bool = True,
@@ -286,9 +285,9 @@ class ContinuousBatchingEngine:
         draft-side admission prefill (speculative mode) stays one
         dispatch — the draft is small by construction.
 
-        ``kv_layout``: "paged" only — the scheduler is PAGED-NATIVE
-        (docs/DESIGN.md §14): its slot cache IS a device-resident page
-        pool ``[L, num_blocks, H, block_tokens, D]`` addressed through
+        The scheduler is PAGED-NATIVE (docs/DESIGN.md §14): its slot
+        cache IS a device-resident page pool
+        ``[L, num_blocks, H, block_tokens, D]`` addressed through
         per-slot block tables.  HBM is reserved per page actually
         allocated instead of ``B x max_seq`` worst-case rows, radix
         prefix hits are shared block-table entries (zero H2D, zero
@@ -296,12 +295,10 @@ class ContinuousBatchingEngine:
         (zero D2H), and EVERY slot mode rides the pool: plain decode,
         the draft-model and prompt-lookup speculative proposers (the
         draft gets its own scratch page pool, reserved and freed with
-        the request), and tp meshes (the pool shards by kv head exactly
-        like the dense cache did).  ``kv_cache_blocks`` sizes the pool
-        (0/None = the dense-equivalent ``B x table_width`` — there is
-        no cache-off mode: the pool is the decode cache).  The dense
-        batch cache is deleted, and since the gateway release the
-        dense layout itself is gone everywhere (docs/DESIGN.md §14).
+        the request), and tp meshes (the pool shards by kv head).
+        ``kv_cache_blocks`` sizes the pool (0/None = ``B x
+        table_width``, a row's worth a slot — there is no cache-off
+        mode: the pool is the decode cache).
 
         ``max_queue_depth``: overload shedding — when the admission
         queue (submitted-but-unslotted requests) already holds this
@@ -432,14 +429,7 @@ class ContinuousBatchingEngine:
             b for b in sorted(prompt_buckets) if b <= self.max_seq
         ) or (self.max_seq,)
 
-        from .kvcache import resolve_kvcache_config, resolve_kv_layout
-        self.kv_layout = resolve_kv_layout(kv_layout)
-        if self.kv_layout != "paged":
-            raise ValueError(
-                f"kv_layout={self.kv_layout!r} is not supported by the "
-                "paged-native continuous-batching scheduler: its slot "
-                "cache IS the device page pool (docs/DESIGN.md §14); "
-                "paged is the only layout (dense was removed).")
+        from .kvcache import resolve_kvcache_config
         n_blocks_arg, block_tokens = resolve_kvcache_config(
             kv_cache_blocks, kv_block_tokens, default_blocks=0)
         if block_tokens < 1:
@@ -2213,7 +2203,6 @@ class ContinuousBatchingEngine:
 
         from .stats import _percentile
         out = {"slots": self.max_batch, "steps": self._step_count,
-               "kv_layout": self.kv_layout,
                # live occupancy for the /metrics gauges: submitted-but-
                # unslotted requests vs slots mid-decode (racy reads of
                # scheduler-owned state — gauges, not invariants)
@@ -3780,9 +3769,9 @@ class ContinuousBatchingEngine:
             prog, batch=int(plan.active_mask.sum()),
             chunk=self.decode_block, kv_dtype=self.kv_cache.kv_dtype)
         flight = types.SimpleNamespace(
-            plan=plan, sig=sig, t0=self._prof.begin(sig), t_done=0.0,
+            plan=plan, sig=sig, t_done=0.0,
             t_launch=trace.enter("launch"),
-            phases=trace.launched_phases, steps=0, out=None, tok=None)
+            phases=trace.launched_phases, steps=0, out=None)
         try:
             if not spec_mixed:
                 with jax.profiler.StepTraceAnnotation("mixed_step",
@@ -3834,7 +3823,6 @@ class ContinuousBatchingEngine:
             for req in failed:
                 self._fail_request(req, e)
             return None
-        flight.tok = self._last_tok
         trace.enter("wait")          # until the first blocking read
         return flight
 
@@ -3878,8 +3866,7 @@ class ContinuousBatchingEngine:
 
         What stays on the old order, by what the engine is or holds: the
         speculative programs (their pack reads what the drain learns),
-        a dispatch the profiler samples (its end must time one
-        execution), a resume replay among the rows or the admissions
+        a resume replay among the rows or the admissions
         (its drain may fail the row), and a request in ``_pending`` that
         the intake skipped on a hit would act on: a row that ends in
         ``flight`` frees it a slot and pages; where the cap on
@@ -3888,7 +3875,7 @@ class ContinuousBatchingEngine:
         (``store_shared`` moves the tree's epoch) or a page gate that
         is open already."""
         plan = flight.plan
-        if plan.spec_mixed or flight.t0 is not None:
+        if plan.spec_mixed:
             return None, "other"
         with self.dispatch_trace.ahead() as spent:
             rows = list(plan.rows)
@@ -4050,6 +4037,22 @@ class ContinuousBatchingEngine:
         cs["mixed_dispatches"] += 1
         cs["mixed_prefill_tokens"] += prefill_tokens
         cs["mixed_budget_tokens"] += self.mixed_token_budget
+        _t0 = self._prof.begin(flight.sig)
+        if _t0 is not None:
+            # sampled only, and the sample is the record's own time:
+            # nothing is blocked on.  Packed prefill writes + every
+            # active row's per-step history read (a verify round reads
+            # the dispatch's draft width + 1 positions of each), from
+            # the rows' host state as `_decode_kv_bytes` has it from
+            # the device's lengths: after a hit those are the next
+            # dispatch's, and a read of them waits for it
+            held = sum(len(s[0].prompt) + s[1] + steps
+                       for s in plan.rows if s is not None)
+            self._prof.end(
+                flight.sig, _t0, seconds=flight.t_done - flight.t_launch,
+                hbm_bytes=(prefill_tokens + held * max(
+                    1, steps * (plan.k_disp + 1)))
+                * self._kv_bytes_per_token)
         # finals first: install host state + radix adoption, record
         # token #1.  The adoption waits until after the dispatch — the
         # tree must never serve pages whose K/V is still in flight.
@@ -4109,13 +4112,6 @@ class ContinuousBatchingEngine:
             num_rounds, k_vec, live0 = (plan.num_rounds, plan.k_vec,
                                         plan.live0)
             em_np, ns_np = flight.em_np, flight.ns_np
-            if flight.t0 is not None:
-                self._prof.end(flight.sig, flight.t0, out=flight.tok,
-                               hbm_bytes=(
-                    prefill_tokens * self._kv_bytes_per_token
-                    + self._decode_kv_bytes(
-                        plan.active_mask,
-                        num_rounds * (plan.k_disp + 1))))
             emitted = int(ns_np[:, live0].sum()) if live0 else 0
             cs["mixed_packed_tokens"] += prefill_tokens + emitted
             if num_rounds > 0:
@@ -4130,13 +4126,6 @@ class ContinuousBatchingEngine:
                 cs["interleaved_steps"] += 1
             return record
         toks, lps = flight.out[2:4]
-        if flight.t0 is not None:
-            # sampled only (the step count is read already): packed
-            # prefill writes + every active row's per-step history read
-            self._prof.end(flight.sig, flight.t0, out=flight.tok,
-                           hbm_bytes=(
-                prefill_tokens * self._kv_bytes_per_token
-                + self._decode_kv_bytes(plan.active_mask, steps)))
         cs["mixed_packed_tokens"] += (prefill_tokens
                                       + n_active * steps)
         if steps > 0:

@@ -69,7 +69,7 @@ class StageRuntime:
     def __init__(self, cfg: ModelConfig, spec: StageSpec, params: StageParams,
                  max_seq: int, sampling: SamplingParams = SamplingParams(),
                  seed: int = 0, mesh=None, kv_cache_dtype=None,
-                 kv_layout=None, kv_dtype=None):
+                 kv_dtype=None):
         """``mesh``: a local tp mesh — this stage's layer range then runs
         with Megatron-sliced weights and a kv-head-sharded cache on this
         host's chips (pipeline across hosts x tensor parallelism within
@@ -81,16 +81,15 @@ class StageRuntime:
         read-upcast contract as InferenceEngine's — each pipeline stage
         halves its own cache bytes independently.
 
-        ``kv_layout``: "paged" (the default, docs/DESIGN.md §14) backs
-        every request's cache with ONE per-stage page pool: blocks are
-        allocated per chunk actually run (a request holding 40 tokens
-        holds ceil(40/bt) pages, not a max_seq row) and returned on
+        Every request's cache is backed by ONE per-stage page pool
+        (docs/DESIGN.md §14): blocks are allocated per chunk actually
+        run (a request holding 40 tokens holds ceil(40/bt) pages, not a
+        max_seq row) and returned on
         ``end:{rid}``, so concurrent rids (``pool_size`` dynamic
         batching) share the pool instead of each reserving worst-case
         rows.  Pool size: ``DWT_STAGE_KV_BLOCKS`` (default
         ``DWT_STAGE_KV_ROWS`` = 16 rows' worth); exhaustion raises
-        loudly rather than silently evicting live KV.  Paged is the
-        only layout ("dense" was removed — docs/DESIGN.md §14)."""
+        loudly rather than silently evicting live KV."""
         if spec.num_stages > 1:
             require_single_pass(cfg, "a pipeline of stages")
             require_kv_pair(cfg, "a pipeline of stages")
@@ -109,106 +108,68 @@ class StageRuntime:
                 f"kv_dtype={self.kv_dtype!r} quantizes the stage page "
                 "pool and cannot compose with a kv_cache_dtype storage "
                 f"cast ({self.kv_cache_dtype}); drop one of the two knobs")
-        from .kvcache import resolve_kv_layout
-        self.kv_layout = resolve_kv_layout(kv_layout)
         self._rng_base = jax.random.PRNGKey(seed)
-        self.caches: Dict[int, KVCache] = {}      # dense layout only
 
-        from ..parallel.tensor import (make_forward_seam,
-                                       make_paged_forward_seam)
+        import math
+
+        from ..parallel.tensor import make_paged_forward_seam
+        from ..telemetry._env import env_int
+        from .kvcache import resolve_kvcache_config
         # the last stage samples from the chunk's final position and its
         # head runs on that position alone (``logits_at = s - 1``); a
         # stage that is not last hands on every position's hidden state
         take_last = spec.is_last
-        if self.kv_layout == "paged":
-            import math
+        _, bt = resolve_kvcache_config(None, None)
+        g = math.lcm(8, bt)
+        S = -(-max_seq // g) * g
+        self._bt, self._table_width = bt, S // bt
+        rows = env_int("DWT_STAGE_KV_ROWS", 16)
+        n_blocks = env_int("DWT_STAGE_KV_BLOCKS",
+                           rows * self._table_width)
+        fwd, bind, pool_sharding = make_paged_forward_seam(
+            cfg, spec, mesh, params, bt)
+        if pool_sharding is not None:
+            from .engine import shard_engine_params
+            params = shard_engine_params(params, cfg, mesh)
+        self.params = params
+        from ..ops.quant import alloc_kv_pool
+        page_dtype = self.kv_cache_dtype or cfg.dtype
+        self._pk, self._pv = alloc_kv_pool(
+            (spec.num_layers * cfg.ut_steps, n_blocks,
+             cfg.num_kv_heads, bt, cfg.head_dim), self.kv_dtype,
+            page_dtype, pool_sharding)
+        self._sentinel = n_blocks
+        self._pool_free = list(range(n_blocks - 1, -1, -1))
+        self._tables: Dict[int, np.ndarray] = {}
+        self._rid_len: Dict[int, int] = {}
+        self._rid_blocks: Dict[int, int] = {}
 
-            from ..telemetry._env import env_int
-            from .kvcache import resolve_kvcache_config
-            _, bt = resolve_kvcache_config(None, None)
-            g = math.lcm(8, bt)
-            S = -(-max_seq // g) * g
-            self._bt, self._table_width = bt, S // bt
-            rows = env_int("DWT_STAGE_KV_ROWS", 16)
-            n_blocks = env_int("DWT_STAGE_KV_BLOCKS",
-                               rows * self._table_width)
-            fwd, bind, pool_sharding = make_paged_forward_seam(
-                cfg, spec, mesh, params, bt)
-            self._cache_sharding = pool_sharding
-            if pool_sharding is not None:
-                from .engine import shard_engine_params
-                params = shard_engine_params(params, cfg, mesh)
-            self.params = params
-            from ..ops.quant import alloc_kv_pool
-            page_dtype = self.kv_cache_dtype or cfg.dtype
-            self._pk, self._pv = alloc_kv_pool(
-                (spec.num_layers * cfg.ut_steps, n_blocks,
-                 cfg.num_kv_heads, bt, cfg.head_dim), self.kv_dtype,
-                page_dtype, pool_sharding)
-            self._sentinel = n_blocks
-            self._pool_free = list(range(n_blocks - 1, -1, -1))
-            self._tables: Dict[int, np.ndarray] = {}
-            self._rid_len: Dict[int, int] = {}
-            self._rid_blocks: Dict[int, int] = {}
+        @jax.jit
+        def forward_p(params, inputs, pk, pv, table, length):
+            bind(table, "stage_forward")
+            cache = KVCache(pk, pv, length)
+            b, s = inputs.shape[0], inputs.shape[1]
+            pos = length + jnp.broadcast_to(jnp.arange(s), (b, s))
+            out, cache = fwd(params, inputs, cache, pos, s - 1)
+            return ((out[:, -1] if take_last else out),
+                    cache.keys, cache.values)
 
-            @jax.jit
-            def forward_p(params, inputs, pk, pv, table, length):
-                bind(table, "stage_forward")
-                cache = KVCache(pk, pv, length)
-                b, s = inputs.shape[0], inputs.shape[1]
-                pos = length + jnp.broadcast_to(jnp.arange(s), (b, s))
-                out, cache = fwd(params, inputs, cache, pos, s - 1)
-                return ((out[:, -1] if take_last else out),
-                        cache.keys, cache.values)
+        @jax.jit
+        def forward_sample_p(params, inputs, pk, pv, table, length,
+                             rng):
+            """Paged tail hot path: layer range + LM head + in-jit
+            sampling in ONE dispatch over the page pool — same rng,
+            same sample_logits as the split pair (§13)."""
+            bind(table, "stage_forward_sample")
+            cache = KVCache(pk, pv, length)
+            b, s = inputs.shape[0], inputs.shape[1]
+            pos = length + jnp.broadcast_to(jnp.arange(s), (b, s))
+            out, cache = fwd(params, inputs, cache, pos, s - 1)
+            return (sample_logits(out[:, -1], rng, sampling),
+                    cache.keys, cache.values)
 
-            @jax.jit
-            def forward_sample_p(params, inputs, pk, pv, table, length,
-                                 rng):
-                """Paged tail hot path: layer range + LM head + in-jit
-                sampling in ONE dispatch over the page pool — same rng,
-                same sample_logits as the split pair (§13)."""
-                bind(table, "stage_forward_sample")
-                cache = KVCache(pk, pv, length)
-                b, s = inputs.shape[0], inputs.shape[1]
-                pos = length + jnp.broadcast_to(jnp.arange(s), (b, s))
-                out, cache = fwd(params, inputs, cache, pos, s - 1)
-                return (sample_logits(out[:, -1], rng, sampling),
-                        cache.keys, cache.values)
-
-            self._forward_p = forward_p
-            self._forward_sample_p = forward_sample_p
-        else:
-            fwd, self._cache_sharding = make_forward_seam(cfg, spec,
-                                                          mesh, params)
-            if self._cache_sharding is not None:
-                from .engine import shard_engine_params
-                params = shard_engine_params(params, cfg, mesh)
-            self.params = params
-
-            @jax.jit
-            def forward(params, inputs, cache):
-                b, s = inputs.shape[0], inputs.shape[1]
-                pos = cache.length + jnp.broadcast_to(jnp.arange(s),
-                                                      (b, s))
-                out, cache = fwd(params, inputs, cache, pos, s - 1)
-                return (out[:, -1] if take_last else out), cache
-
-            @jax.jit
-            def forward_sample(params, inputs, cache, rng):
-                """Tail hot path: layer range + LM head + in-jit
-                sampling fused into ONE program (docs/DESIGN.md §13) —
-                halves the tail's per-token host dispatches vs
-                forward-then-sample.  Same rng, same sample_logits:
-                bit-identical tokens to the split pair by
-                construction."""
-                b, s = inputs.shape[0], inputs.shape[1]
-                pos = cache.length + jnp.broadcast_to(jnp.arange(s),
-                                                      (b, s))
-                out, cache = fwd(params, inputs, cache, pos, s - 1)
-                return sample_logits(out[:, -1], rng, sampling), cache
-
-            self._forward = forward
-            self._forward_sample = forward_sample
+        self._forward_p = forward_p
+        self._forward_sample_p = forward_sample_p
 
         @jax.jit
         def sample(last_logits, rng):
@@ -220,7 +181,6 @@ class StageRuntime:
         # tail's device-side win is dispatch FUSION, not K-fusion —
         # DWT_RING_FUSED_TAIL=0 restores the split pair (the parity
         # reference the fused program is pinned against)
-        from ..telemetry._env import env_int
         self.fused_tail = (spec.is_last
                            and env_int("DWT_RING_FUSED_TAIL", 1) != 0)
         # §20 observatory handles: the tail's fused dispatch is profiled
@@ -229,19 +189,8 @@ class StageRuntime:
         self._prof = _profiling.get_profiler()
         self._kv_token_bytes = _profiling.kv_dispatch_bytes(
             1, spec.num_layers * cfg.ut_steps, cfg.num_kv_heads,
-            cfg.head_dim, self.kv_dtype if self.kv_layout == "paged" else None,
+            cfg.head_dim, self.kv_dtype,
             (self.kv_cache_dtype or cfg.dtype))
-
-    def _cache_for(self, rid: int, batch: int) -> KVCache:
-        cache = self.caches.get(rid)
-        if cache is None:
-            cache = KVCache.create(self.cfg, self.spec.num_layers, batch,
-                                   self.max_seq,
-                                   dtype=self.kv_cache_dtype)
-            if self._cache_sharding is not None:
-                cache = jax.device_put(cache, self._cache_sharding)
-            self.caches[rid] = cache
-        return cache
 
     def _paged_chunk_state(self, rid: int, batch: int, s: int):
         """(table, length) for this rid's next ``s``-token chunk,
@@ -279,8 +228,6 @@ class StageRuntime:
     def _sample_stage_hbm(self) -> None:
         """One HBM-watermark sample for this stage's page pool (§20) —
         host-side integer math only, called per chunk served."""
-        if self.kv_layout != "paged":
-            return
         used = self._sentinel - len(self._pool_free)
         _profiling.get_hbm_watermarks().sample(
             "stage_pool", used * self._bt * self._kv_token_bytes)
@@ -289,17 +236,12 @@ class StageRuntime:
         """Run this stage on a chunk; updates the request's cache in place.
         Returns hidden [b,s,H] (or last-position logits on the tail)."""
         x = jnp.asarray(inputs)
-        if self.kv_layout == "paged":
-            tbl, cur = self._paged_chunk_state(rid, x.shape[0],
-                                               x.shape[1])
-            out, self._pk, self._pv = self._forward_p(
-                self.params, x, self._pk, self._pv, jnp.asarray(tbl),
-                jnp.int32(cur))
-            self._rid_len[rid] = cur + x.shape[1]
-            self._sample_stage_hbm()
-            return out
-        cache = self._cache_for(rid, x.shape[0])
-        out, self.caches[rid] = self._forward(self.params, x, cache)
+        tbl, cur = self._paged_chunk_state(rid, x.shape[0], x.shape[1])
+        out, self._pk, self._pv = self._forward_p(
+            self.params, x, self._pk, self._pv, jnp.asarray(tbl),
+            jnp.int32(cur))
+        self._rid_len[rid] = cur + x.shape[1]
+        self._sample_stage_hbm()
         return out
 
     def sample_tokens(self, rid: int, step: int,
@@ -319,52 +261,36 @@ class StageRuntime:
                                  step)
         b, s = x.shape[0], x.shape[1]
         _sig = _profiling.dispatch_signature(
-            "ring_chunk_sample", batch=b, chunk=s,
-            kv_dtype=(self.kv_dtype if self.kv_layout == "paged" else
-                      np.dtype(self.kv_cache_dtype or self.cfg.dtype).name))
+            "ring_chunk_sample", batch=b, chunk=s, kv_dtype=self.kv_dtype)
         _t0 = self._prof.begin(_sig)
-        if self.kv_layout == "paged":
-            tbl, cur = self._paged_chunk_state(rid, b, s)
-            tok, self._pk, self._pv = self._forward_sample_p(
-                self.params, x, self._pk, self._pv, jnp.asarray(tbl),
-                jnp.int32(cur), rng)
-            self._rid_len[rid] = cur + s
-            tok = np.asarray(tok)
-            if _t0 is not None:
-                # the asarray above synced; the chunk attends the rid's
-                # whole KV prefix and writes s new tokens
-                self._prof.end(_sig, _t0, hbm_bytes=(
-                    b * (cur + s) * self._kv_token_bytes))
-            self._sample_stage_hbm()
-            return tok
-        cache = self._cache_for(rid, b)
-        tok, self.caches[rid] = self._forward_sample(self.params, x,
-                                                     cache, rng)
+        tbl, cur = self._paged_chunk_state(rid, b, s)
+        tok, self._pk, self._pv = self._forward_sample_p(
+            self.params, x, self._pk, self._pv, jnp.asarray(tbl),
+            jnp.int32(cur), rng)
+        self._rid_len[rid] = cur + s
         tok = np.asarray(tok)
         if _t0 is not None:
+            # the asarray above synced; the chunk attends the rid's
+            # whole KV prefix and writes s new tokens
             self._prof.end(_sig, _t0, hbm_bytes=(
-                b * int(np.asarray(self.caches[rid].length))
-                * self._kv_token_bytes))
+                b * (cur + s) * self._kv_token_bytes))
+        self._sample_stage_hbm()
         return tok
 
     def free(self, rid: int) -> None:
-        self.caches.pop(rid, None)
-        if self.kv_layout == "paged":
-            tbl = self._tables.pop(rid, None)
-            self._rid_len.pop(rid, None)
-            self._rid_blocks.pop(rid, None)
-            if tbl is not None:
-                self._pool_free.extend(
-                    int(v) for v in tbl.flat if v != self._sentinel)
+        tbl = self._tables.pop(rid, None)
+        self._rid_len.pop(rid, None)
+        self._rid_blocks.pop(rid, None)
+        if tbl is not None:
+            self._pool_free.extend(
+                int(v) for v in tbl.flat if v != self._sentinel)
 
     def reset_caches(self) -> None:
-        """Drop every request's cache state (reshard/restart): dense
-        rows garbage-collect; paged tables hand their pages back to the
-        stage pool (clearing the dict alone would leak them)."""
-        self.caches.clear()
-        if self.kv_layout == "paged":
-            for rid in list(self._tables):
-                self.free(rid)
+        """Drop every request's cache state (reshard/restart): the
+        tables hand their pages back to the stage pool (clearing the
+        dict alone would leak them)."""
+        for rid in list(self._tables):
+            self.free(rid)
 
 
 def _h_tag(rid: int, step: int) -> str:

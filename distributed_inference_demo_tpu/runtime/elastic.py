@@ -74,13 +74,11 @@ class ElasticStageRuntime(StageRuntime):
     def __init__(self, cfg: ModelConfig, spec: StageSpec,
                  full_params: StageParams, max_seq: int,
                  sampling: SamplingParams = SamplingParams(),
-                 seed: int = 0, mesh=None, kv_cache_dtype=None,
-                 kv_layout=None):
+                 seed: int = 0, mesh=None, kv_cache_dtype=None):
         self.full_params = full_params
         super().__init__(cfg, spec, slice_stage(full_params, cfg, spec),
                          max_seq, sampling, seed, mesh=mesh,
-                         kv_cache_dtype=kv_cache_dtype,
-                         kv_layout=kv_layout)
+                         kv_cache_dtype=kv_cache_dtype)
         self._seed = seed
 
     def reassign(self, spec: StageSpec) -> None:
@@ -88,8 +86,8 @@ class ElasticStageRuntime(StageRuntime):
                 spec.num_stages) == (self.spec.layer_start,
                                      self.spec.layer_end, self.spec.stage_id,
                                      self.spec.num_stages):
-            # topology unchanged but run restarts: paged tables hand
-            # their pages back; dense rows garbage-collect
+            # topology unchanged but run restarts: the tables hand
+            # their pages back
             self.reset_caches()
             return
         # Re-init via StageRuntime.__init__ to rebuild the jitted closures
@@ -98,8 +96,7 @@ class ElasticStageRuntime(StageRuntime):
                               slice_stage(self.full_params, self.cfg, spec),
                               self.max_seq, self.sampling, self._seed,
                               mesh=self.mesh,
-                              kv_cache_dtype=self.kv_cache_dtype,
-                              kv_layout=self.kv_layout)
+                              kv_cache_dtype=self.kv_cache_dtype)
 
 
 def _spec_payload(spec: StageSpec) -> dict:

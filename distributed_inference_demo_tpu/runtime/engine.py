@@ -358,23 +358,19 @@ class InferenceEngine:
                  mesh=None,
                  kv_cache_blocks: Optional[int] = None,
                  kv_block_tokens: Optional[int] = None,
-                 kv_layout: Optional[str] = None,
                  kv_dtype: Optional[str] = None,
                  stop_token_ids=None,
                  stream_block: Optional[int] = None):
         """``attn_backend``: "auto" (Pallas flash kernel on TPU, jnp
         elsewhere), "flash", or "jnp".
 
-        ``kv_layout``: layout of the prefix-reuse pool behind the
-        ``runtime/kvcache`` backend seam (docs/DESIGN.md §14).  "paged"
-        (the default) keeps the pool device-resident: hits gather pages
-        into the fresh cache on device and stores scatter blocks back —
-        zero bytes cross the host boundary either way; it is the ONLY
-        layout ("dense", the §10 host-pool escape hatch, was removed
-        after its one-release deprecation).  The ONE request in flight
-        decodes against a dense working cache its decode loop donates —
-        the layout governs the standing pool, which is where reserved
-        HBM lives.
+        ``kv_cache_blocks`` / ``kv_block_tokens``: the prefix-reuse pool
+        behind the ``runtime/kvcache`` backend seam (docs/DESIGN.md
+        §14), device-resident: hits gather pages into the fresh cache
+        on device and stores scatter blocks back — zero bytes cross the
+        host boundary either way.  The ONE request in flight decodes
+        against a contiguous working cache its decode loop donates; the
+        standing pool is where reserved HBM lives.
 
         ``mesh``: a ``jax.sharding.Mesh`` with a ``tp`` axis — every
         forward then runs inside a shard_map with Megatron-sliced weights
@@ -443,8 +439,6 @@ class InferenceEngine:
         1 (default; ``DWT_STREAM_BLOCK`` env between) keeps the
         per-token path, which the fused loop is bit-identical to
         (greedy) by construction."""
-        from .kvcache import resolve_kv_layout
-        self.kv_layout = resolve_kv_layout(kv_layout)
         self.cfg = cfg
         self.params = params
         self.max_seq = max_seq or cfg.max_seq_len
@@ -473,7 +467,7 @@ class InferenceEngine:
 
         from .kvcache import make_kv_backend
         self.kv_cache = make_kv_backend(
-            cfg, kv_cache_blocks, kv_block_tokens, layout=self.kv_layout,
+            cfg, kv_cache_blocks, kv_block_tokens,
             dtype=self.kv_cache_dtype, kv_dtype=kv_dtype,
             default_blocks=0)
 

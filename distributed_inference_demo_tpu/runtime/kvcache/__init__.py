@@ -1,81 +1,37 @@
 """Block-level KV cache with radix-tree prefix sharing (docs/DESIGN.md
-§10 host pool, §11 paged layout, §14 universal-paged contract).
+§10 the tree, §11 the page pool, §14 the backend seam).
 
 The single prefix-reuse path for the serving stack, behind the
-:mod:`~.backend` seam every engine consumes.  The blocks live on
-device — the batching scheduler's slot cache IS a page pool addressed
-through block tables, the ring stage workers hold per-stage page
-pools, and the single-request engines keep a device-resident prefix
-pool (:class:`~.backend.PagedKVBackend`).  Hits are device gathers /
-block-table references, stores are device scatters / ownership
-adoptions — zero bytes cross the host boundary
-(``dwt_kvcache_h2d_bytes_total == 0`` structurally).
+:mod:`~.backend` seam every engine consumes.  A KV cache is one pool
+of pages on the device: the batching scheduler's slot cache IS a page
+pool addressed through block tables, the ring stage workers hold
+per-stage page pools, and the single-request engines keep a
+device-resident prefix pool (:class:`~.backend.PagedKVBackend`).  One
+manager of page ids (:class:`~.paged.PagedKVCacheManager`) and one
+radix tree over them (:class:`~.radix.RadixTree`) serve all three.
+Hits are device gathers / block-table references, stores are device
+scatters / ownership adoptions — zero bytes cross the host boundary
+(``dwt_kvcache_h2d_bytes_total`` counts only §21 tier promotions).
 
-The dense host-pool *layout* (``--kv-layout dense``, deprecated in the
-disaggregation release) is REMOVED: :func:`resolve_kv_layout` fails
-loudly on it.  The §10 host pool itself (:class:`KVCacheManager`)
-survives as a host-staging building block, but no engine runs behind
-it — the dense backend class and the legacy require-dense shim are
-deleted, and ``tools/check_kv_layout.py`` lints that neither identifier
-regrows anywhere in the package.
-
-Layout selection: the ``kv_layout`` engine kwarg / ``--kv-layout`` flag
-over the ``DWT_KV_LAYOUT`` env knob over the default ``paged`` — all
-three funnel through :func:`resolve_kv_layout`, the one owner.
-
-Page WIDTH selection mirrors it (docs/DESIGN.md §17): the ``kv_dtype``
-kwarg / ``--kv-dtype`` flag over ``DWT_KV_DTYPE`` over ``bf16``,
-funneled through :func:`resolve_kv_dtype` (owned by ``ops/quant.py``
-next to the quantized-page rails, re-exported here) — called at every
-pool-creation site, so the env knob reaches engines without an explicit
-kwarg.
+Page WIDTH selection (docs/DESIGN.md §17): the ``kv_dtype`` kwarg /
+``--kv-dtype`` flag over ``DWT_KV_DTYPE`` over ``bf16``, funneled
+through :func:`resolve_kv_dtype` (owned by ``ops/quant.py`` next to the
+quantized-page rails, re-exported here) — called at every pool-creation
+site, so the env knob reaches engines without an explicit kwarg.
 """
-
-import os
 
 from ...ops.quant import KV_DTYPES, resolve_kv_dtype
 from .backend import PagedKVBackend, make_kv_backend
-from .manager import (DEFAULT_BLOCK_TOKENS, KVCacheManager, KVLease,
-                      resolve_kvcache_config)
-from .paged import PagedBlockLease, PagedKVCacheManager
-from .pool import KVBlockPool
+from .paged import (DEFAULT_BLOCK_TOKENS, PagedBlockLease,
+                    PagedKVCacheManager, resolve_kvcache_config)
 from .radix import RadixTree
 from .tiered import (TieredKVStore, make_demote_hook, promote_prefix,
                      resolve_tier_config)
 
-KV_LAYOUTS = ("paged",)
-
-# The message every removed-layout path fails with — one string so the
-# CLI flag, the env knob, and the direct engine kwarg all name the same
-# removal and the same migration.
-_DENSE_REMOVED_MSG = (
-    "kv_layout='dense' was REMOVED in the gateway release "
-    "(docs/DESIGN.md §14): the host-pool escape hatch was deprecated "
-    "for one release and is deleted — drop --kv-layout dense / "
-    "DWT_KV_LAYOUT=dense; the paged layout is the only layout and "
-    "needs no flag")
-
-
-def resolve_kv_layout(kv_layout=None) -> str:
-    """``kv_layout`` arg over ``DWT_KV_LAYOUT`` env over "paged".
-
-    The one owner of layout resolution: the removed dense layout fails
-    here, loudly, naming the removal — whether it arrives via flag, env
-    knob, or direct engine kwarg (none can bypass this funnel)."""
-    layout = kv_layout or os.environ.get("DWT_KV_LAYOUT", "") or "paged"
-    if layout == "dense":
-        raise ValueError(_DENSE_REMOVED_MSG)
-    if layout not in KV_LAYOUTS:
-        raise ValueError(
-            f"unknown kv layout {layout!r}; expected one of {KV_LAYOUTS}")
-    return layout
-
-
-__all__ = ["KVBlockPool", "KVCacheManager", "KVLease",
-           "PagedKVBackend", "make_kv_backend",
+__all__ = ["PagedKVBackend", "make_kv_backend",
            "PagedBlockLease", "PagedKVCacheManager", "RadixTree",
-           "resolve_kvcache_config", "resolve_kv_layout",
+           "resolve_kvcache_config",
            "resolve_kv_dtype", "DEFAULT_BLOCK_TOKENS",
-           "KV_LAYOUTS", "KV_DTYPES",
+           "KV_DTYPES",
            "TieredKVStore", "make_demote_hook", "promote_prefix",
            "resolve_tier_config"]

@@ -1,9 +1,7 @@
-"""The KV backend seam: ONE prefix-reuse surface over the paged pool.
+"""The KV backend seam: ONE prefix-reuse surface over the page pool.
 
-Before this seam, every engine special-cased the dense manager inline
-(match → host gather → H2D seed; D2H slice → store) and *rejected*
-``--kv-layout paged`` outright — the DESIGN.md §11 rejection matrix.
-The seam is the two calls an engine actually needs around its prefill:
+The seam is the two calls a single-request engine needs around its
+prefill:
 
 - ``seed(ids, cache) -> (start, cache)`` — write the longest cached
   prefix of the (batch-1) prompt into a fresh engine cache's leading
@@ -12,11 +10,6 @@ The seam is the two calls an engine actually needs around its prefill:
 - ``store(ids, cache) -> None`` — cache the prefilled prompt's full
   blocks for the next shared-prefix request.  Runs before the decode
   program donates the cache buffers.
-
-The dense host-pool backend (a hit paying one H2D gather and a store
-one D2H slice) was deleted with the ``--kv-layout dense`` escape
-hatch; the §10 :class:`~.manager.KVCacheManager` it wrapped survives
-as a host-staging building block only.
 
 :class:`PagedKVBackend` owns a DEVICE-resident page pool
   ``[L, N, H, bt, D]`` plus the §11 page-id
@@ -28,13 +21,12 @@ as a host-staging building block only.
   prefills ride this, so a draft/verify request never duplicates an
   accepted prefix already paged in).
 
-The single-request engines keep a dense *working* cache for the one
-request in flight (its decode loop donates it); the layout choice
-governs the standing *pool* — which is where the reserved-HBM story
-lives once the batching scheduler and the ring stages page their own
-decode caches (docs/DESIGN.md §14).
+The single-request engines keep a contiguous *working* cache for the
+one request in flight (its decode loop donates it); the standing
+*pool* is what this class pages — the batching scheduler and the ring
+stages page their own decode caches (docs/DESIGN.md §14).
 
-Ownership (paged): pages are tree-owned or free — a seed copies out of
+Ownership: pages are tree-owned or free — a seed copies out of
 tree pages under a short-lived pin, a store hands freshly written pages
 to the tree (redundant ones are freed immediately), so after every
 ``seed``/``store`` the leak invariant ``used == tree.block_count``
@@ -47,14 +39,12 @@ from typing import Optional
 
 import numpy as np
 
-from .manager import apply_byte_budget, resolve_kvcache_config
-from .paged import PagedKVCacheManager
+from .paged import (PagedKVCacheManager, apply_byte_budget,
+                    resolve_kvcache_config)
 
 
 class PagedKVBackend:
     """Device page pool behind the seam (docs/DESIGN.md §11/§14)."""
-
-    layout = "paged"
 
     def __init__(self, cfg, num_blocks: int, block_tokens: int,
                  dtype=None, kv_dtype=None,
@@ -188,14 +178,14 @@ class PagedKVBackend:
 
 
 def make_kv_backend(cfg, kv_cache_blocks: Optional[int],
-                    kv_block_tokens: Optional[int], *, layout: str,
+                    kv_block_tokens: Optional[int], *,
                     dtype=None, kv_dtype=None, default_blocks: int = 0,
                     kv_host_tier_bytes: Optional[int] = None,
                     kv_disk_tier_path: Optional[str] = None,
                     kv_disk_tier_bytes: Optional[int] = None):
     """The one constructor every engine calls: resolve the block-count /
     block-tokens knobs (CLI over env over ``default_blocks``) and build
-    the layout's backend — or None when the pool is off (0 blocks, or a
+    the backend — or None when the pool is off (0 blocks, or a
     ``DWT_KVCACHE_BYTES`` ceiling below one block: a knob documented as
     a ceiling must never crash engine construction).
 
@@ -204,10 +194,6 @@ def make_kv_backend(cfg, kv_cache_blocks: Optional[int],
     plumbing.  Mutually exclusive with a ``dtype`` storage cast: the
     cast rescales the same full-width layout, quantization replaces it."""
     from ...ops.quant import kv_token_head_bytes, resolve_kv_dtype
-    if layout != "paged":
-        raise ValueError(
-            f"unknown kv layout {layout!r}: paged is the only layout "
-            "(the dense backend was removed; docs/DESIGN.md §14)")
     kv_dtype = resolve_kv_dtype(kv_dtype)
     if kv_dtype != "bf16" and dtype is not None:
         raise ValueError(

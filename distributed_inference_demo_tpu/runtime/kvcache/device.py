@@ -1,20 +1,16 @@
 """Device-side copy programs for the block KV cache.
 
-The manager (``manager.py``) is pure host bookkeeping; these are the two
-ends of the device seam the engines share:
+The manager (``paged.py``) is pure host bookkeeping over page ids; these
+are the device ends of the seam the engines share — the row <-> pages
+and cache <-> pages gathers and scatters (docs/DESIGN.md §11), all
+device-to-device: neither a prefix hit nor a store crosses the host
+boundary.
 
-- loads ride :func:`seed_prefix_cache` — one fused dynamic_update_slice
-  pair writing a gathered block run into a fresh cache's columns
-  ``[0, m)`` (the engine-cache twin of batching's ``load_prefix`` row
-  program);
-- stores are plain ``np.asarray`` D2H slices taken by
-  ``KVCacheManager.store`` (no program needed — the copy is the fence).
-
-Kept separate from ``manager.py`` so the manager (and its tests) never
+Kept separate from ``paged.py`` so the manager (and its tests) never
 import jax.
 
-Every paged program below is dtype-polymorphic (docs/DESIGN.md §17): a
-pool tensor is either a plain array or a :class:`QuantizedKVPages` tree
+Every program below is dtype-polymorphic (docs/DESIGN.md §17): a pool
+tensor is either a plain array or a :class:`QuantizedKVPages` tree
 whose leaves share the pool's leading ``[L, N, H, bt]`` axes, so one
 tree-mapped gather/scatter serves both.  The quantize/dequantize always
 happens HERE, at the row <-> pages seam — dense working rows stay
@@ -52,55 +48,15 @@ def _scatter_run(pool, run, table):
 
 
 @partial(jax.jit, donate_argnums=(0, 1))
-def seed_prefix_cache(ck, cv, pk, pv):
-    """Write a ``[L, b, H, m, D]`` block run into a (fresh, donatable)
-    cache's columns ``[0, m)``.  The caller sets the cache's valid
-    length to ``m`` afterwards; columns past m stay zero and are
-    overwritten by the suffix prefill before any query attends them
-    (stale-slot invariant)."""
-    zero = jnp.zeros((), jnp.int32)
-    idx = (zero, zero, zero, zero, zero)
-    return (jax.lax.dynamic_update_slice(ck, pk.astype(ck.dtype), idx),
-            jax.lax.dynamic_update_slice(cv, pv.astype(cv.dtype), idx))
-
-
-# ---------------------------------------------------------------------------
-# paged layout (docs/DESIGN.md §11): the row <-> pages seam.  Both
-# programs are device-to-device — the paged cache's whole point is that
-# neither a prefix hit nor a store crosses the host boundary.
-
-
-@jax.jit
-def seed_row_from_pages(pk, pv, table):
-    """Gather one slot's block table out of the page pool into a dense
-    prefill row: pages ``[L, N, H, bt, D]`` + table ``[W]`` ->
-    row ``[L, 1, H, W*bt, D]``.
-
-    The WHOLE table gathers in one compiled shape regardless of how many
-    entries are real: sentinel entries (>= N) clamp to some page and the
-    gathered garbage sits at columns past the matched prefix, which the
-    suffix prefill / decode rewrite before any query attends them
-    (stale-slot invariant) — garbage is finite (pool pages always hold
-    finite values), so the causal mask zeroes it exactly."""
-    L, N, H, bt, D = pk.shape
-    W = table.shape[0]
-    safe = jnp.clip(table, 0, N - 1)
-    rk = _gather_run(pk, safe)               # [L, W, H, bt, D]
-    rv = _gather_run(pv, safe)
-    rk = rk.transpose(0, 2, 1, 3, 4).reshape(L, 1, H, W * bt, D)
-    rv = rv.transpose(0, 2, 1, 3, 4).reshape(L, 1, H, W * bt, D)
-    return rk, rv
-
-
-@partial(jax.jit, donate_argnums=(0, 1))
 def seed_cache_from_pages(ck, cv, pk, pv, table):
     """Gather a matched block run out of the page pool into a (fresh,
-    donatable) engine cache's columns ``[0, n*bt)`` — the PAGED twin of
-    :func:`seed_prefix_cache`: pages ``[L, N, H, bt, D]`` + table ``[n]``
-    of real page ids -> cache ``[L, 1, H, S, D]``.  Device-to-device:
-    a prefix hit on the paged backend moves zero bytes through the host
-    (``dwt_kvcache_h2d_bytes_total`` stays 0 by construction).  Compiled
-    per matched length, like the dense seed program it mirrors."""
+    donatable) engine cache's columns ``[0, n*bt)``: pages
+    ``[L, N, H, bt, D]`` + table ``[n]`` of real page ids -> cache
+    ``[L, 1, H, S, D]``.  The caller sets the cache's valid length to
+    ``n*bt`` afterwards; columns past it stay zero and are overwritten
+    by the suffix prefill before any query attends them (stale-slot
+    invariant).  Device-to-device: a prefix hit moves zero bytes
+    through the host.  Compiled per matched length."""
     L, N, H, bt, D = pk.shape
     n = table.shape[0]
     rk = _gather_run(pk, table)               # [L, n, H, bt, D]
@@ -118,8 +74,7 @@ def store_cache_to_pages(pk, pv, ck, cv, table, start):
     """Scatter an engine cache's full blocks ``[start, start + n)`` into
     the page pool at ``table``'s ids — the paged store: cache ``[L, 1,
     H, S, D]`` columns ``[start*bt, (start+n)*bt)`` land in pages
-    ``table[0..n)`` in place on device, zero D2H (the dense manager's
-    per-store host slice is the copy this program deletes).  ``start``
+    ``table[0..n)`` in place on device, zero D2H.  ``start``
     (traced block offset) is the tail-only store seam: blocks the radix
     tree already covers are neither re-allocated nor re-written.  The
     cache is read, not donated — the caller keeps decoding against it;

@@ -1,22 +1,28 @@
 """Paged KV cache manager: bookkeeping for a DEVICE-resident block pool.
 
-The dense-era :class:`KVCacheManager` owns host numpy blocks and moves
-bytes (H2D on hit, D2H on store).  This manager owns NO data at all —
-the K/V pages live on device in the engine's preallocated
-``[L, num_blocks, H, block_tokens, D]`` pool arrays (see
-``ops/paged_attention.py``), and what lives here is everything the
-device cannot do for itself:
+This manager owns NO data at all — the K/V pages live on device in the
+engine's preallocated ``[L, num_blocks, H, block_tokens, D]`` pool
+arrays (see ``ops/paged_attention.py``), and what lives here is
+everything the device cannot do for itself:
 
 - a free list over page ids (``alloc``/``free``), with LRU leaf eviction
-  of unpinned radix-tree entries under pressure;
-- the same block-keyed :class:`~.radix.RadixTree` as the dense manager,
-  giving longest-partial-prefix matches — but a hit now returns page
-  IDS for the caller's block table, not bytes (``dwt_kvcache_h2d_bytes``
-  stays 0 by construction);
+  of unpinned radix-tree entries under pressure (feasibility first: an
+  allocation that cannot be met evicts nothing);
+- a block-keyed :class:`~.radix.RadixTree` giving longest-partial-prefix
+  matches, whole blocks, capped at ``len(prompt) - 1`` so the caller's
+  suffix forward is never empty — a hit returns page IDS for the
+  caller's block table, not bytes (``dwt_kvcache_h2d_bytes`` counts
+  only §21 tier promotions);
 - copy-FREE stores: :meth:`store_shared` adopts a request's
   already-on-device full-prompt pages into the tree (ownership
   transfer, no copy), so the next shared-prefix request references the
-  very same pages.
+  very same pages;
+- ``peek(prompt)`` — match length without stats, leases, or LRU touch
+  (scheduler classification).
+
+Reuse is EXACT by construction: blocks are keyed by the exact token ids
+they cover, and causal attention makes a prefix's K/V independent of
+any suffix — a primed generation is token-identical to a cold one.
 
 Ownership rule (the one invariant everything else hangs off): every
 allocated page has exactly one owner — the radix tree (freed only by
@@ -26,8 +32,13 @@ references are protected by node pins (leases), never by ownership.
 Tree pages are immutable: decode writes only land at positions >= the
 prompt length, which sit in the request's own private pages.
 
-Thread-safety matches the dense manager: one lock, mutations on the
-scheduler thread, ``snapshot``/``debug_state`` from scrape threads.
+Thread-safety: one lock, mutations on the scheduler thread,
+``snapshot``/``debug_state`` from scrape threads.
+
+Config knobs (CLI flags override env, 0 disables):
+``DWT_KVCACHE_BLOCKS`` (pool size, blocks), ``DWT_KVCACHE_BLOCK_TOKENS``
+(granularity, default 16), ``DWT_KVCACHE_BYTES`` (cap: shrinks BLOCKS
+to fit when set).
 """
 
 from __future__ import annotations
@@ -37,18 +48,42 @@ from typing import List, Optional
 
 import numpy as np
 
+from ...telemetry._env import env_int
 from ...telemetry.flightrecorder import get_flight_recorder
-from .manager import apply_byte_budget
 from .radix import RadixTree
+
+DEFAULT_BLOCK_TOKENS = 16
+
+
+def resolve_kvcache_config(num_blocks: Optional[int] = None,
+                           block_tokens: Optional[int] = None,
+                           default_blocks: int = 0):
+    """(num_blocks, block_tokens) from explicit args over env knobs over
+    ``default_blocks`` (each engine's own default — the batching
+    scheduler defaults ON, the single-request engines default OFF).
+    ``None`` means "not specified"; 0 blocks disables the subsystem."""
+    if num_blocks is None:
+        num_blocks = env_int("DWT_KVCACHE_BLOCKS", default_blocks)
+    if block_tokens is None:
+        block_tokens = env_int("DWT_KVCACHE_BLOCK_TOKENS",
+                               DEFAULT_BLOCK_TOKENS)
+    return num_blocks, block_tokens
+
+
+def apply_byte_budget(num_blocks: int, block_bytes: int) -> int:
+    """Shrink ``num_blocks`` to the DWT_KVCACHE_BYTES cap (0 = uncapped).
+    Never rounds up — the env cap is a ceiling, not a target."""
+    budget = env_int("DWT_KVCACHE_BYTES", 0)
+    if budget > 0 and block_bytes > 0:
+        num_blocks = min(num_blocks, budget // block_bytes)
+    return num_blocks
 
 
 class PagedBlockLease:
     """A pin on a radix node protecting the pages a block table
-    references — matched prefixes and adopted stores.  Unlike the dense
-    lease (released the moment bytes are copied out), a paged lease
-    lives as long as the referencing table does: release at request
-    completion, or the evictor may hand the pages to someone else
-    mid-decode."""
+    references — matched prefixes and adopted stores.  It lives as long
+    as the referencing table does: release at request completion, or
+    the evictor may hand the pages to someone else mid-decode."""
 
     def __init__(self, mgr: "PagedKVCacheManager", node,
                  block_ids: List[int], tokens: int):
@@ -119,7 +154,7 @@ class PagedKVCacheManager:
                    streams=cfg.kv_streams)
 
     # ------------------------------------------------------------------
-    # lookup (same tree walk as the dense manager)
+    # lookup
 
     def _block_keys(self, prompt, n_blocks: int):
         bt = self.block_tokens

@@ -66,11 +66,9 @@ class MultimodalEngine:
                  sampling: SamplingParams = SamplingParams(),
                  eos_id: Optional[int] = None,
                  attn_backend: str = "auto",
-                 kv_layout: Optional[str] = None,
                  kv_dtype: Optional[str] = None):
         self.engine = InferenceEngine(cfg, params, max_seq, sampling,
                                       eos_id, attn_backend,
-                                      kv_layout=kv_layout,
                                       kv_dtype=kv_dtype)
         self.cfg = cfg
         self.vcfg = vcfg

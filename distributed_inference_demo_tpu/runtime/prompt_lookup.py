@@ -96,7 +96,6 @@ class PromptLookupEngine:
                  prefill_chunk: Optional[int] = None,
                  kv_cache_blocks: Optional[int] = None,
                  kv_block_tokens: Optional[int] = None,
-                 kv_layout: Optional[str] = None,
                  kv_dtype: Optional[str] = None):
         """``mesh``: tp mesh — the target forward runs sharded (see
         InferenceEngine); proposal matching stays replicated VPU work.
@@ -106,19 +105,16 @@ class PromptLookupEngine:
         (engine.run_chunked_prefill semantics; the proposer's history
         buffer is host-seeded from the ids and unaffected).
 
-        ``kv_cache_blocks`` / ``kv_block_tokens`` / ``kv_layout``: the
+        ``kv_cache_blocks`` / ``kv_block_tokens``: the
         block-level KV prefix pool behind the backend seam
         (docs/DESIGN.md §14), batch 1: a prompt sharing whole leading
         blocks with an earlier prefill seeds its cache and prefills only
         the suffix — exactness is a prefill-side property, so it
         composes with the n-gram proposer untouched (the history buffer
         still seeds from the full ids).  Default off (0 blocks); the
-        pool is device-resident ("paged" is the only layout — "dense"
-        was removed, docs/DESIGN.md §14)."""
+        pool is device-resident."""
         if num_draft < 1:
             raise ValueError("num_draft must be >= 1")
-        from .kvcache import resolve_kv_layout
-        self.kv_layout = resolve_kv_layout(kv_layout)
         self.cfg, self.params = cfg, params
         self.max_seq = max_seq or cfg.max_seq_len
         self.sampling = sampling
@@ -195,7 +191,7 @@ class PromptLookupEngine:
 
         from .kvcache import make_kv_backend
         self.kv_cache = make_kv_backend(
-            cfg, kv_cache_blocks, kv_block_tokens, layout=self.kv_layout,
+            cfg, kv_cache_blocks, kv_block_tokens,
             dtype=self.kv_cache_dtype, kv_dtype=kv_dtype,
             default_blocks=0)
 

@@ -56,28 +56,21 @@ class SequenceParallelBackend:
                  sampling: Optional[SamplingParams] = None,
                  kv_cache_dtype: Optional[str] = None,
                  eos_id: Optional[int] = None,
-                 max_queue_depth: Optional[int] = None,
-                 kv_layout: Optional[str] = None):
+                 max_queue_depth: Optional[int] = None):
         """``max_queue_depth``: how many requests may WAIT behind the
         one running (the sp mesh serializes requests); one more and the
         arrival is rejected with 429 + Retry-After instead of blocking
         on the device lock unboundedly.  ``None`` defers to
         ``DWT_SP_QUEUE_DEPTH`` (default 8); 0 = unbounded.
 
-        ``kv_layout``: accepted for the universal-paged contract
-        (docs/DESIGN.md §14) and surfaced on ``/stats``.  The sp cache
-        is per-request scratch INSIDE the fused sequence-sharded
-        program — allocated at dispatch, freed when the program
-        returns, each chip holding its own ``max_seq/sp`` shard — so
-        there is no standing ``batch x max_seq`` reservation for the
-        paged layout to convert: both layouts run the same sharded
-        program, and the flag records intent instead of being
-        rejected."""
+        The sp cache is per-request scratch INSIDE the fused
+        sequence-sharded program — allocated at dispatch, freed when
+        the program returns, each chip holding its own ``max_seq/sp``
+        shard — so there is no standing ``batch x max_seq`` reservation
+        and no page pool (docs/DESIGN.md §14)."""
         if strategy not in STRATEGIES:
             raise ValueError(f"unknown sp strategy {strategy!r}; "
                              f"known: {STRATEGIES}")
-        from .kvcache import resolve_kv_layout
-        self.kv_layout = resolve_kv_layout(kv_layout)
         self.cfg = cfg
         self.params = params
         self.mesh = mesh
@@ -340,10 +333,6 @@ class SequenceParallelBackend:
                 "strategy": self.strategy,
                 "sp": self.sp,
                 "max_seq": self.max_seq,
-                # per-request sequence-sharded scratch either way (see
-                # __init__): recorded so a fleet scrape can assert the
-                # resolved layout uniformly across serve modes
-                "kv_layout": self.kv_layout,
                 "requests_served": self._served,
                 "tokens_out": self._tokens_out,
                 "seconds_generating": round(self._decode_seconds, 3),

@@ -292,7 +292,6 @@ class SpeculativeEngine:
                  prefill_chunk: Optional[int] = None,
                  kv_cache_blocks: Optional[int] = None,
                  kv_block_tokens: Optional[int] = None,
-                 kv_layout: Optional[str] = None,
                  kv_dtype: Optional[str] = None):
         """``kv_cache_dtype``: reduced-precision storage for BOTH the
         target and draft caches (same contract as InferenceEngine /
@@ -312,15 +311,12 @@ class SpeculativeEngine:
         suffix; the draft always prefills its full prompt (it is cheap
         by construction, and only the target's logits gate emission, so
         reuse exactness is a target-side property).  Default off; env
-        ``DWT_KVCACHE_*`` knobs apply as in InferenceEngine.
-
-        ``kv_layout``: layout of the target-side prefix pool behind the
-        backend seam (docs/DESIGN.md §14) — "paged" (default) keeps it
-        device-resident, so two speculative requests sharing a prompt
-        prefix reference the SAME pages in HBM (the accepted prefix is
-        never duplicated; pinned by the ownership tests) and hits move
-        zero bytes through the host; it is the ONLY layout ("dense"
-        was removed — docs/DESIGN.md §14)."""
+        ``DWT_KVCACHE_*`` knobs apply as in InferenceEngine.  The pool
+        is device-resident behind the backend seam (docs/DESIGN.md
+        §14), so two speculative requests sharing a prompt prefix
+        reference the SAME pages in HBM (the accepted prefix is never
+        duplicated; pinned by the ownership tests) and hits move zero
+        bytes through the host."""
         if cfg.vocab_size != draft_cfg.vocab_size:
             raise ValueError(
                 f"draft vocab ({draft_cfg.vocab_size}) != target vocab "
@@ -328,8 +324,6 @@ class SpeculativeEngine:
                 "token space")
         if num_draft < 1:
             raise ValueError("num_draft must be >= 1")
-        from .kvcache import resolve_kv_layout
-        self.kv_layout = resolve_kv_layout(kv_layout)
         self.cfg, self.params = cfg, params
         self.draft_cfg, self.draft_params = draft_cfg, draft_params
         self.max_seq = max_seq or cfg.max_seq_len
@@ -381,7 +375,7 @@ class SpeculativeEngine:
 
         from .kvcache import make_kv_backend
         self.kv_cache = make_kv_backend(
-            cfg, kv_cache_blocks, kv_block_tokens, layout=self.kv_layout,
+            cfg, kv_cache_blocks, kv_block_tokens,
             dtype=self.kv_cache_dtype, kv_dtype=kv_dtype,
             default_blocks=0)
 

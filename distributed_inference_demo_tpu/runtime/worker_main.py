@@ -53,9 +53,7 @@ def build_worker(args):
                            sampling=sampling, seed=args.seed,
                            mesh=local_tp_mesh(getattr(args, "tp", 1)),
                            kv_cache_dtype=getattr(args, "kv_cache_dtype",
-                                                  "") or None,
-                           kv_layout=getattr(args, "kv_layout",
-                                             None) or None)
+                                                  "") or None)
 
     from ..comm.faults import load_fault_plan, maybe_wrap
     transport = maybe_wrap(
@@ -201,13 +199,6 @@ def main(argv=None) -> int:
     ap.add_argument("--kv-cache-dtype", default="",
                     help="reduced-precision KV cache storage for this "
                          "stage, e.g. float8_e4m3fn")
-    ap.add_argument("--kv-layout", default=None,
-                    choices=["paged"],
-                    help="this stage's request-cache layout (paged is "
-                         "the only layout: every rid backed by one "
-                         "per-stage page pool — blocks reserved per "
-                         "chunk actually run, freed on end:{rid}; "
-                         "'dense' was removed — docs/DESIGN.md §14)")
     ap.add_argument("--tp", type=int, default=1,
                     help="tensor parallelism over this host's first N "
                          "local devices (pipeline x tp)")

@@ -210,18 +210,17 @@ KVCACHE_NODES = gauge(
     "branch points plus leaves")
 KVCACHE_DEVICE_RESIDENT_BYTES = gauge(
     "dwt_kvcache_device_resident_bytes",
-    "Device HBM held by in-use KV blocks (paged layout: pages allocated "
-    "to block tables or the radix tree; 0 on the host-pool dense "
-    "layout)")
+    "Device HBM held by in-use KV blocks (pages allocated to block "
+    "tables or the radix tree)")
 KVCACHE_BLOCKS_IN_USE = gauge(
     "dwt_kvcache_blocks_in_use",
     "KV blocks currently allocated, all owners: radix-tree cache plus "
-    "(paged layout) in-flight requests' private blocks")
+    "in-flight requests' private blocks")
 KVCACHE_H2D_BYTES = counter(
     "dwt_kvcache_h2d_bytes_total",
     "Bytes copied host-to-device to seed caches from prefix hits "
-    "(dense layout's per-hit gather; stays 0 on the paged path, where "
-    "hits are device block-table references)")
+    "(hits are device block-table references and move none; only a "
+    "tier promotion, docs/DESIGN.md §21, counts here)")
 KVCACHE_PAGE_DTYPE = gauge(
     "dwt_kvcache_page_dtype_info",
     "Page width of the paged KV pool as an info gauge: the series with "
@@ -327,7 +326,7 @@ def update_kvcache_tier_series(tier: dict) -> None:
 
 
 def update_kvcache_series(kv: dict) -> None:
-    """Bridge a ``KVCacheManager.snapshot()`` dict onto the
+    """Bridge a ``PagedKVCacheManager.snapshot()`` dict onto the
     ``dwt_kvcache_*`` series."""
     KVCACHE_HITS.set_cumulative(kv.get("hits", 0))
     KVCACHE_MISSES.set_cumulative(kv.get("misses", 0))
@@ -337,14 +336,11 @@ def update_kvcache_series(kv: dict) -> None:
     KVCACHE_EVICTED_BLOCKS.set_cumulative(kv.get("evicted_blocks", 0))
     KVCACHE_RESIDENT_BYTES.set(kv.get("resident_bytes", 0))
     KVCACHE_CAPACITY_BYTES.set(kv.get("capacity_bytes", 0))
-    # used_blocks = the TREE's share (dense snapshots lack tree_blocks
-    # because there blocks_used IS tree-owned); blocks_in_use = all
-    # owners.  The gap between the two gauges is in-flight requests'
-    # private pages — the §11 runbook's leak alert (blocks_in_use >
-    # used_blocks while idle) depends on them being bridged from
-    # DIFFERENT snapshot keys on the paged layout.
-    KVCACHE_USED_BLOCKS.set(kv.get("tree_blocks",
-                                   kv.get("blocks_used", 0)))
+    # used_blocks = the TREE's share; blocks_in_use = all owners.  The
+    # gap between the two gauges is in-flight requests' private pages —
+    # the §11 runbook's leak alert (blocks_in_use > used_blocks while
+    # idle) depends on them being bridged from DIFFERENT snapshot keys.
+    KVCACHE_USED_BLOCKS.set(kv.get("tree_blocks", 0))
     KVCACHE_NODES.set(kv.get("nodes", 0))
     KVCACHE_DEVICE_RESIDENT_BYTES.set(kv.get("device_resident_bytes", 0))
     KVCACHE_BLOCKS_IN_USE.set(kv.get("blocks_used", 0))

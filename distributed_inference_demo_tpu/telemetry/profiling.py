@@ -250,20 +250,26 @@ class DispatchProfiler:
         return self._clock()
 
     def end(self, sig: str, t0: Optional[float], out=None,
-            hbm_bytes: int = 0) -> Optional[float]:
+            hbm_bytes: int = 0,
+            seconds: Optional[float] = None) -> Optional[float]:
         """End-of-dispatch: no-op unless :meth:`begin` sampled it.
         Blocks on ``out`` (any jax pytree) so the timer measures device
         completion, records the duration, and attributes achieved
-        bytes/s when the call site passed an ``hbm_bytes`` estimate."""
+        bytes/s when the call site passed an ``hbm_bytes`` estimate.
+        A call site that has timed the dispatch already (the mixed
+        path's dispatch record) hands its ``seconds`` over: the sample
+        is that time, and nothing is blocked on."""
         if t0 is None:
             return None
-        if out is not None:
-            try:
-                import jax
-                jax.block_until_ready(out)
-            except Exception:
-                pass
-        dt = max(1e-9, self._clock() - t0)
+        if seconds is None:
+            if out is not None:
+                try:
+                    import jax
+                    jax.block_until_ready(out)
+                except Exception:
+                    pass
+            seconds = self._clock() - t0
+        dt = max(1e-9, seconds)
         with self._lock:
             s = self._stats.setdefault(sig, _SigStats())
             s.samples += 1

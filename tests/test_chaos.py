@@ -299,7 +299,7 @@ def test_worker_drops_corrupt_frame_without_forwarding():
     bad[40] ^= 0xFF
     before = _counter_value(catalog.TRANSPORT_CORRUPT_FRAMES)
     assert worker.handle_message("h:0:0", bytes(bad)) is True
-    assert worker.rt.caches == {}              # nothing ran
+    assert worker.rt._tables == {}              # nothing ran
     with pytest.raises(TransportTimeout):      # nothing was forwarded
         t0.recv_any(timeout=0.1)
     assert _counter_value(catalog.TRANSPORT_CORRUPT_FRAMES) == before + 1
@@ -376,18 +376,18 @@ def _supervise(header, threads, ids):
 
 
 def _assert_no_kv_leaks(header, workers, threads):
-    assert header.rt.caches == {}, "header leaked KV slots"
+    assert header.rt._tables == {}, "header leaked KV slots"
     # the ``end`` frees ride the chain asynchronously: give survivors a
     # bounded moment to process them before calling a slot leaked
     deadline = time.monotonic() + 5.0
     while time.monotonic() < deadline:
-        if all(w.rt.caches == {} for w, t in zip(workers, threads)
+        if all(w.rt._tables == {} for w, t in zip(workers, threads)
                if t.is_alive()):
             break
         time.sleep(0.05)
     for w, t in zip(workers, threads):
         if t.is_alive():       # survivors only; the crashed one is gone
-            assert w.rt.caches == {}, (
+            assert w.rt._tables == {}, (
                 f"{w.transport.device_id} leaked KV slots")
 
 
@@ -539,7 +539,7 @@ def test_stale_epoch_frames_dropped_property():
     for rid in (0, 7):
         for stale in (0, 1, 2):
             assert worker.handle_message(f"h:{rid}:0:{stale}", frame)
-            assert worker.rt.caches == {}, (
+            assert worker.rt._tables == {}, (
                 f"stale epoch {stale} frame ran (rid={rid})")
             with pytest.raises(TransportTimeout):
                 t0.recv_any(timeout=0.05)

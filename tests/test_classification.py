@@ -115,11 +115,11 @@ def test_pipeline_classify_matches_engine_and_accuracy(setup):
             lambda b: np.concatenate(header.classify_many([b], LABELS)),
             prompts, flipped, batch_size=2)
         assert result2["accuracy"] == 0.0
-        assert not header.rt.caches          # freed synchronously
+        assert not header.rt._tables          # freed synchronously
         deadline = __import__("time").monotonic() + 10
-        while worker.rt.caches and __import__("time").monotonic() < deadline:
+        while worker.rt._tables and __import__("time").monotonic() < deadline:
             __import__("time").sleep(0.05)   # end:{rid} is async
-        assert not worker.rt.caches
+        assert not worker.rt._tables
     finally:
         header.shutdown_pipeline()
         th.join(timeout=30)

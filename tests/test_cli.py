@@ -405,6 +405,25 @@ def test_serve_mode_pairing_rules(capsys):
     capsys.readouterr()
 
 
+@pytest.mark.parametrize("argv", [
+    ["serve", "--model", "llama-test", "--kv-layout", "paged"],
+    ["worker", "--model", "llama-test", "--stage-id", "1",
+     "--num-stages", "2", "--layer-start", "0", "--layer-end", "2",
+     "--device-id", "w", "--port", "0", "--header", "h@127.0.0.1:1",
+     "--kv-layout", "paged"],
+], ids=["serve", "worker"])
+def test_a_removed_flag_is_argparse_own_error(argv, capsys):
+    """A KV cache has one layout and no flag asks which: ``--kv-layout``
+    (one legal value since the dense layout went) is an unrecognized
+    argument like any other, exit 2, before anything is built; ``serve``
+    drops no flag it does not know."""
+    with pytest.raises(SystemExit) as exc:
+        cli.main(argv)
+    assert exc.value.code == 2
+    assert "unrecognized arguments: --kv-layout paged" in (
+        capsys.readouterr().err)
+
+
 @pytest.mark.slow
 def test_http_batching_with_draft(http_server):
     """The composed serving shape (continuous batching x speculative

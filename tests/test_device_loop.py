@@ -263,8 +263,7 @@ def test_paged_fused_block_reports_actual_steps(params):
     oracle = make_engine(params)
     with ContinuousBatchingEngine(CFG, params, max_seq=96, max_batch=4,
                                   sampling=GREEDY, prompt_buckets=(16,),
-                                  decode_block=16,
-                                  kv_layout="paged") as eng:
+                                  decode_block=16) as eng:
         got = eng.submit([3, 14, 15, 92, 65], 5).wait(timeout=300)
         want = oracle.generate(np.asarray([[3, 14, 15, 92, 65]]),
                                5).tokens[0]

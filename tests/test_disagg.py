@@ -13,9 +13,7 @@ The ISSUE-8 invariants, pinned:
   dense-row host gather);
 - migration frames are idempotent under duplication (the (rid,
   attempt, seq) dedup) and stale attempts are discarded;
-- both roles surface migration state on their debug surfaces;
-- ``--kv-layout dense`` fails loudly naming its removal (the escape
-  hatch was deprecation-staged here and deleted in the gateway PR).
+- both roles surface migration state on their debug surfaces.
 
 The chaos-side invariants (faulted migration, prefill crash
 rescheduling) live in tests/test_chaos.py.
@@ -341,22 +339,3 @@ def test_worker_cli_stage_role_still_rejects_kv_cache_flags(capsys):
         "--kv-cache-blocks", "8"])
     assert rc == 1
     assert "not supported" in capsys.readouterr().err
-
-
-def test_dense_layout_removed_fails_loudly():
-    """ROADMAP item 1 tail, final stage: the dense escape hatch
-    (deprecation-staged in this PR's predecessor) is DELETED —
-    resolving to 'dense' (flag, env, or kwarg: one owner) raises a
-    ValueError naming the removal and the migration, and the
-    once-per-process module-global warning latch is gone with it."""
-    import distributed_inference_demo_tpu.runtime.kvcache as kvc
-    with pytest.raises(ValueError) as ei:
-        kvc.resolve_kv_layout("dense")
-    msg = str(ei.value)
-    assert "REMOVED" in msg and "paged" in msg
-    # the deprecation scaffolding is deleted, not just unused
-    assert not hasattr(kvc, "_dense_deprecation_warned")
-    assert not hasattr(kvc, "DENSE_REMOVAL_RELEASE")
-    assert kvc.KV_LAYOUTS == ("paged",)
-    # paged resolves clean
-    assert kvc.resolve_kv_layout(None) == "paged"

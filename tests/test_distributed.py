@@ -144,7 +144,7 @@ def test_pipeline_eos_early_stop():
     th.join(timeout=30)
     assert got.shape[1] == stop_at                # stopped at EOS
     np.testing.assert_array_equal(got[0], want[0, :stop_at])
-    assert not worker.rt.caches                   # end:{rid} freed the slot
+    assert not worker.rt._tables                   # end:{rid} freed the slot
 
 
 def test_capacity_checked_before_launch():

@@ -121,10 +121,10 @@ def test_live_migration_scale_down_park_and_rejoin():
     np.testing.assert_array_equal(got3, want)
 
     header.reshard(["s0", "s1"])          # drop s2, re-split layers
-    assert workers[1].rt.caches == {}     # s2 parked: caches freed
+    assert workers[1].rt._tables == {}     # s2 parked: caches freed
     got2 = header.generate(PROMPT, 10)
     np.testing.assert_array_equal(got2, want)
-    assert workers[1].rt.caches == {}     # parked spare saw no traffic
+    assert workers[1].rt._tables == {}     # parked spare saw no traffic
 
     header.reshard(["s0", "s1", "s2"])    # the parked spare rejoins
     np.testing.assert_array_equal(header.generate(PROMPT, 10), want)

@@ -1,9 +1,7 @@
 """The universal-paged KV contract (docs/DESIGN.md §14).
 
-Paged is the ONLY layout — the dense escape hatch and its backend were
-deleted (the gateway release), which retired the dense-parity twin
-matrix this file used to run.  What survives is everything the twins
-actually proved about the paged path, now pinned directly:
+A KV cache is a pool of pages behind one backend seam; what this file
+pins about it:
 
 - determinism: cold vs radix-primed runs agree bit-for-bit (a prefix
   hit is a memory optimization, never a semantics change) — greedy in
@@ -16,8 +14,8 @@ actually proved about the paged path, now pinned directly:
 - speculative page-sharing ownership (two requests sharing a prefix
   reference the SAME pages in HBM);
 - the ring-stage per-stage pool frees every page on ``free(rid)``;
-- the sp backend surfaces the universal layout and the removed dense
-  layout fails loudly naming the removal.
+- the sp backend has no pool to report: its cache is per-request
+  scratch inside the sharded program.
 
 The paged-primed coverage for the batching scheduler, chunked prefill,
 ``stream_block`` fusion, and the speculative slot modes lives in
@@ -139,7 +137,6 @@ def test_speculative_page_sharing_ownership(params):
                                quantize=True)
     spec = SpeculativeEngine(CFG, params, cfg8, params8, max_seq=96,
                              sampling=GREEDY, num_draft=3, **POOL)
-    assert spec.kv_layout == "paged"
     r1, _ = spec.generate(PROMPT, 8)
     snap1 = spec.kv_cache.snapshot()
     r2, _ = spec.generate(PROMPT, 8)
@@ -183,17 +180,51 @@ def test_ring_stage_runtime_paged(params):
     assert not rt._tables
 
 
-def test_sp_backend_paged_only(params):
-    """The sp backend accepts the universal layout flag, surfaces it on
-    /stats, and fails the removed dense layout loudly (its cache is
-    per-request sequence-sharded scratch — documented in
-    runtime/sp_backend.py)."""
+def test_ring_stage_pool_gets_every_page_back(params):
+    """Interleaved rids over one stage pool (two chunked prompts, then
+    their decode steps in turn): ``free`` hands one request's pages
+    back, ``reset_caches`` (a reshard, a restart) everybody else's, and
+    the pool then holds every page id once."""
+    from distributed_inference_demo_tpu.runtime.distributed import (
+        StageRuntime)
+    spec = StageSpec(0, 1, 0, CFG.num_layers)
+    rt = StageRuntime(CFG, spec, params, max_seq=64, sampling=GREEDY)
+    n_pages = rt._sentinel
+    assert sorted(rt._pool_free) == list(range(n_pages))
+    prompt = PROMPT.astype(np.int32)
+    half = prompt.shape[1] // 2
+    toks = {}
+    for rid in (1, 2, 3):
+        rt.run_chunk(rid, prompt[:, :half])
+    for rid in (3, 1, 2):
+        toks[rid] = rt.run_chunk_sample(rid, 0, prompt[:, half:])
+    for step in range(1, 4):
+        for rid in (2, 3, 1):
+            toks[rid] = rt.run_chunk_sample(rid, step, toks[rid][:, None])
+    np.testing.assert_array_equal(toks[1], toks[2])
+    np.testing.assert_array_equal(toks[1], toks[3])
+    held = {rid: [int(v) for v in rt._tables[rid].flat
+                  if v != rt._sentinel] for rid in (1, 2, 3)}
+    assert all(len(h) == -(-(prompt.shape[1] + 3) // rt._bt)
+               for h in held.values())
+    assert len(rt._pool_free) == n_pages - sum(map(len, held.values()))
+    rt.free(2)
+    assert 2 not in rt._tables and set(held[2]) <= set(rt._pool_free)
+    rt.free(2)                              # an `end` sent twice
+    rt.reset_caches()
+    assert not rt._tables and not rt._rid_len and not rt._rid_blocks
+    assert sorted(rt._pool_free) == list(range(n_pages))
+
+
+def test_sp_backend_stats_hold_no_page_pool(params):
+    """The sp backend's cache is per-request sequence-sharded scratch
+    (documented in runtime/sp_backend.py): it builds with no KV option
+    and its /stats name no pool."""
     from distributed_inference_demo_tpu.parallel.mesh import local_sp_mesh
     from distributed_inference_demo_tpu.runtime.sp_backend import (
         SequenceParallelBackend)
     mesh = local_sp_mesh(2)
     be = SequenceParallelBackend(CFG, params, mesh, max_seq=64)
-    assert be.stats()["kv_layout"] == "paged"
-    with pytest.raises(ValueError, match="REMOVED"):
-        SequenceParallelBackend(CFG, params, mesh, max_seq=64,
-                                kv_layout="dense")
+    st = be.stats()
+    assert st["mode"] == "sequence_parallel" and st["sp"] == 2
+    assert "kv_cache" not in st and not hasattr(be, "kv_cache")

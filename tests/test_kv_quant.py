@@ -294,7 +294,7 @@ def test_byte_budget_admits_more_narrow_blocks(monkeypatch):
     monkeypatch.setenv("DWT_KVCACHE_BYTES", str(4 * bf16_block))
     n = {}
     for d in KV_DTYPES:
-        be = make_kv_backend(CFG, 64, 8, layout="paged", kv_dtype=d)
+        be = make_kv_backend(CFG, 64, 8, kv_dtype=d)
         n[d] = be.mgr.num_blocks
         assert be.kv_dtype == d
     assert n["bf16"] == 4
@@ -306,8 +306,8 @@ def test_kv_dtype_refuses_storage_cast(params):
     from distributed_inference_demo_tpu.runtime.kvcache import (
         make_kv_backend)
     with pytest.raises(ValueError, match="kv_cache_dtype"):
-        make_kv_backend(CFG, 8, 8, layout="paged",
-                        dtype=jnp.dtype("float16"), kv_dtype="int8")
+        make_kv_backend(CFG, 8, 8, dtype=jnp.dtype("float16"),
+                        kv_dtype="int8")
     with pytest.raises(ValueError, match="kv_cache_dtype"):
         ContinuousBatchingEngine(CFG, params, max_seq=64, max_batch=1,
                                  kv_cache_dtype="float16",

@@ -1,19 +1,20 @@
-"""Block-level KV cache (runtime/kvcache): radix-tree properties against
-a brute-force reference, refcount/CoW + eviction invariants, byte
-accounting, and cold-vs-primed EXACTNESS through the single-request
-engines (ISSUE 3 acceptance: cached-vs-cold generations are
-token-identical; eviction honors live leases).
+"""Block-level KV cache (runtime/kvcache): radix-tree and page-id manager
+properties against brute-force references, lease + eviction
+invariants, page accounting, the backend's store / seed round trip, and
+cold-vs-primed EXACTNESS through the single-request engines (ISSUE 3
+acceptance: cached-vs-cold generations are token-identical; eviction
+honors live leases).
 
-The tree/pool/manager tests run host-only (numpy in, numpy out — no jax
-below the manager); the exactness tests drive real engines on tiny
-models.
+The tree and manager tests run host-only (no jax below the manager);
+the backend and exactness tests drive the device programs and real
+engines on tiny models.
 """
 
 import numpy as np
 import pytest
 
 from distributed_inference_demo_tpu.runtime.kvcache import (
-    KVBlockPool, KVCacheManager, RadixTree)
+    PagedKVCacheManager, RadixTree)
 
 # ---------------------------------------------------------------------------
 # radix tree vs brute-force reference
@@ -113,192 +114,220 @@ def test_radix_release_without_acquire_raises():
 
 
 # ---------------------------------------------------------------------------
-# pool accounting
-
-
-def test_pool_alloc_free_accounting_balances():
-    pool = KVBlockPool(4, num_layers=2, num_kv_heads=2, block_tokens=2,
-                       head_dim=3, dtype=np.float32)
-    assert pool.resident_bytes == 0
-    ids = [pool.alloc() for _ in range(4)]
-    assert pool.alloc() is None                      # exhausted
-    assert pool.used_blocks == 4
-    assert pool.resident_bytes == pool.capacity_bytes
-    pool.free(ids)
-    assert pool.free_blocks == 4 and pool.resident_bytes == 0
-    with pytest.raises(ValueError):
-        pool.free([99])
-
-
-def test_pool_gather_roundtrips_block_data():
-    pool = KVBlockPool(3, num_layers=1, num_kv_heads=2, block_tokens=2,
-                       head_dim=4, dtype=np.float32)
-    rng = np.random.default_rng(1)
-    a, b = pool.alloc(), pool.alloc()
-    ka = rng.normal(size=(1, 2, 2, 4)).astype(np.float32)
-    kb = rng.normal(size=(1, 2, 2, 4)).astype(np.float32)
-    pool.write(a, ka, ka + 1)
-    pool.write(b, kb, kb + 1)
-    k, v = pool.gather([a, b])
-    assert k.shape == (1, 2, 4, 4)                   # [L, H, n*bt, D]
-    np.testing.assert_array_equal(k[:, :, :2], ka)
-    np.testing.assert_array_equal(k[:, :, 2:], kb)
-    np.testing.assert_array_equal(v[:, :, 2:], kb + 1)
-
-
-# ---------------------------------------------------------------------------
-# manager: lease/CoW/eviction invariants (host-only; numpy "device" rows)
+# the page-id manager against a brute-force dictionary of prefixes
 
 
 def _mgr(num_blocks=8, bt=4, L=2, H=2, D=4):
-    return KVCacheManager(L, H, D, num_blocks=num_blocks,
-                          block_tokens=bt, dtype=np.float32)
+    return PagedKVCacheManager(L, H, D, num_blocks=num_blocks,
+                               block_tokens=bt, dtype=np.float32)
 
 
-def _row(rng, L=2, H=2, D=4, S=64):
-    return (rng.normal(size=(L, 1, H, S, D)).astype(np.float32),
-            rng.normal(size=(L, 1, H, S, D)).astype(np.float32))
+class PrefixModel:
+    """Reference for the manager: every stored block-prefix and the page
+    that holds its last block; the pages requests hold for themselves;
+    the leases alive.  ``check`` is what must hold after any operation."""
+
+    def __init__(self, mgr):
+        self.mgr, self.bt = mgr, mgr.block_tokens
+        self.pages = {}              # tuple of block keys -> page id
+        self.private = []            # pages a request owns
+        self.leases = []
+
+    def took(self, ids):
+        """``ids`` came out of ``alloc``: what the tree lost to make
+        room is what now lies in the free list or in ``ids``."""
+        gone = set(self.mgr._free) | set(ids)
+        self.pages = {k: v for k, v in self.pages.items() if v not in gone}
+
+    def longest(self, prompt):
+        keys = _keys([int(t) for t in prompt], self.bt)
+        keys = keys[:(len(prompt) - 1) // self.bt]      # the cap
+        n = 0
+        while n < len(keys) and tuple(keys[:n + 1]) in self.pages:
+            n += 1
+        return [self.pages[tuple(keys[:j + 1])] for j in range(n)]
+
+    def check(self):
+        m = self.mgr
+        m.tree.check()
+        tree = list(self.pages.values())
+        assert m.tree.block_count == len(tree)
+        assert m.used_blocks == len(tree) + len(self.private)
+        held = tree + self.private + list(m._free)
+        assert len(held) == len(set(held)) == m.num_blocks
+        # a stored prefix's own prefixes are stored (eviction takes
+        # leaves), and a lease's pages are the tree's still
+        for keys in self.pages:
+            assert len(keys) == 1 or keys[:-1] in self.pages
+        for lease in self.leases:
+            assert set(lease.block_ids) <= set(tree)
+        snap = m.snapshot()
+        assert snap["blocks_used"] == m.used_blocks
+        assert snap["device_resident_bytes"] == m.used_blocks * m.block_bytes
 
 
-def test_manager_match_caps_below_prompt_and_roundtrips_data():
-    rng = np.random.default_rng(2)
-    mgr = _mgr()
-    k, v = _row(rng)
-    prompt = np.arange(12)                           # 3 whole blocks
-    assert mgr.match(prompt) is None                 # cold: miss
-    mgr.store(prompt, k, v)
-    lease = mgr.match(prompt)                        # exact repeat
-    assert lease.tokens == 8                         # capped below plen
-    pk, pv = lease.gather()
-    np.testing.assert_array_equal(pk, k[:, 0, :, :8])
-    np.testing.assert_array_equal(pv, v[:, 0, :, :8])
-    lease.release()
-    longer = np.concatenate([np.arange(12), [7, 7, 7, 7, 7]])
-    lease2 = mgr.match(longer)                       # mid-prompt hit
-    assert lease2.tokens == 12
-    lease2.release()
-    assert mgr.peek(longer) == 12                    # peek = match, no stats
-    assert mgr.stats["hits"] == 2 and mgr.stats["misses"] == 1
+def _random_workload(seed, steps=300):
+    rng = np.random.default_rng(seed)
+    mgr = _mgr(num_blocks=7, bt=2)
+    ref = PrefixModel(mgr)
+    for _ in range(steps):
+        op = rng.random()
+        prompt = rng.integers(0, 3, size=rng.integers(2, 14))
+        if op < 0.4:                                    # store
+            n = len(prompt) // mgr.block_tokens
+            ids = mgr.alloc(n)
+            if ids is not None:
+                ref.took(ids)
+                adopted, lease = mgr.store_shared(prompt, ids)
+                keys = _keys([int(t) for t in prompt], mgr.block_tokens)
+                # the missing tail alone is adopted, in order
+                have = sum(tuple(keys[:j + 1]) in ref.pages
+                           for j in range(n))
+                assert list(adopted) == ids[have:]
+                for j, page in zip(range(have, n), adopted):
+                    ref.pages[tuple(keys[:j + 1])] = page
+                mgr.free(ids[:have])                    # declined
+                ref.leases.append(lease)
+        elif op < 0.7:                                  # match
+            want = ref.longest(prompt)
+            assert mgr.peek(prompt) == len(want) * mgr.block_tokens
+            lease = mgr.match(prompt)
+            assert (lease.block_ids if lease else []) == want
+            if lease is not None:
+                assert lease.tokens == len(want) * mgr.block_tokens
+                ref.leases.append(lease)
+        elif op < 0.85 and ref.leases:                  # release
+            ref.leases.pop(rng.integers(len(ref.leases))).release()
+        elif op < 0.95:                                 # a request's pages
+            ids = mgr.alloc(int(rng.integers(1, 5)))
+            if ids is not None:
+                ref.took(ids)
+                ref.private += ids
+        elif ref.private:                               # it completes
+            mgr.free(ref.private)
+            ref.private = []
+        while len(ref.leases) > 3:
+            ref.leases.pop(0).release()
+        ref.check()
+    return mgr, ref
 
 
-def test_manager_store_skips_existing_blocks():
-    rng = np.random.default_rng(3)
-    mgr = _mgr()
-    k, v = _row(rng)
-    mgr.store(np.arange(8), k, v)                    # 2 blocks
-    added = mgr.store(np.concatenate([np.arange(8), [50, 51, 52, 53]]),
-                      k, v)
-    assert added == 1                                # only the new tail
-    assert mgr.snapshot()["blocks_used"] == 3
+@pytest.mark.parametrize("seed", [6, 7, 8])
+def test_manager_random_workload_against_prefix_dictionary(seed):
+    """Random store / match / release / evict interleavings with live
+    leases: after every operation the used pages are the tree's and the
+    requests', no page id is held twice, a match is the longest stored
+    prefix capped below the prompt and names the pages that hold it, and
+    a leased prefix outlives any pool pressure."""
+    mgr, ref = _random_workload(seed)
+    assert mgr.stats["evicted_blocks"] > 0 and mgr.stats["hits"] > 0
+    assert mgr.stats["stored_blocks"] > mgr.num_blocks
 
 
-def test_manager_eviction_honors_live_leases():
-    """ISSUE 3 acceptance: eviction honors live leases — a pinned match
-    survives arbitrary pool pressure and still gathers the exact bytes
-    it matched; releasing makes it reclaimable."""
-    rng = np.random.default_rng(4)
-    mgr = _mgr(num_blocks=4, bt=4)
-    k, v = _row(rng)
-    prompt = np.arange(8)                            # 2 blocks
-    mgr.store(prompt, k, v)
-    lease = mgr.match(np.concatenate([prompt, [9]]))
-    assert lease.tokens == 8
-    # flood the pool: every new store needs blocks the leased entry holds
-    for i in range(6):
-        nk, nv = _row(rng)
-        mgr.store(rng.integers(100, 200, size=12), nk, nv)
-        snap = mgr.snapshot()
-        assert snap["blocks_used"] <= 4
-    # the leased blocks were never reclaimed: the gather still matches
-    pk, pv = lease.gather()
-    np.testing.assert_array_equal(pk, k[:, 0, :, :8])
-    lease.release()
-    # released: pressure can now reclaim them
-    for i in range(4):
-        mgr.store(rng.integers(200, 300, size=16), *_row(rng))
-    assert mgr.peek(np.concatenate([prompt, [9]])) in (0, 4, 8)
-
-
-def test_manager_accounting_balances_to_zero_after_drain():
-    """Byte accounting: evicting everything returns every block to the
-    pool and resident bytes to exactly zero."""
-    rng = np.random.default_rng(5)
-    mgr = _mgr(num_blocks=8, bt=4)
-    for _ in range(5):
-        mgr.store(rng.integers(0, 50, size=rng.integers(4, 20)),
-                  *_row(rng))
-        mgr.tree.check()
-    # drain: evict until nothing is left (no leases outstanding)
-    while True:
-        freed = mgr.tree.evict_lru_leaf()
-        if not freed:
-            break
-        mgr.pool.free(freed)
+def test_manager_accounting_returns_to_zero_after_drain():
+    """Every lease released, every request's pages freed and the tree
+    drained: every page is free again and nothing is resident."""
+    mgr, ref = _random_workload(9, steps=120)
+    for lease in ref.leases:
+        lease.release()
+    mgr.free(ref.private)
+    ids = mgr.alloc(mgr.num_blocks)         # evicts all that is left
+    assert ids is not None and sorted(ids) == list(range(mgr.num_blocks))
+    assert mgr.tree.block_count == 0 and mgr.tree.node_count == 1
+    mgr.free(ids)
     snap = mgr.snapshot()
-    assert snap["blocks_used"] == 0
-    assert snap["resident_bytes"] == 0
-    assert snap["nodes"] == 0
-    assert mgr.pool.free_blocks == mgr.pool.num_blocks
+    assert snap["blocks_used"] == snap["tree_blocks"] == snap["nodes"] == 0
+    assert snap["device_resident_bytes"] == 0
+    assert mgr.free_blocks == mgr.num_blocks
+    assert mgr.debug_state()["leased_nodes"] == 0
     mgr.tree.check()
 
 
-def test_manager_random_workload_invariants():
-    """Property sweep over random match/store/evict interleavings with
-    live leases: the pool never over-commits, leased gathers always
-    return the bytes that were stored, accounting never drifts."""
-    rng = np.random.default_rng(6)
-    mgr = _mgr(num_blocks=6, bt=2)
-    stored = {}                                      # tuple(prompt) -> row
-    leases = []
-    for step in range(300):
-        op = rng.random()
-        prompt = rng.integers(0, 4, size=rng.integers(2, 14))
-        if op < 0.45:
-            k, v = _row(rng)
-            mgr.store(prompt, k, v)
-            stored[tuple(int(t) for t in prompt)] = (k, v)
-        elif op < 0.8:
-            lease = mgr.match(prompt)
-            if lease is not None and len(leases) < 3:
-                leases.append(lease)
-            elif lease is not None:
-                lease.release()
-        elif leases:
-            leases.pop(rng.integers(len(leases))).release()
-        mgr.tree.check()
-        snap = mgr.snapshot()
-        assert snap["blocks_used"] <= 6
-        assert (snap["blocks_used"] * mgr.pool.block_bytes
-                == snap["resident_bytes"])
-        assert mgr.pool.free_blocks + snap["blocks_used"] == 6
-    for lease in leases:
-        lease.release()
-
-
-def test_env_knobs_and_byte_budget(monkeypatch):
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_backend_store_then_seed_roundtrips_the_shared_prefix(kv_dtype):
+    """``PagedKVBackend``: what ``store`` scattered into pages is what
+    ``seed`` gathers out of them for a prompt sharing the prefix, bit
+    for bit: full-width pages give back the stored keys and values,
+    int8 pages what their codes and scales (the stored rows' per-token
+    quantization) dequantize to."""
+    import jax
+    import jax.numpy as jnp
+    from distributed_inference_demo_tpu.models import get_model_config
+    from distributed_inference_demo_tpu.models.base import KVCache
+    from distributed_inference_demo_tpu.ops.quant import quantize_kv_pages
     from distributed_inference_demo_tpu.runtime.kvcache import (
-        resolve_kvcache_config)
+        make_kv_backend)
+    cfg = get_model_config("llama-test")
+    be = make_kv_backend(cfg, 8, 4, kv_dtype=kv_dtype)
+    fresh = KVCache.create(cfg, cfg.num_layers, 1, 32)
+    kk, kv = jax.random.split(jax.random.PRNGKey(3))
+    stored = KVCache(
+        jax.random.normal(kk, fresh.keys.shape, fresh.keys.dtype),
+        jax.random.normal(kv, fresh.values.shape, fresh.values.dtype),
+        jnp.int32(14))
+    prompt = np.arange(1, 15)[None]                     # 3 blocks + 2
+    be.store(prompt, stored)
+    assert be.mgr.tree.block_count == be.mgr.used_blocks == 3
+    longer = np.concatenate([prompt[0, :12], [90, 91, 92]])[None]
+    m, seeded = be.seed(longer, fresh)
+    assert m == 12 and int(seeded.length) == 12
+    pages = jnp.asarray(be.mgr.tree.match(
+        _keys(list(range(1, 13)), 4), touch=False)[0])
+
+    def rows(x):        # [L, n, H, bt, D] pages as a cache's columns
+        L, n, H, bt, D = x.shape
+        return np.asarray(x.transpose(0, 2, 1, 3, 4).reshape(
+            L, 1, H, n * bt, D))
+
+    for got, put, pool in ((seeded.keys, stored.keys, be._pk),
+                           (seeded.values, stored.values, be._pv)):
+        want = np.asarray(put[:, :, :, :m])
+        if kv_dtype == "int8":
+            # the pages hold the stored rows' codes, a scale a token,
+            # and the seed is what those pages dequantize to
+            held = jax.tree.map(lambda p: p[:, pages], pool)
+            ref = quantize_kv_pages(put[:, :, :, :m], 8)
+            np.testing.assert_array_equal(rows(held.data),
+                                          np.asarray(ref.data))
+            np.testing.assert_allclose(rows(held.scale),
+                                       np.asarray(ref.scale), rtol=1e-6)
+            want = rows(held.dequantize(jnp.float32).astype(put.dtype))
+        np.testing.assert_array_equal(np.asarray(got[:, :, :, :m]), want)
+        assert not np.asarray(got[:, :, :, m:]).any()   # untouched
+    snap = be.snapshot()
+    assert snap["h2d_bytes"] == 0 and snap["page_dtype"] == kv_dtype
+    assert be.mgr.debug_state()["leased_nodes"] == 0
+
+
+def test_env_knobs_and_byte_budget(monkeypatch, tiny):
+    from distributed_inference_demo_tpu.runtime import InferenceEngine
+    from distributed_inference_demo_tpu.runtime.kvcache import (
+        make_kv_backend, resolve_kvcache_config)
     monkeypatch.setenv("DWT_KVCACHE_BLOCKS", "12")
     monkeypatch.setenv("DWT_KVCACHE_BLOCK_TOKENS", "8")
     assert resolve_kvcache_config(None, None) == (12, 8)
     assert resolve_kvcache_config(3, 2) == (3, 2)    # explicit wins
     monkeypatch.delenv("DWT_KVCACHE_BLOCKS")
     assert resolve_kvcache_config(None, 4, default_blocks=64) == (64, 4)
-    # DWT_KVCACHE_BYTES shrinks the pool to fit
-    mgr_free = _mgr(num_blocks=8, bt=4)
-    monkeypatch.setenv("DWT_KVCACHE_BYTES",
-                       str(3 * mgr_free.pool.block_bytes))
-    mgr_capped = _mgr(num_blocks=8, bt=4)
-    assert mgr_capped.pool.num_blocks == 3
-    # a ceiling below ONE block disables the cache (for_model -> None)
-    # instead of crashing engine construction — the knob is a ceiling
-    import types
-    cfg = types.SimpleNamespace(kv_planes=2, num_kv_heads=2, head_dim=4,
-                                dtype=np.float32)
+    # DWT_KVCACHE_BYTES shrinks the pool to fit: the manager's count,
+    # and the pool the backend allocates for it
+    cfg, params = tiny
+    page = _mgr(num_blocks=8, bt=4).block_bytes
+    model_page = make_kv_backend(cfg, 8, 4).mgr.block_bytes
+    monkeypatch.setenv("DWT_KVCACHE_BYTES", str(3 * page))
+    assert _mgr(num_blocks=8, bt=4).num_blocks == 3
+    monkeypatch.setenv("DWT_KVCACHE_BYTES", str(3 * model_page))
+    capped = make_kv_backend(cfg, 8, 4)
+    assert capped.mgr.num_blocks == capped._pk.shape[1] == 3
+    # a ceiling below ONE page disables reuse (no backend) instead of
+    # crashing engine construction — the knob is a ceiling
     monkeypatch.setenv("DWT_KVCACHE_BYTES", "1")
-    assert KVCacheManager.for_model(cfg, 8, 4) is None
+    assert make_kv_backend(cfg, 8, 4) is None
+    eng = InferenceEngine(cfg, params, max_seq=32, kv_cache_blocks=8,
+                          kv_block_tokens=4)
+    assert eng.kv_cache is None
     monkeypatch.delenv("DWT_KVCACHE_BYTES")
-    assert KVCacheManager.for_model(cfg, 8, 4) is not None
+    assert make_kv_backend(cfg, 8, 4).mgr.num_blocks == 8
 
 
 # ---------------------------------------------------------------------------

@@ -90,41 +90,3 @@ def test_deprecated_prefix_aliases_removed():
     reg.register(Counter("dwt_batching_prefix_cache_hits_total",
                          "resurrected alias"))
     assert any("registered again" in p for p in lint.check_required(reg))
-
-
-def _load_kv_lint():
-    path = (pathlib.Path(__file__).resolve().parents[1] / "tools"
-            / "check_kv_layout.py")
-    spec = importlib.util.spec_from_file_location("check_kv_layout", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-@pytest.mark.quick
-def test_kv_layout_dense_removal_stays_deleted():
-    """Zero references to the removed dense identifiers anywhere in
-    the package (docs/DESIGN.md §14): the escape hatch is deleted and
-    this lint keeps the deletion from silently regrowing."""
-    kv_lint = _load_kv_lint()
-    root = pathlib.Path(__file__).resolve().parents[1]
-    assert kv_lint.check_kv_layout_matrix(root) == []
-    assert kv_lint.main() == 0
-
-
-def test_kv_layout_lint_fires_on_a_resurrected_identifier(tmp_path):
-    """The lint actually detects a resurrected dense identifier —
-    including inside runtime/kvcache/, the shim's former home."""
-    kv_lint = _load_kv_lint()
-    pkg = tmp_path / "distributed_inference_demo_tpu" / "runtime"
-    pkg.mkdir(parents=True)
-    (pkg / "new_engine.py").write_text(
-        "from .kvcache import " + "require_dense_kv_layout\n")
-    former_home = pkg / "kvcache"
-    former_home.mkdir()
-    (former_home / "__init__.py").write_text(
-        "class " + "DenseKVBackend:\n    ...\n")
-    problems = kv_lint.check_kv_layout_matrix(tmp_path)
-    assert len(problems) == 2
-    assert any("new_engine.py" in p for p in problems)
-    assert any("kvcache" in p for p in problems)

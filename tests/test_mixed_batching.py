@@ -23,6 +23,7 @@ Runs on CPU through the XLA-gather fallback — the same control flow
 the TPU prefill kernel's auto-dispatch falls back to.
 """
 
+import contextlib
 import dataclasses
 import sys
 import time
@@ -661,16 +662,37 @@ def settle(eng):
         time.sleep(0.005)
 
 
-def scripted_run(params, sampling, eos_id, refuse, case="base"):
+def scripted_run(params, sampling, eos_id, refuse, case="base",
+                 sample_n=None):
     """The scripted traffic through one engine of three slots; with
     ``refuse`` every prepared dispatch is turned away by a patched
-    validator, so every iteration runs in the old order.  Returns what
-    the two runs must agree on, and what each alone must show."""
+    validator, so every iteration runs in the old order.  With
+    ``sample_n`` the engine has a profiler of its own that samples one
+    dispatch in so many a signature (0: none), and from the moment the
+    engine is ready ``jax.block_until_ready`` notes its callers and
+    raises.  Returns what the two runs must agree on, and what each
+    alone must show."""
     eng = mixed_engine(params, max_batch=3, sampling=sampling, seed=11,
                        eos_id=eos_id)
-    # the profiler's counts run over the whole process: which dispatch
-    # it would sample (and keep on the old order) is not the script's
-    eng._prof = DispatchProfiler(sample_n=0)
+    synced = []
+    with contextlib.ExitStack() as stack:
+        if sample_n is not None:
+            eng._prof = DispatchProfiler(sample_n=sample_n)
+
+            def no_sync(*a, **kw):
+                synced.append(sys._getframe(1).f_code.co_name)
+                raise AssertionError("the mixed loop blocked on the device")
+
+            stack.enter_context(
+                mock.patch.object(jax, "block_until_ready", no_sync))
+        out = _scripted_traffic(eng, eos_id, refuse, case)
+    out["synced"] = synced
+    out["profile"] = {sig: (st.samples, st.total_s)
+                      for sig, st in eng._prof._stats.items()}
+    return out
+
+
+def _scripted_traffic(eng, eos_id, refuse, case):
     script = dict(SCRIPTS[case])
     raise_at = script.pop("raise_at", None)
     n_events = sum(map(len, script.values()))
@@ -934,6 +956,64 @@ def test_prepared_dispatches_change_nothing_but_the_order(params, sampled,
         if b["ahead"] > 0:
             assert b["active_rows"] > 0 or b["segments"] > 0
             assert 0 < b["ahead"] <= a["wait"] + 2e-5
+
+
+_PROFILED = {}
+
+
+def profiled_run(params, sampled, sample_n):
+    """The base script under a profiler that samples one dispatch in
+    ``sample_n`` a signature (0: none), one run a kind."""
+    if (sampled, sample_n) not in _PROFILED:
+        sampling = (SamplingParams(greedy=False, temperature=0.9, top_k=40)
+                    if sampled else GREEDY)
+        _PROFILED[sampled, sample_n] = scripted_run(
+            params, sampling, None, False, sample_n=sample_n)
+    return _PROFILED[sampled, sample_n]
+
+
+@pytest.mark.parametrize("sampled", [False, True],
+                         ids=["greedy", "sampled"])
+def test_a_profiler_sample_changes_no_schedule(params, sampled):
+    """A dispatch the profiler samples is prepared under its predecessor
+    like any other: with every dispatch sampled the script has the hits,
+    the misses, the streams and the records it has with none."""
+    every, none = (profiled_run(params, sampled, n) for n in (1, 0))
+    assert every["script_done"] and none["script_done"]
+    for key in none["same"]:
+        assert every["same"][key] == none["same"][key], key
+    for key in ("ahead_hits", "ahead_hits_slab", "ahead_misses",
+                "ahead_first", "seq"):
+        assert every["trace"][key] == none["trace"][key], key
+    assert every["trace"]["ahead_hits"] >= 5
+    assert none["profile"] == {}
+
+
+def test_a_profiler_sample_is_the_dispatch_records_time(params):
+    """With every dispatch sampled the profiler holds one sample a mixed
+    dispatch, under ``mixed_step`` signatures alone, and their seconds
+    are the records' own ``t_done - t_launch``: no second clock."""
+    run = profiled_run(params, False, 1)
+    recs, profile = run["recs"], run["profile"]
+    assert profile and all(sig.startswith("mixed_step|") for sig in profile)
+    assert sum(n for n, _ in profile.values()) == len(recs) == run[
+        "trace"]["seq"]
+    # a record's instants are rounded to 1e-5 s
+    assert sum(t for _, t in profile.values()) == pytest.approx(
+        sum(r["t_done"] - r["t_launch"] for r in recs),
+        abs=2e-5 * len(recs))
+    assert all(r["t_done"] > r["t_launch"] for r in recs)
+
+
+def test_the_mixed_loop_never_blocks_on_the_device(params):
+    """Once the engine is ready nothing on the mixed path calls
+    ``jax.block_until_ready``, a sampled dispatch's drain included: the
+    script ran to its end with that call made to raise."""
+    run = profiled_run(params, False, 1)
+    assert run["synced"] == []
+    assert run["script_done"]
+    assert all(error == "None"
+               for *_, error, _, _ in run["same"]["streams"].values())
 
 
 def test_under_a_mesh_a_plan_lies_where_the_call_wants_it():

@@ -114,14 +114,12 @@ def test_kv_planes_sizes_every_kv_structure(params):
     """Dense cache, page pool, the manager's block bytes, exported
     blocks: ``layers x passes`` planes, never ``layers``."""
     from distributed_inference_demo_tpu.runtime.kvcache import (
-        KVCacheManager, PagedKVCacheManager, make_kv_backend)
+        PagedKVCacheManager, make_kv_backend)
     assert CFG.kv_planes == L * T == 12
     assert KVCache.create(CFG, L, 2, 32).keys.shape[0] == 12
     block = 2 * 12 * CFG.num_kv_heads * 8 * CFG.head_dim * 4
     assert PagedKVCacheManager.for_model(CFG, 4, 8).block_bytes == block
-    assert KVCacheManager.for_model(CFG, 4, 8).pool.keys.shape[:2] == (4, 12)
-    backend = make_kv_backend(CFG, layout="paged", kv_cache_blocks=4,
-                              kv_block_tokens=8)
+    backend = make_kv_backend(CFG, kv_cache_blocks=4, kv_block_tokens=8)
     assert backend._pk.shape[0] == 12
     with _engine(params, **MIXED) as eng:
         assert eng._pk.shape == (12, 40, CFG.num_kv_heads, 8, CFG.head_dim)

@@ -57,7 +57,7 @@ def paged_engine(params, **kw):
     kw.setdefault("sampling", GREEDY)
     kw.setdefault("prompt_buckets", (16,))
     kw.setdefault("kv_block_tokens", 8)
-    return ContinuousBatchingEngine(CFG, params, kv_layout="paged", **kw)
+    return ContinuousBatchingEngine(CFG, params, **kw)
 
 
 def assert_no_leak(eng):
@@ -79,7 +79,6 @@ def test_cold_parity_concurrent_requests(params, oracle):
         for p, n, r in zip(prompts, ns, reqs):
             np.testing.assert_array_equal(r.wait(timeout=300),
                                           expected(oracle, p, n))
-        assert eng.stats()["kv_layout"] == "paged"
         assert_no_leak(eng)
 
 
@@ -182,27 +181,6 @@ def test_paged_speculative_slot_modes_and_leak(params, oracle):
         assert_no_leak(eng)
         # the draft half of the leak invariant: scratch pages drained
         assert eng._dmgr.used_blocks == 0
-
-
-def test_batching_rejects_dense_env_and_flag(params, monkeypatch):
-    """kv_layout='dense' (flag or env) must fail loudly EVERYWHERE:
-    the escape hatch is removed (docs/DESIGN.md §14) and a knob
-    promising it must never silently run paged.  The error names the
-    removal, not a generic unknown-layout complaint."""
-    with pytest.raises(ValueError, match="REMOVED"):
-        ContinuousBatchingEngine(CFG, params, max_seq=64,
-                                 sampling=GREEDY, kv_layout="dense")
-    monkeypatch.setenv("DWT_KV_LAYOUT", "dense")
-    with pytest.raises(ValueError, match="REMOVED"):
-        ContinuousBatchingEngine(CFG, params, max_seq=64,
-                                 sampling=GREEDY)
-    # the single-request engines reject it the same way — no engine
-    # honors the removed layout
-    with pytest.raises(ValueError, match="REMOVED"):
-        InferenceEngine(CFG, params, max_seq=64, sampling=GREEDY)
-    monkeypatch.delenv("DWT_KV_LAYOUT")
-    eng = InferenceEngine(CFG, params, max_seq=64, sampling=GREEDY)
-    assert eng.kv_layout == "paged"
 
 
 def test_decode_block_fused_parity(params, oracle):

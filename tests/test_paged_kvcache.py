@@ -12,7 +12,7 @@ import pytest
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from distributed_inference_demo_tpu.runtime.kvcache import (
-    PagedKVCacheManager, resolve_kv_layout)
+    PagedKVCacheManager)
 
 
 def mgr(blocks=16, bt=4):
@@ -107,23 +107,6 @@ def test_epoch_bumps_on_store_and_evict():
     e1 = m.epoch
     m.alloc(4)                                  # forces eviction
     assert m.epoch > e1
-
-
-def test_layout_resolution_and_rejection(monkeypatch):
-    # paged is the ONLY layout (docs/DESIGN.md §14); the removed dense
-    # escape hatch fails loudly NAMING the removal, whichever door it
-    # arrives through (kwarg or env — both funnel here)
-    assert resolve_kv_layout(None) == "paged"
-    assert resolve_kv_layout("paged") == "paged"
-    with pytest.raises(ValueError, match="REMOVED"):
-        resolve_kv_layout("dense")
-    with pytest.raises(ValueError, match="unknown kv layout"):
-        resolve_kv_layout("sparse")
-    monkeypatch.setenv("DWT_KV_LAYOUT", "dense")
-    with pytest.raises(ValueError, match="REMOVED"):
-        resolve_kv_layout(None)
-    monkeypatch.setenv("DWT_KV_LAYOUT", "paged")
-    assert resolve_kv_layout(None) == "paged"
 
 
 def test_infeasible_alloc_does_not_flush_the_cache():

@@ -430,7 +430,7 @@ def test_batching_engine_tier_end_to_end(params):
     eng = ContinuousBatchingEngine(
         get_model_config("llama-test"), params, max_seq=64, max_batch=4,
         sampling=SamplingParams(greedy=True), prompt_buckets=(16,),
-        kv_layout="paged", kv_cache_blocks=8, kv_block_tokens=4,
+        kv_cache_blocks=8, kv_block_tokens=4,
         kv_host_tier_bytes=1 << 22)
     with eng:
         tier = eng._kv_tier
