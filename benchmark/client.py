@@ -255,11 +255,16 @@ def run_plan(port: int, plan, stop_sending_at: float, give_up_at: float,
 def ask(port: int, prompt: list, max_new: int, logprobs: bool = False,
         timeout: float = 600.0) -> dict:
     """One blocking, unstreamed request (canaries, warm-up, the reference
-    check): ``{"status", "tokens", "logprobs", "error"}``."""
+    check): ``{"status", "tokens", "logprobs", "generation", "error"}``.
+    ``generation`` is what the reply says of how the one sequence was
+    generated (its entry of the reply's ``generation`` list, JSON, not
+    looked into here: the family's replay reads it), ``None`` where the
+    reply says nothing."""
     body = {"prompt_ids": [prompt], "max_new_tokens": max_new}
     if logprobs:
         body["logprobs"] = True
     status, out = http_json(port, "POST", "/generate", body, timeout)
     return {"status": status, "tokens": list((out.get("tokens") or [[]])[0]),
             "logprobs": list((out.get("logprobs") or [[]])[0]),
+            "generation": (out.get("generation") or [None])[0],
             "error": out.get("error", "")}

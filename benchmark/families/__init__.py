@@ -31,9 +31,46 @@ nothing else.  The contract:
 * optionally ``kv_bytes_per_token(mc, kv_bytes, chips)``, where a token
   does not hold full keys and values for every kv head of every layer
   (``bytes.py`` has that formula as the default).
+* optionally ``replay(mc)``, where the family does not generate one token
+  a pass, left to right (``reference.emitted_logprobs`` is that account:
+  one forward over prompt and emitted ids, row ``t - 1`` scores token
+  ``t``; a family without a ``replay`` is scored by it and any record in
+  the reply is left unread).  It returns
+  ``score(params, ids, n_prompt, generation)`` which answers
+  ``{"logprobs", "best_ids", "best_logprobs"}``, one entry an emitted
+  token (``ids[n_prompt:]``): the plain float32 account of how this family
+  produced those tokens, each scored at the pass that fixed it with the
+  sequence as it stood then.
 
-JAX and ``reference`` are imported inside ``equations`` only: the
-benchmark's parent reads the shape arithmetic and never imports JAX.
+  - ``generation`` is the record the replay needs and the ids do not
+    hold (at which pass each token was fixed, say).  The program writes
+    it: the reply to a ``POST /generate`` with ``"logprobs": true``,
+    through the gateway, carries ``"generation": [<one JSON value a
+    sequence>]`` beside ``tokens`` and ``logprobs``.  ``client.ask`` keeps
+    the first sequence's, ``run.reference_check`` sends it to the replica
+    with ``ids`` and ``n_prompt`` and keeps it in the records file; none
+    of them looks into it.  It is ``None`` where the reply had none.
+  - A replay is written with ``reference.halves(params, mc)``: ``rows(ids)``,
+    the rows ``[T, H]`` after the last layer (the family's own ``embed``
+    and ``layer``, so a mask that is not causal lives in ``layer``), and
+    ``score(x, target)``, chosen rows against chosen targets through the
+    final norm and the head; and with the helpers above.  It repeats
+    neither the layer loop nor the head, and imports no line of the
+    program.  Call ``halves`` once: passes of one length then share the
+    compiled layer function.
+  - It answers ``{"error": "..."}`` where the record is missing or breaks
+    the family's own rule (a token fixed at a pass whose schedule could
+    not have picked it): ``run.py`` fails the run with that sentence.
+  - ``tests/test_reference.py`` compares a family WITHOUT a replay with
+    the program's one causal forward.  A family with one brings
+    ``tests/test_<family>_family.py``, where its comparison with the
+    program lives (``tests/test_families.py`` holds that the file is
+    there); ``tests/test_replay.py`` shows a toy one that generates in
+    blocks, with the faults a record must be shown to catch.
+
+JAX and ``reference`` are imported inside ``equations`` and a replay's
+``score`` only: the benchmark's parent reads the shape arithmetic and
+never imports JAX.
 """
 
 from __future__ import annotations

@@ -11,7 +11,11 @@ final norm, as its paper and its HF ``modeling_*`` file give them) is the
 family's own module, ``families/<family>.py``, found by the name in the
 configuration's ``model_config.family``.  Here is what every family
 shares: the helpers their equations are written in, the layer loop and
-the head.
+the head, as two halves (``halves``: the rows after the last layer for a
+list of ids; chosen rows scored against chosen targets).
+``emitted_logprobs`` composes them for a model that generates one token a
+pass, left to right; a family that generates otherwise brings the
+composition itself, its ``replay``.
 
 One layer runs at a time (one jitted function, the layer picked by index),
 and the head runs in blocks of vocabulary rows with a running
@@ -105,49 +109,85 @@ def _make_layer_fn(layer_eq):
     return layer
 
 
-def emitted_logprobs(params, cfg: dict, ids: list, n_prompt: int) -> dict:
-    """Teacher-forced over ``ids`` (prompt then the tokens the server
-    emitted): for each emitted token the reference's log-probability of
-    it, and the reference's own best token and its log-probability.
+def halves(params, cfg: dict):
+    """The reference of one model in its two halves, ``(rows, score)``.
+    ``emitted_logprobs`` composes them left to right; a family that
+    generates otherwise composes them in its ``replay``
+    (``families/__init__.py``), once a pass.  The layer function is jitted
+    once for both: passes of one length share its compiled program.
 
     ``cfg`` is the configuration file's ``model_config`` group."""
     embed, layer_eq, final_norm = families.load(cfg["family"]).equations(cfg)
-    ids_a = jnp.asarray(ids, jnp.int32)
-    with jax.default_matmul_precision("highest"):
-        x = embed(params, ids_a)
-        layer = _make_layer_fn(layer_eq)
-        for i in range(cfg["num_layers"]):
-            x = layer(x, params.layers, jnp.int32(i))
-        x = x[n_prompt - 1: len(ids) - 1]           # rows that predict
-        x = final_norm(params, x)
-        target = ids_a[n_prompt:]
-        vocab = cfg["vocab_size"]
-        run_max = jnp.full((x.shape[0],), -jnp.inf, F32)
-        run_sum = jnp.zeros((x.shape[0],), F32)
-        best = jnp.full((x.shape[0],), -jnp.inf, F32)
-        best_id = jnp.zeros((x.shape[0],), jnp.int32)
-        picked = jnp.zeros((x.shape[0],), F32)
-        for lo in range(0, vocab, HEAD_BLOCK):
-            hi = min(vocab, lo + HEAD_BLOCK)
-            if cfg.get("tie_embeddings"):
-                w = params.embed["tokens"][lo:hi].astype(F32).T
-            else:
-                w = _f32(params.lm_head["w"])[:, lo:hi]
-            logits = x @ w                              # [G, hi - lo]
-            m = jnp.maximum(run_max, logits.max(-1))
-            run_sum = (run_sum * jnp.exp(run_max - m)
-                       + jnp.exp(logits - m[:, None]).sum(-1))
-            run_max = m
-            blk_best = logits.max(-1)
-            blk_id = logits.argmax(-1).astype(jnp.int32) + lo
-            best_id = jnp.where(blk_best > best, blk_id, best_id)
-            best = jnp.maximum(best, blk_best)
-            inside = (target >= lo) & (target < hi)
-            col = jnp.clip(target - lo, 0, hi - lo - 1)
-            picked = jnp.where(
-                inside, jnp.take_along_axis(logits, col[:, None], 1)[:, 0],
-                picked)
-        lse = run_max + jnp.log(run_sum)
-    return {"logprobs": [float(v) for v in (picked - lse)],
-            "best_ids": [int(v) for v in best_id],
-            "best_logprobs": [float(v) for v in (best - lse)]}
+    layer = _make_layer_fn(layer_eq)
+    vocab = cfg["vocab_size"]
+
+    def rows(ids):
+        """The rows ``[T, H]`` after the last layer for the ids ``[T]``,
+        positions ``0..T-1``: every one, before the final norm."""
+        with jax.default_matmul_precision("highest"):
+            x = embed(params, jnp.asarray(ids, jnp.int32))
+            for i in range(cfg["num_layers"]):
+                x = layer(x, params.layers, jnp.int32(i))
+        return x
+
+    def score(x, target):
+        """Rows ``[G, H]`` of ``rows`` against the ids ``[G]`` each is to
+        be scored on: the final norm, the head (tied or not) in blocks of
+        ``HEAD_BLOCK`` with a running log-sum-exp.  One entry a row: the
+        log-probability of its target, the best id and its
+        log-probability."""
+        target = jnp.asarray(target, jnp.int32)
+        with jax.default_matmul_precision("highest"):
+            x = final_norm(params, x)
+            run_max = jnp.full((x.shape[0],), -jnp.inf, F32)
+            run_sum = jnp.zeros((x.shape[0],), F32)
+            best = jnp.full((x.shape[0],), -jnp.inf, F32)
+            best_id = jnp.zeros((x.shape[0],), jnp.int32)
+            picked = jnp.zeros((x.shape[0],), F32)
+            for lo in range(0, vocab, HEAD_BLOCK):
+                hi = min(vocab, lo + HEAD_BLOCK)
+                if cfg.get("tie_embeddings"):
+                    w = params.embed["tokens"][lo:hi].astype(F32).T
+                else:
+                    w = _f32(params.lm_head["w"])[:, lo:hi]
+                logits = x @ w                              # [G, hi - lo]
+                m = jnp.maximum(run_max, logits.max(-1))
+                run_sum = (run_sum * jnp.exp(run_max - m)
+                           + jnp.exp(logits - m[:, None]).sum(-1))
+                run_max = m
+                blk_best = logits.max(-1)
+                blk_id = logits.argmax(-1).astype(jnp.int32) + lo
+                best_id = jnp.where(blk_best > best, blk_id, best_id)
+                best = jnp.maximum(best, blk_best)
+                inside = (target >= lo) & (target < hi)
+                col = jnp.clip(target - lo, 0, hi - lo - 1)
+                picked = jnp.where(
+                    inside,
+                    jnp.take_along_axis(logits, col[:, None], 1)[:, 0],
+                    picked)
+            lse = run_max + jnp.log(run_sum)
+        return {"logprobs": [float(v) for v in (picked - lse)],
+                "best_ids": [int(v) for v in best_id],
+                "best_logprobs": [float(v) for v in (best - lse)]}
+
+    return rows, score
+
+
+def emitted_logprobs(params, cfg: dict, ids: list, n_prompt: int,
+                     generation=None) -> dict:
+    """For each token the server emitted (``ids`` is the prompt, then
+    those tokens): the reference's log-probability of it, and the
+    reference's own best token and its log-probability.
+
+    A family with a ``replay`` scores the request itself, the way it
+    generated it, from ``generation``: what the server's reply said about
+    the request, ``None`` where it said nothing.  Every other family
+    generates one token a pass, left to right, and needs no record:
+    teacher-forced over ``ids`` in one forward, row ``t - 1`` scores
+    token ``t``."""
+    family = families.load(cfg["family"])
+    if hasattr(family, "replay"):
+        return family.replay(cfg)(params, ids, n_prompt, generation)
+    rows, score = halves(params, cfg)
+    return score(rows(ids)[n_prompt - 1: len(ids) - 1],     # rows that predict
+                 ids[n_prompt:])

@@ -15,7 +15,8 @@ program's files:
    device trace share a clock (``TRACE_STOP`` writes the ``.xplane.pb``
    and not the viewer's ``trace.json.gz``: ``_stop_trace``);
    ``REFERENCE <json>`` runs ``reference.emitted_logprobs`` on the served
-   parameters;
+   parameters (``ids``, ``n_prompt`` and, where the server's reply gave
+   one, its ``generation`` record);
 3. the one loader call ``models.loader.load_or_init`` is wrapped to keep a
    read-only handle on those parameters.
 """
@@ -132,7 +133,7 @@ def _control(model_fields: dict) -> None:
                 t0 = time.monotonic()
                 out = reference.emitted_logprobs(
                     _HELD["params"], model_fields, req["ids"],
-                    req["n_prompt"])
+                    req["n_prompt"], req.get("generation"))
                 out["seconds"] = time.monotonic() - t0
                 _say("REFERENCE_RESULT", out)
         except Exception as e:          # the thread must answer, not die

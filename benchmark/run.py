@@ -18,11 +18,11 @@ walks the same path on the CPU with the configuration's toy model; it
 prints no result line and exits 3, so a CPU number can never be taken for
 a device's.
 
-Phases: children up -> warm-up (both ``mixed_step`` variants) -> reference
-check and canaries -> ramp (the cell's traffic, unmeasured) -> window of
-``--seconds`` -> drain -> canaries again -> ``/stats``, ``/health`` -> stop
-children -> (traced run) reduce the trace -> the result line.  The wall
-seconds of each are printed in the ``[time]`` line (``Stages``).
+Phases: children up -> warm-up -> reference check and canaries -> ramp
+(the cell's traffic, unmeasured) -> window of ``--seconds`` -> drain ->
+canaries again -> ``/stats``, ``/health`` -> stop children -> (traced run)
+reduce the trace -> the result line.  The wall seconds of each are printed
+in the ``[time]`` line (``Stages``).
 """
 
 from __future__ import annotations
@@ -180,14 +180,26 @@ def canaries(port: int, vocab: int, seed: int, scale: float) -> list:
 
 def reference_check(stack: Stack, canary: dict, tolerance: float) -> dict:
     """The served log-probabilities of one canary's tokens against the
-    plain float32 reference, run in the replica on the same parameters."""
+    plain float32 reference, run in the replica on the same parameters.
+    What the reply said of how it generated them (``generation``) goes to
+    the reference as it came and stays in the record."""
+    said = ({} if canary.get("generation") is None
+            else {"generation": canary["generation"]})
     ids = canary["prompt"] + canary["tokens"]
     reply = json.loads(stack.control(
         "REFERENCE " + json.dumps({"ids": ids,
-                                   "n_prompt": len(canary["prompt"])}),
+                                   "n_prompt": len(canary["prompt"]),
+                                   **said}),
         "REFERENCE_RESULT", 600))
     if "error" in reply:
         raise BenchFailure(f"reference check failed to run: {reply}")
+    lengths = (len(canary["logprobs"]), len(reply["logprobs"]),
+               len(canary["tokens"]))
+    if len(set(lengths)) != 1:
+        raise BenchFailure(
+            "reference check: {} served log-probabilities, {} of the "
+            "reference's and {} tokens: one a token is compared".format(
+                *lengths))
     errs = [abs(a - b) for a, b in zip(canary["logprobs"],
                                        reply["logprobs"])]
     return {"max_abs_err": max(errs), "errs": errs,
@@ -195,7 +207,7 @@ def reference_check(stack: Stack, canary: dict, tolerance: float) -> dict:
             "served": canary["logprobs"], "reference": reply["logprobs"],
             "reference_best_ids": reply["best_ids"],
             "reference_best_logprobs": reply["best_logprobs"],
-            "tokens": canary["tokens"], "seconds": reply["seconds"]}
+            "tokens": canary["tokens"], "seconds": reply["seconds"], **said}
 
 
 class Marks(threading.Thread):
@@ -295,8 +307,10 @@ def run(args) -> int:
             f"{health['device_count']} model={health['model']}")
 
         # warm-up: one prompt longer than the chunk (a chunk-only dispatch,
-        # a dispatch with a final, decode-only dispatches: both variants of
-        # mixed_step) and a few short requests at once, through the gateway
+        # a dispatch with a final, decode-only dispatches) and a few short
+        # requests at once, through the gateway.  Every variant of
+        # mixed_step is compiled and launched by the engine before it is
+        # ready, whatever these requests pack
         warm = [Request(0.0, seeded_prompt(args.seed + 1, min(
             chunk + 8, max_seq - 12), vocab), 8)]
         warm += [Request(0.0, seeded_prompt(args.seed + 2 + i, 12, vocab), 6)

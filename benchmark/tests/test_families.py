@@ -165,6 +165,17 @@ def test_every_family_file_serves_a_configuration_and_every_one_has_its():
     assert used == set(FAMILIES)
 
 
+def test_a_family_with_a_replay_brings_its_own_test_file():
+    """``tests/test_reference.py`` compares with the program's one causal
+    forward and leaves out a family that generates otherwise (one with a
+    ``replay``): its comparison with the program is its own file's."""
+    for family in FAMILIES:
+        if hasattr(families.load(family), "replay"):
+            assert (BENCH / "tests" / f"test_{family}_family.py").is_file()
+    # the seven configurations PR 51 found are compared there still
+    assert len(importlib.import_module("test_reference").LEFT_TO_RIGHT) >= 7
+
+
 @pytest.mark.parametrize("name", sorted(PINS["reference"]))
 def test_reference_equals_the_values_pinned_before_the_families_moved(name):
     """``tests/data/family_pins.json`` was written by the parent's tree
