@@ -312,6 +312,7 @@ def cmd_serve(args) -> int:
 
         from .comm.transport import ZmqTransport
         from .models.base import (require_kv_pair, require_one_kind,
+                                  require_token_rows,
                                   require_single_pass,
                                   split_layer_ranges)
         from .models.registry import get_model_config
@@ -321,6 +322,7 @@ def cmd_serve(args) -> int:
         try:
             require_single_pass(cfg, "--chain (a pipeline of stages)")
             require_kv_pair(cfg, "--chain (a pipeline of stages)")
+            require_token_rows(cfg, "--chain (a pipeline of stages)")
             require_one_kind(cfg, "--chain (a pipeline of stages)")
         except ValueError as e:
             print(e, file=sys.stderr)

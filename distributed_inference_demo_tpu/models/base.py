@@ -87,7 +87,9 @@ class BlockKind:
                       "lead_intermediate_size", "num_shared_experts",
                       "router_scoring", "router_bias",
                       "routed_scaling_factor", "period", "lead_kind",
-                      "experts_held"])
+                      "experts_held", "norm_unit_offset", "fp32_residual",
+                      "fp32_logits", "eva_window", "eva_chunk",
+                      "num_pred_heads"])
 @dataclass(frozen=True)
 class ModelConfig:
     """Static, hashable architecture description shared by all model families.
@@ -190,6 +192,25 @@ class ModelConfig:
     # group; what the absent experts would add is left out (no psum, no
     # stand-in).  Empty: every expert is here
     experts_held: tuple = ()
+    # evabyte: RMSNorm's gain is ``1 + w`` (``norm_add_unit_offset``), the
+    # residual stream rides the layers in float32 (``fp32_skip_add``: the
+    # norms' outputs and every matmul keep the model's dtype) and the head
+    # runs in float32 on the float32 normed row (``fp32_logits``)
+    norm_unit_offset: bool = False
+    fp32_residual: bool = False
+    fp32_logits: bool = False
+    # evabyte (EVA attention, docs/DESIGN.md section 26): a query at
+    # position ``i`` sees the exact keys of its own window (``j <= i``,
+    # ``j // eva_window == i // eva_window``) and every EARLIER window as
+    # ``eva_window / eva_chunk`` summaries, one learned-pooled key and
+    # value a chunk of ``eva_chunk`` tokens, in one softmax.  0 = every
+    # key exact
+    eva_window: int = 0
+    eva_chunk: int = 0
+    # evabyte: the head holds ``num_pred_heads x vocab_size`` rows (head
+    # ``n`` predicts byte ``t + n``); the served path reads the first
+    # ``vocab_size``, the next-byte head
+    num_pred_heads: int = 1
 
     def __post_init__(self):
         object.__setattr__(self, "period",
@@ -296,6 +317,14 @@ class ModelConfig:
     @property
     def latent_kv(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def summary_kv(self) -> bool:
+        """A cache of two roles of row (``eva_window``): a window of
+        exact keys and values, and a summary a chunk of every window that
+        closed.  What a row attends is then a function of its position,
+        not its token count (:func:`eva_rows`)."""
+        return self.eva_window > 0
 
     @property
     def kv_streams(self) -> int:
@@ -480,6 +509,38 @@ def require_one_kind(cfg: ModelConfig, what: str) -> None:
             f"--batch-slots --prefill-chunk --mixed-token-budget)")
 
 
+def eva_rows(window: int, chunk: int, n: int) -> tuple:
+    """``(summary rows, exact rows)`` that hold ``n`` tokens under EVA
+    attention (``ModelConfig.eva_window`` / ``eva_chunk``): the summaries
+    of every closed window, ``window / chunk`` each, and the exact keys
+    of the open one.  The query at position ``t`` attends ``eva_rows(...,
+    t + 1)``: at a multiple of ``window`` its own key alone is exact.
+    Host arithmetic (the scheduler's reservations and records), the same
+    function of ``t`` the device builds its tables from."""
+    if n <= 0:
+        return 0, 0
+    closed = (n - 1) // window
+    return closed * (window // chunk), n - closed * window
+
+
+def require_token_rows(cfg: ModelConfig, what: str) -> None:
+    """Refuse a model whose cache is a window of exact rows plus a
+    summary a chunk (``eva_window > 0``) where ``what`` is built for a
+    row a token that stays where it was written: called where such a
+    thing is built, so the model is refused in a sentence and never run
+    wrongly."""
+    if cfg.summary_kv:
+        raise ValueError(
+            f"{what} does not support a model with a summarised cache "
+            f"(family {cfg.family!r}, eva_window={cfg.eva_window}, "
+            f"eva_chunk={cfg.eva_chunk}): a window's pages are written "
+            f"again by the next window and every closed window is "
+            f"{cfg.eva_window // max(1, cfg.eva_chunk)} summary rows, and "
+            f"it is built for a row a token. Serve it on one chip with "
+            f"bf16 pages through the mixed dispatch (serve --batch-slots "
+            f"--prefill-chunk --mixed-token-budget)")
+
+
 def require_single_pass(cfg: ModelConfig, what: str) -> None:
     """Refuse a looped model (``ut_steps > 1``) where ``what`` visits a
     layer once: a stage of a pipeline owns a layer range and would have
@@ -506,6 +567,7 @@ def slice_stage(full: StageParams, cfg: ModelConfig, spec: StageSpec) -> StagePa
         require_one_kind(cfg, "a pipeline of stages")
         require_single_pass(cfg, "a pipeline of stages")
         require_kv_pair(cfg, "a pipeline of stages")
+        require_token_rows(cfg, "a pipeline of stages")
     layers = jax.tree.map(lambda x: x[spec.layer_start:spec.layer_end], full.layers)
     # Tied embeddings: the last stage needs the token table for the LM head.
     needs_embed = spec.is_first or (spec.is_last and cfg.tie_embeddings)

@@ -13,6 +13,12 @@ inferred at SURVEY.md §2.2), a single pure ``stage_forward`` covers:
   output (``sandwich_norm``), the whole stack run ``ut_steps`` times a
   token with the final norm after every pass and K/V planes of each pass's
   own.
+- **evabyte family** (EvaByte-6.5B): llama blocks whose RMSNorm gain is
+  ``1 + w``, a float32 residual stream and float32 logits, and EVA
+  attention: the exact keys of the query's own window and a learned-pooled
+  summary a chunk of every earlier window, in one softmax
+  (``ops.eva_attention``); the head holds ``num_pred_heads`` heads and the
+  served path reads the first.
 - **deepseek_v3 family** (kanana-2-30b-a3b): multi-head latent attention
   (a token's cache is one latent row a layer, read in absorbed form:
   ``ops.latent_attention``), leading dense blocks before the repeated
@@ -36,11 +42,13 @@ from ..ops.grouped_matmul import grouped_matmul
 from ..ops.quant import dense
 from ..ops.stacked import LayerOf
 from ..ops.norms import layer_norm, rms_norm
+from ..ops.eva_attention import eva_dense_attn
 from ..ops.latent_attention import latent_dense_attn
 from ..ops.rope import (apply_rope, apply_rope_interleaved,
                         apply_rope_kind)
 from .base import (KVCache, ModelConfig, StageParams, StageSpec,
-                   require_one_kind, require_single_pass)
+                   require_one_kind, require_single_pass,
+                   require_token_rows)
 
 
 # ---------------------------------------------------------------------------
@@ -151,6 +159,20 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
     if kind is not None and kind.gate == "per-head":
         # one scalar a head from the block's normed input (``_kv_attention``)
         p["wg"] = _dense_init(keys[13], (L, H, nh), dt)
+    if cfg.norm_unit_offset:
+        # the stored weight is the gain's OFFSET (the gain is 1 + w).
+        # Seeded at N(0, 0.1) and not at the published zero, so that a
+        # norm that forgot the offset cannot pass for one that has it
+        p["attn_norm_w"] = _dense_init(keys[14], (L, H), dt, scale=0.1)
+        p["mlp_norm_w"] = _dense_init(keys[15], (L, H), dt, scale=0.1)
+    if cfg.summary_kv:
+        # EVA's two learned pooling vectors a kv head, as published:
+        # clip(N(0, 1), +-1) x head_dim ** -0.5
+        k_mu, k_phi = jax.random.split(jax.random.fold_in(rng, 17))
+        vec = lambda k: (jnp.clip(jax.random.normal(
+            k, (L, nkv, hd), jnp.float32), -1.0, 1.0)
+            * hd ** -0.5).astype(dt)
+        p["adaptive_mu_k"], p["adaptive_phi"] = vec(k_mu), vec(k_phi)
     if cfg.attn_layernorm:  # bloom: LayerNorm has bias; linears have bias
         p["attn_norm_b"] = jnp.zeros((L, H), dt)
         p["mlp_norm_b"] = jnp.zeros((L, H), dt)
@@ -262,12 +284,18 @@ def init_full_params(rng: jax.Array, cfg: ModelConfig,
         embed["norm_w"] = jnp.ones((cfg.hidden_size,), dt)
         embed["norm_b"] = jnp.zeros((cfg.hidden_size,), dt)
     final_norm = {"w": jnp.ones((cfg.hidden_size,), dt)}
+    if cfg.norm_unit_offset:    # the gain's offset (``init_layer_params``)
+        final_norm["w"] = _dense_init(jax.random.fold_in(k_head, 1),
+                                      (cfg.hidden_size,), dt, scale=0.1)
     if cfg.attn_layernorm:
         final_norm["b"] = jnp.zeros((cfg.hidden_size,), dt)
     if cfg.tie_embeddings:
         lm_head = {}  # reuse embed["tokens"]
     else:
-        lm_head = {"w": _dense_init(k_head, (cfg.hidden_size, cfg.vocab_size), dt)}
+        # ``num_pred_heads`` heads side by side, the next token's first
+        lm_head = {"w": _dense_init(
+            k_head, (cfg.hidden_size,
+                     cfg.num_pred_heads * cfg.vocab_size), dt)}
     if cfg.period:
         layers = init_period_params(k_layers, cfg, quantize=quantize)
     else:
@@ -645,6 +673,21 @@ def _kv_attention(cfg: ModelConfig, lp: dict, h: jnp.ndarray, k_cache,
         k = apply_rope(k, positions, cfg.rope_theta)
 
     attn_fn = attn_impl if attn_impl is not None else _default_attn
+    if cfg.summary_kv:
+        # EVA attention: this layer's pooling vectors go to the hook, a
+        # page pool's (``attn_impl.summarised``) or the dense cache's
+        if attn_impl is None:
+            summarised = eva_dense_attn
+        elif hasattr(attn_impl, "summarised"):
+            summarised = attn_impl.summarised
+        else:
+            raise ValueError(
+                "a model with a summarised cache (eva_window) needs an "
+                "attention hook that knows its two roles of row (ops."
+                "paged_attention.make_paged_attn_impl, or the dense "
+                "cache); this one serves a row a token")
+        attn_fn = summarised(cfg.eva_window, cfg.eva_chunk,
+                             lp["adaptive_mu_k"], lp["adaptive_phi"])
     attn, k_cache, v_cache = attn_fn(
         q, k, v, k_cache, v_cache, positions, cache_start, slopes)
     if kind is not None and kind.gate == "per-head":
@@ -732,8 +775,9 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     if cfg.attn_layernorm:
         h = layer_norm(x, lp["attn_norm_w"], lp["attn_norm_b"], cfg.norm_eps)
     else:
-        h = rms_norm(x, lp["attn_norm_w"], cfg.norm_eps)
-    if x.dtype != cfg.dtype:  # a looped model's float32 stream
+        h = rms_norm(x, lp["attn_norm_w"], cfg.norm_eps,
+                     cfg.norm_unit_offset)
+    if x.dtype != cfg.dtype:  # a float32 stream (looped, fp32_residual)
         h = h.astype(cfg.dtype)
 
     if cfg.latent_kv:  # the cache is ``k_cache`` alone; ``v_cache`` is empty
@@ -755,7 +799,8 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     if cfg.attn_layernorm:
         h = layer_norm(x, lp["mlp_norm_w"], lp["mlp_norm_b"], cfg.norm_eps)
     else:
-        h = rms_norm(x, lp["mlp_norm_w"], cfg.norm_eps)
+        h = rms_norm(x, lp["mlp_norm_w"], cfg.norm_eps,
+                     cfg.norm_unit_offset)
     if x.dtype != cfg.dtype:
         h = h.astype(cfg.dtype)
     if moe_stats:
@@ -945,7 +990,8 @@ def stage_forward(
         if cfg.attn_layernorm:
             return layer_norm(x, params.final_norm["w"],
                               params.final_norm["b"], cfg.norm_eps)
-        return rms_norm(x, params.final_norm["w"], cfg.norm_eps)
+        return rms_norm(x, params.final_norm["w"], cfg.norm_eps,
+                        cfg.norm_unit_offset)
 
     # a looped model (ouro): the layer scan below is the body of an outer
     # scan over ``ut_steps`` passes, so the program holds ONE layer body
@@ -1006,7 +1052,12 @@ def stage_forward(
                 f"(ModelConfig.kv_planes), or {n_layers} as a scratch for "
                 f"one call over a whole sequence; got {planes}")
     own_planes = planes == T * n_layers
-    if T > 1:
+    if cfg.summary_kv and (tp_axis is not None or ep_axis is not None
+                           or not cache_in_carry or not spec.is_first
+                           or not spec.is_last):
+        require_token_rows(cfg, "a mesh axis, a stage of a pipeline or "
+                                "the training layout of the cache")
+    if T > 1 or cfg.fp32_residual:
         # The stream rides the layer and pass scans in float32.  Every
         # matmul still takes the model's dtype (``_layer`` casts each
         # norm's output back) and the sublayers' outputs are added as
@@ -1124,10 +1175,17 @@ def stage_forward(
                 row, i, 1, axis=0))(x, at)                 # [b, 1, H]
         if T == 1:  # a looped model's last pass closed with it already
             x = final_norm(x)
-        x = x.astype(cfg.dtype)
         head = (params.embed["tokens"].T if cfg.tie_embeddings
                 else params.lm_head["w"])
-        x = jnp.einsum("bsh,hv->bsv", x, head)
+        if cfg.num_pred_heads > 1:  # the next token's head, the first
+            head = head[:, :cfg.vocab_size]
+        if cfg.fp32_logits:
+            # the float32 normed row times the head in float32
+            x = jnp.einsum("bsh,hv->bsv", x.astype(jnp.float32),
+                           head.astype(jnp.float32),
+                           precision=jax.lax.Precision.HIGHEST)
+        else:
+            x = jnp.einsum("bsh,hv->bsv", x.astype(cfg.dtype), head)
         if tp_axis is not None and x.shape[-1] != cfg.vocab_size:
             # vocab-parallel head: gather the logit shards so every rank
             # sees full logits at the sampling boundary.  Skipped when the

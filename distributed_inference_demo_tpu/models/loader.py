@@ -321,6 +321,15 @@ def params_from_state_dict(raw: Dict[str, np.ndarray],
             "loads wrong weights without a word; serve it on seeded "
             "weights, or add the map to models/loader.py from the "
             "checkpoint's own index")
+    if cfg.family == "evabyte":
+        raise NotImplementedError(
+            "no state-dict mapper for family 'evabyte': no checkpoint's "
+            "index was in the repository when the family was added, so "
+            "the tensor names (adaptive_mu_k, adaptive_phi, the eight "
+            "prediction heads' rows) could not be checked, and a guessed "
+            "name map loads wrong weights without a word; serve it on "
+            "seeded weights, or add the map to models/loader.py from the "
+            "checkpoint's own index")
     if cfg.family not in _SD_MAPPERS:
         raise NotImplementedError(f"no state-dict mapper for {cfg.family!r}")
     return _SD_MAPPERS[cfg.family](raw, cfg)

@@ -114,6 +114,20 @@ MODEL_REGISTRY = {
         v_head_dim=128, lead_dense_layers=1, lead_intermediate_size=6144,
         num_shared_experts=2, router_scoring="sigmoid", router_bias=True,
         routed_scaling_factor=2.448),
+    # --- evabyte (EvaByte/EvaByte config.json, ``model_type: evabyte``;
+    # EVA attention, arXiv:2302.04542): a byte-level decoder, MHA of 32
+    # heads of 128, SwiGLU, RMSNorm with gain 1 + w, a float32 residual
+    # stream and float32 logits.  A query sees the exact keys of its own
+    # 2,048-token window and every earlier window as 128 summaries, one
+    # learned-pooled key and value a 16-token chunk, in one softmax; the
+    # head holds 8 x 320 rows (byte t + 1 .. t + 8) and the served path
+    # reads the first 320 ---
+    "evabyte-6.5b": ModelConfig(
+        family="evabyte", vocab_size=320, hidden_size=4096, num_layers=32,
+        num_heads=32, num_kv_heads=32, intermediate_size=11008,
+        max_seq_len=32768, rope_theta=100000.0, norm_eps=1e-5,
+        norm_unit_offset=True, fp32_residual=True, fp32_logits=True,
+        eva_window=2048, eva_chunk=16, num_pred_heads=8),
     # --- tiny configs for tests and virtual-mesh dry runs ---
     "llama-test": ModelConfig(
         family="llama", vocab_size=256, hidden_size=64, num_layers=4,
@@ -158,6 +172,15 @@ MODEL_REGISTRY = {
         lead_intermediate_size=96, num_shared_experts=1,
         router_scoring="sigmoid", router_bias=True,
         routed_scaling_factor=2.448, dtype_name="float32"),
+    # window 16, chunk 2: with pages of 8 a summary page is one window
+    # (8 chunks) and a window is 2 pages; 3 prediction heads
+    "evabyte-test": ModelConfig(
+        family="evabyte", vocab_size=64, hidden_size=64, num_layers=3,
+        num_heads=4, num_kv_heads=4, intermediate_size=128,
+        max_seq_len=128, rope_theta=100000.0, norm_eps=1e-5,
+        norm_unit_offset=True, fp32_residual=True, fp32_logits=True,
+        eva_window=16, eva_chunk=2, num_pred_heads=3,
+        dtype_name="float32"),
     # a period of unlike blocks: one leading dense block (full attention),
     # then 2 repeats of (window, window, window, full); 6 / 4 query heads
     # over 2 kv heads, window 8, YaRN on half a head of the full kind, a

@@ -9,12 +9,16 @@ fp32 math anyway and this avoids bf16 variance underflow.
 import jax.numpy as jnp
 
 
-def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
+def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-5,
+             unit_offset: bool = False) -> jnp.ndarray:
+    """``unit_offset``: the gain is ``1 + weight`` (evabyte's
+    ``norm_add_unit_offset``), added in float32."""
     dtype = x.dtype
     x = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     x = x * jnp.reciprocal(jnp.sqrt(var + eps))
-    return (x * weight.astype(jnp.float32)).astype(dtype)
+    gain = weight.astype(jnp.float32)
+    return (x * (1.0 + gain if unit_offset else gain)).astype(dtype)
 
 
 def layer_norm(x: jnp.ndarray, weight: jnp.ndarray, bias: jnp.ndarray,

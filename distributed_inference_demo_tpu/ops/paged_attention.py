@@ -644,6 +644,20 @@ def _paged_call_window(q_g, k_pages, v_pages, layer, tables, kv_lens,
                             window=window)
 
 
+# a model with a summarised cache (``ops.eva_attention``): the SAME two
+# programs over the table of summary pages then window pages that its
+# hook builds, under names of their own so that a trace reader can tell
+# its calls from another model's
+@functools.partial(jax.jit,
+                   static_argnames=("block_tokens", "use_alibi",
+                                    "interpret"))
+def _paged_call_eva(q_g, k_pages, v_pages, layer, tables, kv_lens, slopes,
+                    *, block_tokens, use_alibi, interpret):
+    return _paged_call_body(q_g, k_pages, v_pages, layer, tables, kv_lens,
+                            slopes, block_tokens=block_tokens,
+                            use_alibi=use_alibi, interpret=interpret)
+
+
 def paged_flash_attention(
     q: jnp.ndarray,          # [batch, 1, nh, hd] — decode chunk only
     k_pages,                 # [num_pages, nkv, block_tokens, hd] or LayerOf
@@ -654,6 +668,7 @@ def paged_flash_attention(
     *,
     interpret: bool = False,
     window: int = 0,
+    eva: bool = False,
 ) -> jnp.ndarray:
     """Pallas paged decode attention; numerics match
     :func:`paged_gather_attention` (f32 online softmax, same masking).
@@ -687,7 +702,7 @@ def paged_flash_attention(
         # width in it
         return paged_prefill_attention(
             q, k_pages, v_pages, tables, (kv_lens - 1)[:, None], slopes,
-            interpret=interpret, window=window)
+            interpret=interpret, window=window, eva=eva)
     g = nh // nkv
     rows = max(8, -(-g // 8) * 8)    # pad group rows to the sublane granule
 
@@ -717,7 +732,7 @@ def paged_flash_attention(
     else:
         kv_lens = jnp.where(tables[:, 0] >= num_pages, 0,
                             kv_lens.astype(jnp.int32))
-        call = _paged_call
+        call = _paged_call_eva if eva else _paged_call
 
     out = call(q_g, K, V, li.reshape(1), tables, kv_lens, slopes_g,
                block_tokens=bt, use_alibi=slopes is not None,
@@ -1161,6 +1176,19 @@ def _paged_prefill_call_window(q_g, k_pages, v_pages, layer, tables, starts,
         use_alibi=use_alibi, interpret=interpret, window=window)
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("block_tokens", "chunk", "groups",
+                                    "use_alibi", "interpret"))
+def _paged_prefill_call_eva(q_g, k_pages, v_pages, layer, tables, starts,
+                            slopes, *, block_tokens, chunk, groups,
+                            use_alibi, interpret):
+    """:func:`_paged_prefill_call` under a summarised cache's name."""
+    return _paged_prefill_call_body(
+        q_g, k_pages, v_pages, layer, tables, starts, slopes,
+        block_tokens=block_tokens, chunk=chunk, groups=groups,
+        use_alibi=use_alibi, interpret=interpret)
+
+
 # one kernel invocation's query rows = chunk * group; past this the
 # f32 VMEM accumulators (rows x hd + 2 x rows x 128) crowd the page
 # stream — larger chunks take the gather path
@@ -1201,6 +1229,7 @@ def paged_prefill_attention(
     *,
     interpret: bool = False,
     window: int = 0,
+    eva: bool = False,
 ) -> jnp.ndarray:
     """Pallas paged PREFILL attention: each row's chunk of queries
     attends causally over its own prior pages plus the in-chunk keys
@@ -1230,7 +1259,8 @@ def paged_prefill_attention(
             f"prefill kernel rows {q_g.shape[2]} (chunk {chunk} x group "
             f"{g}) exceed {PREFILL_KERNEL_MAX_ROWS}; use the gather path")
     call = (functools.partial(_paged_prefill_call_window, window=window)
-            if window else _paged_prefill_call)
+            if window else
+            _paged_prefill_call_eva if eva else _paged_prefill_call)
     out = call(q_g, K, V, li.reshape(1), tables.astype(jnp.int32),
                q_positions[:, 0].astype(jnp.int32), slopes_g,
                block_tokens=bt, chunk=chunk, groups=g,
@@ -1390,14 +1420,15 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
         bound["program"] = program
 
     def attend(q, k, v, k_pages, v_pages, positions, slopes, tables,
-               program, window=0, split=False):
+               program, window=0, split=False, eva=False):
         """Write the chunk, then attend: one traced attention call over
         ``tables``.  ``window`` > 0 (a window kind of block) bounds what a
         query sees.  ``split`` (the kinds of a period model, whose context
         no gathered view could hold): a chunk of more query rows than the
         prefill kernel holds is cut into sub-chunks, a row of the call
         each (the keys are written before any of them attends, so a
-        sub-chunk is a chunk that starts later)."""
+        sub-chunk is a chunk that starts later).  ``eva``: the kernels'
+        calls under a summarised cache's names."""
         assert isinstance(k_pages, LayerOf), "the pool comes stacked"
         chunk = q.shape[1]
         groups = q.shape[2] // k.shape[2]
@@ -1410,7 +1441,8 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
         if record is not None:
             record.note(program, chunk, path, why, pool)
         whole_k, whole_v = k_pages, v_pages
-        kw = {"window": window} if window else {}
+        gather_kw = {"window": window} if window else {}
+        kw = dict(gather_kw, eva=True) if eva else gather_kw
         # metadata only: a profiler capture keeps the scope with each op
         with jax.named_scope("paged_attention"):
             if pool == POOL_PLANE:
@@ -1439,7 +1471,8 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
                         interpret=interpret, **kw)
             else:
                 out = paged_gather_attention(q, k_pages, v_pages, tables,
-                                             positions, slopes, **kw)
+                                             positions, slopes,
+                                             **gather_kw)
             if pool == POOL_PLANE:
                 k_pages = LayerOf(whole_k.updated(k_pages), whole_k.layer)
                 v_pages = LayerOf(whole_v.updated(v_pages), whole_v.layer)
@@ -1468,6 +1501,28 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
         kind_impl.stacked_cache = True
         return kind_impl
 
+    def summarised(window: int, chunk: int, mu, phi):
+        """The hook of a block with a SUMMARISED cache (EVA attention,
+        ``ops.eva_attention``; docs/DESIGN.md section 26): the bound
+        tables hold a row's summary pages then its window pages, and the
+        hook builds from them and the chunk's positions the table the
+        kernels walk, writes the chunk, attends, and pools what chunks
+        the call completed into the pending summary page.  ``mu`` /
+        ``phi``: this layer's learned pooling vectors, a kv head."""
+        from .eva_attention import paged_eva_attention
+
+        def eva_impl(q, k, v, k_pages, v_pages, positions, cache_start,
+                     slopes):
+            with jax.named_scope("attn_eva"):
+                return paged_eva_attention(
+                    attend, q, k, v, k_pages, v_pages, positions,
+                    bound["tables"], bound["program"], window, chunk, mu,
+                    phi, backend=backend, interpret=interpret)
+
+        eva_impl.stacked_cache = True
+        return eva_impl
+
     impl.for_pool = for_pool
+    impl.summarised = summarised
     impl.stacked_cache = True
     return impl, bind

@@ -29,6 +29,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.base import (KVCache, ModelConfig, StageParams, StageSpec,
                            require_kv_pair, require_one_kind,
+                           require_token_rows,
                            require_single_pass)
 from .sharding import stage_param_spec_tree
 
@@ -200,6 +201,7 @@ def make_pipeline_generate_fn(cfg: ModelConfig, mesh: Mesh, *,
     """
     require_single_pass(cfg, "the circular pipeline")
     require_kv_pair(cfg, "the circular pipeline")
+    require_token_rows(cfg, "the circular pipeline")
     require_one_kind(cfg, "the circular pipeline")
     from ..models.decoder import stage_forward
     from ..ops.sampling import SamplingParams, sample_logits
@@ -368,6 +370,7 @@ def make_pipeline_train_step(cfg: ModelConfig, mesh: Mesh, optimizer,
     """
     require_single_pass(cfg, "the circular pipeline")
     require_kv_pair(cfg, "the circular pipeline")
+    require_token_rows(cfg, "the circular pipeline")
     require_one_kind(cfg, "the circular pipeline")
     use_tp = mesh.shape.get("tp", 1) > 1
     use_dp = mesh.shape.get("dp", 1) > 1

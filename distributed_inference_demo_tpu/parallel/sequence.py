@@ -26,6 +26,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.base import (KVCache, ModelConfig, StageSpec,
                            require_kv_pair, require_one_kind,
+                           require_token_rows,
                            require_single_pass)
 from ..models.decoder import stage_forward
 from ..ops.attention import update_kv_cache
@@ -164,6 +165,7 @@ def _make_ring_cores(cfg: ModelConfig, spec: StageSpec, s_loc: int,
     (the fused path closes over it; the stream path cannot)."""
     require_single_pass(cfg, "ring sequence parallelism")
     require_kv_pair(cfg, "ring sequence parallelism")
+    require_token_rows(cfg, "ring sequence parallelism")
     require_one_kind(cfg, "ring sequence parallelism")
     cache_dtype = kv_dtype if kv_dtype is not None else cfg.dtype
 

@@ -12,7 +12,8 @@ import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.base import (KVCache, ModelConfig, StageParams, StageSpec,
-                           require_kv_pair, require_one_kind)
+                           require_kv_pair, require_one_kind,
+                           require_token_rows)
 from ..models.decoder import stage_forward
 from .sharding import stage_param_spec_tree
 
@@ -53,6 +54,7 @@ def validate_tp(cfg: ModelConfig, mesh: Mesh) -> int:
     tp = mesh.shape.get("tp", 1)
     if tp > 1:
         require_kv_pair(cfg, "tensor parallelism (--tp)")
+        require_token_rows(cfg, "tensor parallelism (--tp)")
         require_one_kind(cfg, "tensor parallelism (--tp)")
     if tp > 1 and cfg.num_kv_heads % tp:
         raise ValueError(

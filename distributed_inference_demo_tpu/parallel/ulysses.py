@@ -31,6 +31,7 @@ from jax.sharding import Mesh
 
 from ..models.base import (KVCache, ModelConfig, StageSpec,
                            require_kv_pair, require_one_kind,
+                           require_token_rows,
                            require_single_pass)
 from ..models.decoder import stage_forward
 from ..ops.attention import attention, update_kv_cache
@@ -87,6 +88,7 @@ def _make_ulysses_cores(cfg: ModelConfig, max_seq: int, sp: int,
     ``(keys, values, length, tok)`` with the cache head-sharded."""
     require_single_pass(cfg, "Ulysses sequence parallelism")
     require_kv_pair(cfg, "Ulysses sequence parallelism")
+    require_token_rows(cfg, "Ulysses sequence parallelism")
     require_one_kind(cfg, "Ulysses sequence parallelism")
     cache_dtype = kv_dtype if kv_dtype is not None else cfg.dtype
     spec = StageSpec(0, 1, 0, cfg.num_layers)

@@ -74,9 +74,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.base import (KVCache, ModelConfig, StageParams,
-                           StageSpec, pad_cache_capacity,
+                           StageSpec, eva_rows, pad_cache_capacity,
                            require_kv_pair, require_one_kind,
+                           require_token_rows,
                            require_single_pass)
+from ..ops.eva_attention import eva_positions
 from ..ops.latent_attention import latent_tile_tokens
 from ..ops.paged_attention import prefill_pages_walked, sub_chunk
 from ..ops.sampling import SamplingParams, filtered_logits, sample_logits
@@ -85,7 +87,8 @@ from ..telemetry import profiling as _profiling
 from ..telemetry.anomaly import AnomalyMonitor
 from ..telemetry.flightrecorder import get_flight_recorder
 from ..telemetry.slo import get_slo_ledger, sanitize_tenant
-from ..telemetry.tracing import (LATENT_DISPATCH_FIELDS,
+from ..telemetry.tracing import (EVA_DISPATCH_FIELDS,
+                                 LATENT_DISPATCH_FIELDS,
                                  LOOP_DISPATCH_FIELDS,
                                  MOE_DISPATCH_FIELDS,
                                  WINDOW_DISPATCH_FIELDS, DispatchTrace,
@@ -398,10 +401,30 @@ class ContinuousBatchingEngine:
                                       "prompt lookup)")
             if mesh is not None and mesh.shape.get("tp", 1) > 1:
                 require_one_kind(cfg, "tensor parallelism (--tp)")
+        if cfg.summary_kv:
+            # a window of exact rows and a summary a chunk in one pool
+            # (docs/DESIGN.md section 26): what a row attends is a
+            # function of its position that the mixed dispatch's programs
+            # build on the device.  What is built for a row a token
+            # refuses it
+            if self.mixed_token_budget == 0:
+                require_token_rows(cfg, "the serialized interleave (no "
+                                        "--mixed-token-budget)")
+            if prompt_lookup or draft_cfg is not None:
+                require_token_rows(cfg, "speculation (a draft model or "
+                                        "prompt lookup)")
+            if (cfg.eva_window % self.prefill_chunk
+                    or self.prefill_chunk % cfg.eva_chunk):
+                raise ValueError(
+                    f"--prefill-chunk {self.prefill_chunk} must divide the "
+                    f"window ({cfg.eva_window}) and hold whole pooling "
+                    f"chunks of {cfg.eva_chunk}: a chunk then lies in one "
+                    f"window and completes every chunk it holds")
         if draft_cfg is not None:
             require_one_kind(draft_cfg, "the draft side of speculation")
             require_single_pass(draft_cfg, "the draft side of speculation")
             require_kv_pair(draft_cfg, "the draft side of speculation")
+            require_token_rows(draft_cfg, "the draft side of speculation")
             if draft_cfg.vocab_size != cfg.vocab_size:
                 raise ValueError(
                     f"draft vocab ({draft_cfg.vocab_size}) != target vocab "
@@ -419,6 +442,7 @@ class ContinuousBatchingEngine:
         self.kv_dtype = resolve_kv_dtype(kv_dtype)
         if self.kv_dtype != "bf16":
             require_kv_pair(cfg, f"a page pool of {self.kv_dtype} pages")
+            require_token_rows(cfg, f"a page pool of {self.kv_dtype} pages")
             require_one_kind(cfg, f"a page pool of {self.kv_dtype} pages")
         if self.kv_dtype != "bf16" and self.kv_cache_dtype is not None:
             raise ValueError(
@@ -485,6 +509,25 @@ class ContinuousBatchingEngine:
         from .kvcache.device import write_row_to_pages
         bt = block_tokens
         self._table_width = S // bt
+        # a summarised cache: a request leases one summary page a window
+        # and one window's pages, so a row of the table is
+        # ``[S_0 .. S_{Ws-1} | P_0 .. P_{Pn-1}]`` and the device builds
+        # the attended table from it at every call (ops.eva_attention)
+        self._eva = None
+        if cfg.summary_kv:
+            if cfg.eva_window // cfg.eva_chunk != bt:
+                raise ValueError(
+                    f"a summary page is one closed window: "
+                    f"--kv-block-tokens must be eva_window / eva_chunk = "
+                    f"{cfg.eva_window // cfg.eva_chunk}, got {bt}")
+            self._eva = types.SimpleNamespace(
+                window=cfg.eva_window, chunk=cfg.eva_chunk,
+                summary_pages=-(-S // cfg.eva_window),
+                window_pages=cfg.eva_window // bt)
+            self._table_width = (self._eva.summary_pages
+                                 + self._eva.window_pages)
+            self.eva_stats = {"windows_closed": 0, "summaries_written": 0,
+                              "rows_held_peak": 0, "tokens_held_peak": 0}
         n_blocks = (n_blocks_arg if n_blocks_arg >= 1
                     else B * self._table_width)
         # the cache spec by kind of block: one POOL for the blocks that
@@ -565,6 +608,7 @@ class ContinuousBatchingEngine:
         self._kv_tier = None
         if tier_host > 0:
             require_kv_pair(cfg, "the host tier of the KV cache")
+            require_token_rows(cfg, "the host tier of the KV cache")
             require_one_kind(cfg, "the host tier of the KV cache")
             self._kv_tier = TieredKVStore(
                 tier_host, bt, disk_path=tier_path,
@@ -1385,7 +1429,8 @@ class ContinuousBatchingEngine:
             (MOE_DISPATCH_FIELDS if moe else ())
             + (LOOP_DISPATCH_FIELDS if loop else ())
             + (LATENT_DISPATCH_FIELDS if latent else ())
-            + (WINDOW_DISPATCH_FIELDS if self._wmgr is not None else ()))
+            + (WINDOW_DISPATCH_FIELDS if self._wmgr is not None else ())
+            + (EVA_DISPATCH_FIELDS if self._eva is not None else ()))
 
         # (mixed mode never dispatches the serialized step programs: it
         # launches every variant of mixed_step instead, below)
@@ -1515,8 +1560,8 @@ class ContinuousBatchingEngine:
         # table (incl. the speculative modes' fused-overshoot slack)
         # can never be allocated would wait in pending forever
         bt = self.kv_cache.block_tokens
-        need = -(-(len(prompt) + max_new_tokens
-                   + self._slack_tokens) // bt)
+        need = sum(self._pages_needed(len(prompt) + max_new_tokens
+                                      + self._slack_tokens))
         pool_bound = self.kv_cache.num_blocks
         if self._dmgr is not None:
             # the draft pool cannot evict (no tree), so it binds too
@@ -1607,6 +1652,7 @@ class ContinuousBatchingEngine:
         if k_blocks is None:
             return self.submit(prompt_ids, max_new_tokens)
         require_kv_pair(self.cfg, "a premigrated prefill (disaggregation)")
+        require_token_rows(self.cfg, "a premigrated prefill (disaggregation)")
         require_one_kind(self.cfg, "a premigrated prefill (disaggregation)")
         prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
         from ..ops.quant import QuantizedKVPages
@@ -1784,6 +1830,7 @@ class ContinuousBatchingEngine:
         history — the importer rebuilds proposer state from
         prompt+tokens, which is cheap and exact (docs/DESIGN.md §22)."""
         require_kv_pair(self.cfg, "export_request (migration)")
+        require_token_rows(self.cfg, "export_request (migration)")
         require_one_kind(self.cfg, "export_request (migration)")
         req = rid if isinstance(rid, Request) else self._by_rid.get(rid)
         if req is None:
@@ -1927,6 +1974,7 @@ class ContinuousBatchingEngine:
         h2d.  Restoring the rng key makes a single-request resume
         sample-exact; greedy streams are bit-identical regardless."""
         require_kv_pair(self.cfg, "import_request (migration)")
+        require_token_rows(self.cfg, "import_request (migration)")
         require_one_kind(self.cfg, "import_request (migration)")
         rid = request_id if request_id is not None else ckpt.get("rid")
         if not ckpt.get("tokens") or int(ckpt.get("length") or 0) <= 0:
@@ -2232,6 +2280,14 @@ class ContinuousBatchingEngine:
                         "pages_unwindowed_peak":
                             ws["pages_unwindowed_peak"],
                         "quota_pages": self._window_quota}}
+            if self._eva is not None:
+                # the two roles of row in the one pool: what closed and
+                # what was pooled, and at the pool's fullest the rows it
+                # held for the running requests beside their tokens
+                out["kvcache"]["eva"] = dict(
+                    self.eva_stats, window=self._eva.window,
+                    chunk=self._eva.chunk,
+                    window_pages=self._eva.window_pages)
         # dispatch-floor picture (§13): dispatches vs device steps —
         # steps/dispatches ≈ decode_block when fusion is engaging
         out["device_loop"] = dict(self.loop_stats,
@@ -2434,7 +2490,9 @@ class ContinuousBatchingEngine:
         mgr = self.kv_cache
         bt = mgr.block_tokens
         plen = len(req.prompt)
-        n_total = -(-(plen + req.max_new + self._slack_tokens) // bt)
+        n_total, n_summary = self._pages_needed(
+            plen + req.max_new + self._slack_tokens)
+        n_total += n_summary
         # retry gate for a previously blocked admission: only re-attempt
         # once the pool could have changed (a completion frees at least
         # one private page — n_total strictly exceeds the adoptable
@@ -2460,8 +2518,11 @@ class ContinuousBatchingEngine:
                 mgr, self._kv_tier, self._pk, self._pv, req.prompt,
                 profiler=self._prof)
         # prefix sharing is OFF for a model with a window kind: a shared
-        # prefix's window pages are gone by the time it could be hit
-        lease = mgr.match(req.prompt) if self._wmgr is None else None
+        # prefix's window pages are gone by the time it could be hit; and
+        # for a summarised cache, whose window pages the next window
+        # writes over
+        lease = (mgr.match(req.prompt)
+                 if self._wmgr is None and self._eva is None else None)
         m = lease.tokens if lease is not None else 0
         n_pref = m // bt
         if (self._wmgr is not None and self._window_reserved
@@ -2493,7 +2554,14 @@ class ContinuousBatchingEngine:
                         np.int32)
         if lease is not None:
             table[:n_pref] = lease.block_ids
-        table[n_pref:n_total] = private
+        if self._eva is not None:
+            # a summary page a window, the pending one included, then the
+            # window's pages, behind the summary columns
+            table[:n_summary] = private[:n_summary]
+            at = self._eva.summary_pages
+            table[at:at + n_total - n_summary] = private[n_summary:]
+        else:
+            table[n_pref:n_total] = private
         if self._wmgr is not None:
             self._window_reserved += self._window_quota
         dtable = None
@@ -2513,6 +2581,17 @@ class ContinuousBatchingEngine:
         # retry re-runs match and must not double-count)
         self._sketch.record_prefix(m, plen)
         return m
+
+    def _pages_needed(self, n: int) -> tuple:
+        """``(pages of tokens, summary pages)`` a request of ``n`` tokens
+        leases at admission: a page a block of tokens; under a summarised
+        cache one window's pages at most and a summary page a window
+        (``min(Pn, ceil(n / bt)) + ceil(n / W)``)."""
+        bt = self.kv_cache.block_tokens
+        if self._eva is None:
+            return -(-n // bt), 0
+        return (min(self._eva.window_pages, -(-n // bt)),
+                -(-n // self._eva.window))
 
     def _release_request_kv(self, req: Request) -> None:
         """Return a paged request's KV resources: release its pins
@@ -3498,7 +3577,13 @@ class ContinuousBatchingEngine:
                 # every segment of this admission the dispatch can carry
                 self._window_hold(req, start, min(
                     len(req.prompt), start + (want - r) * C))
-            while r < want and len(suffix) > C:
+            # a summarised cache: a window's pages are written again by
+            # the next window, so a request's segments of one dispatch
+            # lie in one window, whose earlier segments' queries still
+            # see what it wrote
+            at_edge = lambda: (self._eva is not None and start != a["start"]
+                               and start % self._eva.window == 0)
+            while r < want and len(suffix) > C and not at_edge():
                 seg_ids[r, :] = np.asarray(suffix[:C], np.int32)
                 seg_tables[r] = req._pkv["table"]
                 seg_starts[r] = start
@@ -3512,7 +3597,7 @@ class ContinuousBatchingEngine:
                 r += 1
             if start != a["start"]:
                 advance.append((a, start, suffix))
-            if r >= want or len(suffix) > C:
+            if r >= want or len(suffix) > C or at_edge():
                 break
             if not free:
                 continue     # final parked until a slot frees; later
@@ -3584,11 +3669,31 @@ class ContinuousBatchingEngine:
         # the starts; the kernel computes every row of a segment), and
         # the steps a grid of the table's width would have had for the
         # full kind's tiles
-        walked = [sum(prefill_pages_walked(int(seg_starts[r0]), C, tile,
+        walked = [sum(prefill_pages_walked(self._cache_row(
+                                               int(seg_starts[r0])), C, tile,
                                            self.kv_cache.block_tokens,
                                            self._table_width, window)
                       for (r0, _, _, _) in packed)
                   for tile, window in self._prefill_tiles]
+        # a summarised cache: the rows of the pool the decoding rows'
+        # next query attends (the summaries among them apart), and the
+        # (query, row) pairs of the slab's tokens, each its window's
+        # earlier keys, itself and every closed window's summaries
+        eva_cols = {}
+        if self._eva is not None:
+            Wn, Cn = self._eva.window, self._eva.chunk
+            held = [len(s[0].prompt) + s[1] for s in rows if s is not None]
+            # (a final's first step feeds its token #1, at ``plen``)
+            held += [len(req.prompt) + 1 for req, _ in finals]
+            attended = [eva_rows(Wn, Cn, n) for n in held]
+            pairs = 0
+            for (r0, _, _, _) in packed:
+                n, s0 = int(seg_ntok[r0]), int(seg_starts[r0])
+                pairs += (n * eva_rows(Wn, Cn, s0 + 1)[0]
+                          + n * (s0 % Wn) + n * (n + 1) // 2)
+            eva_cols = {"kv_attended_rows": sum(a + b for a, b in attended),
+                        "kv_summary_rows": sum(a for a, _ in attended),
+                        "prefill_attended_rows": pairs}
         pages_grid = (len(packed) * (C // self._prefill_tiles[0][0])
                       * self._table_width)
         if spec_mixed:
@@ -3633,7 +3738,48 @@ class ContinuousBatchingEngine:
             prefill_pages_walked=walked, prefill_pages_grid=pages_grid,
             live0=live0, kv_tokens=kv_tokens, spec_mixed=spec_mixed,
             k_vec=k_vec, k_disp=k_disp, num_rounds=num_rounds,
-            dev=None, how=None, ahead_s=0.0)
+            eva_cols=eva_cols, dev=None, how=None, ahead_s=0.0)
+
+    def _cache_row(self, position: int) -> int:
+        """The row of its attended table that the token at ``position``
+        sits at: the position itself, but under a summarised cache (a
+        page of summary rows a closed window, then its place in the open
+        one: ``ops.eva_attention.eva_positions``)."""
+        if self._eva is None:
+            return position
+        return eva_positions(position, self._eva.window,
+                             self.kv_cache.block_tokens)
+
+    def _eva_account(self, plan, steps: int) -> int:
+        """A drained dispatch's part in ``eva_stats``: the windows its
+        tokens closed and the summaries they completed (a request whose
+        cached tokens went from ``a`` to ``b`` closed ``b // W - a // W``
+        windows and completed ``b // C - a // C`` chunks: a prompt
+        segment's tokens, a row's decode steps within its budget), and
+        at the pool's fullest the rows it held for the running requests
+        beside their tokens.  Returns the windows closed."""
+        Wn, Cn = self._eva.window, self._eva.chunk
+        spans = [(int(plan.seg[2][r0]),
+                  int(plan.seg[2][r0]) + int(plan.seg[3][r0] if f else
+                                             self.prefill_chunk))
+                 for (r0, _, f, _) in plan.packed]
+        after = []
+        for req, k in ([s for s in plan.rows if s is not None]
+                       + [(req, 1) for req, _ in plan.finals]):
+            a = len(req.prompt) + k - 1
+            b = a + max(0, min(steps, req.max_new - k))
+            spans.append((a, b))
+            after.append(b)
+        closed = sum(b // Wn - a // Wn for a, b in spans)
+        st = self.eva_stats
+        st["windows_closed"] += closed
+        st["summaries_written"] += sum(b // Cn - a // Cn for a, b in spans)
+        done = {id(req) for req, _ in plan.finals}
+        after += [a["start"] for a in self._adms if id(a["req"]) not in done]
+        rows = sum(sum(eva_rows(Wn, Cn, n)) for n in after)
+        if rows > st["rows_held_peak"]:
+            st["rows_held_peak"], st["tokens_held_peak"] = rows, sum(after)
+        return closed
 
     def _put_slab(self, plan, put) -> tuple:
         """The plan's segment arrays on the device, each final's
@@ -4028,6 +4174,9 @@ class ContinuousBatchingEngine:
             record.update(self.loop_counters.add(bool(packed), steps))
         if "prefill_kv_tokens" in self.dispatch_trace.extra_fields:
             record["prefill_kv_tokens"] = plan.prefill_kv_tokens
+        if self._eva is not None:
+            record.update(plan.eva_cols,
+                          windows_closed=self._eva_account(plan, steps))
         if self._wmgr is not None:
             record["kv_window_tokens"] = plan.kv_window_tokens
             record["prefill_window_pairs"] = plan.prefill_window_pairs
@@ -4067,7 +4216,8 @@ class ContinuousBatchingEngine:
                 st = req._pkv
                 plen = len(req.prompt)
                 bt = self.kv_cache.block_tokens
-                if plen // bt >= 1 and self._wmgr is None:
+                if (plen // bt >= 1 and self._wmgr is None
+                        and self._eva is None):
                     adopted, store_lease = self.kv_cache.store_shared(
                         req.prompt, st["table"][:plen // bt])
                     st["adopted"] = adopted

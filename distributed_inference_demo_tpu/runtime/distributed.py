@@ -50,6 +50,7 @@ from ..comm.transport import (BaseTransport, TransportTimeout,
                               record_corrupt_frame)
 from ..models.base import (KVCache, ModelConfig, StageParams, StageSpec,
                            require_kv_pair, require_one_kind,
+                           require_token_rows,
                            require_single_pass)
 from ..ops.sampling import SamplingParams, sample_logits
 from ..telemetry import postmortem
@@ -93,6 +94,7 @@ class StageRuntime:
         if spec.num_stages > 1:
             require_single_pass(cfg, "a pipeline of stages")
             require_kv_pair(cfg, "a pipeline of stages")
+            require_token_rows(cfg, "a pipeline of stages")
             require_one_kind(cfg, "a pipeline of stages")
         self.cfg = cfg
         self.spec = spec

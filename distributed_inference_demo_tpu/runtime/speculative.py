@@ -50,6 +50,7 @@ import numpy as np
 
 from ..models.base import (KVCache, ModelConfig, StageParams, StageSpec,
                            require_kv_pair, require_one_kind,
+                           require_token_rows,
                            require_single_pass)
 from ..models.decoder import stage_forward
 from ..ops.sampling import SamplingParams, filtered_logits, sample_logits
@@ -335,6 +336,7 @@ class SpeculativeEngine:
                                                     self.max_seq)
         require_single_pass(draft_cfg, "the draft side of speculation")
         require_kv_pair(draft_cfg, "the draft side of speculation")
+        require_token_rows(draft_cfg, "the draft side of speculation")
         require_one_kind(draft_cfg, "the draft side of speculation")
         self.spec = StageSpec(0, 1, 0, cfg.num_layers)
         self.draft_spec = StageSpec(0, 1, 0, draft_cfg.num_layers)

@@ -225,6 +225,16 @@ LATENT_DISPATCH_FIELDS = ("prefill_kv_tokens",)
 # window kind, the pages its tiles' windows meet
 WINDOW_DISPATCH_FIELDS = ("kv_window_tokens", "prefill_window_pairs",
                           "prefill_window_pages_walked")
+# a model with a summarised cache (``eva_window``): what its kernels
+# had to read.  ``kv_attended_rows``: the sum over the rows that decode
+# of the rows of the pool their next query attends (a page of summaries a
+# closed window and the open window's exact keys), ``kv_summary_rows``
+# the summaries among them; ``prefill_attended_rows``: the (query, row)
+# pairs the slab's prompt tokens attend over; ``windows_closed``: the
+# windows the dispatch's tokens closed (host arithmetic on the rows'
+# positions, as ``kv_tokens`` is)
+EVA_DISPATCH_FIELDS = ("kv_attended_rows", "kv_summary_rows",
+                       "prefill_attended_rows", "windows_closed")
 _DISPATCH_RING = 128       # x ~135 bytes a row: /stats stays under 18 KB
 # a span that is work (every one but ``await``) and lasts this long is a
 # stall: ten times the longest ordinary span (four chips' ``ahead``,

@@ -64,6 +64,19 @@ the window.  It is held against two controls that must be refused:
 between pages) and ``--window-ignored`` (its window blocks served as full
 ones): ``READINGS_PERIOD``.
 
+A model with a summarised cache (family ``evabyte``: ``--model
+evabyte-6.5b-bf16``) is read short (``--batch 2 --prompt 512 --steps
+16``: inside the first window, a plain causal decoder) and long
+(``--batch 1 --prompt 6136 --steps 16``: 24 chunks, two windows close in
+prefill, the third at decode step 8, then 8 steps over 384 summaries;
+the last chunk is padded to ``--chunk`` as the engine's slab pads it).
+The tables are a row's leases, summary pages then window pages, and the
+program builds the attended table from them (``ops.eva_attention``).  Two
+controls must be refused there: ``--bf16-softmax-state`` and
+``--summaries-withheld`` (the REFERENCE told that no query sees a
+summary: if the served path's summaries moved nothing, the long reading
+would prove nothing about them): ``READINGS_EVA``.
+
 One ``MODEL_PARITY {json}`` line, exit code 1 if a limit is passed.
 """
 
@@ -128,7 +141,36 @@ READINGS_PERIOD = {
 # laguna: the mean between 0.1026 (the largest sound reading, short) and
 # 0.1628 (bf16 state, long), 1.25 x room on both sides; a window ignored
 # reads 0.74 (refused by both limits)
-FAMILY_TOL = {"deepseek_v3": (0.10, TOL_MAX), "laguna": (0.13, TOL_MAX)}
+# (max_over_vocab_mean, max_over_vocab_max, mean_abs); my chip runs, PR 53,
+# TPU v5 lite, evabyte-6.5b-bf16 at published widths (16 of 32 layers)
+READINGS_EVA = {
+    "served, 2 x 512 + 16, seeds 0, 1, 3 (the mean alone)": [
+        (0.0322, 0.0438, 0.0081), (0.0312, 0.0428, 0.0078),
+        (0.0293, None, None)],
+    "served, 1 x 6136 + 16, seeds 0, 1, 2, 3 (the mean alone)": [
+        (0.0258, 0.0325, 0.0068), (0.0269, 0.0341, 0.0068),
+        (0.0251, 0.0329, 0.0067), (0.0280, None, None)],
+    "--bf16-softmax-state, 1 x 6136 + 16, seeds 0, 1, 3 (the mean alone)": [
+        (0.0378, 0.0465, 0.0094), (0.0358, 0.0454, 0.0092),
+        (0.0351, None, None)],
+    "--summaries-withheld, 1 x 6136 + 16, seeds 0, 3 (the mean alone)": [
+        (4.3909, 6.7581, 0.9089), (3.1623, None, None)],
+}
+# evabyte, a reading PAST the first window (the long one, which alone
+# sees a summary and enough pages for the state's precision to show): the
+# mean between 0.0280 (the largest sound reading of four seeds) and
+# 0.0351 (the smallest of three with the state in bf16), 10-13 % of room
+# on both sides; the
+# state's precision is refused by the mean, not by each limit (its
+# maxima read 0.045-0.047 beside a sound 0.044 in the short reading);
+# summaries withheld read 3.2-4.4 (refused by both).  A reading inside the
+# first window (the short one: a plain causal decoder over four pages,
+# which cannot show the state's precision, as READINGS_LATENT's last row
+# found) reads 0.031-0.032 and is held to 0.04.  The maximum: 2.3 x the
+# largest sound one, a gross fault, as for every family
+EVA_LONG_TOL = (0.031, 0.10)
+FAMILY_TOL = {"deepseek_v3": (0.10, TOL_MAX), "laguna": (0.13, TOL_MAX),
+              "evabyte": (0.04, 0.10)}
 
 
 def seeded_ids(seed: int, n: int, vocab: int):
@@ -177,6 +219,18 @@ def window_ignored(cfg):
     return cfg.replace(period=tuple(wide(k) for k in cfg.period))
 
 
+def summaries_withheld():
+    """The evabyte REFERENCE told that no query sees a summary
+    (``--summaries-withheld``): the fault control of the long reading,
+    which must then read far outside the limits."""
+    import jax.numpy as jnp
+
+    from families import evabyte
+
+    evabyte.summaries_seen = lambda n, lo, hi, window, chunk: jnp.zeros(
+        (hi - lo, n), bool)
+
+
 def period_tables(cfg, b: int, W: int, bt: int, lo: int, hi: int,
                   span: int):
     """A period model's tables for a call that writes tokens ``[lo, hi)``
@@ -216,6 +270,10 @@ def served_logprobs(cfg, params, prompts, args):
     b, plen = prompts.shape
     bt, C = args.page, args.chunk
     W = -(-(plen + args.steps + 1) // bt)
+    if cfg.summary_kv:
+        # a row's leases: a summary page a window, then one window's pages
+        W = (-(-(plen + args.steps + 1) // cfg.eva_window)
+             + cfg.eva_window // bt)
     record = AttnPathRecord()
     fwd, bind, _ = make_paged_forward_seam(
         cfg, StageSpec(0, 1, 0, cfg.num_layers), None, params, bt,
@@ -238,11 +296,11 @@ def served_logprobs(cfg, params, prompts, args):
         tables_for = lambda lo, hi: whole
 
     @jax.jit
-    def chunk(params, pk, pv, ids, start, tables):
+    def chunk(params, pk, pv, ids, start, tables, last):
         bind(tables, "prefill")
         pos = start + jnp.broadcast_to(jnp.arange(ids.shape[1]), ids.shape)
         logits, cache = fwd(params, ids, KVCache(pk, pv, jnp.int32(0)),
-                            pos, ids.shape[1] - 1)
+                            pos, last)
         return (jax.nn.log_softmax(logits[:, -1].astype(jnp.float32), -1),
                 cache.keys, cache.values)
 
@@ -256,9 +314,17 @@ def served_logprobs(cfg, params, prompts, args):
                 cache.keys, cache.values)
 
     for start in range(0, plen, C):
-        lp, pk, pv = chunk(params, pk, pv,
-                           jnp.asarray(prompts[:, start:start + C]),
-                           jnp.int32(start), tables_for(start, start + C))
+        ids = prompts[:, start:start + C]
+        last = ids.shape[1] - 1
+        if cfg.summary_kv and ids.shape[1] < C:
+            # a chunk lies in one window and holds whole pooling chunks:
+            # the last one is padded as the engine's slab pads it (what
+            # the pad tokens write lies behind the length, and the decode
+            # steps write it again before any query sees it)
+            ids = np.pad(ids, ((0, 0), (0, C - ids.shape[1])))
+        lp, pk, pv = chunk(params, pk, pv, jnp.asarray(ids),
+                           jnp.int32(start), tables_for(start, start + C),
+                           jnp.int32(last))
     lps, toks = [np.asarray(lp)], []
     length = jnp.full((b,), plen, jnp.int32)
     for t in range(args.steps):
@@ -322,7 +388,8 @@ def reference_logprobs(cfg, params, ids, n_prompt: int):
         x = final_norm(params, x[n_prompt - 1:])
         head = (params.embed["tokens"].astype(jnp.float32).T
                 if cfg.tie_embeddings
-                else reference._f32(params.lm_head["w"]))
+                else reference._f32(params.lm_head["w"])
+                [:, :cfg.vocab_size])   # the next token's head, the first
         return (np.asarray(jax.nn.log_softmax(x @ head, -1)),
                 np.stack(margins) if margins else None)
 
@@ -345,6 +412,9 @@ def main(argv=None) -> int:
     ap.add_argument("--window-ignored", action="store_true",
                     help="serve a period model's window blocks as full "
                          "ones (a control: must be refused)")
+    ap.add_argument("--summaries-withheld", action="store_true",
+                    help="tell an evabyte REFERENCE that no query sees a "
+                         "summary (a control: must be refused)")
     args = ap.parse_args(argv)
     from distributed_inference_demo_tpu.cli import configure_compile_cache
     configure_compile_cache()
@@ -359,6 +429,8 @@ def main(argv=None) -> int:
         bf16_router()
     if args.bf16_softmax_state:
         bf16_softmax_state()
+    if args.summaries_withheld:
+        summaries_withheld()
     dev = jax.devices()[0]
     cfg = model_config_for(args.model)
     t0 = time.monotonic()
@@ -385,11 +457,14 @@ def main(argv=None) -> int:
         chosen = list(toks[r]) + [int(served[r, -1].argmax())]
         own.extend(float(err[i, t]) for i, t in enumerate(chosen))
     tol_mean, tol_max = FAMILY_TOL.get(cfg.family, (TOL_MEAN, TOL_MAX))
+    if cfg.summary_kv and args.prompt + args.steps > cfg.eva_window:
+        tol_mean, tol_max = EVA_LONG_TOL
     row = {"model": args.model, "platform": dev.platform,
            "device_kind": dev.device_kind, "kv_dtype": args.kv_dtype,
            "bf16_router": args.bf16_router,
            "bf16_softmax_state": args.bf16_softmax_state,
            "window_ignored": args.window_ignored,
+           "summaries_withheld": args.summaries_withheld,
            "batch": args.batch,
            "prompt": args.prompt, "steps": args.steps,
            "positions": len(worst), "paths": paths,
