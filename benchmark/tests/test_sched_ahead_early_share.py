@@ -1,0 +1,29 @@
+"""``sched_ahead_early_share_pct`` reads the window's edges, and nothing
+where the program keeps no such counter (the parent of the PR that
+brought it)."""
+from layer_metrics import sched_ahead_early_share_pct as share
+
+
+def _ctx(a, b):
+    return {"stats_open": a, "stats_close": b, "marks": {}}
+
+
+def test_share_of_the_windows_dispatches_enqueued_early():
+    ctx = _ctx({"dispatch_trace": {"seq": 40, "ahead_hits": 22,
+                                   "ahead_early": 10}},
+               {"dispatch_trace": {"seq": 840, "ahead_hits": 622,
+                                   "ahead_early": 410}})
+    assert share.read(ctx) == 50.0
+    # a cell whose plans are never closed reads 0, not nothing
+    ctx["stats_close"]["dispatch_trace"]["ahead_early"] = 10
+    assert share.read(ctx) == 0.0
+
+
+def test_nothing_to_read_is_none_and_never_raises():
+    same = {"dispatch_trace": {"seq": 7, "ahead_early": 5}}
+    assert share.read(_ctx(same, same)) is None      # no dispatch at all
+    assert share.read(_ctx({}, {})) is None          # no such section
+    # the parent's section: dispatches and hits, and no such counter
+    assert share.read(_ctx({"dispatch_trace": {"seq": 1, "ahead_hits": 1}},
+                           {"dispatch_trace": {"seq": 9, "ahead_hits": 8}})
+                      ) is None

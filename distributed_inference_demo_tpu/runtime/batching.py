@@ -57,6 +57,7 @@ bit-exact vs InferenceEngine (pinned by tests).
 
 from __future__ import annotations
 
+import contextlib
 import copy
 import itertools
 import queue
@@ -3179,7 +3180,12 @@ class ContinuousBatchingEngine:
 
     def _drain_all(self, err: BaseException):
         """Fail every in-flight slot, mid-admission, backlogged, and
-        queued request with ``err``."""
+        queued request with ``err``.  A crash may have left dispatches
+        in the device's queue (two, after an early launch, §19): they
+        are waited out first, so that no page goes back under one.  At
+        ``close()`` nothing is in flight by then."""
+        with contextlib.suppress(Exception):
+            self._last_tok.block_until_ready()
         for i, req in enumerate(self._slots):
             if req is not None:
                 self._fail_request(req, err)
@@ -3370,7 +3376,15 @@ class ContinuousBatchingEngine:
         packed (``_ahead_refusal``), the thread launches it first and
         drains afterwards, under the new execution, and stays in this
         call.  Otherwise it drains and returns, and the next iteration
-        is this one again from the top."""
+        is this one again from the top.
+
+        A prepared dispatch that is ``closed`` (``_plan_ahead``: no
+        arrival could change it and nothing the device has yet to say
+        could refute it) is launched as soon as it is made, before the
+        one in flight has returned: the device's queue is then two deep
+        and goes from one execution to the next with no host in
+        between.  The thread awaits and drains the first under the
+        second, as on any hit."""
         trace = self.dispatch_trace
         trace.enter("intake")
         free = [i for i, s in enumerate(self._slots) if s is None]
@@ -3452,28 +3466,50 @@ class ContinuousBatchingEngine:
             [(s, len(s.tokens)) if s is not None else None
              for s in self._slots], self._adms,
             [i for i, s in enumerate(self._slots) if s is None],
-            self._rng, self._tables)
+            self._rng, self._tables.copy())
         plan.how = self._ahead_miss or "first"
         flight = self._launch_mixed(plan)
         del plan       # the flight holds it, and lets it go when drained
         while flight is not None:
             nxt, why = self._plan_ahead(flight)
+            ahead = None
+            if (nxt is not None and nxt.closed
+                    and self._ahead_refusal(flight) is None):
+                with trace.ahead("ahead_launch"):
+                    ahead = self._launch_mixed(nxt, early=True)
+                if ahead is None:           # (as below)
+                    nxt, why = None, "other"
             self._await_mixed(flight)
-            if nxt is not None:
+            if ahead is not None:
+                ahead.t_begin = flight.t_done = trace.returned()
+                # here the old order looked for news, and its intake
+                # would have dropped these before it packed `ahead`
+                ahead.gone = {id(req) for req in (
+                    [s[0] for s in nxt.rows if s is not None]
+                    + [a["req"] for (_, a, _, _) in nxt.packed])
+                    if req.cancelled}
+                if flight.steps != flight.steps_ahead:
+                    raise RuntimeError(
+                        f"dispatch {trace.launched - 1} ran {flight.steps} "
+                        f"steps where its successor, enqueued behind it, "
+                        f"was packed for {flight.steps_ahead}")
+            elif nxt is not None:
                 # the validation is the next dispatch's whole `pack`
                 flight.t_done = trace.enter("pack")
                 why = self._ahead_refusal(flight)
                 if why is None:
                     ahead = self._launch_mixed(nxt)
-                    if ahead is not None:
-                        with trace.ahead("ahead_drain"):
-                            record = self._drain_mixed(flight)
-                        trace.commit(phases=flight.phases, **record)
-                        flight = ahead
-                        continue
-                    # its slab failed its requests and it never reached
-                    # the device: `flight` is drained as after a miss
+                    # None: its slab failed its requests and it never
+                    # reached the device: `flight` is drained as after a
+                    # miss
                     why = "other"
+            if ahead is not None:
+                with trace.ahead("ahead_drain"):
+                    record = self._drain_mixed(flight)
+                trace.commit(phases=flight.phases, **record)
+                flight = ahead
+                continue
+            if nxt is not None:
                 trace.enter("drain")
             else:
                 flight.t_done = trace.enter("drain")
@@ -3514,10 +3550,13 @@ class ContinuousBatchingEngine:
 
         The view: ``rows[i]`` is ``(request, tokens it holds)`` of slot
         i or None, ``adms`` the admissions in flight, ``free`` the slots
-        a final may take, ``rng`` the sampler key, ``tables`` the
-        ``[B, W]`` decode tables.  ``_mixed_iteration`` passes the state
-        as it is; ``_plan_ahead`` the state the dispatch in flight will
-        leave.
+        a final may take, ``rng`` the sampler key, ``tables`` a copy of
+        the ``[B, W]`` decode tables, which becomes the plan's (the
+        transfer may read it as long as the dispatch runs, and the
+        launch of the next one, which may come before this one has
+        returned, writes ``_tables``).  ``_mixed_iteration`` passes the
+        state as it is; ``_plan_ahead`` the state the dispatch in flight
+        will leave.
 
         Packing policy (docs/DESIGN.md §19): every active decode row
         contributes its ``decode_block`` fused-loop tokens off the top
@@ -3625,6 +3664,9 @@ class ContinuousBatchingEngine:
             prefill_kv_tokens += n * start + n * (n + 1) // 2
             r += 1
         finals = [(a["req"], slot) for (_, a, f, slot) in packed if f]
+        # every segment the budget allows is packed: an admission after
+        # these (the loop above never came to it) would get none here
+        all_packed = r == want >= 1
         # the slab is the r segments that were packed: the arrays' shape
         # picks ``mixed_step``'s variant (the speculative programs keep
         # the full slab); a model with experts is also told how many
@@ -3641,8 +3683,6 @@ class ContinuousBatchingEngine:
         # decode inside this dispatch pages through the installed
         # row — its table must be live BEFORE the dispatch; the
         # radix adoption (drain) waits until the pages hold data
-        if finals or self._wmgr is not None:
-            tables = tables.copy()
         for req, slot in finals:
             budget_vec[slot] = req.max_new - 1
             tables[slot] = req._pkv["table"]
@@ -3738,7 +3778,8 @@ class ContinuousBatchingEngine:
             prefill_pages_walked=walked, prefill_pages_grid=pages_grid,
             live0=live0, kv_tokens=kv_tokens, spec_mixed=spec_mixed,
             k_vec=k_vec, k_disp=k_disp, num_rounds=num_rounds,
-            eva_cols=eva_cols, dev=None, how=None, ahead_s=0.0)
+            eva_cols=eva_cols, dev=None, how=None, ahead_s=0.0,
+            full=all_packed)
 
     def _cache_row(self, position: int) -> int:
         """The row of its attended table that the token at ``position``
@@ -3852,12 +3893,16 @@ class ContinuousBatchingEngine:
             self._call_mixed_step(plan)
         jax.block_until_ready(self._last_tok)
 
-    def _launch_mixed(self, plan) -> Optional[types.SimpleNamespace]:
+    def _launch_mixed(self, plan, early: bool = False
+                      ) -> Optional[types.SimpleNamespace]:
         """Commit ``plan``'s effects on the scheduler's state (rng
         spend, admissions' progress, installed table rows, counters,
         the requests' dispatch stamps) and enqueue its program; returns
         the dispatch in flight, or None if it failed its requests and
-        never reached the device."""
+        never reached the device.  ``early``: its predecessor has not
+        returned yet.  The program's pool and rows' state are that
+        one's outputs, so the device runs it behind that one, with no
+        host in between."""
         trace = self.dispatch_trace
         seq = trace.launched + 1     # this dispatch's number
         packed = plan.packed
@@ -3870,7 +3915,12 @@ class ContinuousBatchingEngine:
             self._tables[slot] = req._pkv["table"]
         if self._wmgr is not None:
             # what fell behind the window goes back to its pool, by the
-            # first token this dispatch writes for each request
+            # first token this dispatch writes for each request.  At an
+            # early launch the predecessor still reads those pages (and
+            # a summarised cache's window, which is not freed but
+            # written over, likewise): safe because the device runs its
+            # queue in order, and a page freed here can be written by no
+            # dispatch enqueued before this one
             first = {}
             for (r0, a, _, _) in packed:
                 first.setdefault(id(a["req"]),
@@ -3914,10 +3964,18 @@ class ContinuousBatchingEngine:
         sig = _profiling.dispatch_signature(
             prog, batch=int(plan.active_mask.sum()),
             chunk=self.decode_block, kv_dtype=self.kv_cache.kv_dtype)
+        # (an early launch is work under an execution, span
+        # `ahead_launch`, and no phase of the gap)
+        t_launch = (trace.enter("wait", cut=True) if early
+                    else trace.enter("launch"))
         flight = types.SimpleNamespace(
-            plan=plan, sig=sig, t_done=0.0,
-            t_launch=trace.enter("launch"),
-            phases=trace.launched_phases, steps=0, out=None)
+            plan=plan, sig=sig, t_launch=t_launch, t_done=0.0,
+            phases=trace.launched_phases, out=None, early=early,
+            # the earliest its execution can have begun (an early one's:
+            # when its predecessor returned), the device's count of its
+            # steps, None until it has returned, and the requests that
+            # an early one carries and the old order would not have
+            t_begin=t_launch, steps=None, gone=())
         try:
             if not spec_mixed:
                 with jax.profiler.StepTraceAnnotation("mixed_step",
@@ -3969,7 +4027,10 @@ class ContinuousBatchingEngine:
             for req in failed:
                 self._fail_request(req, e)
             return None
-        trace.enter("wait")          # until the first blocking read
+        if early:
+            trace.opened(t_launch)
+        else:
+            trace.enter("wait")      # until the first blocking read
         return flight
 
     def _await_mixed(self, flight) -> None:
@@ -3977,7 +4038,8 @@ class ContinuousBatchingEngine:
         read of one of its outputs, which the record says was late if
         the output was there before the host came for it."""
         spec = flight.plan.spec_mixed
-        self.dispatch_trace.awaiting(flight.out[2 if spec else 4].is_ready())
+        self.dispatch_trace.awaiting(flight.out[2 if spec else 4].is_ready(),
+                                     flight.phases)
         if spec:
             em, ns = flight.out[2:]
             flight.em_np, flight.ns_np = np.asarray(em), np.asarray(ns)
@@ -4019,7 +4081,25 @@ class ContinuousBatchingEngine:
         admissions (fewer than the free slots, one at least) lets the
         intake try it, a final that ``flight`` installs
         (``store_shared`` moves the tree's epoch) or a page gate that
-        is open already."""
+        is open already.
+
+        The plan is ``closed`` iff the engine has no ``eos`` (the step
+        count above is then the device's, and no token can end a row
+        before its budget does) and it packed every segment its budget
+        allows (``_pack_mixed``'s ``full``: an arrival joins ``_adms``
+        at the end and the pack stops before it looks there, so the
+        gap's pack with the arrival appended would be this plan,
+        segment for segment, and the arrival's first chunk rides the
+        dispatch after it either way).  Such a plan may be launched
+        while ``flight`` still runs if ``_ahead_refusal`` sees no news
+        then.  What lands after that launch waits one dispatch more
+        than it would have: a cancel or an export is served at the next
+        intake, after this plan's dispatch has drained, a resume is
+        adopted there, and a request cancelled before ``flight`` returned
+        rides this dispatch and gets none of its tokens
+        (``_drain_mixed``).  A decode-only plan and a
+        slab with a segment to spare are not closed: an arrival during
+        ``flight`` is served in the next dispatch there."""
         plan = flight.plan
         if plan.spec_mixed:
             return None, "other"
@@ -4077,6 +4157,7 @@ class ContinuousBatchingEngine:
                 return None, "finish"
             self._put_mixed(nxt)
         nxt.how, nxt.ahead_s = "hit", spent[0]
+        nxt.closed = nxt.full and self.eos_id is None
         flight.steps_ahead = steps
         return nxt, None
 
@@ -4098,20 +4179,24 @@ class ContinuousBatchingEngine:
         return None
 
     def _ahead_refusal(self, flight) -> Optional[str]:
-        """``flight`` has returned: None if the dispatch prepared under
-        it is what this order would pack now, else why not (one of
+        """None if the dispatch prepared under ``flight`` is what this
+        order would pack now, else why not (one of
         ``tracing.AHEAD_MISS_REASONS``).  It is iff ``flight`` did what
         the projection assumed (the projected step count, no ``eos``
         among the tokens a row keeps) and nothing reached the scheduler
         meanwhile: no arrival or wake in the queue, no export asked, no
         request cancelled (a row's, an admission's, a waiting one), the
-        engine not closing."""
+        engine not closing.  Asked before ``flight`` has returned, of a
+        closed plan (``_plan_ahead``), it has only the news to look at:
+        the projection is then the device's own arithmetic."""
         plan = flight.plan
         news = self._ahead_news(
             [s[0] for s in plan.rows if s is not None]
             + [a["req"] for a in self._adms])
         if news is not None:
             return news
+        if flight.steps is None:
+            return None
         if flight.steps != flight.steps_ahead:
             return "finish"
         if self.eos_id is not None:
@@ -4139,6 +4224,11 @@ class ContinuousBatchingEngine:
         packed, spec_mixed = plan.packed, plan.spec_mixed
         prefill_tokens, n_active = plan.prefill_tokens, plan.n_active
         final_toks, final_lps = flight.out[:2]
+        # an early dispatch was enqueued before the intake that would
+        # have dropped a request cancelled by then: that request rode
+        # it, gets none of its tokens, and the next intake sweeps it
+        # (by identity: a request compares by its fields)
+        gone = flight.gone
         # the rows the head ran over: the slab's one a segment (a
         # speculative program samples its slab only where it packed a
         # final), and every slot's at each step (a verify round's chunk
@@ -4152,7 +4242,7 @@ class ContinuousBatchingEngine:
             finals=len(plan.finals), prefill_tokens=prefill_tokens,
             active_rows=n_active, steps=steps,
             kv_tokens=plan.kv_tokens, ahead=plan.ahead_s, how=plan.how,
-            slab_rows=plan.slab_rows,
+            early=flight.early, slab_rows=plan.slab_rows,
             prefill_pages_walked=plan.prefill_pages_walked[0],
             prefill_pages_grid=plan.prefill_pages_grid,
             head_rows=head_rows)
@@ -4188,17 +4278,19 @@ class ContinuousBatchingEngine:
         cs["mixed_budget_tokens"] += self.mixed_token_budget
         _t0 = self._prof.begin(flight.sig)
         if _t0 is not None:
-            # sampled only, and the sample is the record's own time:
-            # nothing is blocked on.  Packed prefill writes + every
-            # active row's per-step history read (a verify round reads
-            # the dispatch's draft width + 1 positions of each), from
+            # sampled only, and the sample is the record's own time
+            # (less what an early dispatch spent queued behind its
+            # predecessor): nothing is blocked on.  Packed prefill
+            # writes + every active row's per-step history read (a
+            # verify round reads the dispatch's draft width + 1
+            # positions of each), from
             # the rows' host state as `_decode_kv_bytes` has it from
             # the device's lengths: after a hit those are the next
             # dispatch's, and a read of them waits for it
             held = sum(len(s[0].prompt) + s[1] + steps
                        for s in plan.rows if s is not None)
             self._prof.end(
-                flight.sig, _t0, seconds=flight.t_done - flight.t_launch,
+                flight.sig, _t0, seconds=flight.t_done - flight.t_begin,
                 hbm_bytes=(prefill_tokens + held * max(
                     1, steps * (plan.k_disp + 1)))
                 * self._kv_bytes_per_token)
@@ -4255,9 +4347,10 @@ class ContinuousBatchingEngine:
                                     prompt_len=plen,
                                     max_new=req.max_new,
                                     prefix_reused=a["m"])
-                self._record_token(
-                    slot, req, int(final_toks_np[r0]),
-                    None if spec_mixed else float(final_lps_np[r0]))
+                if id(req) not in gone:
+                    self._record_token(
+                        slot, req, int(final_toks_np[r0]),
+                        None if spec_mixed else float(final_lps_np[r0]))
         if spec_mixed:
             num_rounds, k_vec, live0 = (plan.num_rounds, plan.k_vec,
                                         plan.live0)
@@ -4282,7 +4375,9 @@ class ContinuousBatchingEngine:
             self._count_loop(steps)
             self._step_count += steps
             self._record_row_blocks(
-                np.asarray(toks), np.full(len(self._slots), steps),
+                np.asarray(toks),
+                [0 if req is None or id(req) in gone else steps
+                 for req in self._slots],
                 np.asarray(lps))
         if steps > 0 and plan.adms_left:
             cs["interleaved_steps"] += 1

@@ -187,15 +187,24 @@ DISPATCH_FIELDS = (("seq", "t_launch", "t_done") + DISPATCH_PHASES
                       "prefill_tokens", "prefill_pages_walked",
                       "head_rows", "active_rows", "steps",
                       "kv_tokens", "ahead", "late", "await"))
+# a record's very last column, after what the model adds: 1 if the
+# dispatch was enqueued behind its predecessor, before that one had
+# returned (``commit``'s ``early``; runtime.batching, docs/DESIGN.md §19)
+DISPATCH_LAST_FIELDS = ("early",)
 # what a launched dispatch keeps until its commit: its phases' seconds
 # and the two columns its blocking read fills
 _OWN = DISPATCH_PHASES + ("await", "late")
 # the spans of ``/stats.dispatch_trace.spans`` (wall and thread CPU
 # seconds each): the phases but ``wait``, which is no work of the
-# host's, the two kinds of work done under an execution
-# (``phase_s["ahead"]`` is their sum), and the blocking read
+# host's, the three kinds of work done under an execution
+# (``phase_s["ahead"]`` is their sum; ``ahead_launch``: the call of a
+# dispatch that is enqueued early), and the blocking read
 DISPATCH_SPANS = (tuple(p for p in DISPATCH_PHASES if p != "wait")
-                  + ("ahead_plan", "ahead_drain", "await"))
+                  + ("ahead_plan", "ahead_drain", "ahead_launch", "await"))
+# the number of the dispatch a span of work under an execution belongs
+# to, from the one launched last when the span begins: the one being
+# prepared, the one before, the one about to be enqueued
+_AHEAD_OF = {"ahead_plan": 1, "ahead_drain": -1, "ahead_launch": 1}
 # why a dispatch that followed another at once was packed in the gap and
 # not under its predecessor (runtime.batching, docs/DESIGN.md §19)
 AHEAD_MISS_REASONS = ("arrival", "finish", "cancel", "export", "other")
@@ -357,9 +366,10 @@ class DispatchTrace:
     ``pack`` accrue to the dispatch that is launched next, ``launch``,
     ``wait`` and ``drain`` to the one launched last: entering ``launch``
     is the cut, and the scheduler may launch dispatch n+1 before it has
-    committed n (it drains n under n+1's execution), so each launched
-    dispatch keeps its own seconds until its :meth:`commit` turns them
-    into one row of :data:`DISPATCH_FIELDS`.  An iteration that
+    committed n (it drains n under n+1's execution), and even before n
+    has returned (an early launch: n+1 waits behind n in the device's
+    queue), so each launched dispatch keeps its own seconds until its
+    :meth:`commit` turns them into one row of :data:`DISPATCH_FIELDS`.  An iteration that
     dispatched nothing carries its seconds into the next record.  The
     blocking wait of an idle engine is no phase (:meth:`idle`), and host
     work done under an execution is no seventh tile (:meth:`ahead`).
@@ -367,7 +377,16 @@ class DispatchTrace:
     ``wait`` is not "the device busy all the while": it runs from the
     call's return to the first blocking read and holds the host's work
     under the execution, which may outlast it.  :meth:`awaiting` says
-    for every dispatch whether it did (``late``).  Beside the record,
+    for every dispatch whether it did (``late``).  Where n+1 was
+    launched early, n's ``wait`` ends at that launch and n+1's begins
+    there, the call included (span ``ahead_launch``: the record's
+    ``launch``, like its ``pack``, is 0, for the device waited for
+    neither): the read of n (its ``await`` and ``late``, booked to n
+    all the same), n's drain and the plan of n+2
+    then lie in n+1's ``wait``, and n's ``t_done``
+    (:meth:`returned`) after n+1's ``t_launch``.  A record's two
+    instants still hold its execution between them; n+1's execution
+    starts no earlier than n's ``t_done`` less the host's wake-up.  Beside the record,
     every boundary also reads the thread's and the process's CPU time,
     the thread's involuntary context switches and the seconds garbage
     collections have taken (:meth:`watch_gc`), so that :attr:`spans`
@@ -388,9 +407,12 @@ class DispatchTrace:
         self._at: tuple = ()        # the boundary the phase started at
         self._seq = 0               # the dispatch it belongs to
         self._ann = None
-        self._await = None          # (boundary, annotation) of the read
+        self._await = None          # (boundary, annotation, the
+                                    # dispatch's seconds, its number)
         self._gc_t0: Optional[float] = None
         self.recent: "deque[tuple]" = deque(maxlen=_DISPATCH_RING)
+        # (number, t_launch) of the early dispatches not yet committed
+        self._opened: "deque[tuple]" = deque()
         self.idles: "deque[tuple]" = deque(maxlen=_IDLE_RING)
         self.stalls: "deque[dict]" = deque(maxlen=_STALL_RING)
         self.seq = self.launched = 0
@@ -402,6 +424,7 @@ class DispatchTrace:
         self.stalls.clear()
         # a dispatch in flight commits after the reset, as number 1
         self.launched -= self.seq
+        self._opened = deque((n - self.seq, t) for n, t in self._opened)
         self.seq = 0
         self.phase_s = dict.fromkeys(DISPATCH_PHASES + ("ahead",), 0.0)
         # name -> (n, wall seconds, thread CPU seconds, longest)
@@ -421,7 +444,7 @@ class DispatchTrace:
         self.head_rows = 0
         self.queue_wait_ms_sum = 0.0
         self.queue_wait_count = 0
-        self.ahead_hits = self.ahead_hits_slab = 0
+        self.ahead_hits = self.ahead_hits_slab = self.ahead_early = 0
         self.ahead_misses = dict.fromkeys(AHEAD_MISS_REASONS, 0)
         self.ahead_first = 0
         self.late_reads = 0
@@ -489,13 +512,17 @@ class DispatchTrace:
         self._last = self._next
         self._next = dict.fromkeys(_OWN, 0.0)
 
-    def enter(self, phase: str) -> float:
+    def enter(self, phase: str, cut: bool = False) -> float:
         """Start ``phase`` now, ending the one in progress; returns the
         instant.  ``launch`` opens the next dispatch's own seconds, which
-        :attr:`launched_phases` hands to its :meth:`commit`."""
+        :attr:`launched_phases` hands to its :meth:`commit`.  An early
+        launch is made under an execution and is no tile of its own
+        (the device waits for none of it): ``enter("wait", cut=True)``
+        opens the next dispatch's seconds with its ``wait``, and the
+        call is a span of :meth:`ahead` (``ahead_launch``)."""
         now = self._stamp()
         self._close(now)
-        if phase == "launch":
+        if phase == "launch" or cut:
             self._cut()
         after = phase in _LAUNCHED_PHASES
         self._into = self._last if after else self._next
@@ -520,7 +547,10 @@ class DispatchTrace:
         for p, v in self._last.items():
             self._next[p] += v
         self._last = self._prior
-        if self._phase in _LAUNCHED_PHASES:
+        if self._phase == "wait":
+            # an early launch: the cursor is under the one before again
+            self._into, self._seq = self._last, self.launched
+        elif self._phase in _LAUNCHED_PHASES:
             self._into = self._next
 
     def leave(self) -> float:
@@ -529,14 +559,26 @@ class DispatchTrace:
         self._close(now)
         return now[0]
 
+    def _end_await(self, now: tuple) -> None:
+        if self._await is not None:
+            at, ann, own, seq = self._await
+            own["await"] += self._span("await", at, now, seq)
+            ann.__exit__(None, None, None)
+            self._await = None
+
+    def returned(self) -> float:
+        """The blocking read has returned and the cursor stays where it
+        is, in the ``wait`` of the dispatch that was enqueued behind the
+        one read: ends ``await``; returns the instant, that one's
+        ``t_done``."""
+        now = self._stamp()
+        self._end_await(now)
+        return now[0]
+
     def _close(self, now: tuple) -> None:
         if self._phase is None:
             return
-        if self._await is not None:      # the read ends with `wait`
-            at, ann = self._await
-            self._into["await"] += self._span("await", at, now, self._seq)
-            ann.__exit__(None, None, None)
-            self._await = None
+        self._end_await(now)             # the read ends with `wait`
         dt = now[0] - self._at[0]
         self._into[self._phase] += dt
         self.phase_s[self._phase] += dt
@@ -545,18 +587,24 @@ class DispatchTrace:
         self._ann.__exit__(None, None, None)
         self._phase = self._ann = None
 
-    def awaiting(self, ready: bool) -> None:
-        """The host is about to block on an output of the dispatch in
-        flight (the cursor is in its ``wait``).  ``ready``: the output is
-        there already, so the device finished before the host came to
-        read and stood idle since (the record's ``late``).  The read,
-        span ``await``, ends where ``wait`` does: at the next
-        :meth:`enter`, the record's ``t_done``."""
-        self._into["late"] = late = int(ready)
+    def awaiting(self, ready: bool, own: Optional[dict] = None) -> None:
+        """The host is about to block on an output of a dispatch in
+        flight: the one launched last (the cursor is in its ``wait``),
+        or, given its ``own`` seconds (``launched_phases`` as its launch
+        left them), the one before it, behind which the last was
+        enqueued early.  ``ready``: the output is there already, so the
+        device finished before the host came to read (the record's
+        ``late``; it then stood idle since, unless the early one was
+        waiting behind it).  The read, span ``await``, ends at the
+        record's ``t_done``: the next :meth:`enter`, where ``wait`` ends
+        too, or :meth:`returned`."""
+        own = self._into if own is None else own
+        seq = self._seq - (own is not self._into)
+        own["late"] = late = int(ready)
         self.late_reads += late
-        ann = self._annotate(self._names["await"], seq=self._seq)
+        ann = self._annotate(self._names["await"], seq=seq)
         ann.__enter__()
-        self._await = (self._stamp(), ann)
+        self._await = (self._stamp(), ann, own, seq)
 
     @contextlib.contextmanager
     def idle(self):
@@ -579,16 +627,16 @@ class DispatchTrace:
     @contextlib.contextmanager
     def ahead(self, span: str = "ahead_plan"):
         """Around host work done while the device executes: the next
-        dispatch prepared (span ``ahead_plan``) or the last one drained
-        (``ahead_drain``).  The cursor stays in ``wait``, which still
+        dispatch prepared (span ``ahead_plan``), the last one drained
+        (``ahead_drain``) or the next one's call where it is enqueued
+        early (``ahead_launch``).  The cursor stays in ``wait``, which still
         runs from the call's return to ``t_done``, and the seconds are
         booked to ``phase_s["ahead"]``, so the six phases keep tiling
         the iteration and ``phase_s`` without ``wait`` is still all the
         host did.  Yields a one-element list that holds the seconds once
         the block has ended."""
         spent = [0.0]
-        # the dispatch being prepared, or the one before the one launched
-        seq = self.launched + (1 if span == "ahead_plan" else -1)
+        seq = self.launched + _AHEAD_OF[span]
         t0 = self._stamp()
         with self._annotate(self._names[span], seq=seq):
             try:
@@ -596,6 +644,17 @@ class DispatchTrace:
             finally:
                 spent[0] = self._span(span, t0, self._stamp(), seq)
                 self.phase_s["ahead"] += spent[0]
+
+    def opened(self, t_launch: float) -> None:
+        """The dispatch launched last was enqueued early and reached the
+        device's queue: from now until its :meth:`commit` the snapshot's
+        ``recent`` ends with a row that holds its number, its
+        ``t_launch``, ``early`` and nothing else (``t_done`` 0: not
+        returned).  Its execution may begin before its predecessor's
+        ``t_done``, so a reader that places executions on the records'
+        clock must know of it from its launch on, or it would give that
+        execution to the predecessor, whose interval holds its start."""
+        self._opened.append((self.launched, round(t_launch, 5)))
 
     def queue_wait(self, seconds: float) -> None:
         """A request's submit -> launch of its first dispatch."""
@@ -608,7 +667,8 @@ class DispatchTrace:
                ahead: float = 0.0, how: Optional[str] = None,
                phases: Optional[dict] = None, slab_rows: int = 0,
                prefill_pages_walked: int = 0, prefill_pages_grid: int = 0,
-               head_rows: int = 0, **extra: int) -> int:
+               head_rows: int = 0, early: bool = False,
+               **extra: int) -> int:
         """A dispatch that reached the device is drained: one record.
         ``slab_rows``: the rows of the prefill slab its program computed
         (segments of the launched variant x the chunk), of which
@@ -626,8 +686,9 @@ class DispatchTrace:
         counted in ``ahead_hits``, and in ``ahead_hits_slab`` too if it
         carried a segment), one of :data:`AHEAD_MISS_REASONS` (it
         followed its predecessor at once and was packed in the gap), or
-        ``"first"`` (nothing was executing before it).  Returns its
-        ``seq``."""
+        ``"first"`` (nothing was executing before it).  ``early``: a hit
+        that was enqueued before its predecessor had returned (the last
+        column, and counted in ``ahead_early``).  Returns its ``seq``."""
         if phases is None:
             self.leave()
             if self.launched == self.seq:    # no phase of a launch seen
@@ -641,7 +702,9 @@ class DispatchTrace:
             prefill_pages_walked, head_rows, active_rows, steps, kv_tokens,
             round(ahead, 5),
             int(phases["late"]), round(phases["await"], 5),
-            *(extra[f] for f in self.extra_fields)))
+            *(extra[f] for f in self.extra_fields), int(early)))
+        if early:
+            self._opened.popleft()   # the row above is its record now
         if segments:
             self.prefill += 1
         else:
@@ -655,6 +718,7 @@ class DispatchTrace:
         if how == "hit":
             self.ahead_hits += 1
             self.ahead_hits_slab += bool(segments)
+            self.ahead_early += bool(early)
         elif how == "first":
             self.ahead_first += 1
         elif how is not None:
@@ -666,7 +730,11 @@ class DispatchTrace:
         numbers in the order of ``fields``; ``idles`` rows of ``[t0,
         t1]``; ``stalls`` rows by name (:data:`STALL_FIELDS`).
         ``copy.copy`` of a deque is atomic under the GIL; iterating it
-        would race the scheduler's appends."""
+        would race the scheduler's appends.  After the ring: a row for
+        each early dispatch in flight (:meth:`opened`)."""
+        fields = DISPATCH_FIELDS + self.extra_fields + DISPATCH_LAST_FIELDS
+        in_flight = [[n, t, 0.0] + [0] * (len(fields) - 4) + [1]
+                     for n, t in copy.copy(self._opened)]
         return {"seq": self.seq,
                 "phase_s": {p: round(v, 6)
                             for p, v in self.phase_s.items()},
@@ -683,6 +751,7 @@ class DispatchTrace:
                 "queue_wait_count": self.queue_wait_count,
                 "ahead_hits": self.ahead_hits,
                 "ahead_hits_slab": self.ahead_hits_slab,
+                "ahead_early": self.ahead_early,
                 "ahead_misses": dict(self.ahead_misses),
                 "ahead_first": self.ahead_first,
                 "late_reads": self.late_reads,
@@ -698,8 +767,9 @@ class DispatchTrace:
                 "stall_count": self.stall_count,
                 "stalls": list(copy.copy(self.stalls)),
                 "idles": [list(r) for r in copy.copy(self.idles)],
-                "fields": list(DISPATCH_FIELDS + self.extra_fields),
-                "recent": [list(r) for r in copy.copy(self.recent)]}
+                "fields": list(fields),
+                "recent": [list(r) for r in copy.copy(self.recent)]
+                + in_flight}
 
 
 def to_chrome_trace(spans: Iterable[dict]) -> dict:

@@ -55,7 +55,8 @@ from distributed_inference_demo_tpu.telemetry import tracing
 from distributed_inference_demo_tpu.telemetry.flightrecorder import (
     get_flight_recorder)
 from distributed_inference_demo_tpu.telemetry.tracing import (
-    AHEAD_MISS_REASONS, DISPATCH_FIELDS, DISPATCH_PHASES, DISPATCH_SPANS,
+    AHEAD_MISS_REASONS, DISPATCH_FIELDS, DISPATCH_LAST_FIELDS, DISPATCH_PHASES,
+    DISPATCH_SPANS,
     LOOP_DISPATCH_FIELDS, MOE_DISPATCH_FIELDS, STALL_CAUSES, STALL_FIELDS,
     STALL_S, DispatchTrace)
 
@@ -129,7 +130,7 @@ def test_records_add_up_to_the_counters_that_were_there(model):
             r.wait(timeout=300)
         st = settled_stats(eng)
     dt, recs = st["dispatch_trace"], rows(st)
-    assert tuple(dt["fields"]) == DISPATCH_FIELDS
+    assert tuple(dt["fields"]) == DISPATCH_FIELDS + DISPATCH_LAST_FIELDS
     assert dt["seq"] == st["mixed"]["dispatches"] == len(recs)
     assert [r["seq"] for r in recs] == list(range(1, dt["seq"] + 1))
     assert (sum(r["prefill_tokens"] for r in recs)
@@ -451,6 +452,87 @@ def test_a_dispatch_launched_before_its_predecessor_commits(monkeypatch):
     assert tr.snapshot()["ahead_misses"]["other"] == 1
 
 
+def test_a_dispatch_launched_before_its_predecessor_returned(monkeypatch):
+    """An early launch: the cut falls inside the predecessor's execution,
+    so its ``wait`` ends there and the successor's holds the read of the
+    predecessor, whose ``await``, ``late`` and ``t_done``
+    (``returned``) are still its own; the successor's record says
+    ``early`` in its last column and ``ahead_early`` counts it, and from
+    its launch to its commit the snapshot shows it as a row of its
+    number and ``t_launch`` alone (``t_done`` 0).  An early
+    launch that fails its requests puts the cursor back under the
+    dispatch that is still running."""
+    monkeypatch.setattr(tracing, "time", ticking(100.0, 0.001))
+    tr = DispatchTrace()
+    fields = dict(with_finals=False, segments=2, finals=0,
+                  prefill_tokens=16, active_rows=1, steps=4, kv_tokens=8)
+    tr.enter("pack")
+    t1 = tr.enter("launch")
+    first = tr.launched_phases
+    tr.enter("wait")
+    with tr.ahead() as spent:          # the next one, prepared: closed
+        pass
+    with tr.ahead("ahead_launch"):     # enqueued behind the first: work
+        t2 = tr.enter("wait", cut=True)     # under it, no phase of a gap
+        second = tr.launched_phases
+        tr.opened(t2)
+    # in flight, it shows after the ring: its number, its launch, `early`
+    assert tr.snapshot()["recent"] == [
+        [2, round(t2, 5), 0.0] + [0] * (len(DISPATCH_FIELDS) - 3) + [1]]
+    tr.awaiting(True, first)           # the read of the FIRST
+    d1 = tr.returned()
+    assert tr.launched == 2 and tr.seq == 0 and t2 < d1
+    with tr.ahead("ahead_drain"):      # the first one, drained
+        pass
+    tr.commit(t_launch=t1, t_done=d1, phases=first, how="first", **fields)
+    assert [r[0] for r in tr.snapshot()["recent"]] == [1, 2]
+    with tr.ahead():                   # the third, prepared: not closed
+        pass
+    tr.awaiting(False, second)
+    d2 = tr.enter("drain")
+    tr.commit(t_launch=t2, t_done=d2, ahead=spent[0], how="hit", early=True,
+              **fields)
+    monkeypatch.undo()
+    snap = tr.snapshot()
+    assert snap["fields"][-1] == "early"
+    # the committed record took the place of the row it had in flight
+    a, b = (dict(zip(snap["fields"], r)) for r in snap["recent"])
+    assert [a[p] for p in DISPATCH_PHASES] == [0, 0, .001, .001, .004, 0]
+    # no pack and no launch before an early one: its seconds are `wait`
+    # from the cut on, its call among them
+    assert [b[p] for p in DISPATCH_PHASES] == [0, 0, 0, 0, .009, .001]
+    assert (a["await"], a["late"], a["early"]) == (.001, 1, 0)
+    assert (b["await"], b["late"], b["early"]) == (.001, 0, 1)
+    # the first's read lies in the second's wait, past its own
+    assert a["t_launch"] + a["launch"] + a["wait"] == pytest.approx(
+        b["t_launch"])
+    assert b["t_launch"] < a["t_done"] < b["t_done"]
+    assert b["launch"] + b["wait"] == pytest.approx(d2 - t2)
+    assert snap["phase_s"]["wait"] == pytest.approx(.013)
+    assert snap["phase_s"]["launch"] == pytest.approx(.001)
+    assert snap["phase_s"]["ahead"] == pytest.approx(.005)
+    assert snap["spans"]["ahead_launch"]["n"] == 1
+    assert snap["spans"]["await"]["n"] == 2 and snap["late_reads"] == 1
+    assert (snap["ahead_hits"], snap["ahead_hits_slab"],
+            snap["ahead_early"], snap["ahead_first"]) == (1, 1, 1, 1)
+    # an early launch that fails: the running one is the last launched
+    tr.enter("pack")
+    tr.enter("launch")
+    running = tr.launched_phases
+    tr.enter("wait")
+    tr.enter("wait", cut=True)         # early, and it raises
+    tr.abandon()                       # under the running one again
+    assert tr.launched == 3 and tr.launched_phases is running
+    assert tr._into is running and tr._seq == 3
+    tr.awaiting(False, running)
+    tr.commit(t_launch=1.0, t_done=tr.enter("drain"), how="hit", ahead=.001,
+              **fields)
+    assert tr.seq == tr.launched == 3
+    # a hit that was not early is no early one
+    assert tr.snapshot()["recent"][-1][-1] == 0
+    assert (tr.ahead_hits, tr.ahead_early) == (2, 1)
+
+
 def test_a_prepared_dispatch_that_fails_hands_the_cursor_back(monkeypatch):
     """A dispatch launched as prepared, under a predecessor that is
     still to be drained, fails its requests (``abandon``): the
@@ -506,7 +588,8 @@ def test_the_blocking_read_is_the_end_of_wait(scripted):
     spans = dt["spans"]
     assert tuple(spans) == DISPATCH_SPANS and "wait" not in spans
     assert (spans["ahead_plan"]["wall_s"] + spans["ahead_drain"]["wall_s"]
-            == pytest.approx(dt["phase_s"]["ahead"], abs=2e-6))
+            + spans["ahead_launch"]["wall_s"]
+            == pytest.approx(dt["phase_s"]["ahead"], abs=3e-6))
     for name, sp in spans.items():
         assert set(sp) == {"n", "wall_s", "cpu_s", "max_s"}
         assert 0 <= sp["max_s"] <= sp["wall_s"]
@@ -683,7 +766,8 @@ def test_a_models_own_columns_follow_the_base_columns(model, extra):
         eng.submit(LONG, 6).wait(timeout=300)
         st = settled_stats(eng)
     dt = st["dispatch_trace"]
-    assert tuple(dt["fields"]) == DISPATCH_FIELDS + extra
+    assert tuple(dt["fields"]) == (DISPATCH_FIELDS + extra
+                                   + DISPATCH_LAST_FIELDS)
     assert all(len(r) == len(dt["fields"]) for r in dt["recent"])
     for r in rows(st):
         assert r["late"] in (0, 1) and 0 < r["await"] <= r["wait"] + ROUNDING

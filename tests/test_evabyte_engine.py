@@ -4,6 +4,7 @@
 through windows that close in the slab, in the middle of a fused decode
 block and across rows that finish and are replaced.  CPU, toy widths
 (``evabyte-test``: window 16, chunk 2, pages of 8)."""
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,6 +19,7 @@ from distributed_inference_demo_tpu.ops import eva_attention as eva
 from distributed_inference_demo_tpu.ops.sampling import SamplingParams
 from distributed_inference_demo_tpu.runtime.batching import (
     ContinuousBatchingEngine)
+from test_mixed_batching import settle
 
 CFG = get_model_config("evabyte-test")
 SPEC = StageSpec(0, 1, 0, CFG.num_layers)
@@ -74,6 +76,7 @@ def _dense_greedy(params, prompt, new):
 
 
 def _records(eng):
+    settle(eng)     # a request's wait returns before its last commit
     dt = eng.stats()["dispatch_trace"]
     return [dict(zip(dt["fields"], row)) for row in dt["recent"]]
 
@@ -169,9 +172,9 @@ def test_the_dispatch_records_count_rows_not_tokens(params):
             eng.submit(_prompt(n, 20 + i), new).wait(60)
         recs = _records(eng)
         st = eng.stats()
-    assert st["dispatch_trace"]["fields"][-4:] == [
+    assert st["dispatch_trace"]["fields"][-5:] == [
         "kv_attended_rows", "kv_summary_rows", "prefill_attended_rows",
-        "windows_closed"]
+        "windows_closed", "early"]
     # a slab's (query, row) pairs: each token its window's earlier keys,
     # itself and every closed window's summaries
     want = sum(sum(eva_rows(W, C, p + 1)) for n in plens for p in range(n))

@@ -587,6 +587,7 @@ def test_mixed_matches_serialized_property_sweep(params, kv_dtype,
 
 KEEPER = list(range(2, 24))            # 22 tokens: two whole pages + 6
 LONG40 = list(range(30, 70))           # five segments of eight
+LONG72 = list(range(100, 172))         # nine: eight chunks and the final
 # what reaches the scheduler, keyed by the call of ``mixed_step`` it
 # follows: the hook below acts on the scheduler's own thread right after
 # that call was enqueued, so each event lands DURING that execution
@@ -647,6 +648,15 @@ SCRIPTS = {
         2: [("submit", "victim", LONG40, 6)],
         "raise_at": 4,
         7: [("submit", "tail", LONG40[::-1], 3)],
+    },
+    # two rows decoding (room for two segments) and a prompt of nine:
+    # every slab but the gap's first and the final's is packed full under
+    # its predecessor, and enqueued behind it (calls 5, 6, 7); a second
+    # long prompt follows it through the same dispatches
+    "full_slab": {
+        1: [("submit", "row", [9, 2, 6], 40)],
+        3: [("submit", "long", LONG72, 6),
+            ("submit", "next", LONG72[::-1], 4)],
     },
 }
 RECORD_FIELDS = ("segments", "finals", "prefill_tokens", "active_rows",
@@ -897,7 +907,30 @@ def _a_failed_slab_fails_its_request_alone(ahead, old):
     assert len(ahead["recs"]) == ahead["trace"]["seq"]
 
 
+def _full_slabs_went_behind_their_predecessors(ahead, old):
+    streams = ahead["same"]["streams"]
+    recs = {r["seq"]: r for r in ahead["recs"]}
+    assert len(streams["row"][0]) == 40 and len(streams["keeper"][0]) == 60
+    assert len(streams["long"][0]) == 6 and len(streams["next"][0]) == 4
+    for name in ("long", "next"):
+        first, final = streams[name][4:]
+        # two segments a dispatch, the first two packed in the gap (the
+        # prompt had just arrived, or waited for a slot) and every other
+        # pair enqueued while its predecessor ran; the final rode alone,
+        # a segment to spare: prepared, and launched when that returned
+        assert final == first + 4
+        slabs = [recs[n] for n in range(first, final + 1)]
+        assert [r["segments"] for r in slabs] == [2, 2, 2, 2, 1]
+        assert [r["ahead"] > 0 for r in slabs] == [False] + [True] * 4
+        assert [r["early"] for r in slabs] == [0, 1, 1, 1, 0]
+        assert all(r["active_rows"] >= 1 for r in slabs)
+    assert ahead["trace"]["ahead_early"] == 6
+    # a plan that packs nothing is never closed
+    assert any(r["ahead"] > 0 and not r["segments"] for r in recs.values())
+
+
 _CASE_CHECKS = {
+    "full_slab": _full_slabs_went_behind_their_predecessors,
     "long_prompt": _long_prompt_was_prepared,
     "two_admissions": _two_admissions_were_packed_in_order,
     "parked_final": _an_ended_rows_slot_waited_for_its_drain,
@@ -956,6 +989,28 @@ def test_prepared_dispatches_change_nothing_but_the_order(params, sampled,
         if b["ahead"] > 0:
             assert b["active_rows"] > 0 or b["segments"] > 0
             assert 0 < b["ahead"] <= a["wait"] + 2e-5
+    _early_launches_are_counted(ahead, old, with_eos)
+
+
+def _early_launches_are_counted(ahead, old, with_eos):
+    """``ahead_early`` counts the hits whose record says ``early``: they
+    carried a slab, were launched before their predecessor's ``t_done``
+    and every other dispatch after it; none with every plan refused, none
+    on an engine with an ``eos``."""
+    dt, recs = ahead["trace"], ahead["recs"]
+    assert dt["fields"][-1] == "early"
+    assert dt["ahead_early"] == sum(r["early"] for r in recs)
+    assert dt["ahead_early"] <= dt["ahead_hits_slab"]
+    assert old["trace"]["ahead_early"] == 0
+    assert not any(r["early"] for r in old["recs"])
+    if with_eos:
+        assert dt["ahead_early"] == 0
+    for a, b in zip(recs, recs[1:]):
+        if b["early"]:
+            assert b["ahead"] > 0 and b["segments"] > 0
+            assert b["t_launch"] < a["t_done"] < b["t_done"]
+        else:
+            assert b["t_launch"] >= a["t_done"]
 
 
 _PROFILED = {}
@@ -992,17 +1047,27 @@ def test_a_profiler_sample_changes_no_schedule(params, sampled):
 def test_a_profiler_sample_is_the_dispatch_records_time(params):
     """With every dispatch sampled the profiler holds one sample a mixed
     dispatch, under ``mixed_step`` signatures alone, and their seconds
-    are the records' own ``t_done - t_launch``: no second clock."""
-    run = profiled_run(params, False, 1)
-    recs, profile = run["recs"], run["profile"]
-    assert profile and all(sig.startswith("mixed_step|") for sig in profile)
-    assert sum(n for n, _ in profile.values()) == len(recs) == run[
-        "trace"]["seq"]
-    # a record's instants are rounded to 1e-5 s
-    assert sum(t for _, t in profile.values()) == pytest.approx(
-        sum(r["t_done"] - r["t_launch"] for r in recs),
-        abs=2e-5 * len(recs))
-    assert all(r["t_done"] > r["t_launch"] for r in recs)
+    are the records' own ``t_done - t_launch``: no second clock.  An
+    early dispatch's sample starts when its predecessor returned: what
+    it spent queued behind it is no time of its own."""
+    sampling = GREEDY
+    for case in ("base", "full_slab"):
+        run = (profiled_run(params, False, 1) if case == "base" else
+               scripted_run(params, sampling, None, False, case, sample_n=1))
+        recs, profile = run["recs"], run["profile"]
+        assert profile and all(sig.startswith("mixed_step|")
+                               for sig in profile)
+        assert sum(n for n, _ in profile.values()) == len(recs) == run[
+            "trace"]["seq"]
+        begun = [b["t_launch"] if not b["early"] else a["t_done"]
+                 for a, b in zip([None] + recs, recs)]
+        # a record's instants are rounded to 1e-5 s
+        assert sum(t for _, t in profile.values()) == pytest.approx(
+            sum(r["t_done"] - t0 for r, t0 in zip(recs, begun)),
+            abs=2e-5 * len(recs))
+        assert all(r["t_done"] > t0 >= r["t_launch"]
+                   for r, t0 in zip(recs, begun))
+    assert run["trace"]["ahead_early"] == 6 and run["synced"] == []
 
 
 def test_the_mixed_loop_never_blocks_on_the_device(params):

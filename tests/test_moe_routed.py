@@ -32,7 +32,8 @@ from distributed_inference_demo_tpu.parallel.tensor import make_tp_stage_fn
 from distributed_inference_demo_tpu.runtime.batching import (
     ContinuousBatchingEngine)
 from distributed_inference_demo_tpu.telemetry.tracing import (
-    DISPATCH_FIELDS, MOE_DISPATCH_FIELDS, DispatchTrace, MoeCounters)
+    DISPATCH_FIELDS, DISPATCH_LAST_FIELDS, MOE_DISPATCH_FIELDS, DispatchTrace,
+    MoeCounters)
 
 OLMOE = get_model_config("olmoe-test")          # 8 experts, 2 a token
 MIXTRAL = get_model_config("mixtral-test")      # 4 experts, 2 a token
@@ -372,7 +373,8 @@ def test_moe_counters_sum_to_tokens_times_k_times_layers(olmoe_run):
     cfg, _, prompts, outs, _, st = olmoe_run
     k, L, E = cfg.experts_per_token, cfg.num_layers, cfg.num_experts
     moe, dt = st["moe"], st["dispatch_trace"]
-    assert dt["fields"] == list(DISPATCH_FIELDS + MOE_DISPATCH_FIELDS)
+    assert dt["fields"] == list(DISPATCH_FIELDS + MOE_DISPATCH_FIELDS
+                                + DISPATCH_LAST_FIELDS)
     recs = [dict(zip(dt["fields"], r)) for r in dt["recent"]]
     assert len(recs) == moe["dispatches"] == dt["seq"]
     chunk, slots = 16, 4
@@ -446,7 +448,8 @@ def test_dense_engine_has_no_moe_section_and_the_record_is_unchanged():
         eng.submit(np.arange(1, 20, dtype=np.int32), 4).wait(timeout=300)
         st = eng.stats()
     assert "moe" not in st
-    assert st["dispatch_trace"]["fields"] == list(DISPATCH_FIELDS)
+    assert st["dispatch_trace"]["fields"] == list(DISPATCH_FIELDS
+                                                  + DISPATCH_LAST_FIELDS)
 
 
 def test_dispatch_trace_extra_fields_and_counters():
@@ -461,8 +464,10 @@ def test_dispatch_trace_extra_fields_and_counters():
               finals=0, prefill_tokens=0, active_rows=1, steps=1,
               kv_tokens=3, **extra)
     snap = tr.snapshot()
-    assert snap["fields"][-4:] == list(MOE_DISPATCH_FIELDS)
-    assert snap["recent"][0][-4:] == [8, 6, 2, 5]
+    # a model's own columns, then the one that ends every record
+    assert snap["fields"][-5:] == list(MOE_DISPATCH_FIELDS
+                                       + DISPATCH_LAST_FIELDS)
+    assert snap["recent"][0][-5:] == [8, 6, 2, 5, 0]
     c.add([1, 1, 0, 0], touched=2, load_max=1, layer_calls=1, valid_rows=2)
     assert c.snapshot() == {
         "experts": 4, "dispatches": 2, "rows": 10, "valid_rows": 8,

@@ -42,7 +42,7 @@ from distributed_inference_demo_tpu.parallel.tensor import (  # noqa: E402
 from distributed_inference_demo_tpu.runtime.batching import (  # noqa: E402
     ContinuousBatchingEngine)
 from distributed_inference_demo_tpu.telemetry.tracing import (  # noqa: E402
-    DISPATCH_FIELDS, LOOP_DISPATCH_FIELDS, LoopCounters)
+    DISPATCH_FIELDS, DISPATCH_LAST_FIELDS, LOOP_DISPATCH_FIELDS, LoopCounters)
 from tests.test_mixed_batching import abstract_mixed_call  # noqa: E402
 
 CFG = get_model_config("ouro-test")
@@ -312,7 +312,8 @@ def test_stats_loop_sums_equal_the_dispatch_records(params):
             r.wait(timeout=300)
         st = _settled(eng)
     dt, loop = st["dispatch_trace"], st["loop"]
-    assert dt["fields"] == list(DISPATCH_FIELDS + LOOP_DISPATCH_FIELDS)
+    assert dt["fields"] == list(DISPATCH_FIELDS + LOOP_DISPATCH_FIELDS
+                                + DISPATCH_LAST_FIELDS)
     recs = [dict(zip(dt["fields"], r)) for r in dt["recent"]]
     assert len(recs) == loop["dispatches"] == dt["seq"]
     for r in recs:
@@ -496,4 +497,6 @@ def test_one_pass_record_and_stats_are_the_parent_s(model):
     fields[at:at] = ["prefill_pages_walked"]
     # ... and PR 48's, the rows the head ran over
     fields[at + 1:at + 1] = ["head_rows"]
+    # ... and PR 54's, after a model's own columns: enqueued early or not
+    fields.append("early")
     assert st["dispatch_trace"]["fields"] == fields

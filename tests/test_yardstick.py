@@ -55,5 +55,7 @@ def test_the_benchmarks_own_suite_passes():
         [sys.executable, "-m", "pytest", str(BENCH / "tests"), "-q",
          "-p", "no:cacheprovider"],
         cwd=BENCH.parent, env=env, capture_output=True, text=True,
-        timeout=300)
+        # alone it takes 100-240 s by the machine's load, and it runs
+        # beside five other workers (PR 54: 300 s cut it twice)
+        timeout=900)
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-1000:]
