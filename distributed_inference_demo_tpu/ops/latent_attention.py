@@ -19,12 +19,19 @@ output outside.  It is multi-query attention over one shared key:
   ``(rows of the batch, query tiles)``: a tile is ``tq`` chunk positions x
   all heads (``tq x nh`` query rows, a decode step is one tile of ``nh``
   rows), and walks the row's live pages up to the tile's own causal
-  frontier through a VMEM ring, folding each ``[bt, width]`` page into
-  float32 online-softmax state: scores from ``[rows, width] x [width, bt]``,
-  output from the same page's first ``rank`` lanes.  The pool stays in HBM
-  (``pl.ANY``) and only the pages walked move.  The two jitted wrappers
-  carry the names the trace readers know: ``_paged_call_latent`` (decode)
-  and ``_paged_prefill_call_latent`` (a chunk over its cached context).
+  frontier through a VMEM ring, a GROUP of pages an iteration: ``G``
+  ``[bt, width]`` pages land side by side in one slot (a DMA each) and
+  fold into float32 online-softmax state as one block of keys, scores
+  from ``[rows, width] x [width, G x bt]``, output from the same rows'
+  first ``rank`` lanes, the state rescaled once.  ``G`` and the ring's
+  depth are read off the call's shapes (:func:`latent_fold`): what an
+  iteration costs beside its bytes (a chain of dependent steps, each
+  waiting for the last, and in a tile of 1,024 rows a pass over 3 MiB
+  of state) is paid once a group.
+  The pool stays in HBM (``pl.ANY``) and only the pages walked move.
+  The two jitted wrappers carry the names the trace readers know:
+  ``_paged_call_latent`` (decode) and ``_paged_prefill_call_latent`` (a
+  chunk over its cached context).
 """
 
 import functools
@@ -44,12 +51,30 @@ from .stacked import LayerOf
 _NEG = -1e30
 _LANES = 128
 # query rows one tile holds (chunk positions x heads): the float32
-# accumulator [rows, rank] (2 MiB) and the scores [rows, bt] live in
+# accumulator [rows, rank] (2 MiB) and the scores [rows, G x bt] live in
 # VMEM.  A slab call over 4k + 8k of context took 2.95 ms a layer at 512
 # rows and 2.58 at 1,024 (my chip run, PR 44): a page is read once a tile
 _TILE_ROWS = 1024
-# pages in flight ahead of the fold
-_RING = 4
+# what ONE fold iteration takes (:func:`latent_fold`): a group of pages,
+# as many as these three allow.  Bytes of pages side by side in one slot
+# of the ring (what holds a decode step's group); bytes of the float32
+# scores [rows, G x bt] the iteration holds (what holds a slab tile's:
+# 0.5 MiB a page at 1,024 rows); copies an iteration starts and waits
+# for by hand, a DMA and a semaphore each.  us a page of 128 x 640 bf16
+# by G = 1 / 2 / 4 / 8 (my chip runs, PR 55; 0.20 is the page's bytes at
+# 819 GB/s): a decode step of 32 rows over ~67 pages 0.60 / 0.37 / 0.29
+# / 0.28, and no ring depth moves G = 1; a two-segment slab over 4k + 8k
+# (a page a tile) 3.26 / 2.63 / 2.52 / 2.54, while a prompt's first
+# chunk, whose tiles fill no group, pays 188 / 184 / 219 / 376 us a call
+_GROUP_BYTES = 640 << 10
+_SCORES_BYTES = 2 << 20
+_GROUP_PAGES = 8
+# bytes of pages on their way while a group folds: the ring is the group
+# folding and as many groups landing as hold these.  A decode step at
+# G = 4: 0.31-0.34 us a page with one group landing, 0.27-0.30 with two
+# (my chip runs, PR 55); a slab tile does not feel the depth
+_FLIGHT_BYTES = 1280 << 10
+_VMEM_LIMIT = 32 << 20
 # the online-softmax state (running maximum, sum and output) between
 # pages: float32.  A name of its own so that a parity tool can read the
 # path against the next precision down (``tools/model_parity.py``)
@@ -138,35 +163,65 @@ def latent_gather_attention(q_abs: jnp.ndarray, pages, tables: jnp.ndarray,
     return latent_attend_linear(q_abs, lin, q_positions, rank, scale)
 
 
+def latent_fold(rows: int, block_tokens: int, width: int, itemsize: int,
+                table_pages: int) -> tuple:
+    """``(group, ring)`` of one compiled call, read off its shapes: the
+    pages ONE fold iteration takes (side by side in a slot, one scores
+    product, one maximum, one rescale of the state over all of them) and
+    the slots of the ring (one folding, the rest landing; never more
+    than the table fills).  ``rows`` is the tile's query rows
+    (``tile_tokens x heads``).  Over 160 KiB pages a decode step of 32
+    heads and a slab tile of 1,024 rows both fold 4 pages an iteration
+    through 3 slots, the one held by a slot's bytes and the other by its
+    scores."""
+    page = block_tokens * width * itemsize
+    group = max(1, min(_GROUP_BYTES // page,
+                       _SCORES_BYTES // (rows * block_tokens * 4),
+                       _GROUP_PAGES, table_pages))
+    landing = min(-(-_FLIGHT_BYTES // (group * page)),
+                  -(-table_pages // group))
+    return group, 1 + landing
+
+
 def _latent_kernel(tab_ref, start_ref, layer_ref, q_ref, pool_hbm, o_ref,
                    buf, sems, o_acc, m_acc, l_acc, *, block_tokens: int,
-                   heads: int, tile_tokens: int, rank: int, ring: int,
-                   scale: float):
+                   heads: int, tile_tokens: int, rank: int, group: int,
+                   ring: int, scale: float):
     """Grid (b, query tiles).  Tile ``t`` of row ``b`` holds chunk
     positions ``[t * tq, (t + 1) * tq)`` x ``heads``: query row ``r`` is
     position ``start + t * tq + r // heads``.  It walks pages
     ``0 .. ceil((start + (t + 1) * tq) / bt)`` of ``tab_ref[b]`` (never
-    past the table): page ``j`` is copied into slot ``j % ring`` while
-    earlier pages fold.  A row whose ``start`` is ``-chunk`` (no page: a
-    freed slot) walks none and its output is zero."""
+    past the table), ``group`` pages a fold: page ``j`` is copied, a DMA
+    of its own, into part ``j % group`` of slot ``(j // group) % ring``
+    while earlier groups fold.  A part of the last group that no page
+    fills is zeroed before the fold reads it (its keys are behind the
+    mask, and zero times whatever VMEM held would not be zero).  A row
+    whose ``start`` is ``-chunk`` (no page: a freed slot) walks none and
+    its output is zero."""
     b, t = pl.program_id(0), pl.program_id(1)
     layer = layer_ref[0]
     num_pages, W = pool_hbm.shape[1], tab_ref.shape[1]
-    bt, tq = block_tokens, tile_tokens
+    bt, tq, G = block_tokens, tile_tokens, group
     rows = q_ref.shape[1]
     first = start_ref[b] + t * tq                 # the tile's first position
     n_live = jnp.clip((first + tq + bt - 1) // bt, 0, W)
 
-    def page_copy(j):
-        page = jnp.minimum(tab_ref[b, j], num_pages - 1)
-        slot = j % ring
-        return pltpu.make_async_copy(pool_hbm.at[layer, page, 0],
-                                     buf.at[slot], sems.at[slot])
+    def part(gi, g):
+        return buf.at[gi % ring, g * bt:(g + 1) * bt]
 
-    for j in range(ring - 1):
-        @pl.when(j < n_live)
-        def _prime():
-            page_copy(j).start()
+    def page_copy(gi, g):
+        page = jnp.minimum(tab_ref[b, gi * G + g], num_pages - 1)
+        return pltpu.make_async_copy(pool_hbm.at[layer, page, 0],
+                                     part(gi, g), sems.at[gi % ring, g])
+
+    def start_group(gi):
+        for g in range(G):
+            @pl.when(gi * G + g < n_live)
+            def _start():
+                page_copy(gi, g).start()
+
+    for gi in range(ring - 1):
+        start_group(gi)
 
     o_acc[...] = jnp.zeros_like(o_acc)
     m_acc[...] = jnp.full_like(m_acc, _NEG)
@@ -175,18 +230,26 @@ def _latent_kernel(tab_ref, start_ref, layer_ref, q_ref, pool_hbm, o_ref,
     q_pos = first + jax.lax.broadcasted_iota(
         jnp.int32, (rows, 1), 0) // heads
 
-    def fold(j, carry):
-        @pl.when(j + ring - 1 < n_live)
-        def _prefetch():
-            page_copy(j + ring - 1).start()
+    def fold(gi, carry):
+        start_group(gi + ring - 1)
+        for g in range(G):
+            @pl.when(gi * G + g < n_live)
+            def _landed():
+                page_copy(gi, g).wait()
 
-        page_copy(j).wait()
-        k_blk = buf[j % ring]                           # [bt, width]
+            if g:                       # page gi * G is live in every fold
+                @pl.when(gi * G + g >= n_live)
+                def _dead():
+                    part(gi, g)[...] = jnp.zeros((bt, buf.shape[-1]),
+                                                 buf.dtype)
+
+        k_blk = buf[gi % ring]                          # [G * bt, width]
         s = scale * jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        kv_pos = j * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
-        valid = kv_pos <= q_pos                         # [rows, bt]
+        kv_pos = gi * (G * bt) + jax.lax.broadcasted_iota(
+            jnp.int32, (1, G * bt), 1)
+        valid = kv_pos <= q_pos                         # [rows, G * bt]
         s = jnp.where(valid, s, _NEG)
         m = m_acc[:, :1].astype(jnp.float32)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
@@ -213,14 +276,15 @@ def _latent_kernel(tab_ref, start_ref, layer_ref, q_ref, pool_hbm, o_ref,
         l_acc[...] = jnp.broadcast_to(l_new, l_acc.shape).astype(l_acc.dtype)
         return carry
 
-    jax.lax.fori_loop(0, n_live, fold, 0)
+    jax.lax.fori_loop(0, (n_live + G - 1) // G, fold, 0)
     o_ref[0] = (o_acc[...].astype(jnp.float32)
                 / jnp.maximum(l_acc[:, :1].astype(jnp.float32), 1e-30)
                 ).astype(o_ref.dtype)
 
 
 def _latent_call(q_rows, pool, layer, tables, starts, *, block_tokens, heads,
-                 tile_tokens, rank, scale, interpret, state=jnp.float32):
+                 tile_tokens, rank, group, ring, scale, interpret,
+                 state=jnp.float32):
     b, n_rows, width = q_rows.shape
     rows = tile_tokens * heads
     bt = block_tokens
@@ -228,29 +292,29 @@ def _latent_call(q_rows, pool, layer, tables, starts, *, block_tokens, heads,
                                   lambda bb, t, *_: (bb, t, 0))
     return pl.pallas_call(
         functools.partial(_latent_kernel, block_tokens=bt, heads=heads,
-                          tile_tokens=tile_tokens, rank=rank, ring=_RING,
-                          scale=scale),
+                          tile_tokens=tile_tokens, rank=rank, group=group,
+                          ring=ring, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b, n_rows // rows),
             in_specs=[tile(width), pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=tile(rank),
             scratch_shapes=[
-                pltpu.VMEM((_RING, bt, width), pool.dtype),
-                pltpu.SemaphoreType.DMA((_RING,)),
+                pltpu.VMEM((ring, group * bt, width), pool.dtype),
+                pltpu.SemaphoreType.DMA((ring, group)),
                 pltpu.VMEM((rows, rank), state),
                 pltpu.VMEM((rows, _LANES), state),
                 pltpu.VMEM((rows, _LANES), state),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, n_rows, rank), q_rows.dtype),
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=32 << 20),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(tables, starts, layer, q_rows, pool)
 
 
-_STATIC = ("block_tokens", "heads", "tile_tokens", "rank", "scale",
-           "interpret", "state")
+_STATIC = ("block_tokens", "heads", "tile_tokens", "rank", "group", "ring",
+           "scale", "interpret", "state")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -275,6 +339,13 @@ def latent_tile_tokens(chunk: int, heads: int) -> int:
     return tq
 
 
+def _fold_of(pool, tables, chunk: int, heads: int) -> tuple:
+    """:func:`latent_fold` of a call over ``pool`` [.., bt, width]."""
+    return latent_fold(latent_tile_tokens(chunk, heads) * heads,
+                       pool.shape[-2], pool.shape[-1], pool.dtype.itemsize,
+                       tables.shape[1])
+
+
 def latent_paged_attention(q_abs: jnp.ndarray, pages, tables: jnp.ndarray,
                            q_positions: jnp.ndarray, rank: int,
                            scale: float, *,
@@ -292,10 +363,12 @@ def latent_paged_attention(q_abs: jnp.ndarray, pages, tables: jnp.ndarray,
     starts = jnp.where(tables[:, 0] >= num_pages, -chunk,
                        q_positions[:, 0].astype(jnp.int32))
     call = _paged_call_latent if chunk == 1 else _paged_prefill_call_latent
+    tq = latent_tile_tokens(chunk, nh)
+    group, ring = _fold_of(P, tables, chunk, nh)
     out = call(q_abs.reshape(b, chunk * nh, width), P, li.reshape(1), tables,
-               starts, block_tokens=bt, heads=nh,
-               tile_tokens=latent_tile_tokens(chunk, nh), rank=rank,
-               scale=float(scale), interpret=interpret, state=_STATE_DTYPE)
+               starts, block_tokens=bt, heads=nh, tile_tokens=tq, rank=rank,
+               group=group, ring=ring, scale=float(scale),
+               interpret=interpret, state=_STATE_DTYPE)
     return out.reshape(b, chunk, nh, rank)
 
 
@@ -355,7 +428,9 @@ def make_latent_attn_impl(rank: int, scale: float, backend: str = "auto",
         # one head of ``width`` lanes: the pair pools' rule as it stands
         pool = route_pool(backend, platform, pages, chunk)
         if record is not None:
-            record.note(bound["program"], chunk, path, why, pool)
+            record.note(bound["program"], chunk, path, why, pool,
+                        fold_pages=None if path == PATH_GATHER else
+                        _fold_of(_pool(pages), tables, chunk, nh)[0])
         whole = pages
         with jax.named_scope("mla_attend"):
             if pool == POOL_PLANE:

@@ -1294,17 +1294,27 @@ class AttnPathRecord:
     same shape: ``kernel write`` and ``scatter write`` address ``(layer,
     page)`` in the carried pool and copy nothing of it; ``plane`` slices
     the layer's plane out and puts it back, so a program that fell back
-    to planes shows there."""
+    to planes shows there.
+
+    A kernel over latent pages folds a group of pages an iteration, as
+    many as its call's shapes allow (``ops.latent_attention.latent_fold``):
+    the group is static in the compiled program, recorded here and served
+    under ``/stats["fold_pages"]`` in the same shape (no entry for a call
+    that gathers, none for a pool of key / value pairs)."""
 
     def __init__(self):
         self._paths: dict = {}
         self._addressing: dict = {}
+        self._fold_pages: dict = {}
 
     def note(self, program: str, chunk: int, path: str, why: str,
-             pool: str) -> None:
+             pool: str, fold_pages: Optional[int] = None) -> None:
         entry = path if not why else f"{path}: {why}"
         self._paths.setdefault(program, {})[f"chunk={chunk}"] = entry
         self._addressing.setdefault(program, {})[f"chunk={chunk}"] = pool
+        if fold_pages is not None:
+            self._fold_pages.setdefault(program, {})[
+                f"chunk={chunk}"] = fold_pages
 
     @staticmethod
     def _copy(table: dict) -> dict:
@@ -1317,6 +1327,9 @@ class AttnPathRecord:
 
     def addressing(self) -> dict:
         return self._copy(self._addressing)
+
+    def fold_pages(self) -> dict:
+        return self._copy(self._fold_pages)
 
 
 def route_paged_attention(backend: str, platform: str, k_pages,

@@ -2299,6 +2299,9 @@ class ContinuousBatchingEngine:
         # and how the pool reached it: "stacked" (addressed in place in
         # the scan's carry) or "plane" (a layer's plane was copied out)
         out["pool_addressing"] = self.attn_paths.addressing()
+        # and, over latent pages, the pages one fold iteration of the
+        # compiled kernel takes (read off the call's shapes)
+        out["fold_pages"] = self.attn_paths.fold_pages()
         # completed is the MONOTONIC count; the reservoirs are bounded
         # (the last 512 samples feed the percentiles).  deque.__copy__ is
         # atomic under the GIL — plain iteration would race the

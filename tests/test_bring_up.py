@@ -731,7 +731,11 @@ def test_grouped_matmul_gets_through_mosaic(v5e, rows, quant):
 def test_latent_kernels_get_through_mosaic(v5e, b, chunk):
     """The latent (MLA) page write and the page-walking kernel at the
     kanana cell's shapes (8 planes of 2,048 pages of 128 tokens x 640
-    lanes, 32 heads, rank 512): 16 decoding slots and a two-segment slab.
+    lanes, 32 heads, rank 512): 16 decoding slots and a two-segment slab,
+    each with the group of pages and the ring its shapes derive (both
+    fold 4 pages an iteration through 3 slots: a decode step's group is
+    held by a slot's bytes, a slab tile's by its float32 scores) and
+    their scratch inside the kernel's VMEM limit.
     The calls carry the names the trace readers match by prefix, the pool
     is aliased through the write (temporaries far under a plane), and a
     576-wide row, the width as published, is refused by Mosaic's DMA
@@ -740,6 +744,17 @@ def test_latent_kernels_get_through_mosaic(v5e, b, chunk):
     from distributed_inference_demo_tpu.ops.stacked import LayerOf
     S = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=v5e)  # noqa: E731
     L, N, bt, nh, rank = 8, 2048, 128, 32, 512
+    rows = la.latent_tile_tokens(chunk, nh) * nh
+    group, ring = la.latent_fold(rows, bt, 640, 2, 96)
+    assert (group, ring) == (4, 3)
+    # the ring, the float32 state (output, maximum, sum), an iteration's
+    # float32 scores with the weights made of them (the probabilities and
+    # their two bf16 terms), the pipeline's two copies of the query and
+    # output tiles
+    keys = group * bt
+    scratch = (ring * keys * 640 * 2 + rows * (rank + 2 * 128) * 4
+               + rows * keys * (4 + 4 + 2 * 2) + 2 * rows * (640 + rank) * 2)
+    assert scratch < la._VMEM_LIMIT, scratch
 
     def step(q, pool, li, tables, pos, row):
         pages = la.write_latent_pages(LayerOf(pool, li), row, tables, pos,

@@ -24,6 +24,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
@@ -382,6 +383,7 @@ def test_dispatch_record_counts_what_the_prefill_kernel_attends_over(params):
     with _engine(params, **MIXED) as eng:
         eng.submit(np.arange(1, n + 1, dtype=np.int32), 3).wait(timeout=300)
         dt = _settled(eng)["dispatch_trace"]
+        assert eng.stats()["fold_pages"] == {}       # the CPU gathers
     col = dt["fields"].index("prefill_kv_tokens")
     assert "moe_rows" in dt["fields"]
     assert sum(r[col] for r in dt["recent"]) == n * (n + 1) // 2
@@ -389,24 +391,55 @@ def test_dispatch_record_counts_what_the_prefill_kernel_attends_over(params):
 
 # ------------------------------------------------------------ the kernels
 
+# rows of one call, by where their last group of pages ends (the table is
+# as wide as two groups and a half): positions of a decode step, first
+# positions of a chunk of 32; ``None`` is a freed slot
+_GROUP_ENDS = {1: [170, 255, 70, 7, None], 32: [135, 209, 0, 32, None]}
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("chunk", [1, 32])
-def test_latent_kernels_equal_the_xla_paths(dtype, chunk):
+@pytest.mark.parametrize("rows", ["mixed", "group_ends"])
+def test_latent_kernels_equal_the_xla_paths(dtype, chunk, rows, monkeypatch):
     """Interpreted: the page write against the scatter (bit for bit) and
     the page-walking kernel against the gather, decode (one of whose rows
-    is a freed slot) and a chunk over its cached context."""
+    is a freed slot) and a chunk over its cached context.  ``group_ends``:
+    a fold takes 8 pages here, and the rows end inside a group (11 pages),
+    on a group's boundary (16), before one is full (5; a chunk's 2 and 4)
+    and after exactly one page (a decode step's; a chunk's first tile),
+    beside a freed slot; the chunk runs as two query tiles of its own
+    frontiers.  Every page outside the live part of a table is NaN, a
+    table's dead entries are the sentinel, whose clamp is such a page,
+    and the interpreter's VMEM starts as NaN: a part of a slot that no
+    copy filled, or a dead entry that was copied, reaches a product and
+    fails the comparison."""
     dt = jnp.dtype(dtype)
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(chunk), 3)
     N, bt, nh, rank, width = 20, 16, 4, 128, 256
-    b, starts = (3, [37, 0, 5]) if chunk == 1 else (2, [16, 48])
+    interpret = True
+    if rows == "mixed":
+        W = 5
+        starts = [37, None, 5] if chunk == 1 else [16, 48]
+    else:
+        N, W, starts = 128, 20, _GROUP_ENDS[chunk]
+        monkeypatch.setattr(la, "_TILE_ROWS", 16 * nh)
+        assert la.latent_fold(min(chunk, 16) * nh, bt, width, dt.itemsize,
+                              W)[0] == 8
+        interpret = pltpu.InterpretParams()    # uninitialized VMEM is NaN
+    b = len(starts)
     pool = jax.random.normal(k1, (3, N, 1, bt, width)).astype(dt)
     q = (0.3 * jax.random.normal(k2, (b, chunk, nh, width))).astype(dt)
     row = jax.random.normal(k3, (b, chunk, width)).astype(dt)
-    tables = jnp.asarray(np.random.RandomState(0).permutation(N)[:b * 5]
-                         .reshape(b, 5), jnp.int32)
-    if chunk == 1:
-        tables = tables.at[1].set(N)
-    pos = jnp.asarray(starts)[:, None] + jnp.arange(chunk)[None]
+    tables = np.random.RandomState(0).permutation(N - 1)[:b * W].reshape(b, W)
+    held = np.zeros(N, bool)
+    for r, s in enumerate(starts):
+        n_live = 0 if s is None else (s + chunk + bt - 1) // bt
+        held[tables[r, :n_live]] = True
+        if rows == "group_ends" or s is None:
+            tables[r, n_live:] = N
+    tables = jnp.asarray(tables, jnp.int32)
+    pos = (jnp.asarray([0 if s is None else s for s in starts])[:, None]
+           + jnp.arange(chunk)[None])
     pages = LayerOf(pool, jnp.int32(1))
     wrote = {form: la.write_latent_pages(pages, row, tables, pos, form=form,
                                          interpret=True).stack
@@ -416,13 +449,41 @@ def test_latent_kernels_equal_the_xla_paths(dtype, chunk):
     np.testing.assert_array_equal(wrote[la.WRITE_KERNEL][0], pool[0])
     pages = LayerOf(wrote[la.WRITE_KERNEL], jnp.int32(1))
     want = la.latent_gather_attention(q, pages, tables, pos, rank, 0.3)
+    if rows == "group_ends":
+        pages = LayerOf(jnp.where(jnp.asarray(held)[None, :, None, None,
+                                                    None], pages.stack,
+                                  jnp.nan), pages.layer)
     got = la.latent_paged_attention(q, pages, tables, pos, rank, 0.3,
-                                    interpret=True)
+                                    interpret=interpret)
     live = np.asarray(tables[:, 0] < N)
     np.testing.assert_allclose(
         np.asarray(got, np.float32)[live], np.asarray(want, np.float32)[live],
         atol=5e-6 if dtype == "float32" else 4e-3)
     assert not np.asarray(got, np.float32)[~live].any()
+
+
+def test_the_pages_a_fold_takes_are_recorded_beside_the_path():
+    """``AttnPathRecord.fold_pages`` (``/stats["fold_pages"]``): the group
+    the traced kernel call has, per program and chunk; a call that
+    gathers has none."""
+    N, bt, nh, rank, width, W = 24, 16, 8, 128, 256, 6
+    pool = jnp.zeros((2, N, 1, bt, width), jnp.bfloat16)
+    tables = jnp.arange(2 * W, dtype=jnp.int32).reshape(2, W)
+    record = la.AttnPathRecord()
+    for backend, program in (("pallas", "kernels"), ("xla", "gather")):
+        impl, bind = la.make_latent_attn_impl(rank, 0.3, backend=backend,
+                                              interpret=True, record=record)
+        for chunk in (1, 16):
+            bind(tables, program)
+            q = jnp.zeros((2, chunk, nh, width), jnp.bfloat16)
+            pos = 20 + jnp.zeros((2, 1), jnp.int32) + jnp.arange(chunk)
+            jax.eval_shape(lambda q, p, pos: impl(
+                q, q[:, :, 0], LayerOf(p, jnp.int32(0)), pos), q, pool, pos)
+    want = {f"chunk={c}": la.latent_fold(c * nh, bt, width, 2, W)[0]
+            for c in (1, 16)}
+    assert want == {"chunk=1": W, "chunk=16": W}    # never past the table
+    assert record.fold_pages() == {"kernels": want}
+    assert set(record.snapshot()) == {"kernels", "gather"}
 
 
 def test_routing_of_latent_pages():
