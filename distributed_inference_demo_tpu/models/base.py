@@ -32,14 +32,17 @@ import jax.numpy as jnp
 class BlockKind:
     """What one KIND of block of a period has of its own: its attention
     (``attn`` "full", or "window" over the last ``window`` tokens: query
-    ``i`` sees key ``j`` iff ``0 <= i - j < window``), its query heads,
-    its rope (``rope_theta``; ``rotary_share`` of a head's channels turn,
-    the first ones, rotate-half within them; ``yarn`` = ``(factor,
-    original positions, beta_fast, beta_slow, attention_factor)`` or
-    empty: ``ops.rope.yarn_frequencies``) and its output gate ("none", or
-    "per-head": each head's output times the sigmoid of a linear map of
-    the block's normed input, one scalar a head, before ``wo``).  Built
-    from a JSON object (``ModelConfig.period``); hashable."""
+    ``i`` sees key ``j`` iff ``0 <= i - j < window``, or "kda": the gated
+    delta rule over a recurrent state a head, behind a depthwise causal
+    convolution of ``conv`` taps, ``ops.kda``; no keys, no pages), its
+    query heads, its rope (``rope_theta``; ``rotary_share`` of a head's
+    channels turn, the first ones, rotate-half within them, 0 = no rope;
+    ``yarn`` = ``(factor, original positions, beta_fast, beta_slow,
+    attention_factor)`` or empty: ``ops.rope.yarn_frequencies``) and its
+    output gate ("none"; "per-head": each head's output times the sigmoid
+    of a linear map of the block's normed input, one scalar a head, before
+    ``wo``; "elementwise": the same with one scalar a CHANNEL of every
+    head).  Built from a JSON object (``ModelConfig.period``); hashable."""
 
     attn: str = "full"
     window: int = 0
@@ -48,15 +51,19 @@ class BlockKind:
     rotary_share: float = 1.0
     yarn: tuple = ()
     gate: str = "none"
+    conv: int = 0
 
     def __post_init__(self):
-        if self.attn not in ("full", "window"):
-            raise ValueError(f"a block kind's attn is 'full' or 'window', "
-                             f"got {self.attn!r}")
+        if self.attn not in ("full", "window", "kda"):
+            raise ValueError(f"a block kind's attn is 'full', 'window' or "
+                             f"'kda', got {self.attn!r}")
         if (self.attn == "window") != (self.window > 0):
             raise ValueError("a window kind states its window, a full "
                              "kind none")
-        if self.gate not in ("none", "per-head"):
+        if (self.attn == "kda") != (self.conv > 1):
+            raise ValueError("a kda kind states its convolution's taps "
+                             "(conv >= 2), another kind none")
+        if self.gate not in ("none", "per-head", "elementwise"):
             raise ValueError(f"unknown gate {self.gate!r}")
         yarn = self.yarn
         if isinstance(yarn, dict):
@@ -268,21 +275,84 @@ class ModelConfig:
         if self.lead_kind is not None and self.lead_dense_layers:
             count[self.lead_kind.window] = self.lead_dense_layers
         for k in self.period:
-            count[k.window] = count.get(k.window, 0) + self.num_layers
+            if k.attn != "kda":         # a state, not pages (below)
+                count[k.window] = count.get(k.window, 0) + self.num_layers
         return tuple(sorted(count.items()))
+
+    @property
+    def state_planes(self) -> int:
+        """Blocks whose cache is a recurrent STATE a request (a kda kind,
+        docs/DESIGN.md section 27), not rows of a page pool: repeat ``r``'s
+        ``j``-th such place holds plane ``r x (places a period) + j``."""
+        return self.num_layers * sum(k.attn == "kda" for k in self.period)
+
+    @property
+    def state_shapes(self) -> tuple:
+        """``((heads, hd, hd), (taps - 1, 3 x heads x hd))`` of one
+        request's entry in one state plane: the float32 state ``[key,
+        value]`` a head, and the convolution's tail, the last ``taps - 1``
+        inputs of the q, k and v channels side by side (the model's
+        dtype)."""
+        kind = next(k for k in self.period if k.attn == "kda")
+        hd = self.head_dim
+        return ((kind.num_heads, hd, hd),
+                (kind.conv - 1, 3 * kind.num_heads * hd))
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """What one request holds in the state pool, whatever its length."""
+        if not self.state_planes:
+            return 0
+        (h, a, b), (t, c) = self.state_shapes
+        return self.state_planes * (h * a * b * 4
+                                    + t * c * self.dtype.itemsize)
+
+    def state_arrays(self, keys, values) -> tuple:
+        """``(state pool, convolution tails)`` out of a cache's ``keys`` and
+        ``values``: THE one place that says where a recurrent state rides,
+        and checks it.  The state is not a field of its own (docs/DESIGN.md
+        section 27 says why): the pool ``[state_planes, rows, heads, hd,
+        hd]`` float32 is the LAST entry of ``keys`` after one pool of pages
+        a cache kind, the tails ``[state_planes, rows, taps - 1, 3 x heads
+        x hd]`` the last of ``values``; a row's row of the pool is its
+        table's last column, after one table a kind side by side
+        (``ops.paged_attention``'s ``impl.for_state``)."""
+        kinds = len(self.cache_kinds)
+        s_shape, c_shape = self.state_shapes
+        state, tails = keys[-1], values[-1]
+        if (len(keys) != kinds + 1 or len(values) != kinds + 1
+                or state.dtype != jnp.float32
+                or state.shape[:1] + state.shape[2:]
+                != (self.state_planes,) + s_shape
+                or tails.shape[:1] + tails.shape[2:]
+                != (self.state_planes,) + c_shape
+                or state.shape[1] != tails.shape[1]):
+            raise ValueError(
+                f"a cache of {kinds} pool(s) of pages and, last, a float32 "
+                f"state pool [{self.state_planes}, rows, *{s_shape}] with "
+                f"its tails [{self.state_planes}, rows, *{c_shape}] was "
+                f"expected; got keys "
+                f"{[(tuple(k.shape), str(k.dtype)) for k in keys]}, values "
+                f"{[tuple(v.shape) for v in values]}")
+        return state, tails
 
     def plane_of(self, block: int) -> tuple:
         """``(pool, plane)`` of block ``block`` (the leading blocks first,
         then the repeats of the period in order): the index of its pool in
         ``cache_kinds`` and its plane there.  Within a pool the leading
-        blocks' planes come first, then repeat by repeat."""
+        blocks' planes come first, then repeat by repeat.  A kda block
+        holds no pages: ``(-1, its plane of the state pool)``."""
         windows = [w for w, _ in self.cache_kinds]
         lead = self.lead_dense_layers
         if block < lead:
             return windows.index(self.lead_kind.window), block
         r, p = divmod(block - lead, len(self.period))
+        if self.period[p].attn == "kda":    # a plane of the state pool
+            mine = [q for q, k in enumerate(self.period) if k.attn == "kda"]
+            return -1, r * len(mine) + mine.index(p)
         w = self.period[p].window
-        mine = [q for q, k in enumerate(self.period) if k.window == w]
+        mine = [q for q, k in enumerate(self.period)
+                if k.window == w and k.attn != "kda"]
         base = lead if (self.lead_kind is not None
                         and self.lead_kind.window == w) else 0
         return windows.index(w), base + r * len(mine) + mine.index(p)
@@ -423,7 +493,16 @@ class KVCache:
             zeros = lambda: tuple(
                 jnp.zeros((planes, batch, heads, max_seq, width), dtype)
                 for _, planes in cfg.cache_kinds)
-            return KVCache(keys=zeros(), values=zeros(),
+            keys, values = zeros(), zeros()
+            if cfg.state_planes:
+                # the recurrent state rides last in ``keys`` and its
+                # convolution tail last in ``values``, a row a sequence
+                s_shape, c_shape = cfg.state_shapes
+                keys += (jnp.zeros((cfg.state_planes, batch) + s_shape,
+                                   jnp.float32),)
+                values += (jnp.zeros((cfg.state_planes, batch) + c_shape,
+                                     cfg.dtype),)
+            return KVCache(keys=keys, values=values,
                            length=jnp.zeros((), jnp.int32))
         shape = ((num_layers + cfg.lead_dense_layers) * cfg.ut_steps,
                  batch, heads, max_seq)
@@ -509,6 +588,27 @@ def require_one_kind(cfg: ModelConfig, what: str) -> None:
             f"--batch-slots --prefill-chunk --mixed-token-budget)")
 
 
+def require_no_state(cfg: ModelConfig, what: str) -> None:
+    """Refuse a model with a recurrent state a request (a kda kind of
+    block: ``state_planes > 0``) where ``what`` is built for a cache that
+    is rows of tokens: a prefix's state is not a block of tokens that can
+    be shared, exported or rolled back, and a rejected token has already
+    moved it.  ``require_token_rows`` asks it first, so whatever refuses
+    a summarised cache refuses a state; called by name only where a state
+    alone is in the way (the engine's serialized interleave, speculation
+    and ``--tp``)."""
+    if cfg.state_planes:
+        raise ValueError(
+            f"{what} does not support a model with a recurrent state "
+            f"(family {cfg.family!r}, {cfg.state_planes} kda blocks): a "
+            f"request's state is {cfg.state_bytes_per_slot} bytes that "
+            f"every token rewrites, not rows a token that stay where they "
+            f"were written, and it is built for those. Serve it on one "
+            f"chip with bf16 pages and a float32 state through the mixed "
+            f"dispatch (serve --batch-slots --prefill-chunk "
+            f"--mixed-token-budget)")
+
+
 def eva_rows(window: int, chunk: int, n: int) -> tuple:
     """``(summary rows, exact rows)`` that hold ``n`` tokens under EVA
     attention (``ModelConfig.eva_window`` / ``eva_chunk``): the summaries
@@ -524,11 +624,13 @@ def eva_rows(window: int, chunk: int, n: int) -> tuple:
 
 
 def require_token_rows(cfg: ModelConfig, what: str) -> None:
-    """Refuse a model whose cache is a window of exact rows plus a
-    summary a chunk (``eva_window > 0``) where ``what`` is built for a
-    row a token that stays where it was written: called where such a
+    """Refuse a model whose cache is not a row a token that stays where it
+    was written, where ``what`` is built for one: a recurrent state a
+    request (``require_no_state``'s sentence), or a window of exact rows
+    plus a summary a chunk (``eva_window > 0``).  Called where such a
     thing is built, so the model is refused in a sentence and never run
     wrongly."""
+    require_no_state(cfg, what)
     if cfg.summary_kv:
         raise ValueError(
             f"{what} does not support a model with a summarised cache "
@@ -564,10 +666,10 @@ def slice_stage(full: StageParams, cfg: ModelConfig, spec: StageSpec) -> StagePa
     ONNX zips, realized as array slices.
     """
     if spec.num_stages > 1:
+        require_token_rows(cfg, "a pipeline of stages")
         require_one_kind(cfg, "a pipeline of stages")
         require_single_pass(cfg, "a pipeline of stages")
         require_kv_pair(cfg, "a pipeline of stages")
-        require_token_rows(cfg, "a pipeline of stages")
     layers = jax.tree.map(lambda x: x[spec.layer_start:spec.layer_end], full.layers)
     # Tied embeddings: the last stage needs the token table for the LM head.
     needs_embed = spec.is_first or (spec.is_last and cfg.tie_embeddings)

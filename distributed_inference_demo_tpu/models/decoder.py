@@ -43,6 +43,7 @@ from ..ops.quant import dense
 from ..ops.stacked import LayerOf
 from ..ops.norms import layer_norm, rms_norm
 from ..ops.eva_attention import eva_dense_attn
+from ..ops import kda as kda_ops
 from ..ops.latent_attention import latent_dense_attn
 from ..ops.rope import (apply_rope, apply_rope_interleaved,
                         apply_rope_kind)
@@ -128,7 +129,39 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
     big = (partial(_init_quantized, mode=mode) if mode else _dense_init)
 
     keys = jax.random.split(rng, 16)
-    if cfg.latent_kv:
+    kind = cfg.block_kind
+    if kind is not None and kind.attn == "kda":
+        # a gated delta-rule block (``_kda_mixer``): q, k and v of every
+        # head (no grouped keys), the short convolution's taps, the two
+        # low-rank gates (inner width = the head's), beta, a head's own
+        # output norm.  ``A_log`` a head and ``dt_bias`` a channel are
+        # seeded as the published initialiser does (A uniform in [1, 16],
+        # dt log-uniform in [1e-3, 0.1] through the inverse softplus); the
+        # gate's bias at N(0, 0.1), not zero, so that a path that dropped
+        # it cannot pass for one that has it
+        D, ks = nh * hd, jax.random.split(keys[14], 8)
+        step = jnp.exp(jax.random.uniform(ks[0], (L, D), jnp.float32,
+                                          jnp.log(1e-3), jnp.log(0.1)))
+        p = {
+            "attn_norm_w": jnp.ones((L, H), cfg.dtype),
+            "wq": big(keys[0], (L, H, D), cfg.dtype),
+            "wk": big(keys[1], (L, H, D), cfg.dtype),
+            "wv": big(keys[2], (L, H, D), cfg.dtype),
+            "conv_w": _dense_init(ks[1], (L, kind.conv, 3 * D), cfg.dtype),
+            "wf_dn": _dense_init(ks[2], (L, H, hd), cfg.dtype),
+            "wf_up": _dense_init(ks[3], (L, hd, D), cfg.dtype),
+            "A_log": jnp.log(jax.random.uniform(ks[4], (L, nh), jnp.float32,
+                                                1.0, 16.0)),
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "wb": _dense_init(ks[5], (L, H, nh), cfg.dtype),
+            "wg_dn": _dense_init(ks[6], (L, H, hd), cfg.dtype),
+            "wg_up": _dense_init(ks[7], (L, hd, D), cfg.dtype),
+            "bg": _dense_init(keys[15], (L, D), cfg.dtype, scale=0.1),
+            "o_norm_w": jnp.ones((L, hd), cfg.dtype),
+            "wo": big(keys[3], (L, D, H), cfg.dtype),
+            "mlp_norm_w": jnp.ones((L, H), cfg.dtype),
+        }
+    elif cfg.latent_kv:
         # deepseek_v3: q in one matrix (q_lora_rank null), the latent and
         # the shared rope key from ``wkv_a``, and ``kv_b`` kept as its two
         # halves a head, laid out for the absorbed form: ``w_uk[i]`` =
@@ -155,10 +188,11 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
             "wo": big(keys[3], (L, nh * hd, H), dt),
             "mlp_norm_w": jnp.ones((L, H), dt),
         }
-    kind = cfg.block_kind
     if kind is not None and kind.gate == "per-head":
         # one scalar a head from the block's normed input (``_kv_attention``)
         p["wg"] = _dense_init(keys[13], (L, H, nh), dt)
+    if kind is not None and kind.gate == "elementwise":
+        p["wg"] = big(keys[13], (L, H, nh * hd), dt)    # one a channel
     if cfg.norm_unit_offset:
         # the stored weight is the gain's OFFSET (the gain is 1 + w).
         # Seeded at N(0, 0.1) and not at the published zero, so that a
@@ -232,8 +266,9 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
         if cfg.router_bias:
             # non-zero, so that choosing by score + bias and weighing by
             # the score differ; float32 like the scores it is added to
+            # (over every expert the router scores, held here or not)
             p["router_bias"] = 0.1 * jax.random.normal(
-                keys[9], (L, E), jnp.float32)
+                keys[9], (L, cfg.num_experts), jnp.float32)
         if cfg.num_shared_experts > 0:
             Is = cfg.num_shared_experts * I
             p["ws_gate"] = big(keys[10], (L, H, Is), dt)
@@ -664,10 +699,11 @@ def _kv_attention(cfg: ModelConfig, lp: dict, h: jnp.ndarray, k_cache,
 
     kind = cfg.block_kind       # a period model's block: its own rope
     if kind is not None:
-        q = apply_rope_kind(q, positions, kind.rope_theta,
-                            kind.rotary_share, kind.yarn)
-        k = apply_rope_kind(k, positions, kind.rope_theta,
-                            kind.rotary_share, kind.yarn)
+        if kind.rotary_share > 0:       # 0: no rope (positions by order)
+            q = apply_rope_kind(q, positions, kind.rope_theta,
+                                kind.rotary_share, kind.yarn)
+            k = apply_rope_kind(k, positions, kind.rope_theta,
+                                kind.rotary_share, kind.yarn)
     elif cfg.use_rope:
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
@@ -697,7 +733,115 @@ def _kv_attention(cfg: ModelConfig, lp: dict, h: jnp.ndarray, k_cache,
             dense(h, lp["wg"], "bsh,hn->bsn").astype(jnp.float32))
         attn = (attn.astype(jnp.float32) * gate[..., None]).astype(
             attn.dtype)
-    return attn.reshape(b, s, nh * hd), k_cache, v_cache
+    attn = attn.reshape(b, s, nh * hd)
+    if kind is not None and kind.gate == "elementwise":
+        # the same gate with one scalar a channel of every head
+        with jax.named_scope("gqa_gate"):
+            gate = jax.nn.sigmoid(
+                dense(h, lp["wg"], "bsh,hd->bsd").astype(jnp.float32))
+            attn = (attn.astype(jnp.float32) * gate).astype(attn.dtype)
+    return attn, k_cache, v_cache
+
+
+def _kda_mixer(cfg: ModelConfig, kind, lp: dict, h: jnp.ndarray, state,
+               conv, positions, valid, hook):
+    """A gated delta-rule (KDA) block's mixer over normed rows ``h`` [b,
+    s, H]: ``(y [b, s, heads x hd], state', conv')`` (docs/DESIGN.md
+    section 27; the equations are ``ops.kda``'s first lines).
+
+    ``state`` / ``conv`` are ``LayerOf`` the state pool ``[planes, rows,
+    heads, hd, hd]`` float32 and the convolution tails ``[planes, rows,
+    taps - 1, 3 x heads x hd]``, and come back the same way.  ``hook``
+    (``attn_impl.for_state``) gives each batch row's ROW of the pool and
+    says whether the kernels serve; ``None``: a dense cache, row ``i`` is
+    batch row ``i``.  One token a row (``s == 1``) steps every row at
+    once; a segment (``s > 1``) runs the rows one after another in the
+    chunk form, each from the state the one before left (two segments of
+    one slab may be one prompt's consecutive chunks), and from zero where
+    its first position is 0: a request's first segment needs nothing
+    zeroed for it.  ``valid`` [b, s]: a row's first tokens that are there;
+    the others move neither state nor tail."""
+    b, s, _ = h.shape
+    hd, nh = cfg.head_dim, kind.num_heads
+    D, f32 = nh * hd, jnp.float32
+    plane = state.layer
+    S, tails = state.stack, conv.stack
+    rows = hook.rows() if hook is not None else None
+    kernel, why = (kda_ops.on_kernel(S.shape, s, hook.backend)
+                   if hook is not None else (False, "dense cache"))
+    if hook is not None:
+        hook.note(s, "pallas_kda" if kernel else "xla_kda", why)
+    interpret = hook is not None and hook.interpret
+    if valid is None:
+        valid = jnp.ones((b, s), bool)
+    ntok = jnp.sum(valid, axis=1).astype(jnp.int32)
+    q = dense(h, lp["wq"], "bsh,hd->bsd")
+    k = dense(h, lp["wk"], "bsh,hd->bsd")
+    v = dense(h, lp["wv"], "bsh,hd->bsd")
+    q, k, v = jax.lax.optimization_barrier((q, k, v))   # as ``_kv_attention``
+    u = jnp.concatenate([q, k, v], axis=-1)             # [b, s, 3 D]
+    with jax.named_scope("kda_gates"):
+        f = dense(dense(h, lp["wf_dn"], "bsh,hr->bsr"), lp["wf_up"],
+                  "bsr,rd->bsd").astype(f32) + lp["dt_bias"].astype(f32)
+        g = -(jnp.exp(lp["A_log"].astype(f32))[:, None]
+              * jax.nn.softplus(f).reshape(b, s, nh, hd))
+        beta = 2.0 * jax.nn.sigmoid(
+            dense(h, lp["wb"], "bsh,hn->bsn").astype(f32))
+        g = jnp.where(valid[:, :, None, None], g, 0.0)
+        beta = jnp.where(valid[:, :, None], beta, 0.0)
+        out_gate = jax.nn.sigmoid(
+            dense(dense(h, lp["wg_dn"], "bsh,hr->bsr"), lp["wg_up"],
+                  "bsr,rd->bsd").astype(f32) + lp["bg"].astype(f32))
+    R = tails.shape[1]
+    if rows is None:
+        at = jnp.arange(b, dtype=jnp.int32)
+    else:   # the last row is nobody's: a row that holds nothing goes there
+        at = jnp.where(ntok > 0, jnp.minimum(rows, R - 1), R - 1)
+
+    def heads_of(y):
+        """silu(conv) -> q, k, v a head, q and k of unit length (q times
+        hd ** -0.5 after it); rounded to the model's dtype first, as any
+        block's q, k and v are."""
+        y = y.astype(cfg.dtype).astype(f32)
+        unit = lambda x: x * jax.lax.rsqrt(
+            jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+        cut = lambda x: x.reshape(x.shape[:-1] + (nh, hd))
+        return (unit(cut(y[..., :D])) * hd ** -0.5,
+                unit(cut(y[..., D:2 * D])), cut(y[..., 2 * D:]))
+
+    if s == 1:
+        with jax.named_scope("kda_conv"):
+            tail = jax.lax.dynamic_index_in_dim(tails, plane, 0, False)[at]
+            y, tail = kda_ops.causal_conv(u, tail, lp["conv_w"], ntok)
+            tails = tails.at[plane, at].set(tail)
+            qh, kh, vh = heads_of(y[:, 0])
+        with jax.named_scope("kda_step"):
+            o, S = kda_ops.kda_step(
+                S, plane, None if rows is None else at, qh, kh, vh,
+                g[:, 0], beta[:, 0], ntok > 0, kernel=kernel,
+                interpret=interpret)
+        o = o[:, None]
+    else:
+        fresh = positions[:, 0] == 0
+        outs = []
+        for r in range(b):
+            with jax.named_scope("kda_conv"):
+                tail = jnp.where(fresh[r], 0,
+                                 tails[plane, at[r]])[None].astype(tails.dtype)
+                y, tail = kda_ops.causal_conv(u[r:r + 1], tail,
+                                              lp["conv_w"], ntok[r:r + 1])
+                tails = tails.at[plane, at[r]].set(tail[0])
+                qh, kh, vh = heads_of(y[0])
+            with jax.named_scope("kda_chunk"):
+                o_r, S = kda_ops.kda_chunk(
+                    S, plane, at[r], fresh[r], qh, kh, vh, g[r], beta[r],
+                    kernel=kernel, interpret=interpret)
+            outs.append(o_r)
+        o = jnp.stack(outs)
+    with jax.named_scope("kda_out_norm"):
+        y = rms_norm(o, lp["o_norm_w"], cfg.norm_eps)     # a head's own
+        y = (y * out_gate.reshape(b, s, nh, hd)).astype(cfg.dtype)
+    return (y.reshape(b, s, D), LayerOf(S, plane), LayerOf(tails, plane))
 
 
 def _latent_attention(cfg: ModelConfig, lp: dict, h: jnp.ndarray, cache,
@@ -780,7 +924,12 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     if x.dtype != cfg.dtype:  # a float32 stream (looped, fp32_residual)
         h = h.astype(cfg.dtype)
 
-    if cfg.latent_kv:  # the cache is ``k_cache`` alone; ``v_cache`` is empty
+    kind = cfg.block_kind
+    if kind is not None and kind.attn == "kda":
+        # the caches are the state pool and the convolution tails
+        attn, k_cache, v_cache = _kda_mixer(
+            cfg, kind, lp, h, k_cache, v_cache, positions, valid, attn_impl)
+    elif cfg.latent_kv:  # the cache is ``k_cache`` alone; ``v_cache`` is empty
         attn, k_cache = _latent_attention(cfg, lp, h, k_cache, positions,
                                           cache_start, attn_impl)
     else:
@@ -853,22 +1002,30 @@ def _period_blocks(params: StageParams, cfg: ModelConfig, x, cache: KVCache,
             "make_paged_attn_impl); this one serves one kind")
 
     def hook(name, kind, pool):
+        if kind.attn == "kda":      # rows of the state pool, not pages
+            return (attn_impl.for_state(name) if attn_impl is not None
+                    else None)
         if attn_impl is not None:
             return attn_impl.for_pool(pool, pools, kind.window, name)
         return _window_attn(kind.window) if kind.window else None
 
     def block(block_cfg, lp, x, Ks, Vs, pool, plane, impl, stats):
+        pool %= len(Ks)     # -1, a kda block's (``cfg.state_arrays``)
+        # the state pool goes whole, paged or not (``_kda_mixer``)
+        whole = paged or block_cfg.block_kind.attn == "kda"
         k_of, v_of = LayerOf(Ks[pool], plane), LayerOf(Vs[pool], plane)
-        kc, vc = (k_of, v_of) if paged else (k_of.sliced(), v_of.sliced())
+        kc, vc = (k_of, v_of) if whole else (k_of.sliced(), v_of.sliced())
         x, kc, vc, *rows = _layer(block_cfg, lp, x, kc, vc, positions,
                                   cache_start, None, None, impl, None,
                                   stats, valid)
-        K, V = ((kc.stack, vc.stack) if paged
+        K, V = ((kc.stack, vc.stack) if whole
                 else (k_of.updated(kc), v_of.updated(vc)))
         swap = lambda t, a: t[:pool] + (a,) + t[pool + 1:]
         return x, swap(Ks, K), swap(Vs, V), (rows[0] if rows else None)
 
     Ks, Vs = tuple(cache.keys), tuple(cache.values)
+    if cfg.state_planes:    # the state rides last: checked here, a trace
+        cfg.state_arrays(Ks, Vs)
     # the leading blocks' paths are recorded under their kind's name where
     # the period has that kind too
     lead_name = next((n for n, k, _ in cfg.kinds if k == cfg.lead_kind),

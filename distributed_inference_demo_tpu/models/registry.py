@@ -26,6 +26,10 @@ LAGUNA_TEST_FULL = BlockKind(attn="full", num_heads=4, rope_theta=500000.0,
                              rotary_share=0.5,
                              yarn=(8.0, 32.0, 4.0, 1.0, 1.2079441541679836),
                              gate="per-head")
+SOLAR_TEST_FULL = BlockKind(attn="full", num_heads=4, rotary_share=0.0,
+                            gate="elementwise")
+SOLAR_TEST_KDA = BlockKind(attn="kda", num_heads=4, rotary_share=0.0,
+                           conv=4)
 
 MODEL_REGISTRY = {
     # --- bloom family (reference parity: data/Data.kt:19-33) ---
@@ -195,6 +199,19 @@ MODEL_REGISTRY = {
         experts_held=(4, 0), dtype_name="float32",
         period=tuple([LAGUNA_TEST_WINDOW] * 3 + [LAGUNA_TEST_FULL]),
         lead_kind=LAGUNA_TEST_FULL),
+    # 2 repeats of (full, kda, kda, kda): a gated full block without rope
+    # (4 query heads over 2 kv heads) and three gated delta-rule blocks (4
+    # heads, a recurrent state a request and a convolution of 4 taps: no
+    # pages), a sigmoid router with a stored bias, 2 of 16 experts held
+    "solar-open2-test": ModelConfig(
+        family="solar_open2", vocab_size=256, hidden_size=64, num_layers=2,
+        num_heads=4, num_kv_heads=2, head_dim_override=16,
+        intermediate_size=32, max_seq_len=256, norm_eps=1e-5,
+        num_experts=16, experts_per_token=4, norm_topk_prob=True,
+        num_shared_experts=1, router_scoring="sigmoid", router_bias=True,
+        routed_scaling_factor=1.0, experts_held=(2, 0),
+        dtype_name="float32",
+        period=tuple([SOLAR_TEST_FULL] + [SOLAR_TEST_KDA] * 3)),
 }
 
 

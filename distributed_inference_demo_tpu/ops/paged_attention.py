@@ -65,6 +65,7 @@ configuration runs it.
 """
 
 import functools
+import types
 from typing import Optional
 
 import jax
@@ -1401,7 +1402,8 @@ def prefill_pages_walked(start: int, chunk: int, tile: int, bt: int,
 
 def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
                          interpret: bool = False,
-                         record: Optional[AttnPathRecord] = None):
+                         record: Optional[AttnPathRecord] = None,
+                         state_cols: int = 0):
     """``(impl, bind)``: an attention hook for paged-layout caches plus
     the binder that hands it the block tables.
 
@@ -1422,6 +1424,10 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
     layer's plane, and it hands the stacks back the same way.  It takes
     nothing else, and where :func:`route_pool` says ``plane`` it is the
     hook that slices the layer out and puts it back.
+
+    ``state_cols`` (a model with a recurrent state a request,
+    ``ModelConfig.state_planes``): the bound tables' last column is not a
+    page but the request's ROW of the state pool (``impl.for_state``).
     """
     if backend not in ("auto", "xla", "pallas"):
         raise ValueError(f"unknown paged attention backend {backend!r}; "
@@ -1504,7 +1510,7 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
         def kind_impl(q, k, v, k_pages, v_pages, positions, cache_start,
                       slopes):
             tables = bound["tables"]
-            width = tables.shape[1] // pools
+            width = (tables.shape[1] - state_cols) // pools
             with jax.named_scope(f"attn_{name}"):
                 return attend(q, k, v, k_pages, v_pages, positions, slopes,
                               tables[:, pool * width:(pool + 1) * width],
@@ -1535,7 +1541,23 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
         eva_impl.stacked_cache = True
         return eva_impl
 
+    def for_state(name: str):
+        """The hook of a block whose cache is a recurrent state
+        (``models.decoder._kda_mixer``): ``rows()`` is each bound row's
+        row of the state pool, the tables' last column (a sentinel there
+        is clamped onto the pool's last row, which is nobody's);
+        ``note`` records the path its op took as ``<program>/<name>``."""
+        def note(chunk: int, path: str, why: str) -> None:
+            if record is not None:
+                record.note(f"{bound['program']}/{name}", chunk, path, why,
+                            "state row")
+
+        return types.SimpleNamespace(
+            rows=lambda: bound["tables"][:, -1], note=note,
+            backend=backend, interpret=interpret)
+
     impl.for_pool = for_pool
+    impl.for_state = for_state
     impl.summarised = summarised
     impl.stacked_cache = True
     return impl, bind

@@ -155,8 +155,9 @@ def make_paged_forward_seam(cfg: ModelConfig, spec: StageSpec, mesh,
                 (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5,
                 backend, interpret, record)
         else:
-            impl, bind = make_paged_attn_impl(block_tokens, backend,
-                                              interpret, record)
+            impl, bind = make_paged_attn_impl(
+                block_tokens, backend, interpret, record,
+                state_cols=1 if cfg.state_planes else 0)
 
         def fwd(p, inputs, cache, positions, logits_at,
                 moe_stats=False, valid=None):

@@ -57,6 +57,7 @@ bit-exact vs InferenceEngine (pinned by tests).
 
 from __future__ import annotations
 
+import base64
 import contextlib
 import copy
 import itertools
@@ -77,6 +78,7 @@ import numpy as np
 from ..models.base import (KVCache, ModelConfig, StageParams,
                            StageSpec, eva_rows, pad_cache_capacity,
                            require_kv_pair, require_one_kind,
+                           require_no_state,
                            require_token_rows,
                            require_single_pass)
 from ..ops.eva_attention import eva_positions
@@ -89,6 +91,7 @@ from ..telemetry.anomaly import AnomalyMonitor
 from ..telemetry.flightrecorder import get_flight_recorder
 from ..telemetry.slo import get_slo_ledger, sanitize_tenant
 from ..telemetry.tracing import (EVA_DISPATCH_FIELDS,
+                                 KDA_DISPATCH_FIELDS,
                                  LATENT_DISPATCH_FIELDS,
                                  LOOP_DISPATCH_FIELDS,
                                  MOE_DISPATCH_FIELDS,
@@ -133,6 +136,14 @@ class _BlocksExhausted(Exception):
 # (export_request posts it so a checkpoint never waits on the blocking
 # get of a truly idle loop)
 _WAKE = object()
+
+
+@partial(jax.jit, static_argnums=(2, 3))
+def _state_rows(pool, row, head_step: int, key_step: int):
+    """Row ``row`` of a state pool ``[planes, rows, heads, key, value]``:
+    every ``head_step``-th head's every ``key_step``-th key, float32."""
+    mine = jax.lax.dynamic_index_in_dim(pool, row, 1, keepdims=False)
+    return mine[:, ::head_step, ::key_step].astype(jnp.float32)
 
 
 @dataclass
@@ -180,6 +191,11 @@ class Request:
     # SLO timeline decomposes like a migration pause
     resumed: bool = False
     resume_pause: float = 0.0      # seconds spent re-deriving delivered
+    # a model with a recurrent state: read a sample of the request's row
+    # of the state pool when it completes (``generate(logprobs=True)``
+    # asks; ``state`` is then ``_state_sample``'s record)
+    state_readout: bool = False
+    state: Optional[dict] = None
 
     def wait(self, timeout: Optional[float] = None) -> np.ndarray:
         if not self.done.wait(timeout):
@@ -390,6 +406,24 @@ class ContinuousBatchingEngine:
             raise ValueError("num_draft must be >= 1")
         if (draft_cfg is None) != (draft_params is None):
             raise ValueError("draft_cfg and draft_params go together")
+        if cfg.state_planes:
+            # a recurrent state a request beside the page pool (docs/
+            # DESIGN.md section 27): every token rewrites it, in order,
+            # inside the mixed dispatch's programs.  What re-runs, rolls
+            # back or splits a request's tokens refuses it
+            if self.mixed_token_budget == 0:
+                require_no_state(cfg, "the serialized interleave (no "
+                                      "--mixed-token-budget)")
+            if prompt_lookup or draft_cfg is not None:
+                require_no_state(cfg, "speculation (a draft model or "
+                                      "prompt lookup)")
+            if mesh is not None and mesh.shape.get("tp", 1) > 1:
+                require_no_state(cfg, "tensor parallelism (--tp)")
+            if cfg.num_experts == 0:
+                raise ValueError(
+                    "a model with a recurrent state is served with its "
+                    "experts' row mask (the rows that hold a token): a "
+                    "dense one has no such mask yet")
         if cfg.mixed_kinds:
             # a cache spec a kind of block (docs/DESIGN.md section 25):
             # one pool and one table a kind, window pages freed while the
@@ -422,10 +456,10 @@ class ContinuousBatchingEngine:
                     f"chunks of {cfg.eva_chunk}: a chunk then lies in one "
                     f"window and completes every chunk it holds")
         if draft_cfg is not None:
+            require_token_rows(draft_cfg, "the draft side of speculation")
             require_one_kind(draft_cfg, "the draft side of speculation")
             require_single_pass(draft_cfg, "the draft side of speculation")
             require_kv_pair(draft_cfg, "the draft side of speculation")
-            require_token_rows(draft_cfg, "the draft side of speculation")
             if draft_cfg.vocab_size != cfg.vocab_size:
                 raise ValueError(
                     f"draft vocab ({draft_cfg.vocab_size}) != target vocab "
@@ -566,8 +600,20 @@ class ContinuousBatchingEngine:
             self._page_sentinel = max(N, self._wmgr.num_blocks)
             self.window_stats = {"pages_held_peak": 0, "pages_returned": 0,
                                  "pages_unwindowed_peak": 0}
+        # a recurrent state a request (a kda kind of block): B + 1 rows
+        # (the slots and one admission more, which is all the intake lets
+        # in), leased at admission like pages and held as long as the
+        # request, and one last row that is nobody's, where a row that
+        # holds no token points.  A request's row rides its table's last
+        # column
+        self._state_free: Optional[list] = None
+        if cfg.state_planes:
+            self._state_free = list(range(B + 1))
+            self.state_stats = {"held_peak": 0, "zeroed": 0,
+                                "row_steps": 0, "chunk_tokens": 0}
         # a row of a table: one table a pool, side by side
-        self._table_cols = len(self._pool_specs) * self._table_width
+        self._table_cols = (len(self._pool_specs) * self._table_width
+                            + (1 if cfg.state_planes else 0))
         page_dtype = self.kv_cache_dtype or cfg.dtype
         # which attention path each compiled program took, written at
         # trace time and served under /stats["attention_paths"]
@@ -581,8 +627,11 @@ class ContinuousBatchingEngine:
         heads, width = cfg.kv_page_shape
         if self._wmgr is None:
             self._pk, self._pv = alloc_kv_pool(
-                (cfg.kv_planes, N, heads, bt, width), self.kv_dtype,
-                page_dtype, pool_sharding, streams=cfg.kv_streams)
+                (self._pool_specs[0][1], N, heads, bt, width),
+                self.kv_dtype, page_dtype, pool_sharding,
+                streams=cfg.kv_streams)
+            if cfg.period:      # a period model's pools come as a tuple
+                self._pk, self._pv = (self._pk,), (self._pv,)
         else:       # a tuple of pools, the full kind's first
             pools = [alloc_kv_pool((planes, n, heads, bt, width),
                                    self.kv_dtype, page_dtype)
@@ -590,6 +639,18 @@ class ContinuousBatchingEngine:
                          self._pool_specs, (N, self._wmgr.num_blocks))]
             self._pk, self._pv = (tuple(p[0] for p in pools),
                                   tuple(p[1] for p in pools))
+        if cfg.state_planes:
+            # the state pool rides last among the keys' pools and the
+            # convolution tails among the values': donated, aliased and
+            # carried through ``mixed_step`` as the pages are
+            s_shape, c_shape = cfg.state_shapes
+            rows = (cfg.state_planes, B + 2)
+            # (through the pools' allocator, so that a tool that sizes a
+            # program from shapes alone allocates none of it)
+            self._pk += (alloc_kv_pool(rows + s_shape, "bf16", jnp.float32,
+                                       streams=1)[0],)
+            self._pv += (alloc_kv_pool(rows + c_shape, "bf16", cfg.dtype,
+                                       streams=1)[0],)
         self._tables = np.full((B, self._table_cols), self._page_sentinel,
                                np.int32)
         # write_row_to_pages survives for the DRAFT side only: the draft
@@ -832,6 +893,7 @@ class ContinuousBatchingEngine:
             # touched, the fullest expert's rows in one layer call,
             # and the layer calls); a dense model's program is as it was
             moe_ = cfg_.num_experts > 0
+            state_ = cfg_.state_planes > 0
             E_ = cfg_.experts_here      # the experts this chip holds
 
             def moe_acc0():
@@ -852,12 +914,16 @@ class ContinuousBatchingEngine:
                 a slot that does not decode enters no expert's group
                 (``active`` is frozen for the block: a row that ends
                 inside it steps on to the block's end)."""
-                cache, acc = carry
+                cache, acc, *limit = carry
                 pos = lengths[:, None]
+                # a model with a recurrent state also carries each row's
+                # last length: a row past its budget steps on in the
+                # block, and may move no state
+                valid = active & (lengths < limit[0]) if limit else active
                 logits, cache, rows = fwd_p(
                     params, last_tok[:, None], cache, pos, 0,
-                    moe_stats=True, valid=active[:, None])
-                return ((cache, moe_fold(acc, rows)),
+                    moe_stats=True, valid=valid[:, None])
+                return ((cache, moe_fold(acc, rows), *limit),
                         *_sample_step(logits, lengths, last_tok, active,
                                       rng))
 
@@ -927,9 +993,11 @@ class ContinuousBatchingEngine:
                     if moe_:
                         # the counters ride the loop's carry beside the
                         # cache, which _fused_loop never looks into
-                        ((cache, moe_acc), lengths, tok, toks, lps,
+                        limit = ((lengths + budget,) if state_ else ())
+                        ((cache, moe_acc, *_), lengths, tok, toks, lps,
                          steps) = _fused_loop(
-                            paged_one_step_moe, params, (cache, moe_acc),
+                            paged_one_step_moe, params,
+                            (cache, moe_acc, *limit),
                             lengths, last_tok, active, dec_rng, eos,
                             budget, num_steps, done0=done0)
                         return (cache.keys, cache.values, lengths, tok,
@@ -1431,7 +1499,9 @@ class ContinuousBatchingEngine:
             + (LOOP_DISPATCH_FIELDS if loop else ())
             + (LATENT_DISPATCH_FIELDS if latent else ())
             + (WINDOW_DISPATCH_FIELDS if self._wmgr is not None else ())
-            + (EVA_DISPATCH_FIELDS if self._eva is not None else ()))
+            + (EVA_DISPATCH_FIELDS if self._eva is not None else ())
+            + (KDA_DISPATCH_FIELDS if self._state_free is not None
+               else ()))
 
         # (mixed mode never dispatches the serialized step programs: it
         # launches every variant of mixed_step instead, below)
@@ -1548,7 +1618,7 @@ class ContinuousBatchingEngine:
                _replay: Optional[dict] = None,
                request_id: Optional[str] = None,
                tenant: Optional[str] = None,
-               trace_id: int = 0) -> Request:
+               trace_id: int = 0, state_readout: bool = False) -> Request:
         prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
         check_capacity(self.max_seq, len(prompt), max_new_tokens)
         if len(prompt) == 0:
@@ -1589,7 +1659,9 @@ class ContinuousBatchingEngine:
                       t_submit=time.perf_counter(),
                       t_submit_wall=time.time(),
                       tenant=sanitize_tenant(tenant),
-                      trace_id=int(trace_id or 0))
+                      trace_id=int(trace_id or 0),
+                      state_readout=(state_readout
+                                     and self._state_free is not None))
         # every request gets a migration-addressable id: caller-supplied,
         # or engine-salted auto id (the salt keeps auto rids distinct
         # across replicas sharing a transport namespace).  Wire frame
@@ -2086,7 +2158,7 @@ class ContinuousBatchingEngine:
             ids = ids[None, :]
         t0 = time.perf_counter()
         reqs = self._submit_rows(ids, max_new_tokens, tenant=tenant,
-                                 trace_id=trace_id)
+                                 trace_id=trace_id, state_readout=logprobs)
         try:
             rows = [r.wait(timeout=timeout) for r in reqs]
         except TimeoutError:
@@ -2101,14 +2173,18 @@ class ContinuousBatchingEngine:
             toks[i, :len(r)] = r
             if logprobs:
                 lps[i, :len(r)] = reqs[i].lps
+        # a model with a recurrent state says, with the log-probabilities,
+        # what state each sequence ended in (docs/DESIGN.md section 27)
+        said = ([{"kda_state": r.state} for r in reqs]
+                if logprobs and self._state_free is not None else None)
         return GenerationResult(tokens=toks, prompt_len=ids.shape[1],
                                 num_new=width,
                                 seconds=time.perf_counter() - t0,
-                                logprobs=lps)
+                                logprobs=lps, generation=said)
 
     def _submit_rows(self, ids: np.ndarray, max_new_tokens: int,
                      tenant: Optional[str] = None,
-                     trace_id: int = 0) -> list:
+                     trace_id: int = 0, state_readout: bool = False) -> list:
         """Submit every row or none: if a later row is shed by the
         admission-depth gate, rows already admitted are cancelled before
         the SchedulerOverloaded propagates — a 503'd multi-row request
@@ -2118,7 +2194,8 @@ class ContinuousBatchingEngine:
         try:
             for row in ids:
                 reqs.append(self.submit(row, max_new_tokens,
-                                        tenant=tenant, trace_id=trace_id))
+                                        tenant=tenant, trace_id=trace_id,
+                                        state_readout=state_readout))
         except Exception:
             for r in reqs:
                 r.cancel()
@@ -2281,6 +2358,15 @@ class ContinuousBatchingEngine:
                         "pages_unwindowed_peak":
                             ws["pages_unwindowed_peak"],
                         "quota_pages": self._window_quota}}
+            if self._state_free is not None:
+                # the state pool: a row a request whatever its length
+                # (the last row, nobody's, is not counted), the rows held
+                # now and at most, the first segments that started a row
+                # from zero, and what the two ops advanced
+                out["kvcache"].setdefault("kinds", {})["state"] = dict(
+                    self.state_stats, slots=self.max_batch + 1,
+                    bytes_per_slot=self.cfg.state_bytes_per_slot,
+                    held=self._state_held())
             if self._eva is not None:
                 # the two roles of row in the one pool: what closed and
                 # what was pooled, and at the pool's fullest the rows it
@@ -2525,14 +2611,22 @@ class ContinuousBatchingEngine:
         # prefix's window pages are gone by the time it could be hit; and
         # for a summarised cache, whose window pages the next window
         # writes over
+        # ... and for a model with a recurrent state: a prefix's state is
+        # not a block of tokens the tree could hold
         lease = (mgr.match(req.prompt)
-                 if self._wmgr is None and self._eva is None else None)
+                 if self._wmgr is None and self._eva is None
+                 and self._state_free is None else None)
         m = lease.tokens if lease is not None else 0
         n_pref = m // bt
         if (self._wmgr is not None and self._window_reserved
                 + self._window_quota > self._wmgr.num_blocks):
             # the page gate by kind: no quota of window pages is left
             # (a completion returns one, and frees full-kind pages too)
+            req._pkv_blocked = (mgr.epoch, mgr.free_blocks)
+            raise _BlocksExhausted()
+        if self._state_free is not None and not self._state_free:
+            # no row of the state pool is free (a completion returns one,
+            # and pages with it)
             req._pkv_blocked = (mgr.epoch, mgr.free_blocks)
             raise _BlocksExhausted()
         private = mgr.alloc(n_total - n_pref)
@@ -2568,6 +2662,11 @@ class ContinuousBatchingEngine:
             table[n_pref:n_total] = private
         if self._wmgr is not None:
             self._window_reserved += self._window_quota
+        state_row = None
+        if self._state_free is not None:
+            state_row = table[-1] = self._state_free.pop(0)
+            st = self.state_stats
+            st["held_peak"] = max(st["held_peak"], self._state_held())
         dtable = None
         if dprivate is not None:
             dtable = np.full((self._table_width,), self._dpage_sentinel,
@@ -2579,7 +2678,9 @@ class ContinuousBatchingEngine:
                     "dtable": dtable, "released": False,
                     # the window kind's pages by block of the table, and
                     # the first block it may still read
-                    "wpages": {}, "wfirst": 0}
+                    "wpages": {}, "wfirst": 0,
+                    # its row of the state pool (None: the model has none)
+                    "state_row": state_row}
         # workload sketch: prefix-hit share = matched / prompt tokens,
         # recorded once per SUCCESSFUL reservation (a _BlocksExhausted
         # retry re-runs match and must not double-count)
@@ -2620,6 +2721,32 @@ class ContinuousBatchingEngine:
             self._wmgr.free(list(st["wpages"].values()))
             st["wpages"].clear()
             self._window_reserved -= self._window_quota
+        if st.get("state_row") is not None:
+            # the row goes back as it is: the next request's first
+            # segment starts from zero whatever the row holds
+            self._state_free.append(st["state_row"])
+
+    def _state_held(self) -> int:
+        """Rows of the state pool that requests hold."""
+        return self.max_batch + 1 - len(self._state_free)
+
+    def _state_sample(self, row: int) -> dict:
+        """A sample of row ``row`` of the state pool as it stands: of every
+        plane, four heads' every eighth key with all its values, the
+        pool's own numbers widened to float32 (little-endian, base64) and
+        the pool's dtype by name.  The request that held the row has just
+        completed: nothing has moved its state since its last token but
+        one was absorbed, and no dispatch in flight writes it (it holds no
+        token there).  The read waits for that dispatch."""
+        pool, _ = self.cfg.state_arrays(self._pk, self._pv)
+        hs, ks = max(1, pool.shape[2] // 4), 8
+        got = np.asarray(_state_rows(pool, jnp.int32(row), hs, ks))
+        return {"pool_dtype": str(pool.dtype),
+                "heads": list(range(0, pool.shape[2], hs)),
+                "keys": list(range(0, pool.shape[3], ks)),
+                "shape": list(got.shape),
+                "float32_b64": base64.b64encode(
+                    got.astype("<f4").tobytes()).decode("ascii")}
 
     def _tiles_a_pool(self) -> tuple:
         """``(tile tokens, window)`` a pool, the full (or only) kind's
@@ -2634,7 +2761,7 @@ class ContinuousBatchingEngine:
         if not cfg.period:
             return ((C, 0),)
         kinds = ([cfg.lead_kind] if cfg.lead_kind is not None else []
-                 ) + list(cfg.period)
+                 ) + [k for k in cfg.period if k.attn != "kda"]
         return tuple(
             (sub_chunk(C, next(k for k in kinds if k.window == window)
                        .num_heads // cfg.num_kv_heads), window)
@@ -3073,6 +3200,8 @@ class ContinuousBatchingEngine:
             if len(req.tokens) > 1:
                 self._lat["per_token"].append(
                     (req.t_done - req.t_first) / (len(req.tokens) - 1))
+            if req.state_readout:
+                req.state = self._state_sample(req._pkv["state_row"])
             req.stream.put(None)
             req.done.set()
             # workload sketch: realized decode length at completion
@@ -3947,6 +4076,11 @@ class ContinuousBatchingEngine:
                 if s is not None:
                     self._tables[i] = s[0]._pkv["table"]
         self.chunk_stats["chunks"] += plan.chunks
+        if self._state_free is not None:
+            # a segment at position 0 starts its row of the state pool
+            # from zero, in the program
+            self.state_stats["zeroed"] += sum(
+                int(plan.seg[2][r0]) == 0 for (r0, _, _, _) in packed)
         # a request's queue wait ends at the launch of the first
         # dispatch that carries one of its segments: pending, waiting
         # for pages, for budget, for the running execution to end
@@ -4270,6 +4404,16 @@ class ContinuousBatchingEngine:
         if self._eva is not None:
             record.update(plan.eva_cols,
                           windows_closed=self._eva_account(plan, steps))
+        if self._state_free is not None:
+            # rows x steps that advanced a state in the decode loop (a
+            # row inside its budget; a final's has what token #1 left)
+            # and the prompt tokens through the chunk form
+            row_steps = int(np.minimum(plan.budget_vec, steps).sum())
+            st = self.state_stats
+            st["row_steps"] += row_steps
+            st["chunk_tokens"] += prefill_tokens
+            record.update(kda_row_steps=row_steps,
+                          kda_chunk_tokens=prefill_tokens)
         if self._wmgr is not None:
             record["kv_window_tokens"] = plan.kv_window_tokens
             record["prefill_window_pairs"] = plan.prefill_window_pairs

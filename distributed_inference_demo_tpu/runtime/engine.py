@@ -100,6 +100,10 @@ class GenerationResult:
     # the temperature/top-k-filtered sampling distribution — the
     # OpenAI-style convention), [batch, max_new_tokens] f32, or None
     logprobs: Optional[np.ndarray] = None
+    # what the engine says, a sequence, of how it was generated beyond its
+    # tokens (JSON values; the reply's ``generation``), or None: a model
+    # with a recurrent state gives a sample of the state each ended in
+    generation: Optional[list] = None
     # decode steps the device loop actually RAN (docs/DESIGN.md §13):
     # early exit on eos/stop can make this < num_new, in which case
     # token columns >= steps_computed are deterministic padding the

@@ -782,6 +782,8 @@ class InferenceHTTPServer:
                         if getattr(res, "logprobs", None) is not None:
                             out["logprobs"] = [_round_lps(row)
                                                for row in res.logprobs]
+                        if getattr(res, "generation", None) is not None:
+                            out["generation"] = res.generation
                         if outer.tokenizer is not None:
                             out["text"] = [outer.tokenizer.decode(row)
                                            for row in res.tokens.tolist()]

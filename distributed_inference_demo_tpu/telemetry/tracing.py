@@ -244,6 +244,11 @@ WINDOW_DISPATCH_FIELDS = ("kv_window_tokens", "prefill_window_pairs",
 # positions, as ``kv_tokens`` is)
 EVA_DISPATCH_FIELDS = ("kv_attended_rows", "kv_summary_rows",
                        "prefill_attended_rows", "windows_closed")
+# a model with a recurrent state a request (a kda kind of block): what
+# its two ops advanced.  ``kda_row_steps``: rows x steps that moved a
+# state in the decode loop (a row inside its budget); ``kda_chunk_tokens``:
+# the prompt tokens that went through the chunk form (host arithmetic)
+KDA_DISPATCH_FIELDS = ("kda_row_steps", "kda_chunk_tokens")
 _DISPATCH_RING = 128       # x ~135 bytes a row: /stats stays under 18 KB
 # a span that is work (every one but ``await``) and lasts this long is a
 # stall: ten times the longest ordinary span (four chips' ``ahead``,
