@@ -146,6 +146,27 @@ def _state_rows(pool, row, head_step: int, key_step: int):
     return mine[:, ::head_step, ::key_step].astype(jnp.float32)
 
 
+class TokenStream(queue.Queue):
+    """A request's stream: tokens, then ``None``.  ``get`` hands out one
+    item, as a ``queue.Queue`` does; the scheduler hands over all of a
+    drain's items at once."""
+
+    def put_many(self, items) -> None:
+        """``put`` every item of ``items``, in order, under one lock and
+        one notify: a consumer blocked in ``get`` wakes once."""
+        with self.mutex:
+            self.queue.extend(items)
+            self.unfinished_tasks += len(items)
+            self.not_empty.notify(len(items))
+
+
+# the last item of a request's outbox where the request has ended: it
+# completed (the hand-off stamps ``t_done``, books its latencies and
+# closes its timeline) or was failed or cancelled.  Its stream gets
+# ``None`` for either.
+_COMPLETED, _FAILED = object(), object()
+
+
 @dataclass
 class Request:
     """One in-flight generation request (row-level)."""
@@ -157,8 +178,12 @@ class Request:
     t_submit: float = 0.0
     t_first: float = 0.0
     t_done: float = 0.0
-    stream: "queue.Queue" = field(default_factory=queue.Queue)
+    stream: TokenStream = field(default_factory=TokenStream)
     done: threading.Event = field(default_factory=threading.Event)
+    # what the scheduler has recorded for ``stream`` and not yet handed
+    # over (``ContinuousBatchingEngine._deliver``): tokens in order and,
+    # last, ``_COMPLETED`` or ``_FAILED`` if the request has ended
+    outbox: Optional[list] = None
     error: Optional[BaseException] = None
     cancelled: bool = False
     # engine-unique request id (auto-assigned by submit when the caller
@@ -1563,6 +1588,14 @@ class ContinuousBatchingEngine:
         # prepared under it (tracing.AHEAD_MISS_REASONS); None while
         # nothing executes
         self._ahead_miss: Optional[str] = None
+        # the requests that hold tokens or an end their streams have yet
+        # to get (``Request.outbox``), in the order they were recorded
+        # (``_post`` / ``_deliver``): scheduler thread only, and empty
+        # whenever that thread may block or leave.  ``_gap_drained``: a
+        # dispatch of the mixed loop was drained in the gap since the
+        # last hand-off
+        self._outbox: List[Request] = []
+        self._gap_drained = False
         self.migration_stats = {"exported_requests": 0,
                                 "imported_requests": 0,
                                 "detached_requests": 0}
@@ -1943,6 +1976,10 @@ class ContinuousBatchingEngine:
         handshake (under ``_submit_lock``) makes a timed-out caller's
         box a no-op: servicing it anyway could detach a row nobody
         owns."""
+        if self._export_q:
+            # an export reads whether the request has ended, and a
+            # detaching one hands its stream to the relay
+            self._deliver()
         while self._export_q:
             box = self._export_q.popleft()
             with self._submit_lock:
@@ -3188,22 +3225,13 @@ class ContinuousBatchingEngine:
         req.tokens.append(tok)
         if lp is not None:
             req.lps.append(lp)
-        if len(req.tokens) == 1:
-            req.t_first = time.perf_counter()
-        req.stream.put(tok)
+        self._post(req, tok)
         hit_eos = self.eos_id is not None and tok == self.eos_id
         if len(req.tokens) >= req.max_new or hit_eos:
-            req.t_done = time.perf_counter()
             self._completed += 1
-            self._lat["ttft"].append(req.t_first - req.t_submit)
-            self._lat["e2e"].append(req.t_done - req.t_submit)
-            if len(req.tokens) > 1:
-                self._lat["per_token"].append(
-                    (req.t_done - req.t_first) / (len(req.tokens) - 1))
             if req.state_readout:
                 req.state = self._state_sample(req._pkv["state_row"])
-            req.stream.put(None)
-            req.done.set()
+            self._post(req, _COMPLETED)
             # workload sketch: realized decode length at completion
             self._sketch.record_decode(len(req.tokens))
             if req.rid is not None and self._by_rid.get(req.rid) is req:
@@ -3218,7 +3246,50 @@ class ContinuousBatchingEngine:
             self._flight.record("batch_done", slot=slot,
                                 tokens=len(req.tokens),
                                 reason="eos" if hit_eos else "length")
-            self._close_timeline(req)
+
+    def _post(self, req: Request, item) -> None:
+        """Record ``item`` (a token, or the request's end) for ``req``'s
+        stream: it goes out with the next :meth:`_deliver`."""
+        if req.outbox is None:
+            req.outbox = []
+            self._outbox.append(req)
+        req.outbox.append(item)
+
+    def _deliver(self) -> None:
+        """Hand every request in the outbox what was recorded for its
+        stream since the last hand-off: all its tokens and, after them,
+        its end, under one lock and one wake-up of its consumer
+        (docs/DESIGN.md section 19).  ``t_first`` and ``t_done`` are
+        stamped here, the instants a consumer could have the token, and
+        a completed request's latencies and timeline follow them.
+
+        The scheduler calls it wherever it may block or leave with
+        tokens recorded, and otherwise where the hand-off costs the
+        device nothing: under an execution."""
+        trace = self.dispatch_trace
+        self._gap_drained = False
+        outbox, self._outbox = self._outbox, []
+        for req in outbox:
+            items, req.outbox = req.outbox, None
+            ended = items[-1] is _COMPLETED or items[-1] is _FAILED
+            now = time.perf_counter()
+            if not req.t_first and len(items) > ended:
+                req.t_first = now
+            if items[-1] is _COMPLETED:
+                req.t_done = now
+                self._lat["ttft"].append(req.t_first - req.t_submit)
+                self._lat["e2e"].append(now - req.t_submit)
+                if len(req.tokens) > 1:
+                    self._lat["per_token"].append(
+                        (now - req.t_first) / (len(req.tokens) - 1))
+                self._close_timeline(req)
+            trace.delivered_streams += 1
+            trace.delivered_tokens += len(items) - ended
+            if ended:
+                items[-1] = None
+            req.stream.put_many(items)
+            if ended:
+                req.done.set()
 
     def _fail_request(self, req: Request, err: Optional[BaseException]):
         """Finish a request (with an error, or cleanly for err=None).
@@ -3228,8 +3299,7 @@ class ContinuousBatchingEngine:
         one)."""
         self._release_request_kv(req)
         req.error = err
-        req.stream.put(None)
-        req.done.set()
+        self._post(req, _FAILED)
         if req.rid is not None and self._by_rid.get(req.rid) is req:
             del self._by_rid[req.rid]
         if err is not None:
@@ -3339,9 +3409,10 @@ class ContinuousBatchingEngine:
             try:
                 req = self._queue.get_nowait()
             except queue.Empty:
-                return
+                break
             if req is not None and req is not _WAKE:
                 self._fail_request(req, err)
+        self._deliver()
 
     def _sweep_cancelled(self) -> None:
         """Free the slots of requests cancelled mid-flight — run once per
@@ -3530,6 +3601,7 @@ class ContinuousBatchingEngine:
                 if timeout is None:
                     # nothing executes: the next dispatch is a first
                     self._ahead_miss = None
+                    self._deliver()
                     with trace.idle():     # nobody's host time
                         req = self._queue.get()
                 else:
@@ -3592,6 +3664,7 @@ class ContinuousBatchingEngine:
         self._service_exports()
         if not any(self._slots) and not self._adms:
             self._ahead_miss = None
+            self._deliver()
             return
         trace.enter("pack")
         plan = self._pack_mixed(
@@ -3602,6 +3675,16 @@ class ContinuousBatchingEngine:
         plan.how = self._ahead_miss or "first"
         flight = self._launch_mixed(plan)
         del plan       # the flight holds it, and lets it go when drained
+        if flight is None:
+            self._deliver()
+            return
+        # what the gap recorded for the streams (the drain of this
+        # dispatch's predecessor, a cancel's end) goes out now that the
+        # device has work: the consumers it wakes take the GIL under
+        # this execution and not in front of it
+        with trace.ahead("deliver"):
+            trace.delivered_after_launch += self._gap_drained
+            self._deliver()
         while flight is not None:
             nxt, why = self._plan_ahead(flight)
             ahead = None
@@ -3638,6 +3721,7 @@ class ContinuousBatchingEngine:
             if ahead is not None:
                 with trace.ahead("ahead_drain"):
                     record = self._drain_mixed(flight)
+                    self._deliver()
                 trace.commit(phases=flight.phases, **record)
                 flight = ahead
                 continue
@@ -3647,10 +3731,12 @@ class ContinuousBatchingEngine:
                 flight.t_done = trace.enter("drain")
             record = self._drain_mixed(flight)
             self._ahead_miss = why
-            # committed here, after the flight is let go: `drain` then
+            # its tokens stay in the outbox until the next iteration has
+            # launched its successor (or finds nothing to launch).
+            # Committed here, after the flight is let go: `drain` then
             # holds the teardown too (freeing the dispatch's device
-            # arrays drops the GIL, and the HTTP threads woken by the
-            # tokens just delivered take their turn)
+            # arrays drops the GIL)
+            self._gap_drained = True
             flight = nxt = None
             trace.commit(**record)
 
@@ -4354,9 +4440,9 @@ class ContinuousBatchingEngine:
 
     def _drain_mixed(self, flight) -> dict:
         """Install what a returned dispatch produced: finals (host
-        state, radix adoption, token #1), every row's tokens to its
-        stream, the counters; returns the dispatch record's fields
-        (``DispatchTrace.commit``)."""
+        state, radix adoption, token #1), every row's tokens recorded
+        (its stream gets them with the next ``_deliver``), the counters;
+        returns the dispatch record's fields (``DispatchTrace.commit``)."""
         plan, steps = flight.plan, flight.steps
         packed, spec_mixed = plan.packed, plan.spec_mixed
         prefill_tokens, n_active = plan.prefill_tokens, plan.n_active
@@ -4598,6 +4684,10 @@ class ContinuousBatchingEngine:
             free = [i for i, s in enumerate(self._slots) if s is None]
             # one dispatch of the in-progress chunked admission (if any)
             self._advance_admission(free)
+            # the streams get what a step recorded when it ends, here as
+            # in the mixed loop one hand-off a stream: before the thread
+            # may block, after the admissions, after the step
+            self._deliver()
             # block for work only when truly idle: nothing decoding, no
             # admission mid-stream, nothing waiting to be served
             timeout = (None if not (any(self._slots) or self._adm
@@ -4651,6 +4741,7 @@ class ContinuousBatchingEngine:
             # consistent here (pending drained, cancels swept, no
             # dispatch in flight)
             self._service_exports()
+            self._deliver()
             if not any(self._slots):
                 continue
 
@@ -4668,6 +4759,7 @@ class ContinuousBatchingEngine:
             if self._adm is not None:
                 self.chunk_stats["interleaved_steps"] += 1
             self._step_active(self.decode_block if fuse else 1)
+            self._deliver()
 
         # drain: fail anything still queued or in flight
         self._drain_all(RuntimeError("engine closed while request in flight"))

@@ -196,15 +196,20 @@ DISPATCH_LAST_FIELDS = ("early",)
 _OWN = DISPATCH_PHASES + ("await", "late")
 # the spans of ``/stats.dispatch_trace.spans`` (wall and thread CPU
 # seconds each): the phases but ``wait``, which is no work of the
-# host's, the three kinds of work done under an execution
+# host's, the four kinds of work done under an execution
 # (``phase_s["ahead"]`` is their sum; ``ahead_launch``: the call of a
-# dispatch that is enqueued early), and the blocking read
+# dispatch that is enqueued early; ``deliver``: the hand-off to the
+# streams of what the gap before a launch recorded, a dispatch drained
+# there first of all, made directly behind that launch), and the
+# blocking read
 DISPATCH_SPANS = (tuple(p for p in DISPATCH_PHASES if p != "wait")
-                  + ("ahead_plan", "ahead_drain", "ahead_launch", "await"))
+                  + ("ahead_plan", "ahead_drain", "ahead_launch", "deliver",
+                     "await"))
 # the number of the dispatch a span of work under an execution belongs
 # to, from the one launched last when the span begins: the one being
-# prepared, the one before, the one about to be enqueued
-_AHEAD_OF = {"ahead_plan": 1, "ahead_drain": -1, "ahead_launch": 1}
+# prepared, the one before, the one about to be enqueued, the one before
+_AHEAD_OF = {"ahead_plan": 1, "ahead_drain": -1, "ahead_launch": 1,
+             "deliver": -1}
 # why a dispatch that followed another at once was packed in the gap and
 # not under its predecessor (runtime.batching, docs/DESIGN.md §19)
 AHEAD_MISS_REASONS = ("arrival", "finish", "cancel", "export", "other")
@@ -452,6 +457,13 @@ class DispatchTrace:
         self.ahead_hits = self.ahead_hits_slab = self.ahead_early = 0
         self.ahead_misses = dict.fromkeys(AHEAD_MISS_REASONS, 0)
         self.ahead_first = 0
+        # the hand-offs to the requests' streams (the scheduler adds to
+        # them: runtime.batching ``_deliver``): dispatches drained in
+        # the gap whose tokens went out behind their successor's launch,
+        # wake-ups (one a stream a hand-off) and tokens
+        self.delivered_after_launch = 0
+        self.delivered_streams = 0
+        self.delivered_tokens = 0
         self.late_reads = 0
         self.stall_s = 0.0
         self.stall_count = 0
@@ -633,8 +645,10 @@ class DispatchTrace:
     def ahead(self, span: str = "ahead_plan"):
         """Around host work done while the device executes: the next
         dispatch prepared (span ``ahead_plan``), the last one drained
-        (``ahead_drain``) or the next one's call where it is enqueued
-        early (``ahead_launch``).  The cursor stays in ``wait``, which still
+        (``ahead_drain``), the next one's call where it is enqueued
+        early (``ahead_launch``) or, behind a launch made in the gap,
+        the hand-off of what the gap recorded for the streams
+        (``deliver``).  The cursor stays in ``wait``, which still
         runs from the call's return to ``t_done``, and the seconds are
         booked to ``phase_s["ahead"]``, so the six phases keep tiling
         the iteration and ``phase_s`` without ``wait`` is still all the
@@ -759,6 +773,9 @@ class DispatchTrace:
                 "ahead_early": self.ahead_early,
                 "ahead_misses": dict(self.ahead_misses),
                 "ahead_first": self.ahead_first,
+                "delivered_after_launch": self.delivered_after_launch,
+                "delivered_streams": self.delivered_streams,
+                "delivered_tokens": self.delivered_tokens,
                 "late_reads": self.late_reads,
                 "spans": {name: {"n": n, "wall_s": round(wall, 6),
                                  "cpu_s": round(cpu, 6),

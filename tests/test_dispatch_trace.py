@@ -574,9 +574,11 @@ def test_a_prepared_dispatch_that_fails_hands_the_cursor_back(monkeypatch):
 
 def test_the_blocking_read_is_the_end_of_wait(scripted):
     """``await`` runs from the end of the plan to ``t_done``, inside
-    ``wait``; ``late`` is 0 or 1 and ``late_reads`` their sum; the two
-    parts of the work under an execution add up to the seventh key of
-    ``phase_s``, which keeps its seven keys."""
+    ``wait``; ``late`` is 0 or 1 and ``late_reads`` their sum; the four
+    parts of the work under an execution (the hand-off behind a launch
+    made in the gap, ``deliver``, is the fourth) add up to the seventh
+    key of ``phase_s``, which keeps its seven keys, and the phases still
+    tile (``launch`` + ``wait`` = ``t_done - t_launch``)."""
     dt = scripted[0]["dispatch_trace"]
     recs = rows(scripted[0])
     assert DISPATCH_FIELDS[-3:] == ("ahead", "late", "await")
@@ -588,8 +590,11 @@ def test_the_blocking_read_is_the_end_of_wait(scripted):
     spans = dt["spans"]
     assert tuple(spans) == DISPATCH_SPANS and "wait" not in spans
     assert (spans["ahead_plan"]["wall_s"] + spans["ahead_drain"]["wall_s"]
-            + spans["ahead_launch"]["wall_s"]
-            == pytest.approx(dt["phase_s"]["ahead"], abs=3e-6))
+            + spans["ahead_launch"]["wall_s"] + spans["deliver"]["wall_s"]
+            == pytest.approx(dt["phase_s"]["ahead"], abs=4e-6))
+    for r in recs:
+        assert (abs(r["launch"] + r["wait"] - (r["t_done"] - r["t_launch"]))
+                <= ROUNDING)
     for name, sp in spans.items():
         assert set(sp) == {"n", "wall_s", "cpu_s", "max_s"}
         assert 0 <= sp["max_s"] <= sp["wall_s"]
@@ -600,6 +605,15 @@ def test_the_blocking_read_is_the_end_of_wait(scripted):
     # hits were drained under their successors
     assert spans["await"]["n"] == spans["ahead_plan"]["n"] == dt["seq"]
     assert spans["ahead_drain"]["n"] == dt["ahead_hits"]
+    # a hand-off behind every launch made in the gap, and behind it the
+    # tokens of every dispatch that was drained in a gap but the last of
+    # a request, which no launch followed
+    assert spans["deliver"]["n"] == dt["seq"] - dt["ahead_hits"]
+    assert (dt["delivered_after_launch"]
+            == sum(dt["ahead_misses"].values()))
+    first, second = scripted[1]
+    assert dt["delivered_tokens"] == len(first.tokens) + len(second.tokens)
+    assert 2 <= dt["delivered_streams"] <= dt["seq"]
     assert spans["await"]["wall_s"] == pytest.approx(
         sum(r["await"] for r in recs), abs=len(recs) * 1e-5)
     assert dt["stall_count"] == len(dt["stalls"])
@@ -825,8 +839,10 @@ def test_a_capture_holds_the_sched_rows_on_a_host_plane(capture):
     names = {n for _, n, _ in events}
     assert {f"sched.{p}" for p in DISPATCH_PHASES} <= names
     assert "mixed_step" in names
-    # the work under an execution in its two parts, and the blocking read
-    assert {"sched.ahead_plan", "sched.ahead_drain", "sched.await"} <= names
+    # the work under an execution in its parts (the hand-off behind the
+    # first dispatch's launch among them), and the blocking read
+    assert {"sched.ahead_plan", "sched.ahead_drain", "sched.deliver",
+            "sched.await"} <= names
     assert "sched.ahead" not in names
     # the request's three dispatches and the one after, each under its
     # number

@@ -1135,7 +1135,13 @@ def test_spec_mixed_engine_prepares_nothing(params):
         settle(eng)
         dt = eng.stats()["dispatch_trace"]
     assert dt["seq"] > 4 and dt["ahead_hits"] == 0
-    assert dt["phase_s"]["ahead"] == 0.0
+    # (under an execution they do one thing, as every engine does: hand
+    # the streams what the gap recorded, directly behind the launch)
+    spans = dt["spans"]
+    assert spans["ahead_plan"]["n"] == spans["ahead_drain"]["n"] == 0
+    assert dt["phase_s"]["ahead"] == pytest.approx(
+        spans["deliver"]["wall_s"], abs=2e-6)
+    assert dt["delivered_after_launch"] == dt["ahead_misses"]["other"]
     # ... and their full slab: three segments of eight a dispatch
     assert dt["slab_rows"] == 3 * 8 * dt["seq"]
     assert dt["ahead_misses"]["other"] + dt["ahead_first"] == dt["seq"]
