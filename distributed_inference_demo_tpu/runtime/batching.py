@@ -184,6 +184,11 @@ class Request:
     # over (``ContinuousBatchingEngine._deliver``): tokens in order and,
     # last, ``_COMPLETED`` or ``_FAILED`` if the request has ended
     outbox: Optional[list] = None
+    # ``(the instant, the tokens)`` of every hand-off made to ``stream``
+    # (``_deliver`` appends one before its ``put_many``, with the clock
+    # read it has taken anyway); the stream's consumer takes them off as
+    # it writes the tokens out (``telemetry.tracing.RequestPath``)
+    handoffs: deque = field(default_factory=deque)
     error: Optional[BaseException] = None
     cancelled: bool = False
     # engine-unique request id (auto-assigned by submit when the caller
@@ -2173,12 +2178,15 @@ class ContinuousBatchingEngine:
     def generate(self, prompt_ids: np.ndarray, max_new_tokens: int,
                  seed: int = 0, timeout: Optional[float] = None,
                  logprobs: bool = False, tenant: Optional[str] = None,
-                 trace_id: int = 0) -> GenerationResult:
+                 trace_id: int = 0, on_submit=None) -> GenerationResult:
         """Engine-surface convenience: submit each row as its own request
         (they batch with whatever else is in flight) and wait for all.
         ``seed`` is accepted for surface compatibility but not honored —
         see the module docstring.  On ``timeout`` the requests are
         cancelled (slots freed) before TimeoutError propagates.
+        ``on_submit``: called with the rows' :class:`Request` objects
+        once every row is admitted (the HTTP handler reads their
+        ``t_submit`` there).
 
         ``logprobs=True`` additionally returns each emitted token's raw
         log-softmax probability (the engines' OpenAI-style convention) —
@@ -2196,6 +2204,8 @@ class ContinuousBatchingEngine:
         t0 = time.perf_counter()
         reqs = self._submit_rows(ids, max_new_tokens, tenant=tenant,
                                  trace_id=trace_id, state_readout=logprobs)
+        if on_submit is not None:
+            on_submit(reqs)
         try:
             rows = [r.wait(timeout=timeout) for r in reqs]
         except TimeoutError:
@@ -2242,7 +2252,7 @@ class ContinuousBatchingEngine:
     def generate_stream(self, prompt_ids: np.ndarray, max_new_tokens: int,
                         seed: int = 0, timeout: Optional[float] = None,
                         tenant: Optional[str] = None, trace_id: int = 0,
-                        resume: Optional[dict] = None):
+                        resume: Optional[dict] = None, on_submit=None):
         """Yield [batch] token arrays per step (HTTP streaming surface).
         Single-row streaming only batches trivially; multi-row prompts
         stream in lockstep of the slowest admitted row.  An ABANDONED
@@ -2258,7 +2268,12 @@ class ContinuousBatchingEngine:
         N}`` — gateway-failover resumption (docs/DESIGN.md §23,
         single-row only): the stream yields only the tokens AFTER the
         delivered prefix, which :meth:`submit_resumed` re-derives and
-        verifies bit-exactly."""
+        verifies bit-exactly.
+
+        ``on_submit``: called with the rows' :class:`Request` objects
+        once every row is admitted, before the first token is waited
+        for: the consumer that writes the stream out reads their
+        ``t_submit`` and, as it goes, their ``handoffs``."""
         ids = np.asarray(prompt_ids)
         if ids.ndim == 1:
             ids = ids[None, :]
@@ -2285,6 +2300,8 @@ class ContinuousBatchingEngine:
         fetched = [[] for _ in reqs]
         finished = [False] * len(reqs)   # row's None sentinel was consumed
         try:
+            if on_submit is not None:
+                on_submit(reqs)
             for step_i in range(max_new_tokens):
                 out = []
                 for i, r in enumerate(reqs):
@@ -3285,6 +3302,7 @@ class ContinuousBatchingEngine:
                 self._close_timeline(req)
             trace.delivered_streams += 1
             trace.delivered_tokens += len(items) - ended
+            req.handoffs.append((now, len(items) - ended))
             if ended:
                 items[-1] = None
             req.stream.put_many(items)
@@ -3350,6 +3368,9 @@ class ContinuousBatchingEngine:
                     ts=base + max(0.0, t_sched - req.t_submit),
                     dur=max(0.0, t_first - t_sched),
                     rid=req.rid, tenant=req.tenant,
+                    # the span begins where the wait in the queue ends
+                    queue_wait_ms=round(
+                        1e3 * max(0.0, t_sched - req.t_submit), 3),
                     # the dispatches that served it (dispatch_trace.seq)
                     first_seq=req.first_seq or None,
                     final_seq=req.final_seq or None)

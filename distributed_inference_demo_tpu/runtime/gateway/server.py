@@ -53,11 +53,14 @@ Proxy contract (the hard-won parts):
   candidate down → the gateway's own
   :class:`~..overload.GatewayOverloaded` 503.
 - **tracing**: every proxied request carries ``X-DWT-Trace-Id``; the
-  replica echoes it and logs it to its flight recorder
-  (runtime/http_server.py), and the gateway records ``route`` +
-  ``proxy`` spans under the same id — one trace id covers
-  gateway→replica, exported at ``GET /trace`` (and stitched with the
-  replicas' engine/migration spans at ``GET /trace/fleet``).
+  replica echoes it and records its handler's ``http.ingress`` /
+  ``http.egress`` spans under it (runtime/http_server.py), and the
+  gateway records ``route`` + ``proxy`` spans under the same id — one
+  trace id covers gateway→replica, exported at ``GET /trace`` (and
+  stitched with the replicas' handler/engine/migration spans at ``GET
+  /trace/fleet``).  Beside it rides ``X-DWT-Gateway-Held-S``, the
+  seconds this process held the request before forwarding it, which
+  the replica's request-path record books as the row's first part.
 - **tenant identity**: a ``tenant`` body field or ``X-DWT-Tenant``
   header rides the proxy hop as ``X-DWT-Tenant`` so the replica's SLO
   ledger (telemetry/slo.py) attributes the request's goodput to the
@@ -77,9 +80,9 @@ from ...telemetry import catalog as _catalog
 from ...telemetry import metrics as _m
 from ...telemetry import profiling as _profiling
 from ...telemetry.flightrecorder import get_flight_recorder
-from ...telemetry.tracing import (SpanClock, TraceRecorder,
-                                  merge_chrome_traces, new_trace_id,
-                                  to_chrome_trace)
+from ...telemetry.tracing import (GATEWAY_HELD_HEADER, SpanClock,
+                                  TraceRecorder, merge_chrome_traces,
+                                  new_trace_id, to_chrome_trace)
 from ..overload import GatewayOverloaded, SchedulerOverloaded
 from .federation import FleetScraper
 
@@ -236,6 +239,9 @@ class GatewayHTTPServer:
                     self._json(404, {"error": f"no route {self.path}"})
 
             def do_POST(self):
+                # from here the gateway holds the request: the replica
+                # is told for how long (`_proxy_once`)
+                self.t_accept = time.monotonic()
                 if self.path not in ("/generate", "/drain"):
                     self._json(404, {"error": f"no route {self.path}"})
                     return
@@ -330,9 +336,6 @@ class GatewayHTTPServer:
                 if not self.registry.is_up(rid):
                     continue
                 _catalog.GATEWAY_RETRIED.inc()
-                get_flight_recorder().record(
-                    "gateway_retry", replica=rid, attempt=attempt,
-                    trace_id=f"{trace_id:016x}")
             self.router.acquire(rid)
             proxy_clock = SpanClock()
             try:
@@ -452,6 +455,13 @@ class GatewayHTTPServer:
                 # books this request under the right tenant even when
                 # the body carried it as a header-only hint
                 headers["X-DWT-Tenant"] = tenant[:64]
+            # the seconds the gateway has held the request (body read,
+            # routing, a replica that died before its first token): a
+            # duration, so it means the same on the replica's host,
+            # whose request-path record books it as the row's first
+            # part (telemetry.tracing.RequestPath)
+            headers[GATEWAY_HELD_HEADER] = (
+                f"{time.monotonic() - handler.t_accept:.6f}")
             try:
                 conn.request("POST", "/generate", body=raw,
                              headers=headers)
@@ -577,7 +587,6 @@ class GatewayHTTPServer:
         stream (the client saw delivered prefix + resumed suffix, no
         repeats, gaps, or torn lines); False when attempts are
         exhausted and the caller falls back to the error line."""
-        flight = get_flight_recorder()
         for attempt in range(1, self.resume_limit + 1):
             if not (journal["eligible"] and journal["tokens"]):
                 return False
@@ -593,10 +602,7 @@ class GatewayHTTPServer:
             if not cands:
                 return False
             rid = cands[0]
-            flight.record(
-                "gateway_resume", replica=rid, attempt=attempt,
-                delivered=len(journal["tokens"]),
-                trace_id=f"{trace_id:016x}")
+            delivered = len(journal["tokens"])
             body = dict(journal["body"])
             body["resume"] = {
                 "delivered_tokens": [int(t) for t in journal["tokens"]],
@@ -612,14 +618,12 @@ class GatewayHTTPServer:
                 self.router.release(rid)
                 self.tracer.record(
                     "gateway.resume", trace_id, clock=span_clock,
-                    replica=rid, attempt=attempt)
+                    replica=rid, attempt=attempt, delivered=delivered)
             if ok:
                 _catalog.GATEWAY_RESUME_SUCCEEDED.inc()
                 if journal["routing_tokens"]:
                     # the survivor now holds prompt + stream blocks
                     self.router.record(rid, journal["routing_tokens"])
-                flight.record("gateway_resume_done", replica=rid,
-                              trace_id=f"{trace_id:016x}")
                 return True
             journal["dead"].add(rid)
             self.registry.record_failure(

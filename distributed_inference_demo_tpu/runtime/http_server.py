@@ -8,7 +8,10 @@ inference request with ``"Inference not implemented yet"``
 - ``GET  /stats``     — hot-loop metrics (per-stage comm/compute split,
   byte counts, ring-RTT percentiles — the reference's
   ``commutimeArraySum``/``infertimeArraySum`` dump as an API,
-  ``Communication.java:650-661``)
+  ``Communication.java:650-661``); for a backend that streams, also
+  ``request_path``: what a request costs between the socket and the
+  engine, both ways (``telemetry.tracing.RequestPath``, docs/DESIGN.md
+  §16)
 - ``GET  /metrics``   — Prometheus text exposition (telemetry/catalog):
   the same stage counters as /stats plus batching/speculative and
   monitor series, scrapeable by a stock Prometheus
@@ -41,6 +44,7 @@ from typing import Optional
 import numpy as np
 
 from ..telemetry import catalog as _metrics
+from ..telemetry.tracing import GATEWAY_HELD_HEADER, RequestPath
 from .overload import SchedulerOverloaded
 
 
@@ -275,6 +279,160 @@ def _accepts_kwarg(fn, name: str) -> bool:
                    for p in params.values()))
 
 
+# a handler reads its thread's CPU clock at the first hand-off of every
+# quarter second and at its request's end, not at every hand-off: the
+# read is a system call (5.8 us on the benchmark's machine, where the
+# clock also ticks in steps of 10 ms, seven hand-offs' worth: PERF.md
+# section 6, PR 58), and the differences add up to the same sum
+_CPU_READ_S = 0.25
+
+
+class _Passage:
+    """One ``/generate`` request's way through its handler thread: the
+    stamps of the way in, and what the thread wrote and used on the way
+    out since it last committed to the server's
+    :class:`~..telemetry.tracing.RequestPath` (``rec``; ``None`` for a
+    backend that does not stream: the stamps still feed the two
+    ``dwt_http_*`` series).  Every instant is ``time.monotonic()``.  The
+    handler's own: nothing here is shared with another thread but the
+    requests' ``handoffs``, which the scheduler appends to and this
+    takes off."""
+
+    def __init__(self, rec, headers):
+        self.rec = rec
+        self.t_accept = self.t_cpu = time.monotonic()
+        self.cpu = time.thread_time()
+        try:
+            held = float(headers.get(GATEWAY_HELD_HEADER) or 0.0)
+        except ValueError:
+            held = 0.0
+        self.gateway_s = min(max(held, 0.0), 3600.0)
+        tid = headers.get("X-DWT-Trace-Id")
+        try:
+            self.trace_id = int(tid[:64], 16) if tid else 0
+        except ValueError:
+            self.trace_id = 0
+        # epoch seconds at monotonic 0, for a traced request's two spans
+        self.wall0 = time.time() - self.t_accept if self.trace_id else 0.0
+        self.t_parsed = self.t_submit = 0.0
+        self.rows = self.prompt_tokens = 0
+        self.streamed = False
+        self.reqs: list = []
+        self.bases: list = []
+        self.consumed = 0           # items taken from the backend
+        self.edge = float("inf")    # `consumed` at the next hand-off's end
+        self.lines = self.writes = self.bytes = 0    # since the last commit
+        # the request's own totals: its egress span and the two series
+        self.tokens = self.handoffs = self.all_lines = self.all_writes = 0
+        self.max_s = self.cpu_s = 0.0
+        self.t_first_handoff = self.t_last_write = 0.0
+
+    def parsed(self, ids, streamed: bool) -> None:
+        self.t_parsed = time.monotonic()
+        self.rows, self.prompt_tokens = len(ids), int(ids.size)
+        self.streamed = streamed
+
+    def submitted(self, reqs=()) -> None:
+        """The backend has the request: the engine calls this with the
+        rows' ``Request`` objects (``on_submit``) and the stamp is its
+        own ``t_submit``; for a backend that takes no ``on_submit`` the
+        handler calls it just before the backend."""
+        if self.t_submit:
+            return
+        self.reqs, self.bases = list(reqs), [0] * len(reqs)
+        if self.reqs:
+            last = self.reqs[-1]
+            self.t_submit, self.edge = last.t_submit, 1
+            if self.trace_id and last.t_submit_wall:
+                self.wall0 = last.t_submit_wall - last.t_submit
+        else:
+            self.t_submit = time.monotonic()
+        if self.rec is not None:
+            self.rec.ingress(self.gateway_s, self.t_accept, self.t_parsed,
+                             self.t_submit, self.prompt_tokens,
+                             self.streamed)
+
+    def counted(self, items):
+        for item in items:
+            self.consumed += 1
+            yield item
+
+    def commit(self, final: bool = False) -> float:
+        """The write of a line has returned and ``consumed`` has reached
+        ``edge``: take every hand-off whose tokens are all written off
+        the requests' ``handoffs`` and book it, with what was written
+        since the last commit and the thread's CPU seconds since it last
+        read them (``_CPU_READ_S``)."""
+        now = time.monotonic()
+        stamps, tokens, edge = [], 0, float("inf")
+        # (a reply that is not streamed writes no hand-off out)
+        for i, r in enumerate(self.reqs if self.streamed else ()):
+            q, base = r.handoffs, self.bases[i]
+            while q and base + q[0][1] <= self.consumed:
+                t, n = q.popleft()
+                base += n
+                tokens += n
+                stamps.append(t)
+            self.bases[i] = base
+            if q:
+                edge = min(edge, base + q[0][1])
+        if self.reqs and edge == float("inf"):
+            edge = self.consumed + 1    # the next hand-off is not there yet
+        self.edge = edge
+        if not (stamps or final):
+            return now
+        if final and not self.reqs and self.streamed:
+            tokens = self.consumed * self.rows      # no hand-off to count by
+        cpu_s = 0.0
+        if final or now - self.t_cpu >= _CPU_READ_S:
+            cpu = time.thread_time()
+            cpu_s, self.cpu, self.t_cpu = cpu - self.cpu, cpu, now
+        if self.rec is not None and self.t_submit:
+            self.rec.egress(stamps, now, tokens, self.lines, self.writes,
+                            self.bytes, cpu_s)
+        if stamps:
+            first = min(stamps)
+            self.t_first_handoff = self.t_first_handoff or first
+            self.max_s = max(self.max_s, now - first)
+            self.t_last_write = now
+        self.handoffs += len(stamps)
+        self.tokens += tokens
+        self.all_lines += self.lines
+        self.all_writes += self.writes
+        self.cpu_s += cpu_s
+        self.lines = self.writes = self.bytes = 0
+        return now
+
+    def finish(self, tracer) -> None:
+        """The request's end, whatever it was: the thread's last commit,
+        the layer's two series from the same stamps, and on a request
+        with a trace id its two spans into the replica's recorder."""
+        end = self.commit(final=True)
+        if not self.t_submit:
+            return                  # refused before any backend saw it
+        _metrics.HTTP_REQUEST_SECONDS.observe(end - self.t_accept,
+                                              route="/generate")
+        _metrics.HTTP_GENERATED_TOKENS.inc(self.tokens)
+        if not (self.trace_id and hasattr(tracer, "record")):
+            return
+        tracer.record(
+            "http.ingress", self.trace_id, ts=self.wall0 + self.t_accept,
+            dur=self.t_submit - self.t_accept,
+            gateway_ms=round(1e3 * self.gateway_s, 3),
+            read_parse_ms=round(1e3 * (self.t_parsed - self.t_accept), 3),
+            submit_ms=round(1e3 * (self.t_submit - self.t_parsed), 3),
+            prompt_tokens=self.prompt_tokens, streamed=self.streamed)
+        if self.handoffs:
+            tracer.record(
+                "http.egress", self.trace_id,
+                ts=self.wall0 + self.t_first_handoff,
+                dur=self.t_last_write - self.t_first_handoff,
+                handoffs=self.handoffs, lines=self.all_lines,
+                writes=self.all_writes,
+                max_ms=round(1e3 * self.max_s, 3),
+                cpu_ms=round(1e3 * self.cpu_s, 3))
+
+
 class HeaderBackend:
     """Adapts a PipelineHeader/ElasticHeader to the engine surface used by
     the HTTP handler (generate + generate_stream)."""
@@ -407,6 +565,12 @@ class InferenceHTTPServer:
         self.model_name = model_name
         self.default_max_new = default_max_new
         self.request_timeout = request_timeout or None
+        # the request's path outside the engine, beside the backend's
+        # dispatch record in /stats (a backend that does not stream has
+        # none, and its /stats is as it was)
+        self.request_path = (RequestPath()
+                             if hasattr(backend, "generate_stream")
+                             else None)
         outer = self
 
         class Handler(BaseHTTPRequestHandler):
@@ -447,8 +611,15 @@ class InferenceHTTPServer:
             def _obs_kwargs(self, fn) -> dict:
                 """tenant/trace_id kwargs for backends that take them
                 (the continuous-batching engine) — duck-typed like
-                image/timeout, so pipeline backends stay untouched."""
+                image/timeout, so pipeline backends stay untouched.
+                Called just before the backend is: an engine that takes
+                ``on_submit`` stamps the request's passage itself, for
+                any other backend the handler does, here."""
                 out = {}
+                if _accepts_kwarg(fn, "on_submit"):
+                    out["on_submit"] = self._passage.submitted
+                else:
+                    self._passage.submitted()
                 tenant = getattr(self, "_tenant", None)
                 if tenant and _accepts_kwarg(fn, "tenant"):
                     out["tenant"] = str(tenant)
@@ -542,10 +713,13 @@ class InferenceHTTPServer:
                         "max_seq": getattr(outer.backend, "max_seq", None),
                     })
                 elif self.path == "/stats":
-                    if hasattr(outer.backend, "stats"):
-                        self._json(200, outer.backend.stats())
-                    else:
-                        self._json(200, {"stages": []})
+                    stats = (outer.backend.stats()
+                             if hasattr(outer.backend, "stats")
+                             else {"stages": []})
+                    if outer.request_path is not None:
+                        stats = {**stats, "request_path":
+                                 outer.request_path.snapshot()}
+                    self._json(200, stats)
                 elif self.path.split("?")[0] == "/timeline":
                     # recent closed request timelines + per-tenant SLO
                     # summary (telemetry/slo) — the fleet plane's
@@ -595,6 +769,8 @@ class InferenceHTTPServer:
                     # numbers — the statsreset control message as HTTP)
                     if hasattr(outer.backend, "reset_stats"):
                         outer.backend.reset_stats()
+                        if outer.request_path is not None:
+                            outer.request_path.reset()
                         self._json(200, {"reset": True})
                     else:
                         self._json(501, {"error": "backend has no "
@@ -606,18 +782,25 @@ class InferenceHTTPServer:
                 if self.path != "/generate":
                     self._json(404, {"error": f"no route {self.path}"})
                     return
+                # the request's passage (docs/DESIGN.md §16): `t_accept`
+                # is its first line, and its end books the layer's
+                # series and spans whatever the answer was
+                self._passage = _Passage(outer.request_path, self.headers)
+                try:
+                    self._generate()
+                finally:
+                    self._passage.finish(
+                        getattr(outer.backend, "tracer", None))
+
+            def _generate(self):
                 # gateway trace propagation (docs/DESIGN.md §16): a
                 # proxied request carries the gateway's trace id — echo
-                # it on every response and land it in the flight
-                # recorder, so one id joins gateway spans, replica
-                # flight events, and the client's copy of the response
+                # it on every response; the passage's two spans land in
+                # the replica's recorder under it, so one id joins the
+                # gateway's spans, the handler's, the engine's and the
+                # client's copy of the response
                 tid = self.headers.get("X-DWT-Trace-Id")
-                if tid:
-                    self._trace_id = tid[:64]
-                    from ..telemetry.flightrecorder import \
-                        get_flight_recorder
-                    get_flight_recorder().record(
-                        "http_generate_proxied", trace_id=self._trace_id)
+                self._trace_id = tid[:64] if tid else None
                 try:
                     n = int(self.headers.get("Content-Length", 0))
                     req = json.loads(self.rfile.read(n) or b"{}")
@@ -629,6 +812,7 @@ class InferenceHTTPServer:
                 except (ValueError, KeyError) as e:
                     self._json(400, {"error": str(e)})
                     return
+                self._passage.parsed(ids, bool(req.get("stream")))
                 # tenant identity (docs/DESIGN.md §7): body field wins
                 # over the gateway-forwarded header; either way it rides
                 # the batching rows into the per-tenant SLO ledger
@@ -771,13 +955,9 @@ class InferenceHTTPServer:
                             kwargs["timeout"] = outer.request_timeout
                         kwargs.update(
                             self._obs_kwargs(outer.backend.generate))
-                        t_req = time.perf_counter()
                         res = outer.backend.generate(ids, max_new,
                                                      seed=seed, **kwargs)
-                        _metrics.HTTP_REQUEST_SECONDS.observe(
-                            time.perf_counter() - t_req, route="/generate")
-                        _metrics.HTTP_GENERATED_TOKENS.inc(
-                            int(res.tokens.size))
+                        self._passage.tokens = int(res.tokens.size)
                         out = {"tokens": res.tokens.tolist()}
                         if getattr(res, "logprobs", None) is not None:
                             out["logprobs"] = [_round_lps(row)
@@ -860,6 +1040,7 @@ class InferenceHTTPServer:
                         gen.close()
                         break
                 ses.finish()
+                self._passage.tokens = sum(len(t) for t in ses.toks)
                 out = {"tokens": ses.toks, "text": ses.texts,
                        "stop_reason": ses.reason}
                 if logprobs:
@@ -940,9 +1121,12 @@ class InferenceHTTPServer:
                     # still before headers, so a clean 500 is possible
                     self._json(500, {"error": str(e)})
                     return
-                items = itertools.chain(
-                    [] if first is None else [first], gen)
+                passage = self._passage
+                items = passage.counted(itertools.chain(
+                    [] if first is None else [first], gen))
 
+                # counted like every other answer (`_json` counts its own)
+                _metrics.HTTP_REQUESTS.inc(route="/generate", code="200")
                 self.send_response(200)
                 self.send_header("Content-Type", "application/jsonl")
                 self.send_header("Transfer-Encoding", "chunked")
@@ -957,7 +1141,15 @@ class InferenceHTTPServer:
 
                 try:
                     for line in lines_fn(items, gen):
-                        chunk((json.dumps(line) + "\n").encode("utf-8"))
+                        data = (json.dumps(line) + "\n").encode("utf-8")
+                        chunk(data)
+                        # the egress account: integer adds a line, and
+                        # a commit where a hand-off's last line is out
+                        passage.lines += 1
+                        passage.writes += 2
+                        passage.bytes += len(data)
+                        if passage.consumed >= passage.edge:
+                            passage.commit()
                 except OSError:
                     return      # client went away; the socket is dead
                 except Exception as e:

@@ -27,7 +27,9 @@ Beside the request spans sits the scheduler's own record,
 with the host phases around it, read by ``/stats`` and mirrored as
 ``sched.*`` annotations into any ``jax.profiler`` capture.  A request's
 ``engine.prefill`` span carries the ``seq`` of the dispatches that served
-it, which ties the two together.
+it, which ties the two together.  On the same clock, :class:`RequestPath`
+is the HTTP replica's record of a request's way to the engine and of its
+tokens' way from the scheduler's hand-off to the socket.
 """
 
 from __future__ import annotations
@@ -260,6 +262,11 @@ _DISPATCH_RING = 128       # x ~135 bytes a row: /stats stays under 18 KB
 # 4.65 ms) and longer than the shortest execution (four chips' 30 ms),
 # so a span that long has certainly held the device up
 STALL_S = 0.05
+# the blocking read is the device's time when all is well, so it leaves
+# a row only from a second on (no execution of any cell is a tenth of
+# that): a standstill inside the read, counted apart from the stalls of
+# the host's own work (``await_stall_count``, not ``stall_s``)
+AWAIT_STALL_S = 1.0
 STALL_FIELDS = ("seq", "span", "t0", "wall", "cpu", "proc_cpu", "gc",
                 "nivcsw", "cause")
 # where a stall's seconds went, by rule and in this order: a garbage
@@ -402,7 +409,9 @@ class DispatchTrace:
     collections have taken (:meth:`watch_gc`), so that :attr:`spans`
     holds wall and CPU seconds of each kind of host work and a span of
     :data:`STALL_S` or more leaves a row in :attr:`stalls` that says
-    where its seconds went (:data:`STALL_CAUSES`)."""
+    where its seconds went (:data:`STALL_CAUSES`); so does a blocking
+    read of :data:`AWAIT_STALL_S` or more (``span: await``), counted in
+    ``await_stall_count`` alone."""
 
     def __init__(self, extra_fields: tuple = ()):
         """``extra_fields``: columns after :data:`DISPATCH_FIELDS`
@@ -467,6 +476,7 @@ class DispatchTrace:
         self.late_reads = 0
         self.stall_s = 0.0
         self.stall_count = 0
+        self.await_stall_count = 0
         self.gc_pause_s = 0.0
         self.gc_max_pause_s = 0.0
         self.gc_collections = [0, 0, 0]
@@ -488,7 +498,7 @@ class DispatchTrace:
         n, wall_s, cpu_s, longest = self.spans[name]
         self.spans[name] = (n + 1, wall_s + wall, cpu_s + cpu,
                             max(longest, wall))
-        if wall >= STALL_S and name != "await":
+        if wall >= (AWAIT_STALL_S if name == "await" else STALL_S):
             proc, gc_s, half = b[2] - a[2], b[4] - a[4], wall / 2
             cause = ("gc" if gc_s >= half else "own_cpu" if cpu >= half
                      else "other_threads" if proc - cpu >= half
@@ -497,8 +507,11 @@ class DispatchTrace:
                 seq, name, round(a[0], 5), round(wall, 5), round(cpu, 5),
                 round(proc, 5), round(gc_s, 5), b[3] - a[3], cause)))
             self.stalls.append(row)
-            self.stall_s += wall
-            self.stall_count += 1
+            if name == "await":
+                self.await_stall_count += 1
+            else:
+                self.stall_s += wall
+                self.stall_count += 1
             # the black box keeps it for a postmortem bundle
             get_flight_recorder().record("sched_stall", **row)
         return wall
@@ -787,11 +800,109 @@ class DispatchTrace:
                        "collections": list(self.gc_collections)},
                 "stall_s": round(self.stall_s, 6),
                 "stall_count": self.stall_count,
+                "await_stall_count": self.await_stall_count,
                 "stalls": list(copy.copy(self.stalls)),
                 "idles": [list(r) for r in copy.copy(self.idles)],
                 "fields": list(fields),
                 "recent": [list(r) for r in copy.copy(self.recent)]
                 + in_flight}
+
+
+# ---------------------------------------------------------------------------
+# the request's path outside the engine (docs/DESIGN.md §16, §19)
+
+# a row of ``/stats.request_path.recent``: the instant the gateway took
+# the request (``t_accept`` less the seconds its header says it held
+# it; ``t_accept`` itself for a direct request), the handler's entry,
+# the body read and decoded, the engine's own submit stamp; then the
+# prompt's tokens and whether the reply is streamed
+# the header, beside ``X-DWT-Trace-Id``, in which the gateway tells the
+# replica the seconds it held the request before forwarding it
+GATEWAY_HELD_HEADER = "X-DWT-Gateway-Held-S"
+REQUEST_PATH_FIELDS = ("t_gateway", "t_accept", "t_parsed", "t_submit",
+                       "prompt_tokens", "streamed")
+_REQUEST_RING = 256        # x ~60 bytes a row
+
+
+class RequestPath:
+    """What a request costs between the socket and the engine, both
+    ways: the record beside :class:`DispatchTrace`, on its clock
+    (``time.monotonic()``; the engine's ``Request`` stamps are
+    ``time.perf_counter()``, on Linux the same ``CLOCK_MONOTONIC``).
+
+    Always on; written by the HTTP handler threads, one short lock a
+    call; read by ``/stats`` through :meth:`snapshot`.  **Ingress**, one
+    :meth:`ingress` a request the engine took: the seconds the gateway
+    held it, the handler's read and parse, the engine's submit, and a
+    row of :data:`REQUEST_PATH_FIELDS`.  **Egress**, one :meth:`egress`
+    a hand-off of the scheduler's (``_deliver``: the tokens a drain
+    recorded for one stream) once the handler's write of the hand-off's
+    last line has returned: the seconds a token lay between the hand-off
+    and the socket, what was written, and the handler thread's own CPU
+    seconds since its last call (``time.thread_time()``: at most what
+    the handlers took of the GIL).  The counters advance at every
+    hand-off, not at a request's end, so the difference of two
+    snapshots is exact to one hand-off a stream."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.recent: "deque[tuple]" = deque(maxlen=_REQUEST_RING)
+        self.reset()
+
+    def reset(self) -> None:
+        with self._lock:
+            self.recent.clear()
+            self.ingress_count = 0
+            self.gateway_s = self.read_parse_s = self.submit_s = 0.0
+            self.handoffs = self.tokens = self.lines = 0
+            self.writes = self.bytes = 0
+            self.egress_s = self.egress_max_s = self.handler_cpu_s = 0.0
+
+    def ingress(self, gateway_s: float, t_accept: float, t_parsed: float,
+                t_submit: float, prompt_tokens: int, streamed: bool) -> None:
+        with self._lock:
+            self.ingress_count += 1
+            self.gateway_s += gateway_s
+            self.read_parse_s += t_parsed - t_accept
+            self.submit_s += t_submit - t_parsed
+            self.recent.append((
+                round(t_accept - gateway_s, 5), round(t_accept, 5),
+                round(t_parsed, 5), round(t_submit, 5), prompt_tokens,
+                int(streamed)))
+
+    def egress(self, stamps, now: float, tokens: int, lines: int,
+               writes: int, nbytes: int, cpu_s: float) -> None:
+        """``stamps``: the instants of the hand-offs whose last line was
+        on the socket at ``now`` (none: a request's end, which commits
+        what the handler wrote and used since its last hand-off)."""
+        with self._lock:
+            for t in stamps:
+                self.egress_s += now - t
+                self.egress_max_s = max(self.egress_max_s, now - t)
+            self.handoffs += len(stamps)
+            self.tokens += tokens
+            self.lines += lines
+            self.writes += writes
+            self.bytes += nbytes
+            self.handler_cpu_s += cpu_s
+
+    def snapshot(self) -> dict:
+        """The ``/stats.request_path`` section; ``recent`` as rows of
+        numbers in the order of ``fields``."""
+        with self._lock:        # one consistent reading; built outside
+            recent = list(self.recent)
+            out = {"ingress_count": self.ingress_count,
+                   "gateway_s": round(self.gateway_s, 6),
+                   "read_parse_s": round(self.read_parse_s, 6),
+                   "submit_s": round(self.submit_s, 6),
+                   "handoffs": self.handoffs,
+                   "egress_s": round(self.egress_s, 6),
+                   "egress_max_s": round(self.egress_max_s, 6),
+                   "tokens": self.tokens, "lines": self.lines,
+                   "writes": self.writes, "bytes": self.bytes,
+                   "handler_cpu_s": round(self.handler_cpu_s, 6)}
+        return {**out, "fields": list(REQUEST_PATH_FIELDS),
+                "recent": [list(r) for r in recent]}
 
 
 def to_chrome_trace(spans: Iterable[dict]) -> dict:

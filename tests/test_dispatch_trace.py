@@ -57,8 +57,8 @@ from distributed_inference_demo_tpu.telemetry.flightrecorder import (
 from distributed_inference_demo_tpu.telemetry.tracing import (
     AHEAD_MISS_REASONS, DISPATCH_FIELDS, DISPATCH_LAST_FIELDS, DISPATCH_PHASES,
     DISPATCH_SPANS,
-    LOOP_DISPATCH_FIELDS, MOE_DISPATCH_FIELDS, STALL_CAUSES, STALL_FIELDS,
-    STALL_S, DispatchTrace)
+    AWAIT_STALL_S, LOOP_DISPATCH_FIELDS, MOE_DISPATCH_FIELDS, STALL_CAUSES,
+    STALL_FIELDS, STALL_S, DispatchTrace)
 
 # bf16 weights and pages, and the int8-weight family the chip cells serve
 MODELS = ("llama-test", "qwen2-test-int8")
@@ -722,14 +722,52 @@ def test_a_stall_names_where_its_seconds_went(monkeypatch, cause):
     tr.enter("wait")
     clock["monotonic"] += 1.0              # nor is the device's time
     tr.awaiting(False)
-    clock["monotonic"] += 1.0              # nor the read
+    clock["monotonic"] += 0.5              # nor a read under a second
     tr.leave()
     snap = tr.snapshot()
     assert [(r["span"], r["seq"], r["t0"], r["wall"], r["cause"])
             for r in snap["stalls"]] == [("pack", 1, 10.0, 0.1, cause)]
     assert (snap["stall_count"], snap["stall_s"]) == (1, 0.1)
-    assert snap["spans"]["await"] == {"n": 1, "wall_s": 1.0, "cpu_s": 0.0,
-                                      "max_s": 1.0}
+    assert snap["await_stall_count"] == 0
+    assert snap["spans"]["await"] == {"n": 1, "wall_s": 0.5, "cpu_s": 0.0,
+                                      "max_s": 0.5}
+
+
+def test_a_read_that_stands_still_for_a_second_leaves_a_row_of_its_own(
+        monkeypatch):
+    """The blocking read is the device's time when all is well, so it is
+    no stall of the host's work and ``stall_s`` / ``stall_count`` read as
+    they did; from ``AWAIT_STALL_S`` on it leaves a row all the same
+    (``span: await``), with what the thread and the process did
+    meanwhile, counted apart."""
+    clock = dict(monotonic=10.0, thread_time=1.0, process_time=5.0)
+    monkeypatch.setattr(tracing, "time", types.SimpleNamespace(
+        monotonic=lambda: clock["monotonic"],
+        process_time=lambda: clock["process_time"]))
+    monkeypatch.setattr(tracing, "resource", types.SimpleNamespace(
+        RUSAGE_THREAD=None, getrusage=lambda who: types.SimpleNamespace(
+            ru_utime=clock["thread_time"], ru_stime=0.0, ru_nivcsw=0)))
+    tr = DispatchTrace()
+    tr.enter("launch")
+    tr.enter("wait")
+    for seconds in (AWAIT_STALL_S - 0.01, AWAIT_STALL_S + 0.5):
+        clock["monotonic"] += 0.02
+        tr.awaiting(False)
+        clock["monotonic"] += seconds
+        clock["process_time"] += 0.004     # next to nothing ran meanwhile
+        tr.returned()
+    snap = tr.snapshot()
+    [row] = snap["stalls"]
+    assert set(row) == set(STALL_FIELDS)
+    assert (row["span"], row["seq"], row["wall"], row["cpu"],
+            row["proc_cpu"], row["gc"], row["cause"]) == (
+        "await", 1, 1.5, 0.0, 0.004, 0.0, "off_cpu")
+    assert row["t0"] == pytest.approx(10.04 + AWAIT_STALL_S - 0.01)
+    assert (snap["await_stall_count"], snap["stall_count"],
+            snap["stall_s"]) == (1, 0, 0.0)
+    assert snap["spans"]["await"]["n"] == 2
+    tr.reset()
+    assert tr.snapshot()["await_stall_count"] == 0
 
 
 def test_garbage_collections_are_timed_until_the_engine_closes():

@@ -418,3 +418,71 @@ def test_two_tenant_fleet_with_live_migration_end_to_end(params):
             srv.shutdown()
             eng.close()
         set_slo_ledger(None)
+
+
+@pytest.mark.quick
+@pytest.mark.parametrize("path", ["/trace/fleet", "/trace"])
+def test_one_trace_id_holds_the_requests_whole_path(params, path):
+    """One proxied streamed request, one id: the gateway's ``route`` and
+    ``proxy``, the replica handler's ``http.ingress``, the engine's
+    ``prefill`` and ``decode`` and, beside the decode, the handler's
+    ``http.egress``.  ``/trace/fleet`` stitches the six into one trace;
+    the gateway's and the replica's own ``/trace`` hold them between
+    them.  The seconds the gateway held the request lie in the
+    handler's span and in the replica's request-path record."""
+    eng = ContinuousBatchingEngine(
+        CFG, params, max_seq=96, max_batch=2, sampling=GREEDY,
+        kv_cache_blocks=0, kv_block_tokens=8, decode_block=4)
+    srv = InferenceHTTPServer(eng, port=0)
+    srv.start()
+    registry = ReplicaRegistry([(srv.host, srv.port)], sustain=3,
+                               probe_interval_s=0.2)
+    router = PrefixAwareRouter(registry, min_prefix_tokens=8,
+                               block_tokens=8)
+    gw = GatewayHTTPServer(registry, router, port=0)
+    gw.start()
+    try:
+        st, _, lines = _post_stream(
+            gw.host, gw.port, {"prompt_ids": [[int(t) for t in PROMPT]],
+                               "max_new_tokens": 9, "stream": True})
+        assert st == 200 and len(lines) == 9
+        if path == "/trace/fleet":
+            bodies = [_get(gw.host, gw.port, path)]
+        else:
+            bodies = [_get(gw.host, gw.port, path),
+                      _get(srv.host, srv.port, path)]
+        assert all(st == 200 for st, _ in bodies)
+        events = [ev for _, body in bodies
+                  for ev in json.loads(body)["traceEvents"]
+                  if ev.get("ph") == "X"]
+        [tid] = {ev["args"]["trace_id"] for ev in events
+                 if ev["name"] == "gateway.route"}
+        mine = {ev["name"]: ev for ev in events
+                if ev["args"]["trace_id"] == tid}
+        assert set(mine) == {"gateway.route", "gateway.proxy",
+                             "http.ingress", "engine.prefill",
+                             "engine.decode", "http.egress"}
+        ing, pre = mine["http.ingress"], mine["engine.prefill"]
+        # the handler's span ends where the wait in the queue begins ...
+        assert abs(ing["ts"] + ing["dur"]
+                   + 1e3 * pre["args"]["queue_wait_ms"] - pre["ts"]) <= 3
+        # ... and lies inside the gateway's hop
+        proxy = mine["gateway.proxy"]
+        assert proxy["ts"] <= ing["ts"] + 2000
+        assert ing["ts"] + ing["dur"] <= proxy["ts"] + proxy["dur"] + 2000
+        eg = mine["http.egress"]["args"]
+        assert (eg["lines"], eg["writes"]) == (9, 18)
+        assert eg["handoffs"] >= 2
+        # the gateway's seconds reached the replica: in the span and in
+        # its record, whose row begins that much before the handler's
+        rp = srv.request_path.snapshot()
+        assert rp["ingress_count"] == 1 and rp["gateway_s"] > 0
+        assert ing["args"]["gateway_ms"] == pytest.approx(
+            1e3 * rp["gateway_s"], abs=1e-2)
+        t_gateway, t_accept = rp["recent"][0][:2]
+        assert t_accept - t_gateway == pytest.approx(rp["gateway_s"],
+                                                     abs=2e-5)
+    finally:
+        gw.shutdown()
+        srv.shutdown()
+        eng.close()

@@ -41,6 +41,8 @@ from distributed_inference_demo_tpu.runtime.gateway import (
 from distributed_inference_demo_tpu.runtime.http_server import (
     InferenceHTTPServer)
 from distributed_inference_demo_tpu.runtime.overload import GatewayOverloaded
+from distributed_inference_demo_tpu.telemetry.tracing import (
+    GATEWAY_HELD_HEADER)
 
 CFG = get_model_config("llama-test")
 GREEDY = SamplingParams(greedy=True)
@@ -478,6 +480,7 @@ class _StubReplica:
         self.sever_after = sever_after
         self.requests = 0
         self.trace_ids = []
+        self.held = []
         outer = self
 
         class H(BaseHTTPRequestHandler):
@@ -499,6 +502,7 @@ class _StubReplica:
                 tid = self.headers.get("X-DWT-Trace-Id")
                 if tid:
                     outer.trace_ids.append(tid)
+                outer.held.append(self.headers.get(GATEWAY_HELD_HEADER))
                 self.rfile.read(int(self.headers.get("Content-Length", 0)))
                 if outer.shed is not None:
                     body = json.dumps({"error": "replica saturated"}
@@ -719,6 +723,11 @@ def test_gateway_metrics_debugz_and_trace_surfaces():
         # one trace id covered gateway -> replica: the replica saw the
         # header, and the gateway's /trace holds route + proxy spans
         assert len(stub.trace_ids) == 2
+        # ... beside it the seconds the gateway held each request before
+        # it forwarded it (body read, routing): a duration, so it means
+        # the same on the replica's host
+        assert len(stub.held) == 2
+        assert all(0 < float(h) < 10 for h in stub.held)
         conn = HTTPConnection(gw.host, gw.port, timeout=10)
         conn.request("GET", "/trace")
         tr = json.loads(conn.getresponse().read())
@@ -943,26 +952,40 @@ def test_midstream_replica_kill_chaos_injected_crash(params):
 
 
 @pytest.mark.quick
-def test_replica_echoes_trace_header_on_generate(params):
+@pytest.mark.parametrize("stream", [False, True])
+def test_replica_echoes_trace_header_on_generate(params, stream):
     """The http_server seam: a proxied /generate carries
     X-DWT-Trace-Id, and the replica echoes it on blocking AND
-    streaming responses (one trace id covers gateway -> replica)."""
+    streaming responses (one trace id covers gateway -> replica);
+    the seconds the gateway says it held the request land in the
+    replica's request-path record as the first part of the row, and a
+    direct request books none."""
     eng = _engine(params)
     srv = InferenceHTTPServer(eng, port=0)
     srv.start()
     try:
-        for stream in (False, True):
+        for proxied in (True, False):
             conn = HTTPConnection(srv.host, srv.port, timeout=300)
             conn.request("POST", "/generate", body=json.dumps(
                 {"prompt_ids": [list(range(2, 10))],
                  "max_new_tokens": 2, "stream": stream}),
                 headers={"Content-Type": "application/json",
-                         "X-DWT-Trace-Id": "00ab00ab00ab00ab"})
+                         **({"X-DWT-Trace-Id": "00ab00ab00ab00ab",
+                             GATEWAY_HELD_HEADER: "0.125000"}
+                            if proxied else {})})
             resp = conn.getresponse()
             assert resp.status == 200
-            assert resp.getheader("X-DWT-Trace-Id") == "00ab00ab00ab00ab"
+            assert resp.getheader("X-DWT-Trace-Id") == (
+                "00ab00ab00ab00ab" if proxied else None)
             resp.read()
             conn.close()
+            path = srv.request_path.snapshot()
+            t_gateway, t_accept = path["recent"][-1][:2]
+            assert path["gateway_s"] == pytest.approx(0.125)
+            assert t_accept - t_gateway == pytest.approx(
+                0.125 if proxied else 0.0, abs=2e-5)
+        assert path["ingress_count"] == 2
+        assert [r[5] for r in path["recent"]] == [int(stream)] * 2
     finally:
         srv.shutdown()
         eng.close()

@@ -76,7 +76,8 @@ def _post(url, body):
         url, data=json.dumps(body).encode(),
         headers={"Content-Type": "application/json"})
     with urllib.request.urlopen(req, timeout=120) as r:
-        return json.loads(r.read())
+        # a streamed reply is JSONL: one object a line
+        return [json.loads(l) for l in r.read().splitlines()]
 
 
 @pytest.fixture(scope="module")
@@ -106,15 +107,20 @@ def _histo(samples, name, labels=frozenset()):
 
 
 @pytest.mark.quick
-def test_metrics_scrape_counters_and_histogram(served_engine):
+@pytest.mark.parametrize("stream", [False, True])
+def test_metrics_scrape_counters_and_histogram(served_engine, stream):
+    """The layer's series move on a streamed request as on one that is
+    not: both are fed from the request's passage through the handler
+    (``t_accept`` to the last write; the tokens written)."""
     url = served_engine
-    _post(url + "/generate", {"prompt_ids": PROMPT, "max_new_tokens": 3})
+    body = {"prompt_ids": PROMPT, "max_new_tokens": 3, "stream": stream}
+    assert len(_post(url + "/generate", body)) == (3 if stream else 1)
     text1, ctype = _get(url + "/metrics")
     assert ctype.startswith("text/plain")
     assert "version=0.0.4" in ctype
     s1, types1 = parse_exposition(text1)
 
-    _post(url + "/generate", {"prompt_ids": PROMPT, "max_new_tokens": 3})
+    _post(url + "/generate", body)
     text2, _ = _get(url + "/metrics")
     s2, types2 = parse_exposition(text2)
 
