@@ -147,9 +147,13 @@ def _state_rows(pool, row, head_step: int, key_step: int):
 
 
 class TokenStream(queue.Queue):
-    """A request's stream: tokens, then ``None``.  ``get`` hands out one
-    item, as a ``queue.Queue`` does; the scheduler hands over all of a
-    drain's items at once."""
+    """A request's stream: tokens, then ``None``.  Both ends move a
+    hand-off at a time: the scheduler hands over all of a drain's items
+    at once (``put_many``), and the consumer that writes them out takes
+    all that is there at once (``get_all``), so a hand-off of four
+    tokens costs each side one turn at the lock and its consumer one
+    wake-up.  ``get`` still hands out one item, as a ``queue.Queue``
+    does."""
 
     def put_many(self, items) -> None:
         """``put`` every item of ``items``, in order, under one lock and
@@ -158,6 +162,25 @@ class TokenStream(queue.Queue):
             self.queue.extend(items)
             self.unfinished_tasks += len(items)
             self.not_empty.notify(len(items))
+
+    def get_all(self, timeout: Optional[float] = None) -> list:
+        """Every item that is queued, in order, under one lock; blocks
+        only while the queue is empty, as ``get(timeout=timeout)`` does
+        (``queue.Empty`` once ``timeout`` seconds have passed with
+        nothing to take).  It waits for no more than is there: one item
+        queued is one item returned.  Like ``get`` it leaves
+        ``unfinished_tasks`` to ``task_done``."""
+        deadline = None if timeout is None else time.monotonic() + timeout
+        with self.not_empty:
+            while not self.queue:
+                left = None if deadline is None else (
+                    deadline - time.monotonic())
+                if left is not None and left <= 0.0:
+                    raise queue.Empty
+                self.not_empty.wait(left)
+            items = list(self.queue)
+            self.queue.clear()
+            return items
 
 
 # the last item of a request's outbox where the request has ended: it
@@ -2252,10 +2275,19 @@ class ContinuousBatchingEngine:
     def generate_stream(self, prompt_ids: np.ndarray, max_new_tokens: int,
                         seed: int = 0, timeout: Optional[float] = None,
                         tenant: Optional[str] = None, trace_id: int = 0,
-                        resume: Optional[dict] = None, on_submit=None):
+                        resume: Optional[dict] = None, on_submit=None,
+                        all_ready: bool = False):
         """Yield [batch] token arrays per step (HTTP streaming surface).
         Single-row streaming only batches trivially; multi-row prompts
-        stream in lockstep of the slowest admitted row.  An ABANDONED
+        stream in lockstep of the slowest admitted row.  With
+        ``all_ready`` it yields LISTS of those arrays instead: every
+        step that is ready when the consumer comes for more (a hand-off
+        of four tokens is one list of four; for a multi-row prompt the
+        steps every unfinished row has), never an empty list, and it
+        waits only when no step is ready: the consumer that writes the
+        steps out can then write them in one piece.  Either way a row's
+        stream is taken whole, one turn at its lock a hand-off
+        (``TokenStream.get_all``).  An ABANDONED
         stream (client disconnect, or a stop-sequence early exit closing
         the generator) cancels its in-flight requests, freeing their
         slots after the current step instead of decoding to max_new.
@@ -2299,39 +2331,60 @@ class ContinuousBatchingEngine:
                                      trace_id=trace_id)
         fetched = [[] for _ in reqs]
         finished = [False] * len(reqs)   # row's None sentinel was consumed
+        pad = self.eos_id if self.eos_id is not None else 0
+
+        def take(i: int) -> None:
+            """All that row ``i``'s stream holds, in one turn at its
+            lock; waits, within the deadline, only if it holds nothing."""
+            try:
+                items = reqs[i].stream.get_all(
+                    timeout=None if deadline is None else
+                    max(0.0, deadline - time.monotonic()))
+            except queue.Empty:
+                raise TimeoutError(
+                    f"request deadline ({timeout}s) exceeded") from None
+            if items[-1] is None:       # end sentinel: EOS, or failure
+                finished[i] = True
+                del items[-1]
+            fetched[i].extend(items)
+
         try:
             if on_submit is not None:
                 on_submit(reqs)
-            for step_i in range(max_new_tokens):
-                out = []
+            step_i = 0
+            while step_i < max_new_tokens:
                 for i, r in enumerate(reqs):
-                    while not finished[i] and len(fetched[i]) <= step_i:
-                        try:
-                            item = r.stream.get(
-                                timeout=None if deadline is None else
-                                max(0.0, deadline - time.monotonic()))
-                        except queue.Empty:
-                            raise TimeoutError(
-                                f"request deadline ({timeout}s) "
-                                "exceeded") from None
-                        if item is None:  # end sentinel: EOS, or failure
-                            finished[i] = True
-                            if r.error is not None:
-                                # a scheduler/device failure must surface
-                                # to the streaming consumer, not end the
-                                # stream as a cleanly-truncated
-                                # generation (siblings cancel in the
-                                # finally below)
-                                raise r.error
-                        else:
-                            fetched[i].append(item)
-                    out.append(fetched[i][step_i]
-                               if step_i < len(fetched[i]) else None)
-                if all(o is None for o in out):
+                    # a row that lacks this step waits for its stream;
+                    # one that has it takes what else is there
+                    while not finished[i] and (len(fetched[i]) <= step_i
+                                               or r.stream.queue):
+                        take(i)
+                # the steps every unfinished row has (the longest row's,
+                # once all have ended: shorter rows are padded)
+                have = [len(f) - step_i for f in fetched]
+                live = [h for h, end in zip(have, finished) if not end]
+                n = min(min(live) if live else max(have),
+                        max_new_tokens - step_i)
+                for r, h, end in zip(reqs, have, finished):
+                    if end and r.error is not None:
+                        # a scheduler/device failure must surface to the
+                        # streaming consumer, not end the stream as a
+                        # cleanly-truncated generation (siblings cancel
+                        # in the finally below): behind the tokens the
+                        # row had, which are yielded first
+                        if h <= 0:
+                            raise r.error
+                        n = min(n, h)
+                if n <= 0:
                     return
-                pad = self.eos_id if self.eos_id is not None else 0
-                yield np.asarray([pad if o is None else o for o in out],
-                                 np.int32)
+                steps = [np.asarray([f[s] if s < len(f) else pad
+                                     for f in fetched], np.int32)
+                         for s in range(step_i, step_i + n)]
+                step_i += n
+                if all_ready:
+                    yield steps
+                else:
+                    yield from steps
         finally:
             for r in reqs:
                 if not r.done.is_set():

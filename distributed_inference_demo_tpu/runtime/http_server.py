@@ -352,17 +352,26 @@ class _Passage:
                              self.t_submit, self.prompt_tokens,
                              self.streamed)
 
-    def counted(self, items):
-        for item in items:
-            self.consumed += 1
-            yield item
+    def counted(self, batches, flush):
+        """The backend's items, one by one, out of the ``batches`` it
+        gives them in; ``flush`` is called where a batch is used up,
+        before the backend is asked for the next and may block."""
+        for batch in batches:
+            for item in batch:
+                self.consumed += 1
+                yield item
+            flush()
 
     def commit(self, final: bool = False) -> float:
-        """The write of a line has returned and ``consumed`` has reached
+        """A write has returned, every line of every item taken so far
+        was in it or in one before it, and ``consumed`` has reached
         ``edge``: take every hand-off whose tokens are all written off
-        the requests' ``handoffs`` and book it, with what was written
-        since the last commit and the thread's CPU seconds since it last
-        read them (``_CPU_READ_S``)."""
+        the requests' ``handoffs`` and book it (a write that carried
+        two hand-offs' lines books both, with one instant), with what
+        was written since the last commit (``lines``; ``writes``, the
+        calls of ``wfile.write`` that carried them, one a chunk;
+        ``bytes``, the lines' own) and the thread's CPU seconds since
+        it last read them (``_CPU_READ_S``)."""
         now = time.monotonic()
         stamps, tokens, edge = [], 0, float("inf")
         # (a reply that is not streamed writes no hand-off out)
@@ -1060,10 +1069,6 @@ class InferenceHTTPServer:
                 ``logprobs=True`` — deltas can't carry them: a logprob
                 belongs to a token, and tokens aren't streamed here)."""
                 kwargs = {"logprobs": True} if logprobs else {}
-                kwargs.update(
-                    self._obs_kwargs(outer.backend.generate_stream))
-                gen = outer.backend.generate_stream(ids, max_new,
-                                                    seed=seed, **kwargs)
 
                 def lines(items, gen):
                     ses = _StopSession(
@@ -1089,11 +1094,15 @@ class InferenceHTTPServer:
                                              for row in ses.lps]
                     yield final
 
-                self._stream_lines(gen, lines)
+                self._stream_lines(ids, max_new, seed, kwargs, lines)
 
-            def _stream_lines(self, gen, lines_fn):
+            def _stream_lines(self, ids, max_new, seed, kwargs,
+                              lines_fn):
                 """ONE owner of the chunked-JSONL framing shared by the
-                plain and stop streaming paths: pull the FIRST backend
+                plain and stop streaming paths: open the backend's
+                stream (``kwargs`` are the path's own; the passage's
+                and ``all_ready`` are added here for a backend that
+                takes them), pull the FIRST backend
                 item before committing to 200 + chunked (validation
                 errors surface on first next() and must become a clean
                 400/500, not a status line spliced into an open chunked
@@ -1103,8 +1112,30 @@ class InferenceHTTPServer:
                 always goes out.  ``lines_fn(items, gen)`` receives the
                 first item already spliced back into ``items`` (one
                 owner of that dance too); ``gen`` rides along only for
-                early ``gen.close()``."""
+                early ``gen.close()``.
+
+                A line is never written while another for the same
+                socket is at hand: the lines of everything the backend
+                had ready go out as ONE chunk in ONE ``wfile.write``
+                (``wfile`` is unbuffered: one ``send``), just before
+                the backend is asked for more and may block.  A
+                backend whose ``generate_stream`` takes ``all_ready``
+                (found as ``on_submit`` is, by its signature) yields
+                lists of items, each all that was ready; one that
+                cannot say gives one item at a time, so a line a
+                chunk.  Nothing waits for more: a backend that holds
+                one item has one line written.  The lines are what they
+                were, one a step; a chunk holds as many as there were.
+                The terminating chunk rides with whatever lines are
+                left when the backend ends (a text tail, the stop
+                path's last line, an error's), else it goes alone."""
                 import itertools
+                fn = outer.backend.generate_stream
+                kwargs.update(self._obs_kwargs(fn))
+                batched = _accepts_kwarg(fn, "all_ready")
+                if batched:
+                    kwargs["all_ready"] = True
+                gen = fn(ids, max_new, seed=seed, **kwargs)
                 first = None
                 try:
                     first = next(gen)
@@ -1122,8 +1153,30 @@ class InferenceHTTPServer:
                     self._json(500, {"error": str(e)})
                     return
                 passage = self._passage
-                items = passage.counted(itertools.chain(
-                    [] if first is None else [first], gen))
+                held: list = []         # lines formed and not yet written
+
+                def flush(end: bytes = b"") -> None:
+                    """What is held, as one chunk in one write, ``end``
+                    (the terminating chunk) behind it; then the egress
+                    account: integer adds a write, and a commit where
+                    a hand-off's last line is out."""
+                    if held:
+                        data = "".join(held).encode("utf-8")
+                        self.wfile.write(b"%x\r\n%b\r\n%b"
+                                         % (len(data), data, end))
+                        passage.lines += len(held)
+                        passage.writes += 1
+                        passage.bytes += len(data)
+                        held.clear()
+                        if passage.consumed >= passage.edge:
+                            passage.commit()
+                    elif end:
+                        self.wfile.write(end)
+
+                rest = itertools.chain([] if first is None else [first],
+                                       gen)
+                items = passage.counted(
+                    rest if batched else ([item] for item in rest), flush)
 
                 # counted like every other answer (`_json` counts its own)
                 _metrics.HTTP_REQUESTS.inc(route="/generate", code="200")
@@ -1135,33 +1188,18 @@ class InferenceHTTPServer:
                     self.send_header("X-DWT-Trace-Id", tid)
                 self.end_headers()
 
-                def chunk(data: bytes) -> None:
-                    self.wfile.write(f"{len(data):x}\r\n".encode())
-                    self.wfile.write(data + b"\r\n")
-
+                dumps = json.dumps
                 try:
                     for line in lines_fn(items, gen):
-                        data = (json.dumps(line) + "\n").encode("utf-8")
-                        chunk(data)
-                        # the egress account: integer adds a line, and
-                        # a commit where a hand-off's last line is out
-                        passage.lines += 1
-                        passage.writes += 2
-                        passage.bytes += len(data)
-                        if passage.consumed >= passage.edge:
-                            passage.commit()
+                        held.append(dumps(line) + "\n")
                 except OSError:
                     return      # client went away; the socket is dead
                 except Exception as e:
                     # generator failure mid-stream: an error JSONL line
                     # keeps the chunked framing intact for the client
-                    try:
-                        chunk((json.dumps({"error": str(e)}) + "\n")
-                              .encode("utf-8"))
-                    except OSError:
-                        return
+                    held.append(dumps({"error": str(e)}) + "\n")
                 try:
-                    chunk(b"")      # terminating chunk
+                    flush(b"0\r\n\r\n")     # terminating chunk
                     self.wfile.flush()
                 except OSError:
                     pass
@@ -1171,10 +1209,6 @@ class InferenceHTTPServer:
                 kwargs = {"logprobs": True} if logprobs else {}
                 if resume is not None:
                     kwargs["resume"] = resume
-                kwargs.update(
-                    self._obs_kwargs(outer.backend.generate_stream))
-                gen = outer.backend.generate_stream(ids, max_new, seed=seed,
-                                                    **kwargs)
                 # a resumed stream continues the dead replica's step
                 # numbering so the client's concatenated stream reads
                 # seamlessly (delivered prefix ends at step k-1)
@@ -1196,14 +1230,13 @@ class InferenceHTTPServer:
                     n_steps = 0
                     for i, item in enumerate(items):
                         toks, lps = item if logprobs else (item, None)
-                        line = {"step": step0 + i,
-                                "tokens": np.asarray(toks).tolist()}
+                        toks = np.asarray(toks).tolist()
+                        line = {"step": step0 + i, "tokens": toks}
                         if lps is not None:
                             line["logprobs"] = _round_lps(np.asarray(lps))
                         if outer.tokenizer is not None:
-                            line["text"] = [
-                                row_text(r, t) for r, t in
-                                enumerate(np.asarray(toks).tolist())]
+                            line["text"] = [row_text(r, t)
+                                            for r, t in enumerate(toks)]
                         yield line
                         n_steps = i + 1
                     if outer.tokenizer is not None and detoks:
@@ -1217,7 +1250,7 @@ class InferenceHTTPServer:
                             yield {"step": step0 + n_steps, "tokens": [],
                                    "text": rem}
 
-                self._stream_lines(gen, lines)
+                self._stream_lines(ids, max_new, seed, kwargs, lines)
 
         self.httpd = ThreadingHTTPServer((host, port), Handler)
         self.host, self.port = self.httpd.server_address

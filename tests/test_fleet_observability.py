@@ -446,15 +446,22 @@ def test_one_trace_id_holds_the_requests_whole_path(params, path):
             gw.host, gw.port, {"prompt_ids": [[int(t) for t in PROMPT]],
                                "max_new_tokens": 9, "stream": True})
         assert st == 200 and len(lines) == 9
-        if path == "/trace/fleet":
-            bodies = [_get(gw.host, gw.port, path)]
-        else:
-            bodies = [_get(gw.host, gw.port, path),
-                      _get(srv.host, srv.port, path)]
-        assert all(st == 200 for st, _ in bodies)
-        events = [ev for _, body in bodies
-                  for ev in json.loads(body)["traceEvents"]
-                  if ev.get("ph") == "X"]
+        # the replica's handler and the gateway's record their spans at
+        # the request's end, which lies behind the last byte the client
+        # reads; an export takes what it gives, so read until both are in
+        events, deadline = [], time.monotonic() + 30
+        while not {"http.egress", "gateway.proxy"} <= {
+                ev["name"] for ev in events}:
+            assert time.monotonic() < deadline
+            if path == "/trace/fleet":
+                bodies = [_get(gw.host, gw.port, path)]
+            else:
+                bodies = [_get(gw.host, gw.port, path),
+                          _get(srv.host, srv.port, path)]
+            assert all(st == 200 for st, _ in bodies)
+            events += [ev for _, body in bodies
+                       for ev in json.loads(body)["traceEvents"]
+                       if ev.get("ph") == "X"]
         [tid] = {ev["args"]["trace_id"] for ev in events
                  if ev["name"] == "gateway.route"}
         mine = {ev["name"]: ev for ev in events
@@ -471,8 +478,9 @@ def test_one_trace_id_holds_the_requests_whole_path(params, path):
         assert proxy["ts"] <= ing["ts"] + 2000
         assert ing["ts"] + ing["dur"] <= proxy["ts"] + proxy["dur"] + 2000
         eg = mine["http.egress"]["args"]
-        assert (eg["lines"], eg["writes"]) == (9, 18)
-        assert eg["handoffs"] >= 2
+        # a line a token, a write a chunk of whole hand-offs (PR 59)
+        assert eg["lines"] == 9 and eg["handoffs"] >= 2
+        assert 1 <= eg["writes"] <= eg["handoffs"]
         # the gateway's seconds reached the replica: in the span and in
         # its record, whose row begins that much before the handler's
         rp = srv.request_path.snapshot()

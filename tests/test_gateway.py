@@ -472,13 +472,21 @@ class _StubReplica:
     /generate``.  ``shed`` makes it answer 503/429 + Retry-After;
     ``sever_after`` kills the SOCKET after N stream lines (no
     terminating chunk) — the mid-stream death the gateway must turn
-    into an error line, never a hang."""
+    into an error line, never a hang.  ``lines_a_chunk``: how many
+    lines one chunk (one write) carries, as a replica's handler that
+    found a whole hand-off on its stream sends them (PR 59).  A
+    ``resume`` in the body starts the stream after the delivered
+    prefix (``resumes`` keeps every such payload), and ``sever_after``
+    then counts this response's own lines."""
 
-    def __init__(self, lines=3, shed=None, sever_after=None):
+    def __init__(self, lines=3, shed=None, sever_after=None,
+                 lines_a_chunk=1):
         self.lines = lines
         self.shed = shed
         self.sever_after = sever_after
+        self.lines_a_chunk = lines_a_chunk
         self.requests = 0
+        self.resumes = []
         self.trace_ids = []
         self.held = []
         outer = self
@@ -503,7 +511,12 @@ class _StubReplica:
                 if tid:
                     outer.trace_ids.append(tid)
                 outer.held.append(self.headers.get(GATEWAY_HELD_HEADER))
-                self.rfile.read(int(self.headers.get("Content-Length", 0)))
+                body = self.rfile.read(
+                    int(self.headers.get("Content-Length", 0)))
+                resume, start = json.loads(body or b"{}").get("resume"), 0
+                if resume is not None:
+                    outer.resumes.append(resume)
+                    start = len(resume["delivered_tokens"])
                 if outer.shed is not None:
                     body = json.dumps({"error": "replica saturated"}
                                       ).encode()
@@ -522,9 +535,10 @@ class _StubReplica:
                     self.wfile.write(f"{len(data):x}\r\n".encode())
                     self.wfile.write(data + b"\r\n")
 
-                for i in range(outer.lines):
+                held = b""
+                for i in range(start, outer.lines):
                     if (outer.sever_after is not None
-                            and i >= outer.sever_after):
+                            and i - start >= outer.sever_after):
                         self.wfile.flush()
                         # a real FIN, not just a dropped handle (the
                         # handler's buffered files keep the fd alive):
@@ -532,8 +546,12 @@ class _StubReplica:
                         self.close_connection = True
                         self.connection.shutdown(socket.SHUT_RDWR)
                         return
-                    chunk(json.dumps({"step": i, "tokens": [100 + i]}
-                                     ).encode() + b"\n")
+                    held += json.dumps({"step": i, "tokens": [100 + i]}
+                                       ).encode() + b"\n"
+                    if ((i - start + 1) % outer.lines_a_chunk == 0
+                            or i == outer.lines - 1):
+                        chunk(held)
+                        held = b""
                 chunk(b"")
 
         self.httpd = ThreadingHTTPServer(("127.0.0.1", 0), H)
@@ -685,6 +703,44 @@ def test_midstream_socket_death_becomes_error_line_not_a_hang():
     finally:
         gw.shutdown()
         severing.close()
+
+
+@pytest.mark.quick
+@pytest.mark.parametrize("sever_after", [None, 4, 8],
+                         ids=["whole", "killed_after_chunk_1",
+                              "killed_after_chunk_2"])
+def test_a_replica_that_sends_several_lines_a_chunk(sever_after):
+    """A replica's handler writes a hand-off's lines as one chunk
+    (PR 59).  The relay reads lines, not chunks: every line is forwarded
+    once and as a chunk of its own, the journal counts every token, and
+    a replica killed between two chunks is resumed from the token
+    behind the last chunk (docs/DESIGN.md section 23)."""
+    first = _StubReplica(lines=10, lines_a_chunk=4,
+                         sever_after=sever_after)
+    survivor = _StubReplica(lines=10, lines_a_chunk=4)
+    gw = _gateway([(first.host, first.port),
+                   (survivor.host, survivor.port)], sustain=1)
+    try:
+        toks = list(range(2, 18))
+        gw.router.record(first.rid, toks)
+        st, _, lines, truncated = _post_stream(
+            gw.host, gw.port, {"prompt_ids": [toks],
+                               "max_new_tokens": 10, "stream": True},
+            timeout=30)
+        assert st == 200 and not truncated
+        assert lines == [{"step": i, "tokens": [100 + i]}
+                         for i in range(10)]
+        assert first.requests == 1 and not first.resumes
+        if sever_after is None:
+            assert survivor.requests == 0
+        else:
+            assert survivor.resumes == [{
+                "delivered_tokens": [100 + i for i in range(sever_after)],
+                "rng_step_offset": sever_after}]
+    finally:
+        gw.shutdown()
+        first.close()
+        survivor.close()
 
 
 @pytest.mark.quick
@@ -879,7 +935,9 @@ def test_loopback_soak_three_replicas_cache_aware(params):
 class _CrashyBackend:
     """Wrap an engine so its token stream consults a comm/faults
     FaultPlan: the crash_after rule raises InjectedCrash mid-stream,
-    modeling a replica process dying between decode steps."""
+    modeling a replica process dying between decode steps.  It counts
+    steps, so it reads the engine's stream a step at a time and, asked
+    for what is ready (``all_ready``, PR 59), says one step."""
 
     def __init__(self, inner, plan, rid):
         self._inner = inner
@@ -889,14 +947,14 @@ class _CrashyBackend:
     def __getattr__(self, name):
         return getattr(self._inner, name)
 
-    def generate_stream(self, *a, **kw):
+    def generate_stream(self, *a, all_ready=False, **kw):
         for item in self._inner.generate_stream(*a, **kw):
             ev = self._plan.on_recv(self._rid)
             if ev is not None:
                 raise InjectedCrash(
                     f"{self._rid}: injected crash_after (seq "
                     f"{ev.get('seq')})")
-            yield item
+            yield [item] if all_ready else item
 
 
 def test_midstream_replica_kill_chaos_injected_crash(params):

@@ -16,11 +16,18 @@ only; a time from this file is a time of XLA's CPU backend):
 - the engine's ``perf_counter`` stamps and the record's ``monotonic`` are
   one clock;
 - a backend that does not stream has no ``request_path`` and its
-  ``/stats`` is as it was.
+  ``/stats`` is as it was;
+- what a handler writes (PR 59), read off a raw socket chunk by chunk:
+  a hand-off of ``k`` tokens is ONE chunk of ``k`` lines, the lines
+  byte for byte what a chunk a line carried; one token is written at
+  once; tokens, then a failure's line, then the terminating chunk; a
+  backend that cannot say what is ready has a line a chunk.
 """
 
 import http.client
 import json
+import queue
+import socket
 import sys
 import threading
 import time
@@ -37,7 +44,7 @@ from distributed_inference_demo_tpu.models import get_model_config  # noqa: E402
 from distributed_inference_demo_tpu.models.loader import load_or_init  # noqa: E402
 from distributed_inference_demo_tpu.ops.sampling import SamplingParams  # noqa: E402
 from distributed_inference_demo_tpu.runtime.batching import (  # noqa: E402
-    ContinuousBatchingEngine)
+    ContinuousBatchingEngine, Request)
 from distributed_inference_demo_tpu.runtime.http_server import (  # noqa: E402
     InferenceHTTPServer)
 from distributed_inference_demo_tpu.telemetry.tracing import (  # noqa: E402
@@ -118,9 +125,13 @@ def test_a_rows_instants_are_ordered_on_the_dispatch_records_clock(served):
     assert row["t_accept"] <= row["t_parsed"] <= row["t_submit"]
     assert (row["prompt_tokens"], row["streamed"]) == (18, 1)
     # ... and before the launch of the dispatch that first carried it
-    spans = {e["name"]: e for e in json.loads(
-        call(served, "GET", "/trace")[1])["traceEvents"]
-        if e.get("args", {}).get("trace_id") == tid}
+    # (the handler records its two spans at its request's end, behind the
+    # last byte its client reads, and an export takes what it gives)
+    spans, deadline = {}, time.monotonic() + 30
+    while "http.egress" not in spans and time.monotonic() < deadline:
+        spans.update({e["name"]: e for e in json.loads(
+            call(served, "GET", "/trace")[1])["traceEvents"]
+            if e.get("args", {}).get("trace_id") == tid})
     assert {"http.ingress", "engine.prefill", "engine.decode",
             "http.egress"} <= set(spans)
     dt = after["dispatch_trace"]
@@ -139,7 +150,11 @@ def test_a_rows_instants_are_ordered_on_the_dispatch_records_clock(served):
     assert eg["args"]["max_ms"] >= 0 and eg["args"]["cpu_ms"] >= 0
     d = grown(before["request_path"], rp)
     assert eg["args"]["handoffs"] == d["handoffs"] >= 2    # 5 + 4
-    assert (eg["args"]["lines"], eg["args"]["writes"]) == (9, 18)
+    # a write is a chunk, and a chunk holds whole hand-offs (here 5 + 4
+    # tokens: two writes, or one if the handler came late to the first)
+    assert eg["args"]["lines"] == 9
+    assert 1 <= eg["args"]["writes"] <= eg["args"]["handoffs"]
+    assert d["writes"] == eg["args"]["writes"]
     assert 0 <= d["egress_s"] <= rp["egress_max_s"] * d["handoffs"] + 1e-6
 
 
@@ -166,8 +181,11 @@ def test_the_counters_are_the_sums_over_the_rows_and_a_hand_count(served):
     snap = stats(served)
     rp, dt = snap["request_path"], snap["dispatch_trace"]
     assert rp["tokens"] == received == dt["delivered_tokens"]
-    assert rp["lines"] == received and rp["writes"] == 2 * received
     assert rp["handoffs"] == dt["delivered_streams"]
+    # a line a token as ever; a write a chunk, a chunk one stream's whole
+    # hand-offs: at least one a stream, at most one a hand-off
+    assert rp["lines"] == received and 16 <= rp["writes"] <= rp["handoffs"]
+    assert rp["writes"] < received
     assert rp["bytes"] == sum(len(json.dumps(l)) + 1
                               for lines in got for l in lines)
     assert 0 <= rp["egress_max_s"] <= rp["egress_s"]
@@ -211,7 +229,9 @@ class _Scripted:
     """A backend whose stream the test holds: two hand-offs of two
     tokens, the second only once ``go`` is set.  ``resumed`` is set when
     the handler asks for the third token, which it does once the second
-    line is on the socket and the first hand-off is booked."""
+    line is on the socket and the first hand-off is booked.  It takes no
+    ``all_ready``, so the handler has its items one at a time: a line a
+    chunk, a write a line."""
 
     def __init__(self):
         self.go, self.resumed = threading.Event(), threading.Event()
@@ -248,17 +268,240 @@ def test_a_snapshot_taken_mid_request_holds_its_finished_hand_offs():
         assert backend.resumed.wait(timeout=60)
         mid = stats(server)["request_path"]
         assert (mid["ingress_count"], mid["handoffs"], mid["tokens"],
-                mid["lines"], mid["writes"]) == (1, 1, 2, 2, 4)
+                mid["lines"], mid["writes"]) == (1, 1, 2, 2, 2)
         backend.go.set()
         client.join(timeout=60)
         assert [l["tokens"] for l in lines] == [[7], [8], [7], [8]]
         end = stats(server)["request_path"]
         assert (end["handoffs"], end["tokens"], end["lines"],
-                end["writes"]) == (2, 4, 4, 8)
+                end["writes"]) == (2, 4, 4, 4)
         assert end["egress_s"] >= mid["egress_s"] >= 0
         assert end["handler_cpu_s"] >= mid["handler_cpu_s"] >= 0
     finally:
         backend.go.set()
+        server.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# what a handler writes: one chunk, one write, for all its streams hold
+
+
+class Held:
+    """The engine's own ``generate_stream`` over requests that no
+    scheduler serves: the test is the scheduler, and puts what it likes
+    on their streams (``made`` hands it each request's rows)."""
+
+    eos_id = None
+    generate_stream = ContinuousBatchingEngine.generate_stream
+
+    def __init__(self):
+        self.made = queue.Queue()
+
+    def stats(self):
+        return {"stages": []}
+
+    def generate(self, prompt_ids, max_new_tokens, seed=0):
+        raise NotImplementedError
+
+    def _submit_rows(self, ids, max_new_tokens, tenant=None, trace_id=0):
+        reqs = [Request(prompt=np.asarray(row), max_new=max_new_tokens,
+                        t_submit=time.perf_counter()) for row in ids]
+        self.made.put(reqs)
+        return reqs
+
+
+def hand_off(req, items, error=None) -> None:
+    """What ``ContinuousBatchingEngine._deliver`` does for one stream."""
+    tokens = [t for t in items if t is not None]
+    if tokens:
+        req.handoffs.append((time.perf_counter(), len(tokens)))
+    if items[-1] is None:
+        req.error = error
+        req.done.set()
+    req.stream.put_many(items)
+
+
+class RawClient:
+    """A client that sees the chunks: one streamed ``/generate`` over a
+    bare socket, the reply read chunk by chunk as it was framed."""
+
+    def __init__(self, server, prompt, max_new):
+        body = json.dumps({"prompt_ids": prompt, "max_new_tokens": max_new,
+                           "stream": True}).encode()
+        self.sock = socket.create_connection((server.host, server.port),
+                                             timeout=30)
+        self.sock.sendall(
+            b"POST /generate HTTP/1.1\r\nHost: test\r\n"
+            b"Content-Type: application/json\r\n"
+            b"Content-Length: %d\r\n\r\n%b" % (len(body), body))
+        self.file = self.sock.makefile("rb")
+        self.headers = None
+
+    def chunk(self) -> bytes:
+        """The next chunk's data (``b""`` is the terminating chunk)."""
+        if self.headers is None:
+            assert self.file.readline().split()[1] == b"200"
+            self.headers = []
+            while (line := self.file.readline()) not in (b"\r\n", b""):
+                self.headers.append(line)
+        size = int(self.file.readline().strip(), 16)
+        data = self.file.read(size)
+        assert self.file.read(2) == b"\r\n"
+        return data
+
+    def close(self):
+        self.file.close()
+        self.sock.close()
+
+
+def booked(server, n, key="handoffs") -> dict:
+    """The record once it holds ``n`` hand-offs: a hand-off is booked
+    when its write has returned, which is after the client can have
+    read it (and a request with no hand-off to count by at its end)."""
+    deadline = time.monotonic() + 30
+    while True:
+        rp = stats(server)["request_path"]
+        if rp[key] >= n or time.monotonic() > deadline:
+            return rp
+        time.sleep(0.005)
+
+
+def line_of(step, token) -> bytes:
+    """A step's line as a chunk a line carried it (the parent's bytes)."""
+    return (json.dumps({"step": step, "tokens": [token]}) + "\n").encode()
+
+
+@pytest.fixture
+def held():
+    backend = Held()
+    server = InferenceHTTPServer(backend, port=0)
+    server.start()
+    yield SimpleNamespace(backend=backend, server=server)
+    server.shutdown()
+
+
+@pytest.mark.quick
+@pytest.mark.parametrize("k", [1, 4, 7])
+def test_a_hand_off_of_k_tokens_is_one_chunk_of_k_lines(held, k):
+    """... and dechunked it is byte for byte what a chunk a line was; the
+    record counts ``k`` lines, one write."""
+    client = RawClient(held.server, [1, 2, 3], k + 2)
+    try:
+        [req] = held.backend.made.get(timeout=30)
+        tokens = list(range(40, 40 + k))
+        hand_off(req, tokens)
+        assert client.chunk() == b"".join(
+            line_of(i, t) for i, t in enumerate(tokens))
+        mid = booked(held.server, 1)
+        assert (mid["handoffs"], mid["tokens"], mid["lines"],
+                mid["writes"]) == (1, k, k, 1)
+        # the last hand-off holds the sentinel: its lines, then the end
+        hand_off(req, [98, 99, None])
+        assert client.chunk() == line_of(k, 98) + line_of(k + 1, 99)
+        assert client.chunk() == b""
+        end = booked(held.server, 2)
+        assert (end["handoffs"], end["tokens"], end["lines"],
+                end["writes"]) == (2, k + 2, k + 2, 2)
+        assert end["bytes"] == sum(
+            len(line_of(i, t)) for i, t in enumerate(tokens + [98, 99]))
+    finally:
+        client.close()
+
+
+@pytest.mark.quick
+def test_a_handler_that_finds_one_token_writes_one_at_once(held):
+    """No waiting for more: the first token is on the socket before the
+    second exists, and the second, put 0.2 s later, is a second chunk."""
+    client = RawClient(held.server, [1, 2, 3], 8)
+    try:
+        [req] = held.backend.made.get(timeout=30)
+        hand_off(req, [7])
+        client.sock.settimeout(5)       # a handler that waited would not
+        assert client.chunk() == line_of(0, 7)      # ... have written it
+        time.sleep(0.2)
+        hand_off(req, [8])
+        assert client.chunk() == line_of(1, 8)
+        # two hand-offs that pile up while the handler is away (it cannot
+        # be held, so: put under one lock) go out as one chunk
+        with req.stream.mutex:
+            req.handoffs.extend([(time.perf_counter(), 2)] * 2)
+            req.stream.queue.extend([1, 2, 3, 4])
+            req.stream.not_empty.notify()
+        assert client.chunk() == b"".join(
+            line_of(2 + i, t) for i, t in enumerate([1, 2, 3, 4]))
+        hand_off(req, [None])
+        assert client.chunk() == b""
+        rp = booked(held.server, 4)
+        assert (rp["handoffs"], rp["tokens"], rp["lines"],
+                rp["writes"]) == (4, 6, 6, 3)
+    finally:
+        client.close()
+
+
+@pytest.mark.quick
+def test_tokens_then_a_failure_tokens_lines_error_line_end(held):
+    """A row that fails with tokens ready: their lines are written, then
+    the error's line, then the terminating chunk."""
+    client = RawClient(held.server, [1, 2, 3], 8)
+    try:
+        [req] = held.backend.made.get(timeout=30)
+        hand_off(req, [5])
+        assert client.chunk() == line_of(0, 5)
+        hand_off(req, [6, 7, None], error=RuntimeError("device lost"))
+        assert client.chunk() == line_of(1, 6) + line_of(2, 7)
+        assert json.loads(client.chunk()) == {"error": "device lost"}
+        assert client.chunk() == b""
+    finally:
+        client.close()
+
+
+@pytest.mark.quick
+def test_two_rows_stream_the_steps_both_have(held):
+    """A multi-row prompt: a chunk holds the steps every unfinished row
+    has; a row that ended is padded; the longest row's tail ends it."""
+    client = RawClient(held.server, [[1, 2, 3], [4, 5, 6]], 8)
+    try:
+        a, b = held.backend.made.get(timeout=30)
+        hand_off(a, [10, 11, 12])
+        hand_off(b, [20])
+        assert [json.loads(l)["tokens"]
+                for l in client.chunk().splitlines()] == [[10, 20]]
+        hand_off(b, [21, None])             # b ends a step before a's third
+        assert [json.loads(l) for l in client.chunk().splitlines()] == [
+            {"step": 1, "tokens": [11, 21]}, {"step": 2, "tokens": [12, 0]}]
+        hand_off(a, [13, None])
+        assert [json.loads(l)["tokens"]
+                for l in client.chunk().splitlines()] == [[13, 0]]
+        assert client.chunk() == b""
+    finally:
+        client.close()
+
+
+@pytest.mark.quick
+def test_a_backend_without_the_batch_form_has_a_line_a_chunk():
+    """``generate_stream`` takes no ``all_ready``: the handler has its
+    items one at a time, and every line is a chunk and a write."""
+    class PerStep:
+        def stats(self):
+            return {"stages": []}
+
+        def generate(self, prompt_ids, max_new_tokens, seed=0):
+            raise NotImplementedError
+
+        def generate_stream(self, prompt_ids, max_new_tokens, seed=0):
+            for t in range(max_new_tokens):
+                yield np.asarray([30 + t], np.int32)
+
+    server = InferenceHTTPServer(PerStep(), port=0)
+    server.start()
+    client = RawClient(server, [1, 2, 3], 5)
+    try:
+        assert [client.chunk() for _ in range(6)] == [
+            line_of(t, 30 + t) for t in range(5)] + [b""]
+        rp = booked(server, 5, "tokens")
+        assert (rp["tokens"], rp["lines"], rp["writes"]) == (5, 5, 5)
+    finally:
+        client.close()
         server.shutdown()
 
 
@@ -292,7 +535,7 @@ def test_the_record_alone_adds_up_under_threads():
 
     def work():
         for _ in range(1000):
-            rec.egress([0.0], 1.0, 4, 4, 8, 100, 0.001)
+            rec.egress([0.0], 1.0, 4, 4, 1, 100, 0.001)
 
     # more threads than cores, and a switch every few bytecodes
     threads = [threading.Thread(target=work) for _ in range(64)]
@@ -308,7 +551,7 @@ def test_the_record_alone_adds_up_under_threads():
     assert not any(t.is_alive() for t in threads)
     snap = rec.snapshot()
     assert (snap["handoffs"], snap["tokens"], snap["lines"], snap["writes"],
-            snap["bytes"]) == (64000, 256000, 256000, 512000, 6400000)
+            snap["bytes"]) == (64000, 256000, 256000, 64000, 6400000)
     assert snap["egress_s"] == pytest.approx(64000.0)
     assert snap["egress_max_s"] == 1.0
     assert snap["handler_cpu_s"] == pytest.approx(64.0)

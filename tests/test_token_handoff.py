@@ -9,7 +9,9 @@ the outbox is empty whenever the scheduler thread may block or leave.
   miss and on a hit, by an event log through patched methods;
 - (c) per stream: tokens in order, then the sentinel, then ``done``,
   however the request ends;
-- (d) ``put_many`` itself;
+- (d) ``put_many`` itself, and its counterpart ``get_all`` (PR 59), and
+  what ``generate_stream`` makes of it: the steps that are ready, as a
+  list (``all_ready``) or one by one;
 - (e) the serialized loop, a speculative drain and a migration relay
   deliver what they did;
 - (f) the counters, and greedy outputs against the plain engine's."""
@@ -31,6 +33,7 @@ from test_mixed_batching import (  # noqa: E402
     mixed_engine, settle, spec_kw)
 from test_mixed_batching import (  # noqa: E402,F401  (the fixtures)
     draft_params, oracle, params)
+from test_request_path import Held, hand_off  # noqa: E402
 
 from distributed_inference_demo_tpu.comm.transport import (  # noqa: E402
     LoopbackNetwork, LoopbackTransport)
@@ -316,6 +319,159 @@ def test_put_many_wakes_a_blocked_get_once_and_gets_return_singly():
     stream.put_many([2, 3])
     stream.put(None)
     assert [stream.get() for _ in range(4)] == [1, 2, 3, None]
+
+
+@pytest.mark.quick
+def test_get_all_takes_what_is_there_in_order_and_waits_only_when_empty():
+    stream = TokenStream()
+    stream.put(1)
+    stream.put_many([2, 3])
+    assert stream.get_all() == [1, 2, 3] and stream.empty()
+    # like `get`, it leaves `unfinished_tasks` to `task_done`
+    assert stream.unfinished_tasks == 3
+    # one item queued is one item returned: it waits for no more
+    stream.put(4)
+    assert stream.get_all(timeout=30) == [4]
+    # the deadline: `queue.Empty` once it has passed with nothing to take,
+    # at once for a deadline already reached
+    t0 = time.monotonic()
+    with pytest.raises(queue.Empty):
+        stream.get_all(timeout=0.05)
+    assert 0.04 <= time.monotonic() - t0 < 5
+    with pytest.raises(queue.Empty):
+        stream.get_all(timeout=0.0)
+    # a consumer blocked in it wakes once a hand-off, and has all of it,
+    # the sentinel in its place
+    got = []
+    reader = threading.Thread(target=lambda: got.append(stream.get_all()),
+                              daemon=True)
+    reader.start()
+    deadline = time.monotonic() + 5
+    while not stream.not_empty._waiters and time.monotonic() < deadline:
+        time.sleep(0.001)              # blocked in get_all()
+    stream.put_many([7, 8, 9, None])
+    reader.join(timeout=5)
+    assert got == [[7, 8, 9, None]] and stream.empty()
+    # `get` beside it still hands out one
+    stream.put_many([5, 6])
+    assert stream.get() == 5 and stream.get_all() == [6]
+
+
+def test_get_all_loses_and_repeats_nothing_under_threads():
+    """More threads than cores and a switch every few bytecodes: 16
+    streams, each fed by two producers (``put_many`` and ``put``) and
+    read by one ``get_all`` consumer; every producer's items arrive
+    once and in its order."""
+    n, streams = 2000, [TokenStream() for _ in range(16)]
+    got = [[] for _ in streams]
+
+    def produce(stream, tag):
+        for i in range(0, n, 4):
+            if tag:
+                stream.put_many([(tag, j) for j in range(i, i + 4)])
+            else:
+                for j in range(i, i + 4):
+                    stream.put((tag, j))
+
+    def consume(stream, out):
+        while len(out) < 2 * n:
+            out.extend(stream.get_all(timeout=60))
+
+    threads = [threading.Thread(target=produce, args=(s, tag), daemon=True)
+               for s in streams for tag in (0, 1)]
+    threads += [threading.Thread(target=consume, args=(s, out), daemon=True)
+                for s, out in zip(streams, got)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    for stream, out in zip(streams, got):
+        assert stream.empty() and stream.unfinished_tasks == 2 * n
+        for tag in (0, 1):
+            assert [j for t, j in out if t == tag] == list(range(n))
+
+
+def _stream_of(backend, rows, max_new, **kw):
+    """``backend``'s stream over ``rows`` prompts, started (its requests
+    are made at the first ``next``, which waits for a token: so on a
+    thread), and the requests."""
+    gen = backend.generate_stream(np.arange(3 * rows).reshape(rows, 3),
+                                  max_new, **kw)
+    first = []
+    starter = threading.Thread(target=lambda: first.append(next(gen)),
+                               daemon=True)
+    starter.start()
+    reqs = backend.made.get(timeout=30)
+    return gen, reqs, starter, first
+
+
+@pytest.mark.quick
+@pytest.mark.parametrize("all_ready", [True, False],
+                         ids=["lists", "per_step"])
+def test_generate_stream_yields_the_steps_that_are_ready(all_ready):
+    """With ``all_ready`` a list of every step that is there, never an
+    empty one; without it the same steps one by one."""
+    gen, [req], starter, first = _stream_of(Held(), 1, 16,
+                                            all_ready=all_ready)
+    hand_off(req, [3])
+    starter.join(timeout=30)
+    hand_off(req, [4, 5, 6, 7])
+    hand_off(req, [8, None])
+    got = first + list(gen)
+    if all_ready:
+        assert [[int(s[0]) for s in steps] for steps in got] == [
+            [3], [4, 5, 6, 7, 8]]
+    else:
+        assert [int(s[0]) for s in got] == [3, 4, 5, 6, 7, 8]
+    assert not req.cancelled
+
+
+@pytest.mark.quick
+@pytest.mark.parametrize("all_ready", [True, False],
+                         ids=["lists", "per_step"])
+def test_generate_stream_keeps_its_deadline_error_and_cancel(all_ready):
+    backend = Held()
+    # the deadline: TimeoutError, and the row is cancelled
+    gen, [req], starter, first = _stream_of(backend, 1, 16, timeout=0.3,
+                                            all_ready=all_ready)
+    hand_off(req, [3, 4])
+    starter.join(timeout=30)
+    with pytest.raises(TimeoutError, match="deadline"):
+        list(gen)
+    assert req.cancelled
+    # a row's error reaches the consumer behind the tokens it had, and its
+    # sibling is cancelled
+    gen, [a, b], starter, first = _stream_of(backend, 2, 16,
+                                             all_ready=all_ready)
+    hand_off(a, [10, 11, 12])
+    hand_off(b, [20, 21, None], error=RuntimeError("device lost"))
+    starter.join(timeout=30)
+    steps = list(first[0]) if all_ready else first
+    with pytest.raises(RuntimeError, match="device lost"):
+        for item in gen:
+            steps.extend(item if all_ready else [item])
+    assert [s.tolist() for s in steps] == [[10, 20], [11, 21]]
+    assert a.cancelled and not b.cancelled      # b was done, a was not
+    # a stream abandoned with steps still in hand cancels its row
+    gen, [req], starter, first = _stream_of(backend, 1, 16,
+                                            all_ready=all_ready)
+    hand_off(req, [3, 4, 5])
+    starter.join(timeout=30)
+    gen.close()
+    assert req.cancelled
+    # ... and one that has read its sentinel does not
+    gen, [req], starter, first = _stream_of(backend, 1, 16,
+                                            all_ready=all_ready)
+    hand_off(req, [3, None])
+    starter.join(timeout=30)
+    assert list(gen) == []
+    assert not req.cancelled
 
 
 # ---------------------------------------------------------------------------
