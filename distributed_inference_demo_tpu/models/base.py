@@ -28,6 +28,18 @@ import jax
 import jax.numpy as jnp
 
 
+def _yarn_tuple(yarn) -> tuple:
+    """``(factor, original positions, beta_fast, beta_slow,
+    attention_factor)`` as floats, from that tuple or from the JSON object
+    a configuration file holds (HF's ``rope_scaling`` names); empty stays
+    empty."""
+    if isinstance(yarn, dict):
+        yarn = (yarn["factor"], yarn["original_max_position_embeddings"],
+                yarn["beta_fast"], yarn["beta_slow"],
+                yarn["attention_factor"])
+    return tuple(float(v) for v in yarn)
+
+
 @dataclass(frozen=True)
 class BlockKind:
     """What one KIND of block of a period has of its own: its attention
@@ -65,12 +77,7 @@ class BlockKind:
                              "(conv >= 2), another kind none")
         if self.gate not in ("none", "per-head", "elementwise"):
             raise ValueError(f"unknown gate {self.gate!r}")
-        yarn = self.yarn
-        if isinstance(yarn, dict):
-            yarn = (yarn["factor"], yarn["original_max_position_embeddings"],
-                    yarn["beta_fast"], yarn["beta_slow"],
-                    yarn["attention_factor"])
-        object.__setattr__(self, "yarn", tuple(float(v) for v in yarn))
+        object.__setattr__(self, "yarn", _yarn_tuple(self.yarn))
         object.__setattr__(self, "rope_theta", float(self.rope_theta))
         object.__setattr__(self, "rotary_share", float(self.rotary_share))
 
@@ -96,7 +103,9 @@ class BlockKind:
                       "routed_scaling_factor", "period", "lead_kind",
                       "experts_held", "norm_unit_offset", "fp32_residual",
                       "fp32_logits", "eva_window", "eva_chunk",
-                      "num_pred_heads"])
+                      "num_pred_heads", "hc_streams", "hc_sinkhorn_iters",
+                      "hc_eps", "hc_res_clamp", "q_lora_rank", "yarn",
+                      "attn_scale"])
 @dataclass(frozen=True)
 class ModelConfig:
     """Static, hashable architecture description shared by all model families.
@@ -218,8 +227,33 @@ class ModelConfig:
     # ``n`` predicts byte ``t + n``); the served path reads the first
     # ``vocab_size``, the next-byte head
     num_pred_heads: int = 1
+    # xing4_0 (manifold-constrained hyper-connections, docs/DESIGN.md
+    # section 28): a token rides the blocks as ``hc_streams`` residual
+    # streams of ``hidden_size`` side by side.  Each sublayer reads them
+    # through a sigmoid map ``[n]``, writes back through ``2 x`` a sigmoid
+    # map ``[n]`` and mixes them by an ``[n, n]`` map made doubly
+    # stochastic by ``hc_sinkhorn_iters`` column-then-row normalisations
+    # of ``exp(clip(. , -+hc_res_clamp))`` with ``hc_eps`` in both
+    # denominators, all three computed from the token's own streams
+    # (``ops.hyper_connection``).  0 = one stream, the path every other
+    # model takes, untouched
+    hc_streams: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
+    # deepseek_v3's low-rank query: ``q = rmsnorm(h W_qa, g_q) W_qb`` with
+    # ``W_qa`` ``[H, q_lora_rank]``.  0 = q in one matrix
+    q_lora_rank: int = 0
+    # the latent kind's rope: ``yarn`` = ``(factor, original positions,
+    # beta_fast, beta_slow, attention_factor)`` as ``BlockKind.yarn`` (on
+    # the ``qk_rope_head_dim`` lanes, interleaved pairs), or empty; and
+    # what multiplies its softmax scale ``(nope + rope) ** -0.5``
+    # (deepseek's ``mscale ** 2`` under YaRN)
+    yarn: tuple = ()
+    attn_scale: float = 1.0
 
     def __post_init__(self):
+        object.__setattr__(self, "yarn", _yarn_tuple(self.yarn))
         object.__setattr__(self, "period",
                            tuple(BlockKind.of(k) for k in self.period))
         if self.lead_kind is not None:
@@ -395,6 +429,26 @@ class ModelConfig:
         closed.  What a row attends is then a function of its position,
         not its token count (:func:`eva_rows`)."""
         return self.eva_window > 0
+
+    @property
+    def latent_scale(self) -> float:
+        """The latent kind's softmax scale: ``(nope + rope) ** -0.5``
+        times ``attn_scale``."""
+        return ((self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+                * self.attn_scale)
+
+    @property
+    def hc_maps(self) -> int:
+        """Coefficients a sublayer's three maps hold: ``2 n + n ** 2``."""
+        n = self.hc_streams
+        return 2 * n + n * n
+
+    @property
+    def hc_args(self) -> dict:
+        """What ``ops.hyper_connection.hc_pre`` is told of the maps."""
+        return dict(n=self.hc_streams, iters=self.hc_sinkhorn_iters,
+                    eps=self.hc_eps, clamp=self.hc_res_clamp,
+                    norm_eps=self.norm_eps)
 
     @property
     def kv_streams(self) -> int:
@@ -643,6 +697,24 @@ def require_token_rows(cfg: ModelConfig, what: str) -> None:
             f"--prefill-chunk --mixed-token-budget)")
 
 
+def require_one_stream(cfg: ModelConfig, what: str) -> None:
+    """Refuse a model with more than one residual stream (``hc_streams``)
+    where ``what`` is built for one ``[b, s, H]`` row a token between
+    blocks, or has never compiled the stream's two kernels: called where
+    such a thing is built, so the model is refused in a sentence and
+    never run wrongly."""
+    if cfg.hc_streams:
+        raise ValueError(
+            f"{what} does not support a model with {cfg.hc_streams} "
+            f"residual streams (family {cfg.family!r}, hc_streams="
+            f"{cfg.hc_streams}): a token rides its blocks as "
+            f"{cfg.hc_streams} x {cfg.hidden_size} values that every "
+            f"sublayer mixes, and it is built for one row of "
+            f"{cfg.hidden_size}. Serve it on one chip, on one stage, "
+            f"through the mixed dispatch (serve --batch-slots "
+            f"--prefill-chunk --mixed-token-budget)")
+
+
 def require_single_pass(cfg: ModelConfig, what: str) -> None:
     """Refuse a looped model (``ut_steps > 1``) where ``what`` visits a
     layer once: a stage of a pipeline owns a layer range and would have
@@ -670,6 +742,7 @@ def slice_stage(full: StageParams, cfg: ModelConfig, spec: StageSpec) -> StagePa
         require_one_kind(cfg, "a pipeline of stages")
         require_single_pass(cfg, "a pipeline of stages")
         require_kv_pair(cfg, "a pipeline of stages")
+        require_one_stream(cfg, "a pipeline of stages")
     layers = jax.tree.map(lambda x: x[spec.layer_start:spec.layer_end], full.layers)
     # Tied embeddings: the last stage needs the token table for the LM head.
     needs_embed = spec.is_first or (spec.is_last and cfg.tie_embeddings)

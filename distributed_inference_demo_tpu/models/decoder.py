@@ -24,6 +24,13 @@ inferred at SURVEY.md §2.2), a single pure ``stage_forward`` covers:
   ``ops.latent_attention``), leading dense blocks before the repeated
   expert blocks, a sigmoid router with a selection bias and a scale, and
   shared experts beside the routed ones.
+- **xing4_0 family** (Xing4.0-29B-A4B): deepseek_v3's blocks with a
+  low-rank query (``q_lora_rank``: ``wq_a``, a norm, ``wq``), YaRN on the
+  latent head's rope lanes with the softmax scale that goes with it, and
+  a changed residual path: ``hc_streams`` residual streams a token, read,
+  written and mixed by three learned maps a sublayer
+  (``ops.hyper_connection``); the embedding replicates, the final norm
+  reads the streams' sum.
 
 The per-stage forward is a single ``lax.scan`` over stacked layer weights —
 XLA compiles one loop body reused across layers, keeping compile time flat in
@@ -43,13 +50,14 @@ from ..ops.quant import dense
 from ..ops.stacked import LayerOf
 from ..ops.norms import layer_norm, rms_norm
 from ..ops.eva_attention import eva_dense_attn
+from ..ops import hyper_connection as hc_ops
 from ..ops import kda as kda_ops
 from ..ops.latent_attention import latent_dense_attn
 from ..ops.rope import (apply_rope, apply_rope_interleaved,
                         apply_rope_kind)
 from .base import (KVCache, ModelConfig, StageParams, StageSpec,
-                   require_one_kind, require_single_pass,
-                   require_token_rows)
+                   require_one_kind, require_one_stream,
+                   require_single_pass, require_token_rows)
 
 
 # ---------------------------------------------------------------------------
@@ -162,16 +170,33 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
             "mlp_norm_w": jnp.ones((L, H), cfg.dtype),
         }
     elif cfg.latent_kv:
-        # deepseek_v3: q in one matrix (q_lora_rank null), the latent and
-        # the shared rope key from ``wkv_a``, and ``kv_b`` kept as its two
-        # halves a head, laid out for the absorbed form: ``w_uk[i]`` =
+        # deepseek_v3: q in one matrix, or with ``q_lora_rank`` in two
+        # around a norm (``wq_a`` -> ``q_a_norm_w`` -> ``wq``); the latent
+        # and the shared rope key from ``wkv_a``, and ``kv_b`` kept as its
+        # two halves a head, laid out for the absorbed form: ``w_uk[i]`` =
         # W_UK_i^T (q_nope_i -> latent), ``w_uv[i]`` = W_UV_i (latent ->
         # v_i).  Seeded at fan-in ** -0.5 like every other matrix
         dn, dr, dv, r = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                          cfg.v_head_dim, cfg.kv_lora_rank)
+        rq = cfg.q_lora_rank
+        # Under a softmax scale with a factor of its own (YaRN's mscale
+        # ** 2, ``attn_scale``) the query's matrix is seeded at 1 /
+        # attn_scale of the fan-in scale: the seeded scores then spread as
+        # they do without the factor (std ~1).  At the fan-in scale they
+        # spread twice as wide at xing4.0's 2.005, the softmax is that
+        # much sharper, and the bf16 rounding of the absorbed query and of
+        # the cached latent moves a log-probability twice as far: 0.127-
+        # 0.158 against the float32 reference where kanana reads 0.07,
+        # 0.069 with the factor taken out of both sides, and 0.121 with a
+        # float32 stream, which is not where it comes from (on the chip,
+        # 2 + 5 blocks; my chip runs, PR 60).  A trained checkpoint's
+        # scores have the factor trained in and bring their own weights
+        q_scale = (float(rq or H) ** -0.5 / cfg.attn_scale
+                   if cfg.attn_scale != 1.0 else None)
         p = {
             "attn_norm_w": jnp.ones((L, H), dt),
-            "wq": big(keys[0], (L, H, nh * (dn + dr)), dt),
+            "wq": big(keys[0], (L, rq or H, nh * (dn + dr)), dt,
+                      scale=q_scale),
             "wkv_a": _dense_init(keys[1], (L, H, r + dr), dt),
             "kv_norm_w": jnp.ones((L, r), dt),
             "w_uk": _dense_init(keys[2], (L, nh, dn, r), dt, scale=r ** -0.5),
@@ -179,6 +204,10 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
             "wo": big(keys[3], (L, nh * dv, H), dt),
             "mlp_norm_w": jnp.ones((L, H), dt),
         }
+        if rq:
+            p["wq_a"] = _dense_init(jax.random.fold_in(keys[0], 1),
+                                    (L, H, rq), dt)
+            p["q_a_norm_w"] = jnp.ones((L, rq), dt)
     else:
         p = {
             "attn_norm_w": jnp.ones((L, H), dt),
@@ -207,6 +236,31 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
             k, (L, nkv, hd), jnp.float32), -1.0, 1.0)
             * hd ** -0.5).astype(dt)
         p["adaptive_mu_k"], p["adaptive_phi"] = vec(k_mu), vec(k_phi)
+    if cfg.hc_streams:
+        # the three maps of each sublayer (``ops.hyper_connection``), all
+        # float32.  ``phi`` at N(0, 1 / nH): a token's 2n + n^2 raw
+        # coefficients are N(0, 1).  ``alpha`` (0.5, 0.5, 0.25) and ``b``
+        # N(0, 0.5) for the read and write maps, 1.5 I + N(0, 0.25) for the
+        # mixing map: on the seeded model h_pre and h_post then spread
+        # over ~0.4 from token to token and the doubly-stochastic map
+        # stands ~0.15 an entry from the uniform map and ~0.2 from the
+        # identity, so a path that left a map out, or held it constant,
+        # cannot pass for one that has it; and its second singular value
+        # (~0.5) lets 20 Sinkhorn steps reach 1e-6 where one leaves a
+        # column 5 % (at worst 15-20 %) from 1
+        n, maps = cfg.hc_streams, cfg.hc_maps
+        sd = jnp.asarray([0.5] * (2 * n) + [0.25] * (n * n), jnp.float32)
+        mean = jnp.concatenate([jnp.zeros((2 * n,), jnp.float32),
+                                1.5 * jnp.eye(n).reshape(-1)])
+        for j, sub in enumerate(("attn", "mlp")):
+            k_phi, k_b = jax.random.split(jax.random.fold_in(rng, 23 + j))
+            p[f"hc_{sub}_phi"] = _dense_init(
+                k_phi, (L, maps, n * H), jnp.float32,
+                scale=float(n * H) ** -0.5)
+            p[f"hc_{sub}_alpha"] = jnp.tile(
+                jnp.asarray([0.5, 0.5, 0.25], jnp.float32), (L, 1))
+            p[f"hc_{sub}_b"] = mean + sd * jax.random.normal(
+                k_b, (L, maps), jnp.float32)
     if cfg.attn_layernorm:  # bloom: LayerNorm has bias; linears have bias
         p["attn_norm_b"] = jnp.zeros((L, H), dt)
         p["mlp_norm_b"] = jnp.zeros((L, H), dt)
@@ -380,6 +434,26 @@ def embed_tokens(params: StageParams, cfg: ModelConfig,
         x = layer_norm(x, params.embed["norm_w"], params.embed["norm_b"],
                        cfg.norm_eps)
     return x
+
+
+def hc_sinkhorn_probe(params: StageParams, cfg: ModelConfig,
+                      ids: jnp.ndarray) -> jnp.ndarray:
+    """Largest ``|row or column sum - 1|`` of the first block's attention
+    map over the embedded tokens ``ids`` ``[T]`` (a model with
+    ``hc_streams``): the same ``hc_pre`` and iteration count as the served
+    blocks, the kernel where ``T`` rows take it.  ~1e-6 says the Sinkhorn
+    iterations ran; the engine's ``/stats.hc.sinkhorn_residual_max`` and
+    ``tools/model_parity.py`` read it."""
+    n = cfg.hc_streams
+    first = params.lead if cfg.lead_dense_layers else params.layers
+    x = embed_tokens(params, cfg, ids[None])[0]
+    if cfg.fp32_residual:
+        x = x.astype(jnp.float32)
+    _, coef = hc_ops.hc_pre(
+        hc_ops.expand(x, n), first["hc_attn_phi"][0],
+        first["hc_attn_alpha"][0], first["hc_attn_b"][0], **cfg.hc_args)
+    return hc_ops.sinkhorn_residual(coef, ids.shape[0], n)
+
 
 def _mlp(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
          tp_axis: Optional[str] = None,
@@ -866,17 +940,23 @@ def _latent_attention(cfg: ModelConfig, lp: dict, h: jnp.ndarray, cache,
     dn, dr, dv, r = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                      cfg.v_head_dim, cfg.kv_lora_rank)
     nh = lp["wq"].shape[-1] // (dn + dr)
-    q = dense(h, lp["wq"], "bsh,hd->bsd")
+    if cfg.q_lora_rank:     # q_a_proj -> q_a_layernorm -> q_b_proj
+        q = rms_norm(dense(h, lp["wq_a"], "bsh,hr->bsr"),
+                     lp["q_a_norm_w"], cfg.norm_eps)
+        q = dense(q, lp["wq"], "bsr,rd->bsd")
+    else:
+        q = dense(h, lp["wq"], "bsh,hd->bsd")
     ckv = dense(h, lp["wkv_a"], "bsh,hd->bsd")
     # as in ``_layer``: the head reshape may not reach the dot
     q, ckv = jax.lax.optimization_barrier((q, ckv))
     q = q.reshape(b, s, nh, dn + dr)
     c = rms_norm(ckv[..., :r], lp["kv_norm_w"], cfg.norm_eps)
     k_pe = apply_rope_interleaved(ckv[:, :, None, r:], positions,
-                                  cfg.rope_theta)[:, :, 0]
-    q_pe = apply_rope_interleaved(q[..., dn:], positions, cfg.rope_theta)
+                                  cfg.rope_theta, cfg.yarn)[:, :, 0]
+    q_pe = apply_rope_interleaved(q[..., dn:], positions, cfg.rope_theta,
+                                  cfg.yarn)
     pad = cache.shape[-1] - (r + dr)      # a plane's or a LayerOf's lanes
-    scale = (dn + dr) ** -0.5
+    scale = cfg.latent_scale
     with jax.named_scope("mla_absorb"):
         q_c = jnp.einsum("bshd,hdr->bshr", q[..., :dn], lp["w_uk"])
         q_abs = jnp.concatenate(
@@ -915,7 +995,30 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     that reads ``valid``, the rows that hold a token).  The caches are this
     layer's planes, or ``LayerOf`` the whole stacks where ``attn_impl``
     addresses a page pool in place; either goes to the hook untouched.
+
+    With ``cfg.hc_streams`` ``x`` is the token's ``n`` streams side by
+    side, ``[b, s, n H]`` (docs/DESIGN.md section 28): each sublayer's
+    norm reads their weighted sum (``hc_pre``) and what the sublayer
+    leaves goes back into every stream beside their mix (``hc_post``),
+    where a one-stream block norms ``x`` and adds.
     """
+    n = cfg.hc_streams
+
+    def hc_read(sub, x):
+        note = getattr(attn_impl, "note_streams", None)
+        with jax.named_scope("hc_pre"):
+            return hc_ops.hc_pre(
+                x, lp[f"hc_{sub}_phi"], lp[f"hc_{sub}_alpha"],
+                lp[f"hc_{sub}_b"], **cfg.hc_args,
+                note=note and partial(note, x.shape[-2]))
+
+    def hc_write(x, y, coef):
+        with jax.named_scope("hc_post"):
+            return hc_ops.hc_post(x, y, coef, n=n)
+
+    streams = x
+    if n:
+        x, coef = hc_read("attn", streams)
     if cfg.attn_layernorm:
         h = layer_norm(x, lp["attn_norm_w"], lp["attn_norm_b"], cfg.norm_eps)
     else:
@@ -943,7 +1046,11 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
         attn = attn + lp["bo"]
     if cfg.sandwich_norm:
         attn = rms_norm(attn, lp["attn_post_norm_w"], cfg.norm_eps)
-    x = x + attn
+    if n:
+        streams = hc_write(streams, attn, coef)
+        x, coef = hc_read("mlp", streams)
+    else:
+        x = x + attn
 
     if cfg.attn_layernorm:
         h = layer_norm(x, lp["mlp_norm_w"], lp["mlp_norm_b"], cfg.norm_eps)
@@ -958,9 +1065,10 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
         y, rows = _mlp(cfg, lp, h, tp_axis, ep_axis, valid), None
     if cfg.sandwich_norm:
         y = rms_norm(y, lp["mlp_post_norm_w"], cfg.norm_eps)
+    x = hc_write(streams, y, coef) if n else x + y
     if moe_stats:
-        return x + y, k_cache, v_cache, rows
-    return x + y, k_cache, v_cache
+        return x, k_cache, v_cache, rows
+    return x, k_cache, v_cache
 
 
 def _window_attn(window: int):
@@ -1214,6 +1322,20 @@ def stage_forward(
                            or not spec.is_last):
         require_token_rows(cfg, "a mesh axis, a stage of a pipeline or "
                                 "the training layout of the cache")
+    if cfg.hc_streams:
+        # n residual streams a token (docs/DESIGN.md section 28): the
+        # embedding replicated, ``[b, s, n H]`` through the lead loop and
+        # the scan.  One stage, one pass, one kind of block, no mesh axis,
+        # the inference layout: nothing else has compiled or measured the
+        # stream's two kernels, and the wire between stages would carry
+        # ``n x H`` a token
+        if (tp_axis is not None or ep_axis is not None or not cache_in_carry
+                or not (spec.is_first and spec.is_last)):
+            require_one_stream(cfg, "a mesh axis, a stage of a pipeline or "
+                                    "the training layout of the cache")
+        if T > 1 or cfg.period:
+            require_one_stream(cfg, "a looped or period model")
+        x = hc_ops.expand(x, cfg.hc_streams)
     if T > 1 or cfg.fp32_residual:
         # The stream rides the layer and pass scans in float32.  Every
         # matmul still takes the model's dtype (``_layer`` casts each
@@ -1330,6 +1452,8 @@ def stage_forward(
                 f"logits_at is an index along s, not {at.dtype}")
             x = jax.vmap(lambda row, i: jax.lax.dynamic_slice_in_dim(
                 row, i, 1, axis=0))(x, at)                 # [b, 1, H]
+        if cfg.hc_streams:  # the final norm reads the streams' sum
+            x = hc_ops.collapse(x, cfg.hc_streams)
         if T == 1:  # a looped model's last pass closed with it already
             x = final_norm(x)
         head = (params.embed["tokens"].T if cfg.tie_embeddings

@@ -9,6 +9,8 @@ Also provides tiny "-test" configs for fast unit tests and virtual-mesh
 dry runs.
 """
 
+import math
+
 from .base import BlockKind, ModelConfig
 
 
@@ -30,6 +32,32 @@ SOLAR_TEST_FULL = BlockKind(attn="full", num_heads=4, rotary_share=0.0,
                             gate="elementwise")
 SOLAR_TEST_KDA = BlockKind(attn="kda", num_heads=4, rotary_share=0.0,
                            conv=4)
+
+# xing4_0 (XingChen-AGI/Xing4.0-29B-A4B config.json): deepseek's YaRN on
+# the 64 rope lanes of a latent head (factor 64 over 4,096 positions,
+# beta 32 / 1; cos and sin times mscale / mscale_all_dim = 1) and the
+# softmax scale's factor that goes with it, get_mscale(64, 1) ** 2 with
+# get_mscale(s, m) = 0.1 m ln s + 1
+XING_YARN = (64.0, 4096.0, 32.0, 1.0, 1.0)
+XING_ATTN_SCALE = (0.1 * math.log(64.0) + 1.0) ** 2
+
+
+def _xing(layers: int) -> ModelConfig:
+    """Xing4.0-29B-A4B with ``layers`` expert blocks after its two leading
+    dense ones (38 as published)."""
+    return ModelConfig(
+        family="xing4_0", vocab_size=131072, hidden_size=3584,
+        num_layers=layers, num_heads=32, num_kv_heads=32,
+        intermediate_size=1024, max_seq_len=262144, rope_theta=10000.0,
+        norm_eps=1e-6, num_experts=64, experts_per_token=4,
+        norm_topk_prob=True, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, lead_dense_layers=2,
+        lead_intermediate_size=9216, num_shared_experts=1,
+        router_scoring="sigmoid", router_bias=True,
+        routed_scaling_factor=2.0, hc_streams=4, hc_sinkhorn_iters=20,
+        hc_eps=1e-6, hc_res_clamp=30.0, q_lora_rank=768, yarn=XING_YARN,
+        attn_scale=XING_ATTN_SCALE)
+
 
 MODEL_REGISTRY = {
     # --- bloom family (reference parity: data/Data.kt:19-33) ---
@@ -118,6 +146,18 @@ MODEL_REGISTRY = {
         v_head_dim=128, lead_dense_layers=1, lead_intermediate_size=6144,
         num_shared_experts=2, router_scoring="sigmoid", router_bias=True,
         routed_scaling_factor=2.448),
+    # --- xing4_0 family: Xing4.0-29B-A4B (``model_type: xing4_0``).
+    # deepseek_v3's latent attention with a low-rank query (768) and YaRN,
+    # 2 leading dense blocks of width 9216 and 38 blocks of 64 experts of
+    # width 1024, 4 a token (sigmoid + bias, renormalised, x 2) beside one
+    # shared expert; and FOUR residual streams a token, read, written and
+    # mixed by manifold-constrained hyper-connections (``hc_streams``).
+    # Its multi-token prediction module (``num_nextn_predict_layers`` 1)
+    # is not loaded: the main model runs without it.  ``-7l``: the first
+    # of seven pipeline stages' blocks (2 leading + 5 expert), what one
+    # 16 GB chip holds in bf16 with the whole vocabulary ---
+    "xing4.0-29b-a4b": _xing(38),
+    "xing4.0-29b-a4b-7l": _xing(5),
     # --- evabyte (EvaByte/EvaByte config.json, ``model_type: evabyte``;
     # EVA attention, arXiv:2302.04542): a byte-level decoder, MHA of 32
     # heads of 128, SwiGLU, RMSNorm with gain 1 + w, a float32 residual
@@ -176,6 +216,20 @@ MODEL_REGISTRY = {
         lead_intermediate_size=96, num_shared_experts=1,
         router_scoring="sigmoid", router_bias=True,
         routed_scaling_factor=2.448, dtype_name="float32"),
+    # 2 leading dense + 2 expert blocks, four residual streams, a query
+    # of rank 16, YaRN (factor 8 over 32 positions: the interpolated band
+    # is reached inside 384) with its softmax scale
+    "xing-bench-test": ModelConfig(
+        family="xing4_0", vocab_size=256, hidden_size=64, num_layers=2,
+        num_heads=4, num_kv_heads=4, intermediate_size=32, max_seq_len=384,
+        norm_eps=1e-6, num_experts=16, experts_per_token=2,
+        norm_topk_prob=True, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, lead_dense_layers=2,
+        lead_intermediate_size=96, num_shared_experts=1,
+        router_scoring="sigmoid", router_bias=True,
+        routed_scaling_factor=2.0, hc_streams=4, q_lora_rank=16,
+        yarn=(8.0, 32.0, 4.0, 1.0, 1.0),
+        attn_scale=(0.1 * math.log(8.0) + 1.0) ** 2, dtype_name="float32"),
     # window 16, chunk 2: with pages of 8 a summary page is one window
     # (8 chunks) and a window is 2 pages; 3 prediction heads
     "evabyte-test": ModelConfig(

@@ -45,7 +45,8 @@ from jax.experimental.pallas import tpu as pltpu
 from .paged_attention import (PATH_DECODE_KERNEL, PATH_GATHER,
                               PATH_PREFILL_KERNEL, POOL_PLANE, WRITE_KERNEL,
                               WRITE_SCATTER, AttnPathRecord, _kernel_write,
-                              _like, _pool, _write_group, route_pool)
+                              _like, _pool, _write_group, route_pool,
+                              streams_note)
 from .stacked import LayerOf
 
 _NEG = -1e30
@@ -452,4 +453,5 @@ def make_latent_attn_impl(rank: int, scale: float, backend: str = "auto",
 
     impl.stacked_cache = True
     impl.latent = True
+    impl.note_streams = streams_note(record, bound)
     return impl, bind

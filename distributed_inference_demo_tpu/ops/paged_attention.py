@@ -1309,10 +1309,11 @@ class AttnPathRecord:
         self._fold_pages: dict = {}
 
     def note(self, program: str, chunk: int, path: str, why: str,
-             pool: str, fold_pages: Optional[int] = None) -> None:
+             pool: Optional[str], fold_pages: Optional[int] = None) -> None:
         entry = path if not why else f"{path}: {why}"
         self._paths.setdefault(program, {})[f"chunk={chunk}"] = entry
-        self._addressing.setdefault(program, {})[f"chunk={chunk}"] = pool
+        if pool is not None:    # an op beside the pools addresses none
+            self._addressing.setdefault(program, {})[f"chunk={chunk}"] = pool
         if fold_pages is not None:
             self._fold_pages.setdefault(program, {})[
                 f"chunk={chunk}"] = fold_pages
@@ -1331,6 +1332,19 @@ class AttnPathRecord:
 
     def fold_pages(self) -> dict:
         return self._copy(self._fold_pages)
+
+
+def streams_note(record: Optional[AttnPathRecord], bound: dict):
+    """A hook's ``note_streams(chunk, path, why)``: the path a model's
+    residual-stream ops took (``ops.hyper_connection``, called by
+    ``models.decoder._layer``), recorded as ``<program>/hc`` beside the
+    attention's.  They choose kernel or plain path while the program is
+    traced, as the attention does, and address no pool."""
+    def note_streams(chunk: int, path: str, why: str) -> None:
+        if record is not None:
+            record.note(f"{bound['program']}/hc", chunk, path, why, None)
+
+    return note_streams
 
 
 def route_paged_attention(backend: str, platform: str, k_pages,
@@ -1558,6 +1572,7 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
 
     impl.for_pool = for_pool
     impl.for_state = for_state
+    impl.note_streams = streams_note(record, bound)
     impl.summarised = summarised
     impl.stacked_cache = True
     return impl, bind

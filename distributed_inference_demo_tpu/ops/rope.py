@@ -29,17 +29,29 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0
 
 
 def apply_rope_interleaved(x: jnp.ndarray, positions: jnp.ndarray,
-                           theta: float = 10000.0) -> jnp.ndarray:
+                           theta: float = 10000.0, yarn: tuple = ()
+                           ) -> jnp.ndarray:
     """Rotary embedding over INTERLEAVED pairs: channels ``(2i, 2i+1)``
     turn by ``positions * theta ** (-2i / d)`` (the pairing of the
     RoFormer paper and of deepseek_v3's ``rope_interleave``), where
-    :func:`apply_rope` pairs ``(i, i + d/2)``.  Same shapes."""
+    :func:`apply_rope` pairs ``(i, i + d/2)``.  Same shapes.  ``yarn`` =
+    ``(factor, original, beta_fast, beta_slow, attention_factor)``: the
+    frequencies are :func:`yarn_frequencies` and cos and sin are scaled
+    by ``attention_factor`` (``apply_rope_kind`` says the same of the
+    rotate-half pairing)."""
     dtype = x.dtype
     d = x.shape[-1]
-    angles = positions[..., None].astype(jnp.float32) * rope_frequencies(
-        d, theta)
-    cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
+    if yarn:
+        factor, original, beta_fast, beta_slow, attention_factor = yarn
+        angles = positions[..., None].astype(jnp.float32) * yarn_frequencies(
+            d, theta, factor, original, beta_fast, beta_slow)
+        cos = (jnp.cos(angles) * attention_factor)[:, :, None, :]
+        sin = (jnp.sin(angles) * attention_factor)[:, :, None, :]
+    else:
+        angles = positions[..., None].astype(jnp.float32) * rope_frequencies(
+            d, theta)
+        cos = jnp.cos(angles)[:, :, None, :]
+        sin = jnp.sin(angles)[:, :, None, :]
     pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
     x1, x2 = pairs[..., 0], pairs[..., 1]
     out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)

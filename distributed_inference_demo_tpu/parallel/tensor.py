@@ -151,9 +151,8 @@ def make_paged_forward_seam(cfg: ModelConfig, spec: StageSpec, mesh,
         if cfg.latent_kv:   # one latent row a token: its own hook
             from ..ops.latent_attention import make_latent_attn_impl
             impl, bind = make_latent_attn_impl(
-                cfg.kv_lora_rank,
-                (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5,
-                backend, interpret, record)
+                cfg.kv_lora_rank, cfg.latent_scale, backend, interpret,
+                record)
         else:
             impl, bind = make_paged_attn_impl(
                 block_tokens, backend, interpret, record,

@@ -78,7 +78,7 @@ import numpy as np
 from ..models.base import (KVCache, ModelConfig, StageParams,
                            StageSpec, eva_rows, pad_cache_capacity,
                            require_kv_pair, require_one_kind,
-                           require_no_state,
+                           require_no_state, require_one_stream,
                            require_token_rows,
                            require_single_pass)
 from ..ops.eva_attention import eva_positions
@@ -91,6 +91,7 @@ from ..telemetry.anomaly import AnomalyMonitor
 from ..telemetry.flightrecorder import get_flight_recorder
 from ..telemetry.slo import get_slo_ledger, sanitize_tenant
 from ..telemetry.tracing import (EVA_DISPATCH_FIELDS,
+                                 HC_DISPATCH_FIELDS,
                                  KDA_DISPATCH_FIELDS,
                                  LATENT_DISPATCH_FIELDS,
                                  LOOP_DISPATCH_FIELDS,
@@ -508,7 +509,18 @@ class ContinuousBatchingEngine:
                     f"window ({cfg.eva_window}) and hold whole pooling "
                     f"chunks of {cfg.eva_chunk}: a chunk then lies in one "
                     f"window and completes every chunk it holds")
+        if cfg.hc_streams:
+            # n residual streams a token (docs/DESIGN.md section 28): the
+            # stream's two kernels were compiled and measured inside the
+            # one-chip forward alone.  The speculative programs and a
+            # mesh refuse it
+            if prompt_lookup or draft_cfg is not None:
+                require_one_stream(cfg, "speculation (a draft model or "
+                                        "prompt lookup)")
+            if mesh is not None and mesh.shape.get("tp", 1) > 1:
+                require_one_stream(cfg, "tensor parallelism (--tp)")
         if draft_cfg is not None:
+            require_one_stream(draft_cfg, "the draft side of speculation")
             require_token_rows(draft_cfg, "the draft side of speculation")
             require_one_kind(draft_cfg, "the draft side of speculation")
             require_single_pass(draft_cfg, "the draft side of speculation")
@@ -1554,7 +1566,15 @@ class ContinuousBatchingEngine:
             + (WINDOW_DISPATCH_FIELDS if self._wmgr is not None else ())
             + (EVA_DISPATCH_FIELDS if self._eva is not None else ())
             + (KDA_DISPATCH_FIELDS if self._state_free is not None
-               else ()))
+               else ())
+            + (HC_DISPATCH_FIELDS if cfg.hc_streams else ()))
+        # a model with n residual streams: the token rows its residual
+        # path computed (every row of a slab and every slot of a decode
+        # step, tokens or not: the kernels run over all of them), and how
+        # far the doubly-stochastic maps stand from 1 (``_hc_probe``,
+        # once, when the programs are warm)
+        self.hc_stats = ({"rows": 0, "sinkhorn_residual_max": None}
+                         if cfg.hc_streams else None)
 
         # (mixed mode never dispatches the serialized step programs: it
         # launches every variant of mixed_step instead, below)
@@ -2243,14 +2263,42 @@ class ContinuousBatchingEngine:
             toks[i, :len(r)] = r
             if logprobs:
                 lps[i, :len(r)] = reqs[i].lps
-        # a model with a recurrent state says, with the log-probabilities,
-        # what state each sequence ended in (docs/DESIGN.md section 27)
-        said = ([{"kda_state": r.state} for r in reqs]
-                if logprobs and self._state_free is not None else None)
+        # with the log-probabilities a model says what a check of them
+        # cannot see: one with a recurrent state the state each sequence
+        # ended in (docs/DESIGN.md section 27), one with several residual
+        # streams how far its served maps stood from doubly stochastic
+        # (section 28: the start-up reading, no device call here)
+        said = None
+        if logprobs and self._state_free is not None:
+            said = [{"kda_state": r.state} for r in reqs]
+        elif logprobs and self.hc_stats is not None:
+            said = [{"hc_sinkhorn_residual":
+                     self.hc_stats["sinkhorn_residual_max"]}] * len(reqs)
         return GenerationResult(tokens=toks, prompt_len=ids.shape[1],
                                 num_new=width,
                                 seconds=time.perf_counter() - t0,
                                 logprobs=lps, generation=said)
+
+    _HC_PROBE_ROWS = 128
+
+    def _hc_probe(self) -> None:
+        """``/stats.hc.sinkhorn_residual_max``: the largest ``|row or
+        column sum - 1|`` of the first block's attention map over
+        ``_HC_PROBE_ROWS`` token rows (ids 1 .. 128), through the same
+        ``hc_pre``, the same leaves and the same iteration count as the
+        served blocks (the kernel on the chip): that the iterations ran,
+        in a number.  One small program of its own, run ONCE, at the
+        end of the warm-up: the maps never leave ``mixed_step``, so the
+        timed programs are not what it reads, and no request pays for
+        it.  A reply with
+        log-probabilities repeats the number (``generate``) and the
+        benchmark's reference check holds it."""
+        from ..models.decoder import hc_sinkhorn_probe
+        ids = (np.arange(1, 1 + self._HC_PROBE_ROWS, dtype=np.int32)
+               % self.cfg.vocab_size)
+        probe = jax.jit(partial(hc_sinkhorn_probe, cfg=self.cfg))
+        self.hc_stats["sinkhorn_residual_max"] = float(
+            probe(self.params, ids=jnp.asarray(ids)))
 
     def _submit_rows(self, ids: np.ndarray, max_new_tokens: int,
                      tenant: Optional[str] = None,
@@ -2529,6 +2577,10 @@ class ContinuousBatchingEngine:
                 out["moe"] = self.moe_counters.snapshot()
             if self.loop_counters is not None:
                 out["loop"] = self.loop_counters.snapshot()
+        if self.hc_stats is not None:
+            out["hc"] = dict(
+                self.hc_stats, streams=self.cfg.hc_streams,
+                sinkhorn_iters=self.cfg.hc_sinkhorn_iters)
         if self.disagg_stats["premigrated_requests"]:
             out["disagg"] = dict(self.disagg_stats)
         if self.resume_stats["requests"]:
@@ -4184,6 +4236,8 @@ class ContinuousBatchingEngine:
             plan.keys = [(0, idle.dec_sub)] if r else []
             self._call_mixed_step(plan)
         jax.block_until_ready(self._last_tok)
+        if self.hc_stats is not None:
+            self._hc_probe()
 
     def _launch_mixed(self, plan, early: bool = False
                       ) -> Optional[types.SimpleNamespace]:
@@ -4574,6 +4628,10 @@ class ContinuousBatchingEngine:
             st["chunk_tokens"] += prefill_tokens
             record.update(kda_row_steps=row_steps,
                           kda_chunk_tokens=prefill_tokens)
+        if self.hc_stats is not None:
+            rows = plan.slab_rows + steps * self.max_batch
+            self.hc_stats["rows"] += rows
+            record["hc_rows"] = rows
         if self._wmgr is not None:
             record["kv_window_tokens"] = plan.kv_window_tokens
             record["prefill_window_pairs"] = plan.prefill_window_pairs

@@ -371,6 +371,21 @@ SPEC_ACCEPT_RATIO = gauge(
     "draft)")
 
 
+# -- the residual path of a model with more than one stream
+# (``/stats.hc``; docs/DESIGN.md section 28) -------------------------------
+
+BATCH_HC_ROWS = counter(
+    "dwt_batching_hc_row_tokens_total",
+    "Token rows the residual path's two kernels computed (hc.rows: a "
+    "slab's rows and every slot of every decode step)")
+BATCH_HC_SINKHORN_RESIDUAL = gauge(
+    "dwt_batching_hc_sinkhorn_residual_ratio",
+    "Largest |row or column sum - 1| of the doubly-stochastic stream "
+    "maps the served kernel produced over the token rows probed at "
+    "start-up (hc.sinkhorn_residual_max; ~1e-6 after 20 iterations, "
+    "~0.2 after one)")
+
+
 def update_batching_series(stats: dict) -> None:
     """Bridge ``ContinuousBatchingEngine.stats()`` (or any dict with the
     same keys) onto the ``dwt_batching_*`` / ``dwt_speculative_*`` /
@@ -409,6 +424,12 @@ def update_batching_series(stats: dict) -> None:
     kv = stats.get("kvcache") or {}
     if kv:
         update_kvcache_series(kv)
+    hc = stats.get("hc") or {}
+    if hc:
+        BATCH_HC_ROWS.set_cumulative(hc.get("rows", 0))
+        res = hc.get("sinkhorn_residual_max")
+        BATCH_HC_SINKHORN_RESIDUAL.set(
+            res if res is not None else float("nan"))
     sp = stats.get("speculative") or {}
     if sp:
         SPEC_ROUNDS.set_cumulative(sp.get("rounds", 0))

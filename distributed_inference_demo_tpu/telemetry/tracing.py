@@ -256,6 +256,11 @@ EVA_DISPATCH_FIELDS = ("kv_attended_rows", "kv_summary_rows",
 # state in the decode loop (a row inside its budget); ``kda_chunk_tokens``:
 # the prompt tokens that went through the chunk form (host arithmetic)
 KDA_DISPATCH_FIELDS = ("kda_row_steps", "kda_chunk_tokens")
+# a model with more than one residual stream (``hc_streams``): the token
+# rows the residual path's two kernels computed in the execution, the
+# slab's rows and every slot at every decode step (host arithmetic on the
+# launched program's shapes, as ``head_rows`` is)
+HC_DISPATCH_FIELDS = ("hc_rows",)
 _DISPATCH_RING = 128       # x ~135 bytes a row: /stats stays under 18 KB
 # a span that is work (every one but ``await``) and lasts this long is a
 # stall: ten times the longest ordinary span (four chips' ``ahead``,

@@ -108,6 +108,24 @@ reads the two ops alone at the model's widths against the recurrence
 token by token (``state_ops_reading``): the served float32 state 7e-5-9e-5,
 ``--bf16-state`` 4e-3 (outputs) and 1e-2 (state).
 
+A model of several residual streams (family ``xing4_0``: ``--model
+xing4.0-29b-a4b-bf16``) is read short (``--batch 2 --prompt 512 --steps
+16``) and long (``--batch 1 --prompt 8192 --steps 16``: 32 chunks through
+the stream's two kernels at 256 rows, positions past YaRN's original
+4,096 and a second group of pages, then 16 steps at one row) against two
+controls that must be refused: ``--hc-sinkhorn-iters 1`` (the served maps
+after ONE Sinkhorn step) and ``--bf16-coef-maps`` (the coefficient maps
+computed in bfloat16): ``READINGS_STREAMS``.  The first is refused by the
+log-probabilities (1.3-1.55 x its seed's sound reading); **the second is
+not** (1.06 x: the maps' rounding is small beside bf16 activations), and
+neither would be by a margin worth the name without the maps' own number:
+``hc_sinkhorn_residual``, the largest ``|row or column sum - 1|`` of the
+served first block's map over 128 prompt tokens (the function behind the
+engine's ``/stats.hc.sinkhorn_residual_max``, which every benchmark run of
+the cell holds to the same limit through the family's ``replay``), 1.2e-6
+sound, 4.9e-3 with bfloat16 maps, 0.13-0.17 after one step, held to
+``HC_RESIDUAL_MAX``.
+
 One ``MODEL_PARITY {json}`` line, exit code 1 if a limit is passed.
 """
 
@@ -226,8 +244,40 @@ EVA_LONG_TOL = (0.031, 0.10)
 # stood 2.5 % under its smallest seed and 8 % over the largest sound one):
 # it is listed and not relied on.  A bfloat16 state is refused by no
 # log-probability: ``state_f32_residue`` holds it (the family's limit)
+# xing4_0 (READINGS_STREAMS below): the mean between 0.0805, the largest
+# of six sound readings, and 0.1027, the smaller of two readings after
+# ONE Sinkhorn step: 1.14 x and 1.12 x of room.  bfloat16 coefficient maps
+# are refused by no log-probability: ``hc_sinkhorn_residual`` holds them
 FAMILY_TOL = {"deepseek_v3": (0.10, TOL_MAX), "laguna": (0.13, TOL_MAX),
-              "evabyte": (0.04, 0.10), "solar_open2": (0.12, TOL_MAX)}
+              "evabyte": (0.04, 0.10), "solar_open2": (0.12, TOL_MAX),
+              "xing4_0": (0.092, TOL_MAX)}
+# (max_over_vocab_mean, max_over_vocab_max, mean_abs, hc_sinkhorn_residual);
+# my chip runs, PR 60, TPU v5 lite, xing4.0-29b-a4b-bf16 at published
+# widths (2 leading + 5 expert blocks, 64 of 64 experts, whole vocabulary,
+# a bf16 stream); every path Pallas (pallas_prefill / pallas_decode, the
+# stream's two calls)
+READINGS_STREAMS = {
+    "served, 1 x 8192 + 16, seeds 0, 1, 2, 3 (the last on the committed "
+    "files alone)": [
+        (0.0670, 0.0824, 0.01167, 1.2e-6), (0.0785, 0.0954, 0.01399, 1.1e-6),
+        (0.0805, 0.0888, 0.01415, 1.2e-6), (0.0797, 0.0932, 0.01377, 1.1e-6)],
+    "served, 2 x 512 + 16, seeds 0, 1": [
+        (0.0604, 0.0748, 0.01037, 1.2e-6), (0.0723, 0.0962, 0.01257, 1.1e-6)],
+    "--hc-sinkhorn-iters 1, 1 x 8192 + 16, seeds 0, 1": [
+        (0.1039, 0.1255, 0.01812, 0.128), (0.1027, 0.1436, 0.01829, 0.172)],
+    "--bf16-coef-maps, 1 x 8192 + 16, seed 0": [
+        (0.0711, 0.0895, 0.01264, 4.9e-3)],
+    # an earlier tree of this PR, seed 0: the query's second matrix seeded
+    # at the fan-in scale, so that the seeded scores spread twice as wide
+    # under the softmax scale's factor 2.005 (``init_layer_params`` says
+    # what was done about it).  The stream's dtype is not where the 0.127
+    # came from (float32 stream 0.121); the factor is (0.069 without it)
+    "fan-in wq: served 1 x 8192 / 2 x 512 / float32 stream / attn_scale "
+    "1 on both sides / one Sinkhorn step / bf16 maps": [
+        (0.1266, 0.1635, 0.02222, 1.2e-6), (0.1576, 0.1842, 0.02748, None),
+        (0.1209, 0.1401, 0.02062, 1.1e-6), (0.0686, 0.0928, 0.01182, 1.2e-6),
+        (0.1444, 0.1733, 0.02480, None), (0.1278, 0.1735, 0.02191, None)],
+}
 # (max_over_vocab_mean, max_over_vocab_max, mean_abs); my chip runs, PR 56,
 # TPU v5 lite, solar-open2-250b-bf16-ep8 at published widths (one period,
 # 40 of 320 experts held, an eighth of the vocabulary); every path Pallas
@@ -272,6 +322,10 @@ READINGS_STATE = {
 # outputs' and the final state's relative error: between 8.8e-5 (float32,
 # served) and 4.4e-3 (bfloat16), 11 x and 4 x of room
 STATE_OPS_TOL = 1e-3
+# a model of several residual streams: the served maps' largest |row or
+# column sum - 1| (READINGS_STREAMS): between float32's floor after 20
+# Sinkhorn steps and what bfloat16 maps or one step leave
+HC_RESIDUAL_MAX = 1e-4
 
 
 def seeded_ids(seed: int, n: int, vocab: int):
@@ -295,6 +349,34 @@ def bf16_router():
             jnp.float32)
 
     decoder._router_logits = logits
+
+
+def hc_sinkhorn_residual(cfg, params, ids) -> float:
+    """Largest ``|row or column sum - 1|`` of the first block's attention
+    map over the first 128 tokens of ``ids``, through the served
+    ``hc_pre`` (the kernel on the chip) at ``cfg``'s iteration count: what
+    the engine's ``/stats.hc.sinkhorn_residual_max`` reads."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_inference_demo_tpu.models.decoder import (
+        hc_sinkhorn_probe)
+
+    probe = jax.jit(lambda p, i: hc_sinkhorn_probe(p, cfg, i))
+    return float(probe(params, jnp.asarray(np.resize(ids, 128))))
+
+
+def bf16_coef_maps():
+    """``--bf16-coef-maps``: the residual streams' coefficient maps (the
+    raw coefficients, the sigmoids, the exponential and every Sinkhorn
+    step) in bfloat16, on the plain path (``ops.hyper_connection``'s
+    ``COEF_DTYPE``; the kernels hold them in float32 and are not taken)."""
+    import jax.numpy as jnp
+
+    from distributed_inference_demo_tpu.ops import hyper_connection
+
+    hyper_connection.COEF_DTYPE = jnp.bfloat16
 
 
 def bf16_softmax_state():
@@ -589,8 +671,11 @@ def reference_logprobs(cfg, params, ids, n_prompt: int):
 
     mc = dataclasses.asdict(cfg)
     embed, layer_eq, final_norm = families.load(cfg.family).equations(mc)
-    # (a period model's leaves are named by kind: no margins are read)
-    E, k = 0 if cfg.mixed_kinds else cfg.num_experts, cfg.experts_per_token
+    # (a period model's leaves are named by kind, and the router of a
+    # model of several residual streams reads their weighted sum: no
+    # margins are read)
+    E, k = (0 if cfg.mixed_kinds or cfg.hc_streams else cfg.num_experts,
+            cfg.experts_per_token)
 
     @jax.jit
     def margin(x, layers, i):
@@ -660,6 +745,13 @@ def main(argv=None) -> int:
     ap.add_argument("--conv-tail-dropped", action="store_true",
                     help="every prefill chunk's convolution starts from "
                          "zeros (a control: must be refused)")
+    ap.add_argument("--hc-sinkhorn-iters", type=int, default=None,
+                    help="serve a model of several residual streams with "
+                         "this many Sinkhorn steps (a control at 1: must be "
+                         "refused)")
+    ap.add_argument("--bf16-coef-maps", action="store_true",
+                    help="the streams' coefficient maps computed in "
+                         "bfloat16 (a control: must be refused)")
     args = ap.parse_args(argv)
     from distributed_inference_demo_tpu.cli import configure_compile_cache
     configure_compile_cache()
@@ -679,6 +771,8 @@ def main(argv=None) -> int:
     if args.bf16_state or args.state_not_carried or args.conv_tail_dropped:
         state_controls(args.bf16_state, args.state_not_carried,
                        args.conv_tail_dropped)
+    if args.bf16_coef_maps:
+        bf16_coef_maps()
     dev = jax.devices()[0]
     cfg = model_config_for(args.model)
     t0 = time.monotonic()
@@ -697,9 +791,11 @@ def main(argv=None) -> int:
     prompts = np.stack([seeded_ids(args.seed * 1000 + 17 + i, args.prompt,
                                    cfg.vocab_size)
                         for i in range(args.batch)])
-    toks, served_lp, paths, state = served(
-        window_ignored(cfg) if args.window_ignored else cfg, params,
-        prompts, args)
+    served_cfg = window_ignored(cfg) if args.window_ignored else cfg
+    if args.hc_sinkhorn_iters is not None:
+        served_cfg = served_cfg.replace(
+            hc_sinkhorn_iters=args.hc_sinkhorn_iters)
+    toks, served_lp, paths, state = served(served_cfg, params, prompts, args)
     t_served = time.monotonic() - t0
     worst, own, margins, means = [], [], [], []
     for r in range(args.batch):
@@ -726,6 +822,8 @@ def main(argv=None) -> int:
            "bf16_state": args.bf16_state,
            "state_not_carried": args.state_not_carried,
            "conv_tail_dropped": args.conv_tail_dropped,
+           "hc_sinkhorn_iters": args.hc_sinkhorn_iters,
+           "bf16_coef_maps": args.bf16_coef_maps,
            "batch": args.batch,
            "prompt": args.prompt, "steps": args.steps,
            "positions": len(worst), "paths": paths,
@@ -751,6 +849,14 @@ def main(argv=None) -> int:
         row["state_f32_residue"] = residue
         row["state_f32_residue_min"] = solar_open2.STATE_F32_RESIDUE_MIN
         row["ok"] = row["ok"] and residue >= row["state_f32_residue_min"]
+    if cfg.hc_streams:
+        # what log-probabilities see least: how far the served doubly-
+        # stochastic maps stand from 1 (``hc_sinkhorn_residual``)
+        row["hc_sinkhorn_residual"] = hc_sinkhorn_residual(
+            served_cfg, params, prompts[0])
+        row["hc_sinkhorn_residual_max"] = HC_RESIDUAL_MAX
+        row["ok"] = row["ok"] and (row["hc_sinkhorn_residual"]
+                                   <= HC_RESIDUAL_MAX)
     print("MODEL_PARITY " + json.dumps(row), flush=True)
     return 0 if row["ok"] else 1
 
