@@ -53,6 +53,7 @@ from ..ops.eva_attention import eva_dense_attn
 from ..ops import hyper_connection as hc_ops
 from ..ops import kda as kda_ops
 from ..ops.latent_attention import latent_dense_attn
+from ..ops.paged_attention import join_rows, split_rows
 from ..ops.rope import (apply_rope, apply_rope_interleaved,
                         apply_rope_kind)
 from .base import (KVCache, ModelConfig, StageParams, StageSpec,
@@ -834,21 +835,25 @@ def _kda_mixer(cfg: ModelConfig, kind, lp: dict, h: jnp.ndarray, state,
     one slab may be one prompt's consecutive chunks), and from zero where
     its first position is 0: a request's first segment needs nothing
     zeroed for it.  ``valid`` [b, s]: a row's first tokens that are there;
-    the others move neither state nor tail."""
+    the others move neither state nor tail.  In a merged call
+    (``ops.paged_attention.split_rows``: ``hook.rows()`` is a pair) the
+    projections and the gates run over all rows at once, and the state's
+    part twice: the segments in the chunk form, then the decoding rows'
+    step."""
     b, s, _ = h.shape
     hd, nh = cfg.head_dim, kind.num_heads
     D, f32 = nh * hd, jnp.float32
     plane = state.layer
     S, tails = state.stack, conv.stack
     rows = hook.rows() if hook is not None else None
-    kernel, why = (kda_ops.on_kernel(S.shape, s, hook.backend)
-                   if hook is not None else (False, "dense cache"))
-    if hook is not None:
-        hook.note(s, "pallas_kda" if kernel else "xla_kda", why)
     interpret = hook is not None and hook.interpret
     if valid is None:
         valid = jnp.ones((b, s), bool)
-    ntok = jnp.sum(valid, axis=1).astype(jnp.int32)
+    # a pair of rows: a merged call, the segments' and the decoding rows'
+    merged = isinstance(rows, tuple)
+    in_parts = lambda *a: split_rows(rows, a) if merged else (a,)  # noqa: E731
+    ntoks = [jnp.sum(part, axis=1).astype(jnp.int32)
+             for (part,) in in_parts(valid)]
     q = dense(h, lp["wq"], "bsh,hd->bsd")
     k = dense(h, lp["wk"], "bsh,hd->bsd")
     v = dense(h, lp["wv"], "bsh,hd->bsd")
@@ -867,10 +872,6 @@ def _kda_mixer(cfg: ModelConfig, kind, lp: dict, h: jnp.ndarray, state,
             dense(dense(h, lp["wg_dn"], "bsh,hr->bsr"), lp["wg_up"],
                   "bsr,rd->bsd").astype(f32) + lp["bg"].astype(f32))
     R = tails.shape[1]
-    if rows is None:
-        at = jnp.arange(b, dtype=jnp.int32)
-    else:   # the last row is nobody's: a row that holds nothing goes there
-        at = jnp.where(ntok > 0, jnp.minimum(rows, R - 1), R - 1)
 
     def heads_of(y):
         """silu(conv) -> q, k, v a head, q and k of unit length (q times
@@ -883,19 +884,31 @@ def _kda_mixer(cfg: ModelConfig, kind, lp: dict, h: jnp.ndarray, state,
         return (unit(cut(y[..., :D])) * hd ** -0.5,
                 unit(cut(y[..., D:2 * D])), cut(y[..., 2 * D:]))
 
-    if s == 1:
-        with jax.named_scope("kda_conv"):
-            tail = jax.lax.dynamic_index_in_dim(tails, plane, 0, False)[at]
-            y, tail = kda_ops.causal_conv(u, tail, lp["conv_w"], ntok)
-            tails = tails.at[plane, at].set(tail)
-            qh, kh, vh = heads_of(y[:, 0])
-        with jax.named_scope("kda_step"):
-            o, S = kda_ops.kda_step(
-                S, plane, None if rows is None else at, qh, kh, vh,
-                g[:, 0], beta[:, 0], ntok > 0, kernel=kernel,
-                interpret=interpret)
-        o = o[:, None]
-    else:
+    def mix(rows, ntok, u, g, beta, positions, S, tails):
+        """The state's part over rows ``[b, s]``, each through its row
+        ``rows[i]`` of the pool: ``(o [b, s, heads, hd], S', tails')``."""
+        b, s = u.shape[:2]
+        kernel, why = (kda_ops.on_kernel(S.shape, s, hook.backend)
+                       if hook is not None else (False, "dense cache"))
+        if hook is not None:
+            hook.note(s, "pallas_kda" if kernel else "xla_kda", why)
+        if rows is None:
+            at = jnp.arange(b, dtype=jnp.int32)
+        else:   # the last row is nobody's: a row that holds nothing goes there
+            at = jnp.where(ntok > 0, jnp.minimum(rows, R - 1), R - 1)
+        if s == 1:
+            with jax.named_scope("kda_conv"):
+                tail = jax.lax.dynamic_index_in_dim(tails, plane, 0,
+                                                    False)[at]
+                y, tail = kda_ops.causal_conv(u, tail, lp["conv_w"], ntok)
+                tails = tails.at[plane, at].set(tail)
+                qh, kh, vh = heads_of(y[:, 0])
+            with jax.named_scope("kda_step"):
+                o, S = kda_ops.kda_step(
+                    S, plane, None if rows is None else at, qh, kh, vh,
+                    g[:, 0], beta[:, 0], ntok > 0, kernel=kernel,
+                    interpret=interpret)
+            return o[:, None], S, tails
         fresh = positions[:, 0] == 0
         outs = []
         for r in range(b):
@@ -911,7 +924,14 @@ def _kda_mixer(cfg: ModelConfig, kind, lp: dict, h: jnp.ndarray, state,
                     S, plane, at[r], fresh[r], qh, kh, vh, g[r], beta[r],
                     kernel=kernel, interpret=interpret)
             outs.append(o_r)
-        o = jnp.stack(outs)
+        return jnp.stack(outs), S, tails
+
+    outs = []       # (merged: the segments' chunk form, then the rows' step)
+    for part_rows, ntok, part in zip(rows if merged else (rows,), ntoks,
+                                     in_parts(u, g, beta, positions)):
+        o, S, tails = mix(part_rows, ntok, *part, S, tails)
+        outs.append(o)
+    o = join_rows(outs) if merged else o
     with jax.named_scope("kda_out_norm"):
         y = rms_norm(o, lp["o_norm_w"], cfg.norm_eps)     # a head's own
         y = (y * out_gate.reshape(b, s, nh, hd)).astype(cfg.dtype)
@@ -1004,15 +1024,28 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     """
     n = cfg.hc_streams
 
+    # A merged call's streams (``stage_forward``) hold padding behind the
+    # call's rows, up to the kernels' whole tiles: one call over them all,
+    # recorded under the chunks of the two parts it serves
+    held = positions.shape[1]      # the rows that hold a token
+
     def hc_read(sub, x):
         note = getattr(attn_impl, "note_streams", None)
+        chunks = (x.shape[-2],)
+        if x.shape[1] != held:
+            r, B = (t.shape[0] for t in attn_impl.parts())
+            chunks = ((held - B) // r, 1)
         with jax.named_scope("hc_pre"):
-            return hc_ops.hc_pre(
+            h, coef = hc_ops.hc_pre(
                 x, lp[f"hc_{sub}_phi"], lp[f"hc_{sub}_alpha"],
                 lp[f"hc_{sub}_b"], **cfg.hc_args,
-                note=note and partial(note, x.shape[-2]))
+                note=note and (lambda path, why: [
+                    note(chunk, path, why) for chunk in chunks]))
+        return (h if x.shape[1] == held else h[:, :held]), coef
 
     def hc_write(x, y, coef):
+        if x.shape[1] != held:
+            y = jnp.pad(y, ((0, 0), (0, x.shape[1] - held), (0, 0)))
         with jax.named_scope("hc_post"):
             return hc_ops.hc_post(x, y, coef, n=n)
 
@@ -1219,7 +1252,10 @@ def stage_forward(
     gathers ``x[b, logits_at[b]]`` after its last layer and runs the
     final norm and the head over those rows alone, ``[b, 1, V]`` (an
     index is read as ``lax.dynamic_slice`` reads one: a negative one
-    counts from the end, and it is clamped into ``[0, s)``).  Whoever
+    counts from the end, and it is clamped into ``[0, s)``); ``[b, n]``:
+    ``n`` positions a row, ``[b, n, V]`` (the merged call of
+    ``runtime.engine``'s ``slab_step_body``, whose one row holds a slab's
+    segments and the decoding rows).  Whoever
     samples reads one position a row: decode and a whole-prompt prefill
     pass ``s - 1``, a padded chunk its last real token's column, the
     mixed dispatch's slab each segment's ``seg_lens - 1``; a full
@@ -1336,6 +1372,12 @@ def stage_forward(
         if T > 1 or cfg.period:
             require_one_stream(cfg, "a looped or period model")
         x = hc_ops.expand(x, cfg.hc_streams)
+        if getattr(attn_impl, "parts", lambda: None)() is not None:
+            # a merged call's rows (r C + B) are not whole tiles of the
+            # streams' kernels: the streams ride the blocks padded to them
+            # (``_layer``; one call a sublayer, not one a part)
+            x = jnp.pad(x, ((0, 0), (0, hc_ops.whole_tiles(x.shape[1])
+                                     - x.shape[1]), (0, 0)))
     if T > 1 or cfg.fp32_residual:
         # The stream rides the layer and pass scans in float32.  Every
         # matmul still takes the model's dtype (``_layer`` casts each
@@ -1446,7 +1488,10 @@ def stage_forward(
     new_cache = KVCache(new_k, new_v, cache_start + inputs.shape[1])
 
     if spec.is_last:
-        if logits_at is not None:
+        if logits_at is not None and jnp.ndim(logits_at) == 2:
+            # several positions a row, [b, n]: a merged call's
+            x = jnp.take_along_axis(x, logits_at[:, :, None], axis=1)
+        elif logits_at is not None:
             at = jnp.broadcast_to(jnp.asarray(logits_at), x.shape[:1])
             assert jnp.issubdtype(at.dtype, jnp.integer), (
                 f"logits_at is an index along s, not {at.dtype}")

@@ -82,6 +82,15 @@ def on_kernel(tokens: int, width: int, n: int, backend: str = "auto",
     return True, ""
 
 
+def whole_tiles(tokens: int) -> int:
+    """The rows the kernels serve that hold ``tokens``: sixteens up to one
+    grid step, whole steps of ``TILE_TOKENS`` past it (:func:`on_kernel`).
+    A caller whose rows are no such count pads them to it (zeros: the ops
+    work a row at a time)."""
+    unit = _ROWS if tokens <= TILE_TOKENS else TILE_TOKENS
+    return -(-tokens // unit) * unit
+
+
 # ------------------------------------------------------------- the maps
 
 def _coefficients(m, alpha, b, n: int, iters: int, eps: float,

@@ -45,8 +45,8 @@ from jax.experimental.pallas import tpu as pltpu
 from .paged_attention import (PATH_DECODE_KERNEL, PATH_GATHER,
                               PATH_PREFILL_KERNEL, POOL_PLANE, WRITE_KERNEL,
                               WRITE_SCATTER, AttnPathRecord, _kernel_write,
-                              _like, _pool, _write_group, route_pool,
-                              streams_note)
+                              _like, _pool, _write_group, over_parts,
+                              parts_of, route_pool, streams_note)
 from .stacked import LayerOf
 
 _NEG = -1e30
@@ -419,9 +419,8 @@ def make_latent_attn_impl(rank: int, scale: float, backend: str = "auto",
         bound["tables"] = tables
         bound["program"] = program
 
-    def impl(q_abs, row, pages, positions):
+    def attend(tables, q_abs, row, positions, pages):
         assert isinstance(pages, LayerOf), "the pool comes stacked"
-        tables = bound["tables"]
         chunk, nh = q_abs.shape[1], q_abs.shape[2]
         platform = jax.default_backend()
         path, why = route_latent_attention(backend, platform, pages, chunk,
@@ -451,7 +450,13 @@ def make_latent_attn_impl(rank: int, scale: float, backend: str = "auto",
                 pages = LayerOf(whole.updated(pages), whole.layer)
         return out, pages
 
+    def impl(q_abs, row, pages, positions):
+        # (a pair of tables: a merged call, a part through each)
+        return over_parts(bound["tables"], (q_abs, row, positions),
+                          (pages,), attend)
+
     impl.stacked_cache = True
     impl.latent = True
+    impl.parts = parts_of(bound)
     impl.note_streams = streams_note(record, bound)
     return impl, bind

@@ -1347,6 +1347,54 @@ def streams_note(record: Optional[AttnPathRecord], bound: dict):
     return note_streams
 
 
+def parts_of(bound: dict):
+    """A hook's ``parts()``: the pair a merged call's rows divide by
+    (:func:`split_rows`), or None where one table is bound."""
+    def parts():
+        tables = bound["tables"]
+        return tables if isinstance(tables, tuple) else None
+
+    return parts
+
+
+def split_rows(tables, arrays):
+    """The two parts of a MERGED call's rows (``mixed_step``'s slab pass
+    that carries a decode step, docs/DESIGN.md section 19): ``arrays``
+    ``[1, r x C + B, ...]`` hold a slab's ``r`` segments of ``C`` rows and
+    then the ``B`` decoding rows, one token each, and ``tables`` is the
+    pair of their tables (``[r, W]``, ``[B, W]``), or of anything else with
+    one entry a segment and one a decoding row.  Returns each part's arrays
+    in the layout a hook takes, ``[r, C, ...]`` then ``[B, 1, ...]``."""
+    r, B = tables[0].shape[0], tables[1].shape[0]
+    cut = arrays[0].shape[1] - B
+    return (
+        tuple(a[0, :cut].reshape((r, cut // r) + a.shape[2:])
+              for a in arrays),
+        tuple(a[0, cut:].reshape((B, 1) + a.shape[2:]) for a in arrays))
+
+
+def join_rows(outs):
+    """Parts' outputs ``[rows, chunk, ...]`` side by side again,
+    ``[1, all rows, ...]``: the other half of :func:`split_rows`."""
+    return jnp.concatenate(
+        [o.reshape((1, -1) + o.shape[2:]) for o in outs], axis=1)
+
+
+def over_parts(tables, rows, pages, call):
+    """``call(table, *rows, *pages) -> (out, *pages)``: once where
+    ``tables`` is one table; where it is a pair (a merged call,
+    :func:`split_rows`) once a part, the slab's route and then the
+    decoding rows', each through its own table and with the pages the
+    part before left, the outputs side by side."""
+    if not isinstance(tables, tuple):
+        return call(tables, *rows, *pages)
+    outs = []
+    for tab, part in zip(tables, split_rows(tables, rows)):
+        out, *pages = call(tab, *part, *pages)
+        outs.append(out)
+    return (join_rows(outs), *pages)
+
+
 def route_paged_attention(backend: str, platform: str, k_pages,
                           chunk: int, groups: int):
     """``(path, why)`` for one traced attention call — the ONE routing
@@ -1428,6 +1476,10 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
     during tracing (the layer scan closes over it as a loop constant).
     ``program`` names the compiled program being traced; the path each
     of its attention calls takes lands in ``record`` under that name.
+    ``tables`` may be a PAIR, a slab's and the decoding rows': the forward
+    is then a merged call (:func:`split_rows`), and every hook made here
+    runs the slab's rows through the first table and the decoding rows
+    through the second, on the route each would take alone.
 
     ``backend``: "auto" (Pallas on TPU, XLA gather elsewhere), "xla", or
     "pallas" — the rule is :func:`route_paged_attention`.
@@ -1512,8 +1564,11 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
         return out, k_pages, v_pages
 
     def impl(q, k, v, k_pages, v_pages, positions, cache_start, slopes):
-        return attend(q, k, v, k_pages, v_pages, positions, slopes,
-                      bound["tables"], bound["program"])
+        program = bound["program"]
+        return over_parts(
+            bound["tables"], (q, k, v, positions), (k_pages, v_pages),
+            lambda tab, q, k, v, pos, kp, vp: attend(
+                q, k, v, kp, vp, pos, slopes, tab, program))
 
     def for_pool(pool: int, pools: int, window: int, name: str):
         """The hook of one KIND of block of a model with a cache spec a
@@ -1523,13 +1578,17 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
 
         def kind_impl(q, k, v, k_pages, v_pages, positions, cache_start,
                       slopes):
-            tables = bound["tables"]
-            width = (tables.shape[1] - state_cols) // pools
-            with jax.named_scope(f"attn_{name}"):
-                return attend(q, k, v, k_pages, v_pages, positions, slopes,
+            program = f"{bound['program']}/{name}"
+
+            def one(tables, q, k, v, pos, kp, vp):
+                width = (tables.shape[1] - state_cols) // pools
+                return attend(q, k, v, kp, vp, pos, slopes,
                               tables[:, pool * width:(pool + 1) * width],
-                              f"{bound['program']}/{name}", window or 0,
-                              split=True)
+                              program, window or 0, split=True)
+
+            with jax.named_scope(f"attn_{name}"):
+                return over_parts(bound["tables"], (q, k, v, positions),
+                                  (k_pages, v_pages), one)
 
         kind_impl.stacked_cache = True
         return kind_impl
@@ -1546,11 +1605,15 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
 
         def eva_impl(q, k, v, k_pages, v_pages, positions, cache_start,
                      slopes):
+            program = bound["program"]
             with jax.named_scope("attn_eva"):
-                return paged_eva_attention(
-                    attend, q, k, v, k_pages, v_pages, positions,
-                    bound["tables"], bound["program"], window, chunk, mu,
-                    phi, backend=backend, interpret=interpret)
+                return over_parts(
+                    bound["tables"], (q, k, v, positions),
+                    (k_pages, v_pages),
+                    lambda tab, q, k, v, pos, kp, vp: paged_eva_attention(
+                        attend, q, k, v, kp, vp, pos, tab, program, window,
+                        chunk, mu, phi, backend=backend,
+                        interpret=interpret))
 
         eva_impl.stacked_cache = True
         return eva_impl
@@ -1566,12 +1629,14 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
                 record.note(f"{bound['program']}/{name}", chunk, path, why,
                             "state row")
 
+        # (a merged call's rows are a pair, as its tables are)
         return types.SimpleNamespace(
-            rows=lambda: bound["tables"][:, -1], note=note,
-            backend=backend, interpret=interpret)
+            rows=lambda: jax.tree.map(lambda t: t[:, -1], bound["tables"]),
+            note=note, backend=backend, interpret=interpret)
 
     impl.for_pool = for_pool
     impl.for_state = for_state
+    impl.parts = parts_of(bound)
     impl.note_streams = streams_note(record, bound)
     impl.summarised = summarised
     impl.stacked_cache = True

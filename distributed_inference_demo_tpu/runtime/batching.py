@@ -82,6 +82,7 @@ from ..models.base import (KVCache, ModelConfig, StageParams,
                            require_token_rows,
                            require_single_pass)
 from ..ops.eva_attention import eva_positions
+from ..ops.hyper_connection import whole_tiles
 from ..ops.latent_attention import latent_tile_tokens
 from ..ops.paged_attention import prefill_pages_walked, sub_chunk
 from ..ops.sampling import SamplingParams, filtered_logits, sample_logits
@@ -784,7 +785,7 @@ class ContinuousBatchingEngine:
 
         def _fused_loop(step_fn, params, cache, lengths, last_tok,
                         active, rng, eos, budget, num_steps,
-                        done0=None):
+                        done0=None, begun=None):
             """The device-resident fused-block loop shared by the dense
             and paged multi-step jits (docs/DESIGN.md §13): up to
             ``num_steps`` lockstep steps in one dispatch (one host sync
@@ -805,11 +806,17 @@ class ContinuousBatchingEngine:
             scan's consumption order), so sampled fused blocks keep
             their exact historical streams.  ``done0``: rows already
             done at entry — a mixed dispatch's freshly installed row
-            whose first sampled token hit eos."""
+            whose first sampled token hit eos.  ``begun``: ``(j0, toks,
+            lps)`` of a block whose first ``j0`` steps (a traced count)
+            ran before the loop, ``toks`` / ``lps`` holding their
+            columns: the loop goes on from step ``j0`` with key ``j0``
+            (a mixed dispatch whose slab's pass carried step 0)."""
             B = last_tok.shape[0]
             keys = jax.random.split(rng, num_steps)
-            toks0 = jnp.zeros((B, num_steps), jnp.int32)
-            lps0 = jnp.zeros((B, num_steps), jnp.float32)
+            if begun is None:
+                begun = (jnp.int32(0), jnp.zeros((B, num_steps), jnp.int32),
+                         jnp.zeros((B, num_steps), jnp.float32))
+            j0, toks0, lps0 = begun
             if done0 is None:
                 done0 = jnp.zeros((B,), bool)
 
@@ -832,7 +839,7 @@ class ContinuousBatchingEngine:
 
             (steps, cache, lengths, tok, _, toks, lps) = \
                 jax.lax.while_loop(
-                    cond, body, (jnp.int32(0), cache, lengths, last_tok,
+                    cond, body, (j0, cache, lengths, last_tok,
                                  done0, toks0, lps0))
             return cache, lengths, tok, toks, lps, steps
 
@@ -866,8 +873,8 @@ class ContinuousBatchingEngine:
         # semantics).  Chunks write K/V straight into the request's
         # reserved pages through its block table — no dense temp row,
         # no gather/scatter round trip, zero H2D across cold admission.
-        self._paged_chunk_mid, slab_body = make_paged_chunk_programs(
-            fwd_p, bind_tables)
+        self._paged_chunk_mid, slab_body, slab_step_body = \
+            make_paged_chunk_programs(fwd_p, bind_tables)
 
         @partial(jax.jit, donate_argnums=(1, 2))
         def paged_prefill(params, pk, pv, ids, table, start, real_len,
@@ -960,6 +967,7 @@ class ContinuousBatchingEngine:
             moe_ = cfg_.num_experts > 0
             state_ = cfg_.state_planes > 0
             E_ = cfg_.experts_here      # the experts this chip holds
+            sentinel_ = self._page_sentinel     # a table entry of no page
 
             def moe_acc0():
                 return jnp.zeros((E_ + 3,), jnp.int32)
@@ -1003,75 +1011,117 @@ class ContinuousBatchingEngine:
                 seg_plen, seg_keys)`` over the ``r`` segments that were
                 (the shapes are the program's key), and for a model with
                 experts an eighth array, the tokens each segment holds.
-                Prefill slab first: row r of
-                ``seg_ids`` [r, C] runs at positions
+
+                A slab's pass over the weights carries the decoding rows'
+                first step (``slab_step_body``): ONE forward over the
+                slab, row r of ``seg_ids`` [r, C] at positions
                 ``seg_starts[r] + arange(C)`` through ``seg_tables[r]``
-                (sentinel rows compute into dropped writes).  Each row
-                samples token #1 at ``seg_lens[r] - 1``, the one
-                position of the row the head runs on, from its OWN
-                batch-1 rng key (``seg_keys[r]`` — the serialized
-                prefill's exact spend) and installs itself at
-                ``seg_slot[r]`` (slot = B = not-a-final, the install
-                drops).  Then the fused decode loop runs over
-                ``dec_tables`` with the updated row state — freshly
-                installed rows decode in the SAME dispatch, rows whose
-                token #1 was eos enter the loop already done."""
+                (sentinel rows compute into dropped writes), and over the
+                rows that were decoding when the dispatch was planned
+                (``active``: the riding rows), each at its ``lengths``
+                through ``dec_tables``.  Each segment samples token #1 at
+                ``seg_lens[r] - 1``, the one position of the row the head
+                runs on, from its OWN batch-1 rng key (``seg_keys[r]`` —
+                the serialized prefill's exact spend) and installs itself
+                at ``seg_slot[r]`` (slot = B = not-a-final, the install
+                drops).  The riding rows sample as the loop's first
+                iteration does (key 0 of ``dec_rng``'s ``num_steps``,
+                ``eos`` and ``budget`` folded into done) and fill column
+                0 of ``toks`` / ``lps``.  Then the fused decode loop runs
+                steps 1 .. ``num_steps - 1`` over ``dec_tables`` with the
+                updated row state.  A freshly installed row takes no part
+                in step 0 (its token #1 is the slab's own output): it
+                joins the loop at step 1, its tokens in columns 1 .. of
+                its row, so it gets token #1 + ``num_steps - 1`` tokens in
+                its first dispatch (a row whose token #1 was eos enters
+                the loop already done).  Where no row rides (nothing was
+                decoding), no step was carried: the loop runs all
+                ``num_steps`` from column 0, the installed rows with it.
+                ``steps``, as returned, counts the decode steps that ran,
+                the carried one among them."""
                 B_ = last_tok.shape[0]
                 cache = KVCache(pk, pv, jnp.zeros((), jnp.int32))
                 moe_acc = moe_acc0() if moe_ else None
                 if seg is None:
                     final_toks = jnp.zeros((n_seg,), jnp.int32)
                     final_lps = jnp.zeros((n_seg,), jnp.float32)
-                    done0 = None
+                    done0 = begun = limit = None
                 else:
                     (seg_ids, seg_tables, seg_starts, seg_lens, seg_slot,
                      seg_plen, seg_keys) = seg[:7]
-                    slab_kw = ({"moe_stats": True, "ntok": seg[7]}
-                               if moe_ else {})
+                    riding = active
+                    slab_kw = ({"moe_stats": True, "ntok": seg[7],
+                                "riding": riding} if moe_ else {})
+                    # a row that does not ride writes nowhere: its slot
+                    # may be the one a final of this slab installs, whose
+                    # table is live already
+                    step_tables = jnp.where(riding[:, None], dec_tables,
+                                            sentinel_)
+                    lengths = lengths.at[seg_slot].set(
+                        seg_plen, mode="drop")
+                    # (a model with a recurrent state: a row's last length)
+                    limit = ((lengths + budget,) if state_ else ())
                     # the scopes are metadata on the ops: a capture keeps
                     # each op's path (`jit(mixed_step)/decode_loop/...`)
                     # in the op's event metadata
                     with jax.named_scope("slab_body"):
-                        logits, cache, *moe = slab_body(
+                        logits, step_logits, cache, *moe = slab_step_body(
                             params, cache, seg_ids, seg_tables,
-                            seg_starts, seg_lens - 1, "mixed_step",
-                            **slab_kw)
+                            seg_starts, seg_lens - 1, last_tok,
+                            step_tables, lengths, "mixed_step", **slab_kw)
                     if moe:
                         moe_acc = moe_fold(moe_acc, moe[0])
                     with jax.named_scope("slab_finals"):
                         final_toks, final_lps = slab_finals(
                             logits, seg_keys)
-                    lengths = lengths.at[seg_slot].set(
-                        seg_plen, mode="drop")
+                    lengths, last_tok, lp = _sample_step(
+                        step_logits, lengths, last_tok, riding,
+                        jax.random.split(dec_rng, num_steps)[0])
+                    # the loop's bookkeeping of its step 0, for the rows
+                    # that took it
+                    done0 = riding & (((eos >= 0) & (last_tok == eos))
+                                      | (budget <= 1))
+                    carried = jnp.any(riding).astype(jnp.int32)
+                    zeros = jnp.zeros((B_, num_steps - 1), jnp.int32)
+                    begun = (carried,
+                             jnp.concatenate([last_tok[:, None], zeros], 1),
+                             jnp.concatenate(
+                                 [lp[:, None], zeros.astype(jnp.float32)],
+                                 1))
                     last_tok = last_tok.at[seg_slot].set(
                         final_toks, mode="drop")
                     active = active.at[seg_slot].set(True, mode="drop")
-                    done0 = jnp.zeros((B_,), bool).at[seg_slot].set(
+                    done0 = done0.at[seg_slot].set(
                         (eos >= 0) & (final_toks == eos), mode="drop")
                     # a max_new=1 install has nothing left to decode:
                     # it enters the loop already done (pre-existing
                     # rows always have budget >= 1 — completed rows
                     # free their slot at drain time)
                     done0 = done0 | (budget <= 0)
+                    # an installed row's budget counts from the step it
+                    # joins at
+                    budget = budget + jnp.zeros_like(budget).at[
+                        seg_slot].set(carried, mode="drop")
                 bind_tables(dec_tables, "mixed_step")
                 with jax.named_scope("decode_loop"):
                     if moe_:
                         # the counters ride the loop's carry beside the
                         # cache, which _fused_loop never looks into
-                        limit = ((lengths + budget,) if state_ else ())
+                        if limit is None:
+                            limit = ((lengths + budget,) if state_ else ())
                         ((cache, moe_acc, *_), lengths, tok, toks, lps,
                          steps) = _fused_loop(
                             paged_one_step_moe, params,
                             (cache, moe_acc, *limit),
                             lengths, last_tok, active, dec_rng, eos,
-                            budget, num_steps, done0=done0)
+                            budget, num_steps, done0=done0, begun=begun)
                         return (cache.keys, cache.values, lengths, tok,
                                 final_toks, final_lps, toks, lps, steps,
                                 moe_acc)
                     cache, lengths, tok, toks, lps, steps = _fused_loop(
                         paged_one_step, params, cache, lengths, last_tok,
                         active, dec_rng, eos, budget, num_steps,
-                        done0=done0)
+                        done0=done0, begun=begun)
                 return (cache.keys, cache.values, lengths, tok,
                         final_toks, final_lps, toks, lps, steps)
 
@@ -1080,6 +1130,14 @@ class ContinuousBatchingEngine:
             # num_steps = n_seg + 1 variants, all launched before ready
             self._mixed_step = _ct.wrap("mixed_step", mixed_step,
                                         variant_budget=n_seg + 1)
+            # what a test composes the order before PR 61 from (the slab's
+            # forward alone, then every step in the loop), the reference
+            # the merged pass is held to: tests/test_mixed_batching.py
+            self._mixed_parts = types.SimpleNamespace(
+                slab_body=slab_body, slab_finals=slab_finals,
+                fused_loop=_fused_loop, one_step=paged_one_step,
+                one_step_moe=paged_one_step_moe, moe_acc0=moe_acc0,
+                moe_fold=moe_fold, bind_tables=bind_tables)
 
         def verify_slots(params, cache, drafts, q_logits, lengths,
                          last_tok, active, rng, k_cap=None):
@@ -3272,17 +3330,20 @@ class ContinuousBatchingEngine:
         self._record_token(slot, req, int(tok),
                            float(lp0) if plain else None)
 
-    def _record_row_blocks(self, em_np, counts, lps_np=None) -> None:
+    def _record_row_blocks(self, em_np, counts, lps_np=None,
+                           first=None) -> None:
         """Record per-row emitted token blocks into the slots' requests
-        (``counts[i]`` tokens from row i), stopping a row the moment it
-        finishes (max_new/eos frees the slot mid-block — the stale-slot
-        guard shared by the speculative rounds and the fused
-        decode-block path).  ``lps_np``: matching per-token logprobs
-        (plain mode; the speculative drains pass none)."""
+        (columns ``first[i]``, 0 by default, to ``counts[i]`` of row i),
+        stopping a row the moment it finishes (max_new/eos frees the
+        slot mid-block — the stale-slot guard shared by the speculative
+        rounds and the fused decode-block path).  ``lps_np``: matching
+        per-token logprobs (plain mode; the speculative drains pass
+        none)."""
         for i, req in enumerate(self._slots):
             if req is None:
                 continue
-            for j in range(int(counts[i])):
+            for j in range(0 if first is None else int(first[i]),
+                           int(counts[i])):
                 if self._slots[i] is None:
                     break              # row hit max_new or eos mid-block
                 self._record_token(
@@ -4018,6 +4079,12 @@ class ContinuousBatchingEngine:
         slab_segs = n_seg if spec_mixed else r
         seg = self._slab_of(seg, slab_segs)
         active_mask = np.array([s is not None for s in rows])
+        # ``mixed_step``'s slab carries the first decode step of the rows
+        # that decode already; a final it installs then joins the loop a
+        # step later, and its tokens begin at column 1 of its row
+        carried = int(bool(slab_segs) and n_active > 0 and not spec_mixed)
+        first_col = np.zeros((B,), np.int32)
+        first_col[[slot for _, slot in finals]] = carried
         # budget: remaining tokens per pre-existing row; a freshly
         # installed final's row has max_new - 1 left (token #1 came
         # from its prefill logits)
@@ -4123,7 +4190,7 @@ class ContinuousBatchingEngine:
             live0=live0, kv_tokens=kv_tokens, spec_mixed=spec_mixed,
             k_vec=k_vec, k_disp=k_disp, num_rounds=num_rounds,
             eva_cols=eva_cols, dev=None, how=None, ahead_s=0.0,
-            full=all_packed)
+            full=all_packed, carried=carried, first_col=first_col)
 
     def _cache_row(self, position: int) -> int:
         """The row of its attended table that the token at ``position``
@@ -4149,10 +4216,12 @@ class ContinuousBatchingEngine:
                                              self.prefill_chunk))
                  for (r0, _, f, _) in plan.packed]
         after = []
-        for req, k in ([s for s in plan.rows if s is not None]
-                       + [(req, 1) for req, _ in plan.finals]):
+        # (a final joins the loop after the step its slab carried)
+        for req, k, n in ([(*s, steps) for s in plan.rows if s is not None]
+                          + [(req, 1, steps - plan.carried)
+                             for req, _ in plan.finals]):
             a = len(req.prompt) + k - 1
-            b = a + max(0, min(steps, req.max_new - k))
+            b = a + max(0, min(n, req.max_new - k))
             spans.append((a, b))
             after.append(b)
         closed = sum(b // Wn - a // Wn for a, b in spans)
@@ -4409,7 +4478,9 @@ class ContinuousBatchingEngine:
         more tokens after ``flight``, where ``steps`` is the fused
         loop's count (it runs while any row has budget left,
         ``decode_block`` at most); a final installed by ``flight`` holds
-        its token #1 and then the same; a row whose budget ends in
+        its token #1 and then the same, less the step that ``flight``'s
+        slab carried for the rows before it (``plan.first_col``), which
+        also puts its last token one step later; a row whose budget ends in
         ``flight`` is gone, its table row sentinel (``_record_token`` +
         ``_sentinel_slot``).  Admissions: ``flight``'s launch has moved
         each to where its chunks in ``flight`` end, and its drain takes
@@ -4467,15 +4538,19 @@ class ContinuousBatchingEngine:
                 return None, news
             if any(getattr(req, "_suppress", None) for req in held):
                 return None, "other"
+            # a final joins the loop a step late where the slab carried
+            # one (``mixed_step``): it is at its last token that much later
+            late = plan.first_col
             steps = min(self.decode_block,
-                        max((req.max_new - k for req, k in live),
+                        max((s[0].max_new - s[1] + int(late[i])
+                             for i, s in enumerate(rows) if s is not None),
                             default=0))
             ended = []
             for i, s in enumerate(rows):
                 if s is None:
                     continue
                 req, k = s
-                k += min(steps, req.max_new - k)
+                k += max(0, min(steps - int(late[i]), req.max_new - k))
                 rows[i] = (req, k) if k < req.max_new else None
                 if rows[i] is None:
                     ended.append(i)
@@ -4554,11 +4629,12 @@ class ContinuousBatchingEngine:
             final_toks, _, toks = flight.out[:3]
             # a row keeps its budget's worth of the block (an empty
             # slot's budget is 0, a final's what token #1 leaves)
-            kept = np.minimum(plan.budget_vec, flight.steps)
+            first = plan.first_col
+            kept = np.minimum(plan.budget_vec, flight.steps - first)
             block = np.asarray(toks)
-            if (((block == self.eos_id)
-                 & (np.arange(block.shape[1])[None, :] < kept[:, None])
-                 ).any()
+            cols = np.arange(block.shape[1])[None, :] - first[:, None]
+            if (((block == self.eos_id) & (cols >= 0)
+                 & (cols < kept[:, None])).any()
                     or (plan.finals and any(
                         int(t) == self.eos_id
                         for t in np.asarray(final_toks)[
@@ -4596,7 +4672,8 @@ class ContinuousBatchingEngine:
             early=flight.early, slab_rows=plan.slab_rows,
             prefill_pages_walked=plan.prefill_pages_walked[0],
             prefill_pages_grid=plan.prefill_pages_grid,
-            head_rows=head_rows)
+            head_rows=head_rows,
+            slab_carried_step=n_active if plan.carried else 0)
         if self.moe_counters is not None:
             # real tokens: the live segments' prompt tokens, and the
             # steps of the slots that decoded (rows that finish inside
@@ -4607,12 +4684,15 @@ class ContinuousBatchingEngine:
             E = self.cfg.experts_here
             record.update(self.moe_counters.add(
                 acc[:E], int(acc[E]), int(acc[E + 1]), int(acc[E + 2]),
-                (prefill_tokens + (n_active + len(plan.finals)) * steps)
+                (prefill_tokens + n_active * steps
+                 + len(plan.finals) * max(0, steps - plan.carried))
                 * self.cfg.experts_per_token
                 * (self.cfg.total_layers - self.cfg.lead_dense_layers)
                 * self.cfg.ut_steps))
         if self.loop_counters is not None:
-            record.update(self.loop_counters.add(bool(packed), steps))
+            # (a step that rode the slab's pass is no pass of its own)
+            record.update(self.loop_counters.add(
+                bool(packed), steps - plan.carried))
         if "prefill_kv_tokens" in self.dispatch_trace.extra_fields:
             record["prefill_kv_tokens"] = plan.prefill_kv_tokens
         if self._eva is not None:
@@ -4622,14 +4702,20 @@ class ContinuousBatchingEngine:
             # rows x steps that advanced a state in the decode loop (a
             # row inside its budget; a final's has what token #1 left)
             # and the prompt tokens through the chunk form
-            row_steps = int(np.minimum(plan.budget_vec, steps).sum())
+            row_steps = int(np.minimum(
+                plan.budget_vec, steps - plan.first_col).sum())
             st = self.state_stats
             st["row_steps"] += row_steps
             st["chunk_tokens"] += prefill_tokens
             record.update(kda_row_steps=row_steps,
                           kda_chunk_tokens=prefill_tokens)
         if self.hc_stats is not None:
-            rows = plan.slab_rows + steps * self.max_batch
+            # a slab's pass holds the slots' rows too (whether or not a
+            # step rode it), padded to the kernels' whole tiles
+            rows = steps * self.max_batch
+            if plan.slab_rows:
+                rows += (whole_tiles(plan.slab_rows + self.max_batch)
+                         - plan.carried * self.max_batch)
             self.hc_stats["rows"] += rows
             record["hc_rows"] = rows
         if self._wmgr is not None:
@@ -4739,11 +4825,12 @@ class ContinuousBatchingEngine:
         if steps > 0:
             self._count_loop(steps)
             self._step_count += steps
+            # (a final of a slab that carried a step: columns 1 ..)
             self._record_row_blocks(
                 np.asarray(toks),
                 [0 if req is None or id(req) in gone else steps
                  for req in self._slots],
-                np.asarray(lps))
+                np.asarray(lps), first=plan.first_col)
         if steps > 0 and plan.adms_left:
             cs["interleaved_steps"] += 1
         return record

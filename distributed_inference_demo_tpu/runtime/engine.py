@@ -158,17 +158,21 @@ def make_chunk_programs(fwd):
 
 
 def make_paged_chunk_programs(fwd_p, bind_tables):
-    """``(chunk_mid, slab_body)`` prefill programs over a PAGED forward
-    seam (``make_paged_forward_seam``): chunks write K/V straight to the
-    page pool through the block tables — no dense temp row, no
-    gather/scatter round trip, ``dwt_kvcache_h2d_bytes_total`` stays 0.
+    """``(chunk_mid, slab_body, slab_step_body)`` prefill programs over a
+    PAGED forward seam (``make_paged_forward_seam``): chunks write K/V
+    straight to the page pool through the block tables — no dense temp
+    row, no gather/scatter round trip, ``dwt_kvcache_h2d_bytes_total``
+    stays 0.
 
     ``chunk_mid`` is the jitted non-final-chunk program (pool donated,
     logits dropped) used by serialized chunked admission; ``slab_body``
     is the UNJITTED traced body for a [n_seg, C] slab of segments at
-    per-row start offsets — the mixed token-budget dispatch composes it
-    with the fused decode loop inside ONE jit (batching._mixed_step),
-    so it must stay a plain function.  Both rely on the paged attention
+    per-row start offsets — the mixed token-budget dispatches compose it
+    with their decode rounds inside ONE jit (batching's speculative
+    mixed programs), so it must stay a plain function; ``slab_step_body``
+    is the same slab with the decoding rows' next step in the same
+    forward, which ``batching._mixed_step`` composes with the rest of
+    the fused decode loop.  All rely on the paged attention
     path's prefill contract: in-chunk keys are written before the
     gather/kernel inside each layer, and causal masking keeps a
     segment's queries on its own prior pages plus in-chunk keys
@@ -207,7 +211,41 @@ def make_paged_chunk_programs(fwd_p, bind_tables):
         logits, cache = fwd_p(params, ids, cache, pos, last)
         return logits, cache
 
-    return chunk_mid, slab_body
+    def slab_step_body(params, cache, ids, tables, starts, last, tok,
+                       dec_tables, lengths, program, moe_stats=False,
+                       ntok=None, riding=None):
+        """``slab_body`` and one lockstep decode step of rows ``tok``
+        [B] at positions ``lengths`` through ``dec_tables``, in ONE
+        forward (docs/DESIGN.md section 19): the segments' ``n x s``
+        positions and then the ``B`` decoding rows lie side by side in
+        one row ``[1, n s + B]``, so everything that works a row at a
+        time (the matrices, the experts, the head) runs once over both,
+        and a mixer sublayer runs the slab through ``tables`` on its
+        prefill route and the decoding rows through ``dec_tables`` on
+        their decode route (``bind_tables`` takes the pair:
+        ``ops.paged_attention.split_rows``).  Returns ``(slab logits
+        [n, 1, V], step logits [B, 1, V], cache)``, and with
+        ``moe_stats`` the expert row counts of the one pass, over each
+        segment's first ``ntok`` positions and the rows ``riding`` [B]
+        bool (the rows that decode)."""
+        bind_tables((tables, dec_tables), program)
+        n, s = ids.shape
+        B = tok.shape[0]
+        row = lambda slab, step: jnp.concatenate(
+            [slab.reshape(n * s), step])[None]
+        pos = row(starts[:, None] + jnp.arange(s)[None, :], lengths)
+        at = jnp.concatenate([jnp.arange(n) * s + jnp.clip(last, 0, s - 1),
+                              n * s + jnp.arange(B)])[None]
+        kw = {}
+        if moe_stats:
+            kw = {"moe_stats": True,
+                  "valid": row(jnp.arange(s)[None, :] < ntok[:, None],
+                               riding)}
+        logits, cache, *moe = fwd_p(params, row(ids, tok), cache, pos, at,
+                                    **kw)
+        return (logits[0, :n, None], logits[0, n:, None], cache, *moe)
+
+    return chunk_mid, slab_body, slab_step_body
 
 
 def run_chunked_prefill(params, ids, cache, C: int, max_seq: int,

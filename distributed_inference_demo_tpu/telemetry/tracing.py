@@ -184,11 +184,17 @@ _LAUNCHED_PHASES = ("launch", "wait", "drain")
 # over in the execution, one a segment of the slab (the position it
 # samples, PR 48: not the chunk's every position) and one a slot at
 # each decode step (host arithmetic on the launched program's shapes)
+# ``slab_carried_step`` (the last of them): the rows whose first decode
+# step of the dispatch rode its slab's pass over the weights (the rows
+# that were decoding when a dispatch with a slab was packed); 0 where
+# the slab carried no step (no row was decoding) or there was no slab.
+# ``steps`` counts the carried step among the decode steps that ran
 DISPATCH_FIELDS = (("seq", "t_launch", "t_done") + DISPATCH_PHASES
                    + ("with_finals", "segments", "finals",
                       "prefill_tokens", "prefill_pages_walked",
                       "head_rows", "active_rows", "steps",
-                      "kv_tokens", "ahead", "late", "await"))
+                      "kv_tokens", "ahead", "late", "await",
+                      "slab_carried_step"))
 # a record's very last column, after what the model adds: 1 if the
 # dispatch was enqueued behind its predecessor, before that one had
 # returned (``commit``'s ``early``; runtime.batching, docs/DESIGN.md §19)
@@ -225,8 +231,8 @@ AHEAD_MISS_REASONS = ("arrival", "finish", "cancel", "export", "other")
 MOE_DISPATCH_FIELDS = ("moe_rows", "moe_valid_rows", "moe_touched",
                        "moe_load_max")
 # what a looped model (``ut_steps > 1``) adds: the passes of the layer
-# stack the execution ran, ((1 if it packed a segment else 0) + steps)
-# x ut_steps
+# stack the execution ran, ((1 if it packed a segment else 0) + steps,
+# less the step that rode the slab's pass) x ut_steps
 LOOP_DISPATCH_FIELDS = ("ut_passes",)
 # what a latent-attention model adds: the (query, cached token) pairs
 # the slab's prompt tokens attend over, each token its predecessors and
@@ -258,8 +264,9 @@ EVA_DISPATCH_FIELDS = ("kv_attended_rows", "kv_summary_rows",
 KDA_DISPATCH_FIELDS = ("kda_row_steps", "kda_chunk_tokens")
 # a model with more than one residual stream (``hc_streams``): the token
 # rows the residual path's two kernels computed in the execution, the
-# slab's rows and every slot at every decode step (host arithmetic on the
-# launched program's shapes, as ``head_rows`` is)
+# slab's rows and every slot at every decode step, a slab's pass with the
+# slots' rows in it padded to the kernels' whole tiles (host arithmetic on
+# the launched program's shapes, as ``head_rows`` is)
 HC_DISPATCH_FIELDS = ("hc_rows",)
 _DISPATCH_RING = 128       # x ~135 bytes a row: /stats stays under 18 KB
 # a span that is work (every one but ``await``) and lasts this long is a
@@ -466,6 +473,7 @@ class DispatchTrace:
         self.prefill_pages_walked = 0
         self.prefill_pages_grid = 0
         self.head_rows = 0
+        self.slab_carried_steps = self.slab_carried_rows = 0
         self.queue_wait_ms_sum = 0.0
         self.queue_wait_count = 0
         self.ahead_hits = self.ahead_hits_slab = self.ahead_early = 0
@@ -705,7 +713,7 @@ class DispatchTrace:
                phases: Optional[dict] = None, slab_rows: int = 0,
                prefill_pages_walked: int = 0, prefill_pages_grid: int = 0,
                head_rows: int = 0, early: bool = False,
-               **extra: int) -> int:
+               slab_carried_step: int = 0, **extra: int) -> int:
         """A dispatch that reached the device is drained: one record.
         ``slab_rows``: the rows of the prefill slab its program computed
         (segments of the launched variant x the chunk), of which
@@ -716,6 +724,10 @@ class DispatchTrace:
         table a step would have had, its tiles x the table's width.
         ``head_rows`` (a column, and summed): the rows the LM head ran
         over, one a segment of the slab and one a slot a decode step.
+        ``slab_carried_step`` (a column): the rows whose first step
+        rode the slab's pass; the dispatches with any are counted in
+        ``slab_carried_steps`` and the rows summed in
+        ``slab_carried_rows``.
         ``phases``: its own seconds (``launched_phases`` as they were
         when the NEXT dispatch had not been launched yet; by default the
         last launched one's).  ``how``: ``"hit"`` (launched as prepared
@@ -739,6 +751,7 @@ class DispatchTrace:
             prefill_pages_walked, head_rows, active_rows, steps, kv_tokens,
             round(ahead, 5),
             int(phases["late"]), round(phases["await"], 5),
+            slab_carried_step,
             *(extra[f] for f in self.extra_fields), int(early)))
         if early:
             self._opened.popleft()   # the row above is its record now
@@ -752,6 +765,8 @@ class DispatchTrace:
         self.prefill_pages_walked += prefill_pages_walked
         self.prefill_pages_grid += prefill_pages_grid
         self.head_rows += head_rows
+        self.slab_carried_steps += bool(slab_carried_step)
+        self.slab_carried_rows += slab_carried_step
         if how == "hit":
             self.ahead_hits += 1
             self.ahead_hits_slab += bool(segments)
@@ -784,6 +799,8 @@ class DispatchTrace:
                 "prefill_pages_walked": self.prefill_pages_walked,
                 "prefill_pages_grid": self.prefill_pages_grid,
                 "head_rows": self.head_rows,
+                "slab_carried_steps": self.slab_carried_steps,
+                "slab_carried_rows": self.slab_carried_rows,
                 "queue_wait_ms_sum": round(self.queue_wait_ms_sum, 3),
                 "queue_wait_count": self.queue_wait_count,
                 "ahead_hits": self.ahead_hits,

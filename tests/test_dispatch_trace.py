@@ -240,7 +240,8 @@ def test_ring_is_bounded_and_stats_stay_small_json(monkeypatch):
     assert snap["seq"] == 99_300 and len(snap["recent"]) == 128
     assert [r[0] for r in snap["recent"]] == list(range(99_173, 99_301))
     assert all(r[3:9] == [0.00123] * 6 for r in snap["recent"])
-    assert 16 * 1024 < len(json.dumps(snap["recent"])) < 18 * 1024
+    # (a row is ~147 bytes since PR 61's column, `slab_carried_step`)
+    assert 16 * 1024 < len(json.dumps(snap["recent"])) < 19 * 1024
     assert snap["kv_token_steps"] == 300 * 262144 * 4
     with engine() as eng:
         eng.submit(SHORT, 3).wait(timeout=300)
@@ -581,7 +582,8 @@ def test_the_blocking_read_is_the_end_of_wait(scripted):
     tile (``launch`` + ``wait`` = ``t_done - t_launch``)."""
     dt = scripted[0]["dispatch_trace"]
     recs = rows(scripted[0])
-    assert DISPATCH_FIELDS[-3:] == ("ahead", "late", "await")
+    assert DISPATCH_FIELDS[-4:] == ("ahead", "late", "await",
+                                    "slab_carried_step")
     for r in recs:
         assert 0 < r["await"] <= r["wait"] + ROUNDING
         assert r["late"] in (0, 1)

@@ -81,8 +81,10 @@ def test_what_lands_behind_an_early_launch_waits_one_dispatch_more(
             "ahead_misses"]["arrival"]
     else:
         toks, _, cancelled, error, _, _ = same["streams"]["row"]
-        # token #1 and four dispatches of four: 2 (its final's) to 5
-        assert cancelled and error == "None" and len(toks) == 1 + 4 * 4
+        # token #1 and three more in its final's dispatch (2: the
+        # slab's pass carried the keeper's first step, and the row
+        # joined the loop behind it), then three dispatches of four
+        assert cancelled and error == "None" and len(toks) == 1 + 3 + 3 * 4
         assert ahead["trace"]["ahead_misses"]["cancel"] == 1
         # it rode dispatch 6, which the old order packed without it
         rode, packed = recs[6], old["recs"][5]

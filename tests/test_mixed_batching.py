@@ -27,6 +27,7 @@ import contextlib
 import dataclasses
 import sys
 import time
+from functools import partial
 from pathlib import Path
 from unittest import mock
 
@@ -279,7 +280,9 @@ def test_a_slab_of_the_packed_segments_is_the_full_slab_s_dispatch(model, r):
              "lps", "steps", "moe_acc")
     cut, whole = dict(zip(names, cut)), dict(zip(names, whole))
     assert ("moe_acc" in cut) == (cfg.num_experts > 0)
-    assert int(cut["steps"]) == 4 and list(cut["lengths"][:2]) == [13, 10]
+    # (the row that decodes takes its first step in the slab's pass; the
+    # final, installed behind it, the loop's three)
+    assert int(cut["steps"]) == 4 and list(cut["lengths"][:2]) == [13, 9]
     for name in cut:
         a, b = np.asarray(cut[name]), np.asarray(whole[name])
         if name in ("final_toks", "final_lps"):      # a row a segment
@@ -296,7 +299,7 @@ def test_a_slab_of_the_packed_segments_is_the_full_slab_s_dispatch(model, r):
     if cfg.num_experts:
         # the counters are the rows that hold a token, padding or none
         k, L, E = cfg.experts_per_token, cfg.num_layers, cfg.num_experts
-        tokens = (r - 1) * 8 + 6 + 2 * 4     # slab, then two rows' steps
+        tokens = (r - 1) * 8 + 6 + 4 + 3     # slab, then two rows' steps
         assert int(np.asarray(cut["moe_acc"])[:E].sum()) == tokens * k * L
 
 
@@ -365,6 +368,347 @@ def test_a_final_s_token_is_the_serialized_admission_s(model, r, sampled):
         assert r == 1 or np.abs(want[:, 8:8 + r - 1]).sum() > 0
 
 
+# ------------------------------------------------------------------
+# PR 61: a slab's pass over the weights carries the decoding rows' first
+# step.  The order before it (the slab's forward alone, then every step
+# in the loop) is kept here, composed from the engine's own pieces, as
+# the reference the merged pass is held to.
+
+def old_order_mixed_step(eng):
+    """``mixed_step`` as it was before PR 61, over ``eng``'s forward seam:
+    ``slab_body``, the finals, then ``_fused_loop`` over all
+    ``num_steps``.  Not donated: the caller keeps its pool."""
+    p, cfg, n_seg = eng._mixed_parts, eng.cfg, eng._mixed_seg_cap
+    moe_, state_ = cfg.num_experts > 0, cfg.state_planes > 0
+    from distributed_inference_demo_tpu.models.base import KVCache
+
+    @partial(jax.jit, static_argnums=(11,))
+    def mixed_step(params, pk, pv, seg, dec_tables, lengths, last_tok,
+                   active, dec_rng, eos, budget, num_steps):
+        B_ = last_tok.shape[0]
+        cache = KVCache(pk, pv, jnp.zeros((), jnp.int32))
+        moe_acc = p.moe_acc0() if moe_ else None
+        if seg is None:
+            final_toks = jnp.zeros((n_seg,), jnp.int32)
+            final_lps = jnp.zeros((n_seg,), jnp.float32)
+            done0 = None
+        else:
+            (seg_ids, seg_tables, seg_starts, seg_lens, seg_slot,
+             seg_plen, seg_keys) = seg[:7]
+            slab_kw = ({"moe_stats": True, "ntok": seg[7]} if moe_ else {})
+            with jax.named_scope("slab_body"):
+                logits, cache, *moe = p.slab_body(
+                    params, cache, seg_ids, seg_tables, seg_starts,
+                    seg_lens - 1, "mixed_step", **slab_kw)
+            if moe:
+                moe_acc = p.moe_fold(moe_acc, moe[0])
+            with jax.named_scope("slab_finals"):
+                final_toks, final_lps = p.slab_finals(logits, seg_keys)
+            lengths = lengths.at[seg_slot].set(seg_plen, mode="drop")
+            last_tok = last_tok.at[seg_slot].set(final_toks, mode="drop")
+            active = active.at[seg_slot].set(True, mode="drop")
+            done0 = jnp.zeros((B_,), bool).at[seg_slot].set(
+                (eos >= 0) & (final_toks == eos), mode="drop")
+            done0 = done0 | (budget <= 0)
+        p.bind_tables(dec_tables, "mixed_step")
+        with jax.named_scope("decode_loop"):
+            if moe_:
+                limit = ((lengths + budget,) if state_ else ())
+                ((cache, moe_acc, *_), lengths, tok, toks, lps,
+                 steps) = p.fused_loop(
+                    p.one_step_moe, params, (cache, moe_acc, *limit),
+                    lengths, last_tok, active, dec_rng, eos, budget,
+                    num_steps, done0=done0)
+                return (cache.keys, cache.values, lengths, tok, final_toks,
+                        final_lps, toks, lps, steps, moe_acc)
+            cache, lengths, tok, toks, lps, steps = p.fused_loop(
+                p.one_step, params, cache, lengths, last_tok, active,
+                dec_rng, eos, budget, num_steps, done0=done0)
+        return (cache.keys, cache.values, lengths, tok, final_toks,
+                final_lps, toks, lps, steps)
+
+    return mixed_step
+
+
+_OUT = ("pk", "pv", "lengths", "last_tok", "final_toks", "final_lps",
+        "toks", "lps", "steps", "moe_acc")
+
+
+def _held_to_the_old_order(eng, args, new, old):
+    """One slab-carrying dispatch with a row riding it, both ways: the
+    facts the two orders must agree on (a sentence for each that they do
+    not) and what kind of dispatch it was."""
+    cfg, B = eng.cfg, eng.max_batch
+    new, old = dict(zip(_OUT, new)), dict(zip(_OUT, old))
+    seg, riding, budget = args[3], np.asarray(args[7]), np.asarray(args[10])
+    installed = np.zeros((B + 1,), bool)
+    installed[np.asarray(seg[4])] = True
+    installed = installed[:B]
+    wrong = []
+
+    def same(what, a, b, exact=True):
+        a, b = np.asarray(a), np.asarray(b)
+        ok = ((a == b).all() if exact or a.dtype.kind != "f" else
+              np.allclose(a, b, rtol=1e-5, atol=1e-5))
+        if not ok and a.dtype.kind == "f":
+            wrong.append(f"{what}: max |a - b| {np.abs(a - b).max():.3g} "
+                         f"of {np.abs(b).max():.3g}")
+        elif not ok:
+            wrong.append(f"{what}: {a.tolist()} != {b.tolist()}")
+
+    n_new, n_old = int(new["steps"]), int(old["steps"])
+    same("final_toks", new["final_toks"], old["final_toks"])
+    same("final_lps", new["final_lps"], old["final_lps"], exact=False)
+    toks_n, toks_o = np.asarray(new["toks"]), np.asarray(old["toks"])
+    lps_n, lps_o = np.asarray(new["lps"]), np.asarray(old["lps"])
+    for i in np.flatnonzero(riding):
+        n = min(n_new, n_old)
+        same(f"row {i}'s tokens", toks_n[i, :n], toks_o[i, :n])
+        same(f"row {i}'s lps", lps_n[i, :n], lps_o[i, :n], exact=False)
+    for i in np.flatnonzero(installed):
+        # token #1 + num_steps - 1: its tokens lie one column later
+        n = min(n_new - 1, n_old)
+        same(f"final {i}'s tokens", toks_n[i, 1:1 + n], toks_o[i, :n])
+        same(f"final {i}'s lps", lps_n[i, 1:1 + n], lps_o[i, :n],
+             exact=False)
+    whole = not installed.any()
+    if whole:   # the same steps of the same rows: the same state is left
+        same("steps", n_new, n_old)
+        same("lengths", new["lengths"], old["lengths"])
+        same("last_tok", new["last_tok"], old["last_tok"])
+        for a, b in zip(jax.tree.leaves((new["pk"], new["pv"])),
+                        jax.tree.leaves((old["pk"], old["pv"]))):
+            same("a pool's pages or states", a, b, exact=False)
+        if cfg.num_experts:
+            E = cfg.experts_here
+            acc_n, acc_o = (np.asarray(x["moe_acc"]) for x in (new, old))
+            same("moe_rows", acc_n[:E], acc_o[:E])
+            calls = (cfg.total_layers - cfg.lead_dense_layers) * cfg.ut_steps
+            # a layer call fewer a block: the slab's and the step's are one
+            same("moe layer calls", acc_n[E + 2], acc_o[E + 2] - calls)
+            if not acc_n[E] <= acc_o[E]:
+                wrong.append("moe_touched grew")
+    else:
+        ride = riding & (budget >= 4)
+        same("riding rows' lengths", np.asarray(new["lengths"])[ride],
+             np.asarray(old["lengths"])[ride])
+    return {"whole": whole, "steps": (n_new, n_old), "wrong": wrong,
+            "riding": int(riding.sum())}
+
+
+def _run_held_to_the_old_order(cfg, params, mesh=None, **kw):
+    """A row decoding and a prompt of four segments admitted beside it,
+    through an engine whose every slab-carrying dispatch with a riding row
+    is also run in the old order on a copy of its pool."""
+    kw = dict(dict(max_seq=128, max_batch=3, sampling=GREEDY,
+                   kv_block_tokens=8, prefill_chunk=8, decode_block=4,
+                   mixed_token_budget=24), **kw)
+    with mock.patch.object(ContinuousBatchingEngine, "_warm_mixed_variants",
+                           lambda self: None):
+        eng = ContinuousBatchingEngine(cfg, params, mesh=mesh, **kw)
+    held, inner, old = [], eng._mixed_step, old_order_mixed_step(eng)
+
+    def both(*a):
+        if a[3] is None or not np.asarray(a[7]).any():
+            return inner(*a)
+        ref = old(a[0], *jax.tree.map(jnp.copy, a[1:3]), *a[3:])
+        ref = jax.tree.map(np.asarray, ref)
+        out = inner(*a)
+        try:
+            held.append(_held_to_the_old_order(eng, a, out, ref))
+        except Exception as e:          # the scheduler's thread: keep it
+            held.append({"whole": False, "wrong": [repr(e)]})
+        return out
+
+    eng._mixed_step = both
+    with eng:
+        keeper = eng.submit([5, 4, 3, 2, 1], 40)
+        while len(keeper.tokens) < 2:
+            time.sleep(0.005)
+        long = eng.submit(list(range(30, 59)), 6)
+        toks = [r.wait(timeout=300).tolist() for r in (keeper, long)]
+        settle(eng)
+        st = eng.stats()
+    return held, toks, st
+
+
+@pytest.mark.parametrize("model", [
+    "qwen2-test",           # dense, rope, grouped heads, q / k / v biases
+    "bloom-test",           # ALiBi, every head its own keys, LayerNorm
+    "olmoe-test",           # experts, with their counters
+    "kanana-test",          # latent attention, a leading dense block
+    "laguna-test",          # a period: window and full kinds, two pools
+    "evabyte-test",         # EVA: summaries pooled as windows close
+    "solar-open2-test",     # KDA: a recurrent state a request
+    "xing-bench-test",      # four residual streams a token
+    "ouro-test",            # a looped stack: three passes a token
+    "qwen2-test/tp2",       # two devices, one shard_map
+])
+def test_the_merged_pass_is_the_old_order_s_arithmetic(model):
+    """In a dispatch that packed a slab, the rows that were decoding take
+    their first step inside the slab's forward and the loop runs the other
+    ``num_steps - 1``.  Held, dispatch by dispatch and on the engine's own
+    plans, to the old order on a copy of the same pool: the same greedy
+    tokens (a final installed by the dispatch has token #1 and ``num_steps
+    - 1`` of the old order's four, one column later), and where no final
+    was installed the same lengths, the same pages and states to float32
+    rounding (two shapes of one matmul: see above; a model of several
+    streams mixes them in one call over the pass's rows, padded to whole
+    tiles, where the old order made two: 3e-6 on pages of order 4), the
+    same rows to every expert, in one layer call fewer a block."""
+    name, _, tp = model.partition("/tp")
+    cfg, mesh = get_model_config(name), None
+    if tp:
+        from distributed_inference_demo_tpu.models.loader import load_or_init
+        from distributed_inference_demo_tpu.parallel.mesh import local_tp_mesh
+        mesh = local_tp_mesh(int(tp))
+        params = load_or_init(name, cfg, seed=0, mesh=mesh)
+    else:
+        params = init_full_params(jax.random.PRNGKey(0), cfg)
+    held, toks, st = _run_held_to_the_old_order(cfg, params, mesh)
+    assert [w for h in held for w in h["wrong"]] == []
+    # a slab of chunks alone under the riding row, and one with the final
+    assert [h["whole"] for h in held].count(True) >= 1
+    assert [h["whole"] for h in held].count(False) >= 1
+    assert all(h["riding"] == 1 and h["steps"][0] == 4 for h in held)
+    assert [len(t) for t in toks] == [40, 6]
+    dt = st["dispatch_trace"]
+    recs = [dict(zip(dt["fields"], r)) for r in dt["recent"]]
+    carried = [r for r in recs if r["slab_carried_step"]]
+    assert len(carried) == len(held) == dt["slab_carried_steps"]
+    assert dt["slab_carried_rows"] == sum(
+        r["slab_carried_step"] for r in carried) == len(held)
+    # every slab under a decoding row carried its step; the keeper's own
+    # (nothing was decoding) did not
+    assert all(bool(r["slab_carried_step"]) == (r["active_rows"] > 0)
+               for r in recs if r["segments"])
+    assert all(r["steps"] == 4 for r in carried)
+    assert st["device_loop"]["device_loop_steps"] == sum(
+        r["steps"] for r in recs)
+
+
+def _one_riding_row(eng, slab, budget, eos=-1, tables_too=()):
+    """Row 0 installed with five tokens by a first dispatch, then ``slab``
+    beside it, in the new order and the old: ``(new, old)`` by name."""
+    B, sent = eng.max_batch, eng._page_sentinel
+    copy = lambda t: jax.tree.map(jnp.copy, t)       # noqa: E731
+    old = old_order_mixed_step(eng)
+    first = [x.copy() for x in eng._slab_of(eng._blank_segments(), 1)]
+    first[0][0, :5] = (5, 4, 3, 2, 1)
+    first[1][0, 0] = 0
+    first[3][0], first[4][0], first[5][0] = 5, 0, 5
+    tables = np.full((B, eng._table_width), sent, np.int32)
+    tables[0, :2] = (0, 1)
+    key = jax.random.PRNGKey(1)
+    z = jnp.zeros((B,), jnp.int32)
+    pk, pv, lengths, last, *_ = old(
+        eng.params, eng._pk, eng._pv, tuple(first), jnp.asarray(tables), z,
+        z, jnp.zeros((B,), bool), key, jnp.int32(-1),
+        jnp.asarray([0] * B, jnp.int32), eng.decode_block)
+    assert int(lengths[0]) == 5
+    for slot, pages in tables_too:
+        tables[slot, :len(pages)] = pages
+    args = (slab, jnp.asarray(tables), lengths, last,
+            jnp.asarray([True] + [False] * (B - 1)), key, jnp.int32(eos),
+            jnp.asarray(budget, jnp.int32), eng.decode_block)
+    new = eng._mixed_step.inner(eng.params, *copy((pk, pv)), *args)
+    ref = old(eng.params, pk, pv, *args)
+    return dict(zip(_OUT, new)), dict(zip(_OUT, ref))
+
+
+@pytest.fixture(scope="module")
+def hand_engine(params):
+    with mock.patch.object(ContinuousBatchingEngine, "_warm_mixed_variants",
+                           lambda self: None):
+        eng = ContinuousBatchingEngine(
+            CFG, params, max_seq=96, max_batch=4, sampling=GREEDY,
+            kv_block_tokens=8, prefill_chunk=8, decode_block=4,
+            mixed_token_budget=24)
+    with eng:
+        yield eng
+
+
+def test_a_final_installed_by_the_dispatch_joins_the_loop_a_step_late(
+        hand_engine):
+    """Token #1 + ``num_steps - 1``: the final's row takes no part in the
+    step the slab carries (nobody reads column 0 of its row, and neither
+    its table nor its length is touched by it) and gets the loop's three, which are
+    the old order's first three; the riding row gets its four."""
+    eng = hand_engine
+    new, old = _one_riding_row(eng, _hand_packed(eng, 2), [16, 5, 0, 0],
+                               tables_too=[(1, (4, 5))])
+    assert int(new["steps"]) == int(old["steps"]) == 4
+    assert np.asarray(new["lengths"])[:2].tolist() == [9, 9]
+    assert np.asarray(old["lengths"])[:2].tolist() == [9, 10]
+    toks, was = np.asarray(new["toks"]), np.asarray(old["toks"])
+    assert (toks[0] == was[0]).all()
+    assert (toks[1, 1:] == was[1, :3]).all()
+    assert int(new["final_toks"][1]) == int(old["final_toks"][1])
+    # the final's budget counts from the step it joins at: with two tokens
+    # left it is done after the loop's second step, where the riding row
+    # is done too, and the device says three steps ran
+    new, old = _one_riding_row(eng, _hand_packed(eng, 2), [3, 2, 0, 0],
+                               tables_too=[(1, (4, 5))])
+    assert (int(new["steps"]), int(old["steps"])) == (3, 3)
+    assert np.asarray(new["lengths"])[:2].tolist() == [8, 8]
+
+
+@pytest.mark.parametrize("case", ["eos", "budget"])
+def test_a_row_that_ends_in_the_carried_step_is_done_for_the_loop(
+        hand_engine, case):
+    """The carried step folds ``eos`` and ``budget`` into done as the
+    loop's first iteration does: a riding row whose first token is ``eos``,
+    or whose budget is one token, is done when the loop begins, and with
+    no other row the loop runs no step: one step ran, as in the old
+    order."""
+    eng = hand_engine
+    slab = _hand_packed(eng, 2)
+    slab[4][:] = eng.max_batch          # chunks alone: nothing is installed
+    free, _ = _one_riding_row(eng, slab, [16, 0, 0, 0])
+    first = int(np.asarray(free["toks"])[0, 0])
+    assert int(free["steps"]) == 4
+    new, old = _one_riding_row(
+        eng, slab, [16 if case == "eos" else 1, 0, 0, 0],
+        eos=first if case == "eos" else -1)
+    assert int(new["steps"]) == int(old["steps"]) == 1
+    for out in (new, old):
+        assert np.asarray(out["toks"])[0].tolist() == [first, 0, 0, 0]
+        assert int(out["lengths"][0]) == 6
+    for a, b in zip(jax.tree.leaves((new["pk"], new["pv"])),
+                    jax.tree.leaves((old["pk"], old["pv"]))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
+
+
+def test_the_program_without_a_slab_is_what_it_was(hand_engine):
+    """``seg is None``: the decode loop alone, line for line the old
+    composition's own lowering (``tests/test_xing4.py``, ``test_kanana``,
+    ``test_laguna_engine`` and ``test_ouro`` hold nine toy families'
+    ``.decode`` programs to the hashes kept from before PR 61, which this
+    PR left as they were), and an engine still launches ``n_seg + 1``
+    variants before it is ready."""
+    eng = hand_engine
+    call = abstract_mixed_call(eng, slab=False)
+    text = eng._mixed_step.inner.lower(*call).as_text()
+    was = old_order_mixed_step(eng).lower(*call).as_text()
+    strip = lambda t: "\n".join(                        # noqa: E731
+        line for line in t.splitlines() if "module @jit" not in line)
+    # (the old composition is not donated: its arguments carry no alias)
+    assert strip(text).replace(
+        " {tf.aliasing_output = 0 : i32}", "").replace(
+        " {tf.aliasing_output = 1 : i32}", "") == strip(was)
+    slab = eng._mixed_step.inner.lower(
+        *abstract_mixed_call(eng, slab=True)).as_text()
+    assert slab != text
+    with ContinuousBatchingEngine(
+            CFG, eng.params, max_seq=96, max_batch=4, sampling=GREEDY,
+            kv_block_tokens=8, prefill_chunk=8, decode_block=4,
+            mixed_token_budget=24) as warm:
+        compiled = warm.stats()["compile"]["mixed_step"]
+    assert compiled["cache_entries"] == compiled["variant_budget"] == \
+        warm._mixed_seg_cap + 1 == 4
+
+
 def _shapes_in(jaxpr, prim=None):
     """Every eqn's output shapes in a jaxpr and its sub-jaxprs (of the
     primitive named, its operands' instead)."""
@@ -384,9 +728,11 @@ def test_no_array_of_the_slab_s_every_position_by_the_vocabulary(tp):
     """PR 48, the guard: as traced, ``mixed_step`` with a full slab holds
     no array of ``r x C`` rows by ``V`` (or ``V / tp``) columns: the
     widest thing with the vocabulary's columns is ``[rows, 1, V]``, for
-    the slab's ``r`` segments and for the decode loop's ``B`` slots.
+    the slab's ``r`` segments and for the decode loop's ``B`` slots (and
+    since PR 61 ``[1, r + B, V]``: the slab's pass with the step it
+    carries).
     Over a mesh of four (CPU) devices the vocab-parallel head's
-    ``all_gather`` moves ``[r, 1, V / 4]`` and ``[B, 1, V / 4]``."""
+    ``all_gather`` moves ``[1, r + B, V / 4]`` and ``[B, 1, V / 4]``."""
     from distributed_inference_demo_tpu.parallel.mesh import local_tp_mesh
     from distributed_inference_demo_tpu.runtime.engine import (
         shard_engine_params)
@@ -408,12 +754,14 @@ def test_no_array_of_the_slab_s_every_position_by_the_vocabulary(tp):
             *call).jaxpr
     wide = {s for s in _shapes_in(jaxpr) if s and s[-1] in (V, V // tp)}
     assert (r, 1, V) in wide and (B, 1, V) in wide
-    assert max(int(np.prod(s[:-1])) for s in wide) == max(r, B) < r * C
+    # (the slab's pass carries the decoding rows' step: one head product
+    # over its r segments' rows and the B slots')
+    assert max(int(np.prod(s[:-1])) for s in wide) == r + B < r * C
     gathered = [s for s in _shapes_in(jaxpr, "all_gather")]
     if tp == 1:
         assert not gathered
     else:
-        assert set(gathered) == {(r, 1, V // 4), (B, 1, V // 4)}
+        assert set(gathered) == {(1, r + B, V // 4), (B, 1, V // 4)}
 
 
 @pytest.mark.parametrize("budget", [16, 24])
@@ -859,7 +1207,10 @@ def _two_admissions_were_packed_in_order(ahead, old):
     recs = {r["seq"]: r for r in ahead["recs"]}
     assert recs[first[5]]["finals"] == 1 and recs[first[5]]["ahead"] > 0
     assert recs[second[4]]["segments"] == 2 and recs[second[4]]["ahead"] > 0
-    assert ahead["trace"]["ahead_hits_slab"] >= 3
+    # (the second's final is packed in the gap: the first row, installed
+    # behind the step its slab carried, ends one dispatch later, under
+    # the execution that plan would have been made in)
+    assert ahead["trace"]["ahead_hits_slab"] >= 2
 
 
 def _an_ended_rows_slot_waited_for_its_drain(ahead, old):

@@ -379,22 +379,28 @@ def test_moe_counters_sum_to_tokens_times_k_times_layers(olmoe_run):
     assert len(recs) == moe["dispatches"] == dt["seq"]
     chunk, slots = 16, 4
     for r in recs:
-        # the slab runs where a segment was packed, and only there
-        passes = (r["segments"] > 0) + r["steps"]
+        # the slab runs where a segment was packed, and only there; the
+        # step of the decoding rows that rode it is no pass of its own, and
+        # a final it installs joins the loop behind that step (PR 61)
+        rode = r["slab_carried_step"] > 0
+        passes = (r["segments"] > 0) + r["steps"] - rode
         assert r["moe_rows"] == r["moe_valid_rows"] == (
-            r["prefill_tokens"] + (r["active_rows"] + r["finals"])
-            * r["steps"]) * k * L
+            r["prefill_tokens"] + r["active_rows"] * r["steps"]
+            + r["finals"] * (r["steps"] - rode)) * k * L
         # ... of the rows its program computed
         assert r["moe_rows"] <= (chunk * r["segments"]
                                  + slots * r["steps"]) * k * L
         assert 0 < r["moe_touched"] <= passes * L * E
-        assert r["moe_load_max"] <= max(r["prefill_tokens"], slots)
+        assert r["moe_load_max"] <= max(r["prefill_tokens"] + slots * rode,
+                                        slots)
     # the run did pad: finals of 5 (37 = 2 x 16 + 5), 9 and 4 tokens
     assert any(r["prefill_tokens"] % chunk for r in recs)
     assert moe["rows"] == moe["valid_rows"] \
         == sum(r["moe_rows"] for r in recs) == sum(moe["expert_rows"])
+    assert any(r["slab_carried_step"] for r in recs)
     assert moe["layer_calls"] == sum(
-        ((r["segments"] > 0) + r["steps"]) * L for r in recs)
+        ((r["segments"] > 0) + r["steps"] - (r["slab_carried_step"] > 0)) * L
+        for r in recs)
     assert moe["touched"] == sum(r["moe_touched"] for r in recs)
     assert moe["load_max"] == max(r["moe_load_max"] for r in recs)
     assert len(moe["expert_rows"]) == moe["experts"] == E

@@ -317,10 +317,15 @@ def test_stats_loop_sums_equal_the_dispatch_records(params):
     recs = [dict(zip(dt["fields"], r)) for r in dt["recent"]]
     assert len(recs) == loop["dispatches"] == dt["seq"]
     for r in recs:
-        assert r["ut_passes"] == ((r["segments"] > 0) + r["steps"]) * T
+        # (a step that rode the slab's pass is no pass of its own)
+        assert r["ut_passes"] == ((r["segments"] > 0) + r["steps"]
+                                  - (r["slab_carried_step"] > 0)) * T
+    assert any(r["slab_carried_step"] for r in recs)
     assert loop["slab_passes"] == T * sum(r["segments"] > 0 for r in recs)
-    assert loop["decode_passes"] == T * sum(r["steps"] for r in recs) \
-        == T * st["device_loop"]["device_loop_steps"]
+    assert loop["decode_passes"] == T * sum(
+        r["steps"] - (r["slab_carried_step"] > 0) for r in recs)
+    assert sum(r["steps"] for r in recs) == st["device_loop"][
+        "device_loop_steps"]
     assert loop["slab_passes"] + loop["decode_passes"] == sum(
         r["ut_passes"] for r in recs)
 
@@ -491,7 +496,8 @@ def test_one_pass_record_and_stats_are_the_parent_s(model):
     # a model's own columns
     fields = list(PARENT["fields"][model])
     at = fields.index("kv_tokens") + 1
-    fields[at:at] = ["ahead", "late", "await"]
+    # (... and PR 61's after them: the rows that rode the slab's pass)
+    fields[at:at] = ["ahead", "late", "await", "slab_carried_step"]
     # ... and PR 47's, what the prefill kernel's page loop walks
     at = fields.index("prefill_tokens") + 1
     fields[at:at] = ["prefill_pages_walked"]

@@ -109,9 +109,10 @@ def test_no_plane_and_no_second_pool_in_the_traced_program(model, program):
                     assert name in CARRIES_THE_POOL, (
                         f"{program}: {name} makes a second pool {shape}")
                     writes += name == "scatter"
-        # K and V, once a traced layer body (the slab's and the decode
-        # loop's in a mixed_step that packed a segment)
-        assert writes == (4 if program == "mixed_step" else 2)
+        # K and V, once a traced layer body, and in the merged body of a
+        # mixed_step that packed a segment twice (the slab's rows, then
+        # the decoding rows that ride its pass), beside the decode loop's
+        assert writes == (6 if program == "mixed_step" else 2)
         addressing = eng.attn_paths.addressing()[program.split(",")[0]]
         assert addressing and all(
             how == "scatter write" for how in addressing.values())

@@ -22,6 +22,8 @@ from distributed_inference_demo_tpu.models.decoder import (  # noqa: E402
     init_full_params)
 from distributed_inference_demo_tpu.models.registry import (  # noqa: E402
     get_model_config)
+from distributed_inference_demo_tpu.ops import (        # noqa: E402
+    hyper_connection as hc)
 from distributed_inference_demo_tpu.ops.sampling import (  # noqa: E402
     SamplingParams)
 from distributed_inference_demo_tpu.runtime.batching import (  # noqa: E402
@@ -135,12 +137,16 @@ def test_the_residual_path_s_counters(params):
         st = _settled(eng)
     hcs, trace = st["hc"], st["dispatch_trace"]
     col = trace["fields"].index("hc_rows")
-    seg, steps = (trace["fields"].index(k) for k in ("segments", "steps"))
+    seg, steps, rode = (trace["fields"].index(k) for k in (
+        "segments", "steps", "slab_carried_step"))
+    # a slab's pass holds the slots' rows too (the step it carries, PR 61),
+    # padded to the kernels' whole tiles; a step that rode it is no call
+    # of its own
     assert [r[col] for r in trace["recent"]] == [
-        r[seg] * CHUNK + r[steps] * SLOTS for r in trace["recent"]]
+        r[steps] * SLOTS + (hc.whole_tiles(r[seg] * CHUNK + SLOTS)
+                            - (r[rode] > 0) * SLOTS if r[seg] else 0)
+        for r in trace["recent"]]
     assert hcs["rows"] == sum(r[col] for r in trace["recent"]) > 0
-    assert hcs["rows"] == trace["slab_rows"] + SLOTS * st["device_loop"][
-        "device_loop_steps"]
     assert (hcs["streams"], hcs["sinkhorn_iters"]) == (4, 20)
     # read once: no request moves it
     assert hcs["sinkhorn_residual_max"] == before["sinkhorn_residual_max"]
