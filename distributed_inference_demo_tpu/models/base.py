@@ -20,6 +20,7 @@ in allocation and fully jit-compatible (static shapes).
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass
 from functools import partial
 from typing import Optional
@@ -44,10 +45,13 @@ def _yarn_tuple(yarn) -> tuple:
 class BlockKind:
     """What one KIND of block of a period has of its own: its attention
     (``attn`` "full", or "window" over the last ``window`` tokens: query
-    ``i`` sees key ``j`` iff ``0 <= i - j < window``, or "kda": the gated
-    delta rule over a recurrent state a head, behind a depthwise causal
-    convolution of ``conv`` taps, ``ops.kda``; no keys, no pages), its
-    query heads, its rope (``rope_theta``; ``rotary_share`` of a head's
+    ``i`` sees key ``j`` iff ``0 <= i - j < window``, or a STATE kind, no
+    keys and no pages but a recurrent state a request behind a depthwise
+    causal convolution of ``conv`` taps: "kda", the gated delta rule over
+    ``[hd, hd]`` a head, ``ops.kda``; "ssd", Mamba-2's scalar decay a head
+    over ``state_heads`` states of ``[state_head_dim, state_size]``, B and
+    C shared by the heads of each of ``groups`` groups, the scan in chunks
+    of ``chunk`` tokens, ``ops.ssd``), its query heads, its rope (``rope_theta``; ``rotary_share`` of a head's
     channels turn, the first ones, rotate-half within them, 0 = no rope;
     ``yarn`` = ``(factor, original positions, beta_fast, beta_slow,
     attention_factor)`` or empty: ``ops.rope.yarn_frequencies``) and its
@@ -64,17 +68,37 @@ class BlockKind:
     yarn: tuple = ()
     gate: str = "none"
     conv: int = 0
+    state_heads: int = 0
+    state_head_dim: int = 0
+    state_size: int = 0
+    groups: int = 1
+    chunk: int = 0
+
+    @property
+    def is_state(self) -> bool:
+        """Its cache is a recurrent state a request, not rows of pages."""
+        return self.attn in ("kda", "ssd")
 
     def __post_init__(self):
-        if self.attn not in ("full", "window", "kda"):
-            raise ValueError(f"a block kind's attn is 'full', 'window' or "
-                             f"'kda', got {self.attn!r}")
+        if self.attn not in ("full", "window", "kda", "ssd"):
+            raise ValueError(f"a block kind's attn is 'full', 'window', "
+                             f"'kda' or 'ssd', got {self.attn!r}")
         if (self.attn == "window") != (self.window > 0):
             raise ValueError("a window kind states its window, a full "
                              "kind none")
-        if (self.attn == "kda") != (self.conv > 1):
-            raise ValueError("a kda kind states its convolution's taps "
-                             "(conv >= 2), another kind none")
+        if self.is_state != (self.conv > 1):
+            raise ValueError("a state kind (kda, ssd) states its "
+                             "convolution's taps (conv >= 2), another "
+                             "kind none")
+        sizes = (self.state_heads, self.state_head_dim, self.state_size,
+                 self.chunk)
+        if not (all(v > 0 for v in sizes) if self.attn == "ssd"
+                else not any(sizes)):
+            raise ValueError("an ssd kind states state_heads, "
+                             "state_head_dim, state_size and chunk, "
+                             "another kind none of them")
+        if self.attn == "ssd" and self.state_heads % self.groups:
+            raise ValueError("an ssd kind's groups divide its state_heads")
         if self.gate not in ("none", "per-head", "elementwise"):
             raise ValueError(f"unknown gate {self.gate!r}")
         object.__setattr__(self, "yarn", _yarn_tuple(self.yarn))
@@ -105,7 +129,8 @@ class BlockKind:
                       "fp32_logits", "eva_window", "eva_chunk",
                       "num_pred_heads", "hc_streams", "hc_sinkhorn_iters",
                       "hc_eps", "hc_res_clamp", "q_lora_rank", "yarn",
-                      "attn_scale"])
+                      "attn_scale", "embedding_multiplier",
+                      "residual_multiplier", "logits_scaling"])
 @dataclass(frozen=True)
 class ModelConfig:
     """Static, hashable architecture description shared by all model families.
@@ -251,6 +276,16 @@ class ModelConfig:
     # (deepseek's ``mscale ** 2`` under YaRN)
     yarn: tuple = ()
     attn_scale: float = 1.0
+    # granite's multipliers, 1.0 for every other family (a static branch:
+    # their programs hold no multiply): the embedded rows times
+    # ``embedding_multiplier``, each sublayer's output times
+    # ``residual_multiplier`` before the residual add, the head's logits
+    # divided by ``logits_scaling``; its fourth, the softmax scale, is
+    # ``attn_scale`` above (times ``head_dim ** -0.5``), which a block of
+    # full keys and values reads too
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     def __post_init__(self):
         object.__setattr__(self, "yarn", _yarn_tuple(self.yarn))
@@ -309,25 +344,51 @@ class ModelConfig:
         if self.lead_kind is not None and self.lead_dense_layers:
             count[self.lead_kind.window] = self.lead_dense_layers
         for k in self.period:
-            if k.attn != "kda":         # a state, not pages (below)
+            if not k.is_state:          # a state, not pages (below)
                 count[k.window] = count.get(k.window, 0) + self.num_layers
         return tuple(sorted(count.items()))
 
     @property
+    def state_kind(self) -> Optional[BlockKind]:
+        """The period's state kind (kda or ssd; ``BlockKind.is_state``), or
+        None.  One state pool holds every such block's plane, so a period
+        has one state kind: planes of two shapes are refused here."""
+        kinds = {k for k in self.period if k.is_state}
+        if len(kinds) > 1:
+            raise ValueError(
+                f"a period holds one state kind, its blocks' planes share "
+                f"a pool; got {sorted(k.attn for k in kinds)}")
+        return next(iter(kinds), None)
+
+    @property
     def state_planes(self) -> int:
-        """Blocks whose cache is a recurrent STATE a request (a kda kind,
-        docs/DESIGN.md section 27), not rows of a page pool: repeat ``r``'s
-        ``j``-th such place holds plane ``r x (places a period) + j``."""
-        return self.num_layers * sum(k.attn == "kda" for k in self.period)
+        """Blocks whose cache is a recurrent STATE a request (a state
+        kind, docs/DESIGN.md sections 27 and 29), not rows of a page pool:
+        repeat ``r``'s ``j``-th such place holds plane ``r x (places a
+        period) + j``."""
+        return self.num_layers * sum(k.is_state for k in self.period)
 
     @property
     def state_shapes(self) -> tuple:
-        """``((heads, hd, hd), (taps - 1, 3 x heads x hd))`` of one
-        request's entry in one state plane: the float32 state ``[key,
-        value]`` a head, and the convolution's tail, the last ``taps - 1``
-        inputs of the q, k and v channels side by side (the model's
-        dtype)."""
-        kind = next(k for k in self.period if k.attn == "kda")
+        """``(state, tail)`` of one request's entry in one state plane, by
+        the state KIND: the float32 state a head and the convolution's
+        tail, the last ``taps - 1`` inputs of the convolved channels (the
+        model's dtype).  kda: ``(heads, hd, hd)`` ``[key, value]`` and the
+        q, k and v channels side by side, ``3 x heads x hd``; ssd:
+        ``(state_heads, state_head_dim, state_size)`` and the ``taps - 1``
+        inputs of the ``x | B | C`` channels (``state_heads x
+        state_head_dim + 2 x groups x state_size``) END TO END in one row,
+        ``((taps - 1) x channels,)``: three rows of channels are a padded
+        tile on the chip (16 sublanes for 3) that every gather and scatter
+        of a row re-laid, 46 ms a dispatch of copies of the whole pool (my
+        chip run, PR 62); a request's row of lanes is not."""
+        kind = self.state_kind
+        if kind.attn == "ssd":
+            return ((kind.state_heads, kind.state_head_dim,
+                     kind.state_size),
+                    ((kind.conv - 1) * (
+                        kind.state_heads * kind.state_head_dim
+                        + 2 * kind.groups * kind.state_size),))
         hd = self.head_dim
         return ((kind.num_heads, hd, hd),
                 (kind.conv - 1, 3 * kind.num_heads * hd))
@@ -337,18 +398,18 @@ class ModelConfig:
         """What one request holds in the state pool, whatever its length."""
         if not self.state_planes:
             return 0
-        (h, a, b), (t, c) = self.state_shapes
-        return self.state_planes * (h * a * b * 4
-                                    + t * c * self.dtype.itemsize)
+        s_shape, c_shape = self.state_shapes
+        return self.state_planes * (math.prod(s_shape) * 4 + math.prod(
+            c_shape) * self.dtype.itemsize)
 
     def state_arrays(self, keys, values) -> tuple:
         """``(state pool, convolution tails)`` out of a cache's ``keys`` and
         ``values``: THE one place that says where a recurrent state rides,
         and checks it.  The state is not a field of its own (docs/DESIGN.md
-        section 27 says why): the pool ``[state_planes, rows, heads, hd,
-        hd]`` float32 is the LAST entry of ``keys`` after one pool of pages
-        a cache kind, the tails ``[state_planes, rows, taps - 1, 3 x heads
-        x hd]`` the last of ``values``; a row's row of the pool is its
+        section 27 says why): the pool ``[state_planes, rows,
+        *state_shapes[0]]`` float32 is the LAST entry of ``keys`` after
+        one pool of pages a cache kind, the tails ``[state_planes, rows,
+        *state_shapes[1]]`` the last of ``values``; a row's row of the pool is its
         table's last column, after one table a kind side by side
         (``ops.paged_attention``'s ``impl.for_state``)."""
         kinds = len(self.cache_kinds)
@@ -374,19 +435,19 @@ class ModelConfig:
         """``(pool, plane)`` of block ``block`` (the leading blocks first,
         then the repeats of the period in order): the index of its pool in
         ``cache_kinds`` and its plane there.  Within a pool the leading
-        blocks' planes come first, then repeat by repeat.  A kda block
-        holds no pages: ``(-1, its plane of the state pool)``."""
+        blocks' planes come first, then repeat by repeat.  A block of a
+        state kind holds no pages: ``(-1, its plane of the state pool)``."""
         windows = [w for w, _ in self.cache_kinds]
         lead = self.lead_dense_layers
         if block < lead:
             return windows.index(self.lead_kind.window), block
         r, p = divmod(block - lead, len(self.period))
-        if self.period[p].attn == "kda":    # a plane of the state pool
-            mine = [q for q, k in enumerate(self.period) if k.attn == "kda"]
+        if self.period[p].is_state:         # a plane of the state pool
+            mine = [q for q, k in enumerate(self.period) if k.is_state]
             return -1, r * len(mine) + mine.index(p)
         w = self.period[p].window
         mine = [q for q, k in enumerate(self.period)
-                if k.window == w and k.attn != "kda"]
+                if k.window == w and not k.is_state]
         base = lead if (self.lead_kind is not None
                         and self.lead_kind.window == w) else 0
         return windows.index(w), base + r * len(mine) + mine.index(p)
@@ -643,8 +704,8 @@ def require_one_kind(cfg: ModelConfig, what: str) -> None:
 
 
 def require_no_state(cfg: ModelConfig, what: str) -> None:
-    """Refuse a model with a recurrent state a request (a kda kind of
-    block: ``state_planes > 0``) where ``what`` is built for a cache that
+    """Refuse a model with a recurrent state a request (a state kind of
+    block, kda or ssd: ``state_planes > 0``) where ``what`` is built for a cache that
     is rows of tokens: a prefix's state is not a block of tokens that can
     be shared, exported or rolled back, and a rejected token has already
     moved it.  ``require_token_rows`` asks it first, so whatever refuses
@@ -654,7 +715,8 @@ def require_no_state(cfg: ModelConfig, what: str) -> None:
     if cfg.state_planes:
         raise ValueError(
             f"{what} does not support a model with a recurrent state "
-            f"(family {cfg.family!r}, {cfg.state_planes} kda blocks): a "
+            f"(family {cfg.family!r}, {cfg.state_planes} "
+            f"{cfg.state_kind.attn} blocks): a "
             f"request's state is {cfg.state_bytes_per_slot} bytes that "
             f"every token rewrites, not rows a token that stay where they "
             f"were written, and it is built for those. Serve it on one "

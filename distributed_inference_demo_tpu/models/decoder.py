@@ -38,6 +38,7 @@ depth and the MXU saturated.  The KV cache threads through the scan as
 per-layer xs/ys so each layer updates its slice functionally.
 """
 
+import contextlib
 from functools import partial
 from typing import Optional, Tuple
 
@@ -52,6 +53,7 @@ from ..ops.norms import layer_norm, rms_norm
 from ..ops.eva_attention import eva_dense_attn
 from ..ops import hyper_connection as hc_ops
 from ..ops import kda as kda_ops
+from ..ops import ssd as ssd_ops
 from ..ops.latent_attention import latent_dense_attn
 from ..ops.paged_attention import join_rows, split_rows
 from ..ops.rope import (apply_rope, apply_rope_interleaved,
@@ -169,6 +171,33 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
             "o_norm_w": jnp.ones((L, hd), cfg.dtype),
             "wo": big(keys[3], (L, D, H), cfg.dtype),
             "mlp_norm_w": jnp.ones((L, H), cfg.dtype),
+        }
+    elif kind is not None and kind.attn == "ssd":
+        # a Mamba-2 block (``_ssd_mixer``): ONE in-projection ``z | x B C |
+        # dt``, the convolution's taps AND bias over the ``x B C``
+        # channels, a head's ``A_log``, ``D`` and ``dt_bias``, the gated
+        # norm over all of ``d_inner``, the out-projection as ``wo``.
+        # Seeded as the mamba_ssm initialiser does (A uniform in [1, 16],
+        # dt log-uniform in [1e-3, 0.1] through the inverse softplus, D at
+        # 1); the convolution's bias at N(0, 0.1), not zero, so that a
+        # path that dropped it cannot pass for one that has it
+        sh, P = kind.state_heads, kind.state_head_dim
+        D, GN = sh * P, kind.groups * kind.state_size
+        ks = jax.random.split(keys[14], 4)
+        step = jnp.exp(jax.random.uniform(ks[0], (L, sh), jnp.float32,
+                                          jnp.log(1e-3), jnp.log(0.1)))
+        p = {
+            "attn_norm_w": jnp.ones((L, H), dt),
+            "w_in": big(keys[0], (L, H, 2 * D + 2 * GN + sh), dt),
+            "conv_w": _dense_init(ks[1], (L, kind.conv, D + 2 * GN), dt),
+            "conv_b": _dense_init(ks[2], (L, D + 2 * GN), dt, scale=0.1),
+            "A_log": jnp.log(jax.random.uniform(ks[3], (L, sh), jnp.float32,
+                                                1.0, 16.0)),
+            "D": jnp.ones((L, sh), jnp.float32),
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "ssd_norm_w": jnp.ones((L, D), dt),
+            "wo": big(keys[3], (L, D, H), dt),
+            "mlp_norm_w": jnp.ones((L, H), dt),
         }
     elif cfg.latent_kv:
         # deepseek_v3: q in one matrix, or with ``q_lora_rank`` in two
@@ -312,11 +341,14 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
         # of the stream (0.013) is what it is in olmoe's cell.  The same
         # holds under a softmax router whose k weights are renormalised
         # and scaled up (a period model's top-10 at 2.5: a quarter of the
-        # routed sum an expert).
+        # routed sum an expert), and for a chip's share of a renormalised
+        # top-k (granite's top-10 of 72, half of them held).
         p["w_down"] = big(keys[7], (L, E, I, H), dt,
                           scale=(I ** -0.5 / 32
                                  if cfg.router_scoring == "sigmoid"
                                  or cfg.routed_scaling_factor > 1.0
+                                 or (cfg.norm_topk_prob
+                                     and cfg.experts_held)
                                  else None))
         if cfg.router_bias:
             # non-zero, so that choosing by score + bias and weighing by
@@ -410,9 +442,12 @@ def init_period_params(rng: jax.Array, cfg: ModelConfig,
         stack = init_layer_params(jax.random.fold_in(rng, 2 + i),
                                   cfg.of_kind(kind), R * n,
                                   quantize=quantize)
-        for leaf, a in stack.items():
+        # (a leaf leaves ``stack`` as it is reshaped: the reshape is a new
+        # buffer, and a kind's stacks held twice do not fit the chip where
+        # the kind is most of the model, granite's nine ssd blocks)
+        for leaf in list(stack):
             out[f"{leaf}.{name}"] = jax.tree.map(
-                lambda x: x.reshape((R, n) + x.shape[1:]), a)
+                lambda x: x.reshape((R, n) + x.shape[1:]), stack.pop(leaf))
     return out
 
 
@@ -431,6 +466,8 @@ def embed_tokens(params: StageParams, cfg: ModelConfig,
         # the activation dtype FIRST (HF semantics — the rounding is part
         # of the checkpoint's numerics)
         x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
+    if cfg.embedding_multiplier != 1.0:     # granite
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     if "norm_w" in params.embed:  # bloom embedding LayerNorm
         x = layer_norm(x, params.embed["norm_w"], params.embed["norm_b"],
                        cfg.norm_eps)
@@ -768,6 +805,11 @@ def _kv_attention(cfg: ModelConfig, lp: dict, h: jnp.ndarray, k_cache,
     # fusion (slice of the stack, dequant, matmul, bias) like the MLP's, for a
     # round trip of q, k and v through memory (docs/DESIGN.md section 1).
     q, k, v = jax.lax.optimization_barrier((q, k, v))
+    if cfg.attn_scale != 1.0:
+        # a softmax scale that is not ``hd ** -0.5`` (granite's
+        # ``attention_multiplier``): every attention path scales its
+        # float32 scores by ``hd ** -0.5``, so the rest rides on q
+        q = (q.astype(jnp.float32) * cfg.attn_scale).astype(q.dtype)
     q = q.reshape(b, s, nh, hd)
     k = k.reshape(b, s, nkv, hd)
     v = v.reshape(b, s, nkv, hd)
@@ -938,6 +980,129 @@ def _kda_mixer(cfg: ModelConfig, kind, lp: dict, h: jnp.ndarray, state,
     return (y.reshape(b, s, D), LayerOf(S, plane), LayerOf(tails, plane))
 
 
+def _gated_norm(y, z, w, eps):
+    """Mamba-2's gated RMSNorm over float32 ``y``: the gate ``silu(z)``
+    BEFORE the norm, one mean square over all of ``d_inner`` (one group)."""
+    return rms_norm(y * jax.nn.silu(z.astype(jnp.float32)), w, eps)
+
+
+def _ssd_mixer(cfg: ModelConfig, kind, lp: dict, h: jnp.ndarray, state,
+               conv, positions, valid, hook):
+    """A Mamba-2 (SSD) block's mixer over normed rows ``h`` [b, s, H]:
+    ``(y [b, s, d_inner], state', conv')`` before the out-projection
+    (docs/DESIGN.md section 29; the equations are ``ops.ssd``'s first
+    lines):
+
+        z | xBC | dt = h W_in;   xBC = silu(conv(xBC) + b);   x | B | C = xBC
+        dt = softplus(dt + dt_bias);   the recurrence over (x, B, C, dt, A)
+        y = rms_norm((y + D x) * silu(z), w)        one group, all of d_inner
+
+    ``state`` / ``conv`` are ``LayerOf`` the state pool ``[planes, rows,
+    heads, P, N]`` float32 and the convolution tails ``[planes, rows, taps
+    - 1) x (d_inner + 2 groups N)]`` (a request's tail end to end in one
+    row of lanes: ``ModelConfig.state_shapes``); rows, segments, ``valid`` and a merged
+    call's two parts exactly as :func:`_kda_mixer` has them, the state
+    kind's other mixer."""
+    b, s, _ = h.shape
+    nh, P, N, G = (kind.state_heads, kind.state_head_dim, kind.state_size,
+                   kind.groups)
+    D, f32 = nh * P, jnp.float32
+    plane = state.layer
+    S, tails = state.stack, conv.stack
+    rows = hook.rows() if hook is not None else None
+    interpret = hook is not None and hook.interpret
+    if valid is None:
+        valid = jnp.ones((b, s), bool)
+    merged = isinstance(rows, tuple)
+    in_parts = lambda *a: split_rows(rows, a) if merged else (a,)  # noqa: E731
+    ntoks = [jnp.sum(part, axis=1).astype(jnp.int32)
+             for (part,) in in_parts(valid)]
+    with jax.named_scope("ssd_in_proj"):
+        u = dense(h, lp["w_in"], "bsh,hd->bsd")
+        u = jax.lax.optimization_barrier(u)     # as ``_kv_attention``
+        z, xbc = u[..., :D], u[..., D:2 * D + 2 * G * N]
+        dt = jax.nn.softplus(u[..., 2 * D + 2 * G * N:].astype(f32)
+                             + lp["dt_bias"].astype(f32))
+        dt = jnp.where(valid[:, :, None], dt, 0.0)  # a token not there
+        A = -jnp.exp(lp["A_log"].astype(f32))
+    R = tails.shape[1]
+    taps, chans = kind.conv, D + 2 * G * N
+
+    def parts_of(y):
+        """silu(conv + b) -> x a head, B and C a group; rounded to the
+        model's dtype first, as any block's projections are."""
+        y = y.astype(cfg.dtype)
+        return (y[..., :D].reshape(y.shape[:-1] + (nh, P)),
+                y[..., D:D + G * N].reshape(y.shape[:-1] + (G, N)),
+                y[..., D + G * N:].reshape(y.shape[:-1] + (G, N)))
+
+    def mix(rows, ntok, xbc, dt, positions, S, tails):
+        """The state's part over rows ``[b, s]``, each through its row
+        ``rows[i]`` of the pool: ``(y [b, s, heads, P] float32 with the D
+        skip, S', tails')``."""
+        b, s = xbc.shape[:2]
+        kernel, why = (ssd_ops.on_kernel(S.shape, G, min(s, kind.chunk),
+                                         hook.backend)
+                       if hook is not None else (False, "dense cache"))
+        if hook is not None:
+            hook.note(s, "pallas_ssd" if kernel else "xla_ssd", why)
+        if rows is None:
+            at = jnp.arange(b, dtype=jnp.int32)
+        else:   # the last row is nobody's: a row that holds nothing goes there
+            at = jnp.where(ntok > 0, jnp.minimum(rows, R - 1), R - 1)
+        skip = lambda x: lp["D"].astype(f32)[:, None] * x.astype(f32)  # noqa: E731
+        if s == 1:
+            with jax.named_scope("ssd_conv"):
+                tail = jax.lax.dynamic_index_in_dim(tails, plane, 0,
+                                                    False)[at]
+                y, tail = kda_ops.causal_conv(
+                    xbc, tail.reshape(b, taps - 1, chans), lp["conv_w"],
+                    ntok, lp["conv_b"])
+                tails = tails.at[plane, at].set(tail.reshape(b, -1))
+                x, B, C = parts_of(y[:, 0])
+            with jax.named_scope("ssd_step"):
+                o, S = ssd_ops.ssd_step(
+                    S, plane, None if rows is None else at, x, B, C,
+                    dt[:, 0], A, ntok > 0, kernel=kernel,
+                    interpret=interpret)
+            return (o + skip(x))[:, None], S, tails
+
+        def segment(pools, seg):
+            """One segment from the state and the tail the one before left
+            (two segments of a slab may be one prompt's consecutive
+            chunks): a ``lax.scan`` body, so a slab of any number of
+            segments lowers the convolution and the call ONCE a block."""
+            S, tails = pools
+            xbc, dt, at, fresh, ntok = seg
+            with jax.named_scope("ssd_conv"):
+                tail = jnp.where(fresh, 0, tails[plane, at]).astype(
+                    tails.dtype).reshape(1, taps - 1, chans)
+                y, tail = kda_ops.causal_conv(xbc[None], tail, lp["conv_w"],
+                                              ntok[None], lp["conv_b"])
+                tails = tails.at[plane, at].set(tail.reshape(-1))
+                x, B, C = parts_of(y[0])
+            with jax.named_scope("ssd_chunk"):
+                o, S = ssd_ops.ssd_chunk(
+                    S, plane, at, fresh, x, B, C, dt, A, chunk=kind.chunk,
+                    kernel=kernel, interpret=interpret)
+            return (S, tails), o.astype(f32) + skip(x)
+
+        (S, tails), o = jax.lax.scan(
+            segment, (S, tails), (xbc, dt, at, positions[:, 0] == 0, ntok))
+        return o, S, tails
+
+    outs = []       # (merged: the segments' chunk form, then the rows' step)
+    for part_rows, ntok, part in zip(rows if merged else (rows,), ntoks,
+                                     in_parts(xbc, dt, positions)):
+        o, S, tails = mix(part_rows, ntok, *part, S, tails)
+        outs.append(o)
+    o = join_rows(outs) if merged else o
+    with jax.named_scope("ssd_gated_norm"):
+        y = _gated_norm(o.reshape(b, s, D), z, lp["ssd_norm_w"],
+                        cfg.norm_eps).astype(cfg.dtype)
+    return y, LayerOf(S, plane), LayerOf(tails, plane)
+
+
 def _latent_attention(cfg: ModelConfig, lp: dict, h: jnp.ndarray, cache,
                       positions: jnp.ndarray, cache_start: jnp.ndarray,
                       attn_impl=None):
@@ -1065,6 +1230,9 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
         # the caches are the state pool and the convolution tails
         attn, k_cache, v_cache = _kda_mixer(
             cfg, kind, lp, h, k_cache, v_cache, positions, valid, attn_impl)
+    elif kind is not None and kind.attn == "ssd":
+        attn, k_cache, v_cache = _ssd_mixer(
+            cfg, kind, lp, h, k_cache, v_cache, positions, valid, attn_impl)
     elif cfg.latent_kv:  # the cache is ``k_cache`` alone; ``v_cache`` is empty
         attn, k_cache = _latent_attention(cfg, lp, h, k_cache, positions,
                                           cache_start, attn_impl)
@@ -1072,13 +1240,19 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
         attn, k_cache, v_cache = _kv_attention(
             cfg, lp, h, k_cache, v_cache, positions, cache_start, slopes,
             tp_axis, attn_impl)
-    attn = dense(attn, lp["wo"], "bsd,dh->bsh")
+    is_ssd = kind is not None and kind.attn == "ssd"
+    with (jax.named_scope("ssd_out_proj") if is_ssd
+          else contextlib.nullcontext()):
+        attn = dense(attn, lp["wo"], "bsd,dh->bsh")
     if tp_axis is not None:
         attn = jax.lax.psum(attn, tp_axis)
     if cfg.attn_layernorm:
         attn = attn + lp["bo"]
     if cfg.sandwich_norm:
         attn = rms_norm(attn, lp["attn_post_norm_w"], cfg.norm_eps)
+    rm = cfg.residual_multiplier    # granite: both sublayers' outputs
+    if rm != 1.0:
+        attn = attn * jnp.asarray(rm, attn.dtype)
     if n:
         streams = hc_write(streams, attn, coef)
         x, coef = hc_read("mlp", streams)
@@ -1098,6 +1272,8 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
         y, rows = _mlp(cfg, lp, h, tp_axis, ep_axis, valid), None
     if cfg.sandwich_norm:
         y = rms_norm(y, lp["mlp_post_norm_w"], cfg.norm_eps)
+    if rm != 1.0:
+        y = y * jnp.asarray(rm, y.dtype)
     x = hc_write(streams, y, coef) if n else x + y
     if moe_stats:
         return x, k_cache, v_cache, rows
@@ -1143,7 +1319,7 @@ def _period_blocks(params: StageParams, cfg: ModelConfig, x, cache: KVCache,
             "make_paged_attn_impl); this one serves one kind")
 
     def hook(name, kind, pool):
-        if kind.attn == "kda":      # rows of the state pool, not pages
+        if kind.is_state:           # rows of the state pool, not pages
             return (attn_impl.for_state(name) if attn_impl is not None
                     else None)
         if attn_impl is not None:
@@ -1151,9 +1327,10 @@ def _period_blocks(params: StageParams, cfg: ModelConfig, x, cache: KVCache,
         return _window_attn(kind.window) if kind.window else None
 
     def block(block_cfg, lp, x, Ks, Vs, pool, plane, impl, stats):
-        pool %= len(Ks)     # -1, a kda block's (``cfg.state_arrays``)
-        # the state pool goes whole, paged or not (``_kda_mixer``)
-        whole = paged or block_cfg.block_kind.attn == "kda"
+        pool %= len(Ks)     # -1, a state kind's (``cfg.state_arrays``)
+        # the state pool goes whole, paged or not (``_kda_mixer``,
+        # ``_ssd_mixer``)
+        whole = paged or block_cfg.block_kind.is_state
         k_of, v_of = LayerOf(Ks[pool], plane), LayerOf(Vs[pool], plane)
         kc, vc = (k_of, v_of) if whole else (k_of.sliced(), v_of.sliced())
         x, kc, vc, *rows = _layer(block_cfg, lp, x, kc, vc, positions,
@@ -1512,6 +1689,8 @@ def stage_forward(
                            precision=jax.lax.Precision.HIGHEST)
         else:
             x = jnp.einsum("bsh,hv->bsv", x.astype(cfg.dtype), head)
+        if cfg.logits_scaling != 1.0:       # granite
+            x = x / jnp.asarray(cfg.logits_scaling, x.dtype)
         if tp_axis is not None and x.shape[-1] != cfg.vocab_size:
             # vocab-parallel head: gather the logit shards so every rank
             # sees full logits at the sampling boundary.  Skipped when the

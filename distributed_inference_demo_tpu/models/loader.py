@@ -270,6 +270,77 @@ def deepseek_v3_params_from_state_dict(raw: Dict[str, np.ndarray],
         lead=stack(blocks[:n_lead]) if n_lead else None)
 
 
+def granite_hybrid_params_from_state_dict(raw: Dict[str, np.ndarray],
+                                          cfg: ModelConfig) -> StageParams:
+    """Map a GraniteMoeHybridForCausalLM state dict (granite-4.0-h-small)
+    onto a period model's stacks, one a kind (``<leaf>.<kind name>``
+    shaped ``[repeats, places of the kind in a period, ...]``): checkpoint
+    layer ``r x len(period) + p`` is place ``p`` of repeat ``r``.
+
+    ``mamba.in_proj`` keeps its column order ``z | x B C | dt``;
+    ``mamba.conv1d.weight`` ``[channels, 1, taps]`` becomes ``[taps,
+    channels]`` (torch's cross-correlation reads tap ``k`` at ``t - taps +
+    1 + k``, as ``ops.kda.causal_conv`` does); ``input_linear`` of the
+    experts and of the shared MLP holds gate THEN up on its output axis
+    (``act(chunk 0) * chunk 1``).  A configuration cut to a chip's share
+    takes experts ``[first, first + held)`` of the stacks, the router
+    whole, and the first ``vocab_size`` rows of the tied embedding."""
+    dt = cfg.dtype
+    I = cfg.intermediate_size
+    Is = cfg.num_shared_experts * I
+    held, first = cfg.experts_held or (cfg.num_experts, 0)
+    lin = lambda name: _get(raw, name).T          # [out, in] -> [in, out]
+    f32 = ("A_log", "D", "dt_bias")
+
+    def block(i: int, kind) -> dict:
+        p = f"layers.{i}."
+        out = {"attn_norm_w": _get(raw, p + "input_layernorm.weight"),
+               "mlp_norm_w": _get(raw, p + "post_attention_layernorm.weight")}
+        if kind.attn == "ssd":
+            out.update({
+                "w_in": lin(p + "mamba.in_proj.weight"),
+                "conv_w": _get(raw, p + "mamba.conv1d.weight")[:, 0, :].T,
+                "conv_b": _get(raw, p + "mamba.conv1d.bias"),
+                "A_log": _get(raw, p + "mamba.A_log"),
+                "D": _get(raw, p + "mamba.D"),
+                "dt_bias": _get(raw, p + "mamba.dt_bias"),
+                "ssd_norm_w": _get(raw, p + "mamba.norm.weight"),
+                "wo": lin(p + "mamba.out_proj.weight")})
+        else:
+            out.update({"wq": lin(p + "self_attn.q_proj.weight"),
+                        "wk": lin(p + "self_attn.k_proj.weight"),
+                        "wv": lin(p + "self_attn.v_proj.weight"),
+                        "wo": lin(p + "self_attn.o_proj.weight")})
+        out["router"] = lin(p + "block_sparse_moe.router.layer.weight")
+        w_in = _get(raw, p + "block_sparse_moe.input_linear.weight")[
+            first:first + held]                         # [E, 2 I, H]
+        out["w_gate"] = w_in[:, :I].transpose(0, 2, 1)
+        out["w_up"] = w_in[:, I:].transpose(0, 2, 1)
+        out["w_down"] = _get(
+            raw, p + "block_sparse_moe.output_linear.weight")[
+                first:first + held].transpose(0, 2, 1)   # [E, I, H]
+        ws_in = _get(raw, p + "shared_mlp.input_linear.weight")  # [2 Is, H]
+        out["ws_gate"], out["ws_up"] = ws_in[:Is].T, ws_in[Is:].T
+        out["ws_down"] = lin(p + "shared_mlp.output_linear.weight")
+        return out
+
+    R, P = cfg.num_layers, len(cfg.period)
+    layers = {}
+    for name, kind, places in cfg.kinds:
+        blocks = [[block(r * P + p, kind) for p in places] for r in range(R)]
+        for leaf in blocks[0][0]:
+            layers[f"{leaf}.{name}"] = jnp.asarray(
+                np.stack([np.stack([b[leaf] for b in row])
+                          for row in blocks]),
+                jnp.float32 if leaf in f32 else dt)
+    tokens = _get(raw, "embed_tokens.weight")[:cfg.vocab_size]
+    return StageParams(
+        layers=layers, embed={"tokens": jnp.asarray(tokens, dt)},
+        final_norm={"w": jnp.asarray(_get(raw, "norm.weight"), dt)},
+        lm_head=({} if cfg.tie_embeddings else
+                 {"w": jnp.asarray(_get(raw, "lm_head.weight", ("",)).T, dt)}))
+
+
 def gemma_params_from_state_dict(raw: Dict[str, np.ndarray],
                                  cfg: ModelConfig) -> StageParams:
     """Gemma: llama names end to end, but every RMSNorm applies
@@ -298,6 +369,7 @@ _SD_MAPPERS = {
     "mixtral": moe_params_from_state_dict,
     "olmoe": moe_params_from_state_dict,
     "deepseek_v3": deepseek_v3_params_from_state_dict,
+    "granite_moe_hybrid": granite_hybrid_params_from_state_dict,
 }
 
 

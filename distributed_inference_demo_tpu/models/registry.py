@@ -32,6 +32,10 @@ SOLAR_TEST_FULL = BlockKind(attn="full", num_heads=4, rotary_share=0.0,
                             gate="elementwise")
 SOLAR_TEST_KDA = BlockKind(attn="kda", num_heads=4, rotary_share=0.0,
                            conv=4)
+GRANITE_TEST_FULL = BlockKind(attn="full", num_heads=4, rotary_share=0.0)
+GRANITE_TEST_SSD = BlockKind(attn="ssd", num_heads=4, rotary_share=0.0,
+                             conv=4, state_heads=8, state_head_dim=16,
+                             state_size=16, groups=1, chunk=8)
 
 # xing4_0 (XingChen-AGI/Xing4.0-29B-A4B config.json): deepseek's YaRN on
 # the 64 rope lanes of a latent head (factor 64 over 4,096 positions,
@@ -266,6 +270,24 @@ MODEL_REGISTRY = {
         routed_scaling_factor=1.0, experts_held=(2, 0),
         dtype_name="float32",
         period=tuple([SOLAR_TEST_FULL] + [SOLAR_TEST_KDA] * 3)),
+    # granitemoehybrid at toy size, 2 repeats of (ssd, ssd, full, ssd):
+    # Mamba-2 blocks (8 state heads of 16 x 16, one group, a convolution
+    # of 4 taps with a bias, the scan in chunks of 8: a state a request,
+    # no pages) around a NoPE GQA block (4 query heads over 2 kv heads,
+    # softmax scale 1 / 16 = hd ** -0.5 x attn_scale), 6 of 12 experts
+    # held, top-3 renormalised, a shared MLP of 2 x 32, a tied head, and
+    # granite's four multipliers
+    "granite-hybrid-test": ModelConfig(
+        family="granite_moe_hybrid", vocab_size=256, hidden_size=64,
+        num_layers=2, num_heads=4, num_kv_heads=2, head_dim_override=16,
+        intermediate_size=32, max_seq_len=256, norm_eps=1e-5,
+        tie_embeddings=True, num_experts=12, experts_per_token=3,
+        norm_topk_prob=True, num_shared_experts=2, experts_held=(6, 0),
+        attn_scale=0.25, embedding_multiplier=12.0,
+        residual_multiplier=0.22, logits_scaling=16.0,
+        dtype_name="float32",
+        period=tuple([GRANITE_TEST_SSD] * 2 + [GRANITE_TEST_FULL]
+                     + [GRANITE_TEST_SSD])),
 }
 
 

@@ -360,11 +360,12 @@ def kda_recurrence(S, q, k, v, g, beta):
 
 # ------------------------------------------------- the short convolution
 
-def causal_conv(u, tail, w, ntok):
+def causal_conv(u, tail, w, ntok, bias=None):
     """Depthwise causal convolution, ``taps`` a channel, then silu:
-    ``y_t = silu(sum_tau w[tau] x_{t - taps + 1 + tau})`` over ``x = tail
-    ++ u``.  ``u`` ``[b, s, C]``; ``tail`` ``[b, taps - 1, C]`` the inputs
-    before the call (zeros at a request's start); ``w`` ``[taps, C]``;
+    ``y_t = silu(sum_tau w[tau] x_{t - taps + 1 + tau} + bias)`` over ``x =
+    tail ++ u``.  ``u`` ``[b, s, C]``; ``tail`` ``[b, taps - 1, C]`` the
+    inputs before the call (zeros at a request's start); ``w`` ``[taps,
+    C]``; ``bias`` ``[C]`` or None (``ops.ssd``'s blocks have one);
     ``ntok`` ``[b]`` the tokens each row holds (its first ones).  Returns
     ``(y [b, s, C] float32, tail')``: the last ``taps - 1`` inputs a row
     holds after its ``ntok`` tokens (the old tail where it holds none)."""
@@ -373,6 +374,8 @@ def causal_conv(u, tail, w, ntok):
     x = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
     wf = w.astype(F32)
     y = sum(wf[t] * x[:, t:t + s].astype(F32) for t in range(taps))
+    if bias is not None:
+        y = y + bias.astype(F32)
     at = ntok[:, None] + jnp.arange(taps - 1)[None, :]
     new_tail = jnp.take_along_axis(x, at[:, :, None], axis=1)
     return jax.nn.silu(y), new_tail.astype(tail.dtype)

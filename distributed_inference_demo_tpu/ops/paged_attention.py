@@ -1620,8 +1620,9 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
 
     def for_state(name: str):
         """The hook of a block whose cache is a recurrent state
-        (``models.decoder._kda_mixer``): ``rows()`` is each bound row's
-        row of the state pool, the tables' last column (a sentinel there
+        (``models.decoder._kda_mixer``, ``_ssd_mixer``): ``rows()`` is each
+        bound row's row of the state pool, the tables' last column (a
+        sentinel there
         is clamped onto the pool's last row, which is nobody's);
         ``note`` records the path its op took as ``<program>/<name>``."""
         def note(chunk: int, path: str, why: str) -> None:

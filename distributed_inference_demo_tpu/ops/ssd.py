@@ -1,0 +1,398 @@
+"""Mamba-2's state-space duality (SSD, arXiv:2405.21060): a scalar decay a
+head over a recurrent state.
+
+A head holds a state ``S`` ``[P, N]`` in float32 (``P`` the head's channels,
+``N`` the state size) and every token rewrites it (docs/DESIGN.md section
+29); ``B`` and ``C`` ``[N]`` are shared by the heads of a group:
+
+    a_t = exp(dt_t A)                              dt > 0, A < 0 a head: a in (0, 1)
+    S_t = a_t S_{t-1} + dt_t x_t B_t^T             x_t [P]
+    y_t = S_t C_t                                  (the ``D`` skip is the mixer's)
+
+No delta rule and no solve (``ops.kda``): the decay is one scalar a head, so
+inside a chunk the token-to-token weights are a 1-semiseparable mask over
+``C B^T``.  Two device ops, named so that a trace tells them apart:
+
+* ``_ssd_step`` (:func:`ssd_step`): one token a row.  A row's whole block
+  of the pool ``[heads, P, N]`` is read, decayed, updated and written in
+  place; a row that decodes nothing moves no block of the pool (it names
+  the block of the live row before it, which the call already holds).
+  What is a column over ``P`` in the arithmetic (``dt x`` and the
+  output) rides TRANSPOSED, ``[P, heads]``: head ``h``'s is lane ``h``, and
+  no transpose runs in the kernel.
+* ``_ssd_chunk`` (:func:`ssd_chunk`): a prefill segment, ``chunk`` tokens a
+  pass.  With ``l`` the running sum of ``dt A`` inside the chunk (<= 0, and
+  every difference below is formed BEFORE its exponential, so no exponent
+  is positive) and ``S`` the state the chunk starts from:
+
+      Y^T  = (dt x)^T (B C^T * exp(l_t - l_s) [s <= t]) + (S C^T) * exp(l_t)
+      S'   = exp(l_end) S + ((dt x) * exp(l_end - l_s))^T B
+
+  all of it on the matrix unit, operands in the tokens' own dtype (bfloat16
+  when served, as the published kernels have it), sums, decays and the
+  state in float32.  One call runs a segment's chunks in order over a head
+  block of the pool that stays where it is from the first to the last.
+
+A token that is not there (a padded position, a row that decodes nothing)
+has ``dt = 0``: it leaves the state as it was, bit for bit (a dead row of
+:func:`ssd_step` is not touched at all).  On the chip both ops are Pallas
+calls at a state of ``[128 k heads, 8 j, 128]`` and one group; elsewhere,
+and in float32 tests, plain XLA with the same arithmetic.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+HIGHEST = jax.lax.Precision.HIGHEST
+F32 = jnp.float32
+
+# the published chunk of the scan; a shorter segment is one chunk
+CHUNK = 256
+# heads a grid step of the chunk kernel (the step takes a row's every head)
+_CHUNK_HEADS = 16
+_VMEM = 48 * 1024 * 1024
+
+
+def on_kernel(state_shape, groups: int = 1, chunk: int = 1,
+              backend: str = "auto", platform=None) -> tuple:
+    """``(kernel?, why not)``: the Pallas calls serve a state of
+    ``[.., 128 k heads, 8 j, 128]`` of one group on a TPU, a segment in
+    chunks of whole lanes."""
+    platform = platform or jax.default_backend()
+    if backend == "xla":
+        return False, "backend xla"
+    if platform != "tpu" and backend != "pallas":
+        return False, f"platform {platform}"
+    h, p, n = state_shape[-3:]
+    if n != 128 or p % 8 or h % 128 or groups != 1:
+        return False, f"state {h} x {p} x {n}, {groups} groups"
+    if chunk > 1 and chunk % 128:
+        return False, f"chunk {chunk} not whole lanes of 128"
+    return True, ""
+
+
+def _dot(a, b):
+    """A matrix product with float32 sums: at ``HIGHEST`` where the
+    operands are float32, one pass where they are narrower."""
+    return jnp.dot(a, b, preferred_element_type=F32,
+                   precision=HIGHEST if a.dtype == F32 else None)
+
+
+# --------------------------------------------------------------- the step
+
+def _step_math(S, x, B, C, dt, A):
+    """One token over states ``S`` ``[.., H, P, N]``: ``(y [.., H, P],
+    S')``.  ``x`` ``[.., H, P]``, ``B, C`` ``[.., H, N]`` (a group's spread
+    over its heads), ``dt`` ``[.., H]``, ``A`` ``[H]``; float32, on the
+    vector unit."""
+    a = jnp.exp(dt * A)
+    S = (S * a[..., None, None]
+         + (dt[..., None] * x)[..., :, None] * B[..., None, :])
+    return jnp.sum(S * C[..., None, :], axis=-1), S
+
+
+def _ssd_step_kernel(rows_ref, plane_ref, mode_ref, ax_ref, bc_ref, s_ref,
+                     y_ref, out_ref, *, heads: int):
+    """Grid (rows,).  ``ax_ref`` ``[1, 2, P, H]``: the decay ``a`` (spread
+    over ``P``) and ``(dt x)^T``, head ``h`` in lane ``h``; ``bc_ref``
+    ``[1, 2, N]``: B and C; ``s_ref`` / ``out_ref`` ``[1, 1, H, P, N]``,
+    the same block of the pool; ``y_ref`` ``[1, P, H]``, the output
+    transposed.  float32 throughout.  ``mode_ref[i]``: 1 a live row, worked
+    here; 0 a dead row that names the block of the row before it, which
+    therefore neither comes nor goes again and is left as that row left
+    it; 2 a dead row that opens a block (nobody's row, before the first
+    live one): passed through as it came."""
+    del rows_ref, plane_ref
+    mode = mode_ref[pl.program_id(0)]
+
+    @pl.when(mode == 1)
+    def _live():
+        B = bc_ref[0, 0:1, :]                       # [1, N]
+        C = bc_ref[0, 1:2, :]
+        for h in range(heads):
+            a = ax_ref[0, 0, :, h:h + 1]            # [P, 1]
+            dx = ax_ref[0, 1, :, h:h + 1]
+            S = s_ref[0, 0, h] * a + dx * B         # [P, N]
+            out_ref[0, 0, h] = S
+            y_ref[0, :, h:h + 1] = jnp.sum(S * C, axis=1, keepdims=True)
+
+    @pl.when(mode == 2)
+    def _through():
+        out_ref[...] = s_ref[...]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _ssd_step_call(rows, plane, mode, ax, bc, state, *, interpret=False):
+    """``ax`` ``[b, 2, P, H]``, ``bc`` ``[b, 2, N]``, ``state`` ``[Pl, R,
+    H, P, N]`` aliased to the second output; row ``i`` works on
+    ``state[plane, rows[i]]`` as ``mode[i]`` says (the kernel's words)."""
+    b, _, P, H = ax.shape
+    N = bc.shape[-1]
+    s_spec = pl.BlockSpec((1, 1, H, P, N),
+                          lambda i, rows, plane, mode: (plane[0], rows[i],
+                                                        0, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_ssd_step_kernel, heads=H),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b,),
+            in_specs=[pl.BlockSpec((1, 2, P, H), lambda i, *_: (i, 0, 0, 0)),
+                      pl.BlockSpec((1, 2, N), lambda i, *_: (i, 0, 0)),
+                      s_spec],
+            out_specs=[pl.BlockSpec((1, P, H), lambda i, *_: (i, 0, 0)),
+                       s_spec]),
+        out_shape=[jax.ShapeDtypeStruct((b, P, H), F32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        input_output_aliases={5: 1},    # operands count the three scalars
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM),
+        interpret=interpret,
+        name="_ssd_step",
+    )(rows, plane, mode, ax, bc, state)
+
+
+def _blocks_of(rows, live, trash: int):
+    """``(rows', mode)`` for the step kernel: a dead row names the block of
+    the last live row before it (nobody's row where there is none), so a
+    block comes in and goes out once, for a live row, and a dead row moves
+    nothing."""
+    b = rows.shape[0]
+    at = jnp.arange(b, dtype=jnp.int32)
+    last = jax.lax.cummax(jnp.where(live, at, -1))
+    rows = jnp.where(last >= 0, rows[jnp.maximum(last, 0)], trash)
+    opens = jnp.concatenate([jnp.ones((1,), bool), rows[1:] != rows[:-1]])
+    return rows.astype(jnp.int32), jnp.where(
+        live, 1, jnp.where(opens, 2, 0)).astype(jnp.int32)
+
+
+def _of_heads(a, heads: int):
+    """``[.., G, N]`` -> ``[.., H, N]``: a group's B or C for each of its
+    heads."""
+    return jnp.repeat(a, heads // a.shape[-2], axis=-2)
+
+
+def ssd_step(state, plane, rows, x, B, C, dt, A, live, *,
+             kernel: bool = False, interpret: bool = False):
+    """One token a row.  ``state`` ``[Pl, R, H, P, N]`` float32, the whole
+    pool; ``plane`` an int32 scalar; ``rows`` ``[b]`` the pool row of each
+    batch row, or ``None`` where row ``i`` is pool row ``i`` (a dense
+    cache); ``x`` ``[b, H, P]``, ``B, C`` ``[b, G, N]``, ``dt`` ``[b, H]``
+    (after its softplus), ``A`` ``[H]`` (negative); ``live`` ``[b]`` bool or
+    ``None``.  Returns ``(y [b, H, P] float32, state')``, ``y`` without the
+    ``D`` skip.  A dead row's state is not touched; two live rows never
+    name one pool row.  The last pool row is nobody's (dead rows point
+    there where the kernel needs a place)."""
+    R, H = state.shape[1:3]
+    b = x.shape[0]
+    x, B, C, dt, A = (t.astype(F32) for t in (x, B, C, dt, A))
+    if live is None:
+        live = jnp.ones((b,), bool)
+    if rows is None:
+        rows = jnp.arange(b, dtype=jnp.int32)
+        trash = None
+    else:
+        trash = R - 1
+        rows = jnp.where(live, jnp.minimum(rows, trash), trash)
+    if kernel:
+        assert trash is not None, "the kernel addresses a pool by row"
+        a = jnp.broadcast_to(jnp.exp(dt * A)[:, None, :],
+                             (b, x.shape[2], H))
+        ax = jnp.stack([a, jnp.swapaxes(dt[..., None] * x, 1, 2)], axis=1)
+        rows, mode = _blocks_of(rows, live, trash)
+        yT, state = _ssd_step_call(
+            rows, jnp.reshape(plane, (1,)).astype(jnp.int32), mode, ax,
+            jnp.concatenate([B, C], axis=1), state, interpret=interpret)
+        return (jnp.where(live[:, None, None], jnp.swapaxes(yT, 1, 2), 0.0),
+                state)
+    # XLA: the pool's plane is worked on where it lies, every row of it,
+    # and what is small (x, B, C, dt, the outputs) moves instead
+    S = jax.lax.dynamic_index_in_dim(state, plane, 0, keepdims=False)
+    if trash is None:
+        at, alive = jnp.arange(R), live
+    else:
+        at = jnp.full((R,), b, jnp.int32).at[rows].set(
+            jnp.arange(b, dtype=jnp.int32)).at[trash].set(b)
+        alive = at < b
+    pad = lambda t: jnp.concatenate(
+        [t, jnp.zeros((1,) + t.shape[1:], F32)])[at]
+    y, S_new = _step_math(S, pad(x), pad(_of_heads(B, H)),
+                          pad(_of_heads(C, H)), pad(dt), A)
+    S = jnp.where(alive[:, None, None, None], S_new, S)
+    state = jax.lax.dynamic_update_index_in_dim(state, S, plane, 0)
+    return jnp.where(live[:, None, None], y[rows], 0.0), state
+
+
+# -------------------------------------------------------------- the chunk
+
+def _chunk_pass(S, dx, B, C, l):
+    """One chunk over every head (the XLA form): ``(y [Q, H, P] float32,
+    S')`` from ``S`` ``[H, P, N]`` float32, ``dx = dt x`` ``[Q, H, P]`` and
+    ``B, C`` ``[Q, H, N]`` in the tokens' dtype, ``l`` ``[Q, H]`` the
+    running sum of ``dt A``."""
+    Q = dx.shape[0]
+    dt_ = dx.dtype
+    prec = HIGHEST if dt_ == F32 else None
+    ein = functools.partial(jnp.einsum, preferred_element_type=F32,
+                            precision=prec)
+    lower = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]     # [t, s]
+    diff = l[:, None, :] - l[None, :, :]                        # [t, s, H]
+    M = (ein("thn,shn->tsh", C, B)
+         * jnp.exp(jnp.where(lower[:, :, None], diff, -jnp.inf)))
+    y = (ein("tsh,shp->thp", M.astype(dt_), dx)
+         + ein("thn,hpn->thp", C, S.astype(dt_)) * jnp.exp(l)[:, :, None])
+    end = l[-1]                                                 # [H]
+    w = jnp.exp(end[None, :] - l)                               # [Q, H]
+    S = (S * jnp.exp(end)[:, None, None]
+         + ein("shp,shn->hpn", (dx.astype(F32) * w[:, :, None]).astype(dt_),
+               B))
+    return y, S
+
+
+def _ssd_chunk_kernel(row_ref, plane_ref, fresh_ref, dx_ref, l_ref, lt_ref,
+                      le_ref, b_ref, ct_ref, s_ref, y_ref, out_ref, *,
+                      heads: int):
+    """Grid (head blocks, chunks), the chunks in order.  The block of the
+    pool ``[1, 1, heads, P, N]`` stays in ``out_ref`` from the first chunk
+    (where it is the pool's, or zero for a segment that starts a request)
+    to the last.  ``dx_ref`` ``[1, heads, P, Q]`` is ``(dt x)^T`` a head,
+    ``l_ref`` ``[1, heads, Q]`` the running log decay as rows, ``lt_ref``
+    ``[1, 1, Q, heads]`` the same as columns, ``le_ref`` ``[1, heads, N]``
+    its last entry spread over the state's lanes (Mosaic spreads one number
+    over one axis at a time), ``b_ref`` ``[1, Q, N]`` and
+    ``ct_ref`` ``[1, N, Q]`` the one group's B and C^T; ``y_ref`` ``[1,
+    heads, P, Q]`` the output transposed."""
+    del row_ref, plane_ref
+
+    @pl.when(pl.program_id(1) == 0)
+    def _first():
+        s0 = s_ref[...]
+        out_ref[...] = jnp.where(fresh_ref[0] > 0, jnp.zeros_like(s0), s0)
+
+    B, CT = b_ref[0], ct_ref[0]
+    dt_ = B.dtype
+    Q = B.shape[0]
+    G = _dot(B, CT)                                 # [s, t] = B_s . C_t
+    upper = (jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+             <= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1))   # s <= t
+    for h in range(heads):
+        lrow = l_ref[0, h:h + 1, :]                 # [1, Q] over t (or s)
+        lcol = lt_ref[0, 0, :, h:h + 1]             # [Q, 1] over s
+        MT = (G * jnp.exp(jnp.where(upper, lrow - lcol, -jnp.inf))
+              ).astype(dt_)
+        dx = dx_ref[0, h]                           # [P, Q]
+        S = out_ref[0, 0, h]                        # [P, N]
+        y = _dot(dx, MT) + _dot(S.astype(dt_), CT) * jnp.exp(lrow)
+        y_ref[0, h] = y.astype(y_ref.dtype)
+        end = lrow[:, Q - 1:Q]                      # [1, 1]
+        w = jnp.exp(end - lrow)
+        out_ref[0, 0, h] = S * jnp.exp(le_ref[0, h:h + 1, :]) + _dot(
+            (dx.astype(F32) * w).astype(dt_), B)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _ssd_chunk_call(row, plane, fresh, dxT, l, lT, lE, Bm, CT, state, *,
+                    interpret=False):
+    """``dxT`` ``[n, H, P, Q]``, ``l`` ``[n, H, Q]``, ``lT`` ``[n, H / hb,
+    Q, hb]``, ``lE`` ``[n, H, N]``, ``Bm`` ``[n, Q, N]``, ``CT`` ``[n, N,
+    Q]``; ``state`` aliased to the second output."""
+    n, H, P, Q = dxT.shape
+    N = Bm.shape[-1]
+    hb = lT.shape[-1]
+    s_spec = pl.BlockSpec(
+        (1, 1, hb, P, N),
+        lambda j, i, row, plane, fresh: (plane[0], row[0], j, 0, 0))
+    tile = pl.BlockSpec((1, hb, P, Q), lambda j, i, *_: (i, j, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_ssd_chunk_kernel, heads=hb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(H // hb, n),
+            in_specs=[tile,
+                      pl.BlockSpec((1, hb, Q), lambda j, i, *_: (i, j, 0)),
+                      pl.BlockSpec((1, 1, Q, hb),
+                                   lambda j, i, *_: (i, j, 0, 0)),
+                      pl.BlockSpec((1, hb, N), lambda j, i, *_: (i, j, 0)),
+                      pl.BlockSpec((1, Q, N), lambda j, i, *_: (i, 0, 0)),
+                      pl.BlockSpec((1, N, Q), lambda j, i, *_: (i, 0, 0)),
+                      s_spec],
+            out_specs=[tile, s_spec]),
+        out_shape=[jax.ShapeDtypeStruct(dxT.shape, dxT.dtype),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        input_output_aliases={9: 1},    # operands count the three scalars
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM),
+        interpret=interpret,
+        name="_ssd_chunk",
+    )(row, plane, fresh, dxT, l, lT, lE, Bm, CT, state)
+
+
+def ssd_chunk(state, plane, row, fresh, x, B, C, dt, A, *,
+              chunk: int = CHUNK, kernel: bool = False,
+              interpret: bool = False):
+    """One segment of ``s`` tokens of one request, in order, starting from
+    ``state[plane, row]`` (from zero where ``fresh``: the segment starts a
+    request) and leaving its final state there.  ``x`` ``[s, H, P]`` and
+    ``B, C`` ``[s, G, N]`` in the tokens' dtype (the products' operands),
+    ``dt`` ``[s, H]`` float32 after its softplus and 0 at a token that is
+    not there, ``A`` ``[H]`` (negative); ``row`` and ``plane`` int32
+    scalars (``row`` inside the pool).  Returns ``(y [s, H, P] in ``x``'s
+    dtype, state')``, ``y`` without the ``D`` skip.  ``s`` is padded to
+    whole chunks with tokens that are not there."""
+    s, H, P = x.shape
+    chunk = min(chunk, s)           # a short segment is one chunk
+    pad = -s % chunk
+    dt = dt.astype(F32)
+    dx = (dt[..., None] * x.astype(F32)).astype(x.dtype)
+    dA = dt * A.astype(F32)
+    if pad:
+        z = lambda a: jnp.concatenate(
+            [a, jnp.zeros((pad,) + a.shape[1:], a.dtype)])
+        dx, B, C, dA = z(dx), z(B), z(C), z(dA)
+    n = (s + pad) // chunk
+    cut = lambda a: a.reshape((n, chunk) + a.shape[1:])
+    dx, B, C = cut(dx), cut(B), cut(C)
+    l = jnp.cumsum(cut(dA), axis=1)                         # [n, Q, H]
+    if kernel:
+        hb = _CHUNK_HEADS
+        one = lambda a: jnp.reshape(a, (1,)).astype(jnp.int32)
+        yT, state = _ssd_chunk_call(
+            one(row), one(plane), one(fresh),
+            jnp.transpose(dx, (0, 2, 3, 1)),                # [n, H, P, Q]
+            jnp.swapaxes(l, 1, 2),
+            jnp.swapaxes(l.reshape(n, chunk, H // hb, hb), 1, 2),
+            jnp.broadcast_to(l[:, -1, :, None], (n, H, B.shape[-1])),
+            B[:, :, 0], jnp.swapaxes(C[:, :, 0], 1, 2), state,
+            interpret=interpret)
+        y = jnp.transpose(yT, (0, 3, 1, 2))                 # [n, Q, H, P]
+    else:
+        S = jnp.where(fresh, 0.0, state[plane, row])
+        outs = []
+        for i in range(n):
+            y_i, S = _chunk_pass(S, dx[i], _of_heads(B[i], H),
+                                 _of_heads(C[i], H), l[i])
+            outs.append(y_i.astype(x.dtype))
+        y = jnp.stack(outs)
+        state = state.at[plane, row].set(S)
+    return y.reshape((n * chunk, H, P))[:s], state
+
+
+def ssd_recurrence(S, x, B, C, dt, A):
+    """The recurrence token by token (``lax.scan``), float32: what both
+    forms are held to in tests, and nothing the serving path runs.  ``S``
+    ``[H, P, N]``; the rest as :func:`ssd_chunk`.  Returns ``(y [s, H, P],
+    S')``."""
+    H = S.shape[0]
+    x, B, C, dt, A = (t.astype(F32) for t in (x, B, C, dt, A))
+
+    def body(S, t):
+        y, S = _step_math(S, *t, A)
+        return S, y
+    S, y = jax.lax.scan(body, S, (x, _of_heads(B, H), _of_heads(C, H), dt))
+    return y, S

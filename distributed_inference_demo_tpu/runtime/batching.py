@@ -93,10 +93,10 @@ from ..telemetry.flightrecorder import get_flight_recorder
 from ..telemetry.slo import get_slo_ledger, sanitize_tenant
 from ..telemetry.tracing import (EVA_DISPATCH_FIELDS,
                                  HC_DISPATCH_FIELDS,
-                                 KDA_DISPATCH_FIELDS,
                                  LATENT_DISPATCH_FIELDS,
                                  LOOP_DISPATCH_FIELDS,
                                  MOE_DISPATCH_FIELDS,
+                                 STATE_DISPATCH_FIELDS,
                                  WINDOW_DISPATCH_FIELDS, DispatchTrace,
                                  LoopCounters, MoeCounters, TraceRecorder,
                                  to_chrome_trace)
@@ -666,7 +666,8 @@ class ContinuousBatchingEngine:
             self._page_sentinel = max(N, self._wmgr.num_blocks)
             self.window_stats = {"pages_held_peak": 0, "pages_returned": 0,
                                  "pages_unwindowed_peak": 0}
-        # a recurrent state a request (a kda kind of block): B + 1 rows
+        # a recurrent state a request (a state kind of block, kda or ssd:
+        # the pool's shapes are the kind's, ``cfg.state_shapes``): B + 1 rows
         # (the slots and one admission more, which is all the intake lets
         # in), leased at admission like pages and held as long as the
         # request, and one last row that is nobody's, where a row that
@@ -1623,8 +1624,8 @@ class ContinuousBatchingEngine:
             + (LATENT_DISPATCH_FIELDS if latent else ())
             + (WINDOW_DISPATCH_FIELDS if self._wmgr is not None else ())
             + (EVA_DISPATCH_FIELDS if self._eva is not None else ())
-            + (KDA_DISPATCH_FIELDS if self._state_free is not None
-               else ())
+            + (STATE_DISPATCH_FIELDS[cfg.state_kind.attn]
+               if self._state_free is not None else ())
             + (HC_DISPATCH_FIELDS if cfg.hc_streams else ()))
         # a model with n residual streams: the token rows its residual
         # path computed (every row of a slab and every slot of a decode
@@ -2323,12 +2324,15 @@ class ContinuousBatchingEngine:
                 lps[i, :len(r)] = reqs[i].lps
         # with the log-probabilities a model says what a check of them
         # cannot see: one with a recurrent state the state each sequence
-        # ended in (docs/DESIGN.md section 27), one with several residual
-        # streams how far its served maps stood from doubly stochastic
-        # (section 28: the start-up reading, no device call here)
+        # ended in (docs/DESIGN.md section 27) and its log-probabilities
+        # once more, for a check that holds them to a limit of the
+        # model's own (section 29); one with several residual streams how
+        # far its served maps stood from doubly stochastic (section 28:
+        # the start-up reading, no device call here)
         said = None
         if logprobs and self._state_free is not None:
-            said = [{"kda_state": r.state} for r in reqs]
+            said = [{f"{self.cfg.state_kind.attn}_state": r.state,
+                     "logprobs": [float(v) for v in r.lps]} for r in reqs]
         elif logprobs and self.hc_stats is not None:
             said = [{"hc_sinkhorn_residual":
                      self.hc_stats["sinkhorn_residual_max"]}] * len(reqs)
@@ -2978,7 +2982,7 @@ class ContinuousBatchingEngine:
         if not cfg.period:
             return ((C, 0),)
         kinds = ([cfg.lead_kind] if cfg.lead_kind is not None else []
-                 ) + [k for k in cfg.period if k.attn != "kda"]
+                 ) + [k for k in cfg.period if not k.is_state]
         return tuple(
             (sub_chunk(C, next(k for k in kinds if k.window == window)
                        .num_heads // cfg.num_kv_heads), window)
@@ -4707,8 +4711,9 @@ class ContinuousBatchingEngine:
             st = self.state_stats
             st["row_steps"] += row_steps
             st["chunk_tokens"] += prefill_tokens
-            record.update(kda_row_steps=row_steps,
-                          kda_chunk_tokens=prefill_tokens)
+            record.update(zip(
+                STATE_DISPATCH_FIELDS[self.cfg.state_kind.attn],
+                (row_steps, prefill_tokens)))
         if self.hc_stats is not None:
             # a slab's pass holds the slots' rows too (whether or not a
             # step rode it), padded to the kernels' whole tiles

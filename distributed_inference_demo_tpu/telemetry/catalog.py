@@ -386,6 +386,26 @@ BATCH_HC_SINKHORN_RESIDUAL = gauge(
     "~0.2 after one)")
 
 
+# -- the state pool of a model with a recurrent state a request
+# (``/stats.kvcache.kinds.state``; docs/DESIGN.md sections 27, 29) ----------
+
+BATCH_STATE_ROW_STEPS = counter(
+    "dwt_batching_state_row_steps_total",
+    "Rows x decode steps that advanced a recurrent state (state."
+    "row_steps; the dispatch record's kda_row_steps / ssd_row_steps)")
+BATCH_STATE_CHUNK_TOKENS = counter(
+    "dwt_batching_state_chunk_tokens_total",
+    "Prompt tokens that went through a state kind's chunk form (state."
+    "chunk_tokens; the record's kda_chunk_tokens / ssd_chunk_tokens)")
+KVCACHE_STATE_SLOT_BYTES = gauge(
+    "dwt_kvcache_state_slot_bytes",
+    "What one request holds in the state pool whatever its length, by "
+    "the state kind's shapes (state.bytes_per_slot)")
+KVCACHE_STATE_HELD_SLOTS = gauge(
+    "dwt_kvcache_state_held_slots",
+    "Rows of the state pool that requests hold now (state.held)")
+
+
 def update_batching_series(stats: dict) -> None:
     """Bridge ``ContinuousBatchingEngine.stats()`` (or any dict with the
     same keys) onto the ``dwt_batching_*`` / ``dwt_speculative_*`` /
@@ -424,6 +444,13 @@ def update_batching_series(stats: dict) -> None:
     kv = stats.get("kvcache") or {}
     if kv:
         update_kvcache_series(kv)
+    state = (kv.get("kinds") or {}).get("state") or {}
+    if state:
+        BATCH_STATE_ROW_STEPS.set_cumulative(state.get("row_steps", 0))
+        BATCH_STATE_CHUNK_TOKENS.set_cumulative(
+            state.get("chunk_tokens", 0))
+        KVCACHE_STATE_SLOT_BYTES.set(state.get("bytes_per_slot", 0))
+        KVCACHE_STATE_HELD_SLOTS.set(state.get("held", 0))
     hc = stats.get("hc") or {}
     if hc:
         BATCH_HC_ROWS.set_cumulative(hc.get("rows", 0))

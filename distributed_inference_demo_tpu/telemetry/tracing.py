@@ -257,11 +257,13 @@ WINDOW_DISPATCH_FIELDS = ("kv_window_tokens", "prefill_window_pairs",
 # positions, as ``kv_tokens`` is)
 EVA_DISPATCH_FIELDS = ("kv_attended_rows", "kv_summary_rows",
                        "prefill_attended_rows", "windows_closed")
-# a model with a recurrent state a request (a kda kind of block): what
-# its two ops advanced.  ``kda_row_steps``: rows x steps that moved a
-# state in the decode loop (a row inside its budget); ``kda_chunk_tokens``:
-# the prompt tokens that went through the chunk form (host arithmetic)
-KDA_DISPATCH_FIELDS = ("kda_row_steps", "kda_chunk_tokens")
+# a model with a recurrent state a request (a state kind of block): what
+# its two ops advanced, under the kind's name.  ``<kind>_row_steps``: rows x
+# steps that moved a state in the decode loop (a row inside its budget);
+# ``<kind>_chunk_tokens``: the prompt tokens that went through the chunk
+# form (host arithmetic)
+STATE_DISPATCH_FIELDS = {kind: (f"{kind}_row_steps", f"{kind}_chunk_tokens")
+                         for kind in ("kda", "ssd")}
 # a model with more than one residual stream (``hc_streams``): the token
 # rows the residual path's two kernels computed in the execution, the
 # slab's rows and every slot at every decode step, a slab's pass with the

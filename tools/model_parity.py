@@ -108,6 +108,19 @@ reads the two ops alone at the model's widths against the recurrence
 token by token (``state_ops_reading``): the served float32 state 7e-5-9e-5,
 ``--bf16-state`` 4e-3 (outputs) and 1e-2 (state).
 
+The second state kind (family ``granite_moe_hybrid``: ``--model
+granite-4.0-h-small-bf16-ep2``; Mamba-2 blocks, ``ops.ssd``) is read the
+same ways (long, and the edge reading for the tail's control) and against
+the same three controls, which reach ``ops.ssd``'s two ops and the one
+convolution both kinds share, plus ``--ssd-skip-dropped`` (``D`` = 0 in
+the served parameters alone): ``READINGS_SSD``.  Its readings are small
+(the tied head's / 16 leaves logits of std ~0.08), so its limits are its
+own (``FAMILY_TOL``), and every reading also prints the served state
+against the reference's after the same ids (``state_rel_err``, the
+family's ``STATE_REL_TOL``, for solar's kind too).  ``--state-ops`` holds
+the chunk form's OUTPUTS to ``STATE_OPS_OUT_TOL_SSD`` (bfloat16 operands
+on the matrix unit) and the state to the limit above.
+
 A model of several residual streams (family ``xing4_0``: ``--model
 xing4.0-29b-a4b-bf16``) is read short (``--batch 2 --prompt 512 --steps
 16``) and long (``--batch 1 --prompt 8192 --steps 16``: 32 chunks through
@@ -248,9 +261,68 @@ EVA_LONG_TOL = (0.031, 0.10)
 # of six sound readings, and 0.1027, the smaller of two readings after
 # ONE Sinkhorn step: 1.14 x and 1.12 x of room.  bfloat16 coefficient maps
 # are refused by no log-probability: ``hc_sinkhorn_residual`` holds them
+# granite_moe_hybrid (READINGS_SSD below): the tied head's / 16 over seeded
+# embeddings leaves logits of std ~0.08, so every reading is small: the mean
+# between 0.00505, the largest of five sound readings (0.00467-0.00505 over
+# two shapes), and 0.0577, a state not carried, the nearest control: 3.0 x
+# and 3.8 x of room; the maximum between 0.0065 and 0.064.  A bfloat16 state
+# is refused by no log-probability (0.00468): the state's two numbers hold it
 FAMILY_TOL = {"deepseek_v3": (0.10, TOL_MAX), "laguna": (0.13, TOL_MAX),
               "evabyte": (0.04, 0.10), "solar_open2": (0.12, TOL_MAX),
-              "xing4_0": (0.092, TOL_MAX)}
+              "xing4_0": (0.092, TOL_MAX),
+              "granite_moe_hybrid": (0.015, 0.03)}
+# (max_over_vocab_mean, max_over_vocab_max, mean_abs, state_rel_err,
+# state_f32_residue); my chip runs, PR 62, TPU v5 lite,
+# granite-4.0-h-small-bf16-ep2 at published widths (one period of ten
+# blocks, 36 of 72 experts, half the vocabulary); every path Pallas
+# (pallas_prefill / pallas_decode / pallas_ssd)
+READINGS_SSD = {
+    "served, 1 x 1000 + 16, seeds 0, 1, 2": [
+        (0.00467, 0.00566, 0.00085, 0.0170, 1.41e-3),
+        (0.00470, 0.00546, 0.00087, 0.0148, 1.44e-3),
+        (0.00505, 0.00560, 0.00091, 0.0201, 1.41e-3)],
+    "served, 1 x 770 + 16 (the edge reading), seed 0": [
+        (0.00491, 0.00649, 0.00086, 0.0166, 1.36e-3)],
+    "--state-not-carried, 1 x 1000 + 16, seed 0": [
+        (0.0577, 0.0639, 0.0106, 0.313, 1.43e-3)],
+    "--conv-tail-dropped, 1 x 770 + 16, seed 0": [
+        (0.1381, 0.4178, 0.0250, 0.619, 1.34e-3)],
+    "--ssd-skip-dropped (D = 0), 1 x 1000 + 16, seeds 0, 1": [
+        (0.3815, 0.4251, 0.0705, 1.676, 1.32e-3),
+        (0.3271, 0.3973, 0.0598, 2.715, 1.20e-3)],
+    # the logits cannot see it (0.00468 against a sound 0.00467), nor can
+    # the state's distance to the reference (0.0212 against 0.0148-0.0201);
+    # the residue does: 0.0 exactly
+    "--bf16-state, 1 x 1000 + 16, seed 0": [
+        (0.00468, 0.00533, 0.00086, 0.0212, 0.0)],
+    # --state-ops (out_rel_err, decode_out_rel_err, state_rel_err), 1000 + 16
+    # in segments of 256, both ops the Pallas calls: bfloat16 operands in
+    # the chunk form's products (STATE_OPS_OUT_TOL_SSD)
+    "--state-ops, seed 0": [(3.3e-3, 1.3e-3, 4.0e-4)],
+    "--state-ops --bf16-state, seed 1": [(4.7e-3, None, 3.3e-3)],
+}
+# (own_token_mean, own_token_max, state_rel_err): the emitted tokens' own
+# log-probabilities, which are all a benchmark run's check sees, and which
+# the family holds to LOGPROB_MEAN_TOL (0.0065, the mean); my chip runs, PR
+# 62, the same shapes.  What lies behind the last state plane shows here or
+# nowhere: the last block's second sublayer does, its routed experts alone
+# do not (the seeded down-projections leave their sum under bfloat16's
+# noise)
+READINGS_SSD_OWN = {
+    "served, 1 x 1000 + 16, seeds 0, 1, 3": [
+        (0.00153, 0.00470, 0.0170), (0.00242, 0.00502, 0.0148),
+        (0.00222, 0.00700, 0.0210)],
+    "--last-experts-dropped 36, seeds 0, 1 (rc 0: not seen)": [
+        (0.00153, 0.00470, 0.0170), (0.00242, 0.00502, 0.0148)],
+    "--last-experts-dropped 7, seed 0 (rc 0: not seen)": [
+        (0.00153, 0.00470, 0.0170)],
+    "--last-mlp-dropped, seeds 0, 1 (rc 1, the state sound)": [
+        (0.0127, 0.0267, 0.0170), (0.0418, 0.0555, 0.0148)],
+    "--state-not-carried, seed 0": [(0.0124, 0.0224, 0.313)],
+    "--conv-tail-dropped, 1 x 770 + 16, seed 0": [(0.0378, 0.1212, 0.619)],
+    "--ssd-skip-dropped, seed 0": [(0.1386, 0.1925, 1.676)],
+    "--logits-scaling-dropped, seed 0": [(9.31, 9.35, 0.0170)],
+}
 # (max_over_vocab_mean, max_over_vocab_max, mean_abs, hc_sinkhorn_residual);
 # my chip runs, PR 60, TPU v5 lite, xing4.0-29b-a4b-bf16 at published
 # widths (2 leading + 5 expert blocks, 64 of 64 experts, whole vocabulary,
@@ -322,6 +394,12 @@ READINGS_STATE = {
 # outputs' and the final state's relative error: between 8.8e-5 (float32,
 # served) and 4.4e-3 (bfloat16), 11 x and 4 x of room
 STATE_OPS_TOL = 1e-3
+# an ssd kind's chunk form multiplies bfloat16 operands (dt x, the masked
+# C B^T, the state rounded for its read: ``ops.ssd``), so its OUTPUTS
+# stand ~3e-3 from the float32 recurrence (READINGS_SSD) where kda's
+# float32 products stand 7e-5; the STATE keeps the limit above, which is
+# what tells a rounded state (4e-4 sound)
+STATE_OPS_OUT_TOL_SSD = 8e-3
 # a model of several residual streams: the served maps' largest |row or
 # column sum - 1| (READINGS_STREAMS): between float32's floor after 20
 # Sinkhorn steps and what bfloat16 maps or one step leave
@@ -414,38 +492,111 @@ def summaries_withheld():
         (hi - lo, n), bool)
 
 
-def state_controls(bf16_state=False, not_carried=False, tail_dropped=False):
+def state_controls(bf16_state=False, not_carried=False, tail_dropped=False,
+                   kind="kda"):
     """The three faults a recurrent state's long reading must show
     (``--bf16-state``, ``--state-not-carried``, ``--conv-tail-dropped``),
-    swapped in for ``ops.kda``'s functions, which the decoder calls by
-    name."""
+    swapped in for the two ops of the state KIND's module (``ops.kda`` or
+    ``ops.ssd``) and for the one convolution both kinds share
+    (``ops.kda.causal_conv``), which the decoder calls by name."""
+    import jax
     import jax.numpy as jnp
 
-    from distributed_inference_demo_tpu.ops import kda
+    from distributed_inference_demo_tpu.ops import kda, ssd
 
-    step, chunk, conv = kda.kda_step, kda.kda_chunk, kda.causal_conv
+    mod = {"kda": kda, "ssd": ssd}[kind]
+    names = (f"{kind}_step", f"{kind}_chunk")
+    step, chunk = (getattr(mod, n) for n in names)
+    conv = kda.causal_conv
     # (an op of its own: the compiler elides a convert to bfloat16 and
     # back, and the first reading of this control read the sound one)
-    import jax
     rounded = lambda st: jax.lax.reduce_precision(st, exponent_bits=8,
                                                   mantissa_bits=7)
     if bf16_state:
-        def kda_step(state, *a, **k):
-            o, state = step(state, *a, **k)
-            return o, rounded(state)
+        def rounding(op):
+            def wrapped(state, *a, **k):
+                o, state = op(state, *a, **k)
+                return o, rounded(state)
+            return wrapped
 
-        def kda_chunk(state, *a, **k):
-            o, state = chunk(state, *a, **k)
-            return o, rounded(state)
-
-        kda.kda_step, kda.kda_chunk = kda_step, kda_chunk
+        setattr(mod, names[0], rounding(step))
+        setattr(mod, names[1], rounding(chunk))
     if not_carried:
-        inner = kda.kda_chunk
-        kda.kda_chunk = lambda state, plane, row, fresh, *a, **k: inner(
-            state, plane, row, jnp.bool_(True), *a, **k)
+        inner = getattr(mod, names[1])
+        setattr(mod, names[1],
+                lambda state, plane, row, fresh, *a, **k: inner(
+                    state, plane, row, jnp.bool_(True), *a, **k))
     if tail_dropped:
-        kda.causal_conv = lambda u, tail, w, ntok: conv(
-            u, jnp.zeros_like(tail) if u.shape[1] > 1 else tail, w, ntok)
+        kda.causal_conv = lambda u, tail, w, ntok, bias=None: conv(
+            u, jnp.zeros_like(tail) if u.shape[1] > 1 else tail, w, ntok,
+            bias)
+
+
+def ssd_ops_reading(cfg, args) -> dict:
+    """``--state-ops`` for an ssd kind: :func:`state_ops_reading`'s reading
+    of ``ops.ssd``'s two ops at the model's widths (one row of a pool;
+    ``--prompt`` tokens in segments of ``--chunk`` through ``ssd_chunk`` at
+    the kind's scan chunk, the last padded with tokens that are not there,
+    then ``--steps`` tokens through ``ssd_step``; the Pallas calls on a
+    TPU) against the recurrence token by token in float32.  Vectors as an
+    ssd block makes them: x, B and C in the model's dtype, ``A`` and ``dt``
+    as seeded."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_inference_demo_tpu.ops import ssd
+
+    kind = cfg.state_kind
+    H, P, N, G = (kind.state_heads, kind.state_head_dim, kind.state_size,
+                  kind.groups)
+    C, n = args.chunk, args.prompt + args.steps
+    ks = jax.random.split(jax.random.PRNGKey(args.seed), 6)
+    act = lambda k, shape: jax.nn.silu(jax.random.normal(k, shape)).astype(
+        cfg.dtype)
+    x, B, Cm = act(ks[0], (n, H, P)), act(ks[1], (n, G, N)), act(
+        ks[2], (n, G, N))
+    A = -jax.random.uniform(ks[3], (H,), jnp.float32, 1.0, 16.0)
+    step = jnp.exp(jax.random.uniform(ks[4], (H,), jnp.float32,
+                                      jnp.log(1e-3), jnp.log(0.1)))
+    dt = jax.nn.softplus(jax.random.normal(ks[5], (n, H))
+                         + step + jnp.log(-jnp.expm1(-step)))
+    state = jnp.zeros((1, 2, H, P, N), jnp.float32)
+    kernel, why = ssd.on_kernel(state.shape, G, min(C, kind.chunk))
+    zero = jnp.int32(0)
+
+    @jax.jit
+    def segment(state, fresh, *v):
+        return ssd.ssd_chunk(state, zero, zero, fresh, *v, A,
+                             chunk=kind.chunk, kernel=kernel)
+
+    @jax.jit
+    def token(state, *v):
+        return ssd.ssd_step(state, zero, jnp.zeros((1,), jnp.int32), *v, A,
+                            jnp.ones((1,), bool), kernel=kernel)
+
+    outs = []
+    for lo in range(0, args.prompt, C):
+        hi = min(args.prompt, lo + C)
+        pad = lambda a, fill=0.0: jnp.pad(
+            a[lo:hi], ((0, C - (hi - lo)),) + ((0, 0),) * (a.ndim - 1),
+            constant_values=fill)
+        o, state = segment(state, jnp.bool_(lo == 0), pad(x, 9.0),
+                           pad(B, 9.0), pad(Cm, 9.0), pad(dt))
+        outs.append(o[:hi - lo].astype(jnp.float32))
+    for t in range(args.prompt, n):
+        o, state = token(state, x[t:t + 1], B[t:t + 1], Cm[t:t + 1],
+                         dt[t:t + 1])
+        outs.append(o)
+    o = jnp.concatenate(outs)
+    with jax.default_matmul_precision("highest"):
+        want_o, want_S = jax.jit(ssd.ssd_recurrence)(
+            jnp.zeros((H, P, N), jnp.float32), x, B, Cm, dt, A)
+    rel = lambda a, b: float(jnp.abs(a - b).max() / jnp.abs(b).max())
+    return {"kernel": kernel, "why_not": why,
+            "out_rel_err": rel(o, want_o),
+            "decode_out_rel_err": rel(o[args.prompt:], want_o[args.prompt:]),
+            "state_rel_err": rel(state[0, 0], want_S),
+            "mean_log_decay": float((dt * A).mean())}
 
 
 def state_ops_reading(cfg, args) -> dict:
@@ -465,7 +616,9 @@ def state_ops_reading(cfg, args) -> dict:
 
     from distributed_inference_demo_tpu.ops import kda
 
-    kind = next(k for k in cfg.period if k.attn == "kda")
+    if cfg.state_kind.attn == "ssd":
+        return ssd_ops_reading(cfg, args)
+    kind = cfg.state_kind
     H, d, C = kind.num_heads, cfg.head_dim, args.chunk
     n = args.prompt + args.steps
     ks = jax.random.split(jax.random.PRNGKey(args.seed), 7)
@@ -712,6 +865,31 @@ def reference_logprobs(cfg, params, ids, n_prompt: int):
                 np.stack(margins) if margins else None)
 
 
+def reference_states(cfg, params, ids):
+    """The reference's recurrent states after ``ids``, one a state plane
+    (the family's ``blocks``: its period layer that also returns them),
+    sampled as the engine's reply samples a row: ``[planes, 4 heads, every
+    eighth row, all]``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import families
+    import reference
+
+    fam, mc = families.load(cfg.family), dataclasses.asdict(cfg)
+    embed, _, _ = fam.equations(mc)
+    layer = reference._make_layer_fn(fam.blocks(mc)[1])
+    states = []
+    with jax.default_matmul_precision("highest"):
+        x = embed(params, jnp.asarray(ids, jnp.int32))
+        for i in range(cfg.num_layers):
+            x, planes = layer(x, params.layers, jnp.int32(i))
+            states += [np.asarray(S) for S in planes]
+    hs = max(1, states[0].shape[0] // 4)
+    return np.stack([S[::hs, ::8] for S in states])
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--model", required=True)
@@ -745,6 +923,21 @@ def main(argv=None) -> int:
     ap.add_argument("--conv-tail-dropped", action="store_true",
                     help="every prefill chunk's convolution starts from "
                          "zeros (a control: must be refused)")
+    ap.add_argument("--ssd-skip-dropped", action="store_true",
+                    help="serve an ssd model with D = 0, the skip dropped "
+                         "(a control: its states must be refused)")
+    ap.add_argument("--last-experts-dropped", type=int, default=0,
+                    help="serve a period model with the first N held "
+                         "experts of its LAST block writing nothing (their "
+                         "down-projections zero): a control behind the "
+                         "last state plane, which only log-probabilities "
+                         "reach")
+    ap.add_argument("--last-mlp-dropped", action="store_true",
+                    help="... and its shared MLP too: the last block's "
+                         "whole second sublayer writes nothing")
+    ap.add_argument("--logits-scaling-dropped", action="store_true",
+                    help="serve with logits_scaling 1 (a control behind "
+                         "the last state plane: must be refused)")
     ap.add_argument("--hc-sinkhorn-iters", type=int, default=None,
                     help="serve a model of several residual streams with "
                          "this many Sinkhorn steps (a control at 1: must be "
@@ -768,21 +961,24 @@ def main(argv=None) -> int:
         bf16_softmax_state()
     if args.summaries_withheld:
         summaries_withheld()
-    if args.bf16_state or args.state_not_carried or args.conv_tail_dropped:
-        state_controls(args.bf16_state, args.state_not_carried,
-                       args.conv_tail_dropped)
     if args.bf16_coef_maps:
         bf16_coef_maps()
     dev = jax.devices()[0]
     cfg = model_config_for(args.model)
+    if args.bf16_state or args.state_not_carried or args.conv_tail_dropped:
+        state_controls(args.bf16_state, args.state_not_carried,
+                       args.conv_tail_dropped, cfg.state_kind.attn)
     t0 = time.monotonic()
     if args.state_ops:
         row = dict(state_ops_reading(cfg, args), model=args.model,
                    platform=dev.platform, device_kind=dev.device_kind,
                    bf16_state=args.bf16_state, prompt=args.prompt,
                    steps=args.steps, chunk=args.chunk, tol=STATE_OPS_TOL)
-        row["ok"] = max(row["out_rel_err"],
-                        row["state_rel_err"]) <= STATE_OPS_TOL
+        tol_out = (STATE_OPS_OUT_TOL_SSD if cfg.state_kind.attn == "ssd"
+                   else STATE_OPS_TOL)
+        row["tol_out"] = tol_out
+        row["ok"] = (row["out_rel_err"] <= tol_out
+                     and row["state_rel_err"] <= STATE_OPS_TOL)
         row["total_s"] = round(time.monotonic() - t0, 1)
         print("STATE_OPS " + json.dumps(row), flush=True)
         return 0 if row["ok"] else 1
@@ -795,7 +991,29 @@ def main(argv=None) -> int:
     if args.hc_sinkhorn_iters is not None:
         served_cfg = served_cfg.replace(
             hc_sinkhorn_iters=args.hc_sinkhorn_iters)
-    toks, served_lp, paths, state = served(served_cfg, params, prompts, args)
+    served_params = params
+    if args.ssd_skip_dropped:
+        served_params = dataclasses.replace(params, layers={
+            k: (jax.numpy.zeros_like(v) if k.split(".")[0] == "D" else v)
+            for k, v in params.layers.items()})
+    if args.logits_scaling_dropped:
+        served_cfg = served_cfg.replace(logits_scaling=1.0)
+    if args.last_mlp_dropped:
+        args.last_experts_dropped = cfg.experts_held[0]
+    if args.last_experts_dropped:
+        # the stacks of the period's last place, its last block
+        last = cfg.period[-1].attn
+        of = lambda leaf: next(                                 # noqa: E731
+            k for k in served_params.layers if k.split(".")[0] == leaf
+            and k.split(".")[1].startswith(last))
+        layers = dict(served_params.layers)
+        layers[of("w_down")] = layers[of("w_down")].at[
+            -1, -1, :args.last_experts_dropped].set(0)
+        if args.last_mlp_dropped:
+            layers[of("ws_down")] = layers[of("ws_down")].at[-1, -1].set(0)
+        served_params = dataclasses.replace(served_params, layers=layers)
+    toks, served_lp, paths, state = served(served_cfg, served_params,
+                                           prompts, args)
     t_served = time.monotonic() - t0
     worst, own, margins, means = [], [], [], []
     for r in range(args.batch):
@@ -822,6 +1040,10 @@ def main(argv=None) -> int:
            "bf16_state": args.bf16_state,
            "state_not_carried": args.state_not_carried,
            "conv_tail_dropped": args.conv_tail_dropped,
+           "ssd_skip_dropped": args.ssd_skip_dropped,
+           "last_experts_dropped": args.last_experts_dropped,
+           "last_mlp_dropped": args.last_mlp_dropped,
+           "logits_scaling_dropped": args.logits_scaling_dropped,
            "hc_sinkhorn_iters": args.hc_sinkhorn_iters,
            "bf16_coef_maps": args.bf16_coef_maps,
            "batch": args.batch,
@@ -842,13 +1064,28 @@ def main(argv=None) -> int:
     if state is not None:
         # what log-probabilities cannot see: a float32 state does not
         # survive a rounding to bfloat16 (``--bf16-state`` reads 0)
-        from families import solar_open2
-        residue = min(min(solar_open2.state_readings(
-            state[:, r], state[:, r])["f32_residue"])
-            for r in range(args.batch))
+        # ... and the served state against the reference's after the same
+        # ids, the largest plane (the family's two limits, as its replay
+        # holds them in a benchmark run)
+        import families
+        fam = families.load(cfg.family)
+        readings = [fam.state_readings(
+            state[:, r], reference_states(
+                cfg, params, np.concatenate([prompts[r], toks[r]])))
+            for r in range(args.batch)]
+        residue = min(min(r["f32_residue"]) for r in readings)
         row["state_f32_residue"] = residue
-        row["state_f32_residue_min"] = solar_open2.STATE_F32_RESIDUE_MIN
-        row["ok"] = row["ok"] and residue >= row["state_f32_residue_min"]
+        row["state_f32_residue_min"] = fam.STATE_F32_RESIDUE_MIN
+        row["state_rel_err"] = max(max(r["rel_err"]) for r in readings)
+        row["state_rel_tol"] = fam.STATE_REL_TOL
+        row["ok"] = (row["ok"] and residue >= row["state_f32_residue_min"]
+                     and row["state_rel_err"] <= row["state_rel_tol"])
+        if hasattr(fam, "LOGPROB_MEAN_TOL"):
+            # ... and its own limit on the emitted tokens' log-probabilities
+            # (a batch of one is the benchmark's canary: one mean a request)
+            row["own_token_mean_tol"] = fam.LOGPROB_MEAN_TOL
+            row["ok"] = (row["ok"] and row["own_token_mean"]
+                         <= fam.LOGPROB_MEAN_TOL)
     if cfg.hc_streams:
         # what log-probabilities see least: how far the served doubly-
         # stochastic maps stand from 1 (``hc_sinkhorn_residual``)
