@@ -648,11 +648,14 @@ def _moe_routed(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
         order = jnp.argsort(flat, stable=True)
         token = order // k
         xs = xt[token]                                    # [T k, H]
-        gate = grouped_matmul(xs, lp["w_gate"], sizes)
-        up = grouped_matmul(xs, lp["w_up"], sizes)
+        # ``routed``: the T k rows spread over all E experts, whatever
+        # share of them is here (the row tile follows the rows a group)
+        gate = grouped_matmul(xs, lp["w_gate"], sizes, routed=E)
+        up = grouped_matmul(xs, lp["w_up"], sizes, routed=E)
         hh = (jax.nn.silu(gate.astype(jnp.float32))
               * up.astype(jnp.float32)).astype(x.dtype)
-        out = grouped_matmul(hh, lp["w_down"], sizes).astype(jnp.float32)
+        out = grouped_matmul(hh, lp["w_down"], sizes,
+                             routed=E).astype(jnp.float32)
         if tp_axis is not None or valid is not None or share:
             # rows of other ranks' experts, and rows that hold no token,
             # belong to no group here

@@ -21,15 +21,38 @@ Two forms of the same arithmetic, picked like the paged attention paths
   ``[tk, tn]`` tile is cast to the rows' dtype in VMEM and whose float32
   per-channel scale (of the visit's expert) multiplies the float32
   accumulator once, at the store, so no bf16 copy of an expert stack is
-  ever written to HBM; row counts that are no multiple of the tile (padded
-  here); a contraction tile that spans ``k`` where it fits, so an
-  expert's matrix is read once however many row tiles its group covers;
-  and a right-hand side that is one layer OF a stack (``stacked.LayerOf``):
+  ever written to HBM; row counts that are no multiple of the tile (the
+  last tile is partial: what it reads past ``m`` belongs to no group, a
+  row's output depends on no other row, and its store stops at ``m``; no
+  padded copy of the rows is made); the tiles and the VMEM the call
+  declares (next paragraph); and a right-hand side that is one layer OF a
+  stack (``stacked.LayerOf``):
   the kernel takes the whole ``[L, E, k, n]`` stack and the layer's index
   as a scalar, because a layer sliced out of the stack for a custom call
   is a copy of it in HBM (128 MiB a projection a layer call at
   olmoe-1b-7b's width: 27 % of the device's busy time before this; my
   chip run, PR 28).
+  **The tiles** (``tiling``, a function of the call's shapes alone).  The
+  grid is ``(column tiles, visits, contraction tiles)`` and the right-hand
+  block of a step is ``(layer, expert of the visit, k_i, n_i)``: with one
+  contraction tile, consecutive visits of one expert name the same block
+  and the pipeline fetches it once; with more, every visit streams the
+  expert's ``[k, tn]`` slice again.  So ``tk`` spans ``k`` wherever the
+  VMEM the call then needs (``vmem_bytes``: two buffers of the right-hand
+  tile, an int8 tile's widened copy, two row tiles, two output blocks, the
+  float32 accumulator and the store's temporaries) and declares
+  (``vmem_limit``: a quarter more, ``CompilerParams.vmem_limit_bytes``)
+  stays inside a quarter of the chip's 128 MiB: 3 to 7 MiB a tile at the
+  benchmark's six expert configurations, all of which read once; mixtral's
+  ``[14336, 4096]`` still splits, four ways.  Until PR 63 the tile was
+  held to 2 MiB, olmoe's int8 ``[2048, 1024]`` to the byte, and the five
+  bf16 configurations cut ``k`` in 2 to 4: a slab's gate / up call took
+  1.2-2.2 x what it takes now (granite's, 182 rows a group: 1,292 -> 570
+  us), a decode call, one visit an expert either way, 1.01-1.09 x for its
+  grid steps (my chip run, PR 63, ``tools/gmm_table.py``; PERF.md section
+  6).  The row tile (``row_tile``) follows the rows a group is expected
+  to hold, ``m`` over the experts routed over.  ``/stats.moe.gmm`` lists
+  every traced call's shape, tiles and limit (``noting_calls``).
 * elsewhere (CPU tests, shapes the kernel's tiling does not cover)
   ``jax.lax.ragged_dot`` over the same sorted rows, the int8 scale gathered
   to the rows.
@@ -41,7 +64,10 @@ HBM, 4 x the packed bytes, a layer call) and takes the bf16 form.
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -55,18 +81,38 @@ PATH_KERNEL = "pallas_gmm"
 PATH_XLA = "ragged_dot"
 
 _LANES = 128
-# bytes of one right-hand tile in VMEM (double-buffered by the pipeline,
-# and an int8 tile is widened once more for the MXU)
-_RHS_TILE_BYTES = 2 << 20
+# a v5e core's VMEM; a call declares what its tiles need of it
+# (``vmem_bytes``) and the rule below keeps that under a quarter, so a
+# call fits beside whatever XLA keeps there around it.  Mosaic gives a
+# call that declares nothing 16 MiB, and no call declares less.
+_VMEM_BYTES = 128 << 20
+_VMEM_BUDGET = _VMEM_BYTES // 4
+_VMEM_DEFAULT = 16 << 20
+# rows a 128 x 128 matrix unit takes in one pass: a group that fills
+# them takes a tile of two passes
+_UNIT_ROWS = 128
+
+# while a program is traced: the table its calls' shapes go to
+_noting = threading.local()
 
 
-def row_tile(m: int) -> int:
-    """Rows of one tile.  Every touched expert is visited with at least
-    one whole tile, so few rows spread over many experts (a decode step:
-    256 rows over 64 experts) take a small one.  On the v5e the call's
-    time barely moves with it (16 to 128 rows at 256, 64 to 512 at 4,096:
-    within 10 %, 64 the best at 4,096; my chip run, PR 28): the experts'
-    matrices bound it."""
+def row_tile(m: int, groups: int) -> int:
+    """Rows of one tile, from the rows a group can be expected to hold
+    (``m`` rows routed over ``groups`` experts).  Every touched expert is
+    visited with at least one whole tile, so few rows spread over many
+    experts (a decode step: 256 rows over 64 experts) take a small one.
+    With an expert's matrix read once a group the call is bound by its
+    bytes, the matrices' and the rows', and its time moves little with
+    the tile: over 32 / 64 / 128 / 256 rows a slab call of 14 to 68 rows a
+    group reads within 6 % from 64 on (128 the least by 1-4 %, 32 the
+    most by up to 20 % where the contraction is short), a decode call
+    within 3 % up to 128 (the table of ``tools/gmm_table.py``, PERF.md
+    section 6, PR 63; PR 28's "within 10 %" was the same finding at
+    olmoe's shapes).  Groups that fill the matrix unit's rows (granite's
+    slab: 182 a group) take 256, the least there by 3-7 %: fewer visits,
+    each of whole passes."""
+    if m >= _UNIT_ROWS * groups:
+        return 2 * _UNIT_ROWS
     if m >= 2048:
         return 64
     return 32 if m >= 32 else 16
@@ -81,14 +127,70 @@ def _divisor_tile(dim: int, cap: int) -> int:
     return best
 
 
-def tiling(m: int, k: int, n: int, rhs_itemsize: int) -> tuple:
+def vmem_bytes(tiles: tuple, rhs_itemsize: int, lhs_itemsize: int) -> int:
+    """What a call's tiles hold in VMEM: the pipeline's two buffers of the
+    right-hand tile and, where it is narrower than the rows, its widened
+    copy; two row tiles; two output blocks; and in float32 the
+    accumulator, the product added to it, and the store's masked block
+    and mask."""
+    tm, tk, tn = tiles
+    rhs = tk * tn * (2 * rhs_itemsize
+                     + (lhs_itemsize if rhs_itemsize < lhs_itemsize else 0))
+    return (rhs + 2 * tm * tk * lhs_itemsize + 2 * tm * tn * lhs_itemsize
+            + 4 * tm * tn * 4)
+
+
+def vmem_limit(tiles: tuple, rhs_itemsize: int, lhs_itemsize: int) -> int:
+    """The limit a call declares: its tiles and a quarter more for what
+    Mosaic keeps beside them, and never less than an undeclared call
+    gets."""
+    need = vmem_bytes(tiles, rhs_itemsize, lhs_itemsize)
+    return max(_VMEM_DEFAULT, need + need // 4)
+
+
+def tiling(m: int, k: int, n: int, rhs_itemsize: int, groups: int,
+           lhs_itemsize: int = 2) -> tuple:
     """``(tm, tk, tn)`` for a kernel call: ``tn`` up to 1,024 columns, and
-    ``tk`` the whole contraction where a ``[tk, tn]`` tile fits
-    ``_RHS_TILE_BYTES`` (an expert's matrix is then read once, not once a
-    row tile)."""
+    ``tk`` the whole contraction wherever the call's limit
+    (``vmem_limit``) then stays inside ``_VMEM_BUDGET``: an expert's
+    ``[k, tn]`` slice is then read once a group, not once a row tile.
+    Where it does not (mixtral's ``[14336, 4096]``) ``tk`` is the largest
+    divisor of ``k`` that does; no other order of the grid reads a matrix
+    once without a float32 ``[m, tn]`` of partial sums."""
+    tm = row_tile(m, groups)
     tn = _divisor_tile(n, 1024)
-    tk = _divisor_tile(k, max(_LANES, _RHS_TILE_BYTES // (tn * rhs_itemsize)))
-    return row_tile(m), tk, tn
+    tk = _LANES
+    for t in range(_LANES, k + 1, _LANES):
+        if k % t == 0 and vmem_limit((tm, t, tn), rhs_itemsize,
+                                     lhs_itemsize) <= _VMEM_BUDGET:
+            tk = t
+    return tm, tk, tn
+
+
+def call_shape(m: int, k: int, n: int, rhs_itemsize: int, groups: int,
+               lhs_itemsize: int = 2) -> dict:
+    """One line of ``/stats.moe.gmm``: a call's shape, the tiles the rule
+    gives it, and what they cost."""
+    tiles = tiling(m, k, n, rhs_itemsize, groups, lhs_itemsize)
+    return {"m": m, "k": k, "n": n, "tiles": list(tiles),
+            "tiles_k": k // tiles[1],
+            "rhs_tile_bytes": tiles[1] * tiles[2] * rhs_itemsize,
+            "vmem_limit_bytes": vmem_limit(tiles, rhs_itemsize,
+                                           lhs_itemsize)}
+
+
+@contextlib.contextmanager
+def noting_calls(table: dict):
+    """While a program is traced inside (on this thread), every call whose
+    shape the kernel covers leaves its ``call_shape`` in ``table``,
+    whatever path it takes: an engine serves the values under
+    ``/stats.moe.gmm``."""
+    before = getattr(_noting, "table", None)
+    _noting.table = table
+    try:
+        yield
+    finally:
+        _noting.table = before
 
 
 def route_grouped_matmul(platform: str, k: int, n: int) -> str:
@@ -145,7 +247,8 @@ def _moe_gmm_call(lhs, rhs, scale, group_sizes, layer, *, tiles,
     n = rhs.shape[3]
     tiles_k, tiles_n = k // tk, n // tn
     (offsets, group_ids, m_tile_ids), visits = make_group_metadata(
-        group_sizes=group_sizes, m=m, tm=tm, start_group=jnp.int32(0),
+        group_sizes=group_sizes, m=-(-m // tm) * tm, tm=tm,
+        start_group=jnp.int32(0),
         num_nonzero_groups=rhs.shape[1], visit_empty_groups=False)
     quantized = scale is not None
 
@@ -176,7 +279,9 @@ def _moe_gmm_call(lhs, rhs, scale, group_sizes, layer, *, tiles,
             grid=(tiles_n, visits, tiles_k),
             scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem_limit(tiles, rhs.dtype.itemsize,
+                                        lhs.dtype.itemsize)),
         interpret=interpret,
         name="moe_gmm",
     )(offsets, group_ids, m_tile_ids, layer, *operands)
@@ -199,8 +304,8 @@ def _ragged(lhs, rhs, scale, group_sizes):
 
 
 def grouped_matmul(lhs: jax.Array, rhs, group_sizes: jax.Array, *,
-                   backend: str = "auto", interpret: bool = False
-                   ) -> jax.Array:
+                   routed: Optional[int] = None, backend: str = "auto",
+                   interpret: bool = False) -> jax.Array:
     """``out[r] = lhs[r] @ rhs[g(r)]`` for rows sorted by group.
 
     ``lhs`` [m, k]; ``rhs`` [E, k, n] as an array of the rows' dtype, a
@@ -208,10 +313,14 @@ def grouped_matmul(lhs: jax.Array, rhs, group_sizes: jax.Array, *,
     :class:`QuantizedArray4` (dequantized whole, see the module), or a
     :class:`LayerOf` a stack of any of those;
     ``group_sizes`` [E] int32 with ``sum <= m``.  Returns [m, n] in the
-    rows' dtype, accumulated in float32.  ``backend``: "auto" (the rule
-    above), "xla", or "pallas" (tests: the kernel in interpret mode)."""
+    rows' dtype, accumulated in float32.  ``routed``: the groups the ``m``
+    rows were routed over, where ``rhs`` holds a share of them and the
+    other groups' rows lie past the last group (default: ``E``); the row
+    tile follows ``m / routed``.  ``backend``: "auto" (the rule above),
+    "xla", or "pallas" (tests: the kernel in interpret mode)."""
     m, k = lhs.shape
     n = rhs.shape[2]
+    routed = routed or rhs.shape[0]
     path = (PATH_XLA if backend == "xla" else PATH_KERNEL
             if backend == "pallas"
             else route_grouped_matmul(jax.default_backend(), k, n))
@@ -226,15 +335,14 @@ def grouped_matmul(lhs: jax.Array, rhs, group_sizes: jax.Array, *,
     scale = None
     if isinstance(rhs, QuantizedArray):
         rhs, scale = rhs.q, rhs.scale
+    shape = (m, k, n, rhs.dtype.itemsize, routed, lhs.dtype.itemsize)
+    table = getattr(_noting, "table", None)
+    if table is not None and route_grouped_matmul("tpu", k, n) == PATH_KERNEL:
+        table[shape] = call_shape(*shape)
     if path == PATH_XLA:
         return _ragged(lhs, rhs, scale, group_sizes)
     if rhs.ndim == 3:                   # a stack of one layer
         rhs = rhs[None]
         scale = None if scale is None else scale[None]
-    tiles = tiling(m, k, n, rhs.dtype.itemsize)
-    pad = -m % tiles[0]
-    if pad:
-        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
-    out = _moe_gmm_call(lhs, rhs, scale, group_sizes.astype(jnp.int32),
-                        layer, tiles=tiles, interpret=interpret)
-    return out[:m] if pad else out
+    return _moe_gmm_call(lhs, rhs, scale, group_sizes.astype(jnp.int32),
+                         layer, tiles=tiling(*shape), interpret=interpret)

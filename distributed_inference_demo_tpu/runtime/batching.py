@@ -686,8 +686,18 @@ class ContinuousBatchingEngine:
         # trace time and served under /stats["attention_paths"]
         from ..ops.paged_attention import AttnPathRecord
         self.attn_paths = AttnPathRecord()
-        fwd_p, bind_tables, pool_sharding = make_paged_forward_seam(
+        seam_fwd, bind_tables, pool_sharding = make_paged_forward_seam(
             cfg, self.spec, mesh, params, bt, record=self.attn_paths)
+        # ... and the shape and tiles of every grouped matmul a program
+        # was traced with (a model with experts), served under
+        # /stats["moe"]["gmm"]
+        from ..ops.grouped_matmul import noting_calls
+        self.gmm_calls: dict = {}
+
+        def fwd_p(*args, **kw):
+            with noting_calls(self.gmm_calls):
+                return seam_fwd(*args, **kw)
+
         from ..ops.quant import alloc_kv_pool
         # a latent-attention model's pool is ``_pk`` alone, one row a
         # token a plane; ``_pv`` then holds no element
@@ -2636,7 +2646,9 @@ class ContinuousBatchingEngine:
                     if cs["mixed_budget_tokens"] else None)}
             out["dispatch_trace"] = self.dispatch_trace.snapshot()
             if self.moe_counters is not None:
-                out["moe"] = self.moe_counters.snapshot()
+                # list(): the scheduler thread may be tracing a variant
+                out["moe"] = dict(self.moe_counters.snapshot(),
+                                  gmm=list(self.gmm_calls.values()))
             if self.loop_counters is not None:
                 out["loop"] = self.loop_counters.snapshot()
         if self.hc_stats is not None:

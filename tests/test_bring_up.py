@@ -702,26 +702,45 @@ def test_flash_kernel_with_alibi_gets_through_mosaic(v5e):
         S((), jnp.int32), S((), jnp.int32), S((16,), jnp.float32))
 
 
-@pytest.mark.parametrize("rows", [256, 4096])
-@pytest.mark.parametrize("quant", ["int8", "bf16"])
-def test_grouped_matmul_gets_through_mosaic(v5e, rows, quant):
+# layers, experts here, experts routed over, hidden, intermediate; the
+# token-expert rows of the cell's decode step and of its slab
+_GMM_WIDTHS = {"olmoe": (16, 64, 64, 2048, 1024),
+               "granite": (2, 36, 72, 4096, 768),
+               "xing": (2, 64, 64, 3584, 1024),
+               "solar": (2, 40, 320, 4096, 1280)}
+_GMM_ROWS = {"granite": (320, 13120), "xing": (64, 2112),
+             "solar": (512, 4608)}
+
+
+@pytest.mark.parametrize("model, rows, quant", [
+    *(("olmoe", rows, quant) for quant in ("int8", "bf16")
+      for rows in (256, 4096)),
+    *((model, rows, "bf16") for model, both in _GMM_ROWS.items()
+      for rows in both)])
+def test_grouped_matmul_gets_through_mosaic(v5e, model, rows, quant):
     """The experts' grouped matmul at the olmoe configuration's two
     shapes (256 token-expert rows: a decode step at 32 slots; 4,096: the
-    512-token slab), both projections, the layer picked out of the whole
-    16-layer stack by index.  The compiler's temporaries stay under a
+    512-token slab) and at granite's, xing's and solar's widths and rows
+    (granite's slab, 13,120 rows, has groups that fill the widest row
+    tile and is no multiple of it), both
+    projections, the layer picked out of the whole stack by index.  The
+    contraction is one tile of 3 to 7 MiB and Mosaic accepts it under the
+    limit the call declares.  The compiler's temporaries stay under a
     MiB: no copy of a 128 MiB expert stack (sliced out, or widened from
-    int8) is written to HBM."""
+    int8) is written to HBM, and no padded copy of the rows."""
     from distributed_inference_demo_tpu.ops.grouped_matmul import (
-        LayerOf, grouped_matmul)
+        LayerOf, grouped_matmul, tiling)
     from distributed_inference_demo_tpu.ops.quant import QuantizedArray
     S = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=v5e)  # noqa: E731
-    L, E = 16, 64
-    for k, n in ((2048, 1024), (1024, 2048)):
+    L, E, routed, H, I = _GMM_WIDTHS[model]
+    for k, n in ((H, I), (I, H)):
+        assert tiling(rows, k, n, 1 if quant == "int8" else 2, routed)[1] == k
         stack = (QuantizedArray(q=S((L, E, k, n), jnp.int8),
                                 scale=S((L, E, 1, n), jnp.float32))
                  if quant == "int8" else S((L, E, k, n), jnp.bfloat16))
         compiled = jax.jit(
             lambda x, w, g, i: grouped_matmul(x, LayerOf(w, i), g,
+                                              routed=routed,
                                               backend="pallas")
         ).lower(S((rows, k), jnp.bfloat16), stack, S((E,), jnp.int32),
                 S((), jnp.int32)).compile()

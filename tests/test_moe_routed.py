@@ -198,14 +198,118 @@ def test_kernel_form_equals_the_xla_form(quant):
                                        np.asarray(want)[:n], atol=1e-4)
 
 
-def test_grouped_matmul_routing_and_tiles():
-    assert route_grouped_matmul("tpu", 2048, 1024) == "pallas_gmm"
-    assert route_grouped_matmul("cpu", 2048, 1024) == "ragged_dot"
-    assert route_grouped_matmul("tpu", 64, 32) == "ragged_dot"
-    # the cell's two shapes: a whole int8 expert matrix is one tile
-    assert tiling(256, 2048, 1024, 1) == (32, 2048, 1024)
-    assert tiling(4096, 1024, 2048, 1) == (64, 1024, 1024)
-    assert tiling(8, 14336, 4096, 2)[1:] == (1024, 1024)
+def _exact(rs, shape, top, keep=1.0):
+    """Small whole numbers (a share ``keep`` of them, the rest zero):
+    their products and sums are exact in float32 and, kept under 256, in
+    bfloat16, so two orders of the same sum agree to the bit."""
+    return rs.randint(-top, top + 1, shape) * (rs.rand(*shape) < keep)
+
+
+@pytest.mark.parametrize("quant, k", [("bf16", 2048), ("int8", 4096)])
+def test_kernel_form_reads_the_contraction_in_one_tile(quant, k):
+    """bf16 rows on a bf16 and an int8 stack at shapes whose contraction
+    was cut in two before PR 63 (a right-hand tile of 2 MiB), the layer
+    picked out of the stack: groups that span several row tiles, an empty
+    group, rows past the last group that no group holds.  The kernel
+    (interpreted) equals ``ragged_dot`` on the rows the groups hold."""
+    rs = np.random.RandomState(5)
+    L, E, n, m = 2, 4, 1024, 160
+    item = 1 if quant == "int8" else 2
+    assert tiling(m, k, n, item, E) == (32, k, n)
+    assert k * n * item > 2 << 20                # two tiles before
+    if quant == "int8":
+        rhs = QuantizedArray(
+            q=jnp.asarray(_exact(rs, (L, E, k, n), 3), jnp.int8),
+            scale=jnp.asarray(2.0 ** -rs.randint(1, 4, (L, E, 1, n)),
+                              jnp.float32))
+    else:
+        rhs = jnp.asarray(_exact(rs, (L, E, k, n), 1), jnp.bfloat16)
+    x = jnp.asarray(_exact(rs, (m, k), 1, keep=1 / 16), jnp.bfloat16)
+    gs = jnp.asarray([70, 0, 50, 20], jnp.int32)     # 20 rows in no group
+    for layer in (0, 1):
+        want = grouped_matmul(x, jax.tree.map(lambda a: a[layer], rhs), gs,
+                              backend="xla")
+        got = grouped_matmul(x, LayerOf(rhs, jnp.int32(layer)), gs,
+                             backend="pallas", interpret=True)
+        assert got.shape == want.shape == (m, n) and got.dtype == x.dtype
+        assert float(jnp.abs(want[:140].astype(jnp.float32)).max()) > 8
+        np.testing.assert_allclose(
+            np.asarray(got[:140], np.float32),
+            np.asarray(want[:140], np.float32), atol=1e-4)
+
+
+def _table_tool():
+    """``tools/gmm_table.py`` as a module: it reads the configurations
+    with experts, and their two calls' rows, from the benchmark's files."""
+    import importlib.util
+    from pathlib import Path
+    spec = importlib.util.spec_from_file_location(
+        "gmm_table", Path(__file__).resolve().parent.parent / "tools"
+        / "gmm_table.py")
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    return tool
+
+
+def _expert_shapes():
+    """``(m, k, n, itemsize, routed)``: gate / up and down of every
+    benchmark configuration with experts, at its slab's and its decode
+    step's rows."""
+    for c in _table_tool().expert_configs([]):
+        for call, tokens in c["tokens"].items():
+            for proj, (k, n) in (("gate_up", (c["hidden"], c["inter"])),
+                                 ("down", (c["inter"], c["hidden"]))):
+                yield pytest.param(
+                    tokens * c["top_k"], k, n, 1 if c["int8"] else 2,
+                    c["routed"], id=f"{c['name']}-{call}-{proj}")
+
+
+@pytest.mark.parametrize("m, k, n, item, routed", _expert_shapes())
+def test_a_benchmark_configuration_s_matrix_is_read_once_a_group(
+        m, k, n, item, routed):
+    """Every expert shape the benchmark holds: the contraction is one
+    tile, and the limit the call declares covers its tiles and stays
+    under the budget, a quarter of the chip's VMEM."""
+    from distributed_inference_demo_tpu.ops import grouped_matmul as gmm
+    tm, tk, tn = tiles = tiling(m, k, n, item, routed)
+    line = gmm.call_shape(m, k, n, item, routed)
+    assert tk == k and line["tiles_k"] == 1 and n % tn == 0
+    assert line["tiles"] == list(tiles)
+    assert line["rhs_tile_bytes"] == k * tn * item
+    held = (2 * k * tn * item + (k * tn * 2 if item == 1 else 0)
+            + 2 * tm * k * 2 + 2 * tm * tn * 2 + tm * tn * 4)
+    assert held < gmm.vmem_bytes(tiles, item, 2) \
+        < line["vmem_limit_bytes"] <= gmm._VMEM_BUDGET < gmm._VMEM_BYTES
+    # the row tile: two passes of the matrix unit where a group fills
+    # one, what the call's rows gave before PR 63 elsewhere
+    assert tm == (256 if m >= 128 * routed else 64 if m >= 2048 else 32)
+
+
+@pytest.mark.parametrize("case", [
+    "route-tpu", "route-cpu", "route-narrow", "olmoe-decode", "olmoe-down",
+    "mixtral-down", "mixtral-up"])
+def test_grouped_matmul_routing_and_tiles(case):
+    from distributed_inference_demo_tpu.ops import grouped_matmul as gmm
+    if case == "route-tpu":
+        assert route_grouped_matmul("tpu", 2048, 1024) == "pallas_gmm"
+    elif case == "route-cpu":
+        assert route_grouped_matmul("cpu", 2048, 1024) == "ragged_dot"
+    elif case == "route-narrow":
+        assert route_grouped_matmul("tpu", 64, 32) == "ragged_dot"
+    # the olmoe cell's two shapes: a whole int8 expert matrix is one tile
+    elif case == "olmoe-decode":
+        assert tiling(256, 2048, 1024, 1, 64) == (32, 2048, 1024)
+    elif case == "olmoe-down":
+        assert tiling(4096, 1024, 2048, 1, 64) == (64, 1024, 1024)
+    elif case == "mixtral-down":
+        # 28 MiB a [14336, 1024] tile: the contraction still splits, into
+        # the largest tiles the budget holds
+        tiles = tiling(8, 14336, 4096, 2, 8)
+        assert tiles == (16, 3584, 1024)
+        assert gmm.vmem_limit(tiles, 2, 2) <= gmm._VMEM_BUDGET \
+            < gmm.vmem_limit((16, 7168, 1024), 2, 2)
+    else:
+        assert tiling(8, 4096, 14336, 2, 8) == (16, 4096, 1024)
 
 
 # ----------------------------------------------------------- whole models
@@ -373,6 +477,7 @@ def test_moe_counters_sum_to_tokens_times_k_times_layers(olmoe_run):
     cfg, _, prompts, outs, _, st = olmoe_run
     k, L, E = cfg.experts_per_token, cfg.num_layers, cfg.num_experts
     moe, dt = st["moe"], st["dispatch_trace"]
+    assert moe["gmm"] == []         # 64 x 32: no shape the kernel covers
     assert dt["fields"] == list(DISPATCH_FIELDS + MOE_DISPATCH_FIELDS
                                 + DISPATCH_LAST_FIELDS)
     recs = [dict(zip(dt["fields"], r)) for r in dt["recent"]]
@@ -481,6 +586,57 @@ def test_dispatch_trace_extra_fields_and_counters():
         "expert_rows": [4, 1, 5, 0]}
     c.reset()
     assert c.snapshot()["rows"] == 0
+
+
+def test_stats_list_the_tiles_of_every_grouped_matmul_traced():
+    """``/stats.moe.gmm``: one line a distinct call shape the engine's
+    programs were traced with (a slab of one to budget // chunk segments
+    with the rows' first step, and a decode step; gate / up and down), each the
+    rule's output for it, whatever path ran (here ``ragged_dot``); the
+    olmoe-test engines above, whose widths fill no lane, list none."""
+    from distributed_inference_demo_tpu.ops.grouped_matmul import call_shape
+    cfg = OLMOE.replace(hidden_size=128, intermediate_size=256, num_layers=1)
+    params = init_full_params(jax.random.PRNGKey(0), cfg)
+    with _engine(cfg, params) as eng:
+        eng.submit(np.arange(1, 38, dtype=np.int32), 6).wait(timeout=300)
+        lines = eng.stats()["moe"]["gmm"]
+    k, E, chunk, slots = cfg.experts_per_token, cfg.num_experts, 16, 4
+    want = [call_shape(tokens * k, a, b, 4, E, 4)
+            for tokens in (*(r * chunk + slots for r in (1, 2, 3)), slots)
+            for a, b in ((128, 256), (256, 128))]
+    assert sorted(lines, key=str) == sorted(want, key=str)
+    assert all(line["tiles_k"] == 1 and set(line) == {
+        "m", "k", "n", "tiles", "tiles_k", "rhs_tile_bytes",
+        "vmem_limit_bytes"} for line in lines)
+
+
+def test_the_table_tool_reads_six_configurations_and_the_rule_before():
+    """``tools/gmm_table.py``: the configurations with experts and their
+    two calls' rows come from the benchmark's files, and its ``before``
+    column is the rule as it stood before PR 63 (a 2 MiB right-hand
+    tile): one contraction tile at olmoe's int8 widths, 2 to 4 at the
+    five bf16 configurations', two for solar's down projection."""
+    tool = _table_tool()
+    before = {}
+    for c in tool.expert_configs([]):
+        m, item = c["tokens"]["slab"] * c["top_k"], 1 if c["int8"] else 2
+        before[c["name"]] = (
+            tool.tiles_before(m, c["hidden"], c["inter"], item),
+            c["inter"] // tool.tiles_before(m, c["inter"], c["hidden"],
+                                            item)[1])
+    assert before == {
+        "olmoe-1b-7b-int8": ((64, 2048, 1024), 1),
+        "kanana-2-30b-a3b-bf16": ((64, 1024, 768), 1),
+        "laguna-s-2.1-bf16-ep4": ((64, 1024, 1024), 1),
+        "xing4.0-29b-a4b-bf16": ((64, 896, 1024), 1),
+        "granite-4.0-h-small-bf16-ep2": ((64, 1024, 768), 1),
+        "solar-open2-250b-bf16-ep8": ((64, 1024, 640), 2)}
+    granite = next(tool.expert_configs(["granite-4.0-h-small-bf16-ep2"]))
+    assert granite["tokens"] == {"slab": 1312, "decode": 32}
+    assert (granite["held"], granite["routed"]) == (36, 72)
+    sizes = tool.group_sizes(granite, 1312, 0, even=False)
+    assert sizes.shape == (36,) and 6000 < sizes.sum() < 7100
+    assert (tool.group_sizes(granite, 1312, 0, even=True) == 182).all()
 
 
 def test_model_parity_tool_at_toy_size():

@@ -1,0 +1,207 @@
+#!/usr/bin/env python
+"""The experts' grouped matmul alone, on the chip: us a call at each
+benchmark configuration's slab and decode shapes, for the tiles the rule
+gave before PR 63 and for a contraction tile that spans ``k`` at four row
+tiles.  It is the table ``ops/grouped_matmul.tiling`` was chosen from
+(PERF.md section 6, PR 63); run it again when a configuration brings a
+new shape or the rule changes:
+
+    python tools/gmm_table.py                      # every expert configuration
+    python tools/gmm_table.py --config granite-4.0-h-small-bf16-ep2 --even
+
+Shapes come from ``benchmark/configs/*.json``: ``hidden_size`` x
+``intermediate_size`` (gate and up; down is the transpose), the experts
+held here and routed over, and the rows of the two calls a dispatch makes
+from the serve flags: a slab's ``(segments x chunk + slots) x top-k``
+token-expert rows and a decode step's ``slots x top-k``.  Group sizes come
+from a seeded router that spreads evenly in expectation (top-k of uniform
+scores a token); ``--even`` gives every group the same rows.  The stack
+holds three layers and a call takes the next one, as the scan over layers
+does.
+
+A time is the least of three runs of ``--reps`` calls in one jitted scan,
+host clock around ``block_until_ready``.  ``hbm`` / ``mxu`` are the
+touched experts' matrices over 819 GB/s and the groups' rows x 2 k n over
+197 TFLOP/s: the call's bound is the larger.  ``err`` is the rule's call
+against ``ragged_dot`` on the same rows, max |difference| over max
+|value|.  One JSON line a row goes to ``chiprun_out/gmm_table.jsonl``.
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import sys
+import time
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(ROOT))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from distributed_inference_demo_tpu.ops import grouped_matmul as gmm  # noqa: E402
+from distributed_inference_demo_tpu.telemetry.profiling import (  # noqa: E402
+    DEVICE_PEAKS)
+
+LAYERS = 3
+ROW_TILES = (32, 64, 128, 256)
+# the floors are the v5e's whatever runs the script (a rehearsal has none)
+PEAKS = DEVICE_PEAKS["TPU v5 lite"]
+
+
+def tiles_before(m: int, k: int, n: int, itemsize: int) -> tuple:
+    """The rule as it stood before PR 63: a row tile by ``m`` alone and a
+    right-hand tile of at most 2 MiB."""
+    tm = 64 if m >= 2048 else 32 if m >= 32 else 16
+    tn = gmm._divisor_tile(n, 1024)
+    return tm, gmm._divisor_tile(k, max(128, (2 << 20) // (tn * itemsize))), tn
+
+
+def expert_configs(names):
+    for path in sorted((ROOT / "benchmark" / "configs").glob("*.json")):
+        conf = json.loads(path.read_text())
+        mc = conf["model_config"]
+        if not mc.get("num_experts") or (names and conf["name"] not in names):
+            continue
+        flags = conf["serve_flags"]
+        flag = lambda name: int(flags[flags.index(name) + 1])  # noqa: E731
+        slots, chunk = flag("--batch-slots"), flag("--prefill-chunk")
+        segments = (flag("--mixed-token-budget")
+                    - slots * flag("--decode-block")) // chunk
+        held, first = mc.get("experts_held") or (mc["num_experts"], 0)
+        yield dict(
+            name=conf["name"], hidden=mc["hidden_size"],
+            inter=mc["intermediate_size"], routed=mc["num_experts"],
+            held=held, first=first, top_k=mc["experts_per_token"],
+            int8=conf["name"].endswith("int8"),
+            tokens={"slab": segments * chunk + slots, "decode": slots})
+
+
+def group_sizes(c: dict, tokens: int, seed: int, even: bool) -> np.ndarray:
+    if even:
+        rows = tokens * c["top_k"] * c["held"] // c["routed"]
+        return np.full(c["held"], rows // c["held"], np.int32)
+    scores = np.random.RandomState(seed).rand(tokens, c["routed"])
+    picked = np.argsort(-scores, axis=1)[:, :c["top_k"]]
+    rows = np.bincount(picked.ravel(), minlength=c["routed"])
+    return rows[c["first"]:c["first"] + c["held"]].astype(np.int32)
+
+
+def stack(key, c: dict, k: int, n: int):
+    shape = (LAYERS, c["held"], k, n)
+    if c["int8"]:
+        q = jax.random.randint(key, shape, -127, 128, jnp.int8)
+        return q, jnp.full(shape[:2] + (1, n), k ** -0.5 / 127, jnp.float32)
+    w = jax.random.normal(key, shape, jnp.bfloat16) * k ** -0.5
+    return w.astype(jnp.bfloat16), None
+
+
+def us_a_call(lhs, rhs, scale, sizes, tiles, reps: int,
+              interpret: bool) -> float:
+    @jax.jit
+    def calls(lhs, rhs, scale, sizes):
+        def one(carry, i):
+            out = gmm._moe_gmm_call(lhs, rhs, scale, sizes,
+                                    (i % LAYERS)[None], tiles=tiles,
+                                    interpret=interpret)
+            return carry + out[0, 0].astype(jnp.float32), None
+        return jax.lax.scan(one, jnp.float32(0),
+                            jnp.arange(reps, dtype=jnp.int32))[0]
+
+    calls(lhs, rhs, scale, sizes).block_until_ready()
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        calls(lhs, rhs, scale, sizes).block_until_ready()
+        best = min(best, time.perf_counter() - t0)
+    return 1e6 * best / reps
+
+
+def rows_of(c: dict, args):
+    key = jax.random.PRNGKey(args.seed)
+    for proj, (k, n) in (("gate_up", (c["hidden"], c["inter"])),
+                         ("down", (c["inter"], c["hidden"]))):
+        rhs, scale = stack(key, c, k, n)
+        itemsize = rhs.dtype.itemsize
+        for call, tokens in c["tokens"].items():
+            m = tokens * c["top_k"]
+            sizes_np = group_sizes(c, tokens, args.seed, args.even)
+            sizes = jnp.asarray(sizes_np)
+            lhs = jax.random.normal(key, (m, k), jnp.bfloat16)
+            touched, in_groups = int((sizes_np > 0).sum()), int(sizes_np.sum())
+            rule = gmm.tiling(m, k, n, itemsize, c["routed"])
+            row = dict(
+                config=c["name"], call=call, proj=proj, m=m, k=k, n=n,
+                rows_in_groups=in_groups, touched=touched,
+                rows_a_group=round(in_groups / max(touched, 1), 1),
+                hbm_us=round(1e-3 * touched * k * n * itemsize / PEAKS.hbm_gbs, 1),
+                mxu_us=round(1e-6 * 2 * in_groups * k * n / PEAKS.bf16_tflops, 1),
+                rule=list(rule), before=list(tiles_before(m, k, n, itemsize)))
+            timed = lambda tiles: round(us_a_call(  # noqa: E731
+                lhs, rhs, scale, sizes, tiles, args.reps, args.rehearse), 1)
+            row["before_us"] = timed(tuple(row["before"]))
+            for tm in ROW_TILES:
+                row[f"k_tm{tm}_us"] = timed((tm, k, rule[2]))
+            got = gmm._moe_gmm_call(lhs, rhs, scale, sizes,
+                                    jnp.zeros((1,), jnp.int32), tiles=rule,
+                                    interpret=args.rehearse)
+            want = gmm._ragged(lhs, rhs[0], None if scale is None
+                               else scale[0], sizes)
+            diff = jnp.abs(got[:in_groups].astype(jnp.float32)
+                           - want[:in_groups].astype(jnp.float32))
+            row["err"] = float(diff.max() / jnp.abs(want[:in_groups]).max())
+            yield row
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--config", action="append", default=[],
+                    help="a benchmark configuration's name (default: every "
+                         "one with experts)")
+    ap.add_argument("--even", action="store_true",
+                    help="every group the same rows, not a seeded router's")
+    ap.add_argument("--reps", type=int, default=32)
+    ap.add_argument("--rehearse", action="store_true",
+                    help="off the chip: the kernel interpreted at 48 and 8 "
+                         "tokens a call, to see the script run; its times "
+                         "mean nothing")
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--out", default=str(ROOT / "chiprun_out"
+                                         / "gmm_table.jsonl"))
+    args = ap.parse_args()
+    dev = jax.devices()[0]
+    if dev.platform != "tpu" and not args.rehearse:
+        ap.error(f"no chip here ({dev.platform}): a time comes from the "
+                 "chip (--rehearse runs the script without one)")
+    print(f"# device {dev.platform} {dev.device_kind}; groups "
+          f"{'even' if args.even else f'seeded router, seed {args.seed}'}; "
+          f"us a call, least of 3 x {args.reps}")
+    head = ["config", "call", "proj", "m", "rows/group", "hbm", "mxu",
+            "before", "us", *(f"k,tm={tm}" for tm in ROW_TILES), "rule",
+            "err"]
+    print("| " + " | ".join(head) + " |")
+    print("|" + "---|" * len(head))
+    Path(args.out).parent.mkdir(parents=True, exist_ok=True)
+    with open(args.out, "a") as out:
+        for c in expert_configs(args.config):
+            if args.rehearse:
+                c["tokens"] = {"slab": 48, "decode": 8}
+            for row in rows_of(c, args):
+                row.update(device=dev.device_kind, even=args.even,
+                           seed=args.seed, rehearsal=args.rehearse)
+                out.write(json.dumps(row) + "\n")
+                out.flush()
+                cells = [row["config"], row["call"], row["proj"], row["m"],
+                         row["rows_a_group"], row["hbm_us"], row["mxu_us"],
+                         "x".join(map(str, row["before"])), row["before_us"],
+                         *(row[f"k_tm{tm}_us"] for tm in ROW_TILES),
+                         "x".join(map(str, row["rule"])),
+                         f"{row['err']:.1e}"]
+                print("| " + " | ".join(map(str, cells)) + " |", flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
