@@ -10,7 +10,10 @@ initializes, so a machine that has a TPU still runs the tests on 8 virtual
 CPU devices.
 """
 
+import json
+import math
 import os
+from pathlib import Path
 
 flags = os.environ.get("XLA_FLAGS", "")
 if "host_platform_device_count" not in flags:
@@ -25,13 +28,39 @@ jax.config.update("jax_platforms", "cpu")
 import pytest  # noqa: E402
 
 
+# The deal of tier-1's files over the driver's workers (docs/DESIGN.md, "How
+# tier-1 is dealt"): seconds of case time a file took in a whole run, made
+# by ``tools/tier1_deal.py --write``.  It tunes the order and gates nothing.
+TIER1_SECONDS = json.loads(
+    (Path(__file__).parent / "data" / "tier1_seconds.json").read_text())
+
+
+def deal_key(file_name):
+    """Longest file first, and a file the record does not know before
+    them all: a stale record costs some evenness and never a tail."""
+    return -TIER1_SECONDS.get(file_name, math.inf)
+
+
 def pytest_configure(config):
+    # ``--dist loadfile`` hands the next file to the worker that runs dry,
+    # in the order the files were collected, unless xdist first ranks them
+    # by how many cases they hold, which is what it does by default (and
+    # this suite's longest files hold the fewest).  A single-process run
+    # has no such option.
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
     config.addinivalue_line(
         "markers", "slow: long-running multi-process integration test")
     config.addinivalue_line(
         "markers", "quick: fast-lane smoke set (~2 min): one cheap, "
         "representative test per subsystem, for the edit-verify loop "
         "(`pytest -m quick`); the full suite stays the merge gate")
+
+
+def pytest_collection_modifyitems(items):
+    # stable: the order inside a file stays, and every worker computes the
+    # same order, which xdist requires
+    items.sort(key=lambda item: deal_key(item.path.name))
 
 
 @pytest.fixture(scope="session")

@@ -765,14 +765,29 @@ def _wait_for(cond, timeout=60.0, what="condition"):
     raise AssertionError(f"timed out waiting for {what}")
 
 
+def _hold_the_only_slot(eng):
+    """A request of 1,000 tokens that keeps the engine's one slot until it
+    is cancelled (PR 32; one of 56 can end between a poll and a call),
+    returned once it decodes AND is no longer counted as waiting: the
+    intake takes a request off its pending list a moment after it filled
+    the slot, and a submit in between is shed at depth 1 (seen once under
+    six workers, PR 64)."""
+    blocker = eng.submit(np.arange(8, dtype=np.int32), 1000)
+
+    def holds():
+        st = eng.stats()
+        return st["active_slots"] == 1 and st["queue_depth"] == 0
+
+    _wait_for(holds, what="the blocker to take the slot")
+    return blocker
+
+
 def test_admission_queue_sheds_at_depth():
     from distributed_inference_demo_tpu.runtime.overload import (
         SchedulerOverloaded)
-    with _tiny_batching_engine(max_queue_depth=1) as eng:
+    with _tiny_batching_engine(max_seq=1100, max_queue_depth=1) as eng:
         prompt = np.arange(8, dtype=np.int32)
-        r1 = eng.submit(prompt, 56)        # takes the only slot
-        _wait_for(lambda: eng.stats()["active_slots"] == 1,
-                  what="r1 to take the slot")
+        r1 = _hold_the_only_slot(eng)
         r2 = eng.submit(prompt, 4)         # queued (depth 1)
         with pytest.raises(SchedulerOverloaded) as exc:
             eng.submit(prompt, 4)          # past the limit: shed
@@ -787,11 +802,9 @@ def test_multirow_generate_shed_cancels_admitted_rows():
     not leave orphan rows burning slots while the server sheds load."""
     from distributed_inference_demo_tpu.runtime.overload import (
         SchedulerOverloaded)
-    with _tiny_batching_engine(max_queue_depth=1) as eng:
+    with _tiny_batching_engine(max_seq=1100, max_queue_depth=1) as eng:
         prompt = np.arange(8, dtype=np.int32)
-        r1 = eng.submit(prompt, 56)        # takes the only slot
-        _wait_for(lambda: eng.stats()["active_slots"] == 1,
-                  what="r1 to take the slot")
+        r1 = _hold_the_only_slot(eng)
         with pytest.raises(SchedulerOverloaded):
             eng.generate(np.stack([prompt, prompt]), 4)
         r1.cancel()
@@ -808,12 +821,10 @@ def test_http_generate_returns_503_with_retry_after():
         srv.start()
         try:
             prompt = list(range(8))
-            # r1 holds the only slot until it is cancelled below (1,000
-            # tokens, as the 504 test's blocker), so r2 fills the queue
-            # and stays there whenever the HTTP request lands
-            r1 = eng.submit(np.arange(8, dtype=np.int32), 1000)
-            _wait_for(lambda: eng.stats()["active_slots"] == 1,
-                      what="r1 to take the slot")
+            # r1 holds the only slot until it is cancelled below, so r2
+            # fills the queue and stays there whenever the HTTP request
+            # lands
+            r1 = _hold_the_only_slot(eng)
             r2 = eng.submit(np.arange(8, dtype=np.int32), 4)
             conn = http.client.HTTPConnection(srv.host, srv.port,
                                               timeout=30)

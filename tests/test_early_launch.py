@@ -257,10 +257,10 @@ def eva_params():
 _SEEN = {}      # the dense path's tokens, and the first order's
 
 
-def _dense_once(params, prompt, new):
-    key = (tuple(prompt), new)
+def _dense_once(params, prompt, out):
+    key = (tuple(prompt), tuple(out))
     if key not in _SEEN:
-        _SEEN[key] = laguna._dense_greedy(params, list(prompt), new)
+        _SEEN[key] = laguna._dense_greedy(params, list(prompt), out)
     return _SEEN[key]
 
 
@@ -290,12 +290,13 @@ def test_full_slabs_enqueued_behind_their_predecessors_free_pages_safely(
         st = eng.stats()
         assert eng._wmgr.used_blocks == 0 and eng._window_reserved == 0
         assert eng.kv_cache.used_blocks == 0
-    # (the dense path steps eagerly: a few tokens each, once for both
+    # (the dense path runs eagerly: a few tokens each, once for both
     # orders)
-    assert kept[:8] == _dense_once(laguna_params, keeper, 8)
+    assert len(kept) == 40 and kept[:8] == _dense_once(
+        laguna_params, keeper, kept[:8])
     assert _SEEN.setdefault("kept", kept) == kept
     for p, out in zip(prompts, outs):
-        assert out == _dense_once(laguna_params, p, 4)
+        assert len(out) == 4 and out == _dense_once(laguna_params, p, out)
     dt = st["dispatch_trace"]
     assert st["kvcache"]["kinds"]["window"]["pages_returned"] > 0
     assert dt["ahead_hits"] + sum(dt["ahead_misses"].values()) + dt[

@@ -7,14 +7,11 @@ the CPU.
 3 a token, 1 shared, latent rank 32).  The oracle is the benchmark's plain
 float32 reference (``benchmark/families/deepseek_v3.py`` through
 ``benchmark/reference.py``): the DECOMPRESSED form, no cache, every expert
-computed for every row, no line of the program.  And every other model is
-what it was: ``mixed_step`` of the four older toy families lowers to the
-parent's program character for character.
+computed for every row, no line of the program.  (That every other model
+is what it was is ``tests/test_program_pins.py``'s to hold.)
 """
 
 import dataclasses
-import hashlib
-import json
 import sys
 import time
 from pathlib import Path
@@ -50,7 +47,6 @@ from distributed_inference_demo_tpu.parallel.tensor import (  # noqa: E402
     make_paged_forward_seam)
 from distributed_inference_demo_tpu.runtime.batching import (  # noqa: E402
     ContinuousBatchingEngine)
-from tests.test_mixed_batching import abstract_mixed_call  # noqa: E402
 
 CFG = get_model_config("kanana-test")
 L, LEAD = CFG.num_layers, CFG.lead_dense_layers
@@ -58,8 +54,6 @@ WIDTH = CFG.kv_page_shape[1]
 GREEDY = SamplingParams(temperature=0.0)
 FIELDS = dataclasses.asdict(CFG)        # what the reference is given
 SPEC = StageSpec(0, 1, 0, L)
-PARENT = json.loads((ROOT / "tests" / "data" / "mixed_step_hlo_pr42.json")
-                    .read_text())
 
 
 def _seeded(cfg=CFG):
@@ -664,30 +658,3 @@ def test_serve_chain_refuses_a_latent_model_in_a_sentence(capsys):
                      "w1@127.0.0.1:1", "--device-id", "h"]) == 1
     assert LATENT in capsys.readouterr().err
 
-
-# ----------------------------------------- every other model is what it was
-
-def _parent_engine(model):
-    cfg = get_model_config(model)
-    return ContinuousBatchingEngine(
-        cfg, init_full_params(jax.random.PRNGKey(0), cfg), max_seq=96,
-        max_batch=4, sampling=GREEDY, kv_block_tokens=8, prefill_chunk=8,
-        decode_block=4, mixed_token_budget=24)
-
-
-@pytest.mark.parametrize("slab", [False, True], ids=["decode", "slab"])
-@pytest.mark.parametrize("model", ["qwen2-test", "bloom-test", "olmoe-test",
-                                   "ouro-test"])
-def test_older_families_lower_to_the_parent_s_program(model, slab):
-    """The pre-optimisation program of ``mixed_step`` of the four older
-    toy families is the parent's (09d1212, PR 42) character for
-    character, by the hashes in ``tests/data/mixed_step_hlo_pr42.json``:
-    an empty ``lead`` tree, the latent branch and the sigmoid branch are
-    Python that their traces never take.  The text is this JAX's."""
-    if jax.__version__ != PARENT["jax"]:
-        pytest.skip(f"hashes were made under jax {PARENT['jax']}")
-    with _parent_engine(model) as eng:
-        text = eng._mixed_step.inner.lower(
-            *abstract_mixed_call(eng, slab)).as_text()
-    key = f"{model}.{'slab' if slab else 'decode'}"
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENT["sha256"][key]

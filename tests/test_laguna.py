@@ -3,7 +3,6 @@ attention, two head counts, a per-head output gate, YaRN on half a head, a
 page pool a kind of block that frees behind the window, and a chip's share
 of the routed experts.  CPU, toy widths (``laguna-test``)."""
 import dataclasses
-import hashlib
 import json
 import sys
 from pathlib import Path
@@ -31,7 +30,6 @@ from distributed_inference_demo_tpu.ops.sampling import SamplingParams
 from distributed_inference_demo_tpu.ops.stacked import LayerOf
 from distributed_inference_demo_tpu.runtime.batching import (
     ContinuousBatchingEngine)
-from test_mixed_batching import abstract_mixed_call
 
 ROOT = Path(__file__).resolve().parent.parent
 for extra in ("benchmark", "tools"):
@@ -46,8 +44,6 @@ MC = dataclasses.asdict(CFG)
 SPEC = StageSpec(0, 1, 0, CFG.num_layers)
 GREEDY = SamplingParams(temperature=0.0)
 MIXED = dict(prefill_chunk=8, decode_block=4, mixed_token_budget=24)
-PARENT = json.loads((ROOT / "tests" / "data" / "mixed_step_hlo_pr45.json")
-                    .read_text())
 FAM = families.load("laguna")
 
 

@@ -1,11 +1,9 @@
 """A period model (family ``laguna``, PR 46) in the ENGINE: served through
 the mixed dispatch over a pool a kind of block, window pages given back
-while a request runs, prefix sharing off; every older model's program the
-parent's; and what is built for one kind of block refusing in a sentence.
+while a request runs, prefix sharing off; and what is built for one kind
+of block refusing in a sentence.
 ``tests/test_laguna.py`` holds the model, the kernels and the share."""
 import dataclasses
-import hashlib
-import json
 import sys
 import time
 from pathlib import Path
@@ -33,7 +31,6 @@ from distributed_inference_demo_tpu.ops.sampling import SamplingParams
 from distributed_inference_demo_tpu.ops.stacked import LayerOf
 from distributed_inference_demo_tpu.runtime.batching import (
     ContinuousBatchingEngine)
-from test_mixed_batching import abstract_mixed_call
 
 ROOT = Path(__file__).resolve().parent.parent
 for extra in ("benchmark", "tools"):
@@ -48,8 +45,6 @@ MC = dataclasses.asdict(CFG)
 SPEC = StageSpec(0, 1, 0, CFG.num_layers)
 GREEDY = SamplingParams(temperature=0.0)
 MIXED = dict(prefill_chunk=8, decode_block=4, mixed_token_budget=24)
-PARENT = json.loads((ROOT / "tests" / "data" / "mixed_step_hlo_pr45.json")
-                    .read_text())
 FAM = families.load("laguna")
 
 
@@ -67,17 +62,16 @@ def _engine(params, cfg=CFG, **kw):
 
 # ------------------------------------------------------------ the engine
 
-def _dense_greedy(params, prompt, new):
-    cache = KVCache.create(CFG, CFG.num_layers, 1, len(prompt) + new + 8)
-    logits, cache = stage_forward(params, CFG, SPEC, jnp.asarray([prompt]),
-                                  cache, jnp.arange(len(prompt))[None])
-    out = [int(logits[0, -1].argmax())]
-    for t in range(new - 1):
-        logits, cache = stage_forward(
-            params, CFG, SPEC, jnp.asarray([[out[-1]]]), cache,
-            jnp.asarray([[len(prompt) + t]]))
-        out.append(int(logits[0, -1].argmax()))
-    return out
+def _dense_greedy(params, prompt, out):
+    """The dense path's choice after ``prompt`` and after each token of
+    ``out`` but the last, in ONE forward over ``prompt + out[:-1]``: it is
+    ``out`` exactly where greedy decoding through the dense path, a call
+    a token, gives ``out`` (by induction over its tokens)."""
+    ids = list(prompt) + list(out[:-1])
+    cache = KVCache.create(CFG, CFG.num_layers, 1, len(ids) + 8)
+    logits, _ = stage_forward(params, CFG, SPEC, jnp.asarray([ids]), cache,
+                              jnp.arange(len(ids))[None])
+    return [int(t) for t in logits[0, len(prompt) - 1:].argmax(-1)]
 
 
 def test_engine_serves_the_dense_path_s_tokens_and_returns_its_pages(params):
@@ -90,7 +84,7 @@ def test_engine_serves_the_dense_path_s_tokens_and_returns_its_pages(params):
         assert eng._wmgr.used_blocks == 0 and eng._window_reserved == 0
         assert eng.kv_cache.used_blocks == 0
     for p, out in zip(prompts, outs):
-        assert out == _dense_greedy(params, list(p), 24)
+        assert len(out) == 24 and out == _dense_greedy(params, list(p), out)
     kinds = st["kvcache"]["kinds"]
     assert kinds["window"]["pages_returned"] > 0
     assert kinds["full"]["blocks_total"] == st["kvcache"]["blocks_total"]
@@ -186,33 +180,6 @@ def test_prefix_sharing_is_off_under_a_window(params):
         kv = eng.stats()["kvcache"]
     assert a == b
     assert kv["hits"] == 0 and kv["stores"] == 0 and kv["tree_blocks"] == 0
-
-
-# ------------------------------------------- every other model is what it was
-
-def _parent_engine(model):
-    cfg = get_model_config(model)
-    return ContinuousBatchingEngine(
-        cfg, init_full_params(jax.random.PRNGKey(0), cfg), max_seq=96,
-        max_batch=4, sampling=GREEDY, kv_block_tokens=8, prefill_chunk=8,
-        decode_block=4, mixed_token_budget=24)
-
-
-@pytest.mark.parametrize("slab", [False, True], ids=["decode", "slab"])
-@pytest.mark.parametrize("model", ["qwen2-test", "bloom-test", "olmoe-test",
-                                   "ouro-test", "kanana-test"])
-def test_every_existing_model_lowers_to_the_parent_s_program(model, slab):
-    """``mixed_step`` of the five older toy families, as lowered, is the
-    parent's (9760ca4, PR 45) character for character: the period, the
-    window bound, the gate and the share are Python their traces never
-    take."""
-    if jax.__version__ != PARENT["jax"]:
-        pytest.skip(f"hashes were made under jax {PARENT['jax']}")
-    with _parent_engine(model) as eng:
-        text = eng._mixed_step.inner.lower(
-            *abstract_mixed_call(eng, slab)).as_text()
-    key = f"{model}.{'slab' if slab else 'decode'}"
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENT["sha256"][key]
 
 
 # --------------------------------------------- what refuses, in a sentence

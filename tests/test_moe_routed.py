@@ -378,11 +378,15 @@ def test_a_row_that_holds_no_token_enters_no_group(cfg, tp, quant, devices):
         specs.update({n: jax.tree.map(lambda _: P("tp"), lp[n])
                       for n in stack})
 
+        # (jitted, as the engine runs it: called bare a shard_map runs op
+        # by op, seven seconds a call, and there are three)
+        routed = jax.jit(jax.shard_map(
+            lambda lp_, x_, v_: _moe_routed(cfg, lp_, x_, "tp", v_),
+            mesh=mesh, in_specs=(specs, P(), P()),
+            out_specs=(P(), P()), check_vma=False))
+
         def run(v):
-            return jax.shard_map(
-                lambda lp_, x_, v_: _moe_routed(cfg, lp_, x_, "tp", v_),
-                mesh=mesh, in_specs=(specs, P(), P()),
-                out_specs=(P(), P()), check_vma=False)(lp, x, v)
+            return routed(lp, x, v)
 
     whole, whole_rows = run(None)
     got, rows = run(valid)

@@ -5,12 +5,11 @@ own) at toy size on the CPU.
 ``ouro-test`` has 4 layers and 3 passes, so a count that took one for the
 other shows.  The oracle is the benchmark's plain float32 reference
 (``benchmark/families/ouro.py`` through ``benchmark/reference.py``): no
-line of the program.  And a one-pass model is what it was: its
-``mixed_step`` lowers to the parent's program byte for byte.
+line of the program.  And a one-pass model is what it was: its dispatch
+record and stats are the parent's (its program: ``tests/test_program_pins.py``).
 """
 
 import dataclasses
-import hashlib
 import json
 import re
 import sys
@@ -49,7 +48,7 @@ CFG = get_model_config("ouro-test")
 L, T = CFG.num_layers, CFG.ut_steps
 GREEDY = SamplingParams(temperature=0.0)
 FIELDS = dataclasses.asdict(CFG)        # what the reference is given
-PARENT = json.loads((ROOT / "tests" / "data" / "mixed_step_hlo_pr33.json")
+PARENT = json.loads((ROOT / "tests" / "data" / "mixed_step_hlo.json")
                     .read_text())
 
 
@@ -460,29 +459,6 @@ def _parent_engine(model):
         cfg, init_full_params(jax.random.PRNGKey(0), cfg), max_seq=96,
         max_batch=4, sampling=GREEDY, kv_block_tokens=8, prefill_chunk=8,
         decode_block=4, mixed_token_budget=24)
-
-
-@pytest.mark.parametrize("slab", [False, True], ids=["decode", "slab"])
-@pytest.mark.parametrize("model", ["qwen2-test", "bloom-test", "olmoe-test"])
-def test_one_pass_mixed_step_lowers_to_the_parent_s_program(model, slab):
-    """``ut_steps == 1``: the pre-optimisation program of ``mixed_step``
-    with no segment and with the budget's ``n_seg`` is the kept one
-    character for character, by the hash in ``tests/data``: a dense
-    model's is PR 33's (dd8ee66), so the dense cells run what they ran
-    where they pack a full slab or none; a model with experts' is PR
-    39's, which told it the rows that hold a token (the fixture's
-    ``since_pr39``).  Since PR 42 every layer body holds one
-    ``optimization_barrier`` before the head reshapes, so all six were
-    made again from that tree, each text the parent's line for line but
-    for the barrier (the fixture's ``since_pr42``).  The text is this
-    JAX's; under another version the kept hashes say nothing."""
-    if jax.__version__ != PARENT["jax"]:
-        pytest.skip(f"hashes were made under jax {PARENT['jax']}")
-    with _parent_engine(model) as eng:
-        text = eng._mixed_step.inner.lower(
-            *abstract_mixed_call(eng, slab)).as_text()
-    key = f"{model}.{'slab' if slab else 'decode'}"
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENT["sha256"][key]
 
 
 @pytest.mark.parametrize("model", ["qwen2-test", "bloom-test", "olmoe-test"])

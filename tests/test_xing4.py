@@ -8,18 +8,15 @@ experts, 2 a token, 1 shared, latent rank 32, query rank 16, 4 streams).
 The oracle is the benchmark's plain float32 reference
 (``benchmark/families/xing4_0.py`` through ``benchmark/reference.py``): the
 equations as published, ``[T, n, H]`` streams, ``[T, n, n]`` maps, no
-cache, no kernel, no line of the program.  And every other model is what
-it was: ``mixed_step`` of five toy families of the parent (2da1495, PR 59)
-lowers to the parent's program character for character.
+cache, no kernel, no line of the program.  (That every other model is
+what it was is ``tests/test_program_pins.py``'s to hold.)
 """
 
 import dataclasses
-import hashlib
 import json
 import math
 import sys
 from pathlib import Path
-from unittest import mock
 
 import numpy as np
 import pytest
@@ -46,15 +43,12 @@ from distributed_inference_demo_tpu.ops.sampling import (  # noqa: E402
     SamplingParams)
 from distributed_inference_demo_tpu.runtime.batching import (  # noqa: E402
     ContinuousBatchingEngine)
-from tests.test_mixed_batching import abstract_mixed_call  # noqa: E402
 
 CFG = get_model_config("xing-bench-test")
 L, LEAD, N = CFG.num_layers, CFG.lead_dense_layers, CFG.hc_streams
 GREEDY = SamplingParams(temperature=0.0)
 FIELDS = dataclasses.asdict(CFG)        # what the reference is given
 SPEC = StageSpec(0, 1, 0, L)
-PARENT = json.loads((ROOT / "tests" / "data" / "mixed_step_hlo_pr59.json")
-                    .read_text())
 TOL = 2e-4
 HC = CFG.hc_args
 
@@ -440,30 +434,3 @@ def test_streams_ride_any_block_s_attention():
     assert wide.shape == one.shape and np.isfinite(np.asarray(wide)).all()
     assert float(jnp.abs(wide - one).max()) > 1e-3
 
-
-# ----------------------------------------- every other model is what it was
-
-@pytest.mark.parametrize("slab", [False, True], ids=["decode", "slab"])
-@pytest.mark.parametrize("model", ["llama-test", "kanana-test", "laguna-test",
-                                   "evabyte-test", "solar-open2-test"])
-def test_one_stream_models_lower_to_the_parent_s_program(model, slab):
-    """With ``hc_streams == 0`` the pre-optimisation program of
-    ``mixed_step`` is the parent's (2da1495, PR 59) character for
-    character, by the hashes in ``tests/data/mixed_step_hlo_pr59.json``:
-    the streams, the low-rank query, YaRN on a latent head and the scale's
-    factor are Python that their traces never take.  (``tests/
-    test_kanana.py`` holds qwen2, bloom, olmoe and ouro to PR 42's.)"""
-    if jax.__version__ != PARENT["jax"]:
-        pytest.skip(f"hashes were made under jax {PARENT['jax']}")
-    cfg = get_model_config(model)
-    # (an engine that launches nothing before it is ready: only the text
-    # of the program is read, and warming its variants is most of a case)
-    with mock.patch.object(ContinuousBatchingEngine, "_warm_mixed_variants",
-                           lambda self: None), ContinuousBatchingEngine(
-            cfg, init_full_params(jax.random.PRNGKey(0), cfg), max_seq=96,
-            max_batch=4, sampling=GREEDY, kv_block_tokens=8, prefill_chunk=8,
-            decode_block=4, mixed_token_budget=24) as eng:
-        text = eng._mixed_step.inner.lower(
-            *abstract_mixed_call(eng, slab)).as_text()
-    key = f"{model}.{'slab' if slab else 'decode'}"
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENT["sha256"][key]

@@ -51,6 +51,15 @@ def _engine(params, cfg=CFG, **kw):
     return ContinuousBatchingEngine(cfg, params, sampling=GREEDY, **kw)
 
 
+@pytest.fixture(scope="module")
+def served(params):
+    """One engine as configured for the cases that serve through it (its
+    three variants and the probe are most of such a case), and what
+    ``/stats`` said before its first request."""
+    with _engine(params) as eng:
+        yield eng, eng.stats()
+
+
 def _prompt(n, seed):
     return np.random.default_rng(seed).integers(
         1, CFG.vocab_size, n).astype(np.int32)
@@ -74,20 +83,20 @@ def _settled(eng):
     raise AssertionError("the last dispatch never committed")
 
 
-def test_served_logprobs_equal_the_float32_reference(params):
+def test_served_logprobs_equal_the_float32_reference(params, served):
     """A long prompt (six chunks) and a short one start together; a third
     joins while they decode.  Each emitted token's log-probability is the
     reference's, and the tokens are the ones it would have chosen."""
     prompts = [_prompt(45, 0), _prompt(7, 1), _prompt(19, 2)]
-    with _engine(params) as eng:
-        first = [eng.submit(p, 12) for p in prompts[:2]]
-        while len(first[1].tokens) < 3:         # the short one decodes
-            time.sleep(0.01)
-        late = eng.submit(prompts[2], 9)
-        reqs = first + [late]
-        outs = [np.asarray(r.wait(timeout=300)) for r in reqs]
-        lps = [list(r.lps) for r in reqs]
-        st = _settled(eng)
+    eng, _ = served
+    first = [eng.submit(p, 12) for p in prompts[:2]]
+    while len(first[1].tokens) < 3:         # the short one decodes
+        time.sleep(0.01)
+    late = eng.submit(prompts[2], 9)
+    reqs = first + [late]
+    outs = [np.asarray(r.wait(timeout=300)) for r in reqs]
+    lps = [list(r.lps) for r in reqs]
+    st = _settled(eng)
     for p, o, lp in zip(prompts, outs, lps):
         ref = _reference(params, p, o)
         assert lp == pytest.approx(ref["logprobs"], abs=2e-4)
@@ -118,35 +127,37 @@ def test_one_sinkhorn_step_is_seen_through_the_engine(params):
     assert st["hc"]["sinkhorn_iters"] == 1
 
 
-def test_the_residual_path_s_counters(params):
+def test_the_residual_path_s_counters(served):
     """``/stats.hc``: the rows both kernels computed (a slab's rows and
     every slot of every decode step, by the dispatch records' own column)
     and the start-up probe's reading, which a reply with
     log-probabilities repeats; bridged onto the catalog's series."""
     from distributed_inference_demo_tpu.telemetry import catalog
-    with _engine(params) as eng:
-        before = eng.stats()["hc"]
-        assert before["rows"] == 0
-        # the start-up probe has run: 20 iterations reach float32's floor
-        assert 0 < before["sinkhorn_residual_max"] < 1e-5
-        out = eng.generate(_prompt(30, 4), 6, logprobs=True)
-        assert out.logprobs.shape == (1, 6)
-        assert out.generation == [
-            {"hc_sinkhorn_residual": before["sinkhorn_residual_max"]}]
-        assert eng.generate(_prompt(9, 5), 2).generation is None
-        st = _settled(eng)
+    eng, fresh = served
+    before = fresh["hc"]
+    assert before["rows"] == 0
+    # the start-up probe has run: 20 iterations reach float32's floor
+    assert 0 < before["sinkhorn_residual_max"] < 1e-5
+    was = _settled(eng)         # (whatever another case served before)
+    out = eng.generate(_prompt(30, 4), 6, logprobs=True)
+    assert out.logprobs.shape == (1, 6)
+    assert out.generation == [
+        {"hc_sinkhorn_residual": before["sinkhorn_residual_max"]}]
+    assert eng.generate(_prompt(9, 5), 2).generation is None
+    st = _settled(eng)
     hcs, trace = st["hc"], st["dispatch_trace"]
     col = trace["fields"].index("hc_rows")
-    seg, steps, rode = (trace["fields"].index(k) for k in (
-        "segments", "steps", "slab_carried_step"))
+    seq, seg, steps, rode = (trace["fields"].index(k) for k in (
+        "seq", "segments", "steps", "slab_carried_step"))
+    mine = [r for r in trace["recent"] if r[seq] > was["dispatch_trace"]["seq"]]
     # a slab's pass holds the slots' rows too (the step it carries, PR 61),
     # padded to the kernels' whole tiles; a step that rode it is no call
     # of its own
-    assert [r[col] for r in trace["recent"]] == [
+    assert [r[col] for r in mine] == [
         r[steps] * SLOTS + (hc.whole_tiles(r[seg] * CHUNK + SLOTS)
                             - (r[rode] > 0) * SLOTS if r[seg] else 0)
-        for r in trace["recent"]]
-    assert hcs["rows"] == sum(r[col] for r in trace["recent"]) > 0
+        for r in mine]
+    assert hcs["rows"] - was["hc"]["rows"] == sum(r[col] for r in mine) > 0
     assert (hcs["streams"], hcs["sinkhorn_iters"]) == (4, 20)
     # read once: no request moves it
     assert hcs["sinkhorn_residual_max"] == before["sinkhorn_residual_max"]

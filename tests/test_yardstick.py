@@ -6,12 +6,17 @@ test command does not collect.
   of the benchmark) and reduces the small recorded trace through both of
   its routes; the numbers are the ones ``benchmark/tests/make_small_xplane.py``
   built the file to have.
-- ``benchmark/tests`` runs whole in a subprocess: it stood at 70 of 75
-  over three PRs and no command of the driver's showed it.
+- ``benchmark/tests`` runs here, a case and a subprocess a file of it (it
+  stood at 70 of 75 over three PRs and no command of the driver's showed
+  it): the files found by glob, so a new one is a case the day it lands.
+  The ``test_*_family.py`` files, most of the seconds, are the cases of
+  ``tests/test_yardstick_families.py``, so that neither file is a unit
+  the deal cannot place (``docs/DESIGN.md``, "How tier-1 is dealt").
 """
 
 import importlib.util
 import os
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -49,13 +54,24 @@ def test_trace_reducer_reads_the_small_trace(route):
     assert len(reduced["modules"]["jit_mixed_step"]) == 2
 
 
-def test_the_benchmarks_own_suite_passes():
+def benchmark_test_files(family: bool):
+    return sorted(p.name for p in (BENCH / "tests").glob("test_*.py")
+                  if p.name.endswith("_family.py") == family)
+
+
+def run_benchmark_test_file(name):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
-        [sys.executable, "-m", "pytest", str(BENCH / "tests"), "-q",
+        [sys.executable, "-m", "pytest", str(BENCH / "tests" / name), "-q",
          "-p", "no:cacheprovider"],
         cwd=BENCH.parent, env=env, capture_output=True, text=True,
-        # alone it takes 100-240 s by the machine's load, and it runs
-        # beside five other workers (PR 54: 300 s cut it twice)
-        timeout=900)
+        # the longest (xing's: a rehearsal of its cell) takes 150 s alone
+        # and 210 s beside five busy workers on eight cores
+        timeout=600)
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-1000:]
+    assert re.search(r"\b[1-9]\d* passed", proc.stdout), proc.stdout[-1000:]
+
+
+@pytest.mark.parametrize("name", benchmark_test_files(family=False))
+def test_a_file_of_the_benchmarks_own_suite_passes(name):
+    run_benchmark_test_file(name)
