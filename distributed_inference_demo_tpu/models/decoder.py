@@ -583,6 +583,32 @@ def _route(cfg: ModelConfig, lp: dict, h: jnp.ndarray):
     return weights, experts.astype(jnp.int32)
 
 
+def _combine(out: jnp.ndarray, order: jnp.ndarray, weights: jnp.ndarray,
+             written: jnp.ndarray) -> jnp.ndarray:
+    """A token's ``k`` expert rows back from expert order, weighted and
+    summed: ``y[t] = sum_j float32(out[inv[j, t]]) x weights[t, j]``
+    ([T, H] float32) for ``out`` [k T, H] as the down projection wrote it,
+    ``order`` the sort of the k-major rows (``_moe_routed``) and
+    ``weights`` [T, k] float32.
+
+    The rows cross HBM once, in ``out``'s dtype: the gather by the
+    inverse permutation gives ``[k, T, H]`` (a split of the major axis),
+    and one fusion over it masks, widens, weights and sums over ``k``, the
+    major axis, products and sum in float32.  Rows at ``written`` and past
+    it in expert order (all ``k T`` where every row lies in a group) are
+    rows no group held; they are masked by ``where`` on the row's place,
+    never by a zero weight: 0 x what the kernel never wrote may be NaN.
+    The mask comes before the widening (the two commute exactly): behind
+    it, or with no mask between the gather and the widening, the compiler
+    leaves the widening a pass of its own, a float32 ``[k T, H]`` through
+    HBM."""
+    T, k = weights.shape
+    inv = jnp.argsort(order).reshape(k, T)
+    back = out[inv]                                       # [k, T, H]
+    back = jnp.where((inv < written)[:, :, None], back, 0)
+    return jnp.sum(back.astype(jnp.float32) * weights.T[:, :, None], axis=0)
+
+
 def _moe_routed(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
                 tp_axis: Optional[str] = None,
                 valid: Optional[jnp.ndarray] = None):
@@ -598,6 +624,13 @@ def _moe_routed(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     row times its router weight, and a token's ``k`` rows summed in
     float32.  Shapes are static and the group sizes are data, so an
     expert may take every row or none, and no row of a token is dropped.
+
+    The ``T k`` rows are laid k-major before the sort (row ``j T + t`` is
+    token ``t``'s ``j``-th expert), so the rows come back from expert
+    order as ``[k, T, H]`` by ONE gather of what the down projection
+    wrote, in its dtype, and the sum over ``k`` runs over the major axis:
+    ``k`` is never a tiled axis, and no float32 ``[T k, H]`` crosses HBM
+    (:func:`_combine`).
 
     ``valid`` ``[b, s]`` bool (the mixed dispatch: a slot that decodes,
     a slab position that holds a prompt token) says which rows hold a
@@ -618,13 +651,13 @@ def _moe_routed(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     xt = x.reshape(T, H)
     weights, experts = _route(cfg, lp, xt)
     with jax.named_scope("moe_experts"):
-        flat = experts.reshape(T * k)
+        # k-major: row j T + t is token t's j-th expert
+        flat = experts.T.reshape(k * T)
         if valid is not None:
-            flat = jnp.where(jnp.repeat(valid.reshape(T), k), flat, E)
+            flat = jnp.where(jnp.tile(valid.reshape(T), k), flat, E)
         rows = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
         sizes = rows
-        share = bool(cfg.experts_held)
-        if share:
+        if cfg.experts_held:
             # this chip's share of a block's experts (``experts_held``):
             # the branch below without its ``psum``.  Rows routed to the
             # experts of the other chips enter no group, and what those
@@ -646,24 +679,18 @@ def _moe_routed(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
             flat = jnp.where(mine, flat - e0, e_local)
             sizes = jax.lax.dynamic_slice_in_dim(rows, e0, e_local)
         order = jnp.argsort(flat, stable=True)
-        token = order // k
-        xs = xt[token]                                    # [T k, H]
+        token = order % T
+        xs = xt[token]                                    # [k T, H]
         # ``routed``: the T k rows spread over all E experts, whatever
         # share of them is here (the row tile follows the rows a group)
         gate = grouped_matmul(xs, lp["w_gate"], sizes, routed=E)
         up = grouped_matmul(xs, lp["w_up"], sizes, routed=E)
         hh = (jax.nn.silu(gate.astype(jnp.float32))
               * up.astype(jnp.float32)).astype(x.dtype)
-        out = grouped_matmul(hh, lp["w_down"], sizes,
-                             routed=E).astype(jnp.float32)
-        if tp_axis is not None or valid is not None or share:
-            # rows of other ranks' experts, and rows that hold no token,
-            # belong to no group here
-            out = jnp.where((jnp.arange(T * k) < jnp.sum(sizes))[:, None],
-                            out, 0.0)
-        # back to token order: row t*k + j is token t's j-th expert
-        out = out[jnp.argsort(order)].reshape(T, k, H)
-        y = jnp.einsum("tkh,tk->th", out, weights)
+        out = grouped_matmul(hh, lp["w_down"], sizes, routed=E)
+        # rows of other ranks' experts, and rows that hold no token,
+        # belong to no group here: the kernel never wrote them
+        y = _combine(out, order, weights, jnp.sum(sizes))
         if tp_axis is not None:
             y = jax.lax.psum(y, tp_axis)
     if cfg.num_shared_experts > 0:

@@ -748,6 +748,32 @@ def test_grouped_matmul_gets_through_mosaic(v5e, model, rows, quant):
         assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+@pytest.mark.parametrize("T, k, H", [(1312, 10, 4096), (576, 8, 4096)],
+                         ids=["granite-slab", "solar-slab"])
+def test_the_experts_combine_is_one_gather_and_one_fusion(v5e, T, k, H):
+    """PR 65: ``decoder._combine`` at a slab's rows, compiled for the
+    chip.  What stands between the down projection's bf16 rows and the
+    float32 ``[T, H]`` sum is the un-sort gather, in bf16, and ONE fusion
+    that masks, widens, weights and sums: the compiler's temporaries are
+    the gathered rows and nothing else (the form before held a float32
+    ``[T k, H]`` twice and a ``[T, k, H]`` with ``k`` padded to the
+    sublanes: five times as much), and no instruction outside a fusion
+    makes a float32 array of ``T k H`` elements."""
+    from distributed_inference_demo_tpu.models.decoder import _combine
+    S = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=v5e)  # noqa: E731
+    compiled = jax.jit(_combine).lower(
+        S((k * T, H), jnp.bfloat16), S((k * T,), jnp.int32),
+        S((T, k), jnp.float32), S((), jnp.int32)).compile()
+    # (at most: the compiler may keep solar's 36 MiB in fast memory)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * 2 * k * T * H
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    wide = re.findall(r"= f32\[([\d,]+)\]\S* (?!parameter)[\w-]+\(", entry)
+    assert wide and not [
+        d for d in wide if np.prod([int(n) for n in d.split(",")]) >= k * T * H]
+    assert f"bf16[{k * T},{H}]" in entry          # the gathered rows
+
+
 @pytest.mark.parametrize("b,chunk", [(16, 1), (2, 256)],
                          ids=["decode", "slab"])
 def test_latent_kernels_get_through_mosaic(v5e, b, chunk):

@@ -37,6 +37,9 @@ from distributed_inference_demo_tpu.telemetry.tracing import (
 
 OLMOE = get_model_config("olmoe-test")          # 8 experts, 2 a token
 MIXTRAL = get_model_config("mixtral-test")      # 4 experts, 2 a token
+# granite's kind at toy width: 10 of 72 a token, the first 36 held here
+GRANITE = OLMOE.replace(num_experts=72, experts_per_token=10,
+                        norm_topk_prob=True, experts_held=(36, 0))
 
 
 def _layer(rng, cfg, routing="uniform", dtype=jnp.float32):
@@ -55,13 +58,22 @@ def _layer(rng, cfg, routing="uniform", dtype=jnp.float32):
             "w_down": jax.random.normal(ks[3], (E, I, H), dtype) * I ** -0.5}
 
 
+def _held(cfg, lp):
+    """The layer with its expert stacks cut to the share held here."""
+    held, first = cfg.experts_held or (cfg.num_experts, 0)
+    return {n: w[first:first + held] if n in ("w_gate", "w_up", "w_down")
+            else w for n, w in lp.items()}
+
+
 def _f32(w):
     return (w.dequantize(jnp.float32)
             if isinstance(w, (QuantizedArray, QuantizedArray4)) else w)
 
 
-def _definition(cfg, lp, x):
-    """Every expert for every token, weights zero off the top k."""
+def _definition(cfg, lp, x, valid=None):
+    """Every expert for every token, weights zero off the top k, off the
+    experts held here (``experts_held``: the stacks hold those alone) and
+    on the rows that hold no token (``valid`` [T] bool)."""
     T, E, k = x.shape[0], cfg.num_experts, cfg.experts_per_token
     probs = np.asarray(jax.nn.softmax(
         np.asarray(x, np.float64) @ np.asarray(lp["router"], np.float64)))
@@ -71,6 +83,11 @@ def _definition(cfg, lp, x):
         w[t, order[t]] = probs[t, order[t]]
     if cfg.norm_topk_prob:
         w /= w.sum(-1, keepdims=True)
+    if valid is not None:
+        w[~np.asarray(valid)] = 0
+    if cfg.experts_held:
+        held, first = cfg.experts_held
+        w, E = w[:, first:first + held], held
     g, u, d = (np.asarray(_f32(lp[n]), np.float64)
                for n in ("w_gate", "w_up", "w_down"))
     xs = np.asarray(x, np.float64)
@@ -158,6 +175,131 @@ def test_routed_layer_equals_its_definition(cfg, routing, quant):
         atol=2e-5 if quant == "float32" else 1e-4)
     assert (np.asarray(_moe_mlp(cfg, lp, x[None])) == np.asarray(
         got).reshape(1, T, -1)).all()
+
+
+def _unwritten_rows_are_nan(monkeypatch):
+    """Every grouped matmul of the routed layer leaves NaN in the rows
+    past the last group, as a kernel that never writes them may."""
+    from distributed_inference_demo_tpu.models import decoder
+
+    def poisoned(lhs, rhs, sizes, **kw):
+        out = grouped_matmul(lhs, rhs, sizes, **kw)
+        written = jnp.arange(out.shape[0]) < jnp.sum(sizes)
+        return jnp.where(written[:, None], out, jnp.nan)
+    monkeypatch.setattr(decoder, "grouped_matmul", poisoned)
+
+
+@pytest.mark.parametrize("case", ["held", "valid", "held-valid"])
+def test_unwritten_rows_are_masked_not_weighted(case, monkeypatch):
+    """PR 65: a layer of granite's kind at toy width (10 of 72 experts a
+    token, bf16 rows; the first 36 held here, a ``valid`` mask with idle
+    rows) against the definition, while the grouped matmul writes NaN into
+    every row no group holds (the other chip's experts' rows, the idle
+    rows').  The combine masks a row by its place in expert order: a mask
+    applied as a zero weight gives NaN, and fails here."""
+    cfg = GRANITE if "held" in case else GRANITE.replace(experts_held=())
+    lp = _layer(jax.random.PRNGKey(2), cfg, dtype=jnp.bfloat16)
+    T, H = 24, cfg.hidden_size
+    x = jax.random.normal(jax.random.PRNGKey(3), (T, H), jnp.bfloat16)
+    valid = None
+    if "valid" in case:
+        valid = jnp.asarray(np.random.RandomState(4).rand(T) < 0.6)
+        assert 0 < int(valid.sum()) < T
+    want, want_rows = _definition(
+        cfg, jax.tree.map(lambda a: a.astype(jnp.float32), lp),
+        x.astype(jnp.float32), valid)
+    _unwritten_rows_are_nan(monkeypatch)
+    got, rows = _moe_routed(cfg, _held(cfg, lp), x.reshape(2, T // 2, H),
+                            None,
+                            None if valid is None else valid.reshape(2, -1))
+    got = np.asarray(got.astype(jnp.float32)).reshape(T, H)
+    assert np.isfinite(got).all()
+    assert (np.asarray(rows) == want_rows).all()
+    tokens = T if valid is None else int(valid.sum())
+    # some rows lie in no group: there was something to mask
+    assert 0 < int(rows.sum()) < T * cfg.experts_per_token
+    assert int(rows.sum()) <= tokens * cfg.experts_per_token
+    if valid is not None:
+        assert (got[~np.asarray(valid)] == 0).all()
+    # bf16 rows: the hidden rows and the down projection's are rounded
+    np.testing.assert_allclose(got, want, atol=2e-2 * np.abs(want).max())
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("case", ["plain", "held-valid"])
+def test_the_combine_holds_no_float32_copy_of_the_rows(case):
+    """PR 65, static: ``_moe_routed`` at ``T`` = 24, ``k`` = 10 on bf16
+    rows.  The rows come back from expert order by ONE gather, in the
+    dtype the down projection wrote, as ``[k, T, H]``; no value has the
+    shape ``[T, k, H]`` (top-k on a tiled axis); the mask is a ``select``
+    on the bf16 rows, so nothing unwritten is ever multiplied; and behind
+    the gather the float32 values of ``T k H`` elements are two, the
+    widened rows and their product with the weights, which the sum over
+    the major axis consumes (one fusion on the chip:
+    ``tests/test_bring_up.py`` compiles it there)."""
+    cfg = GRANITE if case == "held-valid" else GRANITE.replace(
+        experts_held=())
+    lp = _held(cfg, _layer(jax.random.PRNGKey(2), cfg, dtype=jnp.bfloat16))
+    T, k, H = 24, cfg.experts_per_token, cfg.hidden_size
+    x = jnp.zeros((2, T // 2, H), jnp.bfloat16)
+    valid = jnp.ones((2, T // 2), bool) if case == "held-valid" else None
+    jaxpr = jax.make_jaxpr(
+        lambda lp_, x_: _moe_routed(cfg, lp_, x_, None, valid))(lp, x)
+    eqns = list(_eqns(jaxpr.jaxpr))
+    outs = [(eqn.primitive.name, v.aval) for eqn in eqns
+            for v in eqn.outvars if hasattr(v.aval, "shape")]
+    assert not [o for o in outs if o[1].shape == (T, k, H)]
+    gathers = [o for o in outs if o[0] == "gather"
+               and o[1].shape == (k, T, H)]
+    assert len(gathers) == 1 and gathers[0][1].dtype == jnp.bfloat16
+    behind = outs[outs.index(gathers[0]) + 1:]    # in the program's order
+    rows = [o for o in behind if o[1].size >= T * k * H
+            and o[0] not in ("jit", "pjit")]      # their equations are here
+    # (the broadcast is the mask's zero)
+    assert [name for name, aval in rows if aval.dtype == jnp.bfloat16] == [
+        "broadcast_in_dim", "select_n"]
+    assert [name for name, aval in rows if aval.dtype == jnp.float32] == [
+        "convert_element_type", "mul"]
+    assert all(aval.shape == (k, T, H) for _, aval in rows
+               if aval.dtype != jnp.bool_)
+    summed, = [e for e in eqns if e.primitive.name == "reduce_sum"
+               and e.invars[0].aval.shape == (k, T, H)]
+    assert summed.params["axes"] == (0,)
+    assert summed.outvars[0].aval.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "valid"])
+@pytest.mark.parametrize("cfg", [OLMOE, MIXTRAL, GRANITE],
+                         ids=["olmoe", "mixtral", "granite"])
+def test_rows_are_the_histogram_the_token_major_order_gave(cfg, masked):
+    """PR 65 lays the token-expert rows k-major before the sort; a group
+    is the same rows in another order, so ``rows`` (the group sizes, and
+    what ``/stats.moe`` sums) are what the order before gave: a count of
+    the valid tokens' experts, the held ones' alone."""
+    lp = _held(cfg, _layer(jax.random.PRNGKey(2), cfg))
+    held, first = cfg.experts_held or (cfg.num_experts, 0)
+    T, k = 24, cfg.experts_per_token
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, T, cfg.hidden_size))
+    valid = (jnp.asarray(np.random.RandomState(4).rand(1, T) < 0.5)
+             if masked else None)
+    _, rows = _moe_routed(cfg, lp, x, None, valid)
+    _, experts = _route(cfg, lp, x[0])
+    flat = np.asarray(experts).reshape(T * k)        # row t k + j
+    if masked:
+        flat = flat[np.repeat(np.asarray(valid[0]), k)]
+    want = np.bincount(flat, minlength=cfg.num_experts)
+    assert (np.asarray(rows) == want[first:first + held]).all()
+    assert rows.dtype == jnp.int32 and rows.shape == (held,)
 
 
 def test_int8_scales_are_an_expert_s_own():
@@ -614,13 +756,37 @@ def test_stats_list_the_tiles_of_every_grouped_matmul_traced():
         "vmem_limit_bytes"} for line in lines)
 
 
-def test_the_table_tool_reads_six_configurations_and_the_rule_before():
+@pytest.mark.parametrize("table", ["gmm", "combine"])
+def test_the_table_tool_reads_six_configurations_and_the_rule_before(table):
     """``tools/gmm_table.py``: the configurations with experts and their
     two calls' rows come from the benchmark's files, and its ``before``
     column is the rule as it stood before PR 63 (a 2 MiB right-hand
     tile): one contraction tile at olmoe's int8 widths, 2 to 4 at the
-    five bf16 configurations', two for solar's down projection."""
+    five bf16 configurations', two for solar's down projection.
+    ``--combine`` (PR 65): the bytes a form moves at granite's slab, and at
+    toy rows the new form against the one before on the same rows."""
     tool = _table_tool()
+    if table == "combine":
+        import argparse
+        nbytes = tool.combine_bytes(1312, 10, 4096)
+        rows = 13120 * 4096
+        assert nbytes["floor"] == 2 * rows + 4 * 1312 * 4096
+        assert nbytes["bf16"] == nbytes["floor"] + 4 * rows
+        assert 1.6e9 < nbytes["before"] < 1.8e9 and nbytes["f32"] < 1.0e9
+        # k = 8 fills a float32 tile's sublanes: nothing was padded
+        assert tool.combine_bytes(64, 8, 128)["before"] == (
+            6 + 8 + 8 + 4) * 64 * 8 * 128 + 4 * 64 * 128
+        granite = next(tool.expert_configs(["granite-4.0-h-small-bf16-ep2"]))
+        granite["tokens"] = {"slab": 8}
+        row, = tool.combine_rows_of(granite,
+                                    argparse.Namespace(seed=0, reps=1))
+        assert (row["call"], row["tokens"], row["top_k"]) == ("slab", 8, 10)
+        assert 0 < row["rows_written"] < 80          # half the experts held
+        assert all(row[f"{f}_us"] > 0 and row[f"{f}_mb"] > 0
+                   for f in tool.COMBINE_FORMS)
+        assert list(tool.COMBINE_FORMS) == ["before", "bf16", "f32"]
+        assert row["err"] < 1e-6
+        return
     before = {}
     for c in tool.expert_configs([]):
         m, item = c["tokens"]["slab"] * c["top_k"], 1 if c["int8"] else 2

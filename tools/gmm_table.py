@@ -25,6 +25,18 @@ touched experts' matrices over 819 GB/s and the groups' rows x 2 k n over
 197 TFLOP/s: the call's bound is the larger.  ``err`` is the rule's call
 against ``ragged_dot`` on the same rows, max |difference| over max
 |value|.  One JSON line a row goes to ``chiprun_out/gmm_table.jsonl``.
+
+``--combine`` times what follows the down projection instead (PERF.md
+section 6, PR 65): a token's ``k`` rows back from expert order, weighted
+and summed, at the same slab and decode shapes.  ``before`` is the
+combine as it stood before PR 65 (widen and mask ``[T k, H]``, gather in
+float32, reshape to ``[T, k, H]``, einsum); ``bf16`` is
+``decoder._combine`` on the rows as the kernel writes them, ``f32`` the
+same on rows widened first, so that the gather moves float32.  ``bytes``
+are what a form's passes move through HBM by its shapes (a float32
+``[T, k, H]`` pads ``k`` to a multiple of 8), ``floor`` the bf16 rows
+read once and ``[T, H]`` float32 written, over 819 GB/s.  ``err`` is
+``bf16`` against ``before`` on the same rows.
 """
 from __future__ import annotations
 
@@ -41,6 +53,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from distributed_inference_demo_tpu.models.decoder import _combine  # noqa: E402
 from distributed_inference_demo_tpu.ops import grouped_matmul as gmm  # noqa: E402
 from distributed_inference_demo_tpu.telemetry.profiling import (  # noqa: E402
     DEVICE_PEAKS)
@@ -155,6 +168,111 @@ def rows_of(c: dict, args):
             yield row
 
 
+def combine_before(out, order, weights, written):
+    """The combine as it stood before PR 65, on rows laid token-major
+    (row ``t k + j`` is token ``t``'s ``j``-th expert) before the sort."""
+    T, k = weights.shape
+    out = out.astype(jnp.float32)
+    out = jnp.where((jnp.arange(T * k) < written)[:, None], out, 0.0)
+    out = out[jnp.argsort(order)].reshape(T, k, -1)
+    return jnp.einsum("tkh,tk->th", out, weights)
+
+
+def combine_f32(out, order, weights, written):
+    return _combine(out.astype(jnp.float32), order, weights, written)
+
+
+COMBINE_FORMS = {"before": combine_before, "bf16": _combine,
+                 "f32": combine_f32}
+
+
+def combine_bytes(T: int, k: int, H: int) -> dict:
+    """Bytes a form's passes move through HBM, and the arithmetic's own."""
+    rows, padded, y = T * k * H, T * -(-k // 8) * 8 * H, 4 * T * H
+    return {"before": (2 + 4) * rows + (4 + 4) * rows + 4 * (rows + padded)
+            + 4 * padded + y,
+            "bf16": (2 + 2) * rows + 2 * rows + y,
+            "f32": (2 + 4) * rows + (4 + 4) * rows + 4 * rows + y,
+            "floor": 2 * rows + y}
+
+
+def us_a_combine(form, out, order, weights, written, reps: int) -> float:
+    # three permutations in turn, and each call's sum written into the
+    # next call's rows in place: no pass is the same twice, so the
+    # compiler hoists none out of the loop, and the rows are not copied
+    orders = jnp.stack([jnp.roll(order, i) for i in range(LAYERS)])
+
+    @jax.jit
+    def calls(out):
+        def one(out, i):
+            y = form(out, orders[i % LAYERS], weights, written)
+            return jax.lax.dynamic_update_slice(
+                out, y[:1].astype(out.dtype), (0, 0)), None
+        return jax.lax.scan(one, out, jnp.arange(reps, dtype=jnp.int32))[0]
+
+    calls(out).block_until_ready()
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        calls(out).block_until_ready()
+        best = min(best, time.perf_counter() - t0)
+    return 1e6 * best / reps
+
+
+def combine_rows_of(c: dict, args):
+    H, k = c["hidden"], c["top_k"]
+    rs = np.random.RandomState(args.seed)
+    for call, T in c["tokens"].items():
+        picked = np.argsort(-rs.rand(T, c["routed"]), axis=1)[:, :k]
+        held = (picked >= c["first"]) & (picked < c["first"] + c["held"])
+        flat = np.where(held, picked - c["first"], c["held"])     # [T, k]
+        written = jnp.int32(held.sum())
+        weights = jnp.asarray(rs.rand(T, k), jnp.float32)
+        per_row = jax.random.normal(jax.random.PRNGKey(args.seed),
+                                    (T, k, H), jnp.bfloat16)
+        # the same rows in the two orders: token-major and k-major
+        order_t = np.argsort(flat.reshape(-1), kind="stable")
+        order_k = np.argsort(flat.T.reshape(-1), kind="stable")
+        out_t = per_row.reshape(T * k, H)[order_t]
+        out_k = per_row.transpose(1, 0, 2).reshape(k * T, H)[order_k]
+        nbytes = combine_bytes(T, k, H)
+        row = dict(config=c["name"], call=call, combine=True, tokens=T,
+                   top_k=k, hidden=H, rows_written=int(written),
+                   floor_us=round(1e-3 * nbytes["floor"] / PEAKS.hbm_gbs, 1))
+        for name, form in COMBINE_FORMS.items():
+            out, order = ((out_t, order_t) if name == "before"
+                          else (out_k, order_k))
+            row[f"{name}_mb"] = round(nbytes[name] / 1e6, 1)
+            row[f"{name}_us"] = round(us_a_combine(
+                form, out, jnp.asarray(order, jnp.int32), weights, written,
+                args.reps), 1)
+        want = combine_before(out_t, jnp.asarray(order_t), weights, written)
+        got = _combine(out_k, jnp.asarray(order_k), weights, written)
+        row["err"] = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+        yield row
+
+
+HEAD = ["config", "call", "proj", "m", "rows/group", "hbm", "mxu", "before",
+        "us", *(f"k,tm={tm}" for tm in ROW_TILES), "rule", "err"]
+COMBINE_HEAD = ["config", "call", "tokens", "k", "H", "floor",
+                *(f"{name}: MB, us" for name in COMBINE_FORMS), "err"]
+
+
+def cells(row: dict) -> list:
+    return [row["config"], row["call"], row["proj"], row["m"],
+            row["rows_a_group"], row["hbm_us"], row["mxu_us"],
+            "x".join(map(str, row["before"])), row["before_us"],
+            *(row[f"k_tm{tm}_us"] for tm in ROW_TILES),
+            "x".join(map(str, row["rule"])), f"{row['err']:.1e}"]
+
+
+def combine_cells(row: dict) -> list:
+    return [row["config"], row["call"], row["tokens"], row["top_k"],
+            row["hidden"], row["floor_us"],
+            *(f"{row[f'{name}_mb']}, {row[f'{name}_us']}"
+              for name in COMBINE_FORMS), f"{row['err']:.1e}"]
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--config", action="append", default=[],
@@ -167,6 +285,9 @@ def main() -> int:
                     help="off the chip: the kernel interpreted at 48 and 8 "
                          "tokens a call, to see the script run; its times "
                          "mean nothing")
+    ap.add_argument("--combine", action="store_true",
+                    help="the combine after the down projection alone, "
+                         "not the grouped matmul")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=str(ROOT / "chiprun_out"
                                          / "gmm_table.jsonl"))
@@ -178,9 +299,9 @@ def main() -> int:
     print(f"# device {dev.platform} {dev.device_kind}; groups "
           f"{'even' if args.even else f'seeded router, seed {args.seed}'}; "
           f"us a call, least of 3 x {args.reps}")
-    head = ["config", "call", "proj", "m", "rows/group", "hbm", "mxu",
-            "before", "us", *(f"k,tm={tm}" for tm in ROW_TILES), "rule",
-            "err"]
+    rows_of_config, head, cells_of = (
+        (combine_rows_of, COMBINE_HEAD, combine_cells) if args.combine
+        else (rows_of, HEAD, cells))
     print("| " + " | ".join(head) + " |")
     print("|" + "---|" * len(head))
     Path(args.out).parent.mkdir(parents=True, exist_ok=True)
@@ -188,18 +309,13 @@ def main() -> int:
         for c in expert_configs(args.config):
             if args.rehearse:
                 c["tokens"] = {"slab": 48, "decode": 8}
-            for row in rows_of(c, args):
+            for row in rows_of_config(c, args):
                 row.update(device=dev.device_kind, even=args.even,
                            seed=args.seed, rehearsal=args.rehearse)
                 out.write(json.dumps(row) + "\n")
                 out.flush()
-                cells = [row["config"], row["call"], row["proj"], row["m"],
-                         row["rows_a_group"], row["hbm_us"], row["mxu_us"],
-                         "x".join(map(str, row["before"])), row["before_us"],
-                         *(row[f"k_tm{tm}_us"] for tm in ROW_TILES),
-                         "x".join(map(str, row["rule"])),
-                         f"{row['err']:.1e}"]
-                print("| " + " | ".join(map(str, cells)) + " |", flush=True)
+                print("| " + " | ".join(map(str, cells_of(row))) + " |",
+                      flush=True)
     return 0
 
 
