@@ -58,7 +58,14 @@ class BlockKind:
     output gate ("none"; "per-head": each head's output times the sigmoid
     of a linear map of the block's normed input, one scalar a head, before
     ``wo``; "elementwise": the same with one scalar a CHANNEL of every
-    head).  Built from a JSON object (``ModelConfig.period``); hashable."""
+    head).  And which SUBLAYERS it has (docs/DESIGN.md section 31): a
+    token mixer unless ``attn`` is "none", and the model's MLP (dense or
+    experts) iff ``mlp``.  A block of both is ``x + mixer(norm x)`` then
+    ``x + mlp(norm x)``; a block of ONE is one norm, one sublayer, one add
+    (nemotron_h: a published layer is a Mamba-2 mixer, an attention or the
+    experts).  A block without a mixer holds no cache at all: no plane of
+    a page pool, none of the state pool.  Built from a JSON object
+    (``ModelConfig.period``); hashable."""
 
     attn: str = "full"
     window: int = 0
@@ -73,16 +80,32 @@ class BlockKind:
     state_size: int = 0
     groups: int = 1
     chunk: int = 0
+    mlp: bool = True
 
     @property
     def is_state(self) -> bool:
         """Its cache is a recurrent state a request, not rows of pages."""
         return self.attn in ("kda", "ssd")
 
+    @property
+    def has_pages(self) -> bool:
+        """Its cache is rows of a page pool (full keys and values)."""
+        return self.attn in ("full", "window")
+
+    @property
+    def name(self) -> str:
+        """What its parameter stacks and paths are named by: its ``attn``,
+        "mlp" for a block that has no mixer."""
+        return "mlp" if self.attn == "none" else self.attn
+
     def __post_init__(self):
-        if self.attn not in ("full", "window", "kda", "ssd"):
+        if self.attn not in ("full", "window", "kda", "ssd", "none"):
             raise ValueError(f"a block kind's attn is 'full', 'window', "
-                             f"'kda' or 'ssd', got {self.attn!r}")
+                             f"'kda', 'ssd' or 'none', got {self.attn!r}")
+        if self.attn == "none" and not self.mlp:
+            raise ValueError("a block kind has a token mixer (attn), an "
+                             "MLP (mlp) or both: this one has neither "
+                             "sublayer")
         if (self.attn == "window") != (self.window > 0):
             raise ValueError("a window kind states its window, a full "
                              "kind none")
@@ -314,11 +337,21 @@ class ModelConfig:
         return self.experts_held[0] if self.experts_held else self.num_experts
 
     @property
+    def mlp_blocks(self) -> int:
+        """Blocks of the repeated stack that have the model's MLP (its
+        experts, where it has any): every block, but for a period of
+        blocks of one sublayer (``BlockKind.mlp``)."""
+        if not self.period:
+            return self.num_layers
+        return self.num_layers * sum(k.mlp for k in self.period)
+
+    @property
     def kinds(self) -> tuple:
         """The period's distinct kinds as ``(name, kind, positions)``, in
         order of first appearance: ``positions`` the kind's places in the
-        period, ``name`` its ``attn`` (with the first place appended where
-        two kinds share one).  The parameter stacks are named by it."""
+        period, ``name`` its ``BlockKind.name`` (with the first place
+        appended where two kinds share one).  The parameter stacks are
+        named by it."""
         seen = []
         for p, k in enumerate(self.period):
             for entry in seen:
@@ -326,7 +359,7 @@ class ModelConfig:
                     entry[2].append(p)
                     break
             else:
-                seen.append([k.attn, k, [p]])
+                seen.append([k.name, k, [p]])
         names = [e[0] for e in seen]
         return tuple((n if names.count(n) == 1 else f"{n}{pos[0]}", k,
                       tuple(pos)) for n, k, pos in seen)
@@ -344,7 +377,7 @@ class ModelConfig:
         if self.lead_kind is not None and self.lead_dense_layers:
             count[self.lead_kind.window] = self.lead_dense_layers
         for k in self.period:
-            if not k.is_state:          # a state, not pages (below)
+            if k.has_pages:     # not a state (below), not a block of none
                 count[k.window] = count.get(k.window, 0) + self.num_layers
         return tuple(sorted(count.items()))
 
@@ -436,18 +469,21 @@ class ModelConfig:
         then the repeats of the period in order): the index of its pool in
         ``cache_kinds`` and its plane there.  Within a pool the leading
         blocks' planes come first, then repeat by repeat.  A block of a
-        state kind holds no pages: ``(-1, its plane of the state pool)``."""
+        state kind holds no pages: ``(-1, its plane of the state pool)``;
+        a block without a mixer holds nothing: ``(None, None)``."""
         windows = [w for w, _ in self.cache_kinds]
         lead = self.lead_dense_layers
         if block < lead:
             return windows.index(self.lead_kind.window), block
         r, p = divmod(block - lead, len(self.period))
+        if self.period[p].attn == "none":
+            return None, None
         if self.period[p].is_state:         # a plane of the state pool
             mine = [q for q, k in enumerate(self.period) if k.is_state]
             return -1, r * len(mine) + mine.index(p)
         w = self.period[p].window
         mine = [q for q, k in enumerate(self.period)
-                if k.window == w and not k.is_state]
+                if k.window == w and k.has_pages]
         base = lead if (self.lead_kind is not None
                         and self.lead_kind.window == w) else 0
         return windows.index(w), base + r * len(mine) + mine.index(p)
@@ -455,7 +491,7 @@ class ModelConfig:
     def of_kind(self, kind: BlockKind) -> "ModelConfig":
         """The configuration of ONE block of ``kind``: its heads and rope
         in the flat fields, the kind itself as a period of one."""
-        return self.replace(num_heads=kind.num_heads,
+        return self.replace(num_heads=kind.num_heads or self.num_heads,
                             rope_theta=kind.rope_theta, period=(kind,),
                             lead_kind=None, lead_dense_layers=0,
                             num_layers=1)
@@ -468,9 +504,13 @@ class ModelConfig:
     @property
     def kv_planes(self) -> int:
         """K/V planes a token holds: one a layer a pass (the leading
-        dense blocks first).  The one source of every KV structure's
+        dense blocks first); in a period model one a block that holds
+        pages (a state kind's block and a block without a mixer hold
+        none), over its pools.  The one source of every KV structure's
         plane count (dense cache, page pool, host tier, exported
         blocks)."""
+        if self.period:
+            return sum(planes for _, planes in self.cache_kinds)
         return self.total_layers * self.ut_steps
 
     @property

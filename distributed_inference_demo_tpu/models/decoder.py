@@ -32,6 +32,12 @@ inferred at SURVEY.md §2.2), a single pure ``stage_forward`` covers:
   (``ops.hyper_connection``); the embedding replicates, the final norm
   reads the streams' sum.
 
+- **nemotron_h family** (Nemotron-3-Nano-30B-A3B): blocks of ONE sublayer
+  each (``BlockKind.mlp`` / ``attn == "none"``; docs/DESIGN.md section
+  31): a Mamba-2 mixer whose B, C and gated norm go by GROUPS of heads, a
+  NoPE GQA attention, or the experts, which are of two matrices,
+  ``down(relu(up h) ** 2)`` (``mlp_act == "relu2"``), beside a shared one.
+
 The per-stage forward is a single ``lax.scan`` over stacked layer weights —
 XLA compiles one loop body reused across layers, keeping compile time flat in
 depth and the MXU saturated.  The KV cache threads through the scan as
@@ -141,7 +147,10 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
 
     keys = jax.random.split(rng, 16)
     kind = cfg.block_kind
-    if kind is not None and kind.attn == "kda":
+    has_mlp = kind is None or kind.mlp
+    if kind is not None and kind.attn == "none":
+        p = {"mlp_norm_w": jnp.ones((L, H), dt)}    # no mixer, no cache
+    elif kind is not None and kind.attn == "kda":
         # a gated delta-rule block (``_kda_mixer``): q, k and v of every
         # head (no grouped keys), the short convolution's taps, the two
         # low-rank gates (inner width = the head's), beta, a head's own
@@ -247,6 +256,8 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
             "wo": big(keys[3], (L, nh * hd, H), dt),
             "mlp_norm_w": jnp.ones((L, H), dt),
         }
+    if not has_mlp:     # a block of the mixer alone: one norm, no MLP leaf
+        del p["mlp_norm_w"]
     if kind is not None and kind.gate == "per-head":
         # one scalar a head from the block's normed input (``_kv_attention``)
         p["wg"] = _dense_init(keys[13], (L, H, nh), dt)
@@ -256,8 +267,9 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
         # the stored weight is the gain's OFFSET (the gain is 1 + w).
         # Seeded at N(0, 0.1) and not at the published zero, so that a
         # norm that forgot the offset cannot pass for one that has it
-        p["attn_norm_w"] = _dense_init(keys[14], (L, H), dt, scale=0.1)
-        p["mlp_norm_w"] = _dense_init(keys[15], (L, H), dt, scale=0.1)
+        for j, leaf in enumerate(("attn_norm_w", "mlp_norm_w")):
+            if leaf in p:
+                p[leaf] = _dense_init(keys[14 + j], (L, H), dt, scale=0.1)
     if cfg.summary_kv:
         # EVA's two learned pooling vectors a kv head, as published:
         # clip(N(0, 1), +-1) x head_dim ** -0.5
@@ -315,14 +327,25 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
         gain = (2 * cfg.num_layers) ** -0.5
         p["attn_post_norm_w"] = jnp.full((L, H), gain, dt)
         p["mlp_post_norm_w"] = jnp.full((L, H), gain, dt)
+    # experts of two matrices, ``down(relu(up h) ** 2)``: no gate projection
+    gated = cfg.mlp_act != "relu2"
+    if not has_mlp:
+        return p
     if cfg.num_experts > 0:  # mixtral / olmoe MoE
         # the router scores every expert; the stacks hold this chip's
         # share of them (``experts_held``: all, for every model but one
         # cut to a deployment's share)
         E = cfg.experts_here
         p["router"] = _dense_init(keys[4], (L, H, cfg.num_experts), dt)
-        p["w_gate"] = big(keys[5], (L, E, H, I), dt)
-        p["w_up"] = big(keys[6], (L, E, H, I), dt)
+        if gated:
+            p["w_gate"] = big(keys[5], (L, E, H, I), dt)
+            p["w_up"] = big(keys[6], (L, E, H, I), dt)
+        else:
+            # stored TRANSPOSED, ``[I, H]`` an expert: the chip lays the
+            # lane-filling dimension minor, and ``I`` need not be whole
+            # lanes (``ops.grouped_matmul``'s ``transposed``)
+            p["w_up_t"] = _dense_init(keys[6], (L, E, I, H), dt,
+                                      scale=H ** -0.5)
         # Routed down projections under a sigmoid router (deepseek_v3)
         # are seeded at 1/32 of the fan-in scale.  There a token's k
         # weights are renormalised to sum to ``routed_scaling_factor``:
@@ -343,8 +366,20 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
         # and scaled up (a period model's top-10 at 2.5: a quarter of the
         # routed sum an expert), and for a chip's share of a renormalised
         # top-k (granite's top-10 of 72, half of them held).
+        # Experts of two matrices (nemotron_h) take 1/16, not 1/32: an
+        # ``E`` block there is the experts and nothing else, and at 1/32
+        # the held routed sum of one block lies under bfloat16's noise on
+        # the stream (PERF.md section 7, PR 62 f: granite's last block's
+        # experts moved no observable of the reply).  At 1/8 the last
+        # block's held sum moved the emitted tokens' log-probabilities by
+        # 0.033 in the mean against a sound 0.013-0.015, but a swapped
+        # expert moved single tokens by 0.10-0.12 (3 of 396 positions past
+        # 0.09, on the chip, my chip runs, PR 66), which the harness's 0.1
+        # on a canary's sixteen tokens would meet once in some dozens of
+        # runs; 1/16 halves both.
         p["w_down"] = big(keys[7], (L, E, I, H), dt,
-                          scale=(I ** -0.5 / 32
+                          scale=(I ** -0.5 / 16 if not gated
+                                 else I ** -0.5 / 32
                                  if cfg.router_scoring == "sigmoid"
                                  or cfg.routed_scaling_factor > 1.0
                                  or (cfg.norm_topk_prob
@@ -353,12 +388,20 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
         if cfg.router_bias:
             # non-zero, so that choosing by score + bias and weighing by
             # the score differ; float32 like the scores it is added to
-            # (over every expert the router scores, held here or not)
-            p["router_bias"] = 0.1 * jax.random.normal(
+            # (over every expert the router scores, held here or not).
+            # Experts of two matrices (nemotron_h) take N(0, 0.02): the
+            # published bias is there to BALANCE the router, and at 0.1,
+            # against a spread of ~0.1 among a token's best scores, the
+            # seeded one sent a step's 192 held rows to 59-62 % of the
+            # held experts where an even router touches 95 % (my chip
+            # runs, PR 66); at 0.02 it still changes most of one choice in
+            # six a token and leaves 93 % touched
+            p["router_bias"] = (0.1 if gated else 0.02) * jax.random.normal(
                 keys[9], (L, cfg.num_experts), jnp.float32)
         if cfg.num_shared_experts > 0:
             Is = cfg.num_shared_experts * I
-            p["ws_gate"] = big(keys[10], (L, H, Is), dt)
+            if gated:
+                p["ws_gate"] = big(keys[10], (L, H, Is), dt)
             p["ws_up"] = big(keys[11], (L, H, Is), dt)
             p["ws_down"] = big(keys[12], (L, Is, H), dt)
     elif cfg.family == "bloom":  # dense 4H GELU MLP with bias
@@ -505,6 +548,11 @@ def _mlp(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     the routed experts only (:func:`_moe_routed`)."""
     if cfg.num_experts > 0:
         if ep_axis is not None:
+            if cfg.mlp_act == "relu2":
+                raise ValueError(
+                    "the capacity-slot expert-parallel path is written for "
+                    "gated experts of three matrices; experts of two "
+                    "(mlp_act relu2) are served dropless on one chip")
             return _moe_mlp_ep(cfg, lp, x, ep_axis)
         return _moe_mlp(cfg, lp, x, tp_axis, valid)
     if cfg.family == "bloom":
@@ -528,7 +576,7 @@ def _mlp(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     return out
 
 
-_EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+_EXPERT_STACKS = ("w_gate", "w_up", "w_up_t", "w_down")
 
 
 def _router_logits(h: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
@@ -583,6 +631,12 @@ def _route(cfg: ModelConfig, lp: dict, h: jnp.ndarray):
     return weights, experts.astype(jnp.int32)
 
 
+def _relu2(up: jnp.ndarray) -> jnp.ndarray:
+    """``relu(up) ** 2`` in float32, back in ``up``'s dtype: the
+    activation of an expert of two matrices (nemotron_h's ``relu2``)."""
+    return jnp.square(jax.nn.relu(up.astype(jnp.float32))).astype(up.dtype)
+
+
 def _combine(out: jnp.ndarray, order: jnp.ndarray, weights: jnp.ndarray,
              written: jnp.ndarray) -> jnp.ndarray:
     """A token's ``k`` expert rows back from expert order, weighted and
@@ -620,9 +674,11 @@ def _moe_routed(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     groups, one grouped matmul a projection
     (``ops.grouped_matmul``: a Pallas call on the chip that reads a
     touched expert's int8 matrix once and never widens a stack in HBM,
-    ``ragged_dot`` elsewhere), silu(gate) x up, the down projection, each
-    row times its router weight, and a token's ``k`` rows summed in
-    float32.  Shapes are static and the group sizes are data, so an
+    ``ragged_dot`` elsewhere), silu(gate) x up (or, for experts of two
+    matrices, ``mlp_act == "relu2"``: ``relu(up) ** 2`` and no gate call),
+    the down projection, each row times its router weight, and a token's
+    ``k`` rows summed in float32.  Shapes are static and the group sizes
+    are data, so an
     expert may take every row or none, and no row of a token is dropped.
 
     The ``T k`` rows are laid k-major before the sort (row ``j T + t`` is
@@ -648,6 +704,7 @@ def _moe_routed(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     the ``psum``."""
     b, s, H = x.shape
     T, k, E = b * s, cfg.experts_per_token, cfg.num_experts
+    gated = cfg.mlp_act != "relu2"
     xt = x.reshape(T, H)
     weights, experts = _route(cfg, lp, xt)
     with jax.named_scope("moe_experts"):
@@ -673,7 +730,7 @@ def _moe_routed(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
             flat = jnp.where(mine, flat - e0, e_local)
             rows = sizes = rows[e0:e0 + e_local]
         if tp_axis is not None:
-            e_local = lp["w_gate"].shape[0]  # quantized, LayerOf: .shape
+            e_local = lp["w_down"].shape[0]  # quantized, LayerOf: .shape
             e0 = jax.lax.axis_index(tp_axis) * e_local
             mine = (flat >= e0) & (flat < e0 + e_local)
             flat = jnp.where(mine, flat - e0, e_local)
@@ -683,10 +740,14 @@ def _moe_routed(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
         xs = xt[token]                                    # [k T, H]
         # ``routed``: the T k rows spread over all E experts, whatever
         # share of them is here (the row tile follows the rows a group)
-        gate = grouped_matmul(xs, lp["w_gate"], sizes, routed=E)
-        up = grouped_matmul(xs, lp["w_up"], sizes, routed=E)
-        hh = (jax.nn.silu(gate.astype(jnp.float32))
-              * up.astype(jnp.float32)).astype(x.dtype)
+        if gated:
+            gate = grouped_matmul(xs, lp["w_gate"], sizes, routed=E)
+            up = grouped_matmul(xs, lp["w_up"], sizes, routed=E)
+            hh = (jax.nn.silu(gate.astype(jnp.float32))
+                  * up.astype(jnp.float32)).astype(x.dtype)
+        else:   # two matrices an expert, the first stored [I, H]
+            hh = _relu2(grouped_matmul(xs, lp["w_up_t"], sizes, routed=E,
+                                       transposed=True))
         out = grouped_matmul(hh, lp["w_down"], sizes, routed=E)
         # rows of other ranks' experts, and rows that hold no token,
         # belong to no group here: the kernel never wrote them
@@ -697,10 +758,13 @@ def _moe_routed(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
         # the shared experts: ONE dense SwiGLU of their summed width on
         # every row, added to the routed sum in float32 before the cast
         with jax.named_scope("moe_shared"):
-            gate = dense(xt, lp["ws_gate"], "th,hi->ti")
-            up = dense(xt, lp["ws_up"], "th,hi->ti")
-            hs = (jax.nn.silu(gate.astype(jnp.float32))
-                  * up.astype(jnp.float32)).astype(x.dtype)
+            if gated:
+                gate = dense(xt, lp["ws_gate"], "th,hi->ti")
+                up = dense(xt, lp["ws_up"], "th,hi->ti")
+                hs = (jax.nn.silu(gate.astype(jnp.float32))
+                      * up.astype(jnp.float32)).astype(x.dtype)
+            else:
+                hs = _relu2(dense(xt, lp["ws_up"], "th,hi->ti"))
             y = y + dense(hs, lp["ws_down"], "ti,ih->th").astype(
                 jnp.float32)
     return y.reshape(b, s, H).astype(x.dtype), rows
@@ -1010,10 +1074,17 @@ def _kda_mixer(cfg: ModelConfig, kind, lp: dict, h: jnp.ndarray, state,
     return (y.reshape(b, s, D), LayerOf(S, plane), LayerOf(tails, plane))
 
 
-def _gated_norm(y, z, w, eps):
-    """Mamba-2's gated RMSNorm over float32 ``y``: the gate ``silu(z)``
-    BEFORE the norm, one mean square over all of ``d_inner`` (one group)."""
-    return rms_norm(y * jax.nn.silu(z.astype(jnp.float32)), w, eps)
+def _gated_norm(y, z, w, eps, groups: int = 1):
+    """Mamba-2's gated RMSNorm over float32 ``y`` ``[.., d_inner]``: the
+    gate ``silu(z)`` BEFORE the norm, then one mean square a GROUP of
+    ``d_inner / groups`` channels (the kind's ``groups``: a group's heads
+    side by side), times ``w`` ``[d_inner]``.  One group is one mean
+    square over all of ``d_inner``."""
+    g = y * jax.nn.silu(z.astype(jnp.float32))
+    if groups == 1:
+        return rms_norm(g, w, eps)
+    by_group = g.reshape(g.shape[:-1] + (groups, g.shape[-1] // groups))
+    return rms_norm(by_group, w.reshape(groups, -1), eps).reshape(g.shape)
 
 
 def _ssd_mixer(cfg: ModelConfig, kind, lp: dict, h: jnp.ndarray, state,
@@ -1025,7 +1096,7 @@ def _ssd_mixer(cfg: ModelConfig, kind, lp: dict, h: jnp.ndarray, state,
 
         z | xBC | dt = h W_in;   xBC = silu(conv(xBC) + b);   x | B | C = xBC
         dt = softplus(dt + dt_bias);   the recurrence over (x, B, C, dt, A)
-        y = rms_norm((y + D x) * silu(z), w)        one group, all of d_inner
+        y = rms_norm((y + D x) * silu(z), w)        a group of d_inner / groups
 
     ``state`` / ``conv`` are ``LayerOf`` the state pool ``[planes, rows,
     heads, P, N]`` float32 and the convolution tails ``[planes, rows, taps
@@ -1128,8 +1199,11 @@ def _ssd_mixer(cfg: ModelConfig, kind, lp: dict, h: jnp.ndarray, state,
         outs.append(o)
     o = join_rows(outs) if merged else o
     with jax.named_scope("ssd_gated_norm"):
-        y = _gated_norm(o.reshape(b, s, D), z, lp["ssd_norm_w"],
-                        cfg.norm_eps).astype(cfg.dtype)
+        # (one group is called as it was, with four arguments:
+        # tests/test_granite_hybrid.py swaps in a norm that takes four)
+        norm = _gated_norm if G == 1 else partial(_gated_norm, groups=G)
+        y = norm(o.reshape(b, s, D), z, lp["ssd_norm_w"],
+                 cfg.norm_eps).astype(cfg.dtype)
     return y, LayerOf(S, plane), LayerOf(tails, plane)
 
 
@@ -1207,7 +1281,11 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     """One decoder block. x: [b, s, H]. Returns (x', k_cache', v_cache'),
     and with ``moe_stats`` a fourth value, the rows routed to each expert
     in this layer call ([E] int32; ``_moe_routed``, which is also all
-    that reads ``valid``, the rows that hold a token).  The caches are this
+    that reads ``valid``, the rows that hold a token; ``None`` from a
+    block that has no MLP).  A block of ONE sublayer (``cfg.block_kind``:
+    ``attn == "none"``, or ``mlp`` false) is one norm, that sublayer and
+    one add; without a mixer the caches come and go as ``None``.  The
+    caches are this
     layer's planes, or ``LayerOf`` the whole stacks where ``attn_impl``
     addresses a page pool in place; either goes to the hook untouched.
 
@@ -1244,67 +1322,79 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
         with jax.named_scope("hc_post"):
             return hc_ops.hc_post(x, y, coef, n=n)
 
-    streams = x
-    if n:
-        x, coef = hc_read("attn", streams)
-    if cfg.attn_layernorm:
-        h = layer_norm(x, lp["attn_norm_w"], lp["attn_norm_b"], cfg.norm_eps)
-    else:
-        h = rms_norm(x, lp["attn_norm_w"], cfg.norm_eps,
-                     cfg.norm_unit_offset)
-    if x.dtype != cfg.dtype:  # a float32 stream (looped, fp32_residual)
-        h = h.astype(cfg.dtype)
-
     kind = cfg.block_kind
-    if kind is not None and kind.attn == "kda":
-        # the caches are the state pool and the convolution tails
-        attn, k_cache, v_cache = _kda_mixer(
-            cfg, kind, lp, h, k_cache, v_cache, positions, valid, attn_impl)
-    elif kind is not None and kind.attn == "ssd":
-        attn, k_cache, v_cache = _ssd_mixer(
-            cfg, kind, lp, h, k_cache, v_cache, positions, valid, attn_impl)
-    elif cfg.latent_kv:  # the cache is ``k_cache`` alone; ``v_cache`` is empty
-        attn, k_cache = _latent_attention(cfg, lp, h, k_cache, positions,
-                                          cache_start, attn_impl)
-    else:
-        attn, k_cache, v_cache = _kv_attention(
-            cfg, lp, h, k_cache, v_cache, positions, cache_start, slopes,
-            tp_axis, attn_impl)
-    is_ssd = kind is not None and kind.attn == "ssd"
-    with (jax.named_scope("ssd_out_proj") if is_ssd
-          else contextlib.nullcontext()):
-        attn = dense(attn, lp["wo"], "bsd,dh->bsh")
-    if tp_axis is not None:
-        attn = jax.lax.psum(attn, tp_axis)
-    if cfg.attn_layernorm:
-        attn = attn + lp["bo"]
-    if cfg.sandwich_norm:
-        attn = rms_norm(attn, lp["attn_post_norm_w"], cfg.norm_eps)
+    # the sublayers this block has: both, but for a kind of one
+    # (``BlockKind``: no mixer at ``attn == "none"``, no MLP at ``mlp``
+    # false); a static branch, so a block of both traces what it did
+    has_mixer = kind is None or kind.attn != "none"
+    has_mlp = kind is None or kind.mlp
     rm = cfg.residual_multiplier    # granite: both sublayers' outputs
-    if rm != 1.0:
-        attn = attn * jnp.asarray(rm, attn.dtype)
-    if n:
-        streams = hc_write(streams, attn, coef)
-        x, coef = hc_read("mlp", streams)
-    else:
-        x = x + attn
+    def normed(x, sub):
+        """The sublayer's input: its norm over the stream, in the model's
+        dtype (a float32 stream, looped or ``fp32_residual``, is cast)."""
+        if cfg.attn_layernorm:
+            h = layer_norm(x, lp[f"{sub}_norm_w"], lp[f"{sub}_norm_b"],
+                           cfg.norm_eps)
+        else:
+            h = rms_norm(x, lp[f"{sub}_norm_w"], cfg.norm_eps,
+                         cfg.norm_unit_offset)
+        return h if x.dtype == cfg.dtype else h.astype(cfg.dtype)
 
-    if cfg.attn_layernorm:
-        h = layer_norm(x, lp["mlp_norm_w"], lp["mlp_norm_b"], cfg.norm_eps)
-    else:
-        h = rms_norm(x, lp["mlp_norm_w"], cfg.norm_eps,
-                     cfg.norm_unit_offset)
-    if x.dtype != cfg.dtype:
-        h = h.astype(cfg.dtype)
-    if moe_stats:
-        y, rows = _moe_routed(cfg, lp, h, tp_axis, valid)
-    else:
-        y, rows = _mlp(cfg, lp, h, tp_axis, ep_axis, valid), None
-    if cfg.sandwich_norm:
-        y = rms_norm(y, lp["mlp_post_norm_w"], cfg.norm_eps)
-    if rm != 1.0:
-        y = y * jnp.asarray(rm, y.dtype)
-    x = hc_write(streams, y, coef) if n else x + y
+    streams = x
+    if has_mixer:
+        if n:
+            x, coef = hc_read("attn", streams)
+        h = normed(x, "attn")
+
+        if kind is not None and kind.attn == "kda":
+            # the caches are the state pool and the convolution tails
+            attn, k_cache, v_cache = _kda_mixer(
+                cfg, kind, lp, h, k_cache, v_cache, positions, valid,
+                attn_impl)
+        elif kind is not None and kind.attn == "ssd":
+            attn, k_cache, v_cache = _ssd_mixer(
+                cfg, kind, lp, h, k_cache, v_cache, positions, valid,
+                attn_impl)
+        elif cfg.latent_kv:  # the cache is ``k_cache`` alone: no ``v_cache``
+            attn, k_cache = _latent_attention(cfg, lp, h, k_cache, positions,
+                                              cache_start, attn_impl)
+        else:
+            attn, k_cache, v_cache = _kv_attention(
+                cfg, lp, h, k_cache, v_cache, positions, cache_start, slopes,
+                tp_axis, attn_impl)
+        is_ssd = kind is not None and kind.attn == "ssd"
+        with (jax.named_scope("ssd_out_proj") if is_ssd
+              else contextlib.nullcontext()):
+            attn = dense(attn, lp["wo"], "bsd,dh->bsh")
+        if tp_axis is not None:
+            attn = jax.lax.psum(attn, tp_axis)
+        if cfg.attn_layernorm:
+            attn = attn + lp["bo"]
+        if cfg.sandwich_norm:
+            attn = rms_norm(attn, lp["attn_post_norm_w"], cfg.norm_eps)
+        if rm != 1.0:
+            attn = attn * jnp.asarray(rm, attn.dtype)
+        if n:
+            streams = hc_write(streams, attn, coef)
+        else:
+            x = x + attn
+
+    rows = None
+    if has_mlp:
+        if n:
+            x, coef = hc_read("mlp", streams)
+        h = normed(x, "mlp")
+        if moe_stats:
+            y, rows = _moe_routed(cfg, lp, h, tp_axis, valid)
+        else:
+            y = _mlp(cfg, lp, h, tp_axis, ep_axis, valid)
+        if cfg.sandwich_norm:
+            y = rms_norm(y, lp["mlp_post_norm_w"], cfg.norm_eps)
+        if rm != 1.0:
+            y = y * jnp.asarray(rm, y.dtype)
+        x = hc_write(streams, y, coef) if n else x + y
+    elif n:
+        x = streams
     if moe_stats:
         return x, k_cache, v_cache, rows
     return x, k_cache, v_cache
@@ -1349,6 +1439,8 @@ def _period_blocks(params: StageParams, cfg: ModelConfig, x, cache: KVCache,
             "make_paged_attn_impl); this one serves one kind")
 
     def hook(name, kind, pool):
+        if kind.attn == "none":     # no mixer: nothing to attend
+            return None
         if kind.is_state:           # rows of the state pool, not pages
             return (attn_impl.for_state(name) if attn_impl is not None
                     else None)
@@ -1357,19 +1449,24 @@ def _period_blocks(params: StageParams, cfg: ModelConfig, x, cache: KVCache,
         return _window_attn(kind.window) if kind.window else None
 
     def block(block_cfg, lp, x, Ks, Vs, pool, plane, impl, stats):
-        pool %= len(Ks)     # -1, a state kind's (``cfg.state_arrays``)
-        # the state pool goes whole, paged or not (``_kda_mixer``,
-        # ``_ssd_mixer``)
-        whole = paged or block_cfg.block_kind.is_state
-        k_of, v_of = LayerOf(Ks[pool], plane), LayerOf(Vs[pool], plane)
-        kc, vc = (k_of, v_of) if whole else (k_of.sliced(), v_of.sliced())
+        kc = vc = None      # a block without a mixer holds no cache
+        if pool is not None:
+            pool %= len(Ks)     # -1, a state kind's (``cfg.state_arrays``)
+            # the state pool goes whole, paged or not (``_kda_mixer``,
+            # ``_ssd_mixer``)
+            whole = paged or block_cfg.block_kind.is_state
+            k_of, v_of = LayerOf(Ks[pool], plane), LayerOf(Vs[pool], plane)
+            kc, vc = ((k_of, v_of) if whole
+                      else (k_of.sliced(), v_of.sliced()))
         x, kc, vc, *rows = _layer(block_cfg, lp, x, kc, vc, positions,
                                   cache_start, None, None, impl, None,
                                   stats, valid)
-        K, V = ((kc.stack, vc.stack) if whole
-                else (k_of.updated(kc), v_of.updated(vc)))
-        swap = lambda t, a: t[:pool] + (a,) + t[pool + 1:]
-        return x, swap(Ks, K), swap(Vs, V), (rows[0] if rows else None)
+        if pool is not None:
+            K, V = ((kc.stack, vc.stack) if whole
+                    else (k_of.updated(kc), v_of.updated(vc)))
+            swap = lambda t, a: t[:pool] + (a,) + t[pool + 1:]
+            Ks, Vs = swap(Ks, K), swap(Vs, V)
+        return x, Ks, Vs, (rows[0] if rows else None)
 
     Ks, Vs = tuple(cache.keys), tuple(cache.values)
     if cfg.state_planes:    # the state rides last: checked here, a trace
@@ -1394,7 +1491,11 @@ def _period_blocks(params: StageParams, cfg: ModelConfig, x, cache: KVCache,
     for name, kind, at in cfg.kinds:
         for j, p in enumerate(at):
             pool, plane0 = cfg.plane_of(lead + p)
-            stride = cfg.plane_of(lead + P + p)[1] - plane0 if R > 1 else 0
+            if pool is None:
+                plane0 = stride = 0
+            else:
+                stride = (cfg.plane_of(lead + P + p)[1] - plane0
+                          if R > 1 else 0)
             places.append((p, name, kind, cfg.of_kind(kind), j, len(at),
                            pool, plane0, stride))
     places.sort()
@@ -1415,16 +1516,21 @@ def _period_blocks(params: StageParams, cfg: ModelConfig, x, cache: KVCache,
                   for k, v in leaves.items() if k.endswith(tail)}
             lp.update({k[:-len(tail)]: LayerOf(v, r * n + j)
                        for k, v in stacks.items() if k.endswith(tail)})
-            x, Ks, Vs, rows = block(kcfg, lp, x, Ks, Vs, pool,
-                                    plane0 + r * stride,
-                                    hook(name, kind, pool), moe_stats)
-            out_rows.append(rows)
+            # (the scope names the block's kind: a trace splits a step by
+            # it, whatever spans lie inside)
+            with jax.named_scope(f"block_{name}"):
+                x, Ks, Vs, rows = block(kcfg, lp, x, Ks, Vs, pool,
+                                        plane0 + r * stride,
+                                        hook(name, kind, pool),
+                                        moe_stats and kind.mlp)
+            if kind.mlp:        # a row of counts a block that has experts
+                out_rows.append(rows)
         return (x, Ks, Vs), (jnp.stack(out_rows) if moe_stats else None)
 
     (x, Ks, Vs), rows = jax.lax.scan(body, (x, Ks, Vs),
                                      (scanned, jnp.arange(R)))
-    if moe_stats:       # [R, P, held] -> one row a block
-        rows = rows.reshape((R * P,) + rows.shape[2:])
+    if moe_stats:       # [R, blocks with experts, held] -> one row a block
+        rows = rows.reshape((-1,) + rows.shape[2:])
     return x, Ks, Vs, rows
 
 
@@ -1625,8 +1731,8 @@ def stage_forward(
         # the hook made for it (``stacked_cache``) addresses
         # (layer, page) and hands the stacks back.  A dense cache is
         # sliced and updated here, as it always was.
-        whole = (_EXPERT_STACKS if cfg.num_experts > 0 and ep_axis is None
-                 else ())
+        whole = (tuple(k for k in _EXPERT_STACKS if k in params.layers)
+                 if cfg.num_experts > 0 and ep_axis is None else ())
         scanned_layers = {k: v for k, v in params.layers.items()
                           if k not in whole}
         stacked_cache = getattr(attn_impl, "stacked_cache", False)

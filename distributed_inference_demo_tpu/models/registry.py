@@ -37,6 +37,15 @@ GRANITE_TEST_SSD = BlockKind(attn="ssd", num_heads=4, rotary_share=0.0,
                              conv=4, state_heads=8, state_head_dim=16,
                              state_size=16, groups=1, chunk=8)
 
+# nemotron_h's three kinds of block, each of ONE sublayer: a Mamba-2 mixer
+# of 8 heads in 2 groups, a NoPE GQA attention, the experts
+NEMOTRON_TEST_M = BlockKind(attn="ssd", num_heads=4, rotary_share=0.0,
+                            conv=4, state_heads=8, state_head_dim=16,
+                            state_size=16, groups=2, chunk=8, mlp=False)
+NEMOTRON_TEST_A = BlockKind(attn="full", num_heads=4, rotary_share=0.0,
+                            mlp=False)
+NEMOTRON_TEST_E = BlockKind(attn="none")
+
 # xing4_0 (XingChen-AGI/Xing4.0-29B-A4B config.json): deepseek's YaRN on
 # the 64 rope lanes of a latent head (factor 64 over 4,096 positions,
 # beta 32 / 1; cos and sin times mscale / mscale_all_dim = 1) and the
@@ -288,6 +297,25 @@ MODEL_REGISTRY = {
         dtype_name="float32",
         period=tuple([GRANITE_TEST_SSD] * 2 + [GRANITE_TEST_FULL]
                      + [GRANITE_TEST_SSD])),
+    # nemotron_h at toy size, 2 repeats of (M, E, M, *, E): every block ONE
+    # sublayer.  M a Mamba-2 mixer (8 state heads of 16 x 16 in 2 groups,
+    # B, C and the gated norm a group, a convolution of 4 taps with a
+    # bias, chunks of 8: a state a request), * a NoPE GQA attention (4
+    # query heads over 2 kv heads: pages), E the experts and no cache at
+    # all: 8 experts of two matrices (relu2, width 24: no multiple of a
+    # lane tile) top-2, 4 of them held, chosen by sigmoid score + bias and
+    # weighed by the renormalised scores x 2.5, beside a shared one of 48
+    "nemotron-h-test": ModelConfig(
+        family="nemotron_h", vocab_size=256, hidden_size=64, num_layers=2,
+        num_heads=4, num_kv_heads=2, head_dim_override=16,
+        intermediate_size=24, max_seq_len=256, norm_eps=1e-5,
+        mlp_act="relu2", num_experts=8, experts_per_token=2,
+        norm_topk_prob=True, num_shared_experts=2,
+        router_scoring="sigmoid", router_bias=True,
+        routed_scaling_factor=2.5, experts_held=(4, 0),
+        dtype_name="float32",
+        period=(NEMOTRON_TEST_M, NEMOTRON_TEST_E, NEMOTRON_TEST_M,
+                NEMOTRON_TEST_A, NEMOTRON_TEST_E)),
 }
 
 

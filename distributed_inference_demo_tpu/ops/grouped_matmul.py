@@ -119,7 +119,12 @@ def row_tile(m: int, groups: int) -> int:
 
 
 def _divisor_tile(dim: int, cap: int) -> int:
-    """The largest multiple of 128 that divides ``dim`` and is <= cap."""
+    """The largest multiple of 128 that divides ``dim`` and is <= cap; a
+    ``dim`` that is not whole lanes is one tile (a block may span a whole
+    dimension whatever its size: nemotron_h's expert width 1,856 = 14.5 x
+    128, the ``n`` of its up projection)."""
+    if dim % _LANES:
+        return dim
     best = _LANES
     for t in range(_LANES, min(dim, cap) + 1, _LANES):
         if dim % t == 0:
@@ -154,11 +159,15 @@ def tiling(m: int, k: int, n: int, rhs_itemsize: int, groups: int,
     ``tk`` the whole contraction wherever the call's limit
     (``vmem_limit``) then stays inside ``_VMEM_BUDGET``: an expert's
     ``[k, tn]`` slice is then read once a group, not once a row tile.
+    A ``k`` or ``n`` that is not whole lanes is one tile that spans it
+    (``route_grouped_matmul`` admits it only where that fits).
     Where it does not (mixtral's ``[14336, 4096]``) ``tk`` is the largest
     divisor of ``k`` that does; no other order of the grid reads a matrix
     once without a float32 ``[m, tn]`` of partial sums."""
     tm = row_tile(m, groups)
     tn = _divisor_tile(n, 1024)
+    if k % _LANES:      # not whole lanes: the whole contraction, one tile
+        return tm, k, tn
     tk = _LANES
     for t in range(_LANES, k + 1, _LANES):
         if k % t == 0 and vmem_limit((tm, t, tn), rhs_itemsize,
@@ -193,16 +202,37 @@ def noting_calls(table: dict):
         _noting.table = before
 
 
-def route_grouped_matmul(platform: str, k: int, n: int) -> str:
-    """The kernel on a TPU where both matrix dimensions fill the lanes,
-    ``ragged_dot`` otherwise."""
-    if platform == "tpu" and k % _LANES == 0 and n % _LANES == 0:
+# the most rows a tile holds (``row_tile``): what a width off the lanes is
+# admitted at, since the route is asked before the rows are known
+_ROWS_MOST = 2 * _UNIT_ROWS
+
+
+def route_grouped_matmul(platform: str, k: int, n: int,
+                         rhs_itemsize: int = 2) -> str:
+    """The kernel on a TPU where both matrix dimensions fill the lanes, or
+    where one that does not (wider than the lanes, and whole sublanes of 16
+    all the same) fits the call's VMEM as ONE tile that spans it, at the
+    largest row tile:
+    nemotron_h's ``[2688, 1856]`` and ``[1856, 2688]`` in bfloat16.
+    ``ragged_dot`` otherwise: on a ``LayerOf`` stack that slices the layer
+    out first, a copy of it in HBM a call."""
+    if platform != "tpu":
+        return PATH_XLA
+    if k % _LANES == 0 and n % _LANES == 0:
+        return PATH_KERNEL
+    if k % 16 or n % 16 or min(k, n) < _LANES:
+        return PATH_XLA
+    # the tile ``tiling`` would give it there: whole where off the lanes,
+    # the smallest the rule can fall back to where not
+    tiles = (_ROWS_MOST, k if k % _LANES else _LANES, _divisor_tile(n, 1024))
+    if vmem_limit(tiles, rhs_itemsize, 2) <= _VMEM_BUDGET:
         return PATH_KERNEL
     return PATH_XLA
 
 
 def _gmm_kernel(offsets_ref, group_ids_ref, m_tile_ids_ref, layer_ref,
-                lhs_ref, rhs_ref, *refs, tm, tn, tiles_k, quantized):
+                lhs_ref, rhs_ref, *refs, tm, tn, tiles_k, quantized,
+                transposed=False):
     del layer_ref                       # the index maps read it
     if quantized:
         scale_ref, out_ref, acc_ref = refs
@@ -216,8 +246,10 @@ def _gmm_kernel(offsets_ref, group_ids_ref, m_tile_ids_ref, layer_ref,
 
     lhs = lhs_ref[...]
     # an int8 tile is widened here, in VMEM: HBM holds the integers only
+    # (a transposed right-hand tile is [tn, tk]: both contract their last)
     acc_ref[...] += jax.lax.dot_general(
-        lhs, rhs_ref[...].astype(lhs.dtype), (((1,), (0,)), ((), ())),
+        lhs, rhs_ref[...].astype(lhs.dtype),
+        (((1,), (1 if transposed else 0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     @pl.when(k_i == tiles_k - 1)
@@ -233,18 +265,19 @@ def _gmm_kernel(offsets_ref, group_ids_ref, m_tile_ids_ref, layer_ref,
             mine, acc, out_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("tiles", "interpret", "transposed"))
 def _moe_gmm_call(lhs, rhs, scale, group_sizes, layer, *, tiles,
-                  interpret=False):
+                  interpret=False, transposed=False):
     """The Pallas call.  ``lhs`` [m, k] with ``m % tm == 0``; ``rhs``
     [L, E, k, n] (int8 with ``scale`` [L, E or 1, 1, n] float32, or the
-    rows' dtype with ``scale`` None); ``layer`` [1] int32 picks the
-    layer."""
+    rows' dtype with ``scale`` None), or ``[L, E, n, k]`` where
+    ``transposed``; ``layer`` [1] int32 picks the layer."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import (
         make_group_metadata)
     tm, tk, tn = tiles
     m, k = lhs.shape
-    n = rhs.shape[3]
+    n = rhs.shape[2 if transposed else 3]
     tiles_k, tiles_n = k // tk, n // tn
     (offsets, group_ids, m_tile_ids), visits = make_group_metadata(
         group_sizes=group_sizes, m=-(-m // tm) * tm, tm=tm,
@@ -255,6 +288,10 @@ def _moe_gmm_call(lhs, rhs, scale, group_sizes, layer, *, tiles,
     in_specs = [
         pl.BlockSpec((tm, tk), lambda n_i, v, k_i, off, gid, mid, lay:
                      (mid[v], k_i)),
+        pl.BlockSpec((None, None, tn, tk),
+                     lambda n_i, v, k_i, off, gid, mid, lay:
+                     (lay[0], gid[v], n_i, k_i))
+        if transposed else
         pl.BlockSpec((None, None, tk, tn),
                      lambda n_i, v, k_i, off, gid, mid, lay:
                      (lay[0], gid[v], k_i, n_i)),
@@ -268,7 +305,7 @@ def _moe_gmm_call(lhs, rhs, scale, group_sizes, layer, *, tiles,
         operands.append(scale)
     return pl.pallas_call(
         functools.partial(_gmm_kernel, tm=tm, tn=tn, tiles_k=tiles_k,
-                          quantized=quantized),
+                          quantized=quantized, transposed=transposed),
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
@@ -305,7 +342,8 @@ def _ragged(lhs, rhs, scale, group_sizes):
 
 def grouped_matmul(lhs: jax.Array, rhs, group_sizes: jax.Array, *,
                    routed: Optional[int] = None, backend: str = "auto",
-                   interpret: bool = False) -> jax.Array:
+                   interpret: bool = False,
+                   transposed: bool = False) -> jax.Array:
     """``out[r] = lhs[r] @ rhs[g(r)]`` for rows sorted by group.
 
     ``lhs`` [m, k]; ``rhs`` [E, k, n] as an array of the rows' dtype, a
@@ -317,13 +355,28 @@ def grouped_matmul(lhs: jax.Array, rhs, group_sizes: jax.Array, *,
     rows were routed over, where ``rhs`` holds a share of them and the
     other groups' rows lie past the last group (default: ``E``); the row
     tile follows ``m / routed``.  ``backend``: "auto" (the rule above),
-    "xla", or "pallas" (tests: the kernel in interpret mode)."""
+    "xla", or "pallas" (tests: the kernel in interpret mode).
+    ``transposed``: ``rhs`` holds each group's matrix as ``[n, k]``, and
+    the product is ``lhs[r] @ rhs[g(r)].T``: how a stack whose ``n`` is
+    not whole lanes is STORED (nemotron_h's up projection, ``[1856,
+    2688]`` a group).  The chip lays an array's lane-filling dimension
+    minor, so ``[.., 2688, 1856]`` sits in HBM transposed and a custom
+    call that wants it row-major gets a copy of the whole stack first,
+    2.6 GB a call at four ``E`` blocks (libtpu's analysis, PR 66); stored
+    ``[.., 1856, 2688]`` it is read where it lies, a ``[tn, tk]`` tile
+    contracted on its last dimension."""
     m, k = lhs.shape
-    n = rhs.shape[2]
+    n = rhs.shape[1 if transposed else 2]
+    if transposed and isinstance(getattr(rhs, "stack", rhs),
+                                 (QuantizedArray, QuantizedArray4)):
+        raise ValueError("a transposed right-hand side is a plain array "
+                         "of the rows' dtype")
     routed = routed or rhs.shape[0]
+    item = (1 if isinstance(getattr(rhs, "stack", rhs), QuantizedArray)
+            else lhs.dtype.itemsize)
     path = (PATH_XLA if backend == "xla" else PATH_KERNEL
             if backend == "pallas"
-            else route_grouped_matmul(jax.default_backend(), k, n))
+            else route_grouped_matmul(jax.default_backend(), k, n, item))
     layer = jnp.zeros((1,), jnp.int32)
     if isinstance(rhs, LayerOf):
         if path == PATH_XLA or isinstance(rhs.stack, QuantizedArray4):
@@ -337,12 +390,15 @@ def grouped_matmul(lhs: jax.Array, rhs, group_sizes: jax.Array, *,
         rhs, scale = rhs.q, rhs.scale
     shape = (m, k, n, rhs.dtype.itemsize, routed, lhs.dtype.itemsize)
     table = getattr(_noting, "table", None)
-    if table is not None and route_grouped_matmul("tpu", k, n) == PATH_KERNEL:
+    if table is not None and route_grouped_matmul(
+            "tpu", k, n, item) == PATH_KERNEL:
         table[shape] = call_shape(*shape)
     if path == PATH_XLA:
-        return _ragged(lhs, rhs, scale, group_sizes)
+        return _ragged(lhs, jnp.swapaxes(rhs, 1, 2) if transposed else rhs,
+                       scale, group_sizes)
     if rhs.ndim == 3:                   # a stack of one layer
         rhs = rhs[None]
         scale = None if scale is None else scale[None]
     return _moe_gmm_call(lhs, rhs, scale, group_sizes.astype(jnp.int32),
-                         layer, tiles=tiling(*shape), interpret=interpret)
+                         layer, tiles=tiling(*shape), interpret=interpret,
+                         transposed=transposed)

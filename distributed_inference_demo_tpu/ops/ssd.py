@@ -3,7 +3,8 @@ head over a recurrent state.
 
 A head holds a state ``S`` ``[P, N]`` in float32 (``P`` the head's channels,
 ``N`` the state size) and every token rewrites it (docs/DESIGN.md section
-29); ``B`` and ``C`` ``[N]`` are shared by the heads of a group:
+29); ``B`` and ``C`` ``[N]`` are shared by the heads of a group (head ``h``
+of ``H`` in ``G`` groups reads group ``h // (H / G)``):
 
     a_t = exp(dt_t A)                              dt > 0, A < 0 a head: a in (0, 1)
     S_t = a_t S_{t-1} + dt_t x_t B_t^T             x_t [P]
@@ -36,8 +37,13 @@ inside a chunk the token-to-token weights are a 1-semiseparable mask over
 A token that is not there (a padded position, a row that decodes nothing)
 has ``dt = 0``: it leaves the state as it was, bit for bit (a dead row of
 :func:`ssd_step` is not touched at all).  On the chip both ops are Pallas
-calls at a state of ``[128 k heads, 8 j, 128]`` and one group; elsewhere,
-and in float32 tests, plain XLA with the same arithmetic.
+calls at a state of ``[128 k heads, 8 j, 128]`` of one group (granite)
+or of ``[heads, 8 j, 128]`` in groups of 8 or 16 heads (nemotron_h's 64
+heads in eight groups): the step rides a row's every head on the lanes
+and reads a head's B and C from its group's row; the chunk call takes a
+block of heads OF ONE GROUP a grid step (16, or the group's where it has
+fewer) with that group's ``B C^T``.  Elsewhere, and in float32 tests, plain
+XLA with the same arithmetic.
 """
 
 from __future__ import annotations
@@ -54,27 +60,40 @@ F32 = jnp.float32
 
 # the published chunk of the scan; a shorter segment is one chunk
 CHUNK = 256
-# heads a grid step of the chunk kernel (the step takes a row's every head)
+# heads a grid step of the chunk kernel at most, all of one group (the step
+# takes a row's every head)
 _CHUNK_HEADS = 16
 _VMEM = 48 * 1024 * 1024
 
 
 def on_kernel(state_shape, groups: int = 1, chunk: int = 1,
               backend: str = "auto", platform=None) -> tuple:
-    """``(kernel?, why not)``: the Pallas calls serve a state of
-    ``[.., 128 k heads, 8 j, 128]`` of one group on a TPU, a segment in
-    chunks of whole lanes."""
+    """``(kernel?, why not)``: the Pallas calls serve, on a TPU, a state
+    of ``[.., 128 k heads, 8 j, 128]`` of one group, or of ``[.., heads, 8
+    j, 128]`` in groups of 8 or 16 heads (a head block of the chunk call
+    is then one group), a segment in chunks of whole lanes."""
     platform = platform or jax.default_backend()
     if backend == "xla":
         return False, "backend xla"
     if platform != "tpu" and backend != "pallas":
         return False, f"platform {platform}"
     h, p, n = state_shape[-3:]
-    if n != 128 or p % 8 or h % 128 or groups != 1:
+    # what has been through Mosaic and read on the chip: one group of 128 k
+    # heads (granite), or groups of 8 or 16 heads, one head block each
+    # (nemotron_h's 64 heads in 8)
+    per = h // groups if h % groups == 0 else 0
+    if n != 128 or p % 8 or not (
+            h % 128 == 0 if groups == 1 else per in (8, _CHUNK_HEADS)):
         return False, f"state {h} x {p} x {n}, {groups} groups"
     if chunk > 1 and chunk % 128:
         return False, f"chunk {chunk} not whole lanes of 128"
     return True, ""
+
+
+def _chunk_heads(heads: int, groups: int) -> int:
+    """Heads a grid step of the chunk kernel: ``_CHUNK_HEADS``, or the
+    group's where it has fewer (a block never spans two groups)."""
+    return min(_CHUNK_HEADS, heads // groups)
 
 
 def _dot(a, b):
@@ -98,10 +117,10 @@ def _step_math(S, x, B, C, dt, A):
 
 
 def _ssd_step_kernel(rows_ref, plane_ref, mode_ref, ax_ref, bc_ref, s_ref,
-                     y_ref, out_ref, *, heads: int):
+                     y_ref, out_ref, *, heads: int, groups: int):
     """Grid (rows,).  ``ax_ref`` ``[1, 2, P, H]``: the decay ``a`` (spread
     over ``P``) and ``(dt x)^T``, head ``h`` in lane ``h``; ``bc_ref``
-    ``[1, 2, N]``: B and C; ``s_ref`` / ``out_ref`` ``[1, 1, H, P, N]``,
+    ``[1, 2, G, N]``: B and C, a row a group; ``s_ref`` / ``out_ref`` ``[1, 1, H, P, N]``,
     the same block of the pool; ``y_ref`` ``[1, P, H]``, the output
     transposed.  float32 throughout.  ``mode_ref[i]``: 1 a live row, worked
     here; 0 a dead row that names the block of the row before it, which
@@ -113,9 +132,10 @@ def _ssd_step_kernel(rows_ref, plane_ref, mode_ref, ax_ref, bc_ref, s_ref,
 
     @pl.when(mode == 1)
     def _live():
-        B = bc_ref[0, 0:1, :]                       # [1, N]
-        C = bc_ref[0, 1:2, :]
         for h in range(heads):
+            g = h // (heads // groups)              # the head's group
+            B = bc_ref[0, 0, g:g + 1, :]            # [1, N]
+            C = bc_ref[0, 1, g:g + 1, :]
             a = ax_ref[0, 0, :, h:h + 1]            # [P, 1]
             dx = ax_ref[0, 1, :, h:h + 1]
             S = s_ref[0, 0, h] * a + dx * B         # [P, N]
@@ -129,21 +149,21 @@ def _ssd_step_kernel(rows_ref, plane_ref, mode_ref, ax_ref, bc_ref, s_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _ssd_step_call(rows, plane, mode, ax, bc, state, *, interpret=False):
-    """``ax`` ``[b, 2, P, H]``, ``bc`` ``[b, 2, N]``, ``state`` ``[Pl, R,
-    H, P, N]`` aliased to the second output; row ``i`` works on
+    """``ax`` ``[b, 2, P, H]``, ``bc`` ``[b, 2, G, N]``, ``state`` ``[Pl,
+    R, H, P, N]`` aliased to the second output; row ``i`` works on
     ``state[plane, rows[i]]`` as ``mode[i]`` says (the kernel's words)."""
     b, _, P, H = ax.shape
-    N = bc.shape[-1]
+    G, N = bc.shape[-2:]
     s_spec = pl.BlockSpec((1, 1, H, P, N),
                           lambda i, rows, plane, mode: (plane[0], rows[i],
                                                         0, 0, 0))
     return pl.pallas_call(
-        functools.partial(_ssd_step_kernel, heads=H),
+        functools.partial(_ssd_step_kernel, heads=H, groups=G),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b,),
             in_specs=[pl.BlockSpec((1, 2, P, H), lambda i, *_: (i, 0, 0, 0)),
-                      pl.BlockSpec((1, 2, N), lambda i, *_: (i, 0, 0)),
+                      pl.BlockSpec((1, 2, G, N), lambda i, *_: (i, 0, 0, 0)),
                       s_spec],
             out_specs=[pl.BlockSpec((1, P, H), lambda i, *_: (i, 0, 0)),
                        s_spec]),
@@ -207,7 +227,7 @@ def ssd_step(state, plane, rows, x, B, C, dt, A, live, *,
         rows, mode = _blocks_of(rows, live, trash)
         yT, state = _ssd_step_call(
             rows, jnp.reshape(plane, (1,)).astype(jnp.int32), mode, ax,
-            jnp.concatenate([B, C], axis=1), state, interpret=interpret)
+            jnp.stack([B, C], axis=1), state, interpret=interpret)
         return (jnp.where(live[:, None, None], jnp.swapaxes(yT, 1, 2), 0.0),
                 state)
     # XLA: the pool's plane is worked on where it lies, every row of it,
@@ -264,9 +284,9 @@ def _ssd_chunk_kernel(row_ref, plane_ref, fresh_ref, dx_ref, l_ref, lt_ref,
     ``l_ref`` ``[1, heads, Q]`` the running log decay as rows, ``lt_ref``
     ``[1, 1, Q, heads]`` the same as columns, ``le_ref`` ``[1, heads, N]``
     its last entry spread over the state's lanes (Mosaic spreads one number
-    over one axis at a time), ``b_ref`` ``[1, Q, N]`` and
-    ``ct_ref`` ``[1, N, Q]`` the one group's B and C^T; ``y_ref`` ``[1,
-    heads, P, Q]`` the output transposed."""
+    over one axis at a time), ``b_ref`` ``[1, 1, Q, N]`` and
+    ``ct_ref`` ``[1, 1, N, Q]`` B and C^T of the head block's group;
+    ``y_ref`` ``[1, heads, P, Q]`` the output transposed."""
     del row_ref, plane_ref
 
     @pl.when(pl.program_id(1) == 0)
@@ -274,7 +294,7 @@ def _ssd_chunk_kernel(row_ref, plane_ref, fresh_ref, dx_ref, l_ref, lt_ref,
         s0 = s_ref[...]
         out_ref[...] = jnp.where(fresh_ref[0] > 0, jnp.zeros_like(s0), s0)
 
-    B, CT = b_ref[0], ct_ref[0]
+    B, CT = b_ref[0, 0], ct_ref[0, 0]
     dt_ = B.dtype
     Q = B.shape[0]
     G = _dot(B, CT)                                 # [s, t] = B_s . C_t
@@ -299,11 +319,13 @@ def _ssd_chunk_kernel(row_ref, plane_ref, fresh_ref, dx_ref, l_ref, lt_ref,
 def _ssd_chunk_call(row, plane, fresh, dxT, l, lT, lE, Bm, CT, state, *,
                     interpret=False):
     """``dxT`` ``[n, H, P, Q]``, ``l`` ``[n, H, Q]``, ``lT`` ``[n, H / hb,
-    Q, hb]``, ``lE`` ``[n, H, N]``, ``Bm`` ``[n, Q, N]``, ``CT`` ``[n, N,
-    Q]``; ``state`` aliased to the second output."""
+    Q, hb]``, ``lE`` ``[n, H, N]``, ``Bm`` ``[n, G, Q, N]``, ``CT`` ``[n,
+    G, N, Q]``; ``state`` aliased to the second output.  Head block ``j``
+    lies in group ``j hb // (H / G)``."""
     n, H, P, Q = dxT.shape
-    N = Bm.shape[-1]
+    G, N = Bm.shape[1], Bm.shape[-1]
     hb = lT.shape[-1]
+    per = (H // G) // hb            # head blocks a group
     s_spec = pl.BlockSpec(
         (1, 1, hb, P, N),
         lambda j, i, row, plane, fresh: (plane[0], row[0], j, 0, 0))
@@ -318,8 +340,10 @@ def _ssd_chunk_call(row, plane, fresh, dxT, l, lT, lE, Bm, CT, state, *,
                       pl.BlockSpec((1, 1, Q, hb),
                                    lambda j, i, *_: (i, j, 0, 0)),
                       pl.BlockSpec((1, hb, N), lambda j, i, *_: (i, j, 0)),
-                      pl.BlockSpec((1, Q, N), lambda j, i, *_: (i, 0, 0)),
-                      pl.BlockSpec((1, N, Q), lambda j, i, *_: (i, 0, 0)),
+                      pl.BlockSpec((1, 1, Q, N),
+                                   lambda j, i, *_: (i, j // per, 0, 0)),
+                      pl.BlockSpec((1, 1, N, Q),
+                                   lambda j, i, *_: (i, j // per, 0, 0)),
                       s_spec],
             out_specs=[tile, s_spec]),
         out_shape=[jax.ShapeDtypeStruct(dxT.shape, dxT.dtype),
@@ -360,7 +384,7 @@ def ssd_chunk(state, plane, row, fresh, x, B, C, dt, A, *,
     dx, B, C = cut(dx), cut(B), cut(C)
     l = jnp.cumsum(cut(dA), axis=1)                         # [n, Q, H]
     if kernel:
-        hb = _CHUNK_HEADS
+        hb = _chunk_heads(H, B.shape[-2])
         one = lambda a: jnp.reshape(a, (1,)).astype(jnp.int32)
         yT, state = _ssd_chunk_call(
             one(row), one(plane), one(fresh),
@@ -368,7 +392,8 @@ def ssd_chunk(state, plane, row, fresh, x, B, C, dt, A, *,
             jnp.swapaxes(l, 1, 2),
             jnp.swapaxes(l.reshape(n, chunk, H // hb, hb), 1, 2),
             jnp.broadcast_to(l[:, -1, :, None], (n, H, B.shape[-1])),
-            B[:, :, 0], jnp.swapaxes(C[:, :, 0], 1, 2), state,
+            jnp.swapaxes(B, 1, 2),                          # [n, G, Q, N]
+            jnp.transpose(C, (0, 2, 3, 1)), state,          # [n, G, N, Q]
             interpret=interpret)
         y = jnp.transpose(yT, (0, 3, 1, 2))                 # [n, Q, H, P]
     else:

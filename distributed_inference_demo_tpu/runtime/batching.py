@@ -2606,6 +2606,18 @@ class ContinuousBatchingEngine:
         # steps/dispatches ≈ decode_block when fusion is engaging
         out["device_loop"] = dict(self.loop_stats,
                                   decode_block=self.decode_block)
+        if any(not k.mlp or k.attn == "none" for k in self.cfg.period):
+            # a period of blocks of ONE sublayer: the blocks a kind (by
+            # the name its stacks and paths carry) and the planes a pool;
+            # a block without a mixer holds a plane of neither
+            cfg = self.cfg
+            out["blocks"] = {
+                "kinds": {name: cfg.num_layers * len(at)
+                          for name, _, at in cfg.kinds},
+                "planes": {"pages": [planes for _, planes
+                                     in self._pool_specs],
+                           "state": cfg.state_planes},
+                "with_experts": cfg.mlp_blocks}
         # kernel routing made visible: per compiled program, which
         # attention path each of its chunk shapes was traced onto
         out["attention_paths"] = self.attn_paths.snapshot()
@@ -2994,7 +3006,7 @@ class ContinuousBatchingEngine:
         if not cfg.period:
             return ((C, 0),)
         kinds = ([cfg.lead_kind] if cfg.lead_kind is not None else []
-                 ) + [k for k in cfg.period if not k.is_state]
+                 ) + [k for k in cfg.period if k.has_pages]
         return tuple(
             (sub_chunk(C, next(k for k in kinds if k.window == window)
                        .num_heads // cfg.num_kv_heads), window)
@@ -4693,7 +4705,9 @@ class ContinuousBatchingEngine:
         if self.moe_counters is not None:
             # real tokens: the live segments' prompt tokens, and the
             # steps of the slots that decoded (rows that finish inside
-            # the block still step to its end); each is k rows a layer.
+            # the block still step to its end); each is k rows a layer
+            # that has experts (a period of blocks of one sublayer has
+            # them in its ``E`` blocks alone: ``ModelConfig.mlp_blocks``).
             # They are the rows the device routed: a row that holds no
             # token enters no expert's group
             acc = np.asarray(flight.out[5])
@@ -4703,7 +4717,7 @@ class ContinuousBatchingEngine:
                 (prefill_tokens + n_active * steps
                  + len(plan.finals) * max(0, steps - plan.carried))
                 * self.cfg.experts_per_token
-                * (self.cfg.total_layers - self.cfg.lead_dense_layers)
+                * self.cfg.mlp_blocks   # the blocks that have experts
                 * self.cfg.ut_steps))
         if self.loop_counters is not None:
             # (a step that rode the slab's pass is no pass of its own)

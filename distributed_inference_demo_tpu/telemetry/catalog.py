@@ -406,6 +406,21 @@ KVCACHE_STATE_HELD_SLOTS = gauge(
     "Rows of the state pool that requests hold now (state.held)")
 
 
+# -- a period of blocks of one sublayer (``/stats.blocks``; docs/DESIGN.md
+# section 31): absent for every other model ---------------------------------
+
+BATCH_KIND_BLOCKS = gauge(
+    "dwt_batching_kind_blocks",
+    "Blocks of the repeated stack by kind of block, the name its stacks "
+    "and paths carry (blocks.kinds: ssd, full, window, kda, or mlp for a "
+    "block of the experts alone, which holds no plane of any pool)",
+    ("kind",))
+BATCH_EXPERT_BLOCKS = gauge(
+    "dwt_batching_expert_blocks",
+    "Blocks that have routed experts (blocks.with_experts): what the moe "
+    "section's rows, valid_rows and layer_calls are counted over")
+
+
 def update_batching_series(stats: dict) -> None:
     """Bridge ``ContinuousBatchingEngine.stats()`` (or any dict with the
     same keys) onto the ``dwt_batching_*`` / ``dwt_speculative_*`` /
@@ -451,6 +466,11 @@ def update_batching_series(stats: dict) -> None:
             state.get("chunk_tokens", 0))
         KVCACHE_STATE_SLOT_BYTES.set(state.get("bytes_per_slot", 0))
         KVCACHE_STATE_HELD_SLOTS.set(state.get("held", 0))
+    blocks = stats.get("blocks") or {}
+    if blocks:
+        for kind, n in blocks.get("kinds", {}).items():
+            BATCH_KIND_BLOCKS.set(n, kind=kind)
+        BATCH_EXPERT_BLOCKS.set(blocks.get("with_experts", 0))
     hc = stats.get("hc") or {}
     if hc:
         BATCH_HC_ROWS.set_cumulative(hc.get("rows", 0))

@@ -37,6 +37,18 @@ are what a form's passes move through HBM by its shapes (a float32
 ``[T, k, H]`` pads ``k`` to a multiple of 8), ``floor`` the bf16 rows
 read once and ``[T, H]`` float32 written, over 819 GB/s.  ``err`` is
 ``bf16`` against ``before`` on the same rows.
+
+``--two-matrix`` times the experts of TWO matrices instead (nemotron_h:
+``down(relu(up h) ** 2)``, an expert width of 1,856 = 14.5 x 128; PERF.md
+section 6, PR 66), the up and the down call at the same slab and decode
+rows in the two forms a width off the lanes can be stored in:
+``transposed`` (the up projection ``[1856, 2688]`` a group, one tile that
+spans the 1,856, contracted on its last dimension; the down projection
+``[1856, 2688]`` with the whole contraction one tile) and ``padded``
+(columns zero-padded to 1,920 = 15 x 128: ``[2688, 1920]`` and ``[1920,
+2688]``, the kernel's ordinary path; ``relu ** 2`` leaves the padding
+exactly zero).  ``hbm`` is the touched experts' matrices at the PUBLISHED
+width in both forms.
 """
 from __future__ import annotations
 
@@ -72,11 +84,16 @@ def tiles_before(m: int, k: int, n: int, itemsize: int) -> tuple:
     return tm, gmm._divisor_tile(k, max(128, (2 << 20) // (tn * itemsize))), tn
 
 
-def expert_configs(names):
+def expert_configs(names, two_matrix: bool = False):
+    """The configurations with gated experts (three calls a block: the
+    table's), or with ``two_matrix`` those whose experts are of two
+    matrices (``--two-matrix``'s rows)."""
     for path in sorted((ROOT / "benchmark" / "configs").glob("*.json")):
         conf = json.loads(path.read_text())
         mc = conf["model_config"]
         if not mc.get("num_experts") or (names and conf["name"] not in names):
+            continue
+        if (mc.get("mlp_act") == "relu2") != two_matrix:
             continue
         flags = conf["serve_flags"]
         flag = lambda name: int(flags[flags.index(name) + 1])  # noqa: E731
@@ -112,13 +129,14 @@ def stack(key, c: dict, k: int, n: int):
 
 
 def us_a_call(lhs, rhs, scale, sizes, tiles, reps: int,
-              interpret: bool) -> float:
+              interpret: bool, transposed: bool = False) -> float:
     @jax.jit
     def calls(lhs, rhs, scale, sizes):
         def one(carry, i):
             out = gmm._moe_gmm_call(lhs, rhs, scale, sizes,
                                     (i % LAYERS)[None], tiles=tiles,
-                                    interpret=interpret)
+                                    interpret=interpret,
+                                    transposed=transposed)
             return carry + out[0, 0].astype(jnp.float32), None
         return jax.lax.scan(one, jnp.float32(0),
                             jnp.arange(reps, dtype=jnp.int32))[0]
@@ -166,6 +184,67 @@ def rows_of(c: dict, args):
                            - want[:in_groups].astype(jnp.float32))
             row["err"] = float(diff.max() / jnp.abs(want[:in_groups]).max())
             yield row
+
+
+def _lanes_up(n: int) -> int:
+    return -(-n // 128) * 128
+
+
+def two_matrix_rows_of(c: dict, args):
+    """An expert of two matrices, ``[H, I]`` then ``[I, H]`` at an ``I``
+    that is not whole lanes, in the two stored forms: one row a (call,
+    form, projection)."""
+    H, I = c["hidden"], c["inter"]
+    Ip = _lanes_up(I)
+    key = jax.random.PRNGKey(args.seed)
+    up = jax.random.normal(key, (LAYERS, c["held"], H, I),
+                           jnp.bfloat16) * H ** -0.5
+    down = jax.random.normal(jax.random.fold_in(key, 1),
+                             (LAYERS, c["held"], I, H),
+                             jnp.bfloat16) * I ** -0.5
+    pad = lambda a, axis: jnp.pad(  # noqa: E731
+        a, [(0, Ip - I) if i == axis else (0, 0) for i in range(4)])
+    # one form's stacks at a time beside the plain pair (1.9 GB a stack)
+    forms = {"transposed": lambda: (jnp.swapaxes(up, 2, 3), down),
+             "padded": lambda: (pad(up, 3), pad(down, 2))}
+    for form, stacks in forms.items():
+        w_up, w_down = stacks()
+        transposed = form == "transposed"
+        width = I if transposed else Ip
+        for call, tokens in c["tokens"].items():
+            m = tokens * c["top_k"]
+            sizes_np = group_sizes(c, tokens, args.seed, args.even)
+            sizes = jnp.asarray(sizes_np)
+            touched, in_groups = (int((sizes_np > 0).sum()),
+                                  int(sizes_np.sum()))
+            x = jax.random.normal(key, (m, H), jnp.bfloat16)
+            hidden = jnp.pad(jax.random.normal(key, (m, I), jnp.bfloat16),
+                             ((0, 0), (0, width - I)))
+            for proj, lhs, rhs, k, n, t, plain in (
+                    ("up", x, w_up, H, width, transposed, up),
+                    ("down", hidden, w_down, width, H, False, down)):
+                tiles = gmm.tiling(m, k, n, 2, c["routed"])
+                us = us_a_call(lhs, rhs, None, sizes, tiles, args.reps,
+                               args.rehearse, transposed=t)
+                got = gmm._moe_gmm_call(
+                    lhs, rhs, None, sizes, jnp.zeros((1,), jnp.int32),
+                    tiles=tiles, interpret=args.rehearse, transposed=t)
+                want = gmm._ragged(lhs[:, :plain.shape[2]], plain[0], None,
+                                   sizes)
+                cols = want.shape[1]
+                diff = jnp.abs(got[:in_groups, :cols].astype(jnp.float32)
+                               - want[:in_groups].astype(jnp.float32))
+                yield dict(
+                    config=c["name"], call=call, two_matrix=True, form=form,
+                    proj=proj, m=m, k=k, n=n, rows_in_groups=in_groups,
+                    touched=touched, tiles=list(tiles),
+                    hbm_us=round(1e-3 * touched * H * I * 2 / PEAKS.hbm_gbs,
+                                 1),
+                    mxu_us=round(1e-6 * 2 * in_groups * H * I
+                                 / PEAKS.bf16_tflops, 1),
+                    us=round(us, 1),
+                    err=float(diff.max() / jnp.abs(want[:in_groups]).max()))
+        del w_up, w_down
 
 
 def combine_before(out, order, weights, written):
@@ -254,6 +333,8 @@ def combine_rows_of(c: dict, args):
 
 HEAD = ["config", "call", "proj", "m", "rows/group", "hbm", "mxu", "before",
         "us", *(f"k,tm={tm}" for tm in ROW_TILES), "rule", "err"]
+TWO_MATRIX_HEAD = ["config", "call", "form", "proj", "m", "k", "n", "touched",
+                   "hbm", "mxu", "tiles", "us", "err"]
 COMBINE_HEAD = ["config", "call", "tokens", "k", "H", "floor",
                 *(f"{name}: MB, us" for name in COMBINE_FORMS), "err"]
 
@@ -264,6 +345,12 @@ def cells(row: dict) -> list:
             "x".join(map(str, row["before"])), row["before_us"],
             *(row[f"k_tm{tm}_us"] for tm in ROW_TILES),
             "x".join(map(str, row["rule"])), f"{row['err']:.1e}"]
+
+
+def two_matrix_cells(row: dict) -> list:
+    return [row["config"], row["call"], row["form"], row["proj"], row["m"],
+            row["k"], row["n"], row["touched"], row["hbm_us"], row["mxu_us"],
+            "x".join(map(str, row["tiles"])), row["us"], f"{row['err']:.1e}"]
 
 
 def combine_cells(row: dict) -> list:
@@ -288,6 +375,10 @@ def main() -> int:
     ap.add_argument("--combine", action="store_true",
                     help="the combine after the down projection alone, "
                          "not the grouped matmul")
+    ap.add_argument("--two-matrix", action="store_true",
+                    help="the experts of two matrices (an expert width "
+                         "off the lanes) in their two stored forms, not "
+                         "the gated experts' table")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=str(ROOT / "chiprun_out"
                                          / "gmm_table.jsonl"))
@@ -301,12 +392,13 @@ def main() -> int:
           f"us a call, least of 3 x {args.reps}")
     rows_of_config, head, cells_of = (
         (combine_rows_of, COMBINE_HEAD, combine_cells) if args.combine
-        else (rows_of, HEAD, cells))
+        else (two_matrix_rows_of, TWO_MATRIX_HEAD, two_matrix_cells)
+        if args.two_matrix else (rows_of, HEAD, cells))
     print("| " + " | ".join(head) + " |")
     print("|" + "---|" * len(head))
     Path(args.out).parent.mkdir(parents=True, exist_ok=True)
     with open(args.out, "a") as out:
-        for c in expert_configs(args.config):
+        for c in expert_configs(args.config, args.two_matrix):
             if args.rehearse:
                 c["tokens"] = {"slab": 48, "decode": 8}
             for row in rows_of_config(c, args):
