@@ -16,11 +16,17 @@ inside a chunk the token-to-token weights are a 1-semiseparable mask over
 
 * ``_ssd_step`` (:func:`ssd_step`): one token a row.  A row's whole block
   of the pool ``[heads, P, N]`` is read, decayed, updated and written in
-  place; a row that decodes nothing moves no block of the pool (it names
-  the block of the live row before it, which the call already holds).
-  What is a column over ``P`` in the arithmetic (``dt x`` and the
-  output) rides TRANSPOSED, ``[P, heads]``: head ``h``'s is lane ``h``, and
-  no transpose runs in the kernel.
+  place; the rows that decode go FIRST, and a row that decodes nothing
+  moves no block of the pool and waits for none (it names what the last
+  live row named, which the call already holds).  The block is worked a
+  TILE of 128 ``(h, p)`` rows at a time (two heads where ``P`` = 64): the
+  decay is a scalar, ``dt x`` and the output are the arrays as they lie,
+  ``[heads P / 128, 128]``, a tile a row of lanes; ``dt x`` becomes the
+  column the arithmetic wants by one transpose of its row laid on every
+  sublane, and ``y = S C`` is summed over the sublanes of the tile's one
+  transpose.  No lane reduce and no lane-slice broadcast a register: the
+  call is bound by what the chip's DMA moves in beside what it moves out
+  (docs/DESIGN.md section 29 has the table).
 * ``_ssd_chunk`` (:func:`ssd_chunk`): a prefill segment, ``chunk`` tokens a
   pass.  With ``l`` the running sum of ``dt A`` inside the chunk (<= 0, and
   every difference below is formed BEFORE its exponential, so no exponent
@@ -37,10 +43,11 @@ inside a chunk the token-to-token weights are a 1-semiseparable mask over
 A token that is not there (a padded position, a row that decodes nothing)
 has ``dt = 0``: it leaves the state as it was, bit for bit (a dead row of
 :func:`ssd_step` is not touched at all).  On the chip both ops are Pallas
-calls at a state of ``[128 k heads, 8 j, 128]`` of one group (granite)
-or of ``[heads, 8 j, 128]`` in groups of 8 or 16 heads (nemotron_h's 64
-heads in eight groups): the step rides a row's every head on the lanes
-and reads a head's B and C from its group's row; the chunk call takes a
+calls at a state of ``[128 k heads, P, 128]`` of one group (granite) or
+of ``[heads, P, 128]`` in groups of 8 or 16 heads (nemotron_h's 64 heads
+in eight groups), ``P`` a power of two from 8 to 128 whose heads fill
+whole tiles: the step reads a head's B and C from its group's row; the
+chunk call takes a
 block of heads OF ONE GROUP a grid step (16, or the group's where it has
 fewer) with that group's ``B C^T``.  Elsewhere, and in float32 tests, plain
 XLA with the same arithmetic.
@@ -49,6 +56,7 @@ XLA with the same arithmetic.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -63,15 +71,21 @@ CHUNK = 256
 # heads a grid step of the chunk kernel at most, all of one group (the step
 # takes a row's every head)
 _CHUNK_HEADS = 16
+_LANES = 128
+# tiles of the step kernel's loop unrolled together: 8 is within 1 % of
+# the whole loop unrolled and lowers in a fifth of its time
+_UNROLL = 8
 _VMEM = 48 * 1024 * 1024
 
 
 def on_kernel(state_shape, groups: int = 1, chunk: int = 1,
               backend: str = "auto", platform=None) -> tuple:
     """``(kernel?, why not)``: the Pallas calls serve, on a TPU, a state
-    of ``[.., 128 k heads, 8 j, 128]`` of one group, or of ``[.., heads, 8
-    j, 128]`` in groups of 8 or 16 heads (a head block of the chunk call
-    is then one group), a segment in chunks of whole lanes."""
+    of ``[.., 128 k heads, P, 128]`` of one group, or of ``[.., heads, P,
+    128]`` in groups of 8 or 16 heads (a head block of the chunk call is
+    then one group), ``P`` of 8 .. 128 dividing the lanes and the heads in
+    whole tiles of ``128 / P`` (the step works a tile of 128 ``(h, p)``
+    rows at once), a segment in chunks of whole lanes."""
     platform = platform or jax.default_backend()
     if backend == "xla":
         return False, "backend xla"
@@ -82,7 +96,7 @@ def on_kernel(state_shape, groups: int = 1, chunk: int = 1,
     # heads (granite), or groups of 8 or 16 heads, one head block each
     # (nemotron_h's 64 heads in 8)
     per = h // groups if h % groups == 0 else 0
-    if n != 128 or p % 8 or not (
+    if n != 128 or p % 8 or _LANES % p or h % (_LANES // p) or not (
             h % 128 == 0 if groups == 1 else per in (8, _CHUNK_HEADS)):
         return False, f"state {h} x {p} x {n}, {groups} groups"
     if chunk > 1 and chunk % 128:
@@ -116,79 +130,125 @@ def _step_math(S, x, B, C, dt, A):
     return jnp.sum(S * C[..., None, :], axis=-1), S
 
 
-def _ssd_step_kernel(rows_ref, plane_ref, mode_ref, ax_ref, bc_ref, s_ref,
-                     y_ref, out_ref, *, heads: int, groups: int):
-    """Grid (rows,).  ``ax_ref`` ``[1, 2, P, H]``: the decay ``a`` (spread
-    over ``P``) and ``(dt x)^T``, head ``h`` in lane ``h``; ``bc_ref``
-    ``[1, 2, G, N]``: B and C, a row a group; ``s_ref`` / ``out_ref`` ``[1, 1, H, P, N]``,
-    the same block of the pool; ``y_ref`` ``[1, P, H]``, the output
-    transposed.  float32 throughout.  ``mode_ref[i]``: 1 a live row, worked
-    here; 0 a dead row that names the block of the row before it, which
-    therefore neither comes nor goes again and is left as that row left
-    it; 2 a dead row that opens a block (nobody's row, before the first
-    live one): passed through as it came."""
+def _ssd_step_kernel(rows_ref, at_ref, plane_ref, n_ref, a_ref, dx_ref,
+                     bc_ref, s_ref, y_ref, out_ref, *, groups: int,
+                     unroll: int):
+    """Grid (rows,), the ``n_ref[0]`` live rows FIRST: step ``i`` is batch
+    row ``at_ref[i]``.  ``a_ref`` ``[b H]`` in scalar memory: the decay of
+    batch row ``r``'s head ``h`` at ``r H + h``; ``dx_ref`` ``[1, H P /
+    128, 128]``: the row's ``dt x`` as it lies, a TILE of 128 ``(h, p)`` a
+    row of lanes; ``bc_ref`` ``[1, 2, G, N]``: B and C, a row a group;
+    ``s_ref`` / ``out_ref`` ``[1, 1, H, P, N]``, the same block of the
+    pool; ``y_ref`` as ``dx_ref``.  float32 throughout.  A step behind the
+    live ones names what the last live step named, operand for operand:
+    nothing comes or goes for it, its body is skipped and the block is
+    left as that row left it.  Where no row is live every step names
+    nobody's row, which the first passes through as it came.
+
+    A tile's 128 rows of the state (two heads where ``P`` = 64) are
+    worked together: ``dt x`` becomes a column spread over the lanes by ONE
+    transpose of its row laid on every sublane, and the read-out sums
+    ``S C`` over the SUBLANES of the tile's one transpose (vector adds
+    and one fold), so that ``y`` leaves as a whole row of lanes."""
     del rows_ref, plane_ref
-    mode = mode_ref[pl.program_id(0)]
+    i = pl.program_id(0)
+    heads, P, N = s_ref.shape[2:]
+    hp = _LANES // P                                # heads a tile
 
-    @pl.when(mode == 1)
-    def _live():
-        for h in range(heads):
+    def tile(t):
+        dx = jnp.broadcast_to(dx_ref[0, pl.ds(t, 1), :],
+                              (N, _LANES)).T        # [128 (h, p), N]
+        SC = []
+        for k in range(hp):
+            h = t * hp + k
             g = h // (heads // groups)              # the head's group
-            B = bc_ref[0, 0, g:g + 1, :]            # [1, N]
-            C = bc_ref[0, 1, g:g + 1, :]
-            a = ax_ref[0, 0, :, h:h + 1]            # [P, 1]
-            dx = ax_ref[0, 1, :, h:h + 1]
-            S = s_ref[0, 0, h] * a + dx * B         # [P, N]
+            B = bc_ref[0, 0, pl.ds(g, 1), :]        # [1, N]
+            C = bc_ref[0, 1, pl.ds(g, 1), :]
+            S = (s_ref[0, 0, h] * a_ref[at_ref[i] * heads + h]
+                 + dx[k * P:(k + 1) * P] * B)       # [P, N]
             out_ref[0, 0, h] = S
-            y_ref[0, :, h:h + 1] = jnp.sum(S * C, axis=1, keepdims=True)
+            SC.append(S * C)
+        y_ref[0, pl.ds(t, 1), :] = jnp.sum(
+            jnp.concatenate(SC, axis=0).T, axis=0, keepdims=True)
 
-    @pl.when(mode == 2)
+    @pl.when(i < n_ref[0])
+    def _live():
+        def some(q, _):
+            for u in range(unroll):
+                tile(q * unroll + u)
+        jax.lax.fori_loop(0, heads // hp // unroll, some, None)
+
+    @pl.when((n_ref[0] == 0) & (i == 0))
     def _through():
         out_ref[...] = s_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _ssd_step_call(rows, plane, mode, ax, bc, state, *, interpret=False):
-    """``ax`` ``[b, 2, P, H]``, ``bc`` ``[b, 2, G, N]``, ``state`` ``[Pl,
-    R, H, P, N]`` aliased to the second output; row ``i`` works on
-    ``state[plane, rows[i]]`` as ``mode[i]`` says (the kernel's words)."""
-    b, _, P, H = ax.shape
+def _step_operands(x, B, C, dt, A):
+    """What XLA lays out for the step call from ``x`` ``[b, H, P]``, ``B,
+    C`` ``[b, G, N]``, ``dt`` ``[b, H]`` and ``A`` ``[H]`` (float32): the
+    decays ``[b H]``, ``dt x`` ``[b, H P / 128, 128]`` (no transpose: the
+    array as it lies) and B over C ``[b, 2, G, N]``."""
+    b, H, P = x.shape
+    return (jnp.exp(dt * A).reshape(b * H),
+            (dt[..., None] * x).reshape(b, H * P // _LANES, _LANES),
+            jnp.stack([B, C], axis=1))
+
+
+@functools.partial(jax.jit, static_argnames=("unroll", "interpret"))
+def _ssd_step_call(rows, at, plane, n, a, dx, bc, state, *, unroll=_UNROLL,
+                   interpret=False):
+    """``a`` ``[b H]``, ``dx`` ``[b, H P / 128, 128]``, ``bc`` ``[b, 2, G,
+    N]``, ``state`` ``[Pl, R, H, P, N]`` aliased to the second output;
+    step ``i`` works batch row ``at[i]`` on ``state[plane, rows[i]]`` where
+    ``i < n`` (:func:`_blocks_of`).  Returns ``(y as dx, state')``; a row
+    that is not live has no ``y`` (what memory held)."""
+    b, tiles, _ = dx.shape
     G, N = bc.shape[-2:]
+    H, P = state.shape[2:4]
     s_spec = pl.BlockSpec((1, 1, H, P, N),
-                          lambda i, rows, plane, mode: (plane[0], rows[i],
-                                                        0, 0, 0))
+                          lambda i, rows, at, plane, n: (plane[0], rows[i],
+                                                         0, 0, 0))
+    tile = pl.BlockSpec((1, tiles, _LANES),
+                        lambda i, rows, at, *_: (at[i], 0, 0))
     return pl.pallas_call(
-        functools.partial(_ssd_step_kernel, heads=H, groups=G),
+        functools.partial(_ssd_step_kernel, groups=G,
+                          unroll=math.gcd(unroll, tiles)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(b,),
-            in_specs=[pl.BlockSpec((1, 2, P, H), lambda i, *_: (i, 0, 0, 0)),
-                      pl.BlockSpec((1, 2, G, N), lambda i, *_: (i, 0, 0, 0)),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), tile,
+                      pl.BlockSpec((1, 2, G, N),
+                                   lambda i, rows, at, *_: (at[i], 0, 0, 0)),
                       s_spec],
-            out_specs=[pl.BlockSpec((1, P, H), lambda i, *_: (i, 0, 0)),
-                       s_spec]),
-        out_shape=[jax.ShapeDtypeStruct((b, P, H), F32),
+            out_specs=[tile, s_spec]),
+        out_shape=[jax.ShapeDtypeStruct(dx.shape, F32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
-        input_output_aliases={5: 1},    # operands count the three scalars
+        input_output_aliases={7: 1},    # operands count the four scalars
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM),
         interpret=interpret,
         name="_ssd_step",
-    )(rows, plane, mode, ax, bc, state)
+    )(rows, at, plane, n, a, dx, bc, state)
 
 
 def _blocks_of(rows, live, trash: int):
-    """``(rows', mode)`` for the step kernel: a dead row names the block of
-    the last live row before it (nobody's row where there is none), so a
-    block comes in and goes out once, for a live row, and a dead row moves
-    nothing."""
+    """``(rows', at, n)`` for the step kernel: its ``n`` live rows first,
+    in the order they came (step ``i`` is batch row ``at[i]`` on pool row
+    ``rows'[i]``), and every step behind them the last live one again
+    (nobody's row where there is none), so a block comes in and goes out
+    once, for a live row, and a dead row moves nothing and waits for
+    nothing: a dead step BETWEEN two live ones would hold the next row's
+    block back until the step itself, uncovered by any arithmetic (3.0 us
+    a dead row at granite's shape; PERF.md section 6, PR 67)."""
     b = rows.shape[0]
-    at = jnp.arange(b, dtype=jnp.int32)
-    last = jax.lax.cummax(jnp.where(live, at, -1))
-    rows = jnp.where(last >= 0, rows[jnp.maximum(last, 0)], trash)
-    opens = jnp.concatenate([jnp.ones((1,), bool), rows[1:] != rows[:-1]])
-    return rows.astype(jnp.int32), jnp.where(
-        live, 1, jnp.where(opens, 2, 0)).astype(jnp.int32)
+    upto = jnp.cumsum(live, dtype=jnp.int32)        # live rows up to here
+    n = upto[-1]
+    step = jnp.minimum(jnp.arange(b, dtype=jnp.int32), jnp.maximum(n - 1, 0))
+    # the batch row of the k-th live one: those with fewer than k + 1 so far
+    at = jnp.minimum(jnp.sum(upto[None, :] <= step[:, None], axis=1,
+                             dtype=jnp.int32), b - 1)
+    return (jnp.where(n > 0, rows[at], trash).astype(jnp.int32), at,
+            n.reshape(1))
 
 
 def _of_heads(a, heads: int):
@@ -221,15 +281,11 @@ def ssd_step(state, plane, rows, x, B, C, dt, A, live, *,
         rows = jnp.where(live, jnp.minimum(rows, trash), trash)
     if kernel:
         assert trash is not None, "the kernel addresses a pool by row"
-        a = jnp.broadcast_to(jnp.exp(dt * A)[:, None, :],
-                             (b, x.shape[2], H))
-        ax = jnp.stack([a, jnp.swapaxes(dt[..., None] * x, 1, 2)], axis=1)
-        rows, mode = _blocks_of(rows, live, trash)
-        yT, state = _ssd_step_call(
-            rows, jnp.reshape(plane, (1,)).astype(jnp.int32), mode, ax,
-            jnp.stack([B, C], axis=1), state, interpret=interpret)
-        return (jnp.where(live[:, None, None], jnp.swapaxes(yT, 1, 2), 0.0),
-                state)
+        rows, at, n = _blocks_of(rows, live, trash)
+        y, state = _ssd_step_call(
+            rows, at, jnp.reshape(plane, (1,)).astype(jnp.int32), n,
+            *_step_operands(x, B, C, dt, A), state, interpret=interpret)
+        return jnp.where(live[:, None, None], y.reshape(x.shape), 0.0), state
     # XLA: the pool's plane is worked on where it lies, every row of it,
     # and what is small (x, B, C, dt, the outputs) moves instead
     S = jax.lax.dynamic_index_in_dim(state, plane, 0, keepdims=False)

@@ -208,6 +208,93 @@ def test_a_step_is_the_recurrence_for_live_rows_and_no_other(kernel, live):
     np.testing.assert_array_equal(state, want)
 
 
+# the two cells' states (heads, P, N, groups): granite's, nemotron's
+CELL_STATES = {"granite": (128, 64, 128, 1), "nemotron": (64, 64, 128, 8)}
+PATTERNS = {"0110": (False, True, True, False),     # a dead row first
+            "1011": (True, False, True, True),      # ... between two live
+            "0000": (False, False, False, False),   # nothing decodes
+            "1111": (True, True, True, True)}
+
+
+@pytest.mark.parametrize("live", PATTERNS.values(), ids=PATTERNS)
+@pytest.mark.parametrize("cell", CELL_STATES)
+def test_the_step_kernel_at_the_cells_states_is_the_step_s_arithmetic(
+        cell, live):
+    """The tile loop (interpreted) at both cells' head counts and
+    groupings against ``_step_math`` a live row, whatever the dead rows
+    lie between: their states, nobody's row and the other plane bit for
+    bit."""
+    heads, p, n, groups = CELL_STATES[cell]
+    assert ssd.on_kernel((2, 6, heads, p, n), groups, platform="tpu")[0]
+    x, B, C, dt, A = _vectors(4, 7, heads, p, n, groups)
+    pool = jax.random.normal(jax.random.PRNGKey(8), (2, 6, heads, p, n))
+    rows = jnp.asarray([4, 0, 3, 1])
+    y, state = ssd.ssd_step(pool, jnp.int32(1), rows, x, B, C, dt, A,
+                            jnp.asarray(live), kernel=True, interpret=True)
+    want = np.array(pool)
+    for i in range(4):
+        if not live[i]:
+            assert not np.asarray(y[i]).any()
+            continue
+        want_y, want_S = ssd._step_math(
+            pool[1, rows[i]], x[i], ssd._of_heads(B[i], heads),
+            ssd._of_heads(C[i], heads), dt[i], A)
+        np.testing.assert_allclose(y[i], want_y, atol=2e-5)
+        np.testing.assert_allclose(state[1, rows[i]], want_S, atol=2e-5)
+        want[1, rows[i]] = state[1, rows[i]]
+    np.testing.assert_array_equal(state, want)
+
+
+@pytest.mark.parametrize("live,at,n", [
+    ((True, False, True, True), (0, 2, 3, 3), 3),
+    ((False, True, True, False), (1, 2, 2, 2), 2),
+    ((False, False, False, True), (3, 3, 3, 3), 1),
+    ((False, False, False, False), None, 0),
+], ids=["1011", "0110", "0001", "0000"])
+def test_the_live_rows_go_first_and_a_dead_step_names_the_last_live_one(
+        live, at, n):
+    """What the step kernel walks: no dead step lies between two live
+    ones (it would hold the next row's block back), and every dead step
+    names what the call already holds."""
+    rows = jnp.asarray([7, 5, 2, 4])
+    got_rows, got_at, got_n = ssd._blocks_of(rows, jnp.asarray(live), 9)
+    assert int(got_n[0]) == n and got_n.shape == (1,)
+    if n:
+        assert tuple(np.asarray(got_at)) == at
+        assert tuple(np.asarray(got_rows)) == tuple(
+            int(rows[i]) for i in at)
+    else:       # nobody's row, at every step
+        assert tuple(np.asarray(got_rows)) == (9,) * 4
+
+
+@pytest.mark.parametrize("form", ["before", "served", "columns", "nt"])
+def test_the_table_tool_holds_every_form_to_the_step_s_arithmetic(form):
+    """``tools/ssd_step_table.py``: the loop as it stood before PR 67 and
+    each form tried since run the same rows (the dead ones between the
+    live), each against ``ssd_step``'s XLA form, a dead row's state left
+    bit for bit; the two cells' states are its rows."""
+    import argparse
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "ssd_step_table", ROOT / "tools" / "ssd_step_table.py")
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    assert {c["name"]: (c["heads"], c["p"], c["n"], c["groups"], c["planes"],
+                        c["slots"], c["live"])
+            for c in tool.ssd_configs([])} == {
+        "granite-4.0-h-small-bf16-ep2": (128, 64, 128, 1, 9, 32, 20),
+        "nemotron-3-nano-30b-a3b-bf16-ep2": (64, 64, 128, 8, 4, 64, 64)}
+    toy = dict(name="toy", planes=2, slots=5, live=3, heads=16, p=16, n=128,
+               groups=2)
+    row, = tool.rows_of(toy, argparse.Namespace(
+        seed=3, dead="seeded", reps=1, rehearse=True, trace=False,
+        form=[form]))
+    assert row["bytes_us"] == round(
+        1e-3 * 3 * 2 * 16 * 16 * 128 * 4 / tool.PEAKS.hbm_gbs, 1)
+    assert row[form]["err"] < 1e-6 and row[form]["dead_rows_untouched"]
+    assert row[form]["us"] > 0
+
+
 def test_groups_share_b_and_c_among_their_heads():
     """Two groups (the XLA form; the kernels serve one): heads 0-3 read
     group 0's B and C, heads 4-7 group 1's."""
@@ -231,6 +318,10 @@ def test_where_the_kernels_serve():
     assert not ok((9, 34, 128, 64, 128), 1, 64)[0]      # not whole lanes
     assert not ok((6, 5, 8, 16, 16))[0]                 # the toy state
     assert not ok((9, 34, 128, 64, 128), 2)[0]          # two groups
+    # the step's tile is 128 (h, p) rows: P divides the lanes
+    assert ok((9, 34, 128, 128, 128))[0] and ok((9, 34, 128, 16, 128))[0]
+    assert not ok((9, 34, 128, 24, 128))[0]
+    assert not ok((9, 34, 128, 256, 128))[0]
     assert ssd.on_kernel((9, 34, 128, 64, 128), platform="cpu") == (
         False, "platform cpu")
     assert ssd.on_kernel((1, 2, 128, 8, 128), backend="pallas",

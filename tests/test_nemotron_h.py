@@ -200,6 +200,9 @@ def test_where_the_grouped_kernels_serve():
     assert not ok(nano, 8, 64)[0]                       # not whole lanes
     assert not ok(nano, 1, 128)[0]      # one group: 128 k heads, as it was
     assert ssd._chunk_heads(64, 8) == 8 and ssd._chunk_heads(128, 1) == 16
+    # 24 heads of 8 channels are a tile and a half of the step's
+    assert ok((4, 66, 32, 8, 128), 4, 128)[0]
+    assert not ok((4, 66, 24, 8, 128), 3, 128)[0]
 
 
 def test_the_gated_norm_goes_a_group_at_a_time():
@@ -501,6 +504,14 @@ def test_the_new_shapes_get_through_mosaic_and_copy_no_stack(v5e):
         S((64, 8, 128), bf), S((64, 8, 128), bf), S((64, 64), f32),
         S((64,), f32), S((64,), jnp.bool_)).compile()
     assert "_ssd_step" in step.as_text()
+    # ... and the step's tile loop at granite's state, 128 heads in one
+    # group (PR 67: two transposes a tile of two heads)
+    wide = jax.jit(lambda st, p, r, x, B, C, dt, A_, live: ssd.ssd_step(
+        st, p, r, x, B, C, dt, A_, live, kernel=True)).lower(
+        S((9, 34, 128, 64, 128), f32), S((), jnp.int32), S((32,), jnp.int32),
+        S((32, 128, 64), bf), S((32, 1, 128), bf), S((32, 1, 128), bf),
+        S((32, 128), f32), S((128,), f32), S((32,), jnp.bool_)).compile()
+    assert "_ssd_step" in wide.as_text()
     chunk = jax.jit(lambda st, p, r, fr, x, B, C, dt, A_: ssd.ssd_chunk(
         st, p, r, fr, x, B, C, dt, A_, chunk=128, kernel=True)).lower(
         pool, S((), jnp.int32), S((), jnp.int32), S((), jnp.bool_),
@@ -508,5 +519,5 @@ def test_the_new_shapes_get_through_mosaic_and_copy_no_stack(v5e):
         S((256, 64), f32), S((64,), f32)).compile()
     assert "_ssd_chunk" in chunk.as_text()
     # the pool is worked on where it lies: no temporary of a plane's size
-    for c in (step, chunk):
+    for c in (step, wide, chunk):
         assert c.memory_analysis().temp_size_in_bytes < 64 << 20

@@ -316,6 +316,11 @@ READINGS_ONE_SUBLAYER = {
     # --state-ops (out_rel_err, decode_out_rel_err, state_rel_err), 512 + 32
     # in segments of 256 (two scan chunks of 128), both ops the Pallas calls
     "--state-ops": [(3.47e-3, 5.3e-4, 9.2e-4)],
+    # read again with the step's tile loop (PR 67: another order of the
+    # read-out's float32 sums): seed 0 as above to the digits kept (3.47e-3,
+    # 5.35e-4, 9.16e-4: the chunk form's bfloat16 operands set all three),
+    # and seed 1, on the parent's loop the same
+    "--state-ops, seed 1": [(4.88e-3, 6.3e-4, 2.8e-4)],
     # THE REVIEW'S ROUND (the selection bias reseeded at N(0, 0.02); the
     # rows above were read at 0.1): --replay on requests of the canary's
     # shape, 8 x 96 + 15: the family's routed_share a block as (least,
@@ -379,6 +384,9 @@ READINGS_SSD = {
     # the chunk form's products (STATE_OPS_OUT_TOL_SSD)
     "--state-ops, seed 0": [(3.3e-3, 1.3e-3, 4.0e-4)],
     "--state-ops --bf16-state, seed 1": [(4.7e-3, None, 3.3e-3)],
+    # read again with the step's tile loop (PR 67): seed 0 as above (3.32e-3,
+    # 1.31e-3, 4.04e-4), and seed 1
+    "--state-ops, seed 1 (PR 67)": [(4.70e-3, 1.38e-3, 5.0e-4)],
 }
 # (own_token_mean, own_token_max, state_rel_err): the emitted tokens' own
 # log-probabilities, which are all a benchmark run's check sees, and which
