@@ -27,6 +27,11 @@ Two device ops, named so that a trace tells them apart:
 
   XLA builds the chunk's matrices (they do not touch the state); the pass
   over the chunks that reads and writes the state is the kernel.
+  ``(I + N)^-1`` is formed by products (:func:`unit_lower_inverse`:
+  substitution inside diagonal blocks of ``sub`` rows, block merges
+  above), not by XLA's triangular solve, a custom-call six times slower
+  on the chip, and not by the series ``(I - N)(I + N^2)(I + N^4) ..``,
+  whose powers overflow float32 where one key repeats.
 
 A token that is not there (a padded position of a segment's last chunk, a
 row that decodes nothing) has ``log alpha = 0`` and ``beta = 0``: it leaves
@@ -197,6 +202,58 @@ def kda_step(state, plane, rows, q, k, v, g, beta, live, *,
 
 # -------------------------------------------------------------- the chunk
 
+def _blocks(N, b: int, at):
+    """The ``b x b`` blocks of ``N`` ``[.., c, c]`` at the block
+    coordinates ``at``, stacked ``[.., len(at), b, b]``."""
+    return jnp.stack([N[..., i * b:(i + 1) * b, j * b:(j + 1) * b]
+                      for i, j in at], axis=-3)
+
+
+def _merge_inverted_blocks(X, N):
+    """``(I + N)^-1`` from its inverted diagonal blocks ``X`` ``[.., c / b,
+    b, b]``: level by level two of them ``A``, ``D`` and the block ``N21``
+    of ``N`` under ``A`` become ``[[A, 0], [-D N21 A, D]]``, float32
+    ``HIGHEST`` products."""
+    dot = functools.partial(jnp.matmul, precision=HIGHEST)
+    c, b = N.shape[-1], X.shape[-1]
+    while b < c:
+        A, D = X[..., 0::2, :, :], X[..., 1::2, :, :]
+        N21 = _blocks(N, b, [(2 * i + 1, 2 * i) for i in range(c // b // 2)])
+        X = jnp.concatenate(
+            [jnp.concatenate([A, jnp.zeros_like(A)], axis=-1),
+             jnp.concatenate([-dot(dot(D, N21), A), D], axis=-1)], axis=-2)
+        b *= 2
+    return X[..., 0, :, :]
+
+
+def unit_lower_inverse(N, base: int = SUB):
+    """``(I + N)^-1`` of strictly lower-triangular ``N`` ``[.., c, c]``
+    (float32), formed by products and no solve.  The diagonal blocks of
+    ``b`` rows (``c`` halved while it is even and above ``base``) are
+    inverted by substitution, every block of every matrix at once and the
+    blocks along the LANES (``[b, b, blocks]``: a step's two factors are
+    then slices of the leading axes, nothing crosses a lane): column ``j``
+    of ``N`` times the inverse's finished row ``j`` comes off the rows
+    under it, ``b - 1`` steps of one broadcast product each, exact and in
+    the order a row-by-row solve adds them.  Then the blocks are merged
+    (:func:`_merge_inverted_blocks`).  No series in ``N``: its powers
+    overflow where one key repeats (docs/DESIGN.md section 27)."""
+    c = N.shape[-1]
+    b = c
+    while b > base and b % 2 == 0:
+        b //= 2
+    D = _blocks(N, b, [(i, i) for i in range(c // b)])
+    Dl = jnp.moveaxis(D.reshape((-1, b, b)), 0, 2)       # [b, b, blocks]
+
+    def step(j, X):
+        return X - (jax.lax.dynamic_slice_in_dim(Dl, j, 1, axis=1)
+                    * jax.lax.dynamic_slice_in_dim(X, j, 1, axis=0))
+
+    X = jax.lax.fori_loop(0, b - 1, step, jnp.broadcast_to(
+        jnp.eye(b, dtype=N.dtype)[:, :, None], Dl.shape))
+    return _merge_inverted_blocks(jnp.moveaxis(X, 2, 0).reshape(D.shape), N)
+
+
 def chunk_matrices(q, k, v, g, beta, chunk: int, sub: int) -> dict:
     """What one segment's chunks need beside the state, from ``q, k, g``
     ``[s, H, dk]``, ``v`` ``[s, H, dv]``, ``beta`` ``[s, H]`` (float32, ``s``
@@ -233,10 +290,8 @@ def chunk_matrices(q, k, v, g, beta, chunk: int, sub: int) -> dict:
                 ein("nhtd,nhsd->nhts", ki * here, past))
             B = B.at[:, :, blk, :i * sub].set(
                 ein("nhtd,nhsd->nhts", qi * here, past))
-    eye = jnp.eye(chunk, dtype=F32)
     N = beta[..., None] * jnp.tril(A, -1)
-    Tm = jax.scipy.linalg.solve_triangular(
-        eye + N, beta[..., None] * eye, lower=True, unit_diagonal=True)
+    Tm = unit_lower_inverse(N) * beta[..., None, :]
     decay = jnp.exp(G)
     return {"Kg": k * decay, "Qg": q * decay,
             "KendT": jnp.swapaxes(k * jnp.exp(Gc[:, :, None] - G), 2, 3),

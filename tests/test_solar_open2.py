@@ -29,6 +29,7 @@ for extra in ("benchmark", "tools"):
         sys.path.insert(0, str(ROOT / extra))
 
 import families  # noqa: E402  (benchmark/)
+import kda_tinv_table  # noqa: E402  (tools/)
 import model_parity  # noqa: E402  (tools/)
 
 CFG = get_model_config("solar-open2-test")
@@ -43,16 +44,21 @@ def params():
     return init_full_params(jax.random.PRNGKey(0), CFG)
 
 
-def _vectors(s, heads, d, seed):
+def _vectors(s, heads, d, seed, repeated=False):
     """q, k, v, log alpha, beta of ``s`` tokens as a kda block makes them:
-    q and k of unit length, a decay a channel, beta in (0, 2)."""
-    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q and k of unit length, a decay a channel, beta in (0, 2).
+    ``repeated``: a prompt that repeats one token, every key one direction
+    a head plus 5 % noise, hardly any decay, beta 1.9."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
     q = unit(jax.random.normal(ks[0], (s, heads, d))) * d ** -0.5
     k = unit(jax.random.normal(ks[1], (s, heads, d)))
     v = jax.random.normal(ks[2], (s, heads, d))
     g = -0.3 * jnp.exp(jax.random.normal(ks[3], (s, heads, d)))
     beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (s, heads)))
+    if repeated:
+        k = unit(unit(jax.random.normal(ks[5], (1, heads, d))) + 0.05 * k)
+        g, beta = 0.01 * g, jnp.full_like(beta, 1.9)
     return q, k, v, g, beta
 
 
@@ -111,26 +117,74 @@ def test_the_parameter_stacks_are_one_a_kind(params):
 
 # ------------------------------------------------- the two ops, by the rule
 
-@pytest.mark.parametrize("s,chunk,sub", [
-    (24, 8, 4),         # whole chunks, sub-blocks against earlier ones
-    (40, 16, 8),
-    (16, 16, 16),       # one chunk, one sub-block
-    (21, 8, 4),         # a partial last chunk
-    (7, 128, 32),       # shorter than a chunk
-    (1, 128, 32),       # a single token
+@pytest.mark.parametrize("s,chunk,sub,repeated,atol", [
+    (24, 8, 4, False, 2e-6),    # whole chunks, sub-blocks against earlier ones
+    (40, 16, 8, False, 2e-6),
+    (16, 16, 16, False, 2e-6),  # one chunk, one sub-block
+    (21, 8, 4, False, 2e-6),    # a partial last chunk
+    (7, 128, 32, False, 2e-6),  # shorter than a chunk
+    (1, 128, 32, False, 2e-6),  # a single token
+    # the cell's chunk and base on one key repeated (|N| up to 1.9, |S| up
+    # to 5.3): against the float32 recurrence, substitution over all 128
+    # rows (``unit_lower_inverse(N, 128)``, and the solve before PR 68)
+    # reads 1.0e-5 on the outputs and 5.6e-5 on the state, the blocks of 32
+    # 1.9e-5 and 6.7e-5 (of 16: 2.0e-5, 5.4e-5); the limit is 2.5 x that
+    (256, 128, 32, True, 1.5e-4),
 ])
-def test_the_chunk_form_is_the_recurrence(s, chunk, sub):
-    q, k, v, g, beta = _vectors(s, 2, 16, s)
+def test_the_chunk_form_is_the_recurrence(s, chunk, sub, repeated, atol):
+    q, k, v, g, beta = _vectors(s, 2, 16, s, repeated)
     S0 = jax.random.normal(KEY, (2, 16, 16))
     want_o, want_S = kda.kda_recurrence(S0, q, k, v, g, beta)
     state = jnp.zeros((2, 3, 2, 16, 16)).at[1, 1].set(S0)
     o, out = kda.kda_chunk(state, jnp.int32(1), jnp.int32(1),
                            jnp.bool_(False), q, k, v, g, beta, chunk=chunk,
                            sub=sub)
-    np.testing.assert_allclose(o, want_o, atol=2e-6)
-    np.testing.assert_allclose(out[1, 1], want_S, atol=2e-6)
+    np.testing.assert_allclose(o, want_o, atol=atol)
+    np.testing.assert_allclose(out[1, 1], want_S, atol=atol)
     # nothing else of the pool moved
     assert float(jnp.abs(out.at[1, 1].set(0.0)).max()) == 0.0
+
+
+def _served(N, beta):
+    del beta
+    return kda.unit_lower_inverse(N)
+
+
+def test_the_inverse_is_the_float64_inverse_on_keys_like_the_cell_s():
+    """``[2, 4, 128, 128]``, random unit keys, the configuration's decay,
+    ``beta = 2 sigmoid`` (``tools/kda_tinv_table.cell_input``; ``error``:
+    max |difference| from numpy's float64 inverse over its largest entry):
+    ``|N|`` under 0.5, every form reads 3e-8 to 1.1e-7 (PERF.md section 6,
+    PR 68)."""
+    N, _ = kda_tinv_table.cell_input(68, 2, 4)
+    assert 0.2 < np.abs(N).max() < 0.5
+    assert kda_tinv_table.error(_served, N) <= 5e-7
+
+
+def test_the_inverse_holds_where_one_key_repeats_and_a_series_does_not():
+    """One direction + 5 % noise, the decay x 0.01, ``beta`` 1.9 (a prompt
+    that repeats a token): ``|N|`` up to 1.9.  The limit 5e-5 lies between
+    the worst sound form (substitution in blocks of 16 then merges: 1.5e-5)
+    and the first unsound one (the series inside blocks of 8: 1.2e-4); the
+    whole chunk's product of ``(I + N^(2^j))``, the control, overflows."""
+    N, _ = kda_tinv_table.repeated_input(68, 2, 4)
+    assert np.abs(N).max() > 1.8
+    assert kda_tinv_table.error(_served, N) <= 5e-5
+    assert not kda_tinv_table.error(kda_tinv_table.neumann, N) <= 5e-5
+
+
+@pytest.mark.parametrize("s,heads,d,chunk,sub", [
+    (256, 64, 128, 128, 32),        # the cell's segment
+    (8, 2, 16, 8, 4),
+])
+def test_the_chunk_s_matrices_hold_no_triangular_solve(s, heads, d, chunk,
+                                                       sub):
+    x = jax.ShapeDtypeStruct((s, heads, d), jnp.float32)
+    text = str(jax.make_jaxpr(
+        lambda q, k, v, g, beta: kda.chunk_matrices(q, k, v, g, beta, chunk,
+                                                    sub))(
+        x, x, x, x, jax.ShapeDtypeStruct((s, heads), jnp.float32)))
+    assert "dot_general" in text and "triangular_solve" not in text
 
 
 def test_a_segment_that_starts_a_request_starts_from_zero():
