@@ -81,16 +81,38 @@ class BlockKind:
     groups: int = 1
     chunk: int = 0
     mlp: bool = True
+    # RMSNorm on q and k a HEAD (weight ``[head_dim]``), before any rope
+    qk_norm: bool = False
+    # a "sparse" kind (docs/DESIGN.md section 32; ``ops.sparse_attention``):
+    # keys pooled over ``sparse_kernel`` tokens every ``sparse_stride``
+    # score blocks of ``sparse_block`` tokens, a query past
+    # ``sparse_dense_len`` keeps the first ``sparse_init`` blocks, the
+    # blocks of its last ``sparse_local`` tokens and the ``sparse_topk``
+    # best of the others
+    sparse_kernel: int = 0
+    sparse_stride: int = 0
+    sparse_block: int = 0
+    sparse_topk: int = 0
+    sparse_init: int = 0
+    sparse_local: int = 0
+    sparse_dense_len: int = 0
 
     @property
     def is_state(self) -> bool:
         """Its cache is a recurrent state a request, not rows of pages."""
-        return self.attn in ("kda", "ssd")
+        return self.attn in ("kda", "ssd", "lightning")
 
     @property
     def has_pages(self) -> bool:
         """Its cache is rows of a page pool (full keys and values)."""
-        return self.attn in ("full", "window")
+        return self.attn in ("full", "window", "sparse")
+
+    @property
+    def sparse_sizes(self) -> tuple:
+        """``(kernel, stride, block, topk, init, local, dense_len)``."""
+        return (self.sparse_kernel, self.sparse_stride, self.sparse_block,
+                self.sparse_topk, self.sparse_init, self.sparse_local,
+                self.sparse_dense_len)
 
     @property
     def name(self) -> str:
@@ -99,9 +121,11 @@ class BlockKind:
         return "mlp" if self.attn == "none" else self.attn
 
     def __post_init__(self):
-        if self.attn not in ("full", "window", "kda", "ssd", "none"):
+        if self.attn not in ("full", "window", "kda", "ssd", "none",
+                             "sparse", "lightning"):
             raise ValueError(f"a block kind's attn is 'full', 'window', "
-                             f"'kda', 'ssd' or 'none', got {self.attn!r}")
+                             f"'kda', 'ssd', 'sparse', 'lightning' or "
+                             f"'none', got {self.attn!r}")
         if self.attn == "none" and not self.mlp:
             raise ValueError("a block kind has a token mixer (attn), an "
                              "MLP (mlp) or both: this one has neither "
@@ -109,10 +133,22 @@ class BlockKind:
         if (self.attn == "window") != (self.window > 0):
             raise ValueError("a window kind states its window, a full "
                              "kind none")
-        if self.is_state != (self.conv > 1):
-            raise ValueError("a state kind (kda, ssd) states its "
-                             "convolution's taps (conv >= 2), another "
-                             "kind none")
+        if (self.attn in ("kda", "ssd")) != (self.conv > 1):
+            raise ValueError("a state kind behind a convolution (kda, ssd) "
+                             "states its taps (conv >= 2), another kind "
+                             "none")
+        if self.attn == "sparse":
+            kn, st, bl, topk, init, local, dense = self.sparse_sizes
+            if not (st > 0 and kn >= st and kn % st == 0 and bl % st == 0
+                    and topk > 0 and init >= 0 and local % bl == 0
+                    and dense >= 0):
+                raise ValueError(
+                    "a sparse kind states sparse_kernel (a multiple of "
+                    "sparse_stride), sparse_block (a multiple of the "
+                    "stride), sparse_topk, sparse_init, sparse_local (whole "
+                    f"blocks) and sparse_dense_len; got {self.sparse_sizes}")
+        elif any(self.sparse_sizes):
+            raise ValueError("only a sparse kind states sparse_* sizes")
         sizes = (self.state_heads, self.state_head_dim, self.state_size,
                  self.chunk)
         if not (all(v > 0 for v in sizes) if self.attn == "ssd"
@@ -394,6 +430,57 @@ class ModelConfig:
         return next(iter(kinds), None)
 
     @property
+    def sparse_kind(self) -> Optional[BlockKind]:
+        """The period's block-sparse kind (``attn == "sparse"``), or None.
+        Its blocks' pages lie in the full kind's pool, and beside that pool
+        rides ONE index plane array (:meth:`index_shape`), so a period has
+        one sparse kind."""
+        kinds = {k for k in self.period if k.attn == "sparse"}
+        if len(kinds) > 1:
+            raise ValueError("a period holds one sparse kind: its blocks "
+                             "share one index plane's shape")
+        if kinds and self.num_experts > 0:
+            raise ValueError(
+                "a model with a sparse kind has no experts: a block's row "
+                "of counters in the serving programs holds what its "
+                "selections kept where a block with experts counts the "
+                "rows routed to each")
+        return next(iter(kinds), None)
+
+    def index_shape(self, num_pages: int, block_tokens: int) -> tuple:
+        """The index plane beside the full kind's page pool, ``[planes,
+        pages x pooled keys a page, kv heads x head_dim]`` (rows of whole
+        lanes along a leading axis: a gather or a scatter of rows moves
+        them as they lie): page ``p``'s row ``i`` (row ``p x bt / stride +
+        i``) is the mean, a kv head, of the ``sparse_kernel`` keys from
+        token ``i x sparse_stride`` of the page on (they may run into the
+        next page of the request), written when the last of them is.  A plane a plane
+        of the pool, addressed by the same table: a page's index rows are
+        leased and freed with it.  It rides ``keys`` after the pools of
+        pages (and before a state pool), a placeholder of one element in
+        its place among ``values``."""
+        kind = self.sparse_kind
+        if block_tokens % kind.sparse_block:
+            raise ValueError(
+                f"a page holds whole blocks of the sparse kind: "
+                f"--kv-block-tokens {block_tokens} is no multiple of "
+                f"{kind.sparse_block}")
+        return (self.cache_kinds[0][1],
+                num_pages * (block_tokens // kind.sparse_stride),
+                self.num_kv_heads * self.head_dim)
+
+    @property
+    def sparse_blocks(self) -> int:
+        """Blocks of the sparse kind (each a plane of the index plane)."""
+        return self.num_layers * sum(k.attn == "sparse" for k in self.period)
+
+    @property
+    def cache_arrays(self) -> int:
+        """Arrays of ``keys`` (and of ``values``) before a state pool: one
+        a pool of pages, and a sparse kind's index plane."""
+        return len(self.cache_kinds) + (self.sparse_kind is not None)
+
+    @property
     def state_planes(self) -> int:
         """Blocks whose cache is a recurrent STATE a request (a state
         kind, docs/DESIGN.md sections 27 and 29), not rows of a page pool:
@@ -416,6 +503,10 @@ class ModelConfig:
         of a row re-laid, 46 ms a dispatch of copies of the whole pool (my
         chip run, PR 62); a request's row of lanes is not."""
         kind = self.state_kind
+        if kind.attn == "lightning":
+            # ``[value, key]`` a head (``ops.ssd``'s ``[P, N]``), and no
+            # convolution: one element stands where a tail would
+            return ((kind.num_heads, self.head_dim, self.head_dim), (1,))
         if kind.attn == "ssd":
             return ((kind.state_heads, kind.state_head_dim,
                      kind.state_size),
@@ -445,7 +536,7 @@ class ModelConfig:
         *state_shapes[1]]`` the last of ``values``; a row's row of the pool is its
         table's last column, after one table a kind side by side
         (``ops.paged_attention``'s ``impl.for_state``)."""
-        kinds = len(self.cache_kinds)
+        kinds = self.cache_arrays
         s_shape, c_shape = self.state_shapes
         state, tails = keys[-1], values[-1]
         if (len(keys) != kinds + 1 or len(values) != kinds + 1
@@ -456,7 +547,7 @@ class ModelConfig:
                 != (self.state_planes,) + c_shape
                 or state.shape[1] != tails.shape[1]):
             raise ValueError(
-                f"a cache of {kinds} pool(s) of pages and, last, a float32 "
+                f"a cache of {kinds} array(s) of pages and, last, a float32 "
                 f"state pool [{self.state_planes}, rows, *{s_shape}] with "
                 f"its tails [{self.state_planes}, rows, *{c_shape}] was "
                 f"expected; got keys "

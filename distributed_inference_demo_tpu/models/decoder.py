@@ -208,6 +208,21 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
             "wo": big(keys[3], (L, D, H), dt),
             "mlp_norm_w": jnp.ones((L, H), dt),
         }
+    elif kind is not None and kind.attn == "lightning":
+        # a Lightning linear-attention block (``_lightning_mixer``): q, k
+        # and v of every head (no grouped keys), a head's own output norm
+        # with a weight a channel; the decay is a constant a head and no
+        # leaf (``ops.ssd.lightning_log_decay``); the gate's ``wg`` below
+        D = nh * hd
+        p = {
+            "attn_norm_w": jnp.ones((L, H), dt),
+            "wq": big(keys[0], (L, H, D), dt),
+            "wk": big(keys[1], (L, H, D), dt),
+            "wv": big(keys[2], (L, H, D), dt),
+            "o_norm_w": jnp.ones((L, D), dt),
+            "wo": big(keys[3], (L, D, H), dt),
+            "mlp_norm_w": jnp.ones((L, H), dt),
+        }
     elif cfg.latent_kv:
         # deepseek_v3: q in one matrix, or with ``q_lora_rank`` in two
         # around a norm (``wq_a`` -> ``q_a_norm_w`` -> ``wq``); the latent
@@ -314,6 +329,13 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, num_layers: int,
     if cfg.qk_norm:  # olmoe: RMSNorm over the whole q / k projection
         p["q_norm_w"] = jnp.ones((L, nh * hd), dt)
         p["k_norm_w"] = jnp.ones((L, nkv * hd), dt)
+    if kind is not None and kind.qk_norm:
+        # RMSNorm a HEAD, one weight ``[head_dim]`` for all heads.  Seeded
+        # at 1 + N(0, 0.1), not at one, so that a path that dropped the
+        # weight cannot pass for one that has it
+        for j, leaf in enumerate(("q_norm_w", "k_norm_w")):
+            p[leaf] = 1 + _dense_init(jax.random.fold_in(keys[15], 31 + j),
+                                      (L, hd), dt, scale=0.1)
     if cfg.sandwich_norm:
         # ouro: RMSNorm on each sublayer's output.  Seeded at (2 L)^-1/2
         # and not at one: a pass's 2 L normed outputs then sum to about
@@ -909,6 +931,9 @@ def _kv_attention(cfg: ModelConfig, lp: dict, h: jnp.ndarray, k_cache,
     v = v.reshape(b, s, nkv, hd)
 
     kind = cfg.block_kind       # a period model's block: its own rope
+    if kind is not None and kind.qk_norm:       # a head's own mean square
+        q = rms_norm(q, lp["q_norm_w"], cfg.norm_eps)
+        k = rms_norm(k, lp["k_norm_w"], cfg.norm_eps)
     if kind is not None:
         if kind.rotary_share > 0:       # 0: no rope (positions by order)
             q = apply_rope_kind(q, positions, kind.rope_theta,
@@ -1207,6 +1232,102 @@ def _ssd_mixer(cfg: ModelConfig, kind, lp: dict, h: jnp.ndarray, state,
     return y, LayerOf(S, plane), LayerOf(tails, plane)
 
 
+def _lightning_mixer(cfg: ModelConfig, kind, lp: dict, h: jnp.ndarray,
+                     state, conv, positions, valid, hook):
+    """A Lightning linear-attention block's mixer over normed rows ``h``
+    [b, s, H]: ``(y [b, s, heads x hd], state', conv')`` before ``wo``
+    (docs/DESIGN.md section 32):
+
+        q, k <- rope(rms_norm(q), rms_norm(k))          a head's own norm
+        S_t = lambda_h S_{t-1} + k_t^T v_t              float32, a head
+        o_t = (q_t S_t) * hd ** -0.5;   y = rms_norm(o) * sigmoid(h W_g)
+
+    It is :mod:`ops.ssd`'s recurrence with ``x = v``, ``B = k``, ``C = q``
+    a HEAD (groups = heads), ``dt`` = 1 at a token that is there and ``A``
+    the constant ``log lambda_h``: the state pool ``[planes, rows, heads,
+    hd (value), hd (key)]`` float32 goes through ``ssd_step`` and
+    ``ssd_chunk``, rows, segments, ``valid`` and a merged call's two parts
+    exactly as :func:`_ssd_mixer` has them.  No convolution: ``conv`` (the
+    pool's placeholder for tails) comes back as it came."""
+    b, s, _ = h.shape
+    hd, nh = cfg.head_dim, kind.num_heads
+    D, f32 = nh * hd, jnp.float32
+    plane = state.layer
+    S = state.stack
+    rows = hook.rows() if hook is not None else None
+    interpret = hook is not None and hook.interpret
+    if valid is None:
+        valid = jnp.ones((b, s), bool)
+    merged = isinstance(rows, tuple)
+    in_parts = lambda *a: split_rows(rows, a) if merged else (a,)  # noqa: E731
+    q = dense(h, lp["wq"], "bsh,hd->bsd")
+    k = dense(h, lp["wk"], "bsh,hd->bsd")
+    v = dense(h, lp["wv"], "bsh,hd->bsd")
+    q, k, v = jax.lax.optimization_barrier((q, k, v))   # as ``_kv_attention``
+    cut = lambda x: x.reshape(b, s, nh, hd)  # noqa: E731
+    q, k, v = cut(q), cut(k), cut(v)
+    with jax.named_scope("la_qk"):
+        if kind.qk_norm:
+            q = rms_norm(q, lp["q_norm_w"], cfg.norm_eps)
+            k = rms_norm(k, lp["k_norm_w"], cfg.norm_eps)
+        if kind.rotary_share > 0:
+            q = apply_rope_kind(q, positions, kind.rope_theta,
+                                kind.rotary_share, kind.yarn)
+            k = apply_rope_kind(k, positions, kind.rope_theta,
+                                kind.rotary_share, kind.yarn)
+        # a token that is not there moves no state: dt = 0
+        dt = jnp.broadcast_to(valid[:, :, None].astype(f32), (b, s, nh))
+        A = ssd_ops.lightning_log_decay(nh)
+    R = S.shape[1]
+
+    def mix(rows, q, k, v, dt, positions, S):
+        """The state's part over rows ``[b, s]``: ``(o [b, s, heads, hd]
+        float32, S')``."""
+        b, s = q.shape[:2]
+        ntok = jnp.sum(dt[:, :, 0] > 0, axis=1).astype(jnp.int32)
+        kernel, why = (ssd_ops.on_kernel(S.shape, nh, s, hook.backend)
+                       if hook is not None else (False, "dense cache"))
+        if hook is not None:
+            hook.note(s, "pallas_la" if kernel else "xla_la", why)
+        if rows is None:
+            at = jnp.arange(b, dtype=jnp.int32)
+        else:   # the last row is nobody's: a row that holds nothing goes there
+            at = jnp.where(ntok > 0, jnp.minimum(rows, R - 1), R - 1)
+        if s == 1:
+            with jax.named_scope("la_step"):
+                o, S = ssd_ops.ssd_step(
+                    S, plane, None if rows is None else at, v[:, 0],
+                    k[:, 0], q[:, 0], dt[:, 0], A, ntok > 0, kernel=kernel,
+                    interpret=interpret, name="_la_step")
+            return o[:, None], S
+
+        def segment(S, seg):
+            q, k, v, dt, at, fresh = seg
+            with jax.named_scope("la_chunk"):
+                o, S = ssd_ops.ssd_chunk(
+                    S, plane, at, fresh, v, k, q, dt, A, kernel=kernel,
+                    interpret=interpret, name="_la_chunk")
+            return S, o.astype(f32)
+
+        S, o = jax.lax.scan(segment, S,
+                            (q, k, v, dt, at, positions[:, 0] == 0))
+        return o, S
+
+    outs = []       # (merged: the segments' chunk form, then the rows' step)
+    for part_rows, part in zip(rows if merged else (rows,),
+                               in_parts(q, k, v, dt, positions)):
+        o, S = mix(part_rows, *part, S)
+        outs.append(o)
+    o = join_rows(outs) if merged else o
+    with jax.named_scope("la_out_norm"):
+        y = rms_norm(o * hd ** -0.5, jnp.ones((hd,), f32), cfg.norm_eps)
+        y = y.reshape(b, s, D) * lp["o_norm_w"].astype(f32)
+        gate = jax.nn.sigmoid(
+            dense(h, lp["wg"], "bsh,hd->bsd").astype(f32))
+        y = (y * gate).astype(cfg.dtype)
+    return y, LayerOf(S, plane), conv
+
+
 def _latent_attention(cfg: ModelConfig, lp: dict, h: jnp.ndarray, cache,
                       positions: jnp.ndarray, cache_start: jnp.ndarray,
                       attn_impl=None):
@@ -1355,6 +1476,10 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
             attn, k_cache, v_cache = _ssd_mixer(
                 cfg, kind, lp, h, k_cache, v_cache, positions, valid,
                 attn_impl)
+        elif kind is not None and kind.attn == "lightning":
+            attn, k_cache, v_cache = _lightning_mixer(
+                cfg, kind, lp, h, k_cache, v_cache, positions, valid,
+                attn_impl)
         elif cfg.latent_kv:  # the cache is ``k_cache`` alone: no ``v_cache``
             attn, k_cache = _latent_attention(cfg, lp, h, k_cache, positions,
                                               cache_start, attn_impl)
@@ -1384,10 +1509,12 @@ def _layer(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
         if n:
             x, coef = hc_read("mlp", streams)
         h = normed(x, "mlp")
-        if moe_stats:
+        if moe_stats and cfg.num_experts > 0:
             y, rows = _moe_routed(cfg, lp, h, tp_axis, valid)
         else:
             y = _mlp(cfg, lp, h, tp_axis, ep_axis, valid)
+            if moe_stats:   # a dense MLP routes no row: a row of no expert
+                rows = jnp.zeros((0,), jnp.int32)
         if cfg.sandwich_norm:
             y = rms_norm(y, lp["mlp_post_norm_w"], cfg.norm_eps)
         if rm != 1.0:
@@ -1444,12 +1571,18 @@ def _period_blocks(params: StageParams, cfg: ModelConfig, x, cache: KVCache,
         if kind.is_state:           # rows of the state pool, not pages
             return (attn_impl.for_state(name) if attn_impl is not None
                     else None)
+        if kind.attn == "sparse":
+            return attn_impl.for_sparse(pool, pools, kind, name)
         if attn_impl is not None:
             return attn_impl.for_pool(pool, pools, kind.window, name)
         return _window_attn(kind.window) if kind.window else None
 
+    index_at = len(cfg.cache_kinds)     # (``ModelConfig.index_shape``)
+
     def block(block_cfg, lp, x, Ks, Vs, pool, plane, impl, stats):
         kc = vc = None      # a block without a mixer holds no cache
+        sparse = (block_cfg.block_kind is not None
+                  and block_cfg.block_kind.attn == "sparse")
         if pool is not None:
             pool %= len(Ks)     # -1, a state kind's (``cfg.state_arrays``)
             # the state pool goes whole, paged or not (``_kda_mixer``,
@@ -1458,9 +1591,20 @@ def _period_blocks(params: StageParams, cfg: ModelConfig, x, cache: KVCache,
             k_of, v_of = LayerOf(Ks[pool], plane), LayerOf(Vs[pool], plane)
             kc, vc = ((k_of, v_of) if whole
                       else (k_of.sliced(), v_of.sliced()))
+            if sparse:      # its pages and, beside them, the index plane
+                # (and the rows that hold a token, for the hook's counts)
+                kc = (kc, LayerOf(Ks[index_at], plane), valid)
         x, kc, vc, *rows = _layer(block_cfg, lp, x, kc, vc, positions,
                                   cache_start, None, None, impl, None,
                                   stats, valid)
+        if pool is not None and sparse:
+            kc, ix, kept = kc
+            Ks = Ks[:index_at] + (ix.stack,) + Ks[index_at + 1:]
+        if rows and cfg.sparse_kind is not None:
+            # a model with a sparse kind has no experts (``ModelConfig.
+            # sparse_kind``): a block's row of counts is what its
+            # selections kept (``ops.sparse_attention.kept_counts``)
+            rows = [kept if sparse else jnp.zeros((3,), jnp.int32)]
         if pool is not None:
             K, V = ((kc.stack, vc.stack) if whole
                     else (k_of.updated(kc), v_of.updated(vc)))
@@ -1468,6 +1612,12 @@ def _period_blocks(params: StageParams, cfg: ModelConfig, x, cache: KVCache,
             Ks, Vs = swap(Ks, K), swap(Vs, V)
         return x, Ks, Vs, (rows[0] if rows else None)
 
+    if cfg.sparse_kind is not None and attn_impl is None:
+        raise ValueError(
+            "a block-sparse kind reads an index plane beside its pages, "
+            "which a dense cache does not have: serve it through the page "
+            "pool (serve --batch-slots --prefill-chunk "
+            "--mixed-token-budget)")
     Ks, Vs = tuple(cache.keys), tuple(cache.values)
     if cfg.state_planes:    # the state rides last: checked here, a trace
         cfg.state_arrays(Ks, Vs)
@@ -1530,7 +1680,8 @@ def _period_blocks(params: StageParams, cfg: ModelConfig, x, cache: KVCache,
     (x, Ks, Vs), rows = jax.lax.scan(body, (x, Ks, Vs),
                                      (scanned, jnp.arange(R)))
     if moe_stats:       # [R, blocks with experts, held] -> one row a block
-        rows = rows.reshape((-1,) + rows.shape[2:])
+        rows = rows.reshape((rows.shape[0] * rows.shape[1],)
+                            + rows.shape[2:])
     return x, Ks, Vs, rows
 
 

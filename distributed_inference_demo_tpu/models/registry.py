@@ -46,6 +46,17 @@ NEMOTRON_TEST_A = BlockKind(attn="full", num_heads=4, rotary_share=0.0,
                             mlp=False)
 NEMOTRON_TEST_E = BlockKind(attn="none")
 
+# minicpm_sala's two kinds at toy size: block-sparse GQA with the published
+# proportions (kernel = 2 x stride, block = 4 x stride) small enough that a
+# context of a few hundred tokens selects, and Lightning linear attention
+SALA_TEST_SPARSE = BlockKind(attn="sparse", num_heads=4, rotary_share=0.0,
+                             gate="elementwise", qk_norm=True,
+                             sparse_kernel=4, sparse_stride=2,
+                             sparse_block=8, sparse_topk=3, sparse_init=1,
+                             sparse_local=16, sparse_dense_len=48)
+SALA_TEST_LIGHTNING = BlockKind(attn="lightning", num_heads=4,
+                                gate="elementwise", qk_norm=True)
+
 # xing4_0 (XingChen-AGI/Xing4.0-29B-A4B config.json): deepseek's YaRN on
 # the 64 rope lanes of a latent head (factor 64 over 4,096 positions,
 # beta 32 / 1; cos and sin times mscale / mscale_all_dim = 1) and the
@@ -316,6 +327,18 @@ MODEL_REGISTRY = {
         dtype_name="float32",
         period=(NEMOTRON_TEST_M, NEMOTRON_TEST_E, NEMOTRON_TEST_M,
                 NEMOTRON_TEST_A, NEMOTRON_TEST_E)),
+    # minicpm_sala at toy size, 2 repeats of (sparse, lightning, lightning,
+    # sparse): a dense SwiGLU in every block, muP's three multipliers, an
+    # untied head; 4 query heads over 2 kv heads in the sparse kind, 4
+    # heads of a [16, 16] float32 state in the linear one
+    "minicpm-sala-test": ModelConfig(
+        family="minicpm_sala", vocab_size=256, hidden_size=64, num_layers=2,
+        num_heads=4, num_kv_heads=2, head_dim_override=16,
+        intermediate_size=96, max_seq_len=512, norm_eps=1e-6,
+        embedding_multiplier=12.0, residual_multiplier=1.4 / 32 ** 0.5,
+        logits_scaling=4.0, dtype_name="float32",
+        period=(SALA_TEST_SPARSE, SALA_TEST_LIGHTNING, SALA_TEST_LIGHTNING,
+                SALA_TEST_SPARSE)),
 }
 
 

@@ -749,7 +749,8 @@ def paged_flash_attention(
 # grid step a page of the table.
 
 
-def _fold_page(q, k_blk, v_blk, state, kv_pos, q_pos, slope, window):
+def _fold_page(q, k_blk, v_blk, state, kv_pos, q_pos, slope, window,
+               keeps=None):
     """One page folded into one kv head's online-softmax ``state``
     ``(o [rows, hd], m [rows, 1], l [rows, 1])``, all float32: ``q``
     [rows, hd] scaled, ``k_blk`` / ``v_blk`` [bt, hd] dequantized,
@@ -760,12 +761,16 @@ def _fold_page(q, k_blk, v_blk, state, kv_pos, q_pos, slope, window):
     layer), so causality alone makes a query see exactly its prefix plus
     its own earlier in-chunk keys.  Under a ``window`` a row sees keys
     ``q_pos - window < j <= q_pos``.  Both prefill kernels fold with
-    this, so they agree bit for bit on the pages both visit."""
+    this, so they agree bit for bit on the pages both visit.  ``keeps``
+    [rows, 1] bool (``ops.sparse_attention``): the rows that fold these
+    keys at all."""
     o, m, l = state
     s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)  # [rows, bt]
     valid = kv_pos <= q_pos
     if window:
         valid = valid & (q_pos - kv_pos < window)
+    if keeps is not None:
+        valid = valid & keeps
     if slope is not None:
         s = s - slope * (q_pos - kv_pos).astype(jnp.float32)
     s = jnp.where(valid, s, _NEG)
@@ -1618,6 +1623,45 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
         eva_impl.stacked_cache = True
         return eva_impl
 
+    def for_sparse(pool: int, pools: int, kind, name: str):
+        """The hook of a block-sparse kind (``ops.sparse_attention``;
+        docs/DESIGN.md section 32): its keys' cache comes as ``(pages,
+        index plane, the rows that hold a token or None)`` and goes back as
+        ``(pages, index plane, what the call's selections kept)``
+        (``sparse_attention.kept_counts``, summed over a merged call's
+        parts); it reads pool ``pool``'s table as :func:`for_pool`'s
+        does."""
+        from .sparse_attention import sparse_attend
+
+        def sparse_impl(q, k, v, k_cache, v_pages, positions, cache_start,
+                        slopes):
+            program = f"{bound['program']}/{name}"
+            note = None
+            if record is not None:
+                note = lambda chunk, path, why, to: record.note(  # noqa: E731
+                    program, chunk, path, why, to)
+            pages, index, valid = k_cache
+            if valid is None:
+                valid = jnp.ones(positions.shape, bool)
+
+            def one(tables, q, k, v, pos, valid, kp, vp, ix, kept):
+                width = (tables.shape[1] - state_cols) // pools
+                out, kp, vp, ix, counts = sparse_attend(
+                    q, k, v, kp, vp, ix, pos,
+                    tables[:, pool * width:(pool + 1) * width], kind,
+                    backend=backend, interpret=interpret, note=note,
+                    valid=valid)
+                return out, kp, vp, ix, kept + counts
+
+            with jax.named_scope(f"attn_{name}"):
+                out, kp, vp, ix, kept = over_parts(
+                    bound["tables"], (q, k, v, positions, valid),
+                    (pages, v_pages, index, jnp.zeros((3,), jnp.int32)), one)
+            return out, (kp, ix, kept), vp
+
+        sparse_impl.stacked_cache = True
+        return sparse_impl
+
     def for_state(name: str):
         """The hook of a block whose cache is a recurrent state
         (``models.decoder._kda_mixer``, ``_ssd_mixer``): ``rows()`` is each
@@ -1636,6 +1680,7 @@ def make_paged_attn_impl(block_tokens: int, backend: str = "auto",
             note=note, backend=backend, interpret=interpret)
 
     impl.for_pool = for_pool
+    impl.for_sparse = for_sparse
     impl.for_state = for_state
     impl.parts = parts_of(bound)
     impl.note_streams = streams_note(record, bound)

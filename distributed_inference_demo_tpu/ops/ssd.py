@@ -49,7 +49,11 @@ in eight groups), ``P`` a power of two from 8 to 128 whose heads fill
 whole tiles: the step reads a head's B and C from its group's row; the
 chunk call takes a
 block of heads OF ONE GROUP a grid step (16, or the group's where it has
-fewer) with that group's ``B C^T``.  Elsewhere, and in float32 tests, plain
+fewer) with that group's ``B C^T``.  B and C may also be a HEAD's own
+(groups = heads: a linear-attention kind, whose state is ``sum lambda^(t-s)
+v_s k_s^T`` and whose read-out is ``S q``; :func:`lightning_log_decay`):
+the chunk call's block of heads then brings its own B and C, one ``B C^T``
+a head.  Elsewhere, and in float32 tests, plain
 XLA with the same arithmetic.
 """
 
@@ -96,8 +100,12 @@ def on_kernel(state_shape, groups: int = 1, chunk: int = 1,
     # heads (granite), or groups of 8 or 16 heads, one head block each
     # (nemotron_h's 64 heads in 8)
     per = h // groups if h % groups == 0 else 0
+    # ... or B and C a HEAD (groups = heads, a linear-attention kind's k
+    # and q: the chunk call then takes a block of heads with its own B C^T
+    # each), whole blocks of 8 heads
     if n != 128 or p % 8 or _LANES % p or h % (_LANES // p) or not (
-            h % 128 == 0 if groups == 1 else per in (8, _CHUNK_HEADS)):
+            h % 128 == 0 if groups == 1 else
+            h % 8 == 0 if per == 1 else per in (8, _CHUNK_HEADS)):
         return False, f"state {h} x {p} x {n}, {groups} groups"
     if chunk > 1 and chunk % 128:
         return False, f"chunk {chunk} not whole lanes of 128"
@@ -106,7 +114,11 @@ def on_kernel(state_shape, groups: int = 1, chunk: int = 1,
 
 def _chunk_heads(heads: int, groups: int) -> int:
     """Heads a grid step of the chunk kernel: ``_CHUNK_HEADS``, or the
-    group's where it has fewer (a block never spans two groups)."""
+    group's where it has fewer (a block never spans two groups); where B
+    and C are a head's own (groups = heads) a block of heads brings its B
+    and C along, so it is as many as divide the heads."""
+    if groups == heads:
+        return math.gcd(_CHUNK_HEADS, heads)
     return min(_CHUNK_HEADS, heads // groups)
 
 
@@ -194,9 +206,9 @@ def _step_operands(x, B, C, dt, A):
             jnp.stack([B, C], axis=1))
 
 
-@functools.partial(jax.jit, static_argnames=("unroll", "interpret"))
+@functools.partial(jax.jit, static_argnames=("unroll", "interpret", "name"))
 def _ssd_step_call(rows, at, plane, n, a, dx, bc, state, *, unroll=_UNROLL,
-                   interpret=False):
+                   interpret=False, name="_ssd_step"):
     """``a`` ``[b H]``, ``dx`` ``[b, H P / 128, 128]``, ``bc`` ``[b, 2, G,
     N]``, ``state`` ``[Pl, R, H, P, N]`` aliased to the second output;
     step ``i`` works batch row ``at[i]`` on ``state[plane, rows[i]]`` where
@@ -227,7 +239,7 @@ def _ssd_step_call(rows, at, plane, n, a, dx, bc, state, *, unroll=_UNROLL,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM),
         interpret=interpret,
-        name="_ssd_step",
+        name=name,
     )(rows, at, plane, n, a, dx, bc, state)
 
 
@@ -258,7 +270,8 @@ def _of_heads(a, heads: int):
 
 
 def ssd_step(state, plane, rows, x, B, C, dt, A, live, *,
-             kernel: bool = False, interpret: bool = False):
+             kernel: bool = False, interpret: bool = False,
+             name: str = "_ssd_step"):
     """One token a row.  ``state`` ``[Pl, R, H, P, N]`` float32, the whole
     pool; ``plane`` an int32 scalar; ``rows`` ``[b]`` the pool row of each
     batch row, or ``None`` where row ``i`` is pool row ``i`` (a dense
@@ -267,7 +280,8 @@ def ssd_step(state, plane, rows, x, B, C, dt, A, live, *,
     ``None``.  Returns ``(y [b, H, P] float32, state')``, ``y`` without the
     ``D`` skip.  A dead row's state is not touched; two live rows never
     name one pool row.  The last pool row is nobody's (dead rows point
-    there where the kernel needs a place)."""
+    there where the kernel needs a place).  ``name``: the Pallas call's in a
+    trace (a linear-attention kind's own, ``_la_step``)."""
     R, H = state.shape[1:3]
     b = x.shape[0]
     x, B, C, dt, A = (t.astype(F32) for t in (x, B, C, dt, A))
@@ -284,7 +298,8 @@ def ssd_step(state, plane, rows, x, B, C, dt, A, live, *,
         rows, at, n = _blocks_of(rows, live, trash)
         y, state = _ssd_step_call(
             rows, at, jnp.reshape(plane, (1,)).astype(jnp.int32), n,
-            *_step_operands(x, B, C, dt, A), state, interpret=interpret)
+            *_step_operands(x, B, C, dt, A), state, interpret=interpret,
+            name=name)
         return jnp.where(live[:, None, None], y.reshape(x.shape), 0.0), state
     # XLA: the pool's plane is worked on where it lies, every row of it,
     # and what is small (x, B, C, dt, the outputs) moves instead
@@ -332,7 +347,7 @@ def _chunk_pass(S, dx, B, C, l):
 
 def _ssd_chunk_kernel(row_ref, plane_ref, fresh_ref, dx_ref, l_ref, lt_ref,
                       le_ref, b_ref, ct_ref, s_ref, y_ref, out_ref, *,
-                      heads: int):
+                      heads: int, per_head: bool = False):
     """Grid (head blocks, chunks), the chunks in order.  The block of the
     pool ``[1, 1, heads, P, N]`` stays in ``out_ref`` from the first chunk
     (where it is the pool's, or zero for a segment that starts a request)
@@ -341,8 +356,9 @@ def _ssd_chunk_kernel(row_ref, plane_ref, fresh_ref, dx_ref, l_ref, lt_ref,
     ``[1, 1, Q, heads]`` the same as columns, ``le_ref`` ``[1, heads, N]``
     its last entry spread over the state's lanes (Mosaic spreads one number
     over one axis at a time), ``b_ref`` ``[1, 1, Q, N]`` and
-    ``ct_ref`` ``[1, 1, N, Q]`` B and C^T of the head block's group;
-    ``y_ref`` ``[1, heads, P, Q]`` the output transposed."""
+    ``ct_ref`` ``[1, 1, N, Q]`` B and C^T of the head block's group
+    (``per_head``: ``[1, heads, Q, N]`` / ``[1, heads, N, Q]``, a head's
+    own); ``y_ref`` ``[1, heads, P, Q]`` the output transposed."""
     del row_ref, plane_ref
 
     @pl.when(pl.program_id(1) == 0)
@@ -353,10 +369,14 @@ def _ssd_chunk_kernel(row_ref, plane_ref, fresh_ref, dx_ref, l_ref, lt_ref,
     B, CT = b_ref[0, 0], ct_ref[0, 0]
     dt_ = B.dtype
     Q = B.shape[0]
-    G = _dot(B, CT)                                 # [s, t] = B_s . C_t
+    if not per_head:
+        G = _dot(B, CT)                             # [s, t] = B_s . C_t
     upper = (jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
              <= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1))   # s <= t
     for h in range(heads):
+        if per_head:
+            B, CT = b_ref[0, h], ct_ref[0, h]
+            G = _dot(B, CT)
         lrow = l_ref[0, h:h + 1, :]                 # [1, Q] over t (or s)
         lcol = lt_ref[0, 0, :, h:h + 1]             # [Q, 1] over s
         MT = (G * jnp.exp(jnp.where(upper, lrow - lcol, -jnp.inf))
@@ -371,9 +391,9 @@ def _ssd_chunk_kernel(row_ref, plane_ref, fresh_ref, dx_ref, l_ref, lt_ref,
             (dx.astype(F32) * w).astype(dt_), B)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "name"))
 def _ssd_chunk_call(row, plane, fresh, dxT, l, lT, lE, Bm, CT, state, *,
-                    interpret=False):
+                    interpret=False, name="_ssd_chunk"):
     """``dxT`` ``[n, H, P, Q]``, ``l`` ``[n, H, Q]``, ``lT`` ``[n, H / hb,
     Q, hb]``, ``lE`` ``[n, H, N]``, ``Bm`` ``[n, G, Q, N]``, ``CT`` ``[n,
     G, N, Q]``; ``state`` aliased to the second output.  Head block ``j``
@@ -381,13 +401,16 @@ def _ssd_chunk_call(row, plane, fresh, dxT, l, lT, lE, Bm, CT, state, *,
     n, H, P, Q = dxT.shape
     G, N = Bm.shape[1], Bm.shape[-1]
     hb = lT.shape[-1]
-    per = (H // G) // hb            # head blocks a group
+    per_head = G == H               # B and C a head: a block brings its own
+    per = 1 if per_head else (H // G) // hb     # head blocks a group
+    gb = hb if per_head else 1      # rows of B and C a head block
     s_spec = pl.BlockSpec(
         (1, 1, hb, P, N),
         lambda j, i, row, plane, fresh: (plane[0], row[0], j, 0, 0))
     tile = pl.BlockSpec((1, hb, P, Q), lambda j, i, *_: (i, j, 0, 0))
     return pl.pallas_call(
-        functools.partial(_ssd_chunk_kernel, heads=hb),
+        functools.partial(_ssd_chunk_kernel, heads=hb,
+                          **({"per_head": True} if per_head else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(H // hb, n),
@@ -396,9 +419,9 @@ def _ssd_chunk_call(row, plane, fresh, dxT, l, lT, lE, Bm, CT, state, *,
                       pl.BlockSpec((1, 1, Q, hb),
                                    lambda j, i, *_: (i, j, 0, 0)),
                       pl.BlockSpec((1, hb, N), lambda j, i, *_: (i, j, 0)),
-                      pl.BlockSpec((1, 1, Q, N),
+                      pl.BlockSpec((1, gb, Q, N),
                                    lambda j, i, *_: (i, j // per, 0, 0)),
-                      pl.BlockSpec((1, 1, N, Q),
+                      pl.BlockSpec((1, gb, N, Q),
                                    lambda j, i, *_: (i, j // per, 0, 0)),
                       s_spec],
             out_specs=[tile, s_spec]),
@@ -409,13 +432,13 @@ def _ssd_chunk_call(row, plane, fresh, dxT, l, lT, lE, Bm, CT, state, *,
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM),
         interpret=interpret,
-        name="_ssd_chunk",
+        name=name,
     )(row, plane, fresh, dxT, l, lT, lE, Bm, CT, state)
 
 
 def ssd_chunk(state, plane, row, fresh, x, B, C, dt, A, *,
               chunk: int = CHUNK, kernel: bool = False,
-              interpret: bool = False):
+              interpret: bool = False, name: str = "_ssd_chunk"):
     """One segment of ``s`` tokens of one request, in order, starting from
     ``state[plane, row]`` (from zero where ``fresh``: the segment starts a
     request) and leaving its final state there.  ``x`` ``[s, H, P]`` and
@@ -424,7 +447,8 @@ def ssd_chunk(state, plane, row, fresh, x, B, C, dt, A, *,
     not there, ``A`` ``[H]`` (negative); ``row`` and ``plane`` int32
     scalars (``row`` inside the pool).  Returns ``(y [s, H, P] in ``x``'s
     dtype, state')``, ``y`` without the ``D`` skip.  ``s`` is padded to
-    whole chunks with tokens that are not there."""
+    whole chunks with tokens that are not there.  ``name``: the Pallas
+    call's in a trace (a linear-attention kind's own, ``_la_chunk``)."""
     s, H, P = x.shape
     chunk = min(chunk, s)           # a short segment is one chunk
     pad = -s % chunk
@@ -450,7 +474,7 @@ def ssd_chunk(state, plane, row, fresh, x, B, C, dt, A, *,
             jnp.broadcast_to(l[:, -1, :, None], (n, H, B.shape[-1])),
             jnp.swapaxes(B, 1, 2),                          # [n, G, Q, N]
             jnp.transpose(C, (0, 2, 3, 1)), state,          # [n, G, N, Q]
-            interpret=interpret)
+            interpret=interpret, name=name)
         y = jnp.transpose(yT, (0, 3, 1, 2))                 # [n, Q, H, P]
     else:
         S = jnp.where(fresh, 0.0, state[plane, row])
@@ -462,6 +486,15 @@ def ssd_chunk(state, plane, row, fresh, x, B, C, dt, A, *,
         y = jnp.stack(outs)
         state = state.at[plane, row].set(S)
     return y.reshape((n * chunk, H, P))[:s], state
+
+
+def lightning_log_decay(heads: int):
+    """``log lambda_h`` ``[heads]`` float32 of a Lightning linear-attention
+    kind (``models.decoder._lightning_mixer``), the recurrence above with
+    ``dt`` = 1 and this for ``A``: the Lightning Attention slopes,
+    ``lambda_h = exp(-2 ** (-8 (h + 1) / heads))``, the same in every
+    layer."""
+    return -jnp.exp2(-8.0 * jnp.arange(1, heads + 1, dtype=F32) / heads)
 
 
 def ssd_recurrence(S, x, B, C, dt, A):

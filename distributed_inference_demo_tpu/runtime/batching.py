@@ -96,6 +96,7 @@ from ..telemetry.tracing import (EVA_DISPATCH_FIELDS,
                                  LATENT_DISPATCH_FIELDS,
                                  LOOP_DISPATCH_FIELDS,
                                  MOE_DISPATCH_FIELDS,
+                                 SPARSE_DISPATCH_FIELDS,
                                  STATE_DISPATCH_FIELDS,
                                  WINDOW_DISPATCH_FIELDS, DispatchTrace,
                                  LoopCounters, MoeCounters, TraceRecorder,
@@ -474,11 +475,6 @@ class ContinuousBatchingEngine:
                                       "prompt lookup)")
             if mesh is not None and mesh.shape.get("tp", 1) > 1:
                 require_no_state(cfg, "tensor parallelism (--tp)")
-            if cfg.num_experts == 0:
-                raise ValueError(
-                    "a model with a recurrent state is served with its "
-                    "experts' row mask (the rows that hold a token): a "
-                    "dense one has no such mask yet")
         if cfg.mixed_kinds:
             # a cache spec a kind of block (docs/DESIGN.md section 25):
             # one pool and one table a kind, window pages freed while the
@@ -716,6 +712,21 @@ class ContinuousBatchingEngine:
                          self._pool_specs, (N, self._wmgr.num_blocks))]
             self._pk, self._pv = (tuple(p[0] for p in pools),
                                   tuple(p[1] for p in pools))
+        if cfg.sparse_kind is not None:
+            # a sparse kind's index plane beside the full kind's pool
+            # (``ModelConfig.index_shape``): a page's pooled keys under the
+            # page's id, one element in its place among the values
+            self._pk += (alloc_kv_pool(cfg.index_shape(N, bt), "bf16",
+                                       page_dtype, streams=1)[0],)
+            self._pv += (alloc_kv_pool((1,), "bf16", page_dtype,
+                                       streams=1)[0],)
+            # (``_sparse_account``: the scheduler's arithmetic on the
+            # queries' positions, then what the DEVICE counted)
+            self.sparse_stats = {"queries_dense": 0, "queries_sparse": 0,
+                                 "blocks_live": 0, "blocks_kept": 0,
+                                 "index_rows": 0, "device_queries_sparse": 0,
+                                 "device_blocks_kept_sparse": 0,
+                                 "device_blocks_kept": 0}
         if cfg.state_planes:
             # the state pool rides last among the keys' pools and the
             # convolution tails among the values': donated, aliased and
@@ -975,16 +986,23 @@ class ContinuousBatchingEngine:
             # summed over the execution's layer calls, then experts
             # touched, the fullest expert's rows in one layer call,
             # and the layer calls); a dense model's program is as it was
-            moe_ = cfg_.num_experts > 0
             state_ = cfg_.state_planes > 0
+            # (a dense model with a recurrent state takes the same programs
+            # for the row mask they carry; one with a sparse kind counts in
+            # them what its selections kept, ``[3]`` int32 a block:
+            # ``ops.sparse_attention.kept_counts``)
+            moe_ = cfg_.num_experts > 0 or state_
+            sparse_ = cfg_.sparse_kind is not None
             E_ = cfg_.experts_here      # the experts this chip holds
             sentinel_ = self._page_sentinel     # a table entry of no page
 
             def moe_acc0():
-                return jnp.zeros((E_ + 3,), jnp.int32)
+                return jnp.zeros((3 if sparse_ else E_ + 3,), jnp.int32)
 
             def moe_fold(acc, rows):
                 """Fold one pass's ``[layers, E]`` row counts in."""
+                if sparse_:
+                    return acc + rows.sum(0)
                 return jnp.concatenate([
                     acc[:E_] + rows.sum(0),
                     jnp.stack([acc[E_] + (rows > 0).sum(),
@@ -1612,7 +1630,9 @@ class ContinuousBatchingEngine:
                              if moe else None)
         # ... and its slab is told the tokens each segment holds, an
         # eighth segment array (`_blank_segments`)
-        self._seg_arrays = 8 if moe else 7
+        self._seg_arrays = (8 if moe or (cfg.state_planes
+                                         and self._mixed_step is not None)
+                            else 7)
         self._set_row = jax.jit(lambda rows, r, row: rows.at[r].set(row))
         # a looped model counts its passes (tracing.LoopCounters); a
         # one-pass model's record and /stats are as they were
@@ -1636,6 +1656,8 @@ class ContinuousBatchingEngine:
             + (EVA_DISPATCH_FIELDS if self._eva is not None else ())
             + (STATE_DISPATCH_FIELDS[cfg.state_kind.attn]
                if self._state_free is not None else ())
+            + (SPARSE_DISPATCH_FIELDS if cfg.sparse_kind is not None
+               and self._mixed_step is not None else ())
             + (HC_DISPATCH_FIELDS if cfg.hc_streams else ()))
         # a model with n residual streams: the token rows its residual
         # path computed (every row of a slab and every slot of a decode
@@ -2602,6 +2624,28 @@ class ContinuousBatchingEngine:
                     self.eva_stats, window=self._eva.window,
                     chunk=self._eva.chunk,
                     window_pages=self._eva.window_pages)
+        if self.cfg.sparse_kind is not None:
+            # the sparse kind's queries by rule, and the blocks their
+            # contexts held against the blocks their folds kept (a kv head
+            # a sparse block; ``_sparse_account``): by the scheduler's
+            # arithmetic, and ``device_*`` as the programs' selections
+            # counted them (summed over kv heads and sparse blocks, so
+            # divided by them here)
+            kind = self.cfg.sparse_kind
+            per = self.cfg.num_kv_heads * self.cfg.sparse_blocks
+            dev = {k: v / per for k, v in self.sparse_stats.items()
+                   if k.startswith("device_")}
+            out["sparse"] = dict(
+                self.sparse_stats, **dev,
+                device_kept_a_sparse_query=(
+                    dev["device_blocks_kept_sparse"]
+                    / dev["device_queries_sparse"]
+                    if dev["device_queries_sparse"] else None),
+                block=kind.sparse_block,
+                topk=kind.sparse_topk, dense_len=kind.sparse_dense_len,
+                kept_at_most=(kind.sparse_init
+                              + kind.sparse_local // kind.sparse_block
+                              + kind.sparse_topk))
         # dispatch-floor picture (§13): dispatches vs device steps —
         # steps/dispatches ≈ decode_block when fusion is engaging
         out["device_loop"] = dict(self.loop_stats,
@@ -2992,6 +3036,54 @@ class ContinuousBatchingEngine:
                 "shape": list(got.shape),
                 "float32_b64": base64.b64encode(
                     got.astype("<f4").tobytes()).decode("ascii")}
+
+    def _sparse_account(self, plan, steps: int, device) -> dict:
+        """The record's ``SPARSE_DISPATCH_FIELDS`` of a returned dispatch
+        and ``/stats.sparse``'s sums: what the sparse kind's selections
+        scored and its folds kept for the slab's prompt tokens and the
+        decoding rows' steps inside their budgets (a final's after its
+        token #1), a kv head a sparse block, from the queries' positions
+        alone (the least any program does for them: the kernels' roofline
+        readers count it); and ``device``, the dispatch's own counters
+        (``ops.sparse_attention.kept_counts`` summed over its layer calls:
+        the pairs that selected, the blocks those kept, the blocks all
+        kept), which say what the program DID keep."""
+        from ..ops.sparse_attention import blocks_kept
+        sizes = self.cfg.sparse_kind.sparse_sizes
+
+        def of(t):
+            live, kept, rows = blocks_kept(t, sizes)
+            dense = int((t < sizes[6]).sum())
+            return (int(live.sum()), int(kept.sum()), int(rows.sum()),
+                    dense, len(t) - dense)
+
+        ntok, starts = plan.seg[7], plan.seg[2]
+        slab = [np.arange(int(starts[r0]), int(starts[r0]) + int(ntok[r0]))
+                for (r0, _, _, _) in plan.packed]
+        # a slot's steps inside its budget (as the state's ``row_steps``),
+        # from the position its next token is fed at
+        took = np.maximum(np.minimum(plan.budget_vec,
+                                     steps - plan.first_col), 0)
+        at = [(slot, len(s[0].prompt) + s[1] - 1)
+              for slot, s in enumerate(plan.rows) if s is not None]
+        at += [(slot, len(req.prompt)) for req, slot in plan.finals]
+        rows = [np.arange(t, t + int(took[slot])) for slot, t in at]
+        zero = np.zeros((0,), np.int64)
+        a = of(np.concatenate(slab or [zero]))
+        b = of(np.concatenate(rows or [zero]))
+        st = self.sparse_stats
+        for key, i in (("blocks_live", 0), ("blocks_kept", 1),
+                       ("index_rows", 2), ("queries_dense", 3),
+                       ("queries_sparse", 4)):
+            st[key] += a[i] + b[i]
+        for key, n in zip(("device_queries_sparse",
+                           "device_blocks_kept_sparse",
+                           "device_blocks_kept"), device):
+            st[key] += int(n)
+        per = self.cfg.num_kv_heads * self.cfg.sparse_blocks
+        return dict(zip(SPARSE_DISPATCH_FIELDS,
+                        (a[0] + b[0], a[1] + b[1], a[2] + b[2], b[1], b[2],
+                         int(device[2]) / per)))
 
     def _tiles_a_pool(self) -> tuple:
         """``(tile tokens, window)`` a pool, the full (or only) kind's
@@ -4740,6 +4832,9 @@ class ContinuousBatchingEngine:
             record.update(zip(
                 STATE_DISPATCH_FIELDS[self.cfg.state_kind.attn],
                 (row_steps, prefill_tokens)))
+        if "sparse_blocks_kept" in self.dispatch_trace.extra_fields:
+            record.update(self._sparse_account(
+                plan, steps, np.asarray(flight.out[5])))
         if self.hc_stats is not None:
             # a slab's pass holds the slots' rows too (whether or not a
             # step rode it), padded to the kernels' whole tiles

@@ -392,7 +392,7 @@ BATCH_HC_SINKHORN_RESIDUAL = gauge(
 BATCH_STATE_ROW_STEPS = counter(
     "dwt_batching_state_row_steps_total",
     "Rows x decode steps that advanced a recurrent state (state."
-    "row_steps; the dispatch record's kda_row_steps / ssd_row_steps)")
+    "row_steps; the dispatch record's kda_row_steps / ssd_row_steps / lightning_row_steps)")
 BATCH_STATE_CHUNK_TOKENS = counter(
     "dwt_batching_state_chunk_tokens_total",
     "Prompt tokens that went through a state kind's chunk form (state."
@@ -404,6 +404,35 @@ KVCACHE_STATE_SLOT_BYTES = gauge(
 KVCACHE_STATE_HELD_SLOTS = gauge(
     "dwt_kvcache_state_held_slots",
     "Rows of the state pool that requests hold now (state.held)")
+
+
+# -- a block-sparse kind's selection (``/stats.sparse``; docs/DESIGN.md
+# section 32): absent for every other model ---------------------------------
+
+BATCH_SPARSE_QUERIES = counter(
+    "dwt_batching_sparse_query_tokens_total",
+    "Queries (a token each) of a sparse kind by the rule that folded them "
+    "(kind=dense, sparse.queries_dense: under dense_len, every block of "
+    "the context; kind=sparse, sparse.queries_sparse: the forced blocks "
+    "and the top-k)", ("kind",))
+BATCH_SPARSE_BLOCKS_LIVE = counter(
+    "dwt_batching_sparse_live_blocks_total",
+    "Blocks the sparse kind's queries had in their contexts, a kv head a "
+    "sparse block (sparse.blocks_live; the record's sparse_blocks_live)")
+BATCH_SPARSE_BLOCKS_KEPT = counter(
+    "dwt_batching_sparse_kept_blocks_total",
+    "Blocks the sparse kind's folds keep of those by the equations, the "
+    "scheduler's arithmetic on the queries' positions (sparse.blocks_kept; "
+    "the record's sparse_blocks_kept)")
+BATCH_SPARSE_DEVICE_BLOCKS_KEPT = counter(
+    "dwt_batching_sparse_device_kept_blocks_total",
+    "Blocks the programs' selections kept, counted on the device where "
+    "each mask is handed to its fold, in the same unit (sparse."
+    "device_blocks_kept; the record's sparse_device_blocks_kept)")
+BATCH_SPARSE_INDEX_ROWS = counter(
+    "dwt_batching_sparse_index_entries_total",
+    "Pooled keys of the index plane the selections scored (sparse."
+    "index_rows; the record's sparse_index_rows)")
 
 
 # -- a period of blocks of one sublayer (``/stats.blocks``; docs/DESIGN.md
@@ -466,6 +495,16 @@ def update_batching_series(stats: dict) -> None:
             state.get("chunk_tokens", 0))
         KVCACHE_STATE_SLOT_BYTES.set(state.get("bytes_per_slot", 0))
         KVCACHE_STATE_HELD_SLOTS.set(state.get("held", 0))
+    sparse = stats.get("sparse") or {}
+    if sparse:
+        for rule in ("dense", "sparse"):
+            BATCH_SPARSE_QUERIES.set_cumulative(
+                sparse.get(f"queries_{rule}", 0), kind=rule)
+        BATCH_SPARSE_BLOCKS_LIVE.set_cumulative(sparse.get("blocks_live", 0))
+        BATCH_SPARSE_BLOCKS_KEPT.set_cumulative(sparse.get("blocks_kept", 0))
+        BATCH_SPARSE_DEVICE_BLOCKS_KEPT.set_cumulative(
+            sparse.get("device_blocks_kept", 0))
+        BATCH_SPARSE_INDEX_ROWS.set_cumulative(sparse.get("index_rows", 0))
     blocks = stats.get("blocks") or {}
     if blocks:
         for kind, n in blocks.get("kinds", {}).items():

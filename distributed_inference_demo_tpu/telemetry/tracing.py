@@ -263,7 +263,19 @@ EVA_DISPATCH_FIELDS = ("kv_attended_rows", "kv_summary_rows",
 # ``<kind>_chunk_tokens``: the prompt tokens that went through the chunk
 # form (host arithmetic)
 STATE_DISPATCH_FIELDS = {kind: (f"{kind}_row_steps", f"{kind}_chunk_tokens")
-                         for kind in ("kda", "ssd")}
+                         for kind in ("kda", "ssd", "lightning")}
+# a model with a block-sparse kind (``ops.sparse_attention``), a kv head a
+# sparse block, summed over the execution's queries (host arithmetic on
+# their positions): the blocks of 64 tokens their contexts hold, the blocks
+# their folds keep (all of them under ``dense_len``) and the pooled keys
+# their selections score; and the decoding rows' part of the last two (the
+# slab's is the rest).  Last, NOT host arithmetic: the blocks the program's
+# selections kept, counted on the device where the mask is handed to the
+# fold (``ops.sparse_attention.kept_counts``), in the same unit
+SPARSE_DISPATCH_FIELDS = ("sparse_blocks_live", "sparse_blocks_kept",
+                          "sparse_index_rows", "sparse_decode_blocks_kept",
+                          "sparse_decode_index_rows",
+                          "sparse_device_blocks_kept")
 # a model with more than one residual stream (``hc_streams``): the token
 # rows the residual path's two kernels computed in the execution, the
 # slab's rows and every slot at every decode step, a slab's pass with the

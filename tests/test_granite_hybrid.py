@@ -283,7 +283,9 @@ def test_the_table_tool_holds_every_form_to_the_step_s_arithmetic(form):
                         c["slots"], c["live"])
             for c in tool.ssd_configs([])} == {
         "granite-4.0-h-small-bf16-ep2": (128, 64, 128, 1, 9, 32, 20),
-        "nemotron-3-nano-30b-a3b-bf16-ep2": (64, 64, 128, 8, 4, 64, 64)}
+        "nemotron-3-nano-30b-a3b-bf16-ep2": (64, 64, 128, 8, 4, 64, 64),
+        # (PR 69: the linear kind rides the call, B and C a head's own)
+        "minicpm-sala-9b-bf16": (32, 128, 128, 32, 6, 16, 12)}
     toy = dict(name="toy", planes=2, slots=5, live=3, heads=16, p=16, n=128,
                groups=2)
     row, = tool.rows_of(toy, argparse.Namespace(

@@ -83,7 +83,8 @@ def test_every_older_model_is_a_period_of_nothing():
     for name, cfg in MODEL_REGISTRY.items():
         if name not in ("laguna-test", "solar-open2-test",      # (PR 56)
                         "granite-hybrid-test",                  # (PR 62)
-                        "nemotron-h-test"):                     # (PR 66)
+                        "nemotron-h-test",                      # (PR 66)
+                        "minicpm-sala-test"):                   # (PR 69)
             assert not cfg.mixed_kinds and cfg.cache_kinds == (
                 (0, cfg.kv_planes),), name
 

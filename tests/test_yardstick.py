@@ -59,11 +59,17 @@ def benchmark_test_files(family: bool):
                   if p.name.endswith("_family.py") == family)
 
 
-def run_benchmark_test_file(name):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+def run_benchmark_test_file(name, *options, case=None):
+    """``options`` are further words for pytest; ``case`` is one test of
+    the file, run alone."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(
+                   [str(Path(__file__).resolve().parent),
+                    *filter(None, [os.environ.get("PYTHONPATH")])]))
+    node = str(BENCH / "tests" / name) + (f"::{case}" if case else "")
     proc = subprocess.run(
-        [sys.executable, "-m", "pytest", str(BENCH / "tests" / name), "-q",
-         "-p", "no:cacheprovider"],
+        [sys.executable, "-m", "pytest", node, "-q",
+         "-p", "no:cacheprovider", *options],
         cwd=BENCH.parent, env=env, capture_output=True, text=True,
         # the longest (xing's: a rehearsal of its cell) takes 150 s alone
         # and 210 s beside five busy workers on eight cores

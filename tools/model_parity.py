@@ -139,6 +139,23 @@ the cell holds to the same limit through the family's ``replay``), 1.2e-6
 sound, 4.9e-3 with bfloat16 maps, 0.13-0.17 after one step, held to
 ``HC_RESIDUAL_MAX``.
 
+A model with a block-sparse kind (family ``minicpm_sala``: ``--model
+minicpm-sala-9b-bf16``) is read long (``--batch 1 --prompt 24600 --steps
+16``: every query read is past ``dense_len`` three times over and keeps 97
+of 385 blocks) against three controls that must exit 1, each PLANTED IN THE
+SERVED PROGRAM by swapping a function its dispatch calls
+(``selection_control``): ``--selection forced-only``, ``one-head`` and
+``edge-dropped``.  Two numbers hold them: the log-probabilities
+(``FAMILY_TOL``, between the sound readings and the forced blocks alone),
+and ``kept_differ_max``, the blocks of a query's kept set that differ
+between what the served program's selection handed its fold (``keep_tap``:
+the scores' Pallas call on bfloat16 q, the bisection, for the prompt's last
+queries in a tile of 32 and the last decode steps' in a tile of one; read
+in a second pass of the served path, since the tap's host callback changes
+what the compiler fuses around it and the log-probabilities are the
+untapped program's) and the family's ``kept_blocks`` in float32:
+``READINGS_SPARSE``.
+
 One ``MODEL_PARITY {json}`` line, exit code 1 if a limit is passed.
 """
 
@@ -274,11 +291,20 @@ EVA_LONG_TOL = (0.031, 0.10)
 # control that lies behind the last state plane AND under the in-run check's
 # sixteen tokens: PERF.md section 7): 1.27 x and 1.24 x of room.  A bfloat16
 # state is refused by no log-probability: ``state_f32_residue`` holds it
+# minicpm_sala (READINGS_SPARSE below): the head's / 16 leaves small
+# logits, as granite's; the mean between 0.00439, the larger of two sound
+# readings (0.00436, 0.00439; behind the tap's other compilation 0.0051),
+# and 0.0165, the selection cut to its forced blocks (planted in the served
+# program): 1.8 x and 2.1 x of room; the maximum between 0.0050 (0.0061)
+# and 0.0159 (one head's scores), 2.2 x and 1.4 x.  A kernel's keys dropped
+# at a chunk's edge reads 0.0056 / 0.0081 and is refused by the kept sets
+# (``KEPT_DIFFER_MAX``), not here
 FAMILY_TOL = {"deepseek_v3": (0.10, TOL_MAX), "laguna": (0.13, TOL_MAX),
               "evabyte": (0.04, 0.10), "solar_open2": (0.12, TOL_MAX),
               "xing4_0": (0.092, TOL_MAX),
               "granite_moe_hybrid": (0.015, 0.03),
-              "nemotron_h": (0.070, TOL_MAX)}
+              "nemotron_h": (0.070, TOL_MAX),
+              "minicpm_sala": (0.008, 0.011)}
 # (max_over_vocab_mean, max_over_vocab_max, own_token_mean, own_token_max,
 # state_rel_err, state_f32_residue); my chip runs, PR 66, TPU v5 lite,
 # nemotron-3-nano-30b-a3b-bf16-ep2 at published widths (nine blocks, 64 of
@@ -591,8 +617,10 @@ def state_controls(bf16_state=False, not_carried=False, tail_dropped=False,
 
     from distributed_inference_demo_tpu.ops import kda, ssd
 
-    mod = {"kda": kda, "ssd": ssd}[kind]
-    names = (f"{kind}_step", f"{kind}_chunk")
+    # (the linear-attention kind rides the ssd kind's two ops)
+    mod = {"kda": kda, "ssd": ssd, "lightning": ssd}[kind]
+    base = "ssd" if kind == "lightning" else kind
+    names = (f"{base}_step", f"{base}_chunk")
     step, chunk = (getattr(mod, n) for n in names)
     conv = kda.causal_conv
     # (an op of its own: the compiler elides a convert to bfloat16 and
@@ -826,6 +854,9 @@ def served(cfg, params, prompts, args):
                                cfg.dtype)
                  for (_, planes), n in zip(cfg.cache_kinds, pages)]
         pk, pv = tuple(p[0] for p in pools), tuple(p[1] for p in pools)
+        if cfg.sparse_kind is not None:     # the index plane beside pool 0
+            pk += (jnp.zeros(cfg.index_shape(pages[0], bt), cfg.dtype),)
+            pv += (jnp.zeros((1,), cfg.dtype),)
         if cfg.state_planes:    # b rows and one that is nobody's
             s_shape, c_shape = cfg.state_shapes
             pk += (jnp.zeros((cfg.state_planes, b + 1) + s_shape,
@@ -890,6 +921,166 @@ def served(cfg, params, prompts, args):
     state = (np.asarray(pk[-1][:, :b, ::max(1, pk[-1].shape[2] // 4), ::8])
              if cfg.state_planes else None)
     return np.stack(toks, 1), np.stack(lps, 1), record.snapshot(), state
+
+
+# blocks of a query's kept set (97 at the published sizes) that may differ
+# between the SERVED selection and the equations': between the sound
+# readings' largest, 2, and the nearest control's 11 (READINGS_SPARSE)
+KEPT_DIFFER_MAX = 4
+# (max_over_vocab_mean, max_over_vocab_max, state_rel_err, kept_differ_max,
+# kept_differ_mean over 8 positions x 2 kv heads of the first sparse
+# block: the prompt's last four queries, which the PREFILL calls select and
+# fold in a tile of 32, and the last four decode steps', a tile of one); my
+# chip runs, PR 69, TPU v5 lite, minicpm-sala-9b-bf16 at published widths
+# (eight layers, the whole vocabulary), every path Pallas
+# (pallas_sparse_prefill / pallas_sparse_decode / pallas_la); 1 x 24,600 +
+# 16 unless it says otherwise: every query read is past dense_len three
+# times over and keeps 97 of 385 blocks.  The kept sets are what the served
+# PROGRAM chose (``keep_tap``: the mask its selection handed its fold), and
+# the three controls are planted in that program (``selection_control``),
+# so each is refused through the path a request takes; FAMILY_TOL holds the
+# log-probabilities between the sound readings and the forced blocks alone
+READINGS_SPARSE = {
+    "served, seed 0": (0.00436, 0.00473, 0.0202, 1, 0.3125),
+    "served, seed 1, 1 x 30,000 + 16": (0.00439, 0.00497, 0.0198, 2, 0.3125),
+    "--selection forced-only (exit 1; 33 kept of 97)":
+        (0.01653, 0.02003, 0.0697, 64, 64.0),
+    "--selection one-head (exit 1)": (0.01160, 0.01589, 0.0506, 57, 50.25),
+    "--selection edge-dropped (exit 1, by the kept sets alone)":
+        (0.00563, 0.00808, 0.0260, 11, 5.4375),
+    # the log-probabilities read BEHIND the tap, one pass (before the tool
+    # took a second pass for it): another compilation of the same program
+    "behind the tap, seeds 0 / 1 / 2 (24,600 / 30,000 / 27,000)":
+        ((0.00508, 0.00494, 0.00495), (0.00600, 0.00574, 0.00609),
+         (0.0222, 0.0220, 0.0223), (1, 2, 1), 0.3125)}
+
+
+def selection_control(which: str) -> None:
+    """``--selection``: one fault planted in the served selection, by
+    swapping a function of ``ops.sparse_attention`` that the dispatch calls
+    by name (as :func:`state_controls` does; the op carries no switch):
+    ``forced-only`` chooses no block by score (``_choose`` told top-0),
+    ``one-head`` scores with the first query head of each kv group alone
+    (``select_blocks`` handed that head's q), ``edge-dropped`` reads zeros
+    for the keys before a chunk's first token, so the kernels that straddle
+    it pool half of nothing (``_keys_before``)."""
+    import jax.numpy as jnp
+
+    from distributed_inference_demo_tpu.ops import sparse_attention as sa
+
+    if which == "forced-only":
+        choose = sa._choose
+        sa._choose = lambda sj, t, sizes: choose(
+            sj, t, sizes[:3] + (0,) + sizes[4:])
+    elif which == "one-head":
+        select = sa.select_blocks
+
+        def one_head(q, index, tables, positions, sizes, nkv, *a, **k):
+            b, s, nh, hd = q.shape
+            return select(q.reshape(b, s, nkv, nh // nkv, hd)[:, :, :, 0],
+                          index, tables, positions, sizes, nkv, *a, **k)
+
+        sa.select_blocks = one_head
+    elif which == "edge-dropped":
+        before = sa._keys_before
+        sa._keys_before = lambda *a: jnp.zeros_like(before(*a))
+    else:
+        raise ValueError(f"no control of the selection named {which!r}")
+
+
+def read_positions(n_prompt: int, n: int, last: int = 4) -> list:
+    """The positions whose kept sets a reading of ``n`` tokens compares: the
+    prompt's ``last`` (the prefill calls' queries) and the ``last`` of all
+    (the decode calls')."""
+    return sorted({t for t in (*range(n_prompt - last, n_prompt),
+                               *range(n - last, n)) if t >= 0})
+
+
+def keep_tap(want) -> dict:
+    """The kept blocks as the served PROGRAM chose them: the two folds of
+    ``ops.sparse_attention`` (the Pallas call's wrapper and the gather)
+    swapped for themselves behind a host callback that files the mask
+    ``keep`` its selection handed the call, for the queries at the
+    positions ``want``.  Returns the dict the callbacks fill, ``{(plane,
+    row, position): keep [kv heads, blocks] bool}``; read it after
+    ``jax.effects_barrier()``."""
+    import jax
+    import numpy as np
+
+    from distributed_inference_demo_tpu.ops import sparse_attention as sa
+
+    store, want = {}, set(want)
+
+    def file(plane, positions, keep):
+        for row, col in zip(*np.nonzero(np.isin(positions, list(want)))):
+            store[int(plane), int(row), int(positions[row, col])] = (
+                np.asarray(keep[row, col]))
+
+    def tapped(fold):
+        def call(q, k_pages, v_pages, tables, positions, keep, **kw):
+            jax.debug.callback(file, k_pages.layer, positions, keep)
+            return fold(q, k_pages, v_pages, tables, positions, keep, **kw)
+        return call
+
+    sa.sparse_fold = tapped(sa.sparse_fold)
+    sa.sparse_gather_attention = tapped(sa.sparse_gather_attention)
+    return store
+
+
+def selection_reading(cfg, params, ids, n_prompt: int, kept: dict) -> dict:
+    """How many blocks of a query's kept set differ between the SERVED
+    selection and the equations', at :func:`read_positions` (the prompt's
+    last queries, selected and folded by the prefill calls, and the last of
+    ``ids``, by the decode calls), each kv head of the FIRST sparse block (the
+    period's first place: its input is the embedding, so its float32 q and k
+    are one norm and two products from the parameters).  The served side is
+    ``kept`` (:func:`keep_tap`: what the program's selection handed its
+    fold, the first request's), the other the family's ``kept_blocks`` over
+    float32 keys.  Log-probabilities see a selection over seeded weights
+    faintly (every block weighs alike); this sees a block."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import families
+    from distributed_inference_demo_tpu.ops.sparse_attention import (
+        blocks_kept)
+
+    kind = cfg.period[0]
+    assert kind.attn == "sparse", "the period's first block is the sparse one"
+    name = next(n for n, k, _ in cfg.kinds if k == kind)
+    leaf = lambda key: np.asarray(  # noqa: E731
+        params.layers[f"{key}.{name}"][0, 0].astype(jnp.float32))
+    hd, nkv = cfg.head_dim, cfg.num_kv_heads
+    g = kind.num_heads // nkv
+    norm = lambda x, w: x / np.sqrt(  # noqa: E731
+        (x * x).mean(-1, keepdims=True) + cfg.norm_eps) * w
+    at = read_positions(n_prompt, len(ids))
+    with jax.default_matmul_precision("highest"):
+        x = cfg.embedding_multiplier * np.asarray(
+            params.embed["tokens"][jnp.asarray(ids)].astype(jnp.float32))
+        a = norm(x, leaf("attn_norm_w"))
+        k = np.asarray(jnp.asarray(a) @ jnp.asarray(leaf("wk"))).reshape(
+            len(ids), nkv, hd)
+        q = np.asarray(jnp.asarray(a[at]) @ jnp.asarray(
+            leaf("wq"))).reshape(len(at), nkv, g, hd)
+    if kind.qk_norm:
+        q, k = norm(q, leaf("q_norm_w")), norm(k, leaf("k_norm_w"))
+    fam = families.load(cfg.family)
+    sizes = kind.sparse_sizes
+    differ, sizes_kept = [], []
+    for i, t in enumerate(at):
+        served = kept[0, 0, t]
+        for h in range(nkv):
+            want = set(fam.kept_blocks(q[i, h], k[:t + 1, h], t, sizes))
+            got = set(np.flatnonzero(served[h]).tolist())
+            differ.append(max(len(want - got), len(got - want)))
+            sizes_kept.append(len(got))
+    return {"kept_differ_max": max(differ),
+            "kept_differ_mean": sum(differ) / len(differ),
+            "kept_served": sorted(set(sizes_kept)),
+            "kept_of": int(blocks_kept(len(ids) - 1, sizes)[1]),
+            "kept_differ_max_tol": KEPT_DIFFER_MAX}
 
 
 def reference_logprobs(cfg, params, ids, n_prompt: int):
@@ -1068,6 +1259,14 @@ def main(argv=None) -> int:
                          "period's E blocks, 0 the first; 'all'): controls "
                          "the family's replay must refuse by its paired "
                          "readings (--replay)")
+    ap.add_argument("--selection", default="",
+                    choices=("", "forced-only", "one-head", "edge-dropped"),
+                    help="a control of a block-sparse kind's selection "
+                         "(must exit 1 on a reading past dense_len): the "
+                         "forced blocks alone, one head's scores in place "
+                         "of the group's sum, or the keys before a chunk's "
+                         "first token left out of the kernels that "
+                         "straddle it")
     ap.add_argument("--replay", action="store_true",
                     help="hand each request to the family's OWN replay as "
                          "a benchmark run's canary is (its state's sample "
@@ -1105,6 +1304,8 @@ def main(argv=None) -> int:
         summaries_withheld()
     if args.bf16_coef_maps:
         bf16_coef_maps()
+    if args.selection:
+        selection_control(args.selection)
     dev = jax.devices()[0]
     cfg = model_config_for(args.model)
     if args.bf16_state or args.state_not_carried or args.conv_tail_dropped:
@@ -1200,6 +1401,7 @@ def main(argv=None) -> int:
            "logits_scaling_dropped": args.logits_scaling_dropped,
            "hc_sinkhorn_iters": args.hc_sinkhorn_iters,
            "bf16_coef_maps": args.bf16_coef_maps,
+           "selection": args.selection,
            "batch": args.batch,
            "prompt": args.prompt, "steps": args.steps,
            "positions": len(worst), "paths": paths,
@@ -1240,6 +1442,22 @@ def main(argv=None) -> int:
             row["own_token_mean_tol"] = fam.LOGPROB_MEAN_TOL
             row["ok"] = (row["ok"] and row["own_token_mean"]
                          <= fam.LOGPROB_MEAN_TOL)
+    if cfg.sparse_kind is not None:
+        # the blocks the served program kept against the equations' (the
+        # first request's; its last token was emitted and never fed), from
+        # a SECOND pass behind the tap and over that pass's own tokens: the
+        # host callback changes what the compiler fuses around it (the same
+        # seed reads 0.0051 behind it and 0.0044 without: my chip runs, PR
+        # 69), so the log-probabilities above are the program's as served
+        kept = keep_tap(read_positions(args.prompt,
+                                       args.prompt + args.steps - 1))
+        tapped = served(served_cfg, served_params, prompts, args)[0]
+        jax.effects_barrier()
+        row.update(selection_reading(
+            cfg, params, np.concatenate([prompts[0], tapped[0][:-1]]),
+            prompts.shape[1], kept))
+        row["ok"] = row["ok"] and (row["kept_differ_max"]
+                                   <= row["kept_differ_max_tol"])
     if args.replay:
         row["replay"] = replay_readings(cfg, params, prompts, toks,
                                         served_lp, state)

@@ -75,24 +75,30 @@ F32 = jnp.float32
 PEAKS = DEVICE_PEAKS["TPU v5 lite"]
 # rows that decode of a configuration's slots, as its cell's dispatches have
 # them (PERF.md section 5); a configuration not named here: every slot
-LIVE = {"granite-4.0-h-small-bf16-ep2": 20}
+LIVE = {"granite-4.0-h-small-bf16-ep2": 20, "minicpm-sala-9b-bf16": 12}
 
 
 def ssd_configs(names):
-    """The configurations with an ssd kind of block."""
+    """The configurations with an ssd kind of block, or with a linear-
+    attention kind, which rides the same call with B and C a head's own
+    (groups = heads, P = N = the head's width)."""
     for path in sorted((ROOT / "benchmark" / "configs").glob("*.json")):
         conf = json.loads(path.read_text())
         mc = conf["model_config"]
-        kinds = [k for k in mc.get("period") or [] if k.get("attn") == "ssd"]
+        kinds = [k for k in mc.get("period") or []
+                 if k.get("attn") in ("ssd", "lightning")]
         if not kinds or (names and conf["name"] not in names):
             continue
         flags = conf["serve_flags"]
         slots = int(flags[flags.index("--batch-slots") + 1])
         k = kinds[0]
+        shape = (dict(heads=k["num_heads"], p=mc["head_dim_override"],
+                      n=mc["head_dim_override"], groups=k["num_heads"])
+                 if k["attn"] == "lightning" else
+                 dict(heads=k["state_heads"], p=k["state_head_dim"],
+                      n=k["state_size"], groups=k.get("groups", 1)))
         yield dict(name=conf["name"], planes=len(kinds), slots=slots,
-                   live=LIVE.get(conf["name"], slots),
-                   heads=k["state_heads"], p=k["state_head_dim"],
-                   n=k["state_size"], groups=k.get("groups", 1))
+                   live=LIVE.get(conf["name"], slots), **shape)
 
 
 # ---------------------------------------------------------------- the forms
